@@ -7,7 +7,8 @@ text generation). Run:
         [--requests 64] [--json-out FILE]
 
 Drives the in-process LLMEngine directly (the Serve replica wraps exactly
-this engine; the router adds ~ms). On the real chip use --model opt_1_3b.
+this engine; the router adds ~ms). On the chip use --model opt_1_3b (any
+model but tiny/tiny25m refuses to run without a TPU).
 Prints one JSON line:
   {"metric": "serve_llm", "req_per_s": N, "ttft_p50_ms": N,
    "ttft_p95_ms": N, "decode_tok_per_s": N, ...}
@@ -21,6 +22,10 @@ import threading
 import time
 
 import numpy as np
+
+from ray_tpu.utils.platform import place_compile_cache
+
+place_compile_cache()
 
 
 def _resolve_draft_cfg(name, cfg):
@@ -428,6 +433,16 @@ def main() -> None:
         from ray_tpu.utils.platform import force_cpu_devices
 
         force_cpu_devices(max(1, args.tp))
+    else:
+        # Chip arm: the numbers are device numbers, so the device must be
+        # a TPU — never a quiet CPU/interpret fallback.
+        import jax as _jax
+
+        platform = _jax.devices()[0].platform
+        if platform != "tpu":
+            ap.error(f"--model {args.model} is the chip arm and needs a "
+                     f"TPU; JAX platform is {platform!r} (tiny/tiny25m "
+                     "are the CPU arms)")
 
     if args.fleet_warm:
         _run_fleet_warm(args)
@@ -751,6 +766,8 @@ def main() -> None:
         import jax as _jax
 
         row["llm_tp"] = engine.tp
+        row["platform"] = _jax.devices()[0].platform
+        row["device_kind"] = _jax.devices()[0].device_kind
         row["n_devices"] = len(_jax.devices())
         row["weight_bytes_per_device"] = _weight_bytes_per_device(
             engine.params, engine.tp)

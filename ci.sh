@@ -42,6 +42,8 @@ case "$TIER" in
       tests/test_tracing.py           # distributed tracing across hops
       tests/test_llm_serve.py         # LLM engine: paged KV, batching
       tests/test_paged_attention.py   # Pallas ragged paged-attn kernel
+      tests/test_chip_compile.py      # kernels compile for a described v5e
+      tests/test_chip_smoke.py        # chip_smoke.py phases, tiny, on CPU
       tests/test_chunked_prefill.py   # chunked prefill + token budget
       tests/test_width_bucketing.py   # pow-2 width-bucketed dispatch
       tests/test_prefix_cache.py      # prefix cache: COW page sharing
@@ -72,8 +74,11 @@ esac
 # test_paged_attention this doubles as the pallas-import guard on
 # CPU-only boxes: a broken pallas install must fail the tier, not skip
 # the kernel tests silently (the module asserts the interpret-mode
-# fallback instead of importorskip'ing).
+# fallback instead of importorskip'ing). test_chip_compile is guarded for
+# collection only: whether its tests then PASS or SKIP (no topology can
+# be described) shows in pytest's own summary.
 for guarded in tests/test_tracing.py tests/test_paged_attention.py \
+               tests/test_chip_compile.py \
                tests/test_chunked_prefill.py tests/test_width_bucketing.py \
                tests/test_prefix_cache.py \
                tests/test_spec_decode.py tests/test_kv_objects.py \

@@ -7,14 +7,17 @@ equivalent is `arena.cc` — a best-fit coalescing allocator over one mmap'd
 library; clients mmap the slab once and read extents zero-copy.
 
 The .so is compiled on demand with g++ (no pybind11 in the image; plain C ABI
-+ ctypes) and cached under `_build/`, keyed on source mtime. A pure-Python
++ ctypes) and cached under `_build/` (git-ignored), named after a hash of
+`arena.cc`'s contents — a copied or checked-out tree, whose mtimes say
+nothing, can never load a library built from other source. A pure-Python
 fallback allocator with identical semantics exists for environments without a
-toolchain (`PyArenaAlloc`).
+toolchain (`PyArenaAlloc`); `load() is None` says which one is in use.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -24,8 +27,10 @@ logger = logging.getLogger(__name__)
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _BUILD = os.path.join(_DIR, "_build")
-_SO = os.path.join(_BUILD, "libraytpu.so")
 _SRC = os.path.join(_DIR, "arena.cc")
+with open(_SRC, "rb") as _f:
+    _SO = os.path.join(
+        _BUILD, f"libraytpu-{hashlib.sha256(_f.read()).hexdigest()[:16]}.so")
 
 _lib = None
 _lib_lock = threading.Lock()
@@ -52,13 +57,12 @@ def _compile() -> bool:
 
 
 def load():
-    """Load (building if stale) the native library; None if unavailable."""
+    """Load (building if absent) the native library; None if unavailable."""
     global _lib, _build_failed
     with _lib_lock:
         if _lib is not None or _build_failed:
             return _lib
-        fresh = os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(_SRC)
-        if not fresh and not _compile():
+        if not os.path.exists(_SO) and not _compile():
             _build_failed = True
             return None
         try:

@@ -286,9 +286,11 @@ class Config:
     serve_overload_retry_after_s: float = 1.0
 
     # --- LLM serving engine ---
-    # Fused decode window: tokens generated per device dispatch with
-    # on-device sampling. The dominant knob when dispatch latency is
-    # non-trivial (remote tunnel, loaded host); 1 = per-token dispatch.
+    # Decode window: tokens generated per host sync, with on-device
+    # sampling (dense engine: one fused program; paged engine: that many
+    # back-to-back dispatches of one step program). The dominant knob
+    # when the host round trip is non-trivial (a loaded host); 1 = sync
+    # and sample on the host every token.
     llm_decode_block: int = 8
     # Finished-but-unread token streams are garbage-collected after this.
     llm_stream_ttl_s: float = 600.0

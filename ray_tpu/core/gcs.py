@@ -1047,9 +1047,25 @@ class GcsServer:
     async def _health_loop(self) -> None:
         period = self.config.heartbeat_period_s
         limit = period * self.config.heartbeat_miss_limit
+        last_tick = time.monotonic()
         while True:
             await asyncio.sleep(period)
             now = time.monotonic()
+            # How late this loop itself woke. While the GCS was not
+            # running — a blocked loop, a starved or frozen host — it read
+            # no heartbeat, so that silence is its own and not the nodes':
+            # give them the time back instead of judging them on a clock
+            # that ran while nobody could answer. (Seen on a TPU v5e host:
+            # every TPU runtime start stops the whole machine for 3-4 s,
+            # GCS and raylet alike, and a replica that boots kills its
+            # own node whenever that crosses the limit.)
+            late = now - last_tick - period
+            last_tick = now
+            if late > period:
+                logger.warning(
+                    "GCS ran %.1fs late; heartbeat deadlines extended", late)
+                for info in self.nodes.values():
+                    info.last_heartbeat += late
             for nid, info in list(self.nodes.items()):
                 if info.alive and now - info.last_heartbeat > limit:
                     self._mark_node_dead(nid, "heartbeat timeout")

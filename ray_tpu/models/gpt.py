@@ -343,9 +343,22 @@ def _attention(q, k, v, cfg: GPTConfig, *, causal_offset: int = 0, mesh=None):
     if cfg.attn_impl == "flash":
         from ray_tpu.ops.attention import flash_attention
 
-        return flash_attention(q, k, v, causal=True,
-                               block_q=cfg.attn_block_q,
-                               block_kv=cfg.attn_block_kv)
+        attn = partial(flash_attention, causal=True,
+                       block_q=cfg.attn_block_q, block_kv=cfg.attn_block_kv)
+        if mesh is None or mesh.size == 1:
+            return attn(q, k, v)
+        # The SPMD partitioner cannot split a Mosaic kernel ("wrap the
+        # call in a shard_map"), so on a multi-device mesh the kernel
+        # runs per shard: attention is independent per batch row and per
+        # head — batch over (dp, fsdp), heads over tp, sequence whole
+        # (an sp-sharded sequence is attn_impl="ring").
+        from jax.sharding import PartitionSpec
+
+        from ray_tpu.utils.jax_compat import shard_map
+
+        spec = PartitionSpec(("dp", "fsdp"), None, "tp", None)
+        return shard_map(attn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
     if cfg.attn_impl == "ring":
         from ray_tpu.parallel.ring import ring_attention_sharded
 
@@ -404,8 +417,9 @@ def forward_hidden(
 ) -> jax.Array:
     """tokens: [B, S] int32 → final-norm hidden states [B, S, D] (cfg.dtype).
 
-    `mesh` is only consulted when cfg.attn_impl == "ring" (the sp-sharded
-    ring-attention path runs in an explicit shard_map over it).
+    `mesh` is consulted by the kernel attention paths: "ring" (the
+    sp-sharded ring attention) and, on more than one device, "flash" each
+    run in an explicit shard_map over it.
     """
     x = params["wte"].astype(cfg.dtype)[tokens]
     stacked = stack_block_params(params)

@@ -48,6 +48,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ray_tpu.models.gpt import (GPTConfig, _layer_norm, stack_block_params,
                                 weight_view)
@@ -132,24 +133,45 @@ def _quant_write_full_pages(pool_l, scale_l, pages, values, tp_axis=None):
     return (pool_l.at[pages].set(q), new_scale.astype(scale_l.dtype))
 
 
-def _pool_xs(stacked, pool, quant):
-    """Per-layer scan operands: block params + the pool planes (scale
-    planes ride along when the pool is quantized — scanning [L, P+1]
-    over L hands each layer its [P+1] scale vector)."""
-    if quant:
-        return (stacked, pool["k"], pool["v"],
-                pool["k_scale"], pool["v_scale"])
-    return (stacked, pool["k"], pool["v"])
+def _planes(k, v, k_scale=None, v_scale=None):
+    """One layer's pool slices as `_scan_pool_layers` hands them to a
+    body and takes them back (scale planes only for a quantized pool)."""
+    if k_scale is None:
+        return {"k": k, "v": v}
+    return {"k": k, "v": v, "k_scale": k_scale, "v_scale": v_scale}
 
 
-def _pool_of(carry, quant):
-    """Rebuild the pool dict from a scan's stacked carry outputs."""
-    if quant:
-        new_k, new_v, new_ks, new_vs = carry
-        return {"k": new_k, "v": new_v,
-                "k_scale": new_ks, "v_scale": new_vs}
-    new_k, new_v = carry
-    return {"k": new_k, "v": new_v}
+def _scan_pool_layers(body, x, stacked, pool):
+    """Scan ``body`` over the layer stack with the page pool in the scan
+    CARRY, not as stacked xs/ys operands.
+
+    ``body(x, layer, planes) -> (x, planes)`` sees one layer's slice of
+    every pool plane (``planes[name]`` = ``pool[name][l]``; the scale
+    planes of a quantized pool ride along as ``[P+1]`` vectors) and
+    returns the updated slices, which are written back in place. The
+    carry matters for memory fit on the chip: a pool scanned as xs → ys
+    is TWO buffers in the compiled while loop (the stacked input and the
+    stacked output), which doubled the pool's HBM footprint — a
+    16-slot × 2048-token bf16 pool at OPT-1.3B (6.4 GB) plus its copy
+    did not fit a 16 GB chip beside the weights. A carried pool updated
+    by dynamic-update-slice is one buffer, aliased with the donated
+    argument."""
+
+    def step(carry, inputs):
+        x, pool = carry
+        l, layer = inputs
+        planes = {name: jax.lax.dynamic_index_in_dim(p, l, 0, keepdims=False)
+                  for name, p in pool.items()}
+        x, planes = body(x, layer, planes)
+        pool = {name: jax.lax.dynamic_update_index_in_dim(
+                    p, planes[name], l, 0)
+                for name, p in pool.items()}
+        return (x, pool), None
+
+    n_layers = pool["k"].shape[0]
+    (x, pool), _ = jax.lax.scan(
+        step, (x, pool), (jnp.arange(n_layers), stacked))
+    return x, pool
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -218,11 +240,8 @@ def prefill_batch_paged(cfg: GPTConfig, params, tokens, pool, pages, lengths):
     scale = 1.0 / math.sqrt(cfg.head_dim)
     flat_pages = pages.reshape(-1)                         # [N * n_pg]
 
-    def body(x, inputs):
-        if quant:
-            layer, k_pool_l, v_pool_l, k_sc_l, v_sc_l = inputs
-        else:
-            layer, k_pool_l, v_pool_l = inputs
+    def body(x, layer, planes):
+        k_pool_l, v_pool_l = planes["k"], planes["v"]
         h = _layer_norm(x, layer["ln1_scale"], layer["ln1_bias"])
         q, k, v = _qkv(h, layer, cfg)
         q = _rotary_pos(q, cfg.rotary_dim, pos)
@@ -243,20 +262,20 @@ def prefill_batch_paged(cfg: GPTConfig, params, tokens, pool, pages, lengths):
 
         if quant:
             k_pool_l, k_sc_l = _quant_write_full_pages(
-                k_pool_l, k_sc_l, flat_pages, paged(k))
+                k_pool_l, planes["k_scale"], flat_pages, paged(k))
             v_pool_l, v_sc_l = _quant_write_full_pages(
-                v_pool_l, v_sc_l, flat_pages, paged(v))
-            return x, (k_pool_l, v_pool_l, k_sc_l, v_sc_l)
+                v_pool_l, planes["v_scale"], flat_pages, paged(v))
+            return x, _planes(k_pool_l, v_pool_l, k_sc_l, v_sc_l)
         k_pool_l = k_pool_l.at[flat_pages].set(paged(k.astype(cfg.dtype)))
         v_pool_l = v_pool_l.at[flat_pages].set(paged(v.astype(cfg.dtype)))
-        return x, (k_pool_l, v_pool_l)
+        return x, _planes(k_pool_l, v_pool_l)
 
-    x, carry = jax.lax.scan(body, x, _pool_xs(stacked, pool, quant))
+    x, pool = _scan_pool_layers(body, x, stacked, pool)
     logits = _head(params, cfg, x)                         # [N, S, V]
     last = jnp.take_along_axis(
         logits, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1
     )[:, 0]
-    return last, _pool_of(carry, quant)
+    return last, pool
 
 
 def _chunk_paged_forward(cfg: GPTConfig, params, tokens, pool, tables,
@@ -295,12 +314,9 @@ def _chunk_paged_forward(cfg: GPTConfig, params, tokens, pool, tables,
     write_offs = (pos % ps).reshape(-1)                         # [N*C]
     kv_lens = offsets + n_valid                                 # [N]
 
-    def body(x, inputs):
-        if quant:
-            layer, k_pool_l, v_pool_l, k_sc_l, v_sc_l = inputs
-        else:
-            layer, k_pool_l, v_pool_l = inputs
-            k_sc_l = v_sc_l = None
+    def body(x, layer, planes):
+        k_pool_l, v_pool_l = planes["k"], planes["v"]
+        k_sc_l, v_sc_l = planes.get("k_scale"), planes.get("v_scale")
         h = _layer_norm(x, layer["ln1_scale"], layer["ln1_bias"])
         q, k, v = _qkv(h, layer, cfg)
         q = _rotary_pos(q, cfg.rotary_dim, pos)
@@ -341,12 +357,10 @@ def _chunk_paged_forward(cfg: GPTConfig, params, tokens, pool, tables,
             attn_out = jax.lax.psum(attn_out, tp_axis)
         x = x + attn_out
         x = _mlp(x, layer, cfg, tp_axis=tp_axis)
-        if quant:
-            return x, (k_pool_l, v_pool_l, k_sc_l, v_sc_l)
-        return x, (k_pool_l, v_pool_l)
+        return x, _planes(k_pool_l, v_pool_l, k_sc_l, v_sc_l)
 
-    x, carry = jax.lax.scan(body, x, _pool_xs(stacked, pool, quant))
-    return x, _pool_of(carry, quant)
+    x, pool = _scan_pool_layers(body, x, stacked, pool)
+    return x, pool
 
 
 @functools.partial(jax.jit, static_argnums=(0,),
@@ -484,12 +498,9 @@ def _decode_once_paged(cfg: GPTConfig, params, tokens, pool, positions,
     write_off = positions % ps                               # [B]
     kv_lengths = positions + 1                               # [B]
 
-    def body(x, inputs):
-        if quant:
-            layer, k_pool_l, v_pool_l, k_sc_l, v_sc_l = inputs
-        else:
-            layer, k_pool_l, v_pool_l = inputs
-            k_sc_l = v_sc_l = None
+    def body(x, layer, planes):
+        k_pool_l, v_pool_l = planes["k"], planes["v"]
+        k_sc_l, v_sc_l = planes.get("k_scale"), planes.get("v_scale")
         h = _layer_norm(x, layer["ln1_scale"], layer["ln1_bias"])
         q, k, v = _qkv(h, layer, cfg)
         q = _rotary_pos(q, cfg.rotary_dim, pos)
@@ -529,13 +540,11 @@ def _decode_once_paged(cfg: GPTConfig, params, tokens, pool, positions,
             attn_out = jax.lax.psum(attn_out, tp_axis)
         x = x + attn_out[:, None, :]
         x = _mlp(x, layer, cfg, tp_axis=tp_axis)
-        if quant:
-            return x, (k_pool_l, v_pool_l, k_sc_l, v_sc_l)
-        return x, (k_pool_l, v_pool_l)
+        return x, _planes(k_pool_l, v_pool_l, k_sc_l, v_sc_l)
 
-    x, carry = jax.lax.scan(body, x, _pool_xs(stacked, pool, quant))
+    x, pool = _scan_pool_layers(body, x, stacked, pool)
     logits = _head(params, cfg, x)[:, 0]
-    return logits, _pool_of(carry, quant)
+    return logits, pool
 
 
 def _sample_next(logits, temps, key):
@@ -561,27 +570,6 @@ def _last_valid_logits(cfg: GPTConfig, params, x, n_valid):
         logits,
         jnp.maximum(n_valid - 1, 0)[:, None, None].astype(jnp.int32),
         axis=1)[:, 0]                                      # [N, V]
-
-
-def _decode_multi_scan(cfg: GPTConfig, params, tokens, pool, positions,
-                       tables, n_steps: int, temps, key, attn_impl: str,
-                       tp_axis: str | None = None):
-    """Shared fused-window scan body (`decode_multi_paged` runs it
-    directly; the tp twin runs it inside a shard_map with tp_axis set)
-    — ONE implementation so the sampling/cursor math cannot diverge
-    across the llm_tp knob."""
-
-    def step(carry, _):
-        toks, pos, pool, key = carry
-        logits, pool = _decode_once_paged(
-            cfg, params, toks, pool, pos, tables, attn_impl,
-            tp_axis=tp_axis)
-        nxt, _scaled, key = _sample_next(logits, temps, key)
-        return (nxt, pos + 1, pool, key), nxt
-
-    (_, _, pool, _), out = jax.lax.scan(
-        step, (tokens, positions, pool, key), None, length=n_steps)
-    return out, pool
 
 
 def _spec_propose_scan(cfg: GPTConfig, params, tokens, pool, positions,
@@ -623,18 +611,60 @@ def decode_step_paged(cfg: GPTConfig, params, tokens, pool, positions,
                               attn_impl)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 6),
+@functools.partial(jax.jit, static_argnums=(0,),
                    static_argnames=("attn_impl",), donate_argnums=(3,))
+def _decode_sample_paged(cfg: GPTConfig, params, tokens, pool, positions,
+                         tables, temps, key, *, attn_impl: str = "gather"):
+    """One decode-window step: `_decode_once_paged` + on-device
+    sampling. → (next tokens [B] int32, positions + 1, updated pool,
+    advanced key)."""
+    logits, pool = _decode_once_paged(cfg, params, tokens, pool, positions,
+                                      tables, attn_impl)
+    nxt, _scaled, key = _sample_next(logits, temps, key)
+    return nxt, positions + 1, pool, key
+
+
+def _decode_window(step, tokens, pool, positions, n_steps: int, key):
+    """`n_steps` back-to-back dispatches of one jitted step program
+    (`step(tokens, pool, positions, key)` → the same four, advanced).
+    Tokens, cursors and the donated pool stay on the device between
+    dispatches; all of them are queued before anything is read. The
+    window's tokens are then fetched and stacked on the HOST — where the
+    engine wants them anyway — so the window compiles nothing of its
+    own: a device-side stack would be one more small program per
+    (n_steps, B). → (tokens_out [n_steps, B] int32 numpy, updated pool)."""
+    out = []
+    for _ in range(n_steps):
+        tokens, positions, pool, key = step(tokens, pool, positions, key)
+        out.append(tokens)
+    return np.stack(jax.device_get(out)), pool
+
+
 def decode_multi_paged(cfg: GPTConfig, params, tokens, pool, positions,
                        tables, n_steps: int, temps, key, *,
                        attn_impl: str = "gather"):
-    """`n_steps` fused paged-decode steps with on-device sampling (the
-    paged twin of decode.decode_multi — the engine pre-allocates pages
+    """`n_steps` paged-decode steps with on-device sampling (the paged
+    twin of decode.decode_multi — the engine pre-allocates pages
     covering positions + n_steps before dispatch, so tables are static
     across the window). → (tokens_out [n_steps, B] int32, updated pool).
-    """
-    return _decode_multi_scan(cfg, params, tokens, pool, positions,
-                              tables, n_steps, temps, key, attn_impl)
+
+    The window is a `_decode_window` of ONE step program, not one
+    program scanning over steps. A scan over steps around the scan over
+    layers made the TPU compiler re-lay the whole pool out for the
+    outer loop (head_dim 64 pads to 128 lanes there — a 2x copy of a
+    6.4 GB pool), which did not fit a 16 GB chip at OPT-1.3B; the
+    single-loop step program keeps the pool in the layout it arrives
+    in. The only program a window compiles is that step
+    (`_decode_sample_paged`), one per table width, whatever n_steps is:
+    under the engine's compile_watch label `decode_multi_paged`,
+    `jax_compiles_total{fn}` counts table widths. What it costs is
+    n_steps host dispatches per window where the scan paid one."""
+
+    def step(toks, kv, pos, rng):
+        return _decode_sample_paged(cfg, params, toks, kv, pos, tables,
+                                    temps, rng, attn_impl=attn_impl)
+
+    return _decode_window(step, tokens, pool, positions, n_steps, key)
 
 
 @functools.partial(jax.jit, static_argnums=(0,),
@@ -820,33 +850,46 @@ def decode_step_paged_tp(cfg: GPTConfig, params, tokens, pool, positions,
         params, tokens, pool, positions, tables)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 6),
+@functools.partial(jax.jit, static_argnums=(0,),
                    static_argnames=("mesh", "attn_impl"),
                    donate_argnums=(3,))
-def decode_multi_paged_tp(cfg: GPTConfig, params, tokens, pool, positions,
-                          tables, n_steps: int, temps, key, *, mesh,
-                          attn_impl: str = "gather"):
-    """`decode_multi_paged` over a tp mesh: the whole fused window —
-    n_steps decode passes AND the on-device sampling — runs inside ONE
-    shard_map, so a window still costs one dispatch and one host
-    transfer. Sampling consumes replicated logits with a replicated key:
-    every shard draws the same token, the only cross-shard values being
-    the per-layer psums inside the decode body (`_decode_multi_scan` —
-    the non-tp program's own body, tp_axis threaded)."""
+def _decode_sample_paged_tp(cfg: GPTConfig, params, tokens, pool, positions,
+                            tables, temps, key, *, mesh,
+                            attn_impl: str = "gather"):
+    """`_decode_sample_paged` over a tp mesh: the decode pass AND the
+    sampling run inside one shard_map. Sampling consumes replicated
+    logits with a replicated key, so every shard draws the same token;
+    the only cross-shard values are the per-layer psums."""
     if attn_impl not in ("gather", "kernel"):
         raise ValueError(
             f"attn_impl must be gather|kernel, got {attn_impl!r}")
     pspecs, kvspecs, rep = _tp_specs(params, pool)
 
     def body(params, tokens, pool, positions, tables, temps, key):
-        return _decode_multi_scan(cfg, params, tokens, pool, positions,
-                                  tables, n_steps, temps, key, attn_impl,
-                                  tp_axis="tp")
+        logits, pool = _decode_once_paged(
+            cfg, params, tokens, pool, positions, tables, attn_impl,
+            tp_axis="tp")
+        nxt, _scaled, key = _sample_next(logits, temps, key)
+        return nxt, positions + 1, pool, key
 
     return _smap(body, mesh,
                  (pspecs, rep, kvspecs, rep, rep, rep, rep),
-                 (rep, kvspecs))(
+                 (rep, rep, kvspecs, rep))(
         params, tokens, pool, positions, tables, temps, key)
+
+
+def decode_multi_paged_tp(cfg: GPTConfig, params, tokens, pool, positions,
+                          tables, n_steps: int, temps, key, *, mesh,
+                          attn_impl: str = "gather"):
+    """`decode_multi_paged` over a tp mesh: the same `_decode_window`
+    of the sharded step program."""
+
+    def step(toks, kv, pos, rng):
+        return _decode_sample_paged_tp(
+            cfg, params, toks, kv, pos, tables, temps, rng,
+            mesh=mesh, attn_impl=attn_impl)
+
+    return _decode_window(step, tokens, pool, positions, n_steps, key)
 
 
 @functools.partial(jax.jit, static_argnames=("mesh",), donate_argnums=(0,))

@@ -28,10 +28,11 @@ Design notes:
 - Softmax statistics stay fp32; the QKᵀ/PV contractions run in the input
   dtype with fp32 accumulate (MXU fast path — upcasting operands would
   drop the MXU into its ~4x slower fp32 mode).
-- On non-TPU backends the kernel runs under ``interpret=True`` so every
-  test exercises the identical code path (same pattern as
-  ops/attention.py); a broken pallas install fails loudly in CI instead
-  of silently skipping.
+- On non-TPU backends the kernels run under ``interpret=True`` (same
+  pattern as ops/attention.py): that checks their semantics, not that
+  Mosaic can compile them — compile acceptance is
+  tests/test_chip_compile.py, numbers on hardware chip_smoke.py. A
+  broken pallas install fails loudly in CI instead of silently skipping.
 """
 
 from __future__ import annotations
@@ -91,9 +92,12 @@ def _decode_kernel(
             v = v.astype(jnp.float32) * vs_ref[page]
         # s[h, t] = q[h] · k[t, h] — a per-head batched matvec; decode
         # attention is HBM-bound (~2 flops/byte), so MXU shape efficiency
-        # is irrelevant next to reading the page once.
-        s = jnp.einsum("hk,thk->ht", q, k,
-                       preferred_element_type=jnp.float32) * sm_scale
+        # is irrelevant next to reading the page once. Written as the
+        # prefill kernel's contraction with a one-row query block:
+        # Mosaic's matmul needs a non-contracting lhs dimension, and
+        # refused the bare "hk,thk->ht".
+        s = jnp.einsum("chk,thk->cht", q[None], k,
+                       preferred_element_type=jnp.float32)[0] * sm_scale
         # In-page raggedness: positions at or past the slot's kv length
         # are masked (covers the null page when it IS the write target of
         # an idle slot, and a live slot's partial last page).
@@ -107,8 +111,8 @@ def _decode_kernel(
         p = jnp.exp(s - m_new[:, :1])        # [H, ps] fp32
         corr = jnp.exp(m_prev[:, :1] - m_new[:, :1])
         l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-        pv = jnp.einsum("ht,thk->hk", p.astype(v.dtype), v,
-                        preferred_element_type=jnp.float32)
+        pv = jnp.einsum("cht,thk->chk", p.astype(v.dtype)[None], v,
+                        preferred_element_type=jnp.float32)[0]
         acc_ref[...] = acc_ref[...] * corr + pv
         m_ref[...] = m_new
 

@@ -114,12 +114,11 @@ def make_mesh(
             sizes.setdefault(ax, 1)
         sizes = MeshConfig(**{k: sizes[k] for k in MESH_AXES}).resolve(n)
     shape = tuple(sizes[a] for a in MESH_AXES)
-    try:
-        from jax.experimental import mesh_utils
+    from jax.experimental import mesh_utils
 
-        dev_array = mesh_utils.create_device_mesh(shape, devices=list(devices))
-    except Exception:
-        dev_array = np.asarray(list(devices)).reshape(shape)
+    # No fallback to a plain reshape: on a real 2x2 host a mesh_utils
+    # failure must surface, not quietly yield an ICI-oblivious ordering.
+    dev_array = mesh_utils.create_device_mesh(shape, devices=list(devices))
     return Mesh(dev_array, MESH_AXES)
 
 

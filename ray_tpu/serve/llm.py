@@ -850,6 +850,13 @@ class LLMEngine:
             self._rt.spec_draft_propose = _w(
                 _mp(_paged.spec_draft_propose_tp, mesh=self.mesh),
                 "spec_draft_propose")
+        else:
+            # Weights loaded from a checkpoint are host arrays; place
+            # them once — left on the host, every dispatch would upload
+            # the whole model again (a no-op for device arrays).
+            self.params = jax.device_put(self.params)
+            if spec_draft:
+                self.draft_params = jax.device_put(self.draft_params)
         self._spec_accept_ewma: float | None = None
         self._spec_span_seq = 0
         # Prefix cache (serve/prefix_cache.py): refcounted COW page
@@ -924,10 +931,11 @@ class LLMEngine:
         self.tokens = np.zeros(n_slots, np.int32)
         self.positions = np.zeros(n_slots, np.int32)
         self.temps = np.zeros(n_slots, np.float32)
-        # Fused decode-window sizes (largest first): one dispatch advances
-        # all slots k tokens with on-device sampling, amortizing the
-        # host↔device round trip that dominates per-token latency on
-        # remote-dispatch links. Power-of-two ladder bounds compile count.
+        # Decode-window sizes (largest first): one window advances all
+        # slots k tokens with on-device sampling and ONE host sync,
+        # amortizing the host↔device round trip per token. Power-of-two
+        # ladder bounds the dense engine's compile count (the paged
+        # window is k dispatches of one program, whatever k).
         if decode_block is None:
             from ray_tpu.core.config import runtime_config
 
@@ -1492,6 +1500,8 @@ class LLMEngine:
                 m["kv_pool_bytes"] = sum(
                     int(math.prod(a.shape) * a.dtype.itemsize)
                     for a in self.cache.values())
+            m["weight_bytes"] = sum(
+                int(a.nbytes) for a in self._rt.jax.tree.leaves(self.params))
             m["llm_tp"] = self.tp
             if self.tp > 1:
                 m["mesh_shape"] = {"tp": self.tp}
@@ -1573,7 +1583,7 @@ class LLMEngine:
         if m["completed"]:
             m["ttft_mean_s"] = m["ttft_sum"] / m["completed"]
         # Engine-side rates: what the chip sustains, independent of the
-        # client/tunnel path.
+        # client path.
         if m["decode_time_s"] > 0:
             m["engine_decode_tok_s"] = (
                 m["slot_step_sum"] / m["decode_time_s"])

@@ -1,17 +1,10 @@
-"""Cross-version JAX API shims.
+"""The two JAX API names every sharding call site goes through.
 
-The repo pins no JAX version: driver boxes run 0.4.x while the sharding
-APIs it targets stabilized at different points (``shard_map`` graduated
-from ``jax.experimental.shard_map`` to a top-level ``jax.shard_map`` with
-renamed keywords in 0.6). Every call site goes through this module
-instead of feature-testing inline, and graftlint's JAX-COMPAT rule
+Call sites import ``shard_map`` and ``tree_map`` from here instead of
+spelling the JAX API inline; graftlint's JAX-COMPAT rule
 (tools/graftlint/jax_compat.py) statically flags any direct use of a
-symbol the installed version does not ship — this shim is the canonical
-rewrite target its findings suggest.
-
-Feature detection is attribute-based (``getattr``), never version-string
-parsing: prereleases and vendor builds lie about versions, attributes
-don't.
+symbol the installed version does not ship, and this module is the
+canonical rewrite target its findings suggest.
 """
 
 from __future__ import annotations
@@ -32,43 +25,21 @@ def shard_map(
     check_vma: bool = True,
     axis_names: Any = None,
 ) -> Callable:
-    """``jax.shard_map`` with the 0.6+ keyword surface, on any JAX.
+    """``jax.shard_map``.
 
-    - ``check_vma``: the 0.6 name for replication checking; forwarded as
-      ``check_rep`` to the experimental API.
+    - ``check_vma``: replication (varying-manual-axes) checking.
     - ``axis_names``: the set of mesh axes the body is *manual* over
       (partial-manual mode); ``None`` means fully manual (every mesh
-      axis). On the experimental fallback, partial-manual is DEGRADED to
-      fully manual: 0.4.x expresses it as ``auto`` = the complement axis
-      set, but its lowering is broken at the XLA level (``axis_index``
-      emits a ``PartitionId`` the SPMD partitioner rejects; sharded
-      operands trip ``IsManualSubgroup`` check failures). Degrading is
-      sound for bodies that never name an auto axis — per-spec sharding
-      over those axes becomes replication, same numerics, more memory —
-      and bodies that DO name one would have crashed in XLA anyway.
+      axis).
     """
-    native = getattr(jax, "shard_map", None)
-    if native is not None:
-        kwargs: dict[str, Any] = dict(
-            mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma)
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        return native(f, **kwargs)
-
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma)
+    kwargs: dict[str, Any] = dict(
+        mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=check_vma)
+    if axis_names is not None:
+        kwargs["axis_names"] = set(axis_names)
+    return jax.shard_map(f, **kwargs)
 
 
 def tree_map(f: Callable, tree: Any, *rest: Any, **kwargs: Any) -> Any:
-    """``jax.tree.map`` where it exists (0.4.25+), else the tree_util
-    spelling that every JAX ships. (``jax.tree_map`` itself warns from
-    0.4.25 and is gone in 0.6.)"""
-    ns = getattr(jax, "tree", None)
-    mapper = getattr(ns, "map", None) if ns is not None else None
-    if mapper is None:
-        mapper = jax.tree_util.tree_map
-    return mapper(f, tree, *rest, **kwargs)
+    """``jax.tree.map``."""
+    return jax.tree.map(f, tree, *rest, **kwargs)

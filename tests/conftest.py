@@ -5,8 +5,9 @@ Mirrors the reference's multi-node-without-a-cluster trick
 here, N XLA host devices on one process stand in for N TPU chips so every
 sharding/collective path is exercised without a pod.
 
-Platform forcing lives in ray_tpu.utils.platform (shared with bench.py and
-__graft_entry__.py) — it must run before any backend is initialized.
+Platform forcing lives in ray_tpu.utils.platform (shared with
+__graft_entry__.py and bench.py's BENCH_SMOKE rehearsal) — it must run
+before any backend is initialized.
 """
 
 import os
@@ -28,8 +29,12 @@ import pytest  # noqa: E402
 # small prefill/verify programs (one per pow-2 table width per config) —
 # each compiles in well under 0.5 s, but a cold suite pays hundreds of
 # them; persisting everything keeps cold-box tier-1 inside its budget.
-_cache_dir = os.environ.get("RAY_TPU_TEST_JAX_CACHE",
-                            "/tmp/ray_tpu_jax_cache")
+# A JAX_COMPILATION_CACHE_DIR set from outside wins; otherwise the tests
+# use a fixed directory of their own (not the checkout's .jax_cache, so
+# a test run never fills the cache chip runs read).
+_cache_dir = (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+              or os.environ.get("RAY_TPU_TEST_JAX_CACHE",
+                                "/tmp/ray_tpu_jax_cache"))
 os.makedirs(_cache_dir, exist_ok=True)
 jax.config.update("jax_compilation_cache_dir", _cache_dir)
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)

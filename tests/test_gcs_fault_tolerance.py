@@ -129,3 +129,50 @@ class TestWalDurability:
             assert ray_tpu.get(c2.incr.remote(), timeout=60) == 2
         finally:
             ray_tpu.shutdown()
+
+
+class TestHeartbeatDetector:
+    """The node failure detector at handler level (no cluster): a node is
+    dead after `heartbeat_miss_limit` periods of ITS silence — time the
+    GCS itself was not running does not count against it."""
+
+    LIMIT_S = 0.2       # 4 periods of 0.05 s
+
+    def _run(self, gcs_frozen_s: float, node_silent_s: float) -> bool:
+        """Register a node, heartbeat once, then block the GCS's own loop
+        for `gcs_frozen_s` and stay silent for a further `node_silent_s`
+        of live GCS time. → is the node still alive?"""
+        import asyncio
+
+        from ray_tpu.core.config import Config
+        from ray_tpu.core.gcs import GcsServer
+
+        async def scenario():
+            gcs = GcsServer(Config(heartbeat_period_s=0.05,
+                                   heartbeat_miss_limit=4))
+            nid = b"n" * 16
+            await gcs._register_node(None, {
+                "node_id": nid, "address": ("127.0.0.1", 1),
+                "resources": {"CPU": 1}})
+            health = asyncio.ensure_future(gcs._health_loop())
+            try:
+                await asyncio.sleep(0.06)            # detector is ticking
+                await gcs._heartbeat(None, {
+                    "node_id": nid, "resources_available": {"CPU": 1}})
+                time.sleep(gcs_frozen_s)             # the whole GCS stops
+                await asyncio.sleep(0.06 + node_silent_s)
+                return gcs.nodes[nid].alive
+            finally:
+                health.cancel()
+
+        return asyncio.run(scenario())
+
+    @pytest.mark.parametrize("gcs_frozen_s, node_silent_s, alive", [
+        (0.0, 0.0, True),        # control: heartbeating node, live GCS
+        (0.0, 0.5, False),       # node silent past the limit: dead
+        (0.6, 0.0, True),        # GCS frozen 3x the limit: not the node's
+        (0.6, 0.5, False),       # ...and a node silent afterwards still dies
+    ])
+    def test_gcs_pause_is_not_node_silence(self, gcs_frozen_s,
+                                           node_silent_s, alive):
+        assert self._run(gcs_frozen_s, node_silent_s) is alive
