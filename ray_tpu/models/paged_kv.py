@@ -43,6 +43,7 @@ preempt-by-recompute when the pool runs dry.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 
@@ -624,7 +625,12 @@ def _decode_sample_paged(cfg: GPTConfig, params, tokens, pool, positions,
     return nxt, positions + 1, pool, key
 
 
-def _decode_window(step, tokens, pool, positions, n_steps: int, key):
+def _no_phase(_name: str):
+    return contextlib.nullcontext()
+
+
+def _decode_window(step, tokens, pool, positions, n_steps: int, key,
+                   phase=_no_phase):
     """`n_steps` back-to-back dispatches of one jitted step program
     (`step(tokens, pool, positions, key)` → the same four, advanced).
     Tokens, cursors and the donated pool stay on the device between
@@ -632,17 +638,25 @@ def _decode_window(step, tokens, pool, positions, n_steps: int, key):
     window's tokens are then fetched and stacked on the HOST — where the
     engine wants them anyway — so the window compiles nothing of its
     own: a device-side stack would be one more small program per
-    (n_steps, B). → (tokens_out [n_steps, B] int32 numpy, updated pool)."""
+    (n_steps, B). → (tokens_out [n_steps, B] int32 numpy, updated pool).
+
+    `phase(name)` is the caller's recorder, a context manager factory
+    (`LLMEngine._phase`): this loop is the one part of an engine tick the
+    engine cannot see into, so each dispatch reports as `decode.dispatch`
+    and the fetch as `decode.pull`."""
     out = []
     for _ in range(n_steps):
-        tokens, positions, pool, key = step(tokens, pool, positions, key)
+        with phase("decode.dispatch"):
+            tokens, positions, pool, key = step(tokens, pool, positions, key)
         out.append(tokens)
-    return np.stack(jax.device_get(out)), pool
+    with phase("decode.pull"):
+        toks_out = np.stack(jax.device_get(out))
+    return toks_out, pool
 
 
 def decode_multi_paged(cfg: GPTConfig, params, tokens, pool, positions,
                        tables, n_steps: int, temps, key, *,
-                       attn_impl: str = "gather"):
+                       attn_impl: str = "gather", phase=_no_phase):
     """`n_steps` paged-decode steps with on-device sampling (the paged
     twin of decode.decode_multi — the engine pre-allocates pages
     covering positions + n_steps before dispatch, so tables are static
@@ -664,7 +678,7 @@ def decode_multi_paged(cfg: GPTConfig, params, tokens, pool, positions,
         return _decode_sample_paged(cfg, params, toks, kv, pos, tables,
                                     temps, rng, attn_impl=attn_impl)
 
-    return _decode_window(step, tokens, pool, positions, n_steps, key)
+    return _decode_window(step, tokens, pool, positions, n_steps, key, phase)
 
 
 @functools.partial(jax.jit, static_argnums=(0,),
@@ -880,7 +894,7 @@ def _decode_sample_paged_tp(cfg: GPTConfig, params, tokens, pool, positions,
 
 def decode_multi_paged_tp(cfg: GPTConfig, params, tokens, pool, positions,
                           tables, n_steps: int, temps, key, *, mesh,
-                          attn_impl: str = "gather"):
+                          attn_impl: str = "gather", phase=_no_phase):
     """`decode_multi_paged` over a tp mesh: the same `_decode_window`
     of the sharded step program."""
 
@@ -889,7 +903,7 @@ def decode_multi_paged_tp(cfg: GPTConfig, params, tokens, pool, positions,
             cfg, params, toks, kv, pos, tables, temps, rng,
             mesh=mesh, attn_impl=attn_impl)
 
-    return _decode_window(step, tokens, pool, positions, n_steps, key)
+    return _decode_window(step, tokens, pool, positions, n_steps, key, phase)
 
 
 @functools.partial(jax.jit, static_argnames=("mesh",), donate_argnums=(0,))

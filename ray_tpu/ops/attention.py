@@ -170,6 +170,7 @@ def _fwd(q, k, v, causal, sm_scale, block_q, block_kv, interpret):
             pltpu.VMEM((bq, K), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attn_fwd",
     )(q, k, v)
     return o[:, :, :S], lse[:, :, :S, 0]
 
@@ -318,6 +319,7 @@ def _bwd_impl(q, k, v, o, lse, do, dlse, causal, sm_scale, block_q, block_kv, in
         out_shape=jax.ShapeDtypeStruct((B, H, S_pad, K), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, K), jnp.float32)],
         interpret=interpret,
+        name="flash_attn_bwd_dq",
     )(q, k, v, do, lse, delta)
 
     # kv-major grid: program_id(2)=ik, program_id(3)=iq.
@@ -342,6 +344,7 @@ def _bwd_impl(q, k, v, o, lse, do, dlse, causal, sm_scale, block_q, block_kv, in
             pltpu.VMEM((bk, K), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attn_bwd_dkv",
     )(q, k, v, do, lse, delta)
     return dq[:, :, :S], dk[:, :, :T], dv[:, :, :T]
 

@@ -204,6 +204,7 @@ def paged_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, K), q.dtype),
         interpret=interpret,
+        name="paged_decode_attn",
     )(*prefetch, q, k_pool, v_pool)
 
 
@@ -365,6 +366,7 @@ def paged_prefill_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, C, H, K), q.dtype),
         interpret=interpret,
+        name="paged_prefill_attn",
     )(*prefetch, q, k_pool, v_pool)
 
 

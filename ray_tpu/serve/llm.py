@@ -221,6 +221,90 @@ def _ring_pctls(ring) -> tuple[float, float]:
             round(s[max(0, math.ceil(len(s) * 0.95) - 1)], 3))
 
 
+# The engine tick's phases, in `_step`'s order (LLMEngine._phase). Flat:
+# at most one is open at a time. Host-only phases touch no device;
+# `*.dispatch` hand a program to the runtime and return when it is
+# queued; `*.pull` block until the device has produced what they fetch;
+# `spec_verify` is the speculative tick's verify dispatch AND its pull.
+_HOST_PHASES = ("admit", "prefill.build", "prefill.graduate", "plan", "emit")
+_DISPATCH_PHASES = ("prefill.dispatch", "decode.dispatch")
+_PULL_PHASES = ("prefill.pull", "decode.pull")
+_PHASES = _HOST_PHASES + _DISPATCH_PHASES + _PULL_PHASES + ("spec_verify",)
+
+
+class _TickAccount:
+    """Where the engine thread's time went, over WHOLE ticks.
+
+    A tick runs from one `begin()` (the engine's `admit` phase opening)
+    to the next. Phase durations collect in the open tick and are folded
+    into the totals when it closes, so `phase_s` and `tick_s` always
+    cover the same ticks and their difference is time the engine spent
+    in no phase. Ticks that dispatched nothing (the idle loop) are
+    dropped with their phases: the account describes a working engine.
+    `begin`/`add` are the engine thread's; `reset`/`snapshot` anyone's."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._t0 = 0.0
+        self._cur_s = dict.fromkeys(_PHASES, 0.0)
+        self._cur_n = dict.fromkeys(_PHASES, 0)
+        self.reset()        # no tick is open yet: the first is not counted
+
+    def reset(self) -> None:
+        with self._lock:
+            self.phase_s = dict.fromkeys(_PHASES, 0.0)
+            self.phase_n = dict.fromkeys(_PHASES, 0)
+            self.ticks = self.decode_ticks = 0
+            self.tick_s = self.decode_tick_s = 0.0
+            # The open tick began before the reset: it is not counted.
+            self._stale = True
+
+    def begin(self, now: float) -> None:
+        with self._lock:
+            cur_s, cur_n = self._cur_s, self._cur_n
+            decoded = cur_n["decode.dispatch"] + cur_n["spec_verify"]
+            if not self._stale and (decoded or cur_n["prefill.dispatch"]):
+                dt = now - self._t0
+                self.ticks += 1
+                self.tick_s += dt
+                if decoded:
+                    self.decode_ticks += 1
+                    self.decode_tick_s += dt
+                for name in _PHASES:
+                    self.phase_s[name] += cur_s[name]
+                    self.phase_n[name] += cur_n[name]
+            self._stale = False
+            self._t0 = now
+            self._cur_s = dict.fromkeys(_PHASES, 0.0)
+            self._cur_n = dict.fromkeys(_PHASES, 0)
+
+    def add(self, name: str, seconds: float) -> None:
+        with self._lock:
+            self._cur_s[name] += seconds
+            self._cur_n[name] += 1
+
+    def snapshot(self) -> dict:
+        """The `metrics()` keys this account owns."""
+        with self._lock:
+            phase_s, phase_n = dict(self.phase_s), dict(self.phase_n)
+            tick_s, ticks = self.tick_s, self.ticks
+            decode_tick_s, decode_ticks = self.decode_tick_s, self.decode_ticks
+        out = {"ticks": ticks, "tick_s": tick_s,
+               "phase_s": phase_s, "phase_n": phase_n}
+        if decode_ticks:
+            out["tick_ms_mean"] = decode_tick_s / decode_ticks * 1000.0
+        if tick_s > 0:
+            out["tick_host_share"] = (
+                sum(phase_s[p] for p in _HOST_PHASES) / tick_s)
+            out["tick_blocked_share"] = (
+                sum(phase_s[p] for p in _PULL_PHASES) / tick_s)
+        if phase_n["decode.dispatch"]:
+            out["decode_dispatch_ms_mean"] = (
+                phase_s["decode.dispatch"] / phase_n["decode.dispatch"]
+                * 1000.0)
+        return out
+
+
 def _softmax_f64(row: np.ndarray) -> np.ndarray:
     z = row.astype(np.float64)
     z -= z.max()
@@ -858,7 +942,6 @@ class LLMEngine:
             if spec_draft:
                 self.draft_params = jax.device_put(self.draft_params)
         self._spec_accept_ewma: float | None = None
-        self._spec_span_seq = 0
         # Prefix cache (serve/prefix_cache.py): refcounted COW page
         # sharing across requests — admission binds the longest cached
         # chunk-aligned prefix and chunked prefill starts at the first
@@ -997,7 +1080,14 @@ class LLMEngine:
         self._budget_util_ewma: float | None = None
         self._ttft_seq = 0                    # sampled TTFT-breakdown spans
         self._step_tags: dict | None = None   # lazy: replica id + impl
-        self._window_seq = 0                  # decode windows dispatched
+        # The tick's account of itself (see _phase) and the sequence
+        # numbers of the two phases that are also sampled operator spans.
+        self._ticks = _TickAccount()
+        self._span_seq = {"decode_window": 0, "spec_verify": 0}
+        self._annotate = jax.profiler.TraceAnnotation
+        # High-water mark of requests still owed a first token, kept
+        # like _min_free_pages (engine thread writes, reset re-bases).
+        self._awaiting_max = 0
         self._shutdown = threading.Event()
         self._fatal: str | None = None
         # Drain protocol (replica scale-down / version roll): draining
@@ -1413,20 +1503,57 @@ class LLMEngine:
             self._spec_accept_ewma = None
             if self.kv_mode == "paged":
                 self._min_free_pages = len(self.free_pages)
+            self._awaiting_max = self._awaiting_first_token()
+        self._ticks.reset()
 
     _SPAN_SAMPLE = 64
 
-    def _window_span(self):
-        """Tracing span for 1-in-N decode windows (first window always):
-        enough to see engine step time in /api/traces without the decode
-        loop minting a fresh root trace per window — at decode rates that
-        floods the GCS per-trace index and would eventually exhaust the
-        bounded profile table, starving every other trace producer. The
-        step-latency histogram still observes EVERY window."""
-        seq, self._window_seq = self._window_seq, self._window_seq + 1
-        if seq % self._SPAN_SAMPLE == 0:
-            return tracing.start_span("llm.decode_window", cat="serve_llm")
-        return contextlib.nullcontext()
+    @contextlib.contextmanager
+    def _phase(self, name: str):
+        """One phase of the engine tick, on the engine thread. For the
+        block it wraps: a `jax.profiler.TraceAnnotation("llm.<name>")` —
+        a level check while no profiler session is open, a host event on
+        the device trace's own clock while one is — and the block's
+        `perf_counter` time and one entry in the tick's account
+        (`metrics()["phase_s"]`, zeroed by reset_stats()). Phases are
+        flat: none opens inside another, and no annotation encloses the
+        tick, so the host event covering an idle gap of the device IS
+        the phase the engine was in.
+
+        `decode_window` and `spec_verify` are also operator spans in
+        /api/traces, over the same interval, for 1 entry in _SPAN_SAMPLE
+        (the first always): enough to see engine step time there without
+        the decode loop minting a fresh root trace per window — at
+        decode rates that floods the GCS per-trace index and would
+        eventually exhaust the bounded profile table, starving every
+        other trace producer. `decode_window` is only that span: it
+        groups the `decode.*` phases it encloses and has neither time of
+        its own nor an annotation."""
+        sampled = contextlib.nullcontext()
+        seq = self._span_seq.get(name)
+        if seq is not None:
+            self._span_seq[name] = seq + 1
+            if seq % self._SPAN_SAMPLE == 0:
+                sampled = tracing.start_span("llm." + name, cat="serve_llm")
+        with sampled:
+            if name == "decode_window":
+                yield
+                return
+            t0 = time.perf_counter()
+            try:
+                with self._annotate("llm." + name):
+                    yield
+            finally:
+                self._ticks.add(name, time.perf_counter() - t0)
+
+    def _awaiting_first_token(self) -> int:
+        """Requests owed a first token: queued, deferred, and bound to a
+        slot while their prompt still waits for the prefill budget (which
+        `queued` does not show: the engine admits into free slots at
+        once)."""
+        return (self.pending.qsize() + len(self._deferred)
+                + sum(1 for r in self.slot_req
+                      if r is not None and r.first_token_at is None))
 
     def _impl_tags(self) -> dict:
         """replica/impl tags for the engine-side histograms (built once,
@@ -1481,8 +1608,12 @@ class LLMEngine:
     def metrics(self) -> dict:
         with self._lock:
             active = sum(r is not None for r in self.slot_req)
+            awaiting = self._awaiting_first_token()
             m = dict(self.stats, active_slots=active,
                      queued=self.pending.qsize() + len(self._deferred),
+                     awaiting_first_token=awaiting,
+                     awaiting_first_token_max=max(self._awaiting_max,
+                                                  awaiting),
                      n_slots=self.n_slots)
             if self.kv_mode == "paged":
                 m["kv_pages_total"] = self.n_pages
@@ -1580,6 +1711,7 @@ class LLMEngine:
                 (m["decode_step_burst_ms_p50"],
                  m["decode_step_burst_ms_p95"]) = _ring_pctls(
                     self._burst_step_ms)
+        m.update(self._ticks.snapshot())
         if m["completed"]:
             m["ttft_mean_s"] = m["ttft_sum"] / m["completed"]
         # Engine-side rates: what the chip sustains, independent of the
@@ -1615,6 +1747,7 @@ class LLMEngine:
             prefilling = len(self._prefilling)
             snap: dict = {
                 "queue_depth": self.pending.qsize() + len(self._deferred),
+                "awaiting_first_token": self._awaiting_first_token(),
                 "n_slots": self.n_slots,
                 "active_slots": active,
                 "prefilling_slots": prefilling,
@@ -2175,7 +2308,7 @@ class LLMEngine:
         retroactively from the request's engine-side timestamps. Sampled
         so a request flood doesn't mint a root trace per request and
         starve the bounded profile table (same reasoning as
-        _window_span)."""
+        _phase's sampling)."""
         seq, self._ttft_seq = self._ttft_seq, self._ttft_seq + 1
         if seq % self._TTFT_SPAN_SAMPLE or req.first_chunk_at is None:
             return
@@ -2245,8 +2378,10 @@ class LLMEngine:
     _ADMIT_LOOKAHEAD = 8
     _ADMIT_BYPASS_LIMIT = 16
 
-    def _admit(self) -> None:
-        """Move queued requests into free slots.
+    def _admit(self) -> list[tuple]:
+        """Move queued requests into free slots. → the one-shot prefill
+        groups `(bucket, requests, slots)` the caller now dispatches
+        (`_prefill_group`); empty in chunked mode.
 
         One-shot mode (prefill_chunk=0): whole-prompt admission —
         same-bucket arrivals prefill in ladder-sized GROUPS via one
@@ -2347,7 +2482,7 @@ class LLMEngine:
         if blocked and len(reqs) > head_mark:
             blocked[0].admit_bypasses += 1
         if not reqs:
-            return
+            return []
         if self.prefill_chunk:
             # Chunked admission: bind request → slot now; the prompt
             # enters the pool chunk-by-chunk via _run_prefill_chunks.
@@ -2376,7 +2511,8 @@ class LLMEngine:
                 self.temps[slot] = req.temperature
                 self._chunk_pos[slot] = n_cached
                 self._prefilling.append(slot)
-            return
+            return []
+        groups = []
         by_bucket: dict[int, list[GenRequest]] = {}
         for req in reqs:
             by_bucket.setdefault(
@@ -2388,8 +2524,9 @@ class LLMEngine:
                           if k <= len(group)), 1)
                 batch = group[:n]
                 group = group[n:]
-                slots = [next(slot_iter) for _ in batch]
-                self._prefill_group(bucket, batch, slots)
+                groups.append((bucket, batch,
+                               [next(slot_iter) for _ in batch]))
+        return groups
 
     def _bind_cached_prefix(self, slot: int, req: GenRequest,
                             entry) -> int:
@@ -2479,46 +2616,50 @@ class LLMEngine:
         GROUP of requests in a single dispatch."""
         rt = self._rt
         n = len(group)
-        padded = np.zeros((n, bucket), np.int32)
-        lengths = np.zeros(n, np.int32)
-        for i, req in enumerate(group):
-            lengths[i] = len(req.prompt_ids)
-            padded[i, :lengths[i]] = req.prompt_ids
-        t0 = time.perf_counter()
-        for req in group:
-            if req.first_chunk_at is None:
-                req.first_chunk_at = t0
+        with self._phase("prefill.build"):
+            padded = np.zeros((n, bucket), np.int32)
+            lengths = np.zeros(n, np.int32)
+            for i, req in enumerate(group):
+                lengths[i] = len(req.prompt_ids)
+                padded[i, :lengths[i]] = req.prompt_ids
+            t0 = time.perf_counter()
+            for req in group:
+                if req.first_chunk_at is None:
+                    req.first_chunk_at = t0
         try:
-            if self.kv_mode == "paged":
-                # _admit reserved pool headroom; grow each slot to cover
-                # prompt + first decode write (single-threaded engine, so
-                # the reservation cannot race).
-                pages = np.zeros((n, self._pages_for(bucket - 1)), np.int32)
-                for i, slot in enumerate(slots):
-                    grown = self._grow_slot(slot, int(lengths[i]))
-                    if not grown:   # _admit reserved headroom; can't fail
-                        raise RuntimeError("page reservation desync")
-                    got = int(self.slot_n_pages[slot])
-                    take = min(got, pages.shape[1])
-                    pages[i, :take] = self.page_table[slot, :take]
-                last_logits, self.cache = rt.prefill_batch_paged(
-                    self.cfg, self.params, rt.jnp.asarray(padded),
-                    self.cache, rt.jnp.asarray(pages),
-                    rt.jnp.asarray(lengths))
+            with self._phase("prefill.dispatch"):
+                if self.kv_mode == "paged":
+                    # _admit reserved pool headroom; grow each slot to
+                    # cover prompt + first decode write (single-threaded
+                    # engine, so the reservation cannot race).
+                    pages = np.zeros((n, self._pages_for(bucket - 1)),
+                                     np.int32)
+                    for i, slot in enumerate(slots):
+                        grown = self._grow_slot(slot, int(lengths[i]))
+                        if not grown:   # _admit reserved headroom
+                            raise RuntimeError("page reservation desync")
+                        got = int(self.slot_n_pages[slot])
+                        take = min(got, pages.shape[1])
+                        pages[i, :take] = self.page_table[slot, :take]
+                    last_logits, self.cache = rt.prefill_batch_paged(
+                        self.cfg, self.params, rt.jnp.asarray(padded),
+                        self.cache, rt.jnp.asarray(pages),
+                        rt.jnp.asarray(lengths))
+                elif n == 1:
+                    last_logits, self.cache = rt.prefill(
+                        self.cfg, self.params, rt.jnp.asarray(padded),
+                        self.cache, rt.jnp.int32(slots[0]),
+                        rt.jnp.int32(int(lengths[0])))
+                else:
+                    last_logits, self.cache = rt.prefill_batch(
+                        self.cfg, self.params, rt.jnp.asarray(padded),
+                        self.cache,
+                        rt.jnp.asarray(np.asarray(slots, np.int32)),
+                        rt.jnp.asarray(lengths))
+            with self._phase("prefill.pull"):
                 last_logits = np.asarray(last_logits)
-            elif n == 1:
-                last_logits, self.cache = rt.prefill(
-                    self.cfg, self.params, rt.jnp.asarray(padded),
-                    self.cache, rt.jnp.int32(slots[0]),
-                    rt.jnp.int32(int(lengths[0])))
-                last_logits = np.asarray(last_logits)[None, :]
-            else:
-                last_logits, self.cache = rt.prefill_batch(
-                    self.cfg, self.params, rt.jnp.asarray(padded),
-                    self.cache,
-                    rt.jnp.asarray(np.asarray(slots, np.int32)),
-                    rt.jnp.asarray(lengths))
-                last_logits = np.asarray(last_logits)
+                if last_logits.ndim == 1:       # rt.prefill: one row
+                    last_logits = last_logits[None, :]
         except Exception as e:
             if self.kv_mode == "paged":
                 # Pages grown onto these (still request-less) slots must
@@ -2532,16 +2673,17 @@ class LLMEngine:
         now = time.perf_counter()
         self.stats["prefill_time_s"] += now - t0
         self.stats["prefill_tokens"] += int(lengths.sum())
-        for i, (req, slot) in enumerate(zip(group, slots)):
-            req.last_chunk_at = now
-            tok = self._sample(last_logits[i], req.temperature)
-            with self._lock:
-                self.slot_req[slot] = req
-            self.tokens[slot] = tok
-            self.positions[slot] = int(lengths[i])
-            self.temps[slot] = req.temperature
-            if self._emit(req, tok):
-                self._release(slot)
+        with self._phase("prefill.graduate"):
+            for i, (req, slot) in enumerate(zip(group, slots)):
+                req.last_chunk_at = now
+                tok = self._sample(last_logits[i], req.temperature)
+                with self._lock:
+                    self.slot_req[slot] = req
+                self.tokens[slot] = tok
+                self.positions[slot] = int(lengths[i])
+                self.temps[slot] = req.temperature
+                if self._emit(req, tok):
+                    self._release(slot)
 
     # ----------------------------------------------- chunked prefill
 
@@ -2576,26 +2718,27 @@ class LLMEngine:
             batch: list[tuple[int, GenRequest, int, int]] = []
             planned = 0
             stop = False
-            for slot in self._prefilling:
-                if stop or len(batch) >= self.n_slots:
-                    break
-                req = self.slot_req[slot]
-                done = self._chunk_pos[slot]
-                total = len(req.prompt_ids)
-                while done < total and len(batch) < self.n_slots:
-                    n = min(self.prefill_chunk, total - done)
-                    if spent + planned + n > budget:
-                        stop = True
+            with self._phase("prefill.build"):
+                for slot in self._prefilling:
+                    if stop or len(batch) >= self.n_slots:
                         break
-                    if not self._grow_slot(slot, done + n - 1):
-                        # Pool dry: stop at the blocked chunk (FCFS —
-                        # later work must not consume pages the head
-                        # could use).
-                        stop = True
-                        break
-                    batch.append((slot, req, done, n))
-                    planned += n
-                    done += n
+                    req = self.slot_req[slot]
+                    done = self._chunk_pos[slot]
+                    total = len(req.prompt_ids)
+                    while done < total and len(batch) < self.n_slots:
+                        n = min(self.prefill_chunk, total - done)
+                        if spent + planned + n > budget:
+                            stop = True
+                            break
+                        if not self._grow_slot(slot, done + n - 1):
+                            # Pool dry: stop at the blocked chunk (FCFS —
+                            # later work must not consume pages the head
+                            # could use).
+                            stop = True
+                            break
+                        batch.append((slot, req, done, n))
+                        planned += n
+                        done += n
             if not batch:
                 # Head page-blocked or budget exhausted. With decode in
                 # flight, retiring requests will free pages — stall this
@@ -2677,41 +2820,46 @@ class LLMEngine:
         failure (empty on success) so the bucketed caller can drop their
         follow-on chunks from later buckets in the same tick."""
         rt = self._rt
-        toks = np.zeros((self.n_slots, self.prefill_chunk), np.int32)
-        offsets = np.zeros(self.n_slots, np.int32)
-        valid = np.zeros(self.n_slots, np.int32)
-        tables = np.zeros((self.n_slots, width), np.int32)
-        any_final = False
-        t0 = time.perf_counter()
-        for i, (slot, req, done, n) in enumerate(batch):
-            toks[i, :n] = req.prompt_ids[done:done + n]
-            offsets[i] = done
-            valid[i] = n
-            tables[i] = self.page_table[slot, :width]
-            any_final |= done + n >= len(req.prompt_ids)
-            if req.first_chunk_at is None:
-                req.first_chunk_at = t0
+        with self._phase("prefill.build"):
+            toks = np.zeros((self.n_slots, self.prefill_chunk), np.int32)
+            offsets = np.zeros(self.n_slots, np.int32)
+            valid = np.zeros(self.n_slots, np.int32)
+            tables = np.zeros((self.n_slots, width), np.int32)
+            any_final = False
+            t0 = time.perf_counter()
+            for i, (slot, req, done, n) in enumerate(batch):
+                toks[i, :n] = req.prompt_ids[done:done + n]
+                offsets[i] = done
+                valid[i] = n
+                tables[i] = self.page_table[slot, :width]
+                any_final |= done + n >= len(req.prompt_ids)
+                if req.first_chunk_at is None:
+                    req.first_chunk_at = t0
         try:
-            last, self.cache = rt.prefill_chunk_paged(
-                self.cfg, self.params, rt.jnp.asarray(toks), self.cache,
-                rt.jnp.asarray(tables), rt.jnp.asarray(offsets),
-                rt.jnp.asarray(valid),
-                return_logits=any_final, attn_impl=self.attn_impl)
-            if self.spec_k:
-                # Draft prefill mirror: the same chunk rows through the
-                # draft model into the draft pool (same tables/offsets),
-                # so a slot graduates with draft cursor == target cursor
-                # and the propose loop never needs a catch-up pass. The
-                # draft's graduation logits are unused (propose feeds the
-                # pending token itself), so this is always the cheaper
-                # no-head program.
-                _none, self.draft_cache = rt.prefill_chunk_paged(
-                    self.draft_cfg, self.draft_params, rt.jnp.asarray(toks),
-                    self.draft_cache, rt.jnp.asarray(tables),
-                    rt.jnp.asarray(offsets), rt.jnp.asarray(valid),
-                    return_logits=False, attn_impl=self.attn_impl)
+            with self._phase("prefill.dispatch"):
+                last, self.cache = rt.prefill_chunk_paged(
+                    self.cfg, self.params, rt.jnp.asarray(toks), self.cache,
+                    rt.jnp.asarray(tables), rt.jnp.asarray(offsets),
+                    rt.jnp.asarray(valid),
+                    return_logits=any_final, attn_impl=self.attn_impl)
+                if self.spec_k:
+                    # Draft prefill mirror: the same chunk rows through
+                    # the draft model into the draft pool (same
+                    # tables/offsets), so a slot graduates with draft
+                    # cursor == target cursor and the propose loop never
+                    # needs a catch-up pass. The draft's graduation
+                    # logits are unused (propose feeds the pending token
+                    # itself), so this is always the cheaper no-head
+                    # program.
+                    _none, self.draft_cache = rt.prefill_chunk_paged(
+                        self.draft_cfg, self.draft_params,
+                        rt.jnp.asarray(toks), self.draft_cache,
+                        rt.jnp.asarray(tables), rt.jnp.asarray(offsets),
+                        rt.jnp.asarray(valid),
+                        return_logits=False, attn_impl=self.attn_impl)
             if any_final:
-                last = np.asarray(last)
+                with self._phase("prefill.pull"):
+                    last = np.asarray(last)
         except Exception as e:
             failed = set()
             for slot, req, _done, _n in batch:
@@ -2723,35 +2871,36 @@ class LLMEngine:
                 self._release(slot)
             return failed
         now = time.perf_counter()
-        self.stats["prefill_time_s"] += now - t0
-        self.stats["prefill_tokens"] += sum(n for *_x, n in batch)
-        self.stats["prefill_chunks"] += len(batch)
-        self.stats["prefill_dispatches"] += 1
-        self._dispatch_width_ring.append(width)
-        self._dispatch_width_counts[width] = (
-            self._dispatch_width_counts.get(width, 0) + 1)
-        _PREFILL_CHUNK_HIST.observe(now - t0, tags=self._impl_tags())
-        _PREFILL_DISPATCH_COUNTER.inc(
-            1.0, tags={"replica": self._impl_tags()["replica"],
-                       "width": str(width)})
-        for i, (slot, req, done, n) in enumerate(batch):
-            self._chunk_pos[slot] = done + n
-            if done + n < len(req.prompt_ids):
-                continue
-            req.last_chunk_at = now
-            self._prefilling.remove(slot)
-            self._chunk_pos.pop(slot, None)
-            tok = self._sample(last[i], req.temperature)
-            self.tokens[slot] = tok
-            self.positions[slot] = len(req.prompt_ids)
-            self.temps[slot] = req.temperature
-            if self._emit(req, tok):
-                self._release(slot)
-            elif self.pool_role == "prefill":
-                # Disaggregated serving: the prefill pool's job ends at
-                # the first token — donate the prompt's pages and hand
-                # the stream off to the decode pool.
-                self._handoff_prefill(slot, req)
+        with self._phase("prefill.graduate"):
+            self.stats["prefill_time_s"] += now - t0
+            self.stats["prefill_tokens"] += sum(n for *_x, n in batch)
+            self.stats["prefill_chunks"] += len(batch)
+            self.stats["prefill_dispatches"] += 1
+            self._dispatch_width_ring.append(width)
+            self._dispatch_width_counts[width] = (
+                self._dispatch_width_counts.get(width, 0) + 1)
+            _PREFILL_CHUNK_HIST.observe(now - t0, tags=self._impl_tags())
+            _PREFILL_DISPATCH_COUNTER.inc(
+                1.0, tags={"replica": self._impl_tags()["replica"],
+                           "width": str(width)})
+            for i, (slot, req, done, n) in enumerate(batch):
+                self._chunk_pos[slot] = done + n
+                if done + n < len(req.prompt_ids):
+                    continue
+                req.last_chunk_at = now
+                self._prefilling.remove(slot)
+                self._chunk_pos.pop(slot, None)
+                tok = self._sample(last[i], req.temperature)
+                self.tokens[slot] = tok
+                self.positions[slot] = len(req.prompt_ids)
+                self.temps[slot] = req.temperature
+                if self._emit(req, tok):
+                    self._release(slot)
+                elif self.pool_role == "prefill":
+                    # Disaggregated serving: the prefill pool's job ends
+                    # at the first token — donate the prompt's pages and
+                    # hand the stream off to the decode pool.
+                    self._handoff_prefill(slot, req)
         return set()
 
     def _release(self, slot: int) -> None:
@@ -2963,15 +3112,6 @@ class LLMEngine:
             view[self._prefilling] = 0
         return view
 
-    def _spec_span(self):
-        """Tracing span for 1-in-N verify dispatches (first always) —
-        same sampling rationale as _window_span: visible llm.spec_verify
-        spans in /api/traces without a per-tick root-trace flood."""
-        seq, self._spec_span_seq = self._spec_span_seq, self._spec_span_seq + 1
-        if seq % self._SPAN_SAMPLE == 0:
-            return tracing.start_span("llm.spec_verify", cat="serve_llm")
-        return contextlib.nullcontext()
-
     def _fit_spec_pages(self, active: list[int], k_map: dict) -> list[int]:
         """Paged fit for the speculative window: grow every active slot
         to cover its verify writes (cursor .. cursor + k_i). Pressure
@@ -3041,8 +3181,64 @@ class LLMEngine:
         correction/bonus token, and the rejected tail's pages are rolled
         back in one batched cursor update. → slots that did decode work.
         """
+        with self._phase("plan"):
+            planned = self._plan_spec_window(active)
+        if planned is None:
+            self._last_window_end = None
+            return 0
+        active, k_map, table_view, n_prop = planned
         rt = self._rt
         jnp = rt.jnp
+        k = self.spec_k
+        t0 = time.perf_counter()
+        self._rng_key, sub = rt.jax.random.split(self._rng_key)
+        # Full distributions are only read by the temperature>0
+        # rejection-sampling branch: the draft's q, and the target's
+        # verify logits (greedy acceptance is argmax-chain matching).
+        # When every active slot is greedy — the common serving case —
+        # the draft never materializes its [k, B, V] probs on device
+        # (need_probs=False program variant), and both [.., V]
+        # device->host copies (~14 MB/tick combined at OPT-1.3B vocab,
+        # k=4, B=8) are skipped in favor of the [B, k+1] argmax.
+        sampling = any(self.slot_req[s].temperature > 0.0 for s in active)
+        with self._phase("decode.dispatch"):
+            proposals, draft_probs, self.draft_cache = rt.spec_draft_propose(
+                self.draft_cfg, self.draft_params, jnp.asarray(self.tokens),
+                self.draft_cache, jnp.asarray(self.positions),
+                jnp.asarray(table_view), jnp.asarray(n_prop),
+                jnp.asarray(self.temps), sub, k=k, attn_impl=self.attn_impl,
+                need_probs=sampling)
+        with self._phase("decode.pull"):
+            proposals = np.asarray(proposals)                  # [k, B]
+            draft_probs = np.asarray(draft_probs) if sampling else None
+        with self._phase("plan"):
+            # Verify rows: [pending, d_1 .. d_k] per slot, written at the
+            # slot's decode cursor; inert rows (mid-prefill / free slots)
+            # carry n_valid 0.
+            vtoks = np.zeros((self.n_slots, k + 1), np.int32)
+            vtoks[:, 0] = self.tokens
+            vtoks[:, 1:] = proposals.T
+            n_valid = np.where(n_prop >= 0, n_prop + 1, 0).astype(np.int32)
+        with self._phase("spec_verify"):
+            logits, self.cache = rt.verify_chunk_paged(
+                self.cfg, self.params, jnp.asarray(vtoks), self.cache,
+                jnp.asarray(table_view), jnp.asarray(self.positions),
+                jnp.asarray(n_valid), attn_impl=self.attn_impl)
+            if sampling:
+                logits = np.asarray(logits)                # [B, k+1, V]
+                argmax = None
+            else:
+                argmax = np.asarray(jnp.argmax(logits, axis=-1))
+                logits = None                              # [B, k+1]
+        with self._phase("emit"):
+            return self._accept_spec_window(
+                active, k_map, proposals, draft_probs, logits, argmax, t0,
+                tick_prefill)
+
+    def _plan_spec_window(self, active: list[int]):
+        """Host-side plan of a speculative tick. → (surviving active
+        slots, per-slot proposal budgets, table view, n_prop [B]) or
+        None when nothing is left to run."""
         k = self.spec_k
         survivors = []
         for slot in active:
@@ -3052,8 +3248,7 @@ class LLMEngine:
                 survivors.append(slot)
         active = survivors
         if not active:
-            self._last_window_end = None
-            return 0
+            return None
         # Per-slot proposal budget: never past the request's remaining
         # output budget (− 1: the verify pass itself always emits one
         # token beyond the accepted proposals) or the KV capacity. 0 is
@@ -3072,49 +3267,19 @@ class LLMEngine:
             for s in active}
         active = self._fit_spec_pages(active, k_map)
         if not active:
-            self._last_window_end = None
-            return 0
-        table_view = self._decode_table_view(active)
+            return None
         n_prop = np.full(self.n_slots, -1, np.int32)
         for slot in active:
             n_prop[slot] = k_map[slot]
-        t0 = time.perf_counter()
-        self._rng_key, sub = rt.jax.random.split(self._rng_key)
-        # Full distributions are only read by the temperature>0
-        # rejection-sampling branch: the draft's q, and the target's
-        # verify logits (greedy acceptance is argmax-chain matching).
-        # When every active slot is greedy — the common serving case —
-        # the draft never materializes its [k, B, V] probs on device
-        # (need_probs=False program variant), and both [.., V]
-        # device->host copies (~14 MB/tick combined at OPT-1.3B vocab,
-        # k=4, B=8) are skipped in favor of the [B, k+1] argmax.
-        sampling = any(self.slot_req[s].temperature > 0.0 for s in active)
-        proposals, draft_probs, self.draft_cache = rt.spec_draft_propose(
-            self.draft_cfg, self.draft_params, jnp.asarray(self.tokens),
-            self.draft_cache, jnp.asarray(self.positions),
-            jnp.asarray(table_view), jnp.asarray(n_prop),
-            jnp.asarray(self.temps), sub, k=k, attn_impl=self.attn_impl,
-            need_probs=sampling)
-        proposals = np.asarray(proposals)                  # [k, B]
-        draft_probs = np.asarray(draft_probs) if sampling else None
-        # Verify rows: [pending, d_1 .. d_k] per slot, written at the
-        # slot's decode cursor; inert rows (mid-prefill / free slots)
-        # carry n_valid 0.
-        vtoks = np.zeros((self.n_slots, k + 1), np.int32)
-        vtoks[:, 0] = self.tokens
-        vtoks[:, 1:] = proposals.T
-        n_valid = np.where(n_prop >= 0, n_prop + 1, 0).astype(np.int32)
-        with self._spec_span():
-            logits, self.cache = rt.verify_chunk_paged(
-                self.cfg, self.params, jnp.asarray(vtoks), self.cache,
-                jnp.asarray(table_view), jnp.asarray(self.positions),
-                jnp.asarray(n_valid), attn_impl=self.attn_impl)
-            if sampling:
-                logits = np.asarray(logits)                # [B, k+1, V]
-                argmax = None
-            else:
-                argmax = np.asarray(jnp.argmax(logits, axis=-1))
-                logits = None                              # [B, k+1]
+        return active, k_map, self._decode_table_view(active), n_prop
+
+    def _accept_spec_window(self, active: list[int], k_map: dict, proposals,
+                            draft_probs, logits, argmax, t0: float,
+                            tick_prefill: bool) -> int:
+        """Rejection-sample each slot's proposals against the verify
+        pass, emit, roll the rejected tails' pages back, and book the
+        tick. → slots that did decode work."""
+        k = self.spec_k
         proposed = accepted = emitted_total = 0
         survivors = []
         for slot in active:
@@ -3196,11 +3361,20 @@ class LLMEngine:
         rt = self._rt
         jnp = rt.jnp
         pt0 = self.stats["prefill_tokens"]
-        self._admit()
-        # COW flush MUST precede any dispatch that could write this
-        # tick: admission queued the pairs, and the first cold chunk of
-        # a warm slot writes into its COW'd tail page.
-        self._apply_cow()
+        # A tick is the interval from one `admit` to the next.
+        self._ticks.begin(time.perf_counter())
+        with self._phase("admit"):
+            groups = self._admit()
+            # COW flush MUST precede any dispatch that could write this
+            # tick: admission queued the pairs, and the first cold chunk
+            # of a warm slot writes into its COW'd tail page.
+            self._apply_cow()
+            with self._lock:
+                self._awaiting_max = max(self._awaiting_max,
+                                         self._awaiting_first_token())
+        for bucket, group, slots in groups:
+            # graftlint: disable=HOST-SYNC-IN-HOT-LOOP (one pull per one-shot prefill group by design: its first tokens are sampled on the host)
+            self._prefill_group(bucket, group, slots)
         if self.prefill_chunk:
             decode_ready = any(
                 self.slot_req[i] is not None and i not in self._chunk_pos
@@ -3216,94 +3390,116 @@ class LLMEngine:
                     self._budget_util_ewma = self._ewma(
                         self._budget_util_ewma,
                         min(1.0, spent / self.prefill_budget))
-        # Mid-prefill slots are not decode-active (their page tables are
-        # masked off below); chunks completed this tick already graduated.
-        active = [i for i in range(self.n_slots)
-                  if self.slot_req[i] is not None
-                  and i not in self._chunk_pos]
         n_prefilling = len(self._prefilling)
-        if not active:
-            # graftlint: disable=GUARDED-BY (engine-thread state: _step runs only on the engine loop thread; the locked writes elsewhere are reader-side snapshots, and a plain store is torn-read-free)
-            self._last_window_end = None
-            return n_prefilling
-        tick_prefill = self.stats["prefill_tokens"] > pt0
-        # Chaos fault point: a "kill" rule here exits the replica process
-        # abruptly with decodes in flight — the scenario the cross-replica
-        # failover path must make invisible to clients.
-        _chaos.hit("llm.decode_window")
         if self.spec_k:
             # Speculative decoding replaces the fused decode window
             # entirely: one draft propose dispatch + one batched verify
             # per tick, emitting 1..k+1 tokens per slot.
-            return self._spec_decode_window(active, tick_prefill) \
-                + n_prefilling
-        k = self._pick_window(active)
-        table_view = None
-        if self.kv_mode == "paged":
-            active, k = self._fit_window_pages(active, k)
+            active = self._decode_ready_slots()
             if not active:
+                # graftlint: disable=GUARDED-BY (engine-thread state: _step runs only on the engine loop thread; the locked writes elsewhere are reader-side snapshots, and a plain store is torn-read-free)
                 self._last_window_end = None
                 return n_prefilling
-            table_view = self._decode_table_view(active)
-        t0 = time.perf_counter()
+            _chaos.hit("llm.decode_window")
+            return self._spec_decode_window(
+                active, self.stats["prefill_tokens"] > pt0) + n_prefilling
+        with self._phase("plan"):
+            # Mid-prefill slots are not decode-active (their page tables
+            # are masked off below); chunks completed this tick already
+            # graduated.
+            active = self._decode_ready_slots()
+            k = 0
+            if active:
+                # Chaos fault point: a "kill" rule here exits the replica
+                # process abruptly with decodes in flight — the scenario
+                # the cross-replica failover path must make invisible to
+                # clients.
+                _chaos.hit("llm.decode_window")
+                k = self._pick_window(active)
+                table_view = None
+                if self.kv_mode == "paged":
+                    active, k = self._fit_window_pages(active, k)
+                    if active:
+                        table_view = self._decode_table_view(active)
+            if not active:
+                # graftlint: disable=GUARDED-BY (engine-thread state: _step runs only on the engine loop thread; the locked writes elsewhere are reader-side snapshots, and a plain store is torn-read-free)
+                self._last_window_end = None
+                return n_prefilling
+            tick_prefill = self.stats["prefill_tokens"] > pt0
+            # decode_step_ms is taken from here (the window's inputs go
+            # to the device) to the end of the pull.
+            t0 = time.perf_counter()
+            if k > 1:
+                self._rng_key, sub = rt.jax.random.split(self._rng_key)
+                temps = jnp.asarray(self.temps)
+            tokens = jnp.asarray(self.tokens)
+            positions = jnp.asarray(self.positions)
+            if table_view is not None:
+                table_view = jnp.asarray(table_view)
         if k > 1:
-            self._rng_key, sub = rt.jax.random.split(self._rng_key)
-            with self._window_span():
+            with self._phase("decode_window"):
                 if self.kv_mode == "paged":
                     # graftlint: disable=GUARDED-BY (engine-thread state: only _step writes the KV cache while the loop runs; drain/export mutate it after stop() joins the thread)
                     toks_out, self.cache = rt.decode_multi_paged(
-                        self.cfg, self.params, jnp.asarray(self.tokens),
-                        self.cache, jnp.asarray(self.positions),
-                        jnp.asarray(table_view), k,
-                        jnp.asarray(self.temps), sub,
-                        attn_impl=self.attn_impl)
+                        self.cfg, self.params, tokens, self.cache,
+                        positions, table_view, k, temps, sub,
+                        attn_impl=self.attn_impl, phase=self._phase)
                 else:
-                    toks_out, self.cache = rt.decode_multi(
-                        self.cfg, self.params, jnp.asarray(self.tokens),
-                        self.cache, jnp.asarray(self.positions), k,
-                        jnp.asarray(self.temps), sub)
-                toks_out = np.asarray(toks_out)  # [k, B]
-            self._observe_window(t0, time.perf_counter(), k, len(active),
+                    with self._phase("decode.dispatch"):
+                        toks_out, self.cache = rt.decode_multi(
+                            self.cfg, self.params, tokens, self.cache,
+                            positions, k, temps, sub)
+                    with self._phase("decode.pull"):
+                        toks_out = np.asarray(toks_out)  # [k, B]
+            with self._phase("emit"):
+                self._observe_window(t0, time.perf_counter(), k,
+                                     len(active), tick_prefill)
+                for slot in active:
+                    req = self.slot_req[slot]
+                    finished = False
+                    for i in range(k):
+                        if self._emit(req, int(toks_out[i, slot])):
+                            finished = True
+                            break
+                    if finished:
+                        self._release(slot)
+                    else:
+                        # graftlint: disable=GUARDED-BY (engine-thread state, see cache note above)
+                        self.tokens[slot] = toks_out[k - 1, slot]
+                        # graftlint: disable=GUARDED-BY (engine-thread state, see cache note above)
+                        self.positions[slot] += k
+            return len(active) + n_prefilling
+        with self._phase("decode_window"):
+            with self._phase("decode.dispatch"):
+                if self.kv_mode == "paged":
+                    logits, self.cache = rt.decode_step_paged(
+                        self.cfg, self.params, tokens, self.cache,
+                        positions, table_view, attn_impl=self.attn_impl)
+                else:
+                    logits, self.cache = rt.decode_step(
+                        self.cfg, self.params, tokens, self.cache,
+                        positions)
+            with self._phase("decode.pull"):
+                logits = np.asarray(logits)
+        with self._phase("emit"):
+            self._observe_window(t0, time.perf_counter(), 1, len(active),
                                  tick_prefill)
             for slot in active:
                 req = self.slot_req[slot]
-                finished = False
-                for i in range(k):
-                    if self._emit(req, int(toks_out[i, slot])):
-                        finished = True
-                        break
-                if finished:
+                if self.positions[slot] + 1 >= self.max_len:
+                    self._finish_capacity(slot)
+                    continue
+                tok = self._sample(logits[slot], req.temperature)
+                self.tokens[slot] = tok
+                self.positions[slot] += 1
+                if self._emit(req, tok):
                     self._release(slot)
-                else:
-                    # graftlint: disable=GUARDED-BY (engine-thread state, see cache note above)
-                    self.tokens[slot] = toks_out[k - 1, slot]
-                    # graftlint: disable=GUARDED-BY (engine-thread state, see cache note above)
-                    self.positions[slot] += k
-            return len(active) + n_prefilling
-        with self._window_span():
-            if self.kv_mode == "paged":
-                logits, self.cache = rt.decode_step_paged(
-                    self.cfg, self.params, jnp.asarray(self.tokens),
-                    self.cache, jnp.asarray(self.positions),
-                    jnp.asarray(table_view), attn_impl=self.attn_impl)
-            else:
-                logits, self.cache = rt.decode_step(
-                    self.cfg, self.params, jnp.asarray(self.tokens),
-                    self.cache, jnp.asarray(self.positions))
-            logits = np.asarray(logits)
-        self._observe_window(t0, time.perf_counter(), 1, len(active),
-                             tick_prefill)
-        for slot in active:
-            req = self.slot_req[slot]
-            if self.positions[slot] + 1 >= self.max_len:
-                self._finish_capacity(slot)
-                continue
-            tok = self._sample(logits[slot], req.temperature)
-            self.tokens[slot] = tok
-            self.positions[slot] += 1
-            if self._emit(req, tok):
-                self._release(slot)
         return len(active) + n_prefilling
+
+    def _decode_ready_slots(self) -> list[int]:
+        return [i for i in range(self.n_slots)
+                if self.slot_req[i] is not None
+                and i not in self._chunk_pos]
 
     def _loop(self) -> None:
         try:

@@ -20,6 +20,7 @@ same reason; the persistent compile cache is off around these compiles
 
 import dataclasses
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -70,10 +71,19 @@ def chip(topo, no_persistent_cache):
         shape, dtype, sharding=one)
 
 
-def _compile(fn, *args):
+def _compile(fn, *args, kernels=()):
+    """`kernels`: the `name=` of each pallas_call, which must be the
+    custom call's instruction name in the compiled program — what a
+    device trace shows and `benchmarks/harness/trace_reduce.op_key`
+    matches (unnamed, the decode kernel was `%closed_call.8`)."""
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text(), \
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, \
         "the compiled program holds no Mosaic kernel"
+    for name in kernels:
+        # (autodiff wraps it: `%transpose_jvp_flash_attn_bwd_dq__.1`)
+        assert re.search(rf"%\w*{name}[\w.]* = [^\n]*custom-call\(", text), \
+            f"no custom call named %{name}"
     return compiled
 
 
@@ -91,13 +101,13 @@ def test_decode_kernel_compiles(chip, kv):
         _compile(lambda q, k, v, t, n: paged_attention(
             q, k, v, t, n, interpret=False),
             q, _pool(chip, jnp.bfloat16), _pool(chip, jnp.bfloat16),
-            tables, lengths)
+            tables, lengths, kernels=("paged_decode_attn",))
     else:
         scale = chip((P,), jnp.float32)   # per-page scales in scalar memory
         _compile(lambda q, k, v, t, n, ks, vs: paged_attention(
             q, k, v, t, n, interpret=False, k_scale=ks, v_scale=vs),
             q, _pool(chip, jnp.int8), _pool(chip, jnp.int8),
-            tables, lengths, scale, scale)
+            tables, lengths, scale, scale, kernels=("paged_decode_attn",))
 
 
 @pytest.mark.parametrize("chunk", [C, 5], ids=["prefill128", "verify5"])
@@ -108,7 +118,8 @@ def test_prefill_kernel_compiles(chip, chunk):
         q, k, v, t, o, n, interpret=False),
         chip((B, chunk, H, K), jnp.bfloat16), _pool(chip, jnp.bfloat16),
         _pool(chip, jnp.bfloat16), chip((B, N_PG), jnp.int32),
-        chip((B,), jnp.int32), chip((B,), jnp.int32))
+        chip((B,), jnp.int32), chip((B,), jnp.int32),
+        kernels=("paged_prefill_attn",))
 
 
 @pytest.mark.parametrize("heads,block", [(12, 512), (12, 1024), (32, 1024)],
@@ -122,7 +133,9 @@ def test_flash_fwd_bwd_compiles(chip, heads, block):
                             block_kv=block, interpret=False)
         return jnp.sum(o.astype(jnp.float32))
 
-    _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), x, x, x)
+    _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), x, x, x,
+             kernels=("flash_attn_fwd", "flash_attn_bwd_dq",
+                      "flash_attn_bwd_dkv"))
 
 
 def test_decode_kernel_compiles_under_tp_mesh(topo, no_persistent_cache):
