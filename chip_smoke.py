@@ -85,8 +85,8 @@ class Size:
     the CPU rehearsal of the same control flow (tests only)."""
 
     model: str
-    # kernels: decode q [B,H,K] vs pool [P,ps,H,K], tables [B,n_pg];
-    # prefill chunk C; flash [fb, fs, H, K].
+    # kernels: decode q [B,H,K] vs the whole pool [KERNEL_LAYERS,P,ps,H*K],
+    # tables [B,n_pg]; prefill chunk C; flash [fb, fs, H, K].
     B: int
     H: int
     K: int
@@ -110,6 +110,9 @@ class Size:
     # None = leave the worker's JAX alone (the chip); "cpu" for rehearsal.
     jax_platform: str | None = None
 
+
+# Layers of the pool the kernel phase builds (the kernels read the last).
+KERNEL_LAYERS = 3
 
 SIZES = {
     # OPT-1.3B at full width; pool = 16 slots x 2048 tokens.
@@ -333,11 +336,16 @@ def phase_kernels(size: Size, expect: str = "tpu") -> dict:
             free = list(rng.permutation(np.arange(1, P)))
         tables[b, :need] = [free.pop() for _ in range(need)]
     tables_j, lengths_j = jnp.asarray(tables), jnp.asarray(lengths)
-    k_pool, v_pool = normal(P, ps, H, K), normal(P, ps, H, K)
-    k_i8 = jnp.asarray(rng.integers(-127, 128, (P, ps, H, K)), jnp.int8)
-    v_i8 = jnp.asarray(rng.integers(-127, 128, (P, ps, H, K)), jnp.int8)
-    k_sc = jnp.asarray(rng.uniform(0.005, 0.02, P), jnp.float32)
-    v_sc = jnp.asarray(rng.uniform(0.005, 0.02, P), jnp.float32)
+    # The pool as the programs hold it: every layer in one plane, heads
+    # flattened into the minor axis. The kernels read layer `layer` of it
+    # in place (not layer 0: a wrong block index would read zeros there).
+    L, layer = KERNEL_LAYERS, jnp.int32(KERNEL_LAYERS - 1)
+    shape = (L, P, ps, H * K)
+    k_pool, v_pool = normal(*shape), normal(*shape)
+    k_i8 = jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+    v_i8 = jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+    k_sc = jnp.asarray(rng.uniform(0.005, 0.02, (L, P)), jnp.float32)
+    v_sc = jnp.asarray(rng.uniform(0.005, 0.02, (L, P)), jnp.float32)
     q = normal(B, H, K)
     results = {}
 
@@ -365,22 +373,25 @@ def phase_kernels(size: Size, expect: str = "tpu") -> dict:
             f"mosaic={has_mosaic}, compile+run {dt:.1f}s")
 
     compare("paged_attention[bf16]",
-            lambda q, k, v, t, n: paged_attention(
-                q, k, v, t, n, interpret=interpret),
-            reference_paged_attention, q, k_pool, v_pool, tables_j, lengths_j)
+            lambda q, k, v, l, t, n: paged_attention(
+                q, k, v, l, t, n, interpret=interpret),
+            reference_paged_attention,
+            q, k_pool, v_pool, layer, tables_j, lengths_j)
     compare("paged_attention[int8]",
-            lambda q, k, v, t, n, ks, vs: paged_attention(
-                q, k, v, t, n, interpret=interpret, k_scale=ks, v_scale=vs),
-            lambda q, k, v, t, n, ks, vs: reference_paged_attention(
-                q, k, v, t, n, k_scale=ks, v_scale=vs),
-            q, k_i8, v_i8, tables_j, lengths_j, k_sc, v_sc)
+            lambda q, k, v, l, t, n, ks, vs: paged_attention(
+                q, k, v, l, t, n, interpret=interpret,
+                k_scale=ks, v_scale=vs),
+            lambda q, k, v, l, t, n, ks, vs: reference_paged_attention(
+                q, k, v, l, t, n, k_scale=ks, v_scale=vs),
+            q, k_i8, v_i8, layer, tables_j, lengths_j, k_sc, v_sc)
     # Prefill: each slot's chunk of C queries ends at its kv length.
     offsets = jnp.asarray(np.maximum(lengths - C, 0).astype(np.int32))
     compare("paged_prefill_attention[bf16]",
-            lambda q, k, v, t, o, n: paged_prefill_attention(
-                q, k, v, t, o, n, interpret=interpret),
+            lambda q, k, v, l, t, o, n: paged_prefill_attention(
+                q, k, v, l, t, o, n, interpret=interpret),
             reference_paged_prefill_attention,
-            normal(B, C, H, K), k_pool, v_pool, tables_j, offsets, lengths_j)
+            normal(B, C, H, K), k_pool, v_pool, layer, tables_j, offsets,
+            lengths_j)
 
     fq, fk, fv, fw = (normal(size.fb, size.fs, H, K) for _ in range(4))
 

@@ -4,7 +4,7 @@ The dense cache (models/decode.py `init_kv_cache`) preallocates
 ``[L, B, T_max]`` per slot — HBM capacity, not compute, caps the slot
 count (OPT-1.3B at 16 slots × 2048 OOM'd a 16 GB chip, ROUND4_NOTES
 item 1b). Paged KV decouples slot count from max_len: a shared pool of
-fixed-size pages ``[L, P+1, page_size, H, K]`` plus a per-slot page
+fixed-size pages ``[L, P+1, page_size, H*K]`` plus a per-slot page
 table ``[B, max_pages]`` of page ids. Slots consume pages as they grow,
 so pool capacity is sized to the *expected total live tokens*, not
 ``B × T_max`` worst case (PAPERS.md "Ragged Paged Attention"; the
@@ -13,13 +13,26 @@ reference's serving delegates KV management to torch models —
 being out-scaled here).
 
 XLA-first layout decisions:
+- The pool is lane-dense and addressed in place by (layer, page). Its
+  minor axis is ``H*K`` — every head of one token, heads major — which is
+  a multiple of the TPU's 128 lanes at every served model (2,048 at
+  OPT-1.3B, 1,024 a shard for GPT-J at tp=4) where head_dim alone (64)
+  is not: with ``[..., H, K]`` the chip's own layout made the PAGE axis
+  minor, and every layer of every step cut its plane out of the pool,
+  re-laid it out for the kernel, re-laid it back and wrote it back
+  whole (90 % of a decode step, PERF.md PR 25). Now the chip's layout IS
+  row-major with no padding, a page is ``page_size`` dense rows, and no
+  op of a paged program moves more pool bytes than the pages it
+  touches: `_scan_pool_layers` carries the whole pool, writes land at
+  ``(l, page, offset)`` and the kernels' block index is ``(l, page)``.
+  One layout, no switch: nothing here looks at head_dim.
 - Page 0 is a reserved null page. Table entries that aren't allocated
   point at 0; writes land there harmlessly and reads of it are always
   position-masked, so every shape stays static with no host branching.
 - Reads have two implementations, selected by the static ``attn_impl``
   argument (engine knob ``llm_attn_impl``):
-  * ``"gather"`` (reference): gather the slot's pages back into a
-    contiguous ``[B, T, H, K]`` timeline per layer (transient, inside
+  * ``"gather"`` (reference): gather the slot's pages of layer ``l``
+    back into a contiguous ``[B, T, H, K]`` timeline (transient, inside
     the layer scan) and run the *same* attention math as the dense path
     — exact-match with the dense engine by construction (tested).
   * ``"kernel"``: the Pallas ragged paged-attention kernel
@@ -27,7 +40,8 @@ XLA-first layout decisions:
     with online-softmax state in VMEM — no timeline is materialized in
     HBM. Exact-match with ``"gather"`` within fp32-softmax
     reassociation (tested); the throughput path on real chips.
-- Writes scatter at ``(table[b, pos // ps], pos % ps)``. Distinct live
+- Writes scatter rows of ``H*K`` at ``(l, table[b, pos // ps],
+  pos % ps)`` of the carried, donated pool. Distinct live
   slots never share a *writable* page: exclusively-owned pages are the
   common case, and the prefix cache (serve/prefix_cache.py) may bind
   the same already-written page into several slots' tables READ-ONLY —
@@ -71,7 +85,8 @@ def init_paged_kv(cfg: GPTConfig, n_pages: int, page_size: int,
     resets — offset 0 means the writer owns a fresh or recycled page)
     and frozen until the page restarts; later tokens clip at the
     frozen scale, so no already-written token is ever re-scaled."""
-    shape = (cfg.n_layers, n_pages + 1, page_size, cfg.n_heads, cfg.head_dim)
+    shape = (cfg.n_layers, n_pages + 1, page_size,
+             cfg.n_heads * cfg.head_dim)
     if kv_dtype in (None, "bf16"):
         return {"k": jnp.zeros(shape, cfg.dtype),
                 "v": jnp.zeros(shape, cfg.dtype)}
@@ -84,12 +99,19 @@ def init_paged_kv(cfg: GPTConfig, n_pages: int, page_size: int,
             "v_scale": jnp.zeros(scale_shape, jnp.bfloat16)}
 
 
-def _quant_write(pool_l, scale_l, write_pages, write_offs, values,
-                 tp_axis=None):
-    """Quantized scatter of per-token K/V rows into one layer's int8
-    page plane, maintaining the per-page scale plane.
+def _rows(x):
+    """[..., H, K] → [M, H*K]: K/V of M tokens as rows of the pool's
+    minor axis (heads major, so a tp shard's rows are its own heads)."""
+    return x.reshape(-1, x.shape[-2] * x.shape[-1])
 
-    values: [M, ...] float rows landing at (write_pages[m],
+
+def _quant_write(plane, scale, l, write_pages, write_offs, values,
+                 tp_axis=None):
+    """Quantized scatter of per-token K/V rows into layer ``l`` of an
+    int8 page plane [L, P+1, ps, H*K], in place, maintaining that
+    layer's row of the per-page scale plane [L, P+1].
+
+    values: [M, H*K] float rows landing at (l, write_pages[m],
     write_offs[m]). Scale policy — frozen-at-first-write: a page's
     scale is (re)set from this dispatch's scatter-max of |values| over
     rows landing in it iff some row lands at offset 0 (a fresh/recycled
@@ -100,74 +122,80 @@ def _quant_write(pool_l, scale_l, write_pages, write_offs, values,
     the null scale, which no masked read ever consumes. Under tensor
     parallelism the contribution is pmax'd across head shards so the
     replicated scale plane stays shard-identical."""
-    n_rows = scale_l.shape[0]
+    n_rows = scale.shape[1]
     v32 = values.astype(jnp.float32)
-    vmax = jnp.max(jnp.abs(v32), axis=tuple(range(1, v32.ndim)))   # [M]
+    vmax = jnp.max(jnp.abs(v32), axis=1)                           # [M]
     starts = jnp.zeros((n_rows,), jnp.int32).at[write_pages].max(
         (write_offs == 0).astype(jnp.int32))
     contrib = jnp.zeros((n_rows,), jnp.float32).at[write_pages].max(vmax)
     if tp_axis is not None:
         contrib = jax.lax.pmax(contrib, tp_axis)
-    old = scale_l.astype(jnp.float32)
+    old = scale[l].astype(jnp.float32)
     new_scale = jnp.where((starts > 0) | (old <= 0.0),
                           jnp.maximum(contrib, 1e-8) / 127.0, old)
-    s = new_scale[write_pages].reshape((-1,) + (1,) * (v32.ndim - 1))
-    q = jnp.clip(jnp.round(v32 / s), -127, 127).astype(jnp.int8)
-    return (pool_l.at[write_pages, write_offs].set(q),
-            new_scale.astype(scale_l.dtype))
+    q = jnp.clip(jnp.round(v32 / new_scale[write_pages][:, None]),
+                 -127, 127).astype(jnp.int8)
+    return (plane.at[l, write_pages, write_offs].set(q),
+            scale.at[l].set(new_scale.astype(scale.dtype)))
 
 
-def _quant_write_full_pages(pool_l, scale_l, pages, values, tp_axis=None):
-    """Whole-page variant (one-shot paged prefill): values [M, ps, ...]
-    fills pages[m] end to end — by construction a first write, so every
-    target page's scale resets from its own payload. Duplicate ids only
-    ever name the null page (zero padding), where any write order gives
-    the same harmless result."""
+def _quant_write_full_pages(plane, scale, l, pages, values, tp_axis=None):
+    """Whole-page variant (one-shot paged prefill): values [M, ps, H*K]
+    fills pages[m] of layer ``l`` end to end — by construction a first
+    write, so every target page's scale resets from its own payload.
+    Duplicate ids only ever name the null page (zero padding), where any
+    write order gives the same harmless result."""
     v32 = values.astype(jnp.float32)
-    vmax = jnp.max(jnp.abs(v32), axis=tuple(range(1, v32.ndim)))   # [M]
+    vmax = jnp.max(jnp.abs(v32), axis=(1, 2))                      # [M]
     if tp_axis is not None:
         vmax = jax.lax.pmax(vmax, tp_axis)
-    new_scale = scale_l.astype(jnp.float32).at[pages].set(
+    new_scale = scale[l].astype(jnp.float32).at[pages].set(
         jnp.maximum(vmax, 1e-8) / 127.0)
-    s = new_scale[pages].reshape((-1,) + (1,) * (v32.ndim - 1))
-    q = jnp.clip(jnp.round(v32 / s), -127, 127).astype(jnp.int8)
-    return (pool_l.at[pages].set(q), new_scale.astype(scale_l.dtype))
+    q = jnp.clip(jnp.round(v32 / new_scale[pages][:, None, None]),
+                 -127, 127).astype(jnp.int8)
+    return (plane.at[l, pages].set(q),
+            scale.at[l].set(new_scale.astype(scale.dtype)))
 
 
-def _planes(k, v, k_scale=None, v_scale=None):
-    """One layer's pool slices as `_scan_pool_layers` hands them to a
-    body and takes them back (scale planes only for a quantized pool)."""
-    if k_scale is None:
-        return {"k": k, "v": v}
-    return {"k": k, "v": v, "k_scale": k_scale, "v_scale": v_scale}
+def _write_rows(pool, l, write_pages, write_offs, k_rows, v_rows,
+                tp_axis=None):
+    """K/V rows [M, H*K] → ``(l, write_pages[m], write_offs[m])`` of the
+    carried pool, quantizing against the layer's scale row for an int8
+    pool. Only the M rows move: the pool is updated in place."""
+    if "k_scale" in pool:
+        k, k_sc = _quant_write(pool["k"], pool["k_scale"], l, write_pages,
+                               write_offs, k_rows, tp_axis)
+        v, v_sc = _quant_write(pool["v"], pool["v_scale"], l, write_pages,
+                               write_offs, v_rows, tp_axis)
+        return {"k": k, "v": v, "k_scale": k_sc, "v_scale": v_sc}
+    dtype = pool["k"].dtype
+    return {"k": pool["k"].at[l, write_pages, write_offs].set(
+                k_rows.astype(dtype)),
+            "v": pool["v"].at[l, write_pages, write_offs].set(
+                v_rows.astype(dtype))}
 
 
 def _scan_pool_layers(body, x, stacked, pool):
     """Scan ``body`` over the layer stack with the page pool in the scan
-    CARRY, not as stacked xs/ys operands.
+    CARRY, not as stacked xs/ys operands, and never sliced.
 
-    ``body(x, layer, planes) -> (x, planes)`` sees one layer's slice of
-    every pool plane (``planes[name]`` = ``pool[name][l]``; the scale
-    planes of a quantized pool ride along as ``[P+1]`` vectors) and
-    returns the updated slices, which are written back in place. The
-    carry matters for memory fit on the chip: a pool scanned as xs → ys
-    is TWO buffers in the compiled while loop (the stacked input and the
-    stacked output), which doubled the pool's HBM footprint — a
-    16-slot × 2048-token bf16 pool at OPT-1.3B (6.4 GB) plus its copy
-    did not fit a 16 GB chip beside the weights. A carried pool updated
-    by dynamic-update-slice is one buffer, aliased with the donated
-    argument."""
+    ``body(x, layer, l, pool) -> (x, pool)`` receives the layer index
+    and the WHOLE pool and returns the whole pool: it writes the rows
+    (or pages) it touches at ``(l, page, offset)`` and its attention
+    reads pages at ``(l, page)``, so no op of a paged program moves
+    more pool bytes than the pages it touches. The carry matters for
+    memory fit on the chip: a pool scanned as xs → ys is TWO buffers in
+    the compiled while loop (the stacked input and the stacked output),
+    which doubled the pool's HBM footprint — a 16-slot × 2048-token bf16
+    pool at OPT-1.3B (6.4 GB) plus its copy did not fit a 16 GB chip
+    beside the weights. A carried pool updated in place is one buffer,
+    aliased with the donated argument
+    (tests/test_chip_compile.py holds the compiled programs to it)."""
 
     def step(carry, inputs):
         x, pool = carry
         l, layer = inputs
-        planes = {name: jax.lax.dynamic_index_in_dim(p, l, 0, keepdims=False)
-                  for name, p in pool.items()}
-        x, planes = body(x, layer, planes)
-        pool = {name: jax.lax.dynamic_update_index_in_dim(
-                    p, planes[name], l, 0)
-                for name, p in pool.items()}
-        return (x, pool), None
+        return body(x, layer, l, pool), None
 
     n_layers = pool["k"].shape[0]
     (x, pool), _ = jax.lax.scan(
@@ -194,7 +222,7 @@ def copy_pages(pool, src, dst):
 @jax.jit
 def gather_pages(pool, pages):
     """Read pages ``pages[i]`` out of the pool across every layer for
-    both K and V in ONE fused dispatch → ``{"k": [L, n, ps, H, Kd],
+    both K and V in ONE fused dispatch → ``{"k": [L, n, ps, H*Kd],
     "v": ...}``. The donation path of the KV page-set store
     (serve/kv_objects.py): the caller pads ``pages`` to a power-of-two
     length with null-page (0) ids — reading the null page is harmless
@@ -241,8 +269,7 @@ def prefill_batch_paged(cfg: GPTConfig, params, tokens, pool, pages, lengths):
     scale = 1.0 / math.sqrt(cfg.head_dim)
     flat_pages = pages.reshape(-1)                         # [N * n_pg]
 
-    def body(x, layer, planes):
-        k_pool_l, v_pool_l = planes["k"], planes["v"]
+    def body(x, layer, l, pool):
         h = _layer_norm(x, layer["ln1_scale"], layer["ln1_bias"])
         q, k, v = _qkv(h, layer, cfg)
         q = _rotary_pos(q, cfg.rotary_dim, pos)
@@ -257,19 +284,20 @@ def prefill_batch_paged(cfg: GPTConfig, params, tokens, pool, pages, lengths):
                            weight_view(layer, "wo", cfg.dtype))
         x = _mlp(x, layer, cfg)
 
-        def paged(arr):                                    # [N,S,H,K] → pages
+        def paged(arr):                            # [N,S,H,K] → whole pages
             a = jnp.pad(arr, ((0, 0), (0, S_pad - S), (0, 0), (0, 0)))
-            return a.reshape(N * n_pg, ps, cfg.n_heads, cfg.head_dim)
+            return a.reshape(N * n_pg, ps, cfg.n_heads * cfg.head_dim)
 
         if quant:
-            k_pool_l, k_sc_l = _quant_write_full_pages(
-                k_pool_l, planes["k_scale"], flat_pages, paged(k))
-            v_pool_l, v_sc_l = _quant_write_full_pages(
-                v_pool_l, planes["v_scale"], flat_pages, paged(v))
-            return x, _planes(k_pool_l, v_pool_l, k_sc_l, v_sc_l)
-        k_pool_l = k_pool_l.at[flat_pages].set(paged(k.astype(cfg.dtype)))
-        v_pool_l = v_pool_l.at[flat_pages].set(paged(v.astype(cfg.dtype)))
-        return x, _planes(k_pool_l, v_pool_l)
+            k_pl, k_sc = _quant_write_full_pages(
+                pool["k"], pool["k_scale"], l, flat_pages, paged(k))
+            v_pl, v_sc = _quant_write_full_pages(
+                pool["v"], pool["v_scale"], l, flat_pages, paged(v))
+            return x, {"k": k_pl, "v": v_pl,
+                       "k_scale": k_sc, "v_scale": v_sc}
+        return x, {
+            "k": pool["k"].at[l, flat_pages].set(paged(k.astype(cfg.dtype))),
+            "v": pool["v"].at[l, flat_pages].set(paged(v.astype(cfg.dtype)))}
 
     x, pool = _scan_pool_layers(body, x, stacked, pool)
     logits = _head(params, cfg, x)                         # [N, S, V]
@@ -295,7 +323,6 @@ def _chunk_paged_forward(cfg: GPTConfig, params, tokens, pool, tables,
     → (hidden states [N, C, D], updated pool)."""
     N, C = tokens.shape
     ps = pool["k"].shape[2]
-    quant = "k_scale" in pool
     x = params["wte"].astype(cfg.dtype)[tokens]            # [N, C, D]
     rel = jnp.arange(C)
     pos = offsets[:, None] + rel[None, :]                  # [N, C]
@@ -315,9 +342,7 @@ def _chunk_paged_forward(cfg: GPTConfig, params, tokens, pool, tables,
     write_offs = (pos % ps).reshape(-1)                         # [N*C]
     kv_lens = offsets + n_valid                                 # [N]
 
-    def body(x, layer, planes):
-        k_pool_l, v_pool_l = planes["k"], planes["v"]
-        k_sc_l, v_sc_l = planes.get("k_scale"), planes.get("v_scale")
+    def body(x, layer, l, pool):
         h = _layer_norm(x, layer["ln1_scale"], layer["ln1_bias"])
         q, k, v = _qkv(h, layer, cfg)
         q = _rotary_pos(q, cfg.rotary_dim, pos)
@@ -327,38 +352,27 @@ def _chunk_paged_forward(cfg: GPTConfig, params, tokens, pool, tables,
         # intra-chunk causality is just the tpos <= qpos mask.
         # Head count from the array, not the config: under tensor
         # parallelism this body sees the per-shard head slice.
-        if quant:
-            k_pool_l, k_sc_l = _quant_write(
-                k_pool_l, k_sc_l, write_pages, write_offs,
-                k.reshape(N * C, *k.shape[2:]), tp_axis)
-            v_pool_l, v_sc_l = _quant_write(
-                v_pool_l, v_sc_l, write_pages, write_offs,
-                v.reshape(N * C, *v.shape[2:]), tp_axis)
-        else:
-            k_pool_l = k_pool_l.at[write_pages, write_offs].set(
-                k.reshape(N * C, *k.shape[2:]).astype(cfg.dtype))
-            v_pool_l = v_pool_l.at[write_pages, write_offs].set(
-                v.reshape(N * C, *v.shape[2:]).astype(cfg.dtype))
+        pool = _write_rows(pool, l, write_pages, write_offs,
+                           _rows(k), _rows(v), tp_axis)
         if attn_impl == "kernel":
             from ray_tpu.ops.paged_attention import paged_prefill_attention
 
-            attn = paged_prefill_attention(
-                q, k_pool_l, v_pool_l, tables, offsets, kv_lens,
-                sm_scale=scale, k_scale=k_sc_l, v_scale=v_sc_l)
+            attend = paged_prefill_attention
         else:
             from ray_tpu.ops.paged_attention import (
                 reference_paged_prefill_attention)
 
-            attn = reference_paged_prefill_attention(
-                q, k_pool_l, v_pool_l, tables, offsets, kv_lens,
-                sm_scale=scale, k_scale=k_sc_l, v_scale=v_sc_l)
+            attend = reference_paged_prefill_attention
+        attn = attend(q, pool["k"], pool["v"], l, tables, offsets, kv_lens,
+                      sm_scale=scale, k_scale=pool.get("k_scale"),
+                      v_scale=pool.get("v_scale"))
         attn_out = jnp.einsum("bchk,hkd->bcd", attn,
                               weight_view(layer, "wo", cfg.dtype))
         if tp_axis is not None:
             attn_out = jax.lax.psum(attn_out, tp_axis)
         x = x + attn_out
         x = _mlp(x, layer, cfg, tp_axis=tp_axis)
-        return x, _planes(k_pool_l, v_pool_l, k_sc_l, v_sc_l)
+        return x, pool
 
     x, pool = _scan_pool_layers(body, x, stacked, pool)
     return x, pool
@@ -463,14 +477,15 @@ def _decode_once_paged(cfg: GPTConfig, params, tokens, pool, positions,
     (static): "gather" reconstitutes each slot's contiguous timeline
     [B, T, H, K] (T = max_pages × page_size) per layer — math identical
     to the dense `_decode_once`; "kernel" runs the Pallas ragged
-    paged-attention kernel against the pool in place. `write_mask`
-    ([B] bool, optional) routes masked rows' K/V writes to the null
+    paged-attention kernel against the pool in place, at (layer, page).
+    `write_mask` ([B] bool, optional) routes masked rows' K/V writes to the null
     page — the speculative draft loop uses it so proposal steps past a
     slot's per-tick budget never touch real pages. `tp_axis` (optional):
     the tensor-parallel mesh axis when this body runs inside a
     shard_map over head-sharded params and pool — both attention impls
     read their per-shard pages unchanged (pages are indexed by id; only
-    the head dim is sliced) and the attention-out / MLP-down partial
+    the H*K axis is sliced, into whole heads) and the attention-out /
+    MLP-down partial
     sums psum across shards.
     → (logits [B, V] fp32, updated pool).
     """
@@ -478,7 +493,6 @@ def _decode_once_paged(cfg: GPTConfig, params, tokens, pool, positions,
         raise ValueError(
             f"attn_impl must be gather|kernel, got {attn_impl!r}")
     ps = pool["k"].shape[2]
-    quant = "k_scale" in pool
     x = params["wte"].astype(cfg.dtype)[tokens][:, None, :]  # [B, 1, D]
     pos = positions[:, None]
     # Pre-cast the stacked block params once: the per-layer weight_view
@@ -499,32 +513,21 @@ def _decode_once_paged(cfg: GPTConfig, params, tokens, pool, positions,
     write_off = positions % ps                               # [B]
     kv_lengths = positions + 1                               # [B]
 
-    def body(x, layer, planes):
-        k_pool_l, v_pool_l = planes["k"], planes["v"]
-        k_sc_l, v_sc_l = planes.get("k_scale"), planes.get("v_scale")
+    def body(x, layer, l, pool):
         h = _layer_norm(x, layer["ln1_scale"], layer["ln1_bias"])
         q, k, v = _qkv(h, layer, cfg)
         q = _rotary_pos(q, cfg.rotary_dim, pos)
         k = _rotary_pos(k, cfg.rotary_dim, pos)
-        if quant:
-            k_pool_l, k_sc_l = _quant_write(
-                k_pool_l, k_sc_l, write_page, write_off, k[:, 0], tp_axis)
-            v_pool_l, v_sc_l = _quant_write(
-                v_pool_l, v_sc_l, write_page, write_off, v[:, 0], tp_axis)
-        else:
-            k_pool_l = k_pool_l.at[write_page, write_off].set(
-                k[:, 0].astype(cfg.dtype))
-            v_pool_l = v_pool_l.at[write_page, write_off].set(
-                v[:, 0].astype(cfg.dtype))
+        pool = _write_rows(pool, l, write_page, write_off,
+                           _rows(k), _rows(v), tp_axis)
         if attn_impl == "kernel":
             # Ragged paged attention: K/V pages are read in place from
-            # the pool (one DMA per live page, pl.when-skipped null
-            # tail); no [B, T, H, K] timeline ever hits HBM.
+            # the pool at (l, page) (one DMA per live page,
+            # pl.when-skipped null tail); no [B, T, H, K] timeline ever
+            # hits HBM.
             from ray_tpu.ops.paged_attention import paged_attention
 
-            attn = paged_attention(q[:, 0], k_pool_l, v_pool_l, tables,
-                                   kv_lengths, sm_scale=scale,
-                                   k_scale=k_sc_l, v_scale=v_sc_l)
+            attend = paged_attention
         else:
             # Gather reference: reconstitute the contiguous [B, T, H, K]
             # timeline — ONE implementation shared with the kernel's test
@@ -532,16 +535,17 @@ def _decode_once_paged(cfg: GPTConfig, params, tokens, pool, positions,
             from ray_tpu.ops.paged_attention import (
                 reference_paged_attention)
 
-            attn = reference_paged_attention(
-                q[:, 0], k_pool_l, v_pool_l, tables, kv_lengths,
-                sm_scale=scale, k_scale=k_sc_l, v_scale=v_sc_l)
+            attend = reference_paged_attention
+        attn = attend(q[:, 0], pool["k"], pool["v"], l, tables, kv_lengths,
+                      sm_scale=scale, k_scale=pool.get("k_scale"),
+                      v_scale=pool.get("v_scale"))
         attn_out = jnp.einsum("bhk,hkd->bd", attn,
                               weight_view(layer, "wo", cfg.dtype))
         if tp_axis is not None:
             attn_out = jax.lax.psum(attn_out, tp_axis)
         x = x + attn_out[:, None, :]
         x = _mlp(x, layer, cfg, tp_axis=tp_axis)
-        return x, _planes(k_pool_l, v_pool_l, k_sc_l, v_sc_l)
+        return x, pool
 
     x, pool = _scan_pool_layers(body, x, stacked, pool)
     logits = _head(params, cfg, x)[:, 0]
@@ -663,16 +667,20 @@ def decode_multi_paged(cfg: GPTConfig, params, tokens, pool, positions,
     across the window). → (tokens_out [n_steps, B] int32, updated pool).
 
     The window is a `_decode_window` of ONE step program, not one
-    program scanning over steps. A scan over steps around the scan over
-    layers made the TPU compiler re-lay the whole pool out for the
-    outer loop (head_dim 64 pads to 128 lanes there — a 2x copy of a
-    6.4 GB pool), which did not fit a 16 GB chip at OPT-1.3B; the
-    single-loop step program keeps the pool in the layout it arrives
-    in. The only program a window compiles is that step
-    (`_decode_sample_paged`), one per table width, whatever n_steps is:
-    under the engine's compile_watch label `decode_multi_paged`,
-    `jax_compiles_total{fn}` counts table widths. What it costs is
-    n_steps host dispatches per window where the scan paid one."""
+    program scanning over steps. It became that in PR 21, when the pool
+    was ``[..., H, K]``: a scan over steps around the scan over layers
+    made the TPU compiler re-lay the whole pool out for the outer loop
+    (head_dim 64 padded to 128 lanes there — a 2x copy of a 6.4 GB
+    pool), which did not fit a 16 GB chip at OPT-1.3B. The lane-dense
+    pool has no padded layout to fall into, so that reason is gone; the
+    window stays k dispatches because a dispatch costs 0.45 ms against
+    a 9 ms step (PERF.md section 5) and fusing it again is its own
+    change (ROADMAP S3). The only program a window compiles is that
+    step (`_decode_sample_paged`), one per table width, whatever
+    n_steps is: under the engine's compile_watch label
+    `decode_multi_paged`, `jax_compiles_total{fn}` counts table widths.
+    What it costs is n_steps host dispatches per window where the scan
+    paid one."""
 
     def step(toks, kv, pos, rng):
         return _decode_sample_paged(cfg, params, toks, kv, pos, tables,
@@ -729,7 +737,8 @@ def spec_draft_propose(cfg: GPTConfig, params, tokens, pool, positions,
 # Tensor-parallel twins (llm_tp > 1): the SAME bodies as above, run
 # per-shard over a 1-axis ("tp",) mesh via utils/jax_compat.shard_map.
 # Params shard per models/gpt.py::partition_rules and the page pool
-# shards along its HEAD axis (KV_POOL_PARTITION_RULES below) — each
+# shards along its minor H*K axis, heads major
+# (KV_POOL_PARTITION_RULES below) — each
 # shard owns every page id for n_heads/tp heads, so page tables,
 # cursors, and the host-side allocator are shard-invariant and both
 # attention impls (including the Pallas kernels, which derive H from
@@ -740,9 +749,11 @@ def spec_draft_propose(cfg: GPTConfig, params, tokens, pool, positions,
 # dispatch table.
 # --------------------------------------------------------------------------
 
-# Pool pytree {"k": [L, P+1, ps, H, K], "v": ...} → heads (axis 3) shard
-# over tp. Lives here (not partition.py) because the pool layout is this
-# module's contract; the axis name comes from partition.TP_AXIS.
+# Pool pytree {"k": [L, P+1, ps, H*K], "v": ...} → the minor axis
+# (axis 3, heads major within it) shards over tp: a shard's
+# [..., (H/tp)*K] lanes are its own whole, contiguous heads. Lives here
+# (not partition.py) because the pool layout is this module's contract;
+# the axis name comes from partition.TP_AXIS.
 def _kv_pool_partition_rules():
     from jax.sharding import PartitionSpec
 
@@ -752,7 +763,7 @@ def _kv_pool_partition_rules():
     # every head, and _quant_write pmax's the scale contribution across
     # head shards, so each shard's copy stays identical by construction.
     return ((r"^(k|v)$",
-             PartitionSpec(None, None, None, TP_AXIS, None)),
+             PartitionSpec(None, None, None, TP_AXIS)),
             (r"^(k|v)_scale$", PartitionSpec()))
 
 

@@ -41,8 +41,10 @@ __all__ = [
     "split_head_planes", "concat_head_planes",
 ]
 
-# KV page planes [L, n_pages, page_size, H, K] shard on their head dim —
+# KV page planes [L, n_pages, page_size, H*K] shard on their minor axis —
 # the axis the ("tp",) mesh partitions (paged_kv.KV_POOL_PARTITION_RULES).
+# Heads are the major part of it, so an even split into tp pieces hands
+# each shard (H/tp)*K contiguous lanes: its own whole heads.
 # split_head_planes/concat_head_planes below speak the same axis.
 KV_HEAD_AXIS = 3
 
@@ -51,7 +53,7 @@ def split_head_planes(payload: dict, tp: int) -> dict:
     """Full-head host page planes → per-shard planes keyed ``name@s``.
 
     The KV page-set donation path at ``llm_tp > 1``: a gathered payload
-    ``{"k": [L, n, ps, H, K], ...}`` splits along the head axis into
+    ``{"k": [L, n, ps, H*K], ...}`` splits along the head axis into
     ``tp`` planes (``k@0`` … ``k@{tp-1}``), so each entry in the object
     store is one shard's bytes and an adopter reassembles exactly the
     shards it needs. ``_scale``-suffixed planes ([L, n] per-page

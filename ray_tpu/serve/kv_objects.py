@@ -104,10 +104,15 @@ def engine_fingerprint(cfg, page_size: int, chunk: int,
     pool mirrors target pages, so adoption must fill both. A quantized
     pool (int8 planes + per-page scales) appends its kv_dtype: its
     payloads carry an extra plane set a bf16 adopter has no slot for,
-    and vice versa."""
+    and vice versa. ``:hk`` names the payload layout — K/V planes
+    [L, n, page_size, H*K], heads flattened into the minor axis as the
+    pool stores them (models/paged_kv.py) — so a page set donated in the
+    older [L, n, page_size, H, K] shape never matches and falls through
+    to re-prefill; like the rest it is tp-invariant (full-head
+    geometry)."""
     fp = (f"{cfg.n_layers}x{cfg.n_heads}x{cfg.head_dim}"
           f":{cfg.dtype.__name__ if hasattr(cfg.dtype, '__name__') else cfg.dtype}"
-          f":ps{page_size}:c{chunk}")
+          f":ps{page_size}:c{chunk}:hk")
     if draft_cfg is not None:
         fp += (f":d{draft_cfg.n_layers}x{draft_cfg.n_heads}"
                f"x{draft_cfg.head_dim}")

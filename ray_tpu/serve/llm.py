@@ -2166,7 +2166,8 @@ class LLMEngine:
                     from ray_tpu.models import partition as _partition
 
                     p = _partition.concat_head_planes(p, donor_tp)
-                if (p["k"].shape[1] != meta["n_pages"]
+                if (p["k"].shape[1:] != (meta["n_pages"],)
+                        + tuple(self.cache["k"].shape[2:])
                         or (self.spec_k and "dk" not in p)):
                     raise ValueError("kv payload shape mismatch")
                 payloads.append(p)

@@ -33,8 +33,10 @@ from ray_tpu.ops.attention import flash_attention
 from ray_tpu.ops.paged_attention import (paged_attention,
                                          paged_prefill_attention)
 
-# OPT-1.3B head shapes with a deployment-sized pool (16 slots x 2048).
+# OPT-1.3B head shapes with a deployment-sized pool (16 slots x 2048);
+# the kernels see the WHOLE pool [L, P, page_size, H*K] and a layer index.
 B, H, K, P, PS, N_PG, C = 16, 32, 64, 512, 64, 16, 128
+L = 24
 
 
 @pytest.fixture(scope="module")
@@ -87,8 +89,12 @@ def _compile(fn, *args, kernels=()):
     return compiled
 
 
-def _pool(chip, dtype):
-    return chip((P, PS, H, K), dtype)
+def _pool(chip, dtype, heads=H, head_dim=K):
+    return chip((L, P, PS, heads * head_dim), dtype)
+
+
+def _layer(chip):
+    return chip((), jnp.int32)
 
 
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
@@ -98,26 +104,40 @@ def test_decode_kernel_compiles(chip, kv):
     q = chip((B, H, K), jnp.bfloat16)
     tables, lengths = chip((B, N_PG), jnp.int32), chip((B,), jnp.int32)
     if kv == "bf16":
-        _compile(lambda q, k, v, t, n: paged_attention(
-            q, k, v, t, n, interpret=False),
+        _compile(lambda q, k, v, l, t, n: paged_attention(
+            q, k, v, l, t, n, interpret=False),
             q, _pool(chip, jnp.bfloat16), _pool(chip, jnp.bfloat16),
-            tables, lengths, kernels=("paged_decode_attn",))
+            _layer(chip), tables, lengths, kernels=("paged_decode_attn",))
     else:
-        scale = chip((P,), jnp.float32)   # per-page scales in scalar memory
-        _compile(lambda q, k, v, t, n, ks, vs: paged_attention(
-            q, k, v, t, n, interpret=False, k_scale=ks, v_scale=vs),
-            q, _pool(chip, jnp.int8), _pool(chip, jnp.int8),
+        # Per-page scale planes; the layer's row goes to scalar memory.
+        scale = chip((L, P), jnp.float32)
+        _compile(lambda q, k, v, l, t, n, ks, vs: paged_attention(
+            q, k, v, l, t, n, interpret=False, k_scale=ks, v_scale=vs),
+            q, _pool(chip, jnp.int8), _pool(chip, jnp.int8), _layer(chip),
             tables, lengths, scale, scale, kernels=("paged_decode_attn",))
+
+
+def test_decode_kernel_compiles_at_head_dim_256(chip):
+    """GPT-J's heads (16 of 256; 4 a shard at tp=4) through the same
+    kernel: one layout serves head_dim 64 and 256."""
+    heads, head_dim = 4, 256
+    _compile(lambda q, k, v, l, t, n: paged_attention(
+        q, k, v, l, t, n, interpret=False),
+        chip((B, heads, head_dim), jnp.bfloat16),
+        _pool(chip, jnp.bfloat16, heads, head_dim),
+        _pool(chip, jnp.bfloat16, heads, head_dim), _layer(chip),
+        chip((B, N_PG), jnp.int32), chip((B,), jnp.int32),
+        kernels=("paged_decode_attn",))
 
 
 @pytest.mark.parametrize("chunk", [C, 5], ids=["prefill128", "verify5"])
 def test_prefill_kernel_compiles(chip, chunk):
     """Chunked prefill (C=128) and the speculative-verify row (C=k+1=5),
     which is the same kernel."""
-    _compile(lambda q, k, v, t, o, n: paged_prefill_attention(
-        q, k, v, t, o, n, interpret=False),
+    _compile(lambda q, k, v, l, t, o, n: paged_prefill_attention(
+        q, k, v, l, t, o, n, interpret=False),
         chip((B, chunk, H, K), jnp.bfloat16), _pool(chip, jnp.bfloat16),
-        _pool(chip, jnp.bfloat16), chip((B, N_PG), jnp.int32),
+        _pool(chip, jnp.bfloat16), _layer(chip), chip((B, N_PG), jnp.int32),
         chip((B,), jnp.int32), chip((B,), jnp.int32),
         kernels=("paged_prefill_attn",))
 
@@ -145,7 +165,7 @@ def test_decode_kernel_compiles_under_tp_mesh(topo, no_persistent_cache):
 
     mesh = Mesh(np.asarray(topo.devices[:4]), ("tp",))
     heads = PartitionSpec(None, "tp", None)
-    pool = PartitionSpec(None, None, "tp", None)
+    pool = PartitionSpec(None, None, None, "tp")   # H*K: whole heads a shard
     rep = PartitionSpec()
 
     def sds(shape, dtype, spec):
@@ -154,17 +174,19 @@ def test_decode_kernel_compiles_under_tp_mesh(topo, no_persistent_cache):
 
     fn = shard_map(
         functools.partial(paged_attention, interpret=False), mesh=mesh,
-        in_specs=(heads, pool, pool, rep, rep), out_specs=heads,
+        in_specs=(heads, pool, pool, rep, rep, rep), out_specs=heads,
         check_vma=False)
+    n_layers = 2
     compiled = _compile(
         fn, sds((B, H, K), jnp.bfloat16, heads),
-        sds((P, PS, H, K), jnp.bfloat16, pool),
-        sds((P, PS, H, K), jnp.bfloat16, pool),
+        sds((n_layers, P, PS, H * K), jnp.bfloat16, pool),
+        sds((n_layers, P, PS, H * K), jnp.bfloat16, pool),
+        sds((), jnp.int32, rep),
         sds((B, N_PG), jnp.int32, rep), sds((B,), jnp.int32, rep))
     # Each device holds a quarter of the heads: q 16x8x64 bf16 in,
-    # plus its pool shards.
+    # plus its pool shards, with no padding (512 lanes a row).
     per_device = compiled.memory_analysis().argument_size_in_bytes
-    assert per_device < 2 * P * PS * H * K * 2 / 4 * 2.5
+    assert per_device < 2 * n_layers * P * PS * H * K * 2 / 4 * 1.05
 
 
 def test_flash_training_step_partitions_over_fsdp(topo, no_persistent_cache):
@@ -212,3 +234,124 @@ def test_flash_training_step_partitions_over_fsdp(topo, no_persistent_cache):
     finally:
         attention_mod._interpret_default = saved
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# --- the WHOLE step programs of the benchmark's serving cell -------------
+# OPT-1.3B as `benchmarks/configs/opt-1.3b.json` serves it: 32 slots,
+# 512 pages of 64, full table width 32, prefill chunk 128.
+CELL_SLOTS, CELL_PAGES, CELL_WIDTH, CELL_CHUNK = 32, 512, 32, 128
+_MOVES = re.compile(r"\b(copy|dynamic-slice|dynamic-update-slice)\b")
+_RESULT = re.compile(r"^\s*(?:ROOT )?(%[\w.\-]+) = \(?(\w+)\[([\d,]*)\]")
+_OPCODE = re.compile(r"(?:^|\s)([a-z][\w\-.]*)\(")
+
+
+def _pool_moves(text, dtype, min_elems):
+    """Lines of compiled HLO `text` that are a copy, dynamic-slice or
+    dynamic-update-slice — the instruction itself or a fusion named
+    after one — with a result of the pool's `dtype` holding `min_elems`
+    (one layer of a plane) or more. Pool-typed results only: the prefill
+    head's fp32 logits [32,128,V/2] are copied too, and are nobody's
+    pool."""
+    moved = []
+    for line in text.splitlines():
+        hit = _RESULT.match(line)
+        if not hit or hit.group(2) != dtype or not hit.group(3):
+            continue
+        opcode = _OPCODE.search(line.split(" = ", 1)[1])
+        key = hit.group(1) + " " + (opcode.group(1) if opcode else "")
+        if (_MOVES.search(key.replace("_", " ")) and np.prod(
+                [int(d) for d in hit.group(3).split(",")]) >= min_elems):
+            moved.append(line.strip()[:160])
+    return moved
+
+
+def test_pool_move_rule_finds_the_old_programs_ops():
+    """The rule below, held to the four kinds of op that were 90 % of the
+    5-D pool's decode step (the ledger's PR 24 `breakdown`)."""
+    layer = 513 * 64 * 32 * 64
+    old = """
+  %copy.75 = bf16[1,513,64,32,64]{4,3,2,1,0:T(8,128)(2,1)} copy(%fusion.4)
+  %copy.81 = bf16[1,513,64,32,64]{1,4,3,2,0:T(8,128)(2,1)} copy(%custom-call.2)
+  %constant_dynamic-slice_fusion.4 = bf16[1,513,64,32,64]{1,4,3,2,0:T(8,128)(2,1)} fusion(%p.1, %p.2), kind=kLoop
+  %constant_dynamic-update-slice_fusion.5 = bf16[24,513,64,32,64]{1,4,3,2,0:T(8,128)(2,1)} fusion(%p.1), kind=kLoop
+  ROOT %dynamic-update-slice.9 = bf16[24,513,64,32,64]{1,4,3,2,0:T(8,128)(2,1)} dynamic-update-slice(%a, %b, %c)
+  %copy.3 = bf16[32,32,64]{2,1,0:T(8,128)(2,1)} copy(%q)
+  %copy.34 = f32[32,128,25216]{2,1,0:T(8,128)} copy(%mini-gather-slice.2)
+  %fusion.198 = bf16[787968,2048]{1,0:T(8,128)(2,1)} fusion(%bitcast.335, %rows), kind=kCustom
+"""
+    assert [m.split(" = ")[0] for m in _pool_moves(old, "bf16", layer)] == [
+        "%copy.75", "%copy.81", "%constant_dynamic-slice_fusion.4",
+        "%constant_dynamic-update-slice_fusion.5",
+        "ROOT %dynamic-update-slice.9"]
+
+
+@pytest.fixture(scope="module")
+def opt_serving(chip):
+    """(cfg, params, pool) as shapes on one described chip."""
+    import importlib
+
+    from ray_tpu.models import gpt, paged_kv
+
+    cfg = gpt.GPTConfig.opt_1_3b(vocab_size=50272, max_seq=2048)
+    params = {name: chip(spec["shape"], jnp.bfloat16)
+              for name, spec in gpt.param_specs(cfg).items()}
+    pool = jax.tree.map(
+        lambda x: chip(x.shape, x.dtype),
+        jax.eval_shape(lambda: paged_kv.init_paged_kv(cfg, CELL_PAGES, PS)))
+    # The programs ask the backend whether to interpret; the backend here
+    # is the CPU, the target is the chip — steer it in the test.
+    mod = importlib.import_module("ray_tpu.ops.paged_attention")
+    saved = mod._interpret_default
+    mod._interpret_default = lambda: False
+    yield cfg, params, pool
+    mod._interpret_default = saved
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_paged_program_moves_no_pool_layer(chip, opt_serving, program):
+    """`_decode_sample_paged` and `prefill_chunk_paged`, compiled whole at
+    the cell's size: both kernels are in them under their names, the pool
+    is lane-dense and row-major as the chip lays it out, and nothing in
+    them cuts a layer out of the pool, re-lays it out or puts it back —
+    the eight ops that were 90 % of a decode step (PERF.md, PR 25)."""
+    from ray_tpu.models import paged_kv
+
+    cfg, params, pool = opt_serving
+    i32 = lambda *shape: chip(shape, jnp.int32)
+    if program == "decode":
+        key = jax.eval_shape(lambda: jax.random.key(0))
+        compiled = paged_kv._decode_sample_paged.lower(
+            cfg, params, i32(CELL_SLOTS), pool, i32(CELL_SLOTS),
+            i32(CELL_SLOTS, CELL_WIDTH), chip((CELL_SLOTS,), jnp.float32),
+            chip(key.shape, key.dtype), attn_impl="kernel").compile()
+        kernel = "paged_decode_attn"
+    else:
+        compiled = paged_kv.prefill_chunk_paged.lower(
+            cfg, params, i32(CELL_SLOTS, CELL_CHUNK), pool,
+            i32(CELL_SLOTS, CELL_WIDTH), i32(CELL_SLOTS), i32(CELL_SLOTS),
+            return_logits=True, attn_impl="kernel").compile()
+        kernel = "paged_prefill_attn"
+    text = compiled.as_text()
+    # (a) the kernel, under the name the trace and the benchmark find it by.
+    assert re.search(rf"%\w*{kernel}[\w.]* = [^\n]*custom-call\(", text), \
+        f"no custom call named %{kernel}"
+    # The pool's own layout: minor axis H*K = 2,048 lanes, row-major, so
+    # the kernel's page block is what the chip stores.
+    lanes = cfg.n_heads * cfg.head_dim
+    plane = f"bf16[{cfg.n_layers},{CELL_PAGES + 1},{PS},{lanes}]"
+    assert lanes % 128 == 0
+    assert plane + "{3,2,1,0:" in text, "the pool is not row-major"
+    assert plane.replace("bf16[", "bf16[1,") not in text   # no layer slice
+    # (b) no copy, dynamic-slice or dynamic-update-slice (an instruction
+    # or a fusion named after one) whose result holds a layer of the
+    # pool or more.
+    layer_elems = (CELL_PAGES + 1) * PS * lanes
+    moved = _pool_moves(text, "bf16", layer_elems)
+    assert not moved, "pool-sized moves:\n" + "\n".join(moved)
+    # (c) no second pool: what the program needs beyond its arguments is
+    # under one K or V plane (in fact: the head's logits in prefill, half
+    # a megabyte in decode), and the donated pool is updated in place.
+    mem = compiled.memory_analysis()
+    plane_bytes = cfg.n_layers * layer_elems * 2
+    assert mem.temp_size_in_bytes < plane_bytes
+    assert mem.alias_size_in_bytes >= 2 * plane_bytes

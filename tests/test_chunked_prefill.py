@@ -54,6 +54,54 @@ def _ragged_prompts(rng, lengths):
             for n in lengths]
 
 
+class TestChunkProgramOnTheFlatPool:
+    """`prefill_chunk_paged` itself (no engine): rows land at
+    (layer, page, offset) of the pool [L, P+1, ps, H*K], nothing else
+    moves, and the kernel reads back what the gather oracle reads."""
+
+    @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+    def test_rows_land_at_layer_page_offset(self, params, kv_dtype):
+        from ray_tpu.models.paged_kv import (init_paged_kv,
+                                             prefill_chunk_paged)
+
+        ps, C, n_pages = 8, 12, 6
+        rng = np.random.default_rng(9)
+        pool = init_paged_kv(CFG, n_pages, ps, kv_dtype)
+        assert pool["k"].shape == (CFG.n_layers, n_pages + 1, ps,
+                                   CFG.n_heads * CFG.head_dim)
+        toks = jnp.asarray(rng.integers(1, CFG.vocab_size, (3, C)),
+                           jnp.int32)
+        tables = jnp.asarray([[1, 2, 3], [4, 5, 0], [0, 0, 0]], jnp.int32)
+        offsets = jnp.asarray([5, 0, 0], jnp.int32)     # row 0: mid-page
+        n_valid = jnp.asarray([C, 7, 0], jnp.int32)     # row 2: inert
+        outs = {}
+        for impl in ("gather", "kernel"):
+            outs[impl] = prefill_chunk_paged(
+                CFG, params, toks, jax.tree.map(jnp.copy, pool), tables,
+                offsets, n_valid, attn_impl=impl)
+        (lg_g, pool_g), (lg_k, pool_k) = outs["gather"], outs["kernel"]
+        live = np.asarray(n_valid) > 0
+        np.testing.assert_allclose(np.asarray(lg_k)[live],
+                                   np.asarray(lg_g)[live],
+                                   rtol=2e-3, atol=2e-3)
+        k = np.asarray(pool_g["k"]).astype(np.float32)
+        for layer in range(CFG.n_layers):
+            # Row 0 wrote positions 5..16: page 1 from offset 5, all of
+            # page 2, page 3's first row. Row 1 wrote 0..6 of page 4.
+            written = np.abs(k[layer]).sum(axis=2) > 0       # [P+1, ps]
+            assert not written[1, :5].any() and written[1, 5:].all()
+            assert written[2].all()
+            assert written[3, 0] and not written[3, 1:].any()
+            assert written[4, :7].all() and not written[4, 7:].any()
+            assert not written[5:].any()
+        atol = 1e-5 if kv_dtype == "bf16" else 1     # int8: one rounding step
+        for name in pool_g:
+            np.testing.assert_allclose(
+                np.asarray(pool_k[name]).astype(np.float32)[:, 1:],
+                np.asarray(pool_g[name]).astype(np.float32)[:, 1:],
+                rtol=1e-4, atol=atol, err_msg=name)
+
+
 class TestExactness:
     """Chunked == one-shot == dense, token-for-token."""
 

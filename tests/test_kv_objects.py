@@ -494,6 +494,33 @@ class TestAdoptionLadder:
         assert out == exp[len(cont["generated_ids"]):] or out == exp
         _closure(adopter)
 
+    @pytest.mark.parametrize("stale", ["fingerprint", "payload"])
+    def test_old_layout_page_set_refuses_to_adopt(self, params, stale):
+        """A page set donated in the pool's older [L, n, ps, H, K] shape
+        never binds: its fingerprint lacks the layout token, so nothing
+        resolves; and even under a matching fingerprint a payload of that
+        shape fails the bind's shape check. Both fall through to
+        re-prefill with the cold stream."""
+        prompt = _prompt(23, 50)
+        store = LocalKVStore(budget=64)
+        _donor, cont = _export_mid_decode(params, prompt, store)
+        fp = cont["kv"]["fingerprint"]
+        assert fp.endswith(":hk")
+        for ent in store._entries.values():
+            if stale == "fingerprint":
+                ent["meta"]["fingerprint"] = fp[:-len(":hk")]
+            else:
+                ent["payload"] = {
+                    name: a.reshape(a.shape[:3] + (CFG.n_heads,
+                                                   CFG.head_dim))
+                    if a.ndim == 4 else a
+                    for name, a in ent["payload"].items()}
+        adopter, out = _resume(params, cont, store)
+        m = adopter.metrics()
+        assert m["kv_adoptions"] == 0
+        assert m["kv_adopt_failures"] == (1 if stale == "payload" else 0)
+        assert out == self._expected(params, prompt)
+
     def test_fingerprint_mismatch_never_adopts(self, params):
         prompt = _prompt(17, 50)
         store = LocalKVStore(budget=64)

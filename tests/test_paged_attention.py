@@ -18,7 +18,9 @@ from ray_tpu.models import gpt
 from ray_tpu.ops.paged_attention import (
     _interpret_default,
     paged_attention,
+    paged_prefill_attention,
     reference_paged_attention,
+    reference_paged_prefill_attention,
 )
 
 CFG = gpt.GPTConfig.tiny(attn_impl="xla", dtype=jnp.float32)
@@ -39,13 +41,19 @@ def test_interpret_fallback_is_asserted_off_tpu():
         assert _interpret_default() is False
 
 
+N_LAYERS = 3      # every op-level pool below holds several layers
+
+
 def _pool_and_tables(rng, *, B, H, K, ps, n_pg, dtype):
-    """A pool with every slot's pages allocated plus ragged lengths:
-    length 1 (fresh slot), mid-page, exact page boundary, full table, and
-    an all-null table (idle slot)."""
+    """A whole pool [L, P, ps, H*K] (the layout the programs store) with
+    every slot's pages allocated plus ragged lengths: length 1 (fresh
+    slot), mid-page, exact page boundary, full table, and an all-null
+    table (idle slot)."""
     n_pages = B * n_pg + 1
-    k_pool = jnp.asarray(rng.normal(size=(n_pages, ps, H, K)), dtype)
-    v_pool = jnp.asarray(rng.normal(size=(n_pages, ps, H, K)), dtype)
+    k_pool = jnp.asarray(
+        rng.normal(size=(N_LAYERS, n_pages, ps, H * K)), dtype)
+    v_pool = jnp.asarray(
+        rng.normal(size=(N_LAYERS, n_pages, ps, H * K)), dtype)
     tables = np.zeros((B, n_pg), np.int32)
     lengths = np.zeros(B, np.int32)
     specs = [1, ps // 2 + 1, ps, n_pg * ps, 1]
@@ -68,17 +76,142 @@ def _pool_and_tables(rng, *, B, H, K, ps, n_pg, dtype):
 @pytest.mark.parametrize("ps", [16, 64])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_kernel_matches_gather_reference(ps, dtype):
+    """Kernel against oracle at EVERY layer of one pool: the layer index
+    is part of the block index, and layers hold different values."""
     rng = np.random.default_rng(0)
     B, H, K, n_pg = 5, 4, 16, 3
     q = jnp.asarray(rng.normal(size=(B, H, K)), dtype)
     k_pool, v_pool, tables, lengths = _pool_and_tables(
         rng, B=B, H=H, K=K, ps=ps, n_pg=n_pg, dtype=dtype)
-    o = paged_attention(q, k_pool, v_pool, tables, lengths)
-    ref = reference_paged_attention(q, k_pool, v_pool, tables, lengths)
-    assert o.dtype == q.dtype
     atol = 2e-6 if dtype == jnp.float32 else 3e-2
+    outs = []
+    for layer in range(N_LAYERS):
+        o = paged_attention(q, k_pool, v_pool, jnp.int32(layer), tables,
+                            lengths)
+        ref = reference_paged_attention(q, k_pool, v_pool, layer, tables,
+                                        lengths)
+        assert o.dtype == q.dtype
+        np.testing.assert_allclose(
+            np.asarray(o, np.float32), np.asarray(ref, np.float32),
+            atol=atol)
+        outs.append(np.asarray(o, np.float32))
+    assert not np.allclose(outs[0], outs[1], atol=1e-2)
+
+
+@pytest.mark.parametrize("head_dim", [64, 256])
+def test_kernel_one_path_for_head_dim_64_and_256(head_dim):
+    """OPT's head_dim 64 (under the 128 lanes) and GPT-J's 256 go down
+    the same path: the same pool layout [L, P, ps, H*K], the same block
+    (one page of H*K lanes) and the same kernel body, with nothing in the
+    lowered program keyed on the head size but the shapes."""
+    rng = np.random.default_rng(5)
+    B, H, ps, n_pg = 3, 2, 16, 2
+    q = jnp.asarray(rng.normal(size=(B, H, head_dim)), jnp.float32)
+    k_pool, v_pool, tables, lengths = _pool_and_tables(
+        rng, B=B, H=H, K=head_dim, ps=ps, n_pg=n_pg, dtype=jnp.float32)
+    assert k_pool.shape == (N_LAYERS, B * n_pg + 1, ps, H * head_dim)
+    layer = jnp.int32(N_LAYERS - 1)
+    o = paged_attention(q, k_pool, v_pool, layer, tables, lengths)
+    ref = reference_paged_attention(q, k_pool, v_pool, layer, tables,
+                                    lengths)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(ref), atol=5e-6)
+    text = jax.jit(paged_attention).lower(
+        q, k_pool, v_pool, layer, tables, lengths).as_text()
+    assert f"x{ps}x{H * head_dim}xf32" in text     # the page block
+
+
+def _quantized(rng, shape):
+    """int8 planes [L, P, ps, H*K] and per-page scale planes [L, P]."""
+    planes = [jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+              for _ in range(2)]
+    scales = [jnp.asarray(rng.uniform(0.005, 0.02, shape[:2]), jnp.float32)
+              for _ in range(2)]
+    return planes, scales
+
+
+def test_int8_kernel_matches_gather_reference_at_every_layer():
+    """int8 pages dequantise in the kernel with THEIR layer's scale row
+    (ragged lengths: fresh slot, mid-page end, null tail, idle slot)."""
+    rng = np.random.default_rng(2)
+    B, H, K, ps, n_pg = 5, 4, 16, 16, 3
+    q = jnp.asarray(rng.normal(size=(B, H, K)), jnp.float32)
+    _k, _v, tables, lengths = _pool_and_tables(
+        rng, B=B, H=H, K=K, ps=ps, n_pg=n_pg, dtype=jnp.float32)
+    (k8, v8), (ks, vs) = _quantized(rng, _k.shape)
+    for layer in range(N_LAYERS):
+        o = paged_attention(q, k8, v8, jnp.int32(layer), tables, lengths,
+                            k_scale=ks, v_scale=vs)
+        ref = reference_paged_attention(q, k8, v8, layer, tables, lengths,
+                                        k_scale=ks, v_scale=vs)
+        np.testing.assert_allclose(np.asarray(o), np.asarray(ref),
+                                   atol=5e-6)
+
+
+@pytest.mark.parametrize("kv", ["float32", "bfloat16", "int8"])
+@pytest.mark.parametrize("head_dim", [16, 64, 256])
+def test_prefill_kernel_matches_gather_reference(kv, head_dim):
+    """The chunk kernel against its oracle on one shared pool, at a layer
+    other than 0: a chunk ending mid-page, one starting at 0, one whose
+    table has a null tail, and an inert row (no valid token)."""
+    rng = np.random.default_rng(4)
+    B, H, ps, n_pg, C = 4, 2, 8, 4, 6
+    dtype = jnp.bfloat16 if kv == "bfloat16" else jnp.float32
+    q = jnp.asarray(rng.normal(size=(B, C, H, head_dim)), dtype)
+    k_pool, v_pool, _t, _n = _pool_and_tables(
+        rng, B=B, H=H, K=head_dim, ps=ps, n_pg=n_pg, dtype=dtype)
+    tables = jnp.asarray(
+        [[1, 2, 3, 4], [5, 0, 0, 0], [6, 7, 0, 0], [0, 0, 0, 0]], jnp.int32)
+    offsets = jnp.asarray([21, 0, 7, 0], jnp.int32)
+    n_valid = jnp.asarray([C, C, 4, 0], jnp.int32)
+    scales = {}
+    if kv == "int8":
+        (k_pool, v_pool), (ks, vs) = _quantized(rng, k_pool.shape)
+        scales = {"k_scale": ks, "v_scale": vs}
+    layer = jnp.int32(N_LAYERS - 1)
+    o = paged_prefill_attention(q, k_pool, v_pool, layer, tables, offsets,
+                                offsets + n_valid, **scales)
+    ref = reference_paged_prefill_attention(
+        q, k_pool, v_pool, layer, tables, offsets, offsets + n_valid,
+        **scales)
+    assert o.shape == q.shape and o.dtype == q.dtype
+    valid = np.arange(C)[None, :] < np.asarray(n_valid)[:, None]
     np.testing.assert_allclose(
-        np.asarray(o, np.float32), np.asarray(ref, np.float32), atol=atol)
+        np.asarray(o, np.float32)[valid], np.asarray(ref, np.float32)[valid],
+        atol=3e-2 if kv == "bfloat16" else 1e-5)
+
+
+def test_page_ops_on_the_flat_pool():
+    """COW copy, donation gather and adoption scatter are generic over
+    the layer and page axes of the flat pool [L, P+1, ps, H*K]: a page
+    moves as ps rows of H*K lanes in every layer, scales beside it."""
+    from ray_tpu.models.paged_kv import (copy_pages, gather_pages,
+                                         init_paged_kv, scatter_pages)
+
+    rng = np.random.default_rng(6)
+    pool = init_paged_kv(CFG, 6, 4, "int8")
+    lanes = CFG.n_heads * CFG.head_dim
+    assert pool["k"].shape == (CFG.n_layers, 7, 4, lanes)
+    assert pool["k_scale"].shape == (CFG.n_layers, 7)
+    pool = {name: jnp.asarray(rng.integers(1, 100, a.shape), a.dtype)
+            for name, a in pool.items()}
+    before = jax.tree.map(np.asarray, pool)
+    pool = copy_pages(pool, jnp.asarray([2, 0], jnp.int32),
+                      jnp.asarray([5, 0], jnp.int32))
+    for name, a in pool.items():
+        assert np.array_equal(np.asarray(a[:, 5]), before[name][:, 2]), name
+        assert np.array_equal(np.asarray(a[:, 1:5]), before[name][:, 1:5])
+    ids = jnp.asarray([5, 3], jnp.int32)
+    payload = gather_pages(pool, ids)
+    assert payload["v"].shape == (CFG.n_layers, 2, 4, lanes)
+    assert payload["v_scale"].shape == (CFG.n_layers, 2)
+    fresh = scatter_pages(init_paged_kv(CFG, 6, 4, "int8"),
+                          jnp.asarray([1, 6], jnp.int32), payload)
+    for name in pool:
+        assert np.array_equal(np.asarray(fresh[name][:, 1]),
+                              before[name][:, 2]), name
+        assert np.array_equal(np.asarray(fresh[name][:, 6]),
+                              before[name][:, 3]), name
+        assert not np.asarray(fresh[name][:, 2:6]).any()
 
 
 def test_kernel_single_token_slot():
@@ -87,16 +220,18 @@ def test_kernel_single_token_slot():
     rng = np.random.default_rng(1)
     B, H, K, ps = 2, 4, 8, 16
     q = jnp.asarray(rng.normal(size=(B, H, K)), jnp.float32)
-    k_pool = jnp.asarray(rng.normal(size=(3, ps, H, K)), jnp.float32)
-    v_pool = jnp.asarray(rng.normal(size=(3, ps, H, K)), jnp.float32)
+    k_pool = jnp.asarray(rng.normal(size=(2, 3, ps, H * K)), jnp.float32)
+    v_pool = jnp.asarray(rng.normal(size=(2, 3, ps, H * K)), jnp.float32)
     tables = jnp.asarray([[1], [2]], jnp.int32)
     lengths = jnp.asarray([1, 1], jnp.int32)
-    o = paged_attention(q, k_pool, v_pool, tables, lengths)
-    # One valid position ⇒ output IS that position's V row.
+    o = paged_attention(q, k_pool, v_pool, jnp.int32(1), tables, lengths)
+    # One valid position ⇒ output IS that position's V row (of layer 1).
     np.testing.assert_allclose(
-        np.asarray(o[0]), np.asarray(v_pool[1, 0]), atol=1e-6)
+        np.asarray(o[0]), np.asarray(v_pool[1, 1, 0]).reshape(H, K),
+        atol=1e-6)
     np.testing.assert_allclose(
-        np.asarray(o[1]), np.asarray(v_pool[2, 0]), atol=1e-6)
+        np.asarray(o[1]), np.asarray(v_pool[1, 2, 0]).reshape(H, K),
+        atol=1e-6)
 
 
 class TestDecodeStepEquivalence:

@@ -124,8 +124,42 @@ class TestMatchPartitionRules:
         pool = paged_kv.init_paged_kv(CFG, 8, 4)
         specs = partition.match_partition_rules(
             paged_kv.KV_POOL_PARTITION_RULES, pool)
-        want = PartitionSpec(None, None, None, "tp", None)
+        # [L, P+1, ps, H*K]: the minor axis, heads major within it.
+        assert pool["k"].ndim == 4
+        want = PartitionSpec(None, None, None, "tp")
         assert specs == {"k": want, "v": want}
+        assert partition.KV_HEAD_AXIS == 3
+
+    @pytest.mark.parametrize("impl", ["kernel", "gather"])
+    def test_pool_shards_hold_whole_heads(self, impl):
+        """Attention over each tp=2 shard of the flat pool (its half of
+        the lanes, its half of the heads) equals the unsharded result:
+        a shard's lanes are whole, contiguous heads."""
+        from ray_tpu.ops.paged_attention import (paged_attention,
+                                                 reference_paged_attention)
+
+        attend = paged_attention if impl == "kernel" \
+            else reference_paged_attention
+        rng = np.random.default_rng(3)
+        L, P, ps, H, K, B = 2, 5, 8, 4, 16, 2
+        k_pool = jnp.asarray(rng.normal(size=(L, P, ps, H * K)), jnp.float32)
+        v_pool = jnp.asarray(rng.normal(size=(L, P, ps, H * K)), jnp.float32)
+        q = jnp.asarray(rng.normal(size=(B, H, K)), jnp.float32)
+        tables = jnp.asarray([[1, 2], [3, 0]], jnp.int32)
+        lengths = jnp.asarray([13, 5], jnp.int32)
+        layer = jnp.int32(1)
+        full = attend(q, k_pool, v_pool, layer, tables, lengths)
+        payload = {"k": np.asarray(k_pool), "v": np.asarray(v_pool)}
+        shards = partition.split_head_planes(payload, 2)
+        parts = [attend(q[:, s * H // 2:(s + 1) * H // 2],
+                        jnp.asarray(shards[f"k@{s}"]),
+                        jnp.asarray(shards[f"v@{s}"]),
+                        layer, tables, lengths) for s in range(2)]
+        np.testing.assert_allclose(
+            np.concatenate([np.asarray(x) for x in parts], axis=1),
+            np.asarray(full), atol=2e-6)
+        back = partition.concat_head_planes(shards, 2)
+        assert np.array_equal(back["k"], payload["k"])
 
     def test_sharding_module_folded(self):
         """ONE spec-derivation implementation: parallel/sharding.py now
