@@ -349,7 +349,10 @@ class Config:
     # (the decode-stall bound: a tick's prefill work never exceeds this).
     # 0 = pure-decode ticks (prefill only advances while nothing is
     # decoding); otherwise must be >= llm_prefill_chunk. Ignored unless
-    # llm_prefill_chunk > 0.
+    # llm_prefill_chunk > 0. It also sets the chunk program's height:
+    # every chunk dispatch is [chunk_rows, llm_prefill_chunk] with
+    # chunk_rows = min(n_slots, ceil(max(budget, chunk) / chunk)), the
+    # full chunks one tick can hold (2 for a chunk of 128).
     llm_prefill_token_budget: int = 256
     # Paged-KV prefix cache (serve/prefix_cache.py): completed requests
     # donate their chunk-aligned prefix pages (refcounted, read-only)

@@ -384,12 +384,14 @@ def _chunk_paged_forward(cfg: GPTConfig, params, tokens, pool, tables,
 def prefill_chunk_paged(cfg: GPTConfig, params, tokens, pool, tables,
                         offsets, n_valid, *, return_logits: bool = True,
                         attn_impl: str = "gather"):
-    """Write ONE chunk per slot of up to N prompts' KV pages, each at its
-    own arbitrary token offset (Sarathi/Orca-style chunked prefill, one
-    fused dispatch per scheduler tick).
+    """Write N chunk rows into their prompts' KV pages, each at its own
+    arbitrary token offset (Sarathi/Orca-style chunked prefill; rows may
+    be consecutive chunks of one prompt or chunks of different ones).
 
     The compile-count story for prefill: N and C are engine constants
-    (n_slots × chunk size) and `offsets`/`n_valid` are traced vectors,
+    (N = the engine's `chunk_rows`, the full chunks one tick's token
+    budget holds, at most n_slots; C = the chunk size) and
+    `offsets`/`n_valid` are traced vectors,
     so the table WIDTH is the only shape degree of freedom — one program
     lowers per (table width, ``return_logits``) pair. The engine slices
     tables to the pow-2 width each bucket of rows actually attends over
@@ -404,7 +406,7 @@ def prefill_chunk_paged(cfg: GPTConfig, params, tokens, pool, tables,
     width, which is the whole point for interior chunks of long-max-len
     prompts.
 
-    tokens: [N, C] (row = slot; tail chunks padded); tables: [N, width]
+    tokens: [N, C] (tail chunks padded); tables: [N, width]
     page ids, width ≤ max_pages (pages covering positions
     ``offsets[i] .. offsets[i]+n_valid[i]-1`` must be allocated and fall
     inside the sliced width — the engine's bucket rule guarantees this);

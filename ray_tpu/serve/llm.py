@@ -20,8 +20,10 @@ Design notes:
   The decode stall per tick is bounded by one budget of chunk compute,
   admission back-pressure needs one CHUNK of pool headroom instead of
   the whole prompt, and the prefill compile grid collapses from
-  buckets × admission-ladder to exactly two programs
-  (models/paged_kv.py `prefill_chunk_paged`).
+  buckets × admission-ladder to two programs per page-table width
+  (models/paged_kv.py `prefill_chunk_paged`), each
+  [chunk_rows, chunk]: as many rows as full chunks fit the budget, not
+  as the engine has slots.
 - The engine thread owns the cache; submit()/result flow through plain
   thread-safe queues, so the Serve replica's asyncio loop never blocks on
   device work.
@@ -811,8 +813,19 @@ class LLMEngine:
         # hold is admissible (buckets only cap the one-shot path).
         if prefill_chunk:
             self._prompt_cap = max_len - 1
+            # Height of the chunk program: the full chunks one tick's
+            # budget can hold (an idle tick's floor of one chunk
+            # included), never more rows than slots. A constant of the
+            # engine, so the set of programs stays one per (table width,
+            # head); a tick with more rows than this (many short
+            # prompts) goes round the packing loop in _run_prefill_chunks
+            # again instead of widening the program.
+            full_chunks = -(-max(prefill_token_budget, prefill_chunk)
+                            // prefill_chunk)
+            self.chunk_rows = min(n_slots, full_chunks)
         else:
             self._prompt_cap = min(self.buckets[-1], max_len - 1)
+            self.chunk_rows = 0
         if kv_mode == "paged":
             # HBM holds `n_pages` pages TOTAL instead of n_slots × max_len:
             # slot count stops being bounded by the worst-case sequence
@@ -1296,10 +1309,11 @@ class LLMEngine:
     def warmup_compile(self) -> int:
         """Pre-compile the chunk-program width ladder so no measured
         window (or live request) pays a first-touch compile: one inert
-        dispatch (all rows n_valid 0 — every write lands on the reserved
-        null page, pool bytes untouched) per table width per head
-        variant of `prefill_chunk_paged`, plus the draft-prefill mirror
-        and `verify_chunk_paged` when speculative decoding is on. Runs
+        [chunk_rows, C] dispatch (all rows n_valid 0 — every write lands
+        on the reserved null page, pool bytes untouched) per table width
+        per head variant of `prefill_chunk_paged`, plus the draft-prefill
+        mirror (same rows) and `verify_chunk_paged` ([n_slots, k+1]: one
+        row per decoding slot) when speculative decoding is on. Runs
         under `compile_watch.warmup_scope()` so the back-to-back ladder
         (well past the storm threshold, well inside the storm window)
         never files a false `recompile.storm` event; the compiles still
@@ -1315,17 +1329,17 @@ class LLMEngine:
 
         rt = self._rt
         jnp = rt.jnp
-        toks = jnp.asarray(
-            np.zeros((self.n_slots, self.prefill_chunk), np.int32))
-        zeros = jnp.asarray(np.zeros(self.n_slots, np.int32))
+        rows = self.chunk_rows
+        toks = jnp.asarray(np.zeros((rows, self.prefill_chunk), np.int32))
+        zeros = jnp.asarray(np.zeros(rows, np.int32))
         if self.spec_k:
             vtoks = jnp.asarray(
                 np.zeros((self.n_slots, self.spec_k + 1), np.int32))
+            vzeros = jnp.asarray(np.zeros(self.n_slots, np.int32))
         n = 0
         with _cw.warmup_scope():
             for width in self._width_ladder():
-                tables = jnp.asarray(
-                    np.zeros((self.n_slots, width), np.int32))
+                tables = jnp.asarray(np.zeros((rows, width), np.int32))
                 for head in (False, True):
                     # graftlint: disable=GUARDED-BY (warmup runs before the engine thread exists: start() calls it pre-spawn under _lifecycle_lock, and direct callers own the engine single-threaded)
                     _x, self.cache = rt.prefill_chunk_paged(
@@ -1339,9 +1353,11 @@ class LLMEngine:
                         self.draft_cfg, self.draft_params, toks,
                         self.draft_cache, tables, zeros, zeros,
                         return_logits=False, attn_impl=self.attn_impl)
+                    vtables = jnp.asarray(
+                        np.zeros((self.n_slots, width), np.int32))
                     _x, self.cache = rt.verify_chunk_paged(
-                        self.cfg, self.params, vtoks, self.cache, tables,
-                        zeros, zeros, attn_impl=self.attn_impl)
+                        self.cfg, self.params, vtoks, self.cache, vtables,
+                        vzeros, vzeros, attn_impl=self.attn_impl)
                     n += 2
         # graftlint: disable=GUARDED-BY (pre-spawn, see above)
         self._warmed = True
@@ -1644,6 +1660,12 @@ class LLMEngine:
             if self.prefill_chunk:
                 m["prefill_chunk"] = self.prefill_chunk
                 m["prefill_token_budget"] = self.prefill_budget
+                m["chunk_rows"] = self.chunk_rows
+                # Prompt tokens placed over token positions the chunk
+                # dispatches carried: 1.0 = every row a full chunk.
+                m["prefill_row_fill"] = m["prefill_tokens"] / max(
+                    1, m["prefill_dispatches"] * self.chunk_rows
+                    * self.prefill_chunk)
                 m["prefilling_slots"] = len(self._prefilling)
                 m["prefill_width_bucketing"] = self.prefill_width_bucketing
                 if self._dispatch_width_ring:
@@ -1788,6 +1810,7 @@ class LLMEngine:
             if self.prefill_chunk:
                 snap["prefill_chunk"] = self.prefill_chunk
                 snap["prefill_token_budget"] = self.prefill_budget
+                snap["chunk_rows"] = self.chunk_rows
                 if self._budget_util_ewma is not None:
                     snap["prefill_budget_util"] = round(
                         self._budget_util_ewma, 4)
@@ -2697,6 +2720,16 @@ class LLMEngine:
         are bounded by one budget of chunk compute (budget 0 = pure
         decode ticks). With nothing decoding there is nobody to stall:
         an idle tick always advances at least one chunk. → tokens spent.
+
+        One dispatch holds at most `chunk_rows` rows: as many full
+        chunks as the budget has room for, so the program is as tall as
+        a tick can fill it and no taller. The budget stops the packing
+        loop before the row bound does whenever the rows are full
+        chunks; a tick of many SHORT prompts (more rows than full chunks
+        fit the budget) goes round the loop again, several short
+        dispatches where one n_slots-row program ran — the same
+        algorithm with its one parameter read off the engine's own
+        budget, not a second path.
         """
         if not self._prefilling:
             return 0
@@ -2705,7 +2738,7 @@ class LLMEngine:
             budget = max(budget, self.prefill_chunk)
         spent = 0
         while self._prefilling:
-            # Build one fused dispatch of up to n_slots chunk ROWS, FCFS,
+            # Build one fused dispatch of up to chunk_rows chunk ROWS, FCFS,
             # until rows or the budget run out. Rows from the same prompt
             # (consecutive chunks) are as legal as rows from different
             # slots: within a layer every row's K/V is written to its
@@ -2721,12 +2754,12 @@ class LLMEngine:
             stop = False
             with self._phase("prefill.build"):
                 for slot in self._prefilling:
-                    if stop or len(batch) >= self.n_slots:
+                    if stop or len(batch) >= self.chunk_rows:
                         break
                     req = self.slot_req[slot]
                     done = self._chunk_pos[slot]
                     total = len(req.prompt_ids)
-                    while done < total and len(batch) < self.n_slots:
+                    while done < total and len(batch) < self.chunk_rows:
                         n = min(self.prefill_chunk, total - done)
                         if spent + planned + n > budget:
                             stop = True
@@ -2775,8 +2808,9 @@ class LLMEngine:
     def _dispatch_chunks(self, batch) -> None:
         """Width-bucketed chunk dispatch: group the tick's packed chunk
         rows by the pow-2 page width each row actually attends over
-        (`_chunk_width`) and issue one fixed-shape [n_slots, C] dispatch
-        per non-empty bucket, each carrying a table view sliced to its
+        (`_chunk_width`) and issue one fixed-shape [chunk_rows, C] dispatch
+        per non-empty bucket (a bucket that took only some of the batch
+        pads with inert rows), each carrying a table view sliced to its
         bucket's width — interior chunks of a long-max-len engine stop
         paying attention compute/bytes ∝ max_pages_per_slot. Buckets
         run in ASCENDING width order: consecutive chunks of one prompt
@@ -2803,11 +2837,12 @@ class LLMEngine:
                 failed |= self._dispatch_chunk_bucket(rows, width)
 
     def _dispatch_chunk_bucket(self, batch, width: int) -> set[int]:
-        """One fixed-shape [n_slots, C] prefill_chunk_paged dispatch at
-        one page-table width: each (slot, req, done, n) ROW writes
+        """One fixed-shape [chunk_rows, C] prefill_chunk_paged dispatch
+        at one page-table width: each (slot, req, done, n) ROW writes
         prompt tokens [done, done+n) into its slot's pages (several rows
         may carry consecutive chunks of the same prompt); rows without
-        work are inert (n_valid 0). The table view is sliced to `width`
+        work are inert (n_valid 0). `batch` holds at most chunk_rows
+        rows (the packing loop's bound). The table view is sliced to `width`
         columns — every row's written prefix + chunk fits by bucket
         construction, and a slot's allocation BEYOND the row's own width
         (a later same-tick chunk already grew it) is simply invisible to
@@ -2822,10 +2857,11 @@ class LLMEngine:
         follow-on chunks from later buckets in the same tick."""
         rt = self._rt
         with self._phase("prefill.build"):
-            toks = np.zeros((self.n_slots, self.prefill_chunk), np.int32)
-            offsets = np.zeros(self.n_slots, np.int32)
-            valid = np.zeros(self.n_slots, np.int32)
-            tables = np.zeros((self.n_slots, width), np.int32)
+            rows = self.chunk_rows
+            toks = np.zeros((rows, self.prefill_chunk), np.int32)
+            offsets = np.zeros(rows, np.int32)
+            valid = np.zeros(rows, np.int32)
+            tables = np.zeros((rows, width), np.int32)
             any_final = False
             t0 = time.perf_counter()
             for i, (slot, req, done, n) in enumerate(batch):
@@ -2844,8 +2880,8 @@ class LLMEngine:
                     rt.jnp.asarray(valid),
                     return_logits=any_final, attn_impl=self.attn_impl)
                 if self.spec_k:
-                    # Draft prefill mirror: the same chunk rows through
-                    # the draft model into the draft pool (same
+                    # Draft prefill mirror: the same [chunk_rows, C] rows
+                    # through the draft model into the draft pool (same
                     # tables/offsets), so a slot graduates with draft
                     # cursor == target cursor and the propose loop never
                     # needs a catch-up pass. The draft's graduation

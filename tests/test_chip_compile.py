@@ -238,8 +238,11 @@ def test_flash_training_step_partitions_over_fsdp(topo, no_persistent_cache):
 
 # --- the WHOLE step programs of the benchmark's serving cell -------------
 # OPT-1.3B as `benchmarks/configs/opt-1.3b.json` serves it: 32 slots,
-# 512 pages of 64, full table width 32, prefill chunk 128.
+# 512 pages of 64, full table width 32, prefill chunk 128; the chunk
+# program is `LLMEngine.chunk_rows` tall: the 2 full chunks the default
+# budget of 256 tokens holds.
 CELL_SLOTS, CELL_PAGES, CELL_WIDTH, CELL_CHUNK = 32, 512, 32, 128
+CELL_CHUNK_ROWS = 2
 _MOVES = re.compile(r"\b(copy|dynamic-slice|dynamic-update-slice)\b")
 _RESULT = re.compile(r"^\s*(?:ROOT )?(%[\w.\-]+) = \(?(\w+)\[([\d,]*)\]")
 _OPCODE = re.compile(r"(?:^|\s)([a-z][\w\-.]*)\(")
@@ -326,12 +329,19 @@ def test_paged_program_moves_no_pool_layer(chip, opt_serving, program):
             chip(key.shape, key.dtype), attn_impl="kernel").compile()
         kernel = "paged_decode_attn"
     else:
+        rows = CELL_CHUNK_ROWS
         compiled = paged_kv.prefill_chunk_paged.lower(
-            cfg, params, i32(CELL_SLOTS, CELL_CHUNK), pool,
-            i32(CELL_SLOTS, CELL_WIDTH), i32(CELL_SLOTS), i32(CELL_SLOTS),
+            cfg, params, i32(rows, CELL_CHUNK), pool,
+            i32(rows, CELL_WIDTH), i32(rows), i32(rows),
             return_logits=True, attn_impl="kernel").compile()
         kernel = "paged_prefill_attn"
     text = compiled.as_text()
+    if program == "prefill":
+        # The chunk program is as tall as the budget fills it: nothing in
+        # it carries n_slots x chunk = 4,096 token rows, flat or split.
+        tall = re.findall(rf"\w+\[(?:{CELL_SLOTS * CELL_CHUNK}|"
+                          rf"{CELL_SLOTS},{CELL_CHUNK}),[\d,]*\]", text)
+        assert not tall, f"n_slots-tall operands: {sorted(set(tall))[:8]}"
     # (a) the kernel, under the name the trace and the benchmark find it by.
     assert re.search(rf"%\w*{kernel}[\w.]* = [^\n]*custom-call\(", text), \
         f"no custom call named %{kernel}"

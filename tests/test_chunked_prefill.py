@@ -54,6 +54,26 @@ def _ragged_prompts(rng, lengths):
             for n in lengths]
 
 
+def _spy_chunk_shapes(eng):
+    """Record the (tokens, tables, offsets, n_valid) shapes of every
+    `prefill_chunk_paged` call the engine makes from here on."""
+    real, seen = eng._rt.prefill_chunk_paged, []
+
+    def spy(cfg, params, toks, pool, tables, offsets, valid, **kw):
+        seen.append((toks.shape, tables.shape, offsets.shape, valid.shape))
+        return real(cfg, params, toks, pool, tables, offsets, valid, **kw)
+
+    eng._rt.prefill_chunk_paged = spy
+    return seen
+
+
+def _parent_rule(eng):
+    """The rule before chunk_rows: every chunk dispatch as tall as the
+    engine has slots, up to n_slots rows packed into one."""
+    eng.chunk_rows = eng.n_slots
+    return eng
+
+
 class TestChunkProgramOnTheFlatPool:
     """`prefill_chunk_paged` itself (no engine): rows land at
     (layer, page, offset) of the pool [L, P+1, ps, H*K], nothing else
@@ -217,6 +237,122 @@ class TestExactness:
                           page_size=16, max_len=256, buckets=(64,),
                           prefill_chunk=32, prefill_token_budget=64)
         assert chunked == dense_big
+
+
+class TestChunkRows:
+    """The chunk program is as tall as the tick's budget can fill
+    (`chunk_rows`), not as the engine has slots: same programs per
+    (width, head), same tokens a tick, same streams."""
+
+    @pytest.mark.parametrize("chunk,budget,n_slots,rows", [
+        (16, 0, 4, 1),          # an idle tick still advances one chunk
+        (16, 16, 4, 1),
+        (128, 256, 32, 2),      # the serving cell's defaults
+        (128, 384, 32, 3),
+        (16, 40, 4, 3),         # a non-multiple rounds up
+        (16, 64, 4, 4),         # n_slots x chunk: the old program
+        (16, 4096, 4, 4),       # never more rows than slots
+    ])
+    def test_chunk_rows_from_budget(self, params, chunk, budget, n_slots,
+                                    rows):
+        eng = LLMEngine(CFG, params, n_slots=n_slots, max_len=256,
+                        prefill_buckets=(64,), kv_mode="paged",
+                        page_size=16, n_pages=20, prefill_chunk=chunk,
+                        prefill_token_budget=budget)
+        assert eng.chunk_rows == rows
+        assert eng.metrics()["chunk_rows"] == rows
+        assert eng.load_snapshot()["chunk_rows"] == rows
+
+    @pytest.mark.parametrize("budget", [0, 16, 32, 48])
+    def test_dispatch_arrays_are_chunk_rows_tall(self, params, budget):
+        """Warm-up's inert ladder and every live dispatch hand the
+        program [chunk_rows, chunk] arrays, whatever the batch holds."""
+        eng = LLMEngine(CFG, params, n_slots=4, max_len=128,
+                        prefill_buckets=(64,), kv_mode="paged",
+                        page_size=16, prefill_chunk=16,
+                        prefill_token_budget=budget)
+        seen = _spy_chunk_shapes(eng)
+        assert eng.warmup_compile() == 2 * len(eng._width_ladder())
+        prompts = _ragged_prompts(np.random.default_rng(12), (50, 3, 17))
+        _drive(eng, [eng.submit(p, max_tokens=3) for p in prompts])
+        rows = eng.chunk_rows
+        assert len(seen) > 2 * len(eng._width_ladder())
+        for toks, tables, offsets, valid in seen:
+            assert toks == (rows, 16)
+            assert tables[0] == rows and tables[1] in eng._width_ladder()
+            assert offsets == valid == (rows,)
+
+    @pytest.mark.parametrize("case", ["long", "short_together",
+                                      "warm_beside_cold"])
+    def test_streams_equal_oneshot_and_parent_rule(self, params, case):
+        """Same tokens for the same requests as the one-shot engine and
+        as the parent's rule (n_slots rows a dispatch), for a lone long
+        prompt, n_slots short prompts at once, and a warm-prefix row
+        beside a cold one."""
+        rng = np.random.default_rng(13)
+        kw = dict(n_slots=6, max_len=128, prefill_buckets=(128,),
+                  kv_mode="paged", page_size=16)
+        chunked = dict(kw, prefill_chunk=16, prefill_token_budget=32,
+                       prefix_cache=(case == "warm_beside_cold"))
+        first = []
+        if case == "long":
+            prompts = _ragged_prompts(rng, (100,))
+        elif case == "short_together":
+            prompts = _ragged_prompts(rng, (5, 7, 3, 9, 4, 6))
+        else:
+            shared = _ragged_prompts(rng, (40,))[0]
+            first = [shared + _ragged_prompts(rng, (9,))[0]]
+            prompts = [shared + _ragged_prompts(rng, (13,))[0],
+                       _ragged_prompts(rng, (37,))[0]]
+
+        def serve(eng):
+            for p in first:         # donates the shared prefix
+                _drive(eng, [eng.submit(p, max_tokens=6)])
+            return _drive(eng, [eng.submit(p, max_tokens=6)
+                                for p in prompts]), eng
+
+        oneshot, _ = serve(LLMEngine(CFG, params, **kw))
+        parent, _ = serve(_parent_rule(LLMEngine(CFG, params, **chunked)))
+        out, eng = serve(LLMEngine(CFG, params, **chunked))
+        assert eng.chunk_rows == 2
+        assert out == parent == oneshot
+        if case == "warm_beside_cold":
+            assert eng.metrics()["prefix_hits"] > 0
+
+    def test_short_prompts_take_several_dispatches_a_tick(self, params):
+        """More rows than full chunks fit the budget: the tick goes
+        round the packing loop again (where the parent's rule ran one
+        n_slots-row program), FCFS, and never past the budget."""
+        rng = np.random.default_rng(14)
+        budget = 32
+        kw = dict(n_slots=6, max_len=128, prefill_buckets=(64,),
+                  kv_mode="paged", page_size=16, prefill_chunk=16,
+                  prefill_token_budget=budget)
+        eng = LLMEngine(CFG, params, **kw)
+        seen = _spy_chunk_shapes(eng)
+        reqs = [eng.submit(p, max_tokens=4)
+                for p in _ragged_prompts(rng, (5, 7, 3, 9, 4, 6))]
+        eng.step()
+        # 5+7 | 3+9 | 4 (6 more would pass the budget): three dispatches
+        # of two rows, 28 tokens; the last prompt waits for the next tick.
+        assert eng.stats["prefill_dispatches"] == len(seen) == 3
+        assert eng.stats["prefill_tokens"] == 28 <= budget
+        assert [r.first_token_at is not None for r in reqs] == (
+            [True] * 5 + [False])
+        while not all(r.done.is_set() for r in reqs):
+            pt = eng.stats["prefill_tokens"]
+            eng.step()
+            assert eng.stats["prefill_tokens"] - pt <= budget
+        firsts = [r.first_token_at for r in reqs]
+        assert firsts == sorted(firsts), "first tokens left FCFS order"
+        assert all(shape[0] == (2, 16) for shape in seen)
+        parent = _parent_rule(LLMEngine(CFG, params, **kw))
+        again = [parent.submit(list(r.prompt_ids[:r.n_prompt]),
+                               max_tokens=4) for r in reqs]
+        parent.step()
+        assert parent.stats["prefill_dispatches"] == 1
+        assert parent.stats["prefill_tokens"] == 28
+        assert _drive(parent, again) == [r.out_ids for r in reqs]
 
 
 class TestCompileCount:
@@ -407,6 +543,35 @@ class TestObservability:
         ev = next(e for e in profiling.peek_events()
                   if e.get("name") == "llm.ttft")
         assert "trace_id" in ev.get("args", {})
+
+    @pytest.mark.parametrize("n_prompt,fill", [(32, 1.0), (48, 1.0),
+                                               (33, 33 / 48), (5, 5 / 16)])
+    def test_prefill_row_fill(self, params, n_prompt, fill):
+        """Prompt tokens placed over positions dispatched: 1.0 for full
+        chunks at chunk_rows 1, less with a padded tail; zeroed by
+        reset_stats() with the stats it reads."""
+        eng = LLMEngine(CFG, params, n_slots=2, max_len=128,
+                        prefill_buckets=(64,), kv_mode="paged",
+                        page_size=16, prefill_chunk=16,
+                        prefill_token_budget=16)
+        assert eng.chunk_rows == 1
+        assert eng.metrics()["prefill_row_fill"] == 0
+        _drive(eng, [eng.submit(list(range(1, n_prompt + 1)),
+                                max_tokens=2)])
+        assert eng.metrics()["prefill_row_fill"] == pytest.approx(fill)
+        eng.reset_stats()
+        assert eng.metrics()["prefill_row_fill"] == 0
+
+    def test_prefill_row_fill_counts_inert_rows(self, params):
+        """At chunk_rows 2 a lone chunk in its width bucket pads with an
+        inert row: a 32-token prompt's chunks sit at widths 1 and 2, two
+        dispatches of 2 x 16 positions for 32 tokens."""
+        _, eng = _run(params, [list(range(1, 33))], kv_mode="paged",
+                      page_size=16, prefill_chunk=16,
+                      prefill_token_budget=32)
+        m = eng.metrics()
+        assert m["chunk_rows"] == 2 and m["prefill_dispatches"] == 2
+        assert m["prefill_row_fill"] == pytest.approx(0.5)
 
     def test_request_chunk_timestamps(self, params):
         eng = LLMEngine(CFG, params, n_slots=2, max_len=128,

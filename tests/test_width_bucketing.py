@@ -166,12 +166,22 @@ class TestCompileBudget:
             self, params):
         """`warmup_compile()` lowers exactly the width ladder — one
         (interior, final) pair per pow-2 width, ≤ 2·log₂(max_pages)+2
-        programs — and a subsequent ragged traffic mix compiles NOTHING
-        new (the bench's jax_compiles_delta == 0 contract)."""
+        programs, each [chunk_rows, chunk] (the full chunks the budget
+        holds, not n_slots rows: no row ladder beside the width one) —
+        and a subsequent ragged traffic mix compiles NOTHING new (the
+        bench's jax_compiles_delta == 0 contract)."""
         from ray_tpu.models.paged_kv import prefill_chunk_paged
 
         prefill_chunk_paged.clear_cache()
         eng = _engine(params, prefill_width_bucketing=True)
+        assert eng.chunk_rows == 2 < eng.n_slots    # budget 32 / chunk 16
+        real, shapes = eng._rt.prefill_chunk_paged, set()
+
+        def spy(cfg, prm, toks, *rest, **kw):
+            shapes.add(toks.shape)
+            return real(cfg, prm, toks, *rest, **kw)
+
+        eng._rt.prefill_chunk_paged = spy
         n = eng.warmup_compile()
         ladder = eng._width_ladder()
         assert ladder == [1, 2, 4, 8]          # max_len 128 / page 16
@@ -183,6 +193,7 @@ class TestCompileBudget:
         _drive(eng, [eng.submit(p, max_tokens=8) for p in prompts])
         assert prefill_chunk_paged._cache_size() == n, (
             "traffic after warmup must not lower new chunk programs")
+        assert shapes == {(eng.chunk_rows, eng.prefill_chunk)}
 
     def test_warmup_idempotent_and_gated(self, params):
         eng = _engine(params, prefill_width_bucketing=True)
