@@ -297,7 +297,7 @@ class Config:
     # KV layout: "dense" preallocates [n_slots, max_len] per slot;
     # "paged" shares a page pool with per-slot tables + ragged attention
     # reads (models/paged_kv.py) — more slots per GB, preempt-by-
-    # recompute under pressure. BENCH_SERVE.json measures the trade.
+    # recompute under pressure.
     llm_kv_mode: str = "dense"
     # Tokens per KV page in paged mode.
     llm_kv_page_size: int = 64
@@ -308,9 +308,10 @@ class Config:
     # timeline in HBM — the throughput path on real chips; runs under
     # interpret=True off-TPU) | "auto" (resolve at engine init: "kernel"
     # when the default JAX backend is a TPU, "gather" elsewhere — one
-    # fleet-wide export serves both chip and CPU replicas). The default
-    # stays "gather" until the chip round confirms the kernel roofline
-    # (ROADMAP). Env: RAY_TPU_LLM_ATTN_IMPL=auto.
+    # fleet-wide export serves both chip and CPU replicas). The chip has
+    # run the kernel in every ledger line since PR 25; the default goes
+    # to "auto" with ROADMAP D3's deletions. Env:
+    # RAY_TPU_LLM_ATTN_IMPL=auto.
     llm_attn_impl: str = "gather"
     # Chunked prefill (paged mode only): prompts enter their slot's page
     # table in fixed-size chunks co-scheduled against decode instead of

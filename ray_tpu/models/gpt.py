@@ -42,10 +42,9 @@ class GPTConfig:
     tie_embeddings: bool = True
     remat: bool = False              # jax.checkpoint each block (for big models)
     attn_impl: str = "xla"           # "xla" | "flash" (pallas) | "ring" (sp-sharded)
-    # Pallas flash-attention tile sizes. 1024 measured best across the
-    # whole size curve on v5e (BENCH.md round-5 ablation: +8.6% tok/s at
-    # 124M, +2.5pp MFU at 1.3B vs 512) — at S<=1024 the kernel clamps to
-    # one tile per (batch, head), minimizing blocking overhead.
+    # Pallas flash-attention tile sizes: at S<=1024 a 1024 tile clamps
+    # the kernel to one tile per (batch, head), minimizing blocking
+    # overhead.
     attn_block_q: int = 1024
     attn_block_kv: int = 1024
     # Cross-entropy head chunking: compute logits/loss over sequence chunks of

@@ -2,8 +2,7 @@
 
 The dense cache (models/decode.py `init_kv_cache`) preallocates
 ``[L, B, T_max]`` per slot — HBM capacity, not compute, caps the slot
-count (OPT-1.3B at 16 slots × 2048 OOM'd a 16 GB chip, ROUND4_NOTES
-item 1b). Paged KV decouples slot count from max_len: a shared pool of
+count (OPT-1.3B at 16 slots × 2048 OOM'd a 16 GB chip). Paged KV decouples slot count from max_len: a shared pool of
 fixed-size pages ``[L, P+1, page_size, H*K]`` plus a per-slot page
 table ``[B, max_pages]`` of page ids. Slots consume pages as they grow,
 so pool capacity is sized to the *expected total live tokens*, not

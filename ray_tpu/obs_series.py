@@ -11,9 +11,9 @@ metrics, and every signal this repo already exports (`slo_burn_rate`,
 - The GCS folds every `metrics_push` snapshot into per-key rings
   (key = metric name + tags + source), queryable via the `series_query`
   RPC → `state.query_series()` → `GET /api/series`.
-- `bench_serve.py --ramp` and tests run a local store with the same
-  semantics, so the shadow autoscaler's series interface is identical
-  in-process and against a live cluster.
+- Tests run a local store with the same semantics, so the shadow
+  autoscaler's series interface is identical in-process and against a
+  live cluster.
 
 Memory is fixed by construction: at most `max_series` rings of at most
 `max_points` points each. Scalar rows store floats; histogram rows store

@@ -437,7 +437,12 @@ class GenRequest:
 
 
 class LLMEngine:
-    """Slot-based continuous batching over ray_tpu.models.decode."""
+    """Slot-based continuous batching: one engine thread drives the device
+    programs of models/paged_kv.py (a paged KV pool; models/decode.py when
+    `kv_mode="dense"`) over a fixed set of slots. What its options resolve
+    to is serve/llm_options.py's; which KV pages are free, shared or bound
+    to a slot is serve/page_pool.py's (`self.pool`, None when dense). The
+    device pool (`self.cache`) and the scheduler are here."""
 
     def __init__(self, cfg, params=None, *, n_slots: int = 8,
                  max_len: int = 2048, seed: int = 0,
@@ -457,50 +462,14 @@ class LLMEngine:
                  kv_dtype: str | None = None,
                  prefill_width_bucketing: bool | None = None,
                  warmup: bool | None = None):
-        import types
-
         import jax
-        import jax.numpy as jnp
 
-        from ray_tpu.models import decode as _decode
         from ray_tpu.models import gpt
         from ray_tpu.models import paged_kv as _paged
         from ray_tpu.models.decode import init_kv_cache
+        from ray_tpu.serve.llm_options import resolve_options
+        from ray_tpu.serve.page_pool import PagePool, pages_for
 
-        # One engine-init resolution of the jax / model-fn surface the hot
-        # loop touches: _admit/step/_dispatch_chunk run every engine tick
-        # and must not re-execute import machinery per iteration. Every
-        # jitted callable goes through compile_watch.wrap so XLA compiles
-        # are attributed to the owning program at /metrics
-        # (jax_compiles_total{fn}) and per-step recompile churn trips the
-        # recompile-storm alarm instead of hiding in step-time noise.
-        from ray_tpu import compile_watch as _cw
-
-        _cw.install()
-        _w = _cw.wrap
-        self._rt = types.SimpleNamespace(
-            jax=jax, jnp=jnp,
-            prefill=_w(_decode.prefill, "prefill"),
-            prefill_batch=_w(_decode.prefill_batch, "prefill_batch"),
-            decode_step=_w(_decode.decode_step, "decode_step"),
-            decode_multi=_w(_decode.decode_multi, "decode_multi"),
-            sample_token=_w(_decode.sample_token, "sample_token"),
-            prefill_batch_paged=_w(_paged.prefill_batch_paged,
-                                   "prefill_batch_paged"),
-            prefill_chunk_paged=_w(_paged.prefill_chunk_paged,
-                                   "prefill_chunk_paged"),
-            decode_step_paged=_w(_paged.decode_step_paged,
-                                 "decode_step_paged"),
-            decode_multi_paged=_w(_paged.decode_multi_paged,
-                                  "decode_multi_paged"),
-            copy_pages=_w(_paged.copy_pages, "copy_pages"),
-            gather_pages=_w(_paged.gather_pages, "gather_pages"),
-            scatter_pages=_w(_paged.scatter_pages, "scatter_pages"),
-            verify_chunk_paged=_w(_paged.verify_chunk_paged,
-                                  "verify_chunk_paged"),
-            spec_draft_propose=_w(_paged.spec_draft_propose,
-                                  "spec_draft_propose"),
-        )
         self.cfg = cfg
         self.n_slots = n_slots
         self.max_len = max_len
@@ -513,302 +482,39 @@ class LLMEngine:
         self.buckets = buckets
         self.params = params if params is not None else gpt.init_params(
             cfg, jax.random.key(seed))
-        chunk_explicit = prefill_chunk is not None
-        cache_explicit = prefix_cache is not None
-        spec_explicit = spec_draft is not None
-        tp_explicit = tp is not None
-        kv_explicit = kv_transfer is not None
-        wdtype_explicit = weight_dtype is not None
-        kvdtype_explicit = kv_dtype is not None
-        if (kv_mode is None or page_size is None or attn_impl is None
-                or prefill_chunk is None or prefill_token_budget is None
-                or prefix_cache is None or prefix_cache_pages is None
-                or spec_draft is None or spec_k is None or tp is None
-                or kv_transfer is None or weight_dtype is None
-                or kv_dtype is None or prefill_width_bucketing is None
-                or warmup is None):
-            from ray_tpu.core.config import runtime_config
-
-            _rc = runtime_config()
-            kv_mode = _rc.llm_kv_mode if kv_mode is None else kv_mode
-            page_size = (_rc.llm_kv_page_size if page_size is None
-                         else page_size)
-            attn_impl = (_rc.llm_attn_impl if attn_impl is None
-                         else attn_impl)
-            prefill_chunk = (_rc.llm_prefill_chunk if prefill_chunk is None
-                             else prefill_chunk)
-            prefill_token_budget = (
-                _rc.llm_prefill_token_budget if prefill_token_budget is None
-                else prefill_token_budget)
-            prefix_cache = (_rc.llm_prefix_cache if prefix_cache is None
-                            else prefix_cache)
-            prefix_cache_pages = (
-                _rc.llm_prefix_cache_pages if prefix_cache_pages is None
-                else prefix_cache_pages)
-            spec_draft = (_rc.llm_spec_draft if spec_draft is None
-                          else spec_draft)
-            spec_k = _rc.llm_spec_k if spec_k is None else spec_k
-            tp = _rc.llm_tp if tp is None else tp
-            kv_transfer = (_rc.llm_kv_transfer if kv_transfer is None
-                           else kv_transfer)
-            weight_dtype = (_rc.llm_weight_dtype if weight_dtype is None
-                            else weight_dtype)
-            kv_dtype = _rc.llm_kv_dtype if kv_dtype is None else kv_dtype
-            prefill_width_bucketing = (
-                _rc.llm_prefill_width_bucketing
-                if prefill_width_bucketing is None
-                else prefill_width_bucketing)
-            warmup = _rc.llm_warmup_compile if warmup is None else warmup
-        if prefill_chunk and kv_mode != "paged" and not chunk_explicit:
-            # The global llm_prefill_chunk knob applies to paged engines;
-            # a dense engine alongside it just keeps one-shot admission
-            # (an EXPLICIT dense+chunk arg still errors below).
-            prefill_chunk = 0
-        if prefix_cache and not (kv_mode == "paged" and prefill_chunk):
-            if cache_explicit:
-                raise ValueError(
-                    "prefix_cache requires kv_mode='paged' AND "
-                    "prefill_chunk > 0 (the cache granularity is the "
-                    f"prefill chunk); got kv_mode={kv_mode!r}, "
-                    f"prefill_chunk={prefill_chunk}")
-            # Global knob alongside an incompatible engine: soft-off,
-            # like the llm_prefill_chunk knob above.
-            prefix_cache = False
-        if prefix_cache_pages < 0:
-            raise ValueError(
-                f"prefix_cache_pages must be >= 0, got {prefix_cache_pages}")
-        if kv_mode not in ("dense", "paged"):
-            raise ValueError(f"kv_mode must be dense|paged, got {kv_mode!r}")
-        if attn_impl == "auto":
-            # Backend-resolved attention impl: the Pallas kernel on real
-            # TPUs (pages DMA'd in place — the throughput path), the
-            # exact-semantics gather reference everywhere else (off-TPU
-            # the kernel only runs under interpret=True, which is slower
-            # than the XLA gather it would replace). Resolved ONCE here:
-            # metrics()/load_snapshot() report the resolved value, so a
-            # fleet-wide RAY_TPU_LLM_ATTN_IMPL=auto export shows what
-            # each replica actually runs.
-            attn_impl = ("kernel" if jax.default_backend() == "tpu"
-                         else "gather")
-        if attn_impl not in ("gather", "kernel"):
-            raise ValueError(
-                f"attn_impl must be gather|kernel|auto, got {attn_impl!r}")
-        # Quantized serving (config-validation pattern from
-        # llm_prefill_chunk): the int8 weight/KV streams ride the paged
-        # engine only — dense mode keeps whole-tensor caches with no
-        # page planes to carry scales. GLOBAL dtype knobs alongside a
-        # dense engine soft-disable to "bf16" (a fleet-wide export must
-        # not crash replica boot); explicit args raise typed errors.
-        if weight_dtype not in ("bf16", "int8"):
-            raise ValueError(
-                f"weight_dtype must be bf16|int8, got {weight_dtype!r}")
-        if kv_dtype not in ("bf16", "int8"):
-            raise ValueError(
-                f"kv_dtype must be bf16|int8, got {kv_dtype!r}")
-        if weight_dtype == "int8" and kv_mode != "paged":
-            if wdtype_explicit:
-                raise ValueError(
-                    "weight_dtype='int8' requires kv_mode='paged' "
-                    "(quantized serving targets the paged engine; the "
-                    f"dense path is unquantized); got kv_mode={kv_mode!r}")
-            weight_dtype = "bf16"
-        if kv_dtype == "int8" and kv_mode != "paged":
-            if kvdtype_explicit:
-                raise ValueError(
-                    "kv_dtype='int8' requires kv_mode='paged' (the scale "
-                    "planes ride the page tables; the dense cache has "
-                    f"none); got kv_mode={kv_mode!r}")
-            kv_dtype = "bf16"
-        self.weight_dtype = weight_dtype
-        self.kv_dtype = kv_dtype
-        if prefill_chunk < 0 or (prefill_chunk and kv_mode != "paged"):
-            raise ValueError(
-                "prefill_chunk requires kv_mode='paged' (chunked prefill "
-                f"grows page tables chunk-by-chunk); got chunk="
-                f"{prefill_chunk} with kv_mode={kv_mode!r}")
-        if prefill_chunk and prefill_chunk > max_len:
-            # Chunked prompts are cache-capped at max_len - 1: a chunk
-            # wider than the cache would only ever pad (every dispatch
-            # computing + null-scattering dead columns).
-            raise ValueError(
-                f"prefill_chunk ({prefill_chunk}) exceeds the KV cache "
-                f"(max_len = {max_len})")
-        if prefill_chunk and prefill_token_budget != 0 and (
-                prefill_token_budget < prefill_chunk):
-            # A budget smaller than one chunk could never make progress on
-            # a busy engine (and a negative budget would silently act like
-            # 0) — reject the silent-deadlock config up front.
-            raise ValueError(
-                f"prefill_token_budget ({prefill_token_budget}) must be 0 "
-                f"(pure-decode ticks) or >= prefill_chunk ({prefill_chunk})")
-        # Speculative decoding (config-validation pattern from
-        # llm_prefill_chunk): the verify program IS the chunked-prefill
-        # program, so spec rides the paged+chunked engine only. The
-        # GLOBAL knob alongside an incompatible engine soft-disables; an
-        # explicit constructor arg errors with the typed message.
-        draft_cfg = None
-        if spec_draft and not (kv_mode == "paged" and prefill_chunk):
-            if spec_explicit:
-                raise ValueError(
-                    "speculative decoding requires kv_mode='paged' AND "
-                    "prefill_chunk > 0 (the verify pass is a chunked-"
-                    f"prefill row); got kv_mode={kv_mode!r}, "
-                    f"prefill_chunk={prefill_chunk}")
-            spec_draft = ""
-        if spec_draft_params is not None and not spec_draft:
-            # Weights were supplied (a checkpoint was read off disk) but
-            # nothing enables speculation — serving non-speculatively
-            # here would silently discard them, with only a missing
-            # spec_accepted_per_step metric as a hint.
-            raise ValueError(
-                "spec_draft_params supplied but speculative decoding is "
-                "not enabled — set spec_draft / llm_spec_draft (and note "
-                "the global knob soft-disables on non-paged/non-chunked "
-                "engines)")
-        if spec_draft:
-            if spec_k < 1:
-                raise ValueError(
-                    f"llm_spec_k must be >= 1 (tokens the draft proposes "
-                    f"per slot per tick), got {spec_k}")
-            draft_cfg = (spec_draft if isinstance(spec_draft, gpt.GPTConfig)
-                         else gpt.GPTConfig.by_name(spec_draft))
-            if draft_cfg.vocab_size != cfg.vocab_size:
-                # Proposals index the target distribution by token id;
-                # mismatched vocabs would silently verify garbage.
-                raise ValueError(
-                    "speculative draft/target vocab mismatch: draft "
-                    f"vocab_size {draft_cfg.vocab_size} != target "
-                    f"vocab_size {cfg.vocab_size} (the tokenizer must be "
-                    "tied)")
-        # Tensor-parallel decode (models/partition.py): tp > 1 runs every
-        # paged program per-shard over a ("tp",) mesh with params and the
-        # KV pool sharded along the head axis. Same validation pattern as
-        # llm_prefill_chunk: the GLOBAL llm_tp knob alongside an
-        # incompatible engine soft-disables to 1; explicit constructor
-        # args raise typed errors. tp=1 is byte-for-byte the single-chip
-        # engine (no mesh, no shard_map — the untouched dispatch table).
-        tp = int(tp)
-        if tp < 1:
-            raise ValueError(f"llm_tp must be >= 1, got {tp}")
-        if tp > 1 and not (kv_mode == "paged" and prefill_chunk):
-            if tp_explicit:
-                raise ValueError(
-                    "tensor-parallel decode requires kv_mode='paged' AND "
-                    "prefill_chunk > 0 (the sharded programs are the "
-                    f"paged chunked set); got kv_mode={kv_mode!r}, "
-                    f"prefill_chunk={prefill_chunk}")
-            tp = 1
-        self.mesh = None
-        if tp > 1 and not tp_explicit and (
-                tp > len(jax.devices())
-                or cfg.n_heads % tp or cfg.d_ff % tp
-                or (draft_cfg is not None
-                    and (draft_cfg.n_heads % tp or draft_cfg.d_ff % tp))):
-            # GLOBAL knob misfit (too few devices / non-divisor): serve
-            # unsharded rather than refuse to boot — a fleet-wide
-            # RAY_TPU_LLM_TP export must not crash the replicas whose
-            # host or model it doesn't fit (the PR 10
-            # _cpu_worker_xla_flags lesson). Explicit args stay strict
-            # below; metrics/llm_tp expose the degrade.
-            tp = 1
-        if tp > 1:
-            # The mesh build IS the device-count validation (one
-            # spelling of that error, models/partition.make_tp_mesh).
-            from ray_tpu.models import partition as _partition
-
-            self.mesh = _partition.make_tp_mesh(tp)
-            if cfg.n_heads % tp or cfg.d_ff % tp:
-                raise ValueError(
-                    f"llm_tp={tp} must divide the model's n_heads "
-                    f"({cfg.n_heads}) and d_ff ({cfg.d_ff}) — the KV pool "
-                    "shards along the head axis and the MLP along its "
-                    "hidden width")
-            if draft_cfg is not None and (
-                    draft_cfg.n_heads % tp or draft_cfg.d_ff % tp):
-                raise ValueError(
-                    f"llm_tp={tp} must divide the DRAFT model's n_heads "
-                    f"({draft_cfg.n_heads}) and d_ff ({draft_cfg.d_ff}) "
-                    "— the draft pool shards along the same head axis")
-        self.tp = tp
-        # Disaggregated serving (serve/kv_objects.py): pool_role splits
-        # replicas into a PREFILL pool — which runs a prompt's prefill,
-        # emits the first token, donates the written KV pages as
-        # page-set objects, and hands the stream off — and a DECODE pool
-        # that ADOPTS the donated pages by reference instead of
-        # re-prefilling. kv_transfer alone (no role) enables the same
-        # donate/adopt machinery on a fused engine: completed requests
-        # donate, and failover resumes adopt when the refs resolve.
-        # Validation pattern from llm_prefill_chunk: the GLOBAL
-        # llm_kv_transfer knob soft-disables on any misfit so a
-        # fleet-wide export can't crash replica boot; explicit
-        # constructor args raise typed errors.
-        if pool_role not in (None, "", "prefill", "decode"):
-            raise ValueError(
-                f"pool_role must be None|'prefill'|'decode', "
-                f"got {pool_role!r}")
-        pool_role = pool_role or None
-        if pool_role is not None and kv_explicit and not kv_transfer:
-            raise ValueError(
-                f"pool_role={pool_role!r} requires kv_transfer — the "
-                "prefill→decode handoff IS a page-set donation + "
-                "adoption")
-        if pool_role is not None:
-            kv_transfer = True
-        self._kv_transfer_disabled_reason = ""
-        if kv_transfer and not (kv_mode == "paged" and prefill_chunk
-                                and prefill_chunk % page_size == 0):
-            # chunk % page_size == 0 is load-bearing, not cosmetic:
-            # page-set entries are deduped per chain DEPTH across
-            # donations, and with page-aligned chunks every depth's
-            # span is self-contained. A mid-page chunk boundary would
-            # let a chain compose depths from DIFFERENT donations whose
-            # shared boundary page only one of them fully wrote —
-            # adopting it would serve garbage KV for the boundary
-            # positions and silently break byte-exactness. tp is NOT
-            # gated: tp>1 donors publish per-shard head planes and
-            # adopters reassemble/re-slice at bind time (heads are
-            # shard-invariant math — partition.split_head_planes).
-            reason = (
-                "KV page-set transfer requires kv_mode='paged' and "
-                "prefill_chunk > 0 with prefill_chunk % page_size == 0 "
-                "(cross-donation dedup needs page-aligned chain "
-                f"depths); got kv_mode={kv_mode!r}, "
-                f"prefill_chunk={prefill_chunk}, page_size={page_size}")
-            if kv_explicit or pool_role is not None:
-                raise ValueError(reason)
-            # Observable soft-disable (same degrade contract as the
-            # llm_prefill_chunk global knob, but never silent): the
-            # reason lands in metrics()/load_snapshot() as
-            # kv_transfer_disabled_reason and is logged once here.
-            self._kv_transfer_disabled_reason = reason
-            logger.warning("llm_kv_transfer soft-disabled: %s", reason)
-            kv_transfer = False
-        self.pool_role = pool_role
-        self.kv_transfer = bool(kv_transfer)
-        self.kv_mode = kv_mode
-        # Paged-decode attention path (models/paged_kv.py): "kernel" = the
-        # Pallas ragged paged-attention kernel, "gather" = the exact-match
-        # reference. Dense mode ignores it.
-        self.attn_impl = attn_impl
-        # Width-bucketed chunk dispatch: chunk rows group by the pow-2
-        # page width they actually attend over and each bucket's
-        # dispatch carries a table sliced to that width (the prefill
-        # twin of _decode_table_view). False = every dispatch carries
-        # the full max_pages_per_slot table (the PR 4 two-program grid;
-        # the bench ablation's control arm). Dense / one-shot engines
-        # never consult it.
-        self.prefill_width_bucketing = bool(prefill_width_bucketing)
-        # Bucket-ladder compile warmup at start() (llm_warmup_compile):
-        # serving deployments opt in so measured windows pay zero
-        # compiles; warmup_compile() is also directly callable.
-        self._warmup_on_start = bool(warmup)
+        # Resolution and every refusal: serve/llm_options.py.
+        o = resolve_options(
+            cfg, max_len=max_len, spec_draft_params=spec_draft_params,
+            pool_role=pool_role, kv_mode=kv_mode, page_size=page_size,
+            attn_impl=attn_impl, prefill_chunk=prefill_chunk,
+            prefill_token_budget=prefill_token_budget,
+            prefix_cache=prefix_cache,
+            prefix_cache_pages=prefix_cache_pages, spec_draft=spec_draft,
+            spec_k=spec_k, tp=tp, kv_transfer=kv_transfer,
+            weight_dtype=weight_dtype, kv_dtype=kv_dtype,
+            prefill_width_bucketing=prefill_width_bucketing, warmup=warmup,
+            decode_block=decode_block)
+        page_size, prefill_chunk = o.page_size, o.prefill_chunk
+        spec_draft, draft_cfg = o.spec_draft, o.draft_cfg
+        self.weight_dtype = o.weight_dtype
+        self.kv_dtype = o.kv_dtype
+        self.mesh = o.mesh
+        self.tp = o.tp
+        self.pool_role = o.pool_role
+        self.kv_transfer = bool(o.kv_transfer)
+        self._kv_transfer_disabled_reason = o.kv_transfer_disabled_reason
+        if o.kv_transfer_disabled_reason:
+            logger.warning("llm_kv_transfer soft-disabled: %s",
+                           o.kv_transfer_disabled_reason)
+        # What each of these means: core/config.py's `llm_*` knobs.
+        self.kv_mode = o.kv_mode
+        self.attn_impl = o.attn_impl
+        self.prefill_width_bucketing = bool(o.prefill_width_bucketing)
+        self._warmup_on_start = bool(o.warmup)
         self._warmed = False
-        # Chunked prefill (Sarathi/Orca-style stall-free batching): >0 =
-        # prompts enter their slot chunk-by-chunk, co-scheduled against
-        # decode under prefill_token_budget tokens per engine tick; 0 =
-        # one-shot bucketed admission (the legacy path, dense default).
+        self._bind_programs()
         self.prefill_chunk = prefill_chunk
-        self.prefill_budget = prefill_token_budget
+        self.prefill_budget = o.prefill_token_budget
         # Chunked mode is not bucket-bound: any prompt the cache/pool can
         # hold is admissible (buckets only cap the one-shot path).
         if prefill_chunk:
@@ -820,41 +526,29 @@ class LLMEngine:
             # head); a tick with more rows than this (many short
             # prompts) goes round the packing loop in _run_prefill_chunks
             # again instead of widening the program.
-            full_chunks = -(-max(prefill_token_budget, prefill_chunk)
+            full_chunks = -(-max(o.prefill_token_budget, prefill_chunk)
                             // prefill_chunk)
             self.chunk_rows = min(n_slots, full_chunks)
         else:
             self._prompt_cap = min(self.buckets[-1], max_len - 1)
             self.chunk_rows = 0
-        if kv_mode == "paged":
+        # Host-side page accounting (serve/page_pool.py). None = dense.
+        self.pool = None
+        if self.kv_mode == "paged":
             # HBM holds `n_pages` pages TOTAL instead of n_slots × max_len:
             # slot count stops being bounded by the worst-case sequence
             # length (models/paged_kv.py). Default pool = half the dense
             # footprint — the capacity win that un-OOMs 2× the slots.
-            from ray_tpu.models.paged_kv import init_paged_kv
-
             self.page_size = page_size
-            self.max_pages_per_slot = self._pages_for(max_len - 1)
+            self.max_pages_per_slot = pages_for(max_len - 1, page_size)
             if n_pages is None:
                 n_pages = max(self.max_pages_per_slot + 1,
                               (n_slots * self.max_pages_per_slot) // 2)
             self.n_pages = n_pages
-            self.cache = init_paged_kv(cfg, n_pages, page_size,
-                                       kv_dtype=self.kv_dtype)
-            self.page_table = np.zeros(
-                (n_slots, self.max_pages_per_slot), np.int32)
-            self.slot_n_pages = np.zeros(n_slots, np.int64)
-            # pop() hands out ascending ids; 0 stays reserved (null page).
-            self.free_pages = list(range(n_pages, 0, -1))
-            # Per-page reference counts: slots' tables AND prefix-cache
-            # entries each hold one ref; a page returns to free_pages
-            # only when the LAST ref drops (exclusive pages — refcount 1
-            # — behave exactly like the pre-cache allocator).
-            self.page_refs = np.zeros(n_pages + 1, np.int32)
-            # Low-water mark of the free list (peak pool occupancy =
-            # total - min_free): benches commit it so pool-pressure
-            # regressions show up in JSONs, not just preemption counts.
-            self._min_free_pages = n_pages
+            self.cache = _paged.init_paged_kv(cfg, n_pages, page_size,
+                                              kv_dtype=self.kv_dtype)
+            self.pool = PagePool(n_pages, page_size, n_slots,
+                                 self.max_pages_per_slot)
         else:
             self.cache = init_kv_cache(cfg, n_slots, max_len)
         # Speculative decoding: the draft model keeps its OWN page pool
@@ -864,20 +558,18 @@ class LLMEngine:
         # copies are all mirrored), so target-side page accounting,
         # prefix sharing, and rollback govern both pools and the draft
         # never holds a reference of its own.
-        self.spec_k = int(spec_k) if spec_draft else 0
+        self.spec_k = int(o.spec_k) if spec_draft else 0
         self.spec_draft_name = (
             spec_draft if isinstance(spec_draft, str)
             else "custom" if spec_draft else "")
-        self.draft_cfg = draft_cfg if spec_draft else None
+        self.draft_cfg = draft_cfg
         self.draft_params = None
         self.draft_cache = None
         if spec_draft:
-            from ray_tpu.models.paged_kv import init_paged_kv
-
             self.draft_params = (
                 spec_draft_params if spec_draft_params is not None
                 else gpt.init_params(draft_cfg, jax.random.key(seed + 1)))
-            self.draft_cache = init_paged_kv(
+            self.draft_cache = _paged.init_paged_kv(
                 draft_cfg, self.n_pages, self.page_size,
                 kv_dtype=self.kv_dtype)
             # Acceptance draws (temperature>0 rejection sampling) come
@@ -896,16 +588,10 @@ class LLMEngine:
             if spec_draft:
                 self.draft_params = gpt.quantize_params(self.draft_params)
         if self.tp > 1:
-            # Shard ONCE at load onto the mesh validation built: params
+            # Shard ONCE at load onto the mesh the options built: params
             # (target + draft) per gpt.partition_rules, page pools along
-            # the head axis — then swap the paged dispatch table for the
-            # shard_map twins with the mesh bound as a static kwarg, so
-            # every call site (and every byte of host-side
-            # scheduler/allocator state: page ids, tables, cursors) is
-            # unchanged. Wrapped under the SAME compile-watch names as
-            # the single-shard programs: shard-induced recompiles
-            # attribute to the owning program at /metrics and in the
-            # storm alarm.
+            # the head axis. Every byte of host-side scheduler/allocator
+            # state (page ids, tables, cursors) is shard-invariant.
             from ray_tpu.models import partition as _partition
 
             self.params = _partition.shard_by_rules(
@@ -918,35 +604,6 @@ class LLMEngine:
                 self.draft_cache = _partition.shard_by_rules(
                     self.mesh, _paged.KV_POOL_PARTITION_RULES,
                     self.draft_cache)
-            _mp = functools.partial
-            self._rt.prefill_chunk_paged = _w(
-                _mp(_paged.prefill_chunk_paged_tp, mesh=self.mesh),
-                "prefill_chunk_paged")
-            self._rt.verify_chunk_paged = _w(
-                _mp(_paged.verify_chunk_paged_tp, mesh=self.mesh),
-                "verify_chunk_paged")
-            self._rt.decode_step_paged = _w(
-                _mp(_paged.decode_step_paged_tp, mesh=self.mesh),
-                "decode_step_paged")
-            self._rt.decode_multi_paged = _w(
-                _mp(_paged.decode_multi_paged_tp, mesh=self.mesh),
-                "decode_multi_paged")
-            self._rt.copy_pages = _w(
-                _mp(_paged.copy_pages_tp, mesh=self.mesh), "copy_pages")
-            # KV page-set donation/adoption at tp>1: gather reads each
-            # shard's head slice (host asarray reassembles full heads
-            # for the donor-side split), scatter re-slices a full-head
-            # adopted payload per THIS engine's mesh — the resharding
-            # half of cross-tp adoption.
-            self._rt.gather_pages = _w(
-                _mp(_paged.gather_pages_tp, mesh=self.mesh),
-                "gather_pages")
-            self._rt.scatter_pages = _w(
-                _mp(_paged.scatter_pages_tp, mesh=self.mesh),
-                "scatter_pages")
-            self._rt.spec_draft_propose = _w(
-                _mp(_paged.spec_draft_propose_tp, mesh=self.mesh),
-                "spec_draft_propose")
         else:
             # Weights loaded from a checkpoint are host arrays; place
             # them once — left on the host, every dispatch would upload
@@ -960,15 +617,16 @@ class LLMEngine:
         # chunk-aligned prefix and chunked prefill starts at the first
         # cold token. None = off (exact pre-cache engine behavior).
         self.prefix_cache = None
-        if prefix_cache:
+        if o.prefix_cache:
             from ray_tpu.serve.prefix_cache import PrefixCache
 
-            budget = (min(prefix_cache_pages, self.n_pages)
-                      if prefix_cache_pages else max(1, self.n_pages // 2))
+            budget = (min(o.prefix_cache_pages, self.n_pages)
+                      if o.prefix_cache_pages
+                      else max(1, self.n_pages // 2))
             self.prefix_cache = PrefixCache(
                 chunk=prefill_chunk, page_size=page_size,
-                max_pages=budget, ref_page=self._ref_page,
-                unref_page=self._unref_page)
+                max_pages=budget, ref_page=self.pool.ref_pages,
+                unref_page=self.pool.unref_pages)
         # KV page-set store (serve/kv_objects.py): donation target +
         # adoption source. Backend selection gates on an ALREADY
         # attached client (never _ensure_client — constructing an
@@ -981,8 +639,9 @@ class LLMEngine:
         # page -> refs held by an IN-FLIGHT donation (device gather +
         # store put): the "in-flight-donated" category of the page-
         # accounting closure (free + live + cached + exporting-only
-        # == total), rolled back in a finally so a chaos raise at
-        # serve.kv.donate can't leak a reference.
+        # == total). Empty between ticks; a chaos raise at
+        # serve.kv.donate is exactly when the closure must still hold,
+        # so it is rolled back in a finally.
         self._kv_exporting: dict[int, int] = {}
         self._kv_donated: "OrderedDict[str, int]" = OrderedDict()
         self._kv_summary_max = 0
@@ -1002,8 +661,7 @@ class LLMEngine:
             self._kv_store = (kv_store if kv_store is not None
                               else _kvo.get_store(donor=self._kv_donor))
             self._kv_fingerprint = _kvo.engine_fingerprint(
-                cfg, page_size, prefill_chunk,
-                draft_cfg if spec_draft else None,
+                cfg, page_size, prefill_chunk, draft_cfg,
                 kv_dtype=self.kv_dtype)
             from ray_tpu.core.config import runtime_config as _rc
 
@@ -1017,7 +675,6 @@ class LLMEngine:
             # even the store resolve on repeat traffic).
             self._kv_summary_max = max(
                 1, int(_rc().serve_kv_summary_max))
-            self._kv_donated: "OrderedDict[str, int]" = OrderedDict()
         # slot -> pinned CacheEntry while the slot is live (released on
         # free/preempt), and the tick's pending COW (src, dst) pairs,
         # flushed in one fused device copy per tick (_apply_cow).
@@ -1032,11 +689,7 @@ class LLMEngine:
         # amortizing the host↔device round trip per token. Power-of-two
         # ladder bounds the dense engine's compile count (the paged
         # window is k dispatches of one program, whatever k).
-        if decode_block is None:
-            from ray_tpu.core.config import runtime_config
-
-            decode_block = runtime_config().llm_decode_block
-        self.decode_block = max(1, decode_block)
+        self.decode_block = max(1, o.decode_block)
         self._k_ladder = tuple(
             k for k in (64, 32, 16, 8, 4, 2) if k <= self.decode_block)
         self.slot_req: list[GenRequest | None] = [None] * n_slots
@@ -1055,28 +708,25 @@ class LLMEngine:
         # Width-bucketed dispatch observability: per-dispatch width ring
         # (p50/max for metrics()/load_snapshot()) and cumulative
         # per-width dispatch counts — the host-side mirror of the
-        # llm_prefill_dispatch_total{width} counter, committed by
-        # bench_serve so the ablation JSON proves interior chunks ran at
-        # bucketed width.
+        # llm_prefill_dispatch_total{width} counter.
         self._dispatch_width_ring: "collections.deque[int]" = (
             collections.deque(maxlen=4096))
         self._dispatch_width_counts: dict[int, int] = {}
         self._rng_key = jax.random.key(seed)
         # Per-token decode step times (window wall time / window size),
         # milliseconds — a bounded ring so metrics() can report p50/p95
-        # step latency for the measured window (bench_serve commits them).
+        # step latency for the measured window.
         self._step_ms: "collections.deque[float]" = collections.deque(
             maxlen=4096)
         # Engine-side TTFT ring (submit → first token, ms) and the
         # prefill-interference ring: per-token decode latency measured
         # window-END to window-END across ticks that also ran prefill, so
         # the admission stall between windows IS included — the number the
-        # token budget bounds (bench_serve commits both).
+        # token budget bounds.
         self._ttft_ms: "collections.deque[float]" = collections.deque(
             maxlen=4096)
         # Warm/cold TTFT split (prefix cache): warm = admission bound a
-        # cached prefix (cached_tokens > 0). The committed warm-prefix
-        # bench reads its headline off these.
+        # cached prefix (cached_tokens > 0).
         self._ttft_warm_ms: "collections.deque[float]" = collections.deque(
             maxlen=4096)
         self._ttft_cold_ms: "collections.deque[float]" = collections.deque(
@@ -1099,7 +749,7 @@ class LLMEngine:
         self._span_seq = {"decode_window": 0, "spec_verify": 0}
         self._annotate = jax.profiler.TraceAnnotation
         # High-water mark of requests still owed a first token, kept
-        # like _min_free_pages (engine thread writes, reset re-bases).
+        # like the pool's min_free (engine thread writes, reset re-bases).
         self._awaiting_max = 0
         self._shutdown = threading.Event()
         self._fatal: str | None = None
@@ -1122,9 +772,8 @@ class LLMEngine:
         self.stats = {"requests": 0, "tokens_generated": 0,
                       "ttft_sum": 0.0, "completed": 0,
                       # Engine-side split (device dispatch + sync wall
-                      # time, measured INSIDE the engine loop) so the
-                      # committed bench separates engine capability from
-                      # client-path RTT (VERDICT r4 weak #2).
+                      # time, measured INSIDE the engine loop): engine
+                      # capability apart from client-path RTT.
                       "prefill_time_s": 0.0, "prefill_tokens": 0,
                       "prefill_chunks": 0, "prefill_dispatches": 0,
                       "decode_time_s": 0.0, "decode_windows": 0,
@@ -1156,6 +805,44 @@ class LLMEngine:
                       # warm discovery must ride the routing push, not
                       # per-request GCS RPCs.
                       "kv_digest_lookups": 0}
+
+    def _bind_programs(self) -> None:
+        """`self._rt`: the jax / model-fn surface the hot loop touches,
+        resolved once (a tick must not re-execute import machinery).
+        Every jitted callable goes through compile_watch.wrap, so XLA
+        compiles are attributed to the owning program at /metrics
+        (jax_compiles_total{fn}) and recompile churn trips the
+        recompile-storm alarm instead of hiding in step-time noise.
+
+        At tp > 1 the paged programs are their shard_map twins
+        (models/paged_kv.py `*_tp`) with the mesh bound as a static
+        kwarg, under the SAME compile-watch names: every call site is
+        unchanged."""
+        import types
+
+        import jax
+        import jax.numpy as jnp
+
+        from ray_tpu import compile_watch as _cw
+        from ray_tpu.models import decode as _decode
+        from ray_tpu.models import paged_kv as _paged
+
+        _cw.install()
+        programs = {name: getattr(_decode, name) for name in (
+            "prefill", "prefill_batch", "decode_step", "decode_multi",
+            "sample_token")}
+        programs["prefill_batch_paged"] = _paged.prefill_batch_paged
+        for name in ("prefill_chunk_paged", "verify_chunk_paged",
+                     "decode_step_paged", "decode_multi_paged",
+                     "copy_pages", "gather_pages", "scatter_pages",
+                     "spec_draft_propose"):
+            programs[name] = (
+                getattr(_paged, name) if self.tp == 1 else
+                functools.partial(getattr(_paged, name + "_tp"),
+                                  mesh=self.mesh))
+        self._rt = types.SimpleNamespace(
+            jax=jax, jnp=jnp,
+            **{name: _cw.wrap(fn, name) for name, fn in programs.items()})
 
     # ------------------------------------------------------------- API
 
@@ -1201,8 +888,8 @@ class LLMEngine:
         generated = [int(t) for t in (generated_ids or [])]
         context = list(prompt_ids) + generated
         too_big = (len(context) > self._prompt_cap
-                   or (self.kv_mode == "paged"
-                       and self._pages_for(len(context)) > self.n_pages))
+                   or (self.pool is not None
+                       and self.pool.pages_for(len(context)) > self.n_pages))
         req = GenRequest(
             request_id=request_id or uuid.uuid4().hex[:12],
             prompt_ids=context,
@@ -1255,7 +942,7 @@ class LLMEngine:
                             f"{self.max_len - 1}") + ")")
             # A prompt the pool can never cover would requeue forever.
             raise ValueError(
-                f"prompt needs {self._pages_for(len(context))} KV pages "
+                f"prompt needs {self.pool.pages_for(len(context))} KV pages "
                 f"but the pool only has {self.n_pages}")
         # The fatal/draining check and the enqueue must be atomic with the
         # death handler's / drain export's one-shot pending drain, or a
@@ -1435,7 +1122,7 @@ class LLMEngine:
                     doomed.append(self.pending.get_nowait())
                 except queue.Empty:
                     break
-        if self.kv_mode == "paged":
+        if self.pool is not None:
             # The engine thread is stopped: return every evicted slot's
             # pages (decrement-only — prefix-cache entries keep theirs,
             # so a drained-but-not-killed engine still closes the page
@@ -1447,7 +1134,7 @@ class LLMEngine:
             for slot in range(self.n_slots):
                 req = slot_of.get(slot)
                 if (req is not None and self._kv_store is not None
-                        and int(self.slot_n_pages[slot])):
+                        and self.pool.slot_n_pages[slot]):
                     n_written = int(self.positions[slot])
                     if n_written <= 0:
                         n_written = int(chunk_pos.get(slot, 0))
@@ -1458,13 +1145,11 @@ class LLMEngine:
                     seq = (req.prompt_ids[:req.n_prompt]
                            + req.out_ids)[:n_written]
                     req.kv_handoff = self._donate_kv(
-                        seq, self.page_table[slot],
-                        memo=req.prefix_hashes)
+                        seq, self.pool.row(slot), memo=req.prefix_hashes)
                 entry = self._slot_entry.pop(slot, None)
                 if entry is not None:
                     self.prefix_cache.release(entry)
-                if int(self.slot_n_pages[slot]):
-                    self._free_slot_pages(slot)
+                self.pool.free_slot(slot)
                 # graftlint: disable=GUARDED-BY (single-threaded by protocol: _export_unfinished runs after stop() joined the engine thread — see its docstring — so nothing races these resets)
                 self.positions[slot] = 0
                 self.tokens[slot] = 0
@@ -1517,8 +1202,8 @@ class LLMEngine:
             self._decode_ewma_tok_s = None
             self._budget_util_ewma = None
             self._spec_accept_ewma = None
-            if self.kv_mode == "paged":
-                self._min_free_pages = len(self.free_pages)
+            if self.pool is not None:
+                self.pool.rebase_low_water()
             self._awaiting_max = self._awaiting_first_token()
         self._ticks.reset()
 
@@ -1633,8 +1318,8 @@ class LLMEngine:
                      n_slots=self.n_slots)
             if self.kv_mode == "paged":
                 m["kv_pages_total"] = self.n_pages
-                m["kv_pages_free"] = len(self.free_pages)
-                m["kv_pages_free_min"] = self._min_free_pages
+                m["kv_pages_free"] = self.pool.n_free
+                m["kv_pages_free_min"] = self.pool.min_free
                 m["kv_page_size"] = self.page_size
                 m["llm_attn_impl"] = self.attn_impl
                 # Quantized-serving observability (rides the PR 6 chain:
@@ -1651,12 +1336,7 @@ class LLMEngine:
                 int(a.nbytes) for a in self._rt.jax.tree.leaves(self.params))
             m["llm_tp"] = self.tp
             if self.tp > 1:
-                m["mesh_shape"] = {"tp": self.tp}
-                m["kv_heads_per_shard"] = self.cfg.n_heads // self.tp
-                m["pool_shard_bytes"] = self._pool_shard_bytes()
-                m["pool_shard_bytes_used"] = round(
-                    self._pool_shard_bytes()
-                    * (1.0 - len(self.free_pages) / self.n_pages))
+                m.update(self._tp_topology())
             if self.prefill_chunk:
                 m["prefill_chunk"] = self.prefill_chunk
                 m["prefill_token_budget"] = self.prefill_budget
@@ -1783,10 +1463,10 @@ class LLMEngine:
                     self._decode_ewma_tok_s, 3)
             if self.kv_mode == "paged":
                 snap["pool_pages_total"] = self.n_pages
-                snap["pool_pages_free"] = len(self.free_pages)
-                snap["pool_pages_free_min"] = self._min_free_pages
+                snap["pool_pages_free"] = self.pool.n_free
+                snap["pool_pages_free_min"] = self.pool.min_free
                 snap["pool_utilization"] = round(
-                    1.0 - len(self.free_pages) / self.n_pages, 4)
+                    1.0 - self.pool.n_free / self.n_pages, 4)
                 # Quantized-serving load surface (PR 6 chain: replica
                 # stats → serve.status() → /api/serve/load → CLI).
                 snap["llm_weight_dtype"] = self.weight_dtype
@@ -1795,18 +1475,11 @@ class LLMEngine:
                     int(math.prod(a.shape) * a.dtype.itemsize)
                     for a in self.cache.values())
             if self.tp > 1:
-                # Sharding topology, riding the PR 6 chain as-is:
-                # Replica.stats() → controller probe → serve.status() /
-                # /api/serve/load / `ray_tpu status --serve`. Page ids
-                # (and thus occupancy FRACTION) are shard-invariant; the
-                # per-shard number is the bytes each device pins.
+                # Riding the PR 6 chain as-is: Replica.stats() →
+                # controller probe → serve.status() / /api/serve/load /
+                # `ray_tpu status --serve`.
                 snap["llm_tp"] = self.tp
-                snap["mesh_shape"] = {"tp": self.tp}
-                snap["kv_heads_per_shard"] = self.cfg.n_heads // self.tp
-                snap["pool_shard_bytes"] = self._pool_shard_bytes()
-                snap["pool_shard_bytes_used"] = round(
-                    self._pool_shard_bytes()
-                    * (1.0 - len(self.free_pages) / self.n_pages))
+                snap.update(self._tp_topology())
             if self.prefill_chunk:
                 snap["prefill_chunk"] = self.prefill_chunk
                 snap["prefill_token_budget"] = self.prefill_budget
@@ -1890,10 +1563,6 @@ class LLMEngine:
 
     # --------------------------------------------------- page accounting
 
-    def _pages_for(self, last_pos: int) -> int:
-        """Pages needed to cover writes up to position `last_pos`."""
-        return last_pos // self.page_size + 1
-
     def _pool_shard_bytes(self) -> int:
         """Per-device bytes of the KV pool (K + V planes plus, when
         quantized, the per-page scale planes; null page included). Page
@@ -1908,27 +1577,15 @@ class LLMEngine:
             total += nbytes if key.endswith("_scale") else nbytes // self.tp
         return total
 
-    def _alloc_page(self) -> int | None:
-        """One exclusive page off the free list (refcount 1), or None
-        when the pool is dry (callers reclaim/preempt)."""
-        if not self.free_pages:
-            return None
-        pg = self.free_pages.pop()
-        self.page_refs[pg] = 1
-        if len(self.free_pages) < self._min_free_pages:
-            self._min_free_pages = len(self.free_pages)
-        return pg
-
-    def _ref_page(self, pg: int) -> None:
-        self.page_refs[pg] += 1
-
-    def _unref_page(self, pg: int) -> None:
-        """Drop one reference; the page returns to the pool at zero.
-        Shared (prefix-cache) pages simply outlive any one holder."""
-        self.page_refs[pg] -= 1
-        if self.page_refs[pg] <= 0:
-            self.page_refs[pg] = 0
-            self.free_pages.append(int(pg))
+    def _tp_topology(self) -> dict:
+        """Page ids (and thus the occupancy FRACTION) are shard-invariant;
+        the per-shard number is the bytes each device pins."""
+        shard = self._pool_shard_bytes()
+        return {"mesh_shape": {"tp": self.tp},
+                "kv_heads_per_shard": self.cfg.n_heads // self.tp,
+                "pool_shard_bytes": shard,
+                "pool_shard_bytes_used": round(
+                    shard * (1.0 - self.pool.n_free / self.n_pages))}
 
     def _cache_reclaim(self, need: int) -> None:
         """Pressure valve: evict zero-active prefix-cache entries (LRU)
@@ -1937,7 +1594,7 @@ class LLMEngine:
         window or preempts a live decode."""
         if self.prefix_cache is None:
             return
-        while len(self.free_pages) < need:
+        while self.pool.n_free < need:
             if self.prefix_cache.evict_one() is None:
                 break
         self._sync_cache_evictions()
@@ -1953,27 +1610,6 @@ class LLMEngine:
             _PREFIX_COUNTERS["evictions"].inc(
                 float(delta),
                 tags={"replica": self._impl_tags()["replica"]})
-
-    def _grow_slot(self, slot: int, last_pos: int) -> bool:
-        """Allocate pages so `slot` covers `last_pos`. All-or-nothing."""
-        need = self._pages_for(last_pos) - int(self.slot_n_pages[slot])
-        if need <= 0:
-            return True
-        if need > len(self.free_pages):
-            self._cache_reclaim(need)
-        if need > len(self.free_pages):
-            return False
-        for _ in range(need):
-            pg = self._alloc_page()
-            self.page_table[slot, int(self.slot_n_pages[slot])] = pg
-            self.slot_n_pages[slot] += 1
-        return True
-
-    def _free_slot_pages(self, slot: int) -> None:
-        for i in range(int(self.slot_n_pages[slot])):
-            self._unref_page(int(self.page_table[slot, i]))
-        self.page_table[slot, :] = 0
-        self.slot_n_pages[slot] = 0
 
     # ------------------------------------------- KV page-set transfer
 
@@ -2049,9 +1685,8 @@ class LLMEngine:
             # summary (and the memo spares repeat traffic the resolve).
             self._kv_note_donation(keys[0][:16], n_full)
             return desc
-        for p in pages:
-            self._ref_page(p)
-            self._kv_exporting[p] = self._kv_exporting.get(p, 0) + 1
+        self.pool.ref_pages(pages)
+        self._kv_exporting = dict.fromkeys(pages, 1)
         tags = {"replica": self._impl_tags()["replica"]}
         try:
             rt = self._rt
@@ -2102,13 +1737,8 @@ class LLMEngine:
             # donor keeps serving; already-published depths stay usable.
             logger.debug("kv donation aborted mid-chain: %s", e)
         finally:
-            for p in pages:
-                n = self._kv_exporting.get(p, 0) - 1
-                if n <= 0:
-                    self._kv_exporting.pop(p, None)
-                else:
-                    self._kv_exporting[p] = n
-                self._unref_page(p)
+            self._kv_exporting = {}
+            self.pool.unref_pages(pages)
         return desc
 
     def _kv_adopt_plan(self, req: GenRequest,
@@ -2199,32 +1829,19 @@ class LLMEngine:
                 logger.debug("kv fetch of depth %s failed: %s",
                              meta.get("depth"), e)
                 break
-        if not payloads:
-            self.stats["kv_adopt_failures"] += 1
-            _KV_COUNTERS["adopt_failures"].inc(tags=tags)
-            return 0
         n_adopt = len(payloads) * self.prefill_chunk
-        n_pages = self._pages_for(n_adopt - 1)
-        if n_pages > len(self.free_pages):
-            self._cache_reclaim(n_pages)
-        alloc: list[int] = []
-        for _ in range(n_pages):
-            pg = self._alloc_page()
-            if pg is None:
-                break
-            alloc.append(pg)
-        if len(alloc) < n_pages:
-            # Pool dry mid-bind (reservation shortfall): roll back — a
-            # partial page run can't serve the adopted prefix.
-            for pg in alloc:
-                self._unref_page(pg)
+        n_pages = self.pool.pages_for(n_adopt - 1)
+        if not payloads or not self.pool.grow(slot, n_adopt - 1,
+                                              self._cache_reclaim):
+            # Nothing arrived, or the pool is dry at bind (reservation
+            # shortfall): a partial page run can't serve the prefix.
             self.stats["kv_adopt_failures"] += 1
             _KV_COUNTERS["adopt_failures"].inc(tags=tags)
             return 0
         rt = self._rt
         width = _pow2_width(n_pages)
         ids = np.zeros(width, np.int32)
-        ids[:n_pages] = alloc
+        ids[:n_pages] = self.pool.row(slot, n_pages)
 
         def _stitch(pool, prefix=""):
             # Dict-generic payload stitch: every pool key (K/V planes
@@ -2247,9 +1864,6 @@ class LLMEngine:
             self.draft_cache = rt.scatter_pages(
                 self.draft_cache, rt.jnp.asarray(ids),
                 _stitch(self.draft_cache, prefix="d"))
-        for i, pg in enumerate(alloc):
-            self.page_table[slot, i] = pg
-        self.slot_n_pages[slot] = n_pages
         req.cached_tokens = n_adopt
         self.stats["kv_adoptions"] += 1
         self.stats["kv_adopted_tokens"] += n_adopt
@@ -2268,8 +1882,7 @@ class LLMEngine:
         migration contract as drain export, so greedy streams stay
         byte-identical across the handoff."""
         req.kv_handoff = self._donate_kv(
-            req.prompt_ids, self.page_table[slot],
-            memo=req.prefix_hashes)
+            req.prompt_ids, self.pool.row(slot), memo=req.prefix_hashes)
         req.migrated = True
         if req.stream is not None:
             req.stream.put(None)
@@ -2281,39 +1894,12 @@ class LLMEngine:
         exactly one of free / referenced, and every reference is owned
         by a slot table or a cache entry. Engine-thread-safe only when
         the engine is stopped or driven synchronously."""
-        live: dict[int, int] = {}
-        for slot in range(self.n_slots):
-            for i in range(int(self.slot_n_pages[slot])):
-                pg = int(self.page_table[slot, i])
-                live[pg] = live.get(pg, 0) + 1
-        cached = (self.prefix_cache.cached_pages()
-                  if self.prefix_cache is not None else set())
-        # In-flight-donated: pages reffed by a KV page-set donation in
-        # progress (device gather + store put). Between ticks this is
-        # empty — a chaos kill/raise mid-donation is exactly when the
-        # closure (free + live + cached + in-flight-donated == total)
-        # must still hold.
-        exporting = dict(self._kv_exporting)
-        allocated = set(live) | cached | set(exporting)
-        refs_ok = all(
-            int(self.page_refs[pg]) == live.get(pg, 0)
-            + (self.prefix_cache.page_refs_held(pg)
-               if self.prefix_cache is not None else 0)
-            + exporting.get(pg, 0)
-            for pg in allocated)
-        free = len(self.free_pages)
-        return {
-            "total": self.n_pages,
-            "free": free,
-            "live": len(live),
-            "cached": len(cached),
-            "cached_only": len(cached - set(live)),
-            "exporting": len(exporting),
-            "shared": sum(1 for pg in live if live[pg] > 1 or pg in cached),
-            "closure": free + len(allocated) == self.n_pages,
-            "refs_consistent": refs_ok and not (
-                set(self.free_pages) & allocated),
-        }
+        cache = self.prefix_cache
+        return self.pool.accounting(
+            cached=cache.cached_pages() if cache is not None else set(),
+            cached_refs=(cache.page_refs_held if cache is not None
+                         else lambda pg: 0),
+            exporting=self._kv_exporting)
 
     # ------------------------------------------------------------- engine
 
@@ -2436,7 +2022,7 @@ class LLMEngine:
                 except queue.Empty:
                     break
             hit = None
-            if self.kv_mode == "paged":
+            if self.pool is not None:
                 # Admission back-pressure: one-shot needs the whole prompt
                 # (plus first decode write) covered; chunked only the
                 # FIRST CHUNK — the rest is budgeted lazy growth. A warm
@@ -2472,17 +2058,17 @@ class LLMEngine:
                         plans[req.request_id] = plan
                         end = min(plan["n_tokens"] + self.prefill_chunk,
                                   len(req.prompt_ids))
-                        need = self._pages_for(end - 1)
+                        need = self.pool.pages_for(end - 1)
                     else:
                         end = min(n_cached + self.prefill_chunk,
                                   len(req.prompt_ids))
-                        need = (self._pages_for(end - 1)
+                        need = (self.pool.pages_for(end - 1)
                                 - n_cached // self.page_size)
                 else:
-                    need = self._pages_for(len(req.prompt_ids))
-                if planned_pages + need > len(self.free_pages):
+                    need = self.pool.pages_for(len(req.prompt_ids))
+                if planned_pages + need > self.pool.n_free:
                     self._cache_reclaim(planned_pages + need)
-                if planned_pages + need > len(self.free_pages):
+                if planned_pages + need > self.pool.n_free:
                     plans.pop(req.request_id, None)
                     if hit is not None:
                         # Not admitted this round: unpin (the entry is
@@ -2578,21 +2164,15 @@ class LLMEngine:
         ps = self.page_size
         n_cached = entry.n_tokens
         p_full = n_cached // ps
-        for i in range(p_full):
-            pg = entry.pages[i]
-            self._ref_page(pg)
-            self.page_table[slot, i] = pg
-        self.slot_n_pages[slot] = p_full
+        self.pool.share(slot, entry.pages[:p_full])
         if n_cached % ps:
-            dst = self._alloc_page()
-            if dst is None:
+            if not self.pool.grow(slot, n_cached - 1):
                 # Pool dry for the divergence copy: fall back to the
                 # full-page part (re-prefill the partial tail's tokens).
                 n_cached = p_full * ps
             else:
-                self.page_table[slot, p_full] = dst
-                self.slot_n_pages[slot] = p_full + 1
-                self._cow_pairs.append((int(entry.pages[p_full]), int(dst)))
+                dst = int(self.pool.row(slot)[p_full])
+                self._cow_pairs.append((int(entry.pages[p_full]), dst))
                 self.stats["cow_copies"] += 1
                 _PREFIX_COUNTERS["cow_copies"].inc(tags=tags)
         if n_cached <= 0:
@@ -2652,19 +2232,17 @@ class LLMEngine:
                     req.first_chunk_at = t0
         try:
             with self._phase("prefill.dispatch"):
-                if self.kv_mode == "paged":
+                if self.pool is not None:
                     # _admit reserved pool headroom; grow each slot to
                     # cover prompt + first decode write (single-threaded
                     # engine, so the reservation cannot race).
-                    pages = np.zeros((n, self._pages_for(bucket - 1)),
+                    pages = np.zeros((n, self.pool.pages_for(bucket - 1)),
                                      np.int32)
                     for i, slot in enumerate(slots):
-                        grown = self._grow_slot(slot, int(lengths[i]))
-                        if not grown:   # _admit reserved headroom
+                        if not self.pool.grow(slot, int(lengths[i]),
+                                              self._cache_reclaim):
                             raise RuntimeError("page reservation desync")
-                        got = int(self.slot_n_pages[slot])
-                        take = min(got, pages.shape[1])
-                        pages[i, :take] = self.page_table[slot, :take]
+                        pages[i] = self.pool.row(slot, pages.shape[1])
                     last_logits, self.cache = rt.prefill_batch_paged(
                         self.cfg, self.params, rt.jnp.asarray(padded),
                         self.cache, rt.jnp.asarray(pages),
@@ -2685,11 +2263,11 @@ class LLMEngine:
                 if last_logits.ndim == 1:       # rt.prefill: one row
                     last_logits = last_logits[None, :]
         except Exception as e:
-            if self.kv_mode == "paged":
+            if self.pool is not None:
                 # Pages grown onto these (still request-less) slots must
                 # return to the pool, or repeated failures pin it dry.
                 for slot in slots:
-                    self._free_slot_pages(slot)
+                    self.pool.free_slot(slot)
             for req in group:
                 req.error = f"prefill failed: {e!r}"
                 req.done.set()
@@ -2764,7 +2342,8 @@ class LLMEngine:
                         if spent + planned + n > budget:
                             stop = True
                             break
-                        if not self._grow_slot(slot, done + n - 1):
+                        if not self.pool.grow(slot, done + n - 1,
+                                              self._cache_reclaim):
                             # Pool dry: stop at the blocked chunk (FCFS —
                             # later work must not consume pages the head
                             # could use).
@@ -2784,7 +2363,7 @@ class LLMEngine:
                 if (not decode_active and spent == 0
                         and len(self._prefilling) > 1):
                     reclaim = [s for s in self._prefilling
-                               if int(self.slot_n_pages[s])]
+                               if self.pool.slot_n_pages[s]]
                     if reclaim:
                         # Youngest PAGE-HOLDING slot, as in
                         # _fit_window_pages: a slot admitted but not yet
@@ -2802,7 +2381,7 @@ class LLMEngine:
         needs to attend over: the pages covering its slot's written
         tokens PLUS this chunk, bucketed by the shared `_pow2_width`
         rule (the prefill twin of _decode_table_view's width)."""
-        return min(_pow2_width(self._pages_for(done + n - 1)),
+        return min(_pow2_width(self.pool.pages_for(done + n - 1)),
                    self.max_pages_per_slot)
 
     def _dispatch_chunks(self, batch) -> None:
@@ -2868,7 +2447,7 @@ class LLMEngine:
                 toks[i, :n] = req.prompt_ids[done:done + n]
                 offsets[i] = done
                 valid[i] = n
-                tables[i] = self.page_table[slot, :width]
+                tables[i] = self.pool.row(slot, width)
                 any_final |= done + n >= len(req.prompt_ids)
                 if req.first_chunk_at is None:
                     req.first_chunk_at = t0
@@ -2974,7 +2553,7 @@ class LLMEngine:
             n_written = int(self.positions[slot])
             seq = (req.prompt_ids[:req.n_prompt]
                    + req.out_ids)[:n_written]
-            self.prefix_cache.donate(seq, self.page_table[slot],
+            self.prefix_cache.donate(seq, self.pool.row(slot),
                                      memo=req.prefix_hashes)
             self._sync_cache_evictions()
         if (self.kv_transfer and self._kv_store is not None
@@ -2998,7 +2577,7 @@ class LLMEngine:
             if (head is not None
                     and self._kv_donated.get(head, 0)
                     < len(seq) // self.prefill_chunk):
-                self._donate_kv(seq, self.page_table[slot],
+                self._donate_kv(seq, self.pool.row(slot),
                                 memo=req.prefix_hashes)
         self.tokens[slot] = 0
         self.positions[slot] = 0
@@ -3009,8 +2588,8 @@ class LLMEngine:
         entry = self._slot_entry.pop(slot, None)
         if entry is not None:
             self.prefix_cache.release(entry)
-        if self.kv_mode == "paged":
-            self._free_slot_pages(slot)
+        if self.pool is not None:
+            self.pool.free_slot(slot)
 
     def _preempt(self, slot: int) -> None:
         """Evict a slot by RECOMPUTE (vLLM-style): its pages return to the
@@ -3032,7 +2611,7 @@ class LLMEngine:
         self._release(slot)
         self.stats["preemptions"] += 1
         if (len(req.prompt_ids) > self._prompt_cap
-                or self._pages_for(len(req.prompt_ids)) > self.n_pages):
+                or self.pool.pages_for(len(req.prompt_ids)) > self.n_pages):
             # Regrown context no longer fits any prefill bucket — finish
             # with what we have rather than wedging the queue, flagged so
             # clients can tell this from natural completion.
@@ -3057,21 +2636,12 @@ class LLMEngine:
         → (surviving active slots, window size; 0 = nothing to run)."""
         while active:
             for kk in [k] + [x for x in self._k_ladder if x < k] + [1]:
-                extra = sum(
-                    max(0, self._pages_for(int(self.positions[s]) + kk - 1)
-                        - int(self.slot_n_pages[s]))
-                    for s in active)
-                if extra > len(self.free_pages):
-                    # Cached pages are speculative value; a live decode
-                    # window is not. Zero-active prefix-cache entries
-                    # are evicted before the window shrinks — and long
-                    # before anything is preempted.
-                    self._cache_reclaim(extra)
-                if extra <= len(self.free_pages):
-                    for s in active:
-                        if not self._grow_slot(
-                                s, int(self.positions[s]) + kk - 1):
-                            raise RuntimeError("page fit desync")
+                # Cached pages are speculative value; a live decode
+                # window is not. Zero-active prefix-cache entries are
+                # evicted (the reclaim hook) before the window shrinks —
+                # and long before anything is preempted.
+                if self.pool.grow(active, self.positions[active] + kk - 1,
+                                  self._cache_reclaim):
                     return active, kk
             active = self._shed_for_pages(active)
         return [], 0
@@ -3088,7 +2658,7 @@ class LLMEngine:
         simply too big — finish it; else preempt the decode victim with
         the most remaining budget. → surviving active slots."""
         reclaim = [s for s in self._prefilling
-                   if int(self.slot_n_pages[s])]
+                   if self.pool.slot_n_pages[s]]
         if reclaim:
             self._preempt(reclaim[-1])
             return active
@@ -3141,13 +2711,9 @@ class LLMEngine:
         the pages their chunks already filled (and a long prompt
         mid-prefill never widens — and re-compiles — every window while
         it streams in)."""
-        w = max(1, int(self.slot_n_pages[active].max()))
-        width = min(_pow2_width(w), self.max_pages_per_slot)
-        view = self.page_table[:, :width]
-        if self._prefilling:
-            view = view.copy()
-            view[self._prefilling] = 0
-        return view
+        width = min(_pow2_width(int(self.pool.slot_n_pages[active].max())),
+                    self.max_pages_per_slot)
+        return self.pool.table_view(width, blank=self._prefilling)
 
     def _fit_spec_pages(self, active: list[int], k_map: dict) -> list[int]:
         """Paged fit for the speculative window: grow every active slot
@@ -3160,52 +2726,16 @@ class LLMEngine:
         reclaimed, then a decode victim preempted (the shared
         _shed_for_pages tail)."""
         while active:
-            for shrink in (None, 1, 0):
-                ext = {s: (k_map[s] if shrink is None
-                           else min(k_map[s], shrink)) for s in active}
-                extra = sum(
-                    max(0, self._pages_for(int(self.positions[s]) + ext[s])
-                        - int(self.slot_n_pages[s]))
-                    for s in active)
-                if extra > len(self.free_pages):
-                    self._cache_reclaim(extra)
-                if extra <= len(self.free_pages):
-                    for s in active:
-                        k_map[s] = ext[s]
-                        if not self._grow_slot(
-                                s, int(self.positions[s]) + ext[s]):
-                            raise RuntimeError("page fit desync")
+            for cap in (self.spec_k, 1, 0):
+                ext = {s: min(k_map[s], cap) for s in active}
+                if self.pool.grow(
+                        active,
+                        self.positions[active] + [ext[s] for s in active],
+                        self._cache_reclaim):
+                    k_map.update(ext)
                     return active
             active = self._shed_for_pages(active)
         return []
-
-    def _rollback_spec_pages(self, slots: list[int]) -> None:
-        """Batched rollback of rejected proposals' pages: ONE masked
-        vectorized cursor/table update covering every surviving slot
-        (the host-side twin of copy_pages' fused pow-2 pair batching)
-        instead of per-slot python writes — rollback runs on the shared
-        path every tick, so per-slot loops would tax accepted tokens
-        too. Pages past a slot's rolled-back cursor were grown
-        exclusively for this window (shared prefix-cache pages always
-        sit below the cursor), so dropping one reference frees them and
-        the pool never leaks partially-verified KV."""
-        if not slots:
-            return
-        rows = np.asarray(slots, np.int64)
-        keep = (self.positions[rows] - 1) // self.page_size + 1
-        have = self.slot_n_pages[rows]
-        cols = np.arange(self.max_pages_per_slot)[None, :]
-        drop = (cols >= keep[:, None]) & (cols < have[:, None])
-        if drop.any():
-            tbl = self.page_table[rows]
-            dropped = tbl[drop]
-            self.page_refs[dropped] -= 1
-            freed = dropped[self.page_refs[dropped] <= 0]
-            self.page_refs[freed] = 0
-            self.free_pages.extend(int(p) for p in freed)
-            tbl[drop] = 0
-            self.page_table[rows] = tbl
-            self.slot_n_pages[rows] = np.minimum(have, keep)
 
     def _spec_decode_window(self, active: list[int],
                             tick_prefill: bool) -> int:
@@ -3353,7 +2883,11 @@ class LLMEngine:
             else:
                 self.tokens[slot] = emitted[e - 1]
                 survivors.append(slot)
-        self._rollback_spec_pages(survivors)
+        if survivors:
+            # Rejected proposals' pages: past a rolled-back cursor they
+            # were grown for this window alone (shared prefix-cache pages
+            # sit below it), so no partially-verified KV stays held.
+            self.pool.truncate(survivors, self.positions[survivors])
         end = time.perf_counter()
         per_slot = emitted_total / len(active)
         # Cap = what this tick could have emitted: the FITTED per-slot
@@ -3543,7 +3077,7 @@ class LLMEngine:
             while not self._shutdown.is_set():
                 # step() IS the host-side scheduler tick: it syncs once
                 # per multi-token decode window by design, amortized over
-                # llm_decode_block tokens — see BENCH_SERVE.md.
+                # llm_decode_block tokens.
                 # graftlint: disable=HOST-SYNC-IN-HOT-LOOP (designed once-per-window sync point)
                 n = self.step()
                 if n == 0 and self.pending.empty() and not self._deferred:
@@ -3712,7 +3246,7 @@ class LLMDeployment:
     # long-polls for tokens past the cursor. Tokens come straight from the
     # engine's per-request out_ids, so TTFT is visible to clients the
     # moment prefill lands (ref: the reference proxy's ASGI streaming,
-    # http_proxy.py:217 — VERDICT r2 missing #2).
+    # http_proxy.py:217).
 
     def submit_stream(self, request: dict) -> str:
         if not hasattr(self, "_streams"):

@@ -1,8 +1,7 @@
 """Process-level JAX placement: which backend, and where compiles are cached.
 
 Two helpers that must run BEFORE the first JAX backend touch, shared by
-tests/conftest.py, __graft_entry__.py, bench.py, bench_serve.py and
-chip_smoke.py, plus the on-disk compile-cache hardening every process
+tests/conftest.py, __graft_entry__.py and chip_smoke.py, plus the on-disk compile-cache hardening every process
 that reads or writes cache entries installs.
 """
 
@@ -39,9 +38,9 @@ def place_compile_cache() -> str:
 def force_cpu_devices(n_devices: int = 8) -> None:
     """Pin jax to the CPU platform with >= n_devices virtual devices.
 
-    The CPU rehearsal switch: tests, ``__graft_entry__.py`` and
-    ``BENCH_SMOKE`` runs use it so N XLA host devices stand in for N
-    chips (meshes, shardings, collectives) without an accelerator.
+    The CPU rehearsal switch: tests and ``__graft_entry__.py`` use it so
+    N XLA host devices stand in for N chips (meshes, shardings,
+    collectives) without an accelerator.
     Chip paths never call it. Must run before the first backend touch
     (jax import is fine). Idempotent; raises if the backend already
     exists on another platform (nothing can be done then).

@@ -6,8 +6,7 @@ here, N XLA host devices on one process stand in for N TPU chips so every
 sharding/collective path is exercised without a pod.
 
 Platform forcing lives in ray_tpu.utils.platform (shared with
-__graft_entry__.py and bench.py's BENCH_SMOKE rehearsal) — it must run
-before any backend is initialized.
+__graft_entry__.py) — it must run before any backend is initialized.
 """
 
 import os

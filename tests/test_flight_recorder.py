@@ -182,8 +182,8 @@ class TestLoadSnapshot:
             assert snap["slot_utilization"] == round(
                 snap["active_slots"] / engine.n_slots, 4)
             # Page accounting closes: free + held == pool.
-            held = int(engine.slot_n_pages.sum())
-            assert snap["pool_pages_free"] == len(engine.free_pages)
+            held = int(engine.pool.slot_n_pages.sum())
+            assert snap["pool_pages_free"] == engine.pool.n_free
             assert snap["pool_pages_free"] + held == snap["pool_pages_total"]
             assert snap["pool_pages_free_min"] <= snap["pool_pages_free"]
             assert snap["prefill_chunk"] == 8

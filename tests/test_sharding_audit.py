@@ -80,10 +80,10 @@ class TestSixBTier:
         assert rep.per_device["opt_state"] == 2 * rep.per_device["params"]
 
     def test_scale_curve_tiers_single_chip(self):
-        """Scale-curve tiers (BENCH_SCALE.md): 350M trains on one v5e with
-        full adamw; 1.3B does NOT (5.3 GiB fp32 params → 21 GiB with adam
-        moments + grads) but DOES with factored adafactor state — which is
-        what bench.py runs for that tier."""
+        """Scale-curve tiers: 350M trains on one v5e with full adamw; 1.3B
+        does NOT (5.3 GiB fp32 params → 21 GiB with adam moments + grads)
+        but DOES with factored adafactor state — which is what
+        `opt-1.3b.train` runs (benchmarks/configs/opt-1.3b.json)."""
         cfg350 = gpt.GPTConfig.by_name(
             "gpt2_350m", max_seq=1024, loss_chunk=256)
         one_chip = {"dp": 1, "fsdp": 1, "sp": 1, "tp": 1}

@@ -46,10 +46,13 @@ class ResourceProtocol:
 
 
 PROTOCOLS: tuple[ResourceProtocol, ...] = (
-    # Paged-KV refcounts (serve/llm.py): a ref bumped for a donation or a
-    # spec-verify window must drop on every path out.
-    ResourceProtocol("page-ref", ("_ref_page", "_alloc_page"),
-                     ("_unref_page", "_free_slot_pages", "_free_page")),
+    # Paged-KV refcounts (serve/page_pool.py's methods; the prefix cache
+    # holds them as _ref_page/_unref_page): a ref bumped for a donation
+    # must drop on every path out.
+    ResourceProtocol("page-ref",
+                     ("ref_pages", "take_page", "_ref_page", "_alloc_page"),
+                     ("unref_pages", "free_slot", "_unref_page",
+                      "_free_slot_pages", "_free_page")),
     # Prefix-cache pins and raw lock/semaphore handles share the
     # acquire()/release() spelling — and the same pairing obligation.
     ResourceProtocol("acquire/release", ("acquire",), ("release",)),
