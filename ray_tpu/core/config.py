@@ -346,14 +346,18 @@ class Config:
     # turn it on (benches may also call engine.warmup_compile()
     # directly). Env: RAY_TPU_LLM_WARMUP_COMPILE=1.
     llm_warmup_compile: bool = False
-    # Max prefill tokens one engine tick may run while decode is active
-    # (the decode-stall bound: a tick's prefill work never exceeds this).
-    # 0 = pure-decode ticks (prefill only advances while nothing is
-    # decoding); otherwise must be >= llm_prefill_chunk. Ignored unless
-    # llm_prefill_chunk > 0. It also sets the chunk program's height:
-    # every chunk dispatch is [chunk_rows, llm_prefill_chunk] with
-    # chunk_rows = min(n_slots, ceil(max(budget, chunk) / chunk)), the
-    # full chunks one tick can hold (2 for a chunk of 128).
+    # Max prefill tokens a DECODE STEP may be made to wait for (the
+    # decode-stall bound, per step as Sarathi/Orca state it). A tick
+    # runs one decode window of up to llm_decode_block steps and may
+    # place this many prompt tokens for each step of it (2,048 at the
+    # defaults); a window of one step, and a speculative tick, carry
+    # one. 0 = pure-decode ticks (prefill only advances while nothing
+    # is decoding); otherwise must be >= llm_prefill_chunk. Ignored
+    # unless llm_prefill_chunk > 0. It also sets the chunk program's
+    # height: every chunk dispatch is [chunk_rows, llm_prefill_chunk]
+    # with chunk_rows = min(n_slots, ceil(max(budget, chunk) / chunk)),
+    # the full chunks ONE budget holds (2 for a chunk of 128); a tick
+    # runs that program as often as its allowance has rows.
     llm_prefill_token_budget: int = 256
     # Paged-KV prefix cache (serve/prefix_cache.py): completed requests
     # donate their chunk-aligned prefix pages (refcounted, read-only)

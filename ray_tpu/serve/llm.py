@@ -15,15 +15,19 @@ Design notes:
   not per length — but every admission stalls the decode pool for a
   whole prompt of prefill compute. Chunked (`llm_prefill_chunk` > 0,
   paged KV only): prompts enter their slot's page table in fixed-size
-  chunks co-scheduled against decode under a per-tick token budget
-  (`llm_prefill_token_budget`) — Sarathi/Orca-style stall-free batching.
-  The decode stall per tick is bounded by one budget of chunk compute,
-  admission back-pressure needs one CHUNK of pool headroom instead of
-  the whole prompt, and the prefill compile grid collapses from
-  buckets × admission-ladder to two programs per page-table width
-  (models/paged_kv.py `prefill_chunk_paged`), each
-  [chunk_rows, chunk]: as many rows as full chunks fit the budget, not
-  as the engine has slots.
+  chunks co-scheduled against decode under a token budget for each
+  decode STEP (`llm_prefill_token_budget`) — Sarathi/Orca-style
+  stall-free batching. A tick runs one decode window of k steps and
+  may place k budgets of prompt tokens before it, so the stall a
+  decode step sees is bounded by one budget of chunk compute whatever
+  the window's length; while slots decode, prefill also stops short of
+  the pages they are about to need (a full pool stalls prompts, it
+  does not preempt them). Admission back-pressure needs one CHUNK of
+  pool headroom instead of the whole prompt, and the prefill compile
+  grid collapses from buckets × admission-ladder to two programs per
+  page-table width (models/paged_kv.py `prefill_chunk_paged`), each
+  [chunk_rows, chunk]: as many rows as full chunks fit ONE budget, not
+  as the engine has slots nor as the tick's allowance has rows.
 - The engine thread owns the cache; submit()/result flow through plain
   thread-safe queues, so the Serve replica's asyncio loop never blocks on
   device work.
@@ -111,7 +115,7 @@ _LOAD_GAUGES = {
         ("pool_pages_free", "KV page-pool free pages"),
         ("pool_pages_total", "KV page-pool size"),
         ("prefill_budget_util",
-         "EWMA of per-tick prefill-budget utilization"),
+         "EWMA of the share of a tick's prefill allowance it placed"),
         ("ttft_ewma_ms", "EWMA of time-to-first-token (ms)"),
         ("decode_tok_s_ewma", "EWMA of fused-window decode rate (tok/s)"),
         ("prefix_cache_pages",
@@ -519,13 +523,14 @@ class LLMEngine:
         # hold is admissible (buckets only cap the one-shot path).
         if prefill_chunk:
             self._prompt_cap = max_len - 1
-            # Height of the chunk program: the full chunks one tick's
-            # budget can hold (an idle tick's floor of one chunk
-            # included), never more rows than slots. A constant of the
-            # engine, so the set of programs stays one per (table width,
-            # head); a tick with more rows than this (many short
-            # prompts) goes round the packing loop in _run_prefill_chunks
-            # again instead of widening the program.
+            # Height of the chunk program: the full chunks ONE budget
+            # can hold (an idle tick's floor of one chunk included),
+            # never more rows than slots. A constant of the engine, so
+            # the set of programs stays one per (table width, head); a
+            # tick with more rows than this (a window of several steps
+            # carries as many budgets, and short prompts make short
+            # rows) runs the program again, _dispatch_chunks, instead
+            # of widening it.
             full_chunks = -(-max(o.prefill_token_budget, prefill_chunk)
                             // prefill_chunk)
             self.chunk_rows = min(n_slots, full_chunks)
@@ -776,6 +781,11 @@ class LLMEngine:
                       # capability apart from client-path RTT.
                       "prefill_time_s": 0.0, "prefill_tokens": 0,
                       "prefill_chunks": 0, "prefill_dispatches": 0,
+                      # Prompt tokens the ticks that had prefill work
+                      # waiting were allowed to place (the budget times
+                      # the window's steps): prefill_tokens over it is
+                      # `prefill_allowance_used`.
+                      "prefill_allowance": 0,
                       "decode_time_s": 0.0, "decode_windows": 0,
                       "slot_step_sum": 0, "slot_cap_sum": 0,
                       "preemptions": 0,
@@ -1346,6 +1356,10 @@ class LLMEngine:
                 m["prefill_row_fill"] = m["prefill_tokens"] / max(
                     1, m["prefill_dispatches"] * self.chunk_rows
                     * self.prefill_chunk)
+                # Tokens placed over tokens allowed: under 1.0 the pool
+                # (or the work), not the budget, bounds prefill.
+                m["prefill_allowance_used"] = m["prefill_tokens"] / max(
+                    1, m["prefill_allowance"])
                 m["prefilling_slots"] = len(self._prefilling)
                 m["prefill_width_bucketing"] = self.prefill_width_bucketing
                 if self._dispatch_width_ring:
@@ -2289,78 +2303,94 @@ class LLMEngine:
 
     # ----------------------------------------------- chunked prefill
 
-    def _run_prefill_chunks(self, decode_active: bool) -> int:
-        """Spend the per-tick prefill token budget: advance mid-prefill
+    def _run_prefill_chunks(self, decoding: list[int]) -> int:
+        """Spend the tick's prefill allowance: advance mid-prefill
         slots chunk-by-chunk, FCFS (the head slot finishes before the
         next starts — earliest-admitted reaches its first token first).
-        With decode in flight the budget is strict — a tick never runs
-        more than `prefill_token_budget` prefill tokens, so decode stalls
-        are bounded by one budget of chunk compute (budget 0 = pure
-        decode ticks). With nothing decoding there is nobody to stall:
-        an idle tick always advances at least one chunk. → tokens spent.
+        `prefill_token_budget` is the prefill a DECODE STEP may be made
+        to wait for, and a tick runs one decode window of k steps for
+        the `decoding` slots, so the tick may place budget × k prompt
+        tokens: the per-token stall stays one budget of chunk compute
+        whatever the window's length (a window of one step, and a
+        speculative tick, which is one target pass, carry one budget;
+        budget 0 = pure decode ticks). With nothing decoding there is
+        nobody to stall: an idle tick always advances at least one
+        chunk. → tokens spent.
 
-        One dispatch holds at most `chunk_rows` rows: as many full
-        chunks as the budget has room for, so the program is as tall as
-        a tick can fill it and no taller. The budget stops the packing
-        loop before the row bound does whenever the rows are full
-        chunks; a tick of many SHORT prompts (more rows than full chunks
-        fit the budget) goes round the loop again, several short
-        dispatches where one n_slots-row program ran — the same
-        algorithm with its one parameter read off the engine's own
-        budget, not a second path.
+        The allowance is strict, and so is the pool: while slots decode,
+        chunks grow only into the pages those slots are not about to
+        need (`_decode_page_reserve`), so a full pool stalls prefill
+        instead of preempting it.
+
+        The tick's rows are collected first and dispatched by
+        `_dispatch_chunks`, `chunk_rows` rows a program: the program is
+        as tall as one budget fills it, and a tick with more rows (a
+        longer window, many short prompts) runs it several times — the
+        same algorithm with its parameters read off the engine's own
+        budget and window, not a second path.
         """
         if not self._prefilling:
             return 0
-        budget = self.prefill_budget
-        if not decode_active:
-            budget = max(budget, self.prefill_chunk)
+        if decoding:
+            steps = 1 if self.spec_k else self._pick_window(decoding)
+            allowance = self.prefill_budget * steps
+            spare = self._decode_page_reserve(decoding)
+        else:
+            allowance = max(self.prefill_budget, self.prefill_chunk)
+            spare = 0
+        self.stats["prefill_allowance"] += allowance
         spent = 0
         while self._prefilling:
-            # Build one fused dispatch of up to chunk_rows chunk ROWS, FCFS,
-            # until rows or the budget run out. Rows from the same prompt
+            # Pack the tick's chunk ROWS, FCFS, until the work, the
+            # allowance or the pool runs out. Rows from the same prompt
             # (consecutive chunks) are as legal as rows from different
             # slots: within a layer every row's K/V is written to its
             # pages BEFORE any row attends, and causal masking bounds
             # each row to its own prefix — the same argument that makes
             # chunked prefill exact across dispatches makes it exact
-            # across rows of one dispatch. Packing recovers the one-shot
-            # path's dispatch amortization (a tick costs ~one prefill
-            # round trip, and a lone long prompt still fills the batch)
-            # without giving up the token-budget stall bound.
+            # across rows of one dispatch.
             batch: list[tuple[int, GenRequest, int, int]] = []
             planned = 0
             stop = False
             with self._phase("prefill.build"):
                 for slot in self._prefilling:
-                    if stop or len(batch) >= self.chunk_rows:
+                    if stop:
                         break
                     req = self.slot_req[slot]
                     done = self._chunk_pos[slot]
                     total = len(req.prompt_ids)
-                    while done < total and len(batch) < self.chunk_rows:
+                    while done < total:
                         n = min(self.prefill_chunk, total - done)
-                        if spent + planned + n > budget:
+                        if spent + planned + n > allowance:
                             stop = True
                             break
+                        # A prompt's last chunk makes its slot a decoding
+                        # one: the page its own decode is about to need
+                        # is set aside with the others'.
+                        own = (self._next_page_needed(
+                            slot, total, self.pool.pages_for(total - 1))
+                            if decoding and done + n == total else 0)
                         if not self.pool.grow(slot, done + n - 1,
-                                              self._cache_reclaim):
+                                              self._cache_reclaim,
+                                              spare=spare + own):
                             # Pool dry: stop at the blocked chunk (FCFS —
                             # later work must not consume pages the head
                             # could use).
                             stop = True
                             break
+                        spare += own
                         batch.append((slot, req, done, n))
                         planned += n
                         done += n
             if not batch:
-                # Head page-blocked or budget exhausted. With decode in
-                # flight, retiring requests will free pages — stall this
-                # tick and retry. With nothing decoding and several
+                # Head page-blocked or allowance exhausted. With decode
+                # in flight, retiring requests will free pages — stall
+                # this tick and retry. With nothing decoding and several
                 # mid-prefill slots wedged against each other, preempt
                 # the YOUNGEST (least sunk prefill work) to unwedge the
                 # head. A lone prefilling slot can always grow (submit()
                 # caps prompts at the pool size), so this terminates.
-                if (not decode_active and spent == 0
+                if (not decoding and spent == 0
                         and len(self._prefilling) > 1):
                     reclaim = [s for s in self._prefilling
                                if self.pool.slot_n_pages[s]]
@@ -2374,7 +2404,40 @@ class LLMEngine:
                 break
             self._dispatch_chunks(batch)
             spent += planned
+        if allowance:
+            # Allowance utilization: how much of what ticks WITH waiting
+            # prefill work may place they do place — sustained ~1.0
+            # under queue depth means prefill throughput (not admission,
+            # not the pool) is the TTFT bottleneck.
+            with self._lock:
+                self._budget_util_ewma = self._ewma(
+                    self._budget_util_ewma, spent / allowance)
         return spent
+
+    def _next_page_needed(self, slot: int, position: int, held: int) -> int:
+        """1 if a slot decoding from `position` with `held` pages must
+        take another page before its request's `max_tokens` are out,
+        else 0. A window writes all its steps' positions for every slot
+        it carries, so what is left counts in whole windows."""
+        req = self.slot_req[slot]
+        left = req.max_tokens - len(req.out_ids)
+        left = -(-left // self.decode_block) * self.decode_block
+        last = min(position + left, self.max_len) - 1
+        return int(self.pool.pages_for(last) > held)
+
+    def _decode_page_reserve(self, decoding: list[int]) -> int:
+        """Pages a tick's prefill leaves free for the slots that already
+        decode: for each, the NEXT page it will need within what is left
+        of its `max_tokens` (at most one a slot). The window's own need
+        alone would be too little (full slots swing around the pool's
+        size from tick to tick, and the fitter then preempts a
+        mid-prefill slot whose rows were just paid for); everything a
+        slot could ever need would hold slots empty for a `max_tokens`
+        that is never reached. No clock: positions, page counts and the
+        requests' own limits."""
+        held = self.pool.slot_n_pages
+        return sum(self._next_page_needed(s, int(self.positions[s]),
+                                          int(held[s])) for s in decoding)
 
     def _chunk_width(self, done: int, n: int) -> int:
         """Pow-2 page-table width a chunk row [done, done+n) actually
@@ -2385,35 +2448,43 @@ class LLMEngine:
                    self.max_pages_per_slot)
 
     def _dispatch_chunks(self, batch) -> None:
-        """Width-bucketed chunk dispatch: group the tick's packed chunk
-        rows by the pow-2 page width each row actually attends over
-        (`_chunk_width`) and issue one fixed-shape [chunk_rows, C] dispatch
-        per non-empty bucket (a bucket that took only some of the batch
-        pads with inert rows), each carrying a table view sliced to its
-        bucket's width — interior chunks of a long-max-len engine stop
-        paying attention compute/bytes ∝ max_pages_per_slot. Buckets
-        run in ASCENDING width order: consecutive chunks of one prompt
-        have monotonically non-decreasing widths (written tokens only
-        grow), so ascending order preserves the write-before-attend
-        chain across buckets exactly as batch order does within one
-        (equal-width chunks share a bucket in batch order). With
-        prefill_width_bucketing off, the whole batch dispatches at full
-        width — the PR 4 two-program grid, byte-identical output."""
-        if not self.prefill_width_bucketing:
-            self._dispatch_chunk_bucket(batch, self.max_pages_per_slot)
-            return
-        buckets: dict[int, list] = {}
-        for row in batch:
-            _slot, _req, done, n = row
-            buckets.setdefault(self._chunk_width(done, n), []).append(row)
+        """Width-bucketed chunk dispatch: group the TICK's chunk rows by
+        the pow-2 page width each row actually attends over
+        (`_chunk_width`) and cut each bucket, in batch (FCFS) order,
+        into fixed-shape [chunk_rows, C] dispatches, each carrying a
+        table view sliced to its bucket's width (a bucket's last
+        dispatch pads with inert rows) — interior chunks of a
+        long-max-len engine stop paying attention compute/bytes ∝
+        max_pages_per_slot, and rows of one width from different prompts
+        fill a program together. Buckets run in ASCENDING width order:
+        consecutive chunks of one prompt have monotonically
+        non-decreasing widths (written tokens only grow), so ascending
+        order preserves the write-before-attend chain across buckets
+        exactly as batch order does within one. The program's height
+        does not follow the tick's allowance: a tall program would
+        carry the few rows of one width among inert ones, and an inert
+        row costs what a live one does outside the kernel. With
+        prefill_width_bucketing off, every row dispatches at full width
+        — the PR 4 two-program grid, byte-identical output."""
+        if self.prefill_width_bucketing:
+            buckets: dict[int, list] = {}
+            for row in batch:
+                _slot, _req, done, n = row
+                buckets.setdefault(self._chunk_width(done, n), []).append(row)
+        else:
+            buckets = {self.max_pages_per_slot: batch}
         failed: set[int] = set()
         for width in sorted(buckets):
-            # A dispatch failure releases its slots; later buckets may
-            # still carry those slots' follow-on chunks — drop them (the
-            # request already errored, the slot may be rebound).
-            rows = [r for r in buckets[width] if r[0] not in failed]
-            if rows:
-                failed |= self._dispatch_chunk_bucket(rows, width)
+            rows = buckets[width]
+            for i in range(0, len(rows), self.chunk_rows):
+                # A dispatch failure releases its slots; later dispatches
+                # may still carry those slots' follow-on chunks — drop
+                # them (the request already errored, the slot may be
+                # rebound).
+                group = [r for r in rows[i:i + self.chunk_rows]
+                         if r[0] not in failed]
+                if group:
+                    failed |= self._dispatch_chunk_bucket(group, width)
 
     def _dispatch_chunk_bucket(self, batch, width: int) -> set[int]:
         """One fixed-shape [chunk_rows, C] prefill_chunk_paged dispatch
@@ -2421,7 +2492,7 @@ class LLMEngine:
         prompt tokens [done, done+n) into its slot's pages (several rows
         may carry consecutive chunks of the same prompt); rows without
         work are inert (n_valid 0). `batch` holds at most chunk_rows
-        rows (the packing loop's bound). The table view is sliced to `width`
+        rows (`_dispatch_chunks` cuts them so). The table view is sliced to `width`
         columns — every row's written prefix + chunk fits by bucket
         construction, and a slot's allocation BEYOND the row's own width
         (a later same-tick chunk already grew it) is simply invisible to
@@ -2947,20 +3018,7 @@ class LLMEngine:
             # graftlint: disable=HOST-SYNC-IN-HOT-LOOP (one pull per one-shot prefill group by design: its first tokens are sampled on the host)
             self._prefill_group(bucket, group, slots)
         if self.prefill_chunk:
-            decode_ready = any(
-                self.slot_req[i] is not None and i not in self._chunk_pos
-                for i in range(self.n_slots))
-            had_prefill_work = bool(self._prefilling)
-            spent = self._run_prefill_chunks(decode_ready)
-            if had_prefill_work and self.prefill_budget > 0:
-                # Budget utilization: how much of the per-tick prefill
-                # allowance ticks WITH waiting prefill work actually
-                # spend — sustained ~1.0 under queue depth means prefill
-                # throughput (not admission) is the TTFT bottleneck.
-                with self._lock:
-                    self._budget_util_ewma = self._ewma(
-                        self._budget_util_ewma,
-                        min(1.0, spent / self.prefill_budget))
+            self._run_prefill_chunks(self._decode_ready_slots())
         n_prefilling = len(self._prefilling)
         if self.spec_k:
             # Speculative decoding replaces the fused decode window

@@ -99,11 +99,14 @@ class PagePool:
     # ------------------------------------------------------------ slots
 
     def grow(self, slots, last_pos,
-             reclaim: Callable[[int], None] | None = None) -> bool:
+             reclaim: Callable[[int], None] | None = None,
+             spare: int = 0) -> bool:
         """Take exclusive pages so that each of `slots` (one slot, or an
-        array of them) covers its `last_pos`. All or nothing: short of
-        pages, `reclaim(pages_needed)` is asked to free some first, and
-        if the pool is still short nothing changes."""
+        array of them) covers its `last_pos`, and leave `spare` pages
+        free (what the caller has set aside for somebody else). All or
+        nothing: short of pages, `reclaim(pages_needed)` is asked to
+        free some first, and if the pool is still short nothing
+        changes."""
         if isinstance(slots, (int, np.integer)):
             slots, last_pos = (slots,), (last_pos,)
         # Plain ints: numpy's fixed cost a call would be most of the work.
@@ -114,6 +117,8 @@ class PagePool:
             if need > 0:
                 plan.append((slot, have, need))
                 total += need
+        if total:
+            total += spare
         if total > len(self._free) and reclaim is not None:
             reclaim(total)
         if total > len(self._free):

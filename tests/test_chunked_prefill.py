@@ -4,8 +4,9 @@ Exactness first: the chunked-prefill engine must emit token streams
 byte-identical to the one-shot paged engine (itself exact-match with the
 dense engine) for every chunk size, ragged prompt lengths, both attention
 implementations, and under preempt-by-recompute pool pressure. Then the
-scheduler contracts: the per-tick prefill token budget is a hard cap
-(budget 0 = pure decode ticks), the chunked path lowers within the pow-2
+scheduler contracts: the prefill token budget is a hard cap for each
+decode step of a tick's window (budget 0 = pure decode ticks), a full
+pool stalls prefill instead of preempting it, the chunked path lowers within the pow-2
 width-ladder budget — 2·log₂(max_pages)+2 programs bucketed, exactly two
 with bucketing off (vs the one-shot buckets × admission-ladder grid) —
 and a page-blocked queue head no longer head-of-line-blocks
@@ -54,17 +55,30 @@ def _ragged_prompts(rng, lengths):
             for n in lengths]
 
 
-def _spy_chunk_shapes(eng):
-    """Record the (tokens, tables, offsets, n_valid) shapes of every
+def _spy_chunks(eng, note):
+    """Record `note(toks, tables, offsets, valid, kw)` of every
     `prefill_chunk_paged` call the engine makes from here on."""
     real, seen = eng._rt.prefill_chunk_paged, []
 
     def spy(cfg, params, toks, pool, tables, offsets, valid, **kw):
-        seen.append((toks.shape, tables.shape, offsets.shape, valid.shape))
+        seen.append(note(toks, tables, offsets, valid, kw))
         return real(cfg, params, toks, pool, tables, offsets, valid, **kw)
 
     eng._rt.prefill_chunk_paged = spy
     return seen
+
+
+def _spy_chunk_shapes(eng):
+    """The (tokens, tables, offsets, n_valid) shapes of every call."""
+    return _spy_chunks(eng, lambda toks, tables, offsets, valid, kw: (
+        toks.shape, tables.shape, offsets.shape, valid.shape))
+
+
+def _spy_chunk_programs(eng):
+    """The (table width, head, live rows) of every call."""
+    return _spy_chunks(eng, lambda toks, tables, offsets, valid, kw: (
+        tables.shape[1], bool(kw["return_logits"]),
+        int((np.asarray(valid) > 0).sum())))
 
 
 def _parent_rule(eng):
@@ -253,12 +267,17 @@ class TestChunkRows:
         (16, 64, 4, 4),         # n_slots x chunk: the old program
         (16, 4096, 4, 4),       # never more rows than slots
     ])
+    @pytest.mark.parametrize("decode_block", [8, 1])
     def test_chunk_rows_from_budget(self, params, chunk, budget, n_slots,
-                                    rows):
+                                    rows, decode_block):
+        """The program's height is what ONE budget fills, whatever the
+        window's length multiplies the tick's allowance by."""
         eng = LLMEngine(CFG, params, n_slots=n_slots, max_len=256,
                         prefill_buckets=(64,), kv_mode="paged",
                         page_size=16, n_pages=20, prefill_chunk=chunk,
-                        prefill_token_budget=budget)
+                        prefill_token_budget=budget,
+                        decode_block=decode_block)
+        assert eng.decode_block == decode_block
         assert eng.chunk_rows == rows
         assert eng.metrics()["chunk_rows"] == rows
         assert eng.load_snapshot()["chunk_rows"] == rows
@@ -320,9 +339,10 @@ class TestChunkRows:
             assert eng.metrics()["prefix_hits"] > 0
 
     def test_short_prompts_take_several_dispatches_a_tick(self, params):
-        """More rows than full chunks fit the budget: the tick goes
-        round the packing loop again (where the parent's rule ran one
-        n_slots-row program), FCFS, and never past the budget."""
+        """More rows than full chunks fit the budget: the tick runs the
+        program several times (where the parent's rule ran one
+        n_slots-row program), FCFS, and never past the budget of each
+        step of its window."""
         rng = np.random.default_rng(14)
         budget = 32
         kw = dict(n_slots=6, max_len=128, prefill_buckets=(64,),
@@ -342,7 +362,8 @@ class TestChunkRows:
         while not all(r.done.is_set() for r in reqs):
             pt = eng.stats["prefill_tokens"]
             eng.step()
-            assert eng.stats["prefill_tokens"] - pt <= budget
+            assert (eng.stats["prefill_tokens"] - pt
+                    <= budget * eng.decode_block)
         firsts = [r.first_token_at for r in reqs]
         assert firsts == sorted(firsts), "first tokens left FCFS order"
         assert all(shape[0] == (2, 16) for shape in seen)
@@ -353,6 +374,225 @@ class TestChunkRows:
         assert parent.stats["prefill_dispatches"] == 1
         assert parent.stats["prefill_tokens"] == 28
         assert _drive(parent, again) == [r.out_ids for r in reqs]
+
+
+class TestWindowAllowance:
+    """A tick beside a decode window of k steps may place k budgets of
+    prompt tokens; the program stays as tall as ONE budget fills and
+    runs once per `chunk_rows` rows of one table width."""
+
+    KW = dict(n_slots=8, max_len=128, prefill_buckets=(128,),
+              kv_mode="paged", page_size=8, prefill_chunk=8,
+              prefill_token_budget=16)
+
+    def _beside_decode(self, params, lengths, *, rng, max_tokens=4, **kw):
+        """An engine with one request decoding (for the whole test) and
+        `lengths` prompts submitted behind it. → (eng, reqs)."""
+        eng = LLMEngine(CFG, params, **dict(self.KW, **kw))
+        first = eng.submit([5, 9, 2], max_tokens=100)
+        while first.first_token_at is None:
+            eng.step()
+        return eng, [eng.submit(p, max_tokens=max_tokens)
+                     for p in _ragged_prompts(rng, lengths)]
+
+    def test_decode_block_one_schedules_as_the_parent(self, params):
+        """A window of one step carries one budget: tick by tick the
+        tokens placed and the (width, head, live rows) dispatches are
+        what the parent's loop gave — pack up to chunk_rows rows FCFS
+        within the budget, dispatch them by ascending width, go round
+        again — here replayed beside the engine."""
+        eng, reqs = self._beside_decode(
+            params, (40, 33, 8, 40, 16, 24), rng=np.random.default_rng(21),
+            decode_block=1)
+        seen = _spy_chunk_programs(eng)
+        budget, chunk, cap = 16, 8, eng.chunk_rows
+        assert cap == 2
+        progress = {id(r): 0 for r in reqs}
+        ticks = 0
+        while not all(r.first_token_at is not None for r in reqs):
+            # the parent's rule over the prompts still mid-prefill
+            expect, spent = [], 0
+            waiting = [r for r in reqs               # admitted FCFS
+                       if progress[id(r)] < len(r.prompt_ids)]
+            while True:
+                batch, planned = [], 0
+                for r in waiting:
+                    done, total = progress[id(r)], len(r.prompt_ids)
+                    while done < total and len(batch) < cap:
+                        n = min(chunk, total - done)
+                        if spent + planned + n > budget:
+                            break
+                        batch.append((eng._chunk_width(done, n),
+                                      done + n == total))
+                        planned += n
+                        done += n
+                        progress[id(r)] = done
+                    if done < total or len(batch) >= cap:
+                        break
+                if not batch:
+                    break
+                spent += planned
+                for w in sorted({w for w, _ in batch}):
+                    rows = [h for ww, h in batch if ww == w]
+                    expect.append((w, any(rows), len(rows)))
+            n0, pt = len(seen), eng.stats["prefill_tokens"]
+            eng.step()
+            ticks += 1
+            assert eng.stats["prefill_tokens"] - pt == spent <= budget
+            assert seen[n0:] == expect
+        assert ticks >= sum(len(r.prompt_ids) for r in reqs) // budget
+        _drive(eng, reqs)
+
+    def test_lone_prompt_dispatches_the_programs_of_many_in_flight(
+            self, params):
+        """What a warm-up by lone requests relies on: a prompt alone in
+        an idle engine dispatches every (width, head) program that
+        prompts of its length dispatch when several are in flight
+        beside a decode window, whole-tick buckets and all."""
+        rng = np.random.default_rng(22)
+        lone = LLMEngine(CFG, params, **self.KW)
+        seen_lone = _spy_chunk_programs(lone)
+        _drive(lone, [lone.submit(_ragged_prompts(rng, (100,))[0],
+                                  max_tokens=4)])
+        eng, reqs = self._beside_decode(params, (100,) * 5, rng=rng)
+        seen = _spy_chunk_programs(eng)
+        eng.step()
+        # 16 x 8 tokens a tick: more than one prompt's 13 rows
+        assert eng.stats["prefill_tokens"] >= 100 + 16
+        _drive(eng, reqs)
+        assert {(w, h) for w, h, _ in seen} == {
+            (w, h) for w, h, _ in seen_lone} == {
+            (1, False), (2, False), (4, False), (8, False), (16, False),
+            (16, True)}
+        assert eng.metrics()["prefill_dispatch_widths"].keys() == (
+            lone.metrics()["prefill_dispatch_widths"].keys())
+        # rows of one width from different prompts fill a program together
+        assert max(n for *_x, n in seen) == eng.chunk_rows == 2
+        assert eng.metrics()["prefill_row_fill"] > (
+            lone.metrics()["prefill_row_fill"])
+
+    def test_whole_tick_buckets_ascend_and_first_tokens_stay_fcfs(
+            self, params):
+        """Within a tick the dispatches run in ascending table width
+        (write before attend across a prompt's own rows), each bucket
+        in FCFS order, so prompts of one length reach their first
+        tokens in the order they were admitted."""
+        eng, reqs = self._beside_decode(
+            params, (60,) * 6, rng=np.random.default_rng(23))
+        seen = _spy_chunk_programs(eng)
+        while not all(r.first_token_at is not None for r in reqs):
+            n0 = len(seen)
+            eng.step()
+            widths = [w for w, _h, _n in seen[n0:]]
+            assert widths == sorted(widths)
+        firsts = [r.first_token_at for r in reqs]
+        assert firsts == sorted(firsts), "first tokens left FCFS order"
+        assert len({w for w, _h, _n in seen}) > 2
+        _drive(eng, reqs)
+
+    @pytest.mark.parametrize("case", ["long", "short_together",
+                                      "warm_beside_cold"])
+    def test_streams_equal_oneshot_and_one_budget_a_tick(self, params,
+                                                         case):
+        """Beside a decoding request, a window's worth of budgets gives
+        the streams of the one-shot engine and of the parent's schedule
+        (one budget a tick: decode_block 1), for a long prompt, six
+        short prompts at once, and a warm-prefix row beside a cold
+        one: only the order of dispatches changes."""
+        kw = dict(n_slots=8, max_len=128, prefill_buckets=(128,),
+                  kv_mode="paged", page_size=16)
+        chunked = dict(kw, prefill_chunk=16, prefill_token_budget=32,
+                       prefix_cache=(case == "warm_beside_cold"))
+        rng = np.random.default_rng(24)
+        first = []
+        if case == "long":
+            prompts = _ragged_prompts(rng, (100, 90))
+        elif case == "short_together":
+            prompts = _ragged_prompts(rng, (5, 7, 3, 9, 4, 6))
+        else:
+            shared = _ragged_prompts(rng, (40,))[0]
+            first = [shared + _ragged_prompts(rng, (9,))[0]]
+            prompts = [shared + _ragged_prompts(rng, (13,))[0],
+                       _ragged_prompts(rng, (37,))[0]]
+
+        def serve(eng):
+            for p in first:         # donates the shared prefix
+                _drive(eng, [eng.submit(p, max_tokens=6)])
+            decoding = eng.submit([5, 9, 2], max_tokens=40)
+            while decoding.first_token_at is None:
+                eng.step()
+            pt = eng.stats["prefill_tokens"]
+            reqs = [eng.submit(p, max_tokens=6) for p in prompts]
+            eng.step()
+            placed = eng.stats["prefill_tokens"] - pt
+            return _drive(eng, reqs + [decoding]), placed
+
+        oneshot, _ = serve(LLMEngine(CFG, params, **kw))
+        parent, placed_1 = serve(LLMEngine(CFG, params, decode_block=1,
+                                           **chunked))
+        out, placed_8 = serve(LLMEngine(CFG, params, decode_block=8,
+                                        **chunked))
+        assert out == parent == oneshot
+        assert placed_1 <= 32 < placed_8 <= 32 * 8
+
+
+class TestPoolPressure:
+    """Full slots need more pages than the pool has: prefill stops
+    short of the pages the decoding slots are about to need, so the
+    pool stalls prompts instead of preempting them."""
+
+    KW = dict(n_slots=6, max_len=64, prefill_buckets=(32,),
+              kv_mode="paged", page_size=8, n_pages=20, decode_block=4,
+              prefill_chunk=8, prefill_token_budget=8)
+
+    def _serve(self, params, eng):
+        """12 requests of 20 + 20 tokens (5 pages each at the end; six
+        slots would hold 30 of the pool's 20), two arriving a tick."""
+        rng = np.random.default_rng(31)
+        prompts = _ragged_prompts(rng, (20,) * 12)
+        reqs, worst = [], 0
+        for _ in range(600):
+            for p in prompts[len(reqs):len(reqs) + 2]:
+                reqs.append(eng.submit(p, max_tokens=20))
+            eng.step()
+            acct = eng.page_accounting()
+            assert acct["closure"] and acct["refs_consistent"], acct
+            worst = max(worst, len(eng._decode_ready_slots()))
+            if len(reqs) == len(prompts) and all(
+                    r.done.is_set() for r in reqs):
+                break
+        assert all(r.done.is_set() and r.error is None
+                   and not r.truncated and len(r.out_ids) == 20
+                   for r in reqs)
+        m = eng.metrics()
+        assert m["kv_pages_free"] == m["kv_pages_total"]
+        return [r.out_ids for r in reqs], m, worst
+
+    def test_full_pool_stalls_prefill_and_preempts_nobody(self, params):
+        ample, m_ample, _ = self._serve(
+            params, LLMEngine(CFG, params, **dict(self.KW, n_pages=64)))
+        assert m_ample["preemptions"] == 0
+        out, m, worst = self._serve(params, LLMEngine(CFG, params,
+                                                      **self.KW))
+        assert out == ample
+        assert m["preemptions"] == 0
+        assert m["kv_pages_free_min"] <= 2, "the pool was never full"
+        assert worst >= 3
+        assert m["prefill_allowance_used"] < (
+            m_ample["prefill_allowance_used"]), "the pool never bound"
+
+    def test_preemptions_counts_what_does_happen(self, params):
+        """The same traffic with nothing set aside: prefill grows until
+        the pool is dry, the window fitter takes a mid-prefill slot's
+        pages back, and `preemptions` says so; the streams still equal
+        the ample pool's (preempt by recompute is exact)."""
+        ample, _m, _ = self._serve(
+            params, LLMEngine(CFG, params, **dict(self.KW, n_pages=64)))
+        eng = LLMEngine(CFG, params, **self.KW)
+        eng._next_page_needed = lambda slot, position, held: 0
+        out, m, _ = self._serve(params, eng)
+        assert m["preemptions"] > 0
+        assert out == ample
 
 
 class TestCompileCount:
@@ -420,31 +660,44 @@ class TestScheduler:
         _drive(eng, [rb])  # idle ticks still make progress at budget 0
         assert len(rb.out_ids) == 4
 
-    def test_budget_is_a_hard_cap(self, params):
+    @pytest.mark.parametrize("decode_block", [1, 2, 8])
+    def test_budget_is_a_hard_cap(self, params, decode_block):
         """Oversubscribed queue (many multi-chunk prompts + active
-        decode): no tick ever exceeds the token budget."""
+        decode): no tick ever exceeds one token budget for each decode
+        step of the window it runs beside — the parent's cap, one
+        budget a tick, at decode_block 1 — and a tick with a window of
+        several steps does use more than one."""
         rng = np.random.default_rng(7)
         budget, chunk = 16, 8
         eng = LLMEngine(CFG, params, n_slots=6, max_len=128,
                         prefill_buckets=(64,), kv_mode="paged", page_size=8,
                         prefill_chunk=chunk, prefill_token_budget=budget,
-                        decode_block=2)
-        reqs = [eng.submit(p, max_tokens=6)
+                        decode_block=decode_block)
+        reqs = [eng.submit(p, max_tokens=12)
                 for p in _ragged_prompts(rng, (40, 33, 25, 40, 17, 40))]
         # First request(s) reach decode, then every later tick must cap.
         while not any(r.first_token_at is not None for r in reqs):
             eng.step()
+        most = 0
         while not all(r.done.is_set() for r in reqs):
             pt = eng.stats["prefill_tokens"]
-            decoding = any(
-                eng.slot_req[s] is not None and s not in eng._chunk_pos
-                for s in range(eng.n_slots))
+            decoding = eng._decode_ready_slots()
+            steps = eng._pick_window(decoding) if decoding else 0
             eng.step()
             spent = eng.stats["prefill_tokens"] - pt
             if decoding:
-                assert spent <= budget, (
-                    f"tick ran {spent} prefill tokens past budget {budget}")
+                assert steps <= decode_block
+                assert spent <= budget * steps, (
+                    f"tick ran {spent} prefill tokens beside a window of "
+                    f"{steps} steps, budget {budget} a step")
+                most = max(most, spent)
         assert all(r.error is None for r in reqs)
+        assert (most == budget) if decode_block == 1 else (most > budget)
+        m = eng.metrics()
+        assert 0 < m["prefill_allowance_used"] <= 1
+        assert m["prefill_tokens"] <= m["prefill_allowance"]
+        eng.reset_stats()
+        assert eng.metrics()["prefill_allowance_used"] == 0
 
     def test_bad_configs_rejected(self, params):
         with pytest.raises(ValueError, match="paged"):

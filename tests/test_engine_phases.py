@@ -138,7 +138,9 @@ def test_phases_are_flat_and_cover_the_tick(params, draft_params, mode):
     if mode in ("paged-chunked", "single-step", "speculative"):
         assert (m["phase_n"]["prefill.dispatch"]
                 == m["prefill_dispatches"] > 0)
-        assert m["phase_n"]["prefill.pull"] == 5     # one per prompt's end
+        # One per dispatch that carries a prompt's end; ends of one table
+        # width in one tick share a dispatch.
+        assert 3 <= m["phase_n"]["prefill.pull"] <= 5
 
 
 def test_a_window_is_k_dispatches_and_one_pull(params):

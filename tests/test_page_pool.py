@@ -229,6 +229,22 @@ def test_reclaim_hook_is_tried_before_refusing():
     assert sorted(pool.row(0).tolist()) == [1, 2, 3]
 
 
+@pytest.mark.parametrize("spare,fits", [(0, True), (1, True), (2, False)])
+def test_grow_leaves_the_spare_pages_free(spare, fits):
+    """What the caller set aside for somebody else stays free: a grow
+    that would dip into it is refused whole, the hook is asked for the
+    pages AND the spare, and a grow that needs no page never is."""
+    pool = PagePool(4, 2, 2, 4)
+    assert pool.grow(0, 1)                           # 1 page, 3 free
+    asked = []
+    assert pool.grow(1, 3, asked.append, spare=spare) == fits   # 2 pages
+    assert asked == ([] if fits else [4])
+    assert pool.n_free == (1 if fits else 3)
+    assert pool.slot_n_pages[1] == (2 if fits else 0)
+    assert pool.grow(0, 1, asked.append, spare=99)   # covered already
+    assert asked == ([] if fits else [4])
+
+
 def test_truncate_frees_exclusive_pages_and_never_a_shared_one():
     pool = PagePool(8, 4, 2, 4)
     assert pool.grow([0, 1], [15, 15])               # 4 pages each
