@@ -635,7 +635,7 @@ def _no_phase(_name: str):
 
 
 def _decode_window(step, tokens, pool, positions, n_steps: int, key,
-                   phase=_no_phase):
+                   phase=_no_phase, also=None):
     """`n_steps` back-to-back dispatches of one jitted step program
     (`step(tokens, pool, positions, key)` → the same four, advanced).
     Tokens, cursors and the donated pool stay on the device between
@@ -648,15 +648,21 @@ def _decode_window(step, tokens, pool, positions, n_steps: int, key,
     `phase(name)` is the caller's recorder, a context manager factory
     (`LLMEngine._phase`): this loop is the one part of an engine tick the
     engine cannot see into, so each dispatch reports as `decode.dispatch`
-    and the fetch as `decode.pull`."""
+    and the fetch as `decode.pull`.
+
+    `also(pool)` (optional) names device values of the final pool to
+    fetch in the SAME pull (a family's on-device counters); with it the
+    result is (tokens_out, pool, those values)."""
     out = []
     for _ in range(n_steps):
         with phase("decode.dispatch"):
             tokens, positions, pool, key = step(tokens, pool, positions, key)
         out.append(tokens)
     with phase("decode.pull"):
-        toks_out = np.stack(jax.device_get(out))
-    return toks_out, pool
+        if also is None:
+            return np.stack(jax.device_get(out)), pool
+        out, extra = jax.device_get((out, also(pool)))
+    return np.stack(out), pool, extra
 
 
 def decode_multi_paged(cfg: GPTConfig, params, tokens, pool, positions,

@@ -1,0 +1,178 @@
+"""The serving seam: what `LLMEngine` needs of a model family.
+
+The engine's scheduler, tick, page accounting and host phases are the
+same for every architecture; what differs is the device side: how the
+pool pytree is built (K/V pages, and for some families a per-slot
+state beside them), which jitted programs a chunk and a decode window
+run, how weights and pool shard, and which of the engine's options the
+family's programs cannot carry. A configuration object names its family
+(a class attribute `family`; `GPTConfig` carries none and is "gpt") and
+`family_of(cfg)` hands the engine one `ServingFamily`: the mirror, on
+the program's side, of benchmarks/families/<family>.py. A new
+architecture adds an entry to `_FAMILIES` and a model module; nothing in
+serve/llm.py names a model class.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+from typing import Any, Callable
+
+
+@dataclasses.dataclass(frozen=True)
+class Unsupported:
+    """An engine option this family's programs cannot carry.
+    `fits(o)`: the resolved options as they stand are fine; `neutral`:
+    what a fleet-wide knob is turned to; `why`: the explicit argument's
+    error, naming what would have to be built
+    (serve/llm_options.py `_honour`)."""
+
+    option: str
+    fits: Callable[[Any], bool]
+    neutral: Any
+    why: str
+
+
+@dataclasses.dataclass(frozen=True)
+class ServingFamily:
+    name: str
+    # The model module: init_params(cfg, key), partition_rules(), and
+    # quantize_params(params) where int8 weights are supported.
+    model: Any
+    # (cfg, n_pages, page_size, n_slots, kv_dtype) -> the pool pytree the
+    # paged programs carry (donated): {"k", "v", ...}.
+    init_pool: Callable
+    pool_partition_rules: tuple
+    # (tp, mesh) -> {name: callable}: the programs `_bind_programs` wraps.
+    programs: Callable
+    # The pool carries a per-slot state: chunk programs are told each
+    # row's slot (`slots=`).
+    slot_state: bool = False
+    # The decode programs keep expert counters in the pool: a decode
+    # window hands them over with its tokens (`counters=`).
+    expert_counters: bool = False
+    unsupported: tuple = ()
+
+
+_DENSE = ("prefill", "prefill_batch", "decode_step", "decode_multi",
+          "sample_token")
+_PAGED = ("prefill_chunk_paged", "verify_chunk_paged", "decode_step_paged",
+          "decode_multi_paged", "copy_pages", "gather_pages",
+          "scatter_pages", "spec_draft_propose")
+
+
+def _gpt_programs(tp: int, mesh) -> dict:
+    """models/decode.py's dense set and models/paged_kv.py's paged set;
+    at tp > 1 the paged programs are their shard_map twins (`*_tp`) with
+    the mesh bound as a static kwarg, under the same names."""
+    from ray_tpu.models import decode, paged_kv
+
+    programs = {name: getattr(decode, name) for name in _DENSE}
+    programs["prefill_batch_paged"] = paged_kv.prefill_batch_paged
+    for name in _PAGED:
+        programs[name] = (
+            getattr(paged_kv, name) if tp == 1 else
+            functools.partial(getattr(paged_kv, name + "_tp"), mesh=mesh))
+    return programs
+
+
+def _gpt() -> ServingFamily:
+    from ray_tpu.models import gpt, paged_kv
+
+    return ServingFamily(
+        name="gpt", model=gpt,
+        init_pool=lambda cfg, n_pages, page_size, _n_slots, kv_dtype:
+            paged_kv.init_paged_kv(cfg, n_pages, page_size,
+                                   kv_dtype=kv_dtype),
+        pool_partition_rules=paged_kv.KV_POOL_PARTITION_RULES,
+        programs=_gpt_programs)
+
+
+def _zaya() -> ServingFamily:
+    from jax.sharding import PartitionSpec
+
+    from ray_tpu.models import zaya
+
+    state = ("the slot's conv/shift state (z, c and W_v2 u of its last "
+             "token, per layer: models/zaya.py)")
+    return ServingFamily(
+        name="zaya", model=zaya, init_pool=zaya.init_paged_kv,
+        pool_partition_rules=((r".*", PartitionSpec()),),
+        programs=lambda _tp, _mesh: {
+            name: getattr(zaya, name) for name in (
+                "prefill_chunk_paged", "decode_step_paged",
+                "decode_multi_paged")},
+        slot_state=True, expert_counters=True,
+        unsupported=(
+            Unsupported(
+                "kv_mode", lambda o: o.kv_mode == "paged", "paged",
+                "the zaya family serves from the paged pool only: "
+                "kv_mode='dense' would need a [L, B, T] cache backend of "
+                "the CCA block, with its per-slot state carried beside it"),
+            Unsupported(
+                "prefill_chunk", lambda o: o.prefill_chunk > 0, 128,
+                "the zaya family has no one-shot prefill: prefill_chunk=0 "
+                "would need a whole-prompt program that leaves the prompt's "
+                "last-token state in the slot state"),
+            Unsupported(
+                "prefill_width_bucketing",
+                lambda o: not o.prefill_width_bucketing, False,
+                "prefill_width_bucketing with the zaya family: a chunk "
+                "program here costs a pass over every expert's weights at "
+                "any table width, and one bucket a width spreads a lone "
+                "prompt's rows over more programs; a dispatch that packs "
+                "rows of several widths into one program would have to be "
+                "built"),
+            Unsupported(
+                "prefix_cache", lambda o: not o.prefix_cache, False,
+                "prefix_cache with the zaya family: a cached prefix would "
+                f"need a snapshot of {state} at the prefix's boundary, "
+                "stored with its pages (serve/prefix_cache.py keeps pages "
+                "only)"),
+            Unsupported(
+                "spec_draft", lambda o: not o.spec_draft, "",
+                "speculative decoding with the zaya family: a rejected "
+                "proposal rewinds the cursor, and a verify program that "
+                f"returns {state} at every position to rewind to would "
+                "have to be built"),
+            Unsupported(
+                "kv_transfer", lambda o: not o.kv_transfer, False,
+                "KV page-set transfer with the zaya family: a page set "
+                f"would have to carry {state} (serve/kv_objects.py moves "
+                "pages only)"),
+            Unsupported(
+                "tp", lambda o: int(o.tp) == 1, 1,
+                "tp > 1 with the zaya family: 2 KV heads cannot shard over "
+                "more chips than heads (models/partition.py and "
+                "serve/kv_objects.py split the pool by whole heads), and "
+                "the experts need an expert-parallel dispatch, not a head "
+                "split"),
+            Unsupported(
+                "weight_dtype", lambda o: o.weight_dtype != "int8", "bf16",
+                "weight_dtype='int8' with the zaya family: quantize_params "
+                "knows the gpt tree's planes, and the experts' grouped "
+                "matmul (ops/moe.py) has no int8 form"),
+            Unsupported(
+                "kv_dtype", lambda o: o.kv_dtype != "int8", "bf16",
+                "kv_dtype='int8' with the zaya family: the per-page scale "
+                "planes are kept by models/paged_kv._quant_write, which "
+                "the CCA block's K/V writer would have to call"),
+        ))
+
+
+_FAMILIES = {"gpt": _gpt, "zaya": _zaya}
+
+
+@functools.cache
+def _family(name: str) -> ServingFamily:
+    if name not in _FAMILIES:
+        raise ValueError(f"no serving family {name!r}; have "
+                         f"{sorted(_FAMILIES)}")
+    return _FAMILIES[name]()
+
+
+def family_of(cfg) -> ServingFamily:
+    """The family of a configuration object (its class's `family`
+    attribute; "gpt" when it names none)."""
+    return _family(getattr(type(cfg), "family", "gpt"))

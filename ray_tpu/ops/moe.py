@@ -20,6 +20,7 @@ from the dispatch/combine einsums' shardings; no hand-written a2a.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any
 
@@ -143,3 +144,110 @@ def moe_mlp(x: jax.Array, params: dict[str, jax.Array],
     mean_gate = jnp.mean(gates, axis=0)
     aux = cfg.n_experts * jnp.sum(frac_routed * mean_gate)
     return y.reshape(B, S, D), aux
+
+
+# --------------------------------------------------------------------------
+# Token-choice experts as deployed: no capacity, no dropped token.
+#
+# The serving form of an expert layer. Every (row, choice) assignment is
+# kept: rows are sorted by expert, the experts THIS chip holds run as one
+# grouped matmul over their contiguous row groups (`jax.lax.ragged_dot`,
+# which XLA:TPU lowers to its own Mosaic kernel — `ragged-dot` in a
+# trace), the result is unsorted and scaled by the gate. No [N, E, C]
+# one-hot exists and nothing depends on a capacity. The layer is told
+# which experts it holds (`first_expert` .. + the weights' leading axis)
+# and returns only their part, so the parts of chips holding disjoint
+# ranges add up to the whole layer (tests/test_moe_token_choice.py).
+
+def _mixed_dot_default() -> bool:
+    """Whether the backend multiplies bf16 groups into a float32 result.
+    XLA:TPU does; XLA:CPU has no such thunk for a ragged dot."""
+    return jax.default_backend() == "tpu"
+
+
+def _pad_rows(n: int) -> int:
+    """The smallest odd multiple of 128 that holds n rows."""
+    tiles = -(-n // 128)
+    return 128 * (tiles + 1 - tiles % 2)
+
+
+def _grouped_dot(lhs, rhs, sizes):
+    """`ragged_dot` accumulated to float32: rows of `lhs` [M, K], in
+    contiguous groups of `sizes`, each against its group's `rhs[g]`
+    [K, N]. Off-TPU, narrower operands go up to float32 first: the same
+    values and exact products, so the same sums."""
+    if lhs.dtype != jnp.float32 and not _mixed_dot_default():
+        lhs, rhs = lhs.astype(jnp.float32), rhs.astype(jnp.float32)
+    return jax.lax.ragged_dot(lhs, rhs, sizes,
+                              preferred_element_type=jnp.float32)
+
+
+def token_choice_experts(x: jax.Array, expert_ids: jax.Array,
+                         gates: jax.Array, w_gate: jax.Array,
+                         w_up: jax.Array, w_down: jax.Array, *,
+                         first_expert: int = 0, layer=None, valid=None):
+    """Gated-SiLU experts over token-choice routing, the held part.
+
+    x [N, D]; expert_ids [N] or [N, k] int32 (global expert ids, a row's
+    k choices); gates like expert_ids (float32 weights); w_gate / w_up
+    [E_held, D, F], w_down [E_held, F, D]: experts ``first_expert`` ..
+    ``first_expert + E_held - 1``. `valid` [N] bool (optional): rows that
+    carry a token; the others reach no expert.
+
+    With `layer` (an index, traced inside a layer scan) the weights are
+    the WHOLE stacks [L, E_held, ...]: the stack is handed to the grouped
+    matmul as L * E_held groups of which only this layer's have rows, so
+    no layer's experts are ever sliced out of it (a custom call's operand
+    would be a copy of them, a layer's worth of bytes a step).
+
+    Every held expert gets one extra all-zero row, so each group is
+    non-empty and the grouped matmul reads every held expert's weights
+    whatever the routing: a decode step's time does not move with how
+    many experts a handful of rows happened to reach (a trained router
+    reaches nearly all of them). The zero rows contribute exactly 0.
+
+    → (y [N, D] in x.dtype: Σ over a row's choices that land on a held
+    expert of gate · W_down(silu(W_gate x) ⊙ W_up x), zero for the rest;
+    counts [E_held] int32: rows each held expert received)."""
+    N, D = x.shape
+    E = w_gate.shape[0] if layer is None else w_gate.shape[1]
+    ids = expert_ids.reshape(N, -1)
+    k = ids.shape[1]
+    local = ids.reshape(-1).astype(jnp.int32) - first_expert      # [N*k]
+    held = (local >= 0) & (local < E)
+    if valid is not None:
+        held &= jnp.repeat(valid, k)
+    local = jnp.where(held, local, E)           # E: not here, sorts last
+    counts = jnp.zeros(E + 1, jnp.int32).at[local].add(1)[:E]
+    rows = jnp.repeat(x, k, axis=0) if k > 1 else x
+    local = jnp.concatenate([local, jnp.arange(E, dtype=jnp.int32)])
+    rows = jnp.concatenate([rows, jnp.zeros((E, D), x.dtype)])
+    sizes = counts + 1
+    # XLA:TPU tiles the rows of a grouped matmul by the largest power of
+    # two that divides their number: 16 for a decode step's 80 rows, so
+    # an expert's group straddles tiles and its weights are read for each
+    # (1.25x the bytes; 2x at a chunk's 272 rows). An ODD multiple of 128
+    # rows keeps the tile at 128, the MXU's width: empty rows that belong
+    # to no group are appended to get there.
+    pad = _pad_rows(local.shape[0]) - local.shape[0]
+    local = jnp.concatenate([local, jnp.full(pad, E, jnp.int32)])
+    rows = jnp.concatenate([rows, jnp.zeros((pad, D), x.dtype)])
+    order = jnp.argsort(local, stable=True)
+    rows = rows[order]
+    if layer is not None:
+        n_layers = w_gate.shape[0]
+        sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros(n_layers * E, jnp.int32), sizes, (layer * E,))
+        w_gate, w_up, w_down = (w.reshape((n_layers * E,) + w.shape[2:])
+                                for w in (w_gate, w_up, w_down))
+    dot = functools.partial(_grouped_dot, sizes=sizes)
+    h = (jax.nn.silu(dot(rows, w_gate)) * dot(rows, w_up)).astype(x.dtype)
+    out = dot(h, w_down)                                    # [M, D] fp32
+    # Rows past the held groups belong to no group: whatever the grouped
+    # matmul left there is dropped here.
+    out = jnp.where((local[order] < E)[:, None], out, 0.0)
+    inverse = jnp.zeros_like(order).at[order].set(jnp.arange(order.size))
+    out = out[inverse[:N * k]]                              # unsort
+    y = jnp.sum(out.reshape(N, k, D)
+                * gates.reshape(N, k, 1).astype(jnp.float32), axis=1)
+    return y.astype(x.dtype), counts

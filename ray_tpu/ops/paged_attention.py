@@ -28,6 +28,12 @@ Design notes:
   ``[H, H*K]`` accumulator, and the head-h block of row h is what the
   output keeps. Prefill: a static loop over heads, each on its K-lane
   slice of the query chunk, the page and the accumulator.
+- Grouped-query attention: the pool's minor axis is ``G*K`` for G KV
+  heads, each serving H/G query heads (G = H above). Decode takes the
+  query as ``[H, K]`` and puts row h into the lanes of KV head
+  h // (H/G) of the same block-diagonal ``[H, G*K]``; prefill's head
+  loop reads that KV head's lane slice of the page. G = H runs the code
+  it always ran.
 - Online-softmax state (m, l, acc) lives in VMEM scratch across the kv
   dimension ("arbitrary" grid semantics), exactly like the flash kernel.
 - Null / past-length pages: unallocated table tail entries are 0 (the
@@ -67,14 +73,19 @@ def _interpret_default() -> bool:
 
 
 def _check_pool(q_heads, q_head_dim, k_pool, v_pool):
-    """-> (page_size, H*K) of a whole-pool plane ``[L, P, ps, H*K]``."""
-    if (k_pool.ndim != 4 or k_pool.shape[3] != q_heads * q_head_dim
+    """-> (page_size, G) of a whole-pool plane ``[L, P, ps, G*K]``: G KV
+    heads of the query's head size, each serving H/G query heads (G = H
+    is multi-head attention)."""
+    GK = k_pool.shape[3] if k_pool.ndim == 4 else 0
+    G = GK // q_head_dim
+    if (G < 1 or G * q_head_dim != GK or q_heads % G
             or v_pool.shape != k_pool.shape):
         raise ValueError(
             f"pool/query shape mismatch: q heads x head_dim "
             f"{q_heads}x{q_head_dim}, k_pool {k_pool.shape}, "
-            f"v_pool {v_pool.shape} (want [L, P, page_size, H*K])")
-    return k_pool.shape[2], k_pool.shape[3]
+            f"v_pool {v_pool.shape} (want [L, P, page_size, G*K] with "
+            f"G dividing the query heads)")
+    return k_pool.shape[2], G
 
 
 def _prefetch(layer, scalars, k_scale, v_scale):
@@ -90,12 +101,16 @@ def _prefetch(layer, scalars, k_scale, v_scale):
     return ops
 
 
-def _head_mask(n_heads, head_dim):
-    """[H, H*K] bool: lane c belongs to head r (c // K == r)."""
-    shape = (n_heads, n_heads * head_dim)
-    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+def _head_mask(n_heads, head_dim, n_kv_heads=None):
+    """[H, G*K] bool: lane c belongs to the KV head of query head r
+    (c // K == r // (H/G); with G = H, lane c belongs to head r)."""
+    n_kv_heads = n_kv_heads or n_heads
+    shape = (n_heads, n_kv_heads * head_dim)
+    kv_head = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    if n_kv_heads != n_heads:       # G = H compiles what it always did
+        kv_head = kv_head // (n_heads // n_kv_heads)
     lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-    return (lane >= row * head_dim) & (lane < (row + 1) * head_dim)
+    return (lane >= kv_head * head_dim) & (lane < (kv_head + 1) * head_dim)
 
 
 def _pool_call(kernel, name, q, k_pool, v_pool, prefetch, n_pg, scratch,
@@ -103,10 +118,11 @@ def _pool_call(kernel, name, q, k_pool, v_pool, prefetch, n_pg, scratch,
     """The one pallas_call shape both kernels share: grid (slot, kv page),
     the slot's query rows ``q[b]`` ([rows, H*K]) and its output as one
     block per slot, K and V one page a step at
-    ``(layer, tables[b, j])`` of the whole pool. `prefetch` is
+    ``(layer, tables[b, j])`` of the whole pool (whose minor axis is the
+    KV heads', narrower than the query's under grouped-query attention). `prefetch` is
     `_prefetch`'s tuple (layer first, the page table second)."""
     B, rows, HK = q.shape
-    ps = k_pool.shape[2]
+    ps, GK = k_pool.shape[2], k_pool.shape[3]
     im_q = lambda b, j, *_: (b, 0, 0)
     im_kv = lambda b, j, layer, tbl, *_: (layer[0], tbl[b, j], 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -114,8 +130,8 @@ def _pool_call(kernel, name, q, k_pool, v_pool, prefetch, n_pg, scratch,
         grid=(B, n_pg),
         in_specs=[
             pl.BlockSpec((None, rows, HK), im_q),
-            pl.BlockSpec((None, None, ps, HK), im_kv),
-            pl.BlockSpec((None, None, ps, HK), im_kv),
+            pl.BlockSpec((None, None, ps, GK), im_kv),
+            pl.BlockSpec((None, None, ps, GK), im_kv),
         ],
         out_specs=pl.BlockSpec((None, rows, HK), im_q),
         scratch_shapes=scratch,
@@ -131,7 +147,7 @@ def _pool_call(kernel, name, q, k_pool, v_pool, prefetch, n_pg, scratch,
 
 def _decode_kernel(
     *refs,
-    sm_scale, page_size, n_pg, n_heads, quantized=False,
+    sm_scale, page_size, n_pg, n_heads, n_kv_heads, quantized=False,
 ):
     # Ref order: scalar-prefetch (SMEM) first — layer, page tables, kv
     # lengths, and (quantized pools only) the layer's per-page K/V scale
@@ -150,18 +166,26 @@ def _decode_kernel(
         ks_ref = vs_ref = None
     b = pl.program_id(0)
     j = pl.program_id(1)
-    head_dim = q_ref.shape[-1] // n_heads
+    # Multi-head (G = H): the query is one dense row [1, H*K]. Grouped
+    # (G < H): it is [H, K], a head a row, and the pool's minor axis holds
+    # G heads; row h of the block-diagonal query then sits in the lanes of
+    # KV head h // (H/G), so the two matmuls below are the same.
+    grouped = n_kv_heads != n_heads
+    head_dim = q_ref.shape[-1] if grouped else q_ref.shape[-1] // n_heads
+    mask = lambda: _head_mask(n_heads, head_dim, n_kv_heads)
 
     @pl.when(j == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
-        # Row h = the query with every lane outside head h zeroed (the
-        # select runs in fp32: the mask is built from 32-bit iotas).
-        q = jnp.broadcast_to(q_ref[...].astype(jnp.float32), qbd_ref.shape)
-        qbd_ref[...] = jnp.where(_head_mask(n_heads, head_dim), q,
-                                 0.0).astype(qbd_ref.dtype)
+        # Row h = the query with every lane outside head h's KV head
+        # zeroed (the select runs in fp32: the mask is built from 32-bit
+        # iotas).
+        q = q_ref[...].astype(jnp.float32)
+        q = (jnp.concatenate([q] * n_kv_heads, axis=1) if grouped
+             else jnp.broadcast_to(q, qbd_ref.shape))
+        qbd_ref[...] = jnp.where(mask(), q, 0.0).astype(qbd_ref.dtype)
 
     kv_len = lengths_ref[b]
 
@@ -209,11 +233,16 @@ def _decode_kernel(
     def _finish():
         l = l_ref[:, :1]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        own = jnp.where(_head_mask(n_heads, head_dim),
-                        acc_ref[...] / l_safe, 0.0)
-        # One non-zero row per lane: the sum over rows is the gather of
-        # each head's own block, as one dense [1, H*K] row.
-        o_ref[...] = jnp.sum(own, axis=0, keepdims=True).astype(o_ref.dtype)
+        own = jnp.where(mask(), acc_ref[...] / l_safe, 0.0)
+        if grouped:
+            # One non-zero K-lane block per row: their sum is [H, K].
+            out = sum(own[:, g * head_dim:(g + 1) * head_dim]
+                      for g in range(n_kv_heads))
+        else:
+            # One non-zero row per lane: the sum over rows is the gather
+            # of each head's own block, as one dense [1, H*K] row.
+            out = jnp.sum(own, axis=0, keepdims=True)
+        o_ref[...] = out.astype(o_ref.dtype)
 
 
 def paged_attention(
@@ -233,9 +262,10 @@ def paged_attention(
 
     Args:
       q: [B, H, K] — each slot's current-token query (post-rotary).
-      k_pool, v_pool: [L, P, page_size, H*K] — the WHOLE page pool (row 0
+      k_pool, v_pool: [L, P, page_size, G*K] — the WHOLE page pool (row 0
         of every layer is the reserved null page), read in place at
-        ``(layer, page)``. May be int8 (quantized serving), in which case
+        ``(layer, page)``; G KV heads, each serving H/G query heads
+        (G = H: multi-head). May be int8 (quantized serving), in which case
         ``k_scale``/``v_scale`` must carry the per-page scale planes
         [L, P] — the layer's row rides the scalar-prefetch path next to
         the page table, and each page is dequanted in VMEM right after
@@ -250,7 +280,7 @@ def paged_attention(
     ``reference_paged_attention``).
     """
     B, H, K = q.shape
-    ps, HK = _check_pool(H, K, k_pool, v_pool)
+    ps, G = _check_pool(H, K, k_pool, v_pool)
     n_pg = tables.shape[1]
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(K)
@@ -261,21 +291,22 @@ def paged_attention(
 
     kernel = functools.partial(
         _decode_kernel, sm_scale=sm_scale, page_size=ps, n_pg=n_pg,
-        n_heads=H, quantized=quantized)
+        n_heads=H, n_kv_heads=G, quantized=quantized)
     scratch = [
-        pltpu.VMEM((H, HK), q.dtype),            # block-diagonal query
+        pltpu.VMEM((H, G * K), q.dtype),         # block-diagonal query
         pltpu.VMEM((H, _LANES), jnp.float32),    # m
         pltpu.VMEM((H, _LANES), jnp.float32),    # l
-        pltpu.VMEM((H, HK), jnp.float32),        # acc
+        pltpu.VMEM((H, G * K), jnp.float32),     # acc
     ]
-    out = _pool_call(kernel, "paged_decode_attn", q.reshape(B, 1, HK),
+    out = _pool_call(kernel, "paged_decode_attn",
+                     q.reshape(B, 1, H * K) if G == H else q,
                      k_pool, v_pool, prefetch, n_pg, scratch, interpret)
     return out.reshape(B, H, K)
 
 
 def _prefill_kernel(
     *refs,
-    sm_scale, page_size, n_pg, n_heads, quantized=False,
+    sm_scale, page_size, n_pg, n_heads, n_kv_heads, quantized=False,
 ):
     """Ragged chunked-prefill attention: one query BLOCK (a prompt chunk at
     an arbitrary token offset) against the slot's page pool. The decode
@@ -286,7 +317,8 @@ def _prefill_kernel(
     position), which is what lets the chunk's own K/V be written to the
     pool before the kernel runs and then read back like any earlier page.
     Heads are a static loop over K-lane slices of the [C, H*K] query
-    block, the [ps, H*K] pages and the [C, H*K] accumulator. Ref order
+    block and accumulator and of the [ps, G*K] pages (head h reads KV
+    head h // (H/G)'s lanes). Ref order
     mirrors `_decode_kernel`: scalar-prefetch (layer, tables, offsets,
     lengths, and for int8 pools the per-page K/V scale vectors) first,
     then VMEM blocks; `quantized` dequants each page in VMEM right after
@@ -302,6 +334,10 @@ def _prefill_kernel(
     j = pl.program_id(1)
     C, HK = q_ref.shape
     head_dim = HK // n_heads
+    group = n_heads // n_kv_heads
+
+    def kv_lanes(h):
+        return slice((h // group) * head_dim, (h // group + 1) * head_dim)
 
     @pl.when(j == 0)
     def _init():
@@ -329,8 +365,8 @@ def _prefill_kernel(
         for h in range(n_heads):
             lanes = slice(h * head_dim, (h + 1) * head_dim)
             q = q_ref[:, lanes]              # [C, K]
-            k = k_ref[:, lanes]              # [ps, K]
-            v = v_ref[:, lanes]
+            k = k_ref[:, kv_lanes(h)]        # [ps, K]
+            v = v_ref[:, kv_lanes(h)]
             if quantized:
                 k = k.astype(jnp.float32) * k_sc
                 v = v.astype(jnp.float32) * v_sc
@@ -384,9 +420,9 @@ def paged_prefill_attention(
     Args:
       q: [B, C, H, K] — each slot's chunk of C queries (post-rotary),
         starting at absolute position ``offsets[b]``.
-      k_pool, v_pool: [L, P, page_size, H*K] — the WHOLE page pool, read
+      k_pool, v_pool: [L, P, page_size, G*K] — the WHOLE page pool, read
         in place at ``(layer, page)`` (row 0 of every layer is the
-        reserved null page). May be int8 (quantized serving) with
+        reserved null page); G KV heads under H query heads. May be int8 (quantized serving) with
         ``k_scale``/``v_scale`` [L, P] per-page scale planes, handled
         exactly as in `paged_attention`.
       layer: int32 scalar (traced inside the layer scan).
@@ -402,7 +438,7 @@ def paged_prefill_attention(
     Returns [B, C, H, K] in q.dtype; rows past a slot's valid chunk tokens
     are defined but meaningless (the engine discards them)."""
     B, C, H, K = q.shape
-    ps, HK = _check_pool(H, K, k_pool, v_pool)
+    ps, G = _check_pool(H, K, k_pool, v_pool)
     n_pg = tables.shape[1]
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(K)
@@ -413,13 +449,13 @@ def paged_prefill_attention(
 
     kernel = functools.partial(
         _prefill_kernel, sm_scale=sm_scale, page_size=ps, n_pg=n_pg,
-        n_heads=H, quantized=quantized)
+        n_heads=H, n_kv_heads=G, quantized=quantized)
     scratch = [
         pltpu.VMEM((H, C, _LANES), jnp.float32),  # m
         pltpu.VMEM((H, C, _LANES), jnp.float32),  # l
-        pltpu.VMEM((C, HK), jnp.float32),         # acc
+        pltpu.VMEM((C, H * K), jnp.float32),      # acc
     ]
-    out = _pool_call(kernel, "paged_prefill_attn", q.reshape(B, C, HK),
+    out = _pool_call(kernel, "paged_prefill_attn", q.reshape(B, C, H * K),
                      k_pool, v_pool, prefetch, n_pg, scratch, interpret)
     return out.reshape(B, C, H, K)
 
@@ -435,14 +471,15 @@ def paged_prefill_attention(
 # back host-side by rewinding cursors (models/paged_kv.py
 # verify_chunk_paged documents why the garbage K/V they leave is inert).
 
-def _gather_timeline(k_pool, v_pool, layer, tables, n_heads, k_scale,
-                     v_scale):
+def _gather_timeline(k_pool, v_pool, layer, tables, n_heads, head_dim,
+                     k_scale, v_scale):
     """Each slot's contiguous K and V timelines [B, T, H, K], gathered
     from the whole pool at ``[layer, tables]`` (pages only: no layer's
     plane is cut out) and, for an int8 pool, dequanted exactly as the
     fused kernels do (page.astype(f32) * scale)."""
     B, n_pg = tables.shape
-    ps, HK = k_pool.shape[2], k_pool.shape[3]
+    ps, K = k_pool.shape[2], head_dim
+    G = k_pool.shape[3] // K
     views = []
     for pool, scale in ((k_pool, k_scale), (v_pool, v_scale)):
         view = pool[layer, tables]               # [B, n_pg, ps, H*K]
@@ -450,7 +487,10 @@ def _gather_timeline(k_pool, v_pool, layer, tables, n_heads, k_scale,
             view = (view.astype(jnp.float32)
                     * scale[layer, tables][:, :, None, None].astype(
                         jnp.float32))
-        views.append(view.reshape(B, n_pg * ps, n_heads, HK // n_heads))
+        view = view.reshape(B, n_pg * ps, G, K)
+        # Grouped-query: every query head sees its KV head's timeline.
+        views.append(view if G == n_heads
+                     else jnp.repeat(view, n_heads // G, axis=2))
     return views
 
 
@@ -466,7 +506,7 @@ def reference_paged_attention(q, k_pool, v_pool, layer, tables, lengths, *,
     T = tables.shape[1] * k_pool.shape[2]
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(K)
-    k_view, v_view = _gather_timeline(k_pool, v_pool, layer, tables, H,
+    k_view, v_view = _gather_timeline(k_pool, v_pool, layer, tables, H, K,
                                       k_scale, v_scale)
     s = jnp.einsum("bhk,bthk->bht", q, k_view,
                    preferred_element_type=jnp.float32) * sm_scale
@@ -497,7 +537,7 @@ def reference_paged_prefill_attention(q, k_pool, v_pool, layer, tables,
     T = tables.shape[1] * k_pool.shape[2]
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(K)
-    k_view, v_view = _gather_timeline(k_pool, v_pool, layer, tables, H,
+    k_view, v_view = _gather_timeline(k_pool, v_pool, layer, tables, H, K,
                                       k_scale, v_scale)
     s = jnp.einsum("bchk,bthk->bhct", q, k_view,
                    preferred_element_type=jnp.float32) * sm_scale

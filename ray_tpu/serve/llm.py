@@ -41,7 +41,6 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import functools
 import logging
 import math
 import queue
@@ -471,10 +470,15 @@ class LLMEngine:
         from ray_tpu.models import gpt
         from ray_tpu.models import paged_kv as _paged
         from ray_tpu.models.decode import init_kv_cache
+        from ray_tpu.models.serving import family_of
         from ray_tpu.serve.llm_options import resolve_options
         from ray_tpu.serve.page_pool import PagePool, pages_for
 
         self.cfg = cfg
+        # The device side of this configuration's model family: pool,
+        # programs, sharding rules (models/serving.py). The draft model
+        # of speculative decoding is always a gpt.
+        self._family = fam = family_of(cfg)
         self.n_slots = n_slots
         self.max_len = max_len
         # Clamp buckets to the KV-cache capacity: _bucket() rounds a prompt
@@ -484,8 +488,8 @@ class LLMEngine:
         if not buckets:
             buckets = (max(1, max_len - 1),)
         self.buckets = buckets
-        self.params = params if params is not None else gpt.init_params(
-            cfg, jax.random.key(seed))
+        self.params = (params if params is not None
+                       else fam.model.init_params(cfg, jax.random.key(seed)))
         # Resolution and every refusal: serve/llm_options.py.
         o = resolve_options(
             cfg, max_len=max_len, spec_draft_params=spec_draft_params,
@@ -550,8 +554,8 @@ class LLMEngine:
                 n_pages = max(self.max_pages_per_slot + 1,
                               (n_slots * self.max_pages_per_slot) // 2)
             self.n_pages = n_pages
-            self.cache = _paged.init_paged_kv(cfg, n_pages, page_size,
-                                              kv_dtype=self.kv_dtype)
+            self.cache = fam.init_pool(cfg, n_pages, page_size, n_slots,
+                                       self.kv_dtype)
             self.pool = PagePool(n_pages, page_size, n_slots,
                                  self.max_pages_per_slot)
         else:
@@ -589,7 +593,7 @@ class LLMEngine:
             # BEFORE the tp shard below: the scale rules in
             # gpt.partition_rules shard the new leaves alongside their
             # planes, so quantize-then-shard is the only order.
-            self.params = gpt.quantize_params(self.params)
+            self.params = fam.model.quantize_params(self.params)
             if spec_draft:
                 self.draft_params = gpt.quantize_params(self.draft_params)
         if self.tp > 1:
@@ -600,9 +604,9 @@ class LLMEngine:
             from ray_tpu.models import partition as _partition
 
             self.params = _partition.shard_by_rules(
-                self.mesh, gpt.partition_rules(), self.params)
+                self.mesh, fam.model.partition_rules(), self.params)
             self.cache = _partition.shard_by_rules(
-                self.mesh, _paged.KV_POOL_PARTITION_RULES, self.cache)
+                self.mesh, fam.pool_partition_rules, self.cache)
             if spec_draft:
                 self.draft_params = _partition.shard_by_rules(
                     self.mesh, gpt.partition_rules(), self.draft_params)
@@ -814,7 +818,20 @@ class LLMEngine:
                       # bench pins this at 0 for un-hinted traffic —
                       # warm discovery must ride the routing push, not
                       # per-request GCS RPCs.
-                      "kv_digest_lookups": 0}
+                      "kv_digest_lookups": 0,
+                      # Families with a per-slot state and experts
+                      # (zeros otherwise). Prompt starts that read a
+                      # zeroed slot state; and, from the decode
+                      # programs' on-device counters, pulled with a
+                      # window's tokens: (layer, step) pairs run,
+                      # experts that had a row, the fullest expert's
+                      # rows, rows routed — summed over those pairs.
+                      "slot_state_resets": 0, "moe_layer_steps": 0,
+                      "moe_experts_touched_sum": 0, "moe_rows_max_sum": 0,
+                      "moe_rows_routed": 0}
+        # The decode programs' counters run on, wrapping uint32; the
+        # window's share is the difference from the last pull.
+        self._moe_seen: dict | None = None
 
     def _bind_programs(self) -> None:
         """`self._rt`: the jax / model-fn surface the hot loop touches,
@@ -824,35 +841,50 @@ class LLMEngine:
         (jax_compiles_total{fn}) and recompile churn trips the
         recompile-storm alarm instead of hiding in step-time noise.
 
-        At tp > 1 the paged programs are their shard_map twins
-        (models/paged_kv.py `*_tp`) with the mesh bound as a static
-        kwarg, under the SAME compile-watch names: every call site is
-        unchanged."""
+        The set is the model family's (models/serving.py): for a gpt,
+        models/decode.py and models/paged_kv.py; at tp > 1 the paged
+        programs are their shard_map twins (`*_tp`) with the mesh bound
+        as a static kwarg, under the SAME compile-watch names: every
+        call site is unchanged."""
         import types
 
         import jax
         import jax.numpy as jnp
 
         from ray_tpu import compile_watch as _cw
-        from ray_tpu.models import decode as _decode
-        from ray_tpu.models import paged_kv as _paged
 
         _cw.install()
-        programs = {name: getattr(_decode, name) for name in (
-            "prefill", "prefill_batch", "decode_step", "decode_multi",
-            "sample_token")}
-        programs["prefill_batch_paged"] = _paged.prefill_batch_paged
-        for name in ("prefill_chunk_paged", "verify_chunk_paged",
-                     "decode_step_paged", "decode_multi_paged",
-                     "copy_pages", "gather_pages", "scatter_pages",
-                     "spec_draft_propose"):
-            programs[name] = (
-                getattr(_paged, name) if self.tp == 1 else
-                functools.partial(getattr(_paged, name + "_tp"),
-                                  mesh=self.mesh))
+        programs = self._family.programs(self.tp, self.mesh)
+        # The decode window's extra keyword for a family whose decode
+        # programs count experts' rows in the pool: where the counters
+        # go, fetched with the window's tokens.
+        self._window_counters = (
+            {"counters": self._note_device_counters}
+            if self._family.expert_counters else {})
         self._rt = types.SimpleNamespace(
             jax=jax, jnp=jnp,
             **{name: _cw.wrap(fn, name) for name, fn in programs.items()})
+
+    def _row_slots(self, slots) -> dict:
+        """The chunk program's extra keyword for a family whose pool
+        carries a per-slot state: the slot of each row."""
+        if not self._family.slot_state:
+            return {}
+        return {"slots": self._rt.jnp.asarray(slots)}
+
+    def _note_device_counters(self, totals: dict) -> None:
+        """`totals`: the decode programs' running uint32 counters after a
+        window. The stats take what was added since the last pull (a
+        window of one step pulls nothing: its share arrives with the
+        next window's)."""
+        seen = self._moe_seen or dict.fromkeys(totals, 0)
+        self._moe_seen = totals
+        delta = {k: (v - seen[k]) % (1 << 32) for k, v in totals.items()}
+        with self._lock:
+            self.stats["moe_layer_steps"] += delta["layer_steps"]
+            self.stats["moe_experts_touched_sum"] += delta["experts_touched"]
+            self.stats["moe_rows_max_sum"] += delta["rows_max"]
+            self.stats["moe_rows_routed"] += delta["rows_routed"]
 
     # ------------------------------------------------------------- API
 
@@ -1042,7 +1074,7 @@ class LLMEngine:
                     _x, self.cache = rt.prefill_chunk_paged(
                         self.cfg, self.params, toks, self.cache, tables,
                         zeros, zeros, return_logits=head,
-                        attn_impl=self.attn_impl)
+                        attn_impl=self.attn_impl, **self._row_slots(zeros))
                     n += 1
                 if self.spec_k:
                     # graftlint: disable=GUARDED-BY (pre-spawn, see above)
@@ -1339,9 +1371,14 @@ class LLMEngine:
                 # bytes, scale planes included.
                 m["llm_weight_dtype"] = self.weight_dtype
                 m["llm_kv_dtype"] = self.kv_dtype
+                nbytes = lambda a: int(math.prod(a.shape) * a.dtype.itemsize)
                 m["kv_pool_bytes"] = sum(
-                    int(math.prod(a.shape) * a.dtype.itemsize)
-                    for a in self.cache.values())
+                    nbytes(a) for name, a in self.cache.items()
+                    if name in ("k", "v", "k_scale", "v_scale"))
+                # A family's per-slot state beside the pages (0: none).
+                m["slot_state_bytes"] = (
+                    nbytes(self.cache["slot_state"])
+                    if "slot_state" in self.cache else 0)
             m["weight_bytes"] = sum(
                 int(a.nbytes) for a in self._rt.jax.tree.leaves(self.params))
             m["llm_tp"] = self.tp
@@ -1440,6 +1477,14 @@ class LLMEngine:
                 m["prefill_tokens"] / m["prefill_time_s"])
         if m["slot_cap_sum"] > 0:
             m["slot_occupancy"] = m["slot_step_sum"] / m["slot_cap_sum"]
+        # Per layer and decode step (0 where no expert layer ran): experts
+        # that had a row, and the fullest expert's rows over the mean of
+        # those that had any.
+        pairs = max(1, m["moe_layer_steps"])
+        m["moe_experts_touched"] = m["moe_experts_touched_sum"] / pairs
+        m["moe_rows_max"] = (
+            m["moe_rows_max_sum"] * m["moe_experts_touched_sum"]
+            / max(1, m["moe_rows_routed"]) / pairs)
         return m
 
     _EWMA_ALPHA = 0.2
@@ -2512,12 +2557,14 @@ class LLMEngine:
             offsets = np.zeros(rows, np.int32)
             valid = np.zeros(rows, np.int32)
             tables = np.zeros((rows, width), np.int32)
+            slots = np.zeros(rows, np.int32)
             any_final = False
             t0 = time.perf_counter()
             for i, (slot, req, done, n) in enumerate(batch):
                 toks[i, :n] = req.prompt_ids[done:done + n]
                 offsets[i] = done
                 valid[i] = n
+                slots[i] = slot
                 tables[i] = self.pool.row(slot, width)
                 any_final |= done + n >= len(req.prompt_ids)
                 if req.first_chunk_at is None:
@@ -2528,7 +2575,8 @@ class LLMEngine:
                     self.cfg, self.params, rt.jnp.asarray(toks), self.cache,
                     rt.jnp.asarray(tables), rt.jnp.asarray(offsets),
                     rt.jnp.asarray(valid),
-                    return_logits=any_final, attn_impl=self.attn_impl)
+                    return_logits=any_final, attn_impl=self.attn_impl,
+                    **self._row_slots(slots))
                 if self.spec_k:
                     # Draft prefill mirror: the same [chunk_rows, C] rows
                     # through the draft model into the draft pool (same
@@ -2563,6 +2611,10 @@ class LLMEngine:
             self.stats["prefill_tokens"] += sum(n for *_x, n in batch)
             self.stats["prefill_chunks"] += len(batch)
             self.stats["prefill_dispatches"] += 1
+            if self._family.slot_state:
+                # A row at offset 0 reads zeros for its slot's state.
+                self.stats["slot_state_resets"] += sum(
+                    done == 0 for _s, _r, done, _n in batch)
             self._dispatch_width_ring.append(width)
             self._dispatch_width_counts[width] = (
                 self._dispatch_width_counts.get(width, 0) + 1)
@@ -3072,7 +3124,8 @@ class LLMEngine:
                     toks_out, self.cache = rt.decode_multi_paged(
                         self.cfg, self.params, tokens, self.cache,
                         positions, table_view, k, temps, sub,
-                        attn_impl=self.attn_impl, phase=self._phase)
+                        attn_impl=self.attn_impl, phase=self._phase,
+                        **self._window_counters)
                 else:
                     with self._phase("decode.dispatch"):
                         toks_out, self.cache = rt.decode_multi(

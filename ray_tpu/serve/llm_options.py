@@ -2,9 +2,11 @@
 
 A keyword left at None takes the fleet-wide knob (`runtime_config()`'s
 `llm_*` field, `_KNOBS`). A feature the engine as configured cannot carry
-is settled by `_honour`: the explicit constructor argument raises a typed
-error, the same value arriving from the knob soft-disables — a fleet-wide
-`RAY_TPU_LLM_*` export must not crash the replicas it does not fit.
+— by itself, or with the configuration's model family
+(models/serving.py `ServingFamily.unsupported`) — is settled by `_honour`:
+the explicit constructor argument raises a typed error, the same value
+arriving from the knob soft-disables — a fleet-wide `RAY_TPU_LLM_*`
+export must not crash the replicas it does not fit.
 Errors surface in the order of `resolve_options`' statements.
 """
 
@@ -83,6 +85,7 @@ def resolve_options(cfg, *, max_len: int, spec_draft_params,
     import jax
 
     from ray_tpu.models import gpt
+    from ray_tpu.models.serving import family_of
 
     o = types.SimpleNamespace(**keywords)
     explicit = {kw for kw, _field in _KNOBS if keywords[kw] is not None}
@@ -93,6 +96,16 @@ def resolve_options(cfg, *, max_len: int, spec_draft_params,
         for kw, field in _KNOBS:
             if kw not in explicit:
                 setattr(o, kw, getattr(rc, field))
+
+    # What this configuration's model family cannot carry
+    # (models/serving.py), by the one rule, before the checks that read
+    # kv_mode and prefill_chunk. A pool role asks for the transfer as an
+    # argument does.
+    for miss in family_of(cfg).unsupported:
+        if miss.option == "kv_transfer" and pool_role:
+            raise ValueError(miss.why)
+        _honour(o, explicit, miss.option, miss.fits(o), miss.neutral,
+                miss.why)
 
     def paged_chunked():
         return o.kv_mode == "paged" and o.prefill_chunk

@@ -365,3 +365,98 @@ def test_paged_program_moves_no_pool_layer(chip, opt_serving, program):
     plane_bytes = cfg.n_layers * layer_elems * 2
     assert mem.temp_size_in_bytes < plane_bytes
     assert mem.alias_size_in_bytes >= 2 * plane_bytes
+
+
+# --- grouped-query pages and the zaya family's step programs ------------
+# ZAYA1-8B as `benchmarks/configs/zaya1-8b.json` serves it: 8 query heads
+# over 2 KV heads of 128 (the pool's minor axis is 2 x 128 = 256 lanes),
+# 64 slots, 2,048 pages of 64, table width 32, chunk 128, 24 layers, all
+# 16 experts and the whole 262,272-row tied vocabulary.
+Z_SLOTS, Z_PAGES, Z_H, Z_G, Z_K = 64, 2048, 8, 2, 128
+
+
+def test_grouped_query_kernels_compile_at_head_size_128(chip):
+    """Both paged kernels with G = 2 KV heads under H = 8 query heads of
+    128: the decode kernel takes the query a head a row, the prefill
+    kernel reads head h's page lanes at KV head h // 4."""
+    pool = chip((L, Z_PAGES + 1, PS, Z_G * Z_K), jnp.bfloat16)
+    _compile(lambda q, k, v, l, t, n: paged_attention(
+        q, k, v, l, t, n, interpret=False),
+        chip((Z_SLOTS, Z_H, Z_K), jnp.bfloat16), pool, pool, _layer(chip),
+        chip((Z_SLOTS, 32), jnp.int32), chip((Z_SLOTS,), jnp.int32),
+        kernels=("paged_decode_attn",))
+    _compile(lambda q, k, v, l, t, o, n: paged_prefill_attention(
+        q, k, v, l, t, o, n, interpret=False),
+        chip((2, C, Z_H, Z_K), jnp.bfloat16), pool, pool, _layer(chip),
+        chip((2, 8), jnp.int32), chip((2,), jnp.int32),
+        chip((2,), jnp.int32), kernels=("paged_prefill_attn",))
+
+
+@pytest.fixture(scope="module")
+def zaya_serving(chip):
+    """(cfg, params, pool) of the zaya cell as shapes on one described
+    chip, with the two backend questions steered to the chip's answers."""
+    import importlib
+
+    from ray_tpu.models import zaya
+
+    cfg = zaya.ZayaConfig(n_layers=L)
+    params = {name: chip(spec["shape"], jnp.bfloat16)
+              for name, spec in zaya.param_specs(cfg).items()}
+    pool = jax.tree.map(
+        lambda x: chip(x.shape, x.dtype),
+        jax.eval_shape(lambda: zaya.init_paged_kv(cfg, Z_PAGES, PS,
+                                                  Z_SLOTS)))
+    attn = importlib.import_module("ray_tpu.ops.paged_attention")
+    moe = importlib.import_module("ray_tpu.ops.moe")
+    saved = attn._interpret_default, moe._mixed_dot_default
+    attn._interpret_default = lambda: False
+    moe._mixed_dot_default = lambda: True
+    yield cfg, params, pool
+    attn._interpret_default, moe._mixed_dot_default = saved
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_zaya_program_fits_and_moves_no_expert_layer(chip, zaya_serving,
+                                                     program):
+    """The zaya family's two step programs, compiled whole at the cell's
+    size: the attention kernel and the experts' grouped matmul are in
+    them under the names a trace finds them by; no layer of experts
+    (16 x 2,048 x 2,048 bf16, 134 MB a matrix) and no layer of the pool is
+    copied, sliced out or put back; the donated pool (pages AND slot
+    state) is updated in place; weights + pool + what the program needs
+    besides stay under the chip's 16 GB."""
+    from ray_tpu.models import zaya
+
+    cfg, params, pool = zaya_serving
+    i32 = lambda *shape: chip(shape, jnp.int32)
+    if program == "decode":
+        key = jax.eval_shape(lambda: jax.random.key(0))
+        compiled = zaya._decode_sample_paged.lower(
+            cfg, params, i32(Z_SLOTS), pool, i32(Z_SLOTS),
+            i32(Z_SLOTS, 32), chip((Z_SLOTS,), jnp.float32),
+            chip(key.shape, key.dtype), attn_impl="kernel").compile()
+        kernel = "paged_decode_attn"
+    else:
+        compiled = zaya.prefill_chunk_paged.lower(
+            cfg, params, i32(2, C), pool, i32(2, 8), i32(2), i32(2),
+            slots=i32(2), return_logits=True, attn_impl="kernel").compile()
+        kernel = "paged_prefill_attn"
+    text = compiled.as_text()
+    assert re.search(rf"%\w*{kernel}[\w.]* = [^\n]*custom-call\(", text)
+    # gate, up and down: three grouped matmuls over the WHOLE stack of
+    # 24 x 16 experts, the layer picked by its groups' sizes.
+    assert len(re.findall(r"%ragged-dot[\w.\-]* = [^\n]*custom-call\(",
+                          text)) >= 3
+    assert f"bf16[{L * cfg.n_experts},{cfg.d_model},{cfg.d_ff}]" in text
+    expert_layer = cfg.n_experts * cfg.d_model * cfg.d_ff
+    pool_layer = (Z_PAGES + 1) * PS * Z_G * Z_K
+    moved = _pool_moves(text, "bf16", min(expert_layer, pool_layer))
+    assert not moved, "layer-sized moves:\n" + "\n".join(moved)
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                     for a in pool.values())
+    assert mem.alias_size_in_bytes >= pool_bytes
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 14.0e9 < total < 15.0e9

@@ -1,0 +1,158 @@
+"""ops/moe.py `token_choice_experts`: the no-drop token-choice expert
+layer against a per-token loop, and the parts of chips holding disjoint
+expert ranges against the whole.
+"""
+
+import collections
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.ops.moe import token_choice_experts
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(REPO, "benchmarks")
+if BENCH not in sys.path:
+    sys.path.insert(0, BENCH)
+
+N, D, F, E = 24, 16, 12, 8
+
+
+def _weights(seed=0, n_layers=None):
+    rng = np.random.default_rng(seed)
+    lead = (E,) if n_layers is None else (n_layers, E)
+    mk = lambda *s: jnp.asarray(rng.normal(size=lead + s) * 0.3, jnp.float32)
+    return mk(D, F), mk(D, F), mk(F, D)
+
+
+def _loop(x, ids, gates, w_gate, w_up, w_down, valid=None):
+    """One token at a time, one choice at a time: the definition."""
+    x, ids, gates = (np.asarray(a) for a in (x, ids, gates))
+    ids, gates = ids.reshape(len(x), -1), gates.reshape(len(x), -1)
+    wg, wu, wd = (np.asarray(w, np.float64) for w in (w_gate, w_up, w_down))
+    out = np.zeros((len(x), D))
+    for n in range(len(x)):
+        if valid is not None and not valid[n]:
+            continue
+        for e, g in zip(ids[n], gates[n]):
+            a = x[n].astype(np.float64) @ wg[e]
+            h = a / (1.0 + np.exp(-a)) * (x[n].astype(np.float64) @ wu[e])
+            out[n] += g * (h @ wd[e])
+    return out
+
+
+ROUTINGS = {
+    # forced imbalance: every row to one expert, seven experts with none
+    "all_to_one": lambda rng, n: np.full(n, 5),
+    # an expert with none (3), the rest uneven
+    "one_empty": lambda rng, n: rng.choice([0, 1, 1, 2, 4, 5, 6, 7, 7, 7], n),
+    "uniform": lambda rng, n: rng.integers(0, E, n),
+    "top2": lambda rng, n: np.stack([rng.permutation(E)[:2]
+                                     for _ in range(n)]),
+}
+
+
+# 24 rows and their 8 zero rows fit one 128-row tile; 121 (+ 8, and twice
+# that at top-2) spill into the next and are padded to an odd number of
+# tiles.
+@pytest.mark.parametrize("n_rows", [N, 121])
+@pytest.mark.parametrize("routing", sorted(ROUTINGS))
+def test_matches_a_per_token_loop_and_drops_no_row(routing, n_rows):
+    rng = np.random.default_rng(1)
+    ids = ROUTINGS[routing](rng, n_rows).astype(np.int32)
+    x = jnp.asarray(rng.normal(size=(n_rows, D)), jnp.float32)
+    gates = jnp.asarray(rng.uniform(0.1, 1.0, ids.shape), jnp.float32)
+    w = _weights()
+    with jax.default_matmul_precision("highest"):
+        y, counts = jax.jit(token_choice_experts)(
+            x, jnp.asarray(ids), gates, *w)
+    np.testing.assert_allclose(np.asarray(y), _loop(x, ids, gates, *w),
+                               atol=2e-5)
+    # No capacity: every assignment is counted where it was sent.
+    np.testing.assert_array_equal(
+        np.asarray(counts), np.bincount(ids.reshape(-1), minlength=E))
+    assert int(counts.sum()) == ids.size
+    if routing == "all_to_one":
+        assert int(counts[5]) == n_rows and int((counts > 0).sum()) == 1
+    if routing == "one_empty":
+        assert int(counts[3]) == 0
+
+
+def test_rows_that_carry_no_token_reach_no_expert():
+    rng = np.random.default_rng(2)
+    ids = rng.integers(0, E, N).astype(np.int32)
+    x = jnp.asarray(rng.normal(size=(N, D)), jnp.float32)
+    gates = jnp.ones(N, jnp.float32)
+    valid = rng.uniform(size=N) < 0.5
+    w = _weights()
+    with jax.default_matmul_precision("highest"):
+        y, counts = token_choice_experts(x, jnp.asarray(ids), gates, *w,
+                                         valid=jnp.asarray(valid))
+    np.testing.assert_allclose(np.asarray(y),
+                               _loop(x, ids, gates, *w, valid=valid),
+                               atol=2e-5)
+    assert int(counts.sum()) == int(valid.sum())
+    assert np.all(np.asarray(y)[~valid] == 0.0)
+
+
+def test_the_layer_index_picks_its_experts_out_of_the_whole_stack():
+    """Weights [L, E, ...] and a traced layer index, as the layer scan
+    calls it: the same as that layer's slice."""
+    rng = np.random.default_rng(3)
+    ids = jnp.asarray(rng.integers(0, E, N), jnp.int32)
+    x = jnp.asarray(rng.normal(size=(N, D)), jnp.float32)
+    gates = jnp.asarray(rng.uniform(0.1, 1.0, N), jnp.float32)
+    stack = _weights(seed=4, n_layers=3)
+    with jax.default_matmul_precision("highest"):
+        for layer in range(3):
+            got, _ = jax.jit(token_choice_experts)(
+                x, ids, gates, *stack, layer=jnp.int32(layer))
+            want, _ = token_choice_experts(
+                x, ids, gates, *(w[layer] for w in stack))
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       atol=1e-6)
+
+
+def test_the_parts_of_two_chips_add_up_to_the_references_layer():
+    """A share tied to the whole: the layer holding experts 0-7 and the
+    layer holding experts 8-15 each return only their part, and the two
+    parts add up to the uncut plain reference's expert sublayer
+    (zaya_ref._experts: router, top-1 and experts, float32)."""
+    from harness.reference import zaya_ref
+    from ray_tpu.models import zaya
+
+    cfg = zaya.ZayaConfig.tiny(dtype=jnp.float32, n_experts=16, n_layers=1)
+    params = zaya.init_params(cfg, jax.random.key(0))
+    layer = {k: v[0] for k, v in params.items()
+             if k not in ("wte", "ln_f_scale")}
+    layer["w_down"] = layer["w_down"] * 500.0       # an output to compare
+    rng = np.random.default_rng(5)
+    x = jnp.asarray(rng.normal(size=(40, cfg.d_model)), jnp.float32)
+    r = jnp.asarray(rng.normal(size=(40, cfg.router_dim)), jnp.float32)
+    RC = collections.namedtuple("RC", "n_heads n_kv_heads norm_eps")
+    with jax.default_matmul_precision("highest"):
+        whole, r_ref = zaya_ref._experts(
+            x, r, layer, RC(cfg.n_heads, cfg.n_kv_heads, cfg.norm_eps))
+        u = zaya._rms_norm(x, layer["ln2_scale"], cfg.norm_eps)
+        expert, gate, r_got = zaya._route(cfg, layer, u, r)
+        parts, counts = [], []
+        for lo in (0, 8):
+            held = (layer[k][lo:lo + 8] for k in ("w_gate", "w_up", "w_down"))
+            y, c = token_choice_experts(u, expert, gate, *held,
+                                        first_expert=lo)
+            parts.append(np.asarray(y))
+            counts.append(int(c.sum()))
+    assert sum(counts) == 40 and min(counts) > 0    # both chips had rows
+    assert np.abs(whole).max() > 0.05
+    assert np.abs(parts[0]).max() > 0 and np.abs(parts[1]).max() > 0
+    np.testing.assert_allclose(parts[0] + parts[1], np.asarray(whole),
+                               atol=2e-6)
+    np.testing.assert_allclose(np.asarray(r_got), np.asarray(r_ref),
+                               atol=2e-6)
+    # a row's output comes from ONE chip
+    assert not np.any((np.abs(parts[0]).max(axis=1) > 0)
+                      & (np.abs(parts[1]).max(axis=1) > 0))

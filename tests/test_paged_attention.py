@@ -73,16 +73,26 @@ def _pool_and_tables(rng, *, B, H, K, ps, n_pg, dtype):
     return k_pool, v_pool, jnp.asarray(tables), jnp.asarray(lengths)
 
 
+# (query heads H, KV heads G, head size K): multi-head, and grouped-query
+# at the served head size of 128 (G of H: each KV head serves H/G heads).
+HEADS = [(4, 4, 16), (8, 2, 128), (4, 1, 128)]
+
+
+@pytest.mark.parametrize("n_pg", [3, 4, 6])
+@pytest.mark.parametrize("heads", HEADS)
 @pytest.mark.parametrize("ps", [16, 64])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_kernel_matches_gather_reference(ps, dtype):
+def test_kernel_matches_gather_reference(ps, dtype, heads, n_pg):
     """Kernel against oracle at EVERY layer of one pool: the layer index
-    is part of the block index, and layers hold different values."""
+    is part of the block index, and layers hold different values. Under
+    grouped-query attention the pool holds G heads ([L, P, ps, G*K]);
+    table widths 3, 4 and 6."""
     rng = np.random.default_rng(0)
-    B, H, K, n_pg = 5, 4, 16, 3
+    (H, G, K), B = heads, 5
     q = jnp.asarray(rng.normal(size=(B, H, K)), dtype)
     k_pool, v_pool, tables, lengths = _pool_and_tables(
-        rng, B=B, H=H, K=K, ps=ps, n_pg=n_pg, dtype=dtype)
+        rng, B=B, H=G, K=K, ps=ps, n_pg=n_pg, dtype=dtype)
+    assert k_pool.shape[-1] == G * K
     atol = 2e-6 if dtype == jnp.float32 else 3e-2
     outs = []
     for layer in range(N_LAYERS):
@@ -147,18 +157,20 @@ def test_int8_kernel_matches_gather_reference_at_every_layer():
                                    atol=5e-6)
 
 
+@pytest.mark.parametrize("heads", [(2, 2), (8, 2)])
 @pytest.mark.parametrize("kv", ["float32", "bfloat16", "int8"])
-@pytest.mark.parametrize("head_dim", [16, 64, 256])
-def test_prefill_kernel_matches_gather_reference(kv, head_dim):
+@pytest.mark.parametrize("head_dim", [16, 64, 128, 256])
+def test_prefill_kernel_matches_gather_reference(kv, head_dim, heads):
     """The chunk kernel against its oracle on one shared pool, at a layer
     other than 0: a chunk ending mid-page, one starting at 0, one whose
-    table has a null tail, and an inert row (no valid token)."""
+    table has a null tail, and an inert row (no valid token). `heads` =
+    (H, G): G KV heads in the pool under H query heads."""
     rng = np.random.default_rng(4)
-    B, H, ps, n_pg, C = 4, 2, 8, 4, 6
+    (H, G), B, ps, n_pg, C = heads, 4, 8, 4, 6
     dtype = jnp.bfloat16 if kv == "bfloat16" else jnp.float32
     q = jnp.asarray(rng.normal(size=(B, C, H, head_dim)), dtype)
     k_pool, v_pool, _t, _n = _pool_and_tables(
-        rng, B=B, H=H, K=head_dim, ps=ps, n_pg=n_pg, dtype=dtype)
+        rng, B=B, H=G, K=head_dim, ps=ps, n_pg=n_pg, dtype=dtype)
     tables = jnp.asarray(
         [[1, 2, 3, 4], [5, 0, 0, 0], [6, 7, 0, 0], [0, 0, 0, 0]], jnp.int32)
     offsets = jnp.asarray([21, 0, 7, 0], jnp.int32)
