@@ -16,10 +16,13 @@ Design notes:
   ``[B, n_pg]`` and per-slot kv lengths ``[B]`` land in SMEM before the
   body runs, so the K/V BlockSpec index map selects block
   ``(layer, tables[b, j], 0, 0)`` — layer and page id ARE the block
-  index into the pool. Each grid step DMAs exactly one page, as
-  ``page_size`` dense rows of ``H*K`` lanes (2,048 at OPT-1.3B): the
-  minor axis is a multiple of 128 lanes for every served model, so the
-  chip's own layout of the pool is row-major and nothing re-lays it out.
+  index into the pool. A page arrives by its own DMA, as ``page_size``
+  dense rows of ``H*K`` lanes (2,048 at OPT-1.3B): the minor axis is a
+  multiple of 128 lanes for every served model, so the chip's own
+  layout of the pool is row-major and nothing re-lays it out. A decode
+  grid step takes one page; a prefill grid step takes a BLOCK of
+  `prefill_block_pages` consecutive table columns (4 pages = 256 keys at
+  both served shapes), the pool handed over once a column of the block.
 - The per-head split happens in VMEM, after the read. Decode: the
   slot's query row ``[1, H*K]`` is spread into a block-diagonal
   ``[H, H*K]`` (row h keeps head h's K lanes, zeros elsewhere), so
@@ -27,7 +30,12 @@ Design notes:
   exactly 0.0 to the fp32 accumulation), ``PV`` is one matmul into a
   ``[H, H*K]`` accumulator, and the head-h block of row h is what the
   output keeps. Prefill: a static loop over heads, each on its K-lane
-  slice of the query chunk, the page and the accumulator.
+  slice of the query chunk and the accumulator and on ONE stack of the
+  block's pages' K-lane slices, so a head pays one QKᵀ, one softmax
+  update and one PV a block of keys, not a page: at a page a step the
+  head's fixed work (a lane reduction for the row maximum, the state's
+  read and write, the accumulator's rescale) was ~25x what its 64 keys
+  cost the MXU (PERF.md, PR 34).
 - Grouped-query attention: the pool's minor axis is ``G*K`` for G KV
   heads, each serving H/G query heads (G = H above). Decode takes the
   query as ``[H, K]`` and puts row h into the lanes of KV head
@@ -36,12 +44,18 @@ Design notes:
   it always ran.
 - Online-softmax state (m, l, acc) lives in VMEM scratch across the kv
   dimension ("arbitrary" grid semantics), exactly like the flash kernel.
+  The prefill kernel keeps m lane-uniform and uses it at full width, and
+  keeps l as a partial sum a lane that is summed over lanes once, after
+  the last block: cutting a [C, 1] column out of the state and spreading
+  it again, and a second lane reduction a block, were most of a head's
+  time once the block was wide.
 - Null / past-length pages: unallocated table tail entries are 0 (the
   reserved null page, models/paged_kv.py), so their index maps repeat
   one block and Pallas's revisit elision fetches it at most once;
-  ``pl.when(j*ps < len)`` skips their compute entirely. In-page
-  raggedness (a slot ending mid-page) is position-masked like the flash
-  kernel's kv_len mask.
+  ``pl.when(j*ps < len)`` skips their compute entirely (prefill: a block
+  whose first key is past the length). In-page raggedness (a slot ending
+  mid-page) and the dead columns of a live prefill block are
+  position-masked like the flash kernel's kv_len mask.
 - Softmax statistics stay fp32; the QKᵀ/PV contractions run in the input
   dtype with fp32 accumulate (MXU fast path — upcasting operands would
   drop the MXU into its ~4x slower fp32 mode).
@@ -114,25 +128,33 @@ def _head_mask(n_heads, head_dim, n_kv_heads=None):
 
 
 def _pool_call(kernel, name, q, k_pool, v_pool, prefetch, n_pg, scratch,
-               interpret):
-    """The one pallas_call shape both kernels share: grid (slot, kv page),
+               interpret, block_pages=1):
+    """The one pallas_call shape both kernels share: grid (slot, kv block),
     the slot's query rows ``q[b]`` ([rows, H*K]) and its output as one
-    block per slot, K and V one page a step at
-    ``(layer, tables[b, j])`` of the whole pool (whose minor axis is the
-    KV heads', narrower than the query's under grouped-query attention). `prefetch` is
-    `_prefetch`'s tuple (layer first, the page table second)."""
+    block per slot, K and V a page at ``(layer, tables[b, ·])`` of the
+    whole pool (whose minor axis is the KV heads', narrower than the
+    query's under grouped-query attention). A kv block is `block_pages`
+    consecutive table columns: the pool is handed over once a column of
+    the block (K's pages, then V's), so each page is still its own DMA
+    and the kernel sees `block_pages` page refs a pool; `n_pg` must be a
+    multiple of it. `prefetch` is `_prefetch`'s tuple (layer first, the
+    page table second)."""
     B, rows, HK = q.shape
     ps, GK = k_pool.shape[2], k_pool.shape[3]
+    n = block_pages
     im_q = lambda b, j, *_: (b, 0, 0)
-    im_kv = lambda b, j, layer, tbl, *_: (layer[0], tbl[b, j], 0, 0)
+
+    def im_kv(i):
+        if n == 1:      # decode: the index map it always had
+            return lambda b, j, layer, tbl, *_: (layer[0], tbl[b, j], 0, 0)
+        return lambda b, j, layer, tbl, *_: (
+            layer[0], tbl[b, j * n + i], 0, 0)
+
+    pages = [pl.BlockSpec((None, None, ps, GK), im_kv(i)) for i in range(n)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
-        grid=(B, n_pg),
-        in_specs=[
-            pl.BlockSpec((None, rows, HK), im_q),
-            pl.BlockSpec((None, None, ps, GK), im_kv),
-            pl.BlockSpec((None, None, ps, GK), im_kv),
-        ],
+        grid=(B, n_pg // n),
+        in_specs=[pl.BlockSpec((None, rows, HK), im_q)] + pages + pages,
         out_specs=pl.BlockSpec((None, rows, HK), im_q),
         scratch_shapes=scratch,
     )
@@ -142,7 +164,7 @@ def _pool_call(kernel, name, q, k_pool, v_pool, prefetch, n_pg, scratch,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
         name=name,
-    )(*prefetch, q, k_pool, v_pool)
+    )(*prefetch, q, *([k_pool] * n), *([v_pool] * n))
 
 
 def _decode_kernel(
@@ -304,40 +326,94 @@ def paged_attention(
     return out.reshape(B, H, K)
 
 
+# The prefill kernel's kv block. A block under ~256 keys leaves the two
+# matmuls of a head at a fraction of an MXU tile and pays the head's fixed
+# work (softmax-state update, accumulator rescale) once a 64-key page;
+# the flash kernel beside this one (ops/attention.py) never goes under
+# 128 keys. The budget is what the blocks may take of the 16 MiB of VMEM
+# a kernel gets by default on the chip, beside q, out and the state.
+_PREFILL_BLOCK_KEYS = 256
+_PREFILL_VMEM_BUDGET = 12 * 2**20
+
+
+def prefill_block_pages(n_pg, page_size, kv_lanes, kv_itemsize, chunk,
+                        q_lanes, q_itemsize, n_heads) -> int:
+    """Table columns one grid step of the prefill kernel attends: the
+    largest power of two that is at most `n_pg`, keeps the block at or
+    under `_PREFILL_BLOCK_KEYS` keys, and whose K and V blocks
+    (`kv_lanes` = G*K wide, double-buffered by the pipeline) fit
+    `_PREFILL_VMEM_BUDGET` beside the query and output blocks ([chunk,
+    `q_lanes`], double-buffered), the f32 accumulator and the (m, l)
+    state of `n_heads` heads. Pure in the shapes: the engine's
+    `prefill_block_fill` counter and the kernel ask it the same
+    question."""
+    fixed = (4 * chunk * q_lanes * q_itemsize + 4 * chunk * q_lanes
+             + 2 * n_heads * chunk * _LANES * 4)
+    page = 2 * 2 * page_size * kv_lanes * kv_itemsize   # K, V; two buffers
+    n = 1
+    while (2 * n <= n_pg and 2 * n * page_size <= _PREFILL_BLOCK_KEYS
+           and fixed + 2 * n * page <= _PREFILL_VMEM_BUDGET):
+        n *= 2
+    return n
+
+
+def _spread(x, width, start=0):
+    """[C, width] of a lane-uniform [C, LANES] array, taken at lane
+    `start` where that keeps it aligned with what it meets."""
+    if start + width <= _LANES:
+        return x[:, start:start + width]
+    if width % _LANES == 0:
+        return jnp.concatenate([x] * (width // _LANES), axis=1)
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], width))
+
+
+def _fold(p):
+    """[C, LANES] whose lanes sum to the rows' sums of p [C, width]: the
+    LANES-wide pieces added up (the sum over lanes itself waits for the
+    last kv block, once a row and head, not once a block)."""
+    width = p.shape[1]
+    if width % _LANES:
+        total = jnp.sum(p, axis=1, keepdims=True)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (p.shape[0], _LANES), 1)
+        return jnp.where(lane == 0, total, 0.0)
+    return sum(p[:, i:i + _LANES] for i in range(0, width, _LANES))
+
+
 def _prefill_kernel(
     *refs,
-    sm_scale, page_size, n_pg, n_heads, n_kv_heads, quantized=False,
+    sm_scale, page_size, block_pages, n_heads, n_kv_heads, quantized=False,
 ):
     """Ragged chunked-prefill attention: one query BLOCK (a prompt chunk at
     an arbitrary token offset) against the slot's page pool. The decode
     kernel's twin with a C-sized query dimension: same scalar-prefetch
     layer and page table (they ARE the DMA block index), same
-    online-softmax (m, l, acc) VMEM state across the kv-page grid axis —
+    online-softmax (m, l, acc) VMEM state across the kv grid axis —
     plus the causal mask INSIDE the chunk (tpos <= query's absolute
     position), which is what lets the chunk's own K/V be written to the
     pool before the kernel runs and then read back like any earlier page.
-    Heads are a static loop over K-lane slices of the [C, H*K] query
-    block and accumulator and of the [ps, G*K] pages (head h reads KV
-    head h // (H/G)'s lanes). Ref order
-    mirrors `_decode_kernel`: scalar-prefetch (layer, tables, offsets,
-    lengths, and for int8 pools the per-page K/V scale vectors) first,
-    then VMEM blocks; `quantized` dequants each page in VMEM right after
-    its DMA."""
-    if quantized:
-        (_layer_ref, tables_ref, offsets_ref, lengths_ref, ks_ref, vs_ref,
-         q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref) = refs
-    else:
-        (_layer_ref, tables_ref, offsets_ref, lengths_ref, q_ref, k_ref,
-         v_ref, o_ref, m_ref, l_ref, acc_ref) = refs
-        ks_ref = vs_ref = None
+    A grid step attends a BLOCK of `block_pages` consecutive table
+    columns: their pages arrive as that many [ps, G*K] refs a pool, and
+    a KV head's K-lane slices of them are stacked into one
+    [block_pages*ps, K] operand, so a head does one QKᵀ, one softmax
+    update and one PV a block. Heads are a static loop over K-lane
+    slices of the [C, H*K] query block and accumulator (head h reads KV
+    head h // (H/G)'s stack). Ref order mirrors `_decode_kernel`:
+    scalar-prefetch (layer, tables, offsets, lengths, and for int8 pools
+    the per-page K/V scale vectors) first, then VMEM blocks; `quantized`
+    dequants each page of the block by its own scale as it is stacked."""
+    n = block_pages
+    refs = iter(refs)
+    take = lambda count: [next(refs) for _ in range(count)]
+    _layer_ref, tables_ref, offsets_ref, lengths_ref = take(4)
+    ks_ref, vs_ref = take(2) if quantized else (None, None)
+    (q_ref,), k_refs, v_refs = take(1), take(n), take(n)
+    o_ref, m_ref, l_ref, acc_ref = refs
     b = pl.program_id(0)
     j = pl.program_id(1)
     C, HK = q_ref.shape
     head_dim = HK // n_heads
     group = n_heads // n_kv_heads
-
-    def kv_lanes(h):
-        return slice((h // group) * head_dim, (h // group + 1) * head_dim)
+    block = n * page_size
 
     @pl.when(j == 0)
     def _init():
@@ -354,48 +430,63 @@ def _prefill_kernel(
         # additionally masks pad rows (c >= this chunk's valid tokens,
         # whose absolute position runs past kv_len) to the valid prefix so
         # their softmax stays finite; their output is discarded host-side.
-        tpos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (C, page_size), 1)
-        qpos = q_off + jax.lax.broadcasted_iota(
-            jnp.int32, (C, page_size), 0)
+        # A dead column inside a live block is the null page, whose
+        # positions are past kv_len like any other.
+        tpos = j * block + jax.lax.broadcasted_iota(
+            jnp.int32, (C, block), 1)
+        qpos = q_off + jax.lax.broadcasted_iota(jnp.int32, (C, block), 0)
         visible = (tpos <= qpos) & (tpos < kv_len)
+        k_sc = v_sc = [None] * n
         if quantized:
-            page = tables_ref[b, j]
-            k_sc, v_sc = ks_ref[page], vs_ref[page]
-        for h in range(n_heads):
-            lanes = slice(h * head_dim, (h + 1) * head_dim)
-            q = q_ref[:, lanes]              # [C, K]
-            k = k_ref[:, kv_lanes(h)]        # [ps, K]
-            v = v_ref[:, kv_lanes(h)]
-            if quantized:
-                k = k.astype(jnp.float32) * k_sc
-                v = v.astype(jnp.float32) * v_sc
-                q = q.astype(jnp.float32)
-            s = jax.lax.dot_general(
-                q, k, _NT, preferred_element_type=jnp.float32) * sm_scale
-            s = jnp.where(visible, s, NEG_INF)
+            pages = [tables_ref[b, j * n + i] for i in range(n)]
+            k_sc = [ks_ref[p] for p in pages]
+            v_sc = [vs_ref[p] for p in pages]
 
-            m_prev = m_ref[h]                # [C, LANES] (uniform lanes)
-            row_max = jnp.max(s, axis=1, keepdims=True)      # [C, 1]
-            m_new = jnp.maximum(m_prev, row_max)
-            p = jnp.exp(s - m_new[:, :1])    # [C, ps] fp32
-            corr = jnp.exp(m_prev[:, :1] - m_new[:, :1])
-            l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=1, keepdims=True)
-            pv = jnp.dot(p.astype(v.dtype), v,
-                         preferred_element_type=jnp.float32)  # [C, K]
-            acc_ref[:, lanes] = acc_ref[:, lanes] * corr + pv
-            m_ref[h] = m_new
+        def stack(page_refs, lanes, scales):
+            parts = [r[:, lanes] if sc is None
+                     else r[:, lanes].astype(jnp.float32) * sc
+                     for r, sc in zip(page_refs, scales)]     # n x [ps, K]
+            return parts[0] if n == 1 else jnp.concatenate(parts, axis=0)
 
-    # Pages entirely past the chunk's last valid position do no compute
+        for g in range(n_kv_heads):
+            kv_lanes = slice(g * head_dim, (g + 1) * head_dim)
+            k = stack(k_refs, kv_lanes, k_sc)
+            v = stack(v_refs, kv_lanes, v_sc)
+            for h in range(g * group, (g + 1) * group):
+                lanes = slice(h * head_dim, (h + 1) * head_dim)
+                q = q_ref[:, lanes]              # [C, K]
+                if quantized:
+                    q = q.astype(jnp.float32)
+                s = jax.lax.dot_general(
+                    q, k, _NT, preferred_element_type=jnp.float32) * sm_scale
+                s = jnp.where(visible, s, NEG_INF)
+
+                # The state is lane-uniform [C, LANES] (m) and a partial
+                # sum a lane (l), used at full width: a [C, 1] column cut
+                # out of it and spread again costs more than the matmuls.
+                m_prev = m_ref[h]
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=1, keepdims=True))
+                p = jnp.exp(s - _spread(m_new, block))   # [C, block] fp32
+                corr = jnp.exp(m_prev - m_new)           # [C, LANES]
+                l_ref[h] = l_ref[h] * corr + _fold(p)
+                pv = jnp.dot(p.astype(v.dtype), v,
+                             preferred_element_type=jnp.float32)  # [C, K]
+                acc_ref[:, lanes] = (
+                    acc_ref[:, lanes]
+                    * _spread(corr, head_dim, lanes.start % _LANES) + pv)
+                m_ref[h] = m_new
+
+    # Blocks entirely past the chunk's last valid position do no compute
     # (null-table tail included; its repeated block index also elides
     # the DMA after the first fetch).
-    pl.when(j * page_size < kv_len)(_compute)
+    pl.when(j * block < kv_len)(_compute)
 
-    @pl.when(j == n_pg - 1)
+    @pl.when(j == pl.num_programs(1) - 1)
     def _finish():
         for h in range(n_heads):
             lanes = slice(h * head_dim, (h + 1) * head_dim)
-            l = l_ref[h][:, :1]
+            l = jnp.sum(l_ref[h], axis=1, keepdims=True)
             l_safe = jnp.where(l == 0.0, 1.0, l)
             o_ref[:, lanes] = (acc_ref[:, lanes] / l_safe).astype(
                 o_ref.dtype)
@@ -428,10 +519,13 @@ def paged_prefill_attention(
       layer: int32 scalar (traced inside the layer scan).
       tables: [B, n_pg] int32 page ids per slot (unallocated tail = 0).
         n_pg may be a WIDTH-SLICED view of the engine's full page table
-        (the pow-2 bucket covering each row's written prefix + chunk):
-        the grid is (B, n_pg), so compute and pool-page bytes scale with
-        the sliced width — interior chunks of a long-max-len prompt pay
-        for the prefix they attend over, not for max_pages.
+        (the pow-2 bucket covering each row's written prefix + chunk).
+        The grid is (B, ceil(n_pg / n)) with n = `prefill_block_pages`
+        table columns a step (a width n does not divide is padded with
+        null columns here), so compute and pool-page bytes scale with
+        the sliced width in blocks of n pages — interior chunks of a
+        long-max-len prompt pay for the prefix they attend over, not for
+        max_pages.
       offsets: [B] int32 absolute position of q[:, 0].
       lengths: [B] int32 valid kv positions per slot (= offset + valid
         chunk tokens; must satisfy lengths[b] <= n_pg * page_size).
@@ -445,10 +539,15 @@ def paged_prefill_attention(
     if interpret is None:
         interpret = _interpret_default()
     quantized = k_scale is not None
+    n = prefill_block_pages(n_pg, ps, G * K, k_pool.dtype.itemsize, C,
+                            H * K, q.dtype.itemsize, H)
+    if n_pg % n:
+        # Null columns: position-masked like any dead column of a block.
+        tables = jnp.pad(tables, ((0, 0), (0, -n_pg % n)))
     prefetch = _prefetch(layer, (tables, offsets, lengths), k_scale, v_scale)
 
     kernel = functools.partial(
-        _prefill_kernel, sm_scale=sm_scale, page_size=ps, n_pg=n_pg,
+        _prefill_kernel, sm_scale=sm_scale, page_size=ps, block_pages=n,
         n_heads=H, n_kv_heads=G, quantized=quantized)
     scratch = [
         pltpu.VMEM((H, C, _LANES), jnp.float32),  # m
@@ -456,7 +555,8 @@ def paged_prefill_attention(
         pltpu.VMEM((C, H * K), jnp.float32),      # acc
     ]
     out = _pool_call(kernel, "paged_prefill_attn", q.reshape(B, C, H * K),
-                     k_pool, v_pool, prefetch, n_pg, scratch, interpret)
+                     k_pool, v_pool, prefetch, tables.shape[1], scratch,
+                     interpret, block_pages=n)
     return out.reshape(B, C, H, K)
 
 

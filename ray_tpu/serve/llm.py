@@ -721,6 +721,8 @@ class LLMEngine:
         self._dispatch_width_ring: "collections.deque[int]" = (
             collections.deque(maxlen=4096))
         self._dispatch_width_counts: dict[int, int] = {}
+        # Table width -> pages a grid step of the prefill kernel attends.
+        self._block_pages_at: dict[int, int] = {}
         self._rng_key = jax.random.key(seed)
         # Per-token decode step times (window wall time / window size),
         # milliseconds — a bounded ring so metrics() can report p50/p95
@@ -790,6 +792,10 @@ class LLMEngine:
                       # the window's steps): prefill_tokens over it is
                       # `prefill_allowance_used`.
                       "prefill_allowance": 0,
+                      # Pages the chunk rows attended, and pages the
+                      # kernel's live kv blocks held for them
+                      # (`prefill_block_fill`).
+                      "prefill_pages_live": 0, "prefill_pages_fetched": 0,
                       "decode_time_s": 0.0, "decode_windows": 0,
                       "slot_step_sum": 0, "slot_cap_sum": 0,
                       "preemptions": 0,
@@ -1397,6 +1403,11 @@ class LLMEngine:
                 # (or the work), not the budget, bounds prefill.
                 m["prefill_allowance_used"] = m["prefill_tokens"] / max(
                     1, m["prefill_allowance"])
+                # Live pages attended over the pages the prefill
+                # kernel's live kv blocks fetched: under 1.0 a block's
+                # tail was null or not yet written.
+                m["prefill_block_fill"] = m["prefill_pages_live"] / max(
+                    1, m["prefill_pages_fetched"])
                 m["prefilling_slots"] = len(self._prefilling)
                 m["prefill_width_bucketing"] = self.prefill_width_bucketing
                 if self._dispatch_width_ring:
@@ -2492,6 +2503,23 @@ class LLMEngine:
         return min(_pow2_width(self.pool.pages_for(done + n - 1)),
                    self.max_pages_per_slot)
 
+    def _prefill_block_pages(self, width: int) -> int:
+        """Table columns a grid step of the prefill kernel attends in a
+        dispatch of this width: the kernel's own rule
+        (ops/paged_attention.prefill_block_pages), asked once a width
+        with the shapes the kernel sees (a tp shard's heads)."""
+        if width not in self._block_pages_at:
+            from ray_tpu.ops.paged_attention import prefill_block_pages
+
+            pool = self.cache["k"]
+            heads = self.cfg.n_heads // self.tp
+            self._block_pages_at[width] = prefill_block_pages(
+                width, self.page_size, pool.shape[3] // self.tp,
+                pool.dtype.itemsize, self.prefill_chunk,
+                heads * self.cfg.head_dim,
+                np.dtype(self.cfg.dtype).itemsize, heads)
+        return self._block_pages_at[width]
+
     def _dispatch_chunks(self, batch) -> None:
         """Width-bucketed chunk dispatch: group the TICK's chunk rows by
         the pow-2 page width each row actually attends over
@@ -2611,6 +2639,11 @@ class LLMEngine:
             self.stats["prefill_tokens"] += sum(n for *_x, n in batch)
             self.stats["prefill_chunks"] += len(batch)
             self.stats["prefill_dispatches"] += 1
+            block = self._prefill_block_pages(width)
+            for _s, _r, done, n in batch:
+                live = self.pool.pages_for(done + n - 1)
+                self.stats["prefill_pages_live"] += live
+                self.stats["prefill_pages_fetched"] += -(-live // block) * block
             if self._family.slot_state:
                 # A row at offset 0 reads zeros for its slot's state.
                 self.stats["slot_state_resets"] += sum(

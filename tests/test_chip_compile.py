@@ -130,16 +130,22 @@ def test_decode_kernel_compiles_at_head_dim_256(chip):
         kernels=("paged_decode_attn",))
 
 
-@pytest.mark.parametrize("chunk", [C, 5], ids=["prefill128", "verify5"])
-def test_prefill_kernel_compiles(chip, chunk):
-    """Chunked prefill (C=128) and the speculative-verify row (C=k+1=5),
-    which is the same kernel."""
+@pytest.mark.parametrize("chunk,rows,width", [
+    *[(C, 2, width) for width in (2, 4, 8, 16, 32)], (C, B, N_PG),
+    (5, B, N_PG)],
+    ids=["w2", "w4", "w8", "w16", "w32", "prefill128", "verify5"])
+def test_prefill_kernel_compiles(chip, chunk, rows, width):
+    """Chunked prefill (C=128) at every table width of `opt-1.3b.batch`'s
+    ladder, two rows a program as the cell dispatches it (the kv block is
+    min(4, width) pages: 1 MB each of K and V at width >= 4,
+    double-buffered), at 16 rows, and the speculative-verify row
+    (C=k+1=5), which is the same kernel."""
     _compile(lambda q, k, v, l, t, o, n: paged_prefill_attention(
         q, k, v, l, t, o, n, interpret=False),
-        chip((B, chunk, H, K), jnp.bfloat16), _pool(chip, jnp.bfloat16),
-        _pool(chip, jnp.bfloat16), _layer(chip), chip((B, N_PG), jnp.int32),
-        chip((B,), jnp.int32), chip((B,), jnp.int32),
-        kernels=("paged_prefill_attn",))
+        chip((rows, chunk, H, K), jnp.bfloat16), _pool(chip, jnp.bfloat16),
+        _pool(chip, jnp.bfloat16), _layer(chip),
+        chip((rows, width), jnp.int32), chip((rows,), jnp.int32),
+        chip((rows,), jnp.int32), kernels=("paged_prefill_attn",))
 
 
 @pytest.mark.parametrize("heads,block", [(12, 512), (12, 1024), (32, 1024)],
@@ -378,7 +384,9 @@ Z_SLOTS, Z_PAGES, Z_H, Z_G, Z_K = 64, 2048, 8, 2, 128
 def test_grouped_query_kernels_compile_at_head_size_128(chip):
     """Both paged kernels with G = 2 KV heads under H = 8 query heads of
     128: the decode kernel takes the query a head a row, the prefill
-    kernel reads head h's page lanes at KV head h // 4."""
+    kernel reads head h's page lanes at KV head h // 4, at the one table
+    width `zaya1-8b.reason` dispatches (32: its family turns the width
+    buckets off)."""
     pool = chip((L, Z_PAGES + 1, PS, Z_G * Z_K), jnp.bfloat16)
     _compile(lambda q, k, v, l, t, n: paged_attention(
         q, k, v, l, t, n, interpret=False),
@@ -388,7 +396,7 @@ def test_grouped_query_kernels_compile_at_head_size_128(chip):
     _compile(lambda q, k, v, l, t, o, n: paged_prefill_attention(
         q, k, v, l, t, o, n, interpret=False),
         chip((2, C, Z_H, Z_K), jnp.bfloat16), pool, pool, _layer(chip),
-        chip((2, 8), jnp.int32), chip((2,), jnp.int32),
+        chip((2, 32), jnp.int32), chip((2,), jnp.int32),
         chip((2,), jnp.int32), kernels=("paged_prefill_attn",))
 
 
@@ -439,7 +447,7 @@ def test_zaya_program_fits_and_moves_no_expert_layer(chip, zaya_serving,
         kernel = "paged_decode_attn"
     else:
         compiled = zaya.prefill_chunk_paged.lower(
-            cfg, params, i32(2, C), pool, i32(2, 8), i32(2), i32(2),
+            cfg, params, i32(2, C), pool, i32(2, 32), i32(2), i32(2),
             slots=i32(2), return_logits=True, attn_impl="kernel").compile()
         kernel = "paged_prefill_attn"
     text = compiled.as_text()

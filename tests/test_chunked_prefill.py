@@ -826,6 +826,33 @@ class TestObservability:
         assert m["chunk_rows"] == 2 and m["prefill_dispatches"] == 2
         assert m["prefill_row_fill"] == pytest.approx(0.5)
 
+    def test_prefill_block_fill(self):
+        """Live pages attended over the pages the prefill kernel's live
+        kv blocks fetched, at `opt-1.3b.batch`'s geometry (page 64, chunk
+        128, two rows a program, a 1,020-token prompt): the chunks attend
+        2, 4, ... 16 pages = 72, at table widths 2, 4, 8, 8, 16, 16, 16,
+        16 in blocks of min(4, width) pages = 2 + 4 + 8 + 8 + 12 + 12 +
+        16 + 16 = 78 fetched. 0 with no prefill, and after
+        reset_stats()."""
+        cfg = gpt.GPTConfig.tiny(attn_impl="xla", dtype=jnp.float32,
+                                 max_seq=2048)
+        eng = LLMEngine(cfg, gpt.init_params(cfg, jax.random.key(1)),
+                        n_slots=2, max_len=2048, kv_mode="paged",
+                        page_size=64, n_pages=40, prefill_chunk=128,
+                        prefill_token_budget=256)
+        assert eng.chunk_rows == 2
+        assert eng.metrics()["prefill_block_fill"] == 0
+        rng = np.random.default_rng(3)
+        _drive(eng, [eng.submit(
+            list(map(int, rng.integers(1, cfg.vocab_size, 1020))),
+            max_tokens=2)])
+        m = eng.metrics()
+        assert (m["prefill_pages_live"], m["prefill_pages_fetched"]) == (
+            72, 78)
+        assert m["prefill_block_fill"] == pytest.approx(72 / 78)
+        eng.reset_stats()
+        assert eng.metrics()["prefill_block_fill"] == 0
+
     def test_request_chunk_timestamps(self, params):
         eng = LLMEngine(CFG, params, n_slots=2, max_len=128,
                         prefill_buckets=(64,), kv_mode="paged",

@@ -19,6 +19,7 @@ from ray_tpu.ops.paged_attention import (
     _interpret_default,
     paged_attention,
     paged_prefill_attention,
+    prefill_block_pages,
     reference_paged_attention,
     reference_paged_prefill_attention,
 )
@@ -157,24 +158,80 @@ def test_int8_kernel_matches_gather_reference_at_every_layer():
                                    atol=5e-6)
 
 
-@pytest.mark.parametrize("heads", [(2, 2), (8, 2)])
-@pytest.mark.parametrize("kv", ["float32", "bfloat16", "int8"])
-@pytest.mark.parametrize("head_dim", [16, 64, 128, 256])
-def test_prefill_kernel_matches_gather_reference(kv, head_dim, heads):
+def _small_pages_rows(ps, n_pg, C):
+    """The rows this test always had, on pages of 8 (a whole table is one
+    kv block): a chunk ending mid-page, one starting at 0, one whose
+    table has a null tail, and an inert row (no valid token)."""
+    assert (ps, n_pg) == (8, 4)
+    return ([[1, 2, 3, 4], [5, 0, 0, 0], [6, 7, 0, 0], [0, 0, 0, 0]],
+            [21, 0, 7, 0], [C, C, 4, 0])
+
+
+def _block_boundary_rows(ps, n_pg, C):
+    """Rows that put the end of the valid keys everywhere a kv block's
+    boundary can fall, for a table of `n_pg` columns attended n pages a
+    step: the chunk ends in the last block's first page (the null tail
+    then starts INSIDE a live block), in its middle page, in the table's
+    last page (full table); a chunk that straddles the first block's
+    end (a page's end when the table is one block); a chunk at offset 0;
+    an inert row."""
+    n = prefill_block_pages(n_pg, ps, 128, 4, C, 128, 4, 2)
+    last_block = (n_pg - 1) // n * n
+    straddle = (n if n_pg > n else 1) * ps
+    ends = [last_block * ps + C + 1,                        # first page
+            min(last_block + n // 2, n_pg - 1) * ps + ps // 2,  # middle
+            n_pg * ps,                                      # last page
+            straddle + C // 2,
+            C]
+    tables, page = [], 1
+    for end in ends:
+        live = -(-end // ps)
+        tables.append(list(range(page, page + live)) + [0] * (n_pg - live))
+        page += live
+    return (tables + [[0] * n_pg], [e - C for e in ends] + [0],
+            [C] * len(ends) + [0])
+
+
+# (page size, table width, rows): pages of 64 put 4 pages in a kv block
+# (256 keys), so the table is narrower than a block (2), exactly one (4),
+# two (8), and one and a half, padded with null columns (6); pages of 8
+# make the whole table one block.
+_PREFILL_GEOMETRY = {
+    "ps8": (8, 4, _small_pages_rows),
+    "below": (64, 2, _block_boundary_rows),
+    "equal": (64, 4, _block_boundary_rows),
+    "multiple": (64, 8, _block_boundary_rows),
+    "ragged": (64, 6, _block_boundary_rows),
+}
+
+
+@pytest.mark.parametrize("kv,head_dim,heads,geometry", [
+    *[(kv, head_dim, heads, "ps8")
+      for head_dim in (16, 64, 128, 256)
+      for kv in ("float32", "bfloat16", "int8")
+      for heads in ((2, 2), (8, 2))],
+    *[(kv, 64, heads, geometry)
+      for geometry in ("below", "equal", "multiple", "ragged")
+      for kv in ("float32", "bfloat16", "int8")
+      for heads in ((2, 2), (8, 2))],
+])
+def test_prefill_kernel_matches_gather_reference(kv, head_dim, heads,
+                                                 geometry):
     """The chunk kernel against its oracle on one shared pool, at a layer
-    other than 0: a chunk ending mid-page, one starting at 0, one whose
-    table has a null tail, and an inert row (no valid token). `heads` =
-    (H, G): G KV heads in the pool under H query heads."""
+    other than 0, over `_PREFILL_GEOMETRY`'s rows. `heads` = (H, G): G KV
+    heads in the pool under H query heads. An int8 pool has a scale of
+    its own for every page, so for each page of one block."""
     rng = np.random.default_rng(4)
-    (H, G), B, ps, n_pg, C = heads, 4, 8, 4, 6
+    ps, n_pg, rows = _PREFILL_GEOMETRY[geometry]
+    (H, G), C = heads, 6
+    tables, offsets, n_valid = (jnp.asarray(x, jnp.int32)
+                                for x in rows(ps, n_pg, C))
+    B = tables.shape[0]
     dtype = jnp.bfloat16 if kv == "bfloat16" else jnp.float32
     q = jnp.asarray(rng.normal(size=(B, C, H, head_dim)), dtype)
     k_pool, v_pool, _t, _n = _pool_and_tables(
         rng, B=B, H=G, K=head_dim, ps=ps, n_pg=n_pg, dtype=dtype)
-    tables = jnp.asarray(
-        [[1, 2, 3, 4], [5, 0, 0, 0], [6, 7, 0, 0], [0, 0, 0, 0]], jnp.int32)
-    offsets = jnp.asarray([21, 0, 7, 0], jnp.int32)
-    n_valid = jnp.asarray([C, C, 4, 0], jnp.int32)
+    assert int(tables.max()) < k_pool.shape[1]
     scales = {}
     if kv == "int8":
         (k_pool, v_pool), (ks, vs) = _quantized(rng, k_pool.shape)
@@ -190,6 +247,43 @@ def test_prefill_kernel_matches_gather_reference(kv, head_dim, heads):
     np.testing.assert_allclose(
         np.asarray(o, np.float32)[valid], np.asarray(ref, np.float32)[valid],
         atol=3e-2 if kv == "bfloat16" else 1e-5)
+
+
+# The two cells' prefill shapes (benchmarks/configs): page 64, chunk 128;
+# opt-1.3b 32 heads of 64 over a pool 2,048 lanes wide, zaya1-8b 8 query
+# heads of 128 over 2 KV heads (256 lanes); bf16 pools.
+_CELL_SHAPES = {
+    "opt-1.3b": dict(page_size=64, kv_lanes=2048, kv_itemsize=2, chunk=128,
+                     q_lanes=2048, q_itemsize=2, n_heads=32),
+    "zaya1-8b": dict(page_size=64, kv_lanes=256, kv_itemsize=2, chunk=128,
+                     q_lanes=1024, q_itemsize=2, n_heads=8)}
+
+
+@pytest.mark.parametrize("model", sorted(_CELL_SHAPES))
+@pytest.mark.parametrize("n_pg", [1, 2, 3, 4, 8, 16, 31, 32])
+def test_prefill_block_rule(model, n_pg):
+    """The kv block of the prefill kernel: a power of two, never wider
+    than the table, at most `_PREFILL_BLOCK_KEYS` keys, within the VMEM
+    budget it states (blocks double-buffered, beside q, out and the
+    state), and a function of the shapes alone."""
+    import importlib
+
+    # (`ray_tpu.ops` re-exports a function under the module's name)
+    pa = importlib.import_module("ray_tpu.ops.paged_attention")
+    shape = _CELL_SHAPES[model]
+    n = prefill_block_pages(n_pg, **shape)
+    assert n >= 1 and n & (n - 1) == 0 and n <= n_pg
+    assert n * shape["page_size"] <= pa._PREFILL_BLOCK_KEYS
+    C, HK = shape["chunk"], shape["q_lanes"]
+    blocks = 2 * 2 * n * shape["page_size"] * shape["kv_lanes"] * 2
+    rest = 2 * 2 * C * HK * 2 + C * HK * 4 + 2 * shape["n_heads"] * C * 512
+    assert blocks + rest <= pa._PREFILL_VMEM_BUDGET
+    assert n == prefill_block_pages(n_pg, **dict(shape))    # no hidden input
+    # Wide enough to matter wherever the table allows it: a page a step
+    # was the kernel this rule replaced.
+    assert n == min(4, 1 << (n_pg.bit_length() - 1))
+    # A pool too wide for the budget gets a smaller block, not a refusal.
+    assert prefill_block_pages(32, 64, 16384, 2, 128, 16384, 2, 256) == 1
 
 
 def test_page_ops_on_the_flat_pool():
