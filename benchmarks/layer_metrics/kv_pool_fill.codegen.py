@@ -1,0 +1,14 @@
+"""kv_pool_fill.codegen: the fullest the FULL kind of the KV pool got in
+the window, as a share of its pages (`LLMEngine.metrics()
+["kv_pages_free_min"]`, the free list's low-water mark since
+`reset_stats()`, against the cell's `n_pages`). The window kind is a
+ring a slot and has no free list: it is whole from the start.
+"""
+
+
+def read(ctx):
+    free_min = (ctx.get("engine") or {}).get("kv_pages_free_min")
+    n_pages = (ctx.get("consts") or {}).get("n_pages")
+    if free_min is None or not n_pages:
+        return None
+    return (n_pages - free_min) / n_pages * 100.0

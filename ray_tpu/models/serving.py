@@ -49,6 +49,11 @@ class ServingFamily:
     # The pool carries a per-slot state: chunk programs are told each
     # row's slot (`slots=`).
     slot_state: bool = False
+    # The pool keeps some layers' K/V in a ring of pages a slot, beside
+    # the pages `PagePool` accounts for: chunk programs are told each
+    # row's slot (`slots=`), and `init_pool` is told how many tokens of
+    # one prompt a chunk dispatch may carry (`dispatch_tokens=`).
+    slot_ring: bool = False
     # The decode programs keep expert counters in the pool: a decode
     # window hands them over with its tokens (`counters=`).
     expert_counters: bool = False
@@ -161,7 +166,80 @@ def _zaya() -> ServingFamily:
         ))
 
 
-_FAMILIES = {"gpt": _gpt, "zaya": _zaya}
+def _laguna() -> ServingFamily:
+    from jax.sharding import PartitionSpec
+
+    from ray_tpu.models import laguna
+
+    ring = ("the window layers' ring of pages a slot (models/laguna.py: "
+            "indexed by slot, outside PagePool's page ids)")
+    return ServingFamily(
+        name="laguna", model=laguna, init_pool=laguna.init_paged_kv,
+        pool_partition_rules=((r".*", PartitionSpec()),),
+        programs=lambda _tp, _mesh: {
+            name: getattr(laguna, name) for name in (
+                "prefill_chunk_paged", "decode_step_paged",
+                "decode_multi_paged")},
+        slot_ring=True, expert_counters=True,
+        unsupported=(
+            Unsupported(
+                "kv_mode", lambda o: o.kv_mode == "paged", "paged",
+                "the laguna family serves from the paged pool only: "
+                "kv_mode='dense' would need a [L, B, T] cache backend with "
+                "a window mask and per-layer-kind head counts"),
+            Unsupported(
+                "prefill_chunk", lambda o: o.prefill_chunk > 0, 128,
+                "the laguna family has no one-shot prefill: "
+                "prefill_chunk=0 would need a whole-prompt program that "
+                f"leaves the prompt's last window in {ring}"),
+            Unsupported(
+                "prefill_width_bucketing",
+                lambda o: not o.prefill_width_bucketing, False,
+                "prefill_width_bucketing with the laguna family: a chunk "
+                "program here costs a pass over every held expert's "
+                "weights at any table width, and one bucket a width "
+                "spreads a lone prompt's rows over more programs; a "
+                "dispatch that packs rows of several widths into one "
+                "program would have to be built"),
+            Unsupported(
+                "prefix_cache", lambda o: not o.prefix_cache, False,
+                "prefix_cache with the laguna family: a cached prefix "
+                f"would need {ring} at the prefix's boundary stored with "
+                "its pages: a window kind whose pages can be shared "
+                "(serve/prefix_cache.py keeps PagePool pages only)"),
+            Unsupported(
+                "spec_draft", lambda o: not o.spec_draft, "",
+                "speculative decoding with the laguna family: a rejected "
+                "proposal rewinds the cursor, and a ring whose newest "
+                "pages overwrote the oldest cannot be rewound past them; "
+                "a verify program over a ring with room for the "
+                "proposals would have to be built"),
+            Unsupported(
+                "kv_transfer", lambda o: not o.kv_transfer, False,
+                "KV page-set transfer with the laguna family: a page set "
+                f"would have to carry {ring} (serve/kv_objects.py moves "
+                "PagePool pages only)"),
+            Unsupported(
+                "tp", lambda o: int(o.tp) == 1, 1,
+                "tp > 1 with the laguna family: the experts need an "
+                "expert-parallel exchange of rows between chips "
+                "(ops/moe.py returns the held experts' part only), not a "
+                "head split, and no partition rule shards a ring"),
+            Unsupported(
+                "weight_dtype", lambda o: o.weight_dtype != "int8", "bf16",
+                "weight_dtype='int8' with the laguna family: "
+                "quantize_params knows the gpt tree's planes, and the "
+                "experts' grouped matmul (ops/moe.py) has no int8 form"),
+            Unsupported(
+                "kv_dtype", lambda o: o.kv_dtype != "int8", "bf16",
+                "kv_dtype='int8' with the laguna family: the per-page "
+                "scale planes are kept by models/paged_kv._quant_write, "
+                "and the kernels' window form takes a bf16 pool "
+                "(ops/paged_attention.py)"),
+        ))
+
+
+_FAMILIES = {"gpt": _gpt, "zaya": _zaya, "laguna": _laguna}
 
 
 @functools.cache

@@ -42,6 +42,22 @@ Design notes:
   h // (H/G) of the same block-diagonal ``[H, G*K]``; prefill's head
   loop reads that KV head's lane slice of the page. G = H runs the code
   it always ran.
+- Window layers (`window`, `col_page`): a layer that attends only the
+  last `window` keys keeps its K/V in a RING a slot (models/laguna.py),
+  so table column c no longer holds logical page c. `col_page[b, c]` is
+  the logical page column c holds NOW (-1: none), a key's position is
+  ``col_page[b, c] * page_size + i``, and it is attended when
+  ``pos - window < key <= pos``; a column wholly outside is skipped under
+  `pl.when` like a null page. Columns arrive in ring order, which the
+  online softmax does not mind. With `window=None` (every caller before
+  the window kind) column c is page c and the kernels trace what they
+  always did; the window calls carry names of their own in a trace
+  (`paged_decode_attn_window`, `paged_prefill_attn_window`).
+- Many query heads (prefill): the query block, accumulator and (m, l)
+  state of H heads x C rows pass the kernel's VMEM at 48 or 72 heads of
+  128. `prefill_kv_split` then gives the grid a KV-head axis: a grid
+  step holds ONE KV head's K lanes of the block's pages and its H/G
+  query heads, the same kernel body at n_heads = H/G, n_kv_heads = 1.
 - Online-softmax state (m, l, acc) lives in VMEM scratch across the kv
   dimension ("arbitrary" grid semantics), exactly like the flash kernel.
   The prefill kernel keeps m lane-uniform and uses it at full width, and
@@ -115,6 +131,30 @@ def _prefetch(layer, scalars, k_scale, v_scale):
     return ops
 
 
+def _check_window(window, col_page, tables, quantized):
+    if (window is None) != (col_page is None):
+        raise ValueError("`window` and `col_page` go together: a window "
+                         "layer's ring says which page each column holds")
+    if window is not None and (quantized
+                               or col_page.shape != tables.shape):
+        raise ValueError(
+            f"a window call takes a bf16 pool and col_page shaped like "
+            f"tables {tables.shape}, got {col_page.shape}"
+            + (" and an int8 pool" if quantized else ""))
+
+
+def _call_form(name, layer, scalars, k_scale, v_scale, window, col_page):
+    """(the call's name in a trace, its scalar-prefetch operands, the
+    kernel's extra keywords): a window layer's ring adds `col_page` to
+    the scalars and `_window` to the name; without one the call is the
+    one every family had."""
+    if window is None:
+        return name, _prefetch(layer, scalars, k_scale, v_scale), {}
+    return (name + "_window",
+            _prefetch(layer, scalars + (col_page,), None, None),
+            {"window": int(window)})
+
+
 def _head_mask(n_heads, head_dim, n_kv_heads=None):
     """[H, G*K] bool: lane c belongs to the KV head of query head r
     (c // K == r // (H/G); with G = H, lane c belongs to head r)."""
@@ -128,7 +168,7 @@ def _head_mask(n_heads, head_dim, n_kv_heads=None):
 
 
 def _pool_call(kernel, name, q, k_pool, v_pool, prefetch, n_pg, scratch,
-               interpret, block_pages=1):
+               interpret, block_pages=1, kv_split=1):
     """The one pallas_call shape both kernels share: grid (slot, kv block),
     the slot's query rows ``q[b]`` ([rows, H*K]) and its output as one
     block per slot, K and V a page at ``(layer, tables[b, ·])`` of the
@@ -138,22 +178,32 @@ def _pool_call(kernel, name, q, k_pool, v_pool, prefetch, n_pg, scratch,
     the block (K's pages, then V's), so each page is still its own DMA
     and the kernel sees `block_pages` page refs a pool; `n_pg` must be a
     multiple of it. `prefetch` is `_prefetch`'s tuple (layer first, the
-    page table second)."""
+    page table second). With `kv_split` = G > 1 the grid is (slot, KV
+    head, kv block): a step sees its KV head's K lanes of each page and
+    that head's H/G query heads' lanes of q and of the output."""
     B, rows, HK = q.shape
     ps, GK = k_pool.shape[2], k_pool.shape[3]
     n = block_pages
-    im_q = lambda b, j, *_: (b, 0, 0)
+    if kv_split > 1:
+        G = kv_split
+        grid, HK, GK = (B, G, n_pg // n), HK // G, GK // G
+        im_q = lambda b, g, j, *_: (b, 0, g)
+        im_kv = lambda i: (lambda b, g, j, layer, tbl, *_: (
+            layer[0], tbl[b, j * n + i], 0, g))
+    else:
+        grid = (B, n_pg // n)
+        im_q = lambda b, j, *_: (b, 0, 0)
 
-    def im_kv(i):
-        if n == 1:      # decode: the index map it always had
-            return lambda b, j, layer, tbl, *_: (layer[0], tbl[b, j], 0, 0)
-        return lambda b, j, layer, tbl, *_: (
-            layer[0], tbl[b, j * n + i], 0, 0)
+        def im_kv(i):
+            if n == 1:      # decode: the index map it always had
+                return lambda b, j, layer, tbl, *_: (layer[0], tbl[b, j], 0, 0)
+            return lambda b, j, layer, tbl, *_: (
+                layer[0], tbl[b, j * n + i], 0, 0)
 
     pages = [pl.BlockSpec((None, None, ps, GK), im_kv(i)) for i in range(n)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
-        grid=(B, n_pg // n),
+        grid=grid,
         in_specs=[pl.BlockSpec((None, rows, HK), im_q)] + pages + pages,
         out_specs=pl.BlockSpec((None, rows, HK), im_q),
         scratch_shapes=scratch,
@@ -167,9 +217,20 @@ def _pool_call(kernel, name, q, k_pool, v_pool, prefetch, n_pg, scratch,
     )(*prefetch, q, *([k_pool] * n), *([v_pool] * n))
 
 
+# A ring column that holds no page yet (`col_page` -1) starts here: past
+# every length, so its keys are masked and the column is skipped.
+_NO_PAGE = 2**30
+
+
+def _first_key(col, page_size):
+    """Position of the first key of the logical page(s) `col`."""
+    return jnp.where(col < 0, _NO_PAGE, col * page_size)
+
+
 def _decode_kernel(
     *refs,
     sm_scale, page_size, n_pg, n_heads, n_kv_heads, quantized=False,
+    window=None,
 ):
     # Ref order: scalar-prefetch (SMEM) first — layer, page tables, kv
     # lengths, and (quantized pools only) the layer's per-page K/V scale
@@ -179,7 +240,12 @@ def _decode_kernel(
     # trace switch: the bf16 program is untouched and the int8 program
     # dequants each page right after its DMA, inside the kernel — the
     # fp32 plane never exists in HBM.
-    if quantized:
+    col_ref = None
+    if window is not None:      # a ring: column j holds page col_ref[b, j]
+        (_layer_ref, tables_ref, lengths_ref, col_ref, q_ref, k_ref, v_ref,
+         o_ref, qbd_ref, m_ref, l_ref, acc_ref) = refs
+        ks_ref = vs_ref = None
+    elif quantized:
         (_layer_ref, tables_ref, lengths_ref, ks_ref, vs_ref,
          q_ref, k_ref, v_ref, o_ref, qbd_ref, m_ref, l_ref, acc_ref) = refs
     else:
@@ -210,6 +276,8 @@ def _decode_kernel(
         qbd_ref[...] = jnp.where(mask(), q, 0.0).astype(qbd_ref.dtype)
 
     kv_len = lengths_ref[b]
+    if window is not None:
+        first = _first_key(col_ref[b, j], page_size)
 
     def _compute():
         qbd = qbd_ref[...]                   # [H, H*K]
@@ -229,9 +297,14 @@ def _decode_kernel(
         # In-page raggedness: positions at or past the slot's kv length
         # are masked (covers the null page when it IS the write target of
         # an idle slot, and a live slot's partial last page).
-        tpos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        s = jnp.where(tpos < kv_len, s, NEG_INF)
+        if window is None:
+            tpos = j * page_size + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1)
+            s = jnp.where(tpos < kv_len, s, NEG_INF)
+        else:       # the query sits at kv_len - 1
+            tpos = first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where((tpos < kv_len) & (tpos >= kv_len - window), s,
+                          NEG_INF)
 
         m_prev = m_ref[...]                  # [H, LANES] (uniform rows)
         row_max = jnp.max(s, axis=1, keepdims=True)
@@ -248,8 +321,13 @@ def _decode_kernel(
 
     # Skip pages entirely past the slot's kv length — the whole null tail
     # of the table does no compute (its repeated block index also elides
-    # the DMA after the first fetch).
-    pl.when(j * page_size < kv_len)(_compute)
+    # the DMA after the first fetch). A ring column wholly before the
+    # window does none either.
+    if window is None:
+        pl.when(j * page_size < kv_len)(_compute)
+    else:
+        pl.when((first < kv_len)
+                & (first + page_size > kv_len - window))(_compute)
 
     @pl.when(j == n_pg - 1)
     def _finish():
@@ -279,6 +357,8 @@ def paged_attention(
     interpret: bool | None = None,
     k_scale: jax.Array | None = None,
     v_scale: jax.Array | None = None,
+    window: int | None = None,
+    col_page: jax.Array | None = None,
 ) -> jax.Array:
     """Single-token decode attention straight against the KV page pool.
 
@@ -297,6 +377,11 @@ def paged_attention(
       tables: [B, n_pg] int32 page ids per slot (unallocated tail = 0).
       lengths: [B] int32 valid kv positions per slot (= position + 1; the
         current token's K/V must already be written to its page).
+      window, col_page: a window layer's ring (both or neither): only
+        keys within `window` of the query are attended, and table column
+        c holds logical page ``col_page[b, c]`` ([B, n_pg] int32, -1:
+        none). None: column c is page c and every key under the length
+        is attended.
     Returns [B, H, K] in q.dtype. Numerics match the gather reference
     within blockwise-fp32-softmax reassociation (see
     ``reference_paged_attention``).
@@ -309,18 +394,21 @@ def paged_attention(
     if interpret is None:
         interpret = _interpret_default()
     quantized = k_scale is not None
-    prefetch = _prefetch(layer, (tables, lengths), k_scale, v_scale)
+    _check_window(window, col_page, tables, quantized)
+    name, prefetch, extra = _call_form(
+        "paged_decode_attn", layer, (tables, lengths), k_scale, v_scale,
+        window, col_page)
 
     kernel = functools.partial(
         _decode_kernel, sm_scale=sm_scale, page_size=ps, n_pg=n_pg,
-        n_heads=H, n_kv_heads=G, quantized=quantized)
+        n_heads=H, n_kv_heads=G, quantized=quantized, **extra)
     scratch = [
         pltpu.VMEM((H, G * K), q.dtype),         # block-diagonal query
         pltpu.VMEM((H, _LANES), jnp.float32),    # m
         pltpu.VMEM((H, _LANES), jnp.float32),    # l
         pltpu.VMEM((H, G * K), jnp.float32),     # acc
     ]
-    out = _pool_call(kernel, "paged_decode_attn",
+    out = _pool_call(kernel, name,
                      q.reshape(B, 1, H * K) if G == H else q,
                      k_pool, v_pool, prefetch, n_pg, scratch, interpret)
     return out.reshape(B, H, K)
@@ -336,6 +424,29 @@ _PREFILL_BLOCK_KEYS = 256
 _PREFILL_VMEM_BUDGET = 12 * 2**20
 
 
+def _prefill_fixed_bytes(chunk, q_lanes, q_itemsize, n_heads) -> int:
+    """VMEM the prefill kernel takes whatever its kv block: the query and
+    output blocks ([chunk, q_lanes], double-buffered), the f32
+    accumulator and the (m, l) state of `n_heads` heads."""
+    return (4 * chunk * q_lanes * q_itemsize + 4 * chunk * q_lanes
+            + 2 * n_heads * chunk * _LANES * 4)
+
+
+def prefill_kv_split(kv_lanes, chunk, q_lanes, q_itemsize, n_heads) -> int:
+    """1, or G where the prefill kernel's grid gets a KV-head axis: when
+    the query block, accumulator and state of all `n_heads` heads pass
+    `_PREFILL_VMEM_BUDGET` by themselves (48 or 72 heads of 128 over a
+    128-token chunk: 16-24 MB) and a KV head's lanes are whole lane
+    tiles, so that a grid step can take one KV head's slice of a page."""
+    head_dim = q_lanes // n_heads
+    G = kv_lanes // head_dim
+    if (G > 1 and head_dim % _LANES == 0
+            and _prefill_fixed_bytes(chunk, q_lanes, q_itemsize, n_heads)
+            > _PREFILL_VMEM_BUDGET):
+        return G
+    return 1
+
+
 def prefill_block_pages(n_pg, page_size, kv_lanes, kv_itemsize, chunk,
                         q_lanes, q_itemsize, n_heads) -> int:
     """Table columns one grid step of the prefill kernel attends: the
@@ -346,9 +457,11 @@ def prefill_block_pages(n_pg, page_size, kv_lanes, kv_itemsize, chunk,
     `q_lanes`], double-buffered), the f32 accumulator and the (m, l)
     state of `n_heads` heads. Pure in the shapes: the engine's
     `prefill_block_fill` counter and the kernel ask it the same
-    question."""
-    fixed = (4 * chunk * q_lanes * q_itemsize + 4 * chunk * q_lanes
-             + 2 * n_heads * chunk * _LANES * 4)
+    question. Where the grid splits by KV head (`prefill_kv_split`) the
+    shapes are one KV head's."""
+    G = prefill_kv_split(kv_lanes, chunk, q_lanes, q_itemsize, n_heads)
+    kv_lanes, q_lanes, n_heads = kv_lanes // G, q_lanes // G, n_heads // G
+    fixed = _prefill_fixed_bytes(chunk, q_lanes, q_itemsize, n_heads)
     page = 2 * 2 * page_size * kv_lanes * kv_itemsize   # K, V; two buffers
     n = 1
     while (2 * n <= n_pg and 2 * n * page_size <= _PREFILL_BLOCK_KEYS
@@ -382,6 +495,7 @@ def _fold(p):
 def _prefill_kernel(
     *refs,
     sm_scale, page_size, block_pages, n_heads, n_kv_heads, quantized=False,
+    window=None, kv_axis=1,
 ):
     """Ragged chunked-prefill attention: one query BLOCK (a prompt chunk at
     an arbitrary token offset) against the slot's page pool. The decode
@@ -405,11 +519,12 @@ def _prefill_kernel(
     refs = iter(refs)
     take = lambda count: [next(refs) for _ in range(count)]
     _layer_ref, tables_ref, offsets_ref, lengths_ref = take(4)
+    (col_ref,) = take(1) if window is not None else (None,)
     ks_ref, vs_ref = take(2) if quantized else (None, None)
     (q_ref,), k_refs, v_refs = take(1), take(n), take(n)
     o_ref, m_ref, l_ref, acc_ref = refs
     b = pl.program_id(0)
-    j = pl.program_id(1)
+    j = pl.program_id(kv_axis)
     C, HK = q_ref.shape
     head_dim = HK // n_heads
     group = n_heads // n_kv_heads
@@ -423,6 +538,9 @@ def _prefill_kernel(
 
     kv_len = lengths_ref[b]
     q_off = offsets_ref[b]
+    if window is not None:      # a ring: where each column's page starts
+        firsts = [_first_key(col_ref[b, j * n + i], page_size)
+                  for i in range(n)]
 
     def _compute():
         # Causal within the whole sequence: query row c sits at absolute
@@ -432,10 +550,23 @@ def _prefill_kernel(
         # their softmax stays finite; their output is discarded host-side.
         # A dead column inside a live block is the null page, whose
         # positions are past kv_len like any other.
-        tpos = j * block + jax.lax.broadcasted_iota(
-            jnp.int32, (C, block), 1)
-        qpos = q_off + jax.lax.broadcasted_iota(jnp.int32, (C, block), 0)
-        visible = (tpos <= qpos) & (tpos < kv_len)
+        if window is None:
+            tpos = j * block + jax.lax.broadcasted_iota(
+                jnp.int32, (C, block), 1)
+            qpos = q_off + jax.lax.broadcasted_iota(jnp.int32, (C, block), 0)
+            visible = (tpos <= qpos) & (tpos < kv_len)
+        else:
+            # A row whose window has not reached this block yet has no
+            # visible key in it: its state takes exp(0) for the block and
+            # drops all of it (corr = 0) at its first visible key, which
+            # every row has (its own position, or for a pad row the
+            # chunk's valid tokens).
+            in_page = jax.lax.broadcasted_iota(
+                jnp.int32, (C, page_size), 1)
+            tpos = jnp.concatenate([f + in_page for f in firsts], axis=1)
+            qpos = q_off + jax.lax.broadcasted_iota(jnp.int32, (C, block), 0)
+            visible = ((tpos <= qpos) & (tpos < kv_len)
+                       & (tpos > qpos - window))
         k_sc = v_sc = [None] * n
         if quantized:
             pages = [tables_ref[b, j * n + i] for i in range(n)]
@@ -479,10 +610,16 @@ def _prefill_kernel(
 
     # Blocks entirely past the chunk's last valid position do no compute
     # (null-table tail included; its repeated block index also elides
-    # the DMA after the first fetch).
-    pl.when(j * block < kv_len)(_compute)
+    # the DMA after the first fetch). In a ring a block is live when any
+    # of its columns holds keys the chunk's FIRST query can still see.
+    if window is None:
+        pl.when(j * block < kv_len)(_compute)
+    else:
+        live = [(f < kv_len) & (f + page_size > q_off - window + 1)
+                for f in firsts]
+        pl.when(functools.reduce(jnp.logical_or, live))(_compute)
 
-    @pl.when(j == pl.num_programs(1) - 1)
+    @pl.when(j == pl.num_programs(kv_axis) - 1)
     def _finish():
         for h in range(n_heads):
             lanes = slice(h * head_dim, (h + 1) * head_dim)
@@ -505,6 +642,8 @@ def paged_prefill_attention(
     interpret: bool | None = None,
     k_scale: jax.Array | None = None,
     v_scale: jax.Array | None = None,
+    window: int | None = None,
+    col_page: jax.Array | None = None,
 ) -> jax.Array:
     """Chunked-prefill attention straight against the KV page pool.
 
@@ -528,7 +667,10 @@ def paged_prefill_attention(
         max_pages.
       offsets: [B] int32 absolute position of q[:, 0].
       lengths: [B] int32 valid kv positions per slot (= offset + valid
-        chunk tokens; must satisfy lengths[b] <= n_pg * page_size).
+        chunk tokens; must satisfy lengths[b] <= n_pg * page_size, or in
+        a ring that every page a query can see is in some column).
+      window, col_page: as in `paged_attention`: query i attends keys j
+        with ``i - window < j <= i``.
     Returns [B, C, H, K] in q.dtype; rows past a slot's valid chunk tokens
     are defined but meaningless (the engine discards them)."""
     B, C, H, K = q.shape
@@ -539,24 +681,37 @@ def paged_prefill_attention(
     if interpret is None:
         interpret = _interpret_default()
     quantized = k_scale is not None
+    _check_window(window, col_page, tables, quantized)
     n = prefill_block_pages(n_pg, ps, G * K, k_pool.dtype.itemsize, C,
                             H * K, q.dtype.itemsize, H)
     if n_pg % n:
-        # Null columns: position-masked like any dead column of a block.
+        # Null columns: position-masked like any dead column of a block
+        # (in a ring: columns that hold no page).
         tables = jnp.pad(tables, ((0, 0), (0, -n_pg % n)))
-    prefetch = _prefetch(layer, (tables, offsets, lengths), k_scale, v_scale)
+        if col_page is not None:
+            col_page = jnp.pad(col_page, ((0, 0), (0, -n_pg % n)),
+                               constant_values=-1)
+    name, prefetch, extra = _call_form(
+        "paged_prefill_attn", layer, (tables, offsets, lengths), k_scale,
+        v_scale, window, col_page)
+    split = prefill_kv_split(G * K, C, H * K, q.dtype.itemsize, H)
+    if split > 1:       # one KV head and its H/G query heads a grid step
+        extra["kv_axis"] = 2
+        H_step, G_step = H // split, 1
+    else:
+        H_step, G_step = H, G
 
     kernel = functools.partial(
         _prefill_kernel, sm_scale=sm_scale, page_size=ps, block_pages=n,
-        n_heads=H, n_kv_heads=G, quantized=quantized)
+        n_heads=H_step, n_kv_heads=G_step, quantized=quantized, **extra)
     scratch = [
-        pltpu.VMEM((H, C, _LANES), jnp.float32),  # m
-        pltpu.VMEM((H, C, _LANES), jnp.float32),  # l
-        pltpu.VMEM((C, H * K), jnp.float32),      # acc
+        pltpu.VMEM((H_step, C, _LANES), jnp.float32),  # m
+        pltpu.VMEM((H_step, C, _LANES), jnp.float32),  # l
+        pltpu.VMEM((C, H_step * K), jnp.float32),      # acc
     ]
-    out = _pool_call(kernel, "paged_prefill_attn", q.reshape(B, C, H * K),
+    out = _pool_call(kernel, name, q.reshape(B, C, H * K),
                      k_pool, v_pool, prefetch, tables.shape[1], scratch,
-                     interpret, block_pages=n)
+                     interpret, block_pages=n, kv_split=split)
     return out.reshape(B, C, H, K)
 
 
@@ -594,8 +749,22 @@ def _gather_timeline(k_pool, v_pool, layer, tables, n_heads, head_dim,
     return views
 
 
+def _key_positions(tables, page_size, col_page):
+    """[B, T] position of every key of the gathered timelines: column c
+    is page c, or in a ring the page `col_page` says it holds (a column
+    that holds none lies past every length)."""
+    B, n_pg = tables.shape
+    if col_page is None:
+        return jnp.broadcast_to(jnp.arange(n_pg * page_size)[None],
+                                (B, n_pg * page_size))
+    first = _first_key(col_page, page_size)
+    return (first[:, :, None] + jnp.arange(page_size)[None, None, :]
+            ).reshape(B, n_pg * page_size)
+
+
 def reference_paged_attention(q, k_pool, v_pool, layer, tables, lengths, *,
-                              sm_scale=None, k_scale=None, v_scale=None):
+                              sm_scale=None, k_scale=None, v_scale=None,
+                              window=None, col_page=None):
     """Gather-semantics oracle: reconstitute each slot's contiguous
     timeline and run plain-XLA attention — byte-for-byte the math of
     models/paged_kv.py's gather read path (test oracle + fallback).
@@ -603,14 +772,17 @@ def reference_paged_attention(q, k_pool, v_pool, layer, tables, lengths, *,
     [L, P, page_size, H*K] and the layer index; int8 pools pass the
     per-page ``k_scale``/``v_scale`` planes [L, P]."""
     B, H, K = q.shape
-    T = tables.shape[1] * k_pool.shape[2]
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(K)
+    _check_window(window, col_page, tables, k_scale is not None)
     k_view, v_view = _gather_timeline(k_pool, v_pool, layer, tables, H, K,
                                       k_scale, v_scale)
     s = jnp.einsum("bhk,bthk->bht", q, k_view,
                    preferred_element_type=jnp.float32) * sm_scale
-    mask = jnp.arange(T)[None, :] < lengths[:, None]        # [B, T]
+    tpos = _key_positions(tables, k_pool.shape[2], col_page)   # [B, T]
+    mask = tpos < lengths[:, None]
+    if window is not None:
+        mask &= tpos >= lengths[:, None] - window
     s = jnp.where(mask[:, None, :], s, NEG_INF)
     probs = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     # q.dtype out unconditionally: the dequanted v_view is f32, and the
@@ -620,7 +792,8 @@ def reference_paged_attention(q, k_pool, v_pool, layer, tables, lengths, *,
 
 def reference_paged_prefill_attention(q, k_pool, v_pool, layer, tables,
                                       offsets, lengths, *, sm_scale=None,
-                                      k_scale=None, v_scale=None):
+                                      k_scale=None, v_scale=None,
+                                      window=None, col_page=None):
     """Gather-semantics oracle for chunked prefill: reconstitute each
     slot's contiguous timeline from the pool and run plain-XLA causal
     attention for a C-query chunk at absolute offset — byte-for-byte the
@@ -634,17 +807,18 @@ def reference_paged_prefill_attention(q, k_pool, v_pool, layer, tables,
     with the bucket width, so the oracle's gather/einsum bytes scale the
     same way the kernel's grid does. → [B, C, H, K] in q.dtype."""
     B, C, H, K = q.shape
-    T = tables.shape[1] * k_pool.shape[2]
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(K)
+    _check_window(window, col_page, tables, k_scale is not None)
     k_view, v_view = _gather_timeline(k_pool, v_pool, layer, tables, H, K,
                                       k_scale, v_scale)
     s = jnp.einsum("bchk,bthk->bhct", q, k_view,
                    preferred_element_type=jnp.float32) * sm_scale
-    tpos = jnp.arange(T)                                    # [T]
-    qpos = offsets[:, None] + jnp.arange(C)[None, :]        # [B, C]
-    mask = ((tpos[None, None, :] <= qpos[:, :, None])
-            & (tpos[None, None, :] < lengths[:, None, None]))  # [B, C, T]
+    tpos = _key_positions(tables, k_pool.shape[2], col_page)[:, None]
+    qpos = (offsets[:, None] + jnp.arange(C)[None, :])[:, :, None]
+    mask = (tpos <= qpos) & (tpos < lengths[:, None, None])    # [B, C, T]
+    if window is not None:
+        mask &= tpos > qpos - window
     s = jnp.where(mask[:, None], s, NEG_INF)
     probs = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     # q.dtype out unconditionally (see reference_paged_attention).
@@ -654,4 +828,5 @@ def reference_paged_prefill_attention(q, k_pool, v_pool, layer, tables,
 __all__ = [
     "paged_attention", "paged_prefill_attention",
     "reference_paged_attention", "reference_paged_prefill_attention",
+    "prefill_block_pages", "prefill_kv_split",
 ]

@@ -554,8 +554,12 @@ class LLMEngine:
                 n_pages = max(self.max_pages_per_slot + 1,
                               (n_slots * self.max_pages_per_slot) // 2)
             self.n_pages = n_pages
+            # A ring of pages a slot is sized by what one chunk dispatch
+            # can write of one prompt.
+            ring = ({"dispatch_tokens": self.chunk_rows * prefill_chunk}
+                    if fam.slot_ring else {})
             self.cache = fam.init_pool(cfg, n_pages, page_size, n_slots,
-                                       self.kv_dtype)
+                                       self.kv_dtype, **ring)
             self.pool = PagePool(n_pages, page_size, n_slots,
                                  self.max_pages_per_slot)
         else:
@@ -834,7 +838,7 @@ class LLMEngine:
                       # rows, rows routed — summed over those pairs.
                       "slot_state_resets": 0, "moe_layer_steps": 0,
                       "moe_experts_touched_sum": 0, "moe_rows_max_sum": 0,
-                      "moe_rows_routed": 0}
+                      "moe_rows_routed": 0, "moe_rows_held": 0}
         # The decode programs' counters run on, wrapping uint32; the
         # window's share is the difference from the last pull.
         self._moe_seen: dict | None = None
@@ -873,8 +877,9 @@ class LLMEngine:
 
     def _row_slots(self, slots) -> dict:
         """The chunk program's extra keyword for a family whose pool
-        carries a per-slot state: the slot of each row."""
-        if not self._family.slot_state:
+        carries a per-slot state or a ring of pages a slot: the slot of
+        each row."""
+        if not (self._family.slot_state or self._family.slot_ring):
             return {}
         return {"slots": self._rt.jnp.asarray(slots)}
 
@@ -891,6 +896,9 @@ class LLMEngine:
             self.stats["moe_experts_touched_sum"] += delta["experts_touched"]
             self.stats["moe_rows_max_sum"] += delta["rows_max"]
             self.stats["moe_rows_routed"] += delta["rows_routed"]
+            # A family that holds every expert counts no share of its own.
+            self.stats["moe_rows_held"] += delta.get(
+                "rows_held", delta["rows_routed"])
 
     # ------------------------------------------------------------- API
 
@@ -1378,7 +1386,12 @@ class LLMEngine:
                 m["llm_weight_dtype"] = self.weight_dtype
                 m["llm_kv_dtype"] = self.kv_dtype
                 nbytes = lambda a: int(math.prod(a.shape) * a.dtype.itemsize)
-                m["kv_pool_bytes"] = sum(
+                # A family's window layers keep rings a slot beside the
+                # pages (0: none); the pool's bytes count both kinds.
+                m["window_kv_bytes"] = sum(
+                    nbytes(a) for name, a in self.cache.items()
+                    if name in ("k_win", "v_win"))
+                m["kv_pool_bytes"] = m["window_kv_bytes"] + sum(
                     nbytes(a) for name, a in self.cache.items()
                     if name in ("k", "v", "k_scale", "v_scale"))
                 # A family's per-slot state beside the pages (0: none).
@@ -1488,14 +1501,14 @@ class LLMEngine:
                 m["prefill_tokens"] / m["prefill_time_s"])
         if m["slot_cap_sum"] > 0:
             m["slot_occupancy"] = m["slot_step_sum"] / m["slot_cap_sum"]
-        # Per layer and decode step (0 where no expert layer ran): experts
-        # that had a row, and the fullest expert's rows over the mean of
-        # those that had any.
+        # Per layer and decode step (0 where no expert layer ran): held
+        # experts that had a row, and the fullest one's rows over the
+        # mean of those that had any.
         pairs = max(1, m["moe_layer_steps"])
         m["moe_experts_touched"] = m["moe_experts_touched_sum"] / pairs
         m["moe_rows_max"] = (
             m["moe_rows_max_sum"] * m["moe_experts_touched_sum"]
-            / max(1, m["moe_rows_routed"]) / pairs)
+            / max(1, m["moe_rows_held"]) / pairs)
         return m
 
     _EWMA_ALPHA = 0.2

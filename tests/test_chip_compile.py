@@ -468,3 +468,117 @@ def test_zaya_program_fits_and_moves_no_expert_layer(chip, zaya_serving,
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert 14.0e9 < total < 15.0e9
+
+
+# Laguna-S-2.1's serving shapes: 8 KV heads of 128 under 48 query heads
+# (full layers, the engine's page tables) and 72 (window layers, a ring
+# of 13 pages a slot); the cell's cut: 5 layers, 128 of 256 experts held,
+# half the vocabulary, 64 slots x 64 pages.
+G_SLOTS, G_PAGES, G_G, G_K, G_RING, G_WINDOW = 64, 4096, 8, 128, 13, 512
+
+
+@pytest.mark.parametrize("heads,kind", [(48, "full"), (72, "window")])
+def test_many_head_grouped_kernels_compile(chip, heads, kind):
+    """Both paged kernels at H = 48 and 72 over G = 8 at head size 128.
+    The query block, accumulator and state of that many heads pass the
+    prefill kernel's VMEM by themselves, so its grid splits by KV head
+    (`prefill_kv_split`); the window kind runs over a ring table with
+    `col_page` under names of its own."""
+    from ray_tpu.ops.paged_attention import prefill_kv_split
+
+    assert prefill_kv_split(G_G * G_K, C, heads * G_K, 2, heads) == G_G
+    ring = kind == "window"
+    width = G_RING if ring else 64
+    rows = (G_SLOTS + 1) * G_RING if ring else G_PAGES + 1
+    pool = chip((3, rows, PS, G_G * G_K), jnp.bfloat16)
+    i32 = lambda *shape: chip(shape, jnp.int32)
+    kw = lambda col: ({"window": G_WINDOW, "col_page": col} if ring else {})
+    suffix = "_window" if ring else ""
+    _compile(lambda q, k, v, l, t, n, col: paged_attention(
+        q, k, v, l, t, n, interpret=False, **kw(col)),
+        chip((G_SLOTS, heads, G_K), jnp.bfloat16), pool, pool, _layer(chip),
+        i32(G_SLOTS, width), i32(G_SLOTS), i32(G_SLOTS, width),
+        kernels=("paged_decode_attn" + suffix,))
+    _compile(lambda q, k, v, l, t, o, n, col: paged_prefill_attention(
+        q, k, v, l, t, o, n, interpret=False, **kw(col)),
+        chip((2, C, heads, G_K), jnp.bfloat16), pool, pool, _layer(chip),
+        i32(2, width), i32(2), i32(2), i32(2, width),
+        kernels=("paged_prefill_attn" + suffix,))
+
+
+@pytest.fixture(scope="module")
+def laguna_serving(chip):
+    """(cfg, params, pool) of the laguna cell as shapes on one described
+    chip, with the two backend questions steered to the chip's answers."""
+    import importlib
+
+    from ray_tpu.models import laguna
+
+    cfg = laguna.LagunaConfig(n_layers=5, n_experts=128, vocab_size=50176)
+    params = {name: chip(spec["shape"], jnp.bfloat16)
+              for name, spec in laguna.param_specs(cfg).items()}
+    pool = jax.tree.map(
+        lambda x: chip(x.shape, x.dtype),
+        jax.eval_shape(lambda: laguna.init_paged_kv(
+            cfg, G_PAGES, PS, G_SLOTS, dispatch_tokens=2 * C)))
+    attn = importlib.import_module("ray_tpu.ops.paged_attention")
+    moe = importlib.import_module("ray_tpu.ops.moe")
+    saved = attn._interpret_default, moe._mixed_dot_default
+    attn._interpret_default = lambda: False
+    moe._mixed_dot_default = lambda: True
+    yield cfg, params, pool
+    attn._interpret_default, moe._mixed_dot_default = saved
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_laguna_program_fits_and_moves_no_expert_layer(chip, laguna_serving,
+                                                       program):
+    """The laguna family's two step programs, compiled whole at the
+    cell's size: all four attention calls and the experts' grouped
+    matmul are in them under the names a trace finds them by; no layer
+    of experts (128 x 3,072 x 1,024 bf16, 805 MB a matrix) and no layer
+    of either cache kind (the smaller: 65 rings of 13 pages, 111 MB) is
+    copied, sliced out or put back (XLA prefetches W_o and the dense
+    MLP's matrices into fast memory, `S(1)` copies of 38-75 MB: reads,
+    under the rule's size); the donated pool (pages, rings, counters)
+    is updated in place; weights + pool + what the program needs
+    besides stay under the chip's 16 GB."""
+    from ray_tpu.models import laguna
+
+    cfg, params, pool = laguna_serving
+    assert pool["ring_rows"].shape == (G_SLOTS + 1, G_RING)
+    i32 = lambda *shape: chip(shape, jnp.int32)
+    if program == "decode":
+        key = jax.eval_shape(lambda: jax.random.key(0))
+        compiled = laguna._decode_sample_paged.lower(
+            cfg, params, i32(G_SLOTS), pool, i32(G_SLOTS),
+            i32(G_SLOTS, 64), chip((G_SLOTS,), jnp.float32),
+            chip(key.shape, key.dtype), attn_impl="kernel").compile()
+        kernel = "paged_decode_attn"
+    else:
+        compiled = laguna.prefill_chunk_paged.lower(
+            cfg, params, i32(2, C), pool, i32(2, 64), i32(2), i32(2),
+            slots=i32(2), return_logits=True, attn_impl="kernel").compile()
+        kernel = "paged_prefill_attn"
+    text = compiled.as_text()
+    calls = lambda name: len(re.findall(
+        rf"%\w*{name}[\w.]* = [^\n]*custom-call\(", text))
+    # Two full layers and three window layers (the plain name's pattern
+    # finds the window calls too).
+    assert calls(kernel + "_window") == 3 and calls(kernel) == 5
+    assert len(re.findall(r"%ragged-dot[\w.\-]* = [^\n]*custom-call\(",
+                          text)) >= 3
+    n_sparse = cfg.count("sparse")
+    assert (f"bf16[{n_sparse * cfg.n_experts},{cfg.d_model},{cfg.d_ff}]"
+            in text)
+    expert_layer = cfg.n_experts * cfg.d_model * cfg.d_ff
+    ring_layer = (G_SLOTS + 1) * G_RING * PS * G_G * G_K
+    moved = _pool_moves(text, "bf16", min(expert_layer, ring_layer))
+    assert not moved, "layer-sized moves:\n" + "\n".join(moved)
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                     for a in pool.values())
+    assert mem.alias_size_in_bytes >= pool_bytes
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 13.5e9 < total < 15.0e9

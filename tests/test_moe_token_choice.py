@@ -156,3 +156,51 @@ def test_the_parts_of_two_chips_add_up_to_the_references_layer():
     # a row's output comes from ONE chip
     assert not np.any((np.abs(parts[0]).max(axis=1) > 0)
                       & (np.abs(parts[1]).max(axis=1) > 0))
+
+
+# ------------------------- top-10 of 256 with 128 held: the laguna cell's k
+
+def _top_k_routing(rng, n_rows, n_experts, k):
+    ids = np.stack([rng.permutation(n_experts)[:k] for _ in range(n_rows)])
+    gates = rng.uniform(0.1, 1.0, ids.shape)
+    return ids.astype(np.int32), (2.5 * gates / gates.sum(axis=1,
+                                                          keepdims=True))
+
+
+@pytest.mark.parametrize("first", [0, 128])
+@pytest.mark.parametrize("n_rows,layer", [(64, None), (64, 2), (7, 1)])
+def test_top_10_of_256_with_128_held(first, n_rows, layer):
+    """A decode step of the laguna cell in small widths: 64 rows, 10
+    choices each over 256 experts of which this chip holds 128 (either
+    half), whole stacks and a layer index as the program passes them.
+    The held choices, and only they, against the per-token loop; every
+    choice is counted by exactly one of the two halves."""
+    rng = np.random.default_rng(11)
+    held, d, f = 128, 8, 6
+    ids, gates = _top_k_routing(rng, n_rows, 256, 10)
+    x = jnp.asarray(rng.normal(size=(n_rows, d)), jnp.float32)
+    lead = (held,) if layer is None else (3, held)
+    mk = lambda *s: jnp.asarray(rng.normal(size=lead + s) * 0.3, jnp.float32)
+    w = mk(d, f), mk(d, f), mk(f, d)
+    kw = {} if layer is None else {"layer": jnp.int32(layer)}
+    with jax.default_matmul_precision("highest"):
+        y, counts = jax.jit(token_choice_experts,
+                            static_argnames="first_expert")(
+            x, jnp.asarray(ids), jnp.asarray(gates, jnp.float32), *w,
+            first_expert=first, **kw)
+    here = (ids >= first) & (ids < first + held)
+    want = np.zeros((n_rows, d))
+    mine = [a if layer is None else a[layer]
+            for a in (np.asarray(t, np.float64) for t in w)]
+    for n in range(n_rows):
+        for e, g in zip(ids[n][here[n]], gates[n][here[n]]):
+            a = np.asarray(x[n], np.float64) @ mine[0][e - first]
+            h = a / (1.0 + np.exp(-a)) * (np.asarray(x[n], np.float64)
+                                          @ mine[1][e - first])
+            want[n] += g * (h @ mine[2][e - first])
+    np.testing.assert_allclose(np.asarray(y), want, atol=2e-5)
+    np.testing.assert_array_equal(
+        np.asarray(counts),
+        np.bincount(ids[here] - first, minlength=held))
+    assert 0 < int(counts.sum()) < ids.size         # a share, not the whole
+    assert int(counts.sum()) == int(here.sum())

@@ -486,3 +486,194 @@ class TestEngineKernelPath:
         counts, _sums = _DECODE_STEP_HIST.snapshot_hist()
         assert any("paged-kernel" in k for k in counts), (
             "step-latency histogram has no paged-kernel series")
+
+
+# ----------------------------------------------- window layers: a ring a slot
+
+def _ring(rng, *, lengths, G, K, ps, R, dtype):
+    """Each slot's K/V timeline [T, G*K] and the ring pool that holds its
+    newest pages: logical page j of slot b at row b * R + j % R (the rows
+    a slot's tokens have not reached hold another request's garbage).
+    -> (k_pool, v_pool, tables, col_page, k_dense, v_dense)."""
+    B, T = len(lengths), -(-max(lengths) // ps) * ps
+    dense = [rng.normal(size=(B, T, G * K)).astype(np.float32)
+             for _ in range(2)]
+    pools = [rng.normal(size=(N_LAYERS, B * R, ps, G * K)).astype(np.float32)
+             for _ in range(2)]
+    col_page = np.full((B, R), -1, np.int32)
+    for b, n in enumerate(lengths):
+        for j in range(-(-n // ps)):
+            for pool, line in zip(pools, dense):
+                pool[:, b * R + j % R] = line[b, j * ps:(j + 1) * ps]
+            col_page[b, j % R] = j
+    tables = np.arange(B * R, dtype=np.int32).reshape(B, R)
+    as_dtype = lambda a: jnp.asarray(a, dtype)
+    return (as_dtype(pools[0]), as_dtype(pools[1]), jnp.asarray(tables),
+            jnp.asarray(col_page), as_dtype(dense[0]), as_dtype(dense[1]))
+
+
+def _dense_window_attention(q, k, v, qpos, lengths, window, G):
+    """q [B, C, H, K] at absolute positions qpos [B, C] against whole
+    timelines k, v [B, T, G*K]: plain masked softmax, float64."""
+    B, C, H, K = q.shape
+    q, k, v = (np.asarray(a, np.float64) for a in (q, k, v))
+    k = np.repeat(k.reshape(B, -1, G, K), H // G, axis=2)
+    v = np.repeat(v.reshape(B, -1, G, K), H // G, axis=2)
+    s = np.einsum("bchk,bthk->bhct", q, k) / np.sqrt(K)
+    t = np.arange(k.shape[1])[None, None, :]
+    seen = ((t <= qpos[:, :, None]) & (t > qpos[:, :, None] - window)
+            & (t < np.asarray(lengths)[:, None, None]))
+    s = np.where(seen[:, None], s, -1e30)      # (a pad row sees no key)
+    p = np.exp(s - s.max(axis=-1, keepdims=True))
+    p /= p.sum(axis=-1, keepdims=True)
+    return np.einsum("bhct,bthk->bchk", p, v)
+
+
+# (H, G, K); a window of 2.5 pages in a ring of 6, contexts from inside
+# the first window to several turns of the ring.
+WINDOW_HEADS = [(4, 4, 16), (6, 2, 128), (9, 1, 128)]
+RING = dict(ps=16, R=6, window=40)
+
+
+@pytest.mark.parametrize("heads", WINDOW_HEADS)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_window_decode_kernel_matches_oracle_and_dense(heads, dtype):
+    H, G, K = heads
+    ps, R, window = RING["ps"], RING["R"], RING["window"]
+    lengths = [1, 17, 40, 41, 96, 97, 150, 271]
+    rng = np.random.default_rng(7)
+    k_pool, v_pool, tables, col_page, k_dense, v_dense = _ring(
+        rng, lengths=lengths, G=G, K=K, ps=ps, R=R, dtype=dtype)
+    q = jnp.asarray(rng.normal(size=(len(lengths), H, K)), dtype)
+    n = jnp.asarray(lengths, jnp.int32)
+    layer = jnp.int32(1)
+    # a pool whose OTHER layers differ, so the layer index is live
+    kw = dict(window=window, col_page=col_page)
+    got = paged_attention(q, k_pool.at[0].add(1.0), v_pool, layer, tables,
+                          n, **kw)
+    ref = reference_paged_attention(q, k_pool, v_pool, layer, tables, n, **kw)
+    want = _dense_window_attention(
+        q[:, None], k_dense, v_dense, np.asarray(lengths)[:, None] - 1,
+        lengths, window, G)[:, 0]
+    atol = 3e-2 if dtype == jnp.bfloat16 else 1e-5
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(ref, np.float32), atol=atol)
+    np.testing.assert_allclose(np.asarray(ref, np.float32), want, atol=atol)
+
+
+@pytest.mark.parametrize("split", [False, True])
+@pytest.mark.parametrize("heads", WINDOW_HEADS)
+def test_window_prefill_kernel_matches_oracle_and_dense(heads, split,
+                                                        monkeypatch):
+    """Chunk rows at offsets inside the first window, across a page's
+    edge and after the ring has turned, a ragged last row and an inert
+    one; with the grid split by KV head (`prefill_kv_split`, forced here
+    by a small VMEM budget) and without."""
+    import importlib
+
+    pa = importlib.import_module("ray_tpu.ops.paged_attention")
+    H, G, K = heads
+    ps, R, window, C = RING["ps"], RING["R"], RING["window"], 24
+    if split:
+        monkeypatch.setattr(pa, "_PREFILL_VMEM_BUDGET", 4096)
+    assert pa.prefill_kv_split(G * K, C, H * K, 4, H) == (
+        G if split and G > 1 and K % 128 == 0 else 1)
+    offsets = np.asarray([0, 10, 37, 100, 230, 64, 0], np.int32)
+    n_valid = np.asarray([24, 24, 24, 24, 24, 7, 0], np.int32)
+    lengths = offsets + n_valid
+    rng = np.random.default_rng(8)
+    k_pool, v_pool, tables, col_page, k_dense, v_dense = _ring(
+        rng, lengths=list(np.maximum(lengths, 1)), G=G, K=K, ps=ps, R=R,
+        dtype=jnp.float32)
+    col_page = jnp.where(jnp.asarray(lengths)[:, None] > 0, col_page, -1)
+    q = jnp.asarray(rng.normal(size=(len(offsets), C, H, K)), jnp.float32)
+    args = (jnp.int32(2), tables, jnp.asarray(offsets), jnp.asarray(lengths))
+    kw = dict(window=window, col_page=col_page)
+    got = paged_prefill_attention(q, k_pool, v_pool, *args, **kw)
+    ref = reference_paged_prefill_attention(q, k_pool, v_pool, *args, **kw)
+    qpos = offsets[:, None] + np.arange(C)[None, :]
+    want = _dense_window_attention(q, k_dense, v_dense, qpos, lengths,
+                                   window, G)
+    valid = np.arange(C)[None, :] < n_valid[:, None]
+    np.testing.assert_allclose(np.asarray(got)[valid], np.asarray(ref)[valid],
+                               atol=1e-5)
+    np.testing.assert_allclose(np.asarray(ref)[valid], want[valid], atol=1e-5)
+
+
+@pytest.mark.parametrize("fault", ["window_off_by_one", "page_off_by_one"])
+def test_a_window_fault_moves_the_output(fault):
+    """What the tolerances above are for: one key more in the window, or
+    a column believed to hold the page before its own."""
+    H, G, K = 6, 2, 128
+    ps, R, window = RING["ps"], RING["R"], RING["window"]
+    lengths = [96, 150, 271]
+    rng = np.random.default_rng(9)
+    k_pool, v_pool, tables, col_page, k_dense, v_dense = _ring(
+        rng, lengths=lengths, G=G, K=K, ps=ps, R=R, dtype=jnp.float32)
+    q = jnp.asarray(rng.normal(size=(3, H, K)), jnp.float32)
+    n = jnp.asarray(lengths, jnp.int32)
+    want = _dense_window_attention(
+        q[:, None], k_dense, v_dense, np.asarray(lengths)[:, None] - 1,
+        lengths, window, G)[:, 0]
+    if fault == "window_off_by_one":
+        kw = dict(window=window + 1, col_page=col_page)
+    else:
+        kw = dict(window=window, col_page=jnp.maximum(col_page - 1, 0))
+    for attend in (paged_attention, reference_paged_attention):
+        got = attend(q, k_pool, v_pool, jnp.int32(0), tables, n, **kw)
+        assert np.abs(np.asarray(got) - want).max() > 1e-2
+
+
+def test_window_and_col_page_go_together():
+    rng = np.random.default_rng(1)
+    k_pool, v_pool, tables, n = _pool_and_tables(
+        rng, B=2, H=2, K=16, ps=8, n_pg=3, dtype=jnp.float32)
+    q = jnp.zeros((2, 2, 16), jnp.float32)
+    with pytest.raises(ValueError, match="go together"):
+        paged_attention(q, k_pool, v_pool, jnp.int32(0), tables, n, window=8)
+    with pytest.raises(ValueError, match="shaped like tables"):
+        paged_attention(q, k_pool, v_pool, jnp.int32(0), tables, n, window=8,
+                        col_page=tables[:, :2])
+
+
+@pytest.mark.parametrize("model", ["opt-1.3b", "zaya1-8b"])
+def test_without_a_window_the_kernels_trace_what_they_did(model):
+    """`window=None` hands the two families that have no window layer the
+    calls they had: the names a trace knows, three and four scalar
+    operands ahead of the blocks (no `col_page`), a (slot, kv block) grid
+    with no KV-head axis."""
+    H, G, K = (32, 32, 64) if model == "opt-1.3b" else (8, 2, 128)
+    pool = jax.ShapeDtypeStruct((2, 9, 64, G * K), jnp.bfloat16)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
+    decode = jax.make_jaxpr(lambda q, k, v, t, n: paged_attention(
+        q, k, v, jnp.int32(1), t, n, interpret=True))(
+        jax.ShapeDtypeStruct((4, H, K), jnp.bfloat16), pool, pool, i32(4, 8),
+        i32(4))
+    chunk = jax.make_jaxpr(lambda q, k, v, t, o, n: paged_prefill_attention(
+        q, k, v, jnp.int32(1), t, o, n, interpret=True))(
+        jax.ShapeDtypeStruct((2, 128, H, K), jnp.bfloat16), pool, pool,
+        i32(2, 8), i32(2), i32(2))
+    for jaxpr, name, scalars, grid in ((decode, "paged_decode_attn", 3, (4, 8)),
+                                       (chunk, "paged_prefill_attn", 4, (2, 2))):
+        (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+        spec = call.params["grid_mapping"]
+        assert call.params["name"] == name
+        assert spec.num_index_operands == scalars and spec.grid == grid
+    shape = _CELL_SHAPES[model]
+    from ray_tpu.ops.paged_attention import prefill_kv_split
+    assert prefill_kv_split(shape["kv_lanes"], shape["chunk"],
+                            shape["q_lanes"], shape["q_itemsize"],
+                            shape["n_heads"]) == 1
+
+
+def test_many_heads_split_the_prefill_grid_by_kv_head():
+    """48 and 72 query heads of 128 over 8 KV heads: the query block,
+    accumulator and state pass the VMEM budget by themselves, so a grid
+    step takes one KV head; the block is then four pages again."""
+    from ray_tpu.ops.paged_attention import prefill_kv_split
+
+    for heads in (48, 72):
+        assert prefill_kv_split(1024, 128, heads * 128, 2, heads) == 8
+        assert prefill_block_pages(64, 64, 1024, 2, 128, heads * 128, 2,
+                                   heads) == 4
+    assert prefill_block_pages(13, 64, 1024, 2, 128, 72 * 128, 2, 72) == 4
