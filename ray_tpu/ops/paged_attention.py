@@ -19,18 +19,28 @@ Design notes:
   index into the pool. A page arrives by its own DMA, as ``page_size``
   dense rows of ``H*K`` lanes (2,048 at OPT-1.3B): the minor axis is a
   multiple of 128 lanes for every served model, so the chip's own
-  layout of the pool is row-major and nothing re-lays it out. A decode
-  grid step takes one page; a prefill grid step takes a BLOCK of
-  `prefill_block_pages` consecutive table columns (4 pages = 256 keys at
-  both served shapes), the pool handed over once a column of the block.
+  layout of the pool is row-major and nothing re-lays it out. A grid
+  step takes a BLOCK of consecutive table columns, the pool handed over
+  once a column of the block (each page an operand and its own DMA):
+  `prefill_block_pages` columns in the prefill kernel (4 pages = 256
+  keys at the served shapes), `decode_block_pages` in the decode kernel
+  (up to 512 KiB of K a block: 16 pages of 32 KB at zaya1-8b, 4 of
+  128 KB at laguna-s-2.1, 2 of 256 KB at opt-1.3b). A table the block
+  does not divide is padded with null columns.
 - The per-head split happens in VMEM, after the read. Decode: the
   slot's query row ``[1, H*K]`` is spread into a block-diagonal
   ``[H, H*K]`` (row h keeps head h's K lanes, zeros elsewhere), so
-  ``QKᵀ`` for all heads is ONE matmul against the page (the zeros add
-  exactly 0.0 to the fp32 accumulation), ``PV`` is one matmul into a
-  ``[H, H*K]`` accumulator, and the head-h block of row h is what the
-  output keeps. Prefill: a static loop over heads, each on its K-lane
-  slice of the query chunk and the accumulator and on ONE stack of the
+  ``QKᵀ`` for all heads is ONE matmul against the block's pages stacked
+  into one ``[n*ps, H*K]`` operand (the zeros add exactly 0.0 to the
+  fp32 accumulation), ``PV`` is one matmul into a ``[H, H*K]``
+  accumulator, and the head-h block of row h is what the output keeps.
+  The softmax chain (score tile → masked row maximum → ``exp`` → row
+  sum → accumulator rescale) is serially dependent and costs the same
+  over 64 keys as over 1,024, so it is paid once a block, not once a
+  page (PERF.md, PR 37: at a page a step the chain was 45 % of the
+  kernel at zaya1-8b's shapes and the grid steps' own bookkeeping the
+  rest). Prefill: a static loop over heads, each on its K-lane slice of
+  the query chunk and the accumulator and on ONE stack of the
   block's pages' K-lane slices, so a head pays one QKᵀ, one softmax
   update and one PV a block of keys, not a page: at a page a step the
   head's fixed work (a lane reduction for the row maximum, the state's
@@ -47,9 +57,9 @@ Design notes:
   so table column c no longer holds logical page c. `col_page[b, c]` is
   the logical page column c holds NOW (-1: none), a key's position is
   ``col_page[b, c] * page_size + i``, and it is attended when
-  ``pos - window < key <= pos``; a column wholly outside is skipped under
-  `pl.when` like a null page. Columns arrive in ring order, which the
-  online softmax does not mind. With `window=None` (every caller before
+  ``pos - window < key <= pos``; a block whose every column lies wholly
+  outside is skipped under `pl.when` like a null tail. Columns arrive
+  in ring order, which the online softmax does not mind. With `window=None` (every caller before
   the window kind) column c is page c and the kernels trace what they
   always did; the window calls carry names of their own in a trace
   (`paged_decode_attn_window`, `paged_prefill_attn_window`).
@@ -60,18 +70,22 @@ Design notes:
   query heads, the same kernel body at n_heads = H/G, n_kv_heads = 1.
 - Online-softmax state (m, l, acc) lives in VMEM scratch across the kv
   dimension ("arbitrary" grid semantics), exactly like the flash kernel.
-  The prefill kernel keeps m lane-uniform and uses it at full width, and
-  keeps l as a partial sum a lane that is summed over lanes once, after
-  the last block: cutting a [C, 1] column out of the state and spreading
+  Both kernels keep m lane-uniform and use it at full width, and keep l
+  as a partial sum a lane that is summed over lanes once, after the
+  last block: cutting a [C, 1] column out of the state and spreading
   it again, and a second lane reduction a block, were most of a head's
   time once the block was wide.
 - Null / past-length pages: unallocated table tail entries are 0 (the
   reserved null page, models/paged_kv.py), so their index maps repeat
   one block and Pallas's revisit elision fetches it at most once;
-  ``pl.when(j*ps < len)`` skips their compute entirely (prefill: a block
-  whose first key is past the length). In-page raggedness (a slot ending
-  mid-page) and the dead columns of a live prefill block are
-  position-masked like the flash kernel's kv_len mask.
+  ``pl.when`` skips the compute of a block whose first key is past the
+  length. In-page raggedness (a slot ending mid-page) and the dead
+  columns of a live block are position-masked like the flash kernel's
+  kv_len mask. The decode call goes one step further (`_held_pages`): a
+  dead column (the null tail, a ring's columns outside the window, the
+  pad) is pointed at the page its operand position holds anyway, so it
+  costs no fetch at all; with n columns as n operands each would
+  otherwise fall to the null page once a slot and layer.
 - Softmax statistics stay fp32; the QKᵀ/PV contractions run in the input
   dtype with fp32 accumulate (MXU fast path — upcasting operands would
   drop the MXU into its ~4x slower fp32 mode).
@@ -194,11 +208,8 @@ def _pool_call(kernel, name, q, k_pool, v_pool, prefetch, n_pg, scratch,
         grid = (B, n_pg // n)
         im_q = lambda b, j, *_: (b, 0, 0)
 
-        def im_kv(i):
-            if n == 1:      # decode: the index map it always had
-                return lambda b, j, layer, tbl, *_: (layer[0], tbl[b, j], 0, 0)
-            return lambda b, j, layer, tbl, *_: (
-                layer[0], tbl[b, j * n + i], 0, 0)
+        im_kv = lambda i: (lambda b, j, layer, tbl, *_: (
+            layer[0], tbl[b, j * n + i], 0, 0))
 
     pages = [pl.BlockSpec((None, None, ps, GK), im_kv(i)) for i in range(n)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -227,31 +238,44 @@ def _first_key(col, page_size):
     return jnp.where(col < 0, _NO_PAGE, col * page_size)
 
 
+def _column_live(first, kv_len, page_size, window):
+    """Whether a decode step attends any key of the page(s) that start at
+    `first`: one lies under the kv length and, in a ring, inside the
+    window of the query at ``kv_len - 1``."""
+    live = first < kv_len
+    if window is not None:
+        live &= first + page_size > kv_len - window
+    return live
+
+
 def _decode_kernel(
     *refs,
-    sm_scale, page_size, n_pg, n_heads, n_kv_heads, quantized=False,
+    sm_scale, page_size, block_pages, n_heads, n_kv_heads, quantized=False,
     window=None,
 ):
-    # Ref order: scalar-prefetch (SMEM) first — layer, page tables, kv
-    # lengths, and (quantized pools only) the layer's per-page K/V scale
-    # vectors — then VMEM blocks (q [1, H*K], one K page and one V page
-    # [ps, H*K]), the output, and the scratch: the block-diagonal query
-    # and the (m, l, acc) softmax state. `quantized` is a Python-level
-    # trace switch: the bf16 program is untouched and the int8 program
-    # dequants each page right after its DMA, inside the kernel — the
-    # fp32 plane never exists in HBM.
-    col_ref = None
-    if window is not None:      # a ring: column j holds page col_ref[b, j]
-        (_layer_ref, tables_ref, lengths_ref, col_ref, q_ref, k_ref, v_ref,
-         o_ref, qbd_ref, m_ref, l_ref, acc_ref) = refs
-        ks_ref = vs_ref = None
-    elif quantized:
-        (_layer_ref, tables_ref, lengths_ref, ks_ref, vs_ref,
-         q_ref, k_ref, v_ref, o_ref, qbd_ref, m_ref, l_ref, acc_ref) = refs
-    else:
-        (_layer_ref, tables_ref, lengths_ref, q_ref, k_ref, v_ref,
-         o_ref, qbd_ref, m_ref, l_ref, acc_ref) = refs
-        ks_ref = vs_ref = None
+    """One slot's current-token query against a BLOCK of `block_pages`
+    consecutive table columns a grid step. Ref order: scalar-prefetch
+    (SMEM) first (layer, page tables, kv lengths, a ring's `col_page`,
+    and for an int8 pool the layer's per-page K/V scale vectors), then
+    VMEM blocks (q, `block_pages` K pages and as many V pages, each
+    [ps, G*K] and its own DMA), the output, and the scratch: the
+    block-diagonal query and the (m, l, acc) softmax state. The block's
+    pages are stacked into one [block_pages*ps, G*K] operand a pool, so a
+    step pays ONE score tile, one masked row maximum, one `exp`, one
+    partial row sum and one accumulator update whatever the block holds:
+    at a page a step that chain, not the page's bytes or its matmuls,
+    was the kernel's compute (PERF.md, PR 37). `quantized` is a
+    Python-level trace switch: the int8 program dequants each page of
+    the block by its own scale right after its DMA, inside the kernel,
+    and the fp32 plane never exists in HBM."""
+    n = block_pages
+    refs = iter(refs)
+    take = lambda count: [next(refs) for _ in range(count)]
+    _layer_ref, tables_ref, lengths_ref = take(3)
+    (col_ref,) = take(1) if window is not None else (None,)
+    ks_ref, vs_ref = take(2) if quantized else (None, None)
+    (q_ref,), k_refs, v_refs = take(1), take(n), take(n)
+    o_ref, qbd_ref, m_ref, l_ref, acc_ref = refs
     b = pl.program_id(0)
     j = pl.program_id(1)
     # Multi-head (G = H): the query is one dense row [1, H*K]. Grouped
@@ -261,6 +285,8 @@ def _decode_kernel(
     grouped = n_kv_heads != n_heads
     head_dim = q_ref.shape[-1] if grouped else q_ref.shape[-1] // n_heads
     mask = lambda: _head_mask(n_heads, head_dim, n_kv_heads)
+    block = n * page_size
+    GK = qbd_ref.shape[1]
 
     @pl.when(j == 0)
     def _init():
@@ -276,62 +302,73 @@ def _decode_kernel(
         qbd_ref[...] = jnp.where(mask(), q, 0.0).astype(qbd_ref.dtype)
 
     kv_len = lengths_ref[b]
-    if window is not None:
-        first = _first_key(col_ref[b, j], page_size)
+    if window is not None:      # a ring: where each column's page starts
+        firsts = [_first_key(col_ref[b, j * n + i], page_size)
+                  for i in range(n)]
 
     def _compute():
-        qbd = qbd_ref[...]                   # [H, H*K]
-        k = k_ref[...]                       # [ps, H*K]
-        v = v_ref[...]
+        qbd = qbd_ref[...]                   # [H, G*K]
+        k_sc = v_sc = [None] * n
         if quantized:
-            page = tables_ref[b, j]
-            k = k.astype(jnp.float32) * ks_ref[page]
-            v = v.astype(jnp.float32) * vs_ref[page]
+            pages = [tables_ref[b, j * n + i] for i in range(n)]
+            k_sc = [ks_ref[p] for p in pages]
+            v_sc = [vs_ref[p] for p in pages]
             qbd = qbd.astype(jnp.float32)
+
+        def stack(page_refs, scales):
+            parts = [r[...] if sc is None
+                     else r[...].astype(jnp.float32) * sc
+                     for r, sc in zip(page_refs, scales)]   # n x [ps, G*K]
+            return parts[0] if n == 1 else jnp.concatenate(parts, axis=0)
+
+        k, v = stack(k_refs, k_sc), stack(v_refs, v_sc)
         # s[h, t] = q[h] · k[t, head h's lanes]: the block-diagonal query
-        # makes it one [H, H*K] x [ps, H*K]ᵀ matmul. Decode attention is
-        # HBM-bound (~2 flops/byte), so the H-fold surplus of multiplies
-        # by zero is free next to reading the page once.
+        # makes it one [H, G*K] x [block, G*K]ᵀ matmul. Decode attention
+        # is HBM-bound (~2 flops/byte), so the H-fold surplus of
+        # multiplies by zero is free next to reading the pages once.
         s = jax.lax.dot_general(
             qbd, k, _NT, preferred_element_type=jnp.float32) * sm_scale
-        # In-page raggedness: positions at or past the slot's kv length
-        # are masked (covers the null page when it IS the write target of
-        # an idle slot, and a live slot's partial last page).
+        # Raggedness: positions at or past the slot's kv length are
+        # masked (a live block's dead columns, a partial last page, the
+        # null page when it IS the write target of an idle slot).
+        lane = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         if window is None:
-            tpos = j * page_size + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            s = jnp.where(tpos < kv_len, s, NEG_INF)
-        else:       # the query sits at kv_len - 1
-            tpos = first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(j * block + lane < kv_len, s, NEG_INF)
+        else:       # the query sits at kv_len - 1; column i starts at
+            start = firsts[0]                       # lane i * page_size
+            for i in range(1, n):
+                start = jnp.where(lane >= i * page_size,
+                                  firsts[i] - i * page_size, start)
+            tpos = start + lane
             s = jnp.where((tpos < kv_len) & (tpos >= kv_len - window), s,
                           NEG_INF)
 
-        m_prev = m_ref[...]                  # [H, LANES] (uniform rows)
-        row_max = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, row_max)
-        p = jnp.exp(s - m_new[:, :1])        # [H, ps] fp32
-        corr = jnp.exp(m_prev[:, :1] - m_new[:, :1])
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-        # Row h of [H, H*K]: head h's probabilities against EVERY head's
-        # V lanes; only its own block is kept at the end.
+        # The state as the prefill kernel keeps it: m lane-uniform
+        # [H, LANES] and used at full width, l a partial sum a lane.
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - _spread(m_new, block))       # [H, block] fp32
+        corr = jnp.exp(m_prev - m_new)               # [H, LANES]
+        l_ref[...] = l_ref[...] * corr + _fold(p)
+        # Row h of [H, G*K]: head h's probabilities against EVERY KV
+        # head's V lanes; only its own block is kept at the end.
         pv = jnp.dot(p.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * corr + pv
+        acc_ref[...] = acc_ref[...] * _spread(corr, GK) + pv
         m_ref[...] = m_new
 
-    # Skip pages entirely past the slot's kv length — the whole null tail
-    # of the table does no compute (its repeated block index also elides
-    # the DMA after the first fetch). A ring column wholly before the
-    # window does none either.
+    # A block does no compute when every column of it is dead: wholly
+    # past the slot's kv length (the null tail of the table, whose fetch
+    # `_held_pages` elides too), or in a ring wholly outside the window.
     if window is None:
-        pl.when(j * page_size < kv_len)(_compute)
+        pl.when(j * block < kv_len)(_compute)
     else:
-        pl.when((first < kv_len)
-                & (first + page_size > kv_len - window))(_compute)
+        live = [_column_live(f, kv_len, page_size, window) for f in firsts]
+        pl.when(functools.reduce(jnp.logical_or, live))(_compute)
 
-    @pl.when(j == n_pg - 1)
+    @pl.when(j == pl.num_programs(1) - 1)
     def _finish():
-        l = l_ref[:, :1]
+        l = jnp.sum(l_ref[...], axis=1, keepdims=True)
         l_safe = jnp.where(l == 0.0, 1.0, l)
         own = jnp.where(mask(), acc_ref[...] / l_safe, 0.0)
         if grouped:
@@ -343,6 +380,90 @@ def _decode_kernel(
             # of each head's own block, as one dense [1, H*K] row.
             out = jnp.sum(own, axis=0, keepdims=True)
         o_ref[...] = out.astype(o_ref.dtype)
+
+
+# The decode kernel's kv block. A grid step costs ~0.05 us an operand
+# whatever it holds (each page of the block is an operand a pool), and a
+# live step one softmax chain; the chain is paid once a block, so the
+# block is as large as its bytes stay small beside the step: 512 KiB of K
+# (and of V) read best at all three served page sizes (16 pages of 32 KB,
+# 4 of 128 KB, 2 of 256 KB: PERF.md, PR 37), and past it a live block's
+# dead columns cost more than the chains saved. The budget is what a
+# step may take of the 16 MiB of VMEM a kernel gets by default.
+_DECODE_BLOCK_BYTES = 512 * 2**10
+_DECODE_BLOCK_KEYS = 1024
+_DECODE_VMEM_BUDGET = 12 * 2**20
+
+
+def _decode_vmem_bytes(n, page_size, kv_lanes, kv_itemsize, n_heads) -> int:
+    """VMEM a decode grid step takes at `n` pages a block: the K and V
+    pages (double-buffered by the pipeline), an int8 block's f32 copies,
+    the score and probability tiles, and whatever the block: the
+    block-diagonal query with the f32 accumulator, the (m, l) state and
+    the query and output blocks."""
+    page = page_size * kv_lanes * kv_itemsize
+    dequant = 2 * n * page_size * kv_lanes * 4 if kv_itemsize == 1 else 0
+    tiles = 2 * n_heads * n * page_size * 4
+    fixed = n_heads * kv_lanes * (4 + 4 + 4) + 2 * n_heads * _LANES * 4
+    return 2 * 2 * n * page + dequant + tiles + fixed
+
+
+def decode_block_pages(n_pg, page_size, kv_lanes, kv_itemsize,
+                       n_heads) -> int:
+    """Table columns one grid step of the decode kernel attends: the
+    largest power of two that is at most `n_pg`, keeps the block's K
+    pages (`kv_lanes` = G*K wide) at or under `_DECODE_BLOCK_BYTES` and
+    `_DECODE_BLOCK_KEYS` keys, and fits `_DECODE_VMEM_BUDGET`
+    (`_decode_vmem_bytes`). Pure in the shapes: the engine's
+    `decode_block_fill` counter and the kernel ask it the same
+    question."""
+    page = page_size * kv_lanes * kv_itemsize
+    n = 1
+    while (2 * n <= n_pg and 2 * n * page <= _DECODE_BLOCK_BYTES
+           and 2 * n * page_size <= _DECODE_BLOCK_KEYS
+           and _decode_vmem_bytes(2 * n, page_size, kv_lanes, kv_itemsize,
+                                  n_heads) <= _DECODE_VMEM_BUDGET):
+        n *= 2
+    return n
+
+
+def _pad_columns(tables, col_page, n):
+    """The table (and a ring's `col_page`) padded with null columns to a
+    multiple of `n`: position-masked like any dead column of a block (in
+    a ring: columns that hold no page)."""
+    pad = -tables.shape[1] % n
+    if pad:
+        tables = jnp.pad(tables, ((0, 0), (0, pad)))
+        if col_page is not None:
+            col_page = jnp.pad(col_page, ((0, 0), (0, pad)),
+                               constant_values=-1)
+    return tables, col_page
+
+
+def _held_pages(tables, live, n):
+    """`tables` with every dead column pointed at the page its operand
+    position holds anyway, so that a dead column costs no fetch. Column c
+    is operand c % n of grid step c // n, and Pallas fetches an operand
+    only when its block index differs from the step before: a dead
+    column takes the page of the last live column before it at its
+    position (the null tail of a slot, a ring's columns outside the
+    window), or, with none before it, of the first one after it (that
+    page is then fetched early and kept); a position with no live column
+    keeps its own. `live` [B, n_pg] bool is what the kernel masks by;
+    the table never decides what is attended."""
+    B, n_pg = tables.shape
+    steps = n_pg // n
+    if steps == 1:
+        return tables
+    step = jnp.arange(steps, dtype=jnp.int32)[None, :, None]
+    live = live.reshape(B, steps, n)
+    before = jax.lax.cummax(jnp.where(live, step, -1), axis=1)
+    after = jax.lax.cummin(jnp.where(live, step, steps), axis=1,
+                           reverse=True)
+    src = jnp.where(before >= 0, before, jnp.where(after < steps, after,
+                                                   step))
+    return jnp.take_along_axis(tables.reshape(B, steps, n), src,
+                               axis=1).reshape(B, n_pg)
 
 
 def paged_attention(
@@ -388,19 +509,27 @@ def paged_attention(
     """
     B, H, K = q.shape
     ps, G = _check_pool(H, K, k_pool, v_pool)
-    n_pg = tables.shape[1]
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(K)
     if interpret is None:
         interpret = _interpret_default()
     quantized = k_scale is not None
     _check_window(window, col_page, tables, quantized)
+    n = decode_block_pages(tables.shape[1], ps, G * K,
+                           k_pool.dtype.itemsize, H)
+    tables, col_page = _pad_columns(tables, col_page, n)
+    # What the kernel attends is the lengths' (and the ring's) to say;
+    # the table only has to hold a live column's page.
+    first = (jnp.arange(tables.shape[1], dtype=jnp.int32)[None] * ps
+             if window is None else _first_key(col_page, ps))
+    tables = _held_pages(
+        tables, _column_live(first, lengths[:, None], ps, window), n)
     name, prefetch, extra = _call_form(
         "paged_decode_attn", layer, (tables, lengths), k_scale, v_scale,
         window, col_page)
 
     kernel = functools.partial(
-        _decode_kernel, sm_scale=sm_scale, page_size=ps, n_pg=n_pg,
+        _decode_kernel, sm_scale=sm_scale, page_size=ps, block_pages=n,
         n_heads=H, n_kv_heads=G, quantized=quantized, **extra)
     scratch = [
         pltpu.VMEM((H, G * K), q.dtype),         # block-diagonal query
@@ -410,7 +539,8 @@ def paged_attention(
     ]
     out = _pool_call(kernel, name,
                      q.reshape(B, 1, H * K) if G == H else q,
-                     k_pool, v_pool, prefetch, n_pg, scratch, interpret)
+                     k_pool, v_pool, prefetch, tables.shape[1], scratch,
+                     interpret, block_pages=n)
     return out.reshape(B, H, K)
 
 
@@ -684,13 +814,7 @@ def paged_prefill_attention(
     _check_window(window, col_page, tables, quantized)
     n = prefill_block_pages(n_pg, ps, G * K, k_pool.dtype.itemsize, C,
                             H * K, q.dtype.itemsize, H)
-    if n_pg % n:
-        # Null columns: position-masked like any dead column of a block
-        # (in a ring: columns that hold no page).
-        tables = jnp.pad(tables, ((0, 0), (0, -n_pg % n)))
-        if col_page is not None:
-            col_page = jnp.pad(col_page, ((0, 0), (0, -n_pg % n)),
-                               constant_values=-1)
+    tables, col_page = _pad_columns(tables, col_page, n)
     name, prefetch, extra = _call_form(
         "paged_prefill_attn", layer, (tables, offsets, lengths), k_scale,
         v_scale, window, col_page)
@@ -828,5 +952,5 @@ def reference_paged_prefill_attention(q, k_pool, v_pool, layer, tables,
 __all__ = [
     "paged_attention", "paged_prefill_attention",
     "reference_paged_attention", "reference_paged_prefill_attention",
-    "prefill_block_pages", "prefill_kv_split",
+    "prefill_block_pages", "prefill_kv_split", "decode_block_pages",
 ]

@@ -725,8 +725,10 @@ class LLMEngine:
         self._dispatch_width_ring: "collections.deque[int]" = (
             collections.deque(maxlen=4096))
         self._dispatch_width_counts: dict[int, int] = {}
-        # Table width -> pages a grid step of the prefill kernel attends.
+        # Table width -> pages a grid step of the prefill kernel attends,
+        # and of the decode kernel.
         self._block_pages_at: dict[int, int] = {}
+        self._decode_block_at: dict[int, int] = {}
         self._rng_key = jax.random.key(seed)
         # Per-token decode step times (window wall time / window size),
         # milliseconds — a bounded ring so metrics() can report p50/p95
@@ -800,6 +802,10 @@ class LLMEngine:
                       # kernel's live kv blocks held for them
                       # (`prefill_block_fill`).
                       "prefill_pages_live": 0, "prefill_pages_fetched": 0,
+                      # Pages the decoding slots attend at a window's
+                      # first step, and pages the decode kernel's live
+                      # kv blocks hold for them (`decode_block_fill`).
+                      "decode_pages_live": 0, "decode_pages_fetched": 0,
                       "decode_time_s": 0.0, "decode_windows": 0,
                       "slot_step_sum": 0, "slot_cap_sum": 0,
                       "preemptions": 0,
@@ -1378,6 +1384,11 @@ class LLMEngine:
                 m["kv_pages_free_min"] = self.pool.min_free
                 m["kv_page_size"] = self.page_size
                 m["llm_attn_impl"] = self.attn_impl
+                # Live pages the decoding slots attended over the pages
+                # of the decode kernel's live kv blocks: under 1.0 a
+                # block's tail lay past its slot's last page.
+                m["decode_block_fill"] = m["decode_pages_live"] / max(
+                    1, m["decode_pages_fetched"])
                 # Quantized-serving observability (rides the PR 6 chain:
                 # replica stats → serve.status() → /api/serve/load →
                 # `ray_tpu status --serve`): the dtype knobs as resolved
@@ -2508,6 +2519,25 @@ class LLMEngine:
         return sum(self._next_page_needed(s, int(self.positions[s]),
                                           int(held[s])) for s in decoding)
 
+    def _count_decode_pages(self, active: list[int], width: int) -> None:
+        """`decode_block_fill`'s two sums for one decode window at table
+        width `width`: the pages the decoding slots' keys lie on, and
+        those pages rounded up to whole kv blocks of the decode kernel
+        (its own rule, ops/paged_attention.decode_block_pages, asked
+        once a width with the shapes the kernel sees)."""
+        if width not in self._decode_block_at:
+            from ray_tpu.ops.paged_attention import decode_block_pages
+
+            pool = self.cache["k"]
+            self._decode_block_at[width] = decode_block_pages(
+                width, self.page_size, pool.shape[3] // self.tp,
+                pool.dtype.itemsize, self.cfg.n_heads // self.tp)
+        block = self._decode_block_at[width]
+        live = self.pool.pages_for(self.positions[active])
+        self.stats["decode_pages_live"] += int(live.sum())
+        self.stats["decode_pages_fetched"] += int(
+            (-(-live // block) * block).sum())
+
     def _chunk_width(self, done: int, n: int) -> int:
         """Pow-2 page-table width a chunk row [done, done+n) actually
         needs to attend over: the pages covering its slot's written
@@ -3148,6 +3178,8 @@ class LLMEngine:
                     active, k = self._fit_window_pages(active, k)
                     if active:
                         table_view = self._decode_table_view(active)
+                        self._count_decode_pages(active,
+                                                 table_view.shape[1])
             if not active:
                 # graftlint: disable=GUARDED-BY (engine-thread state: _step runs only on the engine loop thread; the locked writes elsewhere are reader-side snapshots, and a plain store is torn-read-free)
                 self._last_window_end = None

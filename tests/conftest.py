@@ -9,6 +9,7 @@ Platform forcing lives in ray_tpu.utils.platform (shared with
 __graft_entry__.py) — it must run before any backend is initialized.
 """
 
+import gc
 import os
 
 from ray_tpu.utils.platform import (
@@ -51,6 +52,22 @@ harden_jax_compilation_cache()
 # per fresh session dir; content-addressed digests make reuse safe.
 os.environ.setdefault("RAY_TPU_PIP_ENV_CACHE_DIR",
                       "/tmp/ray_tpu_pip_env_cache")
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _release_compiled_programs():
+    """Drop a test module's compiled programs when it ends. Every loaded
+    XLA:CPU executable holds memory mappings until its jit cache entry
+    goes, a process may hold `vm.max_map_count` of them (65,530), and one
+    xdist worker runs many files: tests/test_laguna.py leaves 39,000
+    mappings, test_zaya.py 17,000, test_quant.py 12,000, and a worker
+    that was dealt all three died in jaxlib (a segfault or an abort while
+    it read or wrote a cache entry) at whatever test crossed the line.
+    After `clear_caches` a worker is back under 1,000; the next module
+    loads what it needs from the persistent cache."""
+    yield
+    jax.clear_caches()
+    gc.collect()
 
 
 @pytest.fixture(scope="session")

@@ -128,6 +128,8 @@ def test_every_engine_metric_key_is_a_key_of_metrics(served):
         with open(path) as f:
             keys |= set(re.findall(r"engine_metric:(\w+)", f.read()))
     assert len(keys) >= 10
+    # The two kernels' block counters (PR 34, PR 37) among them.
+    assert {"prefill_block_fill", "decode_block_fill"} <= keys
     keys.discard("compiles_in_window")      # the harness adds this one
     have = eng.metrics()
     assert sorted(keys - set(have)) == []

@@ -853,6 +853,40 @@ class TestObservability:
         eng.reset_stats()
         assert eng.metrics()["prefill_block_fill"] == 0
 
+    def test_decode_block_fill(self):
+        """Live pages the decoding slots attend over the pages of the
+        decode kernel's live kv blocks, summed once a decode window, at
+        `opt-1.3b.batch`'s page (64) and `decode_block_pages`' answer
+        for the tiny model's pool (every column of a table one block):
+        a 100-token prompt decodes over 2 live pages in a table 2 wide
+        (fill 1), a 130-token one over 3 in a table 4 wide (3 / 4). 0
+        before any window, and after reset_stats()."""
+        from ray_tpu.ops.paged_attention import decode_block_pages
+
+        cfg = gpt.GPTConfig.tiny(attn_impl="xla", dtype=jnp.float32,
+                                 max_seq=2048)
+        eng = LLMEngine(cfg, gpt.init_params(cfg, jax.random.key(1)),
+                        n_slots=2, max_len=2048, kv_mode="paged",
+                        page_size=64, n_pages=40, prefill_chunk=128,
+                        prefill_token_budget=256)
+        lanes = cfg.n_heads * cfg.head_dim
+        assert [decode_block_pages(w, 64, lanes, 4, cfg.n_heads)
+                for w in (1, 2, 4)] == [1, 2, 4]
+        assert eng.metrics()["decode_block_fill"] == 0
+        rng = np.random.default_rng(3)
+        for n_prompt, live, width in ((100, 2, 2), (130, 3, 4)):
+            eng.reset_stats()
+            assert eng.metrics()["decode_block_fill"] == 0
+            _drive(eng, [eng.submit(
+                list(map(int, rng.integers(1, cfg.vocab_size, n_prompt))),
+                max_tokens=4)])
+            m = eng.metrics()
+            windows = m["decode_windows"]
+            assert windows >= 1
+            assert (m["decode_pages_live"], m["decode_pages_fetched"]) == (
+                live * windows, width * windows)
+            assert m["decode_block_fill"] == pytest.approx(live / width)
+
     def test_request_chunk_timestamps(self, params):
         eng = LLMEngine(CFG, params, n_slots=2, max_len=128,
                         prefill_buckets=(64,), kv_mode="paged",

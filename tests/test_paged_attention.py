@@ -8,6 +8,8 @@ dense engine). On CPU the kernel runs under interpret=True: the fallback
 is ASSERTED, never silently skipped — a broken pallas install fails here.
 """
 
+import importlib
+
 import numpy as np
 import pytest
 
@@ -17,12 +19,16 @@ import jax.numpy as jnp
 from ray_tpu.models import gpt
 from ray_tpu.ops.paged_attention import (
     _interpret_default,
+    decode_block_pages,
     paged_attention,
     paged_prefill_attention,
     prefill_block_pages,
     reference_paged_attention,
     reference_paged_prefill_attention,
 )
+
+# The module itself (`ray_tpu.ops` re-exports a function under its name).
+pa = importlib.import_module("ray_tpu.ops.paged_attention")
 
 CFG = gpt.GPTConfig.tiny(attn_impl="xla", dtype=jnp.float32)
 
@@ -266,10 +272,6 @@ def test_prefill_block_rule(model, n_pg):
     than the table, at most `_PREFILL_BLOCK_KEYS` keys, within the VMEM
     budget it states (blocks double-buffered, beside q, out and the
     state), and a function of the shapes alone."""
-    import importlib
-
-    # (`ray_tpu.ops` re-exports a function under the module's name)
-    pa = importlib.import_module("ray_tpu.ops.paged_attention")
     shape = _CELL_SHAPES[model]
     n = prefill_block_pages(n_pg, **shape)
     assert n >= 1 and n & (n - 1) == 0 and n <= n_pg
@@ -284,6 +286,155 @@ def test_prefill_block_rule(model, n_pg):
     assert n == min(4, 1 << (n_pg.bit_length() - 1))
     # A pool too wide for the budget gets a smaller block, not a refusal.
     assert prefill_block_pages(32, 64, 16384, 2, 128, 16384, 2, 256) == 1
+
+
+
+def _decode_boundary_lengths(ps, n_pg, n):
+    """Where a decode slot's keys can end against kv blocks of `n` pages
+    in a table of `n_pg` columns: on, one under and one over every block
+    boundary (one over leaves a live block with n - 1 dead columns), the
+    full table, a slot of one token, and an idle slot (all-null table,
+    length 1)."""
+    block = n * ps
+    ends = sorted({e for edge in range(block, n_pg * ps, block)
+                   for e in (edge - 1, edge, edge + 1)}
+                  | {1, n_pg * ps})
+    return ends + [1], len(ends)        # the last row is the idle slot
+
+
+# (page size, table width, block cap in keys or None for the rule's own):
+# a page a step (the parent's grid), a table narrower than the cap, one
+# and two whole blocks, and a table the block does not divide (the ring's
+# 13 columns), padded with null columns.
+_DECODE_GEOMETRY = {
+    "page": (16, 4, 16),
+    "below": (16, 2, 64),
+    "equal": (16, 4, 64),
+    "multiple": (16, 8, 64),
+    "ragged": (16, 13, 64),
+    "rule": (16, 13, None),
+}
+
+
+@pytest.mark.parametrize("kv,heads,geometry", [
+    *[(kv, heads, geometry)
+      for geometry in _DECODE_GEOMETRY
+      for kv in ("float32", "bfloat16", "int8")
+      for heads in ((4, 4, 16), (8, 2, 128))],
+    *[("float32", heads, geometry)
+      for geometry in ("multiple", "ragged")
+      for heads in ((48, 8, 128), (72, 8, 128))],
+])
+def test_decode_kernel_at_block_boundaries(kv, heads, geometry,
+                                           monkeypatch):
+    """The decode kernel against its oracle, at a layer other than 0,
+    over `_decode_boundary_lengths`: a block's dead columns and the pad
+    columns are position-masked, a dead block is skipped, and an int8
+    pool's pages keep a scale each inside one block. `heads` = (H, G,
+    K): the served head counts 8 / 2, 48 / 8 and 72 / 8, and G = H."""
+    ps, n_pg, cap = _DECODE_GEOMETRY[geometry]
+    if cap is not None:
+        monkeypatch.setattr(pa, "_DECODE_BLOCK_KEYS", cap)
+    (H, G, K) = heads
+    dtype = jnp.bfloat16 if kv == "bfloat16" else jnp.float32
+    n = decode_block_pages(n_pg, ps, G * K, 1 if kv == "int8" else
+                           jnp.dtype(dtype).itemsize, H)
+    assert n == (min(cap // ps, 1 << (n_pg.bit_length() - 1)) if cap
+                 else 8)
+    lengths, n_live = _decode_boundary_lengths(ps, n_pg, n)
+    B = len(lengths)
+    rng = np.random.default_rng(11)
+    tables, page = np.zeros((B, n_pg), np.int32), 1
+    for b, end in enumerate(lengths[:n_live]):
+        live = -(-end // ps)
+        tables[b, :live] = np.arange(page, page + live)
+        page += live
+    shape = (N_LAYERS, page, ps, G * K)
+    scales = {}
+    if kv == "int8":
+        (k_pool, v_pool), (ks, vs) = _quantized(rng, shape)
+        scales = {"k_scale": ks, "v_scale": vs}
+    else:
+        k_pool, v_pool = (jnp.asarray(rng.normal(size=shape), dtype)
+                          for _ in range(2))
+    q = jnp.asarray(rng.normal(size=(B, H, K)), dtype)
+    args = (jnp.int32(N_LAYERS - 1), jnp.asarray(tables),
+            jnp.asarray(lengths, jnp.int32))
+    o = paged_attention(q, k_pool, v_pool, *args, **scales)
+    ref = reference_paged_attention(q, k_pool, v_pool, *args, **scales)
+    assert o.shape == q.shape and o.dtype == q.dtype
+    np.testing.assert_allclose(
+        np.asarray(o, np.float32), np.asarray(ref, np.float32),
+        atol=3e-2 if kv == "bfloat16" else 1e-5)
+
+
+# The cells' decode shapes (benchmarks/configs): page 64, bf16 pools;
+# laguna-s-2.1 has two kinds (48 heads over a table of up to 64 columns,
+# 72 over a ring of 13).
+_DECODE_SHAPES = {
+    "opt-1.3b": dict(page_size=64, kv_lanes=2048, kv_itemsize=2, n_heads=32),
+    "zaya1-8b": dict(page_size=64, kv_lanes=256, kv_itemsize=2, n_heads=8),
+    "laguna-s-2.1": dict(page_size=64, kv_lanes=1024, kv_itemsize=2,
+                         n_heads=48),
+    "laguna-s-2.1.window": dict(page_size=64, kv_lanes=1024, kv_itemsize=2,
+                                n_heads=72)}
+_DECODE_BLOCK_AT_FULL_WIDTH = {"opt-1.3b": 2, "zaya1-8b": 16,
+                               "laguna-s-2.1": 4, "laguna-s-2.1.window": 4}
+
+
+@pytest.mark.parametrize("model", sorted(_DECODE_SHAPES))
+@pytest.mark.parametrize("n_pg", [1, 2, 3, 4, 8, 13, 16, 32, 64])
+def test_decode_block_rule(model, n_pg):
+    """The kv block of the decode kernel: a power of two, never wider
+    than the table, at most `_DECODE_BLOCK_BYTES` of K and
+    `_DECODE_BLOCK_KEYS` keys, within the VMEM budget it states, a
+    function of the shapes alone, and at the cells' shapes what the
+    chip read best (PERF.md, PR 37)."""
+    shape = _DECODE_SHAPES[model]
+    n = decode_block_pages(n_pg, **shape)
+    assert n >= 1 and n & (n - 1) == 0 and n <= n_pg
+    page = shape["page_size"] * shape["kv_lanes"] * shape["kv_itemsize"]
+    assert n == 1 or n * page <= pa._DECODE_BLOCK_BYTES
+    assert n * shape["page_size"] <= pa._DECODE_BLOCK_KEYS
+    H, lanes = shape["n_heads"], shape["kv_lanes"]
+    blocks = 2 * 2 * n * page                       # K, V; two buffers
+    rest = (2 * H * n * shape["page_size"] * 4      # scores, probabilities
+            + H * lanes * 12 + 2 * H * 128 * 4)     # q, block-diagonal q, acc
+    assert blocks + rest <= pa._DECODE_VMEM_BUDGET
+    assert n == decode_block_pages(n_pg, **dict(shape))     # no hidden input
+    assert n == min(_DECODE_BLOCK_AT_FULL_WIDTH[model],
+                    1 << (n_pg.bit_length() - 1))
+    # An int8 pool's block holds the same bytes, so twice the pages,
+    # while its f32 copies fit; a pool too wide for the budget gets a
+    # page a step, not a refusal.
+    assert decode_block_pages(32, 64, 2048, 1, 32) == 4
+    assert decode_block_pages(32, 64, 65536, 2, 256) == 1
+
+
+@pytest.mark.parametrize("case", ["tail", "ring", "none_live", "one_step"])
+def test_a_dead_column_holds_its_positions_page(case):
+    """`_held_pages`: column c is operand c % n of grid step c // n, and
+    an operand whose block index repeats is not fetched again. A slot's
+    null tail takes the pages its positions held at the last live step
+    (no null-page fetch a slot and layer); a ring's dead columns take
+    the last live page before them at their position or, with none, the
+    first after; a position that is never live keeps its own column; a
+    table of one step has nothing to hold."""
+    tables, live, n, want = {
+        "tail": ([[11, 12, 13, 14, 15, 0, 0, 0]],
+                 [[1, 1, 1, 1, 1, 0, 0, 0]], 2,
+                 [[11, 12, 13, 14, 15, 14, 15, 14]]),
+        "ring": ([[21, 22, 23, 24, 25, 26, 27, 28]],
+                 [[0, 0, 1, 1, 1, 0, 0, 1]], 2,
+                 [[23, 24, 23, 24, 25, 24, 25, 28]]),
+        "none_live": ([[0, 0, 0, 0], [31, 0, 0, 0]],
+                      [[1, 0, 0, 0], [1, 0, 0, 0]], 2,
+                      [[0, 0, 0, 0], [31, 0, 31, 0]]),
+        "one_step": ([[41, 0, 0, 0]], [[1, 0, 0, 0]], 4, [[41, 0, 0, 0]]),
+    }[case]
+    got = pa._held_pages(jnp.asarray(tables, jnp.int32),
+                         jnp.asarray(live, bool), n)
+    assert np.asarray(got).tolist() == want
 
 
 def test_page_ops_on_the_flat_pool():
@@ -535,11 +686,21 @@ WINDOW_HEADS = [(4, 4, 16), (6, 2, 128), (9, 1, 128)]
 RING = dict(ps=16, R=6, window=40)
 
 
+@pytest.mark.parametrize("block_keys", [16, 32, None])
 @pytest.mark.parametrize("heads", WINDOW_HEADS)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_window_decode_kernel_matches_oracle_and_dense(heads, dtype):
+def test_window_decode_kernel_matches_oracle_and_dense(heads, dtype,
+                                                       block_keys,
+                                                       monkeypatch):
+    """Contexts from one token (five of the ring's six columns hold no
+    page: `col_page` -1) to several turns of the ring, at a page a grid
+    step, at two (the block divides the ring) and at the rule's own four
+    (the ring padded to eight columns with null ones)."""
     H, G, K = heads
     ps, R, window = RING["ps"], RING["R"], RING["window"]
+    if block_keys is not None:
+        monkeypatch.setattr(pa, "_DECODE_BLOCK_KEYS", block_keys)
+    assert decode_block_pages(R, ps, G * K, 4, H) == (block_keys or 64) // ps
     lengths = [1, 17, 40, 41, 96, 97, 150, 271]
     rng = np.random.default_rng(7)
     k_pool, v_pool, tables, col_page, k_dense, v_dense = _ring(
@@ -569,9 +730,6 @@ def test_window_prefill_kernel_matches_oracle_and_dense(heads, split,
     edge and after the ring has turned, a ragged last row and an inert
     one; with the grid split by KV head (`prefill_kv_split`, forced here
     by a small VMEM budget) and without."""
-    import importlib
-
-    pa = importlib.import_module("ray_tpu.ops.paged_attention")
     H, G, K = heads
     ps, R, window, C = RING["ps"], RING["R"], RING["window"], 24
     if split:
@@ -641,7 +799,11 @@ def test_without_a_window_the_kernels_trace_what_they_did(model):
     """`window=None` hands the two families that have no window layer the
     calls they had: the names a trace knows, three and four scalar
     operands ahead of the blocks (no `col_page`), a (slot, kv block) grid
-    with no KV-head axis."""
+    with no KV-head axis. Since PR 37 a decode kv block is
+    `decode_block_pages` table columns, as a prefill block is
+    `prefill_block_pages`: over a table of 8 columns opt-1.3b's 256 KB
+    pages go two a step (4 steps a slot), zaya1-8b's 32 KB pages all
+    eight (1 step), each page an operand a pool."""
     H, G, K = (32, 32, 64) if model == "opt-1.3b" else (8, 2, 128)
     pool = jax.ShapeDtypeStruct((2, 9, 64, G * K), jnp.bfloat16)
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
@@ -653,12 +815,17 @@ def test_without_a_window_the_kernels_trace_what_they_did(model):
         q, k, v, jnp.int32(1), t, o, n, interpret=True))(
         jax.ShapeDtypeStruct((2, 128, H, K), jnp.bfloat16), pool, pool,
         i32(2, 8), i32(2), i32(2))
-    for jaxpr, name, scalars, grid in ((decode, "paged_decode_attn", 3, (4, 8)),
-                                       (chunk, "paged_prefill_attn", 4, (2, 2))):
+    n = decode_block_pages(8, 64, G * K, 2, H)
+    assert n == (2 if model == "opt-1.3b" else 8)
+    for jaxpr, name, scalars, grid, pages in (
+            (decode, "paged_decode_attn", 3, (4, 8 // n), n),
+            (chunk, "paged_prefill_attn", 4, (2, 2), 4)):
         (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
         spec = call.params["grid_mapping"]
         assert call.params["name"] == name
         assert spec.num_index_operands == scalars and spec.grid == grid
+        # q, a block of K pages, a block of V pages, and the output
+        assert len(spec.block_mappings) == 2 * pages + 2
     shape = _CELL_SHAPES[model]
     from ray_tpu.ops.paged_attention import prefill_kv_split
     assert prefill_kv_split(shape["kv_lanes"], shape["chunk"],
