@@ -27,6 +27,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops import scopes
+
 
 @dataclasses.dataclass(frozen=True)
 class GPTConfig:
@@ -382,24 +384,32 @@ def _block(
     x: jax.Array, layer: dict[str, jax.Array], cfg: GPTConfig, mesh=None
 ) -> jax.Array:
     """One pre-norm transformer block. x: [B, S, D]."""
-    h = _layer_norm(x, layer["ln1_scale"], layer["ln1_bias"])
-    q = jnp.einsum("bsd,dhk->bshk", h, weight_view(layer, "wq", cfg.dtype))
-    k = jnp.einsum("bsd,dhk->bshk", h, weight_view(layer, "wk", cfg.dtype))
-    v = jnp.einsum("bsd,dhk->bshk", h, weight_view(layer, "wv", cfg.dtype))
-    q = _rotary(q, cfg.rotary_dim)
-    k = _rotary(k, cfg.rotary_dim)
-    attn = _attention(q, k, v, cfg, mesh=mesh)
-    attn_out = jnp.einsum("bshk,hkd->bsd", attn,
-                          weight_view(layer, "wo", cfg.dtype))
-    x = x + attn_out
-    h = _layer_norm(x, layer["ln2_scale"], layer["ln2_bias"])
-    up = jnp.einsum("bsd,df->bsf", h, weight_view(layer, "w_up", cfg.dtype))
-    up = up + layer["b_up"].astype(cfg.dtype)
-    up = jax.nn.gelu(up)
-    down = jnp.einsum("bsf,fd->bsd", up,
-                      weight_view(layer, "w_down", cfg.dtype))
-    down = down + layer["b_down"].astype(cfg.dtype)
-    return x + down
+    with jax.named_scope(scopes.ATTN_IN):
+        h = _layer_norm(x, layer["ln1_scale"], layer["ln1_bias"])
+        q = jnp.einsum("bsd,dhk->bshk", h,
+                       weight_view(layer, "wq", cfg.dtype))
+        k = jnp.einsum("bsd,dhk->bshk", h,
+                       weight_view(layer, "wk", cfg.dtype))
+        v = jnp.einsum("bsd,dhk->bshk", h,
+                       weight_view(layer, "wv", cfg.dtype))
+        q = _rotary(q, cfg.rotary_dim)
+        k = _rotary(k, cfg.rotary_dim)
+    with jax.named_scope(scopes.ATTN_KERNEL):
+        attn = _attention(q, k, v, cfg, mesh=mesh)
+    with jax.named_scope(scopes.ATTN_OUT):
+        attn_out = jnp.einsum("bshk,hkd->bsd", attn,
+                              weight_view(layer, "wo", cfg.dtype))
+        x = x + attn_out
+    with jax.named_scope(scopes.MLP):
+        h = _layer_norm(x, layer["ln2_scale"], layer["ln2_bias"])
+        up = jnp.einsum("bsd,df->bsf", h,
+                        weight_view(layer, "w_up", cfg.dtype))
+        up = up + layer["b_up"].astype(cfg.dtype)
+        up = jax.nn.gelu(up)
+        down = jnp.einsum("bsf,fd->bsd", up,
+                          weight_view(layer, "w_down", cfg.dtype))
+        down = down + layer["b_down"].astype(cfg.dtype)
+        return x + down
 
 
 _BLOCK_KEYS = (
@@ -420,7 +430,8 @@ def forward_hidden(
     sp-sharded ring attention) and, on more than one device, "flash" each
     run in an explicit shard_map over it.
     """
-    x = params["wte"].astype(cfg.dtype)[tokens]
+    with jax.named_scope(scopes.EMBED):
+        x = params["wte"].astype(cfg.dtype)[tokens]
     stacked = stack_block_params(params)
     block_fn = lambda x, layer: _block(x, layer, cfg, mesh)
 
@@ -429,7 +440,8 @@ def forward_hidden(
         return fn(x, layer), None
 
     x, _ = jax.lax.scan(body, x, stacked)
-    return _layer_norm(x, params["ln_f_scale"], params["ln_f_bias"])
+    with jax.named_scope(scopes.HEAD):
+        return _layer_norm(x, params["ln_f_scale"], params["ln_f_bias"])
 
 
 def _head_matrix(params, cfg: GPTConfig):
@@ -445,11 +457,11 @@ def forward(
 ) -> jax.Array:
     """tokens: [B, S] int32 → logits [B, S, V] (fp32)."""
     x = forward_hidden(params, tokens, cfg, mesh)
-    logits = jnp.einsum(
-        "bsd,dv->bsv", x, _head_matrix(params, cfg),
-        preferred_element_type=jnp.float32,
-    )
-    return logits
+    with jax.named_scope(scopes.HEAD):
+        return jnp.einsum(
+            "bsd,dv->bsv", x, _head_matrix(params, cfg),
+            preferred_element_type=jnp.float32,
+        )
 
 
 def forward_pipeline(
@@ -466,7 +478,8 @@ def forward_pipeline(
     Requires cfg.n_layers % mesh.shape['pp'] == 0."""
     from ray_tpu.parallel.pipeline import pipeline_apply
 
-    x = params["wte"].astype(cfg.dtype)[tokens]
+    with jax.named_scope(scopes.EMBED):
+        x = params["wte"].astype(cfg.dtype)[tokens]
     stacked = stack_block_params(params)
 
     def stage(local_stack, act):
@@ -479,20 +492,22 @@ def forward_pipeline(
         return a
 
     x = pipeline_apply(stage, stacked, x, mesh=mesh, n_micro=n_micro)
-    x = _layer_norm(x, params["ln_f_scale"], params["ln_f_bias"])
-    head = params["lm_head"] if not cfg.tie_embeddings else params["wte"].T
-    return jnp.einsum(
-        "bsd,dv->bsv", x, head.astype(cfg.dtype),
-        preferred_element_type=jnp.float32,
-    )
+    with jax.named_scope(scopes.HEAD):
+        x = _layer_norm(x, params["ln_f_scale"], params["ln_f_bias"])
+        return jnp.einsum(
+            "bsd,dv->bsv", x, _head_matrix(params, cfg),
+            preferred_element_type=jnp.float32,
+        )
 
 
 def pipeline_loss_fn(params, tokens, targets, cfg: GPTConfig, mesh,
                      n_micro: int) -> jax.Array:
     logits = forward_pipeline(params, tokens, cfg, mesh, n_micro)
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-    return jnp.mean(logz - gold)
+    with jax.named_scope(scopes.LOSS):
+        logz = jax.nn.logsumexp(logits, axis=-1)
+        gold = jnp.take_along_axis(
+            logits, targets[..., None], axis=-1)[..., 0]
+        return jnp.mean(logz - gold)
 
 
 def loss_fn(
@@ -511,14 +526,17 @@ def loss_fn(
     chunks inside the scan's own autodiff).
     """
     x = forward_hidden(params, tokens, cfg, mesh)
-    head = _head_matrix(params, cfg)
+    with jax.named_scope(scopes.HEAD):
+        head = _head_matrix(params, cfg)
     if cfg.loss_chunk is None or tokens.shape[1] <= cfg.loss_chunk:
-        logits = jnp.einsum(
-            "bsd,dv->bsv", x, head, preferred_element_type=jnp.float32)
-        logz = jax.nn.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(
-            logits, targets[..., None], axis=-1)[..., 0]
-        return jnp.mean(logz - gold)
+        with jax.named_scope(scopes.HEAD):
+            logits = jnp.einsum(
+                "bsd,dv->bsv", x, head, preferred_element_type=jnp.float32)
+        with jax.named_scope(scopes.LOSS):
+            logz = jax.nn.logsumexp(logits, axis=-1)
+            gold = jnp.take_along_axis(
+                logits, targets[..., None], axis=-1)[..., 0]
+            return jnp.mean(logz - gold)
     S = tokens.shape[1]
     C = cfg.loss_chunk
     if S % C != 0:
@@ -528,11 +546,14 @@ def loss_fn(
 
     @jax.checkpoint
     def chunk_ce(x_c, t_c):
-        logits = jnp.einsum(
-            "bcd,dv->bcv", x_c, head, preferred_element_type=jnp.float32)
-        logz = jax.nn.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, t_c[..., None], axis=-1)[..., 0]
-        return jnp.sum(logz - gold)
+        with jax.named_scope(scopes.HEAD):
+            logits = jnp.einsum(
+                "bcd,dv->bcv", x_c, head, preferred_element_type=jnp.float32)
+        with jax.named_scope(scopes.LOSS):
+            logz = jax.nn.logsumexp(logits, axis=-1)
+            gold = jnp.take_along_axis(
+                logits, t_c[..., None], axis=-1)[..., 0]
+            return jnp.sum(logz - gold)
 
     def body(tot, chunk):
         x_c, t_c = chunk

@@ -71,6 +71,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_tpu.ops import scopes
 from ray_tpu.models.paged_kv import _decode_window, _no_phase, _sample_next
 from ray_tpu.models.zaya import _attend_fn, _rms_norm
 from ray_tpu.ops.moe import token_choice_experts
@@ -256,6 +257,7 @@ def _rope(x, pos, inv_freq: np.ndarray, factor: float):
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1)
 
 
+@jax.named_scope(scopes.ATTN_IN)
 def _attn_inputs(cfg: LagunaConfig, params, l: int, x, pos):
     """Layer l's attention sublayer up to q, k, v and the heads' gates.
     x [N, C, D], pos [N, C] → (q [N, C, H, K], k, v [N, C, G, K] in
@@ -283,6 +285,7 @@ def _gated_mlp(u, w_gate, w_up, w_down):
                       preferred_element_type=_F32)
 
 
+@jax.named_scope(scopes.MOE_ROUTE)
 def _route(cfg: LagunaConfig, w_router, u):
     """The router, float32 throughout. u [M, D] → (experts [M, k] int32
     global ids, gates [M, k] float32: `routed_scale` times the chosen
@@ -303,23 +306,30 @@ def _finish_block(cfg: LagunaConfig, params, l: int, x, attn, gate, valid):
     N, C, D = x.shape
     dt = cfg.dtype
     kind, i, mlp, j = cfg.index(l)
-    o = (attn.astype(_F32) * gate[..., None]).astype(dt)
-    x = x + o.reshape(N, C, -1) @ params[kind[0] + "_wo"][i].astype(dt)
-    u = _rms_norm(x, params["ln2_scale"][l], cfg.norm_eps).reshape(N * C, D)
-    if mlp == "dense":
-        f = _gated_mlp(u, params["d_gate"][j], params["d_up"][j],
-                       params["d_down"][j])
-        return x + f.astype(dt).reshape(N, C, D), None
+    with jax.named_scope(scopes.ATTN_OUT):
+        o = (attn.astype(_F32) * gate[..., None]).astype(dt)
+        x = x + o.reshape(N, C, -1) @ params[kind[0] + "_wo"][i].astype(dt)
+    with jax.named_scope(scopes.MLP):
+        u = _rms_norm(x, params["ln2_scale"][l],
+                      cfg.norm_eps).reshape(N * C, D)
+        if mlp == "dense":
+            f = _gated_mlp(u, params["d_gate"][j], params["d_up"][j],
+                           params["d_down"][j])
+            return x + f.astype(dt).reshape(N, C, D), None
     chosen, gates = _route(cfg, params["router"][j], u)
+    with jax.named_scope(scopes.MOE_EXPERTS):
+        experts = tuple(params[k].astype(dt) for k in _EXPERT_KEYS)
     routed, counts = token_choice_experts(
-        u, chosen, gates, *(params[k].astype(dt) for k in _EXPERT_KEYS),
+        u, chosen, gates, *experts,
         first_expert=cfg.first_expert, layer=j, valid=valid.reshape(-1))
-    shared = _gated_mlp(u, params["s_gate"][j], params["s_up"][j],
-                        params["s_down"][j])
-    f = (shared + routed.astype(_F32)).astype(dt)
-    return x + f.reshape(N, C, D), counts
+    with jax.named_scope(scopes.MLP):
+        shared = _gated_mlp(u, params["s_gate"][j], params["s_up"][j],
+                            params["s_down"][j])
+        f = (shared + routed.astype(_F32)).astype(dt)
+        return x + f.reshape(N, C, D), counts
 
 
+@jax.named_scope(scopes.HEAD)
 def _head(cfg: LagunaConfig, params, x):
     """Final RMSNorm and the untied head → float32 logits [..., V]."""
     h = _rms_norm(x, params["ln_f_scale"], cfg.norm_eps)
@@ -335,18 +345,22 @@ def forward(cfg: LagunaConfig, params, tokens):
     B, S = tokens.shape
     pos = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
     ahead = jnp.arange(S)[:, None] - jnp.arange(S)[None, :]     # i - j
-    x = params["wte"].astype(cfg.dtype)[tokens]
+    with jax.named_scope(scopes.EMBED):
+        x = params["wte"].astype(cfg.dtype)[tokens]
     for l, kind in enumerate(cfg.kinds):
         q, k, v, gate = _attn_inputs(cfg, params, l, x, pos)
-        g = cfg.heads(kind) // cfg.n_kv_heads
-        k, v = (jnp.repeat(t, g, axis=2) for t in (k, v))
-        seen = ahead >= 0
-        if kind == "window":
-            seen &= ahead < cfg.window
-        s = jnp.einsum("bshk,bthk->bhst", q, k, preferred_element_type=_F32)
-        s = jnp.where(seen[None, None], s / math.sqrt(cfg.head_dim), -1e30)
-        attn = jnp.einsum("bhst,bthk->bshk",
-                          jax.nn.softmax(s, axis=-1).astype(cfg.dtype), v)
+        with jax.named_scope(scopes.ATTN_KERNEL):
+            g = cfg.heads(kind) // cfg.n_kv_heads
+            k, v = (jnp.repeat(t, g, axis=2) for t in (k, v))
+            seen = ahead >= 0
+            if kind == "window":
+                seen &= ahead < cfg.window
+            s = jnp.einsum("bshk,bthk->bhst", q, k,
+                           preferred_element_type=_F32)
+            s = jnp.where(seen[None, None],
+                          s / math.sqrt(cfg.head_dim), -1e30)
+            attn = jnp.einsum("bhst,bthk->bshk",
+                              jax.nn.softmax(s, axis=-1).astype(cfg.dtype), v)
         x, _counts = _finish_block(cfg, params, l, x, attn, gate,
                                    jnp.ones((B, S), bool))
     return _head(cfg, params, x)
@@ -394,6 +408,7 @@ def init_paged_kv(cfg: LagunaConfig, n_pages: int, page_size: int,
             "moe_counters": jnp.zeros(len(_COUNTERS), jnp.uint32)}
 
 
+@jax.named_scope(scopes.ATTN_KERNEL)
 def _ring_view(pool, slots, lengths, page_size: int):
     """(table [N, R] of ring rows, col_page [N, R]) for rows that belong
     to `slots` [N] and have `lengths` [N] tokens written."""
@@ -404,6 +419,7 @@ def _ring_view(pool, slots, lengths, page_size: int):
     return pool["ring_rows"][slots], jnp.where(col_page < 0, -1, col_page)
 
 
+@jax.named_scope(scopes.ATTN_KV_WRITE)
 def _ring_targets(pool, table, pos, live, page_size: int):
     """Ring rows the tokens at `pos` [N, C] are written to (the null
     slot's first row where `live` [N, C] is false), flat [N*C]."""
@@ -416,6 +432,7 @@ def _ring_targets(pool, table, pos, live, page_size: int):
 _PLANES = {"full": ("k", "v"), "window": ("k_win", "v_win")}
 
 
+@jax.named_scope(scopes.ATTN_KV_WRITE)
 def _write_kv(pool, kind: str, i: int, pages, offs, k, v):
     """K/V rows [M, G*K] → (i, pages[m], offs[m]) of a kind's planes."""
     rows = lambda t: t.reshape(-1, t.shape[-2] * t.shape[-1])
@@ -440,7 +457,8 @@ def _paged_layers(cfg: LagunaConfig, params, x, pos, valid, pool, attend,
         q, k, v, gate = _attn_inputs(cfg, params, l, x, pos)
         pool = _write_kv(pool, kind, i, pages, offs, k, v)
         kn, vn = _PLANES[kind]
-        attn = attend(q, pool[kn], pool[vn], i, table, **kw)
+        with jax.named_scope(scopes.ATTN_KERNEL):
+            attn = attend(q, pool[kn], pool[vn], i, table, **kw)
         x, n = _finish_block(cfg, params, l, x, attn, gate, valid)
         if n is not None:
             counts.append(n)
@@ -465,16 +483,19 @@ def _chunk_forward(cfg: LagunaConfig, params, tokens, pool, tables, offsets,
     valid = rel[None, :] < n_valid[:, None]
     kv_lens = offsets + n_valid
     # The full kind's write targets, as models/paged_kv sets them.
-    page_idx = jnp.minimum(pos // ps, tables.shape[1] - 1)
-    full_pages = jnp.where(valid, jnp.take_along_axis(tables, page_idx,
-                                                      axis=1), 0).reshape(-1)
+    with jax.named_scope(scopes.ATTN_KV_WRITE):
+        page_idx = jnp.minimum(pos // ps, tables.shape[1] - 1)
+        full_pages = jnp.where(
+            valid, jnp.take_along_axis(tables, page_idx, axis=1),
+            0).reshape(-1)
     ring_table, col_page = _ring_view(pool, slots, kv_lens, ps)
     ring_targets = _ring_targets(pool, ring_table, pos, valid, ps)
     scale = 1.0 / math.sqrt(cfg.head_dim)
     attend = _attend_fn(attn_impl, chunk=True)
     reader = lambda q, kp, vp, i, table, **kw: attend(
         q, kp, vp, i, table, offsets, kv_lens, sm_scale=scale, **kw)
-    x = params["wte"].astype(cfg.dtype)[tokens]
+    with jax.named_scope(scopes.EMBED):
+        x = params["wte"].astype(cfg.dtype)[tokens]
     x, pool, _counts = _paged_layers(
         cfg, params, x, pos, valid, pool, reader,
         (full_pages, tables, {}),
@@ -499,8 +520,9 @@ def prefill_chunk_paged(cfg: LagunaConfig, params, tokens, pool, tables,
                              n_valid, slots, attn_impl)
     if not return_logits:
         return None, pool
-    last = jnp.take_along_axis(
-        x, jnp.maximum(n_valid - 1, 0)[:, None, None], axis=1)[:, 0]
+    with jax.named_scope(scopes.HEAD):
+        last = jnp.take_along_axis(
+            x, jnp.maximum(n_valid - 1, 0)[:, None, None], axis=1)[:, 0]
     return _head(cfg, params, last), pool
 
 
@@ -522,9 +544,11 @@ def _decode_once(cfg: LagunaConfig, params, tokens, pool, positions, tables,
     ps = pool["k"].shape[2]
     active = tables[:, 0] > 0
     pos = positions[:, None]
-    full_pages = jnp.take_along_axis(
-        tables, jnp.minimum(positions // ps, tables.shape[1] - 1)[:, None],
-        axis=1)[:, 0]
+    with jax.named_scope(scopes.ATTN_KV_WRITE):
+        full_pages = jnp.take_along_axis(
+            tables,
+            jnp.minimum(positions // ps, tables.shape[1] - 1)[:, None],
+            axis=1)[:, 0]
     ring_table, col_page = _ring_view(pool, jnp.arange(B), positions + 1, ps)
     ring_targets = _ring_targets(pool, ring_table, pos, active[:, None], ps)
     scale = 1.0 / math.sqrt(cfg.head_dim)
@@ -532,15 +556,17 @@ def _decode_once(cfg: LagunaConfig, params, tokens, pool, positions, tables,
     reader = lambda q, kp, vp, i, table, **kw: attend(
         q[:, 0], kp, vp, i, table, positions + 1, sm_scale=scale,
         **kw)[:, None]
-    x = params["wte"].astype(cfg.dtype)[tokens[:, None]]
+    with jax.named_scope(scopes.EMBED):
+        x = params["wte"].astype(cfg.dtype)[tokens[:, None]]
     x, pool, counts = _paged_layers(
         cfg, params, x, pos, active[:, None], pool, reader,
         (full_pages, tables, {}),
         (ring_targets, ring_table,
          {"window": cfg.window, "col_page": col_page}))
-    n_live = jnp.sum(active)
-    counters = pool["moe_counters"] + sum(_count(cfg, n, n_live)
-                                          for n in counts)
+    with jax.named_scope(scopes.COUNTERS):
+        n_live = jnp.sum(active)
+        counters = pool["moe_counters"] + sum(_count(cfg, n, n_live)
+                                              for n in counts)
     return _head(cfg, params, x[:, 0]), {**pool, "moe_counters": counters}
 
 

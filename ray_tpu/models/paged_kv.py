@@ -64,6 +64,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_tpu.ops import scopes
 from ray_tpu.models.gpt import (GPTConfig, _layer_norm, stack_block_params,
                                 weight_view)
 from ray_tpu.models.decode import _head, _mlp, _qkv, _rotary_pos
@@ -156,6 +157,7 @@ def _quant_write_full_pages(plane, scale, l, pages, values, tp_axis=None):
             scale.at[l].set(new_scale.astype(scale.dtype)))
 
 
+@jax.named_scope(scopes.ATTN_KV_WRITE)
 def _write_rows(pool, l, write_pages, write_offs, k_rows, v_rows,
                 tp_axis=None):
     """K/V rows [M, H*K] → ``(l, write_pages[m], write_offs[m])`` of the
@@ -245,6 +247,16 @@ def scatter_pages(pool, pages, payload):
     return {k: pool[k].at[:, pages].set(payload[k]) for k in pool}
 
 
+@jax.named_scope(scopes.ATTN_IN)
+def _attn_in(cfg: GPTConfig, layer, x, pos):
+    """Pre-norm, q/k/v projections and rotary of one layer: the part of
+    every paged body before its K/V write."""
+    h = _layer_norm(x, layer["ln1_scale"], layer["ln1_bias"])
+    q, k, v = _qkv(h, layer, cfg)
+    return (_rotary_pos(q, cfg.rotary_dim, pos),
+            _rotary_pos(k, cfg.rotary_dim, pos), v)
+
+
 @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(3,))
 def prefill_batch_paged(cfg: GPTConfig, params, tokens, pool, pages, lengths):
     """Prefill N prompts, scattering their K/V into allocated pages.
@@ -259,7 +271,8 @@ def prefill_batch_paged(cfg: GPTConfig, params, tokens, pool, pages, lengths):
     n_pg = pages.shape[1]
     S_pad = n_pg * ps
     quant = "k_scale" in pool
-    x = params["wte"].astype(cfg.dtype)[tokens]            # [N, S, D]
+    with jax.named_scope(scopes.EMBED):
+        x = params["wte"].astype(cfg.dtype)[tokens]        # [N, S, D]
     pos = jnp.broadcast_to(jnp.arange(S)[None, :], (N, S))
     # One up-front cast of the stacked block params (the per-layer
     # weight_view casts inside the scan body become no-ops; int8 planes
@@ -269,40 +282,44 @@ def prefill_batch_paged(cfg: GPTConfig, params, tokens, pool, pages, lengths):
     flat_pages = pages.reshape(-1)                         # [N * n_pg]
 
     def body(x, layer, l, pool):
-        h = _layer_norm(x, layer["ln1_scale"], layer["ln1_bias"])
-        q, k, v = _qkv(h, layer, cfg)
-        q = _rotary_pos(q, cfg.rotary_dim, pos)
-        k = _rotary_pos(k, cfg.rotary_dim, pos)
-        logits = jnp.einsum("bshk,bthk->bhst", q, k,
-                            preferred_element_type=jnp.float32) * scale
-        causal = jnp.arange(S)[:, None] >= jnp.arange(S)[None, :]
-        logits = jnp.where(causal[None, None], logits, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1).astype(cfg.dtype)
-        attn = jnp.einsum("bhst,bthk->bshk", probs, v)
-        x = x + jnp.einsum("bshk,hkd->bsd", attn,
-                           weight_view(layer, "wo", cfg.dtype))
-        x = _mlp(x, layer, cfg)
+        q, k, v = _attn_in(cfg, layer, x, pos)
+        with jax.named_scope(scopes.ATTN_KERNEL):
+            logits = jnp.einsum("bshk,bthk->bhst", q, k,
+                                preferred_element_type=jnp.float32) * scale
+            causal = jnp.arange(S)[:, None] >= jnp.arange(S)[None, :]
+            logits = jnp.where(causal[None, None], logits, -1e30)
+            probs = jax.nn.softmax(logits, axis=-1).astype(cfg.dtype)
+            attn = jnp.einsum("bhst,bthk->bshk", probs, v)
+        with jax.named_scope(scopes.ATTN_OUT):
+            x = x + jnp.einsum("bshk,hkd->bsd", attn,
+                               weight_view(layer, "wo", cfg.dtype))
+        with jax.named_scope(scopes.MLP):
+            x = _mlp(x, layer, cfg)
 
         def paged(arr):                            # [N,S,H,K] → whole pages
             a = jnp.pad(arr, ((0, 0), (0, S_pad - S), (0, 0), (0, 0)))
             return a.reshape(N * n_pg, ps, cfg.n_heads * cfg.head_dim)
 
-        if quant:
-            k_pl, k_sc = _quant_write_full_pages(
-                pool["k"], pool["k_scale"], l, flat_pages, paged(k))
-            v_pl, v_sc = _quant_write_full_pages(
-                pool["v"], pool["v_scale"], l, flat_pages, paged(v))
-            return x, {"k": k_pl, "v": v_pl,
-                       "k_scale": k_sc, "v_scale": v_sc}
-        return x, {
-            "k": pool["k"].at[l, flat_pages].set(paged(k.astype(cfg.dtype))),
-            "v": pool["v"].at[l, flat_pages].set(paged(v.astype(cfg.dtype)))}
+        with jax.named_scope(scopes.ATTN_KV_WRITE):
+            if quant:
+                k_pl, k_sc = _quant_write_full_pages(
+                    pool["k"], pool["k_scale"], l, flat_pages, paged(k))
+                v_pl, v_sc = _quant_write_full_pages(
+                    pool["v"], pool["v_scale"], l, flat_pages, paged(v))
+                return x, {"k": k_pl, "v": v_pl,
+                           "k_scale": k_sc, "v_scale": v_sc}
+            return x, {
+                "k": pool["k"].at[l, flat_pages].set(
+                    paged(k.astype(cfg.dtype))),
+                "v": pool["v"].at[l, flat_pages].set(
+                    paged(v.astype(cfg.dtype)))}
 
     x, pool = _scan_pool_layers(body, x, stacked, pool)
-    logits = _head(params, cfg, x)                         # [N, S, V]
-    last = jnp.take_along_axis(
-        logits, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1
-    )[:, 0]
+    with jax.named_scope(scopes.HEAD):
+        logits = _head(params, cfg, x)                     # [N, S, V]
+        last = jnp.take_along_axis(
+            logits, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1
+        )[:, 0]
     return last, pool
 
 
@@ -322,7 +339,8 @@ def _chunk_paged_forward(cfg: GPTConfig, params, tokens, pool, tables,
     → (hidden states [N, C, D], updated pool)."""
     N, C = tokens.shape
     ps = pool["k"].shape[2]
-    x = params["wte"].astype(cfg.dtype)[tokens]            # [N, C, D]
+    with jax.named_scope(scopes.EMBED):
+        x = params["wte"].astype(cfg.dtype)[tokens]        # [N, C, D]
     rel = jnp.arange(C)
     pos = offsets[:, None] + rel[None, :]                  # [N, C]
     stacked = stack_block_params(params, cfg.dtype)
@@ -334,18 +352,16 @@ def _chunk_paged_forward(cfg: GPTConfig, params, tokens, pool, tables,
     # sliced width on any row whose offset sits near the bucket edge.
     # Only those write-masked pad positions ever hit the clamp: valid
     # positions fall inside the sliced width by bucket construction.
-    page_idx = jnp.minimum(pos // ps, tables.shape[1] - 1)
-    row_pages = jnp.take_along_axis(tables, page_idx, axis=1)   # [N, C]
-    write_pages = jnp.where(rel[None, :] < n_valid[:, None],
-                            row_pages, 0).reshape(-1)           # [N*C]
-    write_offs = (pos % ps).reshape(-1)                         # [N*C]
+    with jax.named_scope(scopes.ATTN_KV_WRITE):
+        page_idx = jnp.minimum(pos // ps, tables.shape[1] - 1)
+        row_pages = jnp.take_along_axis(tables, page_idx, axis=1)  # [N, C]
+        write_pages = jnp.where(rel[None, :] < n_valid[:, None],
+                                row_pages, 0).reshape(-1)          # [N*C]
+        write_offs = (pos % ps).reshape(-1)                        # [N*C]
     kv_lens = offsets + n_valid                                 # [N]
 
     def body(x, layer, l, pool):
-        h = _layer_norm(x, layer["ln1_scale"], layer["ln1_bias"])
-        q, k, v = _qkv(h, layer, cfg)
-        q = _rotary_pos(q, cfg.rotary_dim, pos)
-        k = _rotary_pos(k, cfg.rotary_dim, pos)
+        q, k, v = _attn_in(cfg, layer, x, pos)
         # Write before attending (same order as the decode path): each
         # row then reads its own chunk's K/V back through its table, so
         # intra-chunk causality is just the tpos <= qpos mask.
@@ -362,15 +378,19 @@ def _chunk_paged_forward(cfg: GPTConfig, params, tokens, pool, tables,
                 reference_paged_prefill_attention)
 
             attend = reference_paged_prefill_attention
-        attn = attend(q, pool["k"], pool["v"], l, tables, offsets, kv_lens,
-                      sm_scale=scale, k_scale=pool.get("k_scale"),
-                      v_scale=pool.get("v_scale"))
-        attn_out = jnp.einsum("bchk,hkd->bcd", attn,
-                              weight_view(layer, "wo", cfg.dtype))
-        if tp_axis is not None:
-            attn_out = jax.lax.psum(attn_out, tp_axis)
-        x = x + attn_out
-        x = _mlp(x, layer, cfg, tp_axis=tp_axis)
+        with jax.named_scope(scopes.ATTN_KERNEL):
+            attn = attend(q, pool["k"], pool["v"], l, tables, offsets,
+                          kv_lens, sm_scale=scale,
+                          k_scale=pool.get("k_scale"),
+                          v_scale=pool.get("v_scale"))
+        with jax.named_scope(scopes.ATTN_OUT):
+            attn_out = jnp.einsum("bchk,hkd->bcd", attn,
+                                  weight_view(layer, "wo", cfg.dtype))
+            if tp_axis is not None:
+                attn_out = jax.lax.psum(attn_out, tp_axis)
+            x = x + attn_out
+        with jax.named_scope(scopes.MLP):
+            x = _mlp(x, layer, cfg, tp_axis=tp_axis)
         return x, pool
 
     x, pool = _scan_pool_layers(body, x, stacked, pool)
@@ -466,7 +486,8 @@ def verify_chunk_paged(cfg: GPTConfig, params, tokens, pool, tables,
             f"attn_impl must be gather|kernel, got {attn_impl!r}")
     x, pool = _chunk_paged_forward(cfg, params, tokens, pool, tables,
                                    offsets, n_valid, attn_impl)
-    return _head(params, cfg, x), pool                     # [N, C, V]
+    with jax.named_scope(scopes.HEAD):
+        return _head(params, cfg, x), pool                 # [N, C, V]
 
 
 def _decode_once_paged(cfg: GPTConfig, params, tokens, pool, positions,
@@ -494,7 +515,8 @@ def _decode_once_paged(cfg: GPTConfig, params, tokens, pool, positions,
         raise ValueError(
             f"attn_impl must be gather|kernel, got {attn_impl!r}")
     ps = pool["k"].shape[2]
-    x = params["wte"].astype(cfg.dtype)[tokens][:, None, :]  # [B, 1, D]
+    with jax.named_scope(scopes.EMBED):
+        x = params["wte"].astype(cfg.dtype)[tokens][:, None, :]  # [B, 1, D]
     pos = positions[:, None]
     # Pre-cast the stacked block params once: the per-layer weight_view
     # casts inside the scan body become no-ops instead of re-lowering a
@@ -506,19 +528,18 @@ def _decode_once_paged(cfg: GPTConfig, params, tokens, pool, positions,
     # once here, never inside the scan body. The page index is clamped
     # (like the chunk path) because a masked draft step's position can
     # run past the table on a near-max-len slot.
-    write_page = jnp.take_along_axis(
-        tables, jnp.minimum(positions // ps, tables.shape[1] - 1)[:, None],
-        axis=1)[:, 0]                                        # [B]
-    if write_mask is not None:
-        write_page = jnp.where(write_mask, write_page, 0)
-    write_off = positions % ps                               # [B]
+    with jax.named_scope(scopes.ATTN_KV_WRITE):
+        write_page = jnp.take_along_axis(
+            tables,
+            jnp.minimum(positions // ps, tables.shape[1] - 1)[:, None],
+            axis=1)[:, 0]                                    # [B]
+        if write_mask is not None:
+            write_page = jnp.where(write_mask, write_page, 0)
+        write_off = positions % ps                           # [B]
     kv_lengths = positions + 1                               # [B]
 
     def body(x, layer, l, pool):
-        h = _layer_norm(x, layer["ln1_scale"], layer["ln1_bias"])
-        q, k, v = _qkv(h, layer, cfg)
-        q = _rotary_pos(q, cfg.rotary_dim, pos)
-        k = _rotary_pos(k, cfg.rotary_dim, pos)
+        q, k, v = _attn_in(cfg, layer, x, pos)
         pool = _write_rows(pool, l, write_page, write_off,
                            _rows(k), _rows(v), tp_axis)
         if attn_impl == "kernel":
@@ -537,22 +558,28 @@ def _decode_once_paged(cfg: GPTConfig, params, tokens, pool, positions,
                 reference_paged_attention)
 
             attend = reference_paged_attention
-        attn = attend(q[:, 0], pool["k"], pool["v"], l, tables, kv_lengths,
-                      sm_scale=scale, k_scale=pool.get("k_scale"),
-                      v_scale=pool.get("v_scale"))
-        attn_out = jnp.einsum("bhk,hkd->bd", attn,
-                              weight_view(layer, "wo", cfg.dtype))
-        if tp_axis is not None:
-            attn_out = jax.lax.psum(attn_out, tp_axis)
-        x = x + attn_out[:, None, :]
-        x = _mlp(x, layer, cfg, tp_axis=tp_axis)
+        with jax.named_scope(scopes.ATTN_KERNEL):
+            attn = attend(q[:, 0], pool["k"], pool["v"], l, tables,
+                          kv_lengths, sm_scale=scale,
+                          k_scale=pool.get("k_scale"),
+                          v_scale=pool.get("v_scale"))
+        with jax.named_scope(scopes.ATTN_OUT):
+            attn_out = jnp.einsum("bhk,hkd->bd", attn,
+                                  weight_view(layer, "wo", cfg.dtype))
+            if tp_axis is not None:
+                attn_out = jax.lax.psum(attn_out, tp_axis)
+            x = x + attn_out[:, None, :]
+        with jax.named_scope(scopes.MLP):
+            x = _mlp(x, layer, cfg, tp_axis=tp_axis)
         return x, pool
 
     x, pool = _scan_pool_layers(body, x, stacked, pool)
-    logits = _head(params, cfg, x)[:, 0]
+    with jax.named_scope(scopes.HEAD):
+        logits = _head(params, cfg, x)[:, 0]
     return logits, pool
 
 
+@jax.named_scope(scopes.SAMPLE)
 def _sample_next(logits, temps, key):
     """Shared on-device sampling step for every fused loop (decode
     window + speculative draft, tp and non-tp twins alike): greedy
@@ -566,6 +593,7 @@ def _sample_next(logits, temps, key):
     return nxt, scaled, key
 
 
+@jax.named_scope(scopes.HEAD)
 def _last_valid_logits(cfg: GPTConfig, params, x, n_valid):
     """Chunk-head epilogue shared by `prefill_chunk_paged` and its tp
     twin: LM head over the chunk hiddens, then each row's logits at its
@@ -592,7 +620,9 @@ def _spec_propose_scan(cfg: GPTConfig, params, tokens, pool, positions,
             cfg, params, toks, pool, pos, tables, attn_impl,
             write_mask=i <= n_prop, tp_axis=tp_axis)
         nxt, scaled, key = _sample_next(logits, temps, key)
-        ys = (nxt, jax.nn.softmax(scaled, axis=-1)) if need_probs else nxt
+        with jax.named_scope(scopes.SAMPLE):
+            ys = ((nxt, jax.nn.softmax(scaled, axis=-1)) if need_probs
+                  else nxt)
         return (nxt, pos + 1, pool, key), ys
 
     carry0 = (tokens, positions, pool, key)
@@ -856,7 +886,8 @@ def verify_chunk_paged_tp(cfg: GPTConfig, params, tokens, pool, tables,
                     (pspecs, rep, kvspecs, rep, rep, rep),
                     (rep, kvspecs))(
         params, tokens, pool, tables, offsets, n_valid)
-    return _head(params, cfg, x), pool                     # [N, C, V]
+    with jax.named_scope(scopes.HEAD):
+        return _head(params, cfg, x), pool                 # [N, C, V]
 
 
 @functools.partial(jax.jit, static_argnums=(0,),

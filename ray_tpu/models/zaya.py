@@ -57,6 +57,7 @@ from typing import Any, ClassVar
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops import scopes
 from ray_tpu.models.paged_kv import (_decode_window, _no_phase, _sample_next,
                                      _scan_pool_layers)
 from ray_tpu.ops.moe import token_choice_experts
@@ -249,6 +250,7 @@ def _residual(x, f, layer, arm: str):
                + layer[arm + "_c_b"].astype(dt)))
 
 
+@jax.named_scope(scopes.ATTN_IN)
 def _attn_inputs(cfg: ZayaConfig, layer, x, pos, boundary):
     """The attention sublayer up to its q^, k^ and v.
 
@@ -290,6 +292,7 @@ def _attn_inputs(cfg: ZayaConfig, layer, x, pos, boundary):
     return q, k, v.reshape(N, C, G, K), {"z": z, "c": c, "v": v2u}
 
 
+@jax.named_scope(scopes.MOE_ROUTE)
 def _route(cfg: ZayaConfig, layer, u, r):
     """The MLP router, float32 throughout. u [M, D], r [M, R] (the
     stream of the layer before) → (expert [M] int32, gate [M] f32, r_l)."""
@@ -313,13 +316,16 @@ def _finish_block(cfg: ZayaConfig, layer, experts, l, x, attn, r, valid):
     [L, E, ...] and `l` the layer index.
     → (x, r_l, counts [E] int32: rows each expert received)."""
     N, C, D = x.shape
-    f = attn.reshape(N, C, -1) @ layer["wo"].astype(cfg.dtype)
-    x = _residual(x, f, layer, "res1")
-    u = _rms_norm(x, layer["ln2_scale"], cfg.norm_eps).reshape(N * C, D)
+    with jax.named_scope(scopes.ATTN_OUT):
+        f = attn.reshape(N, C, -1) @ layer["wo"].astype(cfg.dtype)
+        x = _residual(x, f, layer, "res1")
+    with jax.named_scope(scopes.MOE_ROUTE):
+        u = _rms_norm(x, layer["ln2_scale"], cfg.norm_eps).reshape(N * C, D)
     expert, gate, r = _route(cfg, layer, u, r.reshape(N * C, -1))
     y, counts = token_choice_experts(
         u, expert, gate, *experts, layer=l, valid=valid.reshape(-1))
-    x = _residual(x, y.reshape(N, C, D), layer, "res2")
+    with jax.named_scope(scopes.MOE_ROUTE):
+        x = _residual(x, y.reshape(N, C, D), layer, "res2")
     return x, r.reshape(N, C, -1), counts
 
 
@@ -331,11 +337,13 @@ def _stacked(cfg: ZayaConfig, params):
     return stacked, tuple(params[k].astype(cfg.dtype) for k in _EXPERT_KEYS)
 
 
+@jax.named_scope(scopes.EMBED)
 def _embed(cfg: ZayaConfig, params, tokens):
     x = params["wte"].astype(cfg.dtype)[tokens]
     return x, jnp.zeros(tokens.shape + (cfg.router_dim,), _F32)
 
 
+@jax.named_scope(scopes.HEAD)
 def _head(cfg: ZayaConfig, params, x):
     """Final RMSNorm and the tied head → float32 logits [..., V]."""
     h = _rms_norm(x, params["ln_f_scale"], cfg.norm_eps)
@@ -361,11 +369,14 @@ def forward(cfg: ZayaConfig, params, tokens):
         x, r = carry
         l, layer = inputs
         q, k, v, _tails = _attn_inputs(cfg, layer, x, pos, zero)
-        k, v = (jnp.repeat(t, g, axis=2) for t in (k, v))
-        s = jnp.einsum("bshk,bthk->bhst", q, k, preferred_element_type=_F32)
-        s = jnp.where(causal[None, None], s / math.sqrt(cfg.head_dim), -1e30)
-        attn = jnp.einsum("bhst,bthk->bshk",
-                          jax.nn.softmax(s, axis=-1).astype(cfg.dtype), v)
+        with jax.named_scope(scopes.ATTN_KERNEL):
+            k, v = (jnp.repeat(t, g, axis=2) for t in (k, v))
+            s = jnp.einsum("bshk,bthk->bhst", q, k,
+                           preferred_element_type=_F32)
+            s = jnp.where(causal[None, None],
+                          s / math.sqrt(cfg.head_dim), -1e30)
+            attn = jnp.einsum("bhst,bthk->bshk",
+                              jax.nn.softmax(s, axis=-1).astype(cfg.dtype), v)
         x, r, _counts = _finish_block(cfg, layer, experts, l, x, attn, r,
                                       jnp.ones((B, S), bool))
         return (x, r), None
@@ -407,6 +418,7 @@ def _count(counts):
                       jnp.sum(counts).astype(jnp.uint32)])
 
 
+@jax.named_scope(scopes.ATTN_KV_WRITE)
 def _write_kv(pool, l, pages, offs, k, v):
     """K/V rows [M, G*K] → (l, pages[m], offs[m]) of the carried pool."""
     rows = lambda t: t.reshape(-1, t.shape[-2] * t.shape[-1])
@@ -414,6 +426,7 @@ def _write_kv(pool, l, pages, offs, k, v):
             "v": pool["v"].at[l, pages, offs].set(rows(v))}
 
 
+@jax.named_scope(scopes.SLOT_STATE)
 def _write_state(pool, l, rows, tails):
     """tails {"z", "c", "v"}: [N, W] each → slot-state rows `rows` of
     layer l (several rows may name the null slot)."""
@@ -454,19 +467,24 @@ def _chunk_forward(cfg: ZayaConfig, params, tokens, pool, tables, offsets,
     rel, row = jnp.arange(C), jnp.arange(N)
     pos = offsets[:, None] + rel[None, :]
     valid = rel[None, :] < n_valid[:, None]
-    live = n_valid > 0
-    same = (slots[:, None] == slots[None, :]) & live[:, None] & live[None, :]
-    chain = jnp.max(jnp.where(same & (row[None, :] < row[:, None]),
-                              row[None, :], -1), axis=1)          # [N]
-    is_last = live & ~jnp.any(same & (row[None, :] > row[:, None]), axis=1)
-    state_rows = jnp.where(is_last, slots, null_slot)
-    last_tok = jnp.maximum(n_valid - 1, 0)[:, None, None]
+    with jax.named_scope(scopes.SLOT_STATE):
+        live = n_valid > 0
+        same = ((slots[:, None] == slots[None, :])
+                & live[:, None] & live[None, :])
+        chain = jnp.max(jnp.where(same & (row[None, :] < row[:, None]),
+                                  row[None, :], -1), axis=1)      # [N]
+        is_last = live & ~jnp.any(same & (row[None, :] > row[:, None]),
+                                  axis=1)
+        state_rows = jnp.where(is_last, slots, null_slot)
+        last_tok = jnp.maximum(n_valid - 1, 0)[:, None, None]
     at_last = lambda full: jnp.take_along_axis(full, last_tok, axis=1)[:, 0]
     # K/V write targets, as models/paged_kv._chunk_paged_forward sets them.
-    page_idx = jnp.minimum(pos // ps, tables.shape[1] - 1)
-    write_pages = jnp.where(valid, jnp.take_along_axis(tables, page_idx,
-                                                       axis=1), 0).reshape(-1)
-    write_offs = (pos % ps).reshape(-1)
+    with jax.named_scope(scopes.ATTN_KV_WRITE):
+        page_idx = jnp.minimum(pos // ps, tables.shape[1] - 1)
+        write_pages = jnp.where(
+            valid, jnp.take_along_axis(tables, page_idx, axis=1),
+            0).reshape(-1)
+        write_offs = (pos % ps).reshape(-1)
     kv_lens = offsets + n_valid
     attend = _attend_fn(attn_impl, chunk=True)
     x, r = _embed(cfg, params, tokens)
@@ -474,7 +492,8 @@ def _chunk_forward(cfg: ZayaConfig, params, tokens, pool, tables, offsets,
 
     def body(carry, layer, l, pool):
         x, r = carry
-        state = pool["slot_state"][l][slots]                      # [N, W]
+        with jax.named_scope(scopes.SLOT_STATE):
+            state = pool["slot_state"][l][slots]                  # [N, W]
 
         def boundary(name, full):
             before = jnp.where(chain[:, None] >= 0,
@@ -484,10 +503,12 @@ def _chunk_forward(cfg: ZayaConfig, params, tokens, pool, tables, offsets,
 
         q, k, v, tails = _attn_inputs(cfg, layer, x, pos, boundary)
         pool = _write_kv(pool, l, write_pages, write_offs, k, v)
-        attn = attend(q, pool["k"], pool["v"], l, tables, offsets, kv_lens,
-                      sm_scale=1.0 / math.sqrt(cfg.head_dim))
-        pool = _write_state(pool, l, state_rows,
-                            {n: at_last(t) for n, t in tails.items()})
+        with jax.named_scope(scopes.ATTN_KERNEL):
+            attn = attend(q, pool["k"], pool["v"], l, tables, offsets,
+                          kv_lens, sm_scale=1.0 / math.sqrt(cfg.head_dim))
+        with jax.named_scope(scopes.SLOT_STATE):
+            lasts = {n: at_last(t) for n, t in tails.items()}
+        pool = _write_state(pool, l, state_rows, lasts)
         x, r, _counts = _finish_block(cfg, layer, experts, l, x, attn, r,
                                       valid)
         return (x, r), pool
@@ -512,8 +533,9 @@ def prefill_chunk_paged(cfg: ZayaConfig, params, tokens, pool, tables,
                              n_valid, slots, attn_impl)
     if not return_logits:
         return None, pool
-    last = jnp.take_along_axis(
-        x, jnp.maximum(n_valid - 1, 0)[:, None, None], axis=1)[:, 0]
+    with jax.named_scope(scopes.HEAD):
+        last = jnp.take_along_axis(
+            x, jnp.maximum(n_valid - 1, 0)[:, None, None], axis=1)[:, 0]
     return _head(cfg, params, last), pool
 
 
@@ -531,27 +553,34 @@ def _decode_once(cfg: ZayaConfig, params, tokens, pool, positions, tables,
     active = tables[:, 0] > 0
     state_rows = jnp.where(active, jnp.arange(B), null_slot)
     pos = positions[:, None]
-    write_page = jnp.take_along_axis(
-        tables, jnp.minimum(positions // ps, tables.shape[1] - 1)[:, None],
-        axis=1)[:, 0]
-    write_off = positions % ps
+    with jax.named_scope(scopes.ATTN_KV_WRITE):
+        write_page = jnp.take_along_axis(
+            tables,
+            jnp.minimum(positions // ps, tables.shape[1] - 1)[:, None],
+            axis=1)[:, 0]
+        write_off = positions % ps
     attend = _attend_fn(attn_impl, chunk=False)
     x, r = _embed(cfg, params, tokens[:, None])
     stacked, experts = _stacked(cfg, params)
 
     def body(carry, layer, l, pool):
         x, r, counters = carry
-        state = pool["slot_state"][l, :B]
+        with jax.named_scope(scopes.SLOT_STATE):
+            state = pool["slot_state"][l, :B]
         q, k, v, tails = _attn_inputs(
             cfg, layer, x, pos, lambda name, _full: state[:, sl[name]])
         pool = _write_kv(pool, l, write_page, write_off, k, v)
-        attn = attend(q[:, 0], pool["k"], pool["v"], l, tables,
-                      positions + 1, sm_scale=1.0 / math.sqrt(cfg.head_dim))
+        with jax.named_scope(scopes.ATTN_KERNEL):
+            attn = attend(q[:, 0], pool["k"], pool["v"], l, tables,
+                          positions + 1,
+                          sm_scale=1.0 / math.sqrt(cfg.head_dim))
         pool = _write_state(pool, l, state_rows,
                             {n: t[:, 0] for n, t in tails.items()})
         x, r, counts = _finish_block(cfg, layer, experts, l, x,
                                      attn[:, None], r, active[:, None])
-        return (x, r, counters + _count(counts)), pool
+        with jax.named_scope(scopes.COUNTERS):
+            counters = counters + _count(counts)
+        return (x, r, counters), pool
 
     (x, _r, counters), pool = _scan_pool_layers(
         body, (x, r, pool["moe_counters"]), stacked, pool)

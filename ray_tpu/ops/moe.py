@@ -27,6 +27,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops import scopes
+
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
@@ -211,43 +213,51 @@ def token_choice_experts(x: jax.Array, expert_ids: jax.Array,
     counts [E_held] int32: rows each held expert received)."""
     N, D = x.shape
     E = w_gate.shape[0] if layer is None else w_gate.shape[1]
-    ids = expert_ids.reshape(N, -1)
-    k = ids.shape[1]
-    local = ids.reshape(-1).astype(jnp.int32) - first_expert      # [N*k]
-    held = (local >= 0) & (local < E)
-    if valid is not None:
-        held &= jnp.repeat(valid, k)
-    local = jnp.where(held, local, E)           # E: not here, sorts last
-    counts = jnp.zeros(E + 1, jnp.int32).at[local].add(1)[:E]
-    rows = jnp.repeat(x, k, axis=0) if k > 1 else x
-    local = jnp.concatenate([local, jnp.arange(E, dtype=jnp.int32)])
-    rows = jnp.concatenate([rows, jnp.zeros((E, D), x.dtype)])
-    sizes = counts + 1
-    # XLA:TPU tiles the rows of a grouped matmul by the largest power of
-    # two that divides their number: 16 for a decode step's 80 rows, so
-    # an expert's group straddles tiles and its weights are read for each
-    # (1.25x the bytes; 2x at a chunk's 272 rows). An ODD multiple of 128
-    # rows keeps the tile at 128, the MXU's width: empty rows that belong
-    # to no group are appended to get there.
-    pad = _pad_rows(local.shape[0]) - local.shape[0]
-    local = jnp.concatenate([local, jnp.full(pad, E, jnp.int32)])
-    rows = jnp.concatenate([rows, jnp.zeros((pad, D), x.dtype)])
-    order = jnp.argsort(local, stable=True)
-    rows = rows[order]
-    if layer is not None:
-        n_layers = w_gate.shape[0]
-        sizes = jax.lax.dynamic_update_slice(
-            jnp.zeros(n_layers * E, jnp.int32), sizes, (layer * E,))
-        w_gate, w_up, w_down = (w.reshape((n_layers * E,) + w.shape[2:])
-                                for w in (w_gate, w_up, w_down))
-    dot = functools.partial(_grouped_dot, sizes=sizes)
-    h = (jax.nn.silu(dot(rows, w_gate)) * dot(rows, w_up)).astype(x.dtype)
-    out = dot(h, w_down)                                    # [M, D] fp32
-    # Rows past the held groups belong to no group: whatever the grouped
-    # matmul left there is dropped here.
-    out = jnp.where((local[order] < E)[:, None], out, 0.0)
-    inverse = jnp.zeros_like(order).at[order].set(jnp.arange(order.size))
-    out = out[inverse[:N * k]]                              # unsort
-    y = jnp.sum(out.reshape(N, k, D)
-                * gates.reshape(N, k, 1).astype(jnp.float32), axis=1)
-    return y.astype(x.dtype), counts
+    with jax.named_scope(scopes.MOE_ROUTE):
+        ids = expert_ids.reshape(N, -1)
+        k = ids.shape[1]
+        local = ids.reshape(-1).astype(jnp.int32) - first_expert  # [N*k]
+        held = (local >= 0) & (local < E)
+        if valid is not None:
+            held &= jnp.repeat(valid, k)
+        local = jnp.where(held, local, E)       # E: not here, sorts last
+        counts = jnp.zeros(E + 1, jnp.int32).at[local].add(1)[:E]
+        rows = jnp.repeat(x, k, axis=0) if k > 1 else x
+        local = jnp.concatenate([local, jnp.arange(E, dtype=jnp.int32)])
+        rows = jnp.concatenate([rows, jnp.zeros((E, D), x.dtype)])
+        sizes = counts + 1
+        # XLA:TPU tiles the rows of a grouped matmul by the largest power
+        # of two that divides their number: 16 for a decode step's 80
+        # rows, so an expert's group straddles tiles and its weights are
+        # read for each (1.25x the bytes; 2x at a chunk's 272 rows). An
+        # ODD multiple of 128 rows keeps the tile at 128, the MXU's
+        # width: empty rows that belong to no group are appended to get
+        # there.
+        pad = _pad_rows(local.shape[0]) - local.shape[0]
+        local = jnp.concatenate([local, jnp.full(pad, E, jnp.int32)])
+        rows = jnp.concatenate([rows, jnp.zeros((pad, D), x.dtype)])
+        order = jnp.argsort(local, stable=True)
+        rows = rows[order]
+        if layer is not None:
+            n_layers = w_gate.shape[0]
+            sizes = jax.lax.dynamic_update_slice(
+                jnp.zeros(n_layers * E, jnp.int32), sizes, (layer * E,))
+    with jax.named_scope(scopes.MOE_EXPERTS):
+        if layer is not None:
+            w_gate, w_up, w_down = (
+                w.reshape((n_layers * E,) + w.shape[2:])
+                for w in (w_gate, w_up, w_down))
+        dot = functools.partial(_grouped_dot, sizes=sizes)
+        h = (jax.nn.silu(dot(rows, w_gate))
+             * dot(rows, w_up)).astype(x.dtype)
+        out = dot(h, w_down)                                # [M, D] fp32
+    with jax.named_scope(scopes.MOE_ROUTE):
+        # Rows past the held groups belong to no group: whatever the
+        # grouped matmul left there is dropped here.
+        out = jnp.where((local[order] < E)[:, None], out, 0.0)
+        inverse = jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.size))
+        out = out[inverse[:N * k]]                          # unsort
+        y = jnp.sum(out.reshape(N, k, D)
+                    * gates.reshape(N, k, 1).astype(jnp.float32), axis=1)
+        return y.astype(x.dtype), counts

@@ -1,0 +1,31 @@
+"""The names the device programs give their parts.
+
+Every family's decode, chunk and full-sequence program, and the train
+step, wrap each part of their work in `with jax.named_scope(NAME):` with
+one of the names below. A named scope is location metadata only: it adds
+no operation, and the compile-cache key strips it. The TPU trace carries
+it on every op (`tf_op`), which is what
+`benchmarks/harness/scope_times.py` splits device time by. The scopes
+are flat: none opens inside another.
+"""
+
+EMBED = "embed"                  # token embedding lookup
+ATTN_IN = "attn.in"              # pre-norm, q/k/v (and gate) projections,
+                                 # rope; zaya's convolutions and L2 norm
+ATTN_KV_WRITE = "attn.kv_write"  # K/V rows into the pool's pages or ring
+ATTN_KERNEL = "attn.kernel"      # the paged / flash call, or XLA's dense
+                                 # attention; the table ops before it
+ATTN_OUT = "attn.out"            # output (gate and) projection, residual
+MLP = "mlp"                      # dense MLP with its norm; a shared expert
+MOE_ROUTE = "moe.route"          # router, top-k, sort, group sizes,
+                                 # unsort and combine
+MOE_EXPERTS = "moe.experts"      # the grouped matmuls
+SLOT_STATE = "slot_state"        # a per-slot state's read and write
+HEAD = "head"                    # final norm, head matmul, row selection
+SAMPLE = "sample"                # argmax / categorical over the logits
+COUNTERS = "counters"            # on-device counters the host pulls
+LOSS = "loss"                    # cross-entropy over the logits
+OPTIMIZER = "optimizer"          # the optimizer's update and its apply
+
+ALL = (EMBED, ATTN_IN, ATTN_KV_WRITE, ATTN_KERNEL, ATTN_OUT, MLP, MOE_ROUTE,
+       MOE_EXPERTS, SLOT_STATE, HEAD, SAMPLE, COUNTERS, LOSS, OPTIMIZER)

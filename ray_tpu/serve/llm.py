@@ -835,14 +835,12 @@ class LLMEngine:
                       # warm discovery must ride the routing push, not
                       # per-request GCS RPCs.
                       "kv_digest_lookups": 0,
-                      # Families with a per-slot state and experts
-                      # (zeros otherwise). Prompt starts that read a
-                      # zeroed slot state; and, from the decode
-                      # programs' on-device counters, pulled with a
-                      # window's tokens: (layer, step) pairs run,
+                      # Families with experts (zeros otherwise), from
+                      # the decode programs' on-device counters, pulled
+                      # with a window's tokens: (layer, step) pairs run,
                       # experts that had a row, the fullest expert's
                       # rows, rows routed — summed over those pairs.
-                      "slot_state_resets": 0, "moe_layer_steps": 0,
+                      "moe_layer_steps": 0,
                       "moe_experts_touched_sum": 0, "moe_rows_max_sum": 0,
                       "moe_rows_routed": 0, "moe_rows_held": 0}
         # The decode programs' counters run on, wrapping uint32; the
@@ -2687,10 +2685,6 @@ class LLMEngine:
                 live = self.pool.pages_for(done + n - 1)
                 self.stats["prefill_pages_live"] += live
                 self.stats["prefill_pages_fetched"] += -(-live // block) * block
-            if self._family.slot_state:
-                # A row at offset 0 reads zeros for its slot's state.
-                self.stats["slot_state_resets"] += sum(
-                    done == 0 for _s, _r, done, _n in batch)
             self._dispatch_width_ring.append(width)
             self._dispatch_width_counts[width] = (
                 self._dispatch_width_counts.get(width, 0) + 1)

@@ -17,6 +17,7 @@ import jax
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
+from ray_tpu.ops import scopes
 from ray_tpu.parallel.sharding import logical_to_spec, tree_to_shardings
 from ray_tpu.parallel.mesh import DEFAULT_LOGICAL_RULES
 
@@ -101,18 +102,21 @@ def make_train_step(
         def step(params, opt_state, batch):
             inner, count = opt_state
             loss, grads = jax.value_and_grad(loss_fn)(params, *batch)
-            grads = jax.tree.map(
-                lambda g: g.astype(jax.numpy.float32), grads)
-            updates, inner = optimizer.update(grads, inner, params)
-            params = sr_apply_updates(params, updates, count)
+            with jax.named_scope(scopes.OPTIMIZER):
+                grads = jax.tree.map(
+                    lambda g: g.astype(jax.numpy.float32), grads)
+                updates, inner = optimizer.update(grads, inner, params)
+                params = sr_apply_updates(params, updates, count)
             return params, (inner, count + 1), loss
 
         opt_shardings = (opt_shardings, repl)
     else:
         def step(params, opt_state, batch):
             loss, grads = jax.value_and_grad(loss_fn)(params, *batch)
-            updates, opt_state = optimizer.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+            with jax.named_scope(scopes.OPTIMIZER):
+                updates, opt_state = optimizer.update(
+                    grads, opt_state, params)
+                params = optax.apply_updates(params, updates)
             return params, opt_state, loss
 
     return jax.jit(

@@ -219,6 +219,37 @@ def test_tools_take_names_from_paged_kv_that_exist():
     assert sorted(n for n in taken if not hasattr(paged_kv, n)) == []
 
 
+def test_scope_metrics_name_scopes_and_programs_that_exist():
+    """The scope metrics (harness/scope_times.py and the layer_metrics
+    files over it) find a part of a program by the NAME the program
+    gives it (ops/scopes.py) inside a program found by its jitted
+    function's name: every string they pass is one the program has."""
+    from harness import scope_times
+    from ray_tpu.ops import scopes
+
+    assert scope_times.vocabulary() == scopes.ALL
+    jitted = [n for n, fn in vars(paged_kv).items() if hasattr(fn, "lower")]
+    for regex in (scope_times.DECODE, scope_times.CHUNK):
+        for literal in _alternatives(regex):
+            assert any(literal in n for n in jitted), literal
+    passes = {"fwd", "bwd", "remat"}
+    for path in ("jit(f)/transpose(jvp(mlp))/dot_general", "jit(f)/while/"
+                 "body/checkpoint/rematted_computation/mlp/add", "mlp/add"):
+        assert scope_times.scope_of(path, scopes.ALL)[1] in passes
+    named = set()
+    for path in sorted(glob.glob(
+            os.path.join(BENCH, "layer_metrics", "*.py"))):
+        for node in ast.walk(_tree(path)):
+            if (isinstance(node, ast.Call) and getattr(
+                    node.func, "value", None) is not None
+                    and getattr(node.func.value, "id", "") == "scope_times"):
+                for arg in node.args:
+                    if isinstance(arg, ast.Tuple):
+                        named |= {e.value for e in arg.elts}
+    assert len(named) >= 8
+    assert named <= set(scopes.ALL) | passes, named - set(scopes.ALL)
+
+
 # -------------------------- (iv) benchmarks/tests/test_families.py's guards
 
 def test_every_configuration_resolves_its_family_and_reference():

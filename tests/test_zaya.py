@@ -290,7 +290,7 @@ def test_engine_serves_the_references_tokens_and_counts(params):
                                           r.out_ids]
         assert deficit.max() <= ATOL_F32
     m = eng.metrics()
-    assert m["slot_state_resets"] == 4 and m["preemptions"] == 0
+    assert m["preemptions"] == 0
     assert m["slot_state_bytes"] == (
         CFG.n_layers * (N_SLOTS + 1) * CFG.state_width * 4)
     assert m["kv_pool_bytes"] == (
@@ -306,7 +306,7 @@ def test_engine_serves_the_references_tokens_and_counts(params):
     assert m["moe_rows_max"] >= 1.0
     eng.reset_stats()
     after = eng.metrics()
-    assert after["moe_rows_routed"] == after["slot_state_resets"] == 0
+    assert after["moe_rows_routed"] == 0
 
 
 def test_engine_recomputes_the_state_of_a_preempted_request(params):
