@@ -11,22 +11,35 @@ timeline is ever materialized in HBM.
 Design notes:
 - The pool is the WHOLE plane ``[L, P, page_size, H*K]`` (every layer,
   heads flattened into the minor axis: models/paged_kv.py), never one
-  layer's slice of it. Grid is (batch-slot, kv-page) with
-  ``PrefetchScalarGridSpec``: the layer index ``[1]``, the page table
-  ``[B, n_pg]`` and per-slot kv lengths ``[B]`` land in SMEM before the
-  body runs, so the K/V BlockSpec index map selects block
-  ``(layer, tables[b, j], 0, 0)`` — layer and page id ARE the block
-  index into the pool. A page arrives by its own DMA, as ``page_size``
-  dense rows of ``H*K`` lanes (2,048 at OPT-1.3B): the minor axis is a
-  multiple of 128 lanes for every served model, so the chip's own
-  layout of the pool is row-major and nothing re-lays it out. A grid
-  step takes a BLOCK of consecutive table columns, the pool handed over
-  once a column of the block (each page an operand and its own DMA):
-  `prefill_block_pages` columns in the prefill kernel (4 pages = 256
-  keys at the served shapes), `decode_block_pages` in the decode kernel
-  (up to 512 KiB of K a block: 16 pages of 32 KB at zaya1-8b, 4 of
-  128 KB at laguna-s-2.1, 2 of 256 KB at opt-1.3b). A table the block
-  does not divide is padded with null columns.
+  layer's slice of it. The layer index ``[1]``, the page table
+  ``[B, n_pg]`` and per-slot kv lengths ``[B]`` are scalar-prefetched
+  (``PrefetchScalarGridSpec``) and land in SMEM before the body runs:
+  layer and page id ARE the address of a page in the pool. A page
+  arrives by its own DMA, as ``page_size`` dense rows of ``H*K`` lanes
+  (2,048 at OPT-1.3B): the minor axis is a multiple of 128 lanes for
+  every served model, so the chip's own layout of the pool is row-major
+  and nothing re-lays it out. Both kernels attend a BLOCK of
+  consecutive table columns at a time: `prefill_block_pages` columns in
+  the prefill kernel (4 pages = 256 keys at the served shapes),
+  `decode_block_pages` in the decode kernel (up to 512 KiB of K a
+  block: 16 pages of 32 KB at zaya1-8b, 4 of 128 KB at laguna-s-2.1, 2
+  of 256 KB at opt-1.3b).
+- How a page gets to VMEM differs. Prefill: the grid is (row, kv block),
+  the pool is handed over once a column of the block, and the K/V
+  BlockSpec index map selects block ``(layer, tables[b, j], 0, 0)``:
+  Pallas's pipeline fetches it (a table the block does not divide is
+  padded with null columns). Decode (PR 41): the pools stay in HBM
+  (``memory_space=pl.ANY``), one grid step walks the live blocks of
+  every slot in one flat loop, and the kernel starts a
+  ``make_async_copy`` a LIVE page, from ``pool[layer, tables[b, c]]``
+  straight to that page's rows of a block-sized VMEM buffer, two blocks
+  ahead of the one it attends (`_DECODE_BUFFERS`), across slot
+  boundaries. A grid step a (slot, block) with a K and a V operand a
+  column cost ~0.05 us an operand, live or dead: 4.9 of the call's
+  5.6 ms at zaya1-8b, for 2.1 ms of bytes (PERF.md, PR 37). In the flat
+  loop a dead column of a live block gets no DMA and is position-masked,
+  and a dead block, an idle slot and a ring's columns outside the window
+  are never visited: no operand, no step, no index map.
 - The per-head split happens in VMEM, after the read. Decode: the
   slot's query row ``[1, H*K]`` is spread into a block-diagonal
   ``[H, H*K]`` (row h keeps head h's K lanes, zeros elsewhere), so
@@ -58,7 +71,8 @@ Design notes:
   the logical page column c holds NOW (-1: none), a key's position is
   ``col_page[b, c] * page_size + i``, and it is attended when
   ``pos - window < key <= pos``; a block whose every column lies wholly
-  outside is skipped under `pl.when` like a null tail. Columns arrive
+  outside is skipped like a null tail (prefill: under `pl.when`;
+  decode: never visited). Columns arrive
   in ring order, which the online softmax does not mind. With `window=None` (every caller before
   the window kind) column c is page c and the kernels trace what they
   always did; the window calls carry names of their own in a trace
@@ -69,23 +83,23 @@ Design notes:
   step holds ONE KV head's K lanes of the block's pages and its H/G
   query heads, the same kernel body at n_heads = H/G, n_kv_heads = 1.
 - Online-softmax state (m, l, acc) lives in VMEM scratch across the kv
-  dimension ("arbitrary" grid semantics), exactly like the flash kernel.
+  blocks of a row or slot, exactly like the flash kernel.
   Both kernels keep m lane-uniform and use it at full width, and keep l
   as a partial sum a lane that is summed over lanes once, after the
   last block: cutting a [C, 1] column out of the state and spreading
   it again, and a second lane reduction a block, were most of a head's
   time once the block was wide.
-- Null / past-length pages: unallocated table tail entries are 0 (the
-  reserved null page, models/paged_kv.py), so their index maps repeat
-  one block and Pallas's revisit elision fetches it at most once;
-  ``pl.when`` skips the compute of a block whose first key is past the
-  length. In-page raggedness (a slot ending mid-page) and the dead
-  columns of a live block are position-masked like the flash kernel's
-  kv_len mask. The decode call goes one step further (`_held_pages`): a
-  dead column (the null tail, a ring's columns outside the window, the
-  pad) is pointed at the page its operand position holds anyway, so it
-  costs no fetch at all; with n columns as n operands each would
-  otherwise fall to the null page once a slot and layer.
+- Null / past-length pages. Prefill: unallocated table tail entries are
+  0 (the reserved null page, models/paged_kv.py), so their index maps
+  repeat one block and Pallas's revisit elision fetches it at most
+  once; ``pl.when`` skips the compute of a block whose first key is
+  past the length. Decode: a dead column's table entry is never read as
+  a page. In both, in-page raggedness (a slot ending mid-page) and the
+  dead columns of a live block are position-masked like the flash
+  kernel's kv_len mask. The decode buffers' dead rows keep what an
+  earlier block left there: their probabilities are 0, and 0 x NaN is
+  NaN in PV, so V's buffers are zeroed once a call and only pool pages
+  land in them after (an int8 block's dead rows dequant by a scale of 0).
 - Softmax statistics stay fp32; the QKᵀ/PV contractions run in the input
   dtype with fp32 accumulate (MXU fast path — upcasting operands would
   drop the MXU into its ~4x slower fp32 mode).
@@ -253,75 +267,154 @@ def _decode_kernel(
     sm_scale, page_size, block_pages, n_heads, n_kv_heads, quantized=False,
     window=None,
 ):
-    """One slot's current-token query against a BLOCK of `block_pages`
-    consecutive table columns a grid step. Ref order: scalar-prefetch
-    (SMEM) first (layer, page tables, kv lengths, a ring's `col_page`,
-    and for an int8 pool the layer's per-page K/V scale vectors), then
-    VMEM blocks (q, `block_pages` K pages and as many V pages, each
-    [ps, G*K] and its own DMA), the output, and the scratch: the
-    block-diagonal query and the (m, l, acc) softmax state. The block's
-    pages are stacked into one [block_pages*ps, G*K] operand a pool, so a
-    step pays ONE score tile, one masked row maximum, one `exp`, one
-    partial row sum and one accumulator update whatever the block holds:
-    at a page a step that chain, not the page's bytes or its matmuls,
-    was the kernel's compute (PERF.md, PR 37). `quantized` is a
-    Python-level trace switch: the int8 program dequants each page of
-    the block by its own scale right after its DMA, inside the kernel,
-    and the fp32 plane never exists in HBM."""
-    n = block_pages
+    """Every slot of a group against its LIVE kv blocks, one grid step a
+    group (the whole batch at every served shape), the pages fetched by
+    the kernel's own DMAs. Ref order: scalar-prefetch (SMEM) first
+    (layer, page tables, kv lengths, a ring's `col_page`, and for an
+    int8 pool the layer's per-page K/V scale vectors), the group's
+    queries (VMEM), the K and V pools WHOLE and left in HBM, the output,
+    and the scratch: `_DECODE_BUFFERS` K and as many V buffers of a block
+    ([buffers, block_pages*ps, G*K]) with a DMA semaphore a buffer pair,
+    the block-diagonal query and the (m, l, acc) softmax state.
+
+    One flat loop walks the live blocks of the group in (slot, block)
+    order. A block is `block_pages` consecutive table columns; a live
+    column's page goes from ``pool[layer, tables[b, c]]`` straight to
+    its rows of the buffer (the DMA's destination does the stacking), a
+    dead column of a live block gets no DMA and is position-masked, and
+    a dead block, an idle slot and a ring's columns outside the window
+    are never visited. While a block is attended the next live blocks,
+    of this slot or of the next live ones, are already in flight into
+    the other buffers, so no slot waits for its own first page (a wait
+    a slot cost more than the whole grid did: PERF.md, PR 41). A block pays
+    ONE score tile, one masked row maximum, one `exp`, one partial row
+    sum and one accumulator update whatever it holds (PERF.md, PR 37).
+    `quantized` is a Python-level trace switch: the int8 program dequants
+    each page of the block by its own scale after the wait, inside the
+    kernel, and the fp32 plane never exists in HBM."""
+    n, ps = block_pages, page_size
     refs = iter(refs)
     take = lambda count: [next(refs) for _ in range(count)]
-    _layer_ref, tables_ref, lengths_ref = take(3)
+    layer_ref, tables_ref, lengths_ref = take(3)
     (col_ref,) = take(1) if window is not None else (None,)
     ks_ref, vs_ref = take(2) if quantized else (None, None)
-    (q_ref,), k_refs, v_refs = take(1), take(n), take(n)
-    o_ref, qbd_ref, m_ref, l_ref, acc_ref = refs
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    # Multi-head (G = H): the query is one dense row [1, H*K]. Grouped
-    # (G < H): it is [H, K], a head a row, and the pool's minor axis holds
-    # G heads; row h of the block-diagonal query then sits in the lanes of
-    # KV head h // (H/G), so the two matmuls below are the same.
+    q_ref, k_hbm, v_hbm, o_ref = take(4)
+    k_buf, v_buf, sem, qbd_ref, m_ref, l_ref, acc_ref = refs
+    # Multi-head (G = H): a slot's query is one dense row [1, H*K].
+    # Grouped (G < H): it is [H, K], a head a row, and the pool's minor
+    # axis holds G heads; row h of the block-diagonal query then sits in
+    # the lanes of KV head h // (H/G), so the two matmuls are the same.
     grouped = n_kv_heads != n_heads
     head_dim = q_ref.shape[-1] if grouped else q_ref.shape[-1] // n_heads
     mask = lambda: _head_mask(n_heads, head_dim, n_kv_heads)
-    block = n * page_size
+    block = n * ps
     GK = qbd_ref.shape[1]
+    group = q_ref.shape[0]
+    n_pg = tables_ref.shape[1]
+    n_blk = -(-n_pg // n)
+    n_buf = k_buf.shape[0]
+    ragged = n_blk * n != n_pg      # the last block runs past the table
+    base = pl.program_id(0) * group
+    end = base + group
+    layer = layer_ref[0]
 
-    @pl.when(j == 0)
-    def _init():
+    @pl.when(pl.program_id(0) == 0)
+    def _finite_rows():
+        # A dead column's rows of a buffer keep what they held. Their
+        # probabilities are 0, and 0 x NaN is NaN in PV: V's rows are
+        # made finite once, and only pages of the pool land there after.
+        # (K's rows may hold anything: their scores are masked.)
+        v_buf[...] = jnp.zeros_like(v_buf)
+    o_ref[...] = jnp.zeros_like(o_ref)       # an idle slot is never visited
+
+    def columns(b, j):
+        """[(live, table column, first key)] of block j of slot b: what
+        the copies, their waits and the position mask all go by."""
+        kv_len = lengths_ref[b]
+        out = []
+        for i in range(n):
+            c = j * n + i
+            held = jnp.minimum(c, n_pg - 1) if ragged else c
+            first = (c * ps if window is None
+                     else _first_key(col_ref[b, held], ps))
+            if ragged and window is not None:   # past the ring: no page
+                first = jnp.where(c < n_pg, first, _NO_PAGE)
+            out.append((_column_live(first, kv_len, ps, window), held,
+                        first))
+        return out
+
+    def block_live(b, j):
+        if window is None:
+            return j * block < lengths_ref[b]
+        return functools.reduce(jnp.logical_or,
+                                [live for live, _, _ in columns(b, j)])
+
+    def after(b, j):
+        """(slot, block) after (b, j) in the order the walk goes."""
+        last = j == n_blk - 1
+        return jnp.where(last, b + 1, b), jnp.where(last, 0, j + 1)
+
+    def seek(b, j):
+        """The first live block at or after (b, j) in (slot, block)
+        order; a slot at or past `end` when the group has none left."""
+        def dead(at):
+            return (at[0] < end) & ~block_live(jnp.minimum(at[0], end - 1),
+                                               at[1])
+
+        def skip(at):
+            if window is None:      # the blocks after a dead one are dead
+                return at[0] + 1, jnp.int32(0)
+            return after(*at)
+        return jax.lax.while_loop(dead, skip, (b, j))
+
+    def copies(act, b, cols, buf, go=True):
+        """`act` ("start" or "wait") on the K and the V copy of every
+        live column of a block of slot b (`cols`: its `columns`), from
+        the pool to the column's rows of buffer `buf`; none if not `go`."""
+        for i, (live, held, _) in enumerate(cols):
+            @pl.when(live & go)
+            def _():
+                page = tables_ref[b, held]
+                for pool, dst in ((k_hbm, k_buf), (v_hbm, v_buf)):
+                    getattr(pltpu.make_async_copy(
+                        pool.at[layer, page],
+                        dst.at[buf, pl.ds(i * ps, ps)], sem.at[buf]), act)()
+
+    def fetch(item, buf):
+        """Start block `item` on its way into `buf`; past the group's
+        end there is nothing to start."""
+        b = jnp.minimum(item[0], end - 1)
+        copies("start", b, columns(b, item[1]), buf, item[0] < end)
+
+    def init(b):
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
         # Row h = the query with every lane outside head h's KV head
         # zeroed (the select runs in fp32: the mask is built from 32-bit
         # iotas).
-        q = q_ref[...].astype(jnp.float32)
+        q = q_ref[b - base].astype(jnp.float32)
         q = (jnp.concatenate([q] * n_kv_heads, axis=1) if grouped
              else jnp.broadcast_to(q, qbd_ref.shape))
         qbd_ref[...] = jnp.where(mask(), q, 0.0).astype(qbd_ref.dtype)
 
-    kv_len = lengths_ref[b]
-    if window is not None:      # a ring: where each column's page starts
-        firsts = [_first_key(col_ref[b, j * n + i], page_size)
-                  for i in range(n)]
-
-    def _compute():
+    def attend(b, j, cols, buf):
+        kv_len = lengths_ref[b]
         qbd = qbd_ref[...]                   # [H, G*K]
-        k_sc = v_sc = [None] * n
         if quantized:
-            pages = [tables_ref[b, j * n + i] for i in range(n)]
-            k_sc = [ks_ref[p] for p in pages]
-            v_sc = [vs_ref[p] for p in pages]
             qbd = qbd.astype(jnp.float32)
 
-        def stack(page_refs, scales):
-            parts = [r[...] if sc is None
-                     else r[...].astype(jnp.float32) * sc
-                     for r, sc in zip(page_refs, scales)]   # n x [ps, G*K]
+        def stacked(dst, scale_ref):
+            if not quantized:
+                return dst[buf]              # [block, G*K], as the DMAs left it
+            # A dead column's rows are stale int8 under a scale nobody
+            # wrote: they dequant to 0.
+            parts = [dst[buf, i * ps:(i + 1) * ps].astype(jnp.float32)
+                     * jnp.where(live, scale_ref[tables_ref[b, held]], 0.0)
+                     for i, (live, held, _) in enumerate(cols)]
             return parts[0] if n == 1 else jnp.concatenate(parts, axis=0)
 
-        k, v = stack(k_refs, k_sc), stack(v_refs, v_sc)
+        k, v = stacked(k_buf, ks_ref), stacked(v_buf, vs_ref)
         # s[h, t] = q[h] · k[t, head h's lanes]: the block-diagonal query
         # makes it one [H, G*K] x [block, G*K]ᵀ matmul. Decode attention
         # is HBM-bound (~2 flops/byte), so the H-fold surplus of
@@ -329,17 +422,16 @@ def _decode_kernel(
         s = jax.lax.dot_general(
             qbd, k, _NT, preferred_element_type=jnp.float32) * sm_scale
         # Raggedness: positions at or past the slot's kv length are
-        # masked (a live block's dead columns, a partial last page, the
-        # null page when it IS the write target of an idle slot).
+        # masked (a live block's dead columns, a partial last page).
         lane = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         if window is None:
             s = jnp.where(j * block + lane < kv_len, s, NEG_INF)
         else:       # the query sits at kv_len - 1; column i starts at
-            start = firsts[0]                       # lane i * page_size
+            origin = cols[0][2]                     # lane i * page_size
             for i in range(1, n):
-                start = jnp.where(lane >= i * page_size,
-                                  firsts[i] - i * page_size, start)
-            tpos = start + lane
+                origin = jnp.where(lane >= i * ps, cols[i][2] - i * ps,
+                                   origin)
+            tpos = origin + lane
             s = jnp.where((tpos < kv_len) & (tpos >= kv_len - window), s,
                           NEG_INF)
 
@@ -357,20 +449,9 @@ def _decode_kernel(
         acc_ref[...] = acc_ref[...] * _spread(corr, GK) + pv
         m_ref[...] = m_new
 
-    # A block does no compute when every column of it is dead: wholly
-    # past the slot's kv length (the null tail of the table, whose fetch
-    # `_held_pages` elides too), or in a ring wholly outside the window.
-    if window is None:
-        pl.when(j * block < kv_len)(_compute)
-    else:
-        live = [_column_live(f, kv_len, page_size, window) for f in firsts]
-        pl.when(functools.reduce(jnp.logical_or, live))(_compute)
-
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _finish():
+    def finish(b):
         l = jnp.sum(l_ref[...], axis=1, keepdims=True)
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        own = jnp.where(mask(), acc_ref[...] / l_safe, 0.0)
+        own = jnp.where(mask(), acc_ref[...] / l, 0.0)
         if grouped:
             # One non-zero K-lane block per row: their sum is [H, K].
             out = sum(own[:, g * head_dim:(g + 1) * head_dim]
@@ -379,40 +460,73 @@ def _decode_kernel(
             # One non-zero row per lane: the sum over rows is the gather
             # of each head's own block, as one dense [1, H*K] row.
             out = jnp.sum(own, axis=0, keepdims=True)
-        o_ref[...] = out.astype(o_ref.dtype)
+        o_ref[b - base] = out.astype(o_ref.dtype)
+
+    def visit(at):
+        # `ahead`: this block and the ones in flight behind it. The next
+        # live block after them, this slot's or a later live slot's, goes
+        # out into the buffer the block before this one left, before
+        # this one is waited for.
+        ahead, buf, prev = at
+        b, j = ahead[0]
+        ahead += (seek(*after(*ahead[-1])),)
+        fetch(ahead[-1], jnp.where(buf == 0, n_buf - 1, buf - 1))
+        pl.when(b != prev)(lambda: init(b))
+        cols = columns(b, j)
+        copies("wait", b, cols, buf)
+        attend(b, j, cols, buf)
+        pl.when(ahead[1][0] != b)(lambda: finish(b))
+        return ahead[1:], jnp.where(buf == n_buf - 1, 0, buf + 1), b
+
+    ahead = (seek(base, jnp.int32(0)),)
+    for _ in range(n_buf - 2):
+        ahead += (seek(*after(*ahead[-1])),)
+    for buf, item in enumerate(ahead):
+        fetch(item, buf)
+    jax.lax.while_loop(lambda at: at[0][0][0] < end, visit,
+                       (ahead, jnp.int32(0), jnp.int32(-1)))
 
 
-# The decode kernel's kv block. A grid step costs ~0.05 us an operand
-# whatever it holds (each page of the block is an operand a pool), and a
-# live step one softmax chain; the chain is paid once a block, so the
-# block is as large as its bytes stay small beside the step: 512 KiB of K
-# (and of V) read best at all three served page sizes (16 pages of 32 KB,
-# 4 of 128 KB, 2 of 256 KB: PERF.md, PR 37), and past it a live block's
-# dead columns cost more than the chains saved. The budget is what a
-# step may take of the 16 MiB of VMEM a kernel gets by default.
+# The decode kernel's kv block. A live block costs one softmax chain and
+# one pass of its keys through the MXU whatever it holds, and its pages'
+# DMAs hide behind the blocks before it, so the block is as large as its
+# bytes stay small beside the chain: 512 KiB of K (and of V) read best
+# at all three served page sizes (16 pages of 32 KB, 4 of 128 KB, 2 of
+# 256 KB: PERF.md, PR 37; with the kernel's own DMAs half and twice that
+# read within 2 % of it, PR 41), and past it a live block's dead columns
+# cost more compute than the chains saved. Three buffers a pool: two
+# blocks in flight behind the one attended, because a slot's last block
+# is often a page or two and its DMA ends long before the block's fixed
+# compute does (the third buffer is worth 3 % at 32 KB pages, 15 % in
+# the ring; a fourth nothing: PERF.md, PR 41). The budget is what the
+# kernel may take of the 16 MiB of VMEM it gets by default: the block's
+# buffers and tiles (`_decode_vmem_bytes`) and beside them a slot
+# group's queries and outputs.
 _DECODE_BLOCK_BYTES = 512 * 2**10
 _DECODE_BLOCK_KEYS = 1024
+_DECODE_BUFFERS = 3
 _DECODE_VMEM_BUDGET = 12 * 2**20
+_DECODE_GROUP_BUDGET = 14 * 2**20
 
 
 def _decode_vmem_bytes(n, page_size, kv_lanes, kv_itemsize, n_heads) -> int:
-    """VMEM a decode grid step takes at `n` pages a block: the K and V
-    pages (double-buffered by the pipeline), an int8 block's f32 copies,
-    the score and probability tiles, and whatever the block: the
-    block-diagonal query with the f32 accumulator, the (m, l) state and
-    the query and output blocks."""
+    """VMEM the decode kernel takes at `n` pages a block, beside its
+    queries and outputs: `_DECODE_BUFFERS` K and as many V buffers of a
+    block, an int8 block's f32 copies, the score and probability tiles, and whatever
+    the block: the block-diagonal query with the f32 accumulator and the
+    (m, l) state."""
     page = page_size * kv_lanes * kv_itemsize
     dequant = 2 * n * page_size * kv_lanes * 4 if kv_itemsize == 1 else 0
     tiles = 2 * n_heads * n * page_size * 4
     fixed = n_heads * kv_lanes * (4 + 4 + 4) + 2 * n_heads * _LANES * 4
-    return 2 * 2 * n * page + dequant + tiles + fixed
+    return _DECODE_BUFFERS * 2 * n * page + dequant + tiles + fixed
 
 
 def decode_block_pages(n_pg, page_size, kv_lanes, kv_itemsize,
                        n_heads) -> int:
-    """Table columns one grid step of the decode kernel attends: the
-    largest power of two that is at most `n_pg`, keeps the block's K
-    pages (`kv_lanes` = G*K wide) at or under `_DECODE_BLOCK_BYTES` and
+    """Table columns one block of the decode kernel holds: the largest
+    power of two that is at most `n_pg`, keeps the block's K pages
+    (`kv_lanes` = G*K wide) at or under `_DECODE_BLOCK_BYTES` and
     `_DECODE_BLOCK_KEYS` keys, and fits `_DECODE_VMEM_BUDGET`
     (`_decode_vmem_bytes`). Pure in the shapes: the engine's
     `decode_block_fill` counter and the kernel ask it the same
@@ -427,6 +541,18 @@ def decode_block_pages(n_pg, page_size, kv_lanes, kv_itemsize,
     return n
 
 
+def _decode_slot_group(n_slots, slot_bytes, block_bytes) -> int:
+    """Slots one grid step of the decode kernel walks: the largest
+    divisor of `n_slots` whose queries and outputs (`slot_bytes` a slot
+    each, double-buffered by the pipeline) fit `_DECODE_GROUP_BUDGET`
+    beside the block's `block_bytes`: the whole batch at every served
+    shape. A group fetches its own first block, so a smaller group is a
+    wait more, not another program."""
+    room = _DECODE_GROUP_BUDGET - block_bytes
+    return max(g for g in range(1, n_slots + 1)
+               if n_slots % g == 0 and (g == 1 or 4 * g * slot_bytes <= room))
+
+
 def _pad_columns(tables, col_page, n):
     """The table (and a ring's `col_page`) padded with null columns to a
     multiple of `n`: position-masked like any dead column of a block (in
@@ -438,32 +564,6 @@ def _pad_columns(tables, col_page, n):
             col_page = jnp.pad(col_page, ((0, 0), (0, pad)),
                                constant_values=-1)
     return tables, col_page
-
-
-def _held_pages(tables, live, n):
-    """`tables` with every dead column pointed at the page its operand
-    position holds anyway, so that a dead column costs no fetch. Column c
-    is operand c % n of grid step c // n, and Pallas fetches an operand
-    only when its block index differs from the step before: a dead
-    column takes the page of the last live column before it at its
-    position (the null tail of a slot, a ring's columns outside the
-    window), or, with none before it, of the first one after it (that
-    page is then fetched early and kept); a position with no live column
-    keeps its own. `live` [B, n_pg] bool is what the kernel masks by;
-    the table never decides what is attended."""
-    B, n_pg = tables.shape
-    steps = n_pg // n
-    if steps == 1:
-        return tables
-    step = jnp.arange(steps, dtype=jnp.int32)[None, :, None]
-    live = live.reshape(B, steps, n)
-    before = jax.lax.cummax(jnp.where(live, step, -1), axis=1)
-    after = jax.lax.cummin(jnp.where(live, step, steps), axis=1,
-                           reverse=True)
-    src = jnp.where(before >= 0, before, jnp.where(after < steps, after,
-                                                   step))
-    return jnp.take_along_axis(tables.reshape(B, steps, n), src,
-                               axis=1).reshape(B, n_pg)
 
 
 def paged_attention(
@@ -486,18 +586,21 @@ def paged_attention(
     Args:
       q: [B, H, K] — each slot's current-token query (post-rotary).
       k_pool, v_pool: [L, P, page_size, G*K] — the WHOLE page pool (row 0
-        of every layer is the reserved null page), read in place at
-        ``(layer, page)``; G KV heads, each serving H/G query heads
-        (G = H: multi-head). May be int8 (quantized serving), in which case
+        of every layer is the reserved null page), left in HBM and read
+        in place at ``(layer, page)`` by the kernel's own DMAs, a live
+        page each; G KV heads, each serving H/G query heads (G = H:
+        multi-head). May be int8 (quantized serving), in which case
         ``k_scale``/``v_scale`` must carry the per-page scale planes
         [L, P] — the layer's row rides the scalar-prefetch path next to
         the page table, and each page is dequanted in VMEM right after
         its DMA (the fp32 plane never exists in HBM).
       layer: int32 scalar (traced inside the layer scan) — which layer's
         pages to attend over.
-      tables: [B, n_pg] int32 page ids per slot (unallocated tail = 0).
+      tables: [B, n_pg] int32 page ids per slot (unallocated tail = 0;
+        a dead column's entry is never read as a page).
       lengths: [B] int32 valid kv positions per slot (= position + 1; the
-        current token's K/V must already be written to its page).
+        current token's K/V must already be written to its page; 0: an
+        idle slot, whose output is 0).
       window, col_page: a window layer's ring (both or neither): only
         keys within `window` of the query are attended, and table column
         c holds logical page ``col_page[b, c]`` ([B, n_pg] int32, -1:
@@ -515,32 +618,43 @@ def paged_attention(
         interpret = _interpret_default()
     quantized = k_scale is not None
     _check_window(window, col_page, tables, quantized)
-    n = decode_block_pages(tables.shape[1], ps, G * K,
-                           k_pool.dtype.itemsize, H)
-    tables, col_page = _pad_columns(tables, col_page, n)
-    # What the kernel attends is the lengths' (and the ring's) to say;
-    # the table only has to hold a live column's page.
-    first = (jnp.arange(tables.shape[1], dtype=jnp.int32)[None] * ps
-             if window is None else _first_key(col_page, ps))
-    tables = _held_pages(
-        tables, _column_live(first, lengths[:, None], ps, window), n)
+    kv_item = k_pool.dtype.itemsize
+    n = decode_block_pages(tables.shape[1], ps, G * K, kv_item, H)
     name, prefetch, extra = _call_form(
         "paged_decode_attn", layer, (tables, lengths), k_scale, v_scale,
         window, col_page)
+    group = _decode_slot_group(
+        B, H * K * q.dtype.itemsize,
+        _decode_vmem_bytes(n, ps, G * K, kv_item, H))
 
     kernel = functools.partial(
         _decode_kernel, sm_scale=sm_scale, page_size=ps, block_pages=n,
         n_heads=H, n_kv_heads=G, quantized=quantized, **extra)
-    scratch = [
-        pltpu.VMEM((H, G * K), q.dtype),         # block-diagonal query
-        pltpu.VMEM((H, _LANES), jnp.float32),    # m
-        pltpu.VMEM((H, _LANES), jnp.float32),    # l
-        pltpu.VMEM((H, G * K), jnp.float32),     # acc
-    ]
-    out = _pool_call(kernel, name,
-                     q.reshape(B, 1, H * K) if G == H else q,
-                     k_pool, v_pool, prefetch, tables.shape[1], scratch,
-                     interpret, block_pages=n)
+    q = q.reshape(B, 1, H * K) if G == H else q
+    slots = pl.BlockSpec((group,) + q.shape[1:], lambda g, *_: (g, 0, 0))
+    pool = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(B // group,),
+        in_specs=[slots, pool, pool],
+        out_specs=slots,
+        scratch_shapes=[
+            pltpu.VMEM((_DECODE_BUFFERS, n * ps, G * K), k_pool.dtype),
+            pltpu.VMEM((_DECODE_BUFFERS, n * ps, G * K), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((_DECODE_BUFFERS,)),   # one a K, V pair
+            pltpu.VMEM((H, G * K), q.dtype),         # block-diagonal query
+            pltpu.VMEM((H, _LANES), jnp.float32),    # m
+            pltpu.VMEM((H, _LANES), jnp.float32),    # l
+            pltpu.VMEM((H, G * K), jnp.float32),     # acc
+        ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        interpret=interpret,
+        name=name,
+    )(*prefetch, q, k_pool, v_pool)
     return out.reshape(B, H, K)
 
 

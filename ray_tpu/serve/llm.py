@@ -725,7 +725,7 @@ class LLMEngine:
         self._dispatch_width_ring: "collections.deque[int]" = (
             collections.deque(maxlen=4096))
         self._dispatch_width_counts: dict[int, int] = {}
-        # Table width -> pages a grid step of the prefill kernel attends,
+        # Table width -> pages a kv block of the prefill kernel holds,
         # and of the decode kernel.
         self._block_pages_at: dict[int, int] = {}
         self._decode_block_at: dict[int, int] = {}
@@ -803,9 +803,12 @@ class LLMEngine:
                       # (`prefill_block_fill`).
                       "prefill_pages_live": 0, "prefill_pages_fetched": 0,
                       # Pages the decoding slots attend at a window's
-                      # first step, and pages the decode kernel's live
-                      # kv blocks hold for them (`decode_block_fill`).
+                      # first step, the pages the decode kernel's live
+                      # kv blocks hold for them (`decode_block_fill`),
+                      # and the columns of the window's table, every
+                      # slot's (`decode_live_column_share`).
                       "decode_pages_live": 0, "decode_pages_fetched": 0,
+                      "decode_columns": 0,
                       "decode_time_s": 0.0, "decode_windows": 0,
                       "slot_step_sum": 0, "slot_cap_sum": 0,
                       "preemptions": 0,
@@ -1387,6 +1390,11 @@ class LLMEngine:
                 # block's tail lay past its slot's last page.
                 m["decode_block_fill"] = m["decode_pages_live"] / max(
                     1, m["decode_pages_fetched"])
+                # The same live pages over every column of the windows'
+                # tables: the share of a (slot, column) grid that the
+                # decode kernel fetches; the rest costs it nothing.
+                m["decode_live_column_share"] = m["decode_pages_live"] / max(
+                    1, m["decode_columns"])
                 # Quantized-serving observability (rides the PR 6 chain:
                 # replica stats → serve.status() → /api/serve/load →
                 # `ray_tpu status --serve`): the dtype knobs as resolved
@@ -2519,10 +2527,13 @@ class LLMEngine:
 
     def _count_decode_pages(self, active: list[int], width: int) -> None:
         """`decode_block_fill`'s two sums for one decode window at table
-        width `width`: the pages the decoding slots' keys lie on, and
-        those pages rounded up to whole kv blocks of the decode kernel
-        (its own rule, ops/paged_attention.decode_block_pages, asked
-        once a width with the shapes the kernel sees)."""
+        width `width`: the pages the decoding slots' keys lie on (the
+        pages the decode kernel fetches), and those pages rounded up to
+        whole kv blocks of the kernel (its own rule,
+        ops/paged_attention.decode_block_pages, asked once a width with
+        the shapes the kernel sees: a live block's dead columns are
+        masked compute). `decode_live_column_share` puts the first sum
+        over the table the call is handed: every slot's `width` columns."""
         if width not in self._decode_block_at:
             from ray_tpu.ops.paged_attention import decode_block_pages
 
@@ -2535,6 +2546,7 @@ class LLMEngine:
         self.stats["decode_pages_live"] += int(live.sum())
         self.stats["decode_pages_fetched"] += int(
             (-(-live // block) * block).sum())
+        self.stats["decode_columns"] += self.n_slots * width
 
     def _chunk_width(self, done: int, n: int) -> int:
         """Pow-2 page-table width a chunk row [done, done+n) actually

@@ -128,8 +128,10 @@ def test_every_engine_metric_key_is_a_key_of_metrics(served):
         with open(path) as f:
             keys |= set(re.findall(r"engine_metric:(\w+)", f.read()))
     assert len(keys) >= 10
-    # The two kernels' block counters (PR 34, PR 37) among them.
-    assert {"prefill_block_fill", "decode_block_fill"} <= keys
+    # The two kernels' block counters (PR 34, PR 37) among them, and
+    # the share of its table the decode kernel fetches (PR 41).
+    assert {"prefill_block_fill", "decode_block_fill",
+            "decode_live_column_share"} <= keys
     keys.discard("compiles_in_window")      # the harness adds this one
     have = eng.metrics()
     assert sorted(keys - set(have)) == []

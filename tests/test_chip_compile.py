@@ -506,6 +506,75 @@ def test_many_head_grouped_kernels_compile(chip, heads, kind):
         kernels=("paged_prefill_attn" + suffix,))
 
 
+# The decode call as each cell's decode program makes it (slots, H, G, K,
+# table width, pool dtype, ring): `opt-1.3b.batch` and its int8 pool,
+# `zaya1-8b.reason`, `laguna-s-2.1.codegen`'s full and window kinds.
+_DECODE_CELLS = {
+    "opt-1.3b": (32, 32, 32, 64, 32, jnp.bfloat16, False),
+    "opt-1.3b-int8": (32, 32, 32, 64, 32, jnp.int8, False),
+    "zaya1-8b": (64, 8, 2, 128, 32, jnp.bfloat16, False),
+    "laguna-full": (64, 48, 8, 128, 64, jnp.bfloat16, False),
+    "laguna-window": (64, 72, 8, 128, 13, jnp.bfloat16, True),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_DECODE_CELLS))
+def test_decode_kernel_at_a_cells_shapes_fits_what_its_rule_reckons(chip,
+                                                                    cell):
+    """The decode kernel keeps the pools in HBM and fetches live pages by
+    its own DMAs into buffers of a block (PR 41): at every cell's shapes
+    it compiles for the chip with one grid step for the whole batch, its
+    pools are operands in no block of VMEM, and its VMEM scratch (the K
+    and V buffers, the block-diagonal query, the softmax state) plus the
+    score tiles is what `_decode_vmem_bytes` reckons for the rule's
+    block, to the byte for a bf16 pool; with the batch's queries and
+    outputs it stays inside the 16 MiB a kernel gets."""
+    import importlib
+
+    attn = importlib.import_module("ray_tpu.ops.paged_attention")
+    slots, heads, kv_heads, head_dim, width, dtype, ring = _DECODE_CELLS[cell]
+    lanes, item = kv_heads * head_dim, jnp.dtype(dtype).itemsize
+    pool = chip((3, 1025, PS, lanes), dtype)
+    i32 = lambda *shape: chip(shape, jnp.int32)
+    scale = chip((3, 1025), jnp.float32)
+
+    def call(q, k, v, l, t, n, col, ks, vs):
+        kw = {"window": 512, "col_page": col} if ring else {}
+        if item == 1:
+            kw.update(k_scale=ks, v_scale=vs)
+        return paged_attention(q, k, v, l, t, n, interpret=False, **kw)
+
+    args = (chip((slots, heads, head_dim), jnp.bfloat16), pool, pool,
+            _layer(chip), i32(slots, width), i32(slots), i32(slots, width),
+            scale, scale)
+    _compile(call, *args,
+             kernels=("paged_decode_attn" + ("_window" if ring else ""),))
+    (eqn,) = [e for e in jax.make_jaxpr(call)(*args).eqns
+              if e.primitive.name == "pallas_call"]
+    spec = eqn.params["grid_mapping"]
+    assert spec.grid == (1,)
+    assert [str(m.block_aval.memory_space).lower().endswith("any")
+            for m in spec.block_mappings] == [False, True, True, False]
+    scratch = [v.aval for v in eqn.params["jaxpr"].invars[
+        -spec.num_scratch_operands:]]
+    vmem = sum(int(np.prod(a.shape)) * jnp.dtype(a.dtype).itemsize
+               for a in scratch if "sem" not in str(a.dtype).lower())
+    n = attn.decode_block_pages(width, PS, lanes, item, heads)
+    assert n == {"opt-1.3b": 2, "opt-1.3b-int8": 4, "zaya1-8b": 16,
+                 "laguna-full": 4, "laguna-window": 4}[cell]
+    buffers = attn._DECODE_BUFFERS * 2 * n * PS * lanes * item
+    assert vmem == (buffers + heads * lanes * (2 + 4)
+                    + 2 * heads * 128 * 4)
+    tiles = 2 * heads * n * PS * 4
+    dequant = 2 * n * PS * lanes * 4 if item == 1 else 0
+    reckoned = attn._decode_vmem_bytes(n, PS, lanes, item, heads)
+    assert vmem + tiles + dequant <= reckoned <= attn._DECODE_VMEM_BUDGET
+    # bf16: all the rule overcounts is the block-diagonal query at 4 bytes
+    assert item == 1 or reckoned - (vmem + tiles) == heads * lanes * 6
+    queries = 2 * 2 * slots * heads * head_dim * 2      # q, out; two each
+    assert reckoned + queries <= attn._DECODE_GROUP_BUDGET < 16 * 2**20
+
+
 @pytest.fixture(scope="module")
 def laguna_serving(chip):
     """(cfg, params, pool) of the laguna cell as shapes on one described

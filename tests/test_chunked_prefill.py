@@ -887,6 +887,31 @@ class TestObservability:
                 live * windows, width * windows)
             assert m["decode_block_fill"] == pytest.approx(live / width)
 
+    def test_decode_live_column_share(self):
+        """The same live pages over EVERY column of the decode call's
+        table (n_slots x the window's width): what the decode kernel
+        fetches of a (slot, column) grid since PR 41. Two slots, one
+        decoding: 2 live pages of 2 x 2 columns, then 3 of 2 x 4; 0
+        before any window and after reset_stats()."""
+        cfg = gpt.GPTConfig.tiny(attn_impl="xla", dtype=jnp.float32,
+                                 max_seq=2048)
+        eng = LLMEngine(cfg, gpt.init_params(cfg, jax.random.key(1)),
+                        n_slots=2, max_len=2048, kv_mode="paged",
+                        page_size=64, n_pages=40, prefill_chunk=128,
+                        prefill_token_budget=256)
+        assert eng.metrics()["decode_live_column_share"] == 0
+        rng = np.random.default_rng(3)
+        for n_prompt, live, width in ((100, 2, 2), (130, 3, 4)):
+            eng.reset_stats()
+            assert eng.metrics()["decode_live_column_share"] == 0
+            _drive(eng, [eng.submit(
+                list(map(int, rng.integers(1, cfg.vocab_size, n_prompt))),
+                max_tokens=4)])
+            m = eng.metrics()
+            assert m["decode_columns"] == 2 * width * m["decode_windows"] > 0
+            assert m["decode_live_column_share"] == pytest.approx(
+                live / (2 * width))
+
     def test_request_chunk_timestamps(self, params):
         eng = LLMEngine(CFG, params, n_slots=2, max_len=128,
                         prefill_buckets=(64,), kv_mode="paged",

@@ -397,9 +397,9 @@ def test_decode_block_rule(model, n_pg):
     assert n == 1 or n * page <= pa._DECODE_BLOCK_BYTES
     assert n * shape["page_size"] <= pa._DECODE_BLOCK_KEYS
     H, lanes = shape["n_heads"], shape["kv_lanes"]
-    blocks = 2 * 2 * n * page                       # K, V; two buffers
+    blocks = 3 * 2 * n * page               # K, V; three buffers (PR 41)
     rest = (2 * H * n * shape["page_size"] * 4      # scores, probabilities
-            + H * lanes * 12 + 2 * H * 128 * 4)     # q, block-diagonal q, acc
+            + H * lanes * 12 + 2 * H * 128 * 4)     # block-diagonal q, acc
     assert blocks + rest <= pa._DECODE_VMEM_BUDGET
     assert n == decode_block_pages(n_pg, **dict(shape))     # no hidden input
     assert n == min(_DECODE_BLOCK_AT_FULL_WIDTH[model],
@@ -411,30 +411,210 @@ def test_decode_block_rule(model, n_pg):
     assert decode_block_pages(32, 64, 65536, 2, 256) == 1
 
 
-@pytest.mark.parametrize("case", ["tail", "ring", "none_live", "one_step"])
-def test_a_dead_column_holds_its_positions_page(case):
-    """`_held_pages`: column c is operand c % n of grid step c // n, and
-    an operand whose block index repeats is not fetched again. A slot's
-    null tail takes the pages its positions held at the last live step
-    (no null-page fetch a slot and layer); a ring's dead columns take
-    the last live page before them at their position or, with none, the
-    first after; a position that is never live keeps its own column; a
-    table of one step has nothing to hold."""
-    tables, live, n, want = {
-        "tail": ([[11, 12, 13, 14, 15, 0, 0, 0]],
-                 [[1, 1, 1, 1, 1, 0, 0, 0]], 2,
-                 [[11, 12, 13, 14, 15, 14, 15, 14]]),
-        "ring": ([[21, 22, 23, 24, 25, 26, 27, 28]],
-                 [[0, 0, 1, 1, 1, 0, 0, 1]], 2,
-                 [[23, 24, 23, 24, 25, 24, 25, 28]]),
-        "none_live": ([[0, 0, 0, 0], [31, 0, 0, 0]],
-                      [[1, 0, 0, 0], [1, 0, 0, 0]], 2,
-                      [[0, 0, 0, 0], [31, 0, 31, 0]]),
-        "one_step": ([[41, 0, 0, 0]], [[1, 0, 0, 0]], 4, [[41, 0, 0, 0]]),
-    }[case]
-    got = pa._held_pages(jnp.asarray(tables, jnp.int32),
-                         jnp.asarray(live, bool), n)
-    assert np.asarray(got).tolist() == want
+def _scattered_ring(rng, *, col_page, G, K, ps, dtype=jnp.float32):
+    """A ring pool whose column c of slot b holds logical page
+    ``col_page[b][c]`` (-1: none) of that slot's dense timeline, at page
+    id 1 + b * R + c. -> (k_pool, v_pool, tables, col_page)."""
+    col_page = np.asarray(col_page, np.int32)
+    B, R = col_page.shape
+    T = (col_page.max() + 1) * ps
+    dense = [rng.normal(size=(B, T, G * K)).astype(np.float32)
+             for _ in range(2)]
+    pools = [rng.normal(size=(N_LAYERS, 1 + B * R, ps, G * K)).astype(
+        np.float32) for _ in range(2)]
+    tables = 1 + np.arange(B * R, dtype=np.int32).reshape(B, R)
+    for b, c in zip(*np.nonzero(col_page >= 0)):
+        for pool, line in zip(pools, dense):
+            pool[:, tables[b, c]] = line[b, col_page[b, c] * ps:
+                                         (col_page[b, c] + 1) * ps]
+    return (jnp.asarray(pools[0], dtype), jnp.asarray(pools[1], dtype),
+            jnp.asarray(tables), jnp.asarray(col_page))
+
+
+def _live_pages(tables, lengths, ps, *, col_page=None, window=None):
+    """Page ids some slot attends: under its length and, in a ring,
+    inside the window of its query."""
+    tables, lengths = np.asarray(tables), np.asarray(lengths)[:, None]
+    first = (np.arange(tables.shape[1])[None] * ps if col_page is None
+             else np.where(np.asarray(col_page) < 0, 2**30,
+                           np.asarray(col_page) * ps))
+    live = first < lengths
+    if window is not None:
+        live &= first + ps > lengths - window
+    return np.unique(tables[live])
+
+
+@pytest.mark.parametrize("kind", ["full", "ring", "int8"])
+def test_a_page_no_slot_holds_live_is_never_read(kind, monkeypatch):
+    """The decode kernel fetches live pages only: with EVERY other page
+    of the pool NaN (the null page, the pages under a slot's dead
+    columns, a ring's columns outside the window; an int8 pool's dead
+    pages carry a NaN scale) the output is finite and the oracle's over
+    the clean pool. Dead table entries point at poisoned pages, not at
+    the null page alone."""
+    H, G, K, ps = 8, 2, 128, 16
+    monkeypatch.setattr(pa, "_DECODE_BLOCK_KEYS", 64)
+    rng = np.random.default_rng(21)
+    scales, kw = {}, {}
+    if kind == "ring":
+        col_page = [[6, 1, 2, 3, 4, 5], [0, -1, -1, -1, -1, -1],
+                    [12, 13, 8, 9, 10, 11], [6, 7, 8, 3, 4, 5]]
+        lengths = [100, 3, 214, 140]
+        k_pool, v_pool, tables, col_page = _scattered_ring(
+            rng, col_page=col_page, G=G, K=K, ps=ps)
+        kw = dict(window=40, col_page=col_page)
+    else:
+        n_pg, lengths = 8, [1, 17, 64, 65, 100, 128, 0]
+        B = len(lengths)
+        tables = rng.permutation(np.arange(1, 1 + B * n_pg)).astype(
+            np.int32).reshape(B, n_pg)      # dead columns hold real ids
+        shape = (N_LAYERS, 1 + B * n_pg, ps, G * K)
+        if kind == "int8":
+            (k_pool, v_pool), (ks, vs) = _quantized(rng, shape)
+            scales = dict(k_scale=ks, v_scale=vs)
+        else:
+            k_pool, v_pool = (jnp.asarray(rng.normal(size=shape), jnp.float32)
+                              for _ in range(2))
+    n = jnp.asarray(lengths, jnp.int32)
+    q = jnp.asarray(rng.normal(size=(len(lengths), H, K)), jnp.float32)
+    layer = jnp.int32(1)
+    want = reference_paged_attention(q, k_pool, v_pool, layer, tables, n,
+                                     **scales, **kw)
+    dead = np.setdiff1d(np.arange(k_pool.shape[1]),
+                        _live_pages(tables, lengths, ps,
+                                    col_page=kw.get("col_page"),
+                                    window=kw.get("window")))
+    assert len(dead) > len(lengths)
+    if kind == "int8":
+        scales = {name: s.at[:, dead].set(jnp.nan)
+                  for name, s in scales.items()}
+        k_pool, v_pool = (p.at[:, dead].set(127) for p in (k_pool, v_pool))
+    else:
+        k_pool, v_pool = (p.at[:, dead].set(jnp.nan)
+                          for p in (k_pool, v_pool))
+    got = np.asarray(paged_attention(q, k_pool, v_pool, layer, tables, n,
+                                     **scales, **kw))
+    assert np.isfinite(got).all()
+    live = np.asarray(lengths) > 0
+    np.testing.assert_allclose(got[live], np.asarray(want)[live], atol=1e-5)
+    assert not got[~live].any()
+
+
+@pytest.mark.parametrize("idle", ["first", "last", "all"])
+def test_idle_slots_are_never_visited(idle):
+    """Length 0: no DMA, no block, no index map; the slot's output is 0
+    and its neighbours' are the oracle's, wherever the idle slots sit
+    (the walk starts on one, ends on one, or finds no block at all)."""
+    H, G, K, ps, n_pg = 4, 4, 16, 16, 8
+    lengths = {"first": [0, 0, 70, 128, 1], "last": [33, 128, 5, 0, 0],
+               "all": [0, 0, 0, 0, 0]}[idle]
+    rng = np.random.default_rng(22)
+    k_pool, v_pool, tables, _ = _pool_and_tables(
+        rng, B=5, H=G, K=K, ps=ps, n_pg=n_pg, dtype=jnp.float32)
+    tables = jnp.asarray(1 + np.arange(5 * n_pg).reshape(5, n_pg), jnp.int32)
+    q = jnp.asarray(rng.normal(size=(5, H, K)), jnp.float32)
+    n = jnp.asarray(lengths, jnp.int32)
+    got = np.asarray(paged_attention(q, k_pool, v_pool, jnp.int32(2), tables,
+                                     n))
+    want = np.asarray(reference_paged_attention(q, k_pool, v_pool, 2, tables,
+                                                n))
+    live = np.asarray(lengths) > 0
+    np.testing.assert_allclose(got[live], want[live], atol=1e-5)
+    assert not got[~live].any()
+
+
+def test_a_batch_too_large_for_one_step_walks_in_slot_groups(monkeypatch):
+    """The queries and outputs of the whole batch sit in VMEM beside the
+    block's buffers; where they would not fit (`_DECODE_GROUP_BUDGET`,
+    forced small here) the grid gets a step a group of slots, each
+    fetching its own first blocks: groups of 2 over 6 slots, idle slots
+    and a one-token slot among them, read what one step over all reads."""
+    H, G, K, ps, n_pg = 8, 2, 128, 16, 8
+    lengths = [128, 0, 0, 77, 1, 16]
+    rng = np.random.default_rng(25)
+    k_pool, v_pool, _, _ = _pool_and_tables(
+        rng, B=6, H=G, K=K, ps=ps, n_pg=n_pg, dtype=jnp.float32)
+    tables = jnp.asarray(1 + np.arange(6 * n_pg).reshape(6, n_pg), jnp.int32)
+    q = jnp.asarray(rng.normal(size=(6, H, K)), jnp.float32)
+    args = (jnp.int32(1), tables, jnp.asarray(lengths, jnp.int32))
+    whole = paged_attention(q, k_pool, v_pool, *args)
+    block = pa._decode_vmem_bytes(
+        pa.decode_block_pages(n_pg, ps, G * K, 4, H), ps, G * K, 4, H)
+    slot = H * K * 4
+    assert pa._decode_slot_group(6, slot, block) == 6
+    monkeypatch.setattr(pa, "_DECODE_GROUP_BUDGET", block + 4 * 2 * slot)
+    assert pa._decode_slot_group(6, slot, block) == 2
+    assert pa._decode_slot_group(7, slot, block) == 1       # no divisor
+    grouped = jax.make_jaxpr(lambda *a: paged_attention(*a))(
+        q, k_pool, v_pool, *args)
+    (call,) = [e for e in grouped.eqns if e.primitive.name == "pallas_call"]
+    assert call.params["grid_mapping"].grid == (3,)
+    got = paged_attention(q, k_pool, v_pool, *args)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(whole))
+    want = reference_paged_attention(q, k_pool, v_pool, *args)
+    live = np.asarray(lengths) > 0
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
+                               atol=1e-5)
+
+
+@pytest.mark.parametrize("kv", ["float32", "int8"])
+def test_a_short_slot_after_a_long_one_sees_no_stale_row(kv, monkeypatch):
+    """A block's buffer keeps the rows of the block before it wherever
+    its own slot has a dead column: after a slot that filled every row
+    with large values come a slot of exactly one live block, one whose
+    last live block has dead columns inside, and one of one token; their
+    outputs are the oracle's (the stale rows are position-masked, and an
+    int8 block's dequant to 0)."""
+    H, G, K, ps, n_pg = 8, 2, 128, 16, 8
+    monkeypatch.setattr(pa, "_DECODE_BLOCK_KEYS", 64)        # 4 pages a block
+    lengths = [128, 64, 128, 81, 128, 1]
+    B = len(lengths)
+    rng = np.random.default_rng(23)
+    tables = jnp.asarray(1 + np.arange(B * n_pg).reshape(B, n_pg), jnp.int32)
+    shape = (N_LAYERS, 1 + B * n_pg, ps, G * K)
+    long_pages = np.asarray(tables)[[0, 2, 4]].ravel()
+    scales = {}
+    if kv == "int8":
+        (k_pool, v_pool), (ks, vs) = _quantized(rng, shape)
+        scales = dict(k_scale=ks.at[:, long_pages].set(50.0),
+                      v_scale=vs.at[:, long_pages].set(50.0))
+    else:
+        k_pool, v_pool = (jnp.asarray(rng.normal(size=shape), jnp.float32)
+                          for _ in range(2))
+        v_pool = v_pool.at[:, long_pages].multiply(1e4)
+    q = jnp.asarray(rng.normal(size=(B, H, K)), jnp.float32)
+    args = (jnp.int32(1), tables, jnp.asarray(lengths, jnp.int32))
+    got = np.asarray(paged_attention(q, k_pool, v_pool, *args, **scales))
+    want = np.asarray(reference_paged_attention(q, k_pool, v_pool, *args,
+                                                **scales))
+    short = [1, 3, 5]
+    np.testing.assert_allclose(got[short], want[short], atol=1e-5)
+    np.testing.assert_allclose(got, want, rtol=1e-3, atol=1e-2)   # the long ones
+
+
+@pytest.mark.parametrize("block_keys", [32, None])
+def test_a_ring_whose_live_columns_are_scattered(block_keys, monkeypatch):
+    """`col_page` alone says where a ring's live pages lie: live columns
+    apart from each other, a dead block between two live ones, a dead
+    column first and last, at two pages a block and at the rule's four
+    (six columns: the last block runs past the ring)."""
+    H, G, K, ps, window = 6, 2, 128, 16, 40
+    if block_keys is not None:
+        monkeypatch.setattr(pa, "_DECODE_BLOCK_KEYS", block_keys)
+    col_page = [[4, 0, 6, -1, 3, 5],       # live 0, 2, 4, 5 (keys 60..99)
+                [5, 6, -1, 0, 3, 4],       # the middle block dead
+                [-1, 9, 1, 8, 7, -1],      # dead first and last (119..158)
+                [0, -1, -1, -1, -1, 1]]    # 20 keys over the ring's ends
+    lengths = [100, 100, 159, 20]
+    rng = np.random.default_rng(24)
+    k_pool, v_pool, tables, col_page = _scattered_ring(
+        rng, col_page=col_page, G=G, K=K, ps=ps)
+    q = jnp.asarray(rng.normal(size=(4, H, K)), jnp.float32)
+    args = (jnp.int32(2), tables, jnp.asarray(lengths, jnp.int32))
+    kw = dict(window=window, col_page=col_page)
+    got = paged_attention(q, k_pool, v_pool, *args, **kw)
+    want = reference_paged_attention(q, k_pool, v_pool, *args, **kw)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
 
 
 def test_page_ops_on_the_flat_pool():
@@ -798,12 +978,11 @@ def test_window_and_col_page_go_together():
 def test_without_a_window_the_kernels_trace_what_they_did(model):
     """`window=None` hands the two families that have no window layer the
     calls they had: the names a trace knows, three and four scalar
-    operands ahead of the blocks (no `col_page`), a (slot, kv block) grid
-    with no KV-head axis. Since PR 37 a decode kv block is
+    operands ahead of the blocks (no `col_page`), no KV-head axis in
+    the prefill grid. Since PR 37 a decode kv block is
     `decode_block_pages` table columns, as a prefill block is
     `prefill_block_pages`: over a table of 8 columns opt-1.3b's 256 KB
-    pages go two a step (4 steps a slot), zaya1-8b's 32 KB pages all
-    eight (1 step), each page an operand a pool."""
+    pages go two a block, zaya1-8b's 32 KB pages all eight."""
     H, G, K = (32, 32, 64) if model == "opt-1.3b" else (8, 2, 128)
     pool = jax.ShapeDtypeStruct((2, 9, 64, G * K), jnp.bfloat16)
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
@@ -817,15 +996,17 @@ def test_without_a_window_the_kernels_trace_what_they_did(model):
         i32(2, 8), i32(2), i32(2))
     n = decode_block_pages(8, 64, G * K, 2, H)
     assert n == (2 if model == "opt-1.3b" else 8)
-    for jaxpr, name, scalars, grid, pages in (
-            (decode, "paged_decode_attn", 3, (4, 8 // n), n),
-            (chunk, "paged_prefill_attn", 4, (2, 2), 4)):
+    # Operands: q, the output and, decode (PR 41), the two pools whole
+    # (left in HBM, a live page a DMA of the kernel's own; one grid step
+    # walks all four slots); prefill, a block of K pages and one of V.
+    for jaxpr, name, scalars, grid, operands in (
+            (decode, "paged_decode_attn", 3, (1,), 4),
+            (chunk, "paged_prefill_attn", 4, (2, 2), 2 * 4 + 2)):
         (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
         spec = call.params["grid_mapping"]
         assert call.params["name"] == name
         assert spec.num_index_operands == scalars and spec.grid == grid
-        # q, a block of K pages, a block of V pages, and the output
-        assert len(spec.block_mappings) == 2 * pages + 2
+        assert len(spec.block_mappings) == operands
     shape = _CELL_SHAPES[model]
     from ray_tpu.ops.paged_attention import prefill_kv_split
     assert prefill_kv_split(shape["kv_lanes"], shape["chunk"],
