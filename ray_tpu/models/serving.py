@@ -46,9 +46,10 @@ class ServingFamily:
     pool_partition_rules: tuple
     # (tp, mesh) -> {name: callable}: the programs `_bind_programs` wraps.
     programs: Callable
-    # The pool carries a per-slot state: chunk programs are told each
-    # row's slot (`slots=`).
-    slot_state: bool = False
+    # The pool's leaves that are a state by the slot, beside the pages
+    # (() for none): chunk programs are told each row's slot
+    # (`slots=`), and `metrics()["slot_state_bytes"]` counts them.
+    slot_state: tuple = ()
     # The pool keeps some layers' K/V in a ring of pages a slot, beside
     # the pages `PagePool` accounts for: chunk programs are told each
     # row's slot (`slots=`), and `init_pool` is told how many tokens of
@@ -108,7 +109,7 @@ def _zaya() -> ServingFamily:
             name: getattr(zaya, name) for name in (
                 "prefill_chunk_paged", "decode_step_paged",
                 "decode_multi_paged")},
-        slot_state=True, expert_counters=True,
+        slot_state=("slot_state",), expert_counters=True,
         unsupported=(
             Unsupported(
                 "kv_mode", lambda o: o.kv_mode == "paged", "paged",
@@ -239,7 +240,85 @@ def _laguna() -> ServingFamily:
         ))
 
 
-_FAMILIES = {"gpt": _gpt, "zaya": _zaya, "laguna": _laguna}
+def _qwen3_next() -> ServingFamily:
+    from jax.sharding import PartitionSpec
+
+    from ray_tpu.models import qwen3_next
+
+    state = ("the linear layers' recurrent state and convolution tail "
+             "(models/qwen3_next.py: a float32 matrix a head and layer, "
+             "12.9 MB a slot at the published sizes, indexed by slot)")
+    return ServingFamily(
+        name="qwen3_next", model=qwen3_next,
+        init_pool=qwen3_next.init_paged_kv,
+        pool_partition_rules=((r".*", PartitionSpec()),),
+        programs=lambda _tp, _mesh: {
+            name: getattr(qwen3_next, name) for name in (
+                "prefill_chunk_paged", "decode_step_paged",
+                "decode_multi_paged")},
+        slot_state=qwen3_next.SLOT_STATE_LEAVES, expert_counters=True,
+        unsupported=(
+            Unsupported(
+                "kv_mode", lambda o: o.kv_mode == "paged", "paged",
+                "the qwen3_next family serves from the paged pool only: "
+                "kv_mode='dense' would need a [L, B, T] cache backend for "
+                f"the full layers with {state} carried beside it"),
+            Unsupported(
+                "prefill_chunk", lambda o: o.prefill_chunk > 0, 128,
+                "the qwen3_next family has no one-shot prefill: "
+                "prefill_chunk=0 would need a whole-prompt program that "
+                "leaves the prompt's final recurrent state in the slot"),
+            Unsupported(
+                "prefill_width_bucketing",
+                lambda o: not o.prefill_width_bucketing, False,
+                "prefill_width_bucketing with the qwen3_next family: a "
+                "chunk program here costs a pass over every held expert's "
+                "weights at any table width, and one bucket a width "
+                "spreads a lone prompt's rows over more programs; a "
+                "dispatch that packs rows of several widths into one "
+                "program would have to be built"),
+            Unsupported(
+                "prefix_cache", lambda o: not o.prefix_cache, False,
+                "prefix_cache with the qwen3_next family: a cached prefix "
+                f"would need a snapshot of {state} at the prefix's "
+                "boundary, stored with its pages (serve/prefix_cache.py "
+                "keeps pages only)"),
+            Unsupported(
+                "spec_draft", lambda o: not o.spec_draft, "",
+                "speculative decoding with the qwen3_next family: a "
+                "rejected proposal rewinds the cursor, and a recurrence "
+                "cannot be run backwards; a verify program that returns "
+                f"{state} at every position to rewind to would have to be "
+                "built"),
+            Unsupported(
+                "kv_transfer", lambda o: not o.kv_transfer, False,
+                "KV page-set transfer with the qwen3_next family: a page "
+                f"set would have to carry {state} (serve/kv_objects.py "
+                "moves pages only)"),
+            Unsupported(
+                "tp", lambda o: int(o.tp) == 1, 1,
+                "tp > 1 with the qwen3_next family: 2 KV heads cannot "
+                "shard over more chips than heads, the experts need an "
+                "expert-parallel exchange of rows between chips "
+                "(ops/moe.py returns the held experts' part only), and no "
+                "partition rule splits the recurrent state by value head"),
+            Unsupported(
+                "weight_dtype", lambda o: o.weight_dtype != "int8", "bf16",
+                "weight_dtype='int8' with the qwen3_next family: "
+                "quantize_params knows the gpt tree's planes, and the "
+                "experts' grouped matmul (ops/moe.py) has no int8 form"),
+            Unsupported(
+                "kv_dtype", lambda o: o.kv_dtype != "int8", "bf16",
+                "kv_dtype='int8' with the qwen3_next family: the per-page "
+                "scale planes are kept by models/paged_kv._quant_write, "
+                "which the gated attention's K/V writer would have to "
+                "call, and the recurrent state is float32 by the model's "
+                "own definition"),
+        ))
+
+
+_FAMILIES = {"gpt": _gpt, "zaya": _zaya, "laguna": _laguna,
+             "qwen3_next": _qwen3_next}
 
 
 @functools.cache

@@ -20,7 +20,16 @@ MLP = "mlp"                      # dense MLP with its norm; a shared expert
 MOE_ROUTE = "moe.route"          # router, top-k, sort, group sizes,
                                  # unsort and combine
 MOE_EXPERTS = "moe.experts"      # the grouped matmuls
-SLOT_STATE = "slot_state"        # a per-slot state's read and write
+SLOT_STATE = "slot_state"        # a per-slot state's read and write; the
+                                 # chain of a dispatch's rows and the
+                                 # write-back's targets
+GDN_IN = "gdn.in"                # a gated delta layer: norm, both input
+                                 # projections, the convolution with its
+                                 # tail, SiLU, L2 norms, beta and g
+GDN_SCAN = "gdn.scan"            # the recurrent state's only reader and
+                                 # writer: the decode step's update, a
+                                 # chunk's scan and its write-back
+GDN_OUT = "gdn.out"              # gated norm, output projection, residual
 HEAD = "head"                    # final norm, head matmul, row selection
 SAMPLE = "sample"                # argmax / categorical over the logits
 COUNTERS = "counters"            # on-device counters the host pulls
@@ -28,4 +37,5 @@ LOSS = "loss"                    # cross-entropy over the logits
 OPTIMIZER = "optimizer"          # the optimizer's update and its apply
 
 ALL = (EMBED, ATTN_IN, ATTN_KV_WRITE, ATTN_KERNEL, ATTN_OUT, MLP, MOE_ROUTE,
-       MOE_EXPERTS, SLOT_STATE, HEAD, SAMPLE, COUNTERS, LOSS, OPTIMIZER)
+       MOE_EXPERTS, SLOT_STATE, GDN_IN, GDN_SCAN, GDN_OUT, HEAD, SAMPLE,
+       COUNTERS, LOSS, OPTIMIZER)

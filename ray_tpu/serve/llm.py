@@ -1412,9 +1412,9 @@ class LLMEngine:
                     nbytes(a) for name, a in self.cache.items()
                     if name in ("k", "v", "k_scale", "v_scale"))
                 # A family's per-slot state beside the pages (0: none).
-                m["slot_state_bytes"] = (
-                    nbytes(self.cache["slot_state"])
-                    if "slot_state" in self.cache else 0)
+                m["slot_state_bytes"] = sum(
+                    nbytes(self.cache[name])
+                    for name in self._family.slot_state)
             m["weight_bytes"] = sum(
                 int(a.nbytes) for a in self._rt.jax.tree.leaves(self.params))
             m["llm_tp"] = self.tp

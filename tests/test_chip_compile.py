@@ -651,3 +651,87 @@ def test_laguna_program_fits_and_moves_no_expert_layer(chip, laguna_serving,
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert 13.5e9 < total < 15.0e9
+
+
+Q_SLOTS, Q_PAGES = 128, 8192
+
+
+@pytest.fixture(scope="module")
+def qwen3_next_serving(chip):
+    """(cfg, params, pool) of the qwen3-next cell as shapes on one
+    described chip, with the three backend questions steered to the
+    chip's answers."""
+    import importlib
+
+    from ray_tpu.models import qwen3_next
+
+    cfg = qwen3_next.Qwen3NextConfig(n_layers=8, n_experts=128,
+                                     vocab_size=37984)
+    params = {name: chip(spec["shape"], jnp.bfloat16)
+              for name, spec in qwen3_next.param_specs(cfg).items()}
+    pool = jax.tree.map(
+        lambda x: chip(x.shape, x.dtype),
+        jax.eval_shape(lambda: qwen3_next.init_paged_kv(
+            cfg, Q_PAGES, PS, Q_SLOTS)))
+    mods = [importlib.import_module("ray_tpu.ops." + m)
+            for m in ("paged_attention", "gated_delta", "moe")]
+    names = ("_interpret_default", "_interpret_default", "_mixed_dot_default")
+    saved = [getattr(m, n) for m, n in zip(mods, names)]
+    for m, n, answer in zip(mods, names, (False, False, True)):
+        setattr(m, n, lambda answer=answer: answer)
+    yield cfg, params, pool
+    for m, n, fn in zip(mods, names, saved):
+        setattr(m, n, fn)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_qwen3_next_program_fits_and_moves_no_state(chip, qwen3_next_serving,
+                                                    program):
+    """The qwen3_next family's two step programs, compiled whole at the
+    cell's size: the full layers' attention calls at head size 256, the
+    recurrent step's kernel (decode) and the experts' grouped matmul are
+    in them under the names a trace finds them by; no layer of the
+    recurrent state (129 slots x 2 MiB float32, 271 MB) and no layer of
+    experts (128 x 2,048 x 512 bf16, 268 MB a matrix) is copied, sliced
+    out or put back; the donated pool (pages, state, tails, counters) is
+    updated in place; weights + pool + what the program needs besides
+    are 11-12 GB of the chip's 16."""
+    from ray_tpu.models import qwen3_next
+
+    cfg, params, pool = qwen3_next_serving
+    assert pool["gdn_state"].shape == (6, Q_SLOTS + 1, 32, 128, 128)
+    assert pool["gdn_conv"].shape == (6, Q_SLOTS + 1, 3, 8192)
+    i32 = lambda *shape: chip(shape, jnp.int32)
+    if program == "decode":
+        key = jax.eval_shape(lambda: jax.random.key(0))
+        compiled = qwen3_next._decode_sample_paged.lower(
+            cfg, params, i32(Q_SLOTS), pool, i32(Q_SLOTS),
+            i32(Q_SLOTS, 64), chip((Q_SLOTS,), jnp.float32),
+            chip(key.shape, key.dtype), attn_impl="kernel").compile()
+        kernels = {"paged_decode_attn": 2, "gdn_decode_step": 6}
+    else:
+        compiled = qwen3_next.prefill_chunk_paged.lower(
+            cfg, params, i32(2, C), pool, i32(2, 64), i32(2), i32(2),
+            slots=i32(2), return_logits=True, attn_impl="kernel").compile()
+        kernels = {"paged_prefill_attn": 2}
+    text = compiled.as_text()
+    for name, n in kernels.items():
+        assert len(re.findall(rf"%\w*{name}[\w.]* = [^\n]*custom-call\(",
+                              text)) == n, name
+    assert len(re.findall(r"%ragged-dot[\w.\-]* = [^\n]*custom-call\(",
+                          text)) >= 3
+    assert (f"bf16[{cfg.n_layers * cfg.n_experts},{cfg.d_model},{cfg.d_ff}]"
+            in text)
+    state_layer = (Q_SLOTS + 1) * 32 * 128 * 128
+    moved = _pool_moves(text, "f32", state_layer)
+    assert not moved, "state-sized moves:\n" + "\n".join(moved)
+    expert_layer = cfg.n_experts * cfg.d_model * cfg.d_ff
+    moved = _pool_moves(text, "bf16", expert_layer)
+    assert not moved, "layer-sized moves:\n" + "\n".join(moved)
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                     for a in pool.values())
+    assert mem.alias_size_in_bytes >= pool_bytes
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 11.0e9 < total < 12.5e9

@@ -204,3 +204,49 @@ def test_top_10_of_256_with_128_held(first, n_rows, layer):
         np.bincount(ids[here] - first, minlength=held))
     assert 0 < int(counts.sum()) < ids.size         # a share, not the whole
     assert int(counts.sum()) == int(here.sum())
+
+
+# ------------------- top-10 of 512 at four shares: the qwen3-next cell's k
+
+@pytest.mark.parametrize("n_rows,layer", [(128, None), (128, 5), (7, 1)])
+def test_top_10_of_512_at_four_shares(n_rows, layer):
+    """A decode step of the qwen3-next cell in small widths: 128 rows, 10
+    choices each over 512 experts, of which a chip of the group holds 128
+    (experts 0-127 ... 384-511), whole stacks and a layer index as the
+    program passes them. The four shares' parts add up to the per-token
+    loop over ALL experts, and every choice is counted by exactly one
+    share."""
+    rng = np.random.default_rng(12)
+    held, d, f = 128, 8, 6
+    ids, gates = _top_k_routing(rng, n_rows, 512, 10)
+    gates = gates / 2.5                                # softmax gates sum to 1
+    x = jnp.asarray(rng.normal(size=(n_rows, d)), jnp.float32)
+    lead = (512,) if layer is None else (8, 512)
+    mk = lambda *s: jnp.asarray(rng.normal(size=lead + s) * 0.3, jnp.float32)
+    w = mk(d, f), mk(d, f), mk(f, d)
+    kw = {} if layer is None else {"layer": jnp.int32(layer)}
+    call = jax.jit(token_choice_experts, static_argnames="first_expert")
+    total, counted = 0.0, 0
+    with jax.default_matmul_precision("highest"):
+        for first in (0, 128, 256, 384):
+            share = tuple(t[..., first:first + held, :, :] for t in w)
+            y, counts = call(x, jnp.asarray(ids),
+                             jnp.asarray(gates, jnp.float32), *share,
+                             first_expert=first, **kw)
+            here = (ids >= first) & (ids < first + held)
+            np.testing.assert_array_equal(
+                np.asarray(counts),
+                np.bincount(ids[here] - first, minlength=held))
+            total, counted = total + np.asarray(y, np.float64), (
+                counted + int(counts.sum()))
+    assert counted == ids.size                          # every choice, once
+    want = np.zeros((n_rows, d))
+    mine = [a if layer is None else a[layer]
+            for a in (np.asarray(t, np.float64) for t in w)]
+    for n in range(n_rows):
+        for e, g in zip(ids[n], gates[n]):
+            a = np.asarray(x[n], np.float64) @ mine[0][e]
+            h = a / (1.0 + np.exp(-a)) * (np.asarray(x[n], np.float64)
+                                          @ mine[1][e])
+            want[n] += g * (h @ mine[2][e])
+    np.testing.assert_allclose(total, want, atol=4e-5)

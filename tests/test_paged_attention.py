@@ -220,6 +220,11 @@ _PREFILL_GEOMETRY = {
       for geometry in ("below", "equal", "multiple", "ragged")
       for kv in ("float32", "bfloat16", "int8")
       for heads in ((2, 2), (8, 2))],
+    # The gated attention's shape: 16 query heads over 2 KV heads of 256
+    # (two lane tiles a head).
+    *[(kv, 256, (16, 2), geometry)
+      for geometry in ("ps8", "equal", "ragged")
+      for kv in ("float32", "bfloat16")],
 ])
 def test_prefill_kernel_matches_gather_reference(kv, head_dim, heads,
                                                  geometry):
@@ -262,7 +267,10 @@ _CELL_SHAPES = {
     "opt-1.3b": dict(page_size=64, kv_lanes=2048, kv_itemsize=2, chunk=128,
                      q_lanes=2048, q_itemsize=2, n_heads=32),
     "zaya1-8b": dict(page_size=64, kv_lanes=256, kv_itemsize=2, chunk=128,
-                     q_lanes=1024, q_itemsize=2, n_heads=8)}
+                     q_lanes=1024, q_itemsize=2, n_heads=8),
+    "qwen3-next-80b-a3b": dict(page_size=64, kv_lanes=512, kv_itemsize=2,
+                               chunk=128, q_lanes=4096, q_itemsize=2,
+                               n_heads=16)}
 
 
 @pytest.mark.parametrize("model", sorted(_CELL_SHAPES))
@@ -324,6 +332,9 @@ _DECODE_GEOMETRY = {
     *[("float32", heads, geometry)
       for geometry in ("multiple", "ragged")
       for heads in ((48, 8, 128), (72, 8, 128))],
+    *[(kv, (16, 2, 256), geometry)
+      for geometry in ("page", "multiple", "ragged", "rule")
+      for kv in ("float32", "bfloat16")],
 ])
 def test_decode_kernel_at_block_boundaries(kv, heads, geometry,
                                            monkeypatch):
@@ -331,7 +342,8 @@ def test_decode_kernel_at_block_boundaries(kv, heads, geometry,
     over `_decode_boundary_lengths`: a block's dead columns and the pad
     columns are position-masked, a dead block is skipped, and an int8
     pool's pages keep a scale each inside one block. `heads` = (H, G,
-    K): the served head counts 8 / 2, 48 / 8 and 72 / 8, and G = H."""
+    K): the served head counts 8 / 2, 48 / 8, 72 / 8 and 16 / 2 at head
+    size 256, and G = H."""
     ps, n_pg, cap = _DECODE_GEOMETRY[geometry]
     if cap is not None:
         monkeypatch.setattr(pa, "_DECODE_BLOCK_KEYS", cap)
@@ -377,9 +389,12 @@ _DECODE_SHAPES = {
     "laguna-s-2.1": dict(page_size=64, kv_lanes=1024, kv_itemsize=2,
                          n_heads=48),
     "laguna-s-2.1.window": dict(page_size=64, kv_lanes=1024, kv_itemsize=2,
-                                n_heads=72)}
+                                n_heads=72),
+    "qwen3-next-80b-a3b": dict(page_size=64, kv_lanes=512, kv_itemsize=2,
+                               n_heads=16)}
 _DECODE_BLOCK_AT_FULL_WIDTH = {"opt-1.3b": 2, "zaya1-8b": 16,
-                               "laguna-s-2.1": 4, "laguna-s-2.1.window": 4}
+                               "laguna-s-2.1": 4, "laguna-s-2.1.window": 4,
+                               "qwen3-next-80b-a3b": 8}
 
 
 @pytest.mark.parametrize("model", sorted(_DECODE_SHAPES))
