@@ -353,11 +353,25 @@ class Config:
     # defaults); a window of one step, and a speculative tick, carry
     # one. 0 = pure-decode ticks (prefill only advances while nothing
     # is decoding); otherwise must be >= llm_prefill_chunk. Ignored
-    # unless llm_prefill_chunk > 0. It also sets the chunk program's
-    # height: every chunk dispatch is [chunk_rows, llm_prefill_chunk]
-    # with chunk_rows = min(n_slots, ceil(max(budget, chunk) / chunk)),
-    # the full chunks ONE budget holds (2 for a chunk of 128); a tick
-    # runs that program as often as its allowance has rows.
+    # unless llm_prefill_chunk > 0. It also sets the chunk programs'
+    # heights. Bucketed by table width (llm_prefill_width_bucketing):
+    # every chunk dispatch is [chunk_rows, llm_prefill_chunk] with
+    # chunk_rows = min(n_slots, ceil(max(budget, chunk) / chunk)), the
+    # full chunks ONE budget holds (2 for a chunk of 128); a tick runs
+    # that program as often as its allowance has rows of a width. At
+    # ONE table width (bucketing off: the zaya, laguna and qwen3_next
+    # families, whose chunk program is a pass over the weights whatever
+    # it carries) there are two heights, H = budget * llm_decode_block
+    # / (2 * chunk) rows (half a tick's allowance: 8 at the defaults;
+    # at most n_slots) and H / 2, neither lower than the height above
+    # (where they meet there is one), and both
+    # programs always carry the head: a tick's rows go into H-row
+    # programs and ONE more for the remainder (4 rows -> [4]; 5-8 ->
+    # [8]; 9-12 -> [8, 4]; 13-16 -> [8, 8]), so a prompt is one pass
+    # over the weights and the engine holds two chunk programs in all
+    # (LLMEngine.chunk_programs()). An idle tick's allowance is a
+    # whole tick's (budget * llm_decode_block), so a request alone in
+    # the engine runs the programs the same request runs under load.
     llm_prefill_token_budget: int = 256
     # Paged-KV prefix cache (serve/prefix_cache.py): completed requests
     # donate their chunk-aligned prefix pages (refcounted, read-only)

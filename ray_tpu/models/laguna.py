@@ -392,7 +392,7 @@ def init_paged_kv(cfg: LagunaConfig, n_pages: int, page_size: int,
     page_size, G*K]`` with their row ids `ring_rows` ``[n_slots+1, R]``
     (the last ring the null slot's), and the decode steps' running
     expert counters (`_COUNTERS`). `dispatch_tokens`: the most tokens of
-    one prompt a chunk dispatch carries (the engine's chunk_rows x
+    one prompt a chunk dispatch carries (the engine's tallest program x
     prefill_chunk)."""
     if kv_dtype not in (None, "bf16"):
         raise ValueError(f"the laguna family's pool is bf16, got {kv_dtype!r}")

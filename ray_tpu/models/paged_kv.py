@@ -408,8 +408,9 @@ def prefill_chunk_paged(cfg: GPTConfig, params, tokens, pool, tables,
     be consecutive chunks of one prompt or chunks of different ones).
 
     The compile-count story for prefill: N and C are engine constants
-    (N = the engine's `chunk_rows`, the full chunks one tick's token
-    budget holds, at most n_slots; C = the chunk size) and
+    (N = one of the engine's `chunk_heights`: bucketed by width, the
+    full chunks one step's token budget holds, at most n_slots;
+    C = the chunk size) and
     `offsets`/`n_valid` are traced vectors,
     so the table WIDTH is the only shape degree of freedom — one program
     lowers per (table width, ``return_logits``) pair. The engine slices

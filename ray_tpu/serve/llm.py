@@ -27,7 +27,11 @@ Design notes:
   grid collapses from buckets × admission-ladder to two programs per
   page-table width (models/paged_kv.py `prefill_chunk_paged`), each
   [chunk_rows, chunk]: as many rows as full chunks fit ONE budget, not
-  as the engine has slots nor as the tick's allowance has rows.
+  as the engine has slots nor as the tick's allowance has rows. An
+  engine that dispatches at ONE table width (width bucketing off: a
+  chunk program is a pass over the weights whatever it carries) has
+  two programs in all, half a tick's allowance tall and a quarter of
+  it, both with the head: a prompt is one program, not one a budget.
 - The engine thread owns the cache; submit()/result flow through plain
   thread-safe queues, so the Serve replica's asyncio loop never blocks on
   device work.
@@ -527,20 +531,40 @@ class LLMEngine:
         # hold is admissible (buckets only cap the one-shot path).
         if prefill_chunk:
             self._prompt_cap = max_len - 1
-            # Height of the chunk program: the full chunks ONE budget
-            # can hold (an idle tick's floor of one chunk included),
-            # never more rows than slots. A constant of the engine, so
-            # the set of programs stays one per (table width, head); a
-            # tick with more rows than this (a window of several steps
-            # carries as many budgets, and short prompts make short
-            # rows) runs the program again, _dispatch_chunks, instead
-            # of widening it.
+            # Heights of the chunk programs, constants of the engine.
+            # Where dispatches are bucketed by table width, ONE height:
+            # the full chunks one budget holds (an idle tick's floor of
+            # one chunk included), never more rows than slots; a tick
+            # with more rows of a width runs the program again
+            # (_dispatch_chunks), because a taller program would carry
+            # the few rows of one width among inert ones and multiply
+            # the width ladder. Where every dispatch runs at ONE table
+            # width a chunk program is a pass over the weights whatever
+            # it carries, so a prompt should be one program: TWO
+            # heights, half a tick's allowance in rows (budget x the
+            # window's steps / 2 chunks) and half of that, neither
+            # lower than the one budget's rows above (what a tick
+            # beside a window of one step places; where the window is
+            # that short, or the slots that few, the two are one), and
+            # the head in both (a program almost always holds a final
+            # row: the headless twin would be two programs more to load
+            # for ~1 ms of head a long prompt's interior program).
             full_chunks = -(-max(o.prefill_token_budget, prefill_chunk)
                             // prefill_chunk)
-            self.chunk_rows = min(n_slots, full_chunks)
+            rows = min(n_slots, full_chunks)
+            if self.prefill_width_bucketing:
+                self.chunk_heights: tuple[int, ...] = (rows,)
+                self.chunk_heads: tuple[bool, ...] = (False, True)
+            else:
+                tall = min(n_slots, max(
+                    rows, o.prefill_token_budget * max(1, o.decode_block)
+                    // (2 * prefill_chunk)))
+                self.chunk_heights = tuple(sorted(
+                    {max(rows, -(-tall // 2)), tall}))
+                self.chunk_heads = (True,)
         else:
             self._prompt_cap = min(self.buckets[-1], max_len - 1)
-            self.chunk_rows = 0
+            self.chunk_heights, self.chunk_heads = (), ()
         # Host-side page accounting (serve/page_pool.py). None = dense.
         self.pool = None
         if self.kv_mode == "paged":
@@ -793,6 +817,9 @@ class LLMEngine:
                       # capability apart from client-path RTT.
                       "prefill_time_s": 0.0, "prefill_tokens": 0,
                       "prefill_chunks": 0, "prefill_dispatches": 0,
+                      # Row positions of those dispatches, inert ones
+                      # included (`prefill_row_fill`'s denominator).
+                      "prefill_rows_dispatched": 0,
                       # Prompt tokens the ticks that had prefill work
                       # waiting were allowed to place (the budget times
                       # the window's steps): prefill_tokens over it is
@@ -1056,14 +1083,42 @@ class LLMEngine:
         widths.append(self.max_pages_per_slot)
         return widths
 
+    @property
+    def chunk_rows(self) -> int:
+        """Height of the tallest chunk program: the most rows, so the
+        most chunks of one prompt, one dispatch carries (a family's ring
+        of pages is sized by it). 0 without chunked prefill."""
+        return self.chunk_heights[-1] if self.chunk_heights else 0
+
+    def chunk_programs(self) -> list[tuple[int, int, bool]]:
+        """The (height, table width, head) of every `prefill_chunk_paged`
+        program this engine dispatches: what `_dispatch_chunks` cuts a
+        tick's rows into and what `warmup_compile` walks. Bucketed by
+        width: one height at every width of the ladder, with and
+        without the head. At one width: the two heights, head always."""
+        return [(rows, width, head) for width in self._width_ladder()
+                for rows in self.chunk_heights for head in self.chunk_heads]
+
+    def _cut_rows(self, n: int) -> list[int]:
+        """Heights of the programs that carry `n` chunk rows of one
+        table width: the tallest while it fills, then ONE program for
+        the remainder, the lowest that holds it (its other rows inert).
+        At heights (4, 8): 4 → [4]; 5-8 → [8]; 9-12 → [8, 4]; 13-16 →
+        [8, 8]. At one height, that height ceil(n / height) times."""
+        tall = self.chunk_heights[-1]
+        cut = [tall] * (n // tall)
+        if n % tall:
+            cut.append(next(h for h in self.chunk_heights if h >= n % tall))
+        return cut
+
     def warmup_compile(self) -> int:
-        """Pre-compile the chunk-program width ladder so no measured
-        window (or live request) pays a first-touch compile: one inert
-        [chunk_rows, C] dispatch (all rows n_valid 0 — every write lands
-        on the reserved null page, pool bytes untouched) per table width
-        per head variant of `prefill_chunk_paged`, plus the draft-prefill
-        mirror (same rows) and `verify_chunk_paged` ([n_slots, k+1]: one
-        row per decoding slot) when speculative decoding is on. Runs
+        """Pre-compile the chunk programs so no measured window (or live
+        request) pays a first-touch compile: one inert dispatch (all
+        rows n_valid 0 — every write lands on the reserved null page,
+        pool bytes untouched) per program of `chunk_programs()`, plus
+        the draft-prefill mirror (same rows) and `verify_chunk_paged`
+        ([n_slots, k+1]: one row per decoding slot) when speculative
+        decoding is on. Runs
         under `compile_watch.warmup_scope()` so the back-to-back ladder
         (well past the storm threshold, well inside the storm window)
         never files a false `recompile.storm` event; the compiles still
@@ -1079,36 +1134,33 @@ class LLMEngine:
 
         rt = self._rt
         jnp = rt.jnp
-        rows = self.chunk_rows
-        toks = jnp.asarray(np.zeros((rows, self.prefill_chunk), np.int32))
-        zeros = jnp.asarray(np.zeros(rows, np.int32))
-        if self.spec_k:
-            vtoks = jnp.asarray(
-                np.zeros((self.n_slots, self.spec_k + 1), np.int32))
-            vzeros = jnp.asarray(np.zeros(self.n_slots, np.int32))
+        zeros = lambda *shape: jnp.asarray(np.zeros(shape, np.int32))
         n = 0
         with _cw.warmup_scope():
-            for width in self._width_ladder():
-                tables = jnp.asarray(np.zeros((rows, width), np.int32))
-                for head in (False, True):
-                    # graftlint: disable=GUARDED-BY (warmup runs before the engine thread exists: start() calls it pre-spawn under _lifecycle_lock, and direct callers own the engine single-threaded)
-                    _x, self.cache = rt.prefill_chunk_paged(
-                        self.cfg, self.params, toks, self.cache, tables,
-                        zeros, zeros, return_logits=head,
-                        attn_impl=self.attn_impl, **self._row_slots(zeros))
-                    n += 1
-                if self.spec_k:
+            for rows, width, head in self.chunk_programs():
+                # graftlint: disable=GUARDED-BY (warmup runs before the engine thread exists: start() calls it pre-spawn under _lifecycle_lock, and direct callers own the engine single-threaded)
+                _x, self.cache = rt.prefill_chunk_paged(
+                    self.cfg, self.params, zeros(rows, self.prefill_chunk),
+                    self.cache, zeros(rows, width), zeros(rows), zeros(rows),
+                    return_logits=head, attn_impl=self.attn_impl,
+                    **self._row_slots(zeros(rows)))
+                n += 1
+            for width in self._width_ladder() if self.spec_k else ():
+                for rows in self.chunk_heights:
                     # graftlint: disable=GUARDED-BY (pre-spawn, see above)
                     _x, self.draft_cache = rt.prefill_chunk_paged(
-                        self.draft_cfg, self.draft_params, toks,
-                        self.draft_cache, tables, zeros, zeros,
+                        self.draft_cfg, self.draft_params,
+                        zeros(rows, self.prefill_chunk), self.draft_cache,
+                        zeros(rows, width), zeros(rows), zeros(rows),
                         return_logits=False, attn_impl=self.attn_impl)
-                    vtables = jnp.asarray(
-                        np.zeros((self.n_slots, width), np.int32))
-                    _x, self.cache = rt.verify_chunk_paged(
-                        self.cfg, self.params, vtoks, self.cache, vtables,
-                        vzeros, vzeros, attn_impl=self.attn_impl)
-                    n += 2
+                    n += 1
+                # graftlint: disable=GUARDED-BY (pre-spawn, see above)
+                _x, self.cache = rt.verify_chunk_paged(
+                    self.cfg, self.params,
+                    zeros(self.n_slots, self.spec_k + 1), self.cache,
+                    zeros(self.n_slots, width), zeros(self.n_slots),
+                    zeros(self.n_slots), attn_impl=self.attn_impl)
+                n += 1
         # graftlint: disable=GUARDED-BY (pre-spawn, see above)
         self._warmed = True
         return n
@@ -1424,11 +1476,15 @@ class LLMEngine:
                 m["prefill_chunk"] = self.prefill_chunk
                 m["prefill_token_budget"] = self.prefill_budget
                 m["chunk_rows"] = self.chunk_rows
+                m["chunk_heights"] = list(self.chunk_heights)
                 # Prompt tokens placed over token positions the chunk
                 # dispatches carried: 1.0 = every row a full chunk.
                 m["prefill_row_fill"] = m["prefill_tokens"] / max(
-                    1, m["prefill_dispatches"] * self.chunk_rows
-                    * self.prefill_chunk)
+                    1, m["prefill_rows_dispatched"] * self.prefill_chunk)
+                # Live rows a chunk program: how often one program
+                # carries what would have been several.
+                m["prefill_rows_per_program"] = m["prefill_chunks"] / max(
+                    1, m["prefill_dispatches"])
                 # Tokens placed over tokens allowed: under 1.0 the pool
                 # (or the work), not the budget, bounds prefill.
                 m["prefill_allowance_used"] = m["prefill_tokens"] / max(
@@ -2400,20 +2456,25 @@ class LLMEngine:
         whatever the window's length (a window of one step, and a
         speculative tick, which is one target pass, carry one budget;
         budget 0 = pure decode ticks). With nothing decoding there is
-        nobody to stall: an idle tick always advances at least one
-        chunk. → tokens spent.
+        nobody to stall: an idle tick may place a whole tick's
+        allowance (budget × the engine's decode window, what a tick
+        beside a full window places; so a prompt alone in the engine
+        is cut into the programs the same prompt is cut into under
+        load) and always advances at least one chunk. → tokens spent.
 
         The allowance is strict, and so is the pool: while slots decode,
         chunks grow only into the pages those slots are not about to
         need (`_decode_page_reserve`), so a full pool stalls prefill
         instead of preempting it.
 
-        The tick's rows are collected first and dispatched by
-        `_dispatch_chunks`, `chunk_rows` rows a program: the program is
-        as tall as one budget fills it, and a tick with more rows (a
-        longer window, many short prompts) runs it several times — the
+        The tick's rows are collected first and cut into programs by
+        `_dispatch_chunks`, whose heights are constants of the engine
+        (`chunk_heights`): bucketed by table width, as tall as one
+        budget fills it, run several times by a tick with more rows (a
+        longer window, many short prompts); at one table width, as
+        tall as half the tick's allowance or a quarter of it — the
         same algorithm with its parameters read off the engine's own
-        budget and window, not a second path.
+        budget, chunk and window, not a second path.
         """
         if not self._prefilling:
             return 0
@@ -2422,7 +2483,8 @@ class LLMEngine:
             allowance = self.prefill_budget * steps
             spare = self._decode_page_reserve(decoding)
         else:
-            allowance = max(self.prefill_budget, self.prefill_chunk)
+            allowance = max(self.prefill_budget * self.decode_block,
+                            self.prefill_chunk)
             spare = 0
         self.stats["prefill_allowance"] += allowance
         spent = 0
@@ -2577,7 +2639,8 @@ class LLMEngine:
         """Width-bucketed chunk dispatch: group the TICK's chunk rows by
         the pow-2 page width each row actually attends over
         (`_chunk_width`) and cut each bucket, in batch (FCFS) order,
-        into fixed-shape [chunk_rows, C] dispatches, each carrying a
+        into fixed-shape [rows, C] dispatches (`_cut_rows`: the heights
+        are the engine's own, `chunk_programs`), each carrying a
         table view sliced to its bucket's width (a bucket's last
         dispatch pads with inert rows) — interior chunks of a
         long-max-len engine stop paying attention compute/bytes ∝
@@ -2586,12 +2649,15 @@ class LLMEngine:
         consecutive chunks of one prompt have monotonically
         non-decreasing widths (written tokens only grow), so ascending
         order preserves the write-before-attend chain across buckets
-        exactly as batch order does within one. The program's height
-        does not follow the tick's allowance: a tall program would
-        carry the few rows of one width among inert ones, and an inert
-        row costs what a live one does outside the kernel. With
-        prefill_width_bucketing off, every row dispatches at full width
-        — the PR 4 two-program grid, byte-identical output."""
+        exactly as batch order does within one. A bucketed program's
+        height does not follow the tick's allowance: a tall program
+        would carry the few rows of one width among inert ones, and an
+        inert row costs what a live one does outside the kernel. With
+        prefill_width_bucketing off every row dispatches at full width,
+        the tick's rows are one bucket, and the program is as tall as
+        half a tick's allowance or a quarter of it, whichever holds
+        what is left: a prompt is one pass over the weights, not one a
+        budget."""
         if self.prefill_width_bucketing:
             buckets: dict[int, list] = {}
             for row in batch:
@@ -2601,39 +2667,43 @@ class LLMEngine:
             buckets = {self.max_pages_per_slot: batch}
         failed: set[int] = set()
         for width in sorted(buckets):
-            rows = buckets[width]
-            for i in range(0, len(rows), self.chunk_rows):
+            rows, i = buckets[width], 0
+            for height in self._cut_rows(len(rows)):
                 # A dispatch failure releases its slots; later dispatches
                 # may still carry those slots' follow-on chunks — drop
                 # them (the request already errored, the slot may be
                 # rebound).
-                group = [r for r in rows[i:i + self.chunk_rows]
+                group = [r for r in rows[i:i + height]
                          if r[0] not in failed]
+                i += height
                 if group:
-                    failed |= self._dispatch_chunk_bucket(group, width)
+                    failed |= self._dispatch_chunk_bucket(group, width,
+                                                          height)
 
-    def _dispatch_chunk_bucket(self, batch, width: int) -> set[int]:
-        """One fixed-shape [chunk_rows, C] prefill_chunk_paged dispatch
+    def _dispatch_chunk_bucket(self, batch, width: int, rows: int) -> set[int]:
+        """One fixed-shape [rows, C] prefill_chunk_paged dispatch
         at one page-table width: each (slot, req, done, n) ROW writes
         prompt tokens [done, done+n) into its slot's pages (several rows
         may carry consecutive chunks of the same prompt); rows without
-        work are inert (n_valid 0). `batch` holds at most chunk_rows
+        work are inert (n_valid 0). `batch` holds at most `rows`
         rows (`_dispatch_chunks` cuts them so). The table view is sliced to `width`
         columns — every row's written prefix + chunk fits by bucket
         construction, and a slot's allocation BEYOND the row's own width
         (a later same-tick chunk already grew it) is simply invisible to
         this row, which never reads or writes past its own kv length.
-        The width is part of the jit cache key (tables is a traced
-        argument), so programs lower per (width, head) pair — the
-        2·log₂(max_pages)+2 budget the compile-count test pins. Final
-        chunks alone return logits and graduate their slot to decode
+        The shapes are part of the jit cache key (tables is a traced
+        argument), so programs lower per (height, width, head) triple of
+        `chunk_programs()` — bucketed, the 2·log₂(max_pages)+2 budget
+        the compile-count test pins; at one width, two. Final
+        chunks alone return logits (at one width the program computes
+        them regardless and they are pulled only then) and graduate
+        their slot to decode
         (the first token emits here — TTFT does not wait for the next
         decode window). Returns the set of slots released by a dispatch
         failure (empty on success) so the bucketed caller can drop their
         follow-on chunks from later buckets in the same tick."""
         rt = self._rt
         with self._phase("prefill.build"):
-            rows = self.chunk_rows
             toks = np.zeros((rows, self.prefill_chunk), np.int32)
             offsets = np.zeros(rows, np.int32)
             valid = np.zeros(rows, np.int32)
@@ -2656,10 +2726,10 @@ class LLMEngine:
                     self.cfg, self.params, rt.jnp.asarray(toks), self.cache,
                     rt.jnp.asarray(tables), rt.jnp.asarray(offsets),
                     rt.jnp.asarray(valid),
-                    return_logits=any_final, attn_impl=self.attn_impl,
-                    **self._row_slots(slots))
+                    return_logits=any_final or False not in self.chunk_heads,
+                    attn_impl=self.attn_impl, **self._row_slots(slots))
                 if self.spec_k:
-                    # Draft prefill mirror: the same [chunk_rows, C] rows
+                    # Draft prefill mirror: the same [rows, C] rows
                     # through the draft model into the draft pool (same
                     # tables/offsets), so a slot graduates with draft
                     # cursor == target cursor and the propose loop never
@@ -2692,6 +2762,7 @@ class LLMEngine:
             self.stats["prefill_tokens"] += sum(n for *_x, n in batch)
             self.stats["prefill_chunks"] += len(batch)
             self.stats["prefill_dispatches"] += 1
+            self.stats["prefill_rows_dispatched"] += rows
             block = self._prefill_block_pages(width)
             for _s, _r, done, n in batch:
                 live = self.pool.pages_for(done + n - 1)

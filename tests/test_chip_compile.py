@@ -377,8 +377,13 @@ def test_paged_program_moves_no_pool_layer(chip, opt_serving, program):
 # ZAYA1-8B as `benchmarks/configs/zaya1-8b.json` serves it: 8 query heads
 # over 2 KV heads of 128 (the pool's minor axis is 2 x 128 = 256 lanes),
 # 64 slots, 2,048 pages of 64, table width 32, chunk 128, 24 layers, all
-# 16 experts and the whole 262,272-row tied vocabulary.
+# 16 experts and the whole 262,272-row tied vocabulary. An engine that
+# dispatches at one table width holds two chunk programs, 4 and 8 rows
+# tall at the default budget, chunk and window, both with the head
+# (`LLMEngine.chunk_programs`).
 Z_SLOTS, Z_PAGES, Z_H, Z_G, Z_K = 64, 2048, 8, 2, 128
+ONE_WIDTH_HEIGHTS = (4, 8)
+ONE_WIDTH_PROGRAMS = ["decode"] + [f"prefill-{h}" for h in ONE_WIDTH_HEIGHTS]
 
 
 def test_grouped_query_kernels_compile_at_head_size_128(chip):
@@ -393,11 +398,13 @@ def test_grouped_query_kernels_compile_at_head_size_128(chip):
         chip((Z_SLOTS, Z_H, Z_K), jnp.bfloat16), pool, pool, _layer(chip),
         chip((Z_SLOTS, 32), jnp.int32), chip((Z_SLOTS,), jnp.int32),
         kernels=("paged_decode_attn",))
-    _compile(lambda q, k, v, l, t, o, n: paged_prefill_attention(
-        q, k, v, l, t, o, n, interpret=False),
-        chip((2, C, Z_H, Z_K), jnp.bfloat16), pool, pool, _layer(chip),
-        chip((2, 32), jnp.int32), chip((2,), jnp.int32),
-        chip((2,), jnp.int32), kernels=("paged_prefill_attn",))
+    for rows in ONE_WIDTH_HEIGHTS:
+        _compile(lambda q, k, v, l, t, o, n: paged_prefill_attention(
+            q, k, v, l, t, o, n, interpret=False),
+            chip((rows, C, Z_H, Z_K), jnp.bfloat16), pool, pool,
+            _layer(chip), chip((rows, 32), jnp.int32),
+            chip((rows,), jnp.int32), chip((rows,), jnp.int32),
+            kernels=("paged_prefill_attn",))
 
 
 @pytest.fixture(scope="module")
@@ -424,10 +431,11 @@ def zaya_serving(chip):
     attn._interpret_default, moe._mixed_dot_default = saved
 
 
-@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("program", ONE_WIDTH_PROGRAMS)
 def test_zaya_program_fits_and_moves_no_expert_layer(chip, zaya_serving,
                                                      program):
-    """The zaya family's two step programs, compiled whole at the cell's
+    """The zaya family's step programs (decode, and the chunk program at
+    both of the engine's heights), compiled whole at the cell's
     size: the attention kernel and the experts' grouped matmul are in
     them under the names a trace finds them by; no layer of experts
     (16 x 2,048 x 2,048 bf16, 134 MB a matrix) and no layer of the pool is
@@ -446,9 +454,10 @@ def test_zaya_program_fits_and_moves_no_expert_layer(chip, zaya_serving,
             chip(key.shape, key.dtype), attn_impl="kernel").compile()
         kernel = "paged_decode_attn"
     else:
+        n = int(program.split("-")[1])
         compiled = zaya.prefill_chunk_paged.lower(
-            cfg, params, i32(2, C), pool, i32(2, 32), i32(2), i32(2),
-            slots=i32(2), return_logits=True, attn_impl="kernel").compile()
+            cfg, params, i32(n, C), pool, i32(n, 32), i32(n), i32(n),
+            slots=i32(n), return_logits=True, attn_impl="kernel").compile()
         kernel = "paged_prefill_attn"
     text = compiled.as_text()
     assert re.search(rf"%\w*{kernel}[\w.]* = [^\n]*custom-call\(", text)
@@ -472,9 +481,10 @@ def test_zaya_program_fits_and_moves_no_expert_layer(chip, zaya_serving,
 
 # Laguna-S-2.1's serving shapes: 8 KV heads of 128 under 48 query heads
 # (full layers, the engine's page tables) and 72 (window layers, a ring
-# of 13 pages a slot); the cell's cut: 5 layers, 128 of 256 experts held,
+# of 25 pages a slot: the window's 8, the 16 that eight chunk rows of one
+# prompt write in one dispatch, and one); the cell's cut: 5 layers, 128 of 256 experts held,
 # half the vocabulary, 64 slots x 64 pages.
-G_SLOTS, G_PAGES, G_G, G_K, G_RING, G_WINDOW = 64, 4096, 8, 128, 13, 512
+G_SLOTS, G_PAGES, G_G, G_K, G_RING, G_WINDOW = 64, 4096, 8, 128, 25, 512
 
 
 @pytest.mark.parametrize("heads,kind", [(48, "full"), (72, "window")])
@@ -499,11 +509,12 @@ def test_many_head_grouped_kernels_compile(chip, heads, kind):
         chip((G_SLOTS, heads, G_K), jnp.bfloat16), pool, pool, _layer(chip),
         i32(G_SLOTS, width), i32(G_SLOTS), i32(G_SLOTS, width),
         kernels=("paged_decode_attn" + suffix,))
-    _compile(lambda q, k, v, l, t, o, n, col: paged_prefill_attention(
-        q, k, v, l, t, o, n, interpret=False, **kw(col)),
-        chip((2, C, heads, G_K), jnp.bfloat16), pool, pool, _layer(chip),
-        i32(2, width), i32(2), i32(2), i32(2, width),
-        kernels=("paged_prefill_attn" + suffix,))
+    for n in ONE_WIDTH_HEIGHTS:
+        _compile(lambda q, k, v, l, t, o, n, col: paged_prefill_attention(
+            q, k, v, l, t, o, n, interpret=False, **kw(col)),
+            chip((n, C, heads, G_K), jnp.bfloat16), pool, pool, _layer(chip),
+            i32(n, width), i32(n), i32(n), i32(n, width),
+            kernels=("paged_prefill_attn" + suffix,))
 
 
 # The decode call as each cell's decode program makes it (slots, H, G, K,
@@ -514,7 +525,7 @@ _DECODE_CELLS = {
     "opt-1.3b-int8": (32, 32, 32, 64, 32, jnp.int8, False),
     "zaya1-8b": (64, 8, 2, 128, 32, jnp.bfloat16, False),
     "laguna-full": (64, 48, 8, 128, 64, jnp.bfloat16, False),
-    "laguna-window": (64, 72, 8, 128, 13, jnp.bfloat16, True),
+    "laguna-window": (64, 72, 8, 128, G_RING, jnp.bfloat16, True),
 }
 
 
@@ -589,7 +600,8 @@ def laguna_serving(chip):
     pool = jax.tree.map(
         lambda x: chip(x.shape, x.dtype),
         jax.eval_shape(lambda: laguna.init_paged_kv(
-            cfg, G_PAGES, PS, G_SLOTS, dispatch_tokens=2 * C)))
+            cfg, G_PAGES, PS, G_SLOTS,
+            dispatch_tokens=ONE_WIDTH_HEIGHTS[-1] * C)))
     attn = importlib.import_module("ray_tpu.ops.paged_attention")
     moe = importlib.import_module("ray_tpu.ops.moe")
     saved = attn._interpret_default, moe._mixed_dot_default
@@ -599,14 +611,15 @@ def laguna_serving(chip):
     attn._interpret_default, moe._mixed_dot_default = saved
 
 
-@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("program", ONE_WIDTH_PROGRAMS)
 def test_laguna_program_fits_and_moves_no_expert_layer(chip, laguna_serving,
                                                        program):
-    """The laguna family's two step programs, compiled whole at the
+    """The laguna family's step programs (decode, and the chunk program
+    at both of the engine's heights), compiled whole at the
     cell's size: all four attention calls and the experts' grouped
     matmul are in them under the names a trace finds them by; no layer
     of experts (128 x 3,072 x 1,024 bf16, 805 MB a matrix) and no layer
-    of either cache kind (the smaller: 65 rings of 13 pages, 111 MB) is
+    of either cache kind (the smaller: 65 rings of 25 pages, 213 MB) is
     copied, sliced out or put back (XLA prefetches W_o and the dense
     MLP's matrices into fast memory, `S(1)` copies of 38-75 MB: reads,
     under the rule's size); the donated pool (pages, rings, counters)
@@ -625,9 +638,10 @@ def test_laguna_program_fits_and_moves_no_expert_layer(chip, laguna_serving,
             chip(key.shape, key.dtype), attn_impl="kernel").compile()
         kernel = "paged_decode_attn"
     else:
+        n = int(program.split("-")[1])
         compiled = laguna.prefill_chunk_paged.lower(
-            cfg, params, i32(2, C), pool, i32(2, 64), i32(2), i32(2),
-            slots=i32(2), return_logits=True, attn_impl="kernel").compile()
+            cfg, params, i32(n, C), pool, i32(n, 64), i32(n), i32(n),
+            slots=i32(n), return_logits=True, attn_impl="kernel").compile()
         kernel = "paged_prefill_attn"
     text = compiled.as_text()
     calls = lambda name: len(re.findall(
@@ -684,10 +698,11 @@ def qwen3_next_serving(chip):
         setattr(m, n, fn)
 
 
-@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("program", ONE_WIDTH_PROGRAMS)
 def test_qwen3_next_program_fits_and_moves_no_state(chip, qwen3_next_serving,
                                                     program):
-    """The qwen3_next family's two step programs, compiled whole at the
+    """The qwen3_next family's step programs (decode, and the chunk
+    program at both of the engine's heights), compiled whole at the
     cell's size: the full layers' attention calls at head size 256, the
     recurrent step's kernel (decode) and the experts' grouped matmul are
     in them under the names a trace finds them by; no layer of the
@@ -710,9 +725,10 @@ def test_qwen3_next_program_fits_and_moves_no_state(chip, qwen3_next_serving,
             chip(key.shape, key.dtype), attn_impl="kernel").compile()
         kernels = {"paged_decode_attn": 2, "gdn_decode_step": 6}
     else:
+        n = int(program.split("-")[1])
         compiled = qwen3_next.prefill_chunk_paged.lower(
-            cfg, params, i32(2, C), pool, i32(2, 64), i32(2), i32(2),
-            slots=i32(2), return_logits=True, attn_impl="kernel").compile()
+            cfg, params, i32(n, C), pool, i32(n, 64), i32(n), i32(n),
+            slots=i32(n), return_logits=True, attn_impl="kernel").compile()
         kernels = {"paged_prefill_attn": 2}
     text = compiled.as_text()
     for name, n in kernels.items():

@@ -84,7 +84,7 @@ def _spy_chunk_programs(eng):
 def _parent_rule(eng):
     """The rule before chunk_rows: every chunk dispatch as tall as the
     engine has slots, up to n_slots rows packed into one."""
-    eng.chunk_rows = eng.n_slots
+    eng.chunk_heights = (eng.n_slots,)
     return eng
 
 
@@ -347,12 +347,12 @@ class TestChunkRows:
         budget = 32
         kw = dict(n_slots=6, max_len=128, prefill_buckets=(64,),
                   kv_mode="paged", page_size=16, prefill_chunk=16,
-                  prefill_token_budget=budget)
+                  prefill_token_budget=budget, decode_block=1)
         eng = LLMEngine(CFG, params, **kw)
         seen = _spy_chunk_shapes(eng)
         reqs = [eng.submit(p, max_tokens=4)
                 for p in _ragged_prompts(rng, (5, 7, 3, 9, 4, 6))]
-        eng.step()
+        eng.step()              # idle, a window of one step: one budget
         # 5+7 | 3+9 | 4 (6 more would pass the budget): three dispatches
         # of two rows, 28 tokens; the last prompt waits for the next tick.
         assert eng.stats["prefill_dispatches"] == len(seen) == 3

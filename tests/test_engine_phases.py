@@ -188,11 +188,12 @@ def test_idle_ticks_are_not_counted(params):
 
 
 def test_backlog_counter_sees_slots_waiting_for_the_prefill_budget(params):
-    """Four 40-token prompts, budget 16 tokens a tick: all four are
+    """Four 40-token prompts, budget 16 tokens a tick (a window of one
+    step, so an idle tick's allowance is one budget too): all four are
     admitted into slots at once and wait there, which `queued` does not
     show."""
     eng = _engine(params, kv_mode="paged", page_size=16, prefill_chunk=16,
-                  prefill_token_budget=16)
+                  prefill_token_budget=16, decode_block=1)
     reqs = [eng.submit(p, max_tokens=4)
             for p in _prompts(5, (40, 40, 40, 40, 40))]
     assert eng.metrics()["awaiting_first_token"] == 5

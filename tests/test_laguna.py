@@ -322,6 +322,37 @@ def test_a_dispatch_taller_than_the_ring_allows_is_refused(params):
         pager.chunks([(0, prompt[:CHUNK], 0), (0, prompt[CHUNK:], CHUNK)])
 
 
+@pytest.mark.parametrize("height", [4, 8])
+def test_inert_rows_of_a_tall_program_leave_every_ring_untouched(params,
+                                                                 height):
+    """One-width engines run programs of 4 and 8 rows, most of them
+    inert behind a short prompt, and an inert row names slot 0: slot 0's
+    ring and pages (a finished prompt's) and slot 2's (empty) keep every
+    byte; the live row's prompt reads the reference's logits."""
+    pager = Pager(CFG, params, rows=8)
+    held, prompt = _tokens(40, 5), _tokens(CHUNK + 5, 6)
+    with jax.default_matmul_precision("highest"):
+        pager.prefill(0, held, rows=8)
+        before = {k: np.asarray(v) for k, v in pager.pool.items()}
+        pager.chunks([(1, prompt[:CHUNK], 0)], head=False, height=height)
+        got = pager.chunks([(1, prompt[CHUNK:], CHUNK)], height=height)[0]
+    ring = np.asarray(pager.pool["ring_rows"])
+    assert ring.shape[1] == laguna.ring_pages(CFG.window, PAGE, 8 * CHUNK)
+    for name in ("k_win", "v_win"):
+        after = np.asarray(pager.pool[name])
+        for slot in (0, 2):
+            assert np.array_equal(after[:, ring[slot]],
+                                  before[name][:, ring[slot]])
+        assert not np.array_equal(after[:, ring[1]], before[name][:, ring[1]])
+    theirs = [p for p in range(1, N_PAGES + 1)
+              if p not in pager.tables[1].tolist()]
+    for name in ("k", "v"):
+        assert np.array_equal(np.asarray(pager.pool[name])[:, theirs],
+                              before[name][:, theirs])
+    np.testing.assert_allclose(got, _ref_logits(params, prompt)[-1],
+                               atol=ATOL_F32, rtol=0)
+
+
 @pytest.mark.parametrize("cut", [1, 15, 16, 17, 31, 33, 47, 63])
 def test_a_prompt_split_anywhere_gives_the_unsplit_logits(params, cut):
     """Chunk rows need not be whole chunks: a 70-token prompt whose first
@@ -409,7 +440,9 @@ def test_engine_serves_the_references_tokens_and_counts(params):
     up to three windows, every emitted token the float32 reference's best
     at its position (deficit under ATOL_F32)."""
     eng = _engine(params)
-    assert eng.chunk_rows == ROWS
+    # One table width: half a tick's allowance (8 rows) capped at the
+    # three slots, and half of that rounded up; the head in both.
+    assert eng.chunk_heights == (2, 3) and eng.chunk_heads == (True,)
     rng = np.random.default_rng(0)
     reqs = [eng.submit(rng.integers(1, CFG.vocab_size, n).tolist(),
                        max_tokens=m)
@@ -421,7 +454,7 @@ def test_engine_serves_the_references_tokens_and_counts(params):
     m = eng.metrics()
     assert m["preemptions"] == 0 and m["slot_state_bytes"] == 0
     page_bytes = PAGE * CFG.n_kv_heads * CFG.head_dim * 4
-    ring = laguna.ring_pages(CFG.window, PAGE, ROWS * CHUNK)
+    ring = laguna.ring_pages(CFG.window, PAGE, eng.chunk_rows * CHUNK)
     assert m["window_kv_bytes"] == (
         2 * CFG.count("window") * (N_SLOTS + 1) * ring * page_bytes)
     assert m["kv_pool_bytes"] == m["window_kv_bytes"] + (

@@ -192,8 +192,9 @@ class TestQuantPool:
         donor = _engine(params, kv_transfer=True, kv_store=store,
                         max_len=256, **kw)
         req = donor.submit(prompt, max_tokens=24, stream=True)
-        for _ in range(5):
+        while req.first_token_at is None:
             donor.step()
+        donor.step()            # a second decode window, not the last
         assert not req.done.is_set()
         conts = donor._export_unfinished()
         assert len(conts) == 1

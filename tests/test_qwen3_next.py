@@ -425,7 +425,9 @@ def test_engine_serves_the_references_tokens_and_counts(params):
     one to three chunk rows, every emitted token the float32 reference's
     best at its position (deficit under ATOL_F32)."""
     eng = _engine(params)
-    assert eng.chunk_rows == ROWS
+    # One table width: half a tick's allowance (8 rows) capped at the
+    # three slots, and half of that rounded up; the head in both.
+    assert eng.chunk_heights == (2, 3) and eng.chunk_heads == (True,)
     rng = np.random.default_rng(0)
     reqs = [eng.submit(rng.integers(1, CFG.vocab_size, n).tolist(),
                        max_tokens=m)

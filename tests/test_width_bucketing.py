@@ -203,7 +203,7 @@ class TestCompileBudget:
                           prefill_buckets=(32,), kv_mode="dense")
         assert dense.warmup_compile() == 0     # nothing to warm
         full = _engine(params, prefill_width_bucketing=False)
-        assert full.warmup_compile() == 2      # one width, two heads
+        assert full.warmup_compile() == 2      # one width: two heights, the head in both
 
     def test_warmup_on_start_knob(self, params):
         """`warmup=True` (llm_warmup_compile) warms at `start()`; the
@@ -262,9 +262,10 @@ class TestScheduler:
     def test_mixed_width_tick_issues_one_dispatch_per_bucket(self, params):
         """One budget window packing consecutive chunks of a long prompt
         (done 0 / 16 / 32 → widths 1 / 2 / 4) must dispatch once per
-        distinct width, ascending (write-before-attend order)."""
+        distinct width, ascending (write-before-attend order). A window
+        of one step: an idle tick's allowance is one budget."""
         eng = _engine(params, prefill_width_bucketing=True,
-                      prefill_token_budget=48)
+                      prefill_token_budget=48, decode_block=1)
         rng = np.random.default_rng(6)
         rl = eng.submit(_ragged_prompts(rng, (100,))[0], max_tokens=4)
         eng.step()                                # first budget window
@@ -326,13 +327,13 @@ class TestScheduler:
         LATER buckets carry that slot's follow-on chunks and must be
         skipped, not dispatched against a freed slot."""
         eng = _engine(params, prefill_width_bucketing=True,
-                      prefill_token_budget=48)
+                      prefill_token_budget=48, decode_block=1)
         rng = np.random.default_rng(9)
         doomed = eng.submit(_ragged_prompts(rng, (100,))[0], max_tokens=4)
         real = eng._dispatch_chunk_bucket
         calls = []
 
-        def boom(batch, width):
+        def boom(batch, width, rows):
             calls.append(width)
             # Fail the way a device error surfaces: release the slots.
             for slot, req, _d, _n in batch:
