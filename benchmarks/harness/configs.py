@@ -72,6 +72,14 @@ def metrics_for_cell(bench: dict, group: str, cell: str) -> list[dict]:
             if "workloads" not in m or cell in m["workloads"]]
 
 
+def quantity_of(end_to_end_name: str) -> str:
+    """The harness's quantity that an end-to-end metric reports: the name
+    up to its first dot. `out_tokens_per_s.batch` is `out_tokens_per_s`
+    under a bound of its own cells' spread (PR 47), so an entry gives a
+    cell a bound of its own and no code knows the cell."""
+    return end_to_end_name.split(".", 1)[0]
+
+
 def dims(config: dict) -> dict:
     """Program-side names of the model's sizes: d_model, n_layers, ..."""
     return {field: config[key]

@@ -19,7 +19,7 @@ from . import configs, stats, traffic, weights
 now = time.perf_counter          # the engine stamps requests on this clock
 
 
-def build_engine(family, config: dict, seed: int, log, degrade=None):
+def build_engine(family, config: dict, seed: int, log, mark, degrade=None):
     """-> (cfg, params, engine), the program's configuration object and
     weights as `family` (harness/families.py) makes them. `degrade`
     (benchmarks/tools only) maps the true weights to the ones the engine
@@ -27,7 +27,8 @@ def build_engine(family, config: dict, seed: int, log, degrade=None):
     server; the reference keeps the true ones. The `serve` block's
     `prefix_cache`, `kv_dtype` and `spec_draft` are the engine's options
     of those names; a file without them runs with the prefix cache off,
-    a bf16 pool and no draft model."""
+    a bf16 pool and no draft model. `mark` (run.RunContext.mark) ends
+    the run's phases `weights` and `engine`."""
     import jax.numpy as jnp
 
     from ray_tpu.serve.llm import LLMEngine
@@ -39,6 +40,7 @@ def build_engine(family, config: dict, seed: int, log, degrade=None):
                                  geo["tp"])
     log(f"weights: {sum(a.nbytes for a in params.values()) / 1e9:.2f} GB "
         f"bf16 made on the device in {now() - t0:.1f}s (tp={geo['tp']})")
+    mark("weights")
     t0 = now()
     served = params if degrade is None else degrade(params)
     eng = LLMEngine(
@@ -52,6 +54,7 @@ def build_engine(family, config: dict, seed: int, log, degrade=None):
         decode_block=geo.get("decode_block"))
     log(f"engine built in {now() - t0:.1f}s: attn_impl={eng.attn_impl} "
         f"n_slots={eng.n_slots} pages={eng.n_pages}x{eng.page_size}")
+    mark("engine")
     return cfg, params, eng
 
 
@@ -320,7 +323,7 @@ def tail_ms(rows, field: str, q: float) -> float | None:
 
 
 def check_streams(reference, ref_cfg, params, config: dict, records,
-                  seed: int, log) -> dict:
+                  seed: int, log, compiles) -> dict:
     """Is the served stream as close to the float32 reference as a plain
     bf16 forward of the same weights is?
 
@@ -342,7 +345,19 @@ def check_streams(reference, ref_cfg, params, config: dict, records,
     bf16 is": the run measures it. What it cannot see: a loss of
     precision under about a doubling of the noise (weights rounded
     through int8 read 1.97 x the plain forward's mean deficit on the
-    chip); the configuration's file says why the factor is 3."""
+    chip); the configuration's file says why the factor is 3.
+
+    Every stream is padded to ONE length, the configuration's
+    `serve.max_len`: the references are causal, so the rows under a
+    stream's own length do not depend on what follows them, and the
+    reference is one program a configuration, compiled once a machine
+    and found in the compile cache by every seed after: a program a
+    padded length is 25-50 s each, and which lengths come up is the
+    seed's, so a run's end would be a lottery against the driver's 360 s
+    (PERF.md section 6, PR 47). The rows are cut on the host: a slice of
+    a device array is a program a distinct slice. `compiles`
+    (run.CompileCounter) counts the programs the check compiled and the
+    ones it loaded, for its last log line."""
     import jax
     import jax.numpy as jnp
 
@@ -354,25 +369,28 @@ def check_streams(reference, ref_cfg, params, config: dict, records,
     if not picks:
         return {"ok": False, "why": "no emitted token to check"}
     ref = jax.jit(reference.paired_rows, static_argnums=(2,))
+    asked0, missed0 = compiles(), compiles.misses
+    t0 = now()
     served, plain, n_top1 = [], [], 0
     for r in picks:
         q = r["req"]
         prompt, out = list(q.prompt_ids[:q.n_prompt]), list(q.out_ids)
         n = len(prompt) + len(out)
-        seq = np.zeros(min(-(-(n + 1) // 256) * 256, geo["max_len"]), np.int32)
+        seq = np.zeros(geo["max_len"], np.int32)
         seq[:n] = prompt + out
-        top, arg, at_served, at_plain = ref(params, jnp.asarray(seq), ref_cfg)
         rows = slice(len(prompt) - 1, n - 1)
-        top = np.asarray(top[rows], np.float32)
-        d_served = top - np.asarray(at_served[rows], np.float32)
-        d_plain = top - np.asarray(at_plain[rows], np.float32)
+        top, arg, at_served, at_plain = (
+            np.asarray(a)[rows] for a in ref(params, jnp.asarray(seq), ref_cfg))
+        top = top.astype(np.float32)
+        d_served = top - at_served.astype(np.float32)
+        d_plain = top - at_plain.astype(np.float32)
         if d_served.shape[0] != len(out) or not (
                 np.all(np.isfinite(d_served)) and np.all(np.isfinite(d_plain))):
             return {"ok": False, "why": f"request {r['index']}: non-finite "
                                         "or missing reference rows"}
         served.append(d_served)
         plain.append(d_plain)
-        n_top1 += int(np.sum(np.asarray(arg[rows]) == np.asarray(out)))
+        n_top1 += int(np.sum(arg == np.asarray(out)))
     served, plain = np.concatenate(served), np.concatenate(plain)
     factor, slack = geo["reference_factor"], geo["deficit_slack"]
     got = {"n_tokens": int(served.size),
@@ -382,9 +400,9 @@ def check_streams(reference, ref_cfg, params, config: dict, records,
            "worst_deficit_plain_bf16": float(plain.max()),
            "top1_share": n_top1 / served.size,
            "top1_share_plain_bf16": float(np.mean(plain == 0.0))}
-    got["ok"] = bool(
-        got["mean_deficit"] <= factor * got["mean_deficit_plain_bf16"] + slack
-        and got["worst_deficit"] <= factor * got["worst_deficit_plain_bf16"] + slack)
+    got["limits"] = {arm: factor * got[arm + "_plain_bf16"] + slack
+                     for arm in ("mean_deficit", "worst_deficit")}
+    got["ok"] = all(got[arm] <= limit for arm, limit in got["limits"].items())
     log(f"reference check over {len(picks)} requests, {served.size} emitted "
         f"tokens, in float32 logits; served stream / plain bf16 forward: "
         f"mean deficit {got['mean_deficit']:.6f} / "
@@ -393,6 +411,11 @@ def check_streams(reference, ref_cfg, params, config: dict, records,
         f"{got['top1_share']:.4f} / {got['top1_share_plain_bf16']:.4f}; "
         f"allowed {factor} x plain + {slack}: "
         f"{'ok' if got['ok'] else 'FAILED'}")
+    asked, missed = compiles() - asked0, compiles.misses - missed0
+    got["programs_compiled"], got["programs_loaded"] = missed, asked - missed
+    log(f"reference programs: every stream padded to {geo['max_len']} "
+        f"tokens; {missed} compiled, {asked - missed} loaded from the "
+        f"compile cache; the check took {now() - t0:.1f}s")
     return got
 
 
@@ -401,7 +424,8 @@ def run(rc, degrade=None) -> dict:
     config, mix, log = rc.config, rc.traffic, rc.log
     geo = config["serve"]
     kind = mix["kind"]
-    cfg, params, eng = build_engine(rc.family, config, rc.seed, log, degrade)
+    cfg, params, eng = build_engine(rc.family, config, rc.seed, log, rc.mark,
+                                    degrade)
     if rc.platform == "tpu" and eng.attn_impl != "kernel":
         raise SystemExit(f"engine resolved attn_impl={eng.attn_impl!r} on a "
                          "TPU; the cell measures the kernel path")
@@ -413,9 +437,12 @@ def run(rc, degrade=None) -> dict:
     log(f"traffic {kind}: {made['stats']}")
     warm_up(eng, geo, made["p_lens"], made["o_lens"], cfg.vocab_size,
             rc.seed, log)
+    rc.mark("warm_up")
     win = run_window(eng, kind, mix, made, rc.seconds, clients or 0,
                      rc.tracer, rc.compiles, rc.memory_stats)
     rc.mark_setup_end(win["t_window"])
+    rc.mark("ramp", win["t_window"])
+    rc.mark("window", win["t_window"] + win["window_s"])
     eng.stop()
     rows = request_rows(win["records"], win["t_window"])
     refused = [r["error"] for r in win["records"] if r["error"]]
@@ -461,8 +488,10 @@ def run(rc, degrade=None) -> dict:
     emitting = [r for r in win["all_records"] if r["req"] is not None
                 and r["req"].first_token_at is not None
                 and (r["req"].finished_at or win["t_window"]) >= win["t_window"]]
+    rc.mark("drain")
     check = check_streams(rc.reference, rc.family.reference_config(config),
-                          params, config, emitting, rc.seed, log)
+                          params, config, emitting, rc.seed, log, rc.compiles)
+    rc.mark("check")
     failed = sum(1 for w in rows if not w["ok"])
     end_to_end = {"out_tokens_per_s": rate}
     if kind == "open_loop":
@@ -478,8 +507,17 @@ def run(rc, degrade=None) -> dict:
                           **rc.family.serve_consts(config))}
     ok = (check["ok"] and failed == 0
           and win["engine"]["compiles_in_window"] == 0)
+    # each number `correct` rests on, beside its limit (run.py prints them)
+    compared = {arm: {"value": check[arm], "limit": limit}
+                for arm, limit in check.get("limits", {}).items()}
+    compared["tokens_checked"] = {"value": check.get("n_tokens", 0),
+                                  "limit": 1, "at_least": True}
+    compared["requests_failed"] = {"value": failed, "limit": 0}
+    compared["compiles_in_window"] = {
+        "value": win["engine"]["compiles_in_window"], "limit": 0}
     return {"end_to_end": end_to_end, "attempted": len(rows),
             "failed": failed, "correct": bool(ok), "ctx": ctx,
+            "compared": compared,
             "memory": win["memory"],
             "compiles_in_window": win["engine"]["compiles_in_window"],
             "notes": {"check": check, "gen_stats": made["stats"],

@@ -59,6 +59,7 @@ def run(rc) -> dict:
         model=rc.family.model())
     jax.block_until_ready(params)
     log(f"training state built in {now() - t0:.1f}s")
+    rc.mark("state")
     batches = traffic.TrainBatches(mix, rc.seed, cfg.vocab_size)
     log(f"traffic train_job: batch {batches.batch} x seq {batches.seq}, "
         "fresh seeded tokens every step")
@@ -73,6 +74,7 @@ def run(rc) -> dict:
                          params, first, np.roll(first, -1, axis=1),
                          tr["ref_sequences_at_a_time"])
     log(f"reference loss on the first batch {ref:.5f} in {now() - t0:.1f}s")
+    rc.mark("reference")
     # Step 1 is the warm-up, and its loss (of the untouched parameters on
     # the first batch) is what the reference is compared with.
     t0 = now()
@@ -88,6 +90,7 @@ def run(rc) -> dict:
     c0 = rc.compiles()
     t_window = now()
     rc.mark_setup_end(t_window)
+    rc.mark("warm_up", t_window)
     t_end = t_window + rc.seconds
     losses, step_s, last = [], [], t_window
     while True:
@@ -101,6 +104,7 @@ def run(rc) -> dict:
         if t >= t_end:
             break
     window_s = last - t_window
+    rc.mark("window", last)
     rc.tracer.stop()
     compiles_in_window = rc.compiles() - c0
     tokens_per_step = batches.batch * batches.seq
@@ -117,8 +121,15 @@ def run(rc) -> dict:
            "consts": dict(configs.dims(config), chips=len(rc.devices),
                           window_s=window_s,
                           **rc.family.train_consts(config, batches.seq))}
+    compared = {"step1_loss_rel": {"value": rel, "limit": tr["loss_rtol"]},
+                "losses_not_finite": {
+                    "value": sum(not math.isfinite(x) for x in losses),
+                    "limit": 0},
+                "compiles_in_window": {"value": compiles_in_window,
+                                       "limit": 0}}
     return {"end_to_end": {"train_tokens_per_s": tokens_per_s},
             "attempted": len(step_s), "failed": 0 if finite else 1,
             "correct": bool(ok), "ctx": ctx, "memory": memory,
+            "compared": compared,
             "compiles_in_window": compiles_in_window,
             "notes": {"ref_loss": ref, "loss1": loss1, "rel": rel}}

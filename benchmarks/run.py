@@ -8,8 +8,12 @@ measures for `--seconds`, checks the outputs against the plain reference
 under benchmarks/harness/reference/, prints what it likes on earlier
 lines and, as its LAST line of standard output, one JSON object:
 `correct`, `attempted`, `failed`, `metrics`, `device` (and `breakdown`
-when traced). With `--trace 0` the metrics are the cell's end-to-end
-metrics, with `--trace 1` its per-layer metrics.
+when traced) and, last, `compared`: each number `correct` rests on beside
+its limit, which are also the last lines of standard error. With
+`--trace 0` the metrics are the cell's end-to-end metrics, with
+`--trace 1` its per-layer metrics. The line before it says where the
+run's wall seconds went, phase by phase (`RunContext.mark`): a cell's
+WHOLE run has to end well inside the driver's 360 s.
 
 It refuses to run (exit code other than 0, no result line) without a
 TPU, with fewer chips than the cell asks for, on a `device_kind` missing
@@ -140,6 +144,7 @@ class RunContext:
     tracer: Tracer
     compiles: CompileCounter
     setup_end: float | None = None
+    marks: list = dataclasses.field(default_factory=list)
 
     def log(self, msg: str) -> None:
         print(f"[bench {time.perf_counter() - T_PROCESS:7.1f}s] {msg}",
@@ -147,6 +152,20 @@ class RunContext:
 
     def mark_setup_end(self, t: float) -> None:
         self.setup_end = t
+
+    def mark(self, phase: str, t: float | None = None) -> None:
+        """`phase` ended at `t` (now): it began where the phase before it
+        ended, the first at the process's start, so the phases' seconds
+        add up to the run's."""
+        self.marks.append((phase, time.perf_counter() if t is None else t))
+
+    def phase_line(self) -> str:
+        last, parts = T_PROCESS, []
+        for phase, t in self.marks:
+            parts.append(f"{phase} {t - last:.1f}")
+            last = t
+        return (f"phases, wall seconds: {', '.join(parts)}; sum "
+                f"{last - T_PROCESS:.1f}")
 
     def memory_stats(self) -> dict:
         """`memory_stats()` of the fullest of the chips used, and under
@@ -214,6 +233,7 @@ def main(argv=None, *, root: str = ROOT, rehearsal: bool = False,
                     tracer=Tracer(bool(ns.trace), trace_dir,
                                   float(mix.get("trace_s", 4.0))),
                     compiles=CompileCounter())
+    rc.mark("jax_open")
     rc.log(f"cell {cell['name']} seed {ns.seed} seconds {ns.seconds} trace "
            f"{ns.trace}; {len(devices)} x {kind} ({platform}); compile cache "
            f"{cache_dir}")
@@ -245,8 +265,9 @@ def main(argv=None, *, root: str = ROOT, rehearsal: bool = False,
            "failed": result["failed"]}
     if not ns.trace:
         wanted = configs.metrics_for_cell(bench, "end_to_end", cell["name"])
-        out["metrics"] = {m["name"]: {"value": end_to_end[m["name"]],
-                                      "unit": m["unit"]} for m in wanted}
+        out["metrics"] = {
+            m["name"]: {"value": end_to_end[configs.quantity_of(m["name"])],
+                        "unit": m["unit"]} for m in wanted}
         missing = [n for n, v in out["metrics"].items() if v["value"] is None]
         if missing:
             raise SystemExit(f"no value for end-to-end metric(s) {missing}")
@@ -268,10 +289,21 @@ def main(argv=None, *, root: str = ROOT, rehearsal: bool = False,
                 "idle_gaps": [[n, t] for n, t in red["idle_gaps"][:5]]}
             rc.log(f"programs in the traced window: {red['programs']}")
     out["device"] = device
+    rc.mark("reduce")
+    rc.log(rc.phase_line())
     if rehearsal:
         out["rehearsal"] = True
         out["metrics"] = {"cpu_rehearsal." + n: v
                           for n, v in out["metrics"].items()}
+    # What `correct` rests on, each number beside its limit: the line's
+    # last key, and the last lines of standard error.
+    out["compared"] = result["compared"]
+    sys.stdout.flush()
+    for name, c in out["compared"].items():
+        print(f"compared {name}: {c['value']!r} "
+              f"{'>=' if c.get('at_least') else '<='} limit {c['limit']!r}",
+              file=sys.stderr)
+    print(f"correct: {out['correct']}", file=sys.stderr, flush=True)
     print(json.dumps(out), flush=True)
     return 0
 

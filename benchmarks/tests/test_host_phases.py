@@ -150,5 +150,5 @@ def test_extended_benchmark_json_holds_to_the_contract(capsys):
     dirs = configs.metrics_dirs(util.REPO, bench)
     for name in NEW_METRICS:
         assert entries[name]["workloads"] == ["opt-1.3b.batch"]
-        assert entries[name]["moves"] == "out_tokens_per_s"
+        assert entries[name]["moves"] == "out_tokens_per_s.batch"
         assert readers.load_reader(dirs, name) is not None

@@ -97,6 +97,9 @@ def main() -> int:
         chk(sum(c in cells_of(m) for m in b["end_to_end"]) >= 2
             and any(c in cells_of(m) for m in b["per_layer"]),
             f"cell {c} lacks metrics")
+    # the check's budget reckons a run at run_seconds + 60; the driver
+    # STOPS a whole run (set-up, window, reference check) at 360 s, which
+    # no file can show: tools/measure.py times it on the chip
     rs = b["run_seconds"]
     chk((2 + 14 * 24) * (rs + 60) + 24 * 180 + 1200 <= 43200, "time budget")
     for p in b["paths"]:

@@ -7,6 +7,14 @@ each run is a child that holds the chip alone.
         [--seconds N] [--trace 0|1] [--label set1]
 
 Lines go to chiprun_out/measure_<workload>_<label>.jsonl.
+
+A run is cut at RUN_LIMIT_S, the driver's own limit for a whole run (360 s;
+ledger, PR 46: `run_timed_out` on a cell the PR had not touched). Each
+run's whole wall time is printed beside its metrics with the harness's
+own account of its phases, and the summary flags every run over
+RUN_BUDGET_S (300 s): a cell is offered only when its COLD run ends under
+that (start with JAX_COMPILATION_CACHE_DIR at an empty directory to see a
+cold one).
 """
 
 from __future__ import annotations
@@ -25,6 +33,9 @@ sys.path.insert(0, os.path.dirname(HERE))
 
 from harness import stats  # noqa: E402  (no JAX in it)
 
+RUN_LIMIT_S = 360       # the driver stops a run there and refuses the PR
+RUN_BUDGET_S = 300      # what a cell's whole run, cold, has to end under
+
 
 def main() -> int:
     ap = argparse.ArgumentParser()
@@ -40,7 +51,7 @@ def main() -> int:
     out_dir = os.path.join(ROOT, "chiprun_out")
     os.makedirs(out_dir, exist_ok=True)
     path = os.path.join(out_dir, f"measure_{ns.workload}_{ns.label}.jsonl")
-    rows, rc_all = [], 0
+    rows, walls, rc_all = [], {}, 0
     for seed in ns.seeds.split(","):
         cmd = bench["command"] + ["--workload", ns.workload, "--seed", seed,
                                   "--seconds", str(seconds),
@@ -48,14 +59,16 @@ def main() -> int:
         t0 = time.time()
         try:
             p = subprocess.run(cmd, cwd=ROOT, capture_output=True, text=True,
-                               timeout=600)
+                               timeout=RUN_LIMIT_S)
         except subprocess.TimeoutExpired as e:
-            print(f"seed {seed}: TIMED OUT after 600s\n"
+            walls[seed] = float("inf")
+            print(f"seed {seed}: TIMED OUT after {RUN_LIMIT_S}s, the driver's "
+                  "limit for a whole run\n"
                   + (e.stdout or b"").decode(errors="replace")[-3000:],
                   flush=True)
             rc_all = 1
             continue
-        wall = time.time() - t0
+        wall = walls[seed] = time.time() - t0
         lines = [ln for ln in p.stdout.splitlines() if ln.strip()]
         with open(path.replace(".jsonl", f"_{seed}.log"), "w") as f:
             f.write(p.stdout + "\n--- stderr\n" + p.stderr[-6000:])
@@ -70,10 +83,13 @@ def main() -> int:
         rows.append(row)
         with open(path, "a") as f:
             f.write(json.dumps(row) + "\n")
-        print(f"seed {seed}: wall {wall:.0f}s correct {row['correct']} "
+        print(f"seed {seed}: whole run {wall:.1f}s correct {row['correct']} "
               f"attempted {row['attempted']} failed {row['failed']} "
               + " ".join(f"{k}={v['value']:.4f}"
                          for k, v in row["metrics"].items()), flush=True)
+        for ln in lines[:-1]:
+            if "] phases, wall seconds:" in ln or "] reference programs:" in ln:
+                print("    " + ln, flush=True)
     names = sorted({k for r in rows for k in r["metrics"]})
     for name in names:
         vals = [r["metrics"][name]["value"] for r in rows
@@ -84,6 +100,14 @@ def main() -> int:
             print(f"SPREAD {ns.workload} {ns.label} {name}: n={len(vals)} "
                   f"median {med:.4f} iqr/median {spread:.4%} "
                   f"min {min(vals):.4f} max {max(vals):.4f}", flush=True)
+    if walls:
+        over = {s: w for s, w in walls.items() if w > RUN_BUDGET_S}
+        print(f"WHOLE RUN {ns.workload} {ns.label}: n={len(walls)} shortest "
+              f"{min(walls.values()):.1f}s longest {max(walls.values()):.1f}s; "
+              + (f"OVER {RUN_BUDGET_S}s (the driver stops a run at "
+                 f"{RUN_LIMIT_S}s): " + ", ".join(
+                     f"seed {s} {w:.1f}s" for s, w in over.items())
+                 if over else f"none over {RUN_BUDGET_S}s"), flush=True)
     return rc_all
 
 
