@@ -229,17 +229,22 @@ def partition_rules() -> tuple:
     return ((r".*", PartitionSpec()),)
 
 
-def init_params(cfg: LagunaConfig, rng: jax.Array) -> dict[str, jax.Array]:
-    specs = param_specs(cfg)
+def init_from_specs(specs: dict, rng: jax.Array, dtype) -> dict:
+    """Seeded leaves from a `param_specs` table: normal at the spec's
+    scale, or ones; a key a leaf, in the names' order."""
     keys = jax.random.split(rng, len(specs))
     params = {}
     for key, (name, spec) in zip(keys, sorted(specs.items())):
         if spec["init"] == "normal":
-            params[name] = (jax.random.normal(key, spec["shape"],
-                                              cfg.param_dtype) * spec["scale"])
+            params[name] = (jax.random.normal(key, spec["shape"], dtype)
+                            * spec["scale"])
         else:
-            params[name] = jnp.ones(spec["shape"], cfg.param_dtype)
+            params[name] = jnp.ones(spec["shape"], dtype)
     return params
+
+
+def init_params(cfg: LagunaConfig, rng: jax.Array) -> dict[str, jax.Array]:
+    return init_from_specs(param_specs(cfg), rng, cfg.param_dtype)
 
 
 # ------------------------------------------------------------- the block
@@ -383,29 +388,41 @@ _COUNTERS = ("layer_steps", "experts_touched", "rows_max", "rows_routed",
              "rows_held")
 
 
+def ring_pool(cfg, n_pages: int, page_size: int, n_slots: int,
+              dispatch_tokens: int, lanes: dict, n_counters: int):
+    """The pool pytree of a family with full and window layers, donated
+    to its paged programs: the full layers' pages ``[n_full, P+1,
+    page_size, lanes]`` (row 0 the null page), the window layers' rings
+    ``[n_window, (n_slots+1)*R, page_size, lanes]`` with their row ids
+    `ring_rows` ``[n_slots+1, R]`` (the last ring the null slot's), and
+    the decode steps' `n_counters` running expert counters. `lanes`: the
+    minor width of each plane ("k", "v", "k_win", "v_win"): a kind's KV
+    heads x its K or V head size. `dispatch_tokens`: the most tokens of
+    one prompt a chunk dispatch carries (the engine's tallest program x
+    prefill_chunk)."""
+    R = ring_pages(cfg.window, page_size, dispatch_tokens)
+    rows = {"full": (cfg.count("full"), n_pages + 1),
+            "window": (cfg.count("window"), (n_slots + 1) * R)}
+    planes = {name: jnp.zeros(rows[kind] + (page_size, lanes[name]),
+                              cfg.dtype)
+              for kind, names in _PLANES.items() for name in names}
+    return {**planes,
+            "ring_rows": jnp.arange((n_slots + 1) * R, dtype=jnp.int32
+                                    ).reshape(n_slots + 1, R),
+            "moe_counters": jnp.zeros(n_counters, jnp.uint32)}
+
+
 def init_paged_kv(cfg: LagunaConfig, n_pages: int, page_size: int,
                   n_slots: int, kv_dtype: str | None = None, *,
                   dispatch_tokens: int):
-    """The pool pytree the paged programs carry, donated: the full
-    layers' pages ``[n_full, P+1, page_size, G*K]`` (row 0 the null
-    page), the window layers' rings ``[n_window, (n_slots+1)*R,
-    page_size, G*K]`` with their row ids `ring_rows` ``[n_slots+1, R]``
-    (the last ring the null slot's), and the decode steps' running
-    expert counters (`_COUNTERS`). `dispatch_tokens`: the most tokens of
-    one prompt a chunk dispatch carries (the engine's tallest program x
-    prefill_chunk)."""
+    """`ring_pool` at this family's widths: every plane G*K lanes, the
+    counters `_COUNTERS`."""
     if kv_dtype not in (None, "bf16"):
         raise ValueError(f"the laguna family's pool is bf16, got {kv_dtype!r}")
     GK = cfg.n_kv_heads * cfg.head_dim
-    R = ring_pages(cfg.window, page_size, dispatch_tokens)
-    full = (cfg.count("full"), n_pages + 1, page_size, GK)
-    ring = (cfg.count("window"), (n_slots + 1) * R, page_size, GK)
-    return {"k": jnp.zeros(full, cfg.dtype), "v": jnp.zeros(full, cfg.dtype),
-            "k_win": jnp.zeros(ring, cfg.dtype),
-            "v_win": jnp.zeros(ring, cfg.dtype),
-            "ring_rows": jnp.arange((n_slots + 1) * R, dtype=jnp.int32
-                                    ).reshape(n_slots + 1, R),
-            "moe_counters": jnp.zeros(len(_COUNTERS), jnp.uint32)}
+    return ring_pool(cfg, n_pages, page_size, n_slots, dispatch_tokens,
+                     dict.fromkeys(("k", "v", "k_win", "v_win"), GK),
+                     len(_COUNTERS))
 
 
 @jax.named_scope(scopes.ATTN_KERNEL)
@@ -465,12 +482,13 @@ def _paged_layers(cfg: LagunaConfig, params, x, pos, valid, pool, attend,
     return x, pool, counts
 
 
-def _chunk_forward(cfg: LagunaConfig, params, tokens, pool, tables, offsets,
-                   n_valid, slots, attn_impl: str):
+def _chunk_forward(cfg, params, tokens, pool, tables, offsets,
+                   n_valid, slots, attn_impl: str, layers=_paged_layers):
     """N chunk rows written into their slots' pages and rings, each at
     its own offset. Every row's K/V is written before any row attends
     (a row may continue the row above it), which is why a ring holds a
-    dispatch's pages beyond the window (`ring_pages`).
+    dispatch's pages beyond the window (`ring_pages`). `layers`: the
+    family's walk over its blocks (models/mimo_v2.py hands its own).
     → (hidden states [N, C, D], updated pool)."""
     N, C = tokens.shape
     ps, R = pool["k"].shape[2], pool["ring_rows"].shape[1]
@@ -496,7 +514,7 @@ def _chunk_forward(cfg: LagunaConfig, params, tokens, pool, tables, offsets,
         q, kp, vp, i, table, offsets, kv_lens, sm_scale=scale, **kw)
     with jax.named_scope(scopes.EMBED):
         x = params["wte"].astype(cfg.dtype)[tokens]
-    x, pool, _counts = _paged_layers(
+    x, pool, _counts = layers(
         cfg, params, x, pos, valid, pool, reader,
         (full_pages, tables, {}),
         (ring_targets, ring_table,
@@ -533,12 +551,14 @@ def _count(cfg: LagunaConfig, counts, n_live):
                       jnp.sum(counts).astype(jnp.uint32)])
 
 
-def _decode_once(cfg: LagunaConfig, params, tokens, pool, positions, tables,
-                 attn_impl: str):
+def _decode_once(cfg, params, tokens, pool, positions, tables,
+                 attn_impl: str, layers=_paged_layers, count=_count):
     """All B slots advance one token: row b IS slot b. A row whose table
     is all null (an idle slot, or one still mid-prefill) writes the null
     page and the null slot's ring, reaches no expert and counts nowhere,
     so a prompt's ring survives the decode windows between its chunks.
+    `layers`, `count`: the family's walk over its blocks and what it
+    adds to the running counters for one sparse layer's `counts`.
     → (logits [B, V] fp32, updated pool)."""
     B = tokens.shape[0]
     ps = pool["k"].shape[2]
@@ -558,14 +578,14 @@ def _decode_once(cfg: LagunaConfig, params, tokens, pool, positions, tables,
         **kw)[:, None]
     with jax.named_scope(scopes.EMBED):
         x = params["wte"].astype(cfg.dtype)[tokens[:, None]]
-    x, pool, counts = _paged_layers(
+    x, pool, counts = layers(
         cfg, params, x, pos, active[:, None], pool, reader,
         (full_pages, tables, {}),
         (ring_targets, ring_table,
          {"window": cfg.window, "col_page": col_page}))
     with jax.named_scope(scopes.COUNTERS):
         n_live = jnp.sum(active)
-        counters = pool["moe_counters"] + sum(_count(cfg, n, n_live)
+        counters = pool["moe_counters"] + sum(count(cfg, n, n_live)
                                               for n in counts)
     return _head(cfg, params, x[:, 0]), {**pool, "moe_counters": counters}
 
@@ -613,7 +633,7 @@ def decode_multi_paged(cfg: LagunaConfig, params, tokens, pool, positions,
 
 __all__ = [
     "LagunaConfig", "param_specs", "partition_rules", "init_params",
-    "forward", "init_paged_kv", "ring_pages",
+    "forward", "init_paged_kv", "ring_pages", "ring_pool",
     "yarn_inv_freq", "prefill_chunk_paged", "decode_step_paged",
     "decode_multi_paged",
 ]

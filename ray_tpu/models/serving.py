@@ -167,36 +167,38 @@ def _zaya() -> ServingFamily:
         ))
 
 
-def _laguna() -> ServingFamily:
+def _ring_family(name: str, model, dense_needs: str) -> ServingFamily:
+    """A family whose window layers keep a ring of pages a slot beside
+    the full layers' pages (models/laguna.py `ring_pool`) and whose
+    experts are one chip's share: `model` brings the programs, and
+    `dense_needs` says what a dense cache backend would have to carry."""
     from jax.sharding import PartitionSpec
-
-    from ray_tpu.models import laguna
 
     ring = ("the window layers' ring of pages a slot (models/laguna.py: "
             "indexed by slot, outside PagePool's page ids)")
     return ServingFamily(
-        name="laguna", model=laguna, init_pool=laguna.init_paged_kv,
+        name=name, model=model, init_pool=model.init_paged_kv,
         pool_partition_rules=((r".*", PartitionSpec()),),
         programs=lambda _tp, _mesh: {
-            name: getattr(laguna, name) for name in (
+            prog: getattr(model, prog) for prog in (
                 "prefill_chunk_paged", "decode_step_paged",
                 "decode_multi_paged")},
         slot_ring=True, expert_counters=True,
         unsupported=(
             Unsupported(
                 "kv_mode", lambda o: o.kv_mode == "paged", "paged",
-                "the laguna family serves from the paged pool only: "
+                f"the {name} family serves from the paged pool only: "
                 "kv_mode='dense' would need a [L, B, T] cache backend with "
-                "a window mask and per-layer-kind head counts"),
+                f"{dense_needs}"),
             Unsupported(
                 "prefill_chunk", lambda o: o.prefill_chunk > 0, 128,
-                "the laguna family has no one-shot prefill: "
+                f"the {name} family has no one-shot prefill: "
                 "prefill_chunk=0 would need a whole-prompt program that "
                 f"leaves the prompt's last window in {ring}"),
             Unsupported(
                 "prefill_width_bucketing",
                 lambda o: not o.prefill_width_bucketing, False,
-                "prefill_width_bucketing with the laguna family: a chunk "
+                f"prefill_width_bucketing with the {name} family: a chunk "
                 "program here costs a pass over every held expert's "
                 "weights at any table width, and one bucket a width "
                 "spreads a lone prompt's rows over more programs; a "
@@ -204,40 +206,56 @@ def _laguna() -> ServingFamily:
                 "program would have to be built"),
             Unsupported(
                 "prefix_cache", lambda o: not o.prefix_cache, False,
-                "prefix_cache with the laguna family: a cached prefix "
+                f"prefix_cache with the {name} family: a cached prefix "
                 f"would need {ring} at the prefix's boundary stored with "
                 "its pages: a window kind whose pages can be shared "
                 "(serve/prefix_cache.py keeps PagePool pages only)"),
             Unsupported(
                 "spec_draft", lambda o: not o.spec_draft, "",
-                "speculative decoding with the laguna family: a rejected "
+                f"speculative decoding with the {name} family: a rejected "
                 "proposal rewinds the cursor, and a ring whose newest "
                 "pages overwrote the oldest cannot be rewound past them; "
                 "a verify program over a ring with room for the "
                 "proposals would have to be built"),
             Unsupported(
                 "kv_transfer", lambda o: not o.kv_transfer, False,
-                "KV page-set transfer with the laguna family: a page set "
+                f"KV page-set transfer with the {name} family: a page set "
                 f"would have to carry {ring} (serve/kv_objects.py moves "
                 "PagePool pages only)"),
             Unsupported(
                 "tp", lambda o: int(o.tp) == 1, 1,
-                "tp > 1 with the laguna family: the experts need an "
+                f"tp > 1 with the {name} family: the experts need an "
                 "expert-parallel exchange of rows between chips "
                 "(ops/moe.py returns the held experts' part only), not a "
                 "head split, and no partition rule shards a ring"),
             Unsupported(
                 "weight_dtype", lambda o: o.weight_dtype != "int8", "bf16",
-                "weight_dtype='int8' with the laguna family: "
+                f"weight_dtype='int8' with the {name} family: "
                 "quantize_params knows the gpt tree's planes, and the "
                 "experts' grouped matmul (ops/moe.py) has no int8 form"),
             Unsupported(
                 "kv_dtype", lambda o: o.kv_dtype != "int8", "bf16",
-                "kv_dtype='int8' with the laguna family: the per-page "
+                f"kv_dtype='int8' with the {name} family: the per-page "
                 "scale planes are kept by models/paged_kv._quant_write, "
                 "and the kernels' window form takes a bf16 pool "
                 "(ops/paged_attention.py)"),
         ))
+
+
+def _laguna() -> ServingFamily:
+    from ray_tpu.models import laguna
+
+    return _ring_family("laguna", laguna,
+                        "a window mask and per-layer-kind head counts")
+
+
+def _mimo_v2() -> ServingFamily:
+    from ray_tpu.models import mimo_v2
+
+    return _ring_family(
+        "mimo_v2", mimo_v2,
+        "a window mask, per-layer-kind KV head counts, V heads narrower "
+        "than K heads and a sink in the window layers' softmax")
 
 
 def _qwen3_next() -> ServingFamily:
@@ -318,7 +336,7 @@ def _qwen3_next() -> ServingFamily:
 
 
 _FAMILIES = {"gpt": _gpt, "zaya": _zaya, "laguna": _laguna,
-             "qwen3_next": _qwen3_next}
+             "qwen3_next": _qwen3_next, "mimo_v2": _mimo_v2}
 
 
 @functools.cache

@@ -131,19 +131,21 @@ def _interpret_default() -> bool:
 
 
 def _check_pool(q_heads, q_head_dim, k_pool, v_pool):
-    """-> (page_size, G) of a whole-pool plane ``[L, P, ps, G*K]``: G KV
-    heads of the query's head size, each serving H/G query heads (G = H
-    is multi-head attention)."""
+    """-> (page_size, G, Kv) of whole-pool planes ``[L, P, ps, G*K]`` and
+    ``[L, P, ps, G*Kv]``: G KV heads whose keys have the query's head
+    size, each serving H/G query heads (G = H is multi-head attention),
+    and whose values are Kv wide (= K for every family but one)."""
     GK = k_pool.shape[3] if k_pool.ndim == 4 else 0
     G = GK // q_head_dim
     if (G < 1 or G * q_head_dim != GK or q_heads % G
-            or v_pool.shape != k_pool.shape):
+            or v_pool.shape[:3] != k_pool.shape[:3]
+            or v_pool.shape[3] % G):
         raise ValueError(
             f"pool/query shape mismatch: q heads x head_dim "
             f"{q_heads}x{q_head_dim}, k_pool {k_pool.shape}, "
-            f"v_pool {v_pool.shape} (want [L, P, page_size, G*K] with "
-            f"G dividing the query heads)")
-    return k_pool.shape[2], G
+            f"v_pool {v_pool.shape} (want [L, P, page_size, G*K] and "
+            f"[L, P, page_size, G*Kv] with G dividing the query heads)")
+    return k_pool.shape[2], G, v_pool.shape[3] // G
 
 
 def _prefetch(layer, scalars, k_scale, v_scale):
@@ -196,50 +198,93 @@ def _head_mask(n_heads, head_dim, n_kv_heads=None):
 
 
 def _pool_call(kernel, name, q, k_pool, v_pool, prefetch, n_pg, scratch,
-               interpret, block_pages=1, kv_split=1):
+               interpret, block_pages=1, kv_split=1, sink=None):
     """The one pallas_call shape both kernels share: grid (slot, kv block),
-    the slot's query rows ``q[b]`` ([rows, H*K]) and its output as one
-    block per slot, K and V a page at ``(layer, tables[b, ·])`` of the
-    whole pool (whose minor axis is the KV heads', narrower than the
-    query's under grouped-query attention). A kv block is `block_pages`
+    the slot's query rows ``q[b]`` ([rows, H*K]) and its output
+    ([rows, H*Kv]) as one block per slot, K and V a page at
+    ``(layer, tables[b, ·])`` of the whole pool (whose minor axis is the
+    KV heads', narrower than the query's under grouped-query attention;
+    V's may differ from K's). A kv block is `block_pages`
     consecutive table columns: the pool is handed over once a column of
     the block (K's pages, then V's), so each page is still its own DMA
     and the kernel sees `block_pages` page refs a pool; `n_pg` must be a
     multiple of it. `prefetch` is `_prefetch`'s tuple (layer first, the
-    page table second). With `kv_split` = G > 1 the grid is (slot, KV
-    head, kv block): a step sees its KV head's K lanes of each page and
-    that head's H/G query heads' lanes of q and of the output."""
+    page table second). With `kv_split` = S > 1 the grid is (slot, KV
+    head group, kv block): a step sees its G/S KV heads' lanes of each
+    page and those heads' H/S query heads' lanes of q and of the output.
+    `sink` [H, LANES] float32 (optional) follows q: a step sees its own
+    heads' rows."""
     B, rows, HK = q.shape
-    ps, GK = k_pool.shape[2], k_pool.shape[3]
+    ps, GK, GV = k_pool.shape[2], k_pool.shape[3], v_pool.shape[3]
+    HV = HK // GK * GV
     n = block_pages
     if kv_split > 1:
-        G = kv_split
-        grid, HK, GK = (B, G, n_pg // n), HK // G, GK // G
+        S = kv_split
+        grid, HK, HV, GK, GV = (B, S, n_pg // n), HK // S, HV // S, \
+            GK // S, GV // S
         im_q = lambda b, g, j, *_: (b, 0, g)
+        im_sink = lambda b, g, j, *_: (g, 0)
         im_kv = lambda i: (lambda b, g, j, layer, tbl, *_: (
             layer[0], tbl[b, j * n + i], 0, g))
     else:
         grid = (B, n_pg // n)
         im_q = lambda b, j, *_: (b, 0, 0)
+        im_sink = lambda b, j, *_: (0, 0)
 
         im_kv = lambda i: (lambda b, j, layer, tbl, *_: (
             layer[0], tbl[b, j * n + i], 0, 0))
 
-    pages = [pl.BlockSpec((None, None, ps, GK), im_kv(i)) for i in range(n)]
+    pages = lambda lanes: [pl.BlockSpec((None, None, ps, lanes), im_kv(i))
+                           for i in range(n)]
+    sinks = ([] if sink is None else
+             [pl.BlockSpec((sink.shape[0] // kv_split, _LANES), im_sink)])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=grid,
-        in_specs=[pl.BlockSpec((None, rows, HK), im_q)] + pages + pages,
-        out_specs=pl.BlockSpec((None, rows, HK), im_q),
+        in_specs=([pl.BlockSpec((None, rows, HK), im_q)] + sinks
+                  + pages(GK) + pages(GV)),
+        out_specs=pl.BlockSpec((None, rows, HV), im_q),
         scratch_shapes=scratch,
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, rows, HV * kv_split), q.dtype),
         interpret=interpret,
         name=name,
-    )(*prefetch, q, *([k_pool] * n), *([v_pool] * n))
+    )(*prefetch, q, *(() if sink is None else (sink,)),
+      *([k_pool] * n), *([v_pool] * n))
+
+
+def _sink_form(extra, sink, n_heads, head_dim, v_head_dim):
+    """What a V head size of its own and a learned softmax sink add to a
+    call: the kernel's keywords (into `extra`; none where V is as wide
+    as K and there is no sink) and, → the sink [H] as the [H, LANES]
+    float32 operand the kernels seed their (m, l) state from, or None."""
+    if v_head_dim != head_dim:
+        extra["v_head_dim"] = v_head_dim
+    if sink is None:
+        return None
+    if sink.shape != (n_heads,):
+        raise ValueError(f"`sink` is one logit a query head: want "
+                         f"({n_heads},), got {sink.shape}")
+    extra["sink"] = True
+    return jnp.broadcast_to(sink.astype(jnp.float32)[:, None],
+                            (n_heads, _LANES))
+
+
+def _seed_state(m_ref, l_ref, sink):
+    """(m, l) before the first kv block: (-inf, 0), or with a sink
+    ([rows, LANES], lane-uniform) m = the sink's logit and l = 1: the
+    sink is in the denominator and has no value row. l is a partial sum
+    a lane, so the 1 sits in lane 0."""
+    if sink is None:
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        return
+    m_ref[...] = jnp.broadcast_to(sink, m_ref.shape)
+    lane = jax.lax.broadcasted_iota(jnp.int32, l_ref.shape, l_ref.ndim - 1)
+    l_ref[...] = jnp.where(lane == 0, 1.0, 0.0)
 
 
 # A ring column that holds no page yet (`col_page` -1) starts here: past
@@ -265,7 +310,7 @@ def _column_live(first, kv_len, page_size, window):
 def _decode_kernel(
     *refs,
     sm_scale, page_size, block_pages, n_heads, n_kv_heads, quantized=False,
-    window=None,
+    window=None, v_head_dim=None, sink=False,
 ):
     """Every slot of a group against its LIVE kv blocks, one grid step a
     group (the whole batch at every served shape), the pages fetched by
@@ -291,24 +336,33 @@ def _decode_kernel(
     sum and one accumulator update whatever it holds (PERF.md, PR 37).
     `quantized` is a Python-level trace switch: the int8 program dequants
     each page of the block by its own scale after the wait, inside the
-    kernel, and the fp32 plane never exists in HBM."""
+    kernel, and the fp32 plane never exists in HBM. `v_head_dim`: the
+    V plane's head size where it is not K's (the accumulator and the
+    output are that wide); `sink`: a [H, LANES] operand follows the
+    queries, the logit each head's softmax starts from."""
     n, ps = block_pages, page_size
     refs = iter(refs)
     take = lambda count: [next(refs) for _ in range(count)]
     layer_ref, tables_ref, lengths_ref = take(3)
     (col_ref,) = take(1) if window is not None else (None,)
     ks_ref, vs_ref = take(2) if quantized else (None, None)
-    q_ref, k_hbm, v_hbm, o_ref = take(4)
+    (q_ref,) = take(1)
+    (sink_ref,) = take(1) if sink else (None,)
+    k_hbm, v_hbm, o_ref = take(3)
     k_buf, v_buf, sem, qbd_ref, m_ref, l_ref, acc_ref = refs
     # Multi-head (G = H): a slot's query is one dense row [1, H*K].
     # Grouped (G < H): it is [H, K], a head a row, and the pool's minor
     # axis holds G heads; row h of the block-diagonal query then sits in
     # the lanes of KV head h // (H/G), so the two matmuls are the same.
-    grouped = n_kv_heads != n_heads
+    # K and V of unequal head size take the grouped form whatever G.
+    grouped = n_kv_heads != n_heads or v_head_dim is not None
     head_dim = q_ref.shape[-1] if grouped else q_ref.shape[-1] // n_heads
+    v_dim = v_head_dim or head_dim
     mask = lambda: _head_mask(n_heads, head_dim, n_kv_heads)
+    v_mask = mask if v_dim == head_dim else (
+        lambda: _head_mask(n_heads, v_dim, n_kv_heads))
     block = n * ps
-    GK = qbd_ref.shape[1]
+    GK = acc_ref.shape[1]
     group = q_ref.shape[0]
     n_pg = tables_ref.shape[1]
     n_blk = -(-n_pg // n)
@@ -387,8 +441,7 @@ def _decode_kernel(
         copies("start", b, columns(b, item[1]), buf, item[0] < end)
 
     def init(b):
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        _seed_state(m_ref, l_ref, None if sink_ref is None else sink_ref[...])
         acc_ref[...] = jnp.zeros_like(acc_ref)
         # Row h = the query with every lane outside head h's KV head
         # zeroed (the select runs in fp32: the mask is built from 32-bit
@@ -451,10 +504,10 @@ def _decode_kernel(
 
     def finish(b):
         l = jnp.sum(l_ref[...], axis=1, keepdims=True)
-        own = jnp.where(mask(), acc_ref[...] / l, 0.0)
+        own = jnp.where(v_mask(), acc_ref[...] / l, 0.0)
         if grouped:
             # One non-zero K-lane block per row: their sum is [H, K].
-            out = sum(own[:, g * head_dim:(g + 1) * head_dim]
+            out = sum(own[:, g * v_dim:(g + 1) * v_dim]
                       for g in range(n_kv_heads))
         else:
             # One non-zero row per lane: the sum over rows is the gather
@@ -509,34 +562,39 @@ _DECODE_VMEM_BUDGET = 12 * 2**20
 _DECODE_GROUP_BUDGET = 14 * 2**20
 
 
-def _decode_vmem_bytes(n, page_size, kv_lanes, kv_itemsize, n_heads) -> int:
+def _decode_vmem_bytes(n, page_size, kv_lanes, kv_itemsize, n_heads,
+                       v_lanes=None) -> int:
     """VMEM the decode kernel takes at `n` pages a block, beside its
     queries and outputs: `_DECODE_BUFFERS` K and as many V buffers of a
-    block, an int8 block's f32 copies, the score and probability tiles, and whatever
-    the block: the block-diagonal query with the f32 accumulator and the
-    (m, l) state."""
-    page = page_size * kv_lanes * kv_itemsize
-    dequant = 2 * n * page_size * kv_lanes * 4 if kv_itemsize == 1 else 0
+    block (`kv_lanes` = G*K and `v_lanes` = G*Kv wide; None: as K), an
+    int8 block's f32 copies, the score and probability tiles, and
+    whatever the block: the block-diagonal query (K's width) with the
+    f32 accumulator and its update (V's) and the (m, l) state."""
+    v_lanes = kv_lanes if v_lanes is None else v_lanes
+    both = kv_lanes + v_lanes
+    page = page_size * both * kv_itemsize
+    dequant = n * page_size * both * 4 if kv_itemsize == 1 else 0
     tiles = 2 * n_heads * n * page_size * 4
-    fixed = n_heads * kv_lanes * (4 + 4 + 4) + 2 * n_heads * _LANES * 4
-    return _DECODE_BUFFERS * 2 * n * page + dequant + tiles + fixed
+    fixed = (n_heads * (kv_lanes * 4 + v_lanes * (4 + 4))
+             + 2 * n_heads * _LANES * 4)
+    return _DECODE_BUFFERS * n * page + dequant + tiles + fixed
 
 
 def decode_block_pages(n_pg, page_size, kv_lanes, kv_itemsize,
-                       n_heads) -> int:
+                       n_heads, v_lanes=None) -> int:
     """Table columns one block of the decode kernel holds: the largest
     power of two that is at most `n_pg`, keeps the block's K pages
     (`kv_lanes` = G*K wide) at or under `_DECODE_BLOCK_BYTES` and
     `_DECODE_BLOCK_KEYS` keys, and fits `_DECODE_VMEM_BUDGET`
-    (`_decode_vmem_bytes`). Pure in the shapes: the engine's
-    `decode_block_fill` counter and the kernel ask it the same
-    question."""
+    (`_decode_vmem_bytes`, which counts V's pages at `v_lanes`). Pure in
+    the shapes: the engine's `decode_block_fill` counter and the kernel
+    ask it the same question."""
     page = page_size * kv_lanes * kv_itemsize
     n = 1
     while (2 * n <= n_pg and 2 * n * page <= _DECODE_BLOCK_BYTES
            and 2 * n * page_size <= _DECODE_BLOCK_KEYS
            and _decode_vmem_bytes(2 * n, page_size, kv_lanes, kv_itemsize,
-                                  n_heads) <= _DECODE_VMEM_BUDGET):
+                                  n_heads, v_lanes) <= _DECODE_VMEM_BUDGET):
         n *= 2
     return n
 
@@ -580,6 +638,7 @@ def paged_attention(
     v_scale: jax.Array | None = None,
     window: int | None = None,
     col_page: jax.Array | None = None,
+    sink: jax.Array | None = None,
 ) -> jax.Array:
     """Single-token decode attention straight against the KV page pool.
 
@@ -606,12 +665,16 @@ def paged_attention(
         c holds logical page ``col_page[b, c]`` ([B, n_pg] int32, -1:
         none). None: column c is page c and every key under the length
         is attended.
-    Returns [B, H, K] in q.dtype. Numerics match the gather reference
-    within blockwise-fp32-softmax reassociation (see
+      sink: [H] float32, a learned logit a query head that joins the
+        softmax's denominator and has no value row (None: no sink).
+    V's heads may be narrower or wider than K's (``v_pool``
+    [L, P, page_size, G*Kv]): the scores contract K, the output is Kv
+    wide. Returns [B, H, Kv] in q.dtype. Numerics match the gather
+    reference within blockwise-fp32-softmax reassociation (see
     ``reference_paged_attention``).
     """
     B, H, K = q.shape
-    ps, G = _check_pool(H, K, k_pool, v_pool)
+    ps, G, Kv = _check_pool(H, K, k_pool, v_pool)
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(K)
     if interpret is None:
@@ -619,43 +682,51 @@ def paged_attention(
     quantized = k_scale is not None
     _check_window(window, col_page, tables, quantized)
     kv_item = k_pool.dtype.itemsize
-    n = decode_block_pages(tables.shape[1], ps, G * K, kv_item, H)
+    n = decode_block_pages(tables.shape[1], ps, G * K, kv_item, H, G * Kv)
     name, prefetch, extra = _call_form(
         "paged_decode_attn", layer, (tables, lengths), k_scale, v_scale,
         window, col_page)
+    sink = _sink_form(extra, sink, H, K, Kv)
+    dense = G == H and Kv == K      # a slot's query is one row [1, H*K]
+    # A grouped slot's [H, K] and [H, Kv] blocks, as VMEM pads them.
+    tiled = lambda lanes: lanes if dense else -(-lanes // _LANES) * _LANES
     group = _decode_slot_group(
-        B, H * K * q.dtype.itemsize,
-        _decode_vmem_bytes(n, ps, G * K, kv_item, H))
+        B, H * (tiled(K) + tiled(Kv)) // 2 * q.dtype.itemsize,
+        _decode_vmem_bytes(n, ps, G * K, kv_item, H, G * Kv))
 
     kernel = functools.partial(
         _decode_kernel, sm_scale=sm_scale, page_size=ps, block_pages=n,
         n_heads=H, n_kv_heads=G, quantized=quantized, **extra)
-    q = q.reshape(B, 1, H * K) if G == H else q
-    slots = pl.BlockSpec((group,) + q.shape[1:], lambda g, *_: (g, 0, 0))
+    q = q.reshape(B, 1, H * K) if dense else q
+    out_shape = q.shape[:2] + (q.shape[2] // K * Kv,)
+    slots = lambda shape: pl.BlockSpec((group,) + shape[1:],
+                                       lambda g, *_: (g, 0, 0))
     pool = pl.BlockSpec(memory_space=pl.ANY)
+    sinks = ([] if sink is None else
+             [pl.BlockSpec((H, _LANES), lambda g, *_: (0, 0))])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(B // group,),
-        in_specs=[slots, pool, pool],
-        out_specs=slots,
+        in_specs=[slots(q.shape)] + sinks + [pool, pool],
+        out_specs=slots(out_shape),
         scratch_shapes=[
             pltpu.VMEM((_DECODE_BUFFERS, n * ps, G * K), k_pool.dtype),
-            pltpu.VMEM((_DECODE_BUFFERS, n * ps, G * K), v_pool.dtype),
+            pltpu.VMEM((_DECODE_BUFFERS, n * ps, G * Kv), v_pool.dtype),
             pltpu.SemaphoreType.DMA((_DECODE_BUFFERS,)),   # one a K, V pair
             pltpu.VMEM((H, G * K), q.dtype),         # block-diagonal query
             pltpu.VMEM((H, _LANES), jnp.float32),    # m
             pltpu.VMEM((H, _LANES), jnp.float32),    # l
-            pltpu.VMEM((H, G * K), jnp.float32),     # acc
+            pltpu.VMEM((H, G * Kv), jnp.float32),    # acc
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(out_shape, q.dtype),
         interpret=interpret,
         name=name,
-    )(*prefetch, q, k_pool, v_pool)
-    return out.reshape(B, H, K)
+    )(*prefetch, q, *(() if sink is None else (sink,)), k_pool, v_pool)
+    return out.reshape(B, H, Kv)
 
 
 # The prefill kernel's kv block. A block under ~256 keys leaves the two
@@ -668,45 +739,65 @@ _PREFILL_BLOCK_KEYS = 256
 _PREFILL_VMEM_BUDGET = 12 * 2**20
 
 
-def _prefill_fixed_bytes(chunk, q_lanes, q_itemsize, n_heads) -> int:
-    """VMEM the prefill kernel takes whatever its kv block: the query and
-    output blocks ([chunk, q_lanes], double-buffered), the f32
-    accumulator and the (m, l) state of `n_heads` heads."""
-    return (4 * chunk * q_lanes * q_itemsize + 4 * chunk * q_lanes
-            + 2 * n_heads * chunk * _LANES * 4)
+def _prefill_fixed_bytes(chunk, q_lanes, q_itemsize, n_heads,
+                         o_lanes=None) -> int:
+    """VMEM the prefill kernel takes whatever its kv block: the query
+    and output blocks ([chunk, q_lanes] and [chunk, o_lanes]; None: as
+    the query's; double-buffered), the f32 accumulator and the (m, l)
+    state of `n_heads` heads."""
+    o_lanes = q_lanes if o_lanes is None else o_lanes
+    return (2 * chunk * (q_lanes + o_lanes) * q_itemsize
+            + 4 * chunk * o_lanes + 2 * n_heads * chunk * _LANES * 4)
 
 
-def prefill_kv_split(kv_lanes, chunk, q_lanes, q_itemsize, n_heads) -> int:
-    """1, or G where the prefill kernel's grid gets a KV-head axis: when
-    the query block, accumulator and state of all `n_heads` heads pass
-    `_PREFILL_VMEM_BUDGET` by themselves (48 or 72 heads of 128 over a
-    128-token chunk: 16-24 MB) and a KV head's lanes are whole lane
-    tiles, so that a grid step can take one KV head's slice of a page."""
+def prefill_kv_split(kv_lanes, chunk, q_lanes, q_itemsize, n_heads,
+                     v_lanes=None) -> int:
+    """1, or the S where the prefill kernel's grid gets a KV-head axis
+    of S steps: when the query block, accumulator and state of all
+    `n_heads` heads pass `_PREFILL_VMEM_BUDGET` by themselves (48 or 72
+    heads of 128 over a 128-token chunk: 16-24 MB). A grid step then
+    takes G/S KV heads' slice of a page, the fewest whose K lanes and V
+    lanes (`v_lanes` = G*Kv; None: as K) are both whole lane tiles: one
+    KV head (S = G) at a head size of 128 or 256, two (S = G/2) where K
+    heads are 192 wide; 1 where no group but the whole is, and for heads
+    narrower than a lane tile."""
     head_dim = q_lanes // n_heads
     G = kv_lanes // head_dim
-    if (G > 1 and head_dim % _LANES == 0
-            and _prefill_fixed_bytes(chunk, q_lanes, q_itemsize, n_heads)
-            > _PREFILL_VMEM_BUDGET):
-        return G
+    v_lanes = kv_lanes if v_lanes is None else v_lanes
+    o_lanes = q_lanes // kv_lanes * v_lanes         # H/G x G*Kv
+    if (G > 1 and head_dim >= _LANES
+            and _prefill_fixed_bytes(chunk, q_lanes, q_itemsize, n_heads,
+                                     o_lanes) > _PREFILL_VMEM_BUDGET):
+        for S in range(G, 1, -1):
+            if (G % S == 0 and kv_lanes // S % _LANES == 0
+                    and v_lanes // S % _LANES == 0):
+                return S
     return 1
 
 
 def prefill_block_pages(n_pg, page_size, kv_lanes, kv_itemsize, chunk,
-                        q_lanes, q_itemsize, n_heads) -> int:
+                        q_lanes, q_itemsize, n_heads, v_lanes=None) -> int:
     """Table columns one grid step of the prefill kernel attends: the
     largest power of two that is at most `n_pg`, keeps the block at or
     under `_PREFILL_BLOCK_KEYS` keys, and whose K and V blocks
-    (`kv_lanes` = G*K wide, double-buffered by the pipeline) fit
+    (`kv_lanes` = G*K and `v_lanes` = G*Kv wide; None: as K;
+    double-buffered by the pipeline) fit
     `_PREFILL_VMEM_BUDGET` beside the query and output blocks ([chunk,
     `q_lanes`], double-buffered), the f32 accumulator and the (m, l)
     state of `n_heads` heads. Pure in the shapes: the engine's
     `prefill_block_fill` counter and the kernel ask it the same
     question. Where the grid splits by KV head (`prefill_kv_split`) the
-    shapes are one KV head's."""
-    G = prefill_kv_split(kv_lanes, chunk, q_lanes, q_itemsize, n_heads)
-    kv_lanes, q_lanes, n_heads = kv_lanes // G, q_lanes // G, n_heads // G
-    fixed = _prefill_fixed_bytes(chunk, q_lanes, q_itemsize, n_heads)
-    page = 2 * 2 * page_size * kv_lanes * kv_itemsize   # K, V; two buffers
+    shapes are one step's."""
+    v_lanes = kv_lanes if v_lanes is None else v_lanes
+    o_lanes = q_lanes // kv_lanes * v_lanes         # H/G x G*Kv
+    S = prefill_kv_split(kv_lanes, chunk, q_lanes, q_itemsize, n_heads,
+                         v_lanes)
+    kv_lanes, v_lanes, q_lanes, o_lanes, n_heads = (
+        kv_lanes // S, v_lanes // S, q_lanes // S, o_lanes // S,
+        n_heads // S)
+    fixed = _prefill_fixed_bytes(chunk, q_lanes, q_itemsize, n_heads,
+                                 o_lanes)
+    page = 2 * page_size * (kv_lanes + v_lanes) * kv_itemsize  # two buffers
     n = 1
     while (2 * n <= n_pg and 2 * n * page_size <= _PREFILL_BLOCK_KEYS
            and fixed + 2 * n * page <= _PREFILL_VMEM_BUDGET):
@@ -739,7 +830,7 @@ def _fold(p):
 def _prefill_kernel(
     *refs,
     sm_scale, page_size, block_pages, n_heads, n_kv_heads, quantized=False,
-    window=None, kv_axis=1,
+    window=None, kv_axis=1, v_head_dim=None, sink=False,
 ):
     """Ragged chunked-prefill attention: one query BLOCK (a prompt chunk at
     an arbitrary token offset) against the slot's page pool. The decode
@@ -758,26 +849,35 @@ def _prefill_kernel(
     head h // (H/G)'s stack). Ref order mirrors `_decode_kernel`:
     scalar-prefetch (layer, tables, offsets, lengths, and for int8 pools
     the per-page K/V scale vectors) first, then VMEM blocks; `quantized`
-    dequants each page of the block by its own scale as it is stacked."""
+    dequants each page of the block by its own scale as it is stacked.
+    `v_head_dim` and `sink` as in `_decode_kernel`: V's lane slices, the
+    accumulator and the output go by V's head size, and a head's (m, l)
+    start from its sink's row."""
     n = block_pages
     refs = iter(refs)
     take = lambda count: [next(refs) for _ in range(count)]
     _layer_ref, tables_ref, offsets_ref, lengths_ref = take(4)
     (col_ref,) = take(1) if window is not None else (None,)
     ks_ref, vs_ref = take(2) if quantized else (None, None)
-    (q_ref,), k_refs, v_refs = take(1), take(n), take(n)
+    (q_ref,) = take(1)
+    (sink_ref,) = take(1) if sink else (None,)
+    k_refs, v_refs = take(n), take(n)
     o_ref, m_ref, l_ref, acc_ref = refs
     b = pl.program_id(0)
     j = pl.program_id(kv_axis)
     C, HK = q_ref.shape
     head_dim = HK // n_heads
+    v_dim = v_head_dim or head_dim
     group = n_heads // n_kv_heads
     block = n * page_size
 
     @pl.when(j == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        if sink_ref is None:
+            _seed_state(m_ref, l_ref, None)
+        else:
+            for h in range(n_heads):
+                _seed_state(m_ref.at[h], l_ref.at[h], sink_ref[h:h + 1, :])
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     kv_len = lengths_ref[b]
@@ -824,12 +924,11 @@ def _prefill_kernel(
             return parts[0] if n == 1 else jnp.concatenate(parts, axis=0)
 
         for g in range(n_kv_heads):
-            kv_lanes = slice(g * head_dim, (g + 1) * head_dim)
-            k = stack(k_refs, kv_lanes, k_sc)
-            v = stack(v_refs, kv_lanes, v_sc)
+            k = stack(k_refs, slice(g * head_dim, (g + 1) * head_dim), k_sc)
+            v = stack(v_refs, slice(g * v_dim, (g + 1) * v_dim), v_sc)
             for h in range(g * group, (g + 1) * group):
-                lanes = slice(h * head_dim, (h + 1) * head_dim)
-                q = q_ref[:, lanes]              # [C, K]
+                q = q_ref[:, h * head_dim:(h + 1) * head_dim]    # [C, K]
+                lanes = slice(h * v_dim, (h + 1) * v_dim)
                 if quantized:
                     q = q.astype(jnp.float32)
                 s = jax.lax.dot_general(
@@ -846,10 +945,10 @@ def _prefill_kernel(
                 corr = jnp.exp(m_prev - m_new)           # [C, LANES]
                 l_ref[h] = l_ref[h] * corr + _fold(p)
                 pv = jnp.dot(p.astype(v.dtype), v,
-                             preferred_element_type=jnp.float32)  # [C, K]
+                             preferred_element_type=jnp.float32)  # [C, Kv]
                 acc_ref[:, lanes] = (
                     acc_ref[:, lanes]
-                    * _spread(corr, head_dim, lanes.start % _LANES) + pv)
+                    * _spread(corr, v_dim, lanes.start % _LANES) + pv)
                 m_ref[h] = m_new
 
     # Blocks entirely past the chunk's last valid position do no compute
@@ -866,7 +965,7 @@ def _prefill_kernel(
     @pl.when(j == pl.num_programs(kv_axis) - 1)
     def _finish():
         for h in range(n_heads):
-            lanes = slice(h * head_dim, (h + 1) * head_dim)
+            lanes = slice(h * v_dim, (h + 1) * v_dim)
             l = jnp.sum(l_ref[h], axis=1, keepdims=True)
             l_safe = jnp.where(l == 0.0, 1.0, l)
             o_ref[:, lanes] = (acc_ref[:, lanes] / l_safe).astype(
@@ -888,6 +987,7 @@ def paged_prefill_attention(
     v_scale: jax.Array | None = None,
     window: int | None = None,
     col_page: jax.Array | None = None,
+    sink: jax.Array | None = None,
 ) -> jax.Array:
     """Chunked-prefill attention straight against the KV page pool.
 
@@ -915,10 +1015,12 @@ def paged_prefill_attention(
         a ring that every page a query can see is in some column).
       window, col_page: as in `paged_attention`: query i attends keys j
         with ``i - window < j <= i``.
-    Returns [B, C, H, K] in q.dtype; rows past a slot's valid chunk tokens
+      sink: [H] float32, as in `paged_attention`.
+    Returns [B, C, H, Kv] in q.dtype (Kv: V's head size, K's unless the
+    V plane says otherwise); rows past a slot's valid chunk tokens
     are defined but meaningless (the engine discards them)."""
     B, C, H, K = q.shape
-    ps, G = _check_pool(H, K, k_pool, v_pool)
+    ps, G, Kv = _check_pool(H, K, k_pool, v_pool)
     n_pg = tables.shape[1]
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(K)
@@ -927,17 +1029,16 @@ def paged_prefill_attention(
     quantized = k_scale is not None
     _check_window(window, col_page, tables, quantized)
     n = prefill_block_pages(n_pg, ps, G * K, k_pool.dtype.itemsize, C,
-                            H * K, q.dtype.itemsize, H)
+                            H * K, q.dtype.itemsize, H, G * Kv)
     tables, col_page = _pad_columns(tables, col_page, n)
     name, prefetch, extra = _call_form(
         "paged_prefill_attn", layer, (tables, offsets, lengths), k_scale,
         v_scale, window, col_page)
-    split = prefill_kv_split(G * K, C, H * K, q.dtype.itemsize, H)
-    if split > 1:       # one KV head and its H/G query heads a grid step
+    split = prefill_kv_split(G * K, C, H * K, q.dtype.itemsize, H, G * Kv)
+    if split > 1:       # G/split KV heads and their query heads a grid step
         extra["kv_axis"] = 2
-        H_step, G_step = H // split, 1
-    else:
-        H_step, G_step = H, G
+    H_step, G_step = H // split, G // split
+    sink = _sink_form(extra, sink, H, K, Kv)
 
     kernel = functools.partial(
         _prefill_kernel, sm_scale=sm_scale, page_size=ps, block_pages=n,
@@ -945,12 +1046,12 @@ def paged_prefill_attention(
     scratch = [
         pltpu.VMEM((H_step, C, _LANES), jnp.float32),  # m
         pltpu.VMEM((H_step, C, _LANES), jnp.float32),  # l
-        pltpu.VMEM((C, H_step * K), jnp.float32),      # acc
+        pltpu.VMEM((C, H_step * Kv), jnp.float32),     # acc
     ]
     out = _pool_call(kernel, name, q.reshape(B, C, H * K),
                      k_pool, v_pool, prefetch, tables.shape[1], scratch,
-                     interpret, block_pages=n, kv_split=split)
-    return out.reshape(B, C, H, K)
+                     interpret, block_pages=n, kv_split=split, sink=sink)
+    return out.reshape(B, C, H, Kv)
 
 
 # Speculative-verify reuse: the verify pass of draft-model speculative
@@ -966,13 +1067,14 @@ def paged_prefill_attention(
 
 def _gather_timeline(k_pool, v_pool, layer, tables, n_heads, head_dim,
                      k_scale, v_scale):
-    """Each slot's contiguous K and V timelines [B, T, H, K], gathered
-    from the whole pool at ``[layer, tables]`` (pages only: no layer's
-    plane is cut out) and, for an int8 pool, dequanted exactly as the
-    fused kernels do (page.astype(f32) * scale)."""
+    """Each slot's contiguous K and V timelines [B, T, H, K] and
+    [B, T, H, Kv], gathered from the whole pool at ``[layer, tables]``
+    (pages only: no layer's plane is cut out) and, for an int8 pool,
+    dequanted exactly as the fused kernels do (page.astype(f32) *
+    scale)."""
     B, n_pg = tables.shape
-    ps, K = k_pool.shape[2], head_dim
-    G = k_pool.shape[3] // K
+    ps = k_pool.shape[2]
+    G = k_pool.shape[3] // head_dim
     views = []
     for pool, scale in ((k_pool, k_scale), (v_pool, v_scale)):
         view = pool[layer, tables]               # [B, n_pg, ps, H*K]
@@ -980,7 +1082,7 @@ def _gather_timeline(k_pool, v_pool, layer, tables, n_heads, head_dim,
             view = (view.astype(jnp.float32)
                     * scale[layer, tables][:, :, None, None].astype(
                         jnp.float32))
-        view = view.reshape(B, n_pg * ps, G, K)
+        view = view.reshape(B, n_pg * ps, G, pool.shape[3] // G)
         # Grouped-query: every query head sees its KV head's timeline.
         views.append(view if G == n_heads
                      else jnp.repeat(view, n_heads // G, axis=2))
@@ -1000,9 +1102,21 @@ def _key_positions(tables, page_size, col_page):
             ).reshape(B, n_pg * page_size)
 
 
+def _softmax(s, sink):
+    """Softmax over the last axis of s [B, H, ..., T] float32; with a
+    `sink` [H] each head's logit joins the denominator and is dropped
+    from the probabilities (it has no value row)."""
+    if sink is None:
+        return jax.nn.softmax(s, axis=-1)
+    logit = sink.astype(jnp.float32).reshape((1, -1) + (1,) * (s.ndim - 2))
+    joined = jnp.concatenate(
+        [s, jnp.broadcast_to(logit, s.shape[:-1] + (1,))], axis=-1)
+    return jax.nn.softmax(joined, axis=-1)[..., :-1]
+
+
 def reference_paged_attention(q, k_pool, v_pool, layer, tables, lengths, *,
                               sm_scale=None, k_scale=None, v_scale=None,
-                              window=None, col_page=None):
+                              window=None, col_page=None, sink=None):
     """Gather-semantics oracle: reconstitute each slot's contiguous
     timeline and run plain-XLA attention — byte-for-byte the math of
     models/paged_kv.py's gather read path (test oracle + fallback).
@@ -1022,7 +1136,7 @@ def reference_paged_attention(q, k_pool, v_pool, layer, tables, lengths, *,
     if window is not None:
         mask &= tpos >= lengths[:, None] - window
     s = jnp.where(mask[:, None, :], s, NEG_INF)
-    probs = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+    probs = _softmax(s, sink).astype(q.dtype)
     # q.dtype out unconditionally: the dequanted v_view is f32, and the
     # einsum's promotion must not leak into callers' scan carries.
     return jnp.einsum("bht,bthk->bhk", probs, v_view).astype(q.dtype)
@@ -1031,7 +1145,7 @@ def reference_paged_attention(q, k_pool, v_pool, layer, tables, lengths, *,
 def reference_paged_prefill_attention(q, k_pool, v_pool, layer, tables,
                                       offsets, lengths, *, sm_scale=None,
                                       k_scale=None, v_scale=None,
-                                      window=None, col_page=None):
+                                      window=None, col_page=None, sink=None):
     """Gather-semantics oracle for chunked prefill: reconstitute each
     slot's contiguous timeline from the pool and run plain-XLA causal
     attention for a C-query chunk at absolute offset — byte-for-byte the
@@ -1058,7 +1172,7 @@ def reference_paged_prefill_attention(q, k_pool, v_pool, layer, tables,
     if window is not None:
         mask &= tpos > qpos - window
     s = jnp.where(mask[:, None], s, NEG_INF)
-    probs = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+    probs = _softmax(s, sink).astype(q.dtype)
     # q.dtype out unconditionally (see reference_paged_attention).
     return jnp.einsum("bhct,bthk->bchk", probs, v_view).astype(q.dtype)
 

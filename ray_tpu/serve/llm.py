@@ -869,10 +869,13 @@ class LLMEngine:
                       # the decode programs' on-device counters, pulled
                       # with a window's tokens: (layer, step) pairs run,
                       # experts that had a row, the fullest expert's
-                      # rows, rows routed — summed over those pairs.
+                      # rows, rows routed — summed over those pairs; and
+                      # of the rows routed, the choices a router's bias
+                      # moved out of the unbiased top-k (0: no bias).
                       "moe_layer_steps": 0,
                       "moe_experts_touched_sum": 0, "moe_rows_max_sum": 0,
-                      "moe_rows_routed": 0, "moe_rows_held": 0}
+                      "moe_rows_routed": 0, "moe_rows_held": 0,
+                      "moe_rows_bias_moved": 0}
         # The decode programs' counters run on, wrapping uint32; the
         # window's share is the difference from the last pull.
         self._moe_seen: dict | None = None
@@ -933,6 +936,8 @@ class LLMEngine:
             # A family that holds every expert counts no share of its own.
             self.stats["moe_rows_held"] += delta.get(
                 "rows_held", delta["rows_routed"])
+            self.stats["moe_rows_bias_moved"] += delta.get(
+                "rows_bias_moved", 0)
 
     # ------------------------------------------------------------- API
 
@@ -1457,12 +1462,22 @@ class LLMEngine:
                 nbytes = lambda a: int(math.prod(a.shape) * a.dtype.itemsize)
                 # A family's window layers keep rings a slot beside the
                 # pages (0: none); the pool's bytes count both kinds.
-                m["window_kv_bytes"] = sum(
-                    nbytes(a) for name, a in self.cache.items()
-                    if name in ("k_win", "v_win"))
+                rings = [a for name, a in self.cache.items()
+                         if name in ("k_win", "v_win")]
+                m["window_kv_bytes"] = sum(map(nbytes, rings))
                 m["kv_pool_bytes"] = m["window_kv_bytes"] + sum(
                     nbytes(a) for name, a in self.cache.items()
                     if name in ("k", "v", "k_scale", "v_scale"))
+                # The pool's bytes by kind, and of the window kind what
+                # every slot's live window needs: the rest of a ring is
+                # room for a dispatch's writes (models/laguna.py
+                # `ring_pages`).
+                m["kv_bytes_window"] = m["window_kv_bytes"]
+                m["kv_bytes_full"] = (m["kv_pool_bytes"]
+                                      - m["kv_bytes_window"])
+                m["kv_bytes_window_live"] = sum(
+                    self.n_slots * a.shape[0] * self.cfg.window
+                    * a.shape[3] * a.dtype.itemsize for a in rings)
                 # A family's per-slot state beside the pages (0: none).
                 m["slot_state_bytes"] = sum(
                     nbytes(self.cache[name])
@@ -2602,7 +2617,8 @@ class LLMEngine:
             pool = self.cache["k"]
             self._decode_block_at[width] = decode_block_pages(
                 width, self.page_size, pool.shape[3] // self.tp,
-                pool.dtype.itemsize, self.cfg.n_heads // self.tp)
+                pool.dtype.itemsize, self.cfg.n_heads // self.tp,
+                self.cache["v"].shape[3] // self.tp)
         block = self._decode_block_at[width]
         live = self.pool.pages_for(self.positions[active])
         self.stats["decode_pages_live"] += int(live.sum())
@@ -2632,7 +2648,8 @@ class LLMEngine:
                 width, self.page_size, pool.shape[3] // self.tp,
                 pool.dtype.itemsize, self.prefill_chunk,
                 heads * self.cfg.head_dim,
-                np.dtype(self.cfg.dtype).itemsize, heads)
+                np.dtype(self.cfg.dtype).itemsize, heads,
+                self.cache["v"].shape[3] // self.tp)
         return self._block_pages_at[width]
 
     def _dispatch_chunks(self, batch) -> None:

@@ -751,3 +751,192 @@ def test_qwen3_next_program_fits_and_moves_no_state(chip, qwen3_next_serving,
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert 11.0e9 < total < 12.5e9
+
+
+# MiMo-V2-Flash's serving shapes: 64 query heads of 192 over 4 KV heads
+# (full layers: K pages 768 lanes beside V pages 512) and 8 (window
+# layers: 1,536 beside 1,024, a learned sink a query head, a ring of 19
+# pages a slot: the window's 2, the 16 that eight chunk rows of one
+# prompt write in one dispatch, and one); the cell's cut: 7 layers, 16 of
+# 256 experts held, an eighth of the vocabulary, 128 slots x 96 pages.
+M_SLOTS, M_PAGES, M_H, M_K, M_KV, M_RING, M_WINDOW = 128, 12288, 64, 192, \
+    128, 19, 128
+_MIMO_KINDS = {"full": (4, 96, False), "window": (8, M_RING, True)}
+
+
+def _mimo_call(chip, kind, rows=None):
+    """(fn, args, kernel name) of a mimo attention call of a layer kind:
+    the decode call over all 128 slots, or the prefill call at `rows`
+    chunk rows."""
+    G, width, ring = _MIMO_KINDS[kind]
+    n_rows = (M_SLOTS + 1) * M_RING if ring else M_PAGES + 1
+    k_pool = chip((2, n_rows, PS, G * M_K), jnp.bfloat16)
+    v_pool = chip((2, n_rows, PS, G * M_KV), jnp.bfloat16)
+    i32 = lambda *shape: chip(shape, jnp.int32)
+    kw = lambda col, sink: ({"window": M_WINDOW, "col_page": col,
+                             "sink": sink} if ring else {})
+    sink = chip((M_H,), jnp.float32)
+    suffix = "_window" if ring else ""
+    if rows is None:
+        fn = lambda q, k, v, l, t, n, col, s: paged_attention(
+            q, k, v, l, t, n, interpret=False, **kw(col, s))
+        args = (chip((M_SLOTS, M_H, M_K), jnp.bfloat16), k_pool, v_pool,
+                _layer(chip), i32(M_SLOTS, width), i32(M_SLOTS),
+                i32(M_SLOTS, width), sink)
+        return fn, args, "paged_decode_attn" + suffix
+    fn = lambda q, k, v, l, t, o, n, col, s: paged_prefill_attention(
+        q, k, v, l, t, o, n, interpret=False, **kw(col, s))
+    args = (chip((rows, C, M_H, M_K), jnp.bfloat16), k_pool, v_pool,
+            _layer(chip), i32(rows, width), i32(rows), i32(rows),
+            i32(rows, width), sink)
+    return fn, args, "paged_prefill_attn" + suffix
+
+
+@pytest.mark.parametrize("kind", sorted(_MIMO_KINDS))
+def test_kernels_compile_at_unequal_head_sizes_with_a_sink(chip, kind):
+    """Both paged kernels at K heads of 192 (1.5 lane tiles: every odd
+    head's lanes start mid-tile) beside V heads of 128, 64 query heads
+    over 4 and over 8 KV heads, the window kind over a ring with a sink.
+    The prefill grid splits into KV-head PAIRS (384 and 256 lanes: whole
+    tiles), since one head's 192 lanes are no block of the pool."""
+    from ray_tpu.ops.paged_attention import (prefill_block_pages,
+                                             prefill_kv_split)
+
+    G, width, _ring = _MIMO_KINDS[kind]
+    shape = (G * M_K, C, M_H * M_K, 2, M_H, G * M_KV)
+    assert prefill_kv_split(*shape) == G // 2
+    assert prefill_block_pages(width, PS, G * M_K, 2, *shape[1:]) == 4
+    fn, args, name = _mimo_call(chip, kind)
+    out = _compile(fn, *args, kernels=(name,))
+    assert f"bf16[{M_SLOTS},{M_H},{M_KV}]" in out.as_text()
+    for rows in ONE_WIDTH_HEIGHTS:
+        fn, args, name = _mimo_call(chip, kind, rows)
+        _compile(fn, *args, kernels=(name,))
+
+
+@pytest.mark.parametrize("kind", sorted(_MIMO_KINDS))
+def test_decode_kernel_at_unequal_head_sizes_fits_what_its_rule_reckons(
+        chip, kind):
+    """`_decode_vmem_bytes` with K and V widths apart: the kernel's VMEM
+    scratch (K buffers 192 a head, V buffers 128, the block-diagonal
+    query at K's width, the accumulator at V's, the softmax state) plus
+    the score tiles is what the rule reckons for its block, and with a
+    slot group's queries (192 lanes pad to 256 in VMEM) and outputs it
+    stays inside the 16 MiB a kernel gets: two groups of 64 slots."""
+    import importlib
+
+    attn = importlib.import_module("ray_tpu.ops.paged_attention")
+    G, width, ring = _MIMO_KINDS[kind]
+    fn, args, _name = _mimo_call(chip, kind)
+    (eqn,) = [e for e in jax.make_jaxpr(fn)(*args).eqns
+              if e.primitive.name == "pallas_call"]
+    spec = eqn.params["grid_mapping"]
+    assert spec.grid == (2,)
+    assert [str(m.block_aval.memory_space).lower().endswith("any")
+            for m in spec.block_mappings] == (
+        [False] + [False] * ring + [True, True, False])
+    scratch = [v.aval for v in eqn.params["jaxpr"].invars[
+        -spec.num_scratch_operands:]]
+    vmem = sum(int(np.prod(a.shape)) * jnp.dtype(a.dtype).itemsize
+               for a in scratch if "sem" not in str(a.dtype).lower())
+    k_lanes, v_lanes = G * M_K, G * M_KV
+    n = attn.decode_block_pages(width, PS, k_lanes, 2, M_H, v_lanes)
+    assert n == {"full": 4, "window": 2}[kind]
+    buffers = attn._DECODE_BUFFERS * n * PS * (k_lanes + v_lanes) * 2
+    assert vmem == (buffers + M_H * (k_lanes * 2 + v_lanes * 4)
+                    + 2 * M_H * 128 * 4)
+    tiles = 2 * M_H * n * PS * 4
+    reckoned = attn._decode_vmem_bytes(n, PS, k_lanes, 2, M_H, v_lanes)
+    assert vmem + tiles <= reckoned <= attn._DECODE_VMEM_BUDGET
+    # all the rule overcounts: the block-diagonal query at 4 bytes, and
+    # the accumulator's update
+    assert reckoned - (vmem + tiles) == M_H * (k_lanes * 2 + v_lanes * 4)
+    group = M_SLOTS // 2
+    queries = 2 * group * M_H * (256 + 128) * 2         # q, out; two each
+    assert reckoned + queries <= attn._DECODE_GROUP_BUDGET < 16 * 2**20
+
+
+@pytest.fixture(scope="module")
+def mimo_v2_serving(chip):
+    """(cfg, params, pool) of the mimo-v2-flash cell as shapes on one
+    described chip, with the two backend questions steered to the chip's
+    answers."""
+    import importlib
+
+    from ray_tpu.models import mimo_v2
+
+    cfg = mimo_v2.MiMoV2Config(n_layers=7, n_experts=16, vocab_size=19072)
+    params = {name: chip(spec["shape"], jnp.bfloat16)
+              for name, spec in mimo_v2.param_specs(cfg).items()}
+    pool = jax.tree.map(
+        lambda x: chip(x.shape, x.dtype),
+        jax.eval_shape(lambda: mimo_v2.init_paged_kv(
+            cfg, M_PAGES, PS, M_SLOTS,
+            dispatch_tokens=ONE_WIDTH_HEIGHTS[-1] * C)))
+    attn = importlib.import_module("ray_tpu.ops.paged_attention")
+    moe = importlib.import_module("ray_tpu.ops.moe")
+    saved = attn._interpret_default, moe._mixed_dot_default
+    attn._interpret_default = lambda: False
+    moe._mixed_dot_default = lambda: True
+    yield cfg, params, pool
+    attn._interpret_default, moe._mixed_dot_default = saved
+
+
+@pytest.mark.parametrize("program", ONE_WIDTH_PROGRAMS)
+def test_mimo_v2_program_fits_and_moves_no_expert_layer(chip,
+                                                        mimo_v2_serving,
+                                                        program):
+    """The mimo_v2 family's step programs (decode, and the chunk program
+    at both of the engine's heights), compiled whole at the cell's size:
+    all four attention calls and the experts' grouped matmul are in them
+    under the names a trace finds them by; no layer of experts (16 x
+    4,096 x 2,048 bf16, 268 MB a matrix) and no layer of any of the four
+    planes (the smallest: the full kind's V, 12,289 pages x 64 x 512,
+    805 MB) is copied, sliced out or put back; the donated pool (four
+    planes of four widths, ring rows, six counters) is updated in place;
+    weights + pool + what the program needs besides stay under the
+    chip's 16 GB."""
+    from ray_tpu.models import mimo_v2
+
+    cfg, params, pool = mimo_v2_serving
+    assert pool["ring_rows"].shape == (M_SLOTS + 1, M_RING)
+    assert [pool[n].shape[3] for n in ("k", "v", "k_win", "v_win")] == [
+        768, 512, 1536, 1024]
+    i32 = lambda *shape: chip(shape, jnp.int32)
+    if program == "decode":
+        key = jax.eval_shape(lambda: jax.random.key(0))
+        compiled = mimo_v2._decode_sample_paged.lower(
+            cfg, params, i32(M_SLOTS), pool, i32(M_SLOTS),
+            i32(M_SLOTS, 96), chip((M_SLOTS,), jnp.float32),
+            chip(key.shape, key.dtype), attn_impl="kernel").compile()
+        kernel = "paged_decode_attn"
+    else:
+        n = int(program.split("-")[1])
+        compiled = mimo_v2.prefill_chunk_paged.lower(
+            cfg, params, i32(n, C), pool, i32(n, 96), i32(n), i32(n),
+            slots=i32(n), return_logits=True, attn_impl="kernel").compile()
+        kernel = "paged_prefill_attn"
+    text = compiled.as_text()
+    calls = lambda name: len(re.findall(
+        rf"%\w*{name}[\w.]* = [^\n]*custom-call\(", text))
+    # Two full layers and five window layers (the plain name's pattern
+    # finds the window calls too).
+    assert calls(kernel + "_window") == 5 and calls(kernel) == 7
+    assert len(re.findall(r"%ragged-dot[\w.\-]* = [^\n]*custom-call\(",
+                          text)) >= 3
+    n_sparse = cfg.count("sparse")
+    assert (f"bf16[{n_sparse * cfg.n_experts},{cfg.d_model},{cfg.d_ff}]"
+            in text)
+    expert_layer = cfg.n_experts * cfg.d_model * cfg.d_ff
+    moved = _pool_moves(text, "bf16", expert_layer)
+    assert not moved, "layer-sized moves:\n" + "\n".join(moved)
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                     for a in pool.values())
+    assert mem.alias_size_in_bytes >= pool_bytes
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    print(f"mimo_v2 {program}: arguments "
+          f"{mem.argument_size_in_bytes / 1e9:.3f} GB, temp "
+          f"{mem.temp_size_in_bytes / 1e9:.3f} GB, total {total / 1e9:.3f} GB")
+    assert 14.5e9 < total < 15.8e9

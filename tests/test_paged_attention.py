@@ -1040,3 +1040,283 @@ def test_many_heads_split_the_prefill_grid_by_kv_head():
         assert prefill_block_pages(64, 64, 1024, 2, 128, heads * 128, 2,
                                    heads) == 4
     assert prefill_block_pages(13, 64, 1024, 2, 128, 72 * 128, 2, 72) == 4
+
+
+# ------------- K and V heads of unequal size, and a sink in the softmax
+
+def _dense_attention(q, k, v, qpos, lengths, G, *, window=None, sink=None):
+    """q [B, C, H, K] at absolute positions qpos [B, C] against whole
+    timelines k [B, T, G*K] and v [B, T, G*Kv]: plain masked softmax in
+    float64, a `sink` [H] joining each head's denominator with no value
+    row. -> [B, C, H, Kv]."""
+    B, C, H, K = q.shape
+    q, k, v = (np.asarray(a, np.float64) for a in (q, k, v))
+    k = np.repeat(k.reshape(B, k.shape[1], G, K), H // G, axis=2)
+    v = np.repeat(v.reshape(B, v.shape[1], G, -1), H // G, axis=2)
+    s = np.einsum("bchk,bthk->bhct", q, k) / np.sqrt(K)
+    t = np.arange(k.shape[1])[None, None, :]
+    seen = (t <= qpos[:, :, None]) & (t < np.asarray(lengths)[:, None, None])
+    if window is not None:
+        seen &= t > qpos[:, :, None] - window
+    s = np.where(seen[:, None], s, -1e30)
+    top = s.max(axis=-1, keepdims=True)
+    if sink is not None:
+        logit = np.asarray(sink, np.float64)[None, :, None, None]
+        top = np.maximum(top, logit)
+    p = np.exp(s - top)
+    norm = p.sum(axis=-1, keepdims=True)
+    if sink is not None:
+        norm = norm + np.exp(logit - top)
+    return np.einsum("bhct,bthk->bchk", p / norm, v)
+
+
+def _paged_kv(rng, *, lengths, G, K, Kv, ps, n_pg=None, R=None):
+    """Timelines k [B, T, G*K], v [B, T, G*Kv] and pools that hold them:
+    by scattered page tables of `n_pg` columns, or (R given) in a ring of
+    R pages a slot whose unreached rows hold garbage.
+    -> (k_pool, v_pool, tables, col_page or None, k_dense, v_dense)."""
+    B, T = len(lengths), -(-max(lengths) // ps) * ps
+    widths = (G * K, G * Kv)
+    dense = [rng.normal(size=(B, T, w)).astype(np.float32) for w in widths]
+    rows = B * R if R else B * n_pg + 1
+    pools = [rng.normal(size=(N_LAYERS, rows, ps, w)).astype(np.float32)
+             for w in widths]
+    col_page = np.full((B, R), -1, np.int32) if R else None
+    tables = (np.arange(B * R, dtype=np.int32).reshape(B, R) if R else
+              np.zeros((B, n_pg), np.int32))
+    free = iter(rng.permutation(np.arange(1, rows)))
+    for b, n in enumerate(lengths):
+        for j in range(-(-n // ps)):
+            if R:
+                row, col_page[b, j % R] = b * R + j % R, j
+            else:
+                row = tables[b, j] = next(free)
+            for pool, line in zip(pools, dense):
+                pool[:, row] = line[b, j * ps:(j + 1) * ps]
+    f32 = lambda a: jnp.asarray(a, jnp.float32)
+    return (f32(pools[0]), f32(pools[1]), jnp.asarray(tables),
+            None if col_page is None else jnp.asarray(col_page),
+            dense[0], dense[1])
+
+
+# (H, G, K, Kv): grouped and multi-head at small sizes, the served 192 /
+# 128 (a K head is 1.5 lane tiles), V wider than K.
+UNEQUAL_HEADS = [(4, 2, 24, 16), (4, 4, 24, 16), (8, 4, 192, 128),
+                 (6, 2, 16, 32)]
+
+
+@pytest.mark.parametrize("ring", [False, True])
+@pytest.mark.parametrize("sink", [False, True])
+@pytest.mark.parametrize("heads", UNEQUAL_HEADS)
+def test_decode_kernel_at_unequal_head_sizes_and_a_sink(heads, sink, ring):
+    """The scores contract K, the output is Kv wide, a sink a head seeds
+    (m, l); over scattered pages and over a ring with a window; kernel
+    against oracle against a dense float64 softmax."""
+    H, G, K, Kv = heads
+    ps = 16
+    lengths = [1, 17, 40, 41, 96, 150]
+    rng = np.random.default_rng(11)
+    k_pool, v_pool, tables, col_page, k_dense, v_dense = _paged_kv(
+        rng, lengths=lengths, G=G, K=K, Kv=Kv, ps=ps,
+        **({"R": 6} if ring else {"n_pg": 10}))
+    q = jnp.asarray(rng.normal(size=(len(lengths), H, K)), jnp.float32)
+    n = jnp.asarray(lengths, jnp.int32)
+    kw = {"sink": jnp.asarray(rng.normal(size=H), jnp.float32)} if sink \
+        else {}
+    window = 40 if ring else None
+    if ring:
+        kw.update(window=window, col_page=col_page)
+    got = paged_attention(q, k_pool.at[0].add(1.0), v_pool, jnp.int32(1),
+                          tables, n, **kw)
+    ref = reference_paged_attention(q, k_pool, v_pool, jnp.int32(1), tables,
+                                    n, **kw)
+    want = _dense_attention(q[:, None], k_dense, v_dense,
+                            np.asarray(lengths)[:, None] - 1, lengths, G,
+                            window=window, sink=kw.get("sink"))[:, 0]
+    assert got.shape == ref.shape == (len(lengths), H, Kv)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=1e-5)
+    np.testing.assert_allclose(np.asarray(ref), want, atol=1e-5)
+
+
+@pytest.mark.parametrize("split", [False, True])
+@pytest.mark.parametrize("ring", [False, True])
+@pytest.mark.parametrize("sink", [False, True])
+@pytest.mark.parametrize("heads", UNEQUAL_HEADS)
+def test_prefill_kernel_at_unequal_head_sizes_and_a_sink(heads, sink, ring,
+                                                         split, monkeypatch):
+    """Chunk rows at offsets inside the first window, across a page's
+    edge and after a ring has turned, a ragged last row and an inert one;
+    with the grid split by KV heads (`prefill_kv_split`, forced by a
+    small VMEM budget: pairs of heads at 192 / 128, nothing at widths
+    that fill no lane tile) and without."""
+    H, G, K, Kv = heads
+    ps, C = 16, 24
+    if split:
+        monkeypatch.setattr(pa, "_PREFILL_VMEM_BUDGET", 4096)
+    assert pa.prefill_kv_split(G * K, C, H * K, 4, H, G * Kv) == (
+        G // 2 if split and K == 192 else 1)
+    offsets = np.asarray([0, 10, 37, 100, 230, 64, 0], np.int32)
+    n_valid = np.asarray([24, 24, 24, 24, 24, 7, 0], np.int32)
+    lengths = offsets + n_valid
+    rng = np.random.default_rng(12)
+    k_pool, v_pool, tables, col_page, k_dense, v_dense = _paged_kv(
+        rng, lengths=list(np.maximum(lengths, 1)), G=G, K=K, Kv=Kv, ps=ps,
+        **({"R": 6} if ring else {"n_pg": 16}))
+    kw = {"sink": jnp.asarray(rng.normal(size=H), jnp.float32)} if sink \
+        else {}
+    window = 40 if ring else None
+    if ring:
+        kw.update(window=window, col_page=jnp.where(
+            jnp.asarray(lengths)[:, None] > 0, col_page, -1))
+    q = jnp.asarray(rng.normal(size=(len(offsets), C, H, K)), jnp.float32)
+    args = (jnp.int32(2), tables, jnp.asarray(offsets), jnp.asarray(lengths))
+    got = paged_prefill_attention(q, k_pool, v_pool, *args, **kw)
+    ref = reference_paged_prefill_attention(q, k_pool, v_pool, *args, **kw)
+    qpos = offsets[:, None] + np.arange(C)[None, :]
+    want = _dense_attention(q, k_dense, v_dense, qpos, lengths, G,
+                            window=window, sink=kw.get("sink"))
+    assert got.shape == ref.shape == (len(offsets), C, H, Kv)
+    valid = np.arange(C)[None, :] < n_valid[:, None]
+    np.testing.assert_allclose(np.asarray(got)[valid], np.asarray(ref)[valid],
+                               atol=1e-5)
+    np.testing.assert_allclose(np.asarray(ref)[valid], want[valid], atol=1e-5)
+
+
+@pytest.mark.parametrize("heads", [(4, 4, 16, 16), (6, 2, 128, 128)])
+def test_a_sink_at_equal_head_sizes(heads):
+    """The sink alone, multi-head (a slot's query one dense row) and
+    grouped: both kernels against a dense float64 softmax; a sink of
+    -inf-like size is no sink, and a large one drains the output."""
+    H, G, K, _ = heads
+    lengths = [5, 33, 64]
+    rng = np.random.default_rng(13)
+    k_pool, v_pool, tables, _c, k_dense, v_dense = _paged_kv(
+        rng, lengths=lengths, G=G, K=K, Kv=K, ps=16, n_pg=4)
+    q = jnp.asarray(rng.normal(size=(3, H, K)), jnp.float32)
+    n = jnp.asarray(lengths, jnp.int32)
+    sink = jnp.asarray(rng.normal(size=H), jnp.float32)
+    call = lambda s: np.asarray(paged_attention(
+        q, k_pool, v_pool, jnp.int32(0), tables, n, sink=s))
+    want = lambda s: _dense_attention(
+        q[:, None], k_dense, v_dense, np.asarray(lengths)[:, None] - 1,
+        lengths, G, sink=s)[:, 0]
+    np.testing.assert_allclose(call(sink), want(sink), atol=1e-5)
+    np.testing.assert_allclose(call(jnp.full(H, -80.0)), want(None),
+                               atol=1e-5)
+    assert np.abs(call(jnp.full(H, 40.0))).max() < 1e-6
+    chunk = np.asarray(paged_prefill_attention(
+        q[:, None], k_pool, v_pool, jnp.int32(0), tables, n - 1, n,
+        sink=sink))
+    np.testing.assert_allclose(chunk[:, 0], want(sink), atol=1e-5)
+
+
+@pytest.mark.parametrize("fault", ["sink_dropped", "sink_of_another_head",
+                                   "sink_scaled_like_a_score"])
+def test_a_sink_fault_moves_the_output(fault):
+    """What the tolerances above are for."""
+    H, G, K, Kv = 8, 4, 192, 128
+    lengths = [20, 70, 129]
+    rng = np.random.default_rng(14)
+    k_pool, v_pool, tables, _c, k_dense, v_dense = _paged_kv(
+        rng, lengths=lengths, G=G, K=K, Kv=Kv, ps=16, n_pg=9)
+    q = jnp.asarray(rng.normal(size=(3, H, K)), jnp.float32)
+    n = jnp.asarray(lengths, jnp.int32)
+    sink = jnp.asarray(rng.normal(size=H) + 2.0, jnp.float32)
+    want = _dense_attention(q[:, None], k_dense, v_dense,
+                            np.asarray(lengths)[:, None] - 1, lengths, G,
+                            sink=sink)[:, 0]
+    served = {"sink_dropped": None, "sink_of_another_head": jnp.roll(sink, 1),
+              "sink_scaled_like_a_score": sink / np.sqrt(K)}[fault]
+    for attend in (paged_attention, reference_paged_attention):
+        got = attend(q, k_pool, v_pool, jnp.int32(0), tables, n, sink=served)
+        assert np.abs(np.asarray(got) - want).max() > 1e-2
+
+
+def test_a_sink_is_one_logit_a_query_head_and_planes_share_their_pages():
+    rng = np.random.default_rng(1)
+    k_pool, v_pool, tables, n = _pool_and_tables(
+        rng, B=2, H=2, K=16, ps=8, n_pg=3, dtype=jnp.float32)
+    q = jnp.zeros((2, 2, 16), jnp.float32)
+    with pytest.raises(ValueError, match="one logit a query head"):
+        paged_attention(q, k_pool, v_pool, jnp.int32(0), tables, n,
+                        sink=jnp.zeros(3))
+    with pytest.raises(ValueError, match="pool/query shape mismatch"):
+        paged_attention(q, k_pool, v_pool[:, :-1], jnp.int32(0), tables, n)
+    with pytest.raises(ValueError, match="pool/query shape mismatch"):
+        paged_attention(q, k_pool, v_pool[..., :-1], jnp.int32(0), tables, n)
+
+
+def test_the_vmem_rules_reckon_k_and_v_widths_apart():
+    """At the served shapes of the one family whose widths differ (64
+    query heads of 192 over 4 and 8 KV heads, V heads of 128, pages of
+    64): the decode block is 512 KiB of K at most, the prefill grid
+    takes KV heads in pairs (384 and 256 lanes: whole tiles), and a
+    narrower V plane never costs more than an equal one."""
+    for G, n_pg, n in ((4, 96, 4), (8, 19, 2)):
+        k, v = G * 192, G * 128
+        assert decode_block_pages(n_pg, 64, k, 2, 64, v) == n
+        assert pa._decode_vmem_bytes(n, 64, k, 2, 64, v) < \
+            pa._decode_vmem_bytes(n, 64, k, 2, 64) <= pa._DECODE_VMEM_BUDGET
+        assert pa.prefill_kv_split(k, 128, 64 * 192, 2, 64, v) == G // 2
+        assert prefill_block_pages(n_pg, 64, k, 2, 128, 64 * 192, 2, 64,
+                                   v) == 4
+    # equal widths: the rule every family had (None means "as K")
+    assert pa._decode_vmem_bytes(4, 64, 1024, 2, 48, 1024) == \
+        pa._decode_vmem_bytes(4, 64, 1024, 2, 48)
+    assert pa.prefill_kv_split(1024, 128, 48 * 128, 2, 48, 1024) == 8
+
+
+# sha256 (first 16 hex digits) of `str(jax.make_jaxpr(call))`, addresses
+# blanked, of each family's two calls as the commit before the unequal
+# widths and the sink traced them (02951b4, computed there by this very
+# code): with `sink=None` and equal widths every family gets EXACTLY the
+# program it had. (H, K, G, table width, the call's extras.)
+_PARENT_PROGRAMS = {
+    "gpt.decode": ("6e08013ab7550714", 32, 64, 32, 8, {}),
+    "gpt.decode.int8": ("15dad0340c6f9e91", 32, 64, 32, 8, {"int8": True}),
+    "gpt.prefill": ("1336a0f5802a205f", 32, 64, 32, 8, {}),
+    "gpt.prefill.int8": ("faa614a89791d268", 32, 64, 32, 8, {"int8": True}),
+    "zaya.decode": ("e915f0f72052bbe3", 16, 128, 2, 8, {}),
+    "zaya.prefill": ("1b4e11ceb1865797", 16, 128, 2, 8, {}),
+    "laguna.decode.full": ("9c1cd8a68d51de7d", 48, 128, 8, 8, {}),
+    "laguna.decode.window": ("6892669326d34757", 72, 128, 8, 13,
+                             {"window": 512}),
+    "laguna.prefill.full": ("865ad16ed04cfb5b", 48, 128, 8, 8, {}),
+    "laguna.prefill.window": ("1a61312a96bf9ca6", 72, 128, 8, 13,
+                              {"window": 512}),
+    "qwen3_next.decode": ("f97fe1c605cecbf8", 16, 256, 2, 8, {}),
+    "qwen3_next.prefill": ("cc3e3da62ea1dde2", 16, 256, 2, 8, {}),
+}
+
+
+@pytest.mark.parametrize("program", sorted(_PARENT_PROGRAMS))
+def test_the_other_families_trace_exactly_the_programs_they_had(program):
+    import hashlib
+    import re
+
+    digest, H, K, G, n_pg, extra = _PARENT_PROGRAMS[program]
+    int8, window = extra.get("int8", False), extra.get("window")
+    i32, bf16 = jnp.int32, jnp.bfloat16
+    pool = jnp.zeros((2, 9, 64, G * K), jnp.int8 if int8 else bf16)
+    decode = ".decode" in program
+    B = 4 if decode else 2
+    kw, static = {}, {}
+    if int8:
+        kw = dict(k_scale=jnp.ones((2, 9)), v_scale=jnp.ones((2, 9)))
+    if window:
+        kw, static = dict(col_page=jnp.zeros((B, n_pg), i32)), dict(
+            window=window)
+    tables, rows = jnp.zeros((B, n_pg), i32), jnp.zeros((B,), i32)
+    if decode:
+        jaxpr = jax.make_jaxpr(lambda q, k, v, l, t, n, kw: paged_attention(
+            q, k, v, l, t, n, interpret=True, **kw, **static))(
+            jnp.zeros((B, H, K), bf16), pool, pool, jnp.int32(1), tables,
+            rows, kw)
+    else:
+        jaxpr = jax.make_jaxpr(
+            lambda q, k, v, l, t, o, n, kw: paged_prefill_attention(
+                q, k, v, l, t, o, n, interpret=True, **kw, **static))(
+            jnp.zeros((B, 128, H, K), bf16), pool, pool, jnp.int32(1),
+            tables, rows, rows, kw)
+    text = re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
