@@ -427,11 +427,13 @@ def _decode_sample_paged(cfg: MiMoV2Config, params, tokens, pool, positions,
 def decode_multi_paged(cfg: MiMoV2Config, params, tokens, pool, positions,
                        tables, n_steps: int, temps, key, *,
                        attn_impl: str = "gather", phase=_no_phase,
-                       counters=None):
+                       counters=None, carried=None, ahead=None):
     """models/paged_kv.decode_multi_paged for this block: the shared
     `_decode_window` of this family's step program. `counters(dict)`
     (optional) is handed the pool's running counters (`_COUNTERS`) as
-    they stand after the window, fetched WITH the window's tokens."""
+    they stand after the window's `n_steps`, fetched WITH the window's
+    tokens (what the step `ahead` asks for counts arrives with the next
+    window's)."""
 
     def step(toks, kv, pos, rng):
         return _decode_sample_paged(cfg, params, toks, kv, pos, tables,
@@ -439,7 +441,8 @@ def decode_multi_paged(cfg: MiMoV2Config, params, tokens, pool, positions,
 
     toks_out, pool, totals = _decode_window(
         step, tokens, pool, positions, n_steps, key, phase,
-        also=lambda pool: pool["moe_counters"])
+        also=lambda pool: pool["moe_counters"], carried=carried,
+        ahead=ahead)
     if counters is not None:
         counters(dict(zip(_COUNTERS, (int(t) for t in totals))))
     return toks_out, pool

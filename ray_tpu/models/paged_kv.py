@@ -665,8 +665,28 @@ def _no_phase(_name: str):
     return contextlib.nullcontext()
 
 
+@jax.jit
+def join_window(carried_mask, carried, tokens, positions):
+    """The inputs of a window's first NEW step where a step of the last
+    window is still in flight (`_decode_window`): a slot that step covers
+    (`carried_mask`) feeds the token it sampled, `carried`, still on the
+    device, at the position after the one it wrote; every other slot (one
+    whose prompt graduated this tick) feeds the host's `tokens` at the
+    host's `positions`. [B]-sized, one program an engine.
+    → (tokens [B] int32, positions [B] int32)."""
+    return (jnp.where(carried_mask, carried, tokens),
+            positions + carried_mask.astype(positions.dtype))
+
+
+@jax.jit
+def snapshot(leaves):
+    """A copy of small device values (a family's running counters) that
+    outlives the donation of the pool they are leaves of."""
+    return jax.tree.map(jnp.copy, leaves)
+
+
 def _decode_window(step, tokens, pool, positions, n_steps: int, key,
-                   phase=_no_phase, also=None):
+                   phase=_no_phase, also=None, *, carried=None, ahead=None):
     """`n_steps` back-to-back dispatches of one jitted step program
     (`step(tokens, pool, positions, key)` → the same four, advanced).
     Tokens, cursors and the donated pool stay on the device between
@@ -674,35 +694,62 @@ def _decode_window(step, tokens, pool, positions, n_steps: int, key,
     window's tokens are then fetched and stacked on the HOST — where the
     engine wants them anyway — so the window compiles nothing of its
     own: a device-side stack would be one more small program per
-    (n_steps, B). → (tokens_out [n_steps, B] int32 numpy, updated pool).
+    (n_steps, B). → (tokens_out [rows, B] int32 numpy, updated pool).
+
+    One step in flight across the boundary: with `ahead` (a callable)
+    ONE more run of the step is queued after the `n_steps`, fed by the
+    last one's device-resident tokens, cursors, pool and key, and its
+    tokens and key are handed to `ahead(tokens, key)` UNFETCHED. The
+    fetch asks for the `n_steps` arrays only, so it returns when step
+    `n_steps` is done and the device runs the extra step while the host
+    emits, admits and plans. The caller passes those tokens back as the
+    next call's `carried` (its `tokens` input joined from them,
+    `join_window`; its `key` the one handed over): they come back as the
+    first row of that call's `tokens_out`, so rows = `n_steps` + (1 with
+    `carried`). Everything goes down ONE in-order queue with the pool
+    donated from program to program, which is why the extra step may run
+    on slots the host releases meanwhile: whatever the host dispatches
+    next runs after it.
 
     `phase(name)` is the caller's recorder, a context manager factory
     (`LLMEngine._phase`): this loop is the one part of an engine tick the
-    engine cannot see into, so each dispatch reports as `decode.dispatch`
-    and the fetch as `decode.pull`.
+    engine cannot see into, so each dispatch (the extra one too) reports
+    as `decode.dispatch` and the fetch as `decode.pull`.
 
-    `also(pool)` (optional) names device values of the final pool to
-    fetch in the SAME pull (a family's on-device counters); with it the
-    result is (tokens_out, pool, those values)."""
-    out = []
+    `also(pool)` (optional) names device values of the pool as it stands
+    after step `n_steps` to fetch in the SAME pull (a family's on-device
+    counters; ahead of an extra step, which takes the pool they are
+    leaves of, a `snapshot` of them); with it the result is
+    (tokens_out, pool, those values)."""
+    out = [] if carried is None else [carried]
     for _ in range(n_steps):
         with phase("decode.dispatch"):
             tokens, positions, pool, key = step(tokens, pool, positions, key)
         out.append(tokens)
+    extra = None if also is None else also(pool)
+    if ahead is not None:
+        with phase("decode.dispatch"):
+            if extra is not None:
+                extra = snapshot(extra)
+            tokens, _positions, pool, key = step(tokens, pool, positions, key)
+        ahead(tokens, key)
     with phase("decode.pull"):
-        if also is None:
-            return np.stack(jax.device_get(out)), pool
-        out, extra = jax.device_get((out, also(pool)))
+        out, extra = jax.device_get((out, extra))
+    if also is None:
+        return np.stack(out), pool
     return np.stack(out), pool, extra
 
 
 def decode_multi_paged(cfg: GPTConfig, params, tokens, pool, positions,
                        tables, n_steps: int, temps, key, *,
-                       attn_impl: str = "gather", phase=_no_phase):
+                       attn_impl: str = "gather", phase=_no_phase,
+                       carried=None, ahead=None):
     """`n_steps` paged-decode steps with on-device sampling (the paged
     twin of decode.decode_multi — the engine pre-allocates pages
-    covering positions + n_steps before dispatch, so tables are static
-    across the window). → (tokens_out [n_steps, B] int32, updated pool).
+    covering every position the window writes before dispatch, so tables
+    are static across the window), plus the step `ahead` asks for and
+    after the row `carried` brings (`_decode_window`).
+    → (tokens_out [rows, B] int32, updated pool).
 
     The window is a `_decode_window` of ONE step program, not one
     program scanning over steps. It became that in PR 21, when the pool
@@ -711,20 +758,22 @@ def decode_multi_paged(cfg: GPTConfig, params, tokens, pool, positions,
     (head_dim 64 padded to 128 lanes there — a 2x copy of a 6.4 GB
     pool), which did not fit a 16 GB chip at OPT-1.3B. The lane-dense
     pool has no padded layout to fall into, so that reason is gone; the
-    window stays k dispatches because a dispatch costs 0.45 ms against
-    a 9 ms step (PERF.md section 5) and fusing it again is its own
-    change (ROADMAP S3). The only program a window compiles is that
-    step (`_decode_sample_paged`), one per table width, whatever
-    n_steps is: under the engine's compile_watch label
+    window stays dispatches of one step because a dispatch costs 0.45 ms
+    against a 9 ms step (PERF.md section 5), and because a step is the
+    unit the engine keeps in flight while it reads a window's tokens,
+    which a fused window could not split off. The only program a window
+    compiles is that step (`_decode_sample_paged`), one per table width,
+    whatever n_steps is: under the engine's compile_watch label
     `decode_multi_paged`, `jax_compiles_total{fn}` counts table widths.
-    What it costs is n_steps host dispatches per window where the scan
-    paid one."""
+    What it costs is a host dispatch a step where the scan paid one a
+    window."""
 
     def step(toks, kv, pos, rng):
         return _decode_sample_paged(cfg, params, toks, kv, pos, tables,
                                     temps, rng, attn_impl=attn_impl)
 
-    return _decode_window(step, tokens, pool, positions, n_steps, key, phase)
+    return _decode_window(step, tokens, pool, positions, n_steps, key, phase,
+                          carried=carried, ahead=ahead)
 
 
 @functools.partial(jax.jit, static_argnums=(0,),
@@ -944,7 +993,8 @@ def _decode_sample_paged_tp(cfg: GPTConfig, params, tokens, pool, positions,
 
 def decode_multi_paged_tp(cfg: GPTConfig, params, tokens, pool, positions,
                           tables, n_steps: int, temps, key, *, mesh,
-                          attn_impl: str = "gather", phase=_no_phase):
+                          attn_impl: str = "gather", phase=_no_phase,
+                          carried=None, ahead=None):
     """`decode_multi_paged` over a tp mesh: the same `_decode_window`
     of the sharded step program."""
 
@@ -953,7 +1003,8 @@ def decode_multi_paged_tp(cfg: GPTConfig, params, tokens, pool, positions,
             cfg, params, toks, kv, pos, tables, temps, rng,
             mesh=mesh, attn_impl=attn_impl)
 
-    return _decode_window(step, tokens, pool, positions, n_steps, key, phase)
+    return _decode_window(step, tokens, pool, positions, n_steps, key, phase,
+                          carried=carried, ahead=ahead)
 
 
 @functools.partial(jax.jit, static_argnames=("mesh",), donate_argnums=(0,))
@@ -1043,7 +1094,7 @@ __all__ = [
     "init_paged_kv", "copy_pages", "gather_pages", "scatter_pages",
     "prefill_batch_paged",
     "prefill_chunk_paged", "verify_chunk_paged", "spec_draft_propose",
-    "decode_step_paged", "decode_multi_paged",
+    "decode_step_paged", "decode_multi_paged", "join_window", "snapshot",
     "KV_POOL_PARTITION_RULES", "prefill_chunk_paged_tp",
     "verify_chunk_paged_tp", "decode_step_paged_tp",
     "decode_multi_paged_tp", "copy_pages_tp", "spec_draft_propose_tp",

@@ -241,6 +241,34 @@ _PULL_PHASES = ("prefill.pull", "decode.pull")
 _PHASES = _HOST_PHASES + _DISPATCH_PHASES + _PULL_PHASES + ("spec_verify",)
 
 
+# Why a decode window queued no step beyond its own (`_fit_window_pages`):
+# the pool had no page for one more position; that position would reach
+# max_len; the window is a single step (the host samples it); no slot
+# has a window's worth of budget left after this one.
+_STAND_DOWN = ("pages", "max_len", "k1", "budget")
+
+
+@dataclasses.dataclass
+class _InFlight:
+    """One decode step queued BEHIND a window whose tokens the host has
+    read: `tokens` [B] and `key` are its outputs, still on the device;
+    `mask` [B] bool marks the slots whose next token it computes (the
+    window's slots that did not finish in it). For those the host's
+    `tokens[slot]` at `positions[slot]` is that step's INPUT: the device
+    is one position ahead of the host until the next window's pull
+    brings `tokens` back as its first row.
+
+    `owed`: requests whose LAST token by `max_tokens` this step computes,
+    by the slot they held. Whatever that token is, the request is over
+    with it, so its slot and pages went back when the window before the
+    step was read (a tick sooner than the token can be; `_release`), and
+    the token is handed to the request when the step is."""
+    tokens: Any
+    key: Any
+    mask: np.ndarray
+    owed: dict = dataclasses.field(default_factory=dict)
+
+
 class _TickAccount:
     """Where the engine thread's time went, over WHOLE ticks.
 
@@ -524,7 +552,6 @@ class LLMEngine:
         self.prefill_width_bucketing = bool(o.prefill_width_bucketing)
         self._warmup_on_start = bool(o.warmup)
         self._warmed = False
-        self._bind_programs()
         self.prefill_chunk = prefill_chunk
         self.prefill_budget = o.prefill_token_budget
         # Chunked mode is not bucket-bound: any prompt the cache/pool can
@@ -648,6 +675,9 @@ class LLMEngine:
             self.params = jax.device_put(self.params)
             if spec_draft:
                 self.draft_params = jax.device_put(self.draft_params)
+        # After the weights and the pool are placed: the window's own two
+        # small programs are loaded against them.
+        self._bind_programs()
         self._spec_accept_ewma: float | None = None
         # Prefix cache (serve/prefix_cache.py): refcounted COW page
         # sharing across requests — admission binds the longest cached
@@ -721,6 +751,10 @@ class LLMEngine:
         self.tokens = np.zeros(n_slots, np.int32)
         self.positions = np.zeros(n_slots, np.int32)
         self.temps = np.zeros(n_slots, np.float32)
+        # The decode step in flight across the tick boundary (_InFlight),
+        # None when the device's queue holds no step the host has not
+        # read: the parent state every path outside the window works in.
+        self._carry: _InFlight | None = None
         # Decode-window sizes (largest first): one window advances all
         # slots k tokens with on-device sampling and ONE host sync,
         # amortizing the host↔device round trip per token. Power-of-two
@@ -837,6 +871,14 @@ class LLMEngine:
                       "decode_pages_live": 0, "decode_pages_fetched": 0,
                       "decode_columns": 0,
                       "decode_time_s": 0.0, "decode_windows": 0,
+                      # Of those windows, the ones that left one more
+                      # step in flight (_fit_window_pages), the others by
+                      # what stood it down, and the rows of such steps
+                      # thrown away because their slot had finished.
+                      "lookahead_windows": 0,
+                      **{"lookahead_stood_down_" + cause: 0
+                         for cause in _STAND_DOWN},
+                      "lookahead_rows_dropped": 0,
                       "slot_step_sum": 0, "slot_cap_sum": 0,
                       "preemptions": 0,
                       # Prefix-cache lifecycle (zeros unless enabled).
@@ -911,6 +953,33 @@ class LLMEngine:
         self._rt = types.SimpleNamespace(
             jax=jax, jnp=jnp,
             **{name: _cw.wrap(fn, name) for name, fn in programs.items()})
+        if self.kv_mode != "paged":
+            return
+        # The two programs a window that leaves a step in flight adds to
+        # the step program (models/paged_kv.py `join_window`, `snapshot`),
+        # [B]-sized, loaded here so that no request meets them cold. Each
+        # is called as the tick will call it: `carried` is a step's
+        # output, committed to its devices exactly when some weight or
+        # pool leaf is (a committed operand is another program to jit).
+        from ray_tpu.models import paged_kv as _paged
+
+        self._rt.join_window = _cw.wrap(_paged.join_window, "join_window")
+        placed = next((a for a in jax.tree.leaves((self.params, self.cache))
+                       if a.committed), None)
+        zeros = np.zeros(self.n_slots, np.int32)
+        carried = zeros
+        if placed is not None:
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            carried = jax.device_put(
+                carried, NamedSharding(self.mesh, PartitionSpec())
+                if self.tp > 1 else placed.sharding)
+        with _cw.warmup_scope():
+            self._rt.join_window(
+                jnp.asarray(np.zeros(self.n_slots, bool)),
+                jnp.asarray(carried), jnp.asarray(zeros), jnp.asarray(zeros))
+            if self._family.expert_counters:
+                _paged.snapshot(self.cache["moe_counters"])
 
     def _row_slots(self, slots) -> dict:
         """The chunk program's extra keyword for a family whose pool
@@ -1180,11 +1249,17 @@ class LLMEngine:
                 self._thread.start()
 
     def stop(self) -> None:
+        """Stop the engine thread. A step it left in flight is absorbed
+        (`_absorb_carry`), so a stopped engine's host state is the
+        device's, position for position."""
         self._shutdown.set()
         with self._lifecycle_lock:
             if self._thread is not None:
                 self._thread.join(timeout=30)
+                joined = not self._thread.is_alive()
                 self._thread = None
+                if joined:
+                    self._absorb_carry()
 
     def drain(self, timeout_s: float) -> dict:
         """Drain protocol: stop admission, let in-flight decodes finish,
@@ -1224,6 +1299,9 @@ class LLMEngine:
         (a request must never emit a token after its continuation left)."""
         if self._thread is not None:
             self.stop()
+        # An engine driven by step() has no thread to stop: a step in
+        # flight is absorbed here, before any length or page is read.
+        self._absorb_carry()
         doomed: list[GenRequest] = []
         slot_of: dict[int, GenRequest] = {}
         with self._lock:
@@ -1452,6 +1530,14 @@ class LLMEngine:
                 # decode kernel fetches; the rest costs it nothing.
                 m["decode_live_column_share"] = m["decode_pages_live"] / max(
                     1, m["decode_columns"])
+                # How often a decode window left one more step in flight
+                # for the host to work beside, and what stood the others
+                # down (_STAND_DOWN; the flat counters stay beside it).
+                m["lookahead_share"] = m["lookahead_windows"] / max(
+                    1, m["decode_windows"])
+                m["lookahead_stood_down"] = {
+                    cause: m["lookahead_stood_down_" + cause]
+                    for cause in _STAND_DOWN}
                 # Quantized-serving observability (rides the PR 6 chain:
                 # replica stats → serve.status() → /api/serve/load →
                 # `ray_tpu status --serve`): the dtype knobs as resolved
@@ -2812,9 +2898,13 @@ class LLMEngine:
                     self._handoff_prefill(slot, req)
         return set()
 
-    def _release(self, slot: int) -> None:
+    def _release(self, slot: int, *, owed: bool = False) -> None:
         """Free a slot. Positions reset so multi-step windows never walk an
         idle slot's write cursor toward the cache boundary.
+
+        `owed`: the request is over but for its last token, which the
+        step in flight computes (`_InFlight.owed`): it leaves as a request
+        that completed cleanly does, and the token follows it.
 
         Insert-on-free: a request that completed cleanly donates its
         chunk-aligned written prefix (prompt AND generated tokens — the
@@ -2827,7 +2917,7 @@ class LLMEngine:
         with self._lock:
             self.slot_req[slot] = None
         if (self.prefix_cache is not None and req is not None
-                and req.done.is_set() and req.error is None
+                and (owed or req.done.is_set()) and req.error is None
                 and (not req.migrated or req.kv_handoff is not None)):
             # Migrated requests normally never donate (drain export
             # wants the pages BACK) — except a prefill-pool handoff,
@@ -2851,7 +2941,7 @@ class LLMEngine:
             self._sync_cache_evictions()
         if (self.kv_transfer and self._kv_store is not None
                 and self.pool_role is None and req is not None
-                and req.done.is_set() and req.error is None
+                and (owed or req.done.is_set()) and req.error is None
                 and not req.migrated):
             # Insert-on-free OBJECT donation (the fused-engine half of
             # the init contract: "completed requests donate"): the
@@ -2872,6 +2962,20 @@ class LLMEngine:
                     < len(seq) // self.prefill_chunk):
                 self._donate_kv(seq, self.pool.row(slot),
                                 memo=req.prefix_hashes)
+        carry = self._carry
+        if carry is not None and carry.mask[slot]:
+            # The step in flight computed a token for a request that is
+            # over: the row is thrown away (or, `owed`, kept for the
+            # request alone). Its write and its advance of the slot's
+            # state stand, harmlessly: whatever takes these pages or this
+            # slot next is dispatched after that step.
+            carry.mask[slot] = False
+            if owed:
+                carry.owed[slot] = req
+            else:
+                self.stats["lookahead_rows_dropped"] += 1
+            if not carry.mask.any() and not carry.owed:
+                self._carry = None
         self.tokens[slot] = 0
         self.positions[slot] = 0
         self.temps[slot] = 0.0
@@ -2898,6 +3002,15 @@ class LLMEngine:
         generated tokens on the SECOND preempt, corrupting both the
         recompute context and every digest keyed off it (pinned by
         test_kv_objects.TestPreemptRegrow)."""
+        if self._carry is not None and self._carry.mask[slot]:
+            # Absorb, never discard, for a live slot: the context below
+            # must hold every token the device has computed for it. (The
+            # page fitter never gets here with a step in flight: its
+            # one-step rung then needs no page. This is for callers
+            # outside the tick.)
+            self._absorb_carry()
+            if self.slot_req[slot] is None:
+                return              # that token finished the request
         req = self.slot_req[slot]
         req.prompt_ids = (list(req.prompt_ids[:req.n_prompt])
                           + [int(t) for t in req.out_ids])
@@ -2923,21 +3036,69 @@ class LLMEngine:
             req.stream.put(None)
         req.done.set()
 
-    def _fit_window_pages(self, active: list[int], k: int) -> tuple[list[int], int]:
+    def _fit_window_pages(self, active: list[int],
+                          k: int) -> tuple[list[int], int, str | None]:
         """Paged mode: shrink the window and/or preempt until the pool can
         cover every active slot's writes for the window, then allocate.
-        → (surviving active slots, window size; 0 = nothing to run)."""
+        → (surviving active slots, window size; 0 = nothing to run, why
+        no step follows the window in flight; None = one does).
+
+        A window of `kk` rows dispatches `kk` steps, or `kk - 1` behind a
+        step in flight (`self._carry`: its row is the window's first), and
+        a slot that step covers writes from `positions + 1`. The window
+        leaves one more step in flight when there is a window to follow
+        it and room for its write: `kk` > 1 (the one-step tick samples on
+        the host), some slot with two tokens or more of budget after
+        this window (so the next tick is a window too, not the one-step
+        tick), the position after the window short of max_len for every
+        slot, and a page for it from the pool, asked after the prefix
+        cache's reclaim hook and before the window shrinks or anything
+        is shed. Otherwise the tick is the parent's."""
+        carry = self._carry
+        behind = int(carry is not None)
         while active:
+            first = self.positions[active] + (
+                carry.mask[active] if behind else 0)
             for kk in [k] + [x for x in self._k_ladder if x < k] + [1]:
                 # Cached pages are speculative value; a live decode
                 # window is not. Zero-active prefix-cache entries are
                 # evicted (the reclaim hook) before the window shrinks —
                 # and long before anything is preempted.
-                if self.pool.grow(active, self.positions[active] + kk - 1,
-                                  self._cache_reclaim):
-                    return active, kk
+                last = first + (kk - behind) - 1
+                cause = (self._lookahead_stand_down(active, kk, last + 1)
+                         if kk == k else "pages")
+                if cause is None:
+                    if self.pool.grow(active, last + 1, self._cache_reclaim):
+                        return active, kk, None
+                    cause = "pages"
+                # A slot AT max_len (its last window ended there, where
+                # max_len is whole pages) runs the one-step tick only to
+                # be finished by it: there is no page past the table.
+                last = np.minimum(last, self.max_len - 1)
+                # Behind a step in flight the one-step rung asks for no
+                # page (that step's write is held): nothing is shed
+                # while the device is a position ahead of the host.
+                if self.pool.grow(active, last, self._cache_reclaim):
+                    return active, kk, cause
             active = self._shed_for_pages(active)
-        return [], 0
+        return [], 0, "pages"
+
+    def _lookahead_stand_down(self, active: list[int], k: int,
+                              ahead) -> str | None:
+        """What, pages apart, keeps a window of `k` rows from leaving one
+        more step in flight (one of _STAND_DOWN), or None. `ahead`: the
+        position that step would write, per active slot."""
+        if k == 1:
+            return "k1"
+        if int(ahead.max()) >= self.max_len:
+            return "max_len"
+        carry = self._carry
+        for slot in active:
+            req = self.slot_req[slot]
+            rows = k - int(carry is not None and not carry.mask[slot])
+            if req.max_tokens - len(req.out_ids) - rows >= 2:
+                return None
+        return "budget"
 
     def _shed_for_pages(self, active: list[int]) -> list[int]:
         """Pressure-relief tail shared by the decode-window and
@@ -3210,9 +3371,19 @@ class LLMEngine:
 
     def step(self) -> int:
         """One engine tick: admit queued requests, spend the chunked-
-        prefill token budget, then one fused decode window for every
+        prefill token budget, then one decode window for every
         decode-ready slot. → slots that did work (decoding + prefilling).
-        """
+
+        A paged window of k rows ends with k + 1 steps queued and the
+        first k read: the last runs on the device while the NEXT tick
+        admits, dispatches chunk programs and plans, and is that tick's
+        first row (`_fit_window_pages` says when; `_InFlight`,
+        `_absorb_carry`). Between ticks the device may therefore be one
+        position ahead of `tokens` / `positions` for the slots that
+        step covers; whatever reads them from outside a tick absorbs it
+        first. A request whose last token by `max_tokens` is the one in
+        flight gives its slot back at once (`_InFlight.owed`): it is in
+        no slot and not yet done until that step is read."""
         with self._lock:
             self._mid_tick = True
         try:
@@ -3254,6 +3425,11 @@ class LLMEngine:
             _chaos.hit("llm.decode_window")
             return self._spec_decode_window(
                 active, self.stats["prefill_tokens"] > pt0) + n_prefilling
+        if self._carry is not None and not self._decode_ready_slots():
+            # Nothing decodes, yet a step is in flight: for requests that
+            # left their slots ahead of their last token (`_InFlight.owed`).
+            self._absorb_carry(self._phase)
+        carry = self._carry
         with self._phase("plan"):
             # Mid-prefill slots are not decode-active (their page tables
             # are masked off below); chunks completed this tick already
@@ -3268,8 +3444,9 @@ class LLMEngine:
                 _chaos.hit("llm.decode_window")
                 k = self._pick_window(active)
                 table_view = None
+                stood_down = None
                 if self.kv_mode == "paged":
-                    active, k = self._fit_window_pages(active, k)
+                    active, k, stood_down = self._fit_window_pages(active, k)
                     if active:
                         table_view = self._decode_table_view(active)
                         self._count_decode_pages(active,
@@ -3279,24 +3456,60 @@ class LLMEngine:
                 self._last_window_end = None
                 return n_prefilling
             tick_prefill = self.stats["prefill_tokens"] > pt0
+            # The steps this window dispatches: all its rows, or all but
+            # the first where a step of the last window is in flight.
+            n_new = k - (carry is not None)
             # decode_step_ms is taken from here (the window's inputs go
-            # to the device) to the end of the pull.
+            # to the device) to the end of the pull. Behind a step in
+            # flight that is the rest of that step and the n_new new
+            # ones, over k rows: it reads under the device's step by the
+            # part of one step the host's work ran beside.
             t0 = time.perf_counter()
-            if k > 1:
-                self._rng_key, sub = rt.jax.random.split(self._rng_key)
+            if carry is None:
+                if k > 1:
+                    self._rng_key, sub = rt.jax.random.split(self._rng_key)
+                    temps = jnp.asarray(self.temps)
+                tokens = jnp.asarray(self.tokens)
+                positions = jnp.asarray(self.positions)
+            elif n_new:
+                # A slot the step in flight covers feeds that step's
+                # token, on the device, a position on; a slot that
+                # graduated this tick feeds the host's, as ever.
+                sub = carry.key
                 temps = jnp.asarray(self.temps)
-            tokens = jnp.asarray(self.tokens)
-            positions = jnp.asarray(self.positions)
-            if table_view is not None:
+                tokens, positions = rt.join_window(
+                    jnp.asarray(carry.mask), carry.tokens,
+                    jnp.asarray(self.tokens), jnp.asarray(self.positions))
+            if table_view is not None and n_new:
                 table_view = jnp.asarray(table_view)
+        if not n_new:
+            # A one-row window behind a step in flight IS that step: it
+            # is read, nothing is dispatched, and the next tick starts
+            # from the parent's state.
+            self._absorb_carry(self._phase)
+            return len(active) + n_prefilling
         if k > 1:
             with self._phase("decode_window"):
                 if self.kv_mode == "paged":
+                    # Device order is the safety argument for the step
+                    # `ahead` leaves in flight: it is queued here, behind
+                    # this window's steps and ahead of every chunk
+                    # program, page copy or step a later tick dispatches,
+                    # the pool donated from each to the next, and its
+                    # table view is this tick's immutable upload. A slot
+                    # the emit below releases keeps its pages, ring rows
+                    # and state untouched by anyone else until that step
+                    # has run.
+                    self._carry = None
                     # graftlint: disable=GUARDED-BY (engine-thread state: only _step writes the KV cache while the loop runs; drain/export mutate it after stop() joins the thread)
                     toks_out, self.cache = rt.decode_multi_paged(
                         self.cfg, self.params, tokens, self.cache,
-                        positions, table_view, k, temps, sub,
+                        positions, table_view, n_new, temps, sub,
                         attn_impl=self.attn_impl, phase=self._phase,
+                        carried=None if carry is None else carry.tokens,
+                        ahead=None if stood_down else (
+                            lambda toks, key: self._hold_ahead(
+                                active, toks, key)),
                         **self._window_counters)
                 else:
                     with self._phase("decode.dispatch"):
@@ -3306,22 +3519,43 @@ class LLMEngine:
                     with self._phase("decode.pull"):
                         toks_out = np.asarray(toks_out)  # [k, B]
             with self._phase("emit"):
-                self._observe_window(t0, time.perf_counter(), k,
-                                     len(active), tick_prefill)
+                # Slot-steps the device was handed this tick (the step
+                # left in flight is this tick's; the one absorbed was
+                # the last's), over every slot's: slot_occupancy.
+                steps = n_new + (self._carry is not None)
+                self._observe_decode(
+                    t0, time.perf_counter(), float(k), steps * len(active),
+                    steps * self.n_slots, tick_prefill)
+                if self.kv_mode == "paged":
+                    self.stats["lookahead_windows" if stood_down is None else
+                               "lookahead_stood_down_" + stood_down] += 1
+                if carry is not None:
+                    self._pay_owed(carry, toks_out[0])
                 for slot in active:
                     req = self.slot_req[slot]
+                    # A slot the absorbed step did not cover (it joined
+                    # this tick) has no first row.
+                    rows = toks_out[int(carry is not None
+                                        and not carry.mask[slot]):, slot]
                     finished = False
-                    for i in range(k):
-                        if self._emit(req, int(toks_out[i, slot])):
+                    for tok in rows:
+                        if self._emit(req, int(tok)):
                             finished = True
                             break
                     if finished:
                         self._release(slot)
                     else:
                         # graftlint: disable=GUARDED-BY (engine-thread state, see cache note above)
-                        self.tokens[slot] = toks_out[k - 1, slot]
+                        self.tokens[slot] = rows[-1]
                         # graftlint: disable=GUARDED-BY (engine-thread state, see cache note above)
-                        self.positions[slot] += k
+                        self.positions[slot] += len(rows)
+                        if (self._carry is not None
+                                and req.max_tokens - len(req.out_ids) == 1):
+                            # The step in flight computes this request's
+                            # last token: the slot and its pages are the
+                            # next admission's NOW, as they would be had
+                            # the token been read with this window.
+                            self._release(slot, owed=True)
             return len(active) + n_prefilling
         with self._phase("decode_window"):
             with self._phase("decode.dispatch"):
@@ -3338,6 +3572,8 @@ class LLMEngine:
         with self._phase("emit"):
             self._observe_window(t0, time.perf_counter(), 1, len(active),
                                  tick_prefill)
+            if self.kv_mode == "paged":
+                self.stats["lookahead_stood_down_" + stood_down] += 1
             for slot in active:
                 req = self.slot_req[slot]
                 if self.positions[slot] + 1 >= self.max_len:
@@ -3355,12 +3591,53 @@ class LLMEngine:
                 if self.slot_req[i] is not None
                 and i not in self._chunk_pos]
 
+    def _hold_ahead(self, active: list[int], tokens, key) -> None:
+        """`_decode_window`'s `ahead`: the step it queued behind the
+        window of the slots `active`, unread."""
+        mask = np.zeros(self.n_slots, bool)
+        mask[active] = True
+        self._carry = _InFlight(tokens, key, mask)
+
+    def _pay_owed(self, carry: _InFlight, row) -> None:
+        """Hand the requests that left their slots ahead of their last
+        token (`_InFlight.owed`) that token, from the step's `row` [B]."""
+        for slot, req in carry.owed.items():
+            self._emit(req, int(row[slot]))
+
+    def _absorb_carry(self, phase=lambda _name: contextlib.nullcontext()) -> None:
+        """Read the step in flight, if any, and emit its tokens: absorb,
+        never discard, for a live slot. A step advances a family's
+        per-slot state in place beside writing K/V, so a continuing slot
+        whose token were dropped would stay one token ahead of the host
+        for good. Afterwards the host's `tokens` / `positions` are the
+        device's again, which is what everything that reads or moves a
+        live slot's pages or state outside the window needs (`stop`,
+        `drain` / `_export_unfinished`, `_preempt`). Inside a tick
+        `phase` is the engine's recorder; outside, the engine thread is
+        not running."""
+        carry, self._carry = self._carry, None
+        if carry is None:
+            return
+        with phase("decode.pull"):
+            toks = np.asarray(carry.tokens)
+        with phase("emit"):
+            self._pay_owed(carry, toks)
+            for slot in np.flatnonzero(carry.mask):
+                if self._emit(self.slot_req[slot], int(toks[slot])):
+                    self._release(slot)
+                else:
+                    self.tokens[slot] = toks[slot]
+                    self.positions[slot] += 1
+
     def _loop(self) -> None:
         try:
             while not self._shutdown.is_set():
                 # step() IS the host-side scheduler tick: it syncs once
                 # per multi-token decode window by design, amortized over
-                # llm_decode_block tokens.
+                # llm_decode_block tokens, and on the paged path the sync
+                # waits for the window's rows only: one more step is
+                # queued behind them, so the device's queue is not empty
+                # while this thread emits, admits and plans.
                 # graftlint: disable=HOST-SYNC-IN-HOT-LOOP (designed once-per-window sync point)
                 n = self.step()
                 if n == 0 and self.pending.empty() and not self._deferred:
@@ -3381,6 +3658,9 @@ class LLMEngine:
                         self.slot_req[slot] = None
                 self._prefilling.clear()
                 self._chunk_pos.clear()
+                if self._carry is not None:
+                    doomed.extend(self._carry.owed.values())
+                    self._carry = None
                 doomed.extend(self._deferred)
                 self._deferred.clear()
                 while True:
