@@ -152,6 +152,10 @@ class Qwen3NextConfig:
 # The experts' stacks, handed whole to the grouped matmul. A linear
 # layer's leaves carry the prefix "g_", a full layer's "f_".
 _EXPERT_KEYS = ("w_gate", "w_up", "w_down")
+# The dense matrices a layer: each a matmul's right operand, picked out
+# of its stack by the layer walk's static index (`params[k][i]`).
+_PLANE_KEYS = ("g_qkvz", "g_ba", "g_out", "f_wq", "f_wk", "f_wv", "f_wo",
+               "router", "s_gate", "s_up", "s_down")
 
 
 def param_specs(cfg: Qwen3NextConfig) -> dict[str, dict[str, Any]]:
@@ -199,6 +203,22 @@ def partition_rules() -> tuple:
     from jax.sharding import PartitionSpec
 
     return ((r".*", PartitionSpec()),)
+
+
+def lay_out(_cfg: Qwen3NextConfig, params) -> dict:
+    """The tree as the serving programs want it (models/serving.py
+    `lay_out`): each dense matrix a layer (`_PLANE_KEYS`) a tuple of its
+    stack's per-layer arrays, which `params[k][i]` reads as it reads the
+    stack. Handed a stack, a program slices all of its layers out at
+    its start and only one fits fast memory: the rest go back to HBM and
+    each layer's matmul reads its plane a second time (at the 80B
+    widths `g_qkvz` alone: 502 MB a step). The experts' stacks stay
+    whole (the grouped matmul takes the stack and a layer), the vectors
+    stay stacks (kilobytes). Idempotent."""
+    return {
+        name: tuple(a[i] for i in range(a.shape[0]))
+        if name in _PLANE_KEYS and not isinstance(a, tuple) else a
+        for name, a in params.items()}
 
 
 def init_params(cfg: Qwen3NextConfig, rng: jax.Array) -> dict[str, jax.Array]:

@@ -58,6 +58,9 @@ class ServingFamily:
     # The decode programs keep expert counters in the pool: a decode
     # window hands them over with its tokens (`counters=`).
     expert_counters: bool = False
+    # (cfg, params) -> params: the tree as the family's programs want it
+    # laid out, applied once at load (None: as it came).
+    lay_out: Callable | None = None
     unsupported: tuple = ()
 
 
@@ -275,6 +278,7 @@ def _qwen3_next() -> ServingFamily:
                 "prefill_chunk_paged", "decode_step_paged",
                 "decode_multi_paged")},
         slot_state=qwen3_next.SLOT_STATE_LEAVES, expert_counters=True,
+        lay_out=qwen3_next.lay_out,
         unsupported=(
             Unsupported(
                 "kv_mode", lambda o: o.kv_mode == "paged", "paged",

@@ -640,6 +640,11 @@ class LLMEngine:
             # from a host-side generator: they gate host control flow
             # (emit / rollback), so deviceifying them buys nothing.
             self._spec_rng = np.random.default_rng(seed)
+        if fam.lay_out is not None:
+            # The tree as the family's programs want it laid out
+            # (models/serving.py), before it is placed: a host array is
+            # cut on the host.
+            self.params = fam.lay_out(cfg, self.params)
         if self.weight_dtype == "int8":
             # One-time compression at load: matmul planes become int8 +
             # per-output-channel fp32 scale vectors (gpt.QUANT_RULES).

@@ -294,6 +294,25 @@ def test_pool_move_rule_finds_the_old_programs_ops():
         "ROOT %dynamic-update-slice.9"]
 
 
+def _stacks(chip, model, cfg):
+    """The model's parameter tree as its `param_specs` shape it, bf16."""
+    return {name: chip(spec["shape"], jnp.bfloat16)
+            for name, spec in model.param_specs(cfg).items()}
+
+
+def _served(chip, cfg, stacks):
+    """The tree the engine hands the family's programs: the seam's
+    `lay_out` (models/serving.py) over the stacks, as shapes."""
+    from ray_tpu.models.serving import family_of
+
+    lay_out = family_of(cfg).lay_out
+    if lay_out is None:
+        return stacks
+    return jax.tree.map(
+        lambda x: chip(x.shape, x.dtype),
+        jax.eval_shape(functools.partial(lay_out, cfg), stacks))
+
+
 @pytest.fixture(scope="module")
 def opt_serving(chip):
     """(cfg, params, pool) as shapes on one described chip."""
@@ -302,8 +321,7 @@ def opt_serving(chip):
     from ray_tpu.models import gpt, paged_kv
 
     cfg = gpt.GPTConfig.opt_1_3b(vocab_size=50272, max_seq=2048)
-    params = {name: chip(spec["shape"], jnp.bfloat16)
-              for name, spec in gpt.param_specs(cfg).items()}
+    params = _stacks(chip, gpt, cfg)
     pool = jax.tree.map(
         lambda x: chip(x.shape, x.dtype),
         jax.eval_shape(lambda: paged_kv.init_paged_kv(cfg, CELL_PAGES, PS)))
@@ -317,7 +335,8 @@ def opt_serving(chip):
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
-def test_paged_program_moves_no_pool_layer(chip, opt_serving, program):
+def test_paged_program_moves_no_pool_layer(chip, opt_serving, step_program,
+                                           program):
     """`_decode_sample_paged` and `prefill_chunk_paged`, compiled whole at
     the cell's size: both kernels are in them under their names, the pool
     is lane-dense and row-major as the chip lays it out, and nothing in
@@ -328,11 +347,7 @@ def test_paged_program_moves_no_pool_layer(chip, opt_serving, program):
     cfg, params, pool = opt_serving
     i32 = lambda *shape: chip(shape, jnp.int32)
     if program == "decode":
-        key = jax.eval_shape(lambda: jax.random.key(0))
-        compiled = paged_kv._decode_sample_paged.lower(
-            cfg, params, i32(CELL_SLOTS), pool, i32(CELL_SLOTS),
-            i32(CELL_SLOTS, CELL_WIDTH), chip((CELL_SLOTS,), jnp.float32),
-            chip(key.shape, key.dtype), attn_impl="kernel").compile()
+        compiled = step_program("gpt", "decode")
         kernel = "paged_decode_attn"
     else:
         rows = CELL_CHUNK_ROWS
@@ -386,6 +401,50 @@ ONE_WIDTH_HEIGHTS = (4, 8)
 ONE_WIDTH_PROGRAMS = ["decode"] + [f"prefill-{h}" for h in ONE_WIDTH_HEIGHTS]
 
 
+@pytest.fixture(scope="module")
+def step_program(request, chip):
+    """(family, program) → the family's step program compiled whole at
+    its cell's size, once a module: "decode" is `_decode_sample_paged`
+    over every slot, "prefill-<n>" a one-width family's chunk program n
+    rows tall with the head. `stacks=True`: handed the stacks as
+    `param_specs` shapes them, not the tree the engine serves."""
+    import importlib
+
+    from ray_tpu.models.serving import family_of
+
+    # family → (its serving fixture, its programs' module, slots, width)
+    cells = {"gpt": ("opt_serving", "paged_kv", CELL_SLOTS, CELL_WIDTH),
+             "zaya": ("zaya_serving", "zaya", Z_SLOTS, 32),
+             "laguna": ("laguna_serving", "laguna", G_SLOTS, 64),
+             "qwen3_next": ("qwen3_next_serving", "qwen3_next", Q_SLOTS, 64),
+             "mimo_v2": ("mimo_v2_serving", "mimo_v2", M_SLOTS, 96)}
+    i32 = lambda *shape: chip(shape, jnp.int32)
+
+    @functools.cache
+    def compiled(family, program, stacks=False):
+        fixture, module, slots, width = cells[family]
+        programs = importlib.import_module("ray_tpu.models." + module)
+        cfg, params, pool = request.getfixturevalue(fixture)
+        if stacks:
+            params = _stacks(chip, family_of(cfg).model, cfg)
+        if program == "decode":
+            key = jax.eval_shape(lambda: jax.random.key(0))
+            return programs._decode_sample_paged.lower(
+                cfg, params, i32(slots), pool, i32(slots), i32(slots, width),
+                chip((slots,), jnp.float32), chip(key.shape, key.dtype),
+                attn_impl="kernel").compile()
+        n = int(program.split("-")[1])
+        return programs.prefill_chunk_paged.lower(
+            cfg, params, i32(n, C), pool, i32(n, width), i32(n), i32(n),
+            slots=i32(n), return_logits=True, attn_impl="kernel").compile()
+
+    return compiled
+
+
+def _attn_kernel(program):
+    return "paged_decode_attn" if program == "decode" else "paged_prefill_attn"
+
+
 def test_grouped_query_kernels_compile_at_head_size_128(chip):
     """Both paged kernels with G = 2 KV heads under H = 8 query heads of
     128: the decode kernel takes the query a head a row, the prefill
@@ -416,8 +475,7 @@ def zaya_serving(chip):
     from ray_tpu.models import zaya
 
     cfg = zaya.ZayaConfig(n_layers=L)
-    params = {name: chip(spec["shape"], jnp.bfloat16)
-              for name, spec in zaya.param_specs(cfg).items()}
+    params = _stacks(chip, zaya, cfg)
     pool = jax.tree.map(
         lambda x: chip(x.shape, x.dtype),
         jax.eval_shape(lambda: zaya.init_paged_kv(cfg, Z_PAGES, PS,
@@ -432,8 +490,8 @@ def zaya_serving(chip):
 
 
 @pytest.mark.parametrize("program", ONE_WIDTH_PROGRAMS)
-def test_zaya_program_fits_and_moves_no_expert_layer(chip, zaya_serving,
-                                                     program):
+def test_zaya_program_fits_and_moves_no_expert_layer(zaya_serving,
+                                                     step_program, program):
     """The zaya family's step programs (decode, and the chunk program at
     both of the engine's heights), compiled whole at the cell's
     size: the attention kernel and the experts' grouped matmul are in
@@ -442,23 +500,9 @@ def test_zaya_program_fits_and_moves_no_expert_layer(chip, zaya_serving,
     copied, sliced out or put back; the donated pool (pages AND slot
     state) is updated in place; weights + pool + what the program needs
     besides stay under the chip's 16 GB."""
-    from ray_tpu.models import zaya
-
-    cfg, params, pool = zaya_serving
-    i32 = lambda *shape: chip(shape, jnp.int32)
-    if program == "decode":
-        key = jax.eval_shape(lambda: jax.random.key(0))
-        compiled = zaya._decode_sample_paged.lower(
-            cfg, params, i32(Z_SLOTS), pool, i32(Z_SLOTS),
-            i32(Z_SLOTS, 32), chip((Z_SLOTS,), jnp.float32),
-            chip(key.shape, key.dtype), attn_impl="kernel").compile()
-        kernel = "paged_decode_attn"
-    else:
-        n = int(program.split("-")[1])
-        compiled = zaya.prefill_chunk_paged.lower(
-            cfg, params, i32(n, C), pool, i32(n, 32), i32(n), i32(n),
-            slots=i32(n), return_logits=True, attn_impl="kernel").compile()
-        kernel = "paged_prefill_attn"
+    cfg, _params, pool = zaya_serving
+    compiled = step_program("zaya", program)
+    kernel = _attn_kernel(program)
     text = compiled.as_text()
     assert re.search(rf"%\w*{kernel}[\w.]* = [^\n]*custom-call\(", text)
     # gate, up and down: three grouped matmuls over the WHOLE stack of
@@ -595,8 +639,7 @@ def laguna_serving(chip):
     from ray_tpu.models import laguna
 
     cfg = laguna.LagunaConfig(n_layers=5, n_experts=128, vocab_size=50176)
-    params = {name: chip(spec["shape"], jnp.bfloat16)
-              for name, spec in laguna.param_specs(cfg).items()}
+    params = _stacks(chip, laguna, cfg)
     pool = jax.tree.map(
         lambda x: chip(x.shape, x.dtype),
         jax.eval_shape(lambda: laguna.init_paged_kv(
@@ -612,8 +655,8 @@ def laguna_serving(chip):
 
 
 @pytest.mark.parametrize("program", ONE_WIDTH_PROGRAMS)
-def test_laguna_program_fits_and_moves_no_expert_layer(chip, laguna_serving,
-                                                       program):
+def test_laguna_program_fits_and_moves_no_expert_layer(laguna_serving,
+                                                       step_program, program):
     """The laguna family's step programs (decode, and the chunk program
     at both of the engine's heights), compiled whole at the
     cell's size: all four attention calls and the experts' grouped
@@ -625,24 +668,10 @@ def test_laguna_program_fits_and_moves_no_expert_layer(chip, laguna_serving,
     under the rule's size); the donated pool (pages, rings, counters)
     is updated in place; weights + pool + what the program needs
     besides stay under the chip's 16 GB."""
-    from ray_tpu.models import laguna
-
-    cfg, params, pool = laguna_serving
+    cfg, _params, pool = laguna_serving
     assert pool["ring_rows"].shape == (G_SLOTS + 1, G_RING)
-    i32 = lambda *shape: chip(shape, jnp.int32)
-    if program == "decode":
-        key = jax.eval_shape(lambda: jax.random.key(0))
-        compiled = laguna._decode_sample_paged.lower(
-            cfg, params, i32(G_SLOTS), pool, i32(G_SLOTS),
-            i32(G_SLOTS, 64), chip((G_SLOTS,), jnp.float32),
-            chip(key.shape, key.dtype), attn_impl="kernel").compile()
-        kernel = "paged_decode_attn"
-    else:
-        n = int(program.split("-")[1])
-        compiled = laguna.prefill_chunk_paged.lower(
-            cfg, params, i32(n, C), pool, i32(n, 64), i32(n), i32(n),
-            slots=i32(n), return_logits=True, attn_impl="kernel").compile()
-        kernel = "paged_prefill_attn"
+    compiled = step_program("laguna", program)
+    kernel = _attn_kernel(program)
     text = compiled.as_text()
     calls = lambda name: len(re.findall(
         rf"%\w*{name}[\w.]* = [^\n]*custom-call\(", text))
@@ -673,16 +702,16 @@ Q_SLOTS, Q_PAGES = 128, 8192
 @pytest.fixture(scope="module")
 def qwen3_next_serving(chip):
     """(cfg, params, pool) of the qwen3-next cell as shapes on one
-    described chip, with the three backend questions steered to the
-    chip's answers."""
+    described chip, the weights as the engine serves them (the seam's
+    `lay_out`: a dense plane is a leaf a layer), with the three backend
+    questions steered to the chip's answers."""
     import importlib
 
     from ray_tpu.models import qwen3_next
 
     cfg = qwen3_next.Qwen3NextConfig(n_layers=8, n_experts=128,
                                      vocab_size=37984)
-    params = {name: chip(spec["shape"], jnp.bfloat16)
-              for name, spec in qwen3_next.param_specs(cfg).items()}
+    params = _served(chip, cfg, _stacks(chip, qwen3_next, cfg))
     pool = jax.tree.map(
         lambda x: chip(x.shape, x.dtype),
         jax.eval_shape(lambda: qwen3_next.init_paged_kv(
@@ -699,8 +728,8 @@ def qwen3_next_serving(chip):
 
 
 @pytest.mark.parametrize("program", ONE_WIDTH_PROGRAMS)
-def test_qwen3_next_program_fits_and_moves_no_state(chip, qwen3_next_serving,
-                                                    program):
+def test_qwen3_next_program_fits_and_moves_no_state(qwen3_next_serving,
+                                                    step_program, program):
     """The qwen3_next family's step programs (decode, and the chunk
     program at both of the engine's heights), compiled whole at the
     cell's size: the full layers' attention calls at head size 256, the
@@ -711,25 +740,13 @@ def test_qwen3_next_program_fits_and_moves_no_state(chip, qwen3_next_serving,
     out or put back; the donated pool (pages, state, tails, counters) is
     updated in place; weights + pool + what the program needs besides
     are 11-12 GB of the chip's 16."""
-    from ray_tpu.models import qwen3_next
-
-    cfg, params, pool = qwen3_next_serving
+    cfg, _params, pool = qwen3_next_serving
     assert pool["gdn_state"].shape == (6, Q_SLOTS + 1, 32, 128, 128)
     assert pool["gdn_conv"].shape == (6, Q_SLOTS + 1, 3, 8192)
-    i32 = lambda *shape: chip(shape, jnp.int32)
+    compiled = step_program("qwen3_next", program)
+    kernels = {_attn_kernel(program): 2}
     if program == "decode":
-        key = jax.eval_shape(lambda: jax.random.key(0))
-        compiled = qwen3_next._decode_sample_paged.lower(
-            cfg, params, i32(Q_SLOTS), pool, i32(Q_SLOTS),
-            i32(Q_SLOTS, 64), chip((Q_SLOTS,), jnp.float32),
-            chip(key.shape, key.dtype), attn_impl="kernel").compile()
-        kernels = {"paged_decode_attn": 2, "gdn_decode_step": 6}
-    else:
-        n = int(program.split("-")[1])
-        compiled = qwen3_next.prefill_chunk_paged.lower(
-            cfg, params, i32(n, C), pool, i32(n, 64), i32(n), i32(n),
-            slots=i32(n), return_logits=True, attn_impl="kernel").compile()
-        kernels = {"paged_prefill_attn": 2}
+        kernels["gdn_decode_step"] = 6
     text = compiled.as_text()
     for name, n in kernels.items():
         assert len(re.findall(rf"%\w*{name}[\w.]* = [^\n]*custom-call\(",
@@ -751,6 +768,54 @@ def test_qwen3_next_program_fits_and_moves_no_state(chip, qwen3_next_serving,
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert 11.0e9 < total < 12.5e9
+
+
+_PLANE = 2**21       # elements: the least a dense weight plane holds here
+_SHAPE = re.compile(r"(\w+)\[([\d,]*)\]\{([^}]*)\}")
+_WEIGHT_PASS = re.compile(r"\s(?:fusion|copy)\(([^)]*%params__[^)]*)\)")
+
+
+def _weight_planes_written_to_hbm(text):
+    """{weight parameter: bytes} over the entry computation's `fusion`
+    and `copy` instructions that read a weight parameter and have a bf16
+    result of a plane's size outside the chip's fast memory (`S(1)` in
+    the result's layout): a plane copied out of its stack and written
+    back to HBM, which its matmul then reads a second time. (A prefetch
+    is a `copy-start` whose `copy-done` lands in `S(1)`; a matmul fusion
+    that slices its plane itself has an activation for a result.)"""
+    written = {}
+    for line in text[text.index("\nENTRY "):].splitlines():
+        hit = _WEIGHT_PASS.search(line)
+        if not hit:
+            continue
+        result = line[:hit.start()].partition(" = ")[2]
+        elems = [int(np.prod([int(d) for d in dims.split(",")]))
+                 for dtype, dims, layout in _SHAPE.findall(result)
+                 if dtype == "bf16" and dims and "S(1)" not in layout]
+        nbytes = 2 * sum(n for n in elems if n >= _PLANE)
+        if nbytes:
+            name = re.search(r"%params__(\w+?)__", hit.group(1)).group(1)
+            written[name] = written.get(name, 0) + nbytes
+    return written
+
+
+@pytest.mark.parametrize("program", ONE_WIDTH_PROGRAMS)
+def test_qwen3_next_reads_a_weight_plane_once(step_program, program):
+    """With the tree the seam's `lay_out` returns (a dense plane a leaf a
+    layer), no step program of the qwen3_next cell copies a weight plane
+    out of its parameter into HBM, and its scratch is what the layer
+    walk needs. Handed the stacks, the same program text starts with ONE
+    fusion that slices all six `g_qkvz` planes out (302 MB read) and
+    writes five back to HBM (251.7 MB), for each layer's matmul to read
+    again: the rule is shown to see it."""
+    laid_out = step_program("qwen3_next", program)
+    assert _weight_planes_written_to_hbm(laid_out.as_text()) == {}
+    temp = laid_out.memory_analysis().temp_size_in_bytes
+    assert temp < (400e6 if program == "prefill-8" else 150e6)
+    stacked = step_program("qwen3_next", program, stacks=True)
+    written = _weight_planes_written_to_hbm(stacked.as_text())
+    assert written["g_qkvz"] == 5 * 2048 * 12288 * 2
+    assert stacked.memory_analysis().temp_size_in_bytes > temp + 200e6
 
 
 # MiMo-V2-Flash's serving shapes: 64 query heads of 192 over 4 KV heads
@@ -866,8 +931,7 @@ def mimo_v2_serving(chip):
     from ray_tpu.models import mimo_v2
 
     cfg = mimo_v2.MiMoV2Config(n_layers=7, n_experts=16, vocab_size=19072)
-    params = {name: chip(spec["shape"], jnp.bfloat16)
-              for name, spec in mimo_v2.param_specs(cfg).items()}
+    params = _stacks(chip, mimo_v2, cfg)
     pool = jax.tree.map(
         lambda x: chip(x.shape, x.dtype),
         jax.eval_shape(lambda: mimo_v2.init_paged_kv(
@@ -883,8 +947,8 @@ def mimo_v2_serving(chip):
 
 
 @pytest.mark.parametrize("program", ONE_WIDTH_PROGRAMS)
-def test_mimo_v2_program_fits_and_moves_no_expert_layer(chip,
-                                                        mimo_v2_serving,
+def test_mimo_v2_program_fits_and_moves_no_expert_layer(mimo_v2_serving,
+                                                        step_program,
                                                         program):
     """The mimo_v2 family's step programs (decode, and the chunk program
     at both of the engine's heights), compiled whole at the cell's size:
@@ -896,26 +960,12 @@ def test_mimo_v2_program_fits_and_moves_no_expert_layer(chip,
     planes of four widths, ring rows, six counters) is updated in place;
     weights + pool + what the program needs besides stay under the
     chip's 16 GB."""
-    from ray_tpu.models import mimo_v2
-
-    cfg, params, pool = mimo_v2_serving
+    cfg, _params, pool = mimo_v2_serving
     assert pool["ring_rows"].shape == (M_SLOTS + 1, M_RING)
     assert [pool[n].shape[3] for n in ("k", "v", "k_win", "v_win")] == [
         768, 512, 1536, 1024]
-    i32 = lambda *shape: chip(shape, jnp.int32)
-    if program == "decode":
-        key = jax.eval_shape(lambda: jax.random.key(0))
-        compiled = mimo_v2._decode_sample_paged.lower(
-            cfg, params, i32(M_SLOTS), pool, i32(M_SLOTS),
-            i32(M_SLOTS, 96), chip((M_SLOTS,), jnp.float32),
-            chip(key.shape, key.dtype), attn_impl="kernel").compile()
-        kernel = "paged_decode_attn"
-    else:
-        n = int(program.split("-")[1])
-        compiled = mimo_v2.prefill_chunk_paged.lower(
-            cfg, params, i32(n, C), pool, i32(n, 96), i32(n), i32(n),
-            slots=i32(n), return_logits=True, attn_impl="kernel").compile()
-        kernel = "paged_prefill_attn"
+    compiled = step_program("mimo_v2", program)
+    kernel = _attn_kernel(program)
     text = compiled.as_text()
     calls = lambda name: len(re.findall(
         rf"%\w*{name}[\w.]* = [^\n]*custom-call\(", text))
@@ -940,3 +990,28 @@ def test_mimo_v2_program_fits_and_moves_no_expert_layer(chip,
           f"{mem.argument_size_in_bytes / 1e9:.3f} GB, temp "
           f"{mem.temp_size_in_bytes / 1e9:.3f} GB, total {total / 1e9:.3f} GB")
     assert 14.5e9 < total < 15.8e9
+
+
+# What a family's decode program needs beyond its arguments, read here at
+# its cell's size (MB: gpt 0.5, zaya 5.3, mimo_v2 22.5, laguna 43.4,
+# qwen3_next 111.8 laid out), with about twice the room: all under one
+# dense plane of theirs but qwen3_next's, whose own is the layer walk's.
+_DECODE_SCRATCH = {"gpt": 1e6, "zaya": 11e6, "mimo_v2": 45e6,
+                   "laguna": 87e6, "qwen3_next": 224e6}
+
+
+@pytest.mark.parametrize("family", sorted(_DECODE_SCRATCH))
+def test_decode_scratch_holds_no_second_copy_of_a_weight_plane(step_program,
+                                                               family):
+    """Every family's decode program, compiled at its cell's size with
+    the tree its engine serves: its scratch stays under the family's
+    bound, so a family (or a compiler) that starts slicing the planes of
+    a stack out ahead of their matmuls, as qwen3_next's stacks do
+    (354.6 MB: five `g_qkvz` planes written back to HBM, 3 % of the
+    cell's step), fails here and not on the chip, unseen."""
+    mem = step_program(family, "decode").memory_analysis()
+    assert mem.temp_size_in_bytes < _DECODE_SCRATCH[family]
+    if family == "qwen3_next":
+        stacked = step_program(family, "decode", stacks=True)
+        assert (stacked.memory_analysis().temp_size_in_bytes
+                > _DECODE_SCRATCH[family])

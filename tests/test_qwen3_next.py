@@ -247,6 +247,41 @@ def test_paged_programs_match_the_reference_in_logits(params, attn_impl):
                                atol=ATOL_F32, rtol=0)
 
 
+def test_the_laid_out_tree_serves_the_stacks_logits_bit_for_bit(params):
+    """`lay_out` changes where a plane lives, not what is computed: the
+    chunk programs (with the head and without) and the decode step over
+    a leaf a layer give the logits of the stacks, every bit."""
+    with jax.default_matmul_precision("highest"):
+        want, _other, want_b = _serve_logits(Pager(CFG, params), PROMPT,
+                                             FOLLOW)
+        got, _other, got_b = _serve_logits(
+            Pager(CFG, qn.lay_out(CFG, params)), PROMPT, FOLLOW)
+    assert np.array_equal(got, want) and np.array_equal(got_b, want_b)
+
+
+def test_lay_out_cuts_the_dense_planes_and_nothing_else(params):
+    """A tuple of per-layer arrays for every dense matrix a layer (each
+    a stack of matrices with a side of d_model), the stack's own rows;
+    the experts' stacks, the convolution's taps and every vector come
+    back as they went in; laid out twice is laid out once."""
+    out = qn.lay_out(CFG, params)
+    assert out.keys() == params.keys()
+    planes = {name for name, a in params.items()
+              if a.ndim == 3 and CFG.d_model in a.shape[1:]}
+    assert planes == set(qn._PLANE_KEYS)
+    for name, a in params.items():
+        if name in planes:
+            assert isinstance(out[name], tuple) and len(out[name]) == len(a)
+            assert all(np.array_equal(leaf, a[i])
+                       for i, leaf in enumerate(out[name]))
+        else:
+            assert out[name] is a
+    again = qn.lay_out(CFG, out)
+    assert all(again[name] is out[name] for name in out)
+    nbytes = lambda tree: sum(int(a.nbytes) for a in jax.tree.leaves(tree))
+    assert nbytes(out) == nbytes(params)
+
+
 def test_the_models_own_start_serves_the_references_logits():
     params = _params(wide=False)
     with jax.default_matmul_precision("highest"):
@@ -457,6 +492,18 @@ def test_engine_serves_the_references_tokens_and_counts(params):
     assert 1.0 <= m["moe_experts_touched"] <= CFG.n_experts
 
 
+def test_the_engine_holds_the_laid_out_tree(params):
+    """The engine applies the family's `lay_out` once at load and keeps
+    the result only: tuples where the programs index a plane, the
+    operator's weight account unchanged, the caller's tree untouched."""
+    eng = _engine(params)
+    for name, a in params.items():
+        assert isinstance(eng.params[name], tuple) == (name in qn._PLANE_KEYS)
+        assert not isinstance(a, tuple)
+    assert eng.metrics()["weight_bytes"] == sum(
+        int(a.nbytes) for a in params.values())
+
+
 def test_engine_recomputes_a_preempted_request_to_the_same_tokens(params):
     """A pool too small for both requests: one is evicted by recompute
     and re-prefilled from offset 0 into the slot it had used (zeros, not
@@ -513,3 +560,14 @@ def test_the_family_is_found_by_its_configuration():
     assert fam.name == "qwen3_next" and fam.init_pool is qn.init_paged_kv
     assert fam.slot_state == ("gdn_state", "gdn_conv")
     assert fam.expert_counters and not fam.slot_ring
+    assert fam.lay_out is qn.lay_out
+
+
+@pytest.mark.parametrize("name", ["gpt", "zaya", "laguna", "mimo_v2"])
+def test_no_other_family_lays_its_weights_out(name):
+    """Their decode programs hold no hoisted slice pass
+    (tests/test_chip_compile.py): their trees reach the programs as
+    they came."""
+    from ray_tpu.models import serving
+
+    assert serving._family(name).lay_out is None
