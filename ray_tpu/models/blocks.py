@@ -1,0 +1,139 @@
+"""Parts that two or more served families are built from.
+
+A family module (models/zaya.py, laguna.py, qwen3_next.py, mimo_v2.py)
+writes what is its own: the configuration, the attention inputs, the
+router, the ropes, the pool. What several of them compute the same way
+lives here under public names, so that no family imports a sibling to get
+it: the imports of `ray_tpu/models/` point from a family to this module
+and to the program builder (models/paged_kv.py `paged_programs`), never
+across. (models/mimo_v2.py imports the module models/laguna.py: it IS
+laguna's paged walk and ring with other parts, which is kinship, not
+borrowing.)
+
+Nothing here looks at a configuration class: a function takes the few
+fields it reads (`cfg.dtype`, `cfg.norm_eps`, `cfg.top_k`) off whatever
+it is handed.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.ops import scopes
+
+_F32 = jnp.float32
+
+
+# ------------------------------------------------------------------ norms
+
+def _unit_rms(x, eps):
+    x32 = x.astype(_F32)
+    return x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True)
+                               + eps)
+
+
+def rms_norm(x, scale, eps):
+    """RMSNorm, float32 inside; back to x's type."""
+    return (_unit_rms(x, eps) * scale.astype(_F32)).astype(x.dtype)
+
+
+def rms_norm_centred(x, w, eps):
+    """Zero-centred RMSNorm (a weight of 0 is a scale of 1), float32
+    inside; back to x's type."""
+    return (_unit_rms(x, eps) * (1.0 + w.astype(_F32))).astype(x.dtype)
+
+
+# -------------------------------------------------------------------- MLP
+
+def gated_mlp(u, w_gate, w_up, w_down):
+    """W_down(silu(W_gate u) * W_up u), accumulated to float32."""
+    dt = u.dtype
+    gate = jnp.matmul(u, w_gate.astype(dt), preferred_element_type=_F32)
+    up = jnp.matmul(u, w_up.astype(dt), preferred_element_type=_F32)
+    return jnp.matmul((jax.nn.silu(gate) * up).astype(dt), w_down.astype(dt),
+                      preferred_element_type=_F32)
+
+
+# ------------------------------------------------------------------ heads
+
+@jax.named_scope(scopes.HEAD)
+def tied_head(norm, cfg, params, x):
+    """Final `norm` and the embedding as the head → float32 logits
+    [..., V]."""
+    h = norm(x, params["ln_f_scale"], cfg.norm_eps)
+    return jnp.einsum("...d,vd->...v", h, params["wte"].astype(cfg.dtype),
+                      preferred_element_type=_F32)
+
+
+@jax.named_scope(scopes.HEAD)
+def untied_head(norm, cfg, params, x):
+    """Final `norm` and the untied head → float32 logits [..., V]."""
+    h = norm(x, params["ln_f_scale"], cfg.norm_eps)
+    return jnp.matmul(h, params["lm_head"].astype(cfg.dtype),
+                      preferred_element_type=_F32)
+
+
+def last_token_logits(head):
+    """`head(cfg, params, x)` as the program builder's `chunk_logits`:
+    run on each chunk row's last valid hidden state only ([N, C, V] at a
+    262k vocabulary is not a tensor to make; an inert row clamps to
+    token 0, garbage the engine ignores)."""
+
+    def chunk_logits(cfg, params, x, n_valid):
+        with jax.named_scope(scopes.HEAD):
+            last = jnp.take_along_axis(
+                x, jnp.maximum(n_valid - 1, 0)[:, None, None], axis=1)[:, 0]
+        return head(cfg, params, last)
+
+    return chunk_logits
+
+
+# --------------------------------------------------------- the paged pool
+
+def attend_fn(attn_impl: str, chunk: bool):
+    """The pool reader of a chunk row or of a decode step. "kernel": the
+    Pallas ragged paged-attention kernel, which reads K/V pages in place
+    from the pool at (layer, page); no [B, T, H, K] timeline ever hits
+    HBM. "gather": the reference that reconstitutes the contiguous
+    timeline: ONE implementation shared with the kernel's test oracle,
+    so engine-gather and oracle can never diverge."""
+    from ray_tpu.ops.paged_attention import (
+        paged_attention, paged_prefill_attention, reference_paged_attention,
+        reference_paged_prefill_attention)
+
+    if attn_impl not in ("gather", "kernel"):
+        raise ValueError(
+            f"attn_impl must be gather|kernel, got {attn_impl!r}")
+    kernel, oracle = ((paged_prefill_attention,
+                       reference_paged_prefill_attention) if chunk else
+                      (paged_attention, reference_paged_attention))
+    return kernel if attn_impl == "kernel" else oracle
+
+
+@jax.named_scope(scopes.ATTN_KV_WRITE)
+def write_kv(pool, l, pages, offs, k, v, planes=("k", "v")):
+    """K/V [.., G, K] as rows [M, G*K] → (l, pages[m], offs[m]) of the
+    carried pool's K and V `planes`."""
+    rows = lambda t: t.reshape(-1, t.shape[-2] * t.shape[-1])
+    kn, vn = planes
+    return {**pool, kn: pool[kn].at[l, pages, offs].set(rows(k)),
+            vn: pool[vn].at[l, pages, offs].set(rows(v))}
+
+
+# Running totals over decode steps in `pool["moe_counters"]`, wrapping
+# uint32 (the host takes differences): (sparse layer, step) pairs, held
+# experts that had a row, the fullest held expert's rows, choices routed
+# (k a live row), and the choices that landed on a held expert.
+COUNTERS = ("layer_steps", "experts_touched", "rows_max", "rows_routed",
+            "rows_held")
+
+
+def counter_row(cfg, counts, n_live):
+    """What one sparse layer of one decode step adds to `COUNTERS`:
+    `counts` [n_experts] the rows each held expert received, `n_live` the
+    rows that carried a token."""
+    return jnp.stack([jnp.uint32(1), jnp.sum(counts > 0).astype(jnp.uint32),
+                      jnp.max(counts).astype(jnp.uint32),
+                      (n_live * cfg.top_k).astype(jnp.uint32),
+                      jnp.sum(counts).astype(jnp.uint32)])

@@ -53,9 +53,10 @@ attends, and two rows of a dispatch may be consecutive chunks of one
 prompt, so the later row's pages must not land on those the earlier row
 still reads.
 
-The paged programs carry the names models/paged_kv.py gives its own (a
-trace finds a program by name), take the pool donated, and reuse
-paged_kv's sampling and decode window. Five or so layers of three
+The four paged programs are `paged_kv.paged_programs` over
+`chunk_forward` and `decode_once` (names, donation and the decode window
+are its); models/mimo_v2.py builds its own over the same two with another
+walk (`layers=`) and counter row (`count=`). Five or so layers of three
 different shapes are walked in Python, each kind indexing its own stack;
 the experts' stack goes to the grouped matmul whole (`layer=`).
 """
@@ -72,8 +73,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.ops import scopes
-from ray_tpu.models.paged_kv import _decode_window, _no_phase, _sample_next
-from ray_tpu.models.zaya import _attend_fn, _rms_norm
+# The pool reader's choice as a global of THIS module, which the walk
+# below reads at trace time: benchmarks/tools/probe_laguna.py and
+# probe_mimo_v2.py put their window faults in by rebinding it.
+from ray_tpu.models.blocks import attend_fn as _attend_fn
+from ray_tpu.models.blocks import (COUNTERS, counter_row, gated_mlp,
+                                   last_token_logits, rms_norm, untied_head,
+                                   write_kv)
+from ray_tpu.models.paged_kv import paged_programs
 from ray_tpu.ops.moe import token_choice_experts
 
 _F32 = jnp.float32
@@ -271,7 +278,7 @@ def _attn_inputs(cfg: LagunaConfig, params, l: int, x, pos):
     kind, i, _mlp, _j = cfg.index(l)
     H, G, K, dt = cfg.heads(kind), cfg.n_kv_heads, cfg.head_dim, cfg.dtype
     w = lambda name: params[kind[0] + "_" + name][i].astype(dt)
-    u = _rms_norm(x, params["ln1_scale"][l], cfg.norm_eps)
+    u = rms_norm(x, params["ln1_scale"][l], cfg.norm_eps)
     inv_freq, factor = _rope_of(cfg, kind)
     rope = lambda t, h: _rope(t.reshape(N, C, h, K).astype(_F32), pos,
                               inv_freq, factor).astype(dt)
@@ -279,15 +286,6 @@ def _attn_inputs(cfg: LagunaConfig, params, l: int, x, pos):
     v = (u @ w("wv")).reshape(N, C, G, K)
     gate = jax.nn.sigmoid((u @ w("wg")).astype(_F32))
     return q, k, v, gate
-
-
-def _gated_mlp(u, w_gate, w_up, w_down):
-    """W_down(silu(W_gate u) * W_up u), accumulated to float32."""
-    dt = u.dtype
-    gate = jnp.matmul(u, w_gate.astype(dt), preferred_element_type=_F32)
-    up = jnp.matmul(u, w_up.astype(dt), preferred_element_type=_F32)
-    return jnp.matmul((jax.nn.silu(gate) * up).astype(dt), w_down.astype(dt),
-                      preferred_element_type=_F32)
 
 
 @jax.named_scope(scopes.MOE_ROUTE)
@@ -315,11 +313,11 @@ def _finish_block(cfg: LagunaConfig, params, l: int, x, attn, gate, valid):
         o = (attn.astype(_F32) * gate[..., None]).astype(dt)
         x = x + o.reshape(N, C, -1) @ params[kind[0] + "_wo"][i].astype(dt)
     with jax.named_scope(scopes.MLP):
-        u = _rms_norm(x, params["ln2_scale"][l],
-                      cfg.norm_eps).reshape(N * C, D)
+        u = rms_norm(x, params["ln2_scale"][l],
+                     cfg.norm_eps).reshape(N * C, D)
         if mlp == "dense":
-            f = _gated_mlp(u, params["d_gate"][j], params["d_up"][j],
-                           params["d_down"][j])
+            f = gated_mlp(u, params["d_gate"][j], params["d_up"][j],
+                          params["d_down"][j])
             return x + f.astype(dt).reshape(N, C, D), None
     chosen, gates = _route(cfg, params["router"][j], u)
     with jax.named_scope(scopes.MOE_EXPERTS):
@@ -328,18 +326,13 @@ def _finish_block(cfg: LagunaConfig, params, l: int, x, attn, gate, valid):
         u, chosen, gates, *experts,
         first_expert=cfg.first_expert, layer=j, valid=valid.reshape(-1))
     with jax.named_scope(scopes.MLP):
-        shared = _gated_mlp(u, params["s_gate"][j], params["s_up"][j],
-                            params["s_down"][j])
+        shared = gated_mlp(u, params["s_gate"][j], params["s_up"][j],
+                           params["s_down"][j])
         f = (shared + routed.astype(_F32)).astype(dt)
         return x + f.reshape(N, C, D), counts
 
 
-@jax.named_scope(scopes.HEAD)
-def _head(cfg: LagunaConfig, params, x):
-    """Final RMSNorm and the untied head → float32 logits [..., V]."""
-    h = _rms_norm(x, params["ln_f_scale"], cfg.norm_eps)
-    return jnp.matmul(h, params["lm_head"].astype(cfg.dtype),
-                      preferred_element_type=_F32)
+_head = functools.partial(untied_head, rms_norm)
 
 
 # ------------------------------------------ full sequence (tests, no cache)
@@ -380,14 +373,6 @@ def ring_pages(window: int, page_size: int, dispatch_tokens: int) -> int:
     return -(-window // page_size) + -(-dispatch_tokens // page_size) + 1
 
 
-# Running totals over decode steps, wrapping uint32 (the host takes
-# differences): (sparse layer, step) pairs, held experts that had a row,
-# the fullest held expert's rows, choices routed (k a live row), and the
-# choices that landed on a held expert.
-_COUNTERS = ("layer_steps", "experts_touched", "rows_max", "rows_routed",
-             "rows_held")
-
-
 def ring_pool(cfg, n_pages: int, page_size: int, n_slots: int,
               dispatch_tokens: int, lanes: dict, n_counters: int):
     """The pool pytree of a family with full and window layers, donated
@@ -405,7 +390,7 @@ def ring_pool(cfg, n_pages: int, page_size: int, n_slots: int,
             "window": (cfg.count("window"), (n_slots + 1) * R)}
     planes = {name: jnp.zeros(rows[kind] + (page_size, lanes[name]),
                               cfg.dtype)
-              for kind, names in _PLANES.items() for name in names}
+              for kind, names in PLANES.items() for name in names}
     return {**planes,
             "ring_rows": jnp.arange((n_slots + 1) * R, dtype=jnp.int32
                                     ).reshape(n_slots + 1, R),
@@ -416,13 +401,13 @@ def init_paged_kv(cfg: LagunaConfig, n_pages: int, page_size: int,
                   n_slots: int, kv_dtype: str | None = None, *,
                   dispatch_tokens: int):
     """`ring_pool` at this family's widths: every plane G*K lanes, the
-    counters `_COUNTERS`."""
+    counters `blocks.COUNTERS`."""
     if kv_dtype not in (None, "bf16"):
         raise ValueError(f"the laguna family's pool is bf16, got {kv_dtype!r}")
     GK = cfg.n_kv_heads * cfg.head_dim
     return ring_pool(cfg, n_pages, page_size, n_slots, dispatch_tokens,
                      dict.fromkeys(("k", "v", "k_win", "v_win"), GK),
-                     len(_COUNTERS))
+                     len(COUNTERS))
 
 
 @jax.named_scope(scopes.ATTN_KERNEL)
@@ -446,16 +431,7 @@ def _ring_targets(pool, table, pos, live, page_size: int):
 
 
 # The pool's K and V planes of each cache kind.
-_PLANES = {"full": ("k", "v"), "window": ("k_win", "v_win")}
-
-
-@jax.named_scope(scopes.ATTN_KV_WRITE)
-def _write_kv(pool, kind: str, i: int, pages, offs, k, v):
-    """K/V rows [M, G*K] → (i, pages[m], offs[m]) of a kind's planes."""
-    rows = lambda t: t.reshape(-1, t.shape[-2] * t.shape[-1])
-    kn, vn = _PLANES[kind]
-    return {**pool, kn: pool[kn].at[i, pages, offs].set(rows(k)),
-            vn: pool[vn].at[i, pages, offs].set(rows(v))}
+PLANES = {"full": ("k", "v"), "window": ("k_win", "v_win")}
 
 
 def _paged_layers(cfg: LagunaConfig, params, x, pos, valid, pool, attend,
@@ -472,8 +448,8 @@ def _paged_layers(cfg: LagunaConfig, params, x, pos, valid, pool, attend,
         i = cfg.index(l)[1]
         pages, table, kw = full if kind == "full" else ring
         q, k, v, gate = _attn_inputs(cfg, params, l, x, pos)
-        pool = _write_kv(pool, kind, i, pages, offs, k, v)
-        kn, vn = _PLANES[kind]
+        kn, vn = PLANES[kind]
+        pool = write_kv(pool, i, pages, offs, k, v, (kn, vn))
         with jax.named_scope(scopes.ATTN_KERNEL):
             attn = attend(q, pool[kn], pool[vn], i, table, **kw)
         x, n = _finish_block(cfg, params, l, x, attn, gate, valid)
@@ -482,8 +458,8 @@ def _paged_layers(cfg: LagunaConfig, params, x, pos, valid, pool, attend,
     return x, pool, counts
 
 
-def _chunk_forward(cfg, params, tokens, pool, tables, offsets,
-                   n_valid, slots, attn_impl: str, layers=_paged_layers):
+def chunk_forward(cfg, params, tokens, pool, tables, offsets, n_valid,
+                  slots, attn_impl: str, layers=_paged_layers):
     """N chunk rows written into their slots' pages and rings, each at
     its own offset. Every row's K/V is written before any row attends
     (a row may continue the row above it), which is why a ring holds a
@@ -522,37 +498,8 @@ def _chunk_forward(cfg, params, tokens, pool, tables, offsets,
     return x, pool
 
 
-@functools.partial(jax.jit, static_argnums=(0,),
-                   static_argnames=("return_logits", "attn_impl"),
-                   donate_argnums=(3,))
-def prefill_chunk_paged(cfg: LagunaConfig, params, tokens, pool, tables,
-                        offsets, n_valid, *, slots,
-                        return_logits: bool = True,
-                        attn_impl: str = "gather"):
-    """models/paged_kv.prefill_chunk_paged for this block, with `slots`
-    [N] int32: the slot each row belongs to (an inert row's is ignored).
-    → (last-valid-token logits [N, V] fp32 if return_logits else None,
-    updated pool). The head runs on each row's last valid hidden state
-    only."""
-    x, pool = _chunk_forward(cfg, params, tokens, pool, tables, offsets,
-                             n_valid, slots, attn_impl)
-    if not return_logits:
-        return None, pool
-    with jax.named_scope(scopes.HEAD):
-        last = jnp.take_along_axis(
-            x, jnp.maximum(n_valid - 1, 0)[:, None, None], axis=1)[:, 0]
-    return _head(cfg, params, last), pool
-
-
-def _count(cfg: LagunaConfig, counts, n_live):
-    return jnp.stack([jnp.uint32(1), jnp.sum(counts > 0).astype(jnp.uint32),
-                      jnp.max(counts).astype(jnp.uint32),
-                      (n_live * cfg.top_k).astype(jnp.uint32),
-                      jnp.sum(counts).astype(jnp.uint32)])
-
-
-def _decode_once(cfg, params, tokens, pool, positions, tables,
-                 attn_impl: str, layers=_paged_layers, count=_count):
+def decode_once(cfg, params, tokens, pool, positions, tables,
+                attn_impl: str, layers=_paged_layers, count=counter_row):
     """All B slots advance one token: row b IS slot b. A row whose table
     is all null (an idle slot, or one still mid-prefill) writes the null
     page and the null slot's ring, reaches no expert and counts nowhere,
@@ -590,48 +537,9 @@ def _decode_once(cfg, params, tokens, pool, positions, tables,
     return _head(cfg, params, x[:, 0]), {**pool, "moe_counters": counters}
 
 
-@functools.partial(jax.jit, static_argnums=(0,),
-                   static_argnames=("attn_impl",), donate_argnums=(3,))
-def decode_step_paged(cfg: LagunaConfig, params, tokens, pool, positions,
-                      tables, *, attn_impl: str = "gather"):
-    """One token for every slot. → (logits [B, V] fp32, updated pool)."""
-    return _decode_once(cfg, params, tokens, pool, positions, tables,
-                        attn_impl)
-
-
-@functools.partial(jax.jit, static_argnums=(0,),
-                   static_argnames=("attn_impl",), donate_argnums=(3,))
-def _decode_sample_paged(cfg: LagunaConfig, params, tokens, pool, positions,
-                         tables, temps, key, *, attn_impl: str = "gather"):
-    """One decode-window step: `_decode_once` + on-device sampling."""
-    logits, pool = _decode_once(cfg, params, tokens, pool, positions, tables,
-                                attn_impl)
-    nxt, _scaled, key = _sample_next(logits, temps, key)
-    return nxt, positions + 1, pool, key
-
-
-def decode_multi_paged(cfg: LagunaConfig, params, tokens, pool, positions,
-                       tables, n_steps: int, temps, key, *,
-                       attn_impl: str = "gather", phase=_no_phase,
-                       counters=None, carried=None, ahead=None):
-    """models/paged_kv.decode_multi_paged for this block: the shared
-    `_decode_window` of this family's step program. `counters(dict)`
-    (optional) is handed the pool's running expert counters as they
-    stand after the window's `n_steps`, fetched WITH the window's tokens
-    (what the step `ahead` asks for counts arrives with the next
-    window's)."""
-
-    def step(toks, kv, pos, rng):
-        return _decode_sample_paged(cfg, params, toks, kv, pos, tables,
-                                    temps, rng, attn_impl=attn_impl)
-
-    toks_out, pool, totals = _decode_window(
-        step, tokens, pool, positions, n_steps, key, phase,
-        also=lambda pool: pool["moe_counters"], carried=carried,
-        ahead=ahead)
-    if counters is not None:
-        counters(dict(zip(_COUNTERS, (int(t) for t in totals))))
-    return toks_out, pool
+(prefill_chunk_paged, decode_step_paged, _decode_sample_paged,
+ decode_multi_paged) = paged_programs(
+    chunk_forward, decode_once, last_token_logits(_head), COUNTERS)
 
 
 __all__ = [

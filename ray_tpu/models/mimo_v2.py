@@ -60,11 +60,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.models import laguna
-from ray_tpu.models.laguna import (_PLANES, _gated_mlp, _head, _write_kv,
-                                   ring_pages)
-from ray_tpu.models.paged_kv import _decode_window, _no_phase, _sample_next
-from ray_tpu.models.zaya import _rms_norm
+from ray_tpu.models import blocks, laguna
+from ray_tpu.models.blocks import (gated_mlp, last_token_logits, rms_norm,
+                                   untied_head, write_kv)
+from ray_tpu.models.laguna import PLANES, ring_pages
+from ray_tpu.models.paged_kv import paged_programs
 from ray_tpu.ops import scopes
 from ray_tpu.ops.moe import token_choice_experts
 
@@ -232,7 +232,7 @@ def _attn_inputs(cfg: MiMoV2Config, params, l: int, x, pos):
     kind, i, _mlp, _j = cfg.index(l)
     H, G, dt = cfg.n_heads, cfg.kv_heads(kind), cfg.dtype
     w = lambda name: params[kind[0] + "_" + name][i].astype(dt)
-    u = _rms_norm(x, params["ln1_scale"][l], cfg.norm_eps)
+    u = rms_norm(x, params["ln1_scale"][l], cfg.norm_eps)
     inv_freq = _inv_freq(cfg, kind)
     rope = lambda t, h: _rope(t.astype(_F32), pos, inv_freq,
                               cfg.head_dim).astype(dt).reshape(
@@ -272,11 +272,11 @@ def _finish_block(cfg: MiMoV2Config, params, l: int, x, attn, valid):
     with jax.named_scope(scopes.ATTN_OUT):
         x = x + attn.reshape(N, C, -1) @ params[kind[0] + "_wo"][i].astype(dt)
     with jax.named_scope(scopes.MLP):
-        u = _rms_norm(x, params["ln2_scale"][l],
-                      cfg.norm_eps).reshape(N * C, D)
+        u = rms_norm(x, params["ln2_scale"][l],
+                     cfg.norm_eps).reshape(N * C, D)
         if mlp == "dense":
-            f = _gated_mlp(u, params["d_gate"][j], params["d_up"][j],
-                           params["d_down"][j])
+            f = gated_mlp(u, params["d_gate"][j], params["d_up"][j],
+                          params["d_down"][j])
             return x + f.astype(dt).reshape(N, C, D), None
     chosen, gates, moved = _route(cfg, params["router"][j],
                                   params["router_bias"][j], u)
@@ -289,6 +289,9 @@ def _finish_block(cfg: MiMoV2Config, params, l: int, x, attn, valid):
         moved = jnp.sum(jnp.where(valid.reshape(-1), moved, 0))
     with jax.named_scope(scopes.MLP):
         return x + routed.astype(dt).reshape(N, C, D), (counts, moved)
+
+
+_head = functools.partial(untied_head, rms_norm)
 
 
 # ------------------------------------------ full sequence (tests, no cache)
@@ -329,7 +332,7 @@ def forward(cfg: MiMoV2Config, params, tokens):
 
 # Running totals over decode steps, wrapping uint32 (the host takes
 # differences): laguna's five, and the choices the router's bias moved.
-_COUNTERS = laguna._COUNTERS + ("rows_bias_moved",)
+COUNTERS = blocks.COUNTERS + ("rows_bias_moved",)
 
 
 def init_paged_kv(cfg: MiMoV2Config, n_pages: int, page_size: int,
@@ -341,10 +344,10 @@ def init_paged_kv(cfg: MiMoV2Config, n_pages: int, page_size: int,
         raise ValueError(
             f"the mimo_v2 family's pool is bf16, got {kv_dtype!r}")
     lanes = {name: cfg.kv_heads(kind) * size
-             for kind, names in _PLANES.items()
+             for kind, names in PLANES.items()
              for name, size in zip(names, (cfg.head_dim, cfg.v_head_dim))}
     return laguna.ring_pool(cfg, n_pages, page_size, n_slots,
-                            dispatch_tokens, lanes, len(_COUNTERS))
+                            dispatch_tokens, lanes, len(COUNTERS))
 
 
 def _paged_layers(cfg: MiMoV2Config, params, x, pos, valid, pool, attend,
@@ -358,8 +361,8 @@ def _paged_layers(cfg: MiMoV2Config, params, x, pos, valid, pool, attend,
         i = cfg.index(l)[1]
         pages, table, kw = full if kind == "full" else ring
         q, k, v = _attn_inputs(cfg, params, l, x, pos)
-        pool = _write_kv(pool, kind, i, pages, offs, k, v)
-        kn, vn = _PLANES[kind]
+        kn, vn = PLANES[kind]
+        pool = write_kv(pool, i, pages, offs, k, v, (kn, vn))
         with jax.named_scope(scopes.ATTN_KERNEL):
             attn = attend(q, pool[kn], pool[vn], i, table,
                           sink=_sink(cfg, params, kind, i), **kw)
@@ -371,81 +374,16 @@ def _paged_layers(cfg: MiMoV2Config, params, x, pos, valid, pool, attend,
 
 def _count(cfg: MiMoV2Config, counted, n_live):
     counts, moved = counted
-    return jnp.concatenate([laguna._count(cfg, counts, n_live),
+    return jnp.concatenate([blocks.counter_row(cfg, counts, n_live),
                             moved.astype(jnp.uint32)[None]])
 
 
-@functools.partial(jax.jit, static_argnums=(0,),
-                   static_argnames=("return_logits", "attn_impl"),
-                   donate_argnums=(3,))
-def prefill_chunk_paged(cfg: MiMoV2Config, params, tokens, pool, tables,
-                        offsets, n_valid, *, slots,
-                        return_logits: bool = True,
-                        attn_impl: str = "gather"):
-    """models/paged_kv.prefill_chunk_paged for this block, with `slots`
-    [N] int32: the slot each row belongs to (an inert row's is ignored).
-    → (last-valid-token logits [N, V] fp32 if return_logits else None,
-    updated pool). The head runs on each row's last valid hidden state
-    only."""
-    x, pool = laguna._chunk_forward(cfg, params, tokens, pool, tables,
-                                    offsets, n_valid, slots, attn_impl,
-                                    layers=_paged_layers)
-    if not return_logits:
-        return None, pool
-    with jax.named_scope(scopes.HEAD):
-        last = jnp.take_along_axis(
-            x, jnp.maximum(n_valid - 1, 0)[:, None, None], axis=1)[:, 0]
-    return _head(cfg, params, last), pool
-
-
-def _decode_once(cfg: MiMoV2Config, params, tokens, pool, positions, tables,
-                 attn_impl: str):
-    return laguna._decode_once(cfg, params, tokens, pool, positions, tables,
-                               attn_impl, layers=_paged_layers, count=_count)
-
-
-@functools.partial(jax.jit, static_argnums=(0,),
-                   static_argnames=("attn_impl",), donate_argnums=(3,))
-def decode_step_paged(cfg: MiMoV2Config, params, tokens, pool, positions,
-                      tables, *, attn_impl: str = "gather"):
-    """One token for every slot. → (logits [B, V] fp32, updated pool)."""
-    return _decode_once(cfg, params, tokens, pool, positions, tables,
-                        attn_impl)
-
-
-@functools.partial(jax.jit, static_argnums=(0,),
-                   static_argnames=("attn_impl",), donate_argnums=(3,))
-def _decode_sample_paged(cfg: MiMoV2Config, params, tokens, pool, positions,
-                         tables, temps, key, *, attn_impl: str = "gather"):
-    """One decode-window step: `_decode_once` + on-device sampling."""
-    logits, pool = _decode_once(cfg, params, tokens, pool, positions, tables,
-                                attn_impl)
-    nxt, _scaled, key = _sample_next(logits, temps, key)
-    return nxt, positions + 1, pool, key
-
-
-def decode_multi_paged(cfg: MiMoV2Config, params, tokens, pool, positions,
-                       tables, n_steps: int, temps, key, *,
-                       attn_impl: str = "gather", phase=_no_phase,
-                       counters=None, carried=None, ahead=None):
-    """models/paged_kv.decode_multi_paged for this block: the shared
-    `_decode_window` of this family's step program. `counters(dict)`
-    (optional) is handed the pool's running counters (`_COUNTERS`) as
-    they stand after the window's `n_steps`, fetched WITH the window's
-    tokens (what the step `ahead` asks for counts arrives with the next
-    window's)."""
-
-    def step(toks, kv, pos, rng):
-        return _decode_sample_paged(cfg, params, toks, kv, pos, tables,
-                                    temps, rng, attn_impl=attn_impl)
-
-    toks_out, pool, totals = _decode_window(
-        step, tokens, pool, positions, n_steps, key, phase,
-        also=lambda pool: pool["moe_counters"], carried=carried,
-        ahead=ahead)
-    if counters is not None:
-        counters(dict(zip(_COUNTERS, (int(t) for t in totals))))
-    return toks_out, pool
+# laguna's two forwards over this block's walk and counter row.
+(prefill_chunk_paged, decode_step_paged, _decode_sample_paged,
+ decode_multi_paged) = paged_programs(
+    functools.partial(laguna.chunk_forward, layers=_paged_layers),
+    functools.partial(laguna.decode_once, layers=_paged_layers, count=_count),
+    last_token_logits(_head), COUNTERS)
 
 
 __all__ = [

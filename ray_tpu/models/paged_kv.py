@@ -22,7 +22,7 @@ XLA-first layout decisions:
   whole (90 % of a decode step, PERF.md PR 25). Now the chip's layout IS
   row-major with no padding, a page is ``page_size`` dense rows, and no
   op of a paged program moves more pool bytes than the pages it
-  touches: `_scan_pool_layers` carries the whole pool, writes land at
+  touches: `scan_pool_layers` carries the whole pool, writes land at
   ``(l, page, offset)`` and the kernels' block index is ``(l, page)``.
   One layout, no switch: nothing here looks at head_dim.
 - Page 0 is a reserved null page. Table entries that aren't allocated
@@ -65,6 +65,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.ops import scopes
+from ray_tpu.models.blocks import attend_fn
 from ray_tpu.models.gpt import (GPTConfig, _layer_norm, stack_block_params,
                                 weight_view)
 from ray_tpu.models.decode import _head, _mlp, _qkv, _rotary_pos
@@ -176,7 +177,7 @@ def _write_rows(pool, l, write_pages, write_offs, k_rows, v_rows,
                 v_rows.astype(dtype))}
 
 
-def _scan_pool_layers(body, x, stacked, pool):
+def scan_pool_layers(body, x, stacked, pool):
     """Scan ``body`` over the layer stack with the page pool in the scan
     CARRY, not as stacked xs/ys operands, and never sliced.
 
@@ -314,7 +315,7 @@ def prefill_batch_paged(cfg: GPTConfig, params, tokens, pool, pages, lengths):
                 "v": pool["v"].at[l, flat_pages].set(
                     paged(v.astype(cfg.dtype)))}
 
-    x, pool = _scan_pool_layers(body, x, stacked, pool)
+    x, pool = scan_pool_layers(body, x, stacked, pool)
     with jax.named_scope(scopes.HEAD):
         logits = _head(params, cfg, x)                     # [N, S, V]
         last = jnp.take_along_axis(
@@ -337,6 +338,7 @@ def _chunk_paged_forward(cfg: GPTConfig, params, tokens, pool, tables,
     a head-sharded params/pool slice) everything is shard-local except
     the attention-out and MLP-down partial sums, psum'd per layer.
     → (hidden states [N, C, D], updated pool)."""
+    attend = attend_fn(attn_impl, chunk=True)
     N, C = tokens.shape
     ps = pool["k"].shape[2]
     with jax.named_scope(scopes.EMBED):
@@ -369,15 +371,6 @@ def _chunk_paged_forward(cfg: GPTConfig, params, tokens, pool, tables,
         # parallelism this body sees the per-shard head slice.
         pool = _write_rows(pool, l, write_pages, write_offs,
                            _rows(k), _rows(v), tp_axis)
-        if attn_impl == "kernel":
-            from ray_tpu.ops.paged_attention import paged_prefill_attention
-
-            attend = paged_prefill_attention
-        else:
-            from ray_tpu.ops.paged_attention import (
-                reference_paged_prefill_attention)
-
-            attend = reference_paged_prefill_attention
         with jax.named_scope(scopes.ATTN_KERNEL):
             attn = attend(q, pool["k"], pool["v"], l, tables, offsets,
                           kv_lens, sm_scale=scale,
@@ -393,66 +386,8 @@ def _chunk_paged_forward(cfg: GPTConfig, params, tokens, pool, tables,
             x = _mlp(x, layer, cfg, tp_axis=tp_axis)
         return x, pool
 
-    x, pool = _scan_pool_layers(body, x, stacked, pool)
+    x, pool = scan_pool_layers(body, x, stacked, pool)
     return x, pool
-
-
-@functools.partial(jax.jit, static_argnums=(0,),
-                   static_argnames=("return_logits", "attn_impl"),
-                   donate_argnums=(3,))
-def prefill_chunk_paged(cfg: GPTConfig, params, tokens, pool, tables,
-                        offsets, n_valid, *, return_logits: bool = True,
-                        attn_impl: str = "gather"):
-    """Write N chunk rows into their prompts' KV pages, each at its own
-    arbitrary token offset (Sarathi/Orca-style chunked prefill; rows may
-    be consecutive chunks of one prompt or chunks of different ones).
-
-    The compile-count story for prefill: N and C are engine constants
-    (N = one of the engine's `chunk_heights`: bucketed by width, the
-    full chunks one step's token budget holds, at most n_slots;
-    C = the chunk size) and
-    `offsets`/`n_valid` are traced vectors,
-    so the table WIDTH is the only shape degree of freedom — one program
-    lowers per (table width, ``return_logits``) pair. The engine slices
-    tables to the pow-2 width each bucket of rows actually attends over
-    (`_pow2_width` of pages covering written prefix + chunk), so the
-    grid is the width ladder {1, 2, 4, …, max_pages}: at most
-    2·log₂(max_pages)+2 programs (``return_logits`` False for
-    interior-only batches, True when any row carries a final chunk,
-    which alone pays the LM head), replacing the one-shot path's
-    buckets × admission-ladder grid. Full-width tables remain valid (the
-    width-bucketing-off control arm dispatches exactly the PR 4
-    two-program grid); attention compute/bytes scale with the sliced
-    width, which is the whole point for interior chunks of long-max-len
-    prompts.
-
-    tokens: [N, C] (tail chunks padded); tables: [N, width]
-    page ids, width ≤ max_pages (pages covering positions
-    ``offsets[i] .. offsets[i]+n_valid[i]-1`` must be allocated and fall
-    inside the sliced width — the engine's bucket rule guarantees this);
-    offsets: [N] — absolute position of tokens[i, 0]; n_valid: [N] —
-    valid tokens in row i's chunk (0 = inert row: all writes land on the
-    null page and its logits row is garbage the engine ignores).
-
-    Queries attend causally over everything their slot has written so
-    far: each layer scatters the batch's K/V into its pages FIRST (pad /
-    inert rows land on the null page), then reads back through the page
-    tables — ``gather`` reconstitutes the contiguous timelines
-    (exact-semantics default), ``kernel`` runs the ragged prefill Pallas
-    kernel (ops/paged_attention.py) against the pool in place. Distinct
-    live slots never share a page, so rows are independent.
-
-    → (last-valid-token logits [N, V] fp32 if return_logits else None,
-    updated pool).
-    """
-    if attn_impl not in ("gather", "kernel"):
-        raise ValueError(
-            f"attn_impl must be gather|kernel, got {attn_impl!r}")
-    x, pool = _chunk_paged_forward(cfg, params, tokens, pool, tables,
-                                   offsets, n_valid, attn_impl)
-    if not return_logits:
-        return None, pool
-    return _last_valid_logits(cfg, params, x, n_valid), pool
 
 
 @functools.partial(jax.jit, static_argnums=(0,),
@@ -482,9 +417,6 @@ def verify_chunk_paged(cfg: GPTConfig, params, tokens, pool, tables,
 
     → (logits [N, C, V] fp32, updated pool).
     """
-    if attn_impl not in ("gather", "kernel"):
-        raise ValueError(
-            f"attn_impl must be gather|kernel, got {attn_impl!r}")
     x, pool = _chunk_paged_forward(cfg, params, tokens, pool, tables,
                                    offsets, n_valid, attn_impl)
     with jax.named_scope(scopes.HEAD):
@@ -512,9 +444,7 @@ def _decode_once_paged(cfg: GPTConfig, params, tokens, pool, positions,
     sums psum across shards.
     → (logits [B, V] fp32, updated pool).
     """
-    if attn_impl not in ("gather", "kernel"):
-        raise ValueError(
-            f"attn_impl must be gather|kernel, got {attn_impl!r}")
+    attend = attend_fn(attn_impl, chunk=False)
     ps = pool["k"].shape[2]
     with jax.named_scope(scopes.EMBED):
         x = params["wte"].astype(cfg.dtype)[tokens][:, None, :]  # [B, 1, D]
@@ -543,22 +473,6 @@ def _decode_once_paged(cfg: GPTConfig, params, tokens, pool, positions,
         q, k, v = _attn_in(cfg, layer, x, pos)
         pool = _write_rows(pool, l, write_page, write_off,
                            _rows(k), _rows(v), tp_axis)
-        if attn_impl == "kernel":
-            # Ragged paged attention: K/V pages are read in place from
-            # the pool at (l, page) (one DMA per live page,
-            # pl.when-skipped null tail); no [B, T, H, K] timeline ever
-            # hits HBM.
-            from ray_tpu.ops.paged_attention import paged_attention
-
-            attend = paged_attention
-        else:
-            # Gather reference: reconstitute the contiguous [B, T, H, K]
-            # timeline — ONE implementation shared with the kernel's test
-            # oracle so engine-gather and oracle can never diverge.
-            from ray_tpu.ops.paged_attention import (
-                reference_paged_attention)
-
-            attend = reference_paged_attention
         with jax.named_scope(scopes.ATTN_KERNEL):
             attn = attend(q[:, 0], pool["k"], pool["v"], l, tables,
                           kv_lengths, sm_scale=scale,
@@ -574,7 +488,7 @@ def _decode_once_paged(cfg: GPTConfig, params, tokens, pool, positions,
             x = _mlp(x, layer, cfg, tp_axis=tp_axis)
         return x, pool
 
-    x, pool = _scan_pool_layers(body, x, stacked, pool)
+    x, pool = scan_pool_layers(body, x, stacked, pool)
     with jax.named_scope(scopes.HEAD):
         logits = _head(params, cfg, x)[:, 0]
     return logits, pool
@@ -638,29 +552,6 @@ def _spec_propose_scan(cfg: GPTConfig, params, tokens, pool, positions,
     return toks_out[:k], None, pool
 
 
-@functools.partial(jax.jit, static_argnums=(0,),
-                   static_argnames=("attn_impl",), donate_argnums=(3,))
-def decode_step_paged(cfg: GPTConfig, params, tokens, pool, positions,
-                      tables, *, attn_impl: str = "gather"):
-    """One token for every slot against the paged pool.
-    → (logits [B, V] fp32, updated pool)."""
-    return _decode_once_paged(cfg, params, tokens, pool, positions, tables,
-                              attn_impl)
-
-
-@functools.partial(jax.jit, static_argnums=(0,),
-                   static_argnames=("attn_impl",), donate_argnums=(3,))
-def _decode_sample_paged(cfg: GPTConfig, params, tokens, pool, positions,
-                         tables, temps, key, *, attn_impl: str = "gather"):
-    """One decode-window step: `_decode_once_paged` + on-device
-    sampling. → (next tokens [B] int32, positions + 1, updated pool,
-    advanced key)."""
-    logits, pool = _decode_once_paged(cfg, params, tokens, pool, positions,
-                                      tables, attn_impl)
-    nxt, _scaled, key = _sample_next(logits, temps, key)
-    return nxt, positions + 1, pool, key
-
-
 def _no_phase(_name: str):
     return contextlib.nullcontext()
 
@@ -694,7 +585,8 @@ def _decode_window(step, tokens, pool, positions, n_steps: int, key,
     window's tokens are then fetched and stacked on the HOST — where the
     engine wants them anyway — so the window compiles nothing of its
     own: a device-side stack would be one more small program per
-    (n_steps, B). → (tokens_out [rows, B] int32 numpy, updated pool).
+    (n_steps, B). → (tokens_out [rows, B] int32 numpy, updated pool,
+    what `also` named or None).
 
     One step in flight across the boundary: with `ahead` (a callable)
     ONE more run of the step is queued after the `n_steps`, fed by the
@@ -719,8 +611,7 @@ def _decode_window(step, tokens, pool, positions, n_steps: int, key,
     `also(pool)` (optional) names device values of the pool as it stands
     after step `n_steps` to fetch in the SAME pull (a family's on-device
     counters; ahead of an extra step, which takes the pool they are
-    leaves of, a `snapshot` of them); with it the result is
-    (tokens_out, pool, those values)."""
+    leaves of, a `snapshot` of them)."""
     out = [] if carried is None else [carried]
     for _ in range(n_steps):
         with phase("decode.dispatch"):
@@ -735,45 +626,164 @@ def _decode_window(step, tokens, pool, positions, n_steps: int, key,
         ahead(tokens, key)
     with phase("decode.pull"):
         out, extra = jax.device_get((out, extra))
-    if also is None:
-        return np.stack(out), pool
     return np.stack(out), pool, extra
 
 
-def decode_multi_paged(cfg: GPTConfig, params, tokens, pool, positions,
-                       tables, n_steps: int, temps, key, *,
-                       attn_impl: str = "gather", phase=_no_phase,
-                       carried=None, ahead=None):
-    """`n_steps` paged-decode steps with on-device sampling (the paged
-    twin of decode.decode_multi — the engine pre-allocates pages
-    covering every position the window writes before dispatch, so tables
-    are static across the window), plus the step `ahead` asks for and
-    after the row `carried` brings (`_decode_window`).
-    → (tokens_out [rows, B] int32, updated pool).
+def paged_programs(chunk_forward, decode_once, chunk_logits,
+                   counter_names=None):
+    """The four serving programs of a model family, from what the family
+    writes: a chunk forward, a decode step, and a chunk head.
 
-    The window is a `_decode_window` of ONE step program, not one
-    program scanning over steps. It became that in PR 21, when the pool
-    was ``[..., H, K]``: a scan over steps around the scan over layers
-    made the TPU compiler re-lay the whole pool out for the outer loop
-    (head_dim 64 padded to 128 lanes there — a 2x copy of a 6.4 GB
-    pool), which did not fit a 16 GB chip at OPT-1.3B. The lane-dense
-    pool has no padded layout to fall into, so that reason is gone; the
-    window stays dispatches of one step because a dispatch costs 0.45 ms
-    against a 9 ms step (PERF.md section 5), and because a step is the
-    unit the engine keeps in flight while it reads a window's tokens,
-    which a fused window could not split off. The only program a window
-    compiles is that step (`_decode_sample_paged`), one per table width,
-    whatever n_steps is: under the engine's compile_watch label
-    `decode_multi_paged`, `jax_compiles_total{fn}` counts table widths.
-    What it costs is a host dispatch a step where the scan paid one a
-    window."""
+    `chunk_forward(cfg, params, tokens, pool, tables, offsets, n_valid,
+    attn_impl=, **rows)` → (hidden states [N, C, D], updated pool);
+    `decode_once(cfg, params, tokens, pool, positions, tables, attn_impl)`
+    → (logits [B, V] fp32, updated pool): all B slots advance one token;
+    `chunk_logits(cfg, params, x, n_valid)` → [N, V] fp32: each chunk
+    row's logits at its last valid token;
+    `counter_names` (optional): what the running uint32 totals that the
+    family's `decode_once` keeps in ``pool["moe_counters"]`` count.
+    → (prefill_chunk_paged, decode_step_paged, _decode_sample_paged,
+    decode_multi_paged), which a family module binds under those names.
 
-    def step(toks, kv, pos, rng):
-        return _decode_sample_paged(cfg, params, toks, kv, pos, tables,
-                                    temps, rng, attn_impl=attn_impl)
+    The three jitted ones are nested `def`s because a trace finds a device
+    program by its function's name (`jit_prefill_chunk_paged`,
+    `jit__decode_sample_paged`, `jit_decode_step_paged`:
+    benchmarks/harness/trace_reduce.py), and a functools.partial or a
+    lambda would give `jax.jit` none. The pool is donated to each."""
 
-    return _decode_window(step, tokens, pool, positions, n_steps, key, phase,
-                          carried=carried, ahead=ahead)
+    @functools.partial(jax.jit, static_argnums=(0,),
+                       static_argnames=("return_logits", "attn_impl"),
+                       donate_argnums=(3,))
+    def prefill_chunk_paged(cfg, params, tokens, pool, tables, offsets,
+                            n_valid, *, return_logits: bool = True,
+                            attn_impl: str = "gather", **rows):
+        """Write N chunk rows into their prompts' KV pages, each at its own
+        arbitrary token offset (Sarathi/Orca-style chunked prefill; rows
+        may be consecutive chunks of one prompt or chunks of different
+        ones).
+
+        The compile-count story for prefill: N and C are engine constants
+        (N = one of the engine's `chunk_heights`: bucketed by width, the
+        full chunks one step's token budget holds, at most n_slots;
+        C = the chunk size) and `offsets`/`n_valid` are traced vectors,
+        so the table WIDTH is the only shape degree of freedom — one
+        program lowers per (table width, ``return_logits``) pair. The
+        engine slices tables to the pow-2 width each bucket of rows
+        actually attends over (`_pow2_width` of pages covering written
+        prefix + chunk), so the grid is the width ladder {1, 2, 4, …,
+        max_pages}: at most 2·log₂(max_pages)+2 programs
+        (``return_logits`` False for interior-only batches, True when any
+        row carries a final chunk, which alone pays the LM head),
+        replacing the one-shot path's buckets × admission-ladder grid.
+        Full-width tables remain valid (the width-bucketing-off control
+        arm dispatches exactly the PR 4 two-program grid); attention
+        compute/bytes scale with the sliced width, which is the whole
+        point for interior chunks of long-max-len prompts.
+
+        tokens: [N, C] (tail chunks padded); tables: [N, width] page ids,
+        width ≤ max_pages (pages covering positions ``offsets[i] ..
+        offsets[i]+n_valid[i]-1`` must be allocated and fall inside the
+        sliced width — the engine's bucket rule guarantees this);
+        offsets: [N] — absolute position of tokens[i, 0]; n_valid: [N] —
+        valid tokens in row i's chunk (0 = inert row: all writes land on
+        the null page and its logits row is garbage the engine ignores);
+        `rows`: what the family's chunk forward is told of each row
+        beside its table: ``slots=`` [N] int32, the slot a row belongs to
+        (an inert row's is ignored), where the pool keeps a state or a
+        ring of pages by the slot; nothing for the gpt.
+
+        Queries attend causally over everything their slot has written
+        so far: each layer scatters the batch's K/V into its pages FIRST
+        (pad / inert rows land on the null page), then reads back through
+        the page tables — ``gather`` reconstitutes the contiguous
+        timelines (exact-semantics default), ``kernel`` runs the ragged
+        prefill Pallas kernel (ops/paged_attention.py) against the pool
+        in place. Distinct live slots never share a page, so rows are
+        independent.
+
+        → (last-valid-token logits [N, V] fp32 if return_logits else
+        None, updated pool)."""
+        x, pool = chunk_forward(cfg, params, tokens, pool, tables, offsets,
+                                n_valid, attn_impl=attn_impl, **rows)
+        if not return_logits:
+            return None, pool
+        return chunk_logits(cfg, params, x, n_valid), pool
+
+    @functools.partial(jax.jit, static_argnums=(0,),
+                       static_argnames=("attn_impl",), donate_argnums=(3,))
+    def decode_step_paged(cfg, params, tokens, pool, positions, tables, *,
+                          attn_impl: str = "gather"):
+        """One token for every slot against the paged pool.
+        → (logits [B, V] fp32, updated pool)."""
+        return decode_once(cfg, params, tokens, pool, positions, tables,
+                           attn_impl)
+
+    @functools.partial(jax.jit, static_argnums=(0,),
+                       static_argnames=("attn_impl",), donate_argnums=(3,))
+    def _decode_sample_paged(cfg, params, tokens, pool, positions, tables,
+                             temps, key, *, attn_impl: str = "gather"):
+        """One decode-window step: `decode_once` + on-device sampling.
+        → (next tokens [B] int32, positions + 1, updated pool, advanced
+        key)."""
+        logits, pool = decode_once(cfg, params, tokens, pool, positions,
+                                   tables, attn_impl)
+        nxt, _scaled, key = _sample_next(logits, temps, key)
+        return nxt, positions + 1, pool, key
+
+    def decode_multi_paged(cfg, params, tokens, pool, positions, tables,
+                           n_steps: int, temps, key, *,
+                           attn_impl: str = "gather", phase=_no_phase,
+                           counters=None, carried=None, ahead=None):
+        """`n_steps` paged-decode steps with on-device sampling (the paged
+        twin of decode.decode_multi — the engine pre-allocates pages
+        covering every position the window writes before dispatch, so
+        tables are static across the window), plus the step `ahead` asks
+        for and after the row `carried` brings (`_decode_window`).
+        `counters(dict)` (optional, a family with `counter_names`) is
+        handed the pool's running counters as they stand after the
+        window's `n_steps`, fetched WITH the window's tokens (what the
+        step `ahead` asks for counts arrives with the next window's).
+        → (tokens_out [rows, B] int32, updated pool).
+
+        The window is a `_decode_window` of ONE step program, not one
+        program scanning over steps. It became that in PR 21, when the
+        pool was ``[..., H, K]``: a scan over steps around the scan over
+        layers made the TPU compiler re-lay the whole pool out for the
+        outer loop (head_dim 64 padded to 128 lanes there — a 2x copy of
+        a 6.4 GB pool), which did not fit a 16 GB chip at OPT-1.3B. The
+        lane-dense pool has no padded layout to fall into, so that reason
+        is gone; the window stays dispatches of one step because a
+        dispatch costs 0.45 ms against a 9 ms step (PERF.md section 5),
+        and because a step is the unit the engine keeps in flight while
+        it reads a window's tokens, which a fused window could not split
+        off. The only program a window compiles is that step
+        (`_decode_sample_paged`), one per table width, whatever n_steps
+        is: under the engine's compile_watch label `decode_multi_paged`,
+        `jax_compiles_total{fn}` counts table widths. What it costs is a
+        host dispatch a step where the scan paid one a window."""
+
+        def step(toks, kv, pos, rng):
+            return _decode_sample_paged(cfg, params, toks, kv, pos, tables,
+                                        temps, rng, attn_impl=attn_impl)
+
+        also = (lambda pool: pool["moe_counters"]) if counter_names else None
+        toks_out, pool, totals = _decode_window(
+            step, tokens, pool, positions, n_steps, key, phase, also,
+            carried=carried, ahead=ahead)
+        if counters is not None:
+            counters(dict(zip(counter_names, (int(t) for t in totals))))
+        return toks_out, pool
+
+    return (prefill_chunk_paged, decode_step_paged, _decode_sample_paged,
+            decode_multi_paged)
+
+
+# The gpt's own four. Its chunk head runs over every position of a row
+# and then takes the last valid one (`_last_valid_logits`), it is told
+# nothing of a row beside its table, and its decode step counts nothing.
+(prefill_chunk_paged, decode_step_paged, _decode_sample_paged,
+ decode_multi_paged) = paged_programs(
+    _chunk_paged_forward, _decode_once_paged, _last_valid_logits)
 
 
 @functools.partial(jax.jit, static_argnums=(0,),
@@ -1004,7 +1014,7 @@ def decode_multi_paged_tp(cfg: GPTConfig, params, tokens, pool, positions,
             mesh=mesh, attn_impl=attn_impl)
 
     return _decode_window(step, tokens, pool, positions, n_steps, key, phase,
-                          carried=carried, ahead=ahead)
+                          carried=carried, ahead=ahead)[:2]
 
 
 @functools.partial(jax.jit, static_argnames=("mesh",), donate_argnums=(0,))
@@ -1095,6 +1105,7 @@ __all__ = [
     "prefill_batch_paged",
     "prefill_chunk_paged", "verify_chunk_paged", "spec_draft_propose",
     "decode_step_paged", "decode_multi_paged", "join_window", "snapshot",
+    "paged_programs", "scan_pool_layers",
     "KV_POOL_PARTITION_RULES", "prefill_chunk_paged_tp",
     "verify_chunk_paged_tp", "decode_step_paged_tp",
     "decode_multi_paged_tp", "copy_pages_tp", "spec_draft_propose_tp",

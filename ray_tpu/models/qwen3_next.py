@@ -55,9 +55,9 @@ another row continues is a full chunk (only a prompt's LAST chunk is
 short), which the convolution's tail relies on. The last live row of a
 slot writes state and tail back.
 
-The paged programs carry the names models/paged_kv.py gives its own,
-take the pool donated, and reuse paged_kv's sampling and decode window.
-Eight or so layers of two shapes are walked in Python, each mixer kind
+The four paged programs are `paged_kv.paged_programs` over the chunk
+forward and the decode step (names, donation and the decode window are
+its). Eight or so layers of two shapes are walked in Python, each mixer kind
 indexing its own stack; the MLP's leaves are one stack over all layers
 and the experts' go to the grouped matmul whole (`layer=`).
 """
@@ -74,9 +74,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.ops import scopes
-from ray_tpu.models.laguna import _COUNTERS, _count, _gated_mlp
-from ray_tpu.models.paged_kv import _decode_window, _no_phase, _sample_next
-from ray_tpu.models.zaya import _attend_fn, _write_kv
+from ray_tpu.models.blocks import (COUNTERS, attend_fn, counter_row,
+                                   gated_mlp, last_token_logits,
+                                   untied_head, write_kv)
+from ray_tpu.models.blocks import rms_norm_centred as _norm
+from ray_tpu.models.paged_kv import paged_programs
 from ray_tpu.ops.gated_delta import (
     gdn_chunk_scan, gdn_decode_step, reference_gdn_decode_step)
 from ray_tpu.ops.moe import token_choice_experts
@@ -240,13 +242,6 @@ def init_params(cfg: Qwen3NextConfig, rng: jax.Array) -> dict[str, jax.Array]:
 
 # ------------------------------------------------------------- the block
 
-def _norm(x, w, eps):
-    """Zero-centred RMSNorm, float32 inside; back to x's type."""
-    x32 = x.astype(_F32)
-    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-    return (y * (1.0 + w.astype(_F32))).astype(x.dtype)
-
-
 def _rope(cfg: Qwen3NextConfig, x, pos):
     """Rotate-half rotary on the first `rotary_dim` dims of each head.
     x [N, C, h, K] float32, pos [N, C] absolute positions."""
@@ -380,19 +375,14 @@ def _moe(cfg: Qwen3NextConfig, params, l: int, x, valid):
         u, chosen, gates, *experts,
         first_expert=cfg.first_expert, layer=l, valid=valid.reshape(-1))
     with jax.named_scope(scopes.MLP):
-        shared = _gated_mlp(u, params["s_gate"][l], params["s_up"][l],
-                            params["s_down"][l])
+        shared = gated_mlp(u, params["s_gate"][l], params["s_up"][l],
+                           params["s_down"][l])
         f = (_shared_gate(u, params["s_gate_w"][l]) * shared
              + routed.astype(_F32)).astype(dt)
         return x + f.reshape(N, C, D), counts
 
 
-@jax.named_scope(scopes.HEAD)
-def _head(cfg: Qwen3NextConfig, params, x):
-    """Final norm and the untied head → float32 logits [..., V]."""
-    h = _norm(x, params["ln_f_scale"], cfg.norm_eps)
-    return jnp.matmul(h, params["lm_head"].astype(cfg.dtype),
-                      preferred_element_type=_F32)
+_head = functools.partial(untied_head, _norm)
 
 
 def _repeat_heads(cfg: Qwen3NextConfig, t):
@@ -460,7 +450,7 @@ def init_paged_kv(cfg: Qwen3NextConfig, n_pages: int, page_size: int,
     page), the linear layers' recurrent state ``[n_linear, n_slots+1,
     Hv, dk, dv]`` float32 and convolution tail ``[n_linear, n_slots+1,
     taps-1, channels]`` (the last row the null slot), and the decode
-    steps' running expert counters (models/laguna.py `_COUNTERS`)."""
+    steps' running expert counters (`blocks.COUNTERS`)."""
     if kv_dtype not in (None, "bf16"):
         raise ValueError(
             f"the qwen3_next family's pool is bf16, got {kv_dtype!r}")
@@ -474,7 +464,7 @@ def init_paged_kv(cfg: Qwen3NextConfig, n_pages: int, page_size: int,
             "gdn_conv": jnp.zeros(
                 (nl, n_slots + 1, cfg.conv_taps - 1, cfg.conv_channels),
                 cfg.dtype),
-            "moe_counters": jnp.zeros(len(_COUNTERS), jnp.uint32)}
+            "moe_counters": jnp.zeros(len(COUNTERS), jnp.uint32)}
 
 
 def _chunk_forward(cfg: Qwen3NextConfig, params, tokens, pool, tables,
@@ -509,7 +499,7 @@ def _chunk_forward(cfg: Qwen3NextConfig, params, tokens, pool, tables,
             valid, jnp.take_along_axis(tables, page_idx, axis=1),
             0).reshape(-1)
         write_offs = (pos % ps).reshape(-1)
-    attend = _attend_fn(attn_impl, chunk=True)
+    attend = attend_fn(attn_impl, chunk=True)
     with jax.named_scope(scopes.EMBED):
         x = params["wte"].astype(cfg.dtype)[tokens]
     for l, kind in enumerate(cfg.kinds):
@@ -539,35 +529,13 @@ def _chunk_forward(cfg: Qwen3NextConfig, params, tokens, pool, tables,
             x = _gdn_output(cfg, params, l, x, o, z)
         else:
             q, k, v, gate = _attn_inputs(cfg, params, l, x, pos)
-            pool = _write_kv(pool, i, write_pages, write_offs, k, v)
+            pool = write_kv(pool, i, write_pages, write_offs, k, v)
             with jax.named_scope(scopes.ATTN_KERNEL):
                 attn = attend(q, pool["k"], pool["v"], i, tables, offsets,
                               kv_lens, sm_scale=1.0 / math.sqrt(cfg.head_dim))
             x = _attn_output(cfg, params, l, x, attn, gate)
         x, _counts = _moe(cfg, params, l, x, valid)
     return x, pool
-
-
-@functools.partial(jax.jit, static_argnums=(0,),
-                   static_argnames=("return_logits", "attn_impl"),
-                   donate_argnums=(3,))
-def prefill_chunk_paged(cfg: Qwen3NextConfig, params, tokens, pool, tables,
-                        offsets, n_valid, *, slots,
-                        return_logits: bool = True,
-                        attn_impl: str = "gather"):
-    """models/paged_kv.prefill_chunk_paged for this block, with `slots`
-    [N] int32: the slot each row belongs to (an inert row's is ignored).
-    → (last-valid-token logits [N, V] fp32 if return_logits else None,
-    updated pool). The head runs on each row's last valid hidden state
-    only."""
-    x, pool = _chunk_forward(cfg, params, tokens, pool, tables, offsets,
-                             n_valid, slots, attn_impl)
-    if not return_logits:
-        return None, pool
-    with jax.named_scope(scopes.HEAD):
-        last = jnp.take_along_axis(
-            x, jnp.maximum(n_valid - 1, 0)[:, None, None], axis=1)[:, 0]
-    return _head(cfg, params, last), pool
 
 
 def _decode_once(cfg: Qwen3NextConfig, params, tokens, pool, positions,
@@ -589,7 +557,7 @@ def _decode_once(cfg: Qwen3NextConfig, params, tokens, pool, positions,
             jnp.minimum(positions // ps, tables.shape[1] - 1)[:, None],
             axis=1)[:, 0]
         write_off = positions % ps
-    attend = _attend_fn(attn_impl, chunk=False)
+    attend = attend_fn(attn_impl, chunk=False)
     step = (gdn_decode_step if attn_impl == "kernel"
             else reference_gdn_decode_step)
     repeat = cfg.lin_v_heads // cfg.lin_k_heads
@@ -615,7 +583,7 @@ def _decode_once(cfg: Qwen3NextConfig, params, tokens, pool, positions,
             x = _gdn_output(cfg, params, l, x, o[:, None], z)
         else:
             q, k, v, gate = _attn_inputs(cfg, params, l, x, pos)
-            pool = _write_kv(pool, i, write_page, write_off, k, v)
+            pool = write_kv(pool, i, write_page, write_off, k, v)
             with jax.named_scope(scopes.ATTN_KERNEL):
                 attn = attend(q[:, 0], pool["k"], pool["v"], i, tables,
                               positions + 1,
@@ -625,54 +593,14 @@ def _decode_once(cfg: Qwen3NextConfig, params, tokens, pool, positions,
         counts.append(n)
     with jax.named_scope(scopes.COUNTERS):
         n_live = jnp.sum(active)
-        counters = pool["moe_counters"] + sum(_count(cfg, n, n_live)
+        counters = pool["moe_counters"] + sum(counter_row(cfg, n, n_live)
                                               for n in counts)
     return _head(cfg, params, x[:, 0]), {**pool, "moe_counters": counters}
 
 
-@functools.partial(jax.jit, static_argnums=(0,),
-                   static_argnames=("attn_impl",), donate_argnums=(3,))
-def decode_step_paged(cfg: Qwen3NextConfig, params, tokens, pool, positions,
-                      tables, *, attn_impl: str = "gather"):
-    """One token for every slot. → (logits [B, V] fp32, updated pool)."""
-    return _decode_once(cfg, params, tokens, pool, positions, tables,
-                        attn_impl)
-
-
-@functools.partial(jax.jit, static_argnums=(0,),
-                   static_argnames=("attn_impl",), donate_argnums=(3,))
-def _decode_sample_paged(cfg: Qwen3NextConfig, params, tokens, pool,
-                         positions, tables, temps, key, *,
-                         attn_impl: str = "gather"):
-    """One decode-window step: `_decode_once` + on-device sampling."""
-    logits, pool = _decode_once(cfg, params, tokens, pool, positions, tables,
-                                attn_impl)
-    nxt, _scaled, key = _sample_next(logits, temps, key)
-    return nxt, positions + 1, pool, key
-
-
-def decode_multi_paged(cfg: Qwen3NextConfig, params, tokens, pool, positions,
-                       tables, n_steps: int, temps, key, *,
-                       attn_impl: str = "gather", phase=_no_phase,
-                       counters=None, carried=None, ahead=None):
-    """models/paged_kv.decode_multi_paged for this block: the shared
-    `_decode_window` of this family's step program. `counters(dict)`
-    (optional) is handed the pool's running expert counters as they
-    stand after the window's `n_steps`, fetched WITH the window's tokens
-    (what the step `ahead` asks for counts arrives with the next
-    window's)."""
-
-    def step(toks, kv, pos, rng):
-        return _decode_sample_paged(cfg, params, toks, kv, pos, tables,
-                                    temps, rng, attn_impl=attn_impl)
-
-    toks_out, pool, totals = _decode_window(
-        step, tokens, pool, positions, n_steps, key, phase,
-        also=lambda pool: pool["moe_counters"], carried=carried,
-        ahead=ahead)
-    if counters is not None:
-        counters(dict(zip(_COUNTERS, (int(t) for t in totals))))
-    return toks_out, pool
+(prefill_chunk_paged, decode_step_paged, _decode_sample_paged,
+ decode_multi_paged) = paged_programs(
+    _chunk_forward, _decode_once, last_token_logits(_head), COUNTERS)
 
 
 __all__ = [

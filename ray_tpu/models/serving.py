@@ -8,9 +8,16 @@ run, how weights and pool shard, and which of the engine's options the
 family's programs cannot carry. A configuration object names its family
 (a class attribute `family`; `GPTConfig` carries none and is "gpt") and
 `family_of(cfg)` hands the engine one `ServingFamily`: the mirror, on
-the program's side, of benchmarks/families/<family>.py. A new
-architecture adds an entry to `_FAMILIES` and a model module; nothing in
+the program's side, of benchmarks/families/<family>.py. Nothing in
 serve/llm.py names a model class.
+
+A new family writes a model module (a configuration class, `param_specs`
+/ `init_params` / `partition_rules`, the full-sequence `forward` its
+tests compare with, `init_paged_kv`, ONE chunk forward, ONE decode step
+and a head, handed to `paged_kv.paged_programs`; the parts it shares with
+others come from models/blocks.py) and a row here: what its pool keeps by
+the slot beside the pages, and the clause each of `_REFUSALS` needs about
+that.
 """
 
 from __future__ import annotations
@@ -55,13 +62,18 @@ class ServingFamily:
     # row's slot (`slots=`), and `init_pool` is told how many tokens of
     # one prompt a chunk dispatch may carry (`dispatch_tokens=`).
     slot_ring: bool = False
-    # The decode programs keep expert counters in the pool: a decode
-    # window hands them over with its tokens (`counters=`).
-    expert_counters: bool = False
     # (cfg, params) -> params: the tree as the family's programs want it
     # laid out, applied once at load (None: as it came).
     lay_out: Callable | None = None
     unsupported: tuple = ()
+
+    @property
+    def expert_counters(self) -> tuple:
+        """What the decode programs' running counters in the pool count
+        (the names the module gave the program builder, `model.COUNTERS`;
+        () for none): a decode window hands them over with its tokens
+        (`counters=`)."""
+        return getattr(self.model, "COUNTERS", ())
 
 
 _DENSE = ("prefill", "prefill_batch", "decode_step", "decode_multi",
@@ -98,245 +110,156 @@ def _gpt() -> ServingFamily:
         programs=_gpt_programs)
 
 
-def _zaya() -> ServingFamily:
+# The engine options that the programs of a family with experts and a
+# memory by the slot beside the pages cannot carry, in the order
+# serve/llm_options.py settles them (kv_mode and prefill_chunk before the
+# checks that read them): (option, `fits`, `neutral`, the refusal). A
+# refusal names the family ({name}) and says what would have to be built;
+# where that depends on WHAT the family keeps by the slot ({beside}: a
+# one-token state, a recurrence, a ring) it takes the family's own clause
+# under the option's name.
+_REFUSALS = (
+    ("kv_mode", lambda o: o.kv_mode == "paged", "paged",
+     "the {name} family serves from the paged pool only: kv_mode='dense' "
+     "would need a [L, B, T] cache backend {kv_mode}"),
+    ("prefill_chunk", lambda o: o.prefill_chunk > 0, 128,
+     "the {name} family has no one-shot prefill: prefill_chunk=0 would "
+     "need a whole-prompt program that leaves {prefill_chunk}"),
+    ("prefill_width_bucketing",
+     lambda o: not o.prefill_width_bucketing, False,
+     "prefill_width_bucketing with the {name} family: a chunk program "
+     "here costs a pass over every held expert's weights at any table "
+     "width, and one bucket a width spreads a lone prompt's rows over "
+     "more programs; a dispatch that packs rows of several widths into "
+     "one program would have to be built"),
+    ("prefix_cache", lambda o: not o.prefix_cache, False,
+     "prefix_cache with the {name} family: a cached prefix would need "
+     "{prefix_cache} (serve/prefix_cache.py keeps PagePool pages only)"),
+    ("spec_draft", lambda o: not o.spec_draft, "",
+     "speculative decoding with the {name} family: a rejected proposal "
+     "rewinds the cursor, and {spec_draft} would have to be built"),
+    ("kv_transfer", lambda o: not o.kv_transfer, False,
+     "KV page-set transfer with the {name} family: a page set would have "
+     "to carry {beside} (serve/kv_objects.py moves PagePool pages only)"),
+    ("tp", lambda o: int(o.tp) == 1, 1,
+     "tp > 1 with the {name} family: {tp}"),
+    ("weight_dtype", lambda o: o.weight_dtype != "int8", "bf16",
+     "weight_dtype='int8' with the {name} family: quantize_params knows "
+     "the gpt tree's planes, and the experts' grouped matmul (ops/moe.py) "
+     "has no int8 form"),
+    ("kv_dtype", lambda o: o.kv_dtype != "int8", "bf16",
+     "kv_dtype='int8' with the {name} family: the per-page scale planes "
+     "are kept by models/paged_kv._quant_write, {kv_dtype}"),
+)
+
+_SNAPSHOT = ("a snapshot of {beside} at the prefix's boundary, stored with "
+             "its pages")
+_RETURNS = ("a verify program that returns {beside} at every position to "
+            "rewind to")
+_EXCHANGE = ("the experts need an expert-parallel exchange of rows between "
+             "chips (ops/moe.py returns the held experts' part only)")
+
+
+def _paged_only(model, beside: str, clauses: dict, **fields) -> ServingFamily:
+    """A family served from the paged pool alone, by the four programs
+    its module binds from `paged_kv.paged_programs`, every leaf
+    replicated: `beside` is what its pool keeps by the slot beside the
+    pages, `clauses` what each of `_REFUSALS` needs said about that."""
     from jax.sharding import PartitionSpec
 
-    from ray_tpu.models import zaya
-
-    state = ("the slot's conv/shift state (z, c and W_v2 u of its last "
-             "token, per layer: models/zaya.py)")
-    return ServingFamily(
-        name="zaya", model=zaya, init_pool=zaya.init_paged_kv,
-        pool_partition_rules=((r".*", PartitionSpec()),),
-        programs=lambda _tp, _mesh: {
-            name: getattr(zaya, name) for name in (
-                "prefill_chunk_paged", "decode_step_paged",
-                "decode_multi_paged")},
-        slot_state=("slot_state",), expert_counters=True,
-        unsupported=(
-            Unsupported(
-                "kv_mode", lambda o: o.kv_mode == "paged", "paged",
-                "the zaya family serves from the paged pool only: "
-                "kv_mode='dense' would need a [L, B, T] cache backend of "
-                "the CCA block, with its per-slot state carried beside it"),
-            Unsupported(
-                "prefill_chunk", lambda o: o.prefill_chunk > 0, 128,
-                "the zaya family has no one-shot prefill: prefill_chunk=0 "
-                "would need a whole-prompt program that leaves the prompt's "
-                "last-token state in the slot state"),
-            Unsupported(
-                "prefill_width_bucketing",
-                lambda o: not o.prefill_width_bucketing, False,
-                "prefill_width_bucketing with the zaya family: a chunk "
-                "program here costs a pass over every expert's weights at "
-                "any table width, and one bucket a width spreads a lone "
-                "prompt's rows over more programs; a dispatch that packs "
-                "rows of several widths into one program would have to be "
-                "built"),
-            Unsupported(
-                "prefix_cache", lambda o: not o.prefix_cache, False,
-                "prefix_cache with the zaya family: a cached prefix would "
-                f"need a snapshot of {state} at the prefix's boundary, "
-                "stored with its pages (serve/prefix_cache.py keeps pages "
-                "only)"),
-            Unsupported(
-                "spec_draft", lambda o: not o.spec_draft, "",
-                "speculative decoding with the zaya family: a rejected "
-                "proposal rewinds the cursor, and a verify program that "
-                f"returns {state} at every position to rewind to would "
-                "have to be built"),
-            Unsupported(
-                "kv_transfer", lambda o: not o.kv_transfer, False,
-                "KV page-set transfer with the zaya family: a page set "
-                f"would have to carry {state} (serve/kv_objects.py moves "
-                "pages only)"),
-            Unsupported(
-                "tp", lambda o: int(o.tp) == 1, 1,
-                "tp > 1 with the zaya family: 2 KV heads cannot shard over "
-                "more chips than heads (models/partition.py and "
-                "serve/kv_objects.py split the pool by whole heads), and "
-                "the experts need an expert-parallel dispatch, not a head "
-                "split"),
-            Unsupported(
-                "weight_dtype", lambda o: o.weight_dtype != "int8", "bf16",
-                "weight_dtype='int8' with the zaya family: quantize_params "
-                "knows the gpt tree's planes, and the experts' grouped "
-                "matmul (ops/moe.py) has no int8 form"),
-            Unsupported(
-                "kv_dtype", lambda o: o.kv_dtype != "int8", "bf16",
-                "kv_dtype='int8' with the zaya family: the per-page scale "
-                "planes are kept by models/paged_kv._quant_write, which "
-                "the CCA block's K/V writer would have to call"),
-        ))
-
-
-def _ring_family(name: str, model, dense_needs: str) -> ServingFamily:
-    """A family whose window layers keep a ring of pages a slot beside
-    the full layers' pages (models/laguna.py `ring_pool`) and whose
-    experts are one chip's share: `model` brings the programs, and
-    `dense_needs` says what a dense cache backend would have to carry."""
-    from jax.sharding import PartitionSpec
-
-    ring = ("the window layers' ring of pages a slot (models/laguna.py: "
-            "indexed by slot, outside PagePool's page ids)")
+    name = model.__name__.rpartition(".")[2]
+    said = {option: clause.format(beside=beside)
+            for option, clause in clauses.items()}
     return ServingFamily(
         name=name, model=model, init_pool=model.init_paged_kv,
         pool_partition_rules=((r".*", PartitionSpec()),),
         programs=lambda _tp, _mesh: {
-            prog: getattr(model, prog) for prog in (
+            program: getattr(model, program) for program in (
                 "prefill_chunk_paged", "decode_step_paged",
                 "decode_multi_paged")},
-        slot_ring=True, expert_counters=True,
-        unsupported=(
-            Unsupported(
-                "kv_mode", lambda o: o.kv_mode == "paged", "paged",
-                f"the {name} family serves from the paged pool only: "
-                "kv_mode='dense' would need a [L, B, T] cache backend with "
-                f"{dense_needs}"),
-            Unsupported(
-                "prefill_chunk", lambda o: o.prefill_chunk > 0, 128,
-                f"the {name} family has no one-shot prefill: "
-                "prefill_chunk=0 would need a whole-prompt program that "
-                f"leaves the prompt's last window in {ring}"),
-            Unsupported(
-                "prefill_width_bucketing",
-                lambda o: not o.prefill_width_bucketing, False,
-                f"prefill_width_bucketing with the {name} family: a chunk "
-                "program here costs a pass over every held expert's "
-                "weights at any table width, and one bucket a width "
-                "spreads a lone prompt's rows over more programs; a "
-                "dispatch that packs rows of several widths into one "
-                "program would have to be built"),
-            Unsupported(
-                "prefix_cache", lambda o: not o.prefix_cache, False,
-                f"prefix_cache with the {name} family: a cached prefix "
-                f"would need {ring} at the prefix's boundary stored with "
-                "its pages: a window kind whose pages can be shared "
-                "(serve/prefix_cache.py keeps PagePool pages only)"),
-            Unsupported(
-                "spec_draft", lambda o: not o.spec_draft, "",
-                f"speculative decoding with the {name} family: a rejected "
-                "proposal rewinds the cursor, and a ring whose newest "
-                "pages overwrote the oldest cannot be rewound past them; "
-                "a verify program over a ring with room for the "
-                "proposals would have to be built"),
-            Unsupported(
-                "kv_transfer", lambda o: not o.kv_transfer, False,
-                f"KV page-set transfer with the {name} family: a page set "
-                f"would have to carry {ring} (serve/kv_objects.py moves "
-                "PagePool pages only)"),
-            Unsupported(
-                "tp", lambda o: int(o.tp) == 1, 1,
-                f"tp > 1 with the {name} family: the experts need an "
-                "expert-parallel exchange of rows between chips "
-                "(ops/moe.py returns the held experts' part only), not a "
-                "head split, and no partition rule shards a ring"),
-            Unsupported(
-                "weight_dtype", lambda o: o.weight_dtype != "int8", "bf16",
-                f"weight_dtype='int8' with the {name} family: "
-                "quantize_params knows the gpt tree's planes, and the "
-                "experts' grouped matmul (ops/moe.py) has no int8 form"),
-            Unsupported(
-                "kv_dtype", lambda o: o.kv_dtype != "int8", "bf16",
-                f"kv_dtype='int8' with the {name} family: the per-page "
-                "scale planes are kept by models/paged_kv._quant_write, "
-                "and the kernels' window form takes a bf16 pool "
-                "(ops/paged_attention.py)"),
-        ))
+        unsupported=tuple(
+            Unsupported(option, fits, neutral,
+                        why.format(name=name, beside=beside, **said))
+            for option, fits, neutral, why in _REFUSALS),
+        **fields)
+
+
+def _zaya() -> ServingFamily:
+    from ray_tpu.models import zaya
+
+    return _paged_only(
+        zaya,
+        "the slot's conv/shift state (z, c and W_v2 u of its last token, "
+        "per layer: models/zaya.py)",
+        {"kv_mode": "of the CCA block, with its per-slot state carried "
+                    "beside it",
+         "prefill_chunk": "the prompt's last-token state in the slot state",
+         "prefix_cache": _SNAPSHOT, "spec_draft": _RETURNS,
+         "tp": "2 KV heads cannot shard over more chips than heads "
+               "(models/partition.py and serve/kv_objects.py split the "
+               "pool by whole heads), and the experts need an "
+               "expert-parallel dispatch, not a head split",
+         "kv_dtype": "which the CCA block's K/V writer would have to call"},
+        slot_state=("slot_state",))
+
+
+def _ring(model, dense_needs: str) -> ServingFamily:
+    """A family whose window layers keep a ring of pages a slot beside
+    the full layers' pages (models/laguna.py `ring_pool`); `dense_needs`
+    says what a dense cache backend would have to carry."""
+    return _paged_only(
+        model,
+        "the window layers' ring of pages a slot (models/laguna.py: "
+        "indexed by slot, outside PagePool's page ids)",
+        {"kv_mode": "with " + dense_needs,
+         "prefill_chunk": "the prompt's last window in {beside}",
+         "prefix_cache": "{beside} at the prefix's boundary stored with "
+                         "its pages: a window kind whose pages can be "
+                         "shared",
+         "spec_draft": "a ring whose newest pages overwrote the oldest "
+                       "cannot be rewound past them; a verify program over "
+                       "a ring with room for the proposals",
+         "tp": _EXCHANGE + ", not a head split, and no partition rule "
+               "shards a ring",
+         "kv_dtype": "and the kernels' window form takes a bf16 pool "
+                     "(ops/paged_attention.py)"},
+        slot_ring=True)
 
 
 def _laguna() -> ServingFamily:
     from ray_tpu.models import laguna
 
-    return _ring_family("laguna", laguna,
-                        "a window mask and per-layer-kind head counts")
+    return _ring(laguna, "a window mask and per-layer-kind head counts")
 
 
 def _mimo_v2() -> ServingFamily:
     from ray_tpu.models import mimo_v2
 
-    return _ring_family(
-        "mimo_v2", mimo_v2,
+    return _ring(
+        mimo_v2,
         "a window mask, per-layer-kind KV head counts, V heads narrower "
         "than K heads and a sink in the window layers' softmax")
 
 
 def _qwen3_next() -> ServingFamily:
-    from jax.sharding import PartitionSpec
-
     from ray_tpu.models import qwen3_next
 
-    state = ("the linear layers' recurrent state and convolution tail "
-             "(models/qwen3_next.py: a float32 matrix a head and layer, "
-             "12.9 MB a slot at the published sizes, indexed by slot)")
-    return ServingFamily(
-        name="qwen3_next", model=qwen3_next,
-        init_pool=qwen3_next.init_paged_kv,
-        pool_partition_rules=((r".*", PartitionSpec()),),
-        programs=lambda _tp, _mesh: {
-            name: getattr(qwen3_next, name) for name in (
-                "prefill_chunk_paged", "decode_step_paged",
-                "decode_multi_paged")},
-        slot_state=qwen3_next.SLOT_STATE_LEAVES, expert_counters=True,
-        lay_out=qwen3_next.lay_out,
-        unsupported=(
-            Unsupported(
-                "kv_mode", lambda o: o.kv_mode == "paged", "paged",
-                "the qwen3_next family serves from the paged pool only: "
-                "kv_mode='dense' would need a [L, B, T] cache backend for "
-                f"the full layers with {state} carried beside it"),
-            Unsupported(
-                "prefill_chunk", lambda o: o.prefill_chunk > 0, 128,
-                "the qwen3_next family has no one-shot prefill: "
-                "prefill_chunk=0 would need a whole-prompt program that "
-                "leaves the prompt's final recurrent state in the slot"),
-            Unsupported(
-                "prefill_width_bucketing",
-                lambda o: not o.prefill_width_bucketing, False,
-                "prefill_width_bucketing with the qwen3_next family: a "
-                "chunk program here costs a pass over every held expert's "
-                "weights at any table width, and one bucket a width "
-                "spreads a lone prompt's rows over more programs; a "
-                "dispatch that packs rows of several widths into one "
-                "program would have to be built"),
-            Unsupported(
-                "prefix_cache", lambda o: not o.prefix_cache, False,
-                "prefix_cache with the qwen3_next family: a cached prefix "
-                f"would need a snapshot of {state} at the prefix's "
-                "boundary, stored with its pages (serve/prefix_cache.py "
-                "keeps pages only)"),
-            Unsupported(
-                "spec_draft", lambda o: not o.spec_draft, "",
-                "speculative decoding with the qwen3_next family: a "
-                "rejected proposal rewinds the cursor, and a recurrence "
-                "cannot be run backwards; a verify program that returns "
-                f"{state} at every position to rewind to would have to be "
-                "built"),
-            Unsupported(
-                "kv_transfer", lambda o: not o.kv_transfer, False,
-                "KV page-set transfer with the qwen3_next family: a page "
-                f"set would have to carry {state} (serve/kv_objects.py "
-                "moves pages only)"),
-            Unsupported(
-                "tp", lambda o: int(o.tp) == 1, 1,
-                "tp > 1 with the qwen3_next family: 2 KV heads cannot "
-                "shard over more chips than heads, the experts need an "
-                "expert-parallel exchange of rows between chips "
-                "(ops/moe.py returns the held experts' part only), and no "
-                "partition rule splits the recurrent state by value head"),
-            Unsupported(
-                "weight_dtype", lambda o: o.weight_dtype != "int8", "bf16",
-                "weight_dtype='int8' with the qwen3_next family: "
-                "quantize_params knows the gpt tree's planes, and the "
-                "experts' grouped matmul (ops/moe.py) has no int8 form"),
-            Unsupported(
-                "kv_dtype", lambda o: o.kv_dtype != "int8", "bf16",
-                "kv_dtype='int8' with the qwen3_next family: the per-page "
-                "scale planes are kept by models/paged_kv._quant_write, "
-                "which the gated attention's K/V writer would have to "
-                "call, and the recurrent state is float32 by the model's "
-                "own definition"),
-        ))
+    return _paged_only(
+        qwen3_next,
+        "the linear layers' recurrent state and convolution tail "
+        "(models/qwen3_next.py: a float32 matrix a head and layer, "
+        "12.9 MB a slot at the published sizes, indexed by slot)",
+        {"kv_mode": "for the full layers with {beside} carried beside it",
+         "prefill_chunk": "the prompt's final recurrent state in the slot",
+         "prefix_cache": _SNAPSHOT,
+         "spec_draft": "a recurrence cannot be run backwards; " + _RETURNS,
+         "tp": "2 KV heads cannot shard over more chips than heads, "
+               + _EXCHANGE + ", and no partition rule splits the recurrent "
+               "state by value head",
+         "kv_dtype": "which the gated attention's K/V writer would have to "
+                     "call, and the recurrent state is float32 by the "
+                     "model's own definition"},
+        slot_state=qwen3_next.SLOT_STATE_LEAVES, lay_out=qwen3_next.lay_out)
 
 
 _FAMILIES = {"gpt": _gpt, "zaya": _zaya, "laguna": _laguna,

@@ -41,10 +41,11 @@ re-prefilled after a preemption, starts clean with nothing to clear.
 The block is written once (`_attn_inputs`, `_finish_block`); what
 differs between the full-sequence forward (tests, no cache), a prompt
 chunk and a decode step is where the boundary values come from and how
-attention reads K/V: the three callers below. The paged programs carry
-the names models/paged_kv.py gives its own (a trace finds a program by
-name), take the pool dict ``{"k", "v", "slot_state", "moe_counters"}``
-donated, and reuse paged_kv's layer scan, sampling and decode window.
+attention reads K/V: the three callers below. The four paged programs
+are `paged_kv.paged_programs` over the chunk forward and the decode step
+(names, donation and the decode window are its), and carry the pool dict
+``{"k", "v", "slot_state", "moe_counters"}``; the layer scan is
+paged_kv's too.
 """
 
 from __future__ import annotations
@@ -58,8 +59,11 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops import scopes
-from ray_tpu.models.paged_kv import (_decode_window, _no_phase, _sample_next,
-                                     _scan_pool_layers)
+from ray_tpu.models import blocks
+# The norm under the name benchmarks/tools/probe_zaya.py's router calls
+# it by, as an attribute of this module.
+from ray_tpu.models.blocks import rms_norm as _rms_norm
+from ray_tpu.models.paged_kv import paged_programs, scan_pool_layers
 from ray_tpu.ops.moe import token_choice_experts
 
 _F32 = jnp.float32
@@ -213,12 +217,6 @@ def num_params(cfg: ZayaConfig) -> int:
 
 # ------------------------------------------------------------- the block
 
-def _rms_norm(x, scale, eps):
-    x32 = x.astype(_F32)
-    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-    return (y * scale.astype(_F32)).astype(x.dtype)
-
-
 def _l2_normalise(x):
     """x / |x| over the last axis, float32; a zero vector stays zero."""
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-12)
@@ -343,12 +341,7 @@ def _embed(cfg: ZayaConfig, params, tokens):
     return x, jnp.zeros(tokens.shape + (cfg.router_dim,), _F32)
 
 
-@jax.named_scope(scopes.HEAD)
-def _head(cfg: ZayaConfig, params, x):
-    """Final RMSNorm and the tied head → float32 logits [..., V]."""
-    h = _rms_norm(x, params["ln_f_scale"], cfg.norm_eps)
-    return jnp.einsum("...d,vd->...v", h, params["wte"].astype(cfg.dtype),
-                      preferred_element_type=_F32)
+_head = functools.partial(blocks.tied_head, _rms_norm)
 
 
 # ------------------------------------------ full sequence (tests, no cache)
@@ -394,7 +387,7 @@ def init_paged_kv(cfg: ZayaConfig, n_pages: int, page_size: int,
     ``[L, P+1, page_size, G*K]`` (row 0 the null page; G KV heads, as
     ops/paged_attention.py reads them), the slot state
     ``[L, n_slots+1, state_width]`` (the last row the null slot) and the
-    decode steps' running expert counters (`_COUNTERS`)."""
+    decode steps' running expert counters (`COUNTERS`)."""
     if kv_dtype not in (None, "bf16"):
         raise ValueError(f"the zaya family's pool is bf16, got {kv_dtype!r}")
     shape = (cfg.n_layers, n_pages + 1, page_size,
@@ -403,27 +396,18 @@ def init_paged_kv(cfg: ZayaConfig, n_pages: int, page_size: int,
             "v": jnp.zeros(shape, cfg.dtype),
             "slot_state": jnp.zeros(
                 (cfg.n_layers, n_slots + 1, cfg.state_width), cfg.dtype),
-            "moe_counters": jnp.zeros(len(_COUNTERS), jnp.uint32)}
+            "moe_counters": jnp.zeros(len(COUNTERS), jnp.uint32)}
 
 
-# Running totals over decode steps, wrapping uint32 (the host takes
-# differences): (layer, step) pairs, experts that had a row, the fullest
-# expert's rows, rows routed.
-_COUNTERS = ("layer_steps", "experts_touched", "rows_max", "rows_routed")
+# `blocks.COUNTERS` without its last, `rows_held`: every expert is held
+# here, and a row has one choice.
+COUNTERS = blocks.COUNTERS[:-1]
 
 
 def _count(counts):
     return jnp.stack([jnp.uint32(1), jnp.sum(counts > 0).astype(jnp.uint32),
                       jnp.max(counts).astype(jnp.uint32),
                       jnp.sum(counts).astype(jnp.uint32)])
-
-
-@jax.named_scope(scopes.ATTN_KV_WRITE)
-def _write_kv(pool, l, pages, offs, k, v):
-    """K/V rows [M, G*K] → (l, pages[m], offs[m]) of the carried pool."""
-    rows = lambda t: t.reshape(-1, t.shape[-2] * t.shape[-1])
-    return {**pool, "k": pool["k"].at[l, pages, offs].set(rows(k)),
-            "v": pool["v"].at[l, pages, offs].set(rows(v))}
 
 
 @jax.named_scope(scopes.SLOT_STATE)
@@ -433,22 +417,6 @@ def _write_state(pool, l, rows, tails):
     new = jnp.concatenate([tails[n] for n in ("z", "c", "v")], axis=-1)
     return {**pool,
             "slot_state": pool["slot_state"].at[l, rows].set(new)}
-
-
-def _attend_fn(attn_impl: str, chunk: bool):
-    """The pool reader of a chunk row or of a decode step: the Pallas
-    kernel, or its gather oracle."""
-    from ray_tpu.ops.paged_attention import (
-        paged_attention, paged_prefill_attention, reference_paged_attention,
-        reference_paged_prefill_attention)
-
-    if attn_impl not in ("gather", "kernel"):
-        raise ValueError(
-            f"attn_impl must be gather|kernel, got {attn_impl!r}")
-    kernel, oracle = ((paged_prefill_attention,
-                       reference_paged_prefill_attention) if chunk else
-                      (paged_attention, reference_paged_attention))
-    return kernel if attn_impl == "kernel" else oracle
 
 
 def _chunk_forward(cfg: ZayaConfig, params, tokens, pool, tables, offsets,
@@ -486,7 +454,7 @@ def _chunk_forward(cfg: ZayaConfig, params, tokens, pool, tables, offsets,
             0).reshape(-1)
         write_offs = (pos % ps).reshape(-1)
     kv_lens = offsets + n_valid
-    attend = _attend_fn(attn_impl, chunk=True)
+    attend = blocks.attend_fn(attn_impl, chunk=True)
     x, r = _embed(cfg, params, tokens)
     stacked, experts = _stacked(cfg, params)
 
@@ -502,7 +470,7 @@ def _chunk_forward(cfg: ZayaConfig, params, tokens, pool, tables, offsets,
             return jnp.where((offsets == 0)[:, None], 0, before)
 
         q, k, v, tails = _attn_inputs(cfg, layer, x, pos, boundary)
-        pool = _write_kv(pool, l, write_pages, write_offs, k, v)
+        pool = blocks.write_kv(pool, l, write_pages, write_offs, k, v)
         with jax.named_scope(scopes.ATTN_KERNEL):
             attn = attend(q, pool["k"], pool["v"], l, tables, offsets,
                           kv_lens, sm_scale=1.0 / math.sqrt(cfg.head_dim))
@@ -513,30 +481,8 @@ def _chunk_forward(cfg: ZayaConfig, params, tokens, pool, tables, offsets,
                                       valid)
         return (x, r), pool
 
-    (x, _r), pool = _scan_pool_layers(body, (x, r), stacked, pool)
+    (x, _r), pool = scan_pool_layers(body, (x, r), stacked, pool)
     return x, pool
-
-
-@functools.partial(jax.jit, static_argnums=(0,),
-                   static_argnames=("return_logits", "attn_impl"),
-                   donate_argnums=(3,))
-def prefill_chunk_paged(cfg: ZayaConfig, params, tokens, pool, tables,
-                        offsets, n_valid, *, slots,
-                        return_logits: bool = True,
-                        attn_impl: str = "gather"):
-    """models/paged_kv.prefill_chunk_paged for this block, with `slots`
-    [N] int32: the slot each row belongs to (an inert row's is ignored).
-    → (last-valid-token logits [N, V] fp32 if return_logits else None,
-    updated pool). The head runs on each row's last valid hidden state
-    only: [N, C, V] at a 262k vocabulary is not a tensor to make."""
-    x, pool = _chunk_forward(cfg, params, tokens, pool, tables, offsets,
-                             n_valid, slots, attn_impl)
-    if not return_logits:
-        return None, pool
-    with jax.named_scope(scopes.HEAD):
-        last = jnp.take_along_axis(
-            x, jnp.maximum(n_valid - 1, 0)[:, None, None], axis=1)[:, 0]
-    return _head(cfg, params, last), pool
 
 
 def _decode_once(cfg: ZayaConfig, params, tokens, pool, positions, tables,
@@ -559,7 +505,7 @@ def _decode_once(cfg: ZayaConfig, params, tokens, pool, positions, tables,
             jnp.minimum(positions // ps, tables.shape[1] - 1)[:, None],
             axis=1)[:, 0]
         write_off = positions % ps
-    attend = _attend_fn(attn_impl, chunk=False)
+    attend = blocks.attend_fn(attn_impl, chunk=False)
     x, r = _embed(cfg, params, tokens[:, None])
     stacked, experts = _stacked(cfg, params)
 
@@ -569,7 +515,7 @@ def _decode_once(cfg: ZayaConfig, params, tokens, pool, positions, tables,
             state = pool["slot_state"][l, :B]
         q, k, v, tails = _attn_inputs(
             cfg, layer, x, pos, lambda name, _full: state[:, sl[name]])
-        pool = _write_kv(pool, l, write_page, write_off, k, v)
+        pool = blocks.write_kv(pool, l, write_page, write_off, k, v)
         with jax.named_scope(scopes.ATTN_KERNEL):
             attn = attend(q[:, 0], pool["k"], pool["v"], l, tables,
                           positions + 1,
@@ -582,53 +528,14 @@ def _decode_once(cfg: ZayaConfig, params, tokens, pool, positions, tables,
             counters = counters + _count(counts)
         return (x, r, counters), pool
 
-    (x, _r, counters), pool = _scan_pool_layers(
+    (x, _r, counters), pool = scan_pool_layers(
         body, (x, r, pool["moe_counters"]), stacked, pool)
     return _head(cfg, params, x[:, 0]), {**pool, "moe_counters": counters}
 
 
-@functools.partial(jax.jit, static_argnums=(0,),
-                   static_argnames=("attn_impl",), donate_argnums=(3,))
-def decode_step_paged(cfg: ZayaConfig, params, tokens, pool, positions,
-                      tables, *, attn_impl: str = "gather"):
-    """One token for every slot. → (logits [B, V] fp32, updated pool)."""
-    return _decode_once(cfg, params, tokens, pool, positions, tables,
-                        attn_impl)
-
-
-@functools.partial(jax.jit, static_argnums=(0,),
-                   static_argnames=("attn_impl",), donate_argnums=(3,))
-def _decode_sample_paged(cfg: ZayaConfig, params, tokens, pool, positions,
-                         tables, temps, key, *, attn_impl: str = "gather"):
-    """One decode-window step: `_decode_once` + on-device sampling."""
-    logits, pool = _decode_once(cfg, params, tokens, pool, positions, tables,
-                                attn_impl)
-    nxt, _scaled, key = _sample_next(logits, temps, key)
-    return nxt, positions + 1, pool, key
-
-
-def decode_multi_paged(cfg: ZayaConfig, params, tokens, pool, positions,
-                       tables, n_steps: int, temps, key, *,
-                       attn_impl: str = "gather", phase=_no_phase,
-                       counters=None, carried=None, ahead=None):
-    """models/paged_kv.decode_multi_paged for this block: the shared
-    `_decode_window` of this family's step program. `counters(dict)`
-    (optional) is handed the pool's running expert counters as they
-    stand after the window's `n_steps`, fetched WITH the window's tokens
-    (what the step `ahead` asks for counts arrives with the next
-    window's)."""
-
-    def step(toks, kv, pos, rng):
-        return _decode_sample_paged(cfg, params, toks, kv, pos, tables,
-                                    temps, rng, attn_impl=attn_impl)
-
-    toks_out, pool, totals = _decode_window(
-        step, tokens, pool, positions, n_steps, key, phase,
-        also=lambda pool: pool["moe_counters"], carried=carried,
-        ahead=ahead)
-    if counters is not None:
-        counters(dict(zip(_COUNTERS, (int(t) for t in totals))))
-    return toks_out, pool
+(prefill_chunk_paged, decode_step_paged, _decode_sample_paged,
+ decode_multi_paged) = paged_programs(
+    _chunk_forward, _decode_once, blocks.last_token_logits(_head), COUNTERS)
 
 
 __all__ = [

@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from ray_tpu.models import gpt, paged_kv
+from ray_tpu.models import gpt, paged_kv, serving
 from ray_tpu.serve import llm
 from ray_tpu.serve.llm import GenRequest, LLMEngine
 
@@ -204,6 +204,62 @@ def test_every_program_regex_names_a_jitted_function():
         for literal in _alternatives(args[0]):
             assert any(literal in n for n in jitted), (metric, literal)
     assert seen >= 4
+
+
+@pytest.mark.parametrize("family", sorted(serving._FAMILIES))
+def test_every_familys_programs_are_jitted_under_their_roles(family):
+    """The trace's program regexes are the same for every cell: each
+    family's chunk, decode-step and window-step program is a jitted
+    function whose name IS its role (`jit_<name>` on the device)."""
+    fam = serving._family(family)
+    module = paged_kv if family == "gpt" else fam.model
+    for role in ("prefill_chunk_paged", "decode_step_paged",
+                 "_decode_sample_paged"):
+        fn = getattr(module, role)
+        assert hasattr(fn, "lower") and hasattr(fn, "__wrapped__"), role
+        assert fn.__name__ == role
+    bound = fam.programs(1, None)
+    for role in ("prefill_chunk_paged", "decode_step_paged",
+                 "decode_multi_paged"):
+        assert bound[role] is getattr(module, role), role
+    assert module.decode_multi_paged.__name__ == "decode_multi_paged"
+
+
+def test_probes_patch_globals_that_the_model_modules_read():
+    """The probe tools (benchmarks/tools/probe_*.py) put a fault in by
+    rebinding a global of a model module. That works only while the
+    module's own code reads the global by that name at trace time: a
+    helper moved to another module, or called through another binding,
+    turns the probe into a run of the true program that reports
+    `correct`. Every `<module>.<name> = ...` names a global the module
+    has and some function of it loads; every other `<module>.<name>` a
+    probe reads exists."""
+    import importlib
+
+    rebound, read = set(), set()
+    for path in sorted(glob.glob(os.path.join(BENCH, "tools", "probe_*.py"))):
+        tree = _tree(path)
+        modules = {a.asname or a.name for node in ast.walk(tree)
+                   if isinstance(node, ast.ImportFrom)
+                   and node.module == "ray_tpu.models" for a in node.names}
+        for node in ast.walk(tree):
+            if (isinstance(node, ast.Attribute)
+                    and getattr(node.value, "id", None) in modules):
+                (rebound if isinstance(node.ctx, ast.Store) else read).add(
+                    (node.value.id, node.attr))
+    assert {("laguna", "_attend_fn"), ("zaya", "_route"),
+            ("mimo_v2", "_sink"), ("qwen3_next", "gdn_decode_step")} <= rebound
+    assert ("zaya", "_rms_norm") in read
+    for module_name in sorted({m for m, _name in rebound | read}):
+        module = importlib.import_module("ray_tpu.models." + module_name)
+        source = ast.parse(inspect.getsource(module))
+        loads = {n.id for fn in ast.walk(source)
+                 if isinstance(fn, ast.FunctionDef) for n in ast.walk(fn)
+                 if isinstance(n, ast.Name) and isinstance(n.ctx, ast.Load)}
+        for m, name in sorted(rebound | read):
+            if m == module_name:
+                assert hasattr(module, name), (m, name)
+                assert (m, name) in read or name in loads, (m, name)
 
 
 def test_tools_take_names_from_paged_kv_that_exist():

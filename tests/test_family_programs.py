@@ -1,0 +1,94 @@
+"""Every family's chunk, decode-step and window-step program, letter for
+letter.
+
+The serving programs of the five families of models/serving.py are built
+by one builder (models/paged_kv.py `paged_programs`) from parts that
+several families share (models/blocks.py). A refactor of either must
+leave every lowered program as it was: the digests below were computed
+BEFORE the builder and the shared module existed, by this very code, and
+none is edited by a change that claims to move no number.
+"""
+
+import hashlib
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from ray_tpu.models import gpt, laguna, mimo_v2, paged_kv, qwen3_next, zaya
+
+PAGE, N_PAGES, N_SLOTS, CHUNK = 16, 24, 3, 16
+
+# sha256 (first 16 hex digits) of `str(jax.make_jaxpr(program))`,
+# addresses blanked, of each family's chunk program, decode-step program
+# and decode window's step (`sample`: the step with sampling on the
+# device) at its tiny size with the kernels on. The first eight as commit
+# 02951b4 traced them (the commit before the mimo_v2 family), the mimo_v2
+# pair and the five `sample` programs as commit c3726bb did (the commit
+# before the builder).
+_PINNED = {
+    "gpt.chunk": "aff570174e390475", "gpt.decode": "c4ebbb44ff02a83f",
+    "zaya.chunk": "e3c3c03e1e115475", "zaya.decode": "21818dedb3b70792",
+    "laguna.chunk": "9baeed72a093a253", "laguna.decode": "2a9da728856d4537",
+    "qwen3_next.chunk": "ffb23a00a1eeb73d",
+    "qwen3_next.decode": "36f158373030eabf",
+    "mimo_v2.chunk": "9b0c5bf47a63f542",
+    "mimo_v2.decode": "f65efd374bbc0153",
+    "gpt.sample": "1b3422cefe05e0e4", "zaya.sample": "1f1f708fe2e317cd",
+    "laguna.sample": "1a557bccce429947",
+    "qwen3_next.sample": "a023e156a1cb54d9",
+    "mimo_v2.sample": "cd452c05dcdd0a15",
+}
+
+_RING = {"dispatch_tokens": 2 * CHUNK}
+# family -> (the module of its programs, its model module, a tiny
+# configuration, init_paged_kv's keywords beside the sizes).
+_FAMILIES = {
+    "gpt": (paged_kv, gpt, gpt.GPTConfig.tiny(), None),
+    "zaya": (zaya, zaya, zaya.ZayaConfig.tiny(), {}),
+    "laguna": (laguna, laguna, laguna.LagunaConfig.tiny(), _RING),
+    "qwen3_next": (qwen3_next, qwen3_next,
+                   qwen3_next.Qwen3NextConfig.tiny(), {}),
+    "mimo_v2": (mimo_v2, mimo_v2, mimo_v2.MiMoV2Config.tiny(), _RING),
+}
+
+
+def _traced(program: str):
+    """The jaxpr of `<family>.<chunk|decode|sample>` at tiny size."""
+    name, which = program.split(".")
+    mod, model, cfg, pool_kw = _FAMILIES[name]
+    i32 = lambda *s: jnp.zeros(s, jnp.int32)
+    zeros = lambda tree: jax.tree.map(
+        lambda a: jnp.zeros(a.shape, a.dtype), tree)
+    params = zeros(jax.eval_shape(
+        lambda: model.init_params(cfg, jax.random.key(0))))
+    pool = zeros(jax.eval_shape(
+        (lambda: paged_kv.init_paged_kv(cfg, N_PAGES, PAGE))
+        if pool_kw is None else
+        (lambda: mod.init_paged_kv(cfg, N_PAGES, PAGE, N_SLOTS, **pool_kw))))
+    if which == "chunk":
+        kw = {} if name == "gpt" else {"slots": i32(2)}
+        fn = lambda p, kv: mod.prefill_chunk_paged.__wrapped__(
+            cfg, p, i32(2, CHUNK), kv, i32(2, 8), i32(2), i32(2),
+            attn_impl="kernel", **kw)
+    elif which == "decode":
+        fn = lambda p, kv: mod.decode_step_paged.__wrapped__(
+            cfg, p, i32(N_SLOTS), kv, i32(N_SLOTS), i32(N_SLOTS, 8),
+            attn_impl="kernel")
+    else:
+        fn = lambda p, kv: mod._decode_sample_paged.__wrapped__(
+            cfg, p, i32(N_SLOTS), kv, i32(N_SLOTS), i32(N_SLOTS, 8),
+            jnp.zeros(N_SLOTS, jnp.float32), jax.random.PRNGKey(0),
+            attn_impl="kernel")
+    return jax.make_jaxpr(fn)(params, pool)
+
+
+def _digest(program: str) -> str:
+    text = re.sub(r"0x[0-9a-f]+", "0x", str(_traced(program)))
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("program", sorted(_PINNED))
+def test_every_family_gets_exactly_the_pinned_program(program):
+    assert _digest(program) == _PINNED[program]

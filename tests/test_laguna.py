@@ -135,7 +135,7 @@ def test_the_shares_add_up(params):
                 w["w_down"][half], first_expert=first)
             parts.append(y)
             held += int(counts.sum())
-        shared = laguna._gated_mlp(u, w["s_gate"], w["s_up"], w["s_down"])
+        shared = laguna.gated_mlp(u, w["s_gate"], w["s_up"], w["s_down"])
     assert held == u.shape[0] * whole.top_k         # every choice, once
     assert float(jnp.abs(parts[0]).max()) > 1e-3 < float(
         jnp.abs(parts[1]).max())

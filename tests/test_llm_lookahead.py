@@ -184,7 +184,7 @@ def test_decode_window_queues_one_more_step_and_pulls_only_its_own(
     assert carried.tolist() == [105, 105] and key == k + 1
     # The carried values come back with the next call's, as its first row.
     rec.events.clear()
-    toks, pool = paged_kv._decode_window(
+    toks, pool, _ = paged_kv._decode_window(
         rec.step, carried, pool, np.zeros(2, np.int32), k - 1, key,
         carried=carried)
     assert [e[0] for e in rec.events] == ["step"] * (k - 1) + ["get"]
@@ -194,7 +194,7 @@ def test_decode_window_queues_one_more_step_and_pulls_only_its_own(
 def test_decode_window_without_ahead_is_the_old_window(monkeypatch):
     rec = _Recorder()
     monkeypatch.setattr(paged_kv.jax, "device_get", rec.device_get)
-    toks, pool = paged_kv._decode_window(
+    toks, pool, _ = paged_kv._decode_window(
         rec.step, np.zeros(2, np.int32), {"c": np.uint32(0)},
         np.zeros(2, np.int32), 3, 0)
     assert [e[0] for e in rec.events] == ["step"] * 3 + ["get"]
@@ -330,7 +330,7 @@ class TestEveryFamily:
         s = eng.stats
         assert eng._carry is None and s["lookahead_windows"] >= 2
         names = {"zaya": zaya, "laguna": laguna, "qwen3_next": qwen3_next,
-                 "mimo_v2": mimo_v2}[name]._COUNTERS
+                 "mimo_v2": mimo_v2}[name].COUNTERS
         device = dict(zip(names, (int(t) for t in eng.cache["moe_counters"])))
         seen = eng._moe_seen
         for stat, counter in (("moe_layer_steps", "layer_steps"),

@@ -147,7 +147,7 @@ def test_the_shares_add_up(params):
             parts.append(y)
             held += int(counts.sum())
         shared = (qn._shared_gate(u, w["s_gate_w"])
-                  * qn._gated_mlp(u, w["s_gate"], w["s_up"], w["s_down"]))
+                  * qn.gated_mlp(u, w["s_gate"], w["s_up"], w["s_down"]))
     assert held == u.shape[0] * whole.top_k         # every choice, once
     assert all(float(jnp.abs(y).max()) > 1e-3 for y in parts)
     np.testing.assert_allclose(sum(parts) + shared, want, atol=1e-5, rtol=0)
