@@ -24,7 +24,7 @@ configuration's own sizes (bf16, 2 B a parameter):
   decode_bytes_per_live_expert one routed expert's three matrices
       (3 D F) times the sparse layers: multiplied by the MEAN number of
       held experts that had a row in a layer of a step
-      (`experts_touched.codegen`, the program's counter), so that a
+      (`experts_touched`, the program's counter), so that a
       roofline share counts only experts a token reached and errs low
       (the layer streams all it holds).
   decode_bytes_per_kv_token    K and V of one cached token in the FULL
@@ -166,6 +166,9 @@ def serve_consts(config: dict) -> dict:
         "decode_bytes_per_kv_token": per["n_full"] * kv_token,
         "decode_bytes_per_window_slot":
             per["n_window"] * d["window"] * kv_token,
+        # `decode_stream_roofline` sums five byte terms and reads nothing
+        # where one is missing: this family has no recurrent state.
+        "decode_bytes_per_state_slot": 0.0,
     }
 
 

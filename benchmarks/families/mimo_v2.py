@@ -29,7 +29,7 @@ parameter; a pool that padded a head would still be counted at 192 and
   decode_bytes_per_live_expert one routed expert's three matrices
       (3 D F) times the sparse layers: multiplied by the MEAN number of
       held experts that had a row in a layer of a step
-      (`experts_touched.think`, the program's counter), so that a
+      (`experts_touched`, the program's counter), so that a
       roofline share counts only experts a token reached and errs low
       (the layer streams all it holds).
   decode_bytes_per_kv_token    K and V of one cached token in the FULL
@@ -170,6 +170,9 @@ def serve_consts(config: dict) -> dict:
         "decode_bytes_per_kv_token": per["n_full"] * token(d["n_kv_heads"]),
         "decode_bytes_per_window_slot":
             per["n_window"] * d["window"] * token(d["n_kv_heads_window"]),
+        # `decode_stream_roofline` sums five byte terms and reads nothing
+        # where one is missing: this family has no recurrent state.
+        "decode_bytes_per_state_slot": 0.0,
     }
 
 

@@ -23,7 +23,7 @@ sizes (bf16 weights and pages, 2 B; the recurrent state float32, 4 B):
   decode_bytes_per_live_expert  one routed expert's three matrices
       (3 D F) times the layers: multiplied by the MEAN number of held
       experts that had a row in a layer of a step
-      (`experts_touched.longform`), so a roofline share errs low.
+      (`experts_touched`), so a roofline share errs low.
   decode_bytes_per_kv_token     K and V of one cached token in the full
       layers: full layers x 2 x KV heads x head size.
   decode_bytes_per_state_slot   one decoding slot's recurrent state and
@@ -162,6 +162,10 @@ def serve_consts(config: dict) -> dict:
             BYTES * d["n_layers"] * per["expert"],
         "decode_bytes_per_kv_token":
             per["n_full"] * BYTES * 2 * d["n_kv_heads"] * d["head_dim"],
+        # `decode_stream_roofline` sums five byte terms and reads nothing
+        # where one is missing: this family has no window layer (its two
+        # attention layers read every cached token).
+        "decode_bytes_per_window_slot": 0.0,
         "decode_bytes_per_state_slot": per["n_linear"] * 2 * (
             STATE_BYTES * per["state"] + BYTES * per["tail"]),
         "chunk_scan_bytes_per_token": per["n_linear"] * (

@@ -13,7 +13,7 @@ configuration's own sizes (bf16, 2 B a parameter):
       embedding as the head's [V, D] matrix. No expert.
   decode_bytes_per_live_expert one expert's three matrices (3 D F) times
       the layers: multiplied by the MEAN number of experts that had a
-      row in a layer of a step (`experts_touched.reason`, the program's
+      row in a layer of a step (`experts_touched`, the program's
       counter), so that a roofline share counts only experts a token
       reached and errs low (the layer streams all it holds).
   decode_bytes_per_kv_token    K and V of one cached token: layers x 2 x
@@ -133,6 +133,12 @@ def serve_consts(config: dict) -> dict:
         "decode_bytes_per_live_expert": BYTES * L * per["expert"],
         "decode_bytes_per_kv_token":
             BYTES * L * 2 * d["n_kv_heads"] * d["head_dim"],
+        # `decode_stream_roofline` sums five byte terms and reads nothing
+        # where one is missing: this family has no window layer (every
+        # layer reads every cached token) and no recurrent state (the
+        # conv/shift state is 5.4 KB a slot and layer, left out above).
+        "decode_bytes_per_window_slot": 0.0,
+        "decode_bytes_per_state_slot": 0.0,
     }
 
 

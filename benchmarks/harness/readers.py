@@ -9,6 +9,18 @@ BENCHMARK.json and looks each file up by the entry's name. A reader that
 finds nothing to read returns None and the metric is left out of the
 line.
 
+A metric's name says WHAT is read, its entry's `workloads` WHERE (PR 52):
+`decode_program_dev_ms` is one entry and one file, read in every cell
+the entry lists, and a new cell joins it by its name in that list. No
+reader knows a cell: what differs by family comes through `consts`, and
+a sibling is named by its stem (`m['experts_touched']`). Only an entry
+whose cell reports an end-to-end quantity of its own keeps a suffix
+(`decode_program_dev_ms.batch` moves `out_tokens_per_s.batch`,
+`attn_kernel_share.train` moves `train_tokens_per_s`): an entry has one
+`moves`. A file says what holds in every cell that lists it; what is
+true of one family stays in families/<family>.py beside its constants.
+tools/check_contract.py holds the rule.
+
 `ctx` is what a run hands its readers:
   engine    dict, `LLMEngine.metrics()` at the window's end (counters
             reset at its start), plus `compiles_in_window`

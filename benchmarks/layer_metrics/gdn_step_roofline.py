@@ -1,0 +1,27 @@
+"""gdn_step_roofline: the decode step's update of the recurrent state
+against the HBM roofline.
+
+    decoding slots x `decode_bytes_per_state_slot` / the HBM peak
+    ---------------------------------------------------------------  x 100
+    `gdn.scan`'s milliseconds a decode step
+
+Bytes: every decoding slot's state and convolution tail in the linear
+layers, read AND written (the family's `serve_consts`), the slots
+sampled inside the traced interval. Time: by SCOPE
+(harness/scope_times.py), so it reads the same work whatever implements
+it. An idle slot moves nothing and counts nowhere.
+"""
+
+from harness import scope_times
+from harness.kernel_roofline import traced_mean
+
+
+def read(ctx):
+    per = (ctx.get("consts") or {}).get("decode_bytes_per_state_slot")
+    peak = (ctx.get("peaks") or {}).get("hbm_bytes_per_s")
+    slots = traced_mean(ctx, "decoding_slots")
+    if (not per or not peak or not slots
+            or "gdn.scan" not in scope_times.vocabulary()):
+        return None
+    ms = scope_times.ms_a_run(ctx, scope_times.DECODE, ("gdn.scan",))
+    return slots * per / peak / (ms / 1e3) * 100.0 if ms else None

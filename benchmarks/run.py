@@ -23,11 +23,13 @@ the measured window.
 Everything that belongs to one configuration, one traffic mix or one
 per-layer metric is a file found by the name BENCHMARK.json gives it:
 configs/<config>.json, traffic/<mix>.json, layer_metrics/<metric>.json
-(or .py). What belongs to a model class (the program's configuration
-object and model module, the plain reference, the bytes and operations
-a step must do) is the configuration's FAMILY, families/<family>.py, and
-the reference file the configuration names (harness/families.py). Adding
-a cell, a metric or an architecture is adding files and entries.
+(or .py; a metric's name says what is read, its entry's `workloads` in
+which cells: harness/readers.py). What belongs to a model class (the
+program's configuration object and model module, the plain reference,
+the bytes and operations a step must do) is the configuration's FAMILY,
+families/<family>.py, and the reference file the configuration names
+(harness/families.py). Adding a cell, a metric or an architecture is
+adding files and entries.
 """
 
 from __future__ import annotations

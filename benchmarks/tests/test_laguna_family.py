@@ -18,7 +18,7 @@ import sys
 import pytest
 
 import util
-from harness import configs, families, readers
+from harness import configs, families, readers, scope_times
 
 CELL = "laguna-s-2.1.codegen"
 
@@ -114,13 +114,13 @@ def test_the_family_serves_through_the_harness_at_tiny_size(real, tmp_path):
     program's counters are in the line."""
     _bench, config, _family, _ref = real
     root = util.make_root(str(tmp_path))
-    counters = [("experts_touched.codegen", "count"),
-                ("expert_rows_max.codegen", "ratio"),
-                ("expert_rows_held_share.codegen", "%"),
-                ("slot_occupancy.codegen", "%"),
-                ("kv_pool_fill.codegen", "%"),
-                ("compiles_in_window.codegen", "count"),
-                ("preemptions.codegen", "count")]
+    counters = [("experts_touched", "count"),
+                ("expert_rows_max", "ratio"),
+                ("expert_rows_held_share", "%"),
+                ("slot_occupancy", "%"),
+                ("kv_pool_fill", "%"),
+                ("compiles_in_window", "count"),
+                ("preemptions", "count")]
     cell = util.add_cell(
         root, tiny_config(config), "batch", ["out_tokens_per_s"],
         [{"name": n, "unit": u, "moves": "out_tokens_per_s"}
@@ -131,9 +131,9 @@ def test_the_family_serves_through_the_harness_at_tiny_size(real, tmp_path):
     assert any("reference check over" in ln and ": ok" in ln
                for ln in got["log"])
     value = lambda n: out["metrics"]["cpu_rehearsal." + n]["value"]
-    assert 1.0 <= value("experts_touched.codegen") <= 4
-    assert 20.0 < value("expert_rows_held_share.codegen") < 80.0
-    assert value("preemptions.codegen") == 0
+    assert 1.0 <= value("experts_touched") <= 4
+    assert 20.0 < value("expert_rows_held_share") < 80.0
+    assert value("preemptions") == 0
     # No device plane in a CPU trace: trace-sourced metrics are left out.
     assert not any("roofline" in n or "dev_ms" in n for n in out["metrics"])
 
@@ -179,11 +179,35 @@ def _context(family, config) -> dict:
     }
 
 
-def test_every_metric_of_the_cell_reads_a_synthetic_context(real):
+# What the scope reducer would make of the synthetic trace: seconds by
+# scope in the two programs (harness/scope_times.scope_times' table).
+_TABLE = {
+    "busy_s": 2.7,
+    "programs": {
+        "jit__decode_sample_paged": {
+            "runs": 100, "total_s": 2.6, "by_pass": {}, "unscoped_s": 0.004,
+            "by_scope": {"attn.in": 0.09, "attn.out": 0.034,
+                         "attn.kernel": 0.70, "attn.kv_write": 0.011,
+                         "mlp": 0.036, "moe.route": 0.038,
+                         "moe.experts": 1.40, "head": 0.041,
+                         "sample": 0.009}},
+        "jit_prefill_chunk_paged": {
+            "runs": 4, "total_s": 0.1, "by_pass": {}, "unscoped_s": 0.001,
+            "by_scope": {"attn.in": 0.010, "attn.out": 0.006,
+                         "attn.kernel": 0.025, "moe.route": 0.008,
+                         "moe.experts": 0.050}}},
+}
+
+
+def test_every_metric_of_the_cell_reads_a_synthetic_context(real,
+                                                            monkeypatch):
     bench, config, family, _ref = real
     entries = configs.metrics_for_cell(bench, "per_layer", CELL)
-    assert entries and all(m["workloads"] == [CELL] for m in entries)
+    assert entries and all(CELL in m["workloads"] for m in entries)
+    assert all(m["moves"] == "out_tokens_per_s" and "." not in m["name"]
+               for m in entries)
     ctx = _context(family, config)
+    monkeypatch.setattr(scope_times, "for_run", lambda _ctx: _TABLE)
     got = {n: v["value"] for n, v in readers.read_all(
         configs.metrics_dirs(util.REPO, bench), entries, ctx,
         {"out_tokens_per_s": 2000.0}).items()}
@@ -191,37 +215,46 @@ def test_every_metric_of_the_cell_reads_a_synthetic_context(real):
     # (it is left out, not 0); PR 36's twenty all read it
     assert set(got) <= {m["name"] for m in entries}
     c, peak = ctx["consts"], 819e9
+    scoped = sum(sum(p["by_scope"].values())
+                 for p in _TABLE["programs"].values())
     want = {
-        "decode_program_dev_ms.codegen": 26.0,
-        "prefill_program_dev_ms.codegen": 25.0,
-        "decode_step_ms.codegen": 21.5,
-        "prefill_tokens_per_s.codegen": 9000.0,
-        "slot_occupancy.codegen": 97.0,
-        "kv_pool_fill.codegen": 75.0,
-        "compiles_in_window.codegen": 0.0,
-        "tick_host_share.codegen": 1.2,
-        "device_idle_share.codegen": (1 - 2.7 / 2.8) * 100,
-        "preemptions.codegen": 0.0,
-        "moe_expert_share.codegen": (1.40 + 0.02 + 0.050) / 2.7 * 100,
-        "experts_touched.codegen": 110.0,
-        "expert_rows_max.codegen": 3.5,
-        "expert_rows_held_share.codegen": 50.0,
+        "decode_program_dev_ms": 26.0,
+        "prefill_program_dev_ms": 25.0,
+        "decode_step_ms": 21.5,
+        "prefill_tokens_per_s": 9000.0,
+        "slot_occupancy": 97.0,
+        "kv_pool_fill": 75.0,
+        "compiles_in_window": 0.0,
+        "tick_host_share": 1.2,
+        "device_idle_share": (1 - 2.7 / 2.8) * 100,
+        "preemptions": 0.0,
+        "moe_expert_share": (1.40 + 0.02 + 0.050) / 2.7 * 100,
+        "experts_touched": 110.0,
+        "expert_rows_max": 3.5,
+        "expert_rows_held_share": 50.0,
         # the kernel alone (not its metadata), a decode step
-        "moe_expert_roofline.codegen":
+        "moe_expert_roofline":
             110.0 * c["decode_bytes_per_live_expert"] / peak / 0.0140 * 100,
-        "attn_kernel_share.codegen":
+        "attn_kernel_share":
             (0.30 + 0.40 + 0.010 + 0.015) / 2.7 * 100,
-        "window_attn_share.codegen": (0.30 + 0.010) / 2.7 * 100,
+        "window_attn_share": (0.30 + 0.010) / 2.7 * 100,
         # samples of the TRACED interval: 64 slots, 128,000 tokens
-        "window_attn_roofline.codegen":
+        "window_attn_roofline":
             64 * c["decode_bytes_per_window_slot"] / peak / 0.0030 * 100,
-        "full_attn_roofline.codegen":
+        "decode_attn_roofline":
             128_000 * c["decode_bytes_per_kv_token"] / peak / 0.0040 * 100,
-        "decode_stream_roofline.codegen": (
+        "decode_stream_roofline": (
             c["decode_bytes_weights"]
             + 110.0 * c["decode_bytes_per_live_expert"]
             + 128_000 * c["decode_bytes_per_kv_token"]
             + 64 * c["decode_bytes_per_window_slot"]) / peak / 0.026 * 100,
+        # the pairs PR 52 appended to entries the cell had been denied
+        "decode_block_fill": 80.0,
+        "decode_live_column_share": 60.0,
+        "moe_route_ms": 0.38,
+        "head_ms": (0.041 + 0.009) / 100 * 1000,
+        "decode_dense_ms": (0.09 + 0.034) / 100 * 1000,
+        "scope_coverage": scoped / 2.7 * 100,
     }
     assert set(want) <= set(got)
     for name, value in want.items():
@@ -244,9 +277,9 @@ def test_over_a_program_without_the_new_spans_the_readers_return_nothing(real):
     entries = configs.metrics_for_cell(bench, "per_layer", CELL)
     got = readers.read_all(configs.metrics_dirs(util.REPO, bench), entries,
                            ctx, {"out_tokens_per_s": 2000.0})
-    assert not {"window_attn_roofline.codegen",
-                "expert_rows_held_share.codegen"} & set(got)
-    assert got["window_attn_share.codegen"]["value"] == 0.0
+    assert not {"window_attn_roofline",
+                "expert_rows_held_share"} & set(got)
+    assert got["window_attn_share"]["value"] == 0.0
     ctx["trace"] = None
     got = readers.read_all(configs.metrics_dirs(util.REPO, bench), entries,
                            ctx, {"out_tokens_per_s": 2000.0})

@@ -171,10 +171,10 @@ def test_the_family_serves_through_the_harness_at_tiny_size(real, tmp_path):
     assert any("reference check over" in ln and ": ok" in ln
                for ln in got["log"])
     value = lambda n: out["metrics"]["cpu_rehearsal." + n]["value"]
-    assert 1.0 <= value("experts_touched.think") <= 4
-    assert 0.0 < value("router_bias_moved.think") < 50.0
-    assert value("preemptions.think") == 0
-    assert 0.0 < value("window_ring_live_share.think") < 100.0
+    assert 1.0 <= value("experts_touched") <= 4
+    assert 0.0 < value("router_bias_moved") < 50.0
+    assert value("preemptions") == 0
+    assert 0.0 < value("window_ring_live_share") < 100.0
     # No device plane in a CPU trace: trace-sourced metrics are left out.
     assert not any("roofline" in n or "dev_ms" in n for n in out["metrics"])
 
@@ -246,8 +246,9 @@ def test_every_metric_of_the_cell_reads_a_synthetic_context(real,
     add to it), with hand arithmetic for the entries this PR brought."""
     bench, config, family, _ref = real
     entries = configs.metrics_for_cell(bench, "per_layer", CELL)
-    assert entries and all(m["workloads"] == [CELL] for m in entries)
-    assert all(m["moves"] == "out_tokens_per_s" for m in entries)
+    assert entries and all(CELL in m["workloads"] for m in entries)
+    assert all(m["moves"] == "out_tokens_per_s" and "." not in m["name"]
+               for m in entries)
     ctx = _context(family, config)
     monkeypatch.setattr(scope_times, "for_run", lambda _ctx: _TABLE)
     got = {n: v["value"] for n, v in readers.read_all(
@@ -255,30 +256,45 @@ def test_every_metric_of_the_cell_reads_a_synthetic_context(real,
         {"out_tokens_per_s": 7000.0}).items()}
     assert set(got) <= {m["name"] for m in entries}
     c, peak = ctx["consts"], 819e9
+    scoped = sum(sum(p["by_scope"].values())
+                 for p in _TABLE["programs"].values())
     want = {
-        "decode_program_dev_ms.think": 17.0,
-        "slot_occupancy.think": 99.0,
-        "preemptions.think": 0.0,
-        "device_idle_share.think": (1 - 1.8 / 1.9) * 100,
-        "attn_kernel_share.think": (0.10 + 0.40 + 0.010 + 0.015) / 1.8 * 100,
-        "moe_expert_share.think": (0.70 + 0.02 + 0.050) / 1.8 * 100,
-        "experts_touched.think": 15.5,
+        "decode_program_dev_ms": 17.0,
+        "slot_occupancy": 99.0,
+        "preemptions": 0.0,
+        "device_idle_share": (1 - 1.8 / 1.9) * 100,
+        "attn_kernel_share": (0.10 + 0.40 + 0.010 + 0.015) / 1.8 * 100,
+        "moe_expert_share": (0.70 + 0.02 + 0.050) / 1.8 * 100,
+        "experts_touched": 15.5,
         # the kernel alone (not its metadata), a decode step
-        "moe_expert_roofline.think":
+        "moe_expert_roofline":
             15.5 * c["decode_bytes_per_live_expert"] / peak / 0.0070 * 100,
         # samples of the TRACED interval: 128 slots, 420,000 tokens
-        "window_attn_roofline.think":
+        "window_attn_roofline":
             128 * c["decode_bytes_per_window_slot"] / peak / 0.0010 * 100,
-        "full_attn_roofline.think":
+        "decode_attn_roofline":
             420_000 * c["decode_bytes_per_kv_token"] / peak / 0.0040 * 100,
-        "decode_stream_roofline.think": (
+        "decode_stream_roofline": (
             c["decode_bytes_weights"]
             + 15.5 * c["decode_bytes_per_live_expert"]
             + 420_000 * c["decode_bytes_per_kv_token"]
             + 128 * c["decode_bytes_per_window_slot"]) / peak / 0.017 * 100,
-        "attn_proj_ms.think": (0.16 + 0.09) / 100 * 1000,
-        "router_bias_moved.think": 2.3,
-        "window_ring_live_share.think": 10.5,
+        "decode_dense_ms": (0.16 + 0.09) / 100 * 1000,
+        "router_bias_moved": 2.3,
+        "window_ring_live_share": 10.5,
+        # ISSUE 48's twelve, which found no room then: appended in PR 52
+        "prefill_program_dev_ms": 25.0,
+        "decode_step_ms": 17.5,
+        "prefill_tokens_per_s": 9000.0,
+        "kv_pool_fill": 75.0,
+        "compiles_in_window": 0.0,
+        "tick_host_share": 1.2,
+        "scope_coverage": scoped / 1.8 * 100,
+        "window_attn_share": (0.10 + 0.010) / 1.8 * 100,
+        "expert_rows_max": 2.5,
+        "expert_rows_held_share": 6.4,
+        "moe_route_ms": 0.8,
+        "head_ms": (0.10 + 0.02) / 100 * 1000,
     }
     assert set(want) <= set(got)
     for name, value in want.items():
@@ -302,9 +318,9 @@ def test_over_a_program_without_the_new_spans_the_readers_return_nothing(real):
     entries = configs.metrics_for_cell(bench, "per_layer", CELL)
     got = readers.read_all(configs.metrics_dirs(util.REPO, bench), entries,
                            ctx, {"out_tokens_per_s": 7000.0})
-    assert not {"window_attn_roofline.think", "router_bias_moved.think",
-                "window_ring_live_share.think"} & set(got)
-    assert "full_attn_roofline.think" in got
+    assert not {"window_attn_roofline", "router_bias_moved",
+                "window_ring_live_share"} & set(got)
+    assert "decode_attn_roofline" in got
     ctx["trace"] = None
     got = readers.read_all(configs.metrics_dirs(util.REPO, bench), entries,
                            ctx, {"out_tokens_per_s": 7000.0})

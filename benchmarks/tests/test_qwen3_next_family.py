@@ -177,13 +177,13 @@ def test_the_family_serves_through_the_harness_at_tiny_size(real, tmp_path):
     program's counters are in the line."""
     _bench, config, _family, _ref = real
     root = util.make_root(str(tmp_path))
-    counters = [("experts_touched.longform", "count"),
-                ("expert_rows_max.longform", "ratio"),
-                ("expert_rows_held_share.longform", "%"),
-                ("slot_occupancy.longform", "%"),
-                ("kv_pool_fill.longform", "%"),
-                ("compiles_in_window.longform", "count"),
-                ("preemptions.longform", "count")]
+    counters = [("experts_touched", "count"),
+                ("expert_rows_max", "ratio"),
+                ("expert_rows_held_share", "%"),
+                ("slot_occupancy", "%"),
+                ("kv_pool_fill", "%"),
+                ("compiles_in_window", "count"),
+                ("preemptions", "count")]
     cell = util.add_cell(
         root, tiny_config(config), "batch", ["out_tokens_per_s"],
         [{"name": n, "unit": u, "moves": "out_tokens_per_s"}
@@ -194,9 +194,9 @@ def test_the_family_serves_through_the_harness_at_tiny_size(real, tmp_path):
     assert any("reference check over" in ln and ": ok" in ln
                for ln in got["log"])
     value = lambda n: out["metrics"]["cpu_rehearsal." + n]["value"]
-    assert 1.0 <= value("experts_touched.longform") <= 4
-    assert 20.0 < value("expert_rows_held_share.longform") < 80.0
-    assert value("preemptions.longform") == 0
+    assert 1.0 <= value("experts_touched") <= 4
+    assert 20.0 < value("expert_rows_held_share") < 80.0
+    assert value("preemptions") == 0
     # No device plane in a CPU trace: trace-sourced metrics are left out.
     assert not any("roofline" in n or "dev_ms" in n or "gdn" in n
                    for n in out["metrics"])
@@ -225,6 +225,7 @@ def _context(family, config) -> dict:
         "engine": {"moe_experts_touched": 118.0, "moe_rows_max": 3.1,
                    "moe_rows_held": 2500, "moe_rows_routed": 10000,
                    "slot_occupancy": 0.98, "kv_pages_free_min": 2048,
+                   "decode_block_fill": 0.9, "decode_live_column_share": 0.55,
                    "compiles_in_window": 0, "preemptions": 0,
                    "tick_host_share": 0.011, "engine_prefill_tok_s": 20000.0,
                    "decode_step_ms_p50": 24.5, "prefill_tokens": 2048,
@@ -253,6 +254,7 @@ _TABLE = {
             "runs": 100, "total_s": 2.4, "by_pass": {}, "unscoped_s": 0.05,
             "by_scope": {"gdn.in": 0.22, "gdn.scan": 0.62, "gdn.out": 0.08,
                          "moe.route": 0.15, "moe.experts": 1.10,
+                         "attn.in": 0.007, "attn.out": 0.003,
                          "attn.kernel": 0.20, "head": 0.10, "sample": 0.02}},
         "jit_prefill_chunk_paged": {
             "runs": 8, "total_s": 0.096, "by_pass": {}, "unscoped_s": 0.001,
@@ -265,8 +267,10 @@ def test_every_metric_of_the_cell_reads_a_synthetic_context(real,
                                                             monkeypatch):
     bench, config, family, _ref = real
     entries = configs.metrics_for_cell(bench, "per_layer", CELL)
-    assert len(entries) == 26 and all(m["workloads"] == [CELL]
+    assert len(entries) >= 26 and all(CELL in m["workloads"]
                                       for m in entries)
+    assert all(m["moves"] == "out_tokens_per_s" and "." not in m["name"]
+               for m in entries)
     ctx = _context(family, config)
     monkeypatch.setattr(scope_times, "for_run", lambda _ctx: _TABLE)
     got = {n: v["value"] for n, v in readers.read_all(
@@ -277,44 +281,48 @@ def test_every_metric_of_the_cell_reads_a_synthetic_context(real,
     scoped = sum(sum(p["by_scope"].values())
                  for p in _TABLE["programs"].values())
     want = {
-        "decode_program_dev_ms.longform": 24.0,
-        "prefill_program_dev_ms.longform": 12.0,
-        "decode_step_ms.longform": 24.5,
-        "prefill_tokens_per_s.longform": 20000.0,
-        "slot_occupancy.longform": 98.0,
-        "kv_pool_fill.longform": 75.0,
-        "compiles_in_window.longform": 0.0,
-        "tick_host_share.longform": 1.1,
-        "device_idle_share.longform": (1 - 2.7 / 2.8) * 100,
-        "preemptions.longform": 0.0,
-        "moe_expert_share.longform": (1.10 + 0.02 + 0.050) / 2.7 * 100,
-        "experts_touched.longform": 118.0,
-        "expert_rows_max.longform": 3.1,
-        "expert_rows_held_share.longform": 25.0,
+        "decode_program_dev_ms": 24.0,
+        "prefill_program_dev_ms": 12.0,
+        "decode_step_ms": 24.5,
+        "prefill_tokens_per_s": 20000.0,
+        "slot_occupancy": 98.0,
+        "kv_pool_fill": 75.0,
+        "compiles_in_window": 0.0,
+        "tick_host_share": 1.1,
+        "device_idle_share": (1 - 2.7 / 2.8) * 100,
+        "preemptions": 0.0,
+        "moe_expert_share": (1.10 + 0.02 + 0.050) / 2.7 * 100,
+        "experts_touched": 118.0,
+        "expert_rows_max": 3.1,
+        "expert_rows_held_share": 25.0,
         # the kernel alone (not its metadata), a decode step
-        "moe_expert_roofline.longform":
+        "moe_expert_roofline":
             118.0 * c["decode_bytes_per_live_expert"] / peak / 0.0110 * 100,
-        "attn_kernel_share.longform": (0.20 + 0.010) / 2.7 * 100,
+        "attn_kernel_share": (0.20 + 0.010) / 2.7 * 100,
         # samples of the TRACED interval: 126 slots, 280,000 tokens
-        "decode_attn_roofline.longform":
+        "decode_attn_roofline":
             280_000 * c["decode_bytes_per_kv_token"] / peak / 0.0020 * 100,
-        "decode_stream_roofline.longform": (
+        "decode_stream_roofline": (
             c["decode_bytes_weights"]
             + 118.0 * c["decode_bytes_per_live_expert"]
             + 280_000 * c["decode_bytes_per_kv_token"]
             + 126 * c["decode_bytes_per_state_slot"]) / peak / 0.024 * 100,
-        "moe_route_ms.longform": 1.5,
-        "head_ms.longform": 1.2,
-        "scope_coverage.longform": scoped / 2.7 * 100,
-        "gdn_share.longform":
+        "moe_route_ms": 1.5,
+        "head_ms": 1.2,
+        "scope_coverage": scoped / 2.7 * 100,
+        "gdn_share":
             (0.22 + 0.62 + 0.08 + 0.012 + 0.016 + 0.004) / 2.7 * 100,
-        "gdn_scan_share.longform": (0.62 + 0.016) / 2.7 * 100,
-        "gdn_proj_ms.longform": 3.0,
-        "gdn_step_roofline.longform":
+        "gdn_scan_share": (0.62 + 0.016) / 2.7 * 100,
+        "gdn_proj_ms": 3.0,
+        "gdn_step_roofline":
             126 * c["decode_bytes_per_state_slot"] / peak / 0.0062 * 100,
         # 256 tokens a dispatch; the bytes bound it
-        "gdn_chunk_roofline.longform":
+        "gdn_chunk_roofline":
             256 * c["chunk_scan_bytes_per_token"] / peak / 0.002 * 100,
+        # the pairs PR 52 appended to entries the cell had lacked
+        "decode_block_fill": 90.0,
+        "decode_live_column_share": 55.0,
+        "decode_dense_ms": (0.007 + 0.003) / 100 * 1000,
     }
     assert set(want) == set(got)
     for name, value in want.items():
@@ -345,4 +353,4 @@ def test_over_a_program_without_the_new_scopes_the_readers_return_nothing(
     got = readers.read_all(configs.metrics_dirs(util.REPO, bench), entries,
                            ctx, {"out_tokens_per_s": 5000.0})
     traced = {m["name"] for m in entries if m["source"] == "device_trace"}
-    assert len(traced) == 16 and not traced & set(got)
+    assert len(traced) >= 16 and not traced & set(got)

@@ -133,8 +133,10 @@ def add_cell(root: str, config: dict, traffic: str, end_to_end: list[str],
     """Write `config` as a NEW configuration file of the root and append
     its entries to the root's BENCHMARK.json: the configuration, the cell
     `<config>.<traffic>` (the traffic file is there already), the cell's
-    name on the named end-to-end metrics, and per-layer entries (`name`,
-    `unit`, `moves`) whose reader files the caller has dropped in."""
+    name on the named end-to-end metrics, and per-layer metrics (`name`,
+    `unit`, `moves`): the cell's name appended to the entry the root has
+    by that name and `moves`, or a new entry whose reader file the caller
+    has dropped in."""
     name = config["name"]
     cell = f"{name}.{traffic}"
     with open(os.path.join(root, "benchmarks", "configs", name + ".json"),
@@ -152,6 +154,11 @@ def add_cell(root: str, config: dict, traffic: str, end_to_end: list[str],
         if m["name"] in end_to_end:
             m["workloads"].append(cell)
     for m in per_layer:
+        shared = [e for e in bench["per_layer"]
+                  if (e["name"], e["moves"]) == (m["name"], m["moves"])]
+        if shared:      # a metric the benchmark has: one more cell in its list
+            shared[0]["workloads"].append(cell)
+            continue
         bench["per_layer"].append(dict(
             {"better": "higher", "source": "program_span",
              "layer": "Entry and admission", "workloads": [cell]}, **m))
