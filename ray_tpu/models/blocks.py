@@ -121,6 +121,29 @@ def write_kv(pool, l, pages, offs, k, v, planes=("k", "v")):
             vn: pool[vn].at[l, pages, offs].set(rows(v))}
 
 
+@jax.named_scope(scopes.SLOT_STATE)
+def dispatch_order(slots, offsets, n_valid, null_slot: int):
+    """Where each chunk row of one dispatch finds the state before its
+    first token, for a family whose per-slot state is a RECURRENCE (a
+    row cannot read a chained row's boundary in parallel, so the state
+    pass walks the rows in order). slots, offsets, n_valid [N]: a row's
+    slot, the position of its first token, its tokens (0: an inert row).
+    → (chain [N] int32: the row ABOVE that holds the same slot's chunk
+    before this one, -1 for none (the row then starts from the slot's
+    state in the pool); state_rows [N]: the slot a row writes its final
+    state back to, `null_slot` unless it is its slot's last live row of
+    the dispatch; fresh [N] bool: the row starts a prompt, from zeros,
+    which is the reset of a reused or re-prefilled slot)."""
+    row = jnp.arange(slots.shape[0])
+    live = n_valid > 0
+    same = ((slots[:, None] == slots[None, :])
+            & live[:, None] & live[None, :])
+    chain = jnp.max(jnp.where(same & (row[None, :] < row[:, None]),
+                              row[None, :], -1), axis=1)
+    is_last = live & ~jnp.any(same & (row[None, :] > row[:, None]), axis=1)
+    return chain, jnp.where(is_last, slots, null_slot), offsets == 0
+
+
 # Running totals over decode steps in `pool["moe_counters"]`, wrapping
 # uint32 (the host takes differences): (sparse layer, step) pairs, held
 # experts that had a row, the fullest held expert's rows, choices routed

@@ -75,8 +75,8 @@ import numpy as np
 
 from ray_tpu.ops import scopes
 from ray_tpu.models.blocks import (COUNTERS, attend_fn, counter_row,
-                                   gated_mlp, last_token_logits,
-                                   untied_head, write_kv)
+                                   dispatch_order, gated_mlp,
+                                   last_token_logits, untied_head, write_kv)
 from ray_tpu.models.blocks import rms_norm_centred as _norm
 from ray_tpu.models.paged_kv import paged_programs
 from ray_tpu.ops.gated_delta import (
@@ -477,20 +477,13 @@ def _chunk_forward(cfg: Qwen3NextConfig, params, tokens, pool, tables,
     ps = pool["k"].shape[2]
     null_slot = pool["gdn_state"].shape[1] - 1
     n_tail = cfg.conv_taps - 1
-    rel, row = jnp.arange(C), jnp.arange(N)
+    rel = jnp.arange(C)
     pos = offsets[:, None] + rel[None, :]
     valid = rel[None, :] < n_valid[:, None]
     kv_lens = offsets + n_valid
+    chain, state_rows, fresh = dispatch_order(slots, offsets, n_valid,
+                                              null_slot)
     with jax.named_scope(scopes.SLOT_STATE):
-        live = n_valid > 0
-        same = ((slots[:, None] == slots[None, :])
-                & live[:, None] & live[None, :])
-        chain = jnp.max(jnp.where(same & (row[None, :] < row[:, None]),
-                                  row[None, :], -1), axis=1)      # [N]
-        is_last = live & ~jnp.any(same & (row[None, :] > row[:, None]),
-                                  axis=1)
-        state_rows = jnp.where(is_last, slots, null_slot)
-        fresh = offsets == 0
         # The last taps-1 inputs of a row, as indices into its `ext`.
         tail_at = (n_valid[:, None] + jnp.arange(n_tail)[None, :])[..., None]
     with jax.named_scope(scopes.ATTN_KV_WRITE):

@@ -110,14 +110,14 @@ def _gpt() -> ServingFamily:
         programs=_gpt_programs)
 
 
-# The engine options that the programs of a family with experts and a
-# memory by the slot beside the pages cannot carry, in the order
+# The engine options that the programs of a family with a memory by the
+# slot beside the pages cannot carry (with experts or without), in the order
 # serve/llm_options.py settles them (kv_mode and prefill_chunk before the
 # checks that read them): (option, `fits`, `neutral`, the refusal). A
 # refusal names the family ({name}) and says what would have to be built;
 # where that depends on WHAT the family keeps by the slot ({beside}: a
-# one-token state, a recurrence, a ring) it takes the family's own clause
-# under the option's name.
+# one-token state, a recurrence, a ring) or on whether it has experts, it
+# takes the family's own clause under the option's name.
 _REFUSALS = (
     ("kv_mode", lambda o: o.kv_mode == "paged", "paged",
      "the {name} family serves from the paged pool only: kv_mode='dense' "
@@ -128,10 +128,10 @@ _REFUSALS = (
     ("prefill_width_bucketing",
      lambda o: not o.prefill_width_bucketing, False,
      "prefill_width_bucketing with the {name} family: a chunk program "
-     "here costs a pass over every held expert's weights at any table "
-     "width, and one bucket a width spreads a lone prompt's rows over "
-     "more programs; a dispatch that packs rows of several widths into "
-     "one program would have to be built"),
+     "here costs {prefill_width_bucketing} at any table width, and one "
+     "bucket a width spreads a lone prompt's rows over more programs; a "
+     "dispatch that packs rows of several widths into one program would "
+     "have to be built"),
     ("prefix_cache", lambda o: not o.prefix_cache, False,
      "prefix_cache with the {name} family: a cached prefix would need "
      "{prefix_cache} (serve/prefix_cache.py keeps PagePool pages only)"),
@@ -145,8 +145,7 @@ _REFUSALS = (
      "tp > 1 with the {name} family: {tp}"),
     ("weight_dtype", lambda o: o.weight_dtype != "int8", "bf16",
      "weight_dtype='int8' with the {name} family: quantize_params knows "
-     "the gpt tree's planes, and the experts' grouped matmul (ops/moe.py) "
-     "has no int8 form"),
+     "the gpt tree's planes, {weight_dtype}"),
     ("kv_dtype", lambda o: o.kv_dtype != "int8", "bf16",
      "kv_dtype='int8' with the {name} family: the per-page scale planes "
      "are kept by models/paged_kv._quant_write, {kv_dtype}"),
@@ -158,6 +157,12 @@ _RETURNS = ("a verify program that returns {beside} at every position to "
             "rewind to")
 _EXCHANGE = ("the experts need an expert-parallel exchange of rows between "
              "chips (ops/moe.py returns the held experts' part only)")
+# What a family WITH experts says under the two options whose refusal
+# turns on them.
+_EXPERTS = {
+    "prefill_width_bucketing": "a pass over every held expert's weights",
+    "weight_dtype": "and the experts' grouped matmul (ops/moe.py) has no "
+                    "int8 form"}
 
 
 def _paged_only(model, beside: str, clauses: dict, **fields) -> ServingFamily:
@@ -191,7 +196,8 @@ def _zaya() -> ServingFamily:
         zaya,
         "the slot's conv/shift state (z, c and W_v2 u of its last token, "
         "per layer: models/zaya.py)",
-        {"kv_mode": "of the CCA block, with its per-slot state carried "
+        {**_EXPERTS,
+         "kv_mode": "of the CCA block, with its per-slot state carried "
                     "beside it",
          "prefill_chunk": "the prompt's last-token state in the slot state",
          "prefix_cache": _SNAPSHOT, "spec_draft": _RETURNS,
@@ -211,7 +217,7 @@ def _ring(model, dense_needs: str) -> ServingFamily:
         model,
         "the window layers' ring of pages a slot (models/laguna.py: "
         "indexed by slot, outside PagePool's page ids)",
-        {"kv_mode": "with " + dense_needs,
+        {**_EXPERTS, "kv_mode": "with " + dense_needs,
          "prefill_chunk": "the prompt's last window in {beside}",
          "prefix_cache": "{beside} at the prefix's boundary stored with "
                          "its pages: a window kind whose pages can be "
@@ -249,7 +255,8 @@ def _qwen3_next() -> ServingFamily:
         "the linear layers' recurrent state and convolution tail "
         "(models/qwen3_next.py: a float32 matrix a head and layer, "
         "12.9 MB a slot at the published sizes, indexed by slot)",
-        {"kv_mode": "for the full layers with {beside} carried beside it",
+        {**_EXPERTS,
+         "kv_mode": "for the full layers with {beside} carried beside it",
          "prefill_chunk": "the prompt's final recurrent state in the slot",
          "prefix_cache": _SNAPSHOT,
          "spec_draft": "a recurrence cannot be run backwards; " + _RETURNS,
@@ -262,8 +269,37 @@ def _qwen3_next() -> ServingFamily:
         slot_state=qwen3_next.SLOT_STATE_LEAVES, lay_out=qwen3_next.lay_out)
 
 
+def _jamba() -> ServingFamily:
+    from ray_tpu.models import jamba
+
+    return _paged_only(
+        jamba,
+        "the mamba layers' state-space state and convolution tail "
+        "(models/jamba.py: 16 float32 values a channel and layer, 9.3 MB a "
+        "slot at the published sizes, indexed by slot)",
+        {"kv_mode": "for the attention layers with {beside} carried beside "
+                    "it",
+         "prefill_chunk": "the prompt's final state-space state in the slot",
+         "prefill_width_bucketing": "a pass over all of the model's "
+                                    "weights (every layer is dense)",
+         "prefix_cache": _SNAPSHOT,
+         "spec_draft": "a recurrence cannot be run backwards; " + _RETURNS,
+         "tp": "one KV head cannot shard over more chips than heads "
+               "(models/partition.py and serve/kv_objects.py split the "
+               "pool by whole heads), and no partition rule splits the "
+               "state-space state and the mixer's matrices by channel",
+         "weight_dtype": "and an int8 form of this family's tree (a plane a "
+                         "layer, with its scale vectors) would have to be "
+                         "written",
+         "kv_dtype": "which the attention layers' K/V writer would have to "
+                     "call, and the state-space state is float32: it "
+                     "accumulates thousands of steps"},
+        slot_state=jamba.SLOT_STATE_LEAVES)
+
+
 _FAMILIES = {"gpt": _gpt, "zaya": _zaya, "laguna": _laguna,
-             "qwen3_next": _qwen3_next, "mimo_v2": _mimo_v2}
+             "qwen3_next": _qwen3_next, "mimo_v2": _mimo_v2,
+             "jamba": _jamba}
 
 
 @functools.cache

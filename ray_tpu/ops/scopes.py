@@ -30,6 +30,14 @@ GDN_SCAN = "gdn.scan"            # the recurrent state's only reader and
                                  # writer: the decode step's update, a
                                  # chunk's scan and its write-back
 GDN_OUT = "gdn.out"              # gated norm, output projection, residual
+SSM_IN = "ssm.in"                # a selective-scan layer: norm, input
+                                 # projection, the convolution with its
+                                 # tail, SiLU, W_x, the three inner norms,
+                                 # W_dt, softplus
+SSM_SCAN = "ssm.scan"            # the state-space state's only reader and
+                                 # writer: the decode step's update, a
+                                 # chunk's scan and its write-back
+SSM_OUT = "ssm.out"              # gate, output projection, residual
 HEAD = "head"                    # final norm, head matmul, row selection
 SAMPLE = "sample"                # argmax / categorical over the logits
 COUNTERS = "counters"            # on-device counters the host pulls
@@ -37,5 +45,5 @@ LOSS = "loss"                    # cross-entropy over the logits
 OPTIMIZER = "optimizer"          # the optimizer's update and its apply
 
 ALL = (EMBED, ATTN_IN, ATTN_KV_WRITE, ATTN_KERNEL, ATTN_OUT, MLP, MOE_ROUTE,
-       MOE_EXPERTS, SLOT_STATE, GDN_IN, GDN_SCAN, GDN_OUT, HEAD, SAMPLE,
-       COUNTERS, LOSS, OPTIMIZER)
+       MOE_EXPERTS, SLOT_STATE, GDN_IN, GDN_SCAN, GDN_OUT, SSM_IN, SSM_SCAN,
+       SSM_OUT, HEAD, SAMPLE, COUNTERS, LOSS, OPTIMIZER)

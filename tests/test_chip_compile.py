@@ -417,7 +417,8 @@ def step_program(request, chip):
              "zaya": ("zaya_serving", "zaya", Z_SLOTS, 32),
              "laguna": ("laguna_serving", "laguna", G_SLOTS, 64),
              "qwen3_next": ("qwen3_next_serving", "qwen3_next", Q_SLOTS, 64),
-             "mimo_v2": ("mimo_v2_serving", "mimo_v2", M_SLOTS, 96)}
+             "mimo_v2": ("mimo_v2_serving", "mimo_v2", M_SLOTS, 96),
+             "jamba": ("jamba_serving", "jamba", J_SLOTS, J_WIDTH)}
     i32 = lambda *shape: chip(shape, jnp.int32)
 
     @functools.cache
@@ -775,14 +776,16 @@ _SHAPE = re.compile(r"(\w+)\[([\d,]*)\]\{([^}]*)\}")
 _WEIGHT_PASS = re.compile(r"\s(?:fusion|copy)\(([^)]*%params__[^)]*)\)")
 
 
-def _weight_planes_written_to_hbm(text):
+def _weight_planes_written_to_hbm(text, shapes=None):
     """{weight parameter: bytes} over the entry computation's `fusion`
     and `copy` instructions that read a weight parameter and have a bf16
     result of a plane's size outside the chip's fast memory (`S(1)` in
     the result's layout): a plane copied out of its stack and written
     back to HBM, which its matmul then reads a second time. (A prefetch
     is a `copy-start` whose `copy-done` lands in `S(1)`; a matmul fusion
-    that slices its plane itself has an activation for a result.)"""
+    that slices its plane itself has an activation for a result; where
+    a program's activations are as large as a plane, `shapes` names the
+    planes' own dims, "2560,8192", and only those count.)"""
     written = {}
     for line in text[text.index("\nENTRY "):].splitlines():
         hit = _WEIGHT_PASS.search(line)
@@ -791,7 +794,8 @@ def _weight_planes_written_to_hbm(text):
         result = line[:hit.start()].partition(" = ")[2]
         elems = [int(np.prod([int(d) for d in dims.split(",")]))
                  for dtype, dims, layout in _SHAPE.findall(result)
-                 if dtype == "bf16" and dims and "S(1)" not in layout]
+                 if dtype == "bf16" and dims and "S(1)" not in layout
+                 and (shapes is None or dims in shapes)]
         nbytes = 2 * sum(n for n in elems if n >= _PLANE)
         if nbytes:
             name = re.search(r"%params__(\w+?)__", hit.group(1)).group(1)
@@ -1015,3 +1019,142 @@ def test_decode_scratch_holds_no_second_copy_of_a_weight_plane(step_program,
         stacked = step_program(family, "decode", stacks=True)
         assert (stacked.memory_analysis().temp_size_in_bytes
                 > _DECODE_SCRATCH[family])
+
+
+# --- the jamba family: a state-space state by the slot, one KV head -----
+# AI21-Jamba2-3B as `benchmarks/configs/ai21-jamba2-3b.json` serves it:
+# every layer and width (28 layers, 26 of them mamba: 5,120 channels of
+# 16 float32 state values; 20 query heads over ONE KV head of 128 in
+# layers 7 and 21; MLPs of 8,192; 65,536 tied vocabulary rows), 256
+# slots, pages of 128 tokens for 256 x 4,096 (table width 32), chunk 128.
+J_SLOTS, J_PS, J_PAGES, J_WIDTH, J_H, J_K = 256, 128, 8192, 32, 20, 128
+
+
+@pytest.fixture(scope="module")
+def jamba_serving(chip):
+    """(cfg, params, pool) of the jamba cell as shapes on one described
+    chip, with the two backend questions steered to the chip's answers."""
+    import importlib
+
+    from ray_tpu.models import jamba
+
+    cfg = jamba.JambaConfig()
+    params = _served(chip, cfg, _stacks(chip, jamba, cfg))
+    pool = jax.tree.map(
+        lambda x: chip(x.shape, x.dtype),
+        jax.eval_shape(lambda: jamba.init_paged_kv(
+            cfg, J_PAGES, J_PS, J_SLOTS)))
+    mods = [importlib.import_module("ray_tpu.ops." + m)
+            for m in ("paged_attention", "selective_scan")]
+    saved = [m._interpret_default for m in mods]
+    for m in mods:
+        m._interpret_default = lambda: False
+    yield cfg, params, pool
+    for m, fn in zip(mods, saved):
+        m._interpret_default = fn
+
+
+@pytest.mark.parametrize("page", [64, 128])
+def test_paged_kernels_compile_at_20_query_heads_over_one_kv_head(chip, page):
+    """Both paged kernels with G = 1 KV head of 128 (the pool's minor
+    axis is ONE vreg row of 128 lanes; a page of 64 tokens is 16 KB)
+    under H = 20 query heads: a group of 20 that no other cell has and
+    that is no multiple of the 8 sublanes."""
+    width = 4096 // page
+    pool = chip((2, 256 * width + 1, page, J_K), jnp.bfloat16)
+    _compile(lambda q, k, v, l, t, n: paged_attention(
+        q, k, v, l, t, n, interpret=False),
+        chip((J_SLOTS, J_H, J_K), jnp.bfloat16), pool, pool, _layer(chip),
+        chip((J_SLOTS, width), jnp.int32), chip((J_SLOTS,), jnp.int32),
+        kernels=("paged_decode_attn",))
+    for rows in (2,) + ONE_WIDTH_HEIGHTS:
+        _compile(lambda q, k, v, l, t, o, n: paged_prefill_attention(
+            q, k, v, l, t, o, n, interpret=False),
+            chip((rows, C, J_H, J_K), jnp.bfloat16), pool, pool,
+            _layer(chip), chip((rows, width), jnp.int32),
+            chip((rows,), jnp.int32), chip((rows,), jnp.int32),
+            kernels=("paged_prefill_attn",))
+
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?(%[\w.\-]+) \(.*\{$")
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%[\w.\-]+ = (\w+)\[([\d,]*)\]\{[^}]*\} ([\w\-]+)\(")
+
+
+def _planes_made(text, shapes):
+    """Instructions OUTSIDE a fused computation (the entry's and a loop
+    body's own: what they produce is a buffer) whose result is a bf16
+    array of one of `shapes` ("2560,8192"; a leading 1 is the same
+    plane): a weight plane cut out of its stack and written somewhere,
+    for its matmul to read a second time. A prefetch into fast memory is
+    a `slice-done` or `copy-done` and is not one."""
+    made, fused = [], False
+    for line in text.splitlines():
+        comp = _COMPUTATION.match(line)
+        if comp:
+            fused = "fused" in comp.group(1)
+            continue
+        hit = None if fused else _INSTRUCTION.match(line)
+        if (hit and hit.group(1) == "bf16"
+                and hit.group(2).removeprefix("1,") in shapes
+                and hit.group(3) not in ("parameter", "get-tuple-element",
+                                         "bitcast", "slice-done", "copy-done")
+                and "S(1)" not in line.split(" = ")[1].split(" ")[0]):
+            made.append(line.strip()[:160])
+    return made
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill-2", "prefill-4"])
+def test_jamba_program_fits_and_moves_no_state(jamba_serving, step_program,
+                                               program):
+    """The jamba family's step programs (decode, and the chunk program
+    at both of the engine's heights in the cell, whose decode window is
+    4 steps: 2 rows, one admission's, and 4), compiled whole at the
+    cell's size:
+    a run of mamba layers is ONE loop (three of them, of 7, 13 and 6
+    layers), so the two attention calls and three of each scan kernel are
+    in them under the names a trace finds them by; no layer of the state
+    (257 slots x 16 x 5,120 float32, 84 MB) and nothing of the tail is
+    copied, sliced out or put back, in the entry or in a loop's body;
+    no weight plane is cut out of its stack into a buffer of its own (a
+    matmul's fusion slices its plane where it lies); the donated pool
+    (pages, state, tails) is updated in place; weights + pool + what the
+    program needs besides are 9.5-10.5 GB of the chip's 16."""
+    cfg, _params, pool = jamba_serving
+    assert pool["ssm_state"].shape == (26, J_SLOTS + 1, 16, 5120)
+    assert pool["ssm_conv"].shape == (26, 3, J_SLOTS + 1, 5120)
+    assert pool["k"].shape == (2, J_PAGES + 1, J_PS, J_K)
+    compiled = step_program("jamba", program)
+    runs = 3
+    kernels = ({"paged_decode_attn": 2, "ssm_decode_step": runs,
+                "ssm_conv_step": runs} if program == "decode" else
+               {"paged_prefill_attn": 2, "ssm_chunk_scan": runs})
+    text = compiled.as_text()
+    assert len(re.findall(r" while\(", text)) == runs
+    for name, n in kernels.items():
+        assert len(re.findall(rf"%\w*{name}[\w.]* = [^\n]*custom-call\(",
+                              text)) == n, name
+    moved = (_pool_moves(text, "f32", (J_SLOTS + 1) * 16 * 5120)
+             + _pool_moves(text, "bf16", 26 * 3 * (J_SLOTS + 1) * 5120))
+    assert not moved, "state-sized moves:\n" + "\n".join(moved)
+    planes = {",".join(map(str, a.shape[1:])) for name, a in _params.items()
+              if len(a.shape) == 3 and name[:2] in ("m_", "a_", "w_")}
+    assert "2560,8192" in planes and "2560,10240" in planes
+    made = _planes_made(text, planes)
+    assert not made, "weight planes written out:\n" + "\n".join(made)
+    # (The compiler transposes W_q of the two attention layers for the
+    # decode step's q projection, one of them through HBM: 13 MB of the
+    # 11 GB a step moves, and no stack's doing: it did so for a leaf a
+    # layer too.)
+    written = _weight_planes_written_to_hbm(text, planes)
+    assert set(written) <= {"a_wq"} and sum(written.values()) < 14e6
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                     for a in pool.values())
+    assert mem.alias_size_in_bytes >= pool_bytes
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    print(f"jamba {program}: arguments "
+          f"{mem.argument_size_in_bytes / 1e9:.3f} GB, temp "
+          f"{mem.temp_size_in_bytes / 1e9:.3f} GB, total {total / 1e9:.3f} GB")
+    assert 9.5e9 < total < 10.5e9

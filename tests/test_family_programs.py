@@ -1,7 +1,7 @@
 """Every family's chunk, decode-step and window-step program, letter for
 letter.
 
-The serving programs of the five families of models/serving.py are built
+The serving programs of the six families of models/serving.py are built
 by one builder (models/paged_kv.py `paged_programs`) from parts that
 several families share (models/blocks.py). A refactor of either must
 leave every lowered program as it was: the digests below were computed
@@ -16,7 +16,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from ray_tpu.models import gpt, laguna, mimo_v2, paged_kv, qwen3_next, zaya
+from ray_tpu.models import (gpt, jamba, laguna, mimo_v2, paged_kv, qwen3_next,
+                            zaya)
 
 PAGE, N_PAGES, N_SLOTS, CHUNK = 16, 24, 3, 16
 
@@ -26,12 +27,18 @@ PAGE, N_PAGES, N_SLOTS, CHUNK = 16, 24, 3, 16
 # device) at its tiny size with the kernels on. The first eight as commit
 # 02951b4 traced them (the commit before the mimo_v2 family), the mimo_v2
 # pair and the five `sample` programs as commit c3726bb did (the commit
-# before the builder).
+# before the builder). `qwen3_next.chunk` as PR 53 traced it: the rule
+# by which a dispatch's rows find their predecessor moved to
+# `blocks.dispatch_order` (the same equations, `iota(N)` traced four
+# equations later), and the COMPILED chunk program is instruction for
+# instruction what ffb23a00a1eeb73d compiled to (17,774 lines of
+# optimized HLO, compared less source locations: CHANGES.md, PR 53). The
+# jamba three as PR 53, the family's first, traced them.
 _PINNED = {
     "gpt.chunk": "aff570174e390475", "gpt.decode": "c4ebbb44ff02a83f",
     "zaya.chunk": "e3c3c03e1e115475", "zaya.decode": "21818dedb3b70792",
     "laguna.chunk": "9baeed72a093a253", "laguna.decode": "2a9da728856d4537",
-    "qwen3_next.chunk": "ffb23a00a1eeb73d",
+    "qwen3_next.chunk": "dd1ae71fa6595ff1",
     "qwen3_next.decode": "36f158373030eabf",
     "mimo_v2.chunk": "9b0c5bf47a63f542",
     "mimo_v2.decode": "f65efd374bbc0153",
@@ -39,6 +46,8 @@ _PINNED = {
     "laguna.sample": "1a557bccce429947",
     "qwen3_next.sample": "a023e156a1cb54d9",
     "mimo_v2.sample": "cd452c05dcdd0a15",
+    "jamba.chunk": "16c983f57626c3f6", "jamba.decode": "df66bebf30e06d17",
+    "jamba.sample": "c311836851bbdaa1",
 }
 
 _RING = {"dispatch_tokens": 2 * CHUNK}
@@ -51,6 +60,7 @@ _FAMILIES = {
     "qwen3_next": (qwen3_next, qwen3_next,
                    qwen3_next.Qwen3NextConfig.tiny(), {}),
     "mimo_v2": (mimo_v2, mimo_v2, mimo_v2.MiMoV2Config.tiny(), _RING),
+    "jamba": (jamba, jamba, jamba.JambaConfig.tiny(), {}),
 }
 
 

@@ -23,7 +23,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import gpt, laguna, mimo_v2, paged_kv, qwen3_next, zaya
+from ray_tpu.models import (gpt, jamba, laguna, mimo_v2, paged_kv, qwen3_next,
+                            zaya)
 from ray_tpu.serve import llm
 from ray_tpu.serve.llm import LLMEngine
 
@@ -52,6 +53,8 @@ def _fams() -> dict:
                           qwen3_next.init_params, qwen3_next.forward),
         "mimo_v2": Fam(mimo_v2.MiMoV2Config.tiny(dtype=jnp.float32),
                        mimo_v2.init_params, mimo_v2.forward),
+        "jamba": Fam(jamba.JambaConfig.tiny(dtype=jnp.float32),
+                     jamba.init_params, jamba.forward),
     }
 
 
@@ -72,7 +75,7 @@ def _serve(name):
 
 
 def _drop_programs():
-    """Five families' programs in one process cross the mappings a
+    """Six families' programs in one process cross the mappings a
     process may hold (tests/conftest.py `_release_compiled_programs`):
     each family's go when its tests are over."""
     jax.clear_caches()
@@ -80,7 +83,7 @@ def _drop_programs():
 
 
 @pytest.fixture(scope="class", params=["gpt", "zaya", "laguna", "qwen3_next",
-                                       "mimo_v2"])
+                                       "mimo_v2", "jamba"])
 def family(request):
     """pytest runs a class's tests family by family for this fixture."""
     yield (request.param, *_serve(request.param))
@@ -321,7 +324,7 @@ class TestEveryFamily:
         the run's last ticks' (a one-step tick reads nothing: its share
         comes with the next window's)."""
         name, fam, params = family
-        if name == "gpt":
+        if name in ("gpt", "jamba"):
             pytest.skip("no experts: nothing is counted")
         eng = _engine(fam, params)
         reqs = [eng.submit(_prompt(n, seed=n), max_tokens=m)

@@ -1320,3 +1320,79 @@ def test_the_other_families_trace_exactly_the_programs_they_had(program):
             tables, rows, rows, kw)
     text = re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
+
+
+# --- one KV head under twenty query heads -------------------------------
+# AI21-Jamba2-3B's two attention layers: 20 query heads of 128 over ONE
+# KV head, so the pool's minor axis is a single head's 128 lanes and the
+# group is 20, which no other cell has (6, 8, 9) and which is no multiple
+# of the 8 sublanes a query block is tiled by. No new kernel: the decode
+# kernel's block-diagonal query is [20, 128] with every row in the one
+# head's lanes, the prefill kernel's head loop reads the same lane slice
+# twenty times. Pages of 64 (16 KB) and of 128 (32 KB, the cell's).
+MQA_H, MQA_K = 20, 128
+
+
+@pytest.mark.parametrize("n_pg", [3, 4])
+@pytest.mark.parametrize("ps", [64, 128])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_decode_kernel_at_twenty_query_heads_over_one_kv_head(ps, dtype,
+                                                              n_pg):
+    rng = np.random.default_rng(5)
+    B = 5
+    q = jnp.asarray(rng.normal(size=(B, MQA_H, MQA_K)), dtype)
+    k_pool, v_pool, tables, lengths = _pool_and_tables(
+        rng, B=B, H=1, K=MQA_K, ps=ps, n_pg=n_pg, dtype=dtype)
+    assert k_pool.shape[-1] == MQA_K
+    layer = jnp.int32(N_LAYERS - 1)
+    o = paged_attention(q, k_pool, v_pool, layer, tables, lengths)
+    ref = reference_paged_attention(q, k_pool, v_pool, layer, tables,
+                                    lengths)
+    assert o.shape == q.shape and o.dtype == q.dtype
+    np.testing.assert_allclose(
+        np.asarray(o, np.float32), np.asarray(ref, np.float32),
+        atol=2e-6 if dtype == jnp.float32 else 3e-2)
+    # The heads differ (each has its own query) though they share K, V.
+    assert not np.allclose(np.asarray(o, np.float32)[1, 0],
+                           np.asarray(o, np.float32)[1, 1], atol=1e-2)
+
+
+@pytest.mark.parametrize("ps,n_pg", [(64, 4), (64, 6), (128, 2), (128, 3)])
+@pytest.mark.parametrize("kv", ["float32", "bfloat16"])
+def test_prefill_kernel_at_twenty_query_heads_over_one_kv_head(kv, ps, n_pg):
+    """`_block_boundary_rows` at the cell's head shape: table widths
+    under, at and over a kv block, at both page sizes."""
+    rng = np.random.default_rng(6)
+    C = 6
+    tables, offsets, n_valid = (
+        jnp.asarray(x, jnp.int32)
+        for x in _block_boundary_rows(ps, n_pg, C))
+    B = tables.shape[0]
+    dtype = jnp.bfloat16 if kv == "bfloat16" else jnp.float32
+    q = jnp.asarray(rng.normal(size=(B, C, MQA_H, MQA_K)), dtype)
+    k_pool, v_pool, _t, _n = _pool_and_tables(
+        rng, B=B, H=1, K=MQA_K, ps=ps, n_pg=n_pg, dtype=dtype)
+    assert int(tables.max()) < k_pool.shape[1]
+    layer = jnp.int32(1)
+    o = paged_prefill_attention(q, k_pool, v_pool, layer, tables, offsets,
+                                offsets + n_valid)
+    ref = reference_paged_prefill_attention(
+        q, k_pool, v_pool, layer, tables, offsets, offsets + n_valid)
+    assert o.shape == q.shape and o.dtype == q.dtype
+    valid = np.arange(C)[None, :] < np.asarray(n_valid)[:, None]
+    np.testing.assert_allclose(
+        np.asarray(o, np.float32)[valid], np.asarray(ref, np.float32)[valid],
+        atol=3e-2 if kv == "bfloat16" else 1e-5)
+
+
+def test_a_pool_of_one_kv_head_is_a_group_of_all_the_query_heads():
+    """`_check_pool` reads G off the pool's lanes: 128 lanes under heads
+    of 128 is ONE KV head serving all 20; a pool of three such heads is
+    refused, since 3 does not divide 20, in words that hold at G = 1."""
+    from ray_tpu.ops.paged_attention import _check_pool
+
+    pool = lambda g: jnp.zeros((2, 5, 64, g * MQA_K), jnp.bfloat16)
+    assert _check_pool(MQA_H, MQA_K, pool(1), pool(1)) == (64, 1, MQA_K)
+    assert _check_pool(MQA_H, MQA_K, pool(4), pool(4)) == (64, 4, MQA_K)
+    with pytest.raises(ValueError, match="G dividing the query heads"):
+        _check_pool(MQA_H, MQA_K, pool(3), pool(3))

@@ -18,16 +18,18 @@ import optax
 import pytest
 from jax.sharding import Mesh
 
-from ray_tpu.models import gpt, laguna, paged_kv, qwen3_next, serving, zaya
+from ray_tpu.models import (gpt, jamba, laguna, paged_kv, qwen3_next, serving,
+                            zaya)
 from ray_tpu.ops import scopes
 from ray_tpu.train import spmd
 
 PAGE, N_PAGES, N_SLOTS, CHUNK, ROWS, WIDTH = 16, 24, 4, 16, 2, 4
 TINY = {"gpt": gpt.GPTConfig.tiny_untied, "zaya": zaya.ZayaConfig.tiny,
         "laguna": laguna.LagunaConfig.tiny,
-        "qwen3_next": qwen3_next.Qwen3NextConfig.tiny}
+        "qwen3_next": qwen3_next.Qwen3NextConfig.tiny,
+        "jamba": jamba.JambaConfig.tiny}
 MODULES = {"gpt": paged_kv, "zaya": zaya, "laguna": laguna,
-           "qwen3_next": qwen3_next}
+           "qwen3_next": qwen3_next, "jamba": jamba}
 
 
 def _shapes(fn, *args, **kw):
@@ -167,13 +169,16 @@ def test_every_part_of_a_program_lies_in_one_scope(family, program):
         want |= {scopes.ATTN_KV_WRITE}
         want |= {scopes.SAMPLE} if program == "decode" else set()
         want |= {scopes.MLP} if family != "zaya" else set()
-        if family != "gpt":
+        if family not in ("gpt", "jamba"):      # the two without experts
             want |= {scopes.MOE_ROUTE, scopes.MOE_EXPERTS}
             want |= {scopes.COUNTERS} if program == "decode" else set()
         want |= {scopes.SLOT_STATE} if family == "zaya" else set()
         if family == "qwen3_next":
             # The chain of a dispatch's rows is the chunk program's.
             want |= {scopes.GDN_IN, scopes.GDN_SCAN, scopes.GDN_OUT}
+            want |= {scopes.SLOT_STATE} if program == "chunk" else set()
+        if family == "jamba":
+            want |= {scopes.SSM_IN, scopes.SSM_SCAN, scopes.SSM_OUT}
             want |= {scopes.SLOT_STATE} if program == "chunk" else set()
     assert want <= seen, f"scopes never opened: {sorted(want - seen)}"
 
@@ -190,6 +195,6 @@ def test_a_scope_adds_nothing_to_the_module(family, program):
 
 
 def test_the_vocabulary_is_small_and_flat():
-    assert len(scopes.ALL) == len(set(scopes.ALL)) <= 18
+    assert len(scopes.ALL) == len(set(scopes.ALL)) <= 20
     for name in scopes.ALL:
         assert re.fullmatch(r"[a-z_]+(\.[a-z_]+)?", name), name
