@@ -35,7 +35,13 @@ slots a tap (with the three taps on the second-minor axis the chip pads
 them to a tile of their own; a decode step reads and shifts a block of
 slots' planes in place, `ops.selective_scan.ssm_conv_step`): 9.3 MB a
 slot at the published sizes, whatever the context. The last slot is the
-null slot, which idle rows name. Idle slots, a reused slot's reset at
+null slot, which a chunk row that writes nothing back names. A decode
+step's mamba sublayer runs on planes without the token axis, ``[B, Dn]``
+and ``[B, D]``, a slot a row, and both of its kernels take the slots in
+blocks (`ssm_decode_step` 8 a grid step, the state's ``[8, S, Dn]`` read
+and written in place; `ssm_conv_step` 32): an idle slot inside a live
+block keeps every bit, a block of idle slots moves nothing of the state.
+Idle slots, a reused slot's reset at
 offset 0, the rows of one dispatch that continue each other and a prompt whose chunks
 are split over dispatches with decode windows between behave as
 models/qwen3_next.py states for its recurrence: a decode step's batch IS
@@ -247,7 +253,8 @@ def _causal_conv(boundary, n_taps: int):
 @jax.named_scope(scopes.SSM_IN)
 def _ssm_inputs(cfg: JambaConfig, params, l, i, x, valid, conv):
     """Mamba layer l up to what the scan takes. x [N, C, D]; valid [N, C]
-    bool (a token that is none leaves the state alone: dt = 0);
+    bool (a token that is none leaves the state alone: dt = 0); a decode
+    step passes planes without the token axis, x [N, D] and valid [N];
     `conv(xs, taps, bias)` → (the activated xs, anything): the
     convolution, `_causal_conv(boundary, taps)` over a row's own tokens
     or a decode step's, which keeps the tail in the pool.
@@ -285,7 +292,8 @@ def _decay(params, i):
 @jax.named_scope(scopes.SSM_OUT)
 def _ssm_output(cfg: JambaConfig, params, i, x, y, z):
     """From the scan's output y [N, C, Dn] float32 to the sublayer's
-    end: the gate, W_out, the residual."""
+    end: the gate, W_out, the residual (a decode step's y is [N, Dn] and
+    its x [N, D])."""
     dt_ = cfg.dtype
     y = (y * jax.nn.silu(z.astype(_F32))).astype(dt_)
     return x + y @ params["m_out"][i].astype(dt_)
@@ -490,7 +498,6 @@ def _decode_once(cfg: JambaConfig, params, tokens, pool, positions, tables,
     B = tokens.shape[0]
     ps = pool["k"].shape[2]
     active = tables[:, 0] > 0
-    live = active[:, None]
     with jax.named_scope(scopes.ATTN_KV_WRITE):
         write_page = jnp.take_along_axis(
             tables,
@@ -503,19 +510,21 @@ def _decode_once(cfg: JambaConfig, params, tokens, pool, positions, tables,
                        (reference_ssm_decode_step, reference_ssm_conv_step))
 
     def mamba(l, i, x, pool):
+        # The sublayer on planes [B, ..], a slot a row: the token axis is
+        # put back for the residual stream alone, so that the kernels'
+        # operands and what is fused to them lie a slot a sublane.
         def conv(xs, taps, bias):
-            act, tail = conv_step(pool["ssm_conv"], i, xs[:, 0], taps, bias,
-                                  active)
-            return act[:, None], tail
+            return conv_step(pool["ssm_conv"], i, xs, taps, bias, active)
 
-        xs, z, dt, Bm, Cm, tail = _ssm_inputs(cfg, params, l, i, x, live,
+        x = x[:, 0]
+        xs, z, dt, Bm, Cm, tail = _ssm_inputs(cfg, params, l, i, x, active,
                                               conv)
         pool = {**pool, "ssm_conv": tail}
         with jax.named_scope(scopes.SSM_SCAN):
-            y, state = step(pool["ssm_state"], i, xs[:, 0], dt[:, 0],
-                            Bm[:, 0], Cm[:, 0], *_decay(params, i), active)
+            y, state = step(pool["ssm_state"], i, xs, dt, Bm, Cm,
+                            *_decay(params, i), active)
             pool = {**pool, "ssm_state": state}
-        return _ssm_output(cfg, params, i, x, y[:, None], z), pool
+        return _ssm_output(cfg, params, i, x, y, z)[:, None], pool
 
     def attn(l, i, x, pool):
         q, k, v = _attn_inputs(cfg, params, l, i, x)

@@ -20,12 +20,22 @@ B_t and C_t enter as columns spread over the lanes and y_t is a sum over
 sublanes. A token that must leave the state alone carries dt = 0.
 
 `ssm_decode_step` advances every live slot's state by ONE token, in
-place: a Pallas kernel (`ssm_decode_step` in a trace) whose grid walks
-the slots of the whole ``[L, n_slots+1, S, d_inner]`` stack, aliased in
-and out, so a step reads and writes each live slot's state once and
-nothing is gathered, scattered or copied. An idle slot's grid step names
-the null slot's block (the stack's last row) and passes it through:
-consecutive idle steps move nothing.
+place: a Pallas kernel (`ssm_decode_step` in a trace) over the whole
+``[L, n_slots+1, S, d_inner]`` stack, aliased in and out, whose grid walks
+the slots in blocks of `_STEP_SLOTS` (row b of the batch is slot b): a
+grid step reads a block's states ``[8, S, d_inner]`` (2.6 MB at the
+served sizes), advances them and writes them back, so nothing is
+gathered, scattered or copied. Everything a slot brings is a plane with
+the slots down the sublanes (xs, dt and y ``[n, d_inner]``, eight slots a
+tile) or along the lanes (B and C ``[S, n]``, a value of the state a
+sublane), which are the layouts the compiler gives those values unasked:
+with one slot a grid step they were ``[n, 1, d_inner]``, a row a tile,
+and the fusions beside the kernel ran on an eighth of a register. An idle
+slot inside a live block keeps every bit; a block with no live slot names
+the block the step before it named, so nothing of it is fetched or
+written and its body is skipped. The kernel runs at what HBM gives a
+stream read and written at once (~650 GB/s on the v5e, where reads alone
+reach 746 and writes alone 650), whatever the block's height.
 
 `ssm_chunk_scan` runs N rows of C tokens (`ssm_chunk_scan` in a trace):
 the grid walks (block of channels, row), a row's state sits in fast
@@ -67,6 +77,13 @@ _CHUNK_CHANNELS = 512
 # (taps-1) x 32 x 5,120 bf16 = 1 MB in and 1 MB out, twice for the
 # pipeline; a multiple of the 16 rows a bf16 tile has.
 _CONV_SLOTS = 32
+# Slots a grid step of the scan's decode step holds: a float32 tile's 8
+# sublanes, so xs, dt and y move as whole tiles [8, d_inner]; their state
+# is 8 x 16 x 5,120 float32 = 2.6 MB in and 2.6 MB out, twice for the
+# pipeline, inside the default VMEM limit (16 needs it raised). On the
+# v5e 8, 16 and 32 read 0.2719, 0.2724 and 0.2740 ms a layer of 256 slots
+# (PERF.md, PR 54): the stream is HBM's, not the grid's.
+_STEP_SLOTS = 8
 
 
 def _interpret_default() -> bool:
@@ -220,67 +237,111 @@ def reference_ssm_decode_step(state, layer, xs, dt, B, C, A, D, active):
     return y, state.at[layer, :n].set(h)
 
 
-def _step_kernel(layer_ref, rows_ref, u_ref, bc_ref, a_ref, d_ref, h_ref,
-                 y_ref, h_out_ref, *, null_slot):
-    """One slot. u_ref [2, Dn]: dt over xs; bc_ref [S, 2]: B beside C, a
-    value of the state a sublane."""
-    del layer_ref
-    live = rows_ref[pl.program_id(0)] != null_slot
+def _step_kernel(layer_ref, named_ref, live_ref, xs_ref, dt_ref, b_ref,
+                 c_ref, a_ref, d_ref, h_ref, y_ref, h_out_ref):
+    """A block of R consecutive slots. xs_ref (in the caller's dtype),
+    dt_ref, y_ref [R, Dn]: a slot a sublane; b_ref, c_ref [S, n]: EVERY
+    slot's B and C, a slot a lane and a value of the state a sublane, as
+    the state has them; h_ref [R, S, Dn]; live_ref [n] int32 in scalar
+    memory: `active`."""
+    del layer_ref, named_ref
+    g = pl.program_id(0)
+    R = h_ref.shape[0]
+    live = [live_ref[g * R + r] != 0 for r in range(R)]
+    any_live = functools.reduce(jnp.logical_or, live)
 
-    @pl.when(live)
+    @pl.when(any_live)
     def _():
-        dt, xs = u_ref[0:1, :], u_ref[1:2, :]
-        h = (jnp.exp(dt * a_ref[...]) * h_ref[...]
-             + (dt * xs) * bc_ref[:, 0:1])
-        h_out_ref[...] = h
-        y_ref[...] = (jnp.sum(h * bc_ref[:, 1:2], axis=0, keepdims=True)
-                      + d_ref[...] * xs)
+        a, d = a_ref[...], d_ref[...]
+        xs_rows = xs_ref[...].astype(_F32)
+        b_all, c_all = b_ref[...], c_ref[...]
+        slot = jax.lax.broadcasted_iota(jnp.int32, b_all.shape, 1)
+        for r in range(R):
+            # Slot g R + r's column of B and of C, [S, 1].
+            mine = slot == g * R + r
+            b, c = (jnp.sum(jnp.where(mine, t, 0.0), axis=1, keepdims=True)
+                    for t in (b_all, c_all))
+            xs, dt = xs_rows[r:r + 1, :], dt_ref[r:r + 1, :]
+            old = h_ref[r]
+            h = jnp.exp(dt * a) * old + (dt * xs) * b
+            h_out_ref[r] = jnp.where(live[r], h, old)
+            y_ref[r:r + 1, :] = (jnp.sum(h * c, axis=0, keepdims=True)
+                                 + d * xs)
 
-    @pl.when(jnp.logical_not(live))
+    @pl.when(jnp.logical_not(any_live))
     def _():
-        h_out_ref[...] = h_ref[...]
         y_ref[...] = jnp.zeros_like(y_ref)
+
+        # The first grid step's block is written back whatever follows:
+        # with no live slot in it, as it came.
+        @pl.when(g == 0)
+        def _():
+            h_out_ref[...] = h_ref[...]
 
 
 def ssm_decode_step(state, layer, xs, dt, B, C, A, D, active, *,
                     interpret=None):
     """`reference_ssm_decode_step` as one kernel over the whole stack,
-    donated: slot b's state is read and written once, an idle slot's not
-    at all. → (y [n, Dn] float32, the updated stack)."""
+    donated: row b of the batch is slot b, a grid step holds a block of
+    `_STEP_SLOTS` consecutive slots (all n where n is no multiple), and a
+    live block's states are read and written once, in place.
+
+    An idle slot inside a live block rides along and keeps every bit
+    (`where(live, h, old)`: its 328 KB are moved, which a full batch
+    never pays). A block with NO live slot moves nothing: its grid step
+    names the block the step before it named (the last live block at or
+    before it; before the first live block, that one; the last block
+    when no slot is live), so nothing is fetched, the body is skipped,
+    and the block in fast memory is written back once, when the next
+    live block takes its place. A batch at a tenth of its slots streams
+    a tenth of its blocks, give or take how the live slots lie (measured,
+    PERF.md PR 54: 24 live slots of 256 in three blocks 0.033 ms a layer
+    against a full batch's 0.272).
+    → (y [n, Dn] float32, zeros for an idle block; the updated stack)."""
     if interpret is None:
         interpret = _interpret_default()
-    _L, rows, S, Dn = state.shape
+    _L, _rows, S, Dn = state.shape
     n = xs.shape[0]
     if state.dtype != _F32 or (not interpret and (Dn % 128 or S % 8)):
         raise ValueError(
             f"ssm_decode_step wants a float32 state of a multiple of 8 "
             f"values over a multiple of 128 channels; got {state.dtype} "
             f"{state.shape}")
-    null_slot = rows - 1
-    slot_rows = jnp.where(active, jnp.arange(n, dtype=jnp.int32), null_slot)
+    R = _STEP_SLOTS if n % _STEP_SLOTS == 0 else n
+    G = n // R
     f32 = lambda t: t.astype(_F32)
-    a_slot = lambda *shape: pl.BlockSpec((None,) + shape,
-                                         lambda b, *_: (b, 0, 0))
-    block = pl.BlockSpec((None, None, S, Dn),
-                         lambda b, layer, slot: (layer[0], slot[b], 0, 0))
+    # named [G]: the last block at or before g that holds a live slot,
+    # else the first that does, else the last of all. (Each from `active`
+    # itself by one reduction, no wider than it: what the compiler lifts
+    # out of a loop over layers.)
+    at = jnp.arange(G, dtype=jnp.int32)
+    block_of = jnp.arange(n, dtype=jnp.int32) // R
+    last_live = jnp.max(
+        jnp.where(active[None, :] & (block_of[None, :] <= at[:, None]),
+                  block_of[None, :], -1), axis=1)
+    named = jnp.where(last_live >= 0, last_live,
+                      jnp.min(jnp.where(active, block_of, G - 1)))
+    rows = pl.BlockSpec((R, Dn), lambda g, layer, named, live: (named[g], 0))
+    whole = lambda *shape: pl.BlockSpec(shape, lambda g, *_: (0, 0))
+    block = pl.BlockSpec((None, R, S, Dn),
+                         lambda g, layer, named, live:
+                         (layer[0], named[g], 0, 0))
     y, state = pl.pallas_call(
-        functools.partial(_step_kernel, null_slot=null_slot),
+        _step_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(n,),
-            in_specs=[a_slot(2, Dn), a_slot(S, 2),
-                      pl.BlockSpec((S, Dn), lambda b, *_: (0, 0)),
-                      pl.BlockSpec((1, Dn), lambda b, *_: (0, 0)), block],
-            out_specs=[a_slot(1, Dn), block]),
-        out_shape=[jax.ShapeDtypeStruct((n, 1, Dn), _F32),
+            num_scalar_prefetch=3, grid=(G,),
+            in_specs=[rows, rows, whole(S, n), whole(S, n), whole(S, Dn),
+                      whole(1, Dn), block],
+            out_specs=[pl.BlockSpec((R, Dn), lambda g, *_: (g, 0)), block]),
+        out_shape=[jax.ShapeDtypeStruct((n, Dn), _F32),
                    jax.ShapeDtypeStruct(state.shape, state.dtype)],
-        input_output_aliases={6: 1},
+        input_output_aliases={9: 1},
         interpret=interpret,
         name="ssm_decode_step",
-    )(jnp.asarray(layer, jnp.int32).reshape(1), slot_rows,
-      jnp.stack([f32(dt), f32(xs)], axis=1),
-      jnp.stack([f32(B), f32(C)], axis=2), f32(A), f32(D).reshape(1, Dn),
-      state)
-    return y[:, 0], state
+    )(jnp.asarray(layer, jnp.int32).reshape(1), named,
+      active.astype(jnp.int32), xs, f32(dt), f32(B).T, f32(C).T,
+      f32(A), f32(D).reshape(1, Dn), state)
+    return y, state
 
 
 # ------------------------------------------- the convolution's decode step

@@ -1119,7 +1119,13 @@ def test_jamba_program_fits_and_moves_no_state(jamba_serving, step_program,
     no weight plane is cut out of its stack into a buffer of its own (a
     matmul's fusion slices its plane where it lies); the donated pool
     (pages, state, tails) is updated in place; weights + pool + what the
-    program needs besides are 9.5-10.5 GB of the chip's 16."""
+    program needs besides are 9.5-10.5 GB of the chip's 16. In the decode
+    program every value a slot a row over the mixer's 5,120 channels (the
+    step kernel's xs, dt and y, the gate, what is fused to them) lies a
+    slot a SUBLANE, 8 to a tile: with the kernel's blocks one slot tall
+    the compiler kept 96 such values a row a tile, `[256, 1, 5120]` in
+    `T(1,128)` and `T(2,128)`, and the fusions beside the kernel ran on
+    an eighth of a register (PR 54)."""
     cfg, _params, pool = jamba_serving
     assert pool["ssm_state"].shape == (26, J_SLOTS + 1, 16, 5120)
     assert pool["ssm_conv"].shape == (26, 3, J_SLOTS + 1, 5120)
@@ -1134,6 +1140,12 @@ def test_jamba_program_fits_and_moves_no_state(jamba_serving, step_program,
     for name, n in kernels.items():
         assert len(re.findall(rf"%\w*{name}[\w.]* = [^\n]*custom-call\(",
                               text)) == n, name
+    if program == "decode":
+        sparse = re.findall(
+            rf"\w+\[{J_SLOTS},(?:1,)?5120\]\{{[^}}]*T\([124],128\)[^}}]*\}}",
+            text)
+        assert not sparse, f"{len(sparse)} values a row a tile: {sparse[:4]}"
+        assert f"[{J_SLOTS},5120]" in text
     moved = (_pool_moves(text, "f32", (J_SLOTS + 1) * 16 * 5120)
              + _pool_moves(text, "bf16", 26 * 3 * (J_SLOTS + 1) * 5120))
     assert not moved, "state-sized moves:\n" + "\n".join(moved)

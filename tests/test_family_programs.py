@@ -32,8 +32,11 @@ PAGE, N_PAGES, N_SLOTS, CHUNK = 16, 24, 3, 16
 # `blocks.dispatch_order` (the same equations, `iota(N)` traced four
 # equations later), and the COMPILED chunk program is instruction for
 # instruction what ffb23a00a1eeb73d compiled to (17,774 lines of
-# optimized HLO, compared less source locations: CHANGES.md, PR 53). The
-# jamba three as PR 53, the family's first, traced them.
+# optimized HLO, compared less source locations: CHANGES.md, PR 53).
+# `jamba.chunk` as PR 53, the family's first, traced it; `jamba.decode`
+# and `jamba.sample` as PR 54 did, which meant to change them: the mamba
+# sublayer of a decode step on planes without the token axis and
+# `ssm_decode_step` over blocks of slots.
 _PINNED = {
     "gpt.chunk": "aff570174e390475", "gpt.decode": "c4ebbb44ff02a83f",
     "zaya.chunk": "e3c3c03e1e115475", "zaya.decode": "21818dedb3b70792",
@@ -46,8 +49,8 @@ _PINNED = {
     "laguna.sample": "1a557bccce429947",
     "qwen3_next.sample": "a023e156a1cb54d9",
     "mimo_v2.sample": "cd452c05dcdd0a15",
-    "jamba.chunk": "16c983f57626c3f6", "jamba.decode": "df66bebf30e06d17",
-    "jamba.sample": "c311836851bbdaa1",
+    "jamba.chunk": "16c983f57626c3f6", "jamba.decode": "5262465ac313b68d",
+    "jamba.sample": "ff4734dd0f2b5ee8",
 }
 
 _RING = {"dispatch_tokens": 2 * CHUNK}

@@ -383,10 +383,10 @@ def test_a_fault_fails_the_tolerance(params, fault, monkeypatch):
             lambda xs, dt, B, C, A, D, state, chain, fresh: scan(
                 xs, dt, B, C, A, D, state, chain, jnp.ones_like(fresh)))
     elif fault == "tail_not_carried":
-        # A chunk row (not a decode step) starts its convolution from
-        # zeros whatever came before it.
+        # A chunk row (not a decode step, whose planes have no token
+        # axis) starts its convolution from zeros whatever came before it.
         monkeypatch.setattr(jm, "_ssm_inputs", _ssm_inputs_with(
-            true, lambda *out, x, rerun: out if x.shape[1] == 1 else rerun(
+            true, lambda *out, x, rerun: out if x.ndim == 2 else rerun(
                 lambda xs: (jnp.zeros((xs.shape[0], xs.shape[2]), xs.dtype),)
                 * (CFG.d_conv - 1))))
     elif fault.endswith("_norm_dropped"):

@@ -122,28 +122,46 @@ def test_chunk_scan_chains_fresh_rows_and_tokens_that_are_none(scan):
     assert np.abs(ref(1, state[1])[1] - h1).max() > 1e-2
 
 
+# The live slots of a batch: the step kernel takes the slots in blocks of
+# 8, and all of them as one block where their count is no multiple.
+_T, _F = True, False
+DECODE_BATCHES = {
+    "under-one-block": [_T, _F, _T, _T, _F],
+    "one-and-a-half-blocks": [_T] * 7 + [_F] + [_T, _F, _T, _T],
+    "idle-slot-in-a-live-block": [_T] * 3 + [_F] + [_T] * 12,
+    "idle-block-between": [_T, _F] * 4 + [_F] * 8 + [_F] * 7 + [_T],
+    "idle-blocks-first": [_F] * 16 + [_F, _T] * 4,
+    "idle-blocks-last": [_F, _T, _T] + [_F] * 21,
+    "all-idle": [_F] * 16,
+    "all-live": [_T] * 16,
+}
+
+
+@pytest.mark.parametrize("batch", sorted(DECODE_BATCHES))
 @pytest.mark.parametrize("step", [ssm_decode_step, reference_ssm_decode_step],
                          ids=["kernel", "xla"])
-def test_decode_step_updates_the_live_slots_in_place(step):
-    """Five slots and the null slot, three layers: the live slots of
-    layer 1 advance by the definition, the idle slots, the null slot and
-    the other layers keep every bit."""
+def test_decode_step_updates_the_live_slots_in_place(step, batch):
+    """n slots and the null slot, three layers: the live slots of layer 1
+    advance by the definition; the idle slots (inside a live block, or a
+    whole block of them), the null slot and the other layers keep every
+    bit."""
     rng = np.random.default_rng(2)
-    L, n, Dn = 3, 5, 256
+    L, Dn = 3, 256
+    active = np.asarray(DECODE_BATCHES[batch])
+    n = len(active)
     xs, dt, B, Cm, A, D = _inputs(rng, 1, n, Dn, STEPS["mixed"])
     stack = jnp.asarray(rng.normal(size=(L, n + 1, S, Dn)), jnp.float32)
-    active = jnp.asarray([True, False, True, True, False])
-    y, out = step(stack, 1, xs[0], dt[0], B[0], Cm[0], A, D, active)
-    for b in range(n):
-        if not active[b]:
-            continue
+    y, out = step(stack, 1, xs[0], dt[0], B[0], Cm[0], A, D,
+                  jnp.asarray(active))
+    assert y.shape == (n, Dn) and out.shape == stack.shape
+    for b in np.flatnonzero(active):
         y_ref, h_ref = _by_hand(xs[0, b:b + 1], dt[0, b:b + 1],
                                 B[0, b:b + 1], Cm[0, b:b + 1], A, D,
                                 stack[1, b])
         _close(y[b], y_ref[0])
         _close(out[1, b], h_ref)
-    kept = np.asarray(~active)
-    assert np.array_equal(out[1, :n][kept], stack[1, :n][kept])
+    assert np.all(np.isfinite(y))
+    assert np.array_equal(out[1, :n][~active], stack[1, :n][~active])
     assert np.array_equal(out[1, n], stack[1, n])
     assert np.array_equal(out[0], stack[0]) and np.array_equal(out[2],
                                                                stack[2])
