@@ -494,18 +494,36 @@ def _decode_once_paged(cfg: GPTConfig, params, tokens, pool, positions,
     return logits, pool
 
 
+def _scale_by_temps(logits, temps):
+    """Logits [B, V] over each slot's temperature (a greedy slot's row is
+    scaled by 1e6 and read by nobody)."""
+    return logits / jnp.maximum(temps, 1e-6)[:, None]
+
+
 @jax.named_scope(scopes.SAMPLE)
 def _sample_next(logits, temps, key):
     """Shared on-device sampling step for every fused loop (decode
     window + speculative draft, tp and non-tp twins alike): greedy
     argmax at temp <= 0, else temperature-scaled categorical.
-    → (next tokens int32, scaled logits, advanced key)."""
+
+    The draw (threefry bits over [B, V], Gumbel noise, a second arg-max)
+    runs under a `lax.cond` on what the step observes in its input: a
+    batch in which no slot has a temperature above 0 computes the
+    arg-max and nothing else over [B, V] (the draw was 0.4 ms of every
+    step at 16.8 M logits, PERF.md section 6, PR 55). The key is split
+    ahead of the conditional either way, so a seeded slot's stream and
+    the returned key do not depend on whether its neighbours, or an
+    earlier step's, were greedy.
+    → (next tokens int32, advanced key)."""
     key, sub = jax.random.split(key)
-    scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
-    greedy = jnp.argmax(logits, axis=-1)
-    sampled = jax.random.categorical(sub, scaled, axis=-1)
-    nxt = jnp.where(temps <= 0.0, greedy, sampled).astype(jnp.int32)
-    return nxt, scaled, key
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    def draw():
+        sampled = jax.random.categorical(
+            sub, _scale_by_temps(logits, temps), axis=-1)
+        return jnp.where(temps <= 0.0, greedy, sampled).astype(jnp.int32)
+
+    return jax.lax.cond(jnp.any(temps > 0.0), draw, lambda: greedy), key
 
 
 @jax.named_scope(scopes.HEAD)
@@ -534,10 +552,10 @@ def _spec_propose_scan(cfg: GPTConfig, params, tokens, pool, positions,
         logits, pool = _decode_once_paged(
             cfg, params, toks, pool, pos, tables, attn_impl,
             write_mask=i <= n_prop, tp_axis=tp_axis)
-        nxt, scaled, key = _sample_next(logits, temps, key)
+        nxt, key = _sample_next(logits, temps, key)
         with jax.named_scope(scopes.SAMPLE):
-            ys = ((nxt, jax.nn.softmax(scaled, axis=-1)) if need_probs
-                  else nxt)
+            ys = ((nxt, jax.nn.softmax(_scale_by_temps(logits, temps),
+                                       axis=-1)) if need_probs else nxt)
         return (nxt, pos + 1, pool, key), ys
 
     carry0 = (tokens, positions, pool, key)
@@ -727,7 +745,7 @@ def paged_programs(chunk_forward, decode_once, chunk_logits,
         key)."""
         logits, pool = decode_once(cfg, params, tokens, pool, positions,
                                    tables, attn_impl)
-        nxt, _scaled, key = _sample_next(logits, temps, key)
+        nxt, key = _sample_next(logits, temps, key)
         return nxt, positions + 1, pool, key
 
     def decode_multi_paged(cfg, params, tokens, pool, positions, tables,
@@ -992,7 +1010,7 @@ def _decode_sample_paged_tp(cfg: GPTConfig, params, tokens, pool, positions,
         logits, pool = _decode_once_paged(
             cfg, params, tokens, pool, positions, tables, attn_impl,
             tp_axis="tp")
-        nxt, _scaled, key = _sample_next(logits, temps, key)
+        nxt, key = _sample_next(logits, temps, key)
         return nxt, positions + 1, pool, key
 
     return _smap(body, mesh,

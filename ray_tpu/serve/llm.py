@@ -884,6 +884,10 @@ class LLMEngine:
                       **{"lookahead_stood_down_" + cause: 0
                          for cause in _STAND_DOWN},
                       "lookahead_rows_dropped": 0,
+                      # Windows whose step programs were handed a
+                      # temperature above 0, so that their sampling
+                      # step drew (`_upload_temps`): `decode_draw_share`.
+                      "decode_windows_drawn": 0,
                       "slot_step_sum": 0, "slot_cap_sum": 0,
                       "preemptions": 0,
                       # Prefix-cache lifecycle (zeros unless enabled).
@@ -1543,6 +1547,12 @@ class LLMEngine:
                 m["lookahead_stood_down"] = {
                     cause: m["lookahead_stood_down_" + cause]
                     for cause in _STAND_DOWN}
+                # The share of decode windows whose steps ran the
+                # categorical draw (paged_kv._sample_next skips it for
+                # a batch with no temperature above 0): 0.0 under
+                # greedy traffic, 1.0 where some slot always samples.
+                m["decode_draw_share"] = m["decode_windows_drawn"] / max(
+                    1, m["decode_windows"])
                 # Quantized-serving observability (rides the PR 6 chain:
                 # replica stats → serve.status() → /api/serve/load →
                 # `ray_tpu status --serve`): the dtype knobs as resolved
@@ -3232,7 +3242,7 @@ class LLMEngine:
                 self.draft_cfg, self.draft_params, jnp.asarray(self.tokens),
                 self.draft_cache, jnp.asarray(self.positions),
                 jnp.asarray(table_view), jnp.asarray(n_prop),
-                jnp.asarray(self.temps), sub, k=k, attn_impl=self.attn_impl,
+                self._upload_temps(), sub, k=k, attn_impl=self.attn_impl,
                 need_probs=sampling)
         with self._phase("decode.pull"):
             proposals = np.asarray(proposals)                  # [k, B]
@@ -3473,7 +3483,7 @@ class LLMEngine:
             if carry is None:
                 if k > 1:
                     self._rng_key, sub = rt.jax.random.split(self._rng_key)
-                    temps = jnp.asarray(self.temps)
+                    temps = self._upload_temps()
                 tokens = jnp.asarray(self.tokens)
                 positions = jnp.asarray(self.positions)
             elif n_new:
@@ -3481,7 +3491,7 @@ class LLMEngine:
                 # token, on the device, a position on; a slot that
                 # graduated this tick feeds the host's, as ever.
                 sub = carry.key
-                temps = jnp.asarray(self.temps)
+                temps = self._upload_temps()
                 tokens, positions = rt.join_window(
                     jnp.asarray(carry.mask), carry.tokens,
                     jnp.asarray(self.tokens), jnp.asarray(self.positions))
@@ -3590,6 +3600,16 @@ class LLMEngine:
                 if self._emit(req, tok):
                     self._release(slot)
         return len(active) + n_prefilling
+
+    def _upload_temps(self):
+        """Every slot's temperature, for a window's step programs. The
+        sampling step draws where any of them is above 0
+        (`paged_kv._sample_next`): the same test, on the host's copy,
+        counts the window as one that drew. (A dense cache's window,
+        `decode.decode_multi`, still draws at every step.)"""
+        if (self.temps > 0.0).any():
+            self.stats["decode_windows_drawn"] += 1
+        return self._rt.jnp.asarray(self.temps)
 
     def _decode_ready_slots(self) -> list[int]:
         return [i for i in range(self.n_slots)

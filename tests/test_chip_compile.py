@@ -1170,3 +1170,74 @@ def test_jamba_program_fits_and_moves_no_state(jamba_serving, step_program,
           f"{mem.argument_size_in_bytes / 1e9:.3f} GB, temp "
           f"{mem.temp_size_in_bytes / 1e9:.3f} GB, total {total / 1e9:.3f} GB")
     assert 9.5e9 < total < 10.5e9
+
+
+# --- the sampling step: the draw under a conditional (PR 55) ------------
+_DRAW = re.compile(r"op_name=\"[^\"]*(?:_gumbel|_uniform)")
+
+
+def _entry(text):
+    """The lines of a compiled program's entry computation."""
+    entry = text[text.index("\nENTRY "):]
+    return entry[:entry.index("\n}")].splitlines()
+
+
+def _wide_prefetches(lines, rows, least_cols=4096):
+    """`copy-start`s of a float32 [rows, >= least_cols] value: the logits
+    moved as a conditional's operand, ahead of its predicate."""
+    return [line.strip()[:160] for line in lines if " copy-start(" in line
+            and any(int(cols) >= least_cols for cols in re.findall(
+                rf"f32\[{rows},(\d+)\]", line.partition(" copy-start(")[0]))]
+
+
+def test_draw_rules_find_what_they_are_there_to_refuse(chip):
+    """The two rules of the test below, each shown to see something. The
+    four straight lines `_sample_next` was up to PR 54 (scale, arg-max,
+    categorical, where), compiled for the chip, draw in the entry
+    computation. And `_sample_next` as it is, compiled ALONE, gets its
+    logits as a parameter in HBM: the compiler prefetches all of them
+    into fast memory as the conditional's operand, before the predicate
+    is known (in a step program the head's fusion writes them there)."""
+    from ray_tpu.models.paged_kv import _sample_next
+
+    def straight(logits, temps, key):
+        key, sub = jax.random.split(key)
+        scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
+        sampled = jax.random.categorical(sub, scaled, axis=-1)
+        return jnp.where(temps <= 0.0, jnp.argmax(logits, axis=-1),
+                         sampled).astype(jnp.int32), key
+
+    args = (chip((64, 32768), jnp.float32), chip((64,), jnp.float32),
+            chip((2,), jnp.uint32))
+    text = jax.jit(straight).lower(*args).compile().as_text()
+    assert any(_DRAW.search(line) for line in _entry(text))
+    assert " conditional(" not in text
+    entry = _entry(jax.jit(_sample_next).lower(*args).compile().as_text())
+    assert not any(_DRAW.search(line) for line in entry)
+    assert len(_wide_prefetches(entry, 64)) == 1
+
+
+@pytest.mark.parametrize("family", ["gpt", "jamba", "laguna", "mimo_v2",
+                                    "qwen3_next", "zaya"])
+def test_decode_program_draws_only_inside_its_conditional(step_program,
+                                                          family):
+    """Every family's window step, compiled whole at its cell's size: the
+    sampling step's `lax.cond` is still a `conditional` of the entry
+    computation; nothing of the categorical draw (the threefry words, the
+    Gumbel noise, the reduction they are fused into: 0.40-0.42 ms of
+    every step at 16.8 M logits, PERF.md section 6, PR 55) runs in the
+    entry, where a greedy batch would pay for it; the draw is in the
+    program all the same, inside a branch; and the logits reach the
+    conditional where the head's fusion wrote them, not through a
+    prefetch of [slots, vocabulary] float32 made ahead of the predicate
+    (which a conditional over a PARAMETER of that size got)."""
+    text = step_program(family, "decode").as_text()
+    entry = _entry(text)
+    assert sum(" conditional(" in line for line in entry) == 1
+    drawn = [line.strip()[:160] for line in entry if _DRAW.search(line)]
+    assert not drawn, "the draw, in the entry:\n" + "\n".join(drawn)
+    assert _DRAW.search(text)
+    (slots,) = set(re.findall(r"\(s32\[(\d+)\]\{[^}]*\}\) conditional\(",
+                              "\n".join(entry)))
+    moved = _wide_prefetches(entry, slots)
+    assert not moved, "logits prefetched:\n" + "\n".join(moved)

@@ -26,17 +26,23 @@ PAGE, N_PAGES, N_SLOTS, CHUNK = 16, 24, 3, 16
 # and decode window's step (`sample`: the step with sampling on the
 # device) at its tiny size with the kernels on. The first eight as commit
 # 02951b4 traced them (the commit before the mimo_v2 family), the mimo_v2
-# pair and the five `sample` programs as commit c3726bb did (the commit
-# before the builder). `qwen3_next.chunk` as PR 53 traced it: the rule
+# pair as commit c3726bb did (the commit before the builder). The six
+# `sample` programs as PR 55 traced them, which meant to change them and
+# nothing else: `paged_kv._sample_next` makes its categorical draw under
+# a `lax.cond` on whether any slot's temperature is above 0, where every
+# step had drawn [slots, vocabulary] threefry words and thrown them away
+# (tokens and key bit for bit the straight lines': test_sample_next.py);
+# the six `decode` programs, the same decode step without the sampling,
+# stayed where they were. `qwen3_next.chunk` as PR 53 traced it: the rule
 # by which a dispatch's rows find their predecessor moved to
 # `blocks.dispatch_order` (the same equations, `iota(N)` traced four
 # equations later), and the COMPILED chunk program is instruction for
 # instruction what ffb23a00a1eeb73d compiled to (17,774 lines of
 # optimized HLO, compared less source locations: CHANGES.md, PR 53).
 # `jamba.chunk` as PR 53, the family's first, traced it; `jamba.decode`
-# and `jamba.sample` as PR 54 did, which meant to change them: the mamba
-# sublayer of a decode step on planes without the token axis and
-# `ssm_decode_step` over blocks of slots.
+# as PR 54 did, which meant to change it (and `jamba.sample` with it):
+# the mamba sublayer of a decode step on planes without the token axis
+# and `ssm_decode_step` over blocks of slots.
 _PINNED = {
     "gpt.chunk": "aff570174e390475", "gpt.decode": "c4ebbb44ff02a83f",
     "zaya.chunk": "e3c3c03e1e115475", "zaya.decode": "21818dedb3b70792",
@@ -45,12 +51,12 @@ _PINNED = {
     "qwen3_next.decode": "36f158373030eabf",
     "mimo_v2.chunk": "9b0c5bf47a63f542",
     "mimo_v2.decode": "f65efd374bbc0153",
-    "gpt.sample": "1b3422cefe05e0e4", "zaya.sample": "1f1f708fe2e317cd",
-    "laguna.sample": "1a557bccce429947",
-    "qwen3_next.sample": "a023e156a1cb54d9",
-    "mimo_v2.sample": "cd452c05dcdd0a15",
+    "gpt.sample": "057837dac4200223", "zaya.sample": "55f97b147af972ff",
+    "laguna.sample": "ea4b2a839c31e8ec",
+    "qwen3_next.sample": "13d684043747debb",
+    "mimo_v2.sample": "7e6cafe4f7a93297",
     "jamba.chunk": "16c983f57626c3f6", "jamba.decode": "5262465ac313b68d",
-    "jamba.sample": "ff4734dd0f2b5ee8",
+    "jamba.sample": "09798055e3a58725",
 }
 
 _RING = {"dispatch_tokens": 2 * CHUNK}
@@ -105,3 +111,29 @@ def _digest(program: str) -> str:
 @pytest.mark.parametrize("program", sorted(_PINNED))
 def test_every_family_gets_exactly_the_pinned_program(program):
     assert _digest(program) == _PINNED[program]
+
+
+def _walk(jaxpr, inside_cond=False):
+    """(equation, whether a `cond` encloses it) over a jaxpr and every
+    jaxpr its equations carry, a kernel's own aside (its `pl.when`s are
+    `cond`s of another kind)."""
+    for eqn in jaxpr.eqns:
+        yield eqn, inside_cond
+        if eqn.primitive.name == "pallas_call":
+            continue
+        within = inside_cond or eqn.primitive.name == "cond"
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _walk(sub, within)
+
+
+@pytest.mark.parametrize("family", sorted(_FAMILIES))
+def test_a_window_step_draws_only_under_its_conditional(family):
+    """Every family's window step holds ONE `cond` (the sampling step's,
+    on whether any slot's temperature is above 0: PR 55), and nothing
+    that makes random bits runs outside it: a greedy batch pays for the
+    arg-max alone."""
+    eqns = list(_walk(_traced(family + ".sample").jaxpr))
+    assert sum(e.primitive.name == "cond" for e, _ in eqns) == 1
+    bits = [inside for e, inside in eqns
+            if e.primitive.name in ("random_bits", "threefry2x32")]
+    assert bits and all(bits)

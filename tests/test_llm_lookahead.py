@@ -542,6 +542,36 @@ def test_stands_down_when_no_window_follows(gpt_served):
     assert _deficits(fam, params, r).max() <= ATOL
 
 
+def test_draw_share_counts_the_windows_that_were_handed_a_temperature(
+        gpt_served):
+    """`decode_windows_drawn` over `decode_windows`: 0.0 after greedy
+    windows (their sampling steps skip the categorical draw,
+    `paged_kv._sample_next`), 1.0 after windows that held a slot at
+    temperature 0.8, zeroed by `reset_stats()`. The greedy request beside
+    the sampling one still emits the plain forward's continuation, and
+    the sampling one something else."""
+    fam, params = gpt_served
+    eng = _engine(fam, params)
+    greedy = eng.submit(_prompt(8), max_tokens=17)          # 1 + 8 + 8
+    _run(eng, [greedy])
+    m = eng.metrics()
+    assert m["decode_windows"] == 2 and m["decode_windows_drawn"] == 0
+    assert m["decode_draw_share"] == 0.0
+    eng.reset_stats()
+    pair = [eng.submit(_prompt(8), max_tokens=17),
+            eng.submit(_prompt(8), max_tokens=17, temperature=0.8)]
+    _run(eng, pair)
+    m = eng.metrics()
+    assert m["decode_windows_drawn"] == m["decode_windows"] == 2
+    assert m["decode_draw_share"] == 1.0
+    assert pair[0].out_ids == greedy.out_ids
+    assert _deficits(fam, params, pair[0]).max() <= ATOL
+    assert pair[1].out_ids != greedy.out_ids
+    eng.reset_stats()
+    m = eng.metrics()
+    assert m["decode_windows_drawn"] == 0 and m["decode_draw_share"] == 0.0
+
+
 @pytest.mark.parametrize("kind", ["speculative", "dense"])
 def test_other_engines_never_leave_a_step_in_flight(gpt_served, kind):
     fam, params = gpt_served
