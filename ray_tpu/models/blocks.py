@@ -1,10 +1,10 @@
 """Parts that two or more served families are built from.
 
-A family module (models/zaya.py, laguna.py, qwen3_next.py, mimo_v2.py)
-writes what is its own: the configuration, the attention inputs, the
-router, the ropes, the pool. What several of them compute the same way
-lives here under public names, so that no family imports a sibling to get
-it: the imports of `ray_tpu/models/` point from a family to this module
+A family module (models/zaya.py, laguna.py, qwen3_next.py, mimo_v2.py,
+jamba.py, kimi_k2.py) writes what is its own: the configuration, the
+attention inputs, the router, the ropes, the pool. What several of them
+compute the same way lives here under public names, so that no family
+imports a sibling to get it: the imports of `ray_tpu/models/` point from a family to this module
 and to the program builder (models/paged_kv.py `paged_programs`), never
 across. (models/mimo_v2.py imports the module models/laguna.py: it IS
 laguna's paged walk and ring with other parts, which is kinship, not
@@ -17,12 +17,32 @@ it is handed.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ray_tpu.ops import scopes
 
 _F32 = jnp.float32
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+# ---------------------------------------------------------------- weights
+
+def init_from_specs(specs: dict, rng: jax.Array, dtype) -> dict:
+    """Seeded leaves from a `param_specs` table: normal at the spec's
+    scale, or ones; a key a leaf, in the names' order."""
+    keys = jax.random.split(rng, len(specs))
+    params = {}
+    for key, (name, spec) in zip(keys, sorted(specs.items())):
+        if spec["init"] == "normal":
+            params[name] = (jax.random.normal(key, spec["shape"], dtype)
+                            * spec["scale"])
+        else:
+            params[name] = jnp.ones(spec["shape"], dtype)
+    return params
 
 
 # ------------------------------------------------------------------ norms
@@ -53,6 +73,49 @@ def gated_mlp(u, w_gate, w_up, w_down):
     up = jnp.matmul(u, w_up.astype(dt), preferred_element_type=_F32)
     return jnp.matmul((jax.nn.silu(gate) * up).astype(dt), w_down.astype(dt),
                       preferred_element_type=_F32)
+
+
+# ------------------------------------------------------------------- rope
+
+def yarn_inv_freq(cfg):
+    """YaRN rotary frequencies [rotary_dim / 2], float64 on the host:
+    plain frequencies where a dim turns more than `beta_fast` times over
+    the original context (`yarn_orig`), divided by `yarn_factor` where it
+    turns fewer than `beta_slow`, a linear ramp between."""
+    d, theta = cfg.rotary_dim, float(cfg.rope_theta)
+    f = theta ** (-np.arange(0, d, 2, dtype=np.float64) / d)
+    bound = lambda beta: (d * math.log(cfg.yarn_orig / (beta * 2 * math.pi))
+                          / (2 * math.log(theta)))
+    low = max(math.floor(bound(cfg.beta_fast)), 0)
+    high = min(math.ceil(bound(cfg.beta_slow)), d - 1)
+    ramp = np.clip((np.arange(d // 2, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    return f * (1.0 - ramp) + (f / cfg.yarn_factor) * ramp
+
+
+# ----------------------------------------------------------------- router
+
+@jax.named_scope(scopes.MOE_ROUTE)
+def biased_route(cfg, w_router, bias, u):
+    """A sigmoid router that chooses by a biased score and gates by the
+    unbiased one, float32 throughout. u [M, D] → (experts [M, k] int32
+    global ids, chosen by s + bias; gates [M, k] float32, the chosen
+    experts' UNBIASED scores normalised over all k choices, held here or
+    not, times `cfg.routed_scale` where the configuration has one; moved
+    [M] int32, the choices that are not among the k largest of s
+    alone)."""
+    s = jax.nn.sigmoid(jnp.matmul(u.astype(_F32), w_router.astype(_F32),
+                                  precision=_HIGHEST))
+    _top, chosen = jax.lax.top_k(s + bias.astype(_F32), cfg.top_k)
+    own = jnp.take_along_axis(s, chosen, axis=-1)
+    # A choice's rank by s alone: the experts that score higher.
+    above = jnp.sum(s[:, None, :] > own[:, :, None], axis=-1)
+    chosen = chosen.astype(jnp.int32)
+    gates = own / jnp.sum(own, axis=-1, keepdims=True)
+    if hasattr(cfg, "routed_scale"):
+        gates = gates * cfg.routed_scale
+    return (chosen, gates,
+            jnp.sum(above >= cfg.top_k, axis=-1).astype(jnp.int32))
 
 
 # ------------------------------------------------------------------ heads
@@ -121,6 +184,14 @@ def write_kv(pool, l, pages, offs, k, v, planes=("k", "v")):
             vn: pool[vn].at[l, pages, offs].set(rows(v))}
 
 
+@jax.named_scope(scopes.ATTN_KV_WRITE)
+def write_row(pool, l, pages, offs, row, plane):
+    """A latent cache's ONE row a token, [.., K] as rows [M, K] →
+    (l, pages[m], offs[m]) of the carried pool's `plane`."""
+    rows = row.reshape(-1, row.shape[-1])
+    return {**pool, plane: pool[plane].at[l, pages, offs].set(rows)}
+
+
 @jax.named_scope(scopes.SLOT_STATE)
 def dispatch_order(slots, offsets, n_valid, null_slot: int):
     """Where each chunk row of one dispatch finds the state before its
@@ -150,6 +221,19 @@ def dispatch_order(slots, offsets, n_valid, null_slot: int):
 # (k a live row), and the choices that landed on a held expert.
 COUNTERS = ("layer_steps", "experts_touched", "rows_max", "rows_routed",
             "rows_held")
+
+
+# ... and, behind a router that chooses by a biased score, the choices
+# the bias moved.
+COUNTERS_BIASED = COUNTERS + ("rows_bias_moved",)
+
+
+def counter_row_biased(cfg, counted, n_live):
+    """`counter_row` for `COUNTERS_BIASED`: `counted` a sparse layer's
+    (counts, the live rows' choices the bias moved)."""
+    counts, moved = counted
+    return jnp.concatenate([counter_row(cfg, counts, n_live),
+                            moved.astype(jnp.uint32)[None]])
 
 
 def counter_row(cfg, counts, n_live):

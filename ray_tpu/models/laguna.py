@@ -78,8 +78,9 @@ from ray_tpu.ops import scopes
 # probe_mimo_v2.py put their window faults in by rebinding it.
 from ray_tpu.models.blocks import attend_fn as _attend_fn
 from ray_tpu.models.blocks import (COUNTERS, counter_row, gated_mlp,
-                                   last_token_logits, rms_norm, untied_head,
-                                   write_kv)
+                                   init_from_specs, last_token_logits,
+                                   rms_norm, untied_head, write_kv,
+                                   yarn_inv_freq)
 from ray_tpu.models.paged_kv import paged_programs
 from ray_tpu.ops.moe import token_choice_experts
 
@@ -167,22 +168,6 @@ class LagunaConfig:
                     for m in range(l)))
 
 
-def yarn_inv_freq(cfg: LagunaConfig) -> np.ndarray:
-    """The full layers' rotary frequencies [rotary_dim / 2], float64 on
-    the host: plain frequencies where a dim turns more than `beta_fast`
-    times over the original context, divided by `yarn_factor` where it
-    turns fewer than `beta_slow`, a linear ramp between."""
-    d, theta = cfg.rotary_dim, float(cfg.rope_theta)
-    f = theta ** (-np.arange(0, d, 2, dtype=np.float64) / d)
-    bound = lambda beta: (d * math.log(cfg.yarn_orig / (beta * 2 * math.pi))
-                          / (2 * math.log(theta)))
-    low = max(math.floor(bound(cfg.beta_fast)), 0)
-    high = min(math.ceil(bound(cfg.beta_slow)), d - 1)
-    ramp = np.clip((np.arange(d // 2, dtype=np.float64) - low)
-                   / (high - low), 0.0, 1.0)
-    return f * (1.0 - ramp) + (f / cfg.yarn_factor) * ramp
-
-
 def _rope_of(cfg: LagunaConfig, kind: str) -> tuple[np.ndarray, float]:
     """(frequencies, the factor on cos and sin) of a layer kind."""
     if kind == "full":
@@ -234,20 +219,6 @@ def partition_rules() -> tuple:
     from jax.sharding import PartitionSpec
 
     return ((r".*", PartitionSpec()),)
-
-
-def init_from_specs(specs: dict, rng: jax.Array, dtype) -> dict:
-    """Seeded leaves from a `param_specs` table: normal at the spec's
-    scale, or ones; a key a leaf, in the names' order."""
-    keys = jax.random.split(rng, len(specs))
-    params = {}
-    for key, (name, spec) in zip(keys, sorted(specs.items())):
-        if spec["init"] == "normal":
-            params[name] = (jax.random.normal(key, spec["shape"], dtype)
-                            * spec["scale"])
-        else:
-            params[name] = jnp.ones(spec["shape"], dtype)
-    return params
 
 
 def init_params(cfg: LagunaConfig, rng: jax.Array) -> dict[str, jax.Array]:

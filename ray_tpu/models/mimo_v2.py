@@ -69,7 +69,6 @@ from ray_tpu.ops import scopes
 from ray_tpu.ops.moe import token_choice_experts
 
 _F32 = jnp.float32
-_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 @dataclasses.dataclass(frozen=True)
@@ -242,22 +241,9 @@ def _attn_inputs(cfg: MiMoV2Config, params, l: int, x, pos):
     return q, k, v.reshape(N, C, G, cfg.v_head_dim)
 
 
-@jax.named_scope(scopes.MOE_ROUTE)
-def _route(cfg: MiMoV2Config, w_router, bias, u):
-    """The router, float32 throughout. u [M, D] → (experts [M, k] int32
-    global ids, chosen by s + bias; gates [M, k] float32, the chosen
-    experts' UNBIASED scores normalised over all k choices, held here or
-    not; moved [M] int32, the choices that are not among the k largest
-    of s alone)."""
-    s = jax.nn.sigmoid(jnp.matmul(u.astype(_F32), w_router.astype(_F32),
-                                  precision=_HIGHEST))
-    _top, chosen = jax.lax.top_k(s + bias.astype(_F32), cfg.top_k)
-    own = jnp.take_along_axis(s, chosen, axis=-1)
-    # A choice's rank by s alone: the experts that score higher.
-    above = jnp.sum(s[:, None, :] > own[:, :, None], axis=-1)
-    return (chosen.astype(jnp.int32),
-            own / jnp.sum(own, axis=-1, keepdims=True),
-            jnp.sum(above >= cfg.top_k, axis=-1).astype(jnp.int32))
+# The router that chooses by s + b and gates by s (models/kimi_k2.py is
+# its other user).
+_route = blocks.biased_route
 
 
 def _finish_block(cfg: MiMoV2Config, params, l: int, x, attn, valid):
@@ -332,7 +318,7 @@ def forward(cfg: MiMoV2Config, params, tokens):
 
 # Running totals over decode steps, wrapping uint32 (the host takes
 # differences): laguna's five, and the choices the router's bias moved.
-COUNTERS = blocks.COUNTERS + ("rows_bias_moved",)
+COUNTERS = blocks.COUNTERS_BIASED
 
 
 def init_paged_kv(cfg: MiMoV2Config, n_pages: int, page_size: int,
@@ -372,10 +358,7 @@ def _paged_layers(cfg: MiMoV2Config, params, x, pos, valid, pool, attend,
     return x, pool, counts
 
 
-def _count(cfg: MiMoV2Config, counted, n_live):
-    counts, moved = counted
-    return jnp.concatenate([blocks.counter_row(cfg, counts, n_live),
-                            moved.astype(jnp.uint32)[None]])
+_count = blocks.counter_row_biased
 
 
 # laguna's two forwards over this block's walk and counter row.

@@ -140,7 +140,7 @@ _REFUSALS = (
      "rewinds the cursor, and {spec_draft} would have to be built"),
     ("kv_transfer", lambda o: not o.kv_transfer, False,
      "KV page-set transfer with the {name} family: a page set would have "
-     "to carry {beside} (serve/kv_objects.py moves PagePool pages only)"),
+     "to carry {kv_transfer}"),
     ("tp", lambda o: int(o.tp) == 1, 1,
      "tp > 1 with the {name} family: {tp}"),
     ("weight_dtype", lambda o: o.weight_dtype != "int8", "bf16",
@@ -173,6 +173,8 @@ def _paged_only(model, beside: str, clauses: dict, **fields) -> ServingFamily:
     from jax.sharding import PartitionSpec
 
     name = model.__name__.rpartition(".")[2]
+    clauses = {"kv_transfer": "{beside} (serve/kv_objects.py moves PagePool "
+                              "pages only)", **clauses}
     said = {option: clause.format(beside=beside)
             for option, clause in clauses.items()}
     return ServingFamily(
@@ -297,9 +299,46 @@ def _jamba() -> ServingFamily:
         slot_state=jamba.SLOT_STATE_LEAVES)
 
 
+def _kimi_k2() -> ServingFamily:
+    """The first newer family with NOTHING by the slot: its cache is one
+    latent row a token and layer in `PagePool`'s own pages, so the
+    features below are nearer here than for any family above, and each
+    clause names the module that still speaks of K and V planes with a
+    head axis."""
+    from ray_tpu.models import kimi_k2
+
+    return _paged_only(
+        kimi_k2,
+        "the latent plane `kv` (models/kimi_k2.py: ONE row of 576 values a "
+        "token and layer with no head axis, in PagePool's own pages; "
+        "nothing is kept by the slot)",
+        {**_EXPERTS,
+         "kv_mode": "of one latent row a token, read in the absorbed form",
+         "prefill_chunk": "the prompt's rows in {beside}",
+         "prefix_cache": "no snapshot, the pages are PagePool's: "
+                         "serve/prefix_cache.py's copy-on-write and the "
+                         "engine's page programs (models/paged_kv.py "
+                         "copy_pages) would have to learn {beside} where "
+                         "they name `k` and `v`",
+         "spec_draft": "a verify program over {beside} (the rewind itself "
+                       "is the cursor's alone here) and a draft model of "
+                       "this family",
+         "kv_transfer": "{beside}: serve/kv_objects.py names `k` and `v` "
+                        "planes, splits them by a head axis, and its wire "
+                        "fingerprint names the full-head layout",
+         "tp": _EXCHANGE + ", and models/partition.py has no rule for a "
+               "row without heads: the latent plane would be replicated "
+               "and the query heads split, or the row split within "
+               "itself",
+         "kv_dtype": "which a one-plane writer (blocks.write_row) would "
+                     "have to call, and the kernels' latent form takes a "
+                     "bf16 plane (ops/paged_attention.py)"},
+        lay_out=kimi_k2.lay_out)
+
+
 _FAMILIES = {"gpt": _gpt, "zaya": _zaya, "laguna": _laguna,
              "qwen3_next": _qwen3_next, "mimo_v2": _mimo_v2,
-             "jamba": _jamba}
+             "jamba": _jamba, "kimi_k2": _kimi_k2}
 
 
 @functools.cache

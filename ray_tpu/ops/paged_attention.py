@@ -148,6 +148,26 @@ def _check_pool(q_heads, q_head_dim, k_pool, v_pool):
     return k_pool.shape[2], G, v_pool.shape[3] // G
 
 
+def _check_latent(q_head_dim, kv_pool, v_pool, latent, *others):
+    """-> (page_size, 1, `latent`) of ONE plane ``[L, P, ps, K]`` whose
+    row is a token's key (all K lanes, the query's head size) and its
+    value (the first `latent` lanes): a latent cache has no head axis and
+    no V pool, and takes neither an int8 pool, a window nor a sink
+    (`others`: those operands, all None)."""
+    if (v_pool is not None or kv_pool.ndim != 4
+            or kv_pool.shape[3] != q_head_dim or q_head_dim % _LANES
+            or not 0 < latent <= q_head_dim or latent % _LANES
+            or any(o is not None for o in others)):
+        raise ValueError(
+            f"latent form: want ONE bf16 plane [L, P, page_size, K] with K "
+            f"the query's head size {q_head_dim}, K and the value whole "
+            f"lane tiles of {_LANES} (a row of 576 values lies in 640 "
+            f"lanes on the chip whatever the array says, and a DMA takes "
+            f"whole tiles), no V pool (got latent={latent}, pool "
+            f"{kv_pool.shape}), and no scales, window or sink")
+    return kv_pool.shape[2], 1, latent
+
+
 def _prefetch(layer, scalars, k_scale, v_scale):
     """Scalar-prefetch operands, SMEM order: layer [1], `scalars` (page
     tables and per-slot vectors), then for an int8 pool THIS layer's
@@ -310,7 +330,7 @@ def _column_live(first, kv_len, page_size, window):
 def _decode_kernel(
     *refs,
     sm_scale, page_size, block_pages, n_heads, n_kv_heads, quantized=False,
-    window=None, v_head_dim=None, sink=False,
+    window=None, v_head_dim=None, sink=False, latent=False,
 ):
     """Every slot of a group against its LIVE kv blocks, one grid step a
     group (the whole batch at every served shape), the pages fetched by
@@ -339,7 +359,10 @@ def _decode_kernel(
     kernel, and the fp32 plane never exists in HBM. `v_head_dim`: the
     V plane's head size where it is not K's (the accumulator and the
     output are that wide); `sink`: a [H, LANES] operand follows the
-    queries, the logit each head's softmax starts from."""
+    queries, the logit each head's softmax starts from. `latent`: ONE
+    pool and ONE set of buffers; a row is its token's key and, in its
+    first `v_head_dim` lanes, its value, so a page is fetched once and
+    stands in both matmuls (one KV head, `n_kv_heads` 1)."""
     n, ps = block_pages, page_size
     refs = iter(refs)
     take = lambda count: [next(refs) for _ in range(count)]
@@ -348,8 +371,13 @@ def _decode_kernel(
     ks_ref, vs_ref = take(2) if quantized else (None, None)
     (q_ref,) = take(1)
     (sink_ref,) = take(1) if sink else (None,)
-    k_hbm, v_hbm, o_ref = take(3)
-    k_buf, v_buf, sem, qbd_ref, m_ref, l_ref, acc_ref = refs
+    if latent:
+        (k_hbm, o_ref), (k_buf,) = take(2), take(1)
+        pools = ((k_hbm, k_buf),)
+    else:
+        (k_hbm, v_hbm, o_ref), (k_buf, v_buf) = take(3), take(2)
+        pools = ((k_hbm, k_buf), (v_hbm, v_buf))
+    sem, qbd_ref, m_ref, l_ref, acc_ref = refs
     # Multi-head (G = H): a slot's query is one dense row [1, H*K].
     # Grouped (G < H): it is [H, K], a head a row, and the pool's minor
     # axis holds G heads; row h of the block-diagonal query then sits in
@@ -377,8 +405,10 @@ def _decode_kernel(
         # A dead column's rows of a buffer keep what they held. Their
         # probabilities are 0, and 0 x NaN is NaN in PV: V's rows are
         # made finite once, and only pages of the pool land there after.
-        # (K's rows may hold anything: their scores are masked.)
-        v_buf[...] = jnp.zeros_like(v_buf)
+        # (K's rows may hold anything: their scores are masked; a
+        # latent row is its own value.)
+        finite = k_buf if latent else v_buf
+        finite[...] = jnp.zeros_like(finite)
     o_ref[...] = jnp.zeros_like(o_ref)       # an idle slot is never visited
 
     def columns(b, j):
@@ -429,7 +459,7 @@ def _decode_kernel(
             @pl.when(live & go)
             def _():
                 page = tables_ref[b, held]
-                for pool, dst in ((k_hbm, k_buf), (v_hbm, v_buf)):
+                for pool, dst in pools:
                     getattr(pltpu.make_async_copy(
                         pool.at[layer, page],
                         dst.at[buf, pl.ds(i * ps, ps)], sem.at[buf]), act)()
@@ -467,7 +497,8 @@ def _decode_kernel(
                      for i, (live, held, _) in enumerate(cols)]
             return parts[0] if n == 1 else jnp.concatenate(parts, axis=0)
 
-        k, v = stacked(k_buf, ks_ref), stacked(v_buf, vs_ref)
+        k = stacked(k_buf, ks_ref)
+        v = k[:, :v_dim] if latent else stacked(v_buf, vs_ref)
         # s[h, t] = q[h] · k[t, head h's lanes]: the block-diagonal query
         # makes it one [H, G*K] x [block, G*K]ᵀ matmul. Decode attention
         # is HBM-bound (~2 flops/byte), so the H-fold surplus of
@@ -563,15 +594,16 @@ _DECODE_GROUP_BUDGET = 14 * 2**20
 
 
 def _decode_vmem_bytes(n, page_size, kv_lanes, kv_itemsize, n_heads,
-                       v_lanes=None) -> int:
+                       v_lanes=None, latent=False) -> int:
     """VMEM the decode kernel takes at `n` pages a block, beside its
     queries and outputs: `_DECODE_BUFFERS` K and as many V buffers of a
-    block (`kv_lanes` = G*K and `v_lanes` = G*Kv wide; None: as K), an
+    block (`kv_lanes` = G*K and `v_lanes` = G*Kv wide; None: as K;
+    `latent`: no V buffers, the value is lanes of K's rows), an
     int8 block's f32 copies, the score and probability tiles, and
     whatever the block: the block-diagonal query (K's width) with the
     f32 accumulator and its update (V's) and the (m, l) state."""
     v_lanes = kv_lanes if v_lanes is None else v_lanes
-    both = kv_lanes + v_lanes
+    both = kv_lanes + (0 if latent else v_lanes)
     page = page_size * both * kv_itemsize
     dequant = n * page_size * both * 4 if kv_itemsize == 1 else 0
     tiles = 2 * n_heads * n * page_size * 4
@@ -581,20 +613,21 @@ def _decode_vmem_bytes(n, page_size, kv_lanes, kv_itemsize, n_heads,
 
 
 def decode_block_pages(n_pg, page_size, kv_lanes, kv_itemsize,
-                       n_heads, v_lanes=None) -> int:
+                       n_heads, v_lanes=None, latent=False) -> int:
     """Table columns one block of the decode kernel holds: the largest
     power of two that is at most `n_pg`, keeps the block's K pages
     (`kv_lanes` = G*K wide) at or under `_DECODE_BLOCK_BYTES` and
     `_DECODE_BLOCK_KEYS` keys, and fits `_DECODE_VMEM_BUDGET`
-    (`_decode_vmem_bytes`, which counts V's pages at `v_lanes`). Pure in
-    the shapes: the engine's `decode_block_fill` counter and the kernel
-    ask it the same question."""
+    (`_decode_vmem_bytes`, which counts V's pages at `v_lanes`, or none
+    for a `latent` pool). Pure in the shapes: the engine's
+    `decode_block_fill` counter and the kernel ask it the same question."""
     page = page_size * kv_lanes * kv_itemsize
     n = 1
     while (2 * n <= n_pg and 2 * n * page <= _DECODE_BLOCK_BYTES
            and 2 * n * page_size <= _DECODE_BLOCK_KEYS
            and _decode_vmem_bytes(2 * n, page_size, kv_lanes, kv_itemsize,
-                                  n_heads, v_lanes) <= _DECODE_VMEM_BUDGET):
+                                  n_heads, v_lanes, latent)
+           <= _DECODE_VMEM_BUDGET):
         n *= 2
     return n
 
@@ -639,6 +672,7 @@ def paged_attention(
     window: int | None = None,
     col_page: jax.Array | None = None,
     sink: jax.Array | None = None,
+    latent: int | None = None,
 ) -> jax.Array:
     """Single-token decode attention straight against the KV page pool.
 
@@ -667,6 +701,11 @@ def paged_attention(
         is attended.
       sink: [H] float32, a learned logit a query head that joins the
         softmax's denominator and has no value row (None: no sink).
+      latent: the LATENT form (a compressed cache): ``k_pool`` is ONE
+        plane [L, P, page_size, K] with no head axis, ``v_pool`` is None;
+        a token's row is the key of every query head (all K lanes) and,
+        in its first `latent` lanes, their value. A page is fetched once
+        and stands in both matmuls; the call's name ends `_latent`.
     V's heads may be narrower or wider than K's (``v_pool``
     [L, P, page_size, G*Kv]): the scores contract K, the output is Kv
     wide. Returns [B, H, Kv] in q.dtype. Numerics match the gather
@@ -674,7 +713,11 @@ def paged_attention(
     ``reference_paged_attention``).
     """
     B, H, K = q.shape
-    ps, G, Kv = _check_pool(H, K, k_pool, v_pool)
+    if latent is None:
+        ps, G, Kv = _check_pool(H, K, k_pool, v_pool)
+    else:
+        ps, G, Kv = _check_latent(K, k_pool, v_pool, latent, k_scale,
+                                  window, sink)
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(K)
     if interpret is None:
@@ -682,17 +725,22 @@ def paged_attention(
     quantized = k_scale is not None
     _check_window(window, col_page, tables, quantized)
     kv_item = k_pool.dtype.itemsize
-    n = decode_block_pages(tables.shape[1], ps, G * K, kv_item, H, G * Kv)
+    shared = latent is not None
+    n = decode_block_pages(tables.shape[1], ps, G * K, kv_item, H, G * Kv,
+                           latent=shared)
     name, prefetch, extra = _call_form(
         "paged_decode_attn", layer, (tables, lengths), k_scale, v_scale,
         window, col_page)
     sink = _sink_form(extra, sink, H, K, Kv)
+    if shared:
+        name, extra["latent"] = name + "_latent", True
     dense = G == H and Kv == K      # a slot's query is one row [1, H*K]
     # A grouped slot's [H, K] and [H, Kv] blocks, as VMEM pads them.
     tiled = lambda lanes: lanes if dense else -(-lanes // _LANES) * _LANES
     group = _decode_slot_group(
         B, H * (tiled(K) + tiled(Kv)) // 2 * q.dtype.itemsize,
-        _decode_vmem_bytes(n, ps, G * K, kv_item, H, G * Kv))
+        _decode_vmem_bytes(n, ps, G * K, kv_item, H, G * Kv,
+                           latent=shared))
 
     kernel = functools.partial(
         _decode_kernel, sm_scale=sm_scale, page_size=ps, block_pages=n,
@@ -702,16 +750,17 @@ def paged_attention(
     slots = lambda shape: pl.BlockSpec((group,) + shape[1:],
                                        lambda g, *_: (g, 0, 0))
     pool = pl.BlockSpec(memory_space=pl.ANY)
+    pools = (k_pool,) if shared else (k_pool, v_pool)
     sinks = ([] if sink is None else
              [pl.BlockSpec((H, _LANES), lambda g, *_: (0, 0))])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(B // group,),
-        in_specs=[slots(q.shape)] + sinks + [pool, pool],
+        in_specs=[slots(q.shape)] + sinks + [pool] * len(pools),
         out_specs=slots(out_shape),
         scratch_shapes=[
-            pltpu.VMEM((_DECODE_BUFFERS, n * ps, G * K), k_pool.dtype),
-            pltpu.VMEM((_DECODE_BUFFERS, n * ps, G * Kv), v_pool.dtype),
+            pltpu.VMEM((_DECODE_BUFFERS, n * ps, p.shape[3]), p.dtype)
+            for p in pools] + [
             pltpu.SemaphoreType.DMA((_DECODE_BUFFERS,)),   # one a K, V pair
             pltpu.VMEM((H, G * K), q.dtype),         # block-diagonal query
             pltpu.VMEM((H, _LANES), jnp.float32),    # m
@@ -725,7 +774,7 @@ def paged_attention(
         out_shape=jax.ShapeDtypeStruct(out_shape, q.dtype),
         interpret=interpret,
         name=name,
-    )(*prefetch, q, *(() if sink is None else (sink,)), k_pool, v_pool)
+    )(*prefetch, q, *(() if sink is None else (sink,)), *pools)
     return out.reshape(B, H, Kv)
 
 
@@ -776,7 +825,8 @@ def prefill_kv_split(kv_lanes, chunk, q_lanes, q_itemsize, n_heads,
 
 
 def prefill_block_pages(n_pg, page_size, kv_lanes, kv_itemsize, chunk,
-                        q_lanes, q_itemsize, n_heads, v_lanes=None) -> int:
+                        q_lanes, q_itemsize, n_heads, v_lanes=None,
+                        latent=False) -> int:
     """Table columns one grid step of the prefill kernel attends: the
     largest power of two that is at most `n_pg`, keeps the block at or
     under `_PREFILL_BLOCK_KEYS` keys, and whose K and V blocks
@@ -787,7 +837,11 @@ def prefill_block_pages(n_pg, page_size, kv_lanes, kv_itemsize, chunk,
     state of `n_heads` heads. Pure in the shapes: the engine's
     `prefill_block_fill` counter and the kernel ask it the same
     question. Where the grid splits by KV head (`prefill_kv_split`) the
-    shapes are one step's."""
+    shapes are one step's. `latent`: the latent form's own rule
+    (`latent_prefill_shape`; `v_lanes` the value's lanes of a row)."""
+    if latent:
+        return latent_prefill_shape(n_pg, page_size, kv_lanes, kv_itemsize,
+                                    chunk, n_heads, v_lanes)[0]
     v_lanes = kv_lanes if v_lanes is None else v_lanes
     o_lanes = q_lanes // kv_lanes * v_lanes         # H/G x G*Kv
     S = prefill_kv_split(kv_lanes, chunk, q_lanes, q_itemsize, n_heads,
@@ -988,6 +1042,7 @@ def paged_prefill_attention(
     window: int | None = None,
     col_page: jax.Array | None = None,
     sink: jax.Array | None = None,
+    latent: int | None = None,
 ) -> jax.Array:
     """Chunked-prefill attention straight against the KV page pool.
 
@@ -1016,16 +1071,23 @@ def paged_prefill_attention(
       window, col_page: as in `paged_attention`: query i attends keys j
         with ``i - window < j <= i``.
       sink: [H] float32, as in `paged_attention`.
+      latent: as in `paged_attention`: ONE plane, no ``v_pool``; the
+        heads of a chunk are more query rows against the same pages
+        (`_latent_prefill_kernel`).
     Returns [B, C, H, Kv] in q.dtype (Kv: V's head size, K's unless the
     V plane says otherwise); rows past a slot's valid chunk tokens
     are defined but meaningless (the engine discards them)."""
     B, C, H, K = q.shape
-    ps, G, Kv = _check_pool(H, K, k_pool, v_pool)
-    n_pg = tables.shape[1]
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(K)
     if interpret is None:
         interpret = _interpret_default()
+    if latent is not None:
+        _check_latent(K, k_pool, v_pool, latent, k_scale, window, sink)
+        return _latent_prefill(q, k_pool, layer, tables, offsets, lengths,
+                               latent, sm_scale, interpret)
+    ps, G, Kv = _check_pool(H, K, k_pool, v_pool)
+    n_pg = tables.shape[1]
     quantized = k_scale is not None
     _check_window(window, col_page, tables, quantized)
     n = prefill_block_pages(n_pg, ps, G * K, k_pool.dtype.itemsize, C,
@@ -1054,6 +1116,125 @@ def paged_prefill_attention(
     return out.reshape(B, C, H, Kv)
 
 
+# ---------------------------------------------- the latent form, prefill
+
+def latent_prefill_shape(n_pg, page_size, lanes, itemsize, chunk, n_heads,
+                         v_lanes) -> tuple[int, int]:
+    """(table columns a kv block, query heads a grid step) of the prefill
+    kernel's latent form. A latent row is every head's key, so the heads
+    of a chunk are only more query rows against the same pages: a grid
+    step takes `heads` of them as ONE [heads*chunk, lanes] operand. The
+    block is `prefill_block_pages`' rule without its head terms (the
+    largest power of two at most `n_pg` and `_PREFILL_BLOCK_KEYS` keys);
+    `heads` the largest divisor of `n_heads` whose query, output, f32
+    accumulator, (m, l) state and score tiles fit `_PREFILL_VMEM_BUDGET`
+    beside the block's pages (double-buffered). Pure in the shapes."""
+    n = 1
+    while 2 * n <= n_pg and 2 * n * page_size <= _PREFILL_BLOCK_KEYS:
+        n *= 2
+    block = n * page_size
+    tiled = -(-lanes // _LANES) * _LANES
+    pages = 2 * block * tiled * itemsize
+    row = (2 * (tiled + v_lanes) * itemsize + 4 * v_lanes
+           + 2 * _LANES * 4 + 3 * block * 4)
+    return n, max(h for h in range(1, n_heads + 1) if n_heads % h == 0
+                  and (h == 1 or pages + h * chunk * row
+                       <= _PREFILL_VMEM_BUDGET))
+
+
+def _latent_prefill_kernel(*refs, sm_scale, page_size, block_pages, chunk,
+                           v_dim):
+    """`_prefill_kernel` for a latent pool. Grid (chunk row, head group,
+    kv block); the step's queries are [heads*chunk, K], head after head,
+    so row r sits at the chunk's position ``r % chunk``. No loop over
+    heads: ONE score tile against the block's pages stacked, one softmax
+    update and one PV against the first `v_dim` lanes of the SAME stack.
+    Refs: layer, tables, offsets, lengths (SMEM), q, `block_pages` page
+    refs [ps, K], the output [heads*chunk, v_dim], and (m, l, acc)."""
+    n = block_pages
+    refs = iter(refs)
+    take = lambda count: [next(refs) for _ in range(count)]
+    _layer_ref, _tables_ref, offsets_ref, lengths_ref = take(4)
+    (q_ref,), k_refs = take(1), take(n)
+    o_ref, m_ref, l_ref, acc_ref = refs
+    b, j = pl.program_id(0), pl.program_id(2)
+    rows, block = q_ref.shape[0], n * page_size
+    kv_len, q_off = lengths_ref[b], offsets_ref[b]
+
+    @pl.when(j == 0)
+    def _init():
+        _seed_state(m_ref, l_ref, None)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(j * block < kv_len)
+    def _compute():
+        k = (k_refs[0][...] if n == 1 else
+             jnp.concatenate([r[...] for r in k_refs], axis=0))  # [block, K]
+        tpos = j * block + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, block), 1)
+        qpos = q_off + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, block), 0) % chunk
+        s = jax.lax.dot_general(
+            q_ref[...], k, _NT, preferred_element_type=jnp.float32) * sm_scale
+        # Causal in the whole sequence, and pad rows held to the valid
+        # prefix: `_prefill_kernel` has the why.
+        s = jnp.where((tpos <= qpos) & (tpos < kv_len), s, NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - _spread(m_new, block))
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * corr + _fold(p)
+        pv = jnp.dot(p.astype(k.dtype), k[:, :v_dim],
+                     preferred_element_type=jnp.float32)
+        acc_ref[...] = acc_ref[...] * _spread(corr, v_dim) + pv
+        m_ref[...] = m_new
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _finish():
+        l = jnp.sum(l_ref[...], axis=1, keepdims=True)
+        o_ref[...] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(
+            o_ref.dtype)
+
+
+def _latent_prefill(q, kv_pool, layer, tables, offsets, lengths, latent,
+                    sm_scale, interpret):
+    """`paged_prefill_attention`'s latent form: q [B, C, H, K] against
+    ONE plane [L, P, ps, K] → [B, C, H, latent]."""
+    B, C, H, K = q.shape
+    ps = kv_pool.shape[2]
+    n, heads = latent_prefill_shape(
+        tables.shape[1], ps, K, kv_pool.dtype.itemsize, C, H, latent)
+    tables, _ = _pad_columns(tables, None, n)
+    prefetch = _prefetch(layer, (tables, offsets, lengths), None, None)
+    rows = heads * C
+    im_q = lambda b, g, j, *_: (b, g, 0)
+    page = lambda i: pl.BlockSpec(
+        (None, None, ps, K),
+        lambda b, g, j, layer, tbl, *_: (layer[0], tbl[b, j * n + i], 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(B, H // heads, tables.shape[1] // n),
+        in_specs=[pl.BlockSpec((None, rows, K), im_q)]
+        + [page(i) for i in range(n)],
+        out_specs=pl.BlockSpec((None, rows, latent), im_q),
+        scratch_shapes=[
+            pltpu.VMEM((rows, _LANES), jnp.float32),    # m
+            pltpu.VMEM((rows, _LANES), jnp.float32),    # l
+            pltpu.VMEM((rows, latent), jnp.float32),    # acc
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_latent_prefill_kernel, sm_scale=sm_scale,
+                          page_size=ps, block_pages=n, chunk=C, v_dim=latent),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H * C, latent), q.dtype),
+        interpret=interpret,
+        name="paged_prefill_attn_latent",
+    )(*prefetch, q.transpose(0, 2, 1, 3).reshape(B, H * C, K),
+      *([kv_pool] * n))
+    return out.reshape(B, H, C, latent).transpose(0, 2, 1, 3)
+
+
 # Speculative-verify reuse: the verify pass of draft-model speculative
 # decoding (serve/llm.py) is structurally a ragged chunked-prefill row —
 # k+1 tokens (pending + k draft proposals) written at the slot's decode
@@ -1066,14 +1247,18 @@ def paged_prefill_attention(
 # verify_chunk_paged documents why the garbage K/V they leave is inert).
 
 def _gather_timeline(k_pool, v_pool, layer, tables, n_heads, head_dim,
-                     k_scale, v_scale):
+                     k_scale, v_scale, latent=None):
     """Each slot's contiguous K and V timelines [B, T, H, K] and
     [B, T, H, Kv], gathered from the whole pool at ``[layer, tables]``
     (pages only: no layer's plane is cut out) and, for an int8 pool,
     dequanted exactly as the fused kernels do (page.astype(f32) *
-    scale)."""
+    scale). `latent`: ONE plane; the timelines are [B, T, 1, K] and its
+    first `latent` lanes, one row for every head."""
     B, n_pg = tables.shape
     ps = k_pool.shape[2]
+    if latent is not None:
+        view = k_pool[layer, tables].reshape(B, n_pg * ps, 1, head_dim)
+        return view, view[..., :latent]
     G = k_pool.shape[3] // head_dim
     views = []
     for pool, scale in ((k_pool, k_scale), (v_pool, v_scale)):
@@ -1116,7 +1301,8 @@ def _softmax(s, sink):
 
 def reference_paged_attention(q, k_pool, v_pool, layer, tables, lengths, *,
                               sm_scale=None, k_scale=None, v_scale=None,
-                              window=None, col_page=None, sink=None):
+                              window=None, col_page=None, sink=None,
+                              latent=None):
     """Gather-semantics oracle: reconstitute each slot's contiguous
     timeline and run plain-XLA attention — byte-for-byte the math of
     models/paged_kv.py's gather read path (test oracle + fallback).
@@ -1128,8 +1314,13 @@ def reference_paged_attention(q, k_pool, v_pool, layer, tables, lengths, *,
         sm_scale = 1.0 / math.sqrt(K)
     _check_window(window, col_page, tables, k_scale is not None)
     k_view, v_view = _gather_timeline(k_pool, v_pool, layer, tables, H, K,
-                                      k_scale, v_scale)
-    s = jnp.einsum("bhk,bthk->bht", q, k_view,
+                                      k_scale, v_scale, latent)
+    if latent is not None:      # one row a token under every head
+        _check_latent(K, k_pool, v_pool, latent, k_scale, window, sink)
+        qk, pv = "bhk,btgk->bht", "bht,btgk->bhk"
+    else:
+        qk, pv = "bhk,bthk->bht", "bht,bthk->bhk"
+    s = jnp.einsum(qk, q, k_view,
                    preferred_element_type=jnp.float32) * sm_scale
     tpos = _key_positions(tables, k_pool.shape[2], col_page)   # [B, T]
     mask = tpos < lengths[:, None]
@@ -1139,13 +1330,14 @@ def reference_paged_attention(q, k_pool, v_pool, layer, tables, lengths, *,
     probs = _softmax(s, sink).astype(q.dtype)
     # q.dtype out unconditionally: the dequanted v_view is f32, and the
     # einsum's promotion must not leak into callers' scan carries.
-    return jnp.einsum("bht,bthk->bhk", probs, v_view).astype(q.dtype)
+    return jnp.einsum(pv, probs, v_view).astype(q.dtype)
 
 
 def reference_paged_prefill_attention(q, k_pool, v_pool, layer, tables,
                                       offsets, lengths, *, sm_scale=None,
                                       k_scale=None, v_scale=None,
-                                      window=None, col_page=None, sink=None):
+                                      window=None, col_page=None, sink=None,
+                                      latent=None):
     """Gather-semantics oracle for chunked prefill: reconstitute each
     slot's contiguous timeline from the pool and run plain-XLA causal
     attention for a C-query chunk at absolute offset — byte-for-byte the
@@ -1163,8 +1355,13 @@ def reference_paged_prefill_attention(q, k_pool, v_pool, layer, tables,
         sm_scale = 1.0 / math.sqrt(K)
     _check_window(window, col_page, tables, k_scale is not None)
     k_view, v_view = _gather_timeline(k_pool, v_pool, layer, tables, H, K,
-                                      k_scale, v_scale)
-    s = jnp.einsum("bchk,bthk->bhct", q, k_view,
+                                      k_scale, v_scale, latent)
+    if latent is not None:
+        _check_latent(K, k_pool, v_pool, latent, k_scale, window, sink)
+        qk, pv = "bchk,btgk->bhct", "bhct,btgk->bchk"
+    else:
+        qk, pv = "bchk,bthk->bhct", "bhct,bthk->bchk"
+    s = jnp.einsum(qk, q, k_view,
                    preferred_element_type=jnp.float32) * sm_scale
     tpos = _key_positions(tables, k_pool.shape[2], col_page)[:, None]
     qpos = (offsets[:, None] + jnp.arange(C)[None, :])[:, :, None]
@@ -1174,11 +1371,12 @@ def reference_paged_prefill_attention(q, k_pool, v_pool, layer, tables,
     s = jnp.where(mask[:, None], s, NEG_INF)
     probs = _softmax(s, sink).astype(q.dtype)
     # q.dtype out unconditionally (see reference_paged_attention).
-    return jnp.einsum("bhct,bthk->bchk", probs, v_view).astype(q.dtype)
+    return jnp.einsum(pv, probs, v_view).astype(q.dtype)
 
 
 __all__ = [
     "paged_attention", "paged_prefill_attention",
     "reference_paged_attention", "reference_paged_prefill_attention",
     "prefill_block_pages", "prefill_kv_split", "decode_block_pages",
+    "latent_prefill_shape",
 ]

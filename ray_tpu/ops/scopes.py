@@ -12,6 +12,10 @@ are flat: none opens inside another.
 EMBED = "embed"                  # token embedding lookup
 ATTN_IN = "attn.in"              # pre-norm, q/k/v (and gate) projections,
                                  # rope; zaya's convolutions and L2 norm
+ATTN_ABSORB = "attn.absorb"      # latent attention's absorbed form: the
+                                 # query into the latent's space before
+                                 # the call, the attended latent to the
+                                 # heads' values after it
 ATTN_KV_WRITE = "attn.kv_write"  # K/V rows into the pool's pages or ring
 ATTN_KERNEL = "attn.kernel"      # the paged / flash call, or XLA's dense
                                  # attention; the table ops before it
@@ -44,6 +48,6 @@ COUNTERS = "counters"            # on-device counters the host pulls
 LOSS = "loss"                    # cross-entropy over the logits
 OPTIMIZER = "optimizer"          # the optimizer's update and its apply
 
-ALL = (EMBED, ATTN_IN, ATTN_KV_WRITE, ATTN_KERNEL, ATTN_OUT, MLP, MOE_ROUTE,
-       MOE_EXPERTS, SLOT_STATE, GDN_IN, GDN_SCAN, GDN_OUT, SSM_IN, SSM_SCAN,
-       SSM_OUT, HEAD, SAMPLE, COUNTERS, LOSS, OPTIMIZER)
+ALL = (EMBED, ATTN_IN, ATTN_ABSORB, ATTN_KV_WRITE, ATTN_KERNEL, ATTN_OUT,
+       MLP, MOE_ROUTE, MOE_EXPERTS, SLOT_STATE, GDN_IN, GDN_SCAN, GDN_OUT,
+       SSM_IN, SSM_SCAN, SSM_OUT, HEAD, SAMPLE, COUNTERS, LOSS, OPTIMIZER)

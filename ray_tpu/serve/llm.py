@@ -1568,7 +1568,7 @@ class LLMEngine:
                 m["window_kv_bytes"] = sum(map(nbytes, rings))
                 m["kv_pool_bytes"] = m["window_kv_bytes"] + sum(
                     nbytes(a) for name, a in self.cache.items()
-                    if name in ("k", "v", "k_scale", "v_scale"))
+                    if name in ("k", "v", "kv", "k_scale", "v_scale"))
                 # The pool's bytes by kind, and of the window kind what
                 # every slot's live window needs: the rest of a ring is
                 # room for a dispatch's writes (models/laguna.py
@@ -2703,6 +2703,15 @@ class LLMEngine:
         return sum(self._next_page_needed(s, int(self.positions[s]),
                                           int(held[s])) for s in decoding)
 
+    def _kv_planes(self) -> tuple:
+        """(the plane whose pages the kernels' block rules are asked
+        about, the value's lanes, whether the pool is LATENT: one plane
+        `kv` whose row is key and, in its first `kv_lora_rank` lanes,
+        value) of the full kind's cache."""
+        if "kv" in self.cache:
+            return self.cache["kv"], self.cfg.kv_lora_rank, True
+        return self.cache["k"], self.cache["v"].shape[3], False
+
     def _count_decode_pages(self, active: list[int], width: int) -> None:
         """`decode_block_fill`'s two sums for one decode window at table
         width `width`: the pages the decoding slots' keys lie on (the
@@ -2715,11 +2724,11 @@ class LLMEngine:
         if width not in self._decode_block_at:
             from ray_tpu.ops.paged_attention import decode_block_pages
 
-            pool = self.cache["k"]
+            pool, v_lanes, latent = self._kv_planes()
             self._decode_block_at[width] = decode_block_pages(
                 width, self.page_size, pool.shape[3] // self.tp,
                 pool.dtype.itemsize, self.cfg.n_heads // self.tp,
-                self.cache["v"].shape[3] // self.tp)
+                v_lanes // self.tp, latent=latent)
         block = self._decode_block_at[width]
         live = self.pool.pages_for(self.positions[active])
         self.stats["decode_pages_live"] += int(live.sum())
@@ -2743,14 +2752,14 @@ class LLMEngine:
         if width not in self._block_pages_at:
             from ray_tpu.ops.paged_attention import prefill_block_pages
 
-            pool = self.cache["k"]
+            pool, v_lanes, latent = self._kv_planes()
             heads = self.cfg.n_heads // self.tp
             self._block_pages_at[width] = prefill_block_pages(
                 width, self.page_size, pool.shape[3] // self.tp,
                 pool.dtype.itemsize, self.prefill_chunk,
                 heads * self.cfg.head_dim,
                 np.dtype(self.cfg.dtype).itemsize, heads,
-                self.cache["v"].shape[3] // self.tp)
+                v_lanes // self.tp, latent=latent)
         return self._block_pages_at[width]
 
     def _dispatch_chunks(self, batch) -> None:

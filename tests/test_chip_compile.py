@@ -418,7 +418,8 @@ def step_program(request, chip):
              "laguna": ("laguna_serving", "laguna", G_SLOTS, 64),
              "qwen3_next": ("qwen3_next_serving", "qwen3_next", Q_SLOTS, 64),
              "mimo_v2": ("mimo_v2_serving", "mimo_v2", M_SLOTS, 96),
-             "jamba": ("jamba_serving", "jamba", J_SLOTS, J_WIDTH)}
+             "jamba": ("jamba_serving", "jamba", J_SLOTS, J_WIDTH),
+             "kimi_k2": ("kimi_k2_serving", "kimi_k2", K2_SLOTS, K2_WIDTH)}
     i32 = lambda *shape: chip(shape, jnp.int32)
 
     @functools.cache
@@ -435,9 +436,12 @@ def step_program(request, chip):
                 chip((slots,), jnp.float32), chip(key.shape, key.dtype),
                 attn_impl="kernel").compile()
         n = int(program.split("-")[1])
+        fam = family_of(cfg)        # nothing by the slot: no `slots=`
+        rows = ({"slots": i32(n)} if fam.slot_state or fam.slot_ring
+                else {})
         return programs.prefill_chunk_paged.lower(
             cfg, params, i32(n, C), pool, i32(n, width), i32(n), i32(n),
-            slots=i32(n), return_logits=True, attn_impl="kernel").compile()
+            return_logits=True, attn_impl="kernel", **rows).compile()
 
     return compiled
 
@@ -998,10 +1002,11 @@ def test_mimo_v2_program_fits_and_moves_no_expert_layer(mimo_v2_serving,
 
 # What a family's decode program needs beyond its arguments, read here at
 # its cell's size (MB: gpt 0.5, zaya 5.3, mimo_v2 22.5, laguna 43.4,
-# qwen3_next 111.8 laid out), with about twice the room: all under one
+# qwen3_next 111.8 laid out, kimi_k2 81.3 over stacks), with about twice
+# the room: all under one
 # dense plane of theirs but qwen3_next's, whose own is the layer walk's.
 _DECODE_SCRATCH = {"gpt": 1e6, "zaya": 11e6, "mimo_v2": 45e6,
-                   "laguna": 87e6, "qwen3_next": 224e6}
+                   "laguna": 87e6, "qwen3_next": 224e6, "kimi_k2": 170e6}
 
 
 @pytest.mark.parametrize("family", sorted(_DECODE_SCRATCH))
@@ -1172,6 +1177,124 @@ def test_jamba_program_fits_and_moves_no_state(jamba_serving, step_program,
     assert 9.5e9 < total < 10.5e9
 
 
+# --- the kimi_k2 family: a latent cache, ONE plane -----------------------
+# Kimi-K2.6 as `benchmarks/configs/kimi-k2.6.json` serves it: one chip of
+# a 32-chip group, layers 0-4 (a dense layer, four expert layers of 12
+# held experts of 384 and a shared one), 20,480 vocabulary rows; 256
+# slots x 72 pages of 64 tokens; a cached row of 576 values in 640 lanes
+# under 64 query heads, its first 512 lanes the value.
+K2_SLOTS, K2_PAGES, K2_WIDTH, K2_H, K2_ROW, K2_LATENT = (256, 18432, 72, 64,
+                                                         640, 512)
+
+
+@pytest.fixture(scope="module")
+def kimi_k2_serving(chip):
+    """(cfg, params, pool) of the kimi-k2.6 cell as shapes on one
+    described chip (the tree the engine serves: `lay_out` over the
+    stacks), with the two backend questions steered to the chip's
+    answers."""
+    import importlib
+
+    from ray_tpu.models import kimi_k2
+
+    cfg = kimi_k2.KimiK2Config(n_layers=5, n_experts=12, vocab_size=20480)
+    params = _served(chip, cfg, _stacks(chip, kimi_k2, cfg))
+    pool = jax.tree.map(
+        lambda x: chip(x.shape, x.dtype),
+        jax.eval_shape(lambda: kimi_k2.init_paged_kv(
+            cfg, K2_PAGES, PS, K2_SLOTS)))
+    attn = importlib.import_module("ray_tpu.ops.paged_attention")
+    moe = importlib.import_module("ray_tpu.ops.moe")
+    saved = attn._interpret_default, moe._mixed_dot_default
+    attn._interpret_default = lambda: False
+    moe._mixed_dot_default = lambda: True
+    yield cfg, params, pool
+    attn._interpret_default, moe._mixed_dot_default = saved
+
+
+def test_latent_kernels_compile_at_the_cells_shapes(chip):
+    """Both paged kernels in their latent form at the kimi-k2.6 cell's
+    shapes: 64 query heads over ONE plane of 640-lane rows, the value its
+    first 512 lanes, at the cell's table width and both chunk heights;
+    under names of their own. A plane declared at the row's 576 values is
+    refused before Mosaic is asked (which would refuse a 576-lane DMA of
+    what the chip stores in 640)."""
+    pool = chip((5, K2_PAGES + 1, PS, K2_ROW), jnp.bfloat16)
+    _compile(lambda q, kv, l, t, n: paged_attention(
+        q, kv, None, l, t, n, latent=K2_LATENT, interpret=False),
+        chip((K2_SLOTS, K2_H, K2_ROW), jnp.bfloat16), pool, _layer(chip),
+        chip((K2_SLOTS, K2_WIDTH), jnp.int32), chip((K2_SLOTS,), jnp.int32),
+        kernels=("paged_decode_attn_latent",))
+    for rows in ONE_WIDTH_HEIGHTS:
+        _compile(lambda q, kv, l, t, o, n: paged_prefill_attention(
+            q, kv, None, l, t, o, n, latent=K2_LATENT, interpret=False),
+            chip((rows, C, K2_H, K2_ROW), jnp.bfloat16), pool, _layer(chip),
+            chip((rows, K2_WIDTH), jnp.int32), chip((rows,), jnp.int32),
+            chip((rows,), jnp.int32),
+            kernels=("paged_prefill_attn_latent",))
+    with pytest.raises(ValueError, match="lies in 640"):
+        jax.eval_shape(lambda q, kv, t, n: paged_attention(
+            q, kv, None, 0, t, n, latent=K2_LATENT, interpret=False),
+            chip((K2_SLOTS, K2_H, 576), jnp.bfloat16),
+            chip((5, K2_PAGES + 1, PS, 576), jnp.bfloat16),
+            chip((K2_SLOTS, K2_WIDTH), jnp.int32),
+            chip((K2_SLOTS,), jnp.int32))
+
+
+@pytest.mark.parametrize("program", ONE_WIDTH_PROGRAMS)
+def test_kimi_k2_program_fits_and_moves_no_plane(kimi_k2_serving,
+                                                 step_program, program):
+    """The kimi_k2 family's step programs (decode, and the chunk program
+    at both of the engine's heights), compiled whole at the cell's size:
+    five latent attention calls and the experts' grouped matmul are in
+    them under the names a trace finds them by; no layer of experts (12 x
+    7,168 x 2,048 bf16, 352 MB a matrix) and no layer of the latent plane
+    (18,433 pages x 64 x 640, 1.51 GB) is copied, sliced out or put back;
+    no weight plane is cut out of its stack into a buffer of its own (the
+    tree is stacks: a copy a layer of each plane, 2.2 GB, does not fit
+    beside this pool); the donated pool is updated in place; and the
+    ARGUMENTS' bytes are what the cell's arithmetic says: 6.99 GB of
+    weights + 7.55 GB of pool (a row of 576 values in 640 lanes; 6.80 GB
+    at 1,152 B a token), under the chip's 16 GB with what the program
+    needs besides."""
+    cfg, params, pool = kimi_k2_serving
+    assert set(pool) == {"kv", "moe_counters"}
+    assert pool["kv"].shape == (5, K2_PAGES + 1, PS, K2_ROW)
+    assert params["w_uk"].shape == (5, K2_H, 128, K2_LATENT)
+    assert params["w_uv"].shape == (5, K2_H, K2_LATENT, 128)
+    assert "wkv_b" not in params
+    compiled = step_program("kimi_k2", program)
+    kernel = _attn_kernel(program) + "_latent"
+    text = compiled.as_text()
+    assert len(re.findall(rf"%\w*{kernel}[\w.]* = [^\n]*custom-call\(",
+                          text)) == 5
+    assert len(re.findall(r"%ragged-dot[\w.\-]* = [^\n]*custom-call\(",
+                          text)) >= 3
+    assert f"bf16[{4 * 12},{cfg.d_model},{cfg.d_ff}]" in text
+    moved = (_pool_moves(text, "bf16", 12 * cfg.d_model * cfg.d_ff)
+             + _pool_moves(text, "bf16", (K2_PAGES + 1) * PS * K2_ROW))
+    assert not moved, "layer-sized moves:\n" + "\n".join(moved)
+    planes = {",".join(map(str, a.shape[1:])) for name, a in params.items()
+              if len(a.shape) == 3 and a.shape[1] * a.shape[2] >= _PLANE}
+    assert {"7168,1536", "8192,7168", "7168,18432", "7168,2048"} <= planes
+    made = _planes_made(text, planes)
+    assert not made, "weight planes written out:\n" + "\n".join(made)
+    mem = compiled.memory_analysis()
+    nbytes = lambda tree: sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                              for a in jax.tree.leaves(tree))
+    pool_bytes, weight_bytes = nbytes(pool), nbytes(params)
+    assert mem.alias_size_in_bytes >= pool_bytes
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    print(f"kimi_k2 {program}: arguments "
+          f"{mem.argument_size_in_bytes / 1e9:.3f} GB (weights "
+          f"{weight_bytes / 1e9:.3f} + pool {pool_bytes / 1e9:.3f}), temp "
+          f"{mem.temp_size_in_bytes / 1e9:.3f} GB, total {total / 1e9:.3f} GB")
+    assert 6.98e9 < weight_bytes < 7.00e9 and 7.54e9 < pool_bytes < 7.56e9
+    assert abs(mem.argument_size_in_bytes - weight_bytes - pool_bytes) < 1e7
+    assert 14.5e9 < total < 15.2e9
+
+
 # --- the sampling step: the draw under a conditional (PR 55) ------------
 _DRAW = re.compile(r"op_name=\"[^\"]*(?:_gumbel|_uniform)")
 
@@ -1217,8 +1340,8 @@ def test_draw_rules_find_what_they_are_there_to_refuse(chip):
     assert len(_wide_prefetches(entry, 64)) == 1
 
 
-@pytest.mark.parametrize("family", ["gpt", "jamba", "laguna", "mimo_v2",
-                                    "qwen3_next", "zaya"])
+@pytest.mark.parametrize("family", ["gpt", "jamba", "kimi_k2", "laguna",
+                                    "mimo_v2", "qwen3_next", "zaya"])
 def test_decode_program_draws_only_inside_its_conditional(step_program,
                                                           family):
     """Every family's window step, compiled whole at its cell's size: the

@@ -1,7 +1,7 @@
 """Every family's chunk, decode-step and window-step program, letter for
 letter.
 
-The serving programs of the six families of models/serving.py are built
+The serving programs of the seven families of models/serving.py are built
 by one builder (models/paged_kv.py `paged_programs`) from parts that
 several families share (models/blocks.py). A refactor of either must
 leave every lowered program as it was: the digests below were computed
@@ -16,8 +16,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from ray_tpu.models import (gpt, jamba, laguna, mimo_v2, paged_kv, qwen3_next,
-                            zaya)
+from ray_tpu.models import (gpt, jamba, kimi_k2, laguna, mimo_v2, paged_kv,
+                            qwen3_next, zaya)
 
 PAGE, N_PAGES, N_SLOTS, CHUNK = 16, 24, 3, 16
 
@@ -42,7 +42,12 @@ PAGE, N_PAGES, N_SLOTS, CHUNK = 16, 24, 3, 16
 # `jamba.chunk` as PR 53, the family's first, traced it; `jamba.decode`
 # as PR 54 did, which meant to change it (and `jamba.sample` with it):
 # the mamba sublayer of a decode step on planes without the token axis
-# and `ssm_decode_step` over blocks of slots.
+# and `ssm_decode_step` over blocks of slots. The `kimi_k2` three as PR 56,
+# the family's first, traced them over the tree its `lay_out` makes; that
+# PR moved mimo_v2's router and counter row to models/blocks.py
+# (`biased_route`, `counter_row_biased`), laguna's `yarn_inv_freq` and
+# `init_from_specs` likewise, and gave both paged kernels a latent form:
+# the eighteen above did not move.
 _PINNED = {
     "gpt.chunk": "aff570174e390475", "gpt.decode": "c4ebbb44ff02a83f",
     "zaya.chunk": "e3c3c03e1e115475", "zaya.decode": "21818dedb3b70792",
@@ -57,6 +62,9 @@ _PINNED = {
     "mimo_v2.sample": "7e6cafe4f7a93297",
     "jamba.chunk": "16c983f57626c3f6", "jamba.decode": "5262465ac313b68d",
     "jamba.sample": "09798055e3a58725",
+    "kimi_k2.chunk": "8b845056d1448fc7",
+    "kimi_k2.decode": "f5d19126fc17656e",
+    "kimi_k2.sample": "4be41ea2f7fbebba",
 }
 
 _RING = {"dispatch_tokens": 2 * CHUNK}
@@ -70,6 +78,7 @@ _FAMILIES = {
                    qwen3_next.Qwen3NextConfig.tiny(), {}),
     "mimo_v2": (mimo_v2, mimo_v2, mimo_v2.MiMoV2Config.tiny(), _RING),
     "jamba": (jamba, jamba, jamba.JambaConfig.tiny(), {}),
+    "kimi_k2": (kimi_k2, kimi_k2, kimi_k2.KimiK2Config.tiny(), {}),
 }
 
 
@@ -82,12 +91,14 @@ def _traced(program: str):
         lambda a: jnp.zeros(a.shape, a.dtype), tree)
     params = zeros(jax.eval_shape(
         lambda: model.init_params(cfg, jax.random.key(0))))
+    if name == "kimi_k2":       # the tree its engine serves
+        params = model.lay_out(cfg, params)
     pool = zeros(jax.eval_shape(
         (lambda: paged_kv.init_paged_kv(cfg, N_PAGES, PAGE))
         if pool_kw is None else
         (lambda: mod.init_paged_kv(cfg, N_PAGES, PAGE, N_SLOTS, **pool_kw))))
     if which == "chunk":
-        kw = {} if name == "gpt" else {"slots": i32(2)}
+        kw = {} if name in ("gpt", "kimi_k2") else {"slots": i32(2)}
         fn = lambda p, kv: mod.prefill_chunk_paged.__wrapped__(
             cfg, p, i32(2, CHUNK), kv, i32(2, 8), i32(2), i32(2),
             attn_impl="kernel", **kw)

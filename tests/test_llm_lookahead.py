@@ -23,8 +23,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import (gpt, jamba, laguna, mimo_v2, paged_kv, qwen3_next,
-                            zaya)
+from ray_tpu.models import (gpt, jamba, kimi_k2, laguna, mimo_v2, paged_kv,
+                            qwen3_next, zaya)
 from ray_tpu.serve import llm
 from ray_tpu.serve.llm import LLMEngine
 
@@ -55,6 +55,8 @@ def _fams() -> dict:
                        mimo_v2.init_params, mimo_v2.forward),
         "jamba": Fam(jamba.JambaConfig.tiny(dtype=jnp.float32),
                      jamba.init_params, jamba.forward),
+        "kimi_k2": Fam(kimi_k2.KimiK2Config.tiny(dtype=jnp.float32),
+                       kimi_k2.init_params, kimi_k2.forward),
     }
 
 
@@ -83,7 +85,7 @@ def _drop_programs():
 
 
 @pytest.fixture(scope="class", params=["gpt", "zaya", "laguna", "qwen3_next",
-                                       "mimo_v2", "jamba"])
+                                       "mimo_v2", "jamba", "kimi_k2"])
 def family(request):
     """pytest runs a class's tests family by family for this fixture."""
     yield (request.param, *_serve(request.param))
@@ -333,6 +335,7 @@ class TestEveryFamily:
         s = eng.stats
         assert eng._carry is None and s["lookahead_windows"] >= 2
         names = {"zaya": zaya, "laguna": laguna, "qwen3_next": qwen3_next,
+                 "kimi_k2": kimi_k2,
                  "mimo_v2": mimo_v2}[name].COUNTERS
         device = dict(zip(names, (int(t) for t in eng.cache["moe_counters"])))
         seen = eng._moe_seen

@@ -250,3 +250,46 @@ def test_top_10_of_512_at_four_shares(n_rows, layer):
                                           @ mine[1][e])
             want[n] += g * (h @ mine[2][e])
     np.testing.assert_allclose(total, want, atol=4e-5)
+
+
+# -------------- top-8 of 384 with 12 held: the kimi-k2.6 cell's k and share
+
+@pytest.mark.parametrize("first", [0, 372])
+@pytest.mark.parametrize("n_rows,layer", [(256, None), (256, 3), (7, 1)])
+def test_top_8_of_384_with_12_held(first, n_rows, layer):
+    """A decode step of the kimi-k2.6 cell in small widths: 256 rows, 8
+    choices each over 384 experts of which this chip holds 12 (the first
+    or the last of the group's 32 shares), gates that sum to 2.827, whole
+    stacks and a layer index as the program passes them. The held
+    choices, and only they (a thirty-second of them), against the
+    per-token loop; a row none of whose choices is held gets exactly 0."""
+    rng = np.random.default_rng(13)
+    held, d, f = 12, 8, 6
+    ids, gates = _top_k_routing(rng, n_rows, 384, 8)
+    gates = gates * (2.827 / 2.5)
+    x = jnp.asarray(rng.normal(size=(n_rows, d)), jnp.float32)
+    lead = (held,) if layer is None else (4, held)
+    mk = lambda *s: jnp.asarray(rng.normal(size=lead + s) * 0.3, jnp.float32)
+    w = mk(d, f), mk(d, f), mk(f, d)
+    kw = {} if layer is None else {"layer": jnp.int32(layer)}
+    with jax.default_matmul_precision("highest"):
+        y, counts = jax.jit(token_choice_experts,
+                            static_argnames="first_expert")(
+            x, jnp.asarray(ids), jnp.asarray(gates, jnp.float32), *w,
+            first_expert=first, **kw)
+    here = (ids >= first) & (ids < first + held)
+    want = np.zeros((n_rows, d))
+    mine = [a if layer is None else a[layer]
+            for a in (np.asarray(t, np.float64) for t in w)]
+    for n in range(n_rows):
+        for e, g in zip(ids[n][here[n]], gates[n][here[n]]):
+            a = np.asarray(x[n], np.float64) @ mine[0][e - first]
+            h = a / (1.0 + np.exp(-a)) * (np.asarray(x[n], np.float64)
+                                          @ mine[1][e - first])
+            want[n] += g * (h @ mine[2][e - first])
+    np.testing.assert_allclose(np.asarray(y), want, atol=2e-5)
+    np.testing.assert_array_equal(
+        np.asarray(counts), np.bincount(ids[here] - first, minlength=held))
+    assert int(counts.sum()) == int(here.sum()) < ids.size // 8
+    unreached = ~here.any(axis=1)
+    assert unreached.any() and not np.asarray(y)[unreached].any()

@@ -1396,3 +1396,148 @@ def test_a_pool_of_one_kv_head_is_a_group_of_all_the_query_heads():
     assert _check_pool(MQA_H, MQA_K, pool(4), pool(4)) == (64, 4, MQA_K)
     with pytest.raises(ValueError, match="G dividing the query heads"):
         _check_pool(MQA_H, MQA_K, pool(3), pool(3))
+
+
+# --- the latent form: ONE plane, a row a token ---------------------------
+# Kimi-K2.6's cache (models/kimi_k2.py): a token's row is the key of every
+# query head (all its lanes) and, in its first `latent` lanes, their
+# value; no V pool, no head axis. The decode kernel takes the grouped form
+# at G = 1 with ONE set of buffers (a page is fetched once and stands in
+# both matmuls); the prefill kernel takes the heads of a chunk as more
+# query rows. Rows of whole lane tiles only: 576 values lie in 640 lanes.
+LATENT = [(4, 256, 128, 8), (6, 384, 256, 16), (64, 640, 512, 64)]
+
+
+def _latent_pool(rng, lengths, K, ps, n_pg, dtype=jnp.float32):
+    """ONE plane holding each slot's rows by scattered page tables, the
+    rows past a slot's length garbage. -> (pool, tables, dense [B, T, K])."""
+    pool, _v, tables, _c, dense, _dv = _paged_kv(
+        rng, lengths=lengths, G=1, K=K, Kv=K, ps=ps, n_pg=n_pg)
+    return pool.astype(dtype), tables, dense
+
+
+def _latent_dense(q, rows, qpos, lengths, latent, scale):
+    """Plain attention of q [B, C, H, K] at positions qpos [B, C] over
+    rows [B, T, K] (float64): every head scores a row's K values and
+    sums its first `latent`."""
+    q, rows = np.asarray(q, np.float64), np.asarray(rows, np.float64)
+    s = np.einsum("bchk,btk->bhct", q, rows) * scale
+    t = np.arange(rows.shape[1])
+    seen = ((t[None, None, :] <= qpos[:, :, None])
+            & (t[None, None, :] < np.asarray(lengths)[:, None, None]))
+    s = np.where(seen[:, None], s, -np.inf)
+    with np.errstate(invalid="ignore"):     # a row that sees no key: nan
+        p = np.exp(s - s.max(axis=-1, keepdims=True))
+        p /= p.sum(axis=-1, keepdims=True)
+    return np.einsum("bhct,btv->bchv", p, rows[..., :latent])
+
+
+@pytest.mark.parametrize("H,K,latent,ps", LATENT)
+def test_decode_kernel_in_the_latent_form(H, K, latent, ps):
+    """Ragged lengths, an idle slot, a last page partly full, a length of
+    one: kernel = oracle = plain attention; an idle slot's output is 0."""
+    rng = np.random.default_rng(21)
+    lengths = [0, 1, ps + 3, 5 * ps, 4 * ps + ps // 2]
+    pool, tables, dense = _latent_pool(rng, lengths, K, ps, 6)
+    n = jnp.asarray(lengths, jnp.int32)
+    q = jnp.asarray(rng.normal(size=(len(lengths), H, K)), jnp.float32)
+    args = (q, pool, None, jnp.int32(1), tables, n)
+    with jax.default_matmul_precision("highest"):
+        got = paged_attention(*args, latent=latent, sm_scale=0.11)
+        oracle = reference_paged_attention(*args, latent=latent,
+                                           sm_scale=0.11)
+    assert got.shape == (len(lengths), H, latent)
+    want = _latent_dense(q[:, None], dense, np.asarray(lengths)[:, None] - 1,
+                         lengths, latent, 0.11)[:, 0]
+    np.testing.assert_allclose(np.asarray(got)[1:], want[1:], atol=2e-5)
+    np.testing.assert_allclose(np.asarray(oracle)[1:], want[1:], atol=2e-5)
+    assert not np.asarray(got)[0].any()
+
+
+@pytest.mark.parametrize("H,K,latent,ps", LATENT)
+def test_prefill_kernel_in_the_latent_form(H, K, latent, ps):
+    """Chunk rows at ragged offsets, an inert row, a tail chunk, a table
+    the kv block does not divide: kernel = oracle = plain attention on
+    every valid row."""
+    rng = np.random.default_rng(22)
+    C = 16
+    offsets = np.asarray([0, 0, ps, 4 * ps - 5, 2 * ps + 1], np.int32)
+    n_valid = np.asarray([0, 1, C, C, 7], np.int32)
+    lengths = offsets + n_valid
+    pool, tables, dense = _latent_pool(rng, list(lengths), K, ps, 6)
+    q = jnp.asarray(rng.normal(size=(5, C, H, K)), jnp.float32)
+    args = (q, pool, None, jnp.int32(2), tables, jnp.asarray(offsets),
+            jnp.asarray(lengths))
+    with jax.default_matmul_precision("highest"):
+        got = paged_prefill_attention(*args, latent=latent, sm_scale=0.11)
+        oracle = reference_paged_prefill_attention(*args, latent=latent,
+                                                   sm_scale=0.11)
+    assert got.shape == (5, C, H, latent)
+    qpos = offsets[:, None] + np.arange(C)[None]
+    want = _latent_dense(q, dense, qpos, lengths, latent, 0.11)
+    valid = np.arange(C)[None] < n_valid[:, None]
+    assert valid.sum() == 40
+    np.testing.assert_allclose(np.asarray(got)[valid], want[valid], atol=2e-5)
+    np.testing.assert_allclose(np.asarray(oracle)[valid], want[valid],
+                               atol=2e-5)
+
+
+def test_the_latent_form_reads_one_plane_once():
+    """The decode call holds ONE pool operand and ONE set of buffers (no
+    second DMA a page), the prefill call a page ref a column; both carry
+    names of their own in a trace; at the cell's shapes the block is 4
+    pages and a grid step takes 8 heads of a 128-token chunk."""
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    pool = jnp.zeros((2, 9, 64, 640), bf16)
+    tables, rows = jnp.zeros((4, 8), i32), jnp.zeros((4,), i32)
+    decode = jax.make_jaxpr(lambda q, kv, t, n: paged_attention(
+        q, kv, None, jnp.int32(1), t, n, latent=512, interpret=True))(
+        jnp.zeros((4, 64, 640), bf16), pool, tables, rows)
+    (call,) = [e for e in decode.eqns if e.primitive.name == "pallas_call"]
+    assert call.params["name"] == "paged_decode_attn_latent"
+    shapes = [v.aval.shape for v in call.params["jaxpr"].invars]
+    assert shapes.count(pool.shape) == 1
+    assert [s for s in shapes if len(s) == 3 and s[0] == pa._DECODE_BUFFERS
+            ] == [(pa._DECODE_BUFFERS, 4 * 64, 640)]
+    prefill = jax.make_jaxpr(lambda q, kv, t, o, n: paged_prefill_attention(
+        q, kv, None, jnp.int32(1), t, o, n, latent=512, interpret=True))(
+        jnp.zeros((4, 128, 64, 640), bf16), pool, tables, rows, rows)
+    (call,) = [e for e in prefill.eqns if e.primitive.name == "pallas_call"]
+    assert call.params["name"] == "paged_prefill_attn_latent"
+    assert [v.aval.shape for v in call.invars].count(pool.shape) == 4
+    assert pa.latent_prefill_shape(72, 64, 640, 2, 128, 64, 512) == (4, 8)
+    assert decode_block_pages(72, 64, 640, 2, 64, 512, True) == 4
+    assert prefill_block_pages(72, 64, 640, 2, 128, 64 * 640, 2, 64, 512,
+                               True) == 4
+    # no V buffers: less fast memory than two planes of those widths
+    assert pa._decode_vmem_bytes(4, 64, 640, 2, 64, 512, True) < \
+        pa._decode_vmem_bytes(4, 64, 640, 2, 64, 512)
+
+
+@pytest.mark.parametrize("fault", ["a_v_pool", "a_row_of_576_lanes",
+                                   "a_value_of_half_a_tile", "a_window",
+                                   "a_sink", "an_int8_plane"])
+def test_the_latent_form_refuses_what_it_does_not_read(fault):
+    pool = jnp.zeros((2, 5, 16, 256), jnp.float32)
+    q = jnp.zeros((2, 4, 256), jnp.float32)
+    tables, n = jnp.zeros((2, 3), jnp.int32), jnp.ones((2,), jnp.int32)
+    kw, v, latent = {}, None, 128
+    if fault == "a_v_pool":
+        v = pool
+    elif fault == "a_row_of_576_lanes":
+        pool, q = jnp.zeros((2, 5, 16, 576)), jnp.zeros((2, 4, 576))
+    elif fault == "a_value_of_half_a_tile":
+        latent = 64
+    elif fault == "a_window":
+        kw = dict(window=32, col_page=jnp.zeros((2, 3), jnp.int32))
+    elif fault == "a_sink":
+        kw = dict(sink=jnp.zeros(4))
+    else:
+        kw = dict(k_scale=jnp.ones((2, 5)), v_scale=jnp.ones((2, 5)))
+    for call in (paged_attention, reference_paged_attention):
+        with pytest.raises(ValueError, match="latent form: want ONE bf16"):
+            call(q, pool, v, jnp.int32(0), tables, n, latent=latent, **kw)
+    for call in (paged_prefill_attention, reference_paged_prefill_attention):
+        with pytest.raises(ValueError, match="latent form: want ONE bf16"):
+            call(q[:, None], pool, v, jnp.int32(0), tables, n - 1, n,
+                 latent=latent, **kw)

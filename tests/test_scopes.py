@@ -18,8 +18,8 @@ import optax
 import pytest
 from jax.sharding import Mesh
 
-from ray_tpu.models import (gpt, jamba, laguna, paged_kv, qwen3_next, serving,
-                            zaya)
+from ray_tpu.models import (gpt, jamba, kimi_k2, laguna, paged_kv, qwen3_next,
+                            serving, zaya)
 from ray_tpu.ops import scopes
 from ray_tpu.train import spmd
 
@@ -27,9 +27,10 @@ PAGE, N_PAGES, N_SLOTS, CHUNK, ROWS, WIDTH = 16, 24, 4, 16, 2, 4
 TINY = {"gpt": gpt.GPTConfig.tiny_untied, "zaya": zaya.ZayaConfig.tiny,
         "laguna": laguna.LagunaConfig.tiny,
         "qwen3_next": qwen3_next.Qwen3NextConfig.tiny,
-        "jamba": jamba.JambaConfig.tiny}
+        "jamba": jamba.JambaConfig.tiny,
+        "kimi_k2": kimi_k2.KimiK2Config.tiny}
 MODULES = {"gpt": paged_kv, "zaya": zaya, "laguna": laguna,
-           "qwen3_next": qwen3_next, "jamba": jamba}
+           "qwen3_next": qwen3_next, "jamba": jamba, "kimi_k2": kimi_k2}
 
 
 def _shapes(fn, *args, **kw):
@@ -42,6 +43,8 @@ def lower_serving(family: str, program: str, attn_impl: str = "kernel"):
     cfg = TINY[family]()
     fam = serving.family_of(cfg)
     params = _shapes(fam.model.init_params, cfg, jax.random.PRNGKey(0))
+    if fam.lay_out is not None:         # the tree the engine serves
+        params = jax.eval_shape(functools.partial(fam.lay_out, cfg), params)
     ring = {"dispatch_tokens": ROWS * CHUNK} if fam.slot_ring else {}
     pool = _shapes(fam.init_pool, cfg, N_PAGES, PAGE, N_SLOTS, None, **ring)
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
@@ -177,6 +180,8 @@ def test_every_part_of_a_program_lies_in_one_scope(family, program):
             # The chain of a dispatch's rows is the chunk program's.
             want |= {scopes.GDN_IN, scopes.GDN_SCAN, scopes.GDN_OUT}
             want |= {scopes.SLOT_STATE} if program == "chunk" else set()
+        if family == "kimi_k2":
+            want |= {scopes.ATTN_ABSORB}
         if family == "jamba":
             want |= {scopes.SSM_IN, scopes.SSM_SCAN, scopes.SSM_OUT}
             want |= {scopes.SLOT_STATE} if program == "chunk" else set()
@@ -195,6 +200,8 @@ def test_a_scope_adds_nothing_to_the_module(family, program):
 
 
 def test_the_vocabulary_is_small_and_flat():
-    assert len(scopes.ALL) == len(set(scopes.ALL)) <= 20
+    # 21 since PR 56 (`attn.absorb`: latent attention's two matmuls
+    # around the call, which are neither its inputs nor its output).
+    assert len(scopes.ALL) == len(set(scopes.ALL)) <= 21
     for name in scopes.ALL:
         assert re.fullmatch(r"[a-z_]+(\.[a-z_]+)?", name), name
