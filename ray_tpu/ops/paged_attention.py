@@ -23,7 +23,8 @@ Design notes:
   the prefill kernel (4 pages = 256 keys at the served shapes),
   `decode_block_pages` in the decode kernel (up to 512 KiB of K a
   block: 16 pages of 32 KB at zaya1-8b, 4 of 128 KB at laguna-s-2.1, 2
-  of 256 KB at opt-1.3b).
+  of 256 KB at opt-1.3b; a latent pool's by its keys: 8 pages of 80 KB
+  at kimi-k2.6).
 - How a page gets to VMEM differs. Prefill: the grid is (row, kv block),
   the pool is handed over once a column of the block, and the K/V
   BlockSpec index map selects block ``(layer, tables[b, j], 0, 0)``:
@@ -82,6 +83,32 @@ Design notes:
   128. `prefill_kv_split` then gives the grid a KV-head axis: a grid
   step holds ONE KV head's K lanes of the block's pages and its H/G
   query heads, the same kernel body at n_heads = H/G, n_kv_heads = 1.
+- The latent form (`latent=`; models/kimi_k2.py): ONE plane whose row is
+  every head's key and, in its first lanes, their value; no head axis,
+  no V pool, so a page is fetched once and stands in both matmuls, and
+  the 64 heads are the rows of ONE query operand (no block-diagonal
+  surplus: 115 operations a byte read, where the other kinds do 16-64).
+  At 64 query rows a 128x128 MXU tile pass is half empty whichever
+  operand is latched, so a block's two matmuls take the MXU as long as
+  its pages take the DMAs, and the decode walk's serial body (score ->
+  mask, row maximum, `exp`, fold, cast -> PV -> accumulator, nothing of
+  block j+1 begun before block j's is stored) left the call at half its
+  limit. Measured on the chip at the cell's shapes (256 slots, 362,000
+  cached tokens; PERF.md, PR 57), the parent's body at 4 / 8 / 16 pages
+  a block: the whole call 1.082 / 0.887 / 0.815 ms; every DMA left in
+  and the compute emptied 0.683 / 0.680 / 0.678 (733 GB/s: the floor of
+  any schedule); the DMAs taken out and the compute left 0.878 / 0.675 /
+  0.593. So the latent decode call has a body of its own,
+  `_latent_decode_kernel` (the prefill side has `_latent_prefill_kernel`):
+  the same walk (`_block_walk`), a block sized by its keys
+  (`_LATENT_BLOCK_KEYS`, 8 pages of 64), and TWO blocks in flight
+  through the compute: an iteration scores the NEXT live block and
+  runs the softmax chain and PV of THIS one in one basic block, the
+  next block's scores carried in VMEM scratch, a fourth page buffer
+  keeping two blocks ahead of the one waited for, `sm_scale` folded
+  into the query once a slot. Compute alone 0.545 ms, the whole call
+  0.778 at 8 pages (0.843 at 4, 0.793 at 16). What chooses the body is
+  the pool's form the caller hands over (`latent is not None`).
 - Online-softmax state (m, l, acc) lives in VMEM scratch across the kv
   blocks of a row or slot, exactly like the flash kernel.
   Both kernels keep m lane-uniform and use it at full width, and keep l
@@ -114,6 +141,7 @@ from __future__ import annotations
 
 import functools
 import math
+import types
 
 import jax
 import jax.numpy as jnp
@@ -327,89 +355,19 @@ def _column_live(first, kv_len, page_size, window):
     return live
 
 
-def _decode_kernel(
-    *refs,
-    sm_scale, page_size, block_pages, n_heads, n_kv_heads, quantized=False,
-    window=None, v_head_dim=None, sink=False, latent=False,
-):
-    """Every slot of a group against its LIVE kv blocks, one grid step a
-    group (the whole batch at every served shape), the pages fetched by
-    the kernel's own DMAs. Ref order: scalar-prefetch (SMEM) first
-    (layer, page tables, kv lengths, a ring's `col_page`, and for an
-    int8 pool the layer's per-page K/V scale vectors), the group's
-    queries (VMEM), the K and V pools WHOLE and left in HBM, the output,
-    and the scratch: `_DECODE_BUFFERS` K and as many V buffers of a block
-    ([buffers, block_pages*ps, G*K]) with a DMA semaphore a buffer pair,
-    the block-diagonal query and the (m, l, acc) softmax state.
-
-    One flat loop walks the live blocks of the group in (slot, block)
-    order. A block is `block_pages` consecutive table columns; a live
-    column's page goes from ``pool[layer, tables[b, c]]`` straight to
-    its rows of the buffer (the DMA's destination does the stacking), a
-    dead column of a live block gets no DMA and is position-masked, and
-    a dead block, an idle slot and a ring's columns outside the window
-    are never visited. While a block is attended the next live blocks,
-    of this slot or of the next live ones, are already in flight into
-    the other buffers, so no slot waits for its own first page (a wait
-    a slot cost more than the whole grid did: PERF.md, PR 41). A block pays
-    ONE score tile, one masked row maximum, one `exp`, one partial row
-    sum and one accumulator update whatever it holds (PERF.md, PR 37).
-    `quantized` is a Python-level trace switch: the int8 program dequants
-    each page of the block by its own scale after the wait, inside the
-    kernel, and the fp32 plane never exists in HBM. `v_head_dim`: the
-    V plane's head size where it is not K's (the accumulator and the
-    output are that wide); `sink`: a [H, LANES] operand follows the
-    queries, the logit each head's softmax starts from. `latent`: ONE
-    pool and ONE set of buffers; a row is its token's key and, in its
-    first `v_head_dim` lanes, its value, so a page is fetched once and
-    stands in both matmuls (one KV head, `n_kv_heads` 1)."""
-    n, ps = block_pages, page_size
-    refs = iter(refs)
-    take = lambda count: [next(refs) for _ in range(count)]
-    layer_ref, tables_ref, lengths_ref = take(3)
-    (col_ref,) = take(1) if window is not None else (None,)
-    ks_ref, vs_ref = take(2) if quantized else (None, None)
-    (q_ref,) = take(1)
-    (sink_ref,) = take(1) if sink else (None,)
-    if latent:
-        (k_hbm, o_ref), (k_buf,) = take(2), take(1)
-        pools = ((k_hbm, k_buf),)
-    else:
-        (k_hbm, v_hbm, o_ref), (k_buf, v_buf) = take(3), take(2)
-        pools = ((k_hbm, k_buf), (v_hbm, v_buf))
-    sem, qbd_ref, m_ref, l_ref, acc_ref = refs
-    # Multi-head (G = H): a slot's query is one dense row [1, H*K].
-    # Grouped (G < H): it is [H, K], a head a row, and the pool's minor
-    # axis holds G heads; row h of the block-diagonal query then sits in
-    # the lanes of KV head h // (H/G), so the two matmuls are the same.
-    # K and V of unequal head size take the grouped form whatever G.
-    grouped = n_kv_heads != n_heads or v_head_dim is not None
-    head_dim = q_ref.shape[-1] if grouped else q_ref.shape[-1] // n_heads
-    v_dim = v_head_dim or head_dim
-    mask = lambda: _head_mask(n_heads, head_dim, n_kv_heads)
-    v_mask = mask if v_dim == head_dim else (
-        lambda: _head_mask(n_heads, v_dim, n_kv_heads))
-    block = n * ps
-    GK = acc_ref.shape[1]
-    group = q_ref.shape[0]
+def _block_walk(tables_ref, lengths_ref, col_ref, pools, sem, layer, *,
+                n, ps, window, end):
+    """What both decode kernels walk a slot group's LIVE kv blocks by, in
+    (slot, block) order up to slot `end`: `columns(b, j)`, `after(b, j)`,
+    `seek(b, j)`, `copies(act, b, cols, buf, go)` and `fetch(item, buf)`.
+    A block is `n` consecutive table columns of `ps` keys; `pools`:
+    ((pool in HBM, its [buffers, n*ps, lanes] VMEM buffers), ...), one DMA
+    semaphore a buffer index in `sem`; `col_ref`: a ring's `col_page`
+    with `window`, else None."""
     n_pg = tables_ref.shape[1]
     n_blk = -(-n_pg // n)
-    n_buf = k_buf.shape[0]
+    block = n * ps
     ragged = n_blk * n != n_pg      # the last block runs past the table
-    base = pl.program_id(0) * group
-    end = base + group
-    layer = layer_ref[0]
-
-    @pl.when(pl.program_id(0) == 0)
-    def _finite_rows():
-        # A dead column's rows of a buffer keep what they held. Their
-        # probabilities are 0, and 0 x NaN is NaN in PV: V's rows are
-        # made finite once, and only pages of the pool land there after.
-        # (K's rows may hold anything: their scores are masked; a
-        # latent row is its own value.)
-        finite = k_buf if latent else v_buf
-        finite[...] = jnp.zeros_like(finite)
-    o_ref[...] = jnp.zeros_like(o_ref)       # an idle slot is never visited
 
     def columns(b, j):
         """[(live, table column, first key)] of block j of slot b: what
@@ -470,6 +428,88 @@ def _decode_kernel(
         b = jnp.minimum(item[0], end - 1)
         copies("start", b, columns(b, item[1]), buf, item[0] < end)
 
+    return types.SimpleNamespace(columns=columns, after=after, seek=seek,
+                                 copies=copies, fetch=fetch)
+
+
+def _decode_kernel(
+    *refs,
+    sm_scale, page_size, block_pages, n_heads, n_kv_heads, quantized=False,
+    window=None, v_head_dim=None, sink=False,
+):
+    """Every slot of a group against its LIVE kv blocks, one grid step a
+    group (the whole batch at every served shape), the pages fetched by
+    the kernel's own DMAs. Ref order: scalar-prefetch (SMEM) first
+    (layer, page tables, kv lengths, a ring's `col_page`, and for an
+    int8 pool the layer's per-page K/V scale vectors), the group's
+    queries (VMEM), the K and V pools WHOLE and left in HBM, the output,
+    and the scratch: `_DECODE_BUFFERS` K and as many V buffers of a block
+    ([buffers, block_pages*ps, G*K]) with a DMA semaphore a buffer pair,
+    the block-diagonal query and the (m, l, acc) softmax state.
+
+    One flat loop walks the live blocks of the group in (slot, block)
+    order. A block is `block_pages` consecutive table columns; a live
+    column's page goes from ``pool[layer, tables[b, c]]`` straight to
+    its rows of the buffer (the DMA's destination does the stacking), a
+    dead column of a live block gets no DMA and is position-masked, and
+    a dead block, an idle slot and a ring's columns outside the window
+    are never visited. While a block is attended the next live blocks,
+    of this slot or of the next live ones, are already in flight into
+    the other buffers, so no slot waits for its own first page (a wait
+    a slot cost more than the whole grid did: PERF.md, PR 41). A block pays
+    ONE score tile, one masked row maximum, one `exp`, one partial row
+    sum and one accumulator update whatever it holds (PERF.md, PR 37).
+    `quantized` is a Python-level trace switch: the int8 program dequants
+    each page of the block by its own scale after the wait, inside the
+    kernel, and the fp32 plane never exists in HBM. `v_head_dim`: the
+    V plane's head size where it is not K's (the accumulator and the
+    output are that wide); `sink`: a [H, LANES] operand follows the
+    queries, the logit each head's softmax starts from. (A latent pool
+    has a body of its own, `_latent_decode_kernel`.)"""
+    n, ps = block_pages, page_size
+    refs = iter(refs)
+    take = lambda count: [next(refs) for _ in range(count)]
+    layer_ref, tables_ref, lengths_ref = take(3)
+    (col_ref,) = take(1) if window is not None else (None,)
+    ks_ref, vs_ref = take(2) if quantized else (None, None)
+    (q_ref,) = take(1)
+    (sink_ref,) = take(1) if sink else (None,)
+    (k_hbm, v_hbm, o_ref), (k_buf, v_buf) = take(3), take(2)
+    pools = ((k_hbm, k_buf), (v_hbm, v_buf))
+    sem, qbd_ref, m_ref, l_ref, acc_ref = refs
+    # Multi-head (G = H): a slot's query is one dense row [1, H*K].
+    # Grouped (G < H): it is [H, K], a head a row, and the pool's minor
+    # axis holds G heads; row h of the block-diagonal query then sits in
+    # the lanes of KV head h // (H/G), so the two matmuls are the same.
+    # K and V of unequal head size take the grouped form whatever G.
+    grouped = n_kv_heads != n_heads or v_head_dim is not None
+    head_dim = q_ref.shape[-1] if grouped else q_ref.shape[-1] // n_heads
+    v_dim = v_head_dim or head_dim
+    mask = lambda: _head_mask(n_heads, head_dim, n_kv_heads)
+    v_mask = mask if v_dim == head_dim else (
+        lambda: _head_mask(n_heads, v_dim, n_kv_heads))
+    block = n * ps
+    GK = acc_ref.shape[1]
+    group = q_ref.shape[0]
+    n_buf = k_buf.shape[0]
+    base = pl.program_id(0) * group
+    end = base + group
+    layer = layer_ref[0]
+
+    @pl.when(pl.program_id(0) == 0)
+    def _finite_rows():
+        # A dead column's rows of a buffer keep what they held. Their
+        # probabilities are 0, and 0 x NaN is NaN in PV: V's rows are
+        # made finite once, and only pages of the pool land there after.
+        # (K's rows may hold anything: their scores are masked.)
+        v_buf[...] = jnp.zeros_like(v_buf)
+    o_ref[...] = jnp.zeros_like(o_ref)       # an idle slot is never visited
+
+    walk = _block_walk(tables_ref, lengths_ref, col_ref, pools, sem, layer,
+                       n=n, ps=ps, window=window, end=end)
+    columns, after, seek = walk.columns, walk.after, walk.seek
+    copies, fetch = walk.copies, walk.fetch
+
     def init(b):
         _seed_state(m_ref, l_ref, None if sink_ref is None else sink_ref[...])
         acc_ref[...] = jnp.zeros_like(acc_ref)
@@ -498,7 +538,7 @@ def _decode_kernel(
             return parts[0] if n == 1 else jnp.concatenate(parts, axis=0)
 
         k = stacked(k_buf, ks_ref)
-        v = k[:, :v_dim] if latent else stacked(v_buf, vs_ref)
+        v = stacked(v_buf, vs_ref)
         # s[h, t] = q[h] · k[t, head h's lanes]: the block-diagonal query
         # makes it one [H, G*K] x [block, G*K]ᵀ matmul. Decode attention
         # is HBM-bound (~2 flops/byte), so the H-fold surplus of
@@ -578,7 +618,10 @@ def _decode_kernel(
 # at all three served page sizes (16 pages of 32 KB, 4 of 128 KB, 2 of
 # 256 KB: PERF.md, PR 37; with the kernel's own DMAs half and twice that
 # read within 2 % of it, PR 41), and past it a live block's dead columns
-# cost more compute than the chains saved. Three buffers a pool: two
+# cost more compute than the chains saved. That was measured for the
+# full and window kinds (multi-head and grouped, bf16 and int8; their
+# block-diagonal matmuls leave the MXU time to spare), not for a latent
+# pool, whose block goes by `_LATENT_BLOCK_KEYS`. Three buffers a pool: two
 # blocks in flight behind the one attended, because a slot's last block
 # is often a page or two and its DMA ends long before the block's fixed
 # compute does (the third buffer is worth 3 % at 32 KB pages, 15 % in
@@ -591,17 +634,30 @@ _DECODE_BLOCK_KEYS = 1024
 _DECODE_BUFFERS = 3
 _DECODE_VMEM_BUDGET = 12 * 2**20
 _DECODE_GROUP_BUDGET = 14 * 2**20
+# The latent form's block (`_latent_decode_kernel`) goes by its keys: it
+# has no V buffers, so 512 KiB of one plane bounded it at 4 pages of 80 KB
+# where 8 read best (the whole call at 4 / 8 / 16 pages a block, 362,000
+# cached tokens in 256 slots: 0.84 / 0.78 / 0.79 ms; a live block's dead
+# columns cost MXU passes here, and past 512 keys they cost more than the
+# larger block amortises: PERF.md, PR 57). FOUR buffers: the block
+# attended still stands in its value matmul while the next one is scored,
+# and two more are in flight behind that one (three read 16 % slower, five
+# 0.7 % faster).
+_LATENT_BLOCK_KEYS = 512
+_LATENT_BUFFERS = 4
 
 
 def _decode_vmem_bytes(n, page_size, kv_lanes, kv_itemsize, n_heads,
                        v_lanes=None, latent=False) -> int:
     """VMEM the decode kernel takes at `n` pages a block, beside its
     queries and outputs: `_DECODE_BUFFERS` K and as many V buffers of a
-    block (`kv_lanes` = G*K and `v_lanes` = G*Kv wide; None: as K;
-    `latent`: no V buffers, the value is lanes of K's rows), an
+    block (`kv_lanes` = G*K and `v_lanes` = G*Kv wide; None: as K), an
     int8 block's f32 copies, the score and probability tiles, and
     whatever the block: the block-diagonal query (K's width) with the
-    f32 accumulator and its update (V's) and the (m, l) state."""
+    f32 accumulator and its update (V's) and the (m, l) state. `latent`:
+    `_LATENT_BUFFERS` buffers of the one plane (the value is lanes of
+    its rows), the tiles, this block's scores and the next one's in
+    scratch, and the two queries where the block-diagonal one was."""
     v_lanes = kv_lanes if v_lanes is None else v_lanes
     both = kv_lanes + (0 if latent else v_lanes)
     page = page_size * both * kv_itemsize
@@ -609,6 +665,8 @@ def _decode_vmem_bytes(n, page_size, kv_lanes, kv_itemsize, n_heads,
     tiles = 2 * n_heads * n * page_size * 4
     fixed = (n_heads * (kv_lanes * 4 + v_lanes * (4 + 4))
              + 2 * n_heads * _LANES * 4)
+    if latent:
+        return _LATENT_BUFFERS * n * page + 2 * tiles + fixed
     return _DECODE_BUFFERS * n * page + dequant + tiles + fixed
 
 
@@ -618,13 +676,19 @@ def decode_block_pages(n_pg, page_size, kv_lanes, kv_itemsize,
     power of two that is at most `n_pg`, keeps the block's K pages
     (`kv_lanes` = G*K wide) at or under `_DECODE_BLOCK_BYTES` and
     `_DECODE_BLOCK_KEYS` keys, and fits `_DECODE_VMEM_BUDGET`
-    (`_decode_vmem_bytes`, which counts V's pages at `v_lanes`, or none
-    for a `latent` pool). Pure in the shapes: the engine's
-    `decode_block_fill` counter and the kernel ask it the same question."""
+    (`_decode_vmem_bytes`, which counts V's pages at `v_lanes`). A
+    `latent` pool's block goes by its keys alone, `_LATENT_BLOCK_KEYS`,
+    and the same budget (no V buffers, four of K's and the next block's
+    scores). Pure in the shapes: the engine's `decode_block_fill`
+    counter and the kernel ask it the same question."""
     page = page_size * kv_lanes * kv_itemsize
+    if latent:
+        bounded = lambda m: m * page_size <= _LATENT_BLOCK_KEYS
+    else:
+        bounded = lambda m: (m * page <= _DECODE_BLOCK_BYTES
+                             and m * page_size <= _DECODE_BLOCK_KEYS)
     n = 1
-    while (2 * n <= n_pg and 2 * n * page <= _DECODE_BLOCK_BYTES
-           and 2 * n * page_size <= _DECODE_BLOCK_KEYS
+    while (2 * n <= n_pg and bounded(2 * n)
            and _decode_vmem_bytes(2 * n, page_size, kv_lanes, kv_itemsize,
                                   n_heads, v_lanes, latent)
            <= _DECODE_VMEM_BUDGET):
@@ -713,34 +777,29 @@ def paged_attention(
     ``reference_paged_attention``).
     """
     B, H, K = q.shape
-    if latent is None:
-        ps, G, Kv = _check_pool(H, K, k_pool, v_pool)
-    else:
-        ps, G, Kv = _check_latent(K, k_pool, v_pool, latent, k_scale,
-                                  window, sink)
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(K)
     if interpret is None:
         interpret = _interpret_default()
+    if latent is not None:
+        _check_latent(K, k_pool, v_pool, latent, k_scale, window, sink)
+        return _latent_decode(q, k_pool, layer, tables, lengths, latent,
+                              sm_scale, interpret)
+    ps, G, Kv = _check_pool(H, K, k_pool, v_pool)
     quantized = k_scale is not None
     _check_window(window, col_page, tables, quantized)
     kv_item = k_pool.dtype.itemsize
-    shared = latent is not None
-    n = decode_block_pages(tables.shape[1], ps, G * K, kv_item, H, G * Kv,
-                           latent=shared)
+    n = decode_block_pages(tables.shape[1], ps, G * K, kv_item, H, G * Kv)
     name, prefetch, extra = _call_form(
         "paged_decode_attn", layer, (tables, lengths), k_scale, v_scale,
         window, col_page)
     sink = _sink_form(extra, sink, H, K, Kv)
-    if shared:
-        name, extra["latent"] = name + "_latent", True
     dense = G == H and Kv == K      # a slot's query is one row [1, H*K]
     # A grouped slot's [H, K] and [H, Kv] blocks, as VMEM pads them.
     tiled = lambda lanes: lanes if dense else -(-lanes // _LANES) * _LANES
     group = _decode_slot_group(
         B, H * (tiled(K) + tiled(Kv)) // 2 * q.dtype.itemsize,
-        _decode_vmem_bytes(n, ps, G * K, kv_item, H, G * Kv,
-                           latent=shared))
+        _decode_vmem_bytes(n, ps, G * K, kv_item, H, G * Kv))
 
     kernel = functools.partial(
         _decode_kernel, sm_scale=sm_scale, page_size=ps, block_pages=n,
@@ -750,7 +809,7 @@ def paged_attention(
     slots = lambda shape: pl.BlockSpec((group,) + shape[1:],
                                        lambda g, *_: (g, 0, 0))
     pool = pl.BlockSpec(memory_space=pl.ANY)
-    pools = (k_pool,) if shared else (k_pool, v_pool)
+    pools = (k_pool, v_pool)
     sinks = ([] if sink is None else
              [pl.BlockSpec((H, _LANES), lambda g, *_: (0, 0))])
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -776,6 +835,165 @@ def paged_attention(
         name=name,
     )(*prefetch, q, *(() if sink is None else (sink,)), *pools)
     return out.reshape(B, H, Kv)
+
+
+# ----------------------------------------------- the latent form, decode
+
+def _latent_decode_kernel(*refs, sm_scale, page_size, block_pages, v_dim):
+    """`_decode_kernel` for a latent pool: ONE plane, whose row is every
+    head's key (all K lanes) and, in its first `v_dim` lanes, their
+    value, so a page is fetched once and stands in both matmuls. The
+    same flat walk over the group's live blocks (`_block_walk`), under a
+    schedule of its own: TWO blocks in flight through the compute, as
+    two are through the DMAs. One iteration scores the NEXT live block
+    (its pages waited for; its slot's query, scaled once a slot, in the
+    other half of `qs_ref` when that slot is not this one) and runs the
+    softmax chain and the value matmul of THIS one on the scores the
+    iteration before left in `s_ref`. The two depend on nothing of each
+    other and lie in one basic block, so the next block's pass through
+    the MXU runs under this block's vector chain and the chain's waits
+    for its own matmuls: at 64 query rows against 640-lane rows the
+    MXU's time a block equals its DMA's, and a serial body left the call
+    at half its limit (design notes). Past the walk's end the "next"
+    block is whatever a buffer holds, and nothing reads its scores.
+    Refs: layer, tables, lengths (SMEM); the group's queries
+    [group, H, K]; the pool in HBM; the output [group, H, v_dim]; scratch:
+    `_LATENT_BUFFERS` block buffers with a DMA semaphore each, the two
+    queries, this block's scores and the next one's, and (m, l, acc)."""
+    n, ps = block_pages, page_size
+    (layer_ref, tables_ref, lengths_ref, q_ref, kv_hbm, o_ref,
+     kv_buf, sem, qs_ref, s_ref, s_next_ref, m_ref, l_ref, acc_ref) = refs
+    block = n * ps
+    group = q_ref.shape[0]
+    n_buf = kv_buf.shape[0]
+    base = pl.program_id(0) * group
+    end = base + group
+    walk = _block_walk(tables_ref, lengths_ref, None, ((kv_hbm, kv_buf),),
+                       sem, layer_ref[0], n=n, ps=ps, window=None, end=end)
+    following = lambda item: walk.seek(*walk.after(*item))
+
+    @pl.when(pl.program_id(0) == 0)
+    def _finite_rows():
+        # A dead column's rows keep what they held, a row is its own
+        # value, and 0 x NaN is NaN in PV: made finite once a call.
+        kv_buf[...] = jnp.zeros_like(kv_buf)
+    o_ref[...] = jnp.zeros_like(o_ref)       # an idle slot is never visited
+    # A slot's first block drops the state before it by a factor of 0
+    # (`attend`); what the first block of all drops has to be finite.
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def ready(item, which, buf, fresh):
+        """Block `item` ready to be scored: its slot's query in half
+        `which` of `qs_ref` (scaled here, once a slot: when `fresh`) and
+        its pages waited for in `buf`. Past the group's end: nothing."""
+        more = item[0] < end
+        b = jnp.minimum(item[0], end - 1)
+
+        @pl.when(fresh & more)
+        def _query():
+            q = q_ref[b - base].astype(jnp.float32) * sm_scale
+            qs_ref[which] = q.astype(qs_ref.dtype)
+        walk.copies("wait", b, walk.columns(b, item[1]), buf, more)
+
+    def score(which, buf):
+        """[H, block] float32: every head's query against the rows of
+        the block in `buf`, all K lanes."""
+        return jax.lax.dot_general(qs_ref[which], kv_buf[buf], _NT,
+                                   preferred_element_type=jnp.float32)
+
+    def attend(b, j, buf):
+        """The softmax chain and PV of block j of slot b, whose scores
+        lie in `s_ref` and whose rows in `buf`. No branch: a slot's
+        first block takes m = -inf for the state before it, so `corr`
+        is 0 and (l, acc) start over."""
+        lane = jax.lax.broadcasted_iota(jnp.int32, s_ref.shape, 1)
+        s = jnp.where(lane < lengths_ref[b] - j * block, s_ref[...], NEG_INF)
+        m_prev = jnp.where(j == 0, NEG_INF, m_ref[...])
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - _spread(m_new, block))       # [H, block] fp32
+        corr = jnp.exp(m_prev - m_new)               # [H, LANES]
+        l_ref[...] = l_ref[...] * corr + _fold(p)
+        pv = jnp.dot(p.astype(kv_buf.dtype), kv_buf[buf, :, :v_dim],
+                     preferred_element_type=jnp.float32)
+        acc_ref[...] = acc_ref[...] * _spread(corr, v_dim) + pv
+        m_ref[...] = m_new
+
+    def finish(b):
+        l = jnp.sum(l_ref[...], axis=1, keepdims=True)
+        o_ref[b - base] = (acc_ref[...] * (1.0 / l)).astype(o_ref.dtype)
+
+    def visit(at):
+        # `ahead`: this block, the next one (fetched; scored here) and
+        # those in flight behind it. The live block after them goes out
+        # into the buffer the block before this one left.
+        ahead, buf, which = at
+        (b, j), nxt = ahead[0], ahead[1]
+        ahead += (following(ahead[-1]),)
+        walk.fetch(ahead[-1], jnp.where(buf == 0, n_buf - 1, buf - 1))
+        buf_n = jnp.where(buf == n_buf - 1, 0, buf + 1)
+        crossing = nxt[0] != b
+        which_n = jnp.where(crossing, 1 - which, which)
+        ready(nxt, which_n, buf_n, crossing)
+        # One basic block from here to the scores' copy (a `pl.when`
+        # between the two halves would keep the scheduler from
+        # interleaving them; two scratches, not two halves of one under
+        # an index: it takes accesses at a dynamic index for dependent).
+        s_next_ref[...] = score(which_n, buf_n)
+        attend(b, j, buf)
+        s_ref[...] = s_next_ref[...]
+        pl.when(crossing)(lambda: finish(b))
+        return ahead[1:], buf_n, which_n
+
+    ahead = (walk.seek(base, jnp.int32(0)),)
+    for _ in range(n_buf - 2):
+        ahead += (following(ahead[-1]),)
+    for buf, item in enumerate(ahead):
+        walk.fetch(item, buf)
+    ready(ahead[0], 0, 0, True)
+    s_ref[...] = score(0, 0)
+    jax.lax.while_loop(lambda at: at[0][0][0] < end, visit,
+                       (ahead, jnp.int32(0), jnp.int32(0)))
+
+
+def _latent_decode(q, kv_pool, layer, tables, lengths, latent, sm_scale,
+                   interpret):
+    """`paged_attention`'s latent form: q [B, H, K] against ONE plane
+    [L, P, ps, K] left in HBM -> [B, H, latent]."""
+    B, H, K = q.shape
+    ps, item = kv_pool.shape[2], kv_pool.dtype.itemsize
+    n = decode_block_pages(tables.shape[1], ps, K, item, H, latent,
+                           latent=True)
+    group = _decode_slot_group(
+        B, H * (K + latent) // 2 * q.dtype.itemsize,
+        _decode_vmem_bytes(n, ps, K, item, H, latent, latent=True))
+    prefetch = _prefetch(layer, (tables, lengths), None, None)
+    slots = lambda lanes: pl.BlockSpec((group, H, lanes),
+                                       lambda g, *_: (g, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(B // group,),
+        in_specs=[slots(K), pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=slots(latent),
+        scratch_shapes=[
+            pltpu.VMEM((_LATENT_BUFFERS, n * ps, K), kv_pool.dtype),
+            pltpu.SemaphoreType.DMA((_LATENT_BUFFERS,)),
+            pltpu.VMEM((2, H, K), q.dtype),          # this slot's q, the next's
+            pltpu.VMEM((H, n * ps), jnp.float32),    # this block's scores
+            pltpu.VMEM((H, n * ps), jnp.float32),    # the next block's
+            pltpu.VMEM((H, _LANES), jnp.float32),    # m
+            pltpu.VMEM((H, _LANES), jnp.float32),    # l
+            pltpu.VMEM((H, latent), jnp.float32),    # acc
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_latent_decode_kernel, sm_scale=sm_scale,
+                          page_size=ps, block_pages=n, v_dim=latent),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, latent), q.dtype),
+        interpret=interpret,
+        name="paged_decode_attn_latent",
+    )(*prefetch, q, kv_pool)
 
 
 # The prefill kernel's kv block. A block under ~256 keys leaves the two

@@ -1215,16 +1215,47 @@ def kimi_k2_serving(chip):
 def test_latent_kernels_compile_at_the_cells_shapes(chip):
     """Both paged kernels in their latent form at the kimi-k2.6 cell's
     shapes: 64 query heads over ONE plane of 640-lane rows, the value its
-    first 512 lanes, at the cell's table width and both chunk heights;
-    under names of their own. A plane declared at the row's 576 values is
-    refused before Mosaic is asked (which would refuse a 576-lane DMA of
-    what the chip stores in 640)."""
+    first 512 lanes, at the cell's table widths and both chunk heights;
+    under names of their own. The decode call's body of its own
+    (`_latent_decode_kernel`: 8 pages a block, FOUR block buffers, the
+    two queries and the two score scratches of its two-stage schedule)
+    takes the VMEM its rule reckons, and with a group of 32 slots'
+    queries and outputs stays inside the 16 MiB a kernel gets. A plane
+    declared at the row's 576 values is refused before Mosaic is asked
+    (which would refuse a 576-lane DMA of what the chip stores in 640)."""
+    import importlib
+
+    attn = importlib.import_module("ray_tpu.ops.paged_attention")
     pool = chip((5, K2_PAGES + 1, PS, K2_ROW), jnp.bfloat16)
-    _compile(lambda q, kv, l, t, n: paged_attention(
-        q, kv, None, l, t, n, latent=K2_LATENT, interpret=False),
-        chip((K2_SLOTS, K2_H, K2_ROW), jnp.bfloat16), pool, _layer(chip),
-        chip((K2_SLOTS, K2_WIDTH), jnp.int32), chip((K2_SLOTS,), jnp.int32),
-        kernels=("paged_decode_attn_latent",))
+    decode = lambda q, kv, l, t, n: paged_attention(
+        q, kv, None, l, t, n, latent=K2_LATENT, interpret=False)
+    for width in (64, K2_WIDTH):    # the window's width, and `max_len`'s
+        args = (chip((K2_SLOTS, K2_H, K2_ROW), jnp.bfloat16), pool,
+                _layer(chip), chip((K2_SLOTS, width), jnp.int32),
+                chip((K2_SLOTS,), jnp.int32))
+        _compile(decode, *args, kernels=("paged_decode_attn_latent",))
+        (eqn,) = [e for e in jax.make_jaxpr(decode)(*args).eqns
+                  if e.primitive.name == "pallas_call"]
+        spec = eqn.params["grid_mapping"]
+        assert spec.grid == (8,)
+        scratch = [v.aval for v in eqn.params["jaxpr"].invars[
+            -spec.num_scratch_operands:]]
+        vmem = sum(int(np.prod(a.shape)) * jnp.dtype(a.dtype).itemsize
+                   for a in scratch if "sem" not in str(a.dtype).lower())
+        n = attn.decode_block_pages(width, PS, K2_ROW, 2, K2_H, K2_LATENT,
+                                    latent=True)
+        assert n == 8 and attn._LATENT_BUFFERS == 4
+        block = n * PS
+        assert vmem == (4 * block * K2_ROW * 2          # page buffers
+                        + 2 * K2_H * K2_ROW * 2         # two queries
+                        + 2 * K2_H * block * 4          # two score tiles
+                        + 2 * K2_H * 128 * 4 + K2_H * K2_LATENT * 4)
+        reckoned = attn._decode_vmem_bytes(n, PS, K2_ROW, 2, K2_H,
+                                           K2_LATENT, latent=True)
+        tiles = 2 * K2_H * block * 4        # masked scores, probabilities
+        assert vmem + tiles <= reckoned <= attn._DECODE_VMEM_BUDGET
+        queries = 2 * 32 * K2_H * (K2_ROW + K2_LATENT) * 2
+        assert reckoned + queries <= attn._DECODE_GROUP_BUDGET < 16 * 2**20
     for rows in ONE_WIDTH_HEIGHTS:
         _compile(lambda q, kv, l, t, o, n: paged_prefill_attention(
             q, kv, None, l, t, o, n, latent=K2_LATENT, interpret=False),

@@ -47,7 +47,13 @@ PAGE, N_PAGES, N_SLOTS, CHUNK = 16, 24, 3, 16
 # PR moved mimo_v2's router and counter row to models/blocks.py
 # (`biased_route`, `counter_row_biased`), laguna's `yarn_inv_freq` and
 # `init_from_specs` likewise, and gave both paged kernels a latent form:
-# the eighteen above did not move.
+# the eighteen above did not move. `kimi_k2.decode` and `kimi_k2.sample`
+# as PR 57 traced them, which meant to change them and nothing else: the
+# latent decode call has a kernel body of its own
+# (`ops/paged_attention.py` `_latent_decode_kernel`: a block of 512 keys,
+# the next block's scores under this block's softmax chain);
+# `kimi_k2.chunk` runs the prefill form and stayed, as did the other
+# eighteen.
 _PINNED = {
     "gpt.chunk": "aff570174e390475", "gpt.decode": "c4ebbb44ff02a83f",
     "zaya.chunk": "e3c3c03e1e115475", "zaya.decode": "21818dedb3b70792",
@@ -63,8 +69,8 @@ _PINNED = {
     "jamba.chunk": "16c983f57626c3f6", "jamba.decode": "5262465ac313b68d",
     "jamba.sample": "09798055e3a58725",
     "kimi_k2.chunk": "8b845056d1448fc7",
-    "kimi_k2.decode": "f5d19126fc17656e",
-    "kimi_k2.sample": "4be41ea2f7fbebba",
+    "kimi_k2.decode": "49e6b8e3219adf29",
+    "kimi_k2.sample": "3336df4d80973dd5",
 }
 
 _RING = {"dispatch_tokens": 2 * CHUNK}

@@ -1432,26 +1432,126 @@ def _latent_dense(q, rows, qpos, lengths, latent, scale):
     return np.einsum("bhct,btv->bchv", p, rows[..., :latent])
 
 
-@pytest.mark.parametrize("H,K,latent,ps", LATENT)
-def test_decode_kernel_in_the_latent_form(H, K, latent, ps):
-    """Ragged lengths, an idle slot, a last page partly full, a length of
-    one: kernel = oracle = plain attention; an idle slot's output is 0."""
+# What the latent decode call's two-stage walk can get wrong (an
+# iteration scores the NEXT live block under the softmax chain of THIS
+# one): name -> (pages a block or None for the rule's, table width,
+# lengths from a page's keys `ps` and a block's `blk`, slots a grid step
+# or None for all). The last three shapes of `_LATENT_WALKS` run at the
+# cell's own sizes too.
+_LATENT_WALKS = {
+    # an idle slot, one token, a last page partly full, a table the
+    # rule's block (4 pages) does not divide
+    "ragged": (None, 6, lambda ps, blk: [0, 1, ps + 3, 5 * ps,
+                                         4 * ps + ps // 2], None),
+    # no live block at all: the prologue scores what a buffer holds
+    "every_slot_idle": (2, 6, lambda ps, blk: [0, 0, 0, 0], None),
+    "idle_first_between_last": (2, 6, lambda ps, blk: [
+        0, 0, 3 * ps + 1, 0, ps, 0, 0], None),
+    # the prologue's block has no next one
+    "one_live_block": (2, 6, lambda ps, blk: [0, 0, 5, 0], None),
+    # every iteration crosses into the next slot's query
+    "a_block_a_slot": (2, 6, lambda ps, blk: [3, blk, 1, ps, blk - 1],
+                       None),
+    # the next live block is past `end`: a group's last block
+    "slot_groups": (2, 6, lambda ps, blk: [3 * blk, 0, 0, blk + 7, 1, ps],
+                    2),
+    "block_edges": (2, 6, lambda ps, blk: [1, blk, blk + 1, 6 * ps,
+                                           2 * blk, 2 * blk + 1], None),
+    # the served table width at 16 pages a block: the last block runs
+    # past the table
+    "72_columns_at_16_pages": (16, 72, lambda ps, blk: [
+        1, blk, 64 * ps + 1, 72 * ps, 0, 65 * ps], None),
+}
+
+
+@pytest.mark.parametrize("H,K,latent,ps,walk", [
+    shape + (walk,) for walk in _LATENT_WALKS for shape in LATENT[:2]
+] + [LATENT[2] + (walk,)
+     for walk in ("ragged", "slot_groups", "block_edges")])
+def test_decode_kernel_in_the_latent_form(H, K, latent, ps, walk,
+                                          monkeypatch):
+    """Kernel = oracle = plain attention, and an idle slot's output is 0,
+    over the walks above. Every page no live key lies on is NaN (dead
+    table entries point at such pages), and in interpret mode the page
+    buffers, the score scratch and the state START as NaN, as an earlier
+    call may leave them on the chip: none may reach the output."""
+    from jax._src.pallas.primitives import uninitialized_value
+
+    assert np.isnan(uninitialized_value((1,), jnp.float32)).all()
+    n, n_pg, lengths, group = _LATENT_WALKS[walk]
+    if n is not None:
+        monkeypatch.setattr(pa, "_LATENT_BLOCK_KEYS", n * ps)
+    n = decode_block_pages(n_pg, ps, K, 4, H, latent, latent=True)
+    assert n == (4 if walk == "ragged" else _LATENT_WALKS[walk][0])
+    lengths = lengths(ps, n * ps)
+    B = len(lengths)
     rng = np.random.default_rng(21)
-    lengths = [0, 1, ps + 3, 5 * ps, 4 * ps + ps // 2]
-    pool, tables, dense = _latent_pool(rng, lengths, K, ps, 6)
-    n = jnp.asarray(lengths, jnp.int32)
-    q = jnp.asarray(rng.normal(size=(len(lengths), H, K)), jnp.float32)
-    args = (q, pool, None, jnp.int32(1), tables, n)
+    pool, tables, dense = _latent_pool(rng, lengths, K, ps, n_pg)
+    live = _live_pages(tables, lengths, ps)
+    dead = np.setdiff1d(np.arange(pool.shape[1]), live)
+    pool = pool.at[:, dead].set(jnp.nan)
+    tables = jnp.where(tables == 0, jnp.int32(dead[-1]), tables)
+    lens = jnp.asarray(lengths, jnp.int32)
+    q = jnp.asarray(rng.normal(size=(B, H, K)), jnp.float32)
+    args = (q, pool, None, jnp.int32(1), tables, lens)
+    if group is not None:
+        slot = H * (K + latent) // 2 * 4
+        block = pa._decode_vmem_bytes(n, ps, K, 4, H, latent, latent=True)
+        monkeypatch.setattr(pa, "_DECODE_GROUP_BUDGET",
+                            block + 4 * group * slot)
+        (call,) = [e for e in jax.make_jaxpr(lambda *a: paged_attention(
+            *a, latent=latent))(*args).eqns
+            if e.primitive.name == "pallas_call"]
+        assert call.params["grid_mapping"].grid == (B // group,)
     with jax.default_matmul_precision("highest"):
-        got = paged_attention(*args, latent=latent, sm_scale=0.11)
-        oracle = reference_paged_attention(*args, latent=latent,
-                                           sm_scale=0.11)
-    assert got.shape == (len(lengths), H, latent)
-    want = _latent_dense(q[:, None], dense, np.asarray(lengths)[:, None] - 1,
-                         lengths, latent, 0.11)[:, 0]
-    np.testing.assert_allclose(np.asarray(got)[1:], want[1:], atol=2e-5)
-    np.testing.assert_allclose(np.asarray(oracle)[1:], want[1:], atol=2e-5)
-    assert not np.asarray(got)[0].any()
+        got = np.asarray(paged_attention(*args, latent=latent, sm_scale=0.11))
+        clean = pool.at[:, dead].set(0.0)
+        oracle = np.asarray(reference_paged_attention(
+            q, clean, *args[2:], latent=latent, sm_scale=0.11))
+    assert got.shape == (B, H, latent)
+    busy = np.asarray(lengths) > 0
+    assert np.isfinite(got).all() and not got[~busy].any()
+    if busy.any():
+        want = _latent_dense(q[:, None], dense,
+                             np.asarray(lengths)[:, None] - 1, lengths,
+                             latent, 0.11)[:, 0]
+        np.testing.assert_allclose(got[busy], want[busy], atol=2e-5)
+        np.testing.assert_allclose(oracle[busy], want[busy], atol=2e-5)
+
+
+def test_the_latent_block_rule_is_the_engines_too():
+    """`decode_block_pages` stays the ONE rule: at the kimi-k2.6 cell's
+    shapes the kernel's block and the block the engine's
+    `decode_block_fill` counter rounds a slot's pages up to are the same
+    8 pages (512 keys; the 512 KiB of one plane that bound it at 4 is
+    the other kinds' rule), at every width the engine hands a decode
+    call, and within the VMEM budget the rule states."""
+    import types
+
+    from ray_tpu.serve.llm import LLMEngine
+
+    assert pa._LATENT_BLOCK_KEYS == 512 and pa._LATENT_BUFFERS == 4
+    for width, n in ((1, 1), (4, 4), (8, 8), (16, 8), (64, 8), (72, 8)):
+        assert decode_block_pages(width, 64, 640, 2, 64, 512,
+                                  latent=True) == n
+    reckoned = pa._decode_vmem_bytes(8, 64, 640, 2, 64, 512, latent=True)
+    assert (4 * 8 * 64 * 640 * 2          # four buffers of a block
+            + 2 * 64 * 512 * 4            # the two score scratches
+            ) < reckoned <= pa._DECODE_VMEM_BUDGET
+    engine = types.SimpleNamespace(
+        _decode_block_at={}, page_size=64, tp=1, n_slots=4,
+        cfg=types.SimpleNamespace(n_heads=64, kv_lora_rank=512),
+        cache={"kv": jax.ShapeDtypeStruct((5, 9, 64, 640), jnp.bfloat16)},
+        positions=np.asarray([0, 511, 512, 3516]),
+        pool=types.SimpleNamespace(pages_for=lambda pos: pos // 64 + 1),
+        stats=dict.fromkeys(("decode_pages_live", "decode_pages_fetched",
+                             "decode_columns"), 0))
+    engine._kv_planes = lambda: LLMEngine._kv_planes(engine)
+    LLMEngine._count_decode_pages(engine, [0, 1, 2, 3], 72)
+    assert engine._decode_block_at == {72: 8}
+    assert engine.stats == {"decode_pages_live": 1 + 8 + 9 + 55,
+                            "decode_pages_fetched": 8 + 8 + 16 + 56,
+                            "decode_columns": 4 * 72}
 
 
 @pytest.mark.parametrize("H,K,latent,ps", LATENT)
@@ -1485,20 +1585,21 @@ def test_prefill_kernel_in_the_latent_form(H, K, latent, ps):
 def test_the_latent_form_reads_one_plane_once():
     """The decode call holds ONE pool operand and ONE set of buffers (no
     second DMA a page), the prefill call a page ref a column; both carry
-    names of their own in a trace; at the cell's shapes the block is 4
-    pages and a grid step takes 8 heads of a 128-token chunk."""
+    names of their own in a trace; at the cell's shapes the decode block
+    is 8 pages, the prefill block 4, and a grid step of the prefill call
+    takes 8 heads of a 128-token chunk."""
     bf16, i32 = jnp.bfloat16, jnp.int32
     pool = jnp.zeros((2, 9, 64, 640), bf16)
     tables, rows = jnp.zeros((4, 8), i32), jnp.zeros((4,), i32)
     decode = jax.make_jaxpr(lambda q, kv, t, n: paged_attention(
         q, kv, None, jnp.int32(1), t, n, latent=512, interpret=True))(
-        jnp.zeros((4, 64, 640), bf16), pool, tables, rows)
+        jnp.zeros((2, 64, 640), bf16), pool, tables[:2], rows[:2])
     (call,) = [e for e in decode.eqns if e.primitive.name == "pallas_call"]
     assert call.params["name"] == "paged_decode_attn_latent"
     shapes = [v.aval.shape for v in call.params["jaxpr"].invars]
     assert shapes.count(pool.shape) == 1
-    assert [s for s in shapes if len(s) == 3 and s[0] == pa._DECODE_BUFFERS
-            ] == [(pa._DECODE_BUFFERS, 4 * 64, 640)]
+    assert [s for s in shapes if len(s) == 3 and s[0] == pa._LATENT_BUFFERS
+            ] == [(pa._LATENT_BUFFERS, 8 * 64, 640)]
     prefill = jax.make_jaxpr(lambda q, kv, t, o, n: paged_prefill_attention(
         q, kv, None, jnp.int32(1), t, o, n, latent=512, interpret=True))(
         jnp.zeros((4, 128, 64, 640), bf16), pool, tables, rows, rows)
@@ -1506,12 +1607,13 @@ def test_the_latent_form_reads_one_plane_once():
     assert call.params["name"] == "paged_prefill_attn_latent"
     assert [v.aval.shape for v in call.invars].count(pool.shape) == 4
     assert pa.latent_prefill_shape(72, 64, 640, 2, 128, 64, 512) == (4, 8)
-    assert decode_block_pages(72, 64, 640, 2, 64, 512, True) == 4
+    assert decode_block_pages(72, 64, 640, 2, 64, 512, True) == 8
     assert prefill_block_pages(72, 64, 640, 2, 128, 64 * 640, 2, 64, 512,
                                True) == 4
-    # no V buffers: less fast memory than two planes of those widths
-    assert pa._decode_vmem_bytes(4, 64, 640, 2, 64, 512, True) < \
-        pa._decode_vmem_bytes(4, 64, 640, 2, 64, 512)
+    # no V buffers: four of the one plane take less fast memory than
+    # three of each of two planes of those widths
+    assert pa._decode_vmem_bytes(8, 64, 640, 2, 64, 512, True) < \
+        pa._decode_vmem_bytes(8, 64, 640, 2, 64, 512)
 
 
 @pytest.mark.parametrize("fault", ["a_v_pool", "a_row_of_576_lanes",
