@@ -43,6 +43,7 @@ Design notes:
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
 import logging
@@ -223,11 +224,12 @@ def _pow2_width(n: int) -> int:
     return width
 
 
-def _ring_pctls(ring) -> tuple[float, float]:
-    """(p50, p95) of a bounded sample ring, rounded for JSON metrics."""
+def _ring_pctls(ring, tail: float = 0.95) -> tuple[float, float]:
+    """(p50, the `tail` quantile) of a bounded sample ring, rounded for
+    JSON metrics."""
     s = sorted(ring)
     return (round(s[len(s) // 2], 3),
-            round(s[max(0, math.ceil(len(s) * 0.95) - 1)], 3))
+            round(s[max(0, math.ceil(len(s) * tail) - 1)], 3))
 
 
 # The engine tick's phases, in `_step`'s order (LLMEngine._phase). Flat:
@@ -269,6 +271,77 @@ class _InFlight:
     owed: dict = dataclasses.field(default_factory=dict)
 
 
+class _Heartbeat:
+    """The engine's second clock: a thread that sleeps `INTERVAL_S` at a
+    time and notes how LATE each wake-up was. A phase turn that is long
+    while this clock is on time is the engine waiting (for the device,
+    the runtime, a transfer); a turn that is long while this clock is as
+    late is the GIL held (a full collection, a C call that keeps it) or
+    the process, or the machine, standing still. It writes no
+    profiler annotation and no span: its stamps are `perf_counter`, the
+    clock of `GenRequest`'s stamps and of the tick's account. `clock` and
+    `sleep` are for tests. `run`/`beat` are the heartbeat thread's;
+    `reset`/`late_within`/`snapshot` anyone's."""
+
+    INTERVAL_S = 0.010
+    _KEPT = 64          # late wake-ups remembered for `late_within`
+
+    def __init__(self, clock=time.perf_counter, sleep=time.sleep):
+        self._clock, self._sleep = clock, sleep
+        self._lock = threading.Lock()
+        self._due: float | None = None   # when the sleep under way should end
+        self.reset()
+
+    def reset(self) -> None:
+        with self._lock:
+            self.late_max_s = 0.0
+            # Lateness beyond one interval, summed, and those wake-ups
+            # as (woke at, seconds late): under that a wake-up is the
+            # scheduler's own jitter or another thread's turn at the GIL.
+            self.late_s = 0.0
+            self._late: "collections.deque[tuple[float, float]]" = (
+                collections.deque(maxlen=self._KEPT))
+
+    def run(self, stop: threading.Event) -> None:
+        while not stop.is_set():
+            self.beat()
+
+    def beat(self) -> None:
+        due = self._clock() + self.INTERVAL_S
+        with self._lock:
+            self._due = due
+        self._sleep(self.INTERVAL_S)
+        now = self._clock()
+        late = now - due
+        with self._lock:
+            self._due = None
+            if late > self.late_max_s:
+                self.late_max_s = late
+            if late > self.INTERVAL_S:
+                self.late_s += late
+                self._late.append((now, late))
+
+    def late_within(self, t0: float, t1: float) -> float:
+        """The longest stretch of [t0, t1] over which this clock stood
+        still: the part of a late wake-up's lateness that lies inside it
+        (the sleep under way counts as far as it is overdue: the engine
+        thread may be back before this one). 0.0 where every wake-up in
+        the interval came within one interval of its time."""
+        with self._lock:
+            spans = [(woke - late, woke) for woke, late in self._late]
+            due = self._due
+        now = self._clock()
+        if due is not None and now - due > self.INTERVAL_S:
+            spans.append((due, now))
+        return max([0.0] + [min(b, t1) - max(a, t0) for a, b in spans])
+
+    def snapshot(self) -> dict:
+        """The `metrics()` keys this clock owns."""
+        with self._lock:
+            return {"heartbeat_late_ms_max": self.late_max_s * 1000.0,
+                    "heartbeat_late_s": self.late_s}
+
+
 class _TickAccount:
     """Where the engine thread's time went, over WHOLE ticks.
 
@@ -278,21 +351,47 @@ class _TickAccount:
     cover the same ticks and their difference is time the engine spent
     in no phase. Ticks that dispatched nothing (the idle loop) are
     dropped with their phases: the account describes a working engine.
+
+    Beside the sums, the extremes, folded with them: every phase's
+    longest single turn (`phase_max_s`), the longest tick (`tick_max_s`)
+    and a ring of tick lengths for `tick_ms_p50` / `_p99`, and the STALL
+    LOG: the `_STALLS` longest phase turns since the reset, longest
+    first, each with where it began on `perf_counter`, its tick's index
+    and how much of it `heartbeat` stood still (`_Heartbeat.late_within`,
+    asked once, as the turn enters the log). No threshold decides what
+    enters: a sound run's log is its eight longest pulls, a stalled
+    run's first entry is the stall.
     `begin`/`add` are the engine thread's; `reset`/`snapshot` anyone's."""
 
-    def __init__(self):
+    _STALLS = 8
+    _TICK_RING = 4096
+
+    def __init__(self, heartbeat: _Heartbeat):
         self._lock = threading.Lock()
+        self._heartbeat = heartbeat
         self._t0 = 0.0
+        self._open_tick()
+        self.reset()        # no tick is open yet: the first is not counted
+
+    def _open_tick(self) -> None:
         self._cur_s = dict.fromkeys(_PHASES, 0.0)
         self._cur_n = dict.fromkeys(_PHASES, 0)
-        self.reset()        # no tick is open yet: the first is not counted
+        self._cur_max = dict.fromkeys(_PHASES, 0.0)
+        # The open tick's turns longer than the log's shortest entry:
+        # (seconds, began at, phase).
+        self._cur_long: list[tuple[float, float, str]] = []
 
     def reset(self) -> None:
         with self._lock:
             self.phase_s = dict.fromkeys(_PHASES, 0.0)
             self.phase_n = dict.fromkeys(_PHASES, 0)
+            self.phase_max_s = dict.fromkeys(_PHASES, 0.0)
             self.ticks = self.decode_ticks = 0
-            self.tick_s = self.decode_tick_s = 0.0
+            self.tick_s = self.decode_tick_s = self.tick_max_s = 0.0
+            self._tick_ms: "collections.deque[float]" = collections.deque(
+                maxlen=self._TICK_RING)
+            self._stalls: list[dict] = []
+            self._stall_floor = 0.0     # what a turn must beat to enter
             # The open tick began before the reset: it is not counted.
             self._stale = True
 
@@ -302,23 +401,47 @@ class _TickAccount:
             decoded = cur_n["decode.dispatch"] + cur_n["spec_verify"]
             if not self._stale and (decoded or cur_n["prefill.dispatch"]):
                 dt = now - self._t0
+                self._log_stalls(self.ticks)
                 self.ticks += 1
                 self.tick_s += dt
+                self.tick_max_s = max(self.tick_max_s, dt)
+                self._tick_ms.append(dt * 1000.0)
                 if decoded:
                     self.decode_ticks += 1
                     self.decode_tick_s += dt
                 for name in _PHASES:
                     self.phase_s[name] += cur_s[name]
                     self.phase_n[name] += cur_n[name]
+                    if self._cur_max[name] > self.phase_max_s[name]:
+                        self.phase_max_s[name] = self._cur_max[name]
             self._stale = False
             self._t0 = now
-            self._cur_s = dict.fromkeys(_PHASES, 0.0)
-            self._cur_n = dict.fromkeys(_PHASES, 0)
+            self._open_tick()
 
-    def add(self, name: str, seconds: float) -> None:
+    def _log_stalls(self, tick: int) -> None:
+        """Fold the closing tick's long turns into the stall log."""
+        full = self._STALLS
+        for seconds, t0, name in sorted(self._cur_long, reverse=True)[:full]:
+            if len(self._stalls) >= full and seconds <= self._stall_floor:
+                break
+            late = self._heartbeat.late_within(t0, t0 + seconds)
+            self._stalls.append({"phase": name, "t_start": t0,
+                                 "ms": seconds * 1000.0, "tick": tick,
+                                 "late_ms": late * 1000.0})
+        self._stalls.sort(key=lambda e: -e["ms"])
+        del self._stalls[full:]
+        if len(self._stalls) == full:
+            self._stall_floor = self._stalls[-1]["ms"] / 1000.0
+
+    def add(self, name: str, t0: float, seconds: float) -> None:
+        """One turn of phase `name`, begun at `t0`."""
         with self._lock:
             self._cur_s[name] += seconds
             self._cur_n[name] += 1
+            if seconds > self._cur_max[name]:
+                self._cur_max[name] = seconds
+            if seconds > self._stall_floor:
+                self._cur_long.append((seconds, t0, name))
 
     def snapshot(self) -> dict:
         """The `metrics()` keys this account owns."""
@@ -326,8 +449,14 @@ class _TickAccount:
             phase_s, phase_n = dict(self.phase_s), dict(self.phase_n)
             tick_s, ticks = self.tick_s, self.ticks
             decode_tick_s, decode_ticks = self.decode_tick_s, self.decode_ticks
-        out = {"ticks": ticks, "tick_s": tick_s,
-               "phase_s": phase_s, "phase_n": phase_n}
+            tick_ms = list(self._tick_ms)
+            out = {"phase_max_s": dict(self.phase_max_s),
+                   "tick_ms_max": round(self.tick_max_s * 1000.0, 3),
+                   "stalls": [dict(e) for e in self._stalls]}
+        out.update(ticks=ticks, tick_s=tick_s, phase_s=phase_s,
+                   phase_n=phase_n)
+        out["tick_ms_p50"], out["tick_ms_p99"] = (
+            _ring_pctls(tick_ms, 0.99) if tick_ms else (0.0, 0.0))
         if decode_ticks:
             out["tick_ms_mean"] = decode_tick_s / decode_ticks * 1000.0
         if tick_s > 0:
@@ -424,6 +553,14 @@ class GenRequest:
     # dispatch; chunked prefill spreads them across scheduler ticks.
     first_chunk_at: float | None = None
     last_chunk_at: float | None = None
+    # What the caller felt between tokens (LLMEngine._hand_over, once a
+    # request a decode window, never once a token): when tokens were last
+    # handed to it, the longest interval between two hand-overs since its
+    # first token (a stall, a window it sat out, an admission's chunk
+    # programs, a preemption), and the decode windows it lived through.
+    last_emit_at: float | None = None
+    max_gap_s: float = 0.0
+    windows: int = 0
     # Admission aging: how many _admit rounds bypassed this request while
     # it sat page-blocked at the queue head. Past _ADMIT_BYPASS_LIMIT the
     # head blocks all lookahead until it admits (starvation guard).
@@ -773,8 +910,6 @@ class LLMEngine:
         # Engine-thread-local FIFO drained BEFORE `pending`: requests that
         # failed page back-pressure or were preempted keep their place at
         # the head instead of rotating to the tail (starvation guard).
-        import collections
-
         self._deferred: "collections.deque[GenRequest]" = collections.deque()
         # Chunked-prefill scheduler state: slots whose prompt is still
         # entering the pool (admission order = service order, FCFS), and
@@ -798,11 +933,9 @@ class LLMEngine:
         # step latency for the measured window.
         self._step_ms: "collections.deque[float]" = collections.deque(
             maxlen=4096)
-        # Engine-side TTFT ring (submit → first token, ms) and the
-        # prefill-interference ring: per-token decode latency measured
-        # window-END to window-END across ticks that also ran prefill, so
-        # the admission stall between windows IS included — the number the
-        # token budget bounds.
+        # Engine-side TTFT ring (submit → first token, ms), and the ring
+        # of finished requests' longest waits between two hand-overs of
+        # tokens (GenRequest.max_gap_s, ms).
         self._ttft_ms: "collections.deque[float]" = collections.deque(
             maxlen=4096)
         # Warm/cold TTFT split (prefix cache): warm = admission bound a
@@ -811,9 +944,8 @@ class LLMEngine:
             maxlen=4096)
         self._ttft_cold_ms: "collections.deque[float]" = collections.deque(
             maxlen=4096)
-        self._burst_step_ms: "collections.deque[float]" = collections.deque(
+        self._emit_gap_ms: "collections.deque[float]" = collections.deque(
             maxlen=4096)
-        self._last_window_end: float | None = None
         # Load EWMAs (flight recorder): smoothed TTFT / decode-rate /
         # prefill-budget-utilization signals for load_snapshot() — what
         # the least-loaded router and autoscaler consume. Updated under
@@ -823,9 +955,12 @@ class LLMEngine:
         self._budget_util_ewma: float | None = None
         self._ttft_seq = 0                    # sampled TTFT-breakdown spans
         self._step_tags: dict | None = None   # lazy: replica id + impl
-        # The tick's account of itself (see _phase) and the sequence
-        # numbers of the two phases that are also sampled operator spans.
-        self._ticks = _TickAccount()
+        # The tick's account of itself (see _phase), the second clock it
+        # reads a stall's kind from (its thread runs from start() to
+        # stop()), and the sequence numbers of the two phases that are
+        # also sampled operator spans.
+        self._heartbeat = _Heartbeat()
+        self._ticks = _TickAccount(self._heartbeat)
         self._span_seq = {"decode_window": 0, "spec_verify": 0}
         self._annotate = jax.profiler.TraceAnnotation
         # High-water mark of requests still owed a first token, kept
@@ -843,6 +978,7 @@ class LLMEngine:
         # slot — the quiescence verdict is only stable between ticks.
         self._mid_tick = False
         self._thread: threading.Thread | None = None
+        self._heartbeat_thread: threading.Thread | None = None
         self._lock = threading.Lock()
         # Serializes start()/stop(): two concurrent start() calls would
         # both see _thread is None and spawn two engine loops. Separate
@@ -1255,7 +1391,11 @@ class LLMEngine:
                     self.warmup_compile()
                 self._thread = threading.Thread(
                     target=self._loop, daemon=True, name="llm-engine")
+                self._heartbeat_thread = threading.Thread(
+                    target=self._heartbeat.run, args=(self._shutdown,),
+                    daemon=True, name="llm-heartbeat")
                 self._thread.start()
+                self._heartbeat_thread.start()
 
     def stop(self) -> None:
         """Stop the engine thread. A step it left in flight is absorbed
@@ -1267,6 +1407,8 @@ class LLMEngine:
                 self._thread.join(timeout=30)
                 joined = not self._thread.is_alive()
                 self._thread = None
+                self._heartbeat_thread.join(timeout=1)
+                self._heartbeat_thread = None
                 if joined:
                     self._absorb_carry()
 
@@ -1403,8 +1545,7 @@ class LLMEngine:
             self._ttft_ms.clear()
             self._ttft_warm_ms.clear()
             self._ttft_cold_ms.clear()
-            self._burst_step_ms.clear()
-            self._last_window_end = None
+            self._emit_gap_ms.clear()
             self._ttft_ewma_ms = None
             self._decode_ewma_tok_s = None
             self._budget_util_ewma = None
@@ -1412,6 +1553,17 @@ class LLMEngine:
             if self.pool is not None:
                 self.pool.rebase_low_water()
             self._awaiting_max = self._awaiting_first_token()
+            # A live request's longest wait starts over too: what it waited
+            # before the reset is not the window's (copies: `_deferred` and
+            # the carry are the engine thread's).
+            now = time.perf_counter()
+            carry = self._carry
+            for req in (*self.slot_req, *list(self._deferred),
+                        *(list(carry.owed.values()) if carry is not None
+                          else ())):
+                if req is not None and req.last_emit_at is not None:
+                    req.max_gap_s, req.last_emit_at = 0.0, now
+        self._heartbeat.reset()
         self._ticks.reset()
 
     _SPAN_SAMPLE = 64
@@ -1452,7 +1604,7 @@ class LLMEngine:
                 with self._annotate("llm." + name):
                     yield
             finally:
-                self._ticks.add(name, time.perf_counter() - t0)
+                self._ticks.add(name, t0, time.perf_counter() - t0)
 
     def _awaiting_first_token(self) -> int:
         """Requests owed a first token: queued, deferred, and bound to a
@@ -1473,28 +1625,17 @@ class LLMEngine:
                 "replica": _request_metric_tags()["replica"], "impl": impl}
         return self._step_tags
 
-    def _observe_window(self, t0: float, end: float, k: int, n_active: int,
-                        tick_prefill: bool) -> None:
-        """Per-decode-window accounting for the NON-speculative window:
-        every slot advances exactly k tokens, so tokens-per-slot = k,
-        emitted = k × n_active, and the cap is k per slot."""
-        self._observe_decode(t0, end, float(k), k * n_active,
-                             k * self.n_slots, tick_prefill)
-
     def _observe_decode(self, t0: float, end: float, per_slot: float,
-                        emitted: int, cap: int,
-                        tick_prefill: bool) -> None:
+                        emitted: int, cap: int) -> None:
         """Shared decode-tick accounting (non-speculative window AND
         speculative propose/verify tick — one implementation so the
         bookkeeping can't diverge across the spec knob): engine stats,
         the bounded per-slot-token step-time ring behind metrics()'s
         p50/p95 (tick wall / tokens each slot advanced — the
-        roofline-facing ms-per-weight-pass-per-token number), the
+        roofline-facing ms-per-weight-pass-per-token number) and the
         step-latency histogram that makes kernel-vs-gather runs
-        distinguishable at /metrics — and, for ticks that also ran
-        prefill, the window-end-to-window-end interference ring (the
-        decode stall the prefill token budget bounds). `cap` is the
-        tick's max emittable tokens (slot_occupancy's denominator)."""
+        distinguishable at /metrics. `cap` is the tick's max emittable
+        tokens (slot_occupancy's denominator)."""
         dt = end - t0
         tags = self._impl_tags()
         with self._lock:
@@ -1506,11 +1647,6 @@ class LLMEngine:
             if dt > 0:
                 self._decode_ewma_tok_s = self._ewma(
                     self._decode_ewma_tok_s, emitted / dt)
-            if tick_prefill and self._last_window_end is not None:
-                self._burst_step_ms.append(
-                    (end - self._last_window_end) / max(1.0, per_slot)
-                    * 1000.0)
-            self._last_window_end = end
         _DECODE_STEP_HIST.observe(dt / max(1.0, per_slot), tags=tags)
 
     def metrics(self) -> dict:
@@ -1670,14 +1806,14 @@ class LLMEngine:
             if self._ttft_ms:
                 m["ttft_ms_p50"], m["ttft_ms_p95"] = _ring_pctls(
                     self._ttft_ms)
-            if self._burst_step_ms:
-                # Prefill interference: per-token decode latency across
-                # ticks that also ran prefill (stall between windows
-                # included) — what the chunked scheduler bounds.
-                (m["decode_step_burst_ms_p50"],
-                 m["decode_step_burst_ms_p95"]) = _ring_pctls(
-                    self._burst_step_ms)
+            # The longest wait between two hand-overs of tokens, over
+            # the requests finished since the reset (0.0: none has).
+            gaps = list(self._emit_gap_ms)
+        m["emit_gap_ms_p50"], m["emit_gap_ms_p99"] = (
+            _ring_pctls(gaps, 0.99) if gaps else (0.0, 0.0))
+        m["emit_gap_ms_max"] = round(max(gaps, default=0.0), 3)
         m.update(self._ticks.snapshot())
+        m.update(self._heartbeat.snapshot())
         if m["completed"]:
             m["ttft_mean_s"] = m["ttft_sum"] / m["completed"]
         # Engine-side rates: what the chip sustains, independent of the
@@ -2216,10 +2352,11 @@ class LLMEngine:
                 args=tracing.span_event_args(root.child()))
 
     def _emit(self, req: GenRequest, token: int) -> bool:
-        """Append a token; → True if the request just finished."""
-        now = time.perf_counter()
+        """Append a token; → True if the request just finished. The clock
+        is read for the request's two stamps only (its first token, its
+        end): two reads a request, not one a token."""
         if req.first_token_at is None:
-            req.first_token_at = now
+            now = req.first_token_at = time.perf_counter()
             self.stats["ttft_sum"] += now - req.submitted_at
             # Under the lock: metrics() sorts this ring concurrently.
             with self._lock:
@@ -2237,12 +2374,30 @@ class LLMEngine:
         finished = (len(req.out_ids) >= req.max_tokens
                     or (req.eos_id is not None and token == req.eos_id))
         if finished:
-            req.finished_at = now
+            req.finished_at = time.perf_counter()
             self.stats["completed"] += 1
+            if req.windows:
+                # Under the lock: metrics() copies this ring concurrently.
+                with self._lock:
+                    self._emit_gap_ms.append(req.max_gap_s * 1000.0)
             if req.stream is not None:
                 req.stream.put(None)  # stream sentinel
             req.done.set()
         return finished
+
+    def _hand_over(self, req: GenRequest, now: float, window: int = 1) -> None:
+        """A decode window's tokens (`window=0`: a prefill's one) are being
+        handed to `req`, at `now`: ONCE a request a window, ahead of its
+        `_emit`s, however many tokens those are. Keeps the request's
+        longest wait between two hand-overs; `_emit` puts it into the
+        ring behind metrics()'s `emit_gap_ms_*` when the request ends."""
+        last = req.last_emit_at
+        req.last_emit_at = now
+        req.windows += window
+        if last is not None:
+            gap = now - last
+            if gap > req.max_gap_s:
+                req.max_gap_s = gap
 
     def _sample(self, logits_row, temperature: float) -> int:
         rt = self._rt
@@ -2556,6 +2711,7 @@ class LLMEngine:
                 self.tokens[slot] = tok
                 self.positions[slot] = int(lengths[i])
                 self.temps[slot] = req.temperature
+                self._hand_over(req, now, 0)
                 if self._emit(req, tok):
                     self._release(slot)
 
@@ -2913,6 +3069,7 @@ class LLMEngine:
                 self.tokens[slot] = tok
                 self.positions[slot] = len(req.prompt_ids)
                 self.temps[slot] = req.temperature
+                self._hand_over(req, now, 0)
                 if self._emit(req, tok):
                     self._release(slot)
                 elif self.pool_role == "prefill":
@@ -3215,8 +3372,7 @@ class LLMEngine:
             active = self._shed_for_pages(active)
         return []
 
-    def _spec_decode_window(self, active: list[int],
-                            tick_prefill: bool) -> int:
+    def _spec_decode_window(self, active: list[int]) -> int:
         """One speculative tick for every decode-ready slot: the draft
         proposes up to spec_k tokens per slot in ONE fused on-device
         loop (models/paged_kv.spec_draft_propose — k+1 draft steps, no
@@ -3229,7 +3385,6 @@ class LLMEngine:
         with self._phase("plan"):
             planned = self._plan_spec_window(active)
         if planned is None:
-            self._last_window_end = None
             return 0
         active, k_map, table_view, n_prop = planned
         rt = self._rt
@@ -3277,8 +3432,7 @@ class LLMEngine:
                 logits = None                              # [B, k+1]
         with self._phase("emit"):
             return self._accept_spec_window(
-                active, k_map, proposals, draft_probs, logits, argmax, t0,
-                tick_prefill)
+                active, k_map, proposals, draft_probs, logits, argmax, t0)
 
     def _plan_spec_window(self, active: list[int]):
         """Host-side plan of a speculative tick. → (surviving active
@@ -3319,14 +3473,14 @@ class LLMEngine:
         return active, k_map, self._decode_table_view(active), n_prop
 
     def _accept_spec_window(self, active: list[int], k_map: dict, proposals,
-                            draft_probs, logits, argmax, t0: float,
-                            tick_prefill: bool) -> int:
+                            draft_probs, logits, argmax, t0: float) -> int:
         """Rejection-sample each slot's proposals against the verify
         pass, emit, roll the rejected tails' pages back, and book the
         tick. → slots that did decode work."""
         k = self.spec_k
         proposed = accepted = emitted_total = 0
         survivors = []
+        handed_at = time.perf_counter()
         for slot in active:
             req = self.slot_req[slot]
             ki = k_map[slot]
@@ -3339,6 +3493,7 @@ class LLMEngine:
             t = int(self.positions[slot])
             e = 0
             finished = False
+            self._hand_over(req, handed_at)
             for tok in emitted:
                 e += 1
                 if self._emit(req, tok):
@@ -3374,8 +3529,7 @@ class LLMEngine:
         # slots at the full k+1 like the non-spec window counts them.
         cap = (sum(k_map[s] + 1 for s in active)
                + (self.n_slots - len(active)) * (k + 1))
-        self._observe_decode(t0, end, per_slot, emitted_total, cap,
-                             tick_prefill)
+        self._observe_decode(t0, end, per_slot, emitted_total, cap)
         tags = self._impl_tags()
         with self._lock:
             self.stats["spec_ticks"] += 1
@@ -3419,7 +3573,6 @@ class LLMEngine:
     def _step(self) -> int:
         rt = self._rt
         jnp = rt.jnp
-        pt0 = self.stats["prefill_tokens"]
         # A tick is the interval from one `admit` to the next.
         self._ticks.begin(time.perf_counter())
         with self._phase("admit"):
@@ -3443,12 +3596,9 @@ class LLMEngine:
             # per tick, emitting 1..k+1 tokens per slot.
             active = self._decode_ready_slots()
             if not active:
-                # graftlint: disable=GUARDED-BY (engine-thread state: _step runs only on the engine loop thread; the locked writes elsewhere are reader-side snapshots, and a plain store is torn-read-free)
-                self._last_window_end = None
                 return n_prefilling
             _chaos.hit("llm.decode_window")
-            return self._spec_decode_window(
-                active, self.stats["prefill_tokens"] > pt0) + n_prefilling
+            return self._spec_decode_window(active) + n_prefilling
         if self._carry is not None and not self._decode_ready_slots():
             # Nothing decodes, yet a step is in flight: for requests that
             # left their slots ahead of their last token (`_InFlight.owed`).
@@ -3476,10 +3626,7 @@ class LLMEngine:
                         self._count_decode_pages(active,
                                                  table_view.shape[1])
             if not active:
-                # graftlint: disable=GUARDED-BY (engine-thread state: _step runs only on the engine loop thread; the locked writes elsewhere are reader-side snapshots, and a plain store is torn-read-free)
-                self._last_window_end = None
                 return n_prefilling
-            tick_prefill = self.stats["prefill_tokens"] > pt0
             # The steps this window dispatches: all its rows, or all but
             # the first where a step of the last window is in flight.
             n_new = k - (carry is not None)
@@ -3547,14 +3694,15 @@ class LLMEngine:
                 # left in flight is this tick's; the one absorbed was
                 # the last's), over every slot's: slot_occupancy.
                 steps = n_new + (self._carry is not None)
+                handed_at = time.perf_counter()
                 self._observe_decode(
-                    t0, time.perf_counter(), float(k), steps * len(active),
-                    steps * self.n_slots, tick_prefill)
+                    t0, handed_at, float(k), steps * len(active),
+                    steps * self.n_slots)
                 if self.kv_mode == "paged":
                     self.stats["lookahead_windows" if stood_down is None else
                                "lookahead_stood_down_" + stood_down] += 1
                 if carry is not None:
-                    self._pay_owed(carry, toks_out[0])
+                    self._pay_owed(carry, toks_out[0], handed_at)
                 for slot in active:
                     req = self.slot_req[slot]
                     # A slot the absorbed step did not cover (it joined
@@ -3562,6 +3710,7 @@ class LLMEngine:
                     rows = toks_out[int(carry is not None
                                         and not carry.mask[slot]):, slot]
                     finished = False
+                    self._hand_over(req, handed_at)
                     for tok in rows:
                         if self._emit(req, int(tok)):
                             finished = True
@@ -3594,8 +3743,9 @@ class LLMEngine:
             with self._phase("decode.pull"):
                 logits = np.asarray(logits)
         with self._phase("emit"):
-            self._observe_window(t0, time.perf_counter(), 1, len(active),
-                                 tick_prefill)
+            handed_at = time.perf_counter()
+            self._observe_decode(t0, handed_at, 1.0, len(active),
+                                 self.n_slots)
             if self.kv_mode == "paged":
                 self.stats["lookahead_stood_down_" + stood_down] += 1
             for slot in active:
@@ -3606,6 +3756,7 @@ class LLMEngine:
                 tok = self._sample(logits[slot], req.temperature)
                 self.tokens[slot] = tok
                 self.positions[slot] += 1
+                self._hand_over(req, handed_at)
                 if self._emit(req, tok):
                     self._release(slot)
         return len(active) + n_prefilling
@@ -3632,10 +3783,11 @@ class LLMEngine:
         mask[active] = True
         self._carry = _InFlight(tokens, key, mask)
 
-    def _pay_owed(self, carry: _InFlight, row) -> None:
+    def _pay_owed(self, carry: _InFlight, row, now: float) -> None:
         """Hand the requests that left their slots ahead of their last
         token (`_InFlight.owed`) that token, from the step's `row` [B]."""
         for slot, req in carry.owed.items():
+            self._hand_over(req, now)
             self._emit(req, int(row[slot]))
 
     def _absorb_carry(self, phase=lambda _name: contextlib.nullcontext()) -> None:
@@ -3655,9 +3807,12 @@ class LLMEngine:
         with phase("decode.pull"):
             toks = np.asarray(carry.tokens)
         with phase("emit"):
-            self._pay_owed(carry, toks)
+            now = time.perf_counter()
+            self._pay_owed(carry, toks, now)
             for slot in np.flatnonzero(carry.mask):
-                if self._emit(self.slot_req[slot], int(toks[slot])):
+                req = self.slot_req[slot]
+                self._hand_over(req, now)
+                if self._emit(req, int(toks[slot])):
                     self._release(slot)
                 else:
                     self.tokens[slot] = toks[slot]

@@ -138,7 +138,8 @@ def test_every_engine_metric_key_is_a_key_of_metrics(served):
     assert all(isinstance(have[k], (int, float)) for k in keys)
     # What the harness reads of metrics() outside a layer metric.
     for key in ("queued", "completed", "preemptions", "phase_s", "phase_n",
-                "ticks", "tick_s"):
+                "ticks", "tick_s", "stalls", "tick_ms_max",
+                "heartbeat_late_ms_max", "emit_gap_ms_p99"):
         assert key in have, key
 
 

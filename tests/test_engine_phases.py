@@ -16,6 +16,7 @@ window.
 import contextlib
 import glob
 import os
+import threading
 import time
 
 import numpy as np
@@ -259,3 +260,348 @@ def test_profiler_trace_holds_the_phases(params, tmp_path):
     assert traced["llm.decode.pull"] == 2
     assert traced["llm.decode.dispatch"] == 16
     assert "llm.tick" not in traced
+
+
+# ------------------------------------------------------------------------
+# The account's extremes, the second clock and the request's longest wait
+# (a stalled run names itself).
+
+_STALL_KEYS = {"phase", "t_start", "ms", "tick", "late_ms"}
+_NEW_KEYS = ("phase_max_s", "tick_ms_p50", "tick_ms_p99", "tick_ms_max",
+             "stalls", "heartbeat_late_ms_max", "heartbeat_late_s",
+             "emit_gap_ms_p50", "emit_gap_ms_p99", "emit_gap_ms_max")
+
+
+def _stall_in_admit(eng, seconds, at_call):
+    """Make the `admit` phase's body sleep `seconds`, its `at_call`-th
+    turn from now only."""
+    inner, calls = eng._admit, [0]
+
+    def admit():
+        calls[0] += 1
+        if calls[0] == at_call:
+            time.sleep(seconds)
+        return inner()
+
+    eng._admit = admit
+
+
+@pytest.mark.parametrize("mode", sorted(ENGINES))
+def test_a_stalled_turn_heads_the_stall_log(params, draft_params, mode):
+    eng = _engine(params, draft_params, **ENGINES[mode])
+    # Compile every program first: a compile is a long turn too.
+    _drive(eng, [eng.submit(p, max_tokens=24)
+                 for p in _prompts(0, (40, 9, 23, 31, 5))])
+    eng.reset_stats()
+    eng.step()
+    _stall_in_admit(eng, 0.3, at_call=4)
+    t_before = time.perf_counter()
+    _drive(eng, [eng.submit(p, max_tokens=24)
+                 for p in _prompts(1, (40, 9, 23, 31, 5))])
+    eng.step()                  # closes the last working tick
+    m = eng.metrics()
+    first = m["stalls"][0]
+    assert set(first) == _STALL_KEYS
+    assert first["phase"] == "admit"
+    assert 300.0 <= first["ms"] <= 360.0
+    assert t_before <= first["t_start"] <= time.perf_counter() - 0.3
+    assert first["tick"] == 3 and first["late_ms"] == 0.0   # no thread runs
+    assert m["phase_max_s"]["admit"] * 1000.0 == first["ms"]
+    assert first["ms"] - 1e-3 <= m["tick_ms_max"] <= first["ms"] + 200.0
+    # The sums hide it: the mean moved by the stall over the tick count,
+    # and the median not at all.
+    assert m["ticks"] >= 8
+    rest = (m["tick_s"] * 1000.0 - m["tick_ms_max"]) / (m["ticks"] - 1)
+    assert m["tick_ms_p50"] <= 2.0 * rest + 1.0
+    assert m["tick_s"] * 1000.0 / m["ticks"] - rest <= (
+        m["tick_ms_max"] / m["ticks"])
+    assert m["tick_ms_mean"] < m["tick_ms_max"] / 2.0
+    assert m["tick_ms_p50"] <= m["tick_ms_p99"] <= m["tick_ms_max"]
+
+
+@pytest.mark.parametrize("mode", sorted(ENGINES))
+def test_every_path_fills_the_same_keys(params, draft_params, mode):
+    eng = _engine(params, draft_params, **ENGINES[mode])
+    m = eng.metrics()           # before anything happened: present, zero
+    for key in _NEW_KEYS:
+        assert key in m, key
+    assert m["stalls"] == [] and not any(m["phase_max_s"].values())
+    assert all(m[k] == 0.0 for k in _NEW_KEYS
+               if k not in ("stalls", "phase_max_s"))
+    reqs = [eng.submit(p, max_tokens=12) for p in _prompts(0, (40, 9, 23))]
+    _drive(eng, reqs)
+    eng.step()
+    m = eng.metrics()
+    assert set(m["phase_max_s"]) == set(llm._PHASES)
+    assert 1 <= len(m["stalls"]) <= 8
+    assert all(set(e) == _STALL_KEYS for e in m["stalls"])
+    assert all(e["phase"] in llm._PHASES for e in m["stalls"])
+    for name, longest in m["phase_max_s"].items():
+        assert (longest > 0) == (m["phase_n"][name] > 0), name
+        assert longest <= m["phase_s"][name] + 1e-12
+    assert 0 < m["tick_ms_p50"] <= m["tick_ms_p99"] <= m["tick_ms_max"]
+    # Every request was handed tokens over more than one window.
+    for req in reqs:
+        assert req.windows >= 2 and req.max_gap_s > 0
+        assert req.first_token_at < req.last_emit_at <= req.finished_at
+    gaps = sorted(r.max_gap_s * 1000.0 for r in reqs)
+    assert m["emit_gap_ms_max"] == pytest.approx(gaps[-1], abs=1e-3)
+    assert m["emit_gap_ms_p99"] == pytest.approx(gaps[-1], abs=1e-3)
+    assert m["emit_gap_ms_p50"] == pytest.approx(gaps[1], abs=1e-3)
+
+
+def test_the_stall_log_holds_the_eight_longest_sorted(params):
+    eng = _engine(params, **ENGINES["paged-chunked"])
+    _drive(eng, [eng.submit(p, max_tokens=12)
+                 for p in _prompts(0, (40, 9, 23))])
+    eng.reset_stats()
+    eng.step()
+    seen = _record_phases(eng)
+    _drive(eng, [eng.submit(p, max_tokens=30)
+                 for p in _prompts(1, (40, 9, 23, 31, 5))])
+    t_closed = time.perf_counter()
+    eng.step()
+    stalls = eng.metrics()["stalls"]
+    assert len(stalls) == 8
+    assert [e["ms"] for e in stalls] == sorted(
+        (e["ms"] for e in stalls), reverse=True)
+    # They ARE the eight longest turns of the counted ticks (the
+    # recorder's own clock reads a little more than the account's).
+    turns = sorted((e - s for n, s, e in seen
+                    if n != "decode_window" and e <= t_closed),
+                   reverse=True)
+    assert len(turns) > 40
+    assert stalls[0]["ms"] <= turns[0] * 1000.0
+    assert stalls[7]["ms"] >= turns[11] * 1000.0
+    assert stalls[7]["ms"] <= turns[7] * 1000.0
+    assert len({(e["t_start"], e["phase"]) for e in stalls}) == 8
+
+
+def test_reset_stats_zeroes_the_extremes(params):
+    eng = _engine(params, **ENGINES["paged-chunked"])
+    reqs = [eng.submit(p, max_tokens=6) for p in _prompts(3, (20, 9))]
+    eng._heartbeat._sleep = lambda s: time.sleep(s + 0.03)   # always late
+    eng._heartbeat.beat()
+    _drive(eng, reqs)
+    eng.step()
+    m = eng.metrics()
+    assert m["stalls"] and m["tick_ms_max"] > 0 and m["tick_ms_p99"] > 0
+    assert m["phase_max_s"]["decode.pull"] > 0
+    assert m["heartbeat_late_ms_max"] >= 30 and m["heartbeat_late_s"] >= 0.03
+    assert m["emit_gap_ms_max"] > 0 and m["emit_gap_ms_p99"] > 0
+    eng.reset_stats()
+    m = eng.metrics()
+    assert m["stalls"] == [] and not any(m["phase_max_s"].values())
+    assert set(m["phase_max_s"]) == set(llm._PHASES)
+    for key in _NEW_KEYS:
+        if key not in ("stalls", "phase_max_s"):
+            assert m[key] == 0.0, key
+    assert eng._heartbeat.late_within(0.0, time.perf_counter()) == 0.0
+    # The tick that was open across the reset is not counted, and its
+    # long turn enters no log.
+    reqs = [eng.submit(p, max_tokens=6) for p in _prompts(3, (20, 9))]
+    _stall_in_admit(eng, 0.25, at_call=1)
+    eng.step()                  # a working tick opens, and stalls
+    eng.reset_stats()
+    eng.step()                  # ... and closes, uncounted
+    m = eng.metrics()
+    assert m["ticks"] == 0 and m["stalls"] == [] and m["tick_ms_max"] == 0.0
+    assert not any(m["phase_max_s"].values())
+    _drive(eng, reqs)
+    eng.step()
+    m = eng.metrics()
+    assert m["ticks"] > 0 and m["stalls"]
+    assert m["stalls"][0]["ms"] < 250.0 and m["phase_max_s"]["admit"] < 0.25
+    assert m["tick_ms_max"] < 250.0
+
+
+def test_reset_stats_starts_a_live_requests_wait_over(params):
+    """What a request in a slot waited BEFORE the reset (a ramp's stall)
+    is not the window's: its longest wait starts over with the account."""
+    eng = _engine(params, kv_mode="dense", decode_block=4)
+    _drive(eng, [eng.submit(p, max_tokens=21)
+                 for p in _prompts(20, (9, 9))])          # compiles
+    reqs = [eng.submit(p, max_tokens=21) for p in _prompts(21, (9, 9))]
+    while any(r.windows < 2 for r in reqs):
+        eng.step()
+    time.sleep(0.25)                            # a stall before the window
+    eng.step()
+    assert all(r.max_gap_s >= 0.25 and not r.done.is_set() for r in reqs)
+    t_reset = time.perf_counter()
+    eng.reset_stats()
+    assert all(r.max_gap_s == 0.0 and r.last_emit_at >= t_reset
+               for r in reqs)
+    waiting = eng.submit(_prompts(22, (9,))[0], max_tokens=5)
+    assert waiting.last_emit_at is None         # no token yet: no wait yet
+    _drive(eng, reqs + [waiting])
+    m = eng.metrics()
+    assert 0.0 < m["emit_gap_ms_max"] < 250.0
+    assert m["emit_gap_ms_max"] == pytest.approx(
+        max(r.max_gap_s for r in reqs + [waiting]) * 1e3, abs=1e-3)
+
+
+def test_idle_ticks_enter_no_extreme(params):
+    eng = _engine(params, **ENGINES["paged-chunked"])
+    _stall_in_admit(eng, 0.05, at_call=2)
+    for _ in range(5):
+        eng.step()
+    m = eng.metrics()
+    assert m["ticks"] == 0 and m["stalls"] == []
+    assert not any(m["phase_max_s"].values())
+    assert m["tick_ms_max"] == m["tick_ms_p50"] == m["tick_ms_p99"] == 0.0
+
+
+class _FakeTime:
+    """A clock and a sleep for `_Heartbeat`: `sleep` moves the clock by
+    what was asked and by the next of `oversleeps`."""
+
+    def __init__(self, oversleeps):
+        self.now = 100.0
+        self.oversleeps = list(oversleeps)
+
+    def clock(self):
+        return self.now
+
+    def sleep(self, seconds):
+        self.now += seconds + (self.oversleeps.pop(0) if self.oversleeps
+                               else 0.0)
+
+
+def test_heartbeat_tells_a_process_stall_from_an_engine_wait():
+    fake = _FakeTime([0.0, 0.001, 0.250, 0.0, 0.004])
+    hb = llm._Heartbeat(clock=fake.clock, sleep=fake.sleep)
+    acct = llm._TickAccount(hb)
+    acct.begin(fake.now)
+    acct.begin(fake.now)        # the first tick is never counted
+    t_tick = fake.now
+    hb.beat()
+    hb.beat()
+    t_before = fake.now         # 100.021
+    hb.beat()                   # due at 100.031, woke at 100.281
+    hb.beat()
+    hb.beat()
+    snap = hb.snapshot()
+    assert snap["heartbeat_late_ms_max"] == pytest.approx(250.0)
+    assert snap["heartbeat_late_s"] == pytest.approx(0.250)   # not the 1, 4 ms
+    # A turn that spans the late wake-up has all of it, one that does
+    # not has none, one that ends inside it has its part.
+    assert hb.late_within(t_before, t_before + 0.3) == pytest.approx(0.250)
+    assert hb.late_within(t_tick, t_before) == 0.0
+    assert hb.late_within(t_before + 0.27, fake.now) == 0.0
+    assert hb.late_within(t_before, t_before + 0.110) == pytest.approx(0.100)
+    # ... and the account asks as a turn enters its log.
+    acct.add("decode.dispatch", t_tick, 0.020)
+    acct.add("decode.pull", t_before, 0.300)
+    acct.add("emit", t_before + 0.300, 0.005)
+    acct.begin(fake.now)
+    pull, dispatch, emit = acct.snapshot()["stalls"]
+    assert (pull["phase"], dispatch["phase"], emit["phase"]) == (
+        "decode.pull", "decode.dispatch", "emit")
+    assert pull["late_ms"] == pytest.approx(250.0)
+    assert pull["ms"] == pytest.approx(300.0) and pull["tick"] == 0
+    assert dispatch["late_ms"] == 0.0 and emit["late_ms"] == 0.0
+    assert pull["t_start"] == t_before
+    # The sleep under way counts as far as it is overdue: the engine's
+    # thread may be back before the heartbeat's.
+    fake.oversleeps = [0.5]
+    inner = fake.sleep
+    asked = []
+
+    def sleep(seconds):
+        inner(seconds)
+        asked.append(hb.late_within(fake.now - 0.51, fake.now))
+
+    hb._sleep = sleep
+    hb.beat()
+    assert asked == [pytest.approx(0.5)]
+    hb.reset()
+    assert hb.snapshot() == {"heartbeat_late_ms_max": 0.0,
+                             "heartbeat_late_s": 0.0}
+    assert hb.late_within(0.0, fake.now) == 0.0
+
+
+def test_heartbeat_thread_runs_from_start_to_stop(params):
+    beating = lambda: [t for t in threading.enumerate()
+                       if t.name == "llm-heartbeat" and t.is_alive()]
+    before = len(beating())
+    eng = _engine(params, **ENGINES["paged-oneshot"], warmup=False)
+    assert len(beating()) == before             # not before start()
+    eng.start()
+    try:
+        thread = eng._heartbeat_thread
+        assert thread.daemon and thread.is_alive()
+        assert len(beating()) == before + 1
+        eng.start()                             # no second one
+        assert eng._heartbeat_thread is thread
+        assert len(beating()) == before + 1
+        req = eng.submit(_prompts(9, (9,))[0], max_tokens=12)
+        assert req.done.wait(60)
+        time.sleep(0.05)
+        m = eng.metrics()
+        # It beats: some wake-up was a little late, none by a second.
+        assert 0.0 < m["heartbeat_late_ms_max"] < 1000.0
+    finally:
+        eng.stop()
+    assert not thread.is_alive() and eng._heartbeat_thread is None
+    assert len(beating()) == before
+
+
+def test_a_request_knows_its_longest_wait_once_a_window(params):
+    """Three requests decode side by side; one sits one window out (its
+    row stood down). Its longest wait is two ticks, its neighbours' one,
+    and the gap is touched once a request a window, not once a token."""
+    eng = _engine(params, kv_mode="dense", decode_block=4)
+    _drive(eng, [eng.submit(p, max_tokens=25)
+                 for p in _prompts(10, (9, 9, 9))])       # compiles
+    eng.reset_stats()
+    reqs = [eng.submit(p, max_tokens=25) for p in _prompts(11, (9, 9, 9))]
+    sitter = reqs[1]
+    handed, emitted = [], [0]
+    inner_hand, inner_emit, inner_ready = (
+        eng._hand_over, eng._emit, eng._decode_ready_slots)
+
+    def hand_over(req, now, *window):
+        handed.append((req.request_id, now))
+        return inner_hand(req, now, *window)
+
+    def emit(req, token):
+        emitted[0] += 1
+        return inner_emit(req, token)
+
+    tick = [0]
+
+    def ready():
+        time.sleep(0.02)                        # a tick is 20 ms or more
+        return [s for s in inner_ready()
+                if not (tick[0] == 3 and eng.slot_req[s] is sitter)]
+
+    eng._hand_over, eng._emit, eng._decode_ready_slots = (
+        hand_over, emit, ready)
+    while not all(r.done.is_set() for r in reqs):
+        tick[0] += 1
+        eng.step()
+    assert emitted[0] == 3 * 25
+    # A prefill's token and 6 windows of 4 rows a request: 21 hand-overs
+    # for 75 tokens.
+    assert len(handed) == 3 * 7
+    assert [r.windows for r in reqs] == [6, 6, 6]
+    for req in reqs:
+        stamps = [t for rid, t in handed if rid == req.request_id]
+        # The request's own stamps keep their own clock reads (`_emit`):
+        # at the hand-over or just after it, never before.
+        assert stamps[0] <= req.first_token_at < stamps[1]
+        assert stamps[-1] <= req.finished_at
+        gaps = [b - a for a, b in zip(stamps, stamps[1:])]
+        assert req.max_gap_s == max(gaps)
+        assert req.last_emit_at == stamps[-1]
+    # The sitter's longest wait IS two of its neighbour's ticks, the one
+    # it sat out and the next (a window's requests share one stamp).
+    beside = [t for rid, t in handed if rid == reqs[0].request_id]
+    assert sitter.max_gap_s == pytest.approx(beside[4] - beside[2], abs=1e-9)
+    assert sitter.max_gap_s >= 2 * 0.02
+    for neighbour in (reqs[0], reqs[2]):
+        assert 0.02 <= neighbour.max_gap_s < sitter.max_gap_s
+    m = eng.metrics()
+    assert m["emit_gap_ms_max"] == pytest.approx(sitter.max_gap_s * 1e3,
+                                                 abs=1e-3)
+    assert m["emit_gap_ms_p50"] < m["emit_gap_ms_max"]
