@@ -11,8 +11,8 @@ laguna's paged walk and ring with other parts, which is kinship, not
 borrowing.)
 
 Nothing here looks at a configuration class: a function takes the few
-fields it reads (`cfg.dtype`, `cfg.norm_eps`, `cfg.top_k`) off whatever
-it is handed.
+fields it reads (`cfg.dtype`, `cfg.norm_eps`, `cfg.top_k`,
+`cfg.n_experts_routed`) off whatever it is handed.
 """
 
 from __future__ import annotations
@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.ops import scopes
+from ray_tpu.ops import moe, scopes
 
 _F32 = jnp.float32
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -218,9 +218,11 @@ def dispatch_order(slots, offsets, n_valid, null_slot: int):
 # Running totals over decode steps in `pool["moe_counters"]`, wrapping
 # uint32 (the host takes differences): (sparse layer, step) pairs, held
 # experts that had a row, the fullest held expert's rows, choices routed
-# (k a live row), and the choices that landed on a held expert.
+# (k a live row), the choices that landed on a held expert, and of those
+# the ones the expert layer's first block did not take (`moe.rows_over`;
+# 0 unless the routing sent this share over twice an even router's part).
 COUNTERS = ("layer_steps", "experts_touched", "rows_max", "rows_routed",
-            "rows_held")
+            "rows_held", "rows_over")
 
 
 # ... and, behind a router that chooses by a biased score, the choices
@@ -228,19 +230,21 @@ COUNTERS = ("layer_steps", "experts_touched", "rows_max", "rows_routed",
 COUNTERS_BIASED = COUNTERS + ("rows_bias_moved",)
 
 
-def counter_row_biased(cfg, counted, n_live):
+def counter_row_biased(cfg, counted, n_live, n_rows):
     """`counter_row` for `COUNTERS_BIASED`: `counted` a sparse layer's
     (counts, the live rows' choices the bias moved)."""
     counts, moved = counted
-    return jnp.concatenate([counter_row(cfg, counts, n_live),
+    return jnp.concatenate([counter_row(cfg, counts, n_live, n_rows),
                             moved.astype(jnp.uint32)[None]])
 
 
-def counter_row(cfg, counts, n_live):
+def counter_row(cfg, counts, n_live, n_rows: int):
     """What one sparse layer of one decode step adds to `COUNTERS`:
     `counts` [n_experts] the rows each held expert received, `n_live` the
-    rows that carried a token."""
+    rows that carried a token, `n_rows` the rows the layer was handed."""
     return jnp.stack([jnp.uint32(1), jnp.sum(counts > 0).astype(jnp.uint32),
                       jnp.max(counts).astype(jnp.uint32),
                       (n_live * cfg.top_k).astype(jnp.uint32),
-                      jnp.sum(counts).astype(jnp.uint32)])
+                      jnp.sum(counts).astype(jnp.uint32),
+                      moe.rows_over(counts, n_rows * cfg.top_k,
+                                    cfg.n_experts_routed)])

@@ -330,7 +330,8 @@ def _finish_block(cfg: KimiK2Config, params, l: int, x, attn, valid):
         experts = tuple(params[k].astype(dt) for k in _EXPERT_KEYS)
     routed, counts = token_choice_experts(
         u, chosen, gates, *experts,
-        first_expert=cfg.first_expert, layer=j, valid=valid.reshape(-1))
+        first_expert=cfg.first_expert, layer=j, valid=valid.reshape(-1),
+        n_routed=cfg.n_experts_routed)
     with jax.named_scope(scopes.COUNTERS):
         moved = jnp.sum(jnp.where(valid.reshape(-1), moved, 0))
     with jax.named_scope(scopes.MLP):
@@ -471,7 +472,8 @@ def decode_once(cfg: KimiK2Config, params, tokens, pool, positions, tables,
     with jax.named_scope(scopes.COUNTERS):
         n_live = jnp.sum(active)
         counters = pool["moe_counters"] + sum(
-            blocks.counter_row_biased(cfg, n, n_live) for n in counts)
+            blocks.counter_row_biased(cfg, n, n_live, tokens.shape[0])
+            for n in counts)
     return _head(cfg, params, x[:, 0]), {**pool, "moe_counters": counters}
 
 

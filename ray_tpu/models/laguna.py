@@ -295,7 +295,8 @@ def _finish_block(cfg: LagunaConfig, params, l: int, x, attn, gate, valid):
         experts = tuple(params[k].astype(dt) for k in _EXPERT_KEYS)
     routed, counts = token_choice_experts(
         u, chosen, gates, *experts,
-        first_expert=cfg.first_expert, layer=j, valid=valid.reshape(-1))
+        first_expert=cfg.first_expert, layer=j, valid=valid.reshape(-1),
+        n_routed=cfg.n_experts_routed)
     with jax.named_scope(scopes.MLP):
         shared = gated_mlp(u, params["s_gate"][j], params["s_up"][j],
                            params["s_down"][j])
@@ -503,7 +504,7 @@ def decode_once(cfg, params, tokens, pool, positions, tables,
          {"window": cfg.window, "col_page": col_page}))
     with jax.named_scope(scopes.COUNTERS):
         n_live = jnp.sum(active)
-        counters = pool["moe_counters"] + sum(count(cfg, n, n_live)
+        counters = pool["moe_counters"] + sum(count(cfg, n, n_live, B)
                                               for n in counts)
     return _head(cfg, params, x[:, 0]), {**pool, "moe_counters": counters}
 

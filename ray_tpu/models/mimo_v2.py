@@ -270,7 +270,8 @@ def _finish_block(cfg: MiMoV2Config, params, l: int, x, attn, valid):
         experts = tuple(params[k].astype(dt) for k in _EXPERT_KEYS)
     routed, counts = token_choice_experts(
         u, chosen, gates, *experts,
-        first_expert=cfg.first_expert, layer=j, valid=valid.reshape(-1))
+        first_expert=cfg.first_expert, layer=j, valid=valid.reshape(-1),
+        n_routed=cfg.n_experts_routed)
     with jax.named_scope(scopes.COUNTERS):
         moved = jnp.sum(jnp.where(valid.reshape(-1), moved, 0))
     with jax.named_scope(scopes.MLP):

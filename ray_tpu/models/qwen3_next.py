@@ -373,7 +373,8 @@ def _moe(cfg: Qwen3NextConfig, params, l: int, x, valid):
         experts = tuple(params[k].astype(dt) for k in _EXPERT_KEYS)
     routed, counts = token_choice_experts(
         u, chosen, gates, *experts,
-        first_expert=cfg.first_expert, layer=l, valid=valid.reshape(-1))
+        first_expert=cfg.first_expert, layer=l, valid=valid.reshape(-1),
+        n_routed=cfg.n_experts_routed)
     with jax.named_scope(scopes.MLP):
         shared = gated_mlp(u, params["s_gate"][l], params["s_up"][l],
                            params["s_down"][l])
@@ -586,8 +587,8 @@ def _decode_once(cfg: Qwen3NextConfig, params, tokens, pool, positions,
         counts.append(n)
     with jax.named_scope(scopes.COUNTERS):
         n_live = jnp.sum(active)
-        counters = pool["moe_counters"] + sum(counter_row(cfg, n, n_live)
-                                              for n in counts)
+        counters = pool["moe_counters"] + sum(
+            counter_row(cfg, n, n_live, tokens.shape[0]) for n in counts)
     return _head(cfg, params, x[:, 0]), {**pool, "moe_counters": counters}
 
 

@@ -399,9 +399,10 @@ def init_paged_kv(cfg: ZayaConfig, n_pages: int, page_size: int,
             "moe_counters": jnp.zeros(len(COUNTERS), jnp.uint32)}
 
 
-# `blocks.COUNTERS` without its last, `rows_held`: every expert is held
-# here, and a row has one choice.
-COUNTERS = blocks.COUNTERS[:-1]
+# `blocks.COUNTERS` without its last two, `rows_held` and `rows_over`:
+# every expert is held here, a row has one choice, and the expert layer's
+# block is every row.
+COUNTERS = blocks.COUNTERS[:-2]
 
 
 def _count(counts):

@@ -160,6 +160,10 @@ def moe_mlp(x: jax.Array, params: dict[str, jax.Array],
 # which experts it holds (`first_expert` .. + the weights' leading axis)
 # and returns only their part, so the parts of chips holding disjoint
 # ranges add up to the whole layer (tests/test_moe_token_choice.py).
+# Told the router's width too (`n_routed`), it CARRIES only their part:
+# a chip with 12 of 384 experts gathers, multiplies and combines the
+# ~3 % of the choices that land on them, a block of rows at a time
+# (`block_rows`), and not a row for every choice.
 
 def _mixed_dot_default() -> bool:
     """Whether the backend multiplies bf16 groups into a float32 result.
@@ -184,17 +188,53 @@ def _grouped_dot(lhs, rhs, sizes):
                               preferred_element_type=jnp.float32)
 
 
+# What a block holds beyond its zero rows, in the rows an even router
+# sends a chip's share (N * k * E / n_routed): 2. A trained router's load
+# on one share swings by tens of percent between steps, not by a factor
+# (`expert_rows_max`, the fullest expert over the mean, reads 1.8 where
+# an expert's mean is 5 rows); a share that takes over twice its part is
+# served exactly, in one more turn over its experts.
+_BLOCK_OF_EVEN = 2
+
+
+def block_rows(n_choices: int, n_held: int, n_routed: int | None) -> int:
+    """Rows of one block of `token_choice_experts`: the rows its grouped
+    matmuls take in one turn, for `n_choices` (rows x k) choices over
+    `n_routed` experts of which `n_held` are held. `n_routed` None, or
+    no more than `n_held`: every choice may be held, and the block is
+    every choice and a zero row an expert. Else `_BLOCK_OF_EVEN` times
+    the held choices of an even router and a zero row an expert, never
+    more than every choice. An odd multiple of 128 either way."""
+    full = _pad_rows(n_choices + n_held)
+    if n_routed is None or n_routed <= n_held:
+        return full
+    even = -(-n_choices * n_held // n_routed)
+    return min(full, _pad_rows(n_held + _BLOCK_OF_EVEN * even))
+
+
+def rows_over(counts: jax.Array, n_choices: int,
+              n_routed: int | None) -> jax.Array:
+    """The held choices `token_choice_experts`' first block did not take
+    (0: one turn sufficed), for its `counts` [E_held]. → uint32."""
+    E = counts.shape[0]
+    room = block_rows(n_choices, E, n_routed) - E
+    return jnp.maximum(jnp.sum(counts) - room, 0).astype(jnp.uint32)
+
+
 def token_choice_experts(x: jax.Array, expert_ids: jax.Array,
                          gates: jax.Array, w_gate: jax.Array,
                          w_up: jax.Array, w_down: jax.Array, *,
-                         first_expert: int = 0, layer=None, valid=None):
+                         first_expert: int = 0, layer=None, valid=None,
+                         n_routed: int | None = None):
     """Gated-SiLU experts over token-choice routing, the held part.
 
     x [N, D]; expert_ids [N] or [N, k] int32 (global expert ids, a row's
     k choices); gates like expert_ids (float32 weights); w_gate / w_up
     [E_held, D, F], w_down [E_held, F, D]: experts ``first_expert`` ..
     ``first_expert + E_held - 1``. `valid` [N] bool (optional): rows that
-    carry a token; the others reach no expert.
+    carry a token; the others reach no expert. `n_routed` (static): the
+    experts the router chose among, of which these are the held; None:
+    every choice may be held.
 
     With `layer` (an index, traced inside a layer scan) the weights are
     the WHOLE stacks [L, E_held, ...]: the stack is handed to the grouped
@@ -208,6 +248,14 @@ def token_choice_experts(x: jax.Array, expert_ids: jax.Array,
     many experts a handful of rows happened to reach (a trained router
     reaches nearly all of them). The zero rows contribute exactly 0.
 
+    Only a choice that lands on a held expert becomes a row. The held
+    choices, sorted by expert, are taken `block_rows` at a time: where
+    the block is every choice (a chip that holds every expert, or half
+    of them under top-10) the layer is one straight pass; where it is
+    less, as many turns of ONE loop body as the held rows need (one, for
+    any routing near an even one), each over its own part of every
+    expert's group, summed in float32. No routing drops a row.
+
     → (y [N, D] in x.dtype: Σ over a row's choices that land on a held
     expert of gate · W_down(silu(W_gate x) ⊙ W_up x), zero for the rest;
     counts [E_held] int32: rows each held expert received)."""
@@ -215,13 +263,59 @@ def token_choice_experts(x: jax.Array, expert_ids: jax.Array,
     E = w_gate.shape[0] if layer is None else w_gate.shape[1]
     with jax.named_scope(scopes.MOE_ROUTE):
         ids = expert_ids.reshape(N, -1)
-        k = ids.shape[1]
-        local = ids.reshape(-1).astype(jnp.int32) - first_expert  # [N*k]
-        held = (local >= 0) & (local < E)
-        if valid is not None:
-            held &= jnp.repeat(valid, k)
-        local = jnp.where(held, local, E)       # E: not here, sorts last
-        counts = jnp.zeros(E + 1, jnp.int32).at[local].add(1)[:E]
+    k = ids.shape[1]
+    block = block_rows(N * k, E, n_routed)
+    kw = dict(first_expert=first_expert, layer=layer, valid=valid)
+    if block == _pad_rows(N * k + E):
+        return _every_choice_a_row(x, ids, gates, (w_gate, w_up, w_down),
+                                   **kw)
+    return _held_rows_in_blocks(x, ids, gates, (w_gate, w_up, w_down),
+                                block=block, **kw)
+
+
+def _held_choices(ids, n_held: int, first_expert: int, valid):
+    """ids [N, k] → (local [N * k] int32: a choice's expert among the
+    held, `n_held` for a choice that reaches none of them, which sorts
+    last; counts [n_held] int32: rows each held expert received)."""
+    E, k = n_held, ids.shape[1]
+    local = ids.reshape(-1).astype(jnp.int32) - first_expert
+    held = (local >= 0) & (local < E)
+    if valid is not None:
+        held &= jnp.repeat(valid, k)
+    local = jnp.where(held, local, E)
+    counts = jnp.zeros(E + 1, jnp.int32).at[local].add(1)[:E]
+    return local, counts
+
+
+def _layer_groups(sizes, layer, w_gate):
+    """A layer's group sizes among the whole stack's L * E groups."""
+    if layer is None:
+        return sizes
+    n_layers, E = w_gate.shape[:2]
+    return jax.lax.dynamic_update_slice(
+        jnp.zeros(n_layers * E, jnp.int32), sizes, (layer * E,))
+
+
+def _gated_silu(rows, sizes, weights, layer):
+    """rows [M, D] in contiguous groups of `sizes` → [M, D] float32."""
+    with jax.named_scope(scopes.MOE_EXPERTS):
+        if layer is not None:
+            weights = tuple(w.reshape((-1,) + w.shape[2:]) for w in weights)
+        w_gate, w_up, w_down = weights
+        dot = functools.partial(_grouped_dot, sizes=sizes)
+        h = (jax.nn.silu(dot(rows, w_gate))
+             * dot(rows, w_up)).astype(rows.dtype)
+        return dot(h, w_down)
+
+
+def _every_choice_a_row(x, ids, gates, weights, *, first_expert, layer,
+                        valid):
+    """`token_choice_experts` where any choice may be held: one pass over
+    every choice, the ones that are not held sorted past the groups."""
+    (N, D), k = x.shape, ids.shape[1]
+    E = weights[0].shape[0 if layer is None else 1]
+    with jax.named_scope(scopes.MOE_ROUTE):
+        local, counts = _held_choices(ids, E, first_expert, valid)
         rows = jnp.repeat(x, k, axis=0) if k > 1 else x
         local = jnp.concatenate([local, jnp.arange(E, dtype=jnp.int32)])
         rows = jnp.concatenate([rows, jnp.zeros((E, D), x.dtype)])
@@ -238,19 +332,8 @@ def token_choice_experts(x: jax.Array, expert_ids: jax.Array,
         rows = jnp.concatenate([rows, jnp.zeros((pad, D), x.dtype)])
         order = jnp.argsort(local, stable=True)
         rows = rows[order]
-        if layer is not None:
-            n_layers = w_gate.shape[0]
-            sizes = jax.lax.dynamic_update_slice(
-                jnp.zeros(n_layers * E, jnp.int32), sizes, (layer * E,))
-    with jax.named_scope(scopes.MOE_EXPERTS):
-        if layer is not None:
-            w_gate, w_up, w_down = (
-                w.reshape((n_layers * E,) + w.shape[2:])
-                for w in (w_gate, w_up, w_down))
-        dot = functools.partial(_grouped_dot, sizes=sizes)
-        h = (jax.nn.silu(dot(rows, w_gate))
-             * dot(rows, w_up)).astype(x.dtype)
-        out = dot(h, w_down)                                # [M, D] fp32
+        sizes = _layer_groups(sizes, layer, weights[0])
+    out = _gated_silu(rows, sizes, weights, layer)          # [M, D] fp32
     with jax.named_scope(scopes.MOE_ROUTE):
         # Rows past the held groups belong to no group: whatever the
         # grouped matmul left there is dropped here.
@@ -260,4 +343,60 @@ def token_choice_experts(x: jax.Array, expert_ids: jax.Array,
         out = out[inverse[:N * k]]                          # unsort
         y = jnp.sum(out.reshape(N, k, D)
                     * gates.reshape(N, k, 1).astype(jnp.float32), axis=1)
+        return y.astype(x.dtype), counts
+
+
+def _held_rows_in_blocks(x, ids, gates, weights, *, first_expert, layer,
+                         valid, block: int):
+    """`token_choice_experts` where most choices are held elsewhere: the
+    held choices alone, sorted by expert with every expert's zero row
+    behind its own, `block` sorted rows a turn.
+
+    A turn gathers its rows of `x` by token, runs the grouped matmuls
+    over the part of every expert's group that lies in it (the group
+    ends clipped to the block), scales a row by its choice's gate and
+    adds it to its token's sum as a [N, block] one-hot times the rows on
+    the MXU, in float32 at the highest precision. Any routing near an
+    even one is ONE turn; one that sends this share more than the block
+    holds takes the turns it needs over the same body, each streaming
+    the experts whose rows it carries."""
+    (N, D), k = x.shape, ids.shape[1]
+    E, R, n = weights[0].shape[0 if layer is None else 1], block, N * k
+    with jax.named_scope(scopes.MOE_ROUTE):
+        local, counts = _held_choices(ids, E, first_expert, valid)
+        order = jnp.argsort(
+            jnp.concatenate([local, jnp.arange(E, dtype=jnp.int32)]),
+            stable=True)                # sorted row → choice; >= n: zero row
+        # A whole number of blocks, so that no turn's slice is clamped.
+        order = jnp.concatenate(
+            [order, jnp.full(-(n + E) % R, n, order.dtype)])
+        ends = jnp.cumsum(counts + 1)
+        starts, in_groups = ends - (counts + 1), ends[-1]
+        gate_of = gates.reshape(-1).astype(jnp.float32)
+
+    def turn(t, y):
+        with jax.named_scope(scopes.MOE_ROUTE):
+            lo = t * R
+            choice = jax.lax.dynamic_slice(order, (lo,), (R,))
+            live = (lo + jnp.arange(R) < in_groups) & (choice < n)
+            choice = jnp.where(live, choice, 0)
+            token = choice // k
+            rows = jnp.where(live[:, None], x[token], 0)
+            sizes = _layer_groups(
+                jnp.clip(ends, lo, lo + R) - jnp.clip(starts, lo, lo + R),
+                layer, weights[0])
+        out = _gated_silu(rows, sizes, weights, layer)      # [R, D] fp32
+        with jax.named_scope(scopes.MOE_ROUTE):
+            # A zero row, and a row past the groups (whatever the grouped
+            # matmul left there), add nothing.
+            out = jnp.where(live[:, None],
+                            out * gate_of[choice][:, None], 0.0)
+            onto = (token == jnp.arange(N)[:, None]) & live     # [N, R]
+            return y + jnp.dot(onto.astype(jnp.float32), out,
+                               precision=jax.lax.Precision.HIGHEST)
+
+    with jax.named_scope(scopes.MOE_ROUTE):
+        turns, none = -(-in_groups // R), jnp.zeros((N, D), jnp.float32)
+    y = jax.lax.fori_loop(0, turns, turn, none)
+    with jax.named_scope(scopes.MOE_ROUTE):
         return y.astype(x.dtype), counts

@@ -1056,13 +1056,17 @@ class LLMEngine:
                       # the decode programs' on-device counters, pulled
                       # with a window's tokens: (layer, step) pairs run,
                       # experts that had a row, the fullest expert's
-                      # rows, rows routed — summed over those pairs; and
-                      # of the rows routed, the choices a router's bias
-                      # moved out of the unbiased top-k (0: no bias).
+                      # rows, rows routed — summed over those pairs; of
+                      # the rows routed, the choices that landed on a
+                      # held expert, and of those the ones the expert
+                      # layer's first block did not take (one more turn
+                      # over the experts; 0 near an even router); and
+                      # the choices a router's bias moved out of the
+                      # unbiased top-k (0: no bias).
                       "moe_layer_steps": 0,
                       "moe_experts_touched_sum": 0, "moe_rows_max_sum": 0,
                       "moe_rows_routed": 0, "moe_rows_held": 0,
-                      "moe_rows_bias_moved": 0}
+                      "moe_rows_over": 0, "moe_rows_bias_moved": 0}
         # The decode programs' counters run on, wrapping uint32; the
         # window's share is the difference from the last pull.
         self._moe_seen: dict | None = None
@@ -1147,9 +1151,11 @@ class LLMEngine:
             self.stats["moe_experts_touched_sum"] += delta["experts_touched"]
             self.stats["moe_rows_max_sum"] += delta["rows_max"]
             self.stats["moe_rows_routed"] += delta["rows_routed"]
-            # A family that holds every expert counts no share of its own.
+            # A family that holds every expert counts no share of its
+            # own, and its expert layer's block is every row.
             self.stats["moe_rows_held"] += delta.get(
                 "rows_held", delta["rows_routed"])
+            self.stats["moe_rows_over"] += delta.get("rows_over", 0)
             self.stats["moe_rows_bias_moved"] += delta.get(
                 "rows_bias_moved", 0)
 

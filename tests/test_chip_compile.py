@@ -1395,3 +1395,53 @@ def test_decode_program_draws_only_inside_its_conditional(step_program,
                               "\n".join(entry)))
     moved = _wide_prefetches(entry, slots)
     assert not moved, "logits prefetched:\n" + "\n".join(moved)
+
+
+# --- the expert layer's block: the held rows alone (PR 59) ---------------
+_GROUPED = re.compile(r"%ragged-dot-none[\w.\-]* = f32\[(\d+),\d+\][^\n]*"
+                      r"operand_layout_constraints=\{([^\n]*?)\}\}")
+_MOE_OPS = re.compile(r'op_name="([^"]*?)moe\.(?:route|experts)/')
+
+# family → (rows of its decode step, choices a row, rows of a block: the
+# held rows of twice an even router's share and a zero row an expert, the
+# loops its expert layer's operations sit in: zaya's is its layer scan,
+# the other three's the block loop)
+_EXPERT_BLOCKS = {"kimi_k2": (K2_SLOTS, 8, 384, 1),
+                  "mimo_v2": (M_SLOTS, 8, 384, 1),
+                  "qwen3_next": (Q_SLOTS, 10, 896, 1),
+                  "laguna": (G_SLOTS, 10, 896, 0),
+                  "zaya": (Z_SLOTS, 1, 128, 1)}
+
+
+@pytest.mark.parametrize("family", sorted(_EXPERT_BLOCKS))
+def test_expert_layer_carries_only_a_block_of_held_rows(request, step_program,
+                                                        family):
+    """Every expert family's decode program at its cell's size: the three
+    grouped matmuls take a block's rows and no more (384 of 2,176 at
+    kimi_k2, 384 of 1,152 at mimo_v2, 896 of 1,408 at qwen3_next), over
+    the whole stack of experts, inside ONE loop a layer and no branch; no
+    float32 array of every choice a row is left in the program. Where
+    the block IS every choice (zaya holds all 16, laguna half of 256
+    under top-10) the rows are the ones they were, and the expert layer
+    brings no loop and no branch of its own."""
+    from ray_tpu.ops import moe
+
+    slots, k, rows, loops = _EXPERT_BLOCKS[family]
+    text = step_program(family, "decode").as_text()
+    cfg = request.getfixturevalue(family + "_serving")[0]
+    full = moe._pad_rows(slots * k + cfg.n_experts)
+    assert moe.block_rows(slots * k, cfg.n_experts,
+                          getattr(cfg, "n_experts_routed", None)) == rows
+    assert (rows == full) == (family in ("laguna", "zaya"))
+    calls = _GROUPED.findall(text)
+    assert len(calls) >= 3
+    for result_rows, operands in calls:
+        assert int(result_rows) == rows and f"bf16[{rows}," in operands
+        stack = re.search(r"bf16\[(\d+),\d+,\d+\]", operands)
+        assert stack and int(stack.group(1)) > cfg.n_experts, operands
+    around = set(_MOE_OPS.findall(text))
+    assert around and max(p.count("while/body") for p in around) == loops
+    assert not any("cond" in p or "branch" in p for p in around), around
+    wide = re.findall(rf"f32\[(?:{full}|{slots * k}|{slots},{k}),"
+                      rf"{cfg.d_model}\]", text)
+    assert bool(wide) == (rows == full), wide[:3]

@@ -330,9 +330,9 @@ def _run(eng, reqs):
     assert all(r.done.is_set() and r.error is None for r in reqs)
 
 
-def _deficits(params, r):
+def _deficits(params, r, rc=RC):
     seq = np.asarray(r.prompt_ids[:r.n_prompt] + r.out_ids, np.int32)
-    rows = _ref_logits(params, seq)[r.n_prompt - 1:len(seq) - 1]
+    rows = _ref_logits(params, seq, rc)[r.n_prompt - 1:len(seq) - 1]
     return rows.max(axis=1) - rows[np.arange(len(r.out_ids)), r.out_ids]
 
 
@@ -373,6 +373,49 @@ def test_engine_serves_the_references_tokens_and_counts(params, attn_impl):
     eng.reset_stats()
     after = eng.metrics()
     assert after["moe_rows_routed"] == after["moe_rows_bias_moved"] == 0
+
+
+@pytest.mark.parametrize("router", ["even", "onto_held"])
+def test_rows_over_counts_what_a_first_block_did_not_take(router):
+    """40 slots x top-4 over 64 experts of which 4 are held: the expert
+    layer's block is 128 rows (room for 124 held choices) where every
+    choice a row would be 384. An even router sends a step ~10 held
+    choices and `moe_rows_over` stays 0; a router whose bias puts every
+    choice on the held experts sends 160, the layer takes a second turn
+    and counts the 36 a step, and every emitted token is still the
+    float32 reference's best."""
+    from ray_tpu.ops import moe
+
+    cfg = kimi_k2.KimiK2Config.tiny(dtype=jnp.float32, n_experts_routed=64,
+                                    top_k=4)
+    slots = 40
+    assert moe.block_rows(slots * cfg.top_k, cfg.n_experts, 64) == 128
+    assert moe._pad_rows(slots * cfg.top_k + cfg.n_experts) == 384
+    params = _params(cfg)
+    if router == "onto_held":       # s is a sigmoid: 2 outbids any score
+        params["router_bias"] = params["router_bias"].at[
+            :, :cfg.n_experts].add(2.0)
+    eng = LLMEngine(cfg, params, n_slots=slots, max_len=64, kv_mode="paged",
+                    page_size=PAGE, n_pages=4 * slots, prefill_chunk=CHUNK,
+                    attn_impl="gather", prefill_token_budget=4 * CHUNK)
+    rng = np.random.default_rng(3)
+    reqs = [eng.submit(rng.integers(1, cfg.vocab_size, 5).tolist(),
+                       max_tokens=40) for _ in range(slots)]
+    with jax.default_matmul_precision("highest"):
+        _run(eng, reqs)
+    m = eng.metrics()
+    assert m["preemptions"] == 0 and m["moe_rows_routed"] > 0
+    for r in reqs[::8]:
+        assert _deficits(params, r, _rc(cfg)).max() <= ATOL_F32
+    if router == "even":
+        assert m["moe_rows_over"] == 0
+        assert m["moe_rows_held"] < 0.2 * m["moe_rows_routed"]
+    else:
+        assert m["moe_rows_held"] == m["moe_rows_routed"]
+        # every step of 32 or more live slots passed the block's room
+        assert 0 < m["moe_rows_over"] < m["moe_rows_held"]
+    eng.reset_stats()
+    assert eng.metrics()["moe_rows_over"] == 0
 
 
 def test_engine_recomputes_a_preempted_request_to_the_same_tokens(params):

@@ -293,3 +293,158 @@ def test_top_8_of_384_with_12_held(first, n_rows, layer):
     assert int(counts.sum()) == int(here.sum()) < ids.size // 8
     unreached = ~here.any(axis=1)
     assert unreached.any() and not np.asarray(y)[unreached].any()
+
+
+# ---------- told the router's width, the layer carries the held rows only
+
+from ray_tpu.ops import moe  # noqa: E402
+
+# 64 rows x 4 choices over 64 experts of which 4 are held: an even router
+# sends 16 choices here, so a block is 128 rows (4 zero rows + 124) where
+# every choice a row would be 384.
+B_N, B_K, B_HELD, B_ROUTED, B_D, B_F = 64, 4, 4, 64, 8, 6
+B_ROOM = 124
+
+
+def _block_case(routing, k=B_K, n_rows=B_N):
+    """→ ids [n_rows, k] over B_ROUTED experts; experts 0..3 are held by
+    the first share, 4..7 by the second."""
+    rng = np.random.default_rng(21)
+    away = lambda n: np.stack([8 + rng.permutation(B_ROUTED - 8)[:k]
+                               for _ in range(n)])
+    if routing == "even":
+        ids = np.stack([rng.permutation(B_ROUTED)[:k] for _ in range(n_rows)])
+    elif routing == "all_held":         # every choice on the first share
+        ids = np.stack([rng.permutation(B_HELD)[:k] for _ in range(n_rows)])
+    elif routing in ("room", "room_and_one"):
+        ids = away(n_rows)
+        ids[:B_ROOM // k] = np.arange(k)                    # 31 x 4 = 124
+        if routing == "room_and_one":
+            ids[-1, 0] = 2
+    elif routing == "two_shares":       # every choice on experts 0..5
+        ids = np.stack([rng.permutation(6)[:k] for _ in range(n_rows)])
+    elif routing == "one_expert":       # k = 1: every row to expert 1
+        ids = np.full((n_rows, 1), 1)
+    else:
+        raise ValueError(routing)
+    return ids.astype(np.int32), rng
+
+
+def _held_loop(x, ids, gates, w, first, valid=None):
+    """The definition, over the choices that land on `first` .. + held."""
+    mine = [np.asarray(t, np.float64) for t in w]
+    held = mine[0].shape[0]
+    want = np.zeros(np.asarray(x).shape)
+    for n in range(len(ids)):
+        if valid is not None and not valid[n]:
+            continue
+        for e, g in zip(ids[n], gates[n]):
+            if not first <= e < first + held:
+                continue
+            xn = np.asarray(x[n], np.float64)
+            a = xn @ mine[0][e - first]
+            want[n] += g * ((a / (1.0 + np.exp(-a))
+                             * (xn @ mine[1][e - first])) @ mine[2][e - first])
+    return want
+
+
+@pytest.mark.parametrize("routing,k,layer,masked,over", [
+    ("even", B_K, None, False, 0),
+    ("even", B_K, 2, True, 0),
+    ("all_held", B_K, None, False, B_N * B_K - B_ROOM),
+    ("all_held", B_K, 1, False, B_N * B_K - B_ROOM),
+    ("all_held", B_K, 1, True, None),
+    ("room", B_K, None, False, 0),
+    ("room_and_one", B_K, None, False, 1),
+    ("room_and_one", B_K, 0, False, 1),
+    ("one_expert", 1, None, False, B_N * B_K - B_ROOM),
+    ("one_expert", 1, 2, True, None),
+])
+def test_blocks_of_held_rows_match_the_loop_for_any_routing(
+        routing, k, layer, masked, over):
+    """With `n_routed` the layer takes the held choices a block at a
+    time: an even router's are one turn; a routing that sends every
+    choice to the held experts (the one the loop exists for) takes
+    several, at a plain stack and under a traced layer index; the held
+    rows exactly fill a block, and pass it by one; rows that carry no
+    token; k = 1. Every case against the per-token float64 loop, with the
+    counts the full-width layer gives."""
+    n_rows = B_N * B_K if k == 1 else B_N
+    ids, rng = _block_case(routing, k, n_rows)
+    gates = rng.uniform(0.1, 1.0, ids.shape).astype(np.float32)
+    x = jnp.asarray(rng.normal(size=(n_rows, B_D)), jnp.float32)
+    valid = rng.uniform(size=n_rows) < 0.6 if masked else None
+    lead = (B_HELD,) if layer is None else (3, B_HELD)
+    mk = lambda *s: jnp.asarray(rng.normal(size=lead + s) * 0.3, jnp.float32)
+    w = mk(B_D, B_F), mk(B_D, B_F), mk(B_F, B_D)
+    kw = {} if layer is None else {"layer": jnp.int32(layer)}
+    if masked:
+        kw["valid"] = jnp.asarray(valid)
+    assert moe.block_rows(ids.size, B_HELD, B_ROUTED) == 128 < moe._pad_rows(
+        ids.size + B_HELD)
+    call = jax.jit(token_choice_experts,
+                   static_argnames=("first_expert", "n_routed"))
+    with jax.default_matmul_precision("highest"):
+        y, counts = call(x, jnp.asarray(ids), jnp.asarray(gates), *w,
+                         n_routed=B_ROUTED, **kw)
+        y_full, counts_full = call(x, jnp.asarray(ids), jnp.asarray(gates),
+                                   *w, **kw)
+    mine = w if layer is None else tuple(t[layer] for t in w)
+    np.testing.assert_allclose(
+        np.asarray(y), _held_loop(x, ids, gates, mine, 0, valid), atol=2e-5)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_full), atol=2e-5)
+    np.testing.assert_array_equal(np.asarray(counts), np.asarray(counts_full))
+    here = (ids < B_HELD) & (True if valid is None else valid[:, None])
+    np.testing.assert_array_equal(
+        np.asarray(counts), np.bincount(ids[here], minlength=B_HELD))
+    got = int(moe.rows_over(counts, ids.size, B_ROUTED))
+    assert got == max(0, int(here.sum()) - B_ROOM)
+    if over is not None:
+        assert got == over
+    if valid is not None:
+        assert not np.asarray(y)[~valid].any()
+
+
+@pytest.mark.parametrize("layer", [None, 1])
+def test_two_shares_blocks_add_up_to_the_whole_layer(layer):
+    """Every choice on experts 0..5, a share holding 0..3 (two thirds of
+    the choices: more turns than one) and one holding 4..7, each told the
+    router's 64: the two parts add up to the loop over all eight."""
+    ids, rng = _block_case("two_shares")
+    gates = rng.uniform(0.1, 1.0, ids.shape).astype(np.float32)
+    x = jnp.asarray(rng.normal(size=(B_N, B_D)), jnp.float32)
+    lead = (8,) if layer is None else (2, 8)
+    mk = lambda *s: jnp.asarray(rng.normal(size=lead + s) * 0.3, jnp.float32)
+    w = mk(B_D, B_F), mk(B_D, B_F), mk(B_F, B_D)
+    kw = {} if layer is None else {"layer": jnp.int32(layer)}
+    call = jax.jit(token_choice_experts,
+                   static_argnames=("first_expert", "n_routed"))
+    total, counted = 0.0, 0
+    with jax.default_matmul_precision("highest"):
+        for first in (0, 4):
+            share = tuple(t[..., first:first + 4, :, :] for t in w)
+            y, counts = call(x, jnp.asarray(ids), jnp.asarray(gates), *share,
+                             first_expert=first, n_routed=B_ROUTED, **kw)
+            over = int(moe.rows_over(counts, ids.size, B_ROUTED))
+            assert over > 0 if first == 0 else over == 0
+            total, counted = (total + np.asarray(y, np.float64),
+                              counted + int(counts.sum()))
+    assert counted == ids.size
+    mine = w if layer is None else tuple(t[layer] for t in w)
+    np.testing.assert_allclose(total, _held_loop(x, ids, gates, mine, 0),
+                               atol=4e-5)
+
+
+@pytest.mark.parametrize("n_choices,held,routed,rows,full", [
+    (256 * 8, 12, 384, 384, 2176),      # kimi-k2.6.longthink's decode step
+    (128 * 8, 16, 256, 384, 1152),      # mimo-v2-flash.think's
+    (128 * 10, 128, 512, 896, 1408),    # qwen3-next-80b-a3b.longform's
+    (64 * 10, 128, 256, 896, 896),      # laguna-s-2.1.codegen's: every choice
+    (64, 16, None, 128, 128),           # zaya1-8b.reason's: holds all
+    (64, 16, 16, 128, 128),
+    (1024 * 8, 12, 384, 640, 8320),     # a kimi-k2.6 chunk program of 8 rows
+])
+def test_block_rows_at_the_cells_shapes(n_choices, held, routed, rows, full):
+    assert moe.block_rows(n_choices, held, routed) == rows
+    assert moe._pad_rows(n_choices + held) == full
+    assert rows % 256 == 128 and rows <= full
