@@ -1,7 +1,7 @@
 """Parts that two or more served families are built from.
 
 A family module (models/zaya.py, laguna.py, qwen3_next.py, mimo_v2.py,
-jamba.py, kimi_k2.py) writes what is its own: the configuration, the
+jamba.py, kimi_k2.py, olmo_hybrid.py) writes what is its own: the configuration, the
 attention inputs, the router, the ropes, the pool. What several of them
 compute the same way lives here under public names, so that no family
 imports a sibling to get it: the imports of `ray_tpu/models/` point from a family to this module
@@ -33,13 +33,17 @@ _HIGHEST = jax.lax.Precision.HIGHEST
 
 def init_from_specs(specs: dict, rng: jax.Array, dtype) -> dict:
     """Seeded leaves from a `param_specs` table: normal at the spec's
-    scale, or ones; a key a leaf, in the names' order."""
+    scale, the log of U(1e-3, `high`) (a decay's A_log), or ones; a key
+    a leaf, in the names' order."""
     keys = jax.random.split(rng, len(specs))
     params = {}
     for key, (name, spec) in zip(keys, sorted(specs.items())):
         if spec["init"] == "normal":
             params[name] = (jax.random.normal(key, spec["shape"], dtype)
                             * spec["scale"])
+        elif spec["init"] == "log_uniform":
+            params[name] = jnp.log(jax.random.uniform(
+                key, spec["shape"], dtype, 1e-3, spec["high"]))
         else:
             params[name] = jnp.ones(spec["shape"], dtype)
     return params

@@ -336,9 +336,43 @@ def _kimi_k2() -> ServingFamily:
         lay_out=kimi_k2.lay_out)
 
 
+def _olmo_hybrid() -> ServingFamily:
+    """qwen3_next's two memories (a recurrent state by the slot beside
+    pages) without experts, as jamba is: every layer dense, the pages
+    MULTI-head (30 KV heads), the tree served as it comes (no
+    `lay_out`)."""
+    from ray_tpu.models import olmo_hybrid
+
+    return _paged_only(
+        olmo_hybrid,
+        "the linear layers' recurrent state and convolution tail "
+        "(models/olmo_hybrid.py: a float32 matrix of 96 x 192 a head and "
+        "layer, two heads side by side, 13.7 MB a slot at the published "
+        "sizes, indexed by slot)",
+        {"kv_mode": "for the full layers with {beside} carried beside it",
+         "prefill_chunk": "the prompt's final recurrent state in the slot",
+         "prefill_width_bucketing": "a pass over all of the model's "
+                                    "weights (every layer is dense)",
+         "prefix_cache": _SNAPSHOT,
+         "spec_draft": "a recurrence cannot be run backwards; " + _RETURNS,
+         "tp": "30 heads of each kind divide by neither 4 nor 8 chips, no "
+               "partition rule splits the recurrent state by value head, "
+               "and the deployment this family stands for splits the "
+               "LAYERS over a host's chips (pipeline stages), which "
+               "serve/ does not build",
+         "weight_dtype": "and an int8 form of this family's tree (a plane a "
+                         "layer, with its scale vectors) would have to be "
+                         "written",
+         "kv_dtype": "which the full layers' K/V writer would have to call, "
+                     "and the recurrent state is float32 by the model's own "
+                     "definition"},
+        slot_state=olmo_hybrid.SLOT_STATE_LEAVES)
+
+
 _FAMILIES = {"gpt": _gpt, "zaya": _zaya, "laguna": _laguna,
              "qwen3_next": _qwen3_next, "mimo_v2": _mimo_v2,
-             "jamba": _jamba, "kimi_k2": _kimi_k2}
+             "jamba": _jamba, "kimi_k2": _kimi_k2,
+             "olmo_hybrid": _olmo_hybrid}
 
 
 @functools.cache

@@ -2,17 +2,37 @@
 matrix state a head, in its two serving forms.
 
 A head's state S is [dk keys, dv values], float32. Token t, with a log
-decay g_t <= 0, a write strength beta_t in (0, 1), a unit key k_t, a
-scaled unit query q_t and a value v_t:
+decay g_t <= 0, a write strength beta_t in (0, 2) (a sigmoid in
+qwen3-next, twice a sigmoid where the model allows a negative
+eigenvalue: olmo-hybrid's `linear_allow_neg_eigval`; I - beta k k^T
+then reflects the state's component along k and every eigenvalue still
+lies in [-1, 1]), a unit key k_t, a scaled unit query q_t and a value
+v_t:
 
     S <- exp(g_t) S;  d_t = beta_t (v_t - S^T k_t);
     S <- S + k_t d_t^T;  o_t = S^T q_t
 
+**The stack's layout is the caller's, by `packed_heads`.** The pool
+holds ``[L, n_slots+1, H / p, dk, p dv]``: p heads SIDE BY SIDE along
+the lanes, p the fewest heads whose values fill whole lane tiles of 128
+(1 where dv is a multiple of 128: qwen3-next's [32, 128, 128], which is
+the plain [H, dk, dv]; 2 at olmo-hybrid's dv = 192: [15, 96, 384], 3
+lane tiles over 12 sublane tiles, dense, where [30, 96, 192] would lie
+in 256 lanes a row on the chip: a third more bytes in HBM and in every
+DMA). Both forms read p off the stack's and v's last axes (`pack_state`
+/ `unpack_state` are the two views); no option names it.
+
 `gdn_decode_step` advances every live slot's state by ONE token, in
 place: a Pallas kernel (`gdn_decode_step` in a trace) whose grid walks
-(slot, block of heads) over the whole ``[L, n_slots+1, H, dk, dv]``
-stack, aliased in and out, so a step reads and writes each live slot's
-64 KiB a head once and nothing is gathered, scattered or copied. An idle
+(slot, block of packed heads) over the whole stack, aliased in and out,
+so a step reads and writes each live slot's state (64 KiB a head at 128
+x 128, 72 KiB at 96 x 192) once and nothing is gathered, scattered or
+copied. A block is the most packed heads that divide H / p, stay under
+`_DECODE_HEADS` and under `_DECODE_BLOCK_BYTES` (16 heads = 1 MiB at
+qwen3-next's sizes, 5 pairs = 720 KiB at olmo-hybrid's). A packed
+head's lanes share one decay / beta / value row (the heads' rows side by
+side) and differ only in which key column multiplies them, chosen by
+lane; the sums run down the sublanes, so heads never mix. An idle
 slot's grid steps name the null slot's block (the stack's last row) and
 do no work: consecutive idle steps move nothing. `reference_gdn_decode_step`
 is the same step in plain XLA (the oracle, and the path off the TPU).
@@ -31,13 +51,26 @@ S' = exp(G_T) S_0 + (exp(G_T - G) K)^T D  walk a row's blocks, and a
 slot's rows, in order. A decay ratio is only ever `exp` of a DIFFERENCE
 of cumulated g's that is masked to the causal triangle BEFORE the `exp`:
 g reaches -60 a token, one token of exp(-cumsum) passes float32's range,
-and an `inf` under a mask is a NaN. (I + A)^-1 is the nilpotent series
-(I - A)(I + A^2)(I + A^4)...: log2(block) - 1 squarings, all matmuls.
+and an `inf` under a mask is a NaN. The difference G_i - G_j is summed
+from its own terms g_{j+1} .. g_i, so it keeps its digits whatever came
+before token j. (I + A)^-1 is made from the
+diagonal blocks of `_INVERSE_BASE` tokens, each by forward substitution
+(its rows in order, every entry a bounded sum, every block of every row
+and head at once along the lanes), merged pair by pair
+([[X, 0], [-Y A21 X, Y]]) up to the block: the true inverse's entries
+stay bounded because the recurrence is a contraction at any beta in
+(0, 2), and nothing larger is ever formed. (The nilpotent series
+(I - A)(I + A^2)(I + A^4)... this replaces forms A^k first, whose
+entries grow like (beta k.k)^k C(T, k) and cancel: at a mean key cosine
+of 0.5 it lost 2e-3 of an output at beta <= 1 and everything at
+beta <= 2, block 64; tests/test_gated_delta.py holds both at cosines up
+to 0.99.)
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -47,10 +80,17 @@ from jax.experimental.pallas import tpu as pltpu
 _F32 = jnp.float32
 _HIGHEST = jax.lax.Precision.HIGHEST
 _LANES = 128
-# Heads of one slot a grid step of the decode kernel holds: a block of
-# 16 x 64 KiB = 1 MiB in and as much out, double-buffered 4 MiB of the
-# 16 MiB of VMEM a kernel gets by default.
+# Packed heads of one slot a grid step of the decode kernel holds: at
+# most 16 and at most 1 MiB in and as much out, double-buffered 4 MiB of
+# the 16 MiB of VMEM a kernel gets by default (16 x 64 KiB at 128 x 128
+# heads; 5 pairs x 144 KiB at 96 x 192).
 _DECODE_HEADS = 16
+_DECODE_BLOCK_BYTES = 2**20
+# Tokens of a diagonal block that `_unit_lower_inverse` solves row by row.
+# The whole scan of 8 rows of 128 tokens on the chip, by base (PERF.md,
+# PR 60; 128 x 128 / 96 x 192 heads): 4 1.147 / 1.376 ms, 8 1.064 /
+# 1.275, 16 2.423 / 2.653, 32 4.064 / 4.139; the series 1.168 / 1.409.
+_INVERSE_BASE = 8
 
 
 def _interpret_default() -> bool:
@@ -59,18 +99,48 @@ def _interpret_default() -> bool:
 
 # ----------------------------------------------------------- a prompt chunk
 
+def _substituted(a):
+    """(I + a)^-1 for strictly lower-triangular a [..., t, t], t small,
+    by forward substitution: row i of the inverse is
+    e_i - sum_{j<i} a_ij row_j. Elementwise throughout (t^2 / 2 fused
+    multiply-adds of a row), no matmul and nothing larger than the
+    inverse's own entries. The BATCH lies on the lanes while it runs (a
+    row is [t, M], M the product of the leading axes): a row of t = 8
+    values alone would fill a sixteenth of a lane tile."""
+    t = a.shape[-1]
+    a_m = jnp.moveaxis(a.reshape((-1, t, t)), 0, -1)         # [t, t, M]
+    eye = jnp.eye(t, dtype=a.dtype)
+    rows = []
+    for i in range(t):
+        row = jnp.broadcast_to(eye[i][:, None], a_m.shape[1:])
+        for j in range(i):
+            row = row - a_m[i, j] * rows[j]
+        rows.append(row)
+    return jnp.moveaxis(jnp.stack(rows), -1, 0).reshape(a.shape)
+
+
 def _unit_lower_inverse(a):
     """(I + a)^-1 for a strictly lower-triangular a [..., T, T] (T a
-    power of two): the finite series sum_k (-a)^k as
-    (I - a)(I + a^2)(I + a^4)..."""
+    power of two): the diagonal blocks of `_INVERSE_BASE` tokens by
+    forward substitution, then pairs of neighbours merged,
+    [[X, 0], [c, Y]]^-1 = [[X^-1, 0], [-Y^-1 c X^-1, Y^-1]], until one
+    block is left."""
     T = a.shape[-1]
+    t = min(_INVERSE_BASE, T)
     mm = functools.partial(jnp.matmul, precision=_HIGHEST)
-    power = -a
-    inv = jnp.eye(T, dtype=a.dtype) + power
-    for _ in range(max(T.bit_length() - 2, 0)):
-        power = mm(power, power)
-        inv = inv + mm(inv, power)
-    return inv
+    at = lambda r, c, t: a[..., r * t:(r + 1) * t, c * t:(c + 1) * t]
+    inv = _substituted(
+        jnp.stack([at(b, b, t) for b in range(T // t)], axis=-3))
+    while t < T:
+        top, bot = inv[..., 0::2, :, :], inv[..., 1::2, :, :]
+        corner = -mm(bot, mm(jnp.stack(
+            [at(2 * p + 1, 2 * p, t) for p in range(T // (2 * t))],
+            axis=-3), top))
+        inv = jnp.concatenate(
+            [jnp.concatenate([top, jnp.zeros_like(top)], axis=-1),
+             jnp.concatenate([corner, bot], axis=-1)], axis=-2)
+        t *= 2
+    return inv[..., 0, :, :]
 
 
 def gdn_chunk_scan(q, k, v, g, beta, state, chain, fresh, *, block: int = 64):
@@ -101,13 +171,17 @@ def gdn_chunk_scan(q, k, v, g, beta, state, chain, fresh, *, block: int = 64):
     mm = functools.partial(jnp.einsum, precision=_HIGHEST,
                            preferred_element_type=_F32)
     G = jnp.cumsum(g, axis=-1)                               # [N, nb, H, T]
-    diff = G[..., :, None] - G[..., None, :]                 # G_i - G_j
     i, j = jnp.arange(T)[:, None], jnp.arange(T)[None, :]
+    # G_i - G_j for i > j as the sum of ITS OWN terms, g_{j+1} .. g_i (0
+    # elsewhere), not as a difference of two cumulated sums: one token
+    # whose g is -1e10 (a large A times a large step) leaves every later
+    # G near -1e10, and their differences multiples of 512.
+    diff = jnp.cumsum(jnp.where(i > j, g[..., :, None], 0.0), axis=-2)
     ratio = lambda seen: jnp.exp(jnp.where(seen, diff, -jnp.inf))
     a = beta[..., None] * mm("...ik,...jk->...ij", k, k) * ratio(i > j)
     inv = _unit_lower_inverse(a)
     from_start = jnp.exp(G)[..., None]                       # exp(G_i - G_0)
-    to_end = jnp.exp(G[..., -1:] - G)[..., None]             # exp(G_T - G_i)
+    to_end = jnp.exp(diff[..., -1, :])[..., None]            # exp(G_T - G_i)
     u = mm("...ij,...jv->...iv", inv, beta[..., None] * v)
     w = mm("...ij,...jk->...ik", inv, beta[..., None] * from_start * k)
     qk = mm("...ik,...jk->...ij", q, k) * ratio(i >= j)
@@ -150,43 +224,85 @@ def reference_gdn_scan(q, k, v, g, beta, state):
 
 # ----------------------------------------------------------- a decode step
 
+def packed_heads(n_heads: int, dv: int) -> int:
+    """Heads the pool's state keeps side by side along the lanes: the
+    fewest whose values fill whole lane tiles, where that many divide
+    the heads; else 1 (the plain [H, dk, dv])."""
+    p = _LANES // math.gcd(dv, _LANES)
+    return p if n_heads % p == 0 else 1
+
+
+def pack_state(s, p: int):
+    """States [..., H, dk, dv] as the pool holds them,
+    [..., H / p, dk, p dv]: heads p h .. p h + p - 1 side by side."""
+    if p == 1:
+        return s
+    *lead, H, dk, dv = s.shape
+    return jnp.moveaxis(s.reshape(*lead, H // p, p, dk, dv), -3, -2).reshape(
+        *lead, H // p, dk, p * dv)
+
+
+def unpack_state(s, dv: int):
+    """`pack_state`'s inverse: the pool's [..., H / p, dk, p dv] as
+    [..., H, dk, dv], p read off the last axis."""
+    p = s.shape[-1] // dv
+    if p == 1:
+        return s
+    *lead, Hp, dk, _ = s.shape
+    return jnp.moveaxis(s.reshape(*lead, Hp, dk, p, dv), -2, -3).reshape(
+        *lead, Hp * p, dk, dv)
+
+
 def reference_gdn_decode_step(state, layer, q, k, v, g, beta, active, *,
                               repeat: int = 1):
-    """One token for slots 0 .. B-1 of `state` [L, n_slots+1, H, dk, dv]
-    at `layer`, in plain XLA. q, k [B, Hk, dk]: the KEY heads' (value
-    head h reads key head h // `repeat`); v [B, H, dv], g, beta [B, H]
-    float32; `active` [B] bool: the others' state stays.
+    """One token for slots 0 .. B-1 of `state` [L, n_slots+1, H / p, dk,
+    p dv] at `layer`, in plain XLA. q, k [B, Hk, dk]: the KEY heads'
+    (value head h reads key head h // `repeat`); v [B, H, dv], g, beta
+    [B, H] float32; `active` [B] bool: the others' state stays.
     → (o [B, H, dv] float32, the updated stack)."""
-    B = q.shape[0]
+    B, dv = q.shape[0], v.shape[-1]
     q, k = (jnp.repeat(t.astype(_F32), repeat, axis=1) for t in (q, k))
-    old = state[layer, :B]
+    old = unpack_state(state[layer, :B], dv)
     s = old * jnp.exp(g)[..., None, None]
     read = functools.partial(jnp.einsum, "bhkv,bhk->bhv", precision=_HIGHEST)
     d = beta[..., None] * (v - read(s, k))
     s = s + k[..., :, None] * d[..., None, :]
     o = read(s, q)
     s = jnp.where(active[:, None, None, None], s, old)
-    return o, state.at[layer, :B].set(s)
+    return o, state.at[layer, :B].set(pack_state(s, state.shape[-1] // dv))
 
 
 def _decode_kernel(layer_ref, rows_ref, kq_ref, v_ref, a_ref, beta_ref,
-                   s_ref, o_ref, s_out_ref, *, heads, repeat, null_slot):
-    """One slot's block of `heads` value heads. kq_ref [dk, LANES]:
-    column j the key of the block's j-th key head, column LANES / 2 + j
-    its query, so that a key lies along the state's sublanes; v, a
-    (= exp(g)), beta [heads, dv] rows, a and beta one value a head
-    spread over the lanes."""
+                   s_ref, o_ref, s_out_ref, *, heads, pack, repeat, null_slot):
+    """One slot's block of `heads` packed heads (`pack` value heads side
+    by side each). kq_ref [dk, LANES]: column j the key of the block's
+    j-th key head, column LANES / 2 + j its query, so that a key lies
+    along the state's sublanes; v, a (= exp(g)), beta [heads, pack dv]
+    rows, a and beta one value a head spread over its lanes."""
     del layer_ref
     live = rows_ref[pl.program_id(0)] != null_slot
 
     @pl.when(live)
     def _():
-        dk, dv = s_ref.shape[-2:]
+        dk, width = s_ref.shape[-2:]
+        dv = width // pack
+
+        def column(h, first):
+            """[dk, width] for packed head h: lanes j dv .. (j + 1) dv - 1
+            hold kq_ref's column `first` + the key head of its j-th
+            value head."""
+            at = lambda j: first + (h * pack + j) // repeat
+            col = lambda j: jnp.broadcast_to(kq_ref[:, at(j):at(j) + 1],
+                                             (dk, width))
+            out = col(pack - 1)
+            if pack > 1:
+                lane = jax.lax.broadcasted_iota(jnp.int32, (dk, width), 1)
+                for j in range(pack - 2, -1, -1):
+                    out = jnp.where(lane < (j + 1) * dv, col(j), out)
+            return out
+
         for h in range(heads):
-            kh = h // repeat                # value head h's key head
-            kcol = jnp.broadcast_to(kq_ref[:, kh:kh + 1], (dk, dv))
-            at = _LANES // 2 + kh
-            qcol = jnp.broadcast_to(kq_ref[:, at:at + 1], (dk, dv))
+            kcol, qcol = column(h, 0), column(h, _LANES // 2)
             s = s_ref[h] * a_ref[h:h + 1, :]
             d = beta_ref[h:h + 1, :] * (
                 v_ref[h:h + 1, :] - jnp.sum(s * kcol, axis=0, keepdims=True))
@@ -199,6 +315,19 @@ def _decode_kernel(layer_ref, rows_ref, kq_ref, v_ref, a_ref, beta_ref,
         o_ref[...] = jnp.zeros_like(o_ref)
 
 
+def _decode_block_heads(n_packed: int, pack: int, repeat: int,
+                        head_bytes: int) -> int:
+    """Packed heads a grid step holds: the most that divide `n_packed`,
+    are whole key heads, and stay under `_DECODE_HEADS`, under
+    `_DECODE_BLOCK_BYTES` and under the half of `kq`'s lanes their keys
+    lie in; 0 if none does."""
+    fits = lambda n: (n_packed % n == 0 and (n * pack) % repeat == 0
+                      and n * head_bytes <= _DECODE_BLOCK_BYTES
+                      and n * pack // repeat <= _LANES // 2)
+    return max((n for n in range(1, min(_DECODE_HEADS, n_packed) + 1)
+                if fits(n)), default=0)
+
+
 def gdn_decode_step(state, layer, q, k, v, g, beta, active, *,
                     repeat: int = 1, interpret=None):
     """`reference_gdn_decode_step` as one kernel over the whole stack,
@@ -208,48 +337,59 @@ def gdn_decode_step(state, layer, q, k, v, g, beta, active, *,
     → (o [B, H, dv] float32, the updated stack)."""
     if interpret is None:
         interpret = _interpret_default()
-    L, rows, H, dk, dv = state.shape
+    L, rows, Hp, dk, width = state.shape
     B, Hk, _ = q.shape
-    heads = min(_DECODE_HEADS, H)
-    if (H % heads or heads % repeat or Hk * repeat != H
-            or (not interpret and (dv % _LANES or dk % 8))):
+    H, dv = v.shape[1:]
+    pack = width // dv
+    heads = _decode_block_heads(Hp, pack, repeat, dk * width * 4)
+    if (Hp * pack != H or pack * dv != width or Hk * repeat != H or not heads
+            or (not interpret and (width % _LANES or dk % 8))):
         raise ValueError(
-            f"gdn_decode_step wants value heads in blocks of {heads}, "
-            f"{repeat} a key head, and dv a multiple of {_LANES}; got "
-            f"H={H}, Hk={Hk}, dk={dk}, dv={dv}")
+            f"gdn_decode_step wants a stack [L, slots, H / p, dk, p dv] "
+            f"with p dv a multiple of {_LANES} and dk of 8, {repeat} value "
+            f"heads a key head, in blocks of whole key heads; got "
+            f"state {state.shape}, H={H}, Hk={Hk}, dv={dv}")
     null_slot = rows - 1
     slot_rows = jnp.where(active, jnp.arange(B, dtype=jnp.int32), null_slot)
     # A head block's keys and queries with dk along the sublanes:
-    # [B, H / heads, dk, its keys | 0 | its queries | 0].
-    n_hb, half = H // heads, _LANES // 2
+    # [B, Hp / heads, dk, its keys | 0 | its queries | 0].
+    n_hb, half = Hp // heads, _LANES // 2
+    keys = heads * pack // repeat
     cols = lambda t: jnp.pad(
-        t.astype(_F32).reshape(B, n_hb, heads // repeat, dk),
-        ((0, 0), (0, 0), (0, half - heads // repeat), (0, 0)))
+        t.astype(_F32).reshape(B, n_hb, keys, dk),
+        ((0, 0), (0, 0), (0, half - keys), (0, 0)))
     kq = jnp.swapaxes(jnp.concatenate([cols(k), cols(q)], axis=2), 2, 3)
-    spread = lambda t: jnp.broadcast_to(t.astype(_F32)[..., None], (B, H, dv))
-    per_head = pl.BlockSpec((None, heads, dv), lambda b, hb, *_: (b, hb, 0))
+    # A packed head's row is its heads' rows side by side; a block's
+    # rows a plane of their own, [B, Hp / heads, heads, p dv] (a block of
+    # 5 of 15 rows is neither whole sublane tiles nor the whole axis).
+    side = lambda t: t.reshape(B, n_hb, heads, width)
+    spread = lambda t: side(jnp.broadcast_to(t.astype(_F32)[..., None],
+                                             (B, H, dv)))
+    per_head = pl.BlockSpec((None, None, heads, width),
+                            lambda b, hb, *_: (b, hb, 0, 0))
     block = pl.BlockSpec(
-        (None, None, heads, dk, dv),
+        (None, None, heads, dk, width),
         lambda b, hb, layer, slot, *_: (layer[0], slot[b], hb, 0, 0))
-    kernel = functools.partial(_decode_kernel, heads=heads, repeat=repeat,
-                               null_slot=null_slot)
+    kernel = functools.partial(_decode_kernel, heads=heads, pack=pack,
+                               repeat=repeat, null_slot=null_slot)
     o, state = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(B, H // heads),
+            num_scalar_prefetch=2, grid=(B, n_hb),
             in_specs=[pl.BlockSpec((None, None, dk, _LANES),
                                    lambda b, hb, *_: (b, hb, 0, 0)),
                       per_head, per_head, per_head, block],
             out_specs=[per_head, block]),
-        out_shape=[jax.ShapeDtypeStruct((B, H, dv), _F32),
+        out_shape=[jax.ShapeDtypeStruct((B, n_hb, heads, width), _F32),
                    jax.ShapeDtypeStruct(state.shape, state.dtype)],
         input_output_aliases={6: 1},
         interpret=interpret,
         name="gdn_decode_step",
     )(jnp.asarray(layer, jnp.int32).reshape(1), slot_rows, kq,
-      v.astype(_F32), spread(jnp.exp(g)), spread(beta), state)
-    return o, state
+      side(v.astype(_F32)), spread(jnp.exp(g)), spread(beta), state)
+    return o.reshape(B, H, dv), state
 
 
 __all__ = ["gdn_chunk_scan", "gdn_decode_step", "reference_gdn_scan",
-           "reference_gdn_decode_step"]
+           "reference_gdn_decode_step", "packed_heads", "pack_state",
+           "unpack_state"]

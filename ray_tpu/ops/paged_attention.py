@@ -23,8 +23,11 @@ Design notes:
   the prefill kernel (4 pages = 256 keys at the served shapes),
   `decode_block_pages` in the decode kernel (up to 512 KiB of K a
   block: 16 pages of 32 KB at zaya1-8b, 4 of 128 KB at laguna-s-2.1, 2
-  of 256 KB at opt-1.3b; a latent pool's by its keys: 8 pages of 80 KB
-  at kimi-k2.6).
+  of 256 KB at opt-1.3b, ONE of 480 KB = 64 keys at olmo-hybrid-7b's
+  30 x 128 multi-head rows; a latent pool's by its keys: 8 pages of 80
+  KB at kimi-k2.6). One page a block is no collapse where the page is
+  that wide: its DMA (1.2 us) covers the softmax chain, and the call
+  reads 87.6 % of the HBM peak there (PERF.md, PR 60).
 - How a page gets to VMEM differs. Prefill: the grid is (row, kv block),
   the pool is handed over once a column of the block, and the K/V
   BlockSpec index map selects block ``(layer, tables[b, j], 0, 0)``:

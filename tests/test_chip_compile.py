@@ -419,7 +419,9 @@ def step_program(request, chip):
              "qwen3_next": ("qwen3_next_serving", "qwen3_next", Q_SLOTS, 64),
              "mimo_v2": ("mimo_v2_serving", "mimo_v2", M_SLOTS, 96),
              "jamba": ("jamba_serving", "jamba", J_SLOTS, J_WIDTH),
-             "kimi_k2": ("kimi_k2_serving", "kimi_k2", K2_SLOTS, K2_WIDTH)}
+             "kimi_k2": ("kimi_k2_serving", "kimi_k2", K2_SLOTS, K2_WIDTH),
+             "olmo_hybrid": ("olmo_hybrid_serving", "olmo_hybrid", O_SLOTS,
+                             O_WIDTH)}
     i32 = lambda *shape: chip(shape, jnp.int32)
 
     @functools.cache
@@ -1324,6 +1326,127 @@ def test_kimi_k2_program_fits_and_moves_no_plane(kimi_k2_serving,
     assert 6.98e9 < weight_bytes < 7.00e9 and 7.54e9 < pool_bytes < 7.56e9
     assert abs(mem.argument_size_in_bytes - weight_bytes - pool_bytes) < 1e7
     assert 14.5e9 < total < 15.2e9
+
+
+# --- the olmo_hybrid family: a 96 x 192 delta state, 3,840-lane pages ----
+# Olmo-Hybrid-7B as `benchmarks/configs/olmo-hybrid-7b.json` serves it:
+# stage 0 of a four-stage pipeline, layers 0-7 (six Gated DeltaNet layers
+# of 30 heads of 96 x 192, two multi-head attention layers of 30 x 128,
+# MLPs of 11,008), the whole vocabulary of 100,352; 96 slots x 44 pages of
+# 64 tokens.
+O_SLOTS, O_PAGES, O_WIDTH, O_H, O_K = 96, 4224, 44, 30, 128
+
+
+@pytest.fixture(scope="module")
+def olmo_hybrid_serving(chip):
+    """(cfg, params, pool) of the olmo-hybrid cell as shapes on one
+    described chip (the tree is served as `param_specs` shapes it: the
+    family has no `lay_out`), with the two backend questions steered to
+    the chip's answers."""
+    import importlib
+
+    from ray_tpu.models import olmo_hybrid
+
+    cfg = olmo_hybrid.OlmoHybridConfig(n_layers=8)
+    params = _served(chip, cfg, _stacks(chip, olmo_hybrid, cfg))
+    pool = jax.tree.map(
+        lambda x: chip(x.shape, x.dtype),
+        jax.eval_shape(lambda: olmo_hybrid.init_paged_kv(
+            cfg, O_PAGES, PS, O_SLOTS)))
+    mods = [importlib.import_module("ray_tpu.ops." + m)
+            for m in ("paged_attention", "gated_delta")]
+    saved = [m._interpret_default for m in mods]
+    for m in mods:
+        m._interpret_default = lambda: False
+    yield cfg, params, pool
+    for m, fn in zip(mods, saved):
+        m._interpret_default = fn
+
+
+def test_decode_kernels_compile_at_olmo_hybrid_rows(chip):
+    """Multi-head rows of 30 x 128 = 3,840 lanes, 480 KB a page and
+    plane: the decode kernel attends ONE page a block under a
+    block-diagonal query of [30, 3840], and the chip's compiler takes
+    it; the gated delta step's kernel takes 15 pairs of heads of
+    [96, 384] in blocks of 5."""
+    from ray_tpu.ops.gated_delta import gdn_decode_step
+    from ray_tpu.ops.paged_attention import decode_block_pages
+
+    assert decode_block_pages(O_WIDTH, PS, O_H * O_K, 2, O_H) == 1
+    pool = chip((2, O_PAGES + 1, PS, O_H * O_K), jnp.bfloat16)
+    _compile(lambda q, k, v, l, t, n: paged_attention(
+        q, k, v, l, t, n, interpret=False),
+        chip((O_SLOTS, O_H, O_K), jnp.bfloat16), pool, pool, _layer(chip),
+        chip((O_SLOTS, O_WIDTH), jnp.int32), chip((O_SLOTS,), jnp.int32),
+        kernels=("paged_decode_attn",))
+    f32 = lambda *s: chip(s, jnp.float32)
+    _compile(lambda s, l, q, k, v, g, b, a: gdn_decode_step(
+        s, l, q, k, v, g, b, a, interpret=False),
+        f32(6, O_SLOTS + 1, 15, 96, 384), _layer(chip),
+        f32(O_SLOTS, 30, 96), f32(O_SLOTS, 30, 96), f32(O_SLOTS, 30, 192),
+        f32(O_SLOTS, 30), f32(O_SLOTS, 30), chip((O_SLOTS,), jnp.bool_),
+        kernels=("gdn_decode_step",))
+
+
+@pytest.mark.parametrize("program", ONE_WIDTH_PROGRAMS)
+def test_olmo_hybrid_program_fits_and_moves_no_state(olmo_hybrid_serving,
+                                                     step_program, program):
+    """The olmo_hybrid family's step programs (decode, and the chunk
+    program at both of the engine's heights), compiled whole at the
+    cell's size: the layers are two nested loops, so the attention call
+    over 3,840-lane rows and (in decode) the gated delta step are in
+    them ONCE, under the names a trace finds them by; no layer of the
+    recurrent state (97 slots x 15 pairs
+    of [96, 384] float32, 215 MB: the PACKED leaf, which the chip's
+    tiles hold dense) is copied, sliced out or put back; no weight plane
+    is cut out of its stack into a buffer of its own (the tree is
+    stacks: a copy a layer of each plane, 3.3 GB, does not fit beside
+    this pool); the donated pool is updated in place; the ARGUMENTS'
+    bytes are what the cell's arithmetic says: 4.87 GB of weights +
+    9.64 GB of pool, under the chip's 16 GB with what the program needs
+    besides."""
+    cfg, params, pool = olmo_hybrid_serving
+    assert pool["gdn_state"].shape == (6, O_SLOTS + 1, 15, 96, 384)
+    assert pool["gdn_conv"].shape == (6, O_SLOTS + 1, 3, 11520)
+    assert pool["k"].shape == (2, O_PAGES + 1, PS, O_H * O_K)
+    compiled = step_program("olmo_hybrid", program)
+    # The layers are ONE loop over the periods around a loop over a
+    # period's linear layers: a program holds each kernel once.
+    text = compiled.as_text()
+    # (two nested loops of layers; a chunk program's scan walks its rows
+    # in loops of its own)
+    loops = len(re.findall(r" while\(", text))
+    assert loops == 2 if program == "decode" else loops >= 2
+    kernels = [_attn_kernel(program)]
+    if program == "decode":
+        kernels.append("gdn_decode_step")
+    for name in kernels:
+        assert len(re.findall(rf"%\w*{name}[\w.]* = [^\n]*custom-call\(",
+                              text)) == 1, name
+    moved = (_pool_moves(text, "f32", (O_SLOTS + 1) * 15 * 96 * 384)
+             + _pool_moves(text, "bf16", (O_PAGES + 1) * PS * O_H * O_K))
+    assert not moved, "state- or plane-sized moves:\n" + "\n".join(moved)
+    planes = {",".join(map(str, a.shape[1:])) for name, a in params.items()
+              if len(a.shape) == 3 and a.shape[1] * a.shape[2] >= _PLANE}
+    assert {"3840,17280", "5760,3840", "3840,11520", "3840,11008",
+            "11008,3840"} <= planes
+    made = _planes_made(text, planes)
+    assert not made, "weight planes written out:\n" + "\n".join(made)
+    assert _weight_planes_written_to_hbm(text, planes) == {}
+    mem = compiled.memory_analysis()
+    nbytes = lambda tree: sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                              for a in jax.tree.leaves(tree))
+    pool_bytes, weight_bytes = nbytes(pool), nbytes(params)
+    assert mem.alias_size_in_bytes >= pool_bytes
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    print(f"olmo_hybrid {program}: arguments "
+          f"{mem.argument_size_in_bytes / 1e9:.3f} GB (weights "
+          f"{weight_bytes / 1e9:.3f} + pool {pool_bytes / 1e9:.3f}), temp "
+          f"{mem.temp_size_in_bytes / 1e9:.3f} GB, total {total / 1e9:.3f} GB")
+    assert 4.86e9 < weight_bytes < 4.88e9 and 9.63e9 < pool_bytes < 9.66e9
+    assert abs(mem.argument_size_in_bytes - weight_bytes - pool_bytes) < 1e7
+    assert 14.5e9 < total < 15.3e9
 
 
 # --- the sampling step: the draw under a conditional (PR 55) ------------

@@ -1,7 +1,7 @@
 """Every family's chunk, decode-step and window-step program, letter for
 letter.
 
-The serving programs of the seven families of models/serving.py are built
+The serving programs of the eight families of models/serving.py are built
 by one builder (models/paged_kv.py `paged_programs`) from parts that
 several families share (models/blocks.py). A refactor of either must
 leave every lowered program as it was: the digests below were computed
@@ -16,8 +16,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from ray_tpu.models import (gpt, jamba, kimi_k2, laguna, mimo_v2, paged_kv,
-                            qwen3_next, zaya)
+from ray_tpu.models import (gpt, jamba, kimi_k2, laguna, mimo_v2,
+                            olmo_hybrid, paged_kv, qwen3_next, zaya)
 
 PAGE, N_PAGES, N_SLOTS, CHUNK = 16, 24, 3, 16
 
@@ -64,24 +64,42 @@ PAGE, N_PAGES, N_SLOTS, CHUNK = 16, 24, 3, 16
 # (compared equation for equation with the parent's jaxpr); where the
 # block is less (12 of 384 held), tests/test_chip_compile.py holds the
 # compiled program. `zaya`, which holds every expert and counts neither
-# share nor overflow, `gpt` and `jamba` stayed.
+# share nor overflow, `gpt` and `jamba` stayed. The three of `qwen3_next`
+# as PR 60 traced them, which meant to change them in `ops/gated_delta.py`
+# alone, for the family that shares it (`olmo_hybrid`, whose three are
+# its first): `qwen3_next.chunk` inverts the unit lower-triangular block
+# by substitution over 8-token diagonal blocks (the batch of blocks along
+# the lanes) merged pairwise, where it summed the nilpotent series (that form lost every digit at beta up to 2
+# and correlated keys: tests/test_gated_delta.py), and sums a decay ratio's
+# exponent G_i - G_j from its own terms where it took the difference of
+# two cumulated sums (one token at g = -1e10 left every later difference
+# a multiple of 512); `qwen3_next.decode`
+# and `.sample` hand `gdn_decode_step` the heads' value, decay and beta
+# rows as [B, H / 16, 16, dv] where they were [B, H, dv] (a reshape on
+# either side of the call: a block of 5 of olmo-hybrid's 15 packed rows
+# is neither whole sublane tiles nor the whole axis), the kernel's body
+# equation for equation what it was at one head a packed head. The other
+# eighteen stayed.
 _PINNED = {
     "gpt.chunk": "aff570174e390475", "gpt.decode": "c4ebbb44ff02a83f",
     "zaya.chunk": "e3c3c03e1e115475", "zaya.decode": "21818dedb3b70792",
     "laguna.chunk": "416053d2794175ae", "laguna.decode": "2d25d646070c14ab",
-    "qwen3_next.chunk": "f5f9bca2843387ad",
-    "qwen3_next.decode": "e726d23ec48485cd",
+    "qwen3_next.chunk": "1bdf3087055bdd93",
+    "qwen3_next.decode": "cef6eae62e3648d3",
     "mimo_v2.chunk": "6c90d3e82314369a",
     "mimo_v2.decode": "acce4f518d730655",
     "gpt.sample": "057837dac4200223", "zaya.sample": "55f97b147af972ff",
     "laguna.sample": "613e21949deeeba0",
-    "qwen3_next.sample": "b772c28ed831e0e8",
+    "qwen3_next.sample": "4dcf0d487ad684ba",
     "mimo_v2.sample": "778467ea27873009",
     "jamba.chunk": "16c983f57626c3f6", "jamba.decode": "5262465ac313b68d",
     "jamba.sample": "09798055e3a58725",
     "kimi_k2.chunk": "c7042b4b88e13c46",
     "kimi_k2.decode": "aacbe068562d9c01",
     "kimi_k2.sample": "d123ed2e5fdcf2d5",
+    "olmo_hybrid.chunk": "d3337227332042d8",
+    "olmo_hybrid.decode": "54ec2aa7a880ab04",
+    "olmo_hybrid.sample": "8099e350910eabed",
 }
 
 _RING = {"dispatch_tokens": 2 * CHUNK}
@@ -96,6 +114,8 @@ _FAMILIES = {
     "mimo_v2": (mimo_v2, mimo_v2, mimo_v2.MiMoV2Config.tiny(), _RING),
     "jamba": (jamba, jamba, jamba.JambaConfig.tiny(), {}),
     "kimi_k2": (kimi_k2, kimi_k2, kimi_k2.KimiK2Config.tiny(), {}),
+    "olmo_hybrid": (olmo_hybrid, olmo_hybrid,
+                    olmo_hybrid.OlmoHybridConfig.tiny(), {}),
 }
 
 

@@ -391,10 +391,12 @@ _DECODE_SHAPES = {
     "laguna-s-2.1.window": dict(page_size=64, kv_lanes=1024, kv_itemsize=2,
                                 n_heads=72),
     "qwen3-next-80b-a3b": dict(page_size=64, kv_lanes=512, kv_itemsize=2,
-                               n_heads=16)}
+                               n_heads=16),
+    "olmo-hybrid-7b": dict(page_size=64, kv_lanes=3840, kv_itemsize=2,
+                           n_heads=30)}
 _DECODE_BLOCK_AT_FULL_WIDTH = {"opt-1.3b": 2, "zaya1-8b": 16,
                                "laguna-s-2.1": 4, "laguna-s-2.1.window": 4,
-                               "qwen3-next-80b-a3b": 8}
+                               "qwen3-next-80b-a3b": 8, "olmo-hybrid-7b": 1}
 
 
 @pytest.mark.parametrize("model", sorted(_DECODE_SHAPES))
@@ -1643,3 +1645,75 @@ def test_the_latent_form_refuses_what_it_does_not_read(fault):
         with pytest.raises(ValueError, match="latent form: want ONE bf16"):
             call(q[:, None], pool, v, jnp.int32(0), tables, n - 1, n,
                  latent=latent, **kw)
+
+
+# --- wide multi-head rows (olmo-hybrid-7b: 30 x 128 = 3,840 lanes) --------
+
+@pytest.mark.parametrize("kv,n_pg,block", [
+    ("bfloat16", 8, 1),     # the cell's shapes: 480 KB a page, one a block
+    ("float32", 5, 1),      # f32 pages of 960 KB, a table of odd width
+    ("int8", 8, 2),         # an int8 pool: 240 KB a page, two a block
+])
+def test_decode_kernel_at_wide_multi_head_rows(kv, n_pg, block):
+    """Multi-head rows of 30 x 128 = 3,840 lanes at 64 tokens a page (480
+    KB a plane in bf16: ONE page a block by the bytes' rule, the widest
+    block-diagonal query any family hands the kernel): the kernel is its
+    oracle over lengths on, under and over every block boundary, a slot
+    of one token and an idle slot, at a layer other than 0; an int8
+    pool's pages keep a scale each."""
+    H = G = 30
+    K, ps = 128, 64
+    dtype = jnp.bfloat16 if kv == "bfloat16" else jnp.float32
+    item = 1 if kv == "int8" else jnp.dtype(dtype).itemsize
+    n = decode_block_pages(n_pg, ps, G * K, item, H)
+    assert n == block
+    lengths, n_live = _decode_boundary_lengths(ps, n_pg, n)
+    B = len(lengths)
+    rng = np.random.default_rng(31)
+    tables, page = np.zeros((B, n_pg), np.int32), 1
+    for b, end in enumerate(lengths[:n_live]):
+        live = -(-end // ps)
+        tables[b, :live] = np.arange(page, page + live)
+        page += live
+    shape = (N_LAYERS, page, ps, G * K)
+    scales = {}
+    if kv == "int8":
+        (k_pool, v_pool), (ks, vs) = _quantized(rng, shape)
+        scales = {"k_scale": ks, "v_scale": vs}
+    else:
+        k_pool, v_pool = (jnp.asarray(rng.normal(size=shape), dtype)
+                          for _ in range(2))
+    q = jnp.asarray(rng.normal(size=(B, H, K)), dtype)
+    args = (jnp.int32(N_LAYERS - 1), jnp.asarray(tables),
+            jnp.asarray(lengths, jnp.int32))
+    o = paged_attention(q, k_pool, v_pool, *args, **scales)
+    ref = reference_paged_attention(q, k_pool, v_pool, *args, **scales)
+    assert o.shape == q.shape and o.dtype == q.dtype
+    np.testing.assert_allclose(
+        np.asarray(o, np.float32), np.asarray(ref, np.float32),
+        atol=3e-2 if kv == "bfloat16" else 1e-5)
+
+
+def test_the_wide_row_block_is_the_engines_too():
+    """The engine's `decode_block_fill` counter rounds a slot's pages up
+    to the block the kernel walks: ONE page at olmo-hybrid-7b's shapes,
+    so every fetched page is a live one."""
+    import types
+
+    from ray_tpu.serve.llm import LLMEngine
+
+    plane = jax.ShapeDtypeStruct((2, 9, 64, 3840), jnp.bfloat16)
+    engine = types.SimpleNamespace(
+        _decode_block_at={}, page_size=64, tp=1, n_slots=4,
+        cfg=types.SimpleNamespace(n_heads=30, head_dim=128),
+        cache={"k": plane, "v": plane},
+        positions=np.asarray([0, 127, 128, 2815]),
+        pool=types.SimpleNamespace(pages_for=lambda pos: pos // 64 + 1),
+        stats=dict.fromkeys(("decode_pages_live", "decode_pages_fetched",
+                             "decode_columns"), 0))
+    engine._kv_planes = lambda: LLMEngine._kv_planes(engine)
+    LLMEngine._count_decode_pages(engine, [0, 1, 2, 3], 44)
+    assert engine._decode_block_at == {44: 1}
+    assert engine.stats == {"decode_pages_live": 1 + 2 + 3 + 44,
+                            "decode_pages_fetched": 1 + 2 + 3 + 44,
+                            "decode_columns": 4 * 44}
