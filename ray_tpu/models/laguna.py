@@ -351,7 +351,11 @@ def ring_pool(cfg, n_pages: int, page_size: int, n_slots: int,
     to its paged programs: the full layers' pages ``[n_full, P+1,
     page_size, lanes]`` (row 0 the null page), the window layers' rings
     ``[n_window, (n_slots+1)*R, page_size, lanes]`` with their row ids
-    `ring_rows` ``[n_slots+1, R]`` (the last ring the null slot's), and
+    `ring_rows` ``[n_slots+1, R]`` (the last ring the null slot's; a
+    ring is R CONSECUTIVE rows of the plane, ``ring_rows[s, c] ==
+    ring_rows[s, 0] + c``: the window decode call fetches a slot's window
+    as a run of the plane's rows and is owed that,
+    ops/paged_attention.py `_check_ring`), and
     the decode steps' `n_counters` running expert counters. `lanes`: the
     minor width of each plane ("k", "v", "k_win", "v_win"): a kind's KV
     heads x its K or V head size. `dispatch_tokens`: the most tokens of

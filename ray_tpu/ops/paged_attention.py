@@ -42,8 +42,8 @@ Design notes:
   column cost ~0.05 us an operand, live or dead: 4.9 of the call's
   5.6 ms at zaya1-8b, for 2.1 ms of bytes (PERF.md, PR 37). In the flat
   loop a dead column of a live block gets no DMA and is position-masked,
-  and a dead block, an idle slot and a ring's columns outside the window
-  are never visited: no operand, no step, no index map.
+  and a dead block and an idle slot are never visited: no operand, no
+  step, no index map.
 - The per-head split happens in VMEM, after the read. Decode: the
   slot's query row ``[1, H*K]`` is spread into a block-diagonal
   ``[H, H*K]`` (row h keeps head h's K lanes, zeros elsewhere), so
@@ -74,13 +74,29 @@ Design notes:
   so table column c no longer holds logical page c. `col_page[b, c]` is
   the logical page column c holds NOW (-1: none), a key's position is
   ``col_page[b, c] * page_size + i``, and it is attended when
-  ``pos - window < key <= pos``; a block whose every column lies wholly
-  outside is skipped like a null tail (prefill: under `pl.when`;
-  decode: never visited). Columns arrive
-  in ring order, which the online softmax does not mind. With `window=None` (every caller before
+  ``pos - window < key <= pos``. With `window=None` (every caller before
   the window kind) column c is page c and the kernels trace what they
   always did; the window calls carry names of their own in a trace
-  (`paged_decode_attn_window`, `paged_prefill_attn_window`).
+  (`paged_decode_attn_window`, `paged_prefill_attn_window`). Prefill
+  goes by `col_page` (a block whose every column lies wholly outside the
+  window is skipped under `pl.when`; columns arrive in ring order, which
+  the online softmax does not mind). DECODE has a walk of its own
+  (`_window_walk`, `_window_decode_kernel`; PERF.md, PR 61), because the
+  page walk was the wrong shape for a ring: 128 live keys in a ring of
+  19 columns lay in 3 columns and 2 blocks of 2 (a 192 KB page, the
+  512 KiB cap), so a slot paid two score tiles and two softmax chains,
+  and the call's compute alone (0.224 ms at mimo-v2-flash's shapes, 128
+  slots) outlasted its DMAs alone (0.176 ms: three whole pages a slot at
+  714 GB/s). A ring is R consecutive rows of the pool and logical page p
+  lies in column p % R (`_check_ring`: the contract models/laguna.py
+  `ring_pool` and `_ring_view` keep), so a slot's window is a RUN OF ROWS
+  of its ring known from its length alone: the kernel takes the pools as
+  planes of rows and fetches, a slot, the rows from the sublane tile its
+  first attended key lies in to the tile its last one does (144 rows for
+  128 keys, 528 for 512: ONE copy a plane, two runs where the window
+  passes the ring's end), as ONE block (`window_block_rows`) with one
+  wait, one score tile and one softmax with no state carried. No column
+  is looked up, nothing is sought, and `col_page` is not an operand.
 - Many query heads (prefill): the query block, accumulator and (m, l)
   state of H heads x C rows pass the kernel's VMEM at 48 or 72 heads of
   128. `prefill_kv_split` then gives the grid a KV-head axis: a grid
@@ -148,6 +164,7 @@ import types
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -224,11 +241,43 @@ def _check_window(window, col_page, tables, quantized):
             + (" and an int8 pool" if quantized else ""))
 
 
+def _check_ring(tables, col_page, lengths, page_size):
+    """The window decode call's contract, refused where it can be read
+    (outside a trace; inside one it is the caller's, and
+    models/laguna.py `ring_pool` and `_ring_view` keep it): a slot's ring
+    is R CONSECUTIVE rows of the pool, ``tables[b, c] == tables[b, 0] +
+    c``, and logical page p of the slot lies in column p % R, so that
+    `col_page` is the view the slot's own length gives: with
+    ``last_page = (lengths - 1) // page_size``, column c holds page
+    ``last_page - (last_page - c) % R``, -1 where that is negative. The
+    kernel fetches a slot's window as rows of the pool by that closed
+    form and reads neither operand's columns."""
+    if any(isinstance(a, jax.core.Tracer) for a in (tables, col_page,
+                                                    lengths)):
+        return
+    R = tables.shape[1]
+    cols = np.arange(R)[None]
+    tables = np.asarray(tables)
+    if not np.array_equal(tables, tables[:, :1] + cols):
+        raise ValueError(
+            "a window decode call fetches a slot's window as consecutive "
+            "rows of the pool: a ring's table must be R consecutive page "
+            "ids a slot (models/laguna.py `ring_pool`)")
+    last = ((np.asarray(lengths) - 1) // page_size)[:, None]
+    view = last - (last - cols) % R
+    if not np.array_equal(np.where(view < 0, -1, view), np.asarray(col_page)):
+        raise ValueError(
+            "a window decode call walks a slot's ring in closed form: "
+            "`col_page` must be the ring's own view of `lengths` (column c "
+            "holds page last_page - (last_page - c) % R, -1 where negative; "
+            "models/laguna.py `_ring_view`)")
+
+
 def _call_form(name, layer, scalars, k_scale, v_scale, window, col_page):
-    """(the call's name in a trace, its scalar-prefetch operands, the
-    kernel's extra keywords): a window layer's ring adds `col_page` to
-    the scalars and `_window` to the name; without one the call is the
-    one every family had."""
+    """(a prefill call's name in a trace, its scalar-prefetch operands,
+    the kernel's extra keywords): a window layer's ring adds `col_page`
+    to the scalars and `_window` to the name; without one the call is
+    the one every family had."""
     if window is None:
         return name, _prefetch(layer, scalars, k_scale, v_scale), {}
     return (name + "_window",
@@ -348,25 +397,15 @@ def _first_key(col, page_size):
     return jnp.where(col < 0, _NO_PAGE, col * page_size)
 
 
-def _column_live(first, kv_len, page_size, window):
-    """Whether a decode step attends any key of the page(s) that start at
-    `first`: one lies under the kv length and, in a ring, inside the
-    window of the query at ``kv_len - 1``."""
-    live = first < kv_len
-    if window is not None:
-        live &= first + page_size > kv_len - window
-    return live
-
-
-def _block_walk(tables_ref, lengths_ref, col_ref, pools, sem, layer, *,
-                n, ps, window, end):
-    """What both decode kernels walk a slot group's LIVE kv blocks by, in
-    (slot, block) order up to slot `end`: `columns(b, j)`, `after(b, j)`,
-    `seek(b, j)`, `copies(act, b, cols, buf, go)` and `fetch(item, buf)`.
-    A block is `n` consecutive table columns of `ps` keys; `pools`:
-    ((pool in HBM, its [buffers, n*ps, lanes] VMEM buffers), ...), one DMA
-    semaphore a buffer index in `sem`; `col_ref`: a ring's `col_page`
-    with `window`, else None."""
+def _block_walk(tables_ref, lengths_ref, pools, sem, layer, *, n, ps, end):
+    """What the full and the latent decode kernels walk a slot group's
+    LIVE kv blocks by, in (slot, block) order up to slot `end`:
+    `columns(b, j)`, `after(b, j)`, `seek(b, j)`,
+    `copies(act, b, cols, buf, go)` and `fetch(item, buf)`. A block is `n`
+    consecutive table columns of `ps` keys, column c logical page c;
+    `pools`: ((pool in HBM, its [buffers, n*ps, lanes] VMEM buffers),
+    ...), one DMA semaphore a buffer index in `sem`. (A ring has a walk
+    of its own, `_window_walk`.)"""
     n_pg = tables_ref.shape[1]
     n_blk = -(-n_pg // n)
     block = n * ps
@@ -380,19 +419,9 @@ def _block_walk(tables_ref, lengths_ref, col_ref, pools, sem, layer, *,
         for i in range(n):
             c = j * n + i
             held = jnp.minimum(c, n_pg - 1) if ragged else c
-            first = (c * ps if window is None
-                     else _first_key(col_ref[b, held], ps))
-            if ragged and window is not None:   # past the ring: no page
-                first = jnp.where(c < n_pg, first, _NO_PAGE)
-            out.append((_column_live(first, kv_len, ps, window), held,
-                        first))
+            first = c * ps
+            out.append((first < kv_len, held, first))
         return out
-
-    def block_live(b, j):
-        if window is None:
-            return j * block < lengths_ref[b]
-        return functools.reduce(jnp.logical_or,
-                                [live for live, _, _ in columns(b, j)])
 
     def after(b, j):
         """(slot, block) after (b, j) in the order the walk goes."""
@@ -402,15 +431,15 @@ def _block_walk(tables_ref, lengths_ref, col_ref, pools, sem, layer, *,
     def seek(b, j):
         """The first live block at or after (b, j) in (slot, block)
         order; a slot at or past `end` when the group has none left."""
-        def dead(at):
-            return (at[0] < end) & ~block_live(jnp.minimum(at[0], end - 1),
-                                               at[1])
+        def live(b, j):
+            return j * block < lengths_ref[b]
 
-        def skip(at):
-            if window is None:      # the blocks after a dead one are dead
-                return at[0] + 1, jnp.int32(0)
-            return after(*at)
-        return jax.lax.while_loop(dead, skip, (b, j))
+        def dead(at):
+            return (at[0] < end) & ~live(jnp.minimum(at[0], end - 1), at[1])
+
+        # The blocks after a dead one are dead: on to the next slot.
+        return jax.lax.while_loop(
+            dead, lambda at: (at[0] + 1, jnp.int32(0)), (b, j))
 
     def copies(act, b, cols, buf, go=True):
         """`act` ("start" or "wait") on the K and the V copy of every
@@ -435,18 +464,133 @@ def _block_walk(tables_ref, lengths_ref, col_ref, pools, sem, layer, *,
                                  copies=copies, fetch=fetch)
 
 
+def _slot_softmax(q_ref, sink_ref, o_ref, qbd_ref, m_ref, l_ref, acc_ref, *,
+                  sm_scale, n_heads, n_kv_heads, v_head_dim, base):
+    """One slot's attention over its kv blocks, as the full and the window
+    decode kernels both do it, whatever walk brings the blocks:
+    `init(b)`, then a block `query()`, `scores(qbd, k)`, the kernel's own
+    position mask, `fold(s, v)`, and `finish(b)`. A block pays ONE score
+    tile, one masked row maximum, one `exp`, one partial row sum and one
+    accumulator update whatever it holds (PERF.md, PR 37).
+
+    Multi-head (G = H): a slot's query is one dense row [1, H*K].
+    Grouped (G < H): it is [H, K], a head a row, and the pool's minor
+    axis holds G heads; row h of the block-diagonal query then sits in
+    the lanes of KV head h // (H/G), so the two matmuls are the same.
+    K and V of unequal head size take the grouped form whatever G."""
+    grouped = n_kv_heads != n_heads or v_head_dim is not None
+    head_dim = q_ref.shape[-1] if grouped else q_ref.shape[-1] // n_heads
+    v_dim = v_head_dim or head_dim
+    mask = lambda: _head_mask(n_heads, head_dim, n_kv_heads)
+    v_mask = mask if v_dim == head_dim else (
+        lambda: _head_mask(n_heads, v_dim, n_kv_heads))
+    GK = acc_ref.shape[1]
+
+    def block_diagonal(b):
+        # Row h = the query with every lane outside head h's KV head
+        # zeroed (the select runs in fp32: the mask is built from 32-bit
+        # iotas).
+        q = q_ref[b - base].astype(jnp.float32)
+        q = (jnp.concatenate([q] * n_kv_heads, axis=1) if grouped
+             else jnp.broadcast_to(q, qbd_ref.shape))
+        return jnp.where(mask(), q, 0.0).astype(qbd_ref.dtype)
+
+    def init(b):
+        _seed_state(m_ref, l_ref, None if sink_ref is None else sink_ref[...])
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        qbd_ref[...] = block_diagonal(b)
+
+    def query(upcast=False):
+        qbd = qbd_ref[...]                   # [H, G*K]
+        return qbd.astype(jnp.float32) if upcast else qbd
+
+    def scores(qbd, k):
+        # s[h, t] = q[h] · k[t, head h's lanes]: the block-diagonal query
+        # makes it one [H, G*K] x [block, G*K]ᵀ matmul. Decode attention
+        # is HBM-bound (~2 flops/byte), so the H-fold surplus of
+        # multiplies by zero is free next to reading the pages once.
+        return jax.lax.dot_general(
+            qbd, k, _NT, preferred_element_type=jnp.float32) * sm_scale
+
+    def fold(s, v):
+        # The state as the prefill kernel keeps it: m lane-uniform
+        # [H, LANES] and used at full width, l a partial sum a lane.
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - _spread(m_new, s.shape[1]))  # [H, block] fp32
+        corr = jnp.exp(m_prev - m_new)               # [H, LANES]
+        l_ref[...] = l_ref[...] * corr + _fold(p)
+        # Row h of [H, G*K]: head h's probabilities against EVERY KV
+        # head's V lanes; only its own block is kept at the end.
+        pv = jnp.dot(p.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+        acc_ref[...] = acc_ref[...] * _spread(corr, GK) + pv
+        m_ref[...] = m_new
+
+    def own_heads(own_lanes, rows):
+        """[H, Kv] (grouped) or [1, H*Kv] out of rows [H, G*Kv]: each
+        row's own KV head's lanes (`own_lanes`: `v_mask()`)."""
+        own = jnp.where(own_lanes, rows, 0.0)
+        if grouped:
+            # One non-zero K-lane block per row: their sum is [H, K].
+            return sum(own[:, g * v_dim:(g + 1) * v_dim]
+                       for g in range(n_kv_heads))
+        # One non-zero row per lane: the sum over rows is the gather
+        # of each head's own block, as one dense [1, H*K] row.
+        return jnp.sum(own, axis=0, keepdims=True)
+
+    def finish(b):
+        l = jnp.sum(l_ref[...], axis=1, keepdims=True)
+        o_ref[b - base] = own_heads(v_mask(), acc_ref[...] / l).astype(
+            o_ref.dtype)
+
+    def whole(b, k, v, keep):
+        """A slot whose keys are ONE block (`k`, `v`; `keep(s)` its
+        position mask): the same softmax with nothing carried between
+        blocks, so no state is seeded, rescaled, stored or read back."""
+        s = keep(scores(block_diagonal(b), k))
+        m = jnp.max(s, axis=1, keepdims=True)
+        if sink_ref is not None:
+            m = jnp.maximum(m, sink_ref[:, :1])
+        p = jnp.exp(s - m)
+        l = jnp.sum(p, axis=1, keepdims=True)
+        if sink_ref is not None:
+            l = l + jnp.exp(sink_ref[:, :1] - m)
+        pv = jnp.dot(p.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+        # (a grouped row is divided after its eight lane blocks are one)
+        out = (own_heads(v_mask(), pv) / l if grouped
+               else own_heads(v_mask(), pv / l))
+        o_ref[b - base] = out.astype(o_ref.dtype)
+
+    return types.SimpleNamespace(init=init, query=query, scores=scores,
+                                 fold=fold, finish=finish, whole=whole)
+
+
+def _finite_rows(v_buf, o_ref):
+    """What a decode kernel's body starts with. A dead column's rows of a
+    buffer keep what they held. Their probabilities are 0, and 0 x NaN is
+    NaN in PV: V's rows are made finite once a call, and only pages of
+    the pool land there after. (K's rows may hold anything: their scores
+    are masked.) An idle slot is never visited: its output is 0."""
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        v_buf[...] = jnp.zeros_like(v_buf)
+    o_ref[...] = jnp.zeros_like(o_ref)
+
+
 def _decode_kernel(
     *refs,
     sm_scale, page_size, block_pages, n_heads, n_kv_heads, quantized=False,
-    window=None, v_head_dim=None, sink=False,
+    v_head_dim=None, sink=False,
 ):
     """Every slot of a group against its LIVE kv blocks, one grid step a
     group (the whole batch at every served shape), the pages fetched by
     the kernel's own DMAs. Ref order: scalar-prefetch (SMEM) first
-    (layer, page tables, kv lengths, a ring's `col_page`, and for an
-    int8 pool the layer's per-page K/V scale vectors), the group's
-    queries (VMEM), the K and V pools WHOLE and left in HBM, the output,
-    and the scratch: `_DECODE_BUFFERS` K and as many V buffers of a block
+    (layer, page tables, kv lengths, and for an int8 pool the layer's
+    per-page K/V scale vectors), the group's queries (VMEM), the K and V
+    pools WHOLE and left in HBM, the output, and the scratch:
+    `_DECODE_BUFFERS` K and as many V buffers of a block
     ([buffers, block_pages*ps, G*K]) with a DMA semaphore a buffer pair,
     the block-diagonal query and the (m, l, acc) softmax state.
 
@@ -455,80 +599,49 @@ def _decode_kernel(
     column's page goes from ``pool[layer, tables[b, c]]`` straight to
     its rows of the buffer (the DMA's destination does the stacking), a
     dead column of a live block gets no DMA and is position-masked, and
-    a dead block, an idle slot and a ring's columns outside the window
-    are never visited. While a block is attended the next live blocks,
-    of this slot or of the next live ones, are already in flight into
-    the other buffers, so no slot waits for its own first page (a wait
-    a slot cost more than the whole grid did: PERF.md, PR 41). A block pays
-    ONE score tile, one masked row maximum, one `exp`, one partial row
-    sum and one accumulator update whatever it holds (PERF.md, PR 37).
+    a dead block and an idle slot are never visited. While a block is
+    attended the next live blocks, of this slot or of the next live
+    ones, are already in flight into the other buffers, so no slot waits
+    for its own first page (a wait a slot cost more than the whole grid
+    did: PERF.md, PR 41). What a block pays: `_slot_softmax`.
     `quantized` is a Python-level trace switch: the int8 program dequants
     each page of the block by its own scale after the wait, inside the
     kernel, and the fp32 plane never exists in HBM. `v_head_dim`: the
     V plane's head size where it is not K's (the accumulator and the
     output are that wide); `sink`: a [H, LANES] operand follows the
     queries, the logit each head's softmax starts from. (A latent pool
-    has a body of its own, `_latent_decode_kernel`.)"""
+    and a window layer's ring have bodies of their own,
+    `_latent_decode_kernel` and `_window_decode_kernel`.)"""
     n, ps = block_pages, page_size
     refs = iter(refs)
     take = lambda count: [next(refs) for _ in range(count)]
     layer_ref, tables_ref, lengths_ref = take(3)
-    (col_ref,) = take(1) if window is not None else (None,)
     ks_ref, vs_ref = take(2) if quantized else (None, None)
     (q_ref,) = take(1)
     (sink_ref,) = take(1) if sink else (None,)
     (k_hbm, v_hbm, o_ref), (k_buf, v_buf) = take(3), take(2)
     pools = ((k_hbm, k_buf), (v_hbm, v_buf))
     sem, qbd_ref, m_ref, l_ref, acc_ref = refs
-    # Multi-head (G = H): a slot's query is one dense row [1, H*K].
-    # Grouped (G < H): it is [H, K], a head a row, and the pool's minor
-    # axis holds G heads; row h of the block-diagonal query then sits in
-    # the lanes of KV head h // (H/G), so the two matmuls are the same.
-    # K and V of unequal head size take the grouped form whatever G.
-    grouped = n_kv_heads != n_heads or v_head_dim is not None
-    head_dim = q_ref.shape[-1] if grouped else q_ref.shape[-1] // n_heads
-    v_dim = v_head_dim or head_dim
-    mask = lambda: _head_mask(n_heads, head_dim, n_kv_heads)
-    v_mask = mask if v_dim == head_dim else (
-        lambda: _head_mask(n_heads, v_dim, n_kv_heads))
     block = n * ps
-    GK = acc_ref.shape[1]
     group = q_ref.shape[0]
     n_buf = k_buf.shape[0]
     base = pl.program_id(0) * group
     end = base + group
     layer = layer_ref[0]
+    _finite_rows(v_buf, o_ref)
 
-    @pl.when(pl.program_id(0) == 0)
-    def _finite_rows():
-        # A dead column's rows of a buffer keep what they held. Their
-        # probabilities are 0, and 0 x NaN is NaN in PV: V's rows are
-        # made finite once, and only pages of the pool land there after.
-        # (K's rows may hold anything: their scores are masked.)
-        v_buf[...] = jnp.zeros_like(v_buf)
-    o_ref[...] = jnp.zeros_like(o_ref)       # an idle slot is never visited
-
-    walk = _block_walk(tables_ref, lengths_ref, col_ref, pools, sem, layer,
-                       n=n, ps=ps, window=window, end=end)
+    walk = _block_walk(tables_ref, lengths_ref, pools, sem, layer,
+                       n=n, ps=ps, end=end)
     columns, after, seek = walk.columns, walk.after, walk.seek
     copies, fetch = walk.copies, walk.fetch
-
-    def init(b):
-        _seed_state(m_ref, l_ref, None if sink_ref is None else sink_ref[...])
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        # Row h = the query with every lane outside head h's KV head
-        # zeroed (the select runs in fp32: the mask is built from 32-bit
-        # iotas).
-        q = q_ref[b - base].astype(jnp.float32)
-        q = (jnp.concatenate([q] * n_kv_heads, axis=1) if grouped
-             else jnp.broadcast_to(q, qbd_ref.shape))
-        qbd_ref[...] = jnp.where(mask(), q, 0.0).astype(qbd_ref.dtype)
+    slot = _slot_softmax(q_ref, sink_ref, o_ref, qbd_ref, m_ref, l_ref,
+                         acc_ref, sm_scale=sm_scale, n_heads=n_heads,
+                         n_kv_heads=n_kv_heads, v_head_dim=v_head_dim,
+                         base=base)
 
     def attend(b, j, cols, buf):
         kv_len = lengths_ref[b]
-        qbd = qbd_ref[...]                   # [H, G*K]
-        if quantized:
-            qbd = qbd.astype(jnp.float32)
+        qbd = slot.query(upcast=quantized)
 
         def stacked(dst, scale_ref):
             if not quantized:
@@ -542,52 +655,11 @@ def _decode_kernel(
 
         k = stacked(k_buf, ks_ref)
         v = stacked(v_buf, vs_ref)
-        # s[h, t] = q[h] · k[t, head h's lanes]: the block-diagonal query
-        # makes it one [H, G*K] x [block, G*K]ᵀ matmul. Decode attention
-        # is HBM-bound (~2 flops/byte), so the H-fold surplus of
-        # multiplies by zero is free next to reading the pages once.
-        s = jax.lax.dot_general(
-            qbd, k, _NT, preferred_element_type=jnp.float32) * sm_scale
+        s = slot.scores(qbd, k)
         # Raggedness: positions at or past the slot's kv length are
         # masked (a live block's dead columns, a partial last page).
         lane = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        if window is None:
-            s = jnp.where(j * block + lane < kv_len, s, NEG_INF)
-        else:       # the query sits at kv_len - 1; column i starts at
-            origin = cols[0][2]                     # lane i * page_size
-            for i in range(1, n):
-                origin = jnp.where(lane >= i * ps, cols[i][2] - i * ps,
-                                   origin)
-            tpos = origin + lane
-            s = jnp.where((tpos < kv_len) & (tpos >= kv_len - window), s,
-                          NEG_INF)
-
-        # The state as the prefill kernel keeps it: m lane-uniform
-        # [H, LANES] and used at full width, l a partial sum a lane.
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - _spread(m_new, block))       # [H, block] fp32
-        corr = jnp.exp(m_prev - m_new)               # [H, LANES]
-        l_ref[...] = l_ref[...] * corr + _fold(p)
-        # Row h of [H, G*K]: head h's probabilities against EVERY KV
-        # head's V lanes; only its own block is kept at the end.
-        pv = jnp.dot(p.astype(v.dtype), v,
-                     preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * _spread(corr, GK) + pv
-        m_ref[...] = m_new
-
-    def finish(b):
-        l = jnp.sum(l_ref[...], axis=1, keepdims=True)
-        own = jnp.where(v_mask(), acc_ref[...] / l, 0.0)
-        if grouped:
-            # One non-zero K-lane block per row: their sum is [H, K].
-            out = sum(own[:, g * v_dim:(g + 1) * v_dim]
-                      for g in range(n_kv_heads))
-        else:
-            # One non-zero row per lane: the sum over rows is the gather
-            # of each head's own block, as one dense [1, H*K] row.
-            out = jnp.sum(own, axis=0, keepdims=True)
-        o_ref[b - base] = out.astype(o_ref.dtype)
+        slot.fold(jnp.where(j * block + lane < kv_len, s, NEG_INF), v)
 
     def visit(at):
         # `ahead`: this block and the ones in flight behind it. The next
@@ -598,11 +670,11 @@ def _decode_kernel(
         b, j = ahead[0]
         ahead += (seek(*after(*ahead[-1])),)
         fetch(ahead[-1], jnp.where(buf == 0, n_buf - 1, buf - 1))
-        pl.when(b != prev)(lambda: init(b))
+        pl.when(b != prev)(lambda: slot.init(b))
         cols = columns(b, j)
         copies("wait", b, cols, buf)
         attend(b, j, cols, buf)
-        pl.when(ahead[1][0] != b)(lambda: finish(b))
+        pl.when(ahead[1][0] != b)(lambda: slot.finish(b))
         return ahead[1:], jnp.where(buf == n_buf - 1, 0, buf + 1), b
 
     ahead = (seek(base, jnp.int32(0)),)
@@ -622,9 +694,14 @@ def _decode_kernel(
 # 256 KB: PERF.md, PR 37; with the kernel's own DMAs half and twice that
 # read within 2 % of it, PR 41), and past it a live block's dead columns
 # cost more compute than the chains saved. That was measured for the
-# full and window kinds (multi-head and grouped, bf16 and int8; their
-# block-diagonal matmuls leave the MXU time to spare), not for a latent
-# pool, whose block goes by `_LATENT_BLOCK_KEYS`. Three buffers a pool: two
+# page walk (multi-head and grouped, bf16 and int8; their block-diagonal
+# matmuls leave the MXU time to spare), not for a latent pool, whose
+# block goes by `_LATENT_BLOCK_KEYS`, and it does not cover a window
+# layer's ring, whose block is a run of rows with no dead column in it:
+# there ONE block a slot read best (`_WINDOW_BLOCK_BYTES`, over the
+# 1,056 KiB of K that laguna-s-2.1's 528 rows take: 0.192 ms a call
+# against 0.211 as two blocks of 272; PERF.md, PR 61). Three buffers a
+# pool: two
 # blocks in flight behind the one attended, because a slot's last block
 # is often a page or two and its DMA ends long before the block's fixed
 # compute does (the third buffer is worth 3 % at 32 KB pages, 15 % in
@@ -634,6 +711,7 @@ def _decode_kernel(
 # group's queries and outputs.
 _DECODE_BLOCK_BYTES = 512 * 2**10
 _DECODE_BLOCK_KEYS = 1024
+_WINDOW_BLOCK_BYTES = 1280 * 2**10
 _DECODE_BUFFERS = 3
 _DECODE_VMEM_BUDGET = 12 * 2**20
 _DECODE_GROUP_BUDGET = 14 * 2**20
@@ -683,7 +761,10 @@ def decode_block_pages(n_pg, page_size, kv_lanes, kv_itemsize,
     `latent` pool's block goes by its keys alone, `_LATENT_BLOCK_KEYS`,
     and the same budget (no V buffers, four of K's and the next block's
     scores). Pure in the shapes: the engine's `decode_block_fill`
-    counter and the kernel ask it the same question."""
+    counter and the kernel ask it the same question. (A window layer's
+    ring is not cut into pages: its block goes by the window,
+    `window_block_rows`, under `_WINDOW_BLOCK_BYTES`, the same
+    `_DECODE_BLOCK_KEYS` and the same budget.)"""
     page = page_size * kv_lanes * kv_itemsize
     if latent:
         bounded = lambda m: m * page_size <= _LATENT_BLOCK_KEYS
@@ -697,6 +778,36 @@ def decode_block_pages(n_pg, page_size, kv_lanes, kv_itemsize,
            <= _DECODE_VMEM_BUDGET):
         n *= 2
     return n
+
+
+def window_block_rows(window, kv_lanes, kv_itemsize, n_heads,
+                      v_lanes=None) -> tuple[int, int]:
+    """(rows a block, blocks a slot) of the window decode kernel. A slot
+    attends `window` keys that lie in consecutive rows of its ring; they
+    are fetched from the sublane tile (16 rows of bf16) its first key
+    lies in to the tile its last one does, `window` + a tile of rows at
+    most, cut into the fewest equal blocks of whole tiles that keep to
+    `_WINDOW_BLOCK_BYTES` of K, `_DECODE_BLOCK_KEYS` keys and
+    `_DECODE_VMEM_BUDGET`: ONE block of 144 rows (432 KiB of K) at
+    mimo-v2-flash's window of 128, ONE of 528 (1,056 KiB) at
+    laguna-s-2.1's of 512. The byte cap is not the page walk's 512 KiB:
+    that was measured with blocks whose dead columns cost compute
+    (PERF.md, PRs 37 and 41), and a window's block has none."""
+    tile = _sublanes(kv_itemsize)
+    total = -(-(window + tile - 1) // tile)             # in tiles
+    fits = lambda m: (
+        m * tile * kv_lanes * kv_itemsize <= _WINDOW_BLOCK_BYTES
+        and m * tile <= _DECODE_BLOCK_KEYS
+        and _decode_vmem_bytes(1, m * tile, kv_lanes, kv_itemsize, n_heads,
+                               v_lanes) <= _DECODE_VMEM_BUDGET)
+    most = max([m for m in range(1, total + 1) if fits(m)], default=1)
+    n_blk = -(-total // most)
+    return -(-total // n_blk) * tile, n_blk
+
+
+def _sublanes(itemsize) -> int:
+    """Rows of one tile of the chip's layout: 8 of float32, 16 of bf16."""
+    return 32 // itemsize
 
 
 def _decode_slot_group(n_slots, slot_bytes, block_bytes) -> int:
@@ -791,28 +902,46 @@ def paged_attention(
     ps, G, Kv = _check_pool(H, K, k_pool, v_pool)
     quantized = k_scale is not None
     _check_window(window, col_page, tables, quantized)
+    if window is not None:
+        return _window_decode(q, k_pool, v_pool, layer, tables, lengths,
+                              window, col_page, sink, sm_scale, interpret)
     kv_item = k_pool.dtype.itemsize
     n = decode_block_pages(tables.shape[1], ps, G * K, kv_item, H, G * Kv)
-    name, prefetch, extra = _call_form(
-        "paged_decode_attn", layer, (tables, lengths), k_scale, v_scale,
-        window, col_page)
+    prefetch = _prefetch(layer, (tables, lengths), k_scale, v_scale)
+    extra = {}
     sink = _sink_form(extra, sink, H, K, Kv)
-    dense = G == H and Kv == K      # a slot's query is one row [1, H*K]
-    # A grouped slot's [H, K] and [H, Kv] blocks, as VMEM pads them.
-    tiled = lambda lanes: lanes if dense else -(-lanes // _LANES) * _LANES
-    group = _decode_slot_group(
-        B, H * (tiled(K) + tiled(Kv)) // 2 * q.dtype.itemsize,
-        _decode_vmem_bytes(n, ps, G * K, kv_item, H, G * Kv))
-
     kernel = functools.partial(
         _decode_kernel, sm_scale=sm_scale, page_size=ps, block_pages=n,
         n_heads=H, n_kv_heads=G, quantized=quantized, **extra)
+    return _slot_group_call(
+        kernel, "paged_decode_attn", q, (k_pool, v_pool), prefetch, sink,
+        n * ps,
+        _decode_vmem_bytes(n, ps, G * K, kv_item, H, G * Kv), interpret)
+
+
+def _slot_group_call(kernel, name, q, pools, prefetch, sink, block_rows,
+                     block_bytes, interpret):
+    """The pallas_call of the full and the window decode kernel: q
+    [B, H, K] against `pools` (K's and V's, left in HBM whole, minor axes
+    G*K and G*Kv) -> [B, H, Kv]; one grid step a group of slots
+    (`_decode_slot_group` beside the block's `block_bytes`), `prefetch`
+    in SMEM, `sink` [H, LANES] or None after the queries, and the
+    scratch both kernels name: `_DECODE_BUFFERS` buffers of `block_rows`
+    rows a pool with a DMA semaphore a buffer pair, the block-diagonal
+    query and the (m, l, acc) state."""
+    B, H, K = q.shape
+    GK, GKv = (p.shape[-1] for p in pools)
+    Kv = GKv // (GK // K)
+    dense = GK == H * K and Kv == K     # a slot's query is one row [1, H*K]
+    # A grouped slot's [H, K] and [H, Kv] blocks, as VMEM pads them.
+    tiled = lambda lanes: lanes if dense else -(-lanes // _LANES) * _LANES
+    group = _decode_slot_group(
+        B, H * (tiled(K) + tiled(Kv)) // 2 * q.dtype.itemsize, block_bytes)
     q = q.reshape(B, 1, H * K) if dense else q
     out_shape = q.shape[:2] + (q.shape[2] // K * Kv,)
     slots = lambda shape: pl.BlockSpec((group,) + shape[1:],
                                        lambda g, *_: (g, 0, 0))
     pool = pl.BlockSpec(memory_space=pl.ANY)
-    pools = (k_pool, v_pool)
     sinks = ([] if sink is None else
              [pl.BlockSpec((H, _LANES), lambda g, *_: (0, 0))])
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -821,13 +950,13 @@ def paged_attention(
         in_specs=[slots(q.shape)] + sinks + [pool] * len(pools),
         out_specs=slots(out_shape),
         scratch_shapes=[
-            pltpu.VMEM((_DECODE_BUFFERS, n * ps, p.shape[3]), p.dtype)
+            pltpu.VMEM((_DECODE_BUFFERS, block_rows, p.shape[-1]), p.dtype)
             for p in pools] + [
             pltpu.SemaphoreType.DMA((_DECODE_BUFFERS,)),   # one a K, V pair
-            pltpu.VMEM((H, G * K), q.dtype),         # block-diagonal query
+            pltpu.VMEM((H, GK), q.dtype),            # block-diagonal query
             pltpu.VMEM((H, _LANES), jnp.float32),    # m
             pltpu.VMEM((H, _LANES), jnp.float32),    # l
-            pltpu.VMEM((H, G * Kv), jnp.float32),    # acc
+            pltpu.VMEM((H, GKv), jnp.float32),       # acc
         ],
     )
     out = pl.pallas_call(
@@ -838,6 +967,212 @@ def paged_attention(
         name=name,
     )(*prefetch, q, *(() if sink is None else (sink,)), *pools)
     return out.reshape(B, H, Kv)
+
+
+# ------------------------------------------- a window layer's ring, decode
+
+def _window_walk(row0_ref, lengths_ref, pools, sem, layer, *, rows, n_blk,
+                 tile, ring, window, end):
+    """What the window decode kernel walks a slot group's rings by.
+    Nothing is sought: a slot of `kv_len` keys attends positions ``lo =
+    max(kv_len - window, 0) .. kv_len - 1``, position t lies in row
+    ``t % ring`` of the slot's ring (`ring` = R pages x page_size rows,
+    consecutive in the pool from row ``row0_ref[b]``: `_check_ring`),
+    and the slot's `n_blk` blocks of `rows` positions start at the tile
+    `lo` lies in. What a block fetches is its positions from there to
+    the tile the last key lies in (every row of it this slot's own, but
+    the last tile's tail): ONE copy a plane where that is the whole
+    block and does not pass the ring's end (`window` + a tile of rows
+    covers 15 lengths in 16), else the rows up to the ring's end and
+    the rows from its start, each a `run`. Rows of a buffer that get no
+    copy are position-masked. An idle slot (`kv_len` 0) is never visited
+    and a block past the slot's last key neither. `pools`: ((plane
+    [L, rows, lanes] in HBM, its [buffers, block rows, lanes] VMEM
+    buffers), ...), one DMA semaphore a buffer index in `sem`.
+    `span(b, j)`, `seek(b)`, `after(b, j)`, `copies(act, b, j, buf, go)`,
+    `fetch(item, buf)`."""
+    sizes = [tile << k for k in reversed(range((rows // tile).bit_length()))]
+    tiles = lambda x: x if isinstance(x, int) else pl.multiple_of(x, tile)
+
+    def bounds(b):
+        """(kv length, first attended key, first position of block 0)."""
+        kv_len = lengths_ref[b]
+        lo = jnp.maximum(kv_len - window, 0)
+        return kv_len, lo, jax.lax.div(lo, tile) * tile
+
+    def span(b, j):
+        """(kv length, first attended key, first position of block j,
+        one past the last position it fetches)."""
+        kv_len, lo, start = bounds(b)
+        first = start + j * rows
+        return kv_len, lo, first, jnp.minimum(
+            first + rows, jax.lax.div(kv_len + tile - 1, tile) * tile)
+
+    def seek(b):
+        """The first slot at or after b that has a key (its block 0); a
+        slot at or past `end` when the group has none left."""
+        held = lambda b: lengths_ref[jnp.minimum(b, end - 1)]
+        return jax.lax.while_loop(lambda b: (b < end) & (held(b) == 0),
+                                  lambda b: b + 1, b), jnp.int32(0)
+
+    def after(b, j):
+        """(slot, block) after (b, j): this slot's next live block, else
+        the next live slot's first."""
+        nxt = seek(b + 1)
+        if n_blk == 1:
+            return nxt
+        kv_len, _, start = bounds(jnp.minimum(b, end - 1))
+        last = j == jax.lax.div(kv_len - 1 - start, rows)
+        return jnp.where(last, nxt[0], b), jnp.where(last, 0, j + 1)
+
+    def move(act, buf, src, dst, size):
+        """`act` ("start" or "wait") on the copy of `size` rows from row
+        `src` of each plane to row `dst` of its buffer `buf`."""
+        for pool, block in pools:
+            getattr(pltpu.make_async_copy(
+                pool.at[layer, pl.ds(tiles(src), size)],
+                block.at[buf, pl.ds(tiles(dst), size)], sem.at[buf]), act)()
+
+    def run(act, buf, src, dst, length):
+        """`move` for a run whose `length` is not static, as a DMA's
+        size has to be: the binary digits of `length`, a tile times the
+        powers of two, largest first."""
+        for size in sizes:
+            take = (length & size) != 0
+            pl.when(take)(functools.partial(move, act, buf, src, dst, size))
+            step = jnp.where(take, size, 0)
+            src, dst = src + step, dst + step
+
+    def copies(act, b, j, buf, go=True):
+        _, _, first, stop = span(b, j)
+        src = jax.lax.rem(first, ring)
+        length = stop - first
+        ahead = jnp.minimum(length, ring - src)     # rows up to the ring's end
+        base = row0_ref[b]
+        whole = (length == rows) & (ahead == rows)
+        pl.when(whole & go)(
+            functools.partial(move, act, buf, base + src, 0, rows))
+
+        @pl.when(~whole & go)
+        def _():
+            run(act, buf, base + src, 0, ahead)
+            run(act, buf, base, ahead, length - ahead)
+
+    def fetch(item, buf):
+        copies("start", jnp.minimum(item[0], end - 1), item[1], buf,
+               item[0] < end)
+
+    return types.SimpleNamespace(span=span, seek=seek, after=after,
+                                 copies=copies, fetch=fetch)
+
+
+def _window_decode_kernel(
+    *refs,
+    sm_scale, block_rows, n_blocks, ring_rows, n_heads, n_kv_heads, window,
+    v_head_dim=None, sink=False,
+):
+    """The decode kernel of a window layer's ring (bf16 pools handed over
+    as planes of rows, [L, P * page_size, lanes]): `_decode_kernel`'s
+    arithmetic (`_slot_softmax`) and its three block buffers with two
+    blocks in flight behind the one attended, under a walk of its own
+    (`_window_walk`). A slot's window is consecutive rows of its ring, so
+    a block is `block_rows` consecutive positions, not pages: ONE block
+    a slot where the window and a tile of rows fit one
+    (`window_block_rows`: every served shape), which then pays one copy
+    a plane, one wait, one score tile and one softmax with no state
+    carried (`whole`); where they do not, `n_blocks` blocks through the
+    (m, l, acc) state as in `_decode_kernel`. Ref order: layer, each
+    slot's first ring row and kv length (SMEM), the group's queries, a
+    sink's logits, the K and V planes in HBM, the output, and
+    `_decode_kernel`'s scratch."""
+    refs = iter(refs)
+    take = lambda count: [next(refs) for _ in range(count)]
+    layer_ref, row0_ref, lengths_ref, q_ref = take(4)
+    (sink_ref,) = take(1) if sink else (None,)
+    (k_hbm, v_hbm, o_ref), (k_buf, v_buf) = take(3), take(2)
+    sem, qbd_ref, m_ref, l_ref, acc_ref = refs
+    group = q_ref.shape[0]
+    n_buf = k_buf.shape[0]
+    base = pl.program_id(0) * group
+    end = base + group
+    _finite_rows(v_buf, o_ref)
+
+    walk = _window_walk(
+        row0_ref, lengths_ref, ((k_hbm, k_buf), (v_hbm, v_buf)), sem,
+        layer_ref[0], rows=block_rows, n_blk=n_blocks,
+        tile=_sublanes(k_buf.dtype.itemsize), ring=ring_rows, window=window,
+        end=end)
+    slot = _slot_softmax(q_ref, sink_ref, o_ref, qbd_ref, m_ref, l_ref,
+                         acc_ref, sm_scale=sm_scale, n_heads=n_heads,
+                         n_kv_heads=n_kv_heads, v_head_dim=v_head_dim,
+                         base=base)
+
+    def keep(b, j):
+        """The position mask of block j of slot b. Its keys are
+        consecutive positions: the window and the length are two scalar
+        bounds."""
+        kv_len, lo, first, _ = walk.span(b, j)
+
+        def masked(s):
+            tpos = first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            return jnp.where((tpos >= lo) & (tpos < kv_len), s, NEG_INF)
+        return masked
+
+    def visit(at):
+        # `ahead`: this block and the ones in flight behind it; the one
+        # after them goes out into the buffer the block before this one
+        # left, before this one is waited for.
+        ahead, buf, prev = at
+        b, j = ahead[0]
+        ahead += (walk.after(*ahead[-1]),)
+        walk.fetch(ahead[-1], jnp.where(buf == 0, n_buf - 1, buf - 1))
+        walk.copies("wait", b, j, buf)
+        if n_blocks == 1:
+            slot.whole(b, k_buf[buf], v_buf[buf], keep(b, j))
+        else:
+            pl.when(b != prev)(lambda: slot.init(b))
+            slot.fold(keep(b, j)(slot.scores(slot.query(), k_buf[buf])),
+                      v_buf[buf])
+            pl.when(ahead[1][0] != b)(lambda: slot.finish(b))
+        return ahead[1:], jnp.where(buf == n_buf - 1, 0, buf + 1), b
+
+    ahead = (walk.seek(base),)
+    for _ in range(n_buf - 2):
+        ahead += (walk.after(*ahead[-1]),)
+    for buf, item in enumerate(ahead):
+        walk.fetch(item, buf)
+    jax.lax.while_loop(lambda at: at[0][0][0] < end, visit,
+                       (ahead, jnp.int32(0), jnp.int32(-1)))
+
+
+def _window_decode(q, k_pool, v_pool, layer, tables, lengths, window,
+                   col_page, sink, sm_scale, interpret):
+    """`paged_attention` over a window layer's ring: q [B, H, K] against
+    the slots' rings in the pool -> [B, H, Kv]."""
+    _, H, K = q.shape
+    ps, G, Kv = _check_pool(H, K, k_pool, v_pool)
+    item = k_pool.dtype.itemsize
+    ring = tables.shape[1] * ps
+    if ps % _sublanes(item) or ring < window + ps:
+        raise ValueError(
+            f"a window decode call fetches whole tiles of {_sublanes(item)} "
+            f"rows out of a ring that holds the window and a page: got "
+            f"pages of {ps} rows, {tables.shape[1]} a ring, window {window}")
+    _check_ring(tables, col_page, lengths, ps)
+    rows, n_blk = window_block_rows(window, G * K, item, H, G * Kv)
+    extra = {}
+    sink = _sink_form(extra, sink, H, K, Kv)
+    kernel = functools.partial(
+        _window_decode_kernel, sm_scale=sm_scale, block_rows=rows,
+        n_blocks=n_blk, ring_rows=ring, n_heads=H, n_kv_heads=G,
+        window=int(window), **extra)
+    # A ring's pages as the rows they are, [L, P * ps, lanes], and a
+    # slot's ring by its first row.
+    pools = [p.reshape(p.shape[0], -1, p.shape[3]) for p in (k_pool, v_pool)]
+    prefetch = _prefetch(layer, (tables[:, 0] * ps, lengths), None, None)
+    return _slot_group_call(
+        kernel, "paged_decode_attn_window", q, pools, prefetch, sink, rows,
+        _decode_vmem_bytes(1, rows, G * K, item, H, G * Kv), interpret)
 
 
 # ----------------------------------------------- the latent form, decode
@@ -871,8 +1206,8 @@ def _latent_decode_kernel(*refs, sm_scale, page_size, block_pages, v_dim):
     n_buf = kv_buf.shape[0]
     base = pl.program_id(0) * group
     end = base + group
-    walk = _block_walk(tables_ref, lengths_ref, None, ((kv_hbm, kv_buf),),
-                       sem, layer_ref[0], n=n, ps=ps, window=None, end=end)
+    walk = _block_walk(tables_ref, lengths_ref, ((kv_hbm, kv_buf),), sem,
+                       layer_ref[0], n=n, ps=ps, end=end)
     following = lambda item: walk.seek(*walk.after(*item))
 
     @pl.when(pl.program_id(0) == 0)

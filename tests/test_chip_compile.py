@@ -590,7 +590,9 @@ def test_decode_kernel_at_a_cells_shapes_fits_what_its_rule_reckons(chip,
     and V buffers, the block-diagonal query, the softmax state) plus the
     score tiles is what `_decode_vmem_bytes` reckons for the rule's
     block, to the byte for a bf16 pool; with the batch's queries and
-    outputs it stays inside the 16 MiB a kernel gets."""
+    outputs it stays inside the 16 MiB a kernel gets. The window kind
+    (PR 61) fetches a slot's window as rows of its ring, a block by
+    `window_block_rows`."""
     import importlib
 
     attn = importlib.import_module("ray_tpu.ops.paged_attention")
@@ -621,15 +623,20 @@ def test_decode_kernel_at_a_cells_shapes_fits_what_its_rule_reckons(chip,
         -spec.num_scratch_operands:]]
     vmem = sum(int(np.prod(a.shape)) * jnp.dtype(a.dtype).itemsize
                for a in scratch if "sem" not in str(a.dtype).lower())
-    n = attn.decode_block_pages(width, PS, lanes, item, heads)
-    assert n == {"opt-1.3b": 2, "opt-1.3b-int8": 4, "zaya1-8b": 16,
-                 "laguna-full": 4, "laguna-window": 4}[cell]
-    buffers = attn._DECODE_BUFFERS * 2 * n * PS * lanes * item
+    if ring:    # ONE block a slot: the window's 512 rows and a tile of 16
+        keys, blocks = attn.window_block_rows(512, lanes, item, heads)
+        assert (keys, blocks) == (528, 1)
+        n, page = 1, keys
+    else:
+        n, page = attn.decode_block_pages(width, PS, lanes, item, heads), PS
+        assert n == {"opt-1.3b": 2, "opt-1.3b-int8": 4, "zaya1-8b": 16,
+                     "laguna-full": 4}[cell]
+    buffers = attn._DECODE_BUFFERS * 2 * n * page * lanes * item
     assert vmem == (buffers + heads * lanes * (2 + 4)
                     + 2 * heads * 128 * 4)
-    tiles = 2 * heads * n * PS * 4
-    dequant = 2 * n * PS * lanes * 4 if item == 1 else 0
-    reckoned = attn._decode_vmem_bytes(n, PS, lanes, item, heads)
+    tiles = 2 * heads * n * page * 4
+    dequant = 2 * n * page * lanes * 4 if item == 1 else 0
+    reckoned = attn._decode_vmem_bytes(n, page, lanes, item, heads)
     assert vmem + tiles + dequant <= reckoned <= attn._DECODE_VMEM_BUDGET
     # bf16: all the rule overcounts is the block-diagonal query at 4 bytes
     assert item == 1 or reckoned - (vmem + tiles) == heads * lanes * 6
@@ -915,13 +922,20 @@ def test_decode_kernel_at_unequal_head_sizes_fits_what_its_rule_reckons(
     vmem = sum(int(np.prod(a.shape)) * jnp.dtype(a.dtype).itemsize
                for a in scratch if "sem" not in str(a.dtype).lower())
     k_lanes, v_lanes = G * M_K, G * M_KV
-    n = attn.decode_block_pages(width, PS, k_lanes, 2, M_H, v_lanes)
-    assert n == {"full": 4, "window": 2}[kind]
-    buffers = attn._DECODE_BUFFERS * n * PS * (k_lanes + v_lanes) * 2
+    if ring:    # ONE block a slot: the window's 128 rows and a tile of 16
+        keys, blocks = attn.window_block_rows(M_WINDOW, k_lanes, 2, M_H,
+                                              v_lanes)
+        assert (keys, blocks) == (144, 1)
+        n, page = 1, keys
+    else:
+        n, page = attn.decode_block_pages(width, PS, k_lanes, 2, M_H,
+                                          v_lanes), PS
+        assert n == 4
+    buffers = attn._DECODE_BUFFERS * n * page * (k_lanes + v_lanes) * 2
     assert vmem == (buffers + M_H * (k_lanes * 2 + v_lanes * 4)
                     + 2 * M_H * 128 * 4)
-    tiles = 2 * M_H * n * PS * 4
-    reckoned = attn._decode_vmem_bytes(n, PS, k_lanes, 2, M_H, v_lanes)
+    tiles = 2 * M_H * n * page * 4
+    reckoned = attn._decode_vmem_bytes(n, page, k_lanes, 2, M_H, v_lanes)
     assert vmem + tiles <= reckoned <= attn._DECODE_VMEM_BUDGET
     # all the rule overcounts: the block-diagonal query at 4 bytes, and
     # the accumulator's update

@@ -79,19 +79,25 @@ PAGE, N_PAGES, N_SLOTS, CHUNK = 16, 24, 3, 16
 # either side of the call: a block of 5 of olmo-hybrid's 15 packed rows
 # is neither whole sublane tiles nor the whole axis), the kernel's body
 # equation for equation what it was at one head a packed head. The other
-# eighteen stayed.
+# eighteen stayed. `laguna.decode`, `laguna.sample`, `mimo_v2.decode` and
+# `mimo_v2.sample` as PR 61 traced them, which meant to change them in
+# ONE call: the window layers' decode call
+# (`ops/paged_attention.py` `_window_decode_kernel`: a slot's window
+# fetched as a run of its ring's rows, one block, one softmax; the pools
+# handed over as planes of rows, the ring by its first row); the chunk
+# programs run the prefill kernel and stayed, as did the other families'.
 _PINNED = {
     "gpt.chunk": "aff570174e390475", "gpt.decode": "c4ebbb44ff02a83f",
     "zaya.chunk": "e3c3c03e1e115475", "zaya.decode": "21818dedb3b70792",
-    "laguna.chunk": "416053d2794175ae", "laguna.decode": "2d25d646070c14ab",
+    "laguna.chunk": "416053d2794175ae", "laguna.decode": "ffdc1d6d32268b9b",
     "qwen3_next.chunk": "1bdf3087055bdd93",
     "qwen3_next.decode": "cef6eae62e3648d3",
     "mimo_v2.chunk": "6c90d3e82314369a",
-    "mimo_v2.decode": "acce4f518d730655",
+    "mimo_v2.decode": "4d65032cbde12581",
     "gpt.sample": "057837dac4200223", "zaya.sample": "55f97b147af972ff",
-    "laguna.sample": "613e21949deeeba0",
+    "laguna.sample": "af1ceae66db8ca3e",
     "qwen3_next.sample": "4dcf0d487ad684ba",
-    "mimo_v2.sample": "778467ea27873009",
+    "mimo_v2.sample": "61fae9884dba1561",
     "jamba.chunk": "16c983f57626c3f6", "jamba.decode": "5262465ac313b68d",
     "jamba.sample": "09798055e3a58725",
     "kimi_k2.chunk": "c7042b4b88e13c46",

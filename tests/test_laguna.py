@@ -411,6 +411,33 @@ def test_a_reused_slot_does_not_read_its_predecessors_ring(params, fault,
         np.testing.assert_allclose(again, want, atol=ATOL_F32, rtol=0)
 
 
+def test_a_ring_is_consecutive_rows_and_the_decode_call_is_owed_that():
+    """The window decode call fetches a slot's window as a run of the
+    plane's rows: `ring_pool` lays every ring (the null slot's too) in R
+    consecutive rows, the view `_ring_view` hands the call passes its
+    check, and a table whose columns are not consecutive is refused."""
+    from ray_tpu.ops.paged_attention import paged_attention
+
+    pool = laguna.init_paged_kv(CFG, N_PAGES, PAGE, N_SLOTS,
+                                dispatch_tokens=ROWS * CHUNK)
+    rows = np.asarray(pool["ring_rows"])
+    R = laguna.ring_pages(CFG.window, PAGE, ROWS * CHUNK)
+    assert rows.shape == (N_SLOTS + 1, R)
+    np.testing.assert_array_equal(rows, rows[:, :1] + np.arange(R)[None])
+    np.testing.assert_array_equal(rows[:, 0], R * np.arange(N_SLOTS + 1))
+    lengths = jnp.asarray([1, PAGE * (R + 2) + 3, 0], jnp.int32)
+    table, col_page = laguna._ring_view(pool, jnp.arange(N_SLOTS), lengths,
+                                        PAGE)
+    q = jnp.ones((N_SLOTS, CFG.heads("window"), CFG.head_dim), jnp.float32)
+    kw = dict(window=CFG.window, col_page=col_page)
+    out = paged_attention(q, pool["k_win"], pool["v_win"], jnp.int32(0),
+                          table, lengths, **kw)
+    assert out.shape == q.shape and not np.asarray(out).any()   # a zero pool
+    with pytest.raises(ValueError, match="R consecutive page ids"):
+        paged_attention(q, pool["k_win"], pool["v_win"], jnp.int32(0),
+                        table[:, ::-1], lengths, **kw)
+
+
 # ------------------------------------------------------- through LLMEngine
 
 def _engine(params, **kw):

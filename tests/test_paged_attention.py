@@ -609,29 +609,39 @@ def test_a_short_slot_after_a_long_one_sees_no_stale_row(kv, monkeypatch):
     np.testing.assert_allclose(got, want, rtol=1e-3, atol=1e-2)   # the long ones
 
 
-@pytest.mark.parametrize("block_keys", [32, None])
-def test_a_ring_whose_live_columns_are_scattered(block_keys, monkeypatch):
-    """`col_page` alone says where a ring's live pages lie: live columns
-    apart from each other, a dead block between two live ones, a dead
-    column first and last, at two pages a block and at the rule's four
-    (six columns: the last block runs past the ring)."""
+@pytest.mark.parametrize("ring", ["scattered", "one_page_back"])
+def test_a_ring_that_is_not_its_slots_own_view_is_refused(ring):
+    """The window decode call walks a slot's ring in closed form (logical
+    page p in column p % R, the pages up to the slot's last one): a
+    `col_page` that says anything else is refused where it can be read,
+    and inside a trace, where it cannot, the call goes by the lengths
+    alone and reads the true ring's answer. (The prefill kernel and the
+    oracle still go by `col_page`.)"""
     H, G, K, ps, window = 6, 2, 128, 16, 40
-    if block_keys is not None:
-        monkeypatch.setattr(pa, "_DECODE_BLOCK_KEYS", block_keys)
-    col_page = [[4, 0, 6, -1, 3, 5],       # live 0, 2, 4, 5 (keys 60..99)
-                [5, 6, -1, 0, 3, 4],       # the middle block dead
-                [-1, 9, 1, 8, 7, -1],      # dead first and last (119..158)
-                [0, -1, -1, -1, -1, 1]]    # 20 keys over the ring's ends
     lengths = [100, 100, 159, 20]
     rng = np.random.default_rng(24)
-    k_pool, v_pool, tables, col_page = _scattered_ring(
-        rng, col_page=col_page, G=G, K=K, ps=ps)
+    k_pool, v_pool, tables, col_page, _, _ = _ring(
+        rng, lengths=lengths, G=G, K=K, ps=ps, R=6, dtype=jnp.float32)
     q = jnp.asarray(rng.normal(size=(4, H, K)), jnp.float32)
     args = (jnp.int32(2), tables, jnp.asarray(lengths, jnp.int32))
-    kw = dict(window=window, col_page=col_page)
-    got = paged_attention(q, k_pool, v_pool, *args, **kw)
-    want = reference_paged_attention(q, k_pool, v_pool, *args, **kw)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+    want = reference_paged_attention(q, k_pool, v_pool, *args, window=window,
+                                     col_page=col_page)
+    np.testing.assert_allclose(
+        np.asarray(paged_attention(q, k_pool, v_pool, *args, window=window,
+                                   col_page=col_page)),
+        np.asarray(want), atol=1e-5)
+    other = {"scattered": jnp.asarray([[4, 0, 6, -1, 3, 5],
+                                       [5, 6, -1, 0, 3, 4],
+                                       [-1, 9, 1, 8, 7, -1],
+                                       [0, -1, -1, -1, -1, 1]], jnp.int32),
+             "one_page_back": jnp.maximum(col_page - 1, 0)}[ring]
+    with pytest.raises(ValueError, match="ring's own view"):
+        paged_attention(q, k_pool, v_pool, *args, window=window,
+                        col_page=other)
+    traced = jax.jit(lambda cp: paged_attention(
+        q, k_pool, v_pool, *args, window=window, col_page=cp))(other)
+    np.testing.assert_allclose(np.asarray(traced), np.asarray(want),
+                               atol=1e-5)
 
 
 def test_page_ops_on_the_flat_pool():
@@ -955,10 +965,10 @@ def test_window_prefill_kernel_matches_oracle_and_dense(heads, split,
     np.testing.assert_allclose(np.asarray(ref)[valid], want[valid], atol=1e-5)
 
 
-@pytest.mark.parametrize("fault", ["window_off_by_one", "page_off_by_one"])
+@pytest.mark.parametrize("fault", ["window_off_by_one", "another_slots_ring"])
 def test_a_window_fault_moves_the_output(fault):
     """What the tolerances above are for: one key more in the window, or
-    a column believed to hold the page before its own."""
+    every slot reading the ring of the slot before it."""
     H, G, K = 6, 2, 128
     ps, R, window = RING["ps"], RING["R"], RING["window"]
     lengths = [96, 150, 271]
@@ -972,8 +982,9 @@ def test_a_window_fault_moves_the_output(fault):
         lengths, window, G)[:, 0]
     if fault == "window_off_by_one":
         kw = dict(window=window + 1, col_page=col_page)
-    else:
-        kw = dict(window=window, col_page=jnp.maximum(col_page - 1, 0))
+    else:       # every slot reads the ring of the slot before it
+        kw = dict(window=window, col_page=col_page)
+        tables = jnp.roll(tables, 1, axis=0)
     for attend in (paged_attention, reference_paged_attention):
         got = attend(q, k_pool, v_pool, jnp.int32(0), tables, n, **kw)
         assert np.abs(np.asarray(got) - want).max() > 1e-2
@@ -1140,6 +1151,74 @@ def test_decode_kernel_at_unequal_head_sizes_and_a_sink(heads, sink, ring):
     np.testing.assert_allclose(np.asarray(ref), want, atol=1e-5)
 
 
+# The window decode walk's cases: (page size, ring width, window, slot
+# lengths, (rows a block, blocks a slot) the rule must give: a window and
+# a tile of rows, 8 of float32 and 16 of bf16).
+WINDOW_WALK = {
+    # keys 6..13 of page 0, 22..29 of page 1, 99..106 of page 6
+    "a_window_inside_one_page": (16, 6, 8, [14, 30, 107], (16, 1)),
+    # a window of two pages from a page's first key (32, 64, 112: the
+    # block's last tile gets no copy) and from inside one (33, 45)
+    "a_window_from_a_page_boundary": (16, 6, 32, [32, 64, 112, 33, 45],
+                                      (40, 1)),
+    # the window's rows pass the ring's end (96 rows): two runs of rows
+    "a_window_over_the_rings_end": (16, 6, 40, [100, 104, 200, 97, 193],
+                                    (48, 1)),
+    # positions before the slot's first token do not exist (`col_page`
+    # -1): the block starts at 0 and its tail gets no copy
+    "a_slot_shorter_than_its_window": (16, 6, 40, [1, 5, 17, 39], (48, 1)),
+    "idle_slots_between_live_ones": (16, 6, 40, [50, 0, 0, 97, 0, 130, 0],
+                                     (48, 1)),
+    # 528 rows of 2 KB under a byte cap of 640 KiB: two blocks of 272;
+    # slots whose second block is dead (100, 64) or short (513)
+    "two_blocks_a_slot": (64, 12, 512, [700, 513, 100, 1280, 64], (272, 2)),
+}
+
+
+@pytest.mark.parametrize("v_narrow", [False, True])
+@pytest.mark.parametrize("sink", [False, True])
+@pytest.mark.parametrize("case", sorted(WINDOW_WALK))
+def test_the_window_decode_walk(case, sink, v_narrow, monkeypatch):
+    """A slot's window fetched as consecutive rows of its ring, found in
+    closed form, and attended as one block (or the fewest the caps
+    allow), against the oracle and a dense float64 softmax; with and
+    without a sink, V as wide as K and narrower."""
+    ps, R, window, lengths, blocks = WINDOW_WALK[case]
+    wide = case == "two_blocks_a_slot"       # a bf16 pool of 1,024 lanes
+    if wide:
+        monkeypatch.setattr(pa, "_WINDOW_BLOCK_BYTES", 640 * 2**10)
+    H, G, K, Kv = ((16, 8, 128, 64) if v_narrow else (8, 8, 128, 128)) \
+        if wide else ((8, 4, 192, 128) if v_narrow else (4, 2, 128, 128))
+    dtype = jnp.bfloat16 if wide else jnp.float32
+    assert pa.window_block_rows(window, G * K, dtype.dtype.itemsize, H,
+                                G * Kv) == blocks
+    rng = np.random.default_rng(61)
+    live = [max(m, 1) for m in lengths]
+    k_pool, v_pool, tables, col_page, k_dense, v_dense = _paged_kv(
+        rng, lengths=live, G=G, K=K, Kv=Kv, ps=ps, R=R)
+    col_page = jnp.where(jnp.asarray(lengths)[:, None] > 0, col_page, -1)
+    k_pool, v_pool = k_pool.astype(dtype), v_pool.astype(dtype)
+    q = jnp.asarray(rng.normal(size=(len(lengths), H, K)), dtype)
+    kw = dict(window=window, col_page=col_page)
+    if sink:
+        kw["sink"] = jnp.asarray(rng.normal(size=H), jnp.float32)
+    args = (jnp.int32(1), tables, jnp.asarray(lengths, jnp.int32))
+    got = np.asarray(paged_attention(q, k_pool.at[0].add(1.0), v_pool, *args,
+                                     **kw), np.float32)
+    ref = np.asarray(reference_paged_attention(q, k_pool, v_pool, *args,
+                                               **kw), np.float32)
+    cast = lambda a: np.asarray(jnp.asarray(a, dtype), np.float32)
+    want = _dense_attention(
+        np.asarray(q, np.float32)[:, None], cast(k_dense), cast(v_dense),
+        np.asarray(live)[:, None] - 1, live, G, window=window,
+        sink=kw.get("sink"))[:, 0]
+    held = np.asarray(lengths) > 0
+    atol = 3e-2 if wide else 1e-5
+    np.testing.assert_allclose(got[held], ref[held], atol=atol)
+    np.testing.assert_allclose(ref[held], want[held], atol=atol)
+    assert not got[~held].any()
+
+
 @pytest.mark.parametrize("split", [False, True])
 @pytest.mark.parametrize("ring", [False, True])
 @pytest.mark.parametrize("sink", [False, True])
@@ -1273,6 +1352,11 @@ def test_the_vmem_rules_reckon_k_and_v_widths_apart():
 # widths and the sink traced them (02951b4, computed there by this very
 # code): with `sink=None` and equal widths every family gets EXACTLY the
 # program it had. (H, K, G, table width, the call's extras.)
+# `laguna.decode.window` as PR 61 traced it, which meant to change it and
+# nothing else: the window kind of the decode call has a walk and a body
+# of its own (`_window_walk`, `_window_decode_kernel`: a slot's window
+# fetched as a run of its ring's rows and attended as one block); the
+# other eleven stayed, `laguna.prefill.window` among them.
 _PARENT_PROGRAMS = {
     "gpt.decode": ("6e08013ab7550714", 32, 64, 32, 8, {}),
     "gpt.decode.int8": ("15dad0340c6f9e91", 32, 64, 32, 8, {"int8": True}),
@@ -1281,7 +1365,7 @@ _PARENT_PROGRAMS = {
     "zaya.decode": ("e915f0f72052bbe3", 16, 128, 2, 8, {}),
     "zaya.prefill": ("1b4e11ceb1865797", 16, 128, 2, 8, {}),
     "laguna.decode.full": ("9c1cd8a68d51de7d", 48, 128, 8, 8, {}),
-    "laguna.decode.window": ("6892669326d34757", 72, 128, 8, 13,
+    "laguna.decode.window": ("6fb992ce3b6f2ab3", 72, 128, 8, 13,
                              {"window": 512}),
     "laguna.prefill.full": ("865ad16ed04cfb5b", 48, 128, 8, 8, {}),
     "laguna.prefill.window": ("1a61312a96bf9ca6", 72, 128, 8, 13,
