@@ -1,7 +1,7 @@
 """Parts that two or more served families are built from.
 
 A family module (models/zaya.py, laguna.py, qwen3_next.py, mimo_v2.py,
-jamba.py, kimi_k2.py, olmo_hybrid.py) writes what is its own: the configuration, the
+jamba.py, kimi_k2.py, olmo_hybrid.py, nemotron_h.py) writes what is its own: the configuration, the
 attention inputs, the router, the ropes, the pool. What several of them
 compute the same way lives here under public names, so that no family
 imports a sibling to get it: the imports of `ray_tpu/models/` point from a family to this module
@@ -77,6 +77,26 @@ def gated_mlp(u, w_gate, w_up, w_down):
     up = jnp.matmul(u, w_up.astype(dt), preferred_element_type=_F32)
     return jnp.matmul((jax.nn.silu(gate) * up).astype(dt), w_down.astype(dt),
                       preferred_element_type=_F32)
+
+
+# ------------------------------------------------------------ convolution
+
+def causal_conv(boundary, n_taps: int):
+    """A state-space layer's causal depthwise convolution over a row's
+    own tokens in plain XLA (models/jamba.py, nemotron_h.py).
+    `boundary(xs)` → taps-1 planes [N, Dn]: the inputs BEFORE each row's
+    first token, oldest first, given the rows' own `xs` [N, C, Dn].
+    → conv(xs, taps, bias) → (silu(conv + bias) [N, C, Dn] in xs.dtype,
+    ext [N, taps-1+C, Dn]: the inputs with the boundary in front)."""
+    def conv(xs, taps, bias):
+        C = xs.shape[1]
+        ext = jnp.concatenate(
+            [b[:, None].astype(xs.dtype) for b in boundary(xs)] + [xs],
+            axis=1)
+        acc = sum(taps[j] * ext[:, j:j + C].astype(_F32)
+                  for j in range(n_taps))
+        return jax.nn.silu(acc + bias).astype(xs.dtype), ext
+    return conv
 
 
 # ------------------------------------------------------------------- rope

@@ -82,6 +82,7 @@ import jax.numpy as jnp
 from ray_tpu.ops import scopes
 from ray_tpu.models.blocks import (attend_fn, dispatch_order, gated_mlp,
                                    last_token_logits, tied_head, write_kv)
+from ray_tpu.models.blocks import causal_conv as _causal_conv
 from ray_tpu.models.blocks import rms_norm as _norm
 from ray_tpu.models.paged_kv import paged_programs
 from ray_tpu.ops.selective_scan import (
@@ -231,23 +232,6 @@ def _unit_rms(x, w, eps):
     """One of the mixer's three inner norms, float32 in and out."""
     return (x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
             * w.astype(_F32))
-
-
-def _causal_conv(boundary, n_taps: int):
-    """The convolution over a row's own tokens in plain XLA.
-    `boundary(xs)` → taps-1 planes [N, Dn]: the inputs BEFORE each row's
-    first token, oldest first, given the rows' own `xs` [N, C, Dn].
-    → conv(xs, taps, bias) → (silu(conv + bias) [N, C, Dn] in xs.dtype,
-    ext [N, taps-1+C, Dn]: the inputs with the boundary in front)."""
-    def conv(xs, taps, bias):
-        C = xs.shape[1]
-        ext = jnp.concatenate(
-            [b[:, None].astype(xs.dtype) for b in boundary(xs)] + [xs],
-            axis=1)
-        acc = sum(taps[j] * ext[:, j:j + C].astype(_F32)
-                  for j in range(n_taps))
-        return jax.nn.silu(acc + bias).astype(xs.dtype), ext
-    return conv
 
 
 @jax.named_scope(scopes.SSM_IN)

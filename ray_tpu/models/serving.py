@@ -369,10 +369,40 @@ def _olmo_hybrid() -> ServingFamily:
         slot_state=olmo_hybrid.SLOT_STATE_LEAVES)
 
 
+def _nemotron_h() -> ServingFamily:
+    """jamba's two memories (a state-space state by the slot beside
+    pages) WITH experts, held in part as kimi_k2's are: the state a
+    matrix a head (Mamba-2), the experts in a latent narrower than the
+    model. `serve/` and `PagePool` needed nothing for it."""
+    from ray_tpu.models import nemotron_h
+
+    return _paged_only(
+        nemotron_h,
+        "the Mamba-2 layers' state-space state and convolution tail "
+        "(models/nemotron_h.py: a float32 matrix of 64 x 128 a head and "
+        "layer, two heads side by side, 21.0 MB a slot at the published "
+        "sizes, indexed by slot)",
+        {**_EXPERTS,
+         "kv_mode": "for the attention layers with {beside} carried beside "
+                    "it",
+         "prefill_chunk": "the prompt's final state-space state in the slot",
+         "prefix_cache": _SNAPSHOT,
+         "spec_draft": "a recurrence cannot be run backwards; " + _RETURNS
+                       + " (the model's own multi-token-prediction head "
+                       "would be the draft)",
+         "tp": "2 KV heads cannot shard over more chips than heads, "
+               + _EXCHANGE + ", and no partition rule splits the "
+               "state-space state by head",
+         "kv_dtype": "which the attention layers' K/V writer would have to "
+                     "call, and the state-space state is float32: it "
+                     "accumulates thousands of steps"},
+        slot_state=nemotron_h.SLOT_STATE_LEAVES)
+
+
 _FAMILIES = {"gpt": _gpt, "zaya": _zaya, "laguna": _laguna,
              "qwen3_next": _qwen3_next, "mimo_v2": _mimo_v2,
              "jamba": _jamba, "kimi_k2": _kimi_k2,
-             "olmo_hybrid": _olmo_hybrid}
+             "olmo_hybrid": _olmo_hybrid, "nemotron_h": _nemotron_h}
 
 
 @functools.cache

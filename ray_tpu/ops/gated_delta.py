@@ -254,18 +254,20 @@ def unpack_state(s, dv: int):
 
 
 def reference_gdn_decode_step(state, layer, q, k, v, g, beta, active, *,
-                              repeat: int = 1):
+                              repeat: int = 1, delta: bool = True):
     """One token for slots 0 .. B-1 of `state` [L, n_slots+1, H / p, dk,
     p dv] at `layer`, in plain XLA. q, k [B, Hk, dk]: the KEY heads'
     (value head h reads key head h // `repeat`); v [B, H, dv], g, beta
-    [B, H] float32; `active` [B] bool: the others' state stays.
+    [B, H] float32; `active` [B] bool: the others' state stays. `delta`
+    False: the write is beta v itself, not beta (v - S^T k): a gated
+    LINEAR recurrence over the same stack (ops/ssd.py).
     → (o [B, H, dv] float32, the updated stack)."""
     B, dv = q.shape[0], v.shape[-1]
     q, k = (jnp.repeat(t.astype(_F32), repeat, axis=1) for t in (q, k))
     old = unpack_state(state[layer, :B], dv)
     s = old * jnp.exp(g)[..., None, None]
     read = functools.partial(jnp.einsum, "bhkv,bhk->bhv", precision=_HIGHEST)
-    d = beta[..., None] * (v - read(s, k))
+    d = beta[..., None] * (v - read(s, k) if delta else v)
     s = s + k[..., :, None] * d[..., None, :]
     o = read(s, q)
     s = jnp.where(active[:, None, None, None], s, old)
@@ -273,7 +275,8 @@ def reference_gdn_decode_step(state, layer, q, k, v, g, beta, active, *,
 
 
 def _decode_kernel(layer_ref, rows_ref, kq_ref, v_ref, a_ref, beta_ref,
-                   s_ref, o_ref, s_out_ref, *, heads, pack, repeat, null_slot):
+                   s_ref, o_ref, s_out_ref, *, heads, pack, repeat, null_slot,
+                   delta=True):
     """One slot's block of `heads` packed heads (`pack` value heads side
     by side each). kq_ref [dk, LANES]: column j the key of the block's
     j-th key head, column LANES / 2 + j its query, so that a key lies
@@ -304,8 +307,12 @@ def _decode_kernel(layer_ref, rows_ref, kq_ref, v_ref, a_ref, beta_ref,
         for h in range(heads):
             kcol, qcol = column(h, 0), column(h, _LANES // 2)
             s = s_ref[h] * a_ref[h:h + 1, :]
-            d = beta_ref[h:h + 1, :] * (
-                v_ref[h:h + 1, :] - jnp.sum(s * kcol, axis=0, keepdims=True))
+            if delta:
+                d = beta_ref[h:h + 1, :] * (
+                    v_ref[h:h + 1, :]
+                    - jnp.sum(s * kcol, axis=0, keepdims=True))
+            else:
+                d = beta_ref[h:h + 1, :] * v_ref[h:h + 1, :]
             s = s + kcol * d
             s_out_ref[h] = s
             o_ref[h:h + 1, :] = jnp.sum(s * qcol, axis=0, keepdims=True)
@@ -329,11 +336,14 @@ def _decode_block_heads(n_packed: int, pack: int, repeat: int,
 
 
 def gdn_decode_step(state, layer, q, k, v, g, beta, active, *,
-                    repeat: int = 1, interpret=None):
+                    repeat: int = 1, interpret=None, delta: bool = True,
+                    name: str = "gdn_decode_step"):
     """`reference_gdn_decode_step` as one kernel over the whole stack,
     donated: slot b's heads are read and written once, an idle slot's
     not at all. q, k [B, Hk, dk] float32 are the KEY heads' (value head h
-    reads key head h // `repeat`); v [B, H, dv], g, beta [B, H].
+    reads key head h // `repeat`); v [B, H, dv], g, beta [B, H]. `delta`
+    and `name`: the write without the delta term, under the name its
+    caller's kernel has in a trace (ops/ssd.py).
     → (o [B, H, dv] float32, the updated stack)."""
     if interpret is None:
         interpret = _interpret_default()
@@ -371,7 +381,8 @@ def gdn_decode_step(state, layer, q, k, v, g, beta, active, *,
         (None, None, heads, dk, width),
         lambda b, hb, layer, slot, *_: (layer[0], slot[b], hb, 0, 0))
     kernel = functools.partial(_decode_kernel, heads=heads, pack=pack,
-                               repeat=repeat, null_slot=null_slot)
+                               repeat=repeat, null_slot=null_slot,
+                               delta=delta)
     o, state = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -384,7 +395,7 @@ def gdn_decode_step(state, layer, q, k, v, g, beta, active, *,
                    jax.ShapeDtypeStruct(state.shape, state.dtype)],
         input_output_aliases={6: 1},
         interpret=interpret,
-        name="gdn_decode_step",
+        name=name,
     )(jnp.asarray(layer, jnp.int32).reshape(1), slot_rows, kq,
       side(v.astype(_F32)), spread(jnp.exp(g)), spread(beta), state)
     return o.reshape(B, H, dv), state

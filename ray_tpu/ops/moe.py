@@ -1,20 +1,41 @@
-"""Mixture-of-Experts layer with expert parallelism.
+"""Mixture-of-experts layers: the two this repository has.
 
-Net-new capability (SURVEY §2.4 expert-parallelism row: ❌ in the
-reference). GShard/Switch-style top-2 token-choice routing with capacity:
+**`moe_mlp`: the TRAINING layer, with a capacity** (models/moe_gpt.py).
+GShard/Switch-style top-2 token-choice routing:
 
     gates = softmax(x @ wg)            [tokens, E]
     top-2 experts per token, renormalized; tokens beyond an expert's
     capacity C are dropped (their combine weight is 0 → residual passthrough
     at the call site).
     dispatch [G, E, C] one-hot  → expert inputs  [E, C, D]  (einsum)
-    expert MLP (stacked weights [E, D, F] / [E, F, D])
+    expert MLP (stacked weights [E, D, F] / [E, F, D], GELU, biases)
     combine  [G, E, C] weighted → outputs        [G, D]     (einsum)
 
-TPU-first: everything is dense einsum under jit — the expert axis carries
-the logical "expert" sharding (→ `ep` mesh axis, parallel/mesh.py), so
-XLA partitions expert compute across `ep` and derives the token all-to-all
+Everything is dense einsum under jit: the expert axis carries the
+logical "expert" sharding (→ `ep` mesh axis, parallel/mesh.py), so XLA
+partitions expert compute across `ep` and derives the token all-to-all
 from the dispatch/combine einsums' shardings; no hand-written a2a.
+
+**`token_choice_experts`: the SERVING layer, as deployed** (every
+served family with experts): no capacity, no dropped token, no
+[N, E, C] one-hot. The router is the family's (a softmax or a sigmoid,
+a biased choice or not: models/blocks.py and the family modules); this
+layer takes its choices and gates, is told which experts of the
+router's width this chip HOLDS, sorts the held choices by expert and
+runs them as grouped matmuls (`jax.lax.ragged_dot`, `ragged-dot` in a
+trace), returning the held experts' part of the result. An expert is
+one of two forms, told apart by how many stacks it is handed:
+
+    three matrices   W_down(silu(W_gate x) * W_up x)   gated SiLU
+                     (zaya, laguna, qwen3_next, mimo_v2, kimi_k2)
+    two matrices     W_down relu(W_up x)^2             squared ReLU,
+                     ungated (nemotron_h)
+
+Either form's width in and out is whatever the stacks say, not the
+model's: nemotron_h's experts live in a LATENT of 1,024 under a model
+of 4,096 (the projection into the latent before and out of it after is
+shared by all experts and is the caller's), so rows enter and leave the
+grouped matmuls at 1,024 lanes.
 """
 
 from __future__ import annotations
@@ -222,16 +243,19 @@ def rows_over(counts: jax.Array, n_choices: int,
 
 
 def token_choice_experts(x: jax.Array, expert_ids: jax.Array,
-                         gates: jax.Array, w_gate: jax.Array,
-                         w_up: jax.Array, w_down: jax.Array, *,
+                         gates: jax.Array, *weights: jax.Array,
                          first_expert: int = 0, layer=None, valid=None,
                          n_routed: int | None = None):
-    """Gated-SiLU experts over token-choice routing, the held part.
+    """Experts over token-choice routing, the held part.
 
     x [N, D]; expert_ids [N] or [N, k] int32 (global expert ids, a row's
-    k choices); gates like expert_ids (float32 weights); w_gate / w_up
-    [E_held, D, F], w_down [E_held, F, D]: experts ``first_expert`` ..
-    ``first_expert + E_held - 1``. `valid` [N] bool (optional): rows that
+    k choices); gates like expert_ids (float32 weights); `weights` the
+    held experts' stacks, experts ``first_expert`` .. ``first_expert +
+    E_held - 1``: THREE, w_gate / w_up [E_held, D, F] and w_down
+    [E_held, F, D], are gated-SiLU experts; TWO, w_up [E_held, D, F] and
+    w_down [E_held, F, D], are squared-ReLU experts without a gate. D is
+    the experts' own width in and out (a latent's, where the caller
+    projects into one). `valid` [N] bool (optional): rows that
     carry a token; the others reach no expert. `n_routed` (static): the
     experts the router chose among, of which these are the held; None:
     every choice may be held.
@@ -257,20 +281,21 @@ def token_choice_experts(x: jax.Array, expert_ids: jax.Array,
     expert's group, summed in float32. No routing drops a row.
 
     → (y [N, D] in x.dtype: Σ over a row's choices that land on a held
-    expert of gate · W_down(silu(W_gate x) ⊙ W_up x), zero for the rest;
+    expert of gate · Expert(x), zero for the rest;
     counts [E_held] int32: rows each held expert received)."""
+    if len(weights) not in (2, 3):
+        raise ValueError("an expert is three stacks (gated SiLU) or two "
+                         f"(squared ReLU); got {len(weights)}")
     N, D = x.shape
-    E = w_gate.shape[0] if layer is None else w_gate.shape[1]
+    E = weights[0].shape[0 if layer is None else 1]
     with jax.named_scope(scopes.MOE_ROUTE):
         ids = expert_ids.reshape(N, -1)
     k = ids.shape[1]
     block = block_rows(N * k, E, n_routed)
     kw = dict(first_expert=first_expert, layer=layer, valid=valid)
     if block == _pad_rows(N * k + E):
-        return _every_choice_a_row(x, ids, gates, (w_gate, w_up, w_down),
-                                   **kw)
-    return _held_rows_in_blocks(x, ids, gates, (w_gate, w_up, w_down),
-                                block=block, **kw)
+        return _every_choice_a_row(x, ids, gates, weights, **kw)
+    return _held_rows_in_blocks(x, ids, gates, weights, block=block, **kw)
 
 
 def _held_choices(ids, n_held: int, first_expert: int, valid):
@@ -287,22 +312,27 @@ def _held_choices(ids, n_held: int, first_expert: int, valid):
     return local, counts
 
 
-def _layer_groups(sizes, layer, w_gate):
-    """A layer's group sizes among the whole stack's L * E groups."""
+def _layer_groups(sizes, layer, stack):
+    """A layer's group sizes among the whole `stack`'s L * E groups."""
     if layer is None:
         return sizes
-    n_layers, E = w_gate.shape[:2]
+    n_layers, E = stack.shape[:2]
     return jax.lax.dynamic_update_slice(
         jnp.zeros(n_layers * E, jnp.int32), sizes, (layer * E,))
 
 
-def _gated_silu(rows, sizes, weights, layer):
-    """rows [M, D] in contiguous groups of `sizes` → [M, D] float32."""
+def _experts(rows, sizes, weights, layer):
+    """rows [M, D] in contiguous groups of `sizes` → [M, D] float32:
+    three stacks a gated-SiLU expert, two a squared-ReLU one."""
     with jax.named_scope(scopes.MOE_EXPERTS):
         if layer is not None:
             weights = tuple(w.reshape((-1,) + w.shape[2:]) for w in weights)
-        w_gate, w_up, w_down = weights
         dot = functools.partial(_grouped_dot, sizes=sizes)
+        if len(weights) == 2:
+            w_up, w_down = weights
+            h = jnp.square(jax.nn.relu(dot(rows, w_up))).astype(rows.dtype)
+            return dot(h, w_down)
+        w_gate, w_up, w_down = weights
         h = (jax.nn.silu(dot(rows, w_gate))
              * dot(rows, w_up)).astype(rows.dtype)
         return dot(h, w_down)
@@ -333,7 +363,7 @@ def _every_choice_a_row(x, ids, gates, weights, *, first_expert, layer,
         order = jnp.argsort(local, stable=True)
         rows = rows[order]
         sizes = _layer_groups(sizes, layer, weights[0])
-    out = _gated_silu(rows, sizes, weights, layer)          # [M, D] fp32
+    out = _experts(rows, sizes, weights, layer)          # [M, D] fp32
     with jax.named_scope(scopes.MOE_ROUTE):
         # Rows past the held groups belong to no group: whatever the
         # grouped matmul left there is dropped here.
@@ -385,7 +415,7 @@ def _held_rows_in_blocks(x, ids, gates, weights, *, first_expert, layer,
             sizes = _layer_groups(
                 jnp.clip(ends, lo, lo + R) - jnp.clip(starts, lo, lo + R),
                 layer, weights[0])
-        out = _gated_silu(rows, sizes, weights, layer)      # [R, D] fp32
+        out = _experts(rows, sizes, weights, layer)      # [R, D] fp32
         with jax.named_scope(scopes.MOE_ROUTE):
             # A zero row, and a row past the groups (whatever the grouped
             # matmul left there), add nothing.

@@ -24,6 +24,9 @@ MLP = "mlp"                      # dense MLP with its norm; a shared expert
 MOE_ROUTE = "moe.route"          # router, top-k, sort, group sizes,
                                  # unsort and combine
 MOE_EXPERTS = "moe.experts"      # the grouped matmuls
+MOE_LATENT = "moe.latent"        # latent experts: the shared projection
+                                 # into the experts' latent before the
+                                 # grouped matmuls and out of it after
 SLOT_STATE = "slot_state"        # a per-slot state's read and write; the
                                  # chain of a dispatch's rows and the
                                  # write-back's targets
@@ -34,14 +37,15 @@ GDN_SCAN = "gdn.scan"            # the recurrent state's only reader and
                                  # writer: the decode step's update, a
                                  # chunk's scan and its write-back
 GDN_OUT = "gdn.out"              # gated norm, output projection, residual
-SSM_IN = "ssm.in"                # a selective-scan layer: norm, input
+SSM_IN = "ssm.in"                # a state-space layer: norm, input
                                  # projection, the convolution with its
-                                 # tail, SiLU, W_x, the three inner norms,
-                                 # W_dt, softplus
+                                 # tail, SiLU; Mamba-1's W_x, three inner
+                                 # norms and W_dt; softplus
 SSM_SCAN = "ssm.scan"            # the state-space state's only reader and
                                  # writer: the decode step's update, a
                                  # chunk's scan and its write-back
-SSM_OUT = "ssm.out"              # gate, output projection, residual
+SSM_OUT = "ssm.out"              # gate (Mamba-2: skip and grouped norm),
+                                 # output projection, residual
 HEAD = "head"                    # final norm, head matmul, row selection
 SAMPLE = "sample"                # argmax / categorical over the logits
 COUNTERS = "counters"            # on-device counters the host pulls
@@ -49,5 +53,5 @@ LOSS = "loss"                    # cross-entropy over the logits
 OPTIMIZER = "optimizer"          # the optimizer's update and its apply
 
 ALL = (EMBED, ATTN_IN, ATTN_ABSORB, ATTN_KV_WRITE, ATTN_KERNEL, ATTN_OUT,
-       MLP, MOE_ROUTE, MOE_EXPERTS, SLOT_STATE, GDN_IN, GDN_SCAN, GDN_OUT,
+       MLP, MOE_ROUTE, MOE_EXPERTS, MOE_LATENT, SLOT_STATE, GDN_IN, GDN_SCAN, GDN_OUT,
        SSM_IN, SSM_SCAN, SSM_OUT, HEAD, SAMPLE, COUNTERS, LOSS, OPTIMIZER)

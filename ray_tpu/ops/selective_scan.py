@@ -75,8 +75,11 @@ _F32 = jnp.float32
 _CHUNK_CHANNELS = 512
 # Slots a grid step of the convolution's decode step holds: their tail is
 # (taps-1) x 32 x 5,120 bf16 = 1 MB in and 1 MB out, twice for the
-# pipeline; a multiple of the 16 rows a bf16 tile has.
+# pipeline; a multiple of the 16 rows a bf16 tile has. Wider channels
+# take fewer slots a block, so that its tail stays under that 1 MB (16
+# slots at 10,240 channels: 32 ran the chip's compiler out of VMEM).
 _CONV_SLOTS = 32
+_CONV_BLOCK_BYTES = 2**20
 # Slots a grid step of the scan's decode step holds: a float32 tile's 8
 # sublanes, so xs, dt and y move as whole tiles [8, d_inner]; their state
 # is 8 x 16 x 5,120 float32 = 2.6 MB in and 2.6 MB out, twice for the
@@ -394,6 +397,9 @@ def ssm_conv_step(tail, layer, xs, taps, bias, active, *, interpret=None):
     _L, n_tail, _rows, Dn = tail.shape
     n = xs.shape[0]
     R = _CONV_SLOTS if n % _CONV_SLOTS == 0 else n
+    while (R % 32 == 0 and n % (R // 2) == 0
+           and n_tail * R * Dn * tail.dtype.itemsize > _CONV_BLOCK_BYTES):
+        R //= 2
     if not interpret and (Dn % 128 or R % 16):
         raise ValueError(
             f"ssm_conv_step wants slots in blocks of 16 over a multiple of "

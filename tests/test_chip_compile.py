@@ -421,7 +421,9 @@ def step_program(request, chip):
              "jamba": ("jamba_serving", "jamba", J_SLOTS, J_WIDTH),
              "kimi_k2": ("kimi_k2_serving", "kimi_k2", K2_SLOTS, K2_WIDTH),
              "olmo_hybrid": ("olmo_hybrid_serving", "olmo_hybrid", O_SLOTS,
-                             O_WIDTH)}
+                             O_WIDTH),
+             "nemotron_h": ("nemotron_h_serving", "nemotron_h", N_SLOTS,
+                            N_WIDTH)}
     i32 = lambda *shape: chip(shape, jnp.int32)
 
     @functools.cache
@@ -1461,6 +1463,138 @@ def test_olmo_hybrid_program_fits_and_moves_no_state(olmo_hybrid_serving,
     assert 4.86e9 < weight_bytes < 4.88e9 and 9.63e9 < pool_bytes < 9.66e9
     assert abs(mem.argument_size_in_bytes - weight_bytes - pool_bytes) < 1e7
     assert 14.5e9 < total < 15.3e9
+
+
+# --- the nemotron_h family: a 64 x 128 Mamba-2 state, latent experts -----
+# Nemotron-3-Super-120B-A12B as
+# `benchmarks/configs/nemotron-3-super-120b-a12b.json` serves it: one chip
+# of an expert-parallel four at layers 0-10 (`MEMEMEM*EME`: five Mamba-2
+# layers of 128 heads of 64 x 128, five expert layers holding 128 of 512
+# two-matrix experts in a latent of 1,024 under top-22, one attention
+# layer of 32 heads over 2 KV heads), a quarter of the vocabulary; 192
+# slots x 36 pages of 64 tokens.
+N_SLOTS, N_PAGES, N_WIDTH = 192, 6912, 36
+
+
+@pytest.fixture(scope="module")
+def nemotron_h_serving(chip):
+    """(cfg, params, pool) of the nemotron-3-super cell as shapes on one
+    described chip (the tree is served as `param_specs` shapes it: the
+    family has no `lay_out`), with the four backend questions steered to
+    the chip's answers."""
+    import importlib
+
+    from ray_tpu.models import nemotron_h
+
+    cfg = nemotron_h.NemotronHConfig(vocab_size=32768, n_experts=128,
+                                     max_seq=2304)
+    params = _served(chip, cfg, _stacks(chip, nemotron_h, cfg))
+    pool = jax.tree.map(
+        lambda x: chip(x.shape, x.dtype),
+        jax.eval_shape(lambda: nemotron_h.init_paged_kv(
+            cfg, N_PAGES, PS, N_SLOTS)))
+    mods = [importlib.import_module("ray_tpu.ops." + m)
+            for m in ("paged_attention", "gated_delta", "selective_scan")]
+    moe = importlib.import_module("ray_tpu.ops.moe")
+    saved = [m._interpret_default for m in mods], moe._mixed_dot_default
+    for m in mods:
+        m._interpret_default = lambda: False
+    moe._mixed_dot_default = lambda: True
+    yield cfg, params, pool
+    for m, fn in zip(mods, saved[0]):
+        m._interpret_default = fn
+    moe._mixed_dot_default = saved[1]
+
+
+def test_decode_kernels_compile_at_nemotron_h_rows(chip):
+    """The Mamba-2 step's kernel takes 64 pairs of heads of [128, 128]
+    float32 in blocks of 16 (two groups' B and C a block), 192 slots of
+    a stack of five layers, aliased in and out; the convolution's step
+    takes 16 slots' three planes of 10,240 channels and a float32 plane
+    of new inputs (what it returns stays float32: models/nemotron_h.py
+    `_ssm_inputs`)."""
+    from ray_tpu.ops.selective_scan import ssm_conv_step
+    from ray_tpu.ops.ssd import ssd_decode_step
+
+    f32 = lambda *s: chip(s, jnp.float32)
+    _compile(lambda s, l, x, dt, A, B, C, a: ssd_decode_step(
+        s, l, x, dt, A, B, C, a, interpret=False),
+        f32(5, N_SLOTS + 1, 64, 128, 128), _layer(chip),
+        f32(N_SLOTS, 128, 64), f32(N_SLOTS, 128), f32(128),
+        f32(N_SLOTS, 8, 128), f32(N_SLOTS, 8, 128),
+        chip((N_SLOTS,), jnp.bool_),
+        kernels=("ssd_decode_step",))
+    _compile(lambda t, l, x, w, b, a: ssm_conv_step(
+        t, l, x, w, b, a, interpret=False),
+        chip((5, 3, N_SLOTS + 1, 10240), jnp.bfloat16), _layer(chip),
+        f32(N_SLOTS, 10240), f32(4, 10240), f32(10240),
+        chip((N_SLOTS,), jnp.bool_), kernels=("ssm_conv_step",))
+
+
+@pytest.mark.parametrize("program", ONE_WIDTH_PROGRAMS)
+def test_nemotron_h_program_fits_and_moves_no_state(nemotron_h_serving,
+                                                    step_program, program):
+    """The nemotron_h family's step programs (decode, and the chunk
+    program at both of the engine's heights), compiled whole at the
+    cell's size: the attention call, the Mamba-2 step and the
+    convolution's step (in decode, once a Mamba-2 layer the program
+    holds) and the experts' grouped matmuls (TWO an expert layer's turn,
+    over the whole stack of 5 x 128 experts at the latent's 1,024 lanes)
+    are in them
+    under the names a trace finds them by; no layer of the state (193
+    slots x 64 pairs of [128, 128] float32, 0.81 GB: a copy of the
+    4.05 GB leaf does not fit) is copied, sliced out or put back, and no
+    layer of experts; no weight plane is cut out of its stack into a
+    buffer of its own; the donated pool is updated in place; the
+    ARGUMENTS' bytes are what ISSUE 62's arithmetic says: 9.30 GB of
+    weights + 4.56 GB of pool, under the chip's 16 GB with what the
+    program needs besides."""
+    cfg, params, pool = nemotron_h_serving
+    assert pool["ssm_state"].shape == (5, N_SLOTS + 1, 64, 128, 128)
+    assert pool["ssm_conv"].shape == (5, 3, N_SLOTS + 1, 10240)
+    assert pool["k"].shape == (1, N_PAGES + 1, PS, 256)
+    compiled = step_program("nemotron_h", program)
+    text = compiled.as_text()
+    calls = lambda name: len(re.findall(
+        rf"%\w*{name}[\w.]* = [^\n]*custom-call\(", text))
+    assert calls(_attn_kernel(program)) == 1
+    # "ME" x 3 is ONE loop body: a program holds three Mamba-2 and three
+    # expert layers (the loop's, and layers 6 and 9 / 8 and 10 alone).
+    assert len(re.findall(r" while\(", text)) >= 1
+    if program == "decode":
+        assert calls("ssd_decode_step") == calls("ssm_conv_step") == 3
+    assert len(re.findall(r"%ragged-dot[\w.\-]* = [^\n]*custom-call\(",
+                          text)) >= 2 * 3
+    assert f"bf16[{5 * 128},1024,2688]" in text     # the stack, whole
+    moved = (_pool_moves(text, "f32", (N_SLOTS + 1) * 64 * 128 * 128)
+             + _pool_moves(text, "bf16", 128 * 1024 * 2688)
+             + _pool_moves(text, "bf16", (N_PAGES + 1) * PS * 256))
+    assert not moved, "state-, expert- or plane-sized moves:\n" + "\n".join(
+        moved)
+    planes = {",".join(map(str, a.shape[1:])) for name, a in params.items()
+              if len(a.shape) == 3 and a.shape[1] * a.shape[2] >= _PLANE}
+    assert {"4096,18560", "8192,4096", "4096,1024", "1024,4096",
+            "4096,5376", "5376,4096", "4096,4096"} <= planes
+    # (the 8-row chunk program's embedding rows, 1,024 tokens x 4,096,
+    # have W_lat_out's dims and are no plane of it)
+    made = [m for m in _planes_made(text, planes) if "%params__wte__" not in m]
+    assert not made, "weight planes written out:\n" + "\n".join(made)
+    written = _weight_planes_written_to_hbm(text, planes)
+    assert set(written) <= {"wte"}, written
+    mem = compiled.memory_analysis()
+    nbytes = lambda tree: sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                              for a in jax.tree.leaves(tree))
+    pool_bytes, weight_bytes = nbytes(pool), nbytes(params)
+    assert mem.alias_size_in_bytes >= pool_bytes
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    print(f"nemotron_h {program}: arguments "
+          f"{mem.argument_size_in_bytes / 1e9:.3f} GB (weights "
+          f"{weight_bytes / 1e9:.3f} + pool {pool_bytes / 1e9:.3f}), temp "
+          f"{mem.temp_size_in_bytes / 1e9:.3f} GB, total {total / 1e9:.3f} GB")
+    assert 9.29e9 < weight_bytes < 9.31e9 and 4.55e9 < pool_bytes < 4.58e9
+    assert abs(mem.argument_size_in_bytes - weight_bytes - pool_bytes) < 1e7
+    assert 13.8e9 < total < 15.3e9
 
 
 # --- the sampling step: the draw under a conditional (PR 55) ------------

@@ -1,7 +1,7 @@
 """Every family's chunk, decode-step and window-step program, letter for
 letter.
 
-The serving programs of the eight families of models/serving.py are built
+The serving programs of the nine families of models/serving.py are built
 by one builder (models/paged_kv.py `paged_programs`) from parts that
 several families share (models/blocks.py). A refactor of either must
 leave every lowered program as it was: the digests below were computed
@@ -17,7 +17,8 @@ import jax.numpy as jnp
 import pytest
 
 from ray_tpu.models import (gpt, jamba, kimi_k2, laguna, mimo_v2,
-                            olmo_hybrid, paged_kv, qwen3_next, zaya)
+                            nemotron_h, olmo_hybrid, paged_kv, qwen3_next,
+                            zaya)
 
 PAGE, N_PAGES, N_SLOTS, CHUNK = 16, 24, 3, 16
 
@@ -86,6 +87,11 @@ PAGE, N_PAGES, N_SLOTS, CHUNK = 16, 24, 3, 16
 # fetched as a run of its ring's rows, one block, one softmax; the pools
 # handed over as planes of rows, the ring by its first row); the chunk
 # programs run the prefill kernel and stayed, as did the other families'.
+# The `nemotron_h` three as PR 62, the family's first, traced them; that
+# PR gave `ops/moe.py` `token_choice_experts` a second expert form (two
+# stacks: squared ReLU), `ops/gated_delta.py`'s decode step a form without
+# the delta term (ops/ssd.py) and moved jamba's causal convolution to
+# models/blocks.py: the twenty-four above did not move.
 _PINNED = {
     "gpt.chunk": "aff570174e390475", "gpt.decode": "c4ebbb44ff02a83f",
     "zaya.chunk": "e3c3c03e1e115475", "zaya.decode": "21818dedb3b70792",
@@ -106,6 +112,9 @@ _PINNED = {
     "olmo_hybrid.chunk": "d3337227332042d8",
     "olmo_hybrid.decode": "54ec2aa7a880ab04",
     "olmo_hybrid.sample": "8099e350910eabed",
+    "nemotron_h.chunk": "d2a290f93d9ff44b",
+    "nemotron_h.decode": "3e6fa39a4f27e0b6",
+    "nemotron_h.sample": "91ddb6cb0f06a571",
 }
 
 _RING = {"dispatch_tokens": 2 * CHUNK}
@@ -122,6 +131,8 @@ _FAMILIES = {
     "kimi_k2": (kimi_k2, kimi_k2, kimi_k2.KimiK2Config.tiny(), {}),
     "olmo_hybrid": (olmo_hybrid, olmo_hybrid,
                     olmo_hybrid.OlmoHybridConfig.tiny(), {}),
+    "nemotron_h": (nemotron_h, nemotron_h,
+                   nemotron_h.NemotronHConfig.tiny(), {}),
 }
 
 

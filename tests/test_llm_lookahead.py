@@ -24,7 +24,8 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import (gpt, jamba, kimi_k2, laguna, mimo_v2,
-                            olmo_hybrid, paged_kv, qwen3_next, zaya)
+                            nemotron_h, olmo_hybrid, paged_kv, qwen3_next,
+                            zaya)
 from ray_tpu.serve import llm
 from ray_tpu.serve.llm import LLMEngine
 
@@ -66,6 +67,15 @@ def _fams() -> dict:
             olmo_hybrid.OlmoHybridConfig.tiny(dtype=jnp.float32),
             olmo_hybrid.init_params, olmo_hybrid.forward,
             as_is=("f_qnorm", "f_knorm")),
+        # (the decay's rate a head is a stack [layers, heads]: its log
+        # times 8 is a rate of up to 16^8, a state that forgets at once;
+        # a squared-ReLU shared expert's output has a constant part, the
+        # same for every token, and at 8x it settles a greedy
+        # continuation on a few tokens: benchmarks/families/nemotron_h.py)
+        "nemotron_h": Fam(
+            nemotron_h.NemotronHConfig.tiny(dtype=jnp.float32),
+            nemotron_h.init_params, nemotron_h.forward,
+            as_is=("m_A_log", "s_down")),
     }
 
 
@@ -95,7 +105,7 @@ def _drop_programs():
 
 @pytest.fixture(scope="class", params=["gpt", "zaya", "laguna", "qwen3_next",
                                        "mimo_v2", "jamba", "kimi_k2",
-                                       "olmo_hybrid"])
+                                       "olmo_hybrid", "nemotron_h"])
 def family(request):
     """pytest runs a class's tests family by family for this fixture."""
     yield (request.param, *_serve(request.param))
@@ -345,7 +355,7 @@ class TestEveryFamily:
         s = eng.stats
         assert eng._carry is None and s["lookahead_windows"] >= 2
         names = {"zaya": zaya, "laguna": laguna, "qwen3_next": qwen3_next,
-                 "kimi_k2": kimi_k2,
+                 "kimi_k2": kimi_k2, "nemotron_h": nemotron_h,
                  "mimo_v2": mimo_v2}[name].COUNTERS
         device = dict(zip(names, (int(t) for t in eng.cache["moe_counters"])))
         seen = eng._moe_seen

@@ -1,6 +1,7 @@
 """ops/moe.py `token_choice_experts`: the no-drop token-choice expert
 layer against a per-token loop, and the parts of chips holding disjoint
-expert ranges against the whole.
+expert ranges against the whole; in both expert forms (`FORMS`: three
+stacks a gated-SiLU expert, two a squared-ReLU one).
 """
 
 import collections
@@ -12,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from ray_tpu.ops import moe
 from ray_tpu.ops.moe import token_choice_experts
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -22,26 +24,44 @@ if BENCH not in sys.path:
 N, D, F, E = 24, 16, 12, 8
 
 
-def _weights(seed=0, n_layers=None):
+# An expert's form is how many stacks the layer is handed.
+FORMS = {"gated_silu": 3, "relu2": 2}
+
+
+def _stacks(mk, d, f, form="gated_silu"):
+    """An expert form's stacks from `mk(*shape)`: [.., d, f] once (relu2)
+    or twice (gated SiLU), then [.., f, d]."""
+    return tuple(mk(d, f) for _ in range(FORMS[form] - 1)) + (mk(f, d),)
+
+
+def _weights(seed=0, n_layers=None, form="gated_silu"):
     rng = np.random.default_rng(seed)
     lead = (E,) if n_layers is None else (n_layers, E)
     mk = lambda *s: jnp.asarray(rng.normal(size=lead + s) * 0.3, jnp.float32)
-    return mk(D, F), mk(D, F), mk(F, D)
+    return _stacks(mk, D, F, form)
 
 
-def _loop(x, ids, gates, w_gate, w_up, w_down, valid=None):
+def _expert64(xn, mats):
+    """One expert on one row in float64: W_down(silu(W_gate x) * W_up x)
+    of three matrices, W_down relu(W_up x)^2 of two."""
+    xn = np.asarray(xn, np.float64)
+    if len(mats) == 2:
+        return np.maximum(xn @ mats[0], 0.0) ** 2 @ mats[1]
+    a = xn @ mats[0]
+    return (a / (1.0 + np.exp(-a)) * (xn @ mats[1])) @ mats[2]
+
+
+def _loop(x, ids, gates, *w, valid=None):
     """One token at a time, one choice at a time: the definition."""
     x, ids, gates = (np.asarray(a) for a in (x, ids, gates))
     ids, gates = ids.reshape(len(x), -1), gates.reshape(len(x), -1)
-    wg, wu, wd = (np.asarray(w, np.float64) for w in (w_gate, w_up, w_down))
-    out = np.zeros((len(x), D))
+    w = [np.asarray(t, np.float64) for t in w]
+    out = np.zeros((len(x), w[-1].shape[-1]))
     for n in range(len(x)):
         if valid is not None and not valid[n]:
             continue
         for e, g in zip(ids[n], gates[n]):
-            a = x[n].astype(np.float64) @ wg[e]
-            h = a / (1.0 + np.exp(-a)) * (x[n].astype(np.float64) @ wu[e])
-            out[n] += g * (h @ wd[e])
+            out[n] += g * _expert64(x[n], [t[e] for t in w])
     return out
 
 
@@ -59,14 +79,15 @@ ROUTINGS = {
 # 24 rows and their 8 zero rows fit one 128-row tile; 121 (+ 8, and twice
 # that at top-2) spill into the next and are padded to an odd number of
 # tiles.
+@pytest.mark.parametrize("form", sorted(FORMS))
 @pytest.mark.parametrize("n_rows", [N, 121])
 @pytest.mark.parametrize("routing", sorted(ROUTINGS))
-def test_matches_a_per_token_loop_and_drops_no_row(routing, n_rows):
+def test_matches_a_per_token_loop_and_drops_no_row(routing, n_rows, form):
     rng = np.random.default_rng(1)
     ids = ROUTINGS[routing](rng, n_rows).astype(np.int32)
     x = jnp.asarray(rng.normal(size=(n_rows, D)), jnp.float32)
     gates = jnp.asarray(rng.uniform(0.1, 1.0, ids.shape), jnp.float32)
-    w = _weights()
+    w = _weights(form=form)
     with jax.default_matmul_precision("highest"):
         y, counts = jax.jit(token_choice_experts)(
             x, jnp.asarray(ids), gates, *w)
@@ -295,9 +316,53 @@ def test_top_8_of_384_with_12_held(first, n_rows, layer):
     assert unreached.any() and not np.asarray(y)[unreached].any()
 
 
-# ---------- told the router's width, the layer carries the held rows only
+# ---- top-22 of 512, two-matrix relu2 experts in a latent: nemotron-h's
 
-from ray_tpu.ops import moe  # noqa: E402
+@pytest.mark.parametrize("held,first,n_routed", [
+    (128, 0, 512), (128, 384, 512), (512, 0, 512), (512, 0, None)])
+@pytest.mark.parametrize("n_rows,layer", [(48, None), (48, 3), (5, 1)])
+def test_top_22_of_512_relu2_experts_in_a_latent(held, first, n_routed,
+                                                 n_rows, layer):
+    """A decode step of the nemotron-3-super cell in small widths: 22
+    choices a row over 512 experts of which the chip holds 128 (the
+    first or the last share: the held-rows path, at 48 rows) or all 512
+    (every choice a row), gates that sum to 5, TWO stacks an expert
+    (W_down relu(W_up l)^2) whose width in and out is a latent's (8, of
+    a model that the layer never sees), whole stacks and a layer index
+    as the program passes them. The held choices, and only they, against
+    the per-token float64 loop; a shape the gated form would refuse."""
+    rng = np.random.default_rng(22)
+    d_latent, f = 8, 6
+    ids, gates = _top_k_routing(rng, n_rows, 512, 22)
+    gates = gates * 2.0                                 # sum to 5
+    latent = jnp.asarray(rng.normal(size=(n_rows, d_latent)), jnp.float32)
+    lead = (held,) if layer is None else (5, held)
+    mk = lambda *s: jnp.asarray(rng.normal(size=lead + s) * 0.3, jnp.float32)
+    w = _stacks(mk, d_latent, f, "relu2")
+    assert len(w) == 2
+    kw = {} if layer is None else {"layer": jnp.int32(layer)}
+    blocks_of_held = (n_routed == 512 and held == 128 and n_rows == 48)
+    assert (moe.block_rows(ids.size, held, n_routed)
+            < moe._pad_rows(ids.size + held)) == blocks_of_held
+    with jax.default_matmul_precision("highest"):
+        y, counts = jax.jit(token_choice_experts,
+                            static_argnames=("first_expert", "n_routed"))(
+            latent, jnp.asarray(ids), jnp.asarray(gates, jnp.float32), *w,
+            first_expert=first, n_routed=n_routed, **kw)
+    assert y.shape == (n_rows, d_latent)
+    mine = w if layer is None else tuple(t[layer] for t in w)
+    np.testing.assert_allclose(
+        np.asarray(y), _held_loop(latent, ids, gates, mine, first), atol=4e-5)
+    here = (ids >= first) & (ids < first + held)
+    np.testing.assert_array_equal(
+        np.asarray(counts), np.bincount(ids[here] - first, minlength=held))
+    assert int(counts.sum()) == (ids.size if held == 512 else int(here.sum()))
+    with pytest.raises(ValueError, match="three stacks"):
+        token_choice_experts(latent, jnp.asarray(ids), jnp.asarray(gates),
+                             w[0])
+
+
+# ---------- told the router's width, the layer carries the held rows only
 
 # 64 rows x 4 choices over 64 experts of which 4 are held: an even router
 # sends 16 choices here, so a block is 128 rows (4 zero rows + 124) where
@@ -339,15 +404,12 @@ def _held_loop(x, ids, gates, w, first, valid=None):
         if valid is not None and not valid[n]:
             continue
         for e, g in zip(ids[n], gates[n]):
-            if not first <= e < first + held:
-                continue
-            xn = np.asarray(x[n], np.float64)
-            a = xn @ mine[0][e - first]
-            want[n] += g * ((a / (1.0 + np.exp(-a))
-                             * (xn @ mine[1][e - first])) @ mine[2][e - first])
+            if first <= e < first + held:
+                want[n] += g * _expert64(x[n], [t[e - first] for t in mine])
     return want
 
 
+@pytest.mark.parametrize("form", sorted(FORMS))
 @pytest.mark.parametrize("routing,k,layer,masked,over", [
     ("even", B_K, None, False, 0),
     ("even", B_K, 2, True, 0),
@@ -361,7 +423,7 @@ def _held_loop(x, ids, gates, w, first, valid=None):
     ("one_expert", 1, 2, True, None),
 ])
 def test_blocks_of_held_rows_match_the_loop_for_any_routing(
-        routing, k, layer, masked, over):
+        routing, k, layer, masked, over, form):
     """With `n_routed` the layer takes the held choices a block at a
     time: an even router's are one turn; a routing that sends every
     choice to the held experts (the one the loop exists for) takes
@@ -376,7 +438,7 @@ def test_blocks_of_held_rows_match_the_loop_for_any_routing(
     valid = rng.uniform(size=n_rows) < 0.6 if masked else None
     lead = (B_HELD,) if layer is None else (3, B_HELD)
     mk = lambda *s: jnp.asarray(rng.normal(size=lead + s) * 0.3, jnp.float32)
-    w = mk(B_D, B_F), mk(B_D, B_F), mk(B_F, B_D)
+    w = _stacks(mk, B_D, B_F, form)
     kw = {} if layer is None else {"layer": jnp.int32(layer)}
     if masked:
         kw["valid"] = jnp.asarray(valid)
@@ -405,8 +467,9 @@ def test_blocks_of_held_rows_match_the_loop_for_any_routing(
         assert not np.asarray(y)[~valid].any()
 
 
+@pytest.mark.parametrize("form", sorted(FORMS))
 @pytest.mark.parametrize("layer", [None, 1])
-def test_two_shares_blocks_add_up_to_the_whole_layer(layer):
+def test_two_shares_blocks_add_up_to_the_whole_layer(layer, form):
     """Every choice on experts 0..5, a share holding 0..3 (two thirds of
     the choices: more turns than one) and one holding 4..7, each told the
     router's 64: the two parts add up to the loop over all eight."""
@@ -415,7 +478,7 @@ def test_two_shares_blocks_add_up_to_the_whole_layer(layer):
     x = jnp.asarray(rng.normal(size=(B_N, B_D)), jnp.float32)
     lead = (8,) if layer is None else (2, 8)
     mk = lambda *s: jnp.asarray(rng.normal(size=lead + s) * 0.3, jnp.float32)
-    w = mk(B_D, B_F), mk(B_D, B_F), mk(B_F, B_D)
+    w = _stacks(mk, B_D, B_F, form)
     kw = {} if layer is None else {"layer": jnp.int32(layer)}
     call = jax.jit(token_choice_experts,
                    static_argnames=("first_expert", "n_routed"))
@@ -443,6 +506,9 @@ def test_two_shares_blocks_add_up_to_the_whole_layer(layer):
     (64, 16, None, 128, 128),           # zaya1-8b.reason's: holds all
     (64, 16, 16, 128, 128),
     (1024 * 8, 12, 384, 640, 8320),     # a kimi-k2.6 chunk program of 8 rows
+    # nemotron-3-super-120b-a12b.subagents' decode step: 8.25 rows an expert
+    (192 * 22, 128, 512, 2432, 4480),
+    (1024 * 22, 128, 512, 11392, 22656),   # ... and its chunk program of 8 rows
 ])
 def test_block_rows_at_the_cells_shapes(n_choices, held, routed, rows, full):
     assert moe.block_rows(n_choices, held, routed) == rows

@@ -18,8 +18,8 @@ import optax
 import pytest
 from jax.sharding import Mesh
 
-from ray_tpu.models import (gpt, jamba, kimi_k2, laguna, paged_kv, qwen3_next,
-                            serving, zaya)
+from ray_tpu.models import (gpt, jamba, kimi_k2, laguna, nemotron_h, paged_kv,
+                            qwen3_next, serving, zaya)
 from ray_tpu.ops import scopes
 from ray_tpu.train import spmd
 
@@ -28,9 +28,11 @@ TINY = {"gpt": gpt.GPTConfig.tiny_untied, "zaya": zaya.ZayaConfig.tiny,
         "laguna": laguna.LagunaConfig.tiny,
         "qwen3_next": qwen3_next.Qwen3NextConfig.tiny,
         "jamba": jamba.JambaConfig.tiny,
-        "kimi_k2": kimi_k2.KimiK2Config.tiny}
+        "kimi_k2": kimi_k2.KimiK2Config.tiny,
+        "nemotron_h": nemotron_h.NemotronHConfig.tiny}
 MODULES = {"gpt": paged_kv, "zaya": zaya, "laguna": laguna,
-           "qwen3_next": qwen3_next, "jamba": jamba, "kimi_k2": kimi_k2}
+           "qwen3_next": qwen3_next, "jamba": jamba, "kimi_k2": kimi_k2,
+           "nemotron_h": nemotron_h}
 
 
 def _shapes(fn, *args, **kw):
@@ -182,9 +184,11 @@ def test_every_part_of_a_program_lies_in_one_scope(family, program):
             want |= {scopes.SLOT_STATE} if program == "chunk" else set()
         if family == "kimi_k2":
             want |= {scopes.ATTN_ABSORB}
-        if family == "jamba":
+        if family in ("jamba", "nemotron_h"):
             want |= {scopes.SSM_IN, scopes.SSM_SCAN, scopes.SSM_OUT}
             want |= {scopes.SLOT_STATE} if program == "chunk" else set()
+        if family == "nemotron_h":
+            want |= {scopes.MOE_LATENT}
     assert want <= seen, f"scopes never opened: {sorted(want - seen)}"
 
 
@@ -201,7 +205,9 @@ def test_a_scope_adds_nothing_to_the_module(family, program):
 
 def test_the_vocabulary_is_small_and_flat():
     # 21 since PR 56 (`attn.absorb`: latent attention's two matmuls
-    # around the call, which are neither its inputs nor its output).
-    assert len(scopes.ALL) == len(set(scopes.ALL)) <= 21
+    # around the call, which are neither its inputs nor its output); 22
+    # since PR 62 (`moe.latent`: the projection into the experts' latent
+    # and out of it, which is neither the router nor a grouped matmul).
+    assert len(scopes.ALL) == len(set(scopes.ALL)) <= 22
     for name in scopes.ALL:
         assert re.fullmatch(r"[a-z_]+(\.[a-z_]+)?", name), name
