@@ -1480,7 +1480,7 @@ N_SLOTS, N_PAGES, N_WIDTH = 192, 6912, 36
 def nemotron_h_serving(chip):
     """(cfg, params, pool) of the nemotron-3-super cell as shapes on one
     described chip (the tree is served as `param_specs` shapes it: the
-    family has no `lay_out`), with the four backend questions steered to
+    family has no `lay_out`), with the five backend questions steered to
     the chip's answers."""
     import importlib
 
@@ -1494,7 +1494,8 @@ def nemotron_h_serving(chip):
         jax.eval_shape(lambda: nemotron_h.init_paged_kv(
             cfg, N_PAGES, PS, N_SLOTS)))
     mods = [importlib.import_module("ray_tpu.ops." + m)
-            for m in ("paged_attention", "gated_delta", "selective_scan")]
+            for m in ("paged_attention", "gated_delta", "selective_scan",
+                      "grouped_matmul")]
     moe = importlib.import_module("ray_tpu.ops.moe")
     saved = [m._interpret_default for m in mods], moe._mixed_dot_default
     for m in mods:
@@ -1531,6 +1532,27 @@ def test_decode_kernels_compile_at_nemotron_h_rows(chip):
         chip((N_SLOTS,), jnp.bool_), kernels=("ssm_conv_step",))
 
 
+@pytest.mark.parametrize("rows", [2432, 5760, 11392])
+@pytest.mark.parametrize("K,N", [(1024, 2688), (2688, 1024)])
+def test_grouped_matmul_compiles_at_nemotron_h_planes(chip, K, N, rows):
+    """The repo's grouped matmul at the cell's shapes: a block of the
+    decode step's held rows (2,432) and of the two chunk programs'
+    (5,760 and 11,392), the WHOLE stack of 5 x 128 planes of
+    [1,024, 2,688] or [2,688, 1,024] bf16 as its operand, float32 out. A
+    weight block is one whole plane, 5.5 MB, two of them in flight: the
+    chip's compiler takes it within the kernel's own VMEM limit, and the
+    program holds the stack once (no copy of it, nor of a layer of it)."""
+    from ray_tpu.ops import grouped_matmul as gm
+
+    assert gm._n_tile(K, N, 2) == N
+    compiled = _compile(
+        lambda a, b, s: gm.moe_grouped_matmul(a, b, s, interpret=False),
+        chip((rows, K), jnp.bfloat16), chip((640, K, N), jnp.bfloat16),
+        chip((640,), jnp.int32), kernels=(gm.KERNEL_NAME,))
+    assert not _pool_moves(compiled.as_text(), "bf16", 128 * K * N)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
 @pytest.mark.parametrize("program", ONE_WIDTH_PROGRAMS)
 def test_nemotron_h_program_fits_and_moves_no_state(nemotron_h_serving,
                                                     step_program, program):
@@ -1539,8 +1561,9 @@ def test_nemotron_h_program_fits_and_moves_no_state(nemotron_h_serving,
     cell's size: the attention call, the Mamba-2 step and the
     convolution's step (in decode, once a Mamba-2 layer the program
     holds) and the experts' grouped matmuls (TWO an expert layer's turn,
-    over the whole stack of 5 x 128 experts at the latent's 1,024 lanes)
-    are in them
+    over the whole stack of 5 x 128 experts at the latent's 1,024 lanes:
+    the repo's kernel, the planes being 2,688 = 21 x 128 wide, and no
+    `ragged-dot` of the compiler's) are in them
     under the names a trace finds them by; no layer of the state (193
     slots x 64 pairs of [128, 128] float32, 0.81 GB: a copy of the
     4.05 GB leaf does not fit) is copied, sliced out or put back, and no
@@ -1563,8 +1586,8 @@ def test_nemotron_h_program_fits_and_moves_no_state(nemotron_h_serving,
     assert len(re.findall(r" while\(", text)) >= 1
     if program == "decode":
         assert calls("ssd_decode_step") == calls("ssm_conv_step") == 3
-    assert len(re.findall(r"%ragged-dot[\w.\-]* = [^\n]*custom-call\(",
-                          text)) >= 2 * 3
+    assert calls("moe_grouped_matmul") == 2 * 3
+    assert "ragged-dot" not in text
     assert f"bf16[{5 * 128},1024,2688]" in text     # the stack, whole
     moved = (_pool_moves(text, "f32", (N_SLOTS + 1) * 64 * 128 * 128)
              + _pool_moves(text, "bf16", 128 * 1024 * 2688)
@@ -1694,7 +1717,8 @@ def test_expert_layer_carries_only_a_block_of_held_rows(request, step_program,
     float32 array of every choice a row is left in the program. Where
     the block IS every choice (zaya holds all 16, laguna half of 256
     under top-10) the rows are the ones they were, and the expert layer
-    brings no loop and no branch of its own."""
+    brings no loop and no branch of its own. The grouped matmuls are the
+    compiler's `ragged-dot` in all five."""
     from ray_tpu.ops import moe
 
     slots, k, rows, loops = _EXPERT_BLOCKS[family]
@@ -1706,6 +1730,9 @@ def test_expert_layer_carries_only_a_block_of_held_rows(request, step_program,
     assert (rows == full) == (family in ("laguna", "zaya"))
     calls = _GROUPED.findall(text)
     assert len(calls) >= 3
+    # (every plane of these five is a multiple of 512 both ways: the
+    # compiler's kernel, not the repo's: ops/moe.py `_narrow_tiled`)
+    assert "moe_grouped_matmul" not in text
     for result_rows, operands in calls:
         assert int(result_rows) == rows and f"bf16[{rows}," in operands
         stack = re.search(r"bf16\[(\d+),\d+,\d+\]", operands)

@@ -318,11 +318,13 @@ def test_top_8_of_384_with_12_held(first, n_rows, layer):
 
 # ---- top-22 of 512, two-matrix relu2 experts in a latent: nemotron-h's
 
-@pytest.mark.parametrize("held,first,n_routed", [
-    (128, 0, 512), (128, 384, 512), (512, 0, 512), (512, 0, None)])
+@pytest.mark.parametrize("held,first,n_routed,widths", [
+    (128, 0, 512, (8, 6)), (128, 384, 512, (8, 6)), (512, 0, 512, (8, 6)),
+    (512, 0, None, (8, 6)), (128, 384, 512, (128, 384))])
 @pytest.mark.parametrize("n_rows,layer", [(48, None), (48, 3), (5, 1)])
-def test_top_22_of_512_relu2_experts_in_a_latent(held, first, n_routed,
-                                                 n_rows, layer):
+def test_top_22_of_512_relu2_experts_in_a_latent(monkeypatch, held, first,
+                                                 n_routed, widths, n_rows,
+                                                 layer):
     """A decode step of the nemotron-3-super cell in small widths: 22
     choices a row over 512 experts of which the chip holds 128 (the
     first or the last share: the held-rows path, at 48 rows) or all 512
@@ -330,14 +332,21 @@ def test_top_22_of_512_relu2_experts_in_a_latent(held, first, n_routed,
     (W_down relu(W_up l)^2) whose width in and out is a latent's (8, of
     a model that the layer never sees), whole stacks and a layer index
     as the program passes them. The held choices, and only they, against
-    the per-token float64 loop; a shape the gated form would refuse."""
+    the per-token float64 loop; a shape the gated form would refuse. At a
+    latent of 128 under experts 384 wide both grouped matmuls are the
+    repo's kernel's (`_narrow_tiled`; told it is on the TPU, interpreted
+    here), through the same layer."""
     rng = np.random.default_rng(22)
-    d_latent, f = 8, 6
+    d_latent, f = widths
+    scale = 0.3 if d_latent == 8 else 0.05
+    if moe._narrow_tiled(d_latent, f):
+        monkeypatch.setattr(moe, "_mixed_dot_default", lambda: True)
     ids, gates = _top_k_routing(rng, n_rows, 512, 22)
     gates = gates * 2.0                                 # sum to 5
     latent = jnp.asarray(rng.normal(size=(n_rows, d_latent)), jnp.float32)
     lead = (held,) if layer is None else (5, held)
-    mk = lambda *s: jnp.asarray(rng.normal(size=lead + s) * 0.3, jnp.float32)
+    mk = lambda *s: jnp.asarray(rng.normal(size=lead + s) * scale,
+                                jnp.float32)
     w = _stacks(mk, d_latent, f, "relu2")
     assert len(w) == 2
     kw = {} if layer is None else {"layer": jnp.int32(layer)}
@@ -514,3 +523,120 @@ def test_block_rows_at_the_cells_shapes(n_choices, held, routed, rows, full):
     assert moe.block_rows(n_choices, held, routed) == rows
     assert moe._pad_rows(n_choices + held) == full
     assert rows % 256 == 128 and rows <= full
+
+
+# ------- the grouped matmul of the repo's own, and where the layer takes it
+
+def _group_sizes(case, n_layers, live):
+    """→ sizes [n_layers * 6] int32, a layer's six groups non-empty only
+    in layer `live`: groups of 0, 1 and many rows."""
+    one = {"few": [0, 1, 5, 0, 3, 1],               # 10 rows in one tile
+           "many": [1, 140, 0, 17, 131, 60],        # 349: groups across tiles
+           "full": [100, 0, 28, 200, 50, 6],        # 384: no row past them
+           "none": [0] * 6}[case]
+    sizes = np.zeros(n_layers * 6, np.int32)
+    sizes[live * 6:(live + 1) * 6] = one
+    return sizes
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("case,n_layers,live", [
+    ("few", 1, 0), ("many", 1, 0), ("full", 1, 0), ("none", 1, 0),
+    ("few", 3, 1), ("many", 3, 1)])
+@pytest.mark.parametrize("K,N", [(128, 384), (384, 128), (256, 640)])
+def test_the_repos_grouped_matmul_is_ragged_dot(monkeypatch, K, N, case,
+                                                n_layers, live, dtype):
+    """`moe_grouped_matmul`, interpreted, against `jax.lax.ragged_dot` in
+    float32 on the same values: groups of 0, 1 and many rows, groups
+    that lie across row tiles, a stack of three layers of which only the
+    middle one's groups have rows; rows past the last group may come
+    back holding anything and are not compared. A plane wider than the
+    kernel's block budget (here made 128 x 128 values) is cut along N."""
+    from ray_tpu.ops import grouped_matmul as gm
+
+    rng = np.random.default_rng(K + N + n_layers)
+    sizes = _group_sizes(case, n_layers, live)
+    M, n = 384, int(sizes.sum())
+    lhs = jnp.asarray(rng.normal(size=(M, K)), dtype)
+    rhs = jnp.asarray(rng.normal(size=(len(sizes), K, N)) * 0.1, dtype)
+    with jax.default_matmul_precision("highest"):
+        want = jax.lax.ragged_dot(lhs.astype(jnp.float32),
+                                  rhs.astype(jnp.float32), jnp.asarray(sizes))
+        got = gm.moe_grouped_matmul(lhs, rhs, jnp.asarray(sizes),
+                                    interpret=True)
+        monkeypatch.setattr(gm, "_PLANE_BYTES", K * 128 * rhs.dtype.itemsize)
+        assert gm._n_tile(K, N, rhs.dtype.itemsize) == 128
+        # (the function under its `jit`: a new trace under the new budget)
+        in_parts = gm.moe_grouped_matmul.__wrapped__(
+            lhs, rhs, jnp.asarray(sizes), interpret=True)
+    assert got.shape == (M, N) and got.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got)[:n], np.asarray(want)[:n],
+                               atol=2e-5)
+    np.testing.assert_array_equal(np.asarray(in_parts)[:n],
+                                  np.asarray(got)[:n])
+
+
+def test_the_repos_grouped_matmul_walks_no_empty_group():
+    """The kernel's walk: a visit for every (group, row tile) pair with a
+    row in it, in row order, none for an empty group or for a tile past
+    the last group; rows and shapes it cannot tile are refused."""
+    from ray_tpu.ops import grouped_matmul as gm
+
+    sizes = _group_sizes("many", 3, 1)
+    group, tile, start, end, count = (
+        np.asarray(a) for a in gm.visits(jnp.asarray(sizes), 512))
+    assert len(group) == 512 // 128 + len(sizes)
+    seen = [(g, t, s, e) for g, t, s, e
+            in zip(group, tile, start, end)][:int(count)]
+    assert seen == [(6, 0, 0, 1), (7, 0, 1, 128), (7, 1, 128, 141),
+                    (9, 1, 141, 158), (10, 1, 158, 256), (10, 2, 256, 289),
+                    (11, 2, 289, 349)]
+    assert int(gm.visits(jnp.zeros(4, jnp.int32), 256)[-1]) == 0
+    bf16 = lambda *shape: jnp.zeros(shape, jnp.bfloat16)
+    for lhs, rhs in [(bf16(200, 128), bf16(6, 128, 384)),      # M % 128
+                     (bf16(256, 128), bf16(6, 128, 200)),      # N % 128
+                     (bf16(256, 64), bf16(6, 64, 384)),        # K % 128
+                     (bf16(256, 128), bf16(6, 256, 384))]:     # K != K
+        with pytest.raises(ValueError, match="moe_grouped_matmul"):
+            gm.moe_grouped_matmul(lhs, rhs, jnp.zeros(6, jnp.int32),
+                                  interpret=True)
+
+
+def _grouped_calls(K, N, rows, groups):
+    """The grouped matmuls `_grouped_dot`'s jaxpr holds for a [K, N]
+    plane, by shapes alone (no plane is made)."""
+    # (a fresh function: the trace of one backend's answer is not the other's)
+    text = str(jax.make_jaxpr(lambda *a: moe._grouped_dot(*a))(
+        jax.ShapeDtypeStruct((rows, K), jnp.bfloat16),
+        jax.ShapeDtypeStruct((groups, K, N), jnp.bfloat16),
+        jax.ShapeDtypeStruct((groups,), jnp.int32)))
+    return {name for name in ("ragged_dot", "moe_grouped_matmul")
+            if name in text}
+
+
+@pytest.mark.parametrize("K,N,rows,groups,kernel", [
+    (1024, 2688, 2432, 640, True),      # nemotron-3-super's up plane
+    (2688, 1024, 2432, 640, True),      # ... and its down plane
+    (1024, 2688, 11392, 640, True),     # ... in its chunk program
+    (128, 384, 384, 6, True), (384, 128, 384, 6, True),
+    (256, 640, 384, 6, True),
+    (2048, 2048, 128, 16, False),       # zaya1-8b
+    (3072, 1024, 896, 128, False), (1024, 3072, 896, 128, False),  # laguna
+    (2048, 512, 896, 128, False), (512, 2048, 896, 128, False),  # qwen3_next
+    (4096, 2048, 384, 16, False), (2048, 4096, 384, 16, False),  # mimo_v2
+    (7168, 2048, 384, 12, False), (2048, 7168, 384, 12, False),  # kimi_k2
+    (8, 6, 128, 8, False), (16, 12, 128, 8, False),     # the tiny families'
+    (64, 128, 128, 8, False), (1024, 2560, 384, 6, False)])
+def test_the_plane_says_which_grouped_matmul_runs(monkeypatch, K, N, rows,
+                                                  groups, kernel):
+    """`_grouped_dot` on the TPU: the repo's kernel where a plane's K or
+    N is a multiple of 128 and not of 512 (nemotron-3-super's 2,688 =
+    21 x 128, which XLA:TPU's kernel tiles 128 wide), `ragged_dot` at
+    every plane of the five sibling configurations; off the TPU,
+    `ragged_dot` whatever the plane."""
+    assert moe._narrow_tiled(K, N) == kernel
+    assert not moe._mixed_dot_default()
+    assert _grouped_calls(K, N, rows, groups) == {"ragged_dot"}
+    monkeypatch.setattr(moe, "_mixed_dot_default", lambda: True)
+    assert _grouped_calls(K, N, rows, groups) == {
+        "moe_grouped_matmul" if kernel else "ragged_dot"}
