@@ -127,8 +127,7 @@ def run_scenario(*, clients: int = 32, replicas: int = 3,
     rng = np.random.default_rng(seed)
     prompts = [[int(t) for t in rng.integers(0, cfg.vocab_size, prompt_len)]
                for _ in range(clients)]
-    engine_kwargs = {"prefill_buckets": (16, 32),
-                     "kv_mode": "paged", "page_size": 16,
+    engine_kwargs = {"page_size": 16,
                      "prefill_chunk": prefill_chunk,
                      "prefill_token_budget": max(prefill_chunk,
                                                  n_slots * prefill_chunk)}

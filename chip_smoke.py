@@ -515,8 +515,7 @@ def phase_serve(size: Size, attn_impl: str, ckpt_dir: str,
     cfg = gpt.GPTConfig.by_name(size.model)
     prompts = make_prompts(size, cfg.vocab_size)
     gen = {"max_tokens": size.max_tokens, "temperature": 0.0}
-    engine_kwargs = dict(kv_mode="paged", page_size=size.ps,
-                         n_pages=size.n_pages,
+    engine_kwargs = dict(page_size=size.ps, n_pages=size.n_pages,
                          prefill_chunk=size.prefill_chunk,
                          attn_impl=attn_impl)
     t_start = time.perf_counter()
@@ -707,7 +706,7 @@ def phase_tp(size: Size, expect: str = "tpu", tp: int = 4) -> dict:
     cfg = gpt.GPTConfig.by_name(size.model)
     params = seeded_bf16_params(size.model)
     prompts = make_prompts(size, cfg.vocab_size)[:4]
-    kw = dict(n_slots=size.n_slots, max_len=size.max_len, kv_mode="paged",
+    kw = dict(n_slots=size.n_slots, max_len=size.max_len,
               page_size=size.ps, n_pages=size.n_pages,
               prefill_chunk=size.prefill_chunk,
               attn_impl="kernel" if expect == "tpu" else "gather")

@@ -13,8 +13,8 @@ makes it a first-class signal:
   span (child of the ambient trace when one exists), so compiles are
   visible at /metrics, /api/traces, and in `ray_tpu.timeline()`.
 - `wrap(fn, name)` is the attribution half: jitted callables we own
-  (serve/llm.py's engine dispatch table over models/decode.py +
-  models/paged_kv.py) run under a thread-local label, so listener-observed
+  (serve/llm.py's engine dispatch table over a family's programs,
+  models/serving.py) run under a thread-local label, so listener-observed
   compiles carry the owning program's name instead of "jax". On JAX builds
   without `jax.monitoring`, the wrapper itself detects compiles via the
   jitted callable's `_cache_size()` delta (counted, wall-time-bounded

@@ -287,23 +287,21 @@ class Config:
 
     # --- LLM serving engine ---
     # Decode window: tokens generated per host sync, with on-device
-    # sampling (dense engine: one fused program; paged engine: that many
-    # back-to-back dispatches of one step program). The dominant knob
+    # sampling (that many back-to-back dispatches of one step program,
+    # and one more left in flight). The dominant knob
     # when the host round trip is non-trivial (a loaded host); 1 = sync
     # and sample on the host every token.
     llm_decode_block: int = 8
     # Finished-but-unread token streams are garbage-collected after this.
     llm_stream_ttl_s: float = 600.0
-    # KV layout: "dense" preallocates [n_slots, max_len] per slot;
-    # "paged" shares a page pool with per-slot tables + ragged attention
-    # reads (models/paged_kv.py) — more slots per GB, preempt-by-
-    # recompute under pressure.
-    llm_kv_mode: str = "dense"
-    # Tokens per KV page in paged mode.
+    # Tokens per KV page. The engine's one cache is a page pool shared by
+    # the slots, with per-slot tables and ragged attention reads
+    # (models/paged_kv.py): more slots per GB than [n_slots, max_len],
+    # preempt-by-recompute under pressure.
     llm_kv_page_size: int = 64
-    # Paged-decode attention implementation: "gather" (reference —
-    # reconstitute each slot's contiguous timeline per layer, exact-match
-    # with the dense engine) | "kernel" (Pallas ragged paged-attention:
+    # Paged attention implementation: "gather" (reference —
+    # reconstitute each slot's contiguous timeline per layer, the tests'
+    # oracle) | "kernel" (Pallas ragged paged-attention:
     # K/V pages read in place with online softmax, no [B, T, H, K]
     # timeline in HBM — the throughput path on real chips; runs under
     # interpret=True off-TPU) | "auto" (resolve at engine init: "kernel"
@@ -313,15 +311,16 @@ class Config:
     # to "auto" with ROADMAP D3's deletions. Env:
     # RAY_TPU_LLM_ATTN_IMPL=auto.
     llm_attn_impl: str = "gather"
-    # Chunked prefill (paged mode only): prompts enter their slot's page
-    # table in fixed-size chunks co-scheduled against decode instead of
-    # one whole-prompt prefill per admission. 0 = one-shot bucketed
-    # admission (legacy). >0 = chunk size in tokens; every chunk of every
-    # prompt length lowers the SAME two programs (interior + final), so
-    # the prefill compile grid collapses from buckets × admission-ladder
-    # to 2. Env: RAY_TPU_LLM_PREFILL_CHUNK=64.
-    llm_prefill_chunk: int = 0
-    # Width-bucketed chunk dispatch (paged + chunked engines): chunk rows
+    # Chunked prefill, the one way a prompt is admitted: it enters its
+    # slot's page table in chunks of this many tokens (> 0), co-scheduled
+    # against decode under llm_prefill_token_budget. Every chunk of every
+    # prompt length lowers the SAME programs (a table width's interior +
+    # final). 128 is what every ledger line ran. Beside an engine whose
+    # cache is shorter (max_len < this) the knob takes the largest whole
+    # number of pages that fits; the explicit argument raises.
+    # Env: RAY_TPU_LLM_PREFILL_CHUNK=64.
+    llm_prefill_chunk: int = 128
+    # Width-bucketed chunk dispatch: chunk rows
     # group by the pow-2 page width each row actually attends over
     # (pages covering written tokens + this chunk — the `_pow2_width`
     # rule shared with the decode table view), and every dispatch
@@ -352,9 +351,8 @@ class Config:
     # place this many prompt tokens for each step of it (2,048 at the
     # defaults); a window of one step, and a speculative tick, carry
     # one. 0 = pure-decode ticks (prefill only advances while nothing
-    # is decoding); otherwise must be >= llm_prefill_chunk. Ignored
-    # unless llm_prefill_chunk > 0. It also sets the chunk programs'
-    # heights. Bucketed by table width (llm_prefill_width_bucketing):
+    # is decoding); otherwise must be >= llm_prefill_chunk. It also
+    # sets the chunk programs' heights. Bucketed by table width (llm_prefill_width_bucketing):
     # every chunk dispatch is [chunk_rows, llm_prefill_chunk] with
     # chunk_rows = min(n_slots, ceil(max(budget, chunk) / chunk)), the
     # full chunks ONE budget holds (2 for a chunk of 128); a tick runs
@@ -378,8 +376,8 @@ class Config:
     # and admission binds the longest cached prefix into a new slot's
     # page table — chunked prefill then starts at the first COLD token,
     # so warm-prefix TTFT collapses to the cold suffix + first decode.
-    # Requires kv_mode="paged" AND llm_prefill_chunk > 0 (the cache
-    # granularity IS the prefill chunk). Env: RAY_TPU_LLM_PREFIX_CACHE=1.
+    # The cache granularity IS the prefill chunk.
+    # Env: RAY_TPU_LLM_PREFIX_CACHE=1.
     llm_prefix_cache: bool = False
     # Max distinct pool pages cache entries may pin (the budget a
     # pressure-aware LRU evicts against; zero-ref entries are always
@@ -392,9 +390,8 @@ class Config:
     # verify_chunk_paged — the PR 4 chunk program IS the verify program).
     # Rejection sampling keeps greedy output byte-identical to
     # non-speculative decode and temperature>0 distributionally exact.
-    # "" = off. Requires kv_mode="paged" AND llm_prefill_chunk > 0;
-    # alongside an incompatible engine the global knob soft-disables
-    # (explicit constructor args still error, like llm_prefill_chunk).
+    # "" = off. Beside a model family that cannot carry it the global
+    # knob soft-disables (explicit constructor args still error).
     # NOTE: this knob names the draft ARCHITECTURE only — supply trained
     # draft weights via LLMEngine(spec_draft_params=...) or
     # LLMDeployment(spec_draft_checkpoint=...); a random-init draft has
@@ -410,10 +407,9 @@ class Config:
     # pool along the HEAD axis over a ("tp",) mesh of local devices;
     # every paged program runs per-shard via shard_map with only the
     # per-layer attention-out/MLP-down psums crossing shards. 1 =
-    # single-chip engine, byte-for-byte. Requires kv_mode="paged" AND
-    # llm_prefill_chunk > 0; must divide n_heads and d_ff (target and
-    # draft) and fit the visible device count — on ANY misfit
-    # (incompatible engine, too few devices, non-divisor) the global
+    # single-chip engine, byte-for-byte. Must divide n_heads and d_ff
+    # (target and draft) and fit the visible device count — on ANY misfit
+    # (a family without the twins, too few devices, non-divisor) the global
     # knob soft-disables to 1 so a fleet-wide export can't crash a
     # replica boot; explicit constructor args still raise typed errors,
     # like llm_prefill_chunk. Off-TPU:
@@ -425,9 +421,9 @@ class Config:
     # symmetric int8 matmul planes + fp32 scale vectors; dequant fuses at
     # the consuming einsum via gpt.weight_view — the fp32 plane is never
     # re-materialized in HBM; norms/embeddings/biases stay float).
-    # Requires kv_mode="paged"; alongside an incompatible engine the
-    # global knob soft-disables (explicit constructor args still raise,
-    # like llm_prefill_chunk). Env: RAY_TPU_LLM_WEIGHT_DTYPE=int8.
+    # Beside a model family without an int8 form the global knob
+    # soft-disables (explicit constructor args still raise).
+    # Env: RAY_TPU_LLM_WEIGHT_DTYPE=int8.
     llm_weight_dtype: str = "bf16"
     # Quantized serving — KV stream (models/paged_kv.init_paged_kv):
     # "bf16" (pool planes in cfg.dtype, the default) | "int8" (int8 page
@@ -442,8 +438,8 @@ class Config:
     # chunk-chain-keyed page-set objects; an admitting engine ADOPTS
     # resolvable page sets by reference instead of re-prefilling
     # (failover ladder: adopt → partial-adopt + cold-suffix prefill →
-    # teacher-forced re-prefill). Requires kv_mode="paged" AND
-    # llm_prefill_chunk > 0 (page-aligned chunks); llm_tp > 1 engines
+    # teacher-forced re-prefill). Requires llm_prefill_chunk %
+    # llm_kv_page_size == 0 (page-aligned chunks); llm_tp > 1 engines
     # donate per-shard head planes and adopters reshard at bind time
     # (partition.split_head_planes/concat_head_planes), so tp composes.
     # On any misfit the GLOBAL knob soft-disables (a fleet-wide export

@@ -1,15 +1,17 @@
-"""Block-paged KV cache for the serving engine.
+"""Block-paged KV cache for the serving engine: the ONE cache it has.
 
-The dense cache (models/decode.py `init_kv_cache`) preallocates
-``[L, B, T_max]`` per slot — HBM capacity, not compute, caps the slot
-count (OPT-1.3B at 16 slots × 2048 OOM'd a 16 GB chip). Paged KV decouples slot count from max_len: a shared pool of
-fixed-size pages ``[L, P+1, page_size, H*K]`` plus a per-slot page
-table ``[B, max_pages]`` of page ids. Slots consume pages as they grow,
+A cache of ``[L, B, T_max]`` a slot makes HBM capacity, not compute, cap
+the slot count (OPT-1.3B at 16 slots × 2048 OOM'd a 16 GB chip). Paged
+KV decouples slot count from max_len: a shared pool of fixed-size pages
+``[L, P+1, page_size, H*K]`` plus a per-slot page table
+``[B, max_pages]`` of page ids. Slots consume pages as they grow,
 so pool capacity is sized to the *expected total live tokens*, not
 ``B × T_max`` worst case (PAPERS.md "Ragged Paged Attention"; the
 reference's serving delegates KV management to torch models —
 `/root/reference/python/ray/serve/batching.py:1` is the capability
-being out-scaled here).
+being out-scaled here). The gpt block's pieces every program here is
+built from (`_rotary_pos`, `_qkv`, `_mlp`, `_head`) and the host-side
+`sample_token` of the one-step tick are at the top of this module.
 
 XLA-first layout decisions:
 - The pool is lane-dense and addressed in place by (layer, page). Its
@@ -32,8 +34,8 @@ XLA-first layout decisions:
   argument (engine knob ``llm_attn_impl``):
   * ``"gather"`` (reference): gather the slot's pages of layer ``l``
     back into a contiguous ``[B, T, H, K]`` timeline (transient, inside
-    the layer scan) and run the *same* attention math as the dense path
-    — exact-match with the dense engine by construction (tested).
+    the layer scan) and attend it with a plain masked softmax — the
+    tests' oracle, held to the full-sequence forward (tested).
   * ``"kernel"``: the Pallas ragged paged-attention kernel
     (ops/paged_attention.py) reads K/V pages in place from the pool
     with online-softmax state in VMEM — no timeline is materialized in
@@ -68,7 +70,66 @@ from ray_tpu.ops import scopes
 from ray_tpu.models.blocks import attend_fn
 from ray_tpu.models.gpt import (GPTConfig, _layer_norm, stack_block_params,
                                 weight_view)
-from ray_tpu.models.decode import _head, _mlp, _qkv, _rotary_pos
+
+
+def _rotary_pos(x: jax.Array, rotary_dim: int, pos: jax.Array) -> jax.Array:
+    """Rotary with explicit per-row positions. x: [B, S, H, K]; pos: [B, S]."""
+    rot, rest = x[..., :rotary_dim], x[..., rotary_dim:]
+    inv_freq = 1.0 / (10000 ** (jnp.arange(0, rotary_dim, 2) / rotary_dim))
+    ang = pos[..., None] * inv_freq  # [B, S, R/2]
+    sin = jnp.sin(ang)[:, :, None, :].astype(x.dtype)  # [B, S, 1, R/2]
+    cos = jnp.cos(ang)[:, :, None, :].astype(x.dtype)
+    x1, x2 = rot[..., 0::2], rot[..., 1::2]
+    out1 = x1 * cos - x2 * sin
+    out2 = x2 * cos + x1 * sin
+    rot = jnp.stack([out1, out2], axis=-1).reshape(rot.shape)
+    return jnp.concatenate([rot, rest], axis=-1)
+
+
+def _qkv(h, layer, cfg):
+    q = jnp.einsum("bsd,dhk->bshk", h, weight_view(layer, "wq", cfg.dtype))
+    k = jnp.einsum("bsd,dhk->bshk", h, weight_view(layer, "wk", cfg.dtype))
+    v = jnp.einsum("bsd,dhk->bshk", h, weight_view(layer, "wv", cfg.dtype))
+    return q, k, v
+
+
+def _mlp(x, layer, cfg, tp_axis=None):
+    """Feed-forward block. Under tensor parallelism (`tp_axis` set, the
+    body running inside a shard_map) w_up/b_up/w_down are sharded on the
+    hidden width: the up-projection and gelu are shard-local and the
+    down-projection yields a partial sum reduced across shards BEFORE
+    the replicated b_down joins the residual (each shard adding b_down
+    pre-psum would count it tp times)."""
+    h = _layer_norm(x, layer["ln2_scale"], layer["ln2_bias"])
+    up = jax.nn.gelu(
+        jnp.einsum("bsd,df->bsf", h, weight_view(layer, "w_up", cfg.dtype))
+        + layer["b_up"].astype(cfg.dtype))
+    down = jnp.einsum("bsf,fd->bsd", up,
+                      weight_view(layer, "w_down", cfg.dtype))
+    if tp_axis is not None:
+        down = jax.lax.psum(down, tp_axis)
+    return x + (down + layer["b_down"].astype(cfg.dtype))
+
+
+def _head(params, cfg, x):
+    x = _layer_norm(x, params["ln_f_scale"], params["ln_f_bias"])
+    head = params["lm_head"] if not cfg.tie_embeddings else params["wte"].T
+    return jnp.einsum("bsd,dv->bsv", x, head.astype(cfg.dtype),
+                      preferred_element_type=jnp.float32)
+
+
+def sample_token(logits, *, temperature: float = 0.0, top_k: int = 0,
+                 key=None):
+    """Greedy (temperature=0) or temperature/top-k sampling. logits: [V] or
+    [B, V] fp32 numpy/jax."""
+    if temperature == 0.0:
+        return jnp.argmax(logits, axis=-1)
+    scaled = logits / temperature
+    if top_k > 0:
+        kth = jnp.sort(scaled, axis=-1)[..., -top_k][..., None]
+        scaled = jnp.where(scaled < kth, -1e30, scaled)
+    assert key is not None, "sampling needs a PRNG key"
+    return jax.random.categorical(key, scaled, axis=-1)
 
 
 def init_paged_kv(cfg: GPTConfig, n_pages: int, page_size: int,
@@ -137,24 +198,6 @@ def _quant_write(plane, scale, l, write_pages, write_offs, values,
     q = jnp.clip(jnp.round(v32 / new_scale[write_pages][:, None]),
                  -127, 127).astype(jnp.int8)
     return (plane.at[l, write_pages, write_offs].set(q),
-            scale.at[l].set(new_scale.astype(scale.dtype)))
-
-
-def _quant_write_full_pages(plane, scale, l, pages, values, tp_axis=None):
-    """Whole-page variant (one-shot paged prefill): values [M, ps, H*K]
-    fills pages[m] of layer ``l`` end to end — by construction a first
-    write, so every target page's scale resets from its own payload.
-    Duplicate ids only ever name the null page (zero padding), where any
-    write order gives the same harmless result."""
-    v32 = values.astype(jnp.float32)
-    vmax = jnp.max(jnp.abs(v32), axis=(1, 2))                      # [M]
-    if tp_axis is not None:
-        vmax = jax.lax.pmax(vmax, tp_axis)
-    new_scale = scale[l].astype(jnp.float32).at[pages].set(
-        jnp.maximum(vmax, 1e-8) / 127.0)
-    q = jnp.clip(jnp.round(v32 / new_scale[pages][:, None, None]),
-                 -127, 127).astype(jnp.int8)
-    return (plane.at[l, pages].set(q),
             scale.at[l].set(new_scale.astype(scale.dtype)))
 
 
@@ -256,72 +299,6 @@ def _attn_in(cfg: GPTConfig, layer, x, pos):
     q, k, v = _qkv(h, layer, cfg)
     return (_rotary_pos(q, cfg.rotary_dim, pos),
             _rotary_pos(k, cfg.rotary_dim, pos), v)
-
-
-@functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(3,))
-def prefill_batch_paged(cfg: GPTConfig, params, tokens, pool, pages, lengths):
-    """Prefill N prompts, scattering their K/V into allocated pages.
-
-    tokens: [N, S_bucket]; pages: [N, ceil(S_bucket / page_size)] page ids
-    (unallocated tail entries = 0 → null page); lengths: [N].
-    → (last-token logits [N, V] fp32, updated pool). Attention is the
-    standard causal prompt self-attention (no pool reads needed).
-    """
-    N, S = tokens.shape
-    ps = pool["k"].shape[2]
-    n_pg = pages.shape[1]
-    S_pad = n_pg * ps
-    quant = "k_scale" in pool
-    with jax.named_scope(scopes.EMBED):
-        x = params["wte"].astype(cfg.dtype)[tokens]        # [N, S, D]
-    pos = jnp.broadcast_to(jnp.arange(S)[None, :], (N, S))
-    # One up-front cast of the stacked block params (the per-layer
-    # weight_view casts inside the scan body become no-ops; int8 planes
-    # stay compressed and dequant fuses into their consuming einsums).
-    stacked = stack_block_params(params, cfg.dtype)
-    scale = 1.0 / math.sqrt(cfg.head_dim)
-    flat_pages = pages.reshape(-1)                         # [N * n_pg]
-
-    def body(x, layer, l, pool):
-        q, k, v = _attn_in(cfg, layer, x, pos)
-        with jax.named_scope(scopes.ATTN_KERNEL):
-            logits = jnp.einsum("bshk,bthk->bhst", q, k,
-                                preferred_element_type=jnp.float32) * scale
-            causal = jnp.arange(S)[:, None] >= jnp.arange(S)[None, :]
-            logits = jnp.where(causal[None, None], logits, -1e30)
-            probs = jax.nn.softmax(logits, axis=-1).astype(cfg.dtype)
-            attn = jnp.einsum("bhst,bthk->bshk", probs, v)
-        with jax.named_scope(scopes.ATTN_OUT):
-            x = x + jnp.einsum("bshk,hkd->bsd", attn,
-                               weight_view(layer, "wo", cfg.dtype))
-        with jax.named_scope(scopes.MLP):
-            x = _mlp(x, layer, cfg)
-
-        def paged(arr):                            # [N,S,H,K] → whole pages
-            a = jnp.pad(arr, ((0, 0), (0, S_pad - S), (0, 0), (0, 0)))
-            return a.reshape(N * n_pg, ps, cfg.n_heads * cfg.head_dim)
-
-        with jax.named_scope(scopes.ATTN_KV_WRITE):
-            if quant:
-                k_pl, k_sc = _quant_write_full_pages(
-                    pool["k"], pool["k_scale"], l, flat_pages, paged(k))
-                v_pl, v_sc = _quant_write_full_pages(
-                    pool["v"], pool["v_scale"], l, flat_pages, paged(v))
-                return x, {"k": k_pl, "v": v_pl,
-                           "k_scale": k_sc, "v_scale": v_sc}
-            return x, {
-                "k": pool["k"].at[l, flat_pages].set(
-                    paged(k.astype(cfg.dtype))),
-                "v": pool["v"].at[l, flat_pages].set(
-                    paged(v.astype(cfg.dtype)))}
-
-    x, pool = scan_pool_layers(body, x, stacked, pool)
-    with jax.named_scope(scopes.HEAD):
-        logits = _head(params, cfg, x)                     # [N, S, V]
-        last = jnp.take_along_axis(
-            logits, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1
-        )[:, 0]
-    return last, pool
 
 
 def _chunk_paged_forward(cfg: GPTConfig, params, tokens, pool, tables,
@@ -430,8 +407,8 @@ def _decode_once_paged(cfg: GPTConfig, params, tokens, pool, positions,
 
     tokens: [B]; positions: [B]; tables: [B, max_pages]; attn_impl
     (static): "gather" reconstitutes each slot's contiguous timeline
-    [B, T, H, K] (T = max_pages × page_size) per layer — math identical
-    to the dense `_decode_once`; "kernel" runs the Pallas ragged
+    [B, T, H, K] (T = max_pages × page_size) per layer and attends it
+    with a plain masked softmax; "kernel" runs the Pallas ragged
     paged-attention kernel against the pool in place, at (layer, page).
     `write_mask` ([B] bool, optional) routes masked rows' K/V writes to the null
     page — the speculative draft loop uses it so proposal steps past a
@@ -691,8 +668,7 @@ def paged_programs(chunk_forward, decode_once, chunk_logits,
         prefix + chunk), so the grid is the width ladder {1, 2, 4, …,
         max_pages}: at most 2·log₂(max_pages)+2 programs
         (``return_logits`` False for interior-only batches, True when any
-        row carries a final chunk, which alone pays the LM head),
-        replacing the one-shot path's buckets × admission-ladder grid.
+        row carries a final chunk, which alone pays the LM head).
         Full-width tables remain valid (the width-bucketing-off control
         arm dispatches exactly the PR 4 two-program grid); attention
         compute/bytes scale with the sliced width, which is the whole
@@ -752,10 +728,10 @@ def paged_programs(chunk_forward, decode_once, chunk_logits,
                            n_steps: int, temps, key, *,
                            attn_impl: str = "gather", phase=_no_phase,
                            counters=None, carried=None, ahead=None):
-        """`n_steps` paged-decode steps with on-device sampling (the paged
-        twin of decode.decode_multi — the engine pre-allocates pages
-        covering every position the window writes before dispatch, so
-        tables are static across the window), plus the step `ahead` asks
+        """`n_steps` paged-decode steps with on-device sampling (the
+        engine pre-allocates pages covering every position the window
+        writes before dispatch, so tables are static across the
+        window), plus the step `ahead` asks
         for and after the row `carried` brings (`_decode_window`).
         `counters(dict)` (optional, a family with `counter_names`) is
         handed the pool's running counters as they stand after the
@@ -830,7 +806,7 @@ def spec_draft_propose(cfg: GPTConfig, params, tokens, pool, positions,
     tokens: [B] pending token per slot; positions: [B] decode cursor;
     n_prop: [B] per-slot proposal budget (step i's write is routed to
     the null page when i > n_prop[b]; -1 = fully inert row); temps: [B]
-    sampling temperature (0 = greedy argmax, matching decode_multi).
+    sampling temperature (0 = greedy argmax, as `_sample_next`).
 
     → (proposals [k, B] int32, draft probs [k, B, V] fp32 — the
     temperature-scaled softmax row each proposal was sampled from,
@@ -1120,7 +1096,6 @@ def spec_draft_propose_tp(cfg: GPTConfig, params, tokens, pool, positions,
 
 __all__ = [
     "init_paged_kv", "copy_pages", "gather_pages", "scatter_pages",
-    "prefill_batch_paged",
     "prefill_chunk_paged", "verify_chunk_paged", "spec_draft_propose",
     "decode_step_paged", "decode_multi_paged", "join_window", "snapshot",
     "paged_programs", "scan_pool_layers",

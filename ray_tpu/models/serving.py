@@ -76,26 +76,21 @@ class ServingFamily:
         return getattr(self.model, "COUNTERS", ())
 
 
-_DENSE = ("prefill", "prefill_batch", "decode_step", "decode_multi",
-          "sample_token")
 _PAGED = ("prefill_chunk_paged", "verify_chunk_paged", "decode_step_paged",
           "decode_multi_paged", "copy_pages", "gather_pages",
           "scatter_pages", "spec_draft_propose")
 
 
 def _gpt_programs(tp: int, mesh) -> dict:
-    """models/decode.py's dense set and models/paged_kv.py's paged set;
-    at tp > 1 the paged programs are their shard_map twins (`*_tp`) with
-    the mesh bound as a static kwarg, under the same names."""
-    from ray_tpu.models import decode, paged_kv
+    """models/paged_kv.py's set; at tp > 1 the programs are their
+    shard_map twins (`*_tp`) with the mesh bound as a static kwarg,
+    under the same names."""
+    from ray_tpu.models import paged_kv
 
-    programs = {name: getattr(decode, name) for name in _DENSE}
-    programs["prefill_batch_paged"] = paged_kv.prefill_batch_paged
-    for name in _PAGED:
-        programs[name] = (
-            getattr(paged_kv, name) if tp == 1 else
-            functools.partial(getattr(paged_kv, name + "_tp"), mesh=mesh))
-    return programs
+    return {name: (getattr(paged_kv, name) if tp == 1 else
+                   functools.partial(getattr(paged_kv, name + "_tp"),
+                                     mesh=mesh))
+            for name in _PAGED}
 
 
 def _gpt() -> ServingFamily:
@@ -112,19 +107,12 @@ def _gpt() -> ServingFamily:
 
 # The engine options that the programs of a family with a memory by the
 # slot beside the pages cannot carry (with experts or without), in the order
-# serve/llm_options.py settles them (kv_mode and prefill_chunk before the
-# checks that read them): (option, `fits`, `neutral`, the refusal). A
-# refusal names the family ({name}) and says what would have to be built;
-# where that depends on WHAT the family keeps by the slot ({beside}: a
-# one-token state, a recurrence, a ring) or on whether it has experts, it
-# takes the family's own clause under the option's name.
+# serve/llm_options.py settles them: (option, `fits`, `neutral`, the
+# refusal). A refusal names the family ({name}) and says what would have to
+# be built; where that depends on WHAT the family keeps by the slot
+# ({beside}: a one-token state, a recurrence, a ring) or on whether it has
+# experts, it takes the family's own clause under the option's name.
 _REFUSALS = (
-    ("kv_mode", lambda o: o.kv_mode == "paged", "paged",
-     "the {name} family serves from the paged pool only: kv_mode='dense' "
-     "would need a [L, B, T] cache backend {kv_mode}"),
-    ("prefill_chunk", lambda o: o.prefill_chunk > 0, 128,
-     "the {name} family has no one-shot prefill: prefill_chunk=0 would "
-     "need a whole-prompt program that leaves {prefill_chunk}"),
     ("prefill_width_bucketing",
      lambda o: not o.prefill_width_bucketing, False,
      "prefill_width_bucketing with the {name} family: a chunk program "
@@ -199,9 +187,6 @@ def _zaya() -> ServingFamily:
         "the slot's conv/shift state (z, c and W_v2 u of its last token, "
         "per layer: models/zaya.py)",
         {**_EXPERTS,
-         "kv_mode": "of the CCA block, with its per-slot state carried "
-                    "beside it",
-         "prefill_chunk": "the prompt's last-token state in the slot state",
          "prefix_cache": _SNAPSHOT, "spec_draft": _RETURNS,
          "tp": "2 KV heads cannot shard over more chips than heads "
                "(models/partition.py and serve/kv_objects.py split the "
@@ -211,16 +196,14 @@ def _zaya() -> ServingFamily:
         slot_state=("slot_state",))
 
 
-def _ring(model, dense_needs: str) -> ServingFamily:
+def _ring(model) -> ServingFamily:
     """A family whose window layers keep a ring of pages a slot beside
-    the full layers' pages (models/laguna.py `ring_pool`); `dense_needs`
-    says what a dense cache backend would have to carry."""
+    the full layers' pages (models/laguna.py `ring_pool`)."""
     return _paged_only(
         model,
         "the window layers' ring of pages a slot (models/laguna.py: "
         "indexed by slot, outside PagePool's page ids)",
-        {**_EXPERTS, "kv_mode": "with " + dense_needs,
-         "prefill_chunk": "the prompt's last window in {beside}",
+        {**_EXPERTS,
          "prefix_cache": "{beside} at the prefix's boundary stored with "
                          "its pages: a window kind whose pages can be "
                          "shared",
@@ -237,16 +220,13 @@ def _ring(model, dense_needs: str) -> ServingFamily:
 def _laguna() -> ServingFamily:
     from ray_tpu.models import laguna
 
-    return _ring(laguna, "a window mask and per-layer-kind head counts")
+    return _ring(laguna)
 
 
 def _mimo_v2() -> ServingFamily:
     from ray_tpu.models import mimo_v2
 
-    return _ring(
-        mimo_v2,
-        "a window mask, per-layer-kind KV head counts, V heads narrower "
-        "than K heads and a sink in the window layers' softmax")
+    return _ring(mimo_v2)
 
 
 def _qwen3_next() -> ServingFamily:
@@ -258,8 +238,6 @@ def _qwen3_next() -> ServingFamily:
         "(models/qwen3_next.py: a float32 matrix a head and layer, "
         "12.9 MB a slot at the published sizes, indexed by slot)",
         {**_EXPERTS,
-         "kv_mode": "for the full layers with {beside} carried beside it",
-         "prefill_chunk": "the prompt's final recurrent state in the slot",
          "prefix_cache": _SNAPSHOT,
          "spec_draft": "a recurrence cannot be run backwards; " + _RETURNS,
          "tp": "2 KV heads cannot shard over more chips than heads, "
@@ -279,10 +257,7 @@ def _jamba() -> ServingFamily:
         "the mamba layers' state-space state and convolution tail "
         "(models/jamba.py: 16 float32 values a channel and layer, 9.3 MB a "
         "slot at the published sizes, indexed by slot)",
-        {"kv_mode": "for the attention layers with {beside} carried beside "
-                    "it",
-         "prefill_chunk": "the prompt's final state-space state in the slot",
-         "prefill_width_bucketing": "a pass over all of the model's "
+        {"prefill_width_bucketing": "a pass over all of the model's "
                                     "weights (every layer is dense)",
          "prefix_cache": _SNAPSHOT,
          "spec_draft": "a recurrence cannot be run backwards; " + _RETURNS,
@@ -313,8 +288,6 @@ def _kimi_k2() -> ServingFamily:
         "token and layer with no head axis, in PagePool's own pages; "
         "nothing is kept by the slot)",
         {**_EXPERTS,
-         "kv_mode": "of one latent row a token, read in the absorbed form",
-         "prefill_chunk": "the prompt's rows in {beside}",
          "prefix_cache": "no snapshot, the pages are PagePool's: "
                          "serve/prefix_cache.py's copy-on-write and the "
                          "engine's page programs (models/paged_kv.py "
@@ -349,9 +322,7 @@ def _olmo_hybrid() -> ServingFamily:
         "(models/olmo_hybrid.py: a float32 matrix of 96 x 192 a head and "
         "layer, two heads side by side, 13.7 MB a slot at the published "
         "sizes, indexed by slot)",
-        {"kv_mode": "for the full layers with {beside} carried beside it",
-         "prefill_chunk": "the prompt's final recurrent state in the slot",
-         "prefill_width_bucketing": "a pass over all of the model's "
+        {"prefill_width_bucketing": "a pass over all of the model's "
                                     "weights (every layer is dense)",
          "prefix_cache": _SNAPSHOT,
          "spec_draft": "a recurrence cannot be run backwards; " + _RETURNS,
@@ -383,9 +354,6 @@ def _nemotron_h() -> ServingFamily:
         "layer, two heads side by side, 21.0 MB a slot at the published "
         "sizes, indexed by slot)",
         {**_EXPERTS,
-         "kv_mode": "for the attention layers with {beside} carried beside "
-                    "it",
-         "prefill_chunk": "the prompt's final state-space state in the slot",
          "prefix_cache": _SNAPSHOT,
          "spec_draft": "a recurrence cannot be run backwards; " + _RETURNS
                        + " (the model's own multi-token-prediction head "

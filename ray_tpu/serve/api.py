@@ -265,9 +265,8 @@ class DeploymentHandle:
             _cfg, "serve_overload_retry_after_s", 1.0)
         # Affinity keys hash the chunk-chain head at the engine's prefill
         # chunk granularity (so keys match the prefix cache's depth-1
-        # entries); a one-shot engine (chunk 0) falls back to 64.
-        self._affinity_chunk = int(
-            getattr(_cfg, "llm_prefill_chunk", 0) or 64)
+        # entries).
+        self._affinity_chunk = int(_cfg.llm_prefill_chunk)
         self.deployment_name = deployment_name
         self._version = -1
         self._replicas: list = []

@@ -4,28 +4,27 @@ The TPU-native answer to LLM serving (BASELINE config 5: continuous-batched
 text generation). The reference batches requests per replica with
 `@serve.batch` (`/root/reference/python/ray/serve/batching.py`) — static
 batches that stall on the longest member. Here decode is *continuously*
-batched: a fixed pool of B cache slots advances one fused `decode_step`
-per iteration; requests join mid-flight via a bucketed `prefill` into a
-free slot and retire independently, so shapes are static (XLA-friendly)
-while occupancy tracks load.
+batched: a fixed set of B slots over ONE cache, a paged KV pool, advances
+a window of decode steps per iteration; requests join mid-flight, their
+prompts entering a free slot's pages chunk by chunk, and retire
+independently, so shapes are static (XLA-friendly) while occupancy
+tracks load.
 
 Design notes:
-- Prompt admission has two modes. One-shot (default): prompt lengths
-  round up to power-of-two buckets → one prefill compilation per bucket,
-  not per length — but every admission stalls the decode pool for a
-  whole prompt of prefill compute. Chunked (`llm_prefill_chunk` > 0,
-  paged KV only): prompts enter their slot's page table in fixed-size
-  chunks co-scheduled against decode under a token budget for each
-  decode STEP (`llm_prefill_token_budget`) — Sarathi/Orca-style
-  stall-free batching. A tick runs one decode window of k steps and
-  may place k budgets of prompt tokens before it, so the stall a
-  decode step sees is bounded by one budget of chunk compute whatever
+- A prompt is admitted ONE way: it enters its slot's page table in
+  chunks of `llm_prefill_chunk` tokens, co-scheduled against decode
+  under a token budget for each decode STEP
+  (`llm_prefill_token_budget`) — Sarathi/Orca-style stall-free
+  batching: a whole-prompt prefill per admission would stall every
+  decoding slot for a prompt of compute. A tick runs one decode window
+  of k steps and may place k budgets of prompt tokens before it, so the
+  stall a decode step sees is bounded by one budget of chunk compute whatever
   the window's length; while slots decode, prefill also stops short of
   the pages they are about to need (a full pool stalls prompts, it
   does not preempt them). Admission back-pressure needs one CHUNK of
-  pool headroom instead of the whole prompt, and the prefill compile
-  grid collapses from buckets × admission-ladder to two programs per
-  page-table width (models/paged_kv.py `prefill_chunk_paged`), each
+  pool headroom, not the whole prompt, and the prefill compile grid is
+  two programs per page-table width (models/paged_kv.py
+  `prefill_chunk_paged`), each
   [chunk_rows, chunk]: as many rows as full chunks fit ONE budget, not
   as the engine has slots nor as the tick's allowance has rows. An
   engine that dispatches at ONE table width (width bucketing off: a
@@ -609,16 +608,15 @@ class GenRequest:
 
 
 class LLMEngine:
-    """Slot-based continuous batching: one engine thread drives the device
-    programs of models/paged_kv.py (a paged KV pool; models/decode.py when
-    `kv_mode="dense"`) over a fixed set of slots. What its options resolve
-    to is serve/llm_options.py's; which KV pages are free, shared or bound
-    to a slot is serve/page_pool.py's (`self.pool`, None when dense). The
-    device pool (`self.cache`) and the scheduler are here."""
+    """Slot-based continuous batching: one engine thread drives a model
+    family's paged programs (models/serving.py; models/paged_kv.py for a
+    gpt) over a fixed set of slots and one paged KV pool. What its options
+    resolve to is serve/llm_options.py's; which KV pages are free, shared
+    or bound to a slot is serve/page_pool.py's (`self.pool`). The device
+    pool (`self.cache`) and the scheduler are here."""
 
     def __init__(self, cfg, params=None, *, n_slots: int = 8,
                  max_len: int = 2048, seed: int = 0,
-                 prefill_buckets: tuple[int, ...] = (32, 64, 128, 256, 512),
                  decode_block: int | None = None,
                  kv_mode: str | None = None, page_size: int | None = None,
                  n_pages: int | None = None, attn_impl: str | None = None,
@@ -638,11 +636,17 @@ class LLMEngine:
 
         from ray_tpu.models import gpt
         from ray_tpu.models import paged_kv as _paged
-        from ray_tpu.models.decode import init_kv_cache
         from ray_tpu.models.serving import family_of
         from ray_tpu.serve.llm_options import resolve_options
         from ray_tpu.serve.page_pool import PagePool, pages_for
 
+        if kv_mode not in (None, "paged"):
+            # A keyword only because benchmarks/harness/serve_cell.py
+            # `build_engine` passes "paged" and only a `benchmark` PR may
+            # edit that file (ROADMAP Queue 2 B drops it from both sides).
+            raise ValueError(
+                f"kv_mode={kv_mode!r}: the dense KV cache was removed in "
+                "PR 64, the engine serves from the paged pool only")
         self.cfg = cfg
         # The device side of this configuration's model family: pool,
         # programs, sharding rules (models/serving.py). The draft model
@@ -650,19 +654,12 @@ class LLMEngine:
         self._family = fam = family_of(cfg)
         self.n_slots = n_slots
         self.max_len = max_len
-        # Clamp buckets to the KV-cache capacity: _bucket() rounds a prompt
-        # UP, so a bucket larger than max_len would trace a prefill whose
-        # dynamic_update_slice overruns the cache (advisor finding r1 #3).
-        buckets = tuple(sorted(b for b in prefill_buckets if b < max_len))
-        if not buckets:
-            buckets = (max(1, max_len - 1),)
-        self.buckets = buckets
         self.params = (params if params is not None
                        else fam.model.init_params(cfg, jax.random.key(seed)))
         # Resolution and every refusal: serve/llm_options.py.
         o = resolve_options(
             cfg, max_len=max_len, spec_draft_params=spec_draft_params,
-            pool_role=pool_role, kv_mode=kv_mode, page_size=page_size,
+            pool_role=pool_role, page_size=page_size,
             attn_impl=attn_impl, prefill_chunk=prefill_chunk,
             prefill_token_budget=prefill_token_budget,
             prefix_cache=prefix_cache,
@@ -684,74 +681,64 @@ class LLMEngine:
             logger.warning("llm_kv_transfer soft-disabled: %s",
                            o.kv_transfer_disabled_reason)
         # What each of these means: core/config.py's `llm_*` knobs.
-        self.kv_mode = o.kv_mode
         self.attn_impl = o.attn_impl
         self.prefill_width_bucketing = bool(o.prefill_width_bucketing)
         self._warmup_on_start = bool(o.warmup)
         self._warmed = False
         self.prefill_chunk = prefill_chunk
         self.prefill_budget = o.prefill_token_budget
-        # Chunked mode is not bucket-bound: any prompt the cache/pool can
-        # hold is admissible (buckets only cap the one-shot path).
-        if prefill_chunk:
-            self._prompt_cap = max_len - 1
-            # Heights of the chunk programs, constants of the engine.
-            # Where dispatches are bucketed by table width, ONE height:
-            # the full chunks one budget holds (an idle tick's floor of
-            # one chunk included), never more rows than slots; a tick
-            # with more rows of a width runs the program again
-            # (_dispatch_chunks), because a taller program would carry
-            # the few rows of one width among inert ones and multiply
-            # the width ladder. Where every dispatch runs at ONE table
-            # width a chunk program is a pass over the weights whatever
-            # it carries, so a prompt should be one program: TWO
-            # heights, half a tick's allowance in rows (budget x the
-            # window's steps / 2 chunks) and half of that, neither
-            # lower than the one budget's rows above (what a tick
-            # beside a window of one step places; where the window is
-            # that short, or the slots that few, the two are one), and
-            # the head in both (a program almost always holds a final
-            # row: the headless twin would be two programs more to load
-            # for ~1 ms of head a long prompt's interior program).
-            full_chunks = -(-max(o.prefill_token_budget, prefill_chunk)
-                            // prefill_chunk)
-            rows = min(n_slots, full_chunks)
-            if self.prefill_width_bucketing:
-                self.chunk_heights: tuple[int, ...] = (rows,)
-                self.chunk_heads: tuple[bool, ...] = (False, True)
-            else:
-                tall = min(n_slots, max(
-                    rows, o.prefill_token_budget * max(1, o.decode_block)
-                    // (2 * prefill_chunk)))
-                self.chunk_heights = tuple(sorted(
-                    {max(rows, -(-tall // 2)), tall}))
-                self.chunk_heads = (True,)
+        # Any prompt the cache and the pool can hold is admissible.
+        self._prompt_cap = max_len - 1
+        # Heights of the chunk programs, constants of the engine.
+        # Where dispatches are bucketed by table width, ONE height:
+        # the full chunks one budget holds (an idle tick's floor of
+        # one chunk included), never more rows than slots; a tick
+        # with more rows of a width runs the program again
+        # (_dispatch_chunks), because a taller program would carry
+        # the few rows of one width among inert ones and multiply
+        # the width ladder. Where every dispatch runs at ONE table
+        # width a chunk program is a pass over the weights whatever
+        # it carries, so a prompt should be one program: TWO
+        # heights, half a tick's allowance in rows (budget x the
+        # window's steps / 2 chunks) and half of that, neither
+        # lower than the one budget's rows above (what a tick
+        # beside a window of one step places; where the window is
+        # that short, or the slots that few, the two are one), and
+        # the head in both (a program almost always holds a final
+        # row: the headless twin would be two programs more to load
+        # for ~1 ms of head a long prompt's interior program).
+        full_chunks = -(-max(o.prefill_token_budget, prefill_chunk)
+                        // prefill_chunk)
+        rows = min(n_slots, full_chunks)
+        if self.prefill_width_bucketing:
+            self.chunk_heights: tuple[int, ...] = (rows,)
+            self.chunk_heads: tuple[bool, ...] = (False, True)
         else:
-            self._prompt_cap = min(self.buckets[-1], max_len - 1)
-            self.chunk_heights, self.chunk_heads = (), ()
-        # Host-side page accounting (serve/page_pool.py). None = dense.
-        self.pool = None
-        if self.kv_mode == "paged":
-            # HBM holds `n_pages` pages TOTAL instead of n_slots × max_len:
-            # slot count stops being bounded by the worst-case sequence
-            # length (models/paged_kv.py). Default pool = half the dense
-            # footprint — the capacity win that un-OOMs 2× the slots.
-            self.page_size = page_size
-            self.max_pages_per_slot = pages_for(max_len - 1, page_size)
-            if n_pages is None:
-                n_pages = max(self.max_pages_per_slot + 1,
-                              (n_slots * self.max_pages_per_slot) // 2)
-            self.n_pages = n_pages
-            # A ring of pages a slot is sized by what one chunk dispatch
-            # can write of one prompt.
-            ring = ({"dispatch_tokens": self.chunk_rows * prefill_chunk}
-                    if fam.slot_ring else {})
-            self.cache = fam.init_pool(cfg, n_pages, page_size, n_slots,
-                                       self.kv_dtype, **ring)
-            self.pool = PagePool(n_pages, page_size, n_slots,
-                                 self.max_pages_per_slot)
-        else:
-            self.cache = init_kv_cache(cfg, n_slots, max_len)
+            tall = min(n_slots, max(
+                rows, o.prefill_token_budget * max(1, o.decode_block)
+                // (2 * prefill_chunk)))
+            self.chunk_heights = tuple(sorted(
+                {max(rows, -(-tall // 2)), tall}))
+            self.chunk_heads = (True,)
+        # HBM holds `n_pages` pages TOTAL, not n_slots × max_len: slot
+        # count stops being bounded by the worst-case sequence length
+        # (models/paged_kv.py). Default pool = half the slots' worst
+        # case, at least one slot's.
+        self.page_size = page_size
+        self.max_pages_per_slot = pages_for(max_len - 1, page_size)
+        if n_pages is None:
+            n_pages = max(self.max_pages_per_slot + 1,
+                          (n_slots * self.max_pages_per_slot) // 2)
+        self.n_pages = n_pages
+        # A ring of pages a slot is sized by what one chunk dispatch
+        # can write of one prompt.
+        ring = ({"dispatch_tokens": self.chunk_rows * prefill_chunk}
+                if fam.slot_ring else {})
+        self.cache = fam.init_pool(cfg, n_pages, page_size, n_slots,
+                                   self.kv_dtype, **ring)
+        # Host-side page accounting (serve/page_pool.py).
+        self.pool = PagePool(n_pages, page_size, n_slots,
+                             self.max_pages_per_slot)
         # Speculative decoding: the draft model keeps its OWN page pool
         # (shaped to the draft config) but shares the target's page
         # TABLES and cursors — draft pool row p mirrors target pool row
@@ -899,9 +886,8 @@ class LLMEngine:
         self._carry: _InFlight | None = None
         # Decode-window sizes (largest first): one window advances all
         # slots k tokens with on-device sampling and ONE host sync,
-        # amortizing the host↔device round trip per token. Power-of-two
-        # ladder bounds the dense engine's compile count (the paged
-        # window is k dispatches of one program, whatever k).
+        # amortizing the host↔device round trip per token (a window is k
+        # dispatches of one program, whatever k).
         self.decode_block = max(1, o.decode_block)
         self._k_ladder = tuple(
             k for k in (64, 32, 16, 8, 4, 2) if k <= self.decode_block)
@@ -1080,10 +1066,9 @@ class LLMEngine:
         recompile-storm alarm instead of hiding in step-time noise.
 
         The set is the model family's (models/serving.py): for a gpt,
-        models/decode.py and models/paged_kv.py; at tp > 1 the paged
-        programs are their shard_map twins (`*_tp`) with the mesh bound
-        as a static kwarg, under the SAME compile-watch names: every
-        call site is unchanged."""
+        models/paged_kv.py's; at tp > 1 the programs are their shard_map
+        twins (`*_tp`) with the mesh bound as a static kwarg, under the
+        SAME compile-watch names: every call site is unchanged."""
         import types
 
         import jax
@@ -1099,19 +1084,20 @@ class LLMEngine:
         self._window_counters = (
             {"counters": self._note_device_counters}
             if self._family.expert_counters else {})
+        from ray_tpu.models import paged_kv as _paged
+
+        # `sample_token` is every family's: the host's draw from one row
+        # of logits (a prompt's first token, the one-step tick).
         self._rt = types.SimpleNamespace(
             jax=jax, jnp=jnp,
+            sample_token=_cw.wrap(_paged.sample_token, "sample_token"),
             **{name: _cw.wrap(fn, name) for name, fn in programs.items()})
-        if self.kv_mode != "paged":
-            return
         # The two programs a window that leaves a step in flight adds to
         # the step program (models/paged_kv.py `join_window`, `snapshot`),
         # [B]-sized, loaded here so that no request meets them cold. Each
         # is called as the tick will call it: `carried` is a step's
         # output, committed to its devices exactly when some weight or
         # pool leaf is (a committed operand is another program to jit).
-        from ray_tpu.models import paged_kv as _paged
-
         self._rt.join_window = _cw.wrap(_paged.join_window, "join_window")
         placed = next((a for a in jax.tree.leaves((self.params, self.cache))
                        if a.committed), None)
@@ -1189,8 +1175,7 @@ class LLMEngine:
         never re-hashes its full context; a memo at a different chunk
         granularity is silently dropped (wrong key space).
         """
-        # An empty prompt has no last-token logits to sample from: the
-        # one-shot path would emit an arbitrary token, the chunked path
+        # An empty prompt has no last-token logits to sample from: it
         # would never build a chunk row and wedge its slot forever.
         if not prompt_ids:
             raise ValueError("prompt_ids must be non-empty")
@@ -1203,8 +1188,7 @@ class LLMEngine:
         generated = [int(t) for t in (generated_ids or [])]
         context = list(prompt_ids) + generated
         too_big = (len(context) > self._prompt_cap
-                   or (self.pool is not None
-                       and self.pool.pages_for(len(context)) > self.n_pages))
+                   or self.pool.pages_for(len(context)) > self.n_pages)
         req = GenRequest(
             request_id=request_id or uuid.uuid4().hex[:12],
             prompt_ids=context,
@@ -1216,8 +1200,7 @@ class LLMEngine:
             out_ids=generated,
             stream=queue.Queue() if stream else None,
         )
-        if (prefix_hashes and self.prefill_chunk
-                and prefix_chunk == self.prefill_chunk):
+        if prefix_hashes and prefix_chunk == self.prefill_chunk:
             try:
                 req.prefix_hashes = [
                     bytes.fromhex(h) if isinstance(h, str) else bytes(h)
@@ -1251,16 +1234,13 @@ class LLMEngine:
             if len(context) > self._prompt_cap:
                 raise ValueError(
                     f"prompt too long: {len(context)} (cap "
-                    f"{self._prompt_cap}: "
-                    + ("cache bound, chunked prefill" if self.prefill_chunk
-                       else f"bucket cap {self.buckets[-1]}, cache cap "
-                            f"{self.max_len - 1}") + ")")
+                    f"{self._prompt_cap}: cache bound, chunked prefill)")
             # A prompt the pool can never cover would requeue forever.
             raise ValueError(
                 f"prompt needs {self.pool.pages_for(len(context))} KV pages "
                 f"but the pool only has {self.n_pages}")
         # The fatal/draining check and the enqueue must be atomic with the
-        # death handler's / drain export's one-shot pending drain, or a
+        # death handler's / drain export's single pending drain, or a
         # submit racing them could enqueue after the drain and hang.
         with self._lock:
             if self._fatal is not None:
@@ -1312,8 +1292,8 @@ class LLMEngine:
     def chunk_rows(self) -> int:
         """Height of the tallest chunk program: the most rows, so the
         most chunks of one prompt, one dispatch carries (a family's ring
-        of pages is sized by it). 0 without chunked prefill."""
-        return self.chunk_heights[-1] if self.chunk_heights else 0
+        of pages is sized by it)."""
+        return self.chunk_heights[-1]
 
     def chunk_programs(self) -> list[tuple[int, int, bool]]:
         """The (height, table width, head) of every `prefill_chunk_paged`
@@ -1351,9 +1331,8 @@ class LLMEngine:
         calling this. Idempotent per engine; opt-in at `start()` via
         `llm_warmup_compile` (default off — short-lived engines are
         better served by lazy compilation). Returns the number of
-        warmup dispatches issued (0 on non-chunked/dense engines)."""
-        if (self.kv_mode != "paged" or not self.prefill_chunk
-                or self._warmed):
+        warmup dispatches issued (0 when already warmed)."""
+        if self._warmed:
             return 0
         from ray_tpu import compile_watch as _cw
 
@@ -1477,37 +1456,36 @@ class LLMEngine:
                     doomed.append(self.pending.get_nowait())
                 except queue.Empty:
                     break
-        if self.pool is not None:
-            # The engine thread is stopped: return every evicted slot's
-            # pages (decrement-only — prefix-cache entries keep theirs,
-            # so a drained-but-not-killed engine still closes the page
-            # accounting: free + cached == total). With KV transfer on,
-            # each slot's WRITTEN prefix is donated to the page-set
-            # store FIRST — the destination replica adopts those pages
-            # instead of re-prefilling the teacher-forced context (the
-            # drain rung of the adoption ladder).
-            for slot in range(self.n_slots):
-                req = slot_of.get(slot)
-                if (req is not None and self._kv_store is not None
-                        and self.pool.slot_n_pages[slot]):
-                    n_written = int(self.positions[slot])
-                    if n_written <= 0:
-                        n_written = int(chunk_pos.get(slot, 0))
-                    # True written sequence (see the matching comment
-                    # in _release): anchored at n_prompt so a preempt-
-                    # regrown context can't duplicate generated tokens
-                    # into the donation keys.
-                    seq = (req.prompt_ids[:req.n_prompt]
-                           + req.out_ids)[:n_written]
-                    req.kv_handoff = self._donate_kv(
-                        seq, self.pool.row(slot), memo=req.prefix_hashes)
-                entry = self._slot_entry.pop(slot, None)
-                if entry is not None:
-                    self.prefix_cache.release(entry)
-                self.pool.free_slot(slot)
-                # graftlint: disable=GUARDED-BY (single-threaded by protocol: _export_unfinished runs after stop() joined the engine thread — see its docstring — so nothing races these resets)
-                self.positions[slot] = 0
-                self.tokens[slot] = 0
+        # The engine thread is stopped: return every evicted slot's
+        # pages (decrement-only — prefix-cache entries keep theirs,
+        # so a drained-but-not-killed engine still closes the page
+        # accounting: free + cached == total). With KV transfer on,
+        # each slot's WRITTEN prefix is donated to the page-set
+        # store FIRST — the destination replica adopts those pages
+        # instead of re-prefilling the teacher-forced context (the
+        # drain rung of the adoption ladder).
+        for slot in range(self.n_slots):
+            req = slot_of.get(slot)
+            if (req is not None and self._kv_store is not None
+                    and self.pool.slot_n_pages[slot]):
+                n_written = int(self.positions[slot])
+                if n_written <= 0:
+                    n_written = int(chunk_pos.get(slot, 0))
+                # True written sequence (see the matching comment
+                # in _release): anchored at n_prompt so a preempt-
+                # regrown context can't duplicate generated tokens
+                # into the donation keys.
+                seq = (req.prompt_ids[:req.n_prompt]
+                       + req.out_ids)[:n_written]
+                req.kv_handoff = self._donate_kv(
+                    seq, self.pool.row(slot), memo=req.prefix_hashes)
+            entry = self._slot_entry.pop(slot, None)
+            if entry is not None:
+                self.prefix_cache.release(entry)
+            self.pool.free_slot(slot)
+            # graftlint: disable=GUARDED-BY (single-threaded by protocol: _export_unfinished runs after stop() joined the engine thread — see its docstring — so nothing races these resets)
+            self.positions[slot] = 0
+            self.tokens[slot] = 0
         out = []
         for req in doomed:
             cont = {
@@ -1521,7 +1499,7 @@ class LLMEngine:
                 "temperature": req.temperature,
                 "eos_id": req.eos_id,
             }
-            if self.prefill_chunk and req.prefix_hashes:
+            if req.prefix_hashes:
                 # The memoized chunk-hash chain rides the continuation
                 # (hex — JSON-safe), so the destination replica never
                 # re-hashes the full context on resume; prefix_chunk
@@ -1556,8 +1534,7 @@ class LLMEngine:
             self._decode_ewma_tok_s = None
             self._budget_util_ewma = None
             self._spec_accept_ewma = None
-            if self.pool is not None:
-                self.pool.rebase_low_water()
+            self.pool.rebase_low_water()
             self._awaiting_max = self._awaiting_first_token()
             # A live request's longest wait starts over too: what it waited
             # before the reset is not the window's (copies: `_deferred` and
@@ -1625,10 +1602,9 @@ class LLMEngine:
         """replica/impl tags for the engine-side histograms (built once,
         first use — the replica id needs the runtime context)."""
         if self._step_tags is None:
-            impl = (f"paged-{self.attn_impl}" if self.kv_mode == "paged"
-                    else "dense")
             self._step_tags = {
-                "replica": _request_metric_tags()["replica"], "impl": impl}
+                "replica": _request_metric_tags()["replica"],
+                "impl": f"paged-{self.attn_impl}"}
         return self._step_tags
 
     def _observe_decode(self, t0: float, end: float, per_slot: float,
@@ -1665,107 +1641,102 @@ class LLMEngine:
                      awaiting_first_token_max=max(self._awaiting_max,
                                                   awaiting),
                      n_slots=self.n_slots)
-            if self.kv_mode == "paged":
-                m["kv_pages_total"] = self.n_pages
-                m["kv_pages_free"] = self.pool.n_free
-                m["kv_pages_free_min"] = self.pool.min_free
-                m["kv_page_size"] = self.page_size
-                m["llm_attn_impl"] = self.attn_impl
-                # Live pages the decoding slots attended over the pages
-                # of the decode kernel's live kv blocks: under 1.0 a
-                # block's tail lay past its slot's last page.
-                m["decode_block_fill"] = m["decode_pages_live"] / max(
-                    1, m["decode_pages_fetched"])
-                # The same live pages over every column of the windows'
-                # tables: the share of a (slot, column) grid that the
-                # decode kernel fetches; the rest costs it nothing.
-                m["decode_live_column_share"] = m["decode_pages_live"] / max(
-                    1, m["decode_columns"])
-                # How often a decode window left one more step in flight
-                # for the host to work beside, and what stood the others
-                # down (_STAND_DOWN; the flat counters stay beside it).
-                m["lookahead_share"] = m["lookahead_windows"] / max(
-                    1, m["decode_windows"])
-                m["lookahead_stood_down"] = {
-                    cause: m["lookahead_stood_down_" + cause]
-                    for cause in _STAND_DOWN}
-                # The share of decode windows whose steps ran the
-                # categorical draw (paged_kv._sample_next skips it for
-                # a batch with no temperature above 0): 0.0 under
-                # greedy traffic, 1.0 where some slot always samples.
-                m["decode_draw_share"] = m["decode_windows_drawn"] / max(
-                    1, m["decode_windows"])
-                # Quantized-serving observability (rides the PR 6 chain:
-                # replica stats → serve.status() → /api/serve/load →
-                # `ray_tpu status --serve`): the dtype knobs as resolved
-                # (soft-off shows "bf16") + the pool's actual device
-                # bytes, scale planes included.
-                m["llm_weight_dtype"] = self.weight_dtype
-                m["llm_kv_dtype"] = self.kv_dtype
-                nbytes = lambda a: int(math.prod(a.shape) * a.dtype.itemsize)
-                # A family's window layers keep rings a slot beside the
-                # pages (0: none); the pool's bytes count both kinds.
-                rings = [a for name, a in self.cache.items()
-                         if name in ("k_win", "v_win")]
-                m["window_kv_bytes"] = sum(map(nbytes, rings))
-                m["kv_pool_bytes"] = m["window_kv_bytes"] + sum(
-                    nbytes(a) for name, a in self.cache.items()
-                    if name in ("k", "v", "kv", "k_scale", "v_scale"))
-                # The pool's bytes by kind, and of the window kind what
-                # every slot's live window needs: the rest of a ring is
-                # room for a dispatch's writes (models/laguna.py
-                # `ring_pages`).
-                m["kv_bytes_window"] = m["window_kv_bytes"]
-                m["kv_bytes_full"] = (m["kv_pool_bytes"]
-                                      - m["kv_bytes_window"])
-                m["kv_bytes_window_live"] = sum(
-                    self.n_slots * a.shape[0] * self.cfg.window
-                    * a.shape[3] * a.dtype.itemsize for a in rings)
-                # A family's per-slot state beside the pages (0: none).
-                m["slot_state_bytes"] = sum(
-                    nbytes(self.cache[name])
-                    for name in self._family.slot_state)
+            m["kv_pages_total"] = self.n_pages
+            m["kv_pages_free"] = self.pool.n_free
+            m["kv_pages_free_min"] = self.pool.min_free
+            m["kv_page_size"] = self.page_size
+            m["llm_attn_impl"] = self.attn_impl
+            # Live pages the decoding slots attended over the pages
+            # of the decode kernel's live kv blocks: under 1.0 a
+            # block's tail lay past its slot's last page.
+            m["decode_block_fill"] = m["decode_pages_live"] / max(
+                1, m["decode_pages_fetched"])
+            # The same live pages over every column of the windows'
+            # tables: the share of a (slot, column) grid that the
+            # decode kernel fetches; the rest costs it nothing.
+            m["decode_live_column_share"] = m["decode_pages_live"] / max(
+                1, m["decode_columns"])
+            # How often a decode window left one more step in flight
+            # for the host to work beside, and what stood the others
+            # down (_STAND_DOWN; the flat counters stay beside it).
+            m["lookahead_share"] = m["lookahead_windows"] / max(
+                1, m["decode_windows"])
+            m["lookahead_stood_down"] = {
+                cause: m["lookahead_stood_down_" + cause]
+                for cause in _STAND_DOWN}
+            # The share of decode windows whose steps ran the
+            # categorical draw (paged_kv._sample_next skips it for
+            # a batch with no temperature above 0): 0.0 under
+            # greedy traffic, 1.0 where some slot always samples.
+            m["decode_draw_share"] = m["decode_windows_drawn"] / max(
+                1, m["decode_windows"])
+            # Quantized-serving observability (rides the PR 6 chain:
+            # replica stats → serve.status() → /api/serve/load →
+            # `ray_tpu status --serve`): the dtype knobs as resolved
+            # (soft-off shows "bf16") + the pool's actual device
+            # bytes, scale planes included.
+            m["llm_weight_dtype"] = self.weight_dtype
+            m["llm_kv_dtype"] = self.kv_dtype
+            nbytes = lambda a: int(math.prod(a.shape) * a.dtype.itemsize)
+            # A family's window layers keep rings a slot beside the
+            # pages (0: none); the pool's bytes count both kinds.
+            rings = [a for name, a in self.cache.items()
+                     if name in ("k_win", "v_win")]
+            m["window_kv_bytes"] = sum(map(nbytes, rings))
+            m["kv_pool_bytes"] = m["window_kv_bytes"] + sum(
+                nbytes(a) for name, a in self.cache.items()
+                if name in ("k", "v", "kv", "k_scale", "v_scale"))
+            # The pool's bytes by kind, and of the window kind what
+            # every slot's live window needs: the rest of a ring is
+            # room for a dispatch's writes (models/laguna.py
+            # `ring_pages`).
+            m["kv_bytes_window"] = m["window_kv_bytes"]
+            m["kv_bytes_full"] = m["kv_pool_bytes"] - m["kv_bytes_window"]
+            m["kv_bytes_window_live"] = sum(
+                self.n_slots * a.shape[0] * self.cfg.window
+                * a.shape[3] * a.dtype.itemsize for a in rings)
+            # A family's per-slot state beside the pages (0: none).
+            m["slot_state_bytes"] = sum(
+                nbytes(self.cache[name]) for name in self._family.slot_state)
             m["weight_bytes"] = sum(
                 int(a.nbytes) for a in self._rt.jax.tree.leaves(self.params))
             m["llm_tp"] = self.tp
             if self.tp > 1:
                 m.update(self._tp_topology())
-            if self.prefill_chunk:
-                m["prefill_chunk"] = self.prefill_chunk
-                m["prefill_token_budget"] = self.prefill_budget
-                m["chunk_rows"] = self.chunk_rows
-                m["chunk_heights"] = list(self.chunk_heights)
-                # Prompt tokens placed over token positions the chunk
-                # dispatches carried: 1.0 = every row a full chunk.
-                m["prefill_row_fill"] = m["prefill_tokens"] / max(
-                    1, m["prefill_rows_dispatched"] * self.prefill_chunk)
-                # Live rows a chunk program: how often one program
-                # carries what would have been several.
-                m["prefill_rows_per_program"] = m["prefill_chunks"] / max(
-                    1, m["prefill_dispatches"])
-                # Tokens placed over tokens allowed: under 1.0 the pool
-                # (or the work), not the budget, bounds prefill.
-                m["prefill_allowance_used"] = m["prefill_tokens"] / max(
-                    1, m["prefill_allowance"])
-                # Live pages attended over the pages the prefill
-                # kernel's live kv blocks fetched: under 1.0 a block's
-                # tail was null or not yet written.
-                m["prefill_block_fill"] = m["prefill_pages_live"] / max(
-                    1, m["prefill_pages_fetched"])
-                m["prefilling_slots"] = len(self._prefilling)
-                m["prefill_width_bucketing"] = self.prefill_width_bucketing
-                if self._dispatch_width_ring:
-                    widths = sorted(self._dispatch_width_ring)
-                    m["prefill_dispatch_width_p50"] = widths[
-                        len(widths) // 2]
-                    m["prefill_dispatch_width_max"] = widths[-1]
-                if self._dispatch_width_counts:
-                    # Cumulative-since-reset per-width dispatch counts:
-                    # host mirror of llm_prefill_dispatch_total{width}
-                    # (str keys — this dict rides JSON to /api/serve).
-                    m["prefill_dispatch_widths"] = {
-                        str(w): c for w, c in
-                        sorted(self._dispatch_width_counts.items())}
+            m["prefill_chunk"] = self.prefill_chunk
+            m["prefill_token_budget"] = self.prefill_budget
+            m["chunk_rows"] = self.chunk_rows
+            m["chunk_heights"] = list(self.chunk_heights)
+            # Prompt tokens placed over token positions the chunk
+            # dispatches carried: 1.0 = every row a full chunk.
+            m["prefill_row_fill"] = m["prefill_tokens"] / max(
+                1, m["prefill_rows_dispatched"] * self.prefill_chunk)
+            # Live rows a chunk program: how often one program
+            # carries what would have been several.
+            m["prefill_rows_per_program"] = m["prefill_chunks"] / max(
+                1, m["prefill_dispatches"])
+            # Tokens placed over tokens allowed: under 1.0 the pool
+            # (or the work), not the budget, bounds prefill.
+            m["prefill_allowance_used"] = m["prefill_tokens"] / max(
+                1, m["prefill_allowance"])
+            # Live pages attended over the pages the prefill
+            # kernel's live kv blocks fetched: under 1.0 a block's
+            # tail was null or not yet written.
+            m["prefill_block_fill"] = m["prefill_pages_live"] / max(
+                1, m["prefill_pages_fetched"])
+            m["prefilling_slots"] = len(self._prefilling)
+            m["prefill_width_bucketing"] = self.prefill_width_bucketing
+            if self._dispatch_width_ring:
+                widths = sorted(self._dispatch_width_ring)
+                m["prefill_dispatch_width_p50"] = widths[len(widths) // 2]
+                m["prefill_dispatch_width_max"] = widths[-1]
+            if self._dispatch_width_counts:
+                # Cumulative-since-reset per-width dispatch counts:
+                # host mirror of llm_prefill_dispatch_total{width}
+                # (str keys — this dict rides JSON to /api/serve).
+                m["prefill_dispatch_widths"] = {
+                    str(w): c for w, c in
+                    sorted(self._dispatch_width_counts.items())}
             if self.spec_k:
                 m["spec_k"] = self.spec_k
                 m["spec_draft"] = self.spec_draft_name
@@ -1875,44 +1846,40 @@ class LLMEngine:
             if self._decode_ewma_tok_s is not None:
                 snap["decode_tok_s_ewma"] = round(
                     self._decode_ewma_tok_s, 3)
-            if self.kv_mode == "paged":
-                snap["pool_pages_total"] = self.n_pages
-                snap["pool_pages_free"] = self.pool.n_free
-                snap["pool_pages_free_min"] = self.pool.min_free
-                snap["pool_utilization"] = round(
-                    1.0 - self.pool.n_free / self.n_pages, 4)
-                # Quantized-serving load surface (PR 6 chain: replica
-                # stats → serve.status() → /api/serve/load → CLI).
-                snap["llm_weight_dtype"] = self.weight_dtype
-                snap["llm_kv_dtype"] = self.kv_dtype
-                snap["kv_pool_bytes"] = sum(
-                    int(math.prod(a.shape) * a.dtype.itemsize)
-                    for a in self.cache.values())
+            snap["pool_pages_total"] = self.n_pages
+            snap["pool_pages_free"] = self.pool.n_free
+            snap["pool_pages_free_min"] = self.pool.min_free
+            snap["pool_utilization"] = round(
+                1.0 - self.pool.n_free / self.n_pages, 4)
+            # Quantized-serving load surface (PR 6 chain: replica
+            # stats → serve.status() → /api/serve/load → CLI).
+            snap["llm_weight_dtype"] = self.weight_dtype
+            snap["llm_kv_dtype"] = self.kv_dtype
+            snap["kv_pool_bytes"] = sum(
+                int(math.prod(a.shape) * a.dtype.itemsize)
+                for a in self.cache.values())
             if self.tp > 1:
                 # Riding the PR 6 chain as-is: Replica.stats() →
                 # controller probe → serve.status() / /api/serve/load /
                 # `ray_tpu status --serve`.
                 snap["llm_tp"] = self.tp
                 snap.update(self._tp_topology())
-            if self.prefill_chunk:
-                snap["prefill_chunk"] = self.prefill_chunk
-                snap["prefill_token_budget"] = self.prefill_budget
-                snap["chunk_rows"] = self.chunk_rows
-                if self._budget_util_ewma is not None:
-                    snap["prefill_budget_util"] = round(
-                        self._budget_util_ewma, 4)
-                # Width-bucketed dispatch load (rides the PR 6 chain:
-                # Replica.stats() → controller probe → serve.status() /
-                # /api/serve/load / `ray_tpu status --serve`, plus the
-                # matching llm_* gauges set below): the median/max page-
-                # table width of recent chunk dispatches — full-width
-                # medians on short-prompt traffic are the interior-chunk
-                # waste width bucketing exists to remove.
-                if self._dispatch_width_ring:
-                    widths = sorted(self._dispatch_width_ring)
-                    snap["prefill_dispatch_width_p50"] = widths[
-                        len(widths) // 2]
-                    snap["prefill_dispatch_width_max"] = widths[-1]
+            snap["prefill_chunk"] = self.prefill_chunk
+            snap["prefill_token_budget"] = self.prefill_budget
+            snap["chunk_rows"] = self.chunk_rows
+            if self._budget_util_ewma is not None:
+                snap["prefill_budget_util"] = round(self._budget_util_ewma, 4)
+            # Width-bucketed dispatch load (rides the PR 6 chain:
+            # Replica.stats() → controller probe → serve.status() /
+            # /api/serve/load / `ray_tpu status --serve`, plus the
+            # matching llm_* gauges set below): the median/max page-
+            # table width of recent chunk dispatches — full-width
+            # medians on short-prompt traffic are the interior-chunk
+            # waste width bucketing exists to remove.
+            if self._dispatch_width_ring:
+                widths = sorted(self._dispatch_width_ring)
+                snap["prefill_dispatch_width_p50"] = widths[len(widths) // 2]
+                snap["prefill_dispatch_width_max"] = widths[-1]
             if self.spec_k:
                 # Rides the PR 6 chain as-is: Replica.stats() →
                 # controller reconcile probe → serve.status() /
@@ -1969,9 +1936,9 @@ class LLMEngine:
                         self.stats["prefix_hits"] / looked, 4)
         tags = {"replica": self._impl_tags()["replica"]}
         for key, gauge in _LOAD_GAUGES.items():
-            # Absent fields (dense engine's pool, EWMAs cleared by
-            # reset_stats) export 0, not their last stale value — the
-            # router must never act on a pre-reset TTFT.
+            # Absent fields (EWMAs cleared by reset_stats) export 0, not
+            # their last stale value — the router must never act on a
+            # pre-reset TTFT.
             gauge.set(float(snap.get(key, 0.0)), tags=tags)
         return snap
 
@@ -2317,12 +2284,6 @@ class LLMEngine:
 
     # ------------------------------------------------------------- engine
 
-    def _bucket(self, n: int) -> int:
-        for b in self.buckets:
-            if n <= b:
-                return b
-        raise ValueError(f"no bucket for prompt length {n}")
-
     _TTFT_SPAN_SAMPLE = 16
 
     def _emit_ttft_spans(self, req: GenRequest) -> None:
@@ -2413,7 +2374,6 @@ class LLMEngine:
         return int(rt.sample_token(
             logits_row, temperature=temperature, key=sub))
 
-    _PREFILL_LADDER = (8, 4, 2)
     # Admission lookahead bound: how many page-blocked requests one round
     # scans past (keeps the tick O(1) under a deep blocked queue) — and
     # the aging limit after which a repeatedly-bypassed head goes
@@ -2421,17 +2381,11 @@ class LLMEngine:
     _ADMIT_LOOKAHEAD = 8
     _ADMIT_BYPASS_LIMIT = 16
 
-    def _admit(self) -> list[tuple]:
-        """Move queued requests into free slots. → the one-shot prefill
-        groups `(bucket, requests, slots)` the caller now dispatches
-        (`_prefill_group`); empty in chunked mode.
-
-        One-shot mode (prefill_chunk=0): whole-prompt admission —
-        same-bucket arrivals prefill in ladder-sized GROUPS via one
-        prefill_batch dispatch each (a burst of N costs ~log N round trips
-        instead of N). Chunked mode: a request is admitted once ONE CHUNK
-        of pool headroom exists; its prompt then enters chunk-by-chunk
-        under step()'s token budget.
+    def _admit(self) -> None:
+        """Move queued requests into free slots: a request is admitted
+        once ONE CHUNK of pool headroom exists; its prompt then enters
+        chunk-by-chunk under step()'s token budget
+        (`_run_prefill_chunks`).
 
         Head-of-line fix: a page-blocked request no longer stops the scan.
         Up to _ADMIT_LOOKAHEAD blocked requests are set aside — returning
@@ -2455,68 +2409,63 @@ class LLMEngine:
                 except queue.Empty:
                     break
             hit = None
-            if self.pool is not None:
-                # Admission back-pressure: one-shot needs the whole prompt
-                # (plus first decode write) covered; chunked only the
-                # FIRST CHUNK — the rest is budgeted lazy growth. A warm
-                # prefix shrinks the reservation further: shared full
-                # pages come from the cache, so only the COW tail (if
-                # the prefix ends mid-page) plus the first COLD chunk's
-                # pages need the free list.
-                if self.prefill_chunk:
-                    n_cached = 0
-                    if self.prefix_cache is not None:
-                        # Acquire (pin) at RESERVATION time: the reclaim
-                        # below evicts zero-active entries, and it must
-                        # not evict the entry this reservation is sized
-                        # for — an unpinned match could silently turn a
-                        # warm admission cold with an undersized page
-                        # reservation.
-                        hit = self.prefix_cache.acquire(
-                            req.prompt_ids, memo=req.prefix_hashes)
-                        if hit is not None:
-                            n_cached = hit.n_tokens
-                    if not req.kv_plan_tried:
-                        req.kv_plan = self._kv_adopt_plan(req, n_cached)
-                        req.kv_plan_tried = True
-                    plan = req.kv_plan
-                    if plan is not None:
-                        # Adoption ladder rung 1: donated pages resolve
-                        # DEEPER than any local warm hit. Adopted pages
-                        # are fresh exclusive allocations (nothing is
-                        # shared across replicas), so the reservation
-                        # covers the whole adopted run + first cold
-                        # chunk — the bind may still degrade (partial /
-                        # re-prefill) without exceeding it.
-                        plans[req.request_id] = plan
-                        end = min(plan["n_tokens"] + self.prefill_chunk,
-                                  len(req.prompt_ids))
-                        need = self.pool.pages_for(end - 1)
-                    else:
-                        end = min(n_cached + self.prefill_chunk,
-                                  len(req.prompt_ids))
-                        need = (self.pool.pages_for(end - 1)
-                                - n_cached // self.page_size)
-                else:
-                    need = self.pool.pages_for(len(req.prompt_ids))
-                if planned_pages + need > self.pool.n_free:
-                    self._cache_reclaim(planned_pages + need)
-                if planned_pages + need > self.pool.n_free:
-                    plans.pop(req.request_id, None)
-                    if hit is not None:
-                        # Not admitted this round: unpin (the entry is
-                        # re-acquired when the request is re-scanned).
-                        self.prefix_cache.release(hit)
-                    if not blocked:
-                        head_mark = len(reqs)
-                        if req.admit_bypasses >= self._ADMIT_BYPASS_LIMIT:
-                            blocked.append(req)
-                            break   # aged head: strict FIFO until it fits
-                    blocked.append(req)
-                    if len(blocked) >= self._ADMIT_LOOKAHEAD:
-                        break
-                    continue
-                planned_pages += need
+            # Admission back-pressure: only the FIRST CHUNK has to be
+            # covered — the rest is budgeted lazy growth. A warm
+            # prefix shrinks the reservation further: shared full
+            # pages come from the cache, so only the COW tail (if
+            # the prefix ends mid-page) plus the first COLD chunk's
+            # pages need the free list.
+            n_cached = 0
+            if self.prefix_cache is not None:
+                # Acquire (pin) at RESERVATION time: the reclaim
+                # below evicts zero-active entries, and it must
+                # not evict the entry this reservation is sized
+                # for — an unpinned match could silently turn a
+                # warm admission cold with an undersized page
+                # reservation.
+                hit = self.prefix_cache.acquire(
+                    req.prompt_ids, memo=req.prefix_hashes)
+                if hit is not None:
+                    n_cached = hit.n_tokens
+            if not req.kv_plan_tried:
+                req.kv_plan = self._kv_adopt_plan(req, n_cached)
+                req.kv_plan_tried = True
+            plan = req.kv_plan
+            if plan is not None:
+                # Adoption ladder rung 1: donated pages resolve
+                # DEEPER than any local warm hit. Adopted pages
+                # are fresh exclusive allocations (nothing is
+                # shared across replicas), so the reservation
+                # covers the whole adopted run + first cold
+                # chunk — the bind may still degrade (partial /
+                # re-prefill) without exceeding it.
+                plans[req.request_id] = plan
+                end = min(plan["n_tokens"] + self.prefill_chunk,
+                          len(req.prompt_ids))
+                need = self.pool.pages_for(end - 1)
+            else:
+                end = min(n_cached + self.prefill_chunk,
+                          len(req.prompt_ids))
+                need = (self.pool.pages_for(end - 1)
+                        - n_cached // self.page_size)
+            if planned_pages + need > self.pool.n_free:
+                self._cache_reclaim(planned_pages + need)
+            if planned_pages + need > self.pool.n_free:
+                plans.pop(req.request_id, None)
+                if hit is not None:
+                    # Not admitted this round: unpin (the entry is
+                    # re-acquired when the request is re-scanned).
+                    self.prefix_cache.release(hit)
+                if not blocked:
+                    head_mark = len(reqs)
+                    if req.admit_bypasses >= self._ADMIT_BYPASS_LIMIT:
+                        blocked.append(req)
+                        break   # aged head: strict FIFO until it fits
+                blocked.append(req)
+                if len(blocked) >= self._ADMIT_LOOKAHEAD:
+                    break
+                continue
+            planned_pages += need
             if hit is not None:
                 hits[req.request_id] = hit
             reqs.append(req)
@@ -2524,52 +2473,33 @@ class LLMEngine:
             self._deferred.appendleft(req)   # original order, at the head
         if blocked and len(reqs) > head_mark:
             blocked[0].admit_bypasses += 1
-        if not reqs:
-            return []
-        if self.prefill_chunk:
-            # Chunked admission: bind request → slot now; the prompt
-            # enters the pool chunk-by-chunk via _run_prefill_chunks.
-            # A prefix-cache hit pre-binds the cached page run into the
-            # slot's table and starts the chunk cursor at the first
-            # COLD token — the cached prefix is never re-prefilled.
-            for req, slot in zip(reqs, free):
-                n_cached = 0
-                hit = hits.pop(req.request_id, None)
-                plan = plans.pop(req.request_id, None)
-                if plan is not None:
-                    n_cached = self._bind_kv_adopt(slot, req, plan)
-                if n_cached:
-                    # Adopted: the pinned local entry (if any) goes
-                    # unused — release it; adoption only planned when
-                    # it covers MORE tokens than the local hit.
-                    if hit is not None:
-                        self.prefix_cache.release(hit)
-                elif self.prefix_cache is not None:
-                    # Ladder falls through: local warm hit, else cold.
-                    n_cached = self._bind_cached_prefix(slot, req, hit)
-                with self._lock:
-                    self.slot_req[slot] = req
-                self.tokens[slot] = 0
-                self.positions[slot] = 0
-                self.temps[slot] = req.temperature
-                self._chunk_pos[slot] = n_cached
-                self._prefilling.append(slot)
-            return []
-        groups = []
-        by_bucket: dict[int, list[GenRequest]] = {}
-        for req in reqs:
-            by_bucket.setdefault(
-                self._bucket(len(req.prompt_ids)), []).append(req)
-        slot_iter = iter(free)
-        for bucket, group in by_bucket.items():
-            while group:
-                n = next((k for k in self._PREFILL_LADDER
-                          if k <= len(group)), 1)
-                batch = group[:n]
-                group = group[n:]
-                groups.append((bucket, batch,
-                               [next(slot_iter) for _ in batch]))
-        return groups
+        # Bind request → slot now; the prompt enters the pool
+        # chunk-by-chunk via _run_prefill_chunks. A prefix-cache hit
+        # pre-binds the cached page run into the slot's table and starts
+        # the chunk cursor at the first COLD token — the cached prefix is
+        # never re-prefilled.
+        for req, slot in zip(reqs, free):
+            n_cached = 0
+            hit = hits.pop(req.request_id, None)
+            plan = plans.pop(req.request_id, None)
+            if plan is not None:
+                n_cached = self._bind_kv_adopt(slot, req, plan)
+            if n_cached:
+                # Adopted: the pinned local entry (if any) goes
+                # unused — release it; adoption only planned when
+                # it covers MORE tokens than the local hit.
+                if hit is not None:
+                    self.prefix_cache.release(hit)
+            elif self.prefix_cache is not None:
+                # Ladder falls through: local warm hit, else cold.
+                n_cached = self._bind_cached_prefix(slot, req, hit)
+            with self._lock:
+                self.slot_req[slot] = req
+            self.tokens[slot] = 0
+            self.positions[slot] = 0
+            self.temps[slot] = req.temperature
+            self._chunk_pos[slot] = n_cached
+            self._prefilling.append(slot)
 
     def _bind_cached_prefix(self, slot: int, req: GenRequest,
                             entry) -> int:
@@ -2647,79 +2577,6 @@ class LLMEngine:
             # tail page would feed the draft stale K/V.
             self.draft_cache = rt.copy_pages(
                 self.draft_cache, rt.jnp.asarray(src), rt.jnp.asarray(dst))
-
-    def _prefill_group(self, bucket, group, slots) -> None:
-        """One-shot admission: whole-prompt prefill for a same-bucket
-        GROUP of requests in a single dispatch."""
-        rt = self._rt
-        n = len(group)
-        with self._phase("prefill.build"):
-            padded = np.zeros((n, bucket), np.int32)
-            lengths = np.zeros(n, np.int32)
-            for i, req in enumerate(group):
-                lengths[i] = len(req.prompt_ids)
-                padded[i, :lengths[i]] = req.prompt_ids
-            t0 = time.perf_counter()
-            for req in group:
-                if req.first_chunk_at is None:
-                    req.first_chunk_at = t0
-        try:
-            with self._phase("prefill.dispatch"):
-                if self.pool is not None:
-                    # _admit reserved pool headroom; grow each slot to
-                    # cover prompt + first decode write (single-threaded
-                    # engine, so the reservation cannot race).
-                    pages = np.zeros((n, self.pool.pages_for(bucket - 1)),
-                                     np.int32)
-                    for i, slot in enumerate(slots):
-                        if not self.pool.grow(slot, int(lengths[i]),
-                                              self._cache_reclaim):
-                            raise RuntimeError("page reservation desync")
-                        pages[i] = self.pool.row(slot, pages.shape[1])
-                    last_logits, self.cache = rt.prefill_batch_paged(
-                        self.cfg, self.params, rt.jnp.asarray(padded),
-                        self.cache, rt.jnp.asarray(pages),
-                        rt.jnp.asarray(lengths))
-                elif n == 1:
-                    last_logits, self.cache = rt.prefill(
-                        self.cfg, self.params, rt.jnp.asarray(padded),
-                        self.cache, rt.jnp.int32(slots[0]),
-                        rt.jnp.int32(int(lengths[0])))
-                else:
-                    last_logits, self.cache = rt.prefill_batch(
-                        self.cfg, self.params, rt.jnp.asarray(padded),
-                        self.cache,
-                        rt.jnp.asarray(np.asarray(slots, np.int32)),
-                        rt.jnp.asarray(lengths))
-            with self._phase("prefill.pull"):
-                last_logits = np.asarray(last_logits)
-                if last_logits.ndim == 1:       # rt.prefill: one row
-                    last_logits = last_logits[None, :]
-        except Exception as e:
-            if self.pool is not None:
-                # Pages grown onto these (still request-less) slots must
-                # return to the pool, or repeated failures pin it dry.
-                for slot in slots:
-                    self.pool.free_slot(slot)
-            for req in group:
-                req.error = f"prefill failed: {e!r}"
-                req.done.set()
-            return
-        now = time.perf_counter()
-        self.stats["prefill_time_s"] += now - t0
-        self.stats["prefill_tokens"] += int(lengths.sum())
-        with self._phase("prefill.graduate"):
-            for i, (req, slot) in enumerate(zip(group, slots)):
-                req.last_chunk_at = now
-                tok = self._sample(last_logits[i], req.temperature)
-                with self._lock:
-                    self.slot_req[slot] = req
-                self.tokens[slot] = tok
-                self.positions[slot] = int(lengths[i])
-                self.temps[slot] = req.temperature
-                self._hand_over(req, now, 0)
-                if self._emit(req, tok):
-                    self._release(slot)
 
     # ----------------------------------------------- chunked prefill
 
@@ -3172,8 +3029,7 @@ class LLMEngine:
         entry = self._slot_entry.pop(slot, None)
         if entry is not None:
             self.prefix_cache.release(entry)
-        if self.pool is not None:
-            self.pool.free_slot(slot)
+        self.pool.free_slot(slot)
 
     def _preempt(self, slot: int) -> None:
         """Evict a slot by RECOMPUTE (vLLM-style): its pages return to the
@@ -3205,7 +3061,7 @@ class LLMEngine:
         self.stats["preemptions"] += 1
         if (len(req.prompt_ids) > self._prompt_cap
                 or self.pool.pages_for(len(req.prompt_ids)) > self.n_pages):
-            # Regrown context no longer fits any prefill bucket — finish
+            # Regrown context no longer fits the cache or the pool — finish
             # with what we have rather than wedging the queue, flagged so
             # clients can tell this from natural completion.
             req.truncated = True
@@ -3225,7 +3081,7 @@ class LLMEngine:
 
     def _fit_window_pages(self, active: list[int],
                           k: int) -> tuple[list[int], int, str | None]:
-        """Paged mode: shrink the window and/or preempt until the pool can
+        """Shrink the window and/or preempt until the pool can
         cover every active slot's writes for the window, then allocate.
         → (surviving active slots, window size; 0 = nothing to run, why
         no step follows the window in flight; None = one does).
@@ -3558,7 +3414,7 @@ class LLMEngine:
         prefill token budget, then one decode window for every
         decode-ready slot. → slots that did work (decoding + prefilling).
 
-        A paged window of k rows ends with k + 1 steps queued and the
+        A window of k rows ends with k + 1 steps queued and the
         first k read: the last runs on the device while the NEXT tick
         admits, dispatches chunk programs and plans, and is that tick's
         first row (`_fit_window_pages` says when; `_InFlight`,
@@ -3582,7 +3438,7 @@ class LLMEngine:
         # A tick is the interval from one `admit` to the next.
         self._ticks.begin(time.perf_counter())
         with self._phase("admit"):
-            groups = self._admit()
+            self._admit()
             # COW flush MUST precede any dispatch that could write this
             # tick: admission queued the pairs, and the first cold chunk
             # of a warm slot writes into its COW'd tail page.
@@ -3590,11 +3446,7 @@ class LLMEngine:
             with self._lock:
                 self._awaiting_max = max(self._awaiting_max,
                                          self._awaiting_first_token())
-        for bucket, group, slots in groups:
-            # graftlint: disable=HOST-SYNC-IN-HOT-LOOP (one pull per one-shot prefill group by design: its first tokens are sampled on the host)
-            self._prefill_group(bucket, group, slots)
-        if self.prefill_chunk:
-            self._run_prefill_chunks(self._decode_ready_slots())
+        self._run_prefill_chunks(self._decode_ready_slots())
         n_prefilling = len(self._prefilling)
         if self.spec_k:
             # Speculative decoding replaces the fused decode window
@@ -3623,14 +3475,10 @@ class LLMEngine:
                 # clients.
                 _chaos.hit("llm.decode_window")
                 k = self._pick_window(active)
-                table_view = None
-                stood_down = None
-                if self.kv_mode == "paged":
-                    active, k, stood_down = self._fit_window_pages(active, k)
-                    if active:
-                        table_view = self._decode_table_view(active)
-                        self._count_decode_pages(active,
-                                                 table_view.shape[1])
+                active, k, stood_down = self._fit_window_pages(active, k)
+                if active:
+                    table_view = self._decode_table_view(active)
+                    self._count_decode_pages(active, table_view.shape[1])
             if not active:
                 return n_prefilling
             # The steps this window dispatches: all its rows, or all but
@@ -3657,7 +3505,7 @@ class LLMEngine:
                 tokens, positions = rt.join_window(
                     jnp.asarray(carry.mask), carry.tokens,
                     jnp.asarray(self.tokens), jnp.asarray(self.positions))
-            if table_view is not None and n_new:
+            if n_new:
                 table_view = jnp.asarray(table_view)
         if not n_new:
             # A one-row window behind a step in flight IS that step: it
@@ -3667,34 +3515,25 @@ class LLMEngine:
             return len(active) + n_prefilling
         if k > 1:
             with self._phase("decode_window"):
-                if self.kv_mode == "paged":
-                    # Device order is the safety argument for the step
-                    # `ahead` leaves in flight: it is queued here, behind
-                    # this window's steps and ahead of every chunk
-                    # program, page copy or step a later tick dispatches,
-                    # the pool donated from each to the next, and its
-                    # table view is this tick's immutable upload. A slot
-                    # the emit below releases keeps its pages, ring rows
-                    # and state untouched by anyone else until that step
-                    # has run.
-                    self._carry = None
-                    # graftlint: disable=GUARDED-BY (engine-thread state: only _step writes the KV cache while the loop runs; drain/export mutate it after stop() joins the thread)
-                    toks_out, self.cache = rt.decode_multi_paged(
-                        self.cfg, self.params, tokens, self.cache,
-                        positions, table_view, n_new, temps, sub,
-                        attn_impl=self.attn_impl, phase=self._phase,
-                        carried=None if carry is None else carry.tokens,
-                        ahead=None if stood_down else (
-                            lambda toks, key: self._hold_ahead(
-                                active, toks, key)),
-                        **self._window_counters)
-                else:
-                    with self._phase("decode.dispatch"):
-                        toks_out, self.cache = rt.decode_multi(
-                            self.cfg, self.params, tokens, self.cache,
-                            positions, k, temps, sub)
-                    with self._phase("decode.pull"):
-                        toks_out = np.asarray(toks_out)  # [k, B]
+                # Device order is the safety argument for the step
+                # `ahead` leaves in flight: it is queued here, behind
+                # this window's steps and ahead of every chunk program,
+                # page copy or step a later tick dispatches, the pool
+                # donated from each to the next, and its table view is
+                # this tick's immutable upload. A slot the emit below
+                # releases keeps its pages, ring rows and state untouched
+                # by anyone else until that step has run.
+                self._carry = None
+                # graftlint: disable=GUARDED-BY (engine-thread state: only _step writes the KV cache while the loop runs; drain/export mutate it after stop() joins the thread)
+                toks_out, self.cache = rt.decode_multi_paged(
+                    self.cfg, self.params, tokens, self.cache,
+                    positions, table_view, n_new, temps, sub,
+                    attn_impl=self.attn_impl, phase=self._phase,
+                    carried=None if carry is None else carry.tokens,
+                    ahead=None if stood_down else (
+                        lambda toks, key: self._hold_ahead(
+                            active, toks, key)),
+                    **self._window_counters)
             with self._phase("emit"):
                 # Slot-steps the device was handed this tick (the step
                 # left in flight is this tick's; the one absorbed was
@@ -3704,9 +3543,8 @@ class LLMEngine:
                 self._observe_decode(
                     t0, handed_at, float(k), steps * len(active),
                     steps * self.n_slots)
-                if self.kv_mode == "paged":
-                    self.stats["lookahead_windows" if stood_down is None else
-                               "lookahead_stood_down_" + stood_down] += 1
+                self.stats["lookahead_windows" if stood_down is None else
+                           "lookahead_stood_down_" + stood_down] += 1
                 if carry is not None:
                     self._pay_owed(carry, toks_out[0], handed_at)
                 for slot in active:
@@ -3738,22 +3576,16 @@ class LLMEngine:
             return len(active) + n_prefilling
         with self._phase("decode_window"):
             with self._phase("decode.dispatch"):
-                if self.kv_mode == "paged":
-                    logits, self.cache = rt.decode_step_paged(
-                        self.cfg, self.params, tokens, self.cache,
-                        positions, table_view, attn_impl=self.attn_impl)
-                else:
-                    logits, self.cache = rt.decode_step(
-                        self.cfg, self.params, tokens, self.cache,
-                        positions)
+                logits, self.cache = rt.decode_step_paged(
+                    self.cfg, self.params, tokens, self.cache,
+                    positions, table_view, attn_impl=self.attn_impl)
             with self._phase("decode.pull"):
                 logits = np.asarray(logits)
         with self._phase("emit"):
             handed_at = time.perf_counter()
             self._observe_decode(t0, handed_at, 1.0, len(active),
                                  self.n_slots)
-            if self.kv_mode == "paged":
-                self.stats["lookahead_stood_down_" + stood_down] += 1
+            self.stats["lookahead_stood_down_" + stood_down] += 1
             for slot in active:
                 req = self.slot_req[slot]
                 if self.positions[slot] + 1 >= self.max_len:
@@ -3771,8 +3603,7 @@ class LLMEngine:
         """Every slot's temperature, for a window's step programs. The
         sampling step draws where any of them is above 0
         (`paged_kv._sample_next`): the same test, on the host's copy,
-        counts the window as one that drew. (A dense cache's window,
-        `decode.decode_multi`, still draws at every step.)"""
+        counts the window as one that drew."""
         if (self.temps > 0.0).any():
             self.stats["decode_windows_drawn"] += 1
         return self._rt.jnp.asarray(self.temps)
@@ -3829,7 +3660,7 @@ class LLMEngine:
             while not self._shutdown.is_set():
                 # step() IS the host-side scheduler tick: it syncs once
                 # per multi-token decode window by design, amortized over
-                # llm_decode_block tokens, and on the paged path the sync
+                # llm_decode_block tokens, and the sync
                 # waits for the window's rows only: one more step is
                 # queued behind them, so the device's queue is not empty
                 # while this thread emits, admits and plans.
@@ -3993,7 +3824,7 @@ class LLMDeployment:
             hand["deployment"] = self._pool_peer
         if req.kv_handoff is not None:
             hand["kv"] = req.kv_handoff
-        if req.prefix_hashes and self.engine.prefill_chunk:
+        if req.prefix_hashes:
             hand["prefix_hashes"] = [h.hex() for h in req.prefix_hashes]
             hand["prefix_chunk"] = self.engine.prefill_chunk
         return hand

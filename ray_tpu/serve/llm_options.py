@@ -18,7 +18,6 @@ from typing import Any
 
 # (constructor keyword, runtime_config field): None means the knob.
 _KNOBS = (
-    ("kv_mode", "llm_kv_mode"),
     ("page_size", "llm_kv_page_size"),
     ("attn_impl", "llm_attn_impl"),
     ("prefill_chunk", "llm_prefill_chunk"),
@@ -41,10 +40,9 @@ _KNOBS = (
 class EngineOptions:
     """As the engine runs them (a soft-disabled feature is neutral)."""
 
-    kv_mode: str                   # "dense" | "paged"
     page_size: int
     attn_impl: str                 # "gather" | "kernel" ("auto" resolved)
-    prefill_chunk: int             # 0 = one-shot bucketed admission
+    prefill_chunk: int             # tokens of a prompt a chunk row carries
     prefill_token_budget: int
     prefix_cache: bool
     prefix_cache_pages: int
@@ -98,36 +96,29 @@ def resolve_options(cfg, *, max_len: int, spec_draft_params,
                 setattr(o, kw, getattr(rc, field))
 
     # What this configuration's model family cannot carry
-    # (models/serving.py), by the one rule, before the checks that read
-    # kv_mode and prefill_chunk. A pool role asks for the transfer as an
-    # argument does.
+    # (models/serving.py), by the one rule. A pool role asks for the
+    # transfer as an argument does.
     for miss in family_of(cfg).unsupported:
         if miss.option == "kv_transfer" and pool_role:
             raise ValueError(miss.why)
         _honour(o, explicit, miss.option, miss.fits(o), miss.neutral,
                 miss.why)
 
-    def paged_chunked():
-        return o.kv_mode == "paged" and o.prefill_chunk
-
-    def needs_chunks(what: str, why: str) -> str:
-        return (f"{what} requires kv_mode='paged' AND prefill_chunk > 0 "
-                f"({why}); got kv_mode={o.kv_mode!r}, "
-                f"prefill_chunk={o.prefill_chunk}")
-
-    # A dense engine beside the knob keeps one-shot admission (explicit
-    # dense+chunk is refused below, after the checks that precede it).
-    _honour(o, explicit, "prefill_chunk",
-            not o.prefill_chunk or o.kv_mode == "paged", 0, None)
-    _honour(o, explicit, "prefix_cache",
-            not o.prefix_cache or paged_chunked(), False,
-            needs_chunks("prefix_cache",
-                         "the cache granularity is the prefill chunk"))
+    # A prompt enters its slot's pages chunk by chunk, the one way in.
+    # The knob beside a cache it does not fit (0, or longer than the
+    # cache: chunked prompts are capped at max_len - 1, so a wider chunk
+    # would only ever pad) takes the largest whole number of pages that
+    # does, or the cache's length where one page is longer.
+    refusal = (f"prefill_chunk ({o.prefill_chunk}) exceeds the KV cache "
+               f"(max_len = {max_len})" if o.prefill_chunk > 0 else
+               f"prefill_chunk must be positive, got {o.prefill_chunk}: "
+               "one-shot admission (prefill_chunk=0) was removed in PR 64, "
+               "a prompt enters its slot's pages chunk by chunk")
+    _honour(o, explicit, "prefill_chunk", 0 < o.prefill_chunk <= max_len,
+            max_len // o.page_size * o.page_size or max_len, refusal)
     if o.prefix_cache_pages < 0:
         raise ValueError(
             f"prefix_cache_pages must be >= 0, got {o.prefix_cache_pages}")
-    if o.kv_mode not in ("dense", "paged"):
-        raise ValueError(f"kv_mode must be dense|paged, got {o.kv_mode!r}")
     if o.attn_impl == "auto":
         # The Pallas kernel on real TPUs (pages DMA'd in place — the
         # throughput path), the exact-semantics gather reference
@@ -140,35 +131,11 @@ def resolve_options(cfg, *, max_len: int, spec_draft_params,
     if o.attn_impl not in ("gather", "kernel"):
         raise ValueError(
             f"attn_impl must be gather|kernel|auto, got {o.attn_impl!r}")
-    # Quantized serving: the int8 weight/KV streams ride the paged engine
-    # only — dense mode keeps whole-tensor caches with no page planes to
-    # carry scales.
-    dtypes = (("weight_dtype", "quantized serving targets the paged "
-               "engine; the dense path is unquantized"),
-              ("kv_dtype", "the scale planes ride the page tables; the "
-               "dense cache has none"))
-    for name, _why in dtypes:
+    for name in ("weight_dtype", "kv_dtype"):
         if getattr(o, name) not in ("bf16", "int8"):
             raise ValueError(
                 f"{name} must be bf16|int8, got {getattr(o, name)!r}")
-    for name, why in dtypes:
-        _honour(o, explicit, name,
-                getattr(o, name) != "int8" or o.kv_mode == "paged", "bf16",
-                f"{name}='int8' requires kv_mode='paged' ({why}); "
-                f"got kv_mode={o.kv_mode!r}")
-    if o.prefill_chunk < 0 or (o.prefill_chunk and o.kv_mode != "paged"):
-        raise ValueError(
-            "prefill_chunk requires kv_mode='paged' (chunked prefill "
-            f"grows page tables chunk-by-chunk); got chunk="
-            f"{o.prefill_chunk} with kv_mode={o.kv_mode!r}")
-    if o.prefill_chunk and o.prefill_chunk > max_len:
-        # Chunked prompts are cache-capped at max_len - 1: a chunk wider
-        # than the cache would only ever pad (every dispatch computing +
-        # null-scattering dead columns).
-        raise ValueError(
-            f"prefill_chunk ({o.prefill_chunk}) exceeds the KV cache "
-            f"(max_len = {max_len})")
-    if o.prefill_chunk and o.prefill_token_budget != 0 and (
+    if o.prefill_token_budget != 0 and (
             o.prefill_token_budget < o.prefill_chunk):
         # A budget smaller than one chunk could never make progress on a
         # busy engine (and a negative budget would silently act like 0)
@@ -176,10 +143,6 @@ def resolve_options(cfg, *, max_len: int, spec_draft_params,
         raise ValueError(
             f"prefill_token_budget ({o.prefill_token_budget}) must be 0 "
             f"(pure-decode ticks) or >= prefill_chunk ({o.prefill_chunk})")
-    _honour(o, explicit, "spec_draft",
-            not o.spec_draft or paged_chunked(), "",
-            needs_chunks("speculative decoding",
-                         "the verify pass is a chunked-prefill row"))
     if spec_draft_params is not None and not o.spec_draft:
         # Weights were supplied (a checkpoint was read off disk) but
         # nothing enables speculation — serving non-speculatively here
@@ -188,8 +151,8 @@ def resolve_options(cfg, *, max_len: int, spec_draft_params,
         raise ValueError(
             "spec_draft_params supplied but speculative decoding is "
             "not enabled — set spec_draft / llm_spec_draft (and note "
-            "the global knob soft-disables on non-paged/non-chunked "
-            "engines)")
+            "the global knob soft-disables beside a model family that "
+            "cannot carry it)")
     draft_cfg = None
     if o.spec_draft:
         if o.spec_k < 1:
@@ -210,9 +173,6 @@ def resolve_options(cfg, *, max_len: int, spec_draft_params,
     o.tp = int(o.tp)
     if o.tp < 1:
         raise ValueError(f"llm_tp must be >= 1, got {o.tp}")
-    _honour(o, explicit, "tp", o.tp == 1 or paged_chunked(), 1,
-            needs_chunks("tensor-parallel decode",
-                         "the sharded programs are the paged chunked set"))
 
     def misfit(c):
         return c is not None and (c.n_heads % o.tp or c.d_ff % o.tp)
@@ -269,17 +229,14 @@ def resolve_options(cfg, *, max_len: int, spec_draft_params,
     # re-slice at bind time (heads are shard-invariant math —
     # partition.split_head_planes).
     reason = (
-        "KV page-set transfer requires kv_mode='paged' and "
-        "prefill_chunk > 0 with prefill_chunk % page_size == 0 "
-        "(cross-donation dedup needs page-aligned chain "
-        f"depths); got kv_mode={o.kv_mode!r}, "
+        "KV page-set transfer requires prefill_chunk % page_size == 0 "
+        "(cross-donation dedup needs page-aligned chain depths); got "
         f"prefill_chunk={o.prefill_chunk}, page_size={o.page_size}")
     # The one soft-disable that is never silent: the engine logs the
     # reason and exports it as kv_transfer_disabled_reason.
     disabled = _honour(
         o, explicit, "kv_transfer",
-        not o.kv_transfer or (paged_chunked()
-                              and o.prefill_chunk % o.page_size == 0),
+        not o.kv_transfer or o.prefill_chunk % o.page_size == 0,
         False, reason)
     return EngineOptions(
         draft_cfg=draft_cfg, mesh=mesh, pool_role=pool_role,

@@ -110,7 +110,6 @@ class TestEngineDrain:
         cfg, params = setup
         kw.setdefault("n_slots", 2)
         kw.setdefault("max_len", 64)
-        kw.setdefault("prefill_buckets", (8, 16, 32))
         kw.setdefault("decode_block", 2)
         return LLMEngine(cfg, params, **kw)
 
@@ -192,11 +191,12 @@ class TestEngineDrain:
         assert r2.done.is_set() and r2.out_ids == [1, 2, 3]
 
     def test_overgrown_continuation_truncates_not_errors(self, setup):
-        """prompt + emitted can outgrow a one-shot engine's bucket cap
-        mid-stream; the resume must end the stream cleanly (truncated,
-        like an unresumable in-replica preempt), never drop it with an
-        error — while a FRESH oversized prompt still raises."""
-        eng = self._mk(setup, prefill_buckets=(8,))
+        """prompt + emitted can outgrow the destination engine's cache
+        cap (max_len - 1) mid-stream; the resume must end the stream
+        cleanly (truncated, like an unresumable in-replica preempt),
+        never drop it with an error — while a FRESH oversized prompt
+        still raises."""
+        eng = self._mk(setup, max_len=9)
         r = eng.submit([1] * 6, max_tokens=16, generated_ids=[2, 3, 4])
         assert r.done.is_set() and r.truncated and r.error is None
         assert r.out_ids == [2, 3, 4]
@@ -207,7 +207,7 @@ class TestEngineDrain:
         """After preempt-by-recompute, prompt_ids regrows to prompt +
         generated — the export must still split at the ORIGINAL prompt
         (double-forcing generated tokens would duplicate them)."""
-        eng = self._mk(setup, kv_mode="paged", page_size=16)
+        eng = self._mk(setup, page_size=16)
         req = eng.submit([5, 9, 2], max_tokens=8)
         for _ in range(2):
             eng.step()

@@ -33,7 +33,7 @@ def params():
 
 
 def _engine(params, **kw):
-    opts = dict(n_slots=SLOTS, max_len=192, kv_mode="paged", page_size=PAGE,
+    opts = dict(n_slots=SLOTS, max_len=192, page_size=PAGE,
                 prefill_chunk=CHUNK, prefill_token_budget=BUDGET,
                 prefill_width_bucketing=False)
     return LLMEngine(CFG, params, **{**opts, **kw})
@@ -111,7 +111,7 @@ def test_heights_follow_budget_chunk_and_window(params, chunk, budget, window,
     """H = half a tick's allowance in rows, and H/2, neither lower than
     one budget's rows nor taller than the engine has slots; both with
     the head; the ring of a family that keeps one is sized by H."""
-    kw = dict(n_slots=n_slots, max_len=256, kv_mode="paged", page_size=16,
+    kw = dict(n_slots=n_slots, max_len=256, page_size=16,
               n_pages=20, prefill_chunk=chunk, prefill_token_budget=budget,
               decode_block=window)
     eng = LLMEngine(CFG, params, prefill_width_bucketing=False, **kw)
@@ -362,8 +362,8 @@ def family(request):
     a budget of two chunks, pages of 8 C (three a slot at most, so three
     decode programs a family) unless the family says otherwise."""
     cfg, prm, chunk, kw = FAMILIES[request.param]()
-    opts = dict(n_slots=SLOTS, max_len=24 * chunk, kv_mode="paged",
-                page_size=8 * chunk, prefill_chunk=chunk,
+    opts = dict(n_slots=SLOTS, max_len=24 * chunk, page_size=8 * chunk,
+                prefill_chunk=chunk,
                 prefill_token_budget=2 * chunk, attn_impl="gather")
     if request.param.startswith("gpt"):
         opts["prefill_width_bucketing"] = False

@@ -1,16 +1,15 @@
 """Chunked prefill + stall-free token-budget scheduler (serve engine).
 
-Exactness first: the chunked-prefill engine must emit token streams
-byte-identical to the one-shot paged engine (itself exact-match with the
-dense engine) for every chunk size, ragged prompt lengths, both attention
+Exactness first: the chunked-prefill engine must emit the plain forward's
+own greedy continuation (tests/plain_reference.py) for every chunk size,
+ragged prompt lengths, both attention
 implementations, and under preempt-by-recompute pool pressure. Then the
 scheduler contracts: the prefill token budget is a hard cap for each
 decode step of a tick's window (budget 0 = pure decode ticks), a full
-pool stalls prefill instead of preempting it, the chunked path lowers within the pow-2
-width-ladder budget — 2·log₂(max_pages)+2 programs bucketed, exactly two
-with bucketing off (vs the one-shot buckets × admission-ladder grid) —
-and a page-blocked queue head no longer head-of-line-blocks
-admission. The prefill kernel runs under interpret=True off-TPU, like the
+pool stalls prefill instead of preempting it, the chunked path lowers
+within the pow-2 width-ladder budget — 2·log₂(max_pages)+2 programs
+bucketed, exactly two with bucketing off — and a page-blocked queue head
+no longer head-of-line-blocks admission. The prefill kernel runs under interpret=True off-TPU, like the
 decode kernel (tests/test_paged_attention.py).
 """
 
@@ -20,6 +19,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import plain_reference
 from ray_tpu.models import gpt
 from ray_tpu.serve.llm import LLMEngine
 
@@ -41,13 +41,17 @@ def _drive(eng, reqs, max_steps=800):
     return [r.out_ids for r in reqs]
 
 
-def _run(params, prompts, *, max_tokens=6, n_slots=4, max_len=128,
-         buckets=(64,), **kw):
-    eng = LLMEngine(CFG, params, n_slots=n_slots, max_len=max_len,
-                    prefill_buckets=buckets, **kw)
+def _run(params, prompts, *, max_tokens=6, n_slots=4, max_len=128, **kw):
+    eng = LLMEngine(CFG, params, n_slots=n_slots, max_len=max_len, **kw)
     out = _drive(eng, [eng.submit(p, max_tokens=max_tokens)
                        for p in prompts])
     return out, eng
+
+
+def _assert_plain(params, prompts, outs, n):
+    """Every stream is the plain forward's greedy continuation of its
+    prompt, `n` tokens long."""
+    plain_reference.assert_gpt_greedy(CFG, params, prompts, outs, n=n)
 
 
 def _ragged_prompts(rng, lengths):
@@ -137,19 +141,19 @@ class TestChunkProgramOnTheFlatPool:
 
 
 class TestExactness:
-    """Chunked == one-shot == dense, token-for-token."""
+    """Chunked == the plain forward, token-for-token."""
 
     @pytest.mark.parametrize("chunk", [32, 64, 128])
-    def test_matches_oneshot_across_chunk_sizes(self, params, chunk):
+    def test_matches_plain_forward_across_chunk_sizes(self, params, chunk):
         prompts = _ragged_prompts(
             np.random.default_rng(0), (3, 17, 33, 50, 7, 40))
-        dense, _ = _run(params, prompts, kv_mode="dense")
-        oneshot, _ = _run(params, prompts, kv_mode="paged", page_size=16)
-        assert oneshot == dense
-        chunked, eng = _run(params, prompts, kv_mode="paged", page_size=16,
+        chunked, eng = _run(params, prompts, page_size=16,
                             prefill_chunk=chunk,
                             prefill_token_budget=chunk)
-        assert chunked == oneshot
+        _assert_plain(params, prompts, chunked, 6)
+        # The default engine (the knob's chunk) emits the same streams.
+        default, _ = _run(params, prompts, page_size=16)
+        assert chunked == default
         m = eng.metrics()
         assert m["kv_pages_free"] == m["kv_pages_total"]
         assert m["prefill_chunks"] > 0
@@ -158,9 +162,9 @@ class TestExactness:
         """The ragged prefill Pallas kernel (interpret mode off-TPU)
         produces the same greedy streams as the gather default."""
         prompts = _ragged_prompts(np.random.default_rng(1), (5, 23, 41))
-        gather, _ = _run(params, prompts, kv_mode="paged", page_size=16,
+        gather, _ = _run(params, prompts, page_size=16,
                          prefill_chunk=16, prefill_token_budget=32)
-        kernel, eng = _run(params, prompts, kv_mode="paged", page_size=16,
+        kernel, eng = _run(params, prompts, page_size=16,
                            prefill_chunk=16, prefill_token_budget=32,
                            attn_impl="kernel")
         assert kernel == gather
@@ -169,15 +173,12 @@ class TestExactness:
     def test_exact_under_preemption(self, params):
         """Pool sized so concurrent slots MUST run dry mid-generation:
         chunked admission + preempt-by-recompute still reproduce the
-        dense engine's streams exactly."""
+        plain forward's streams exactly."""
         prompts = [[5, 9, 2], [17, 3], [2, 4, 6], [8, 1, 0]]
-        dense, _ = _run(params, prompts, kv_mode="dense", max_tokens=10,
-                        max_len=64, buckets=(16,))
-        chunked, eng = _run(params, prompts, kv_mode="paged", page_size=4,
+        chunked, eng = _run(params, prompts, page_size=4,
                             n_pages=7, max_tokens=10, max_len=64,
-                            buckets=(16,), prefill_chunk=4,
-                            prefill_token_budget=8)
-        assert chunked == dense
+                            prefill_chunk=4, prefill_token_budget=8)
+        _assert_plain(params, prompts, chunked, 10)
         m = eng.metrics()
         assert m["preemptions"] > 0
         assert m["kv_pages_free"] == m["kv_pages_total"]
@@ -187,12 +188,10 @@ class TestExactness:
         a long prompt admitted mid-generation grows chunk-by-chunk until
         the pool runs dry, and the decoding slot then needs a page at a
         boundary. The window fitter reclaims from the mid-prefill slot
-        (recompute) instead of truncating the decode — a state one-shot
-        whole-prompt admission could never create."""
+        (recompute) instead of truncating the decode."""
         rng = np.random.default_rng(11)
         longp = list(map(int, rng.integers(1, CFG.vocab_size, 24)))
-        eng = LLMEngine(CFG, params, n_slots=2, max_len=64,
-                        prefill_buckets=(32,), kv_mode="paged", page_size=4,
+        eng = LLMEngine(CFG, params, n_slots=2, max_len=64, page_size=4,
                         n_pages=7, decode_block=1, prefill_chunk=4,
                         prefill_token_budget=4)
         a = eng.submit([5, 9, 2], max_tokens=12)
@@ -200,14 +199,10 @@ class TestExactness:
             eng.step()
         b = eng.submit(longp, max_tokens=2)
         _drive(eng, [a, b])
-        assert not a.truncated and len(a.out_ids) == 12
-        assert not b.truncated and len(b.out_ids) == 2
+        assert not a.truncated and not b.truncated
         assert eng.stats["preemptions"] > 0   # contention actually hit
-        a_ref, _ = _run(params, [[5, 9, 2]], max_tokens=12,
-                        kv_mode="dense", n_slots=2, buckets=(32,))
-        b_ref, _ = _run(params, [longp], max_tokens=2, kv_mode="dense",
-                        n_slots=2, buckets=(32,))
-        assert a.out_ids == a_ref[0] and b.out_ids == b_ref[0]
+        _assert_plain(params, [[5, 9, 2]], [a.out_ids], 12)
+        _assert_plain(params, [longp], [b.out_ids], 2)
         m = eng.metrics()
         assert m["kv_pages_free"] == m["kv_pages_total"]
 
@@ -217,12 +212,7 @@ class TestExactness:
         mid-prefill slot's table row is masked to the null page)."""
         rng = np.random.default_rng(3)
         longp = _ragged_prompts(rng, (40,))[0]
-        a_ref, _ = _run(params, [[5, 9, 2]], max_tokens=20,
-                        kv_mode="dense", n_slots=2)
-        b_ref, _ = _run(params, [longp], max_tokens=8, kv_mode="dense",
-                        n_slots=2)
-        eng = LLMEngine(CFG, params, n_slots=2, max_len=128,
-                        prefill_buckets=(64,), kv_mode="paged", page_size=8,
+        eng = LLMEngine(CFG, params, n_slots=2, max_len=128, page_size=8,
                         prefill_chunk=8, prefill_token_budget=8,
                         decode_block=4)
         ra = eng.submit([5, 9, 2], max_tokens=20)
@@ -231,26 +221,21 @@ class TestExactness:
         assert ra.first_token_at is not None  # A is decoding
         rb = eng.submit(longp, max_tokens=8)  # 5 chunks, interleaved
         _drive(eng, [ra, rb])
-        assert ra.out_ids == a_ref[0]
-        assert rb.out_ids == b_ref[0]
+        _assert_plain(params, [[5, 9, 2]], [ra.out_ids], 20)
+        _assert_plain(params, [longp], [rb.out_ids], 8)
 
-    def test_beyond_bucket_cap(self, params):
-        """Chunked mode is not bucket-bound: a prompt larger than every
-        prefill bucket (one-shot rejects it) is admissible up to the
-        cache cap."""
+    def test_prompt_cap_is_the_cache(self, params):
+        """No bucket bounds a prompt: one longer than any chunk is
+        admissible up to the cache cap (max_len - 1), and one past it is
+        refused at submit."""
         rng = np.random.default_rng(4)
         prompt = _ragged_prompts(rng, (100,))[0]
-        oneshot = LLMEngine(CFG, params, n_slots=2, max_len=256,
-                            prefill_buckets=(64,), kv_mode="paged",
-                            page_size=16)
-        with pytest.raises(ValueError, match="too long"):
-            oneshot.submit(prompt, max_tokens=4)
-        dense_big, _ = _run(params, [prompt], max_tokens=4,
-                            kv_mode="dense", max_len=256, buckets=(128,))
-        chunked, _ = _run(params, [prompt], max_tokens=4, kv_mode="paged",
-                          page_size=16, max_len=256, buckets=(64,),
-                          prefill_chunk=32, prefill_token_budget=64)
-        assert chunked == dense_big
+        chunked, eng = _run(params, [prompt], max_tokens=4, page_size=16,
+                            max_len=256, prefill_chunk=32,
+                            prefill_token_budget=64)
+        _assert_plain(params, [prompt], chunked, 4)
+        with pytest.raises(ValueError, match="too long.*cache bound"):
+            eng.submit(_ragged_prompts(rng, (256,))[0], max_tokens=4)
 
 
 class TestChunkRows:
@@ -273,7 +258,6 @@ class TestChunkRows:
         """The program's height is what ONE budget fills, whatever the
         window's length multiplies the tick's allowance by."""
         eng = LLMEngine(CFG, params, n_slots=n_slots, max_len=256,
-                        prefill_buckets=(64,), kv_mode="paged",
                         page_size=16, n_pages=20, prefill_chunk=chunk,
                         prefill_token_budget=budget,
                         decode_block=decode_block)
@@ -287,7 +271,6 @@ class TestChunkRows:
         """Warm-up's inert ladder and every live dispatch hand the
         program [chunk_rows, chunk] arrays, whatever the batch holds."""
         eng = LLMEngine(CFG, params, n_slots=4, max_len=128,
-                        prefill_buckets=(64,), kv_mode="paged",
                         page_size=16, prefill_chunk=16,
                         prefill_token_budget=budget)
         seen = _spy_chunk_shapes(eng)
@@ -303,14 +286,14 @@ class TestChunkRows:
 
     @pytest.mark.parametrize("case", ["long", "short_together",
                                       "warm_beside_cold"])
-    def test_streams_equal_oneshot_and_parent_rule(self, params, case):
-        """Same tokens for the same requests as the one-shot engine and
+    def test_streams_equal_default_and_parent_rule(self, params, case):
+        """Same tokens for the same requests as the default engine (the
+        knob's chunk and budget), held to the plain forward, and
         as the parent's rule (n_slots rows a dispatch), for a lone long
         prompt, n_slots short prompts at once, and a warm-prefix row
         beside a cold one."""
         rng = np.random.default_rng(13)
-        kw = dict(n_slots=6, max_len=128, prefill_buckets=(128,),
-                  kv_mode="paged", page_size=16)
+        kw = dict(n_slots=6, max_len=128, page_size=16)
         chunked = dict(kw, prefill_chunk=16, prefill_token_budget=32,
                        prefix_cache=(case == "warm_beside_cold"))
         first = []
@@ -330,11 +313,12 @@ class TestChunkRows:
             return _drive(eng, [eng.submit(p, max_tokens=6)
                                 for p in prompts]), eng
 
-        oneshot, _ = serve(LLMEngine(CFG, params, **kw))
+        default, _ = serve(LLMEngine(CFG, params, **kw))
+        _assert_plain(params, prompts, default, 6)
         parent, _ = serve(_parent_rule(LLMEngine(CFG, params, **chunked)))
         out, eng = serve(LLMEngine(CFG, params, **chunked))
         assert eng.chunk_rows == 2
-        assert out == parent == oneshot
+        assert out == parent == default
         if case == "warm_beside_cold":
             assert eng.metrics()["prefix_hits"] > 0
 
@@ -345,8 +329,7 @@ class TestChunkRows:
         step of its window."""
         rng = np.random.default_rng(14)
         budget = 32
-        kw = dict(n_slots=6, max_len=128, prefill_buckets=(64,),
-                  kv_mode="paged", page_size=16, prefill_chunk=16,
+        kw = dict(n_slots=6, max_len=128, page_size=16, prefill_chunk=16,
                   prefill_token_budget=budget, decode_block=1)
         eng = LLMEngine(CFG, params, **kw)
         seen = _spy_chunk_shapes(eng)
@@ -381,8 +364,7 @@ class TestWindowAllowance:
     prompt tokens; the program stays as tall as ONE budget fills and
     runs once per `chunk_rows` rows of one table width."""
 
-    KW = dict(n_slots=8, max_len=128, prefill_buckets=(128,),
-              kv_mode="paged", page_size=8, prefill_chunk=8,
+    KW = dict(n_slots=8, max_len=128, page_size=8, prefill_chunk=8,
               prefill_token_budget=16)
 
     def _beside_decode(self, params, lengths, *, rng, max_tokens=4, **kw):
@@ -492,15 +474,15 @@ class TestWindowAllowance:
 
     @pytest.mark.parametrize("case", ["long", "short_together",
                                       "warm_beside_cold"])
-    def test_streams_equal_oneshot_and_one_budget_a_tick(self, params,
+    def test_streams_equal_default_and_one_budget_a_tick(self, params,
                                                          case):
         """Beside a decoding request, a window's worth of budgets gives
-        the streams of the one-shot engine and of the parent's schedule
+        the streams of the default engine (the knob's chunk and budget),
+        held to the plain forward, and of the parent's schedule
         (one budget a tick: decode_block 1), for a long prompt, six
         short prompts at once, and a warm-prefix row beside a cold
         one: only the order of dispatches changes."""
-        kw = dict(n_slots=8, max_len=128, prefill_buckets=(128,),
-                  kv_mode="paged", page_size=16)
+        kw = dict(n_slots=8, max_len=128, page_size=16)
         chunked = dict(kw, prefill_chunk=16, prefill_token_budget=32,
                        prefix_cache=(case == "warm_beside_cold"))
         rng = np.random.default_rng(24)
@@ -527,12 +509,13 @@ class TestWindowAllowance:
             placed = eng.stats["prefill_tokens"] - pt
             return _drive(eng, reqs + [decoding]), placed
 
-        oneshot, _ = serve(LLMEngine(CFG, params, **kw))
+        default, _ = serve(LLMEngine(CFG, params, **kw))
+        _assert_plain(params, prompts + [[5, 9, 2]], default, None)
         parent, placed_1 = serve(LLMEngine(CFG, params, decode_block=1,
                                            **chunked))
         out, placed_8 = serve(LLMEngine(CFG, params, decode_block=8,
                                         **chunked))
-        assert out == parent == oneshot
+        assert out == parent == default
         assert placed_1 <= 32 < placed_8 <= 32 * 8
 
 
@@ -541,8 +524,7 @@ class TestPoolPressure:
     short of the pages the decoding slots are about to need, so the
     pool stalls prompts instead of preempting them."""
 
-    KW = dict(n_slots=6, max_len=64, prefill_buckets=(32,),
-              kv_mode="paged", page_size=8, n_pages=20, decode_block=4,
+    KW = dict(n_slots=6, max_len=64, page_size=8, n_pages=20, decode_block=4,
               prefill_chunk=8, prefill_token_budget=8)
 
     def _serve(self, params, eng):
@@ -609,7 +591,7 @@ class TestCompileCount:
         prefill_chunk_paged.clear_cache()
         prompts = _ragged_prompts(
             np.random.default_rng(5), (3, 16, 17, 33, 50, 64, 7))
-        chunked, _ = _run(params, prompts, kv_mode="paged", page_size=16,
+        chunked, _ = _run(params, prompts, page_size=16,
                           prefill_chunk=16, prefill_token_budget=32)
         assert prefill_chunk_paged._cache_size() <= 8
 
@@ -621,18 +603,10 @@ class TestCompileCount:
         prefill_chunk_paged.clear_cache()
         prompts = _ragged_prompts(
             np.random.default_rng(5), (3, 16, 17, 33, 50, 64, 7))
-        chunked, _ = _run(params, prompts, kv_mode="paged", page_size=16,
+        chunked, _ = _run(params, prompts, page_size=16,
                           prefill_chunk=16, prefill_token_budget=32,
                           prefill_width_bucketing=False)
         assert prefill_chunk_paged._cache_size() <= 2
-
-    def test_oneshot_stream_unaffected_by_cache_clear(self, params):
-        """Sanity companion: clearing the chunk cache above must not
-        disturb one-shot engines (separate jitted programs)."""
-        prompts = [[5, 9, 2], [17, 3]]
-        a, _ = _run(params, prompts, kv_mode="paged", page_size=16)
-        b, _ = _run(params, prompts, kv_mode="dense")
-        assert a == b
 
 
 class TestScheduler:
@@ -642,7 +616,7 @@ class TestScheduler:
         rng = np.random.default_rng(6)
         longp = _ragged_prompts(rng, (40,))[0]
         eng = LLMEngine(CFG, params, n_slots=2, max_len=128,
-                        prefill_buckets=(64,), kv_mode="paged", page_size=8,
+                        page_size=8,
                         prefill_chunk=8, prefill_token_budget=0,
                         decode_block=1)
         ra = eng.submit([5, 9, 2], max_tokens=30)
@@ -670,7 +644,7 @@ class TestScheduler:
         rng = np.random.default_rng(7)
         budget, chunk = 16, 8
         eng = LLMEngine(CFG, params, n_slots=6, max_len=128,
-                        prefill_buckets=(64,), kv_mode="paged", page_size=8,
+                        page_size=8,
                         prefill_chunk=chunk, prefill_token_budget=budget,
                         decode_block=decode_block)
         reqs = [eng.submit(p, max_tokens=12)
@@ -700,26 +674,23 @@ class TestScheduler:
         assert eng.metrics()["prefill_allowance_used"] == 0
 
     def test_bad_configs_rejected(self, params):
-        with pytest.raises(ValueError, match="paged"):
-            LLMEngine(CFG, params, n_slots=2, max_len=64,
-                      kv_mode="dense", prefill_chunk=16)
         with pytest.raises(ValueError, match="prefill_token_budget"):
-            LLMEngine(CFG, params, n_slots=2, max_len=64, kv_mode="paged",
-                      prefill_chunk=16, prefill_token_budget=8)
+            LLMEngine(CFG, params, n_slots=2, max_len=64, prefill_chunk=16,
+                      prefill_token_budget=8)
         # Negative budget would silently behave like 0 (pure-decode ticks)
         # — must be rejected, not accepted as "unlimited".
         with pytest.raises(ValueError, match="prefill_token_budget"):
-            LLMEngine(CFG, params, n_slots=2, max_len=64, kv_mode="paged",
-                      prefill_chunk=16, prefill_token_budget=-1)
+            LLMEngine(CFG, params, n_slots=2, max_len=64, prefill_chunk=16,
+                      prefill_token_budget=-1)
         # A chunk wider than the widest admissible prompt (max_len - 1)
         # would only ever pad — rejected like the other bad knobs.
         with pytest.raises(ValueError, match="prefill_chunk"):
-            LLMEngine(CFG, params, n_slots=2, max_len=64, kv_mode="paged",
-                      prefill_chunk=128, prefill_token_budget=128)
-        # Empty prompt: chunked mode would never build a chunk row and
-        # wedge the slot forever; rejected up front in both modes.
+            LLMEngine(CFG, params, n_slots=2, max_len=64, prefill_chunk=128,
+                      prefill_token_budget=128)
+        # Empty prompt: it would never build a chunk row and wedge the
+        # slot forever; rejected up front.
         eng = LLMEngine(CFG, params, n_slots=2, max_len=64,
-                        kv_mode="paged", page_size=16,
+                        page_size=16,
                         prefill_chunk=16, prefill_token_budget=16)
         with pytest.raises(ValueError, match="non-empty"):
             eng.submit([], max_tokens=4)
@@ -733,7 +704,7 @@ class TestAdmissionLookahead:
         rng = np.random.default_rng(8)
         # Pool of 6 pages (ps=4). R1 occupies a slot and decodes slowly.
         eng = LLMEngine(CFG, params, n_slots=2, max_len=64,
-                        prefill_buckets=(32,), kv_mode="paged", page_size=4,
+                        page_size=4,
                         n_pages=6, decode_block=1)
         r1 = eng.submit([5, 9, 2], max_tokens=24)
         while r1.first_token_at is None:
@@ -756,7 +727,7 @@ class TestAdmissionLookahead:
         its FIRST CHUNK of pool headroom)."""
         rng = np.random.default_rng(9)
         eng = LLMEngine(CFG, params, n_slots=2, max_len=64,
-                        prefill_buckets=(32,), kv_mode="paged", page_size=4,
+                        page_size=4,
                         n_pages=7, decode_block=1, prefill_chunk=20,
                         prefill_token_budget=20)
         r1 = eng.submit([5, 9, 2], max_tokens=24)
@@ -780,7 +751,7 @@ class TestObservability:
         from ray_tpu.serve.llm import _PREFILL_CHUNK_HIST
 
         prompts = _ragged_prompts(np.random.default_rng(10), (33, 17))
-        _, eng = _run(params, prompts, kv_mode="paged", page_size=16,
+        _, eng = _run(params, prompts, page_size=16,
                       prefill_chunk=16, prefill_token_budget=32)
         m = eng.metrics()
         assert m["prefill_chunk"] == 16
@@ -804,7 +775,6 @@ class TestObservability:
         chunks at chunk_rows 1, less with a padded tail; zeroed by
         reset_stats() with the stats it reads."""
         eng = LLMEngine(CFG, params, n_slots=2, max_len=128,
-                        prefill_buckets=(64,), kv_mode="paged",
                         page_size=16, prefill_chunk=16,
                         prefill_token_budget=16)
         assert eng.chunk_rows == 1
@@ -819,9 +789,8 @@ class TestObservability:
         """At chunk_rows 2 a lone chunk in its width bucket pads with an
         inert row: a 32-token prompt's chunks sit at widths 1 and 2, two
         dispatches of 2 x 16 positions for 32 tokens."""
-        _, eng = _run(params, [list(range(1, 33))], kv_mode="paged",
-                      page_size=16, prefill_chunk=16,
-                      prefill_token_budget=32)
+        _, eng = _run(params, [list(range(1, 33))], page_size=16,
+                      prefill_chunk=16, prefill_token_budget=32)
         m = eng.metrics()
         assert m["chunk_rows"] == 2 and m["prefill_dispatches"] == 2
         assert m["prefill_row_fill"] == pytest.approx(0.5)
@@ -837,9 +806,8 @@ class TestObservability:
         cfg = gpt.GPTConfig.tiny(attn_impl="xla", dtype=jnp.float32,
                                  max_seq=2048)
         eng = LLMEngine(cfg, gpt.init_params(cfg, jax.random.key(1)),
-                        n_slots=2, max_len=2048, kv_mode="paged",
-                        page_size=64, n_pages=40, prefill_chunk=128,
-                        prefill_token_budget=256)
+                        n_slots=2, max_len=2048, page_size=64, n_pages=40,
+                        prefill_chunk=128, prefill_token_budget=256)
         assert eng.chunk_rows == 2
         assert eng.metrics()["prefill_block_fill"] == 0
         rng = np.random.default_rng(3)
@@ -866,9 +834,8 @@ class TestObservability:
         cfg = gpt.GPTConfig.tiny(attn_impl="xla", dtype=jnp.float32,
                                  max_seq=2048)
         eng = LLMEngine(cfg, gpt.init_params(cfg, jax.random.key(1)),
-                        n_slots=2, max_len=2048, kv_mode="paged",
-                        page_size=64, n_pages=40, prefill_chunk=128,
-                        prefill_token_budget=256)
+                        n_slots=2, max_len=2048, page_size=64, n_pages=40,
+                        prefill_chunk=128, prefill_token_budget=256)
         lanes = cfg.n_heads * cfg.head_dim
         assert [decode_block_pages(w, 64, lanes, 4, cfg.n_heads)
                 for w in (1, 2, 4)] == [1, 2, 4]
@@ -896,9 +863,8 @@ class TestObservability:
         cfg = gpt.GPTConfig.tiny(attn_impl="xla", dtype=jnp.float32,
                                  max_seq=2048)
         eng = LLMEngine(cfg, gpt.init_params(cfg, jax.random.key(1)),
-                        n_slots=2, max_len=2048, kv_mode="paged",
-                        page_size=64, n_pages=40, prefill_chunk=128,
-                        prefill_token_budget=256)
+                        n_slots=2, max_len=2048, page_size=64, n_pages=40,
+                        prefill_chunk=128, prefill_token_budget=256)
         assert eng.metrics()["decode_live_column_share"] == 0
         rng = np.random.default_rng(3)
         for n_prompt, live, width in ((100, 2, 2), (130, 3, 4)):
@@ -914,7 +880,6 @@ class TestObservability:
 
     def test_request_chunk_timestamps(self, params):
         eng = LLMEngine(CFG, params, n_slots=2, max_len=128,
-                        prefill_buckets=(64,), kv_mode="paged",
                         page_size=16, prefill_chunk=16,
                         prefill_token_budget=16)
         req = eng.submit(list(range(1, 34)), max_tokens=3)  # 3 chunks

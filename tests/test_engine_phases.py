@@ -35,13 +35,12 @@ DRAFT_CFG = gpt.GPTConfig.tiny(attn_impl="xla", dtype=jnp.float32,
                                n_layers=1, d_model=32, n_heads=4, d_ff=64)
 
 ENGINES = {
-    "paged-chunked": dict(kv_mode="paged", page_size=16, prefill_chunk=16,
+    "paged-chunked": dict(page_size=16, prefill_chunk=16,
                           prefill_token_budget=32, attn_impl="kernel"),
-    "paged-oneshot": dict(kv_mode="paged", page_size=16),
-    "dense": dict(kv_mode="dense"),
-    "single-step": dict(kv_mode="paged", page_size=16, prefill_chunk=16,
+    "paged-default": dict(page_size=16),      # the knobs' chunk and budget
+    "single-step": dict(page_size=16, prefill_chunk=16,
                         prefill_token_budget=32, decode_block=1),
-    "speculative": dict(kv_mode="paged", page_size=16, prefill_chunk=16,
+    "speculative": dict(page_size=16, prefill_chunk=16,
                         prefill_token_budget=32, spec_draft=DRAFT_CFG,
                         spec_k=4),
 }
@@ -60,7 +59,6 @@ def draft_params():
 def _engine(params, draft_params=None, **kw):
     kw.setdefault("n_slots", 4)
     kw.setdefault("max_len", 128)
-    kw.setdefault("prefill_buckets", (64,))
     if "spec_draft" in kw:
         kw["spec_draft_params"] = draft_params
     return LLMEngine(CFG, params, **kw)
@@ -193,7 +191,7 @@ def test_backlog_counter_sees_slots_waiting_for_the_prefill_budget(params):
     step, so an idle tick's allowance is one budget too): all four are
     admitted into slots at once and wait there, which `queued` does not
     show."""
-    eng = _engine(params, kv_mode="paged", page_size=16, prefill_chunk=16,
+    eng = _engine(params, page_size=16, prefill_chunk=16,
                   prefill_token_budget=16, decode_block=1)
     reqs = [eng.submit(p, max_tokens=4)
             for p in _prompts(5, (40, 40, 40, 40, 40))]
@@ -418,7 +416,7 @@ def test_reset_stats_zeroes_the_extremes(params):
 def test_reset_stats_starts_a_live_requests_wait_over(params):
     """What a request in a slot waited BEFORE the reset (a ramp's stall)
     is not the window's: its longest wait starts over with the account."""
-    eng = _engine(params, kv_mode="dense", decode_block=4)
+    eng = _engine(params, **ENGINES["paged-default"], decode_block=4)
     _drive(eng, [eng.submit(p, max_tokens=21)
                  for p in _prompts(20, (9, 9))])          # compiles
     reqs = [eng.submit(p, max_tokens=21) for p in _prompts(21, (9, 9))]
@@ -524,7 +522,7 @@ def test_heartbeat_thread_runs_from_start_to_stop(params):
     beating = lambda: [t for t in threading.enumerate()
                        if t.name == "llm-heartbeat" and t.is_alive()]
     before = len(beating())
-    eng = _engine(params, **ENGINES["paged-oneshot"], warmup=False)
+    eng = _engine(params, **ENGINES["paged-default"], warmup=False)
     assert len(beating()) == before             # not before start()
     eng.start()
     try:
@@ -549,8 +547,12 @@ def test_heartbeat_thread_runs_from_start_to_stop(params):
 def test_a_request_knows_its_longest_wait_once_a_window(params):
     """Three requests decode side by side; one sits one window out (its
     row stood down). Its longest wait is two ticks, its neighbours' one,
-    and the gap is touched once a request a window, not once a token."""
-    eng = _engine(params, kv_mode="dense", decode_block=4)
+    and the gap is touched once a request a window, not once a token.
+    (No step is left in flight here: a row cannot sit out a window whose
+    first row the device has already computed for it.)"""
+    eng = _engine(params, **ENGINES["paged-default"], decode_block=4)
+    fit = eng._fit_window_pages
+    eng._fit_window_pages = lambda active, k: (*fit(active, k)[:2], "budget")
     _drive(eng, [eng.submit(p, max_tokens=25)
                  for p in _prompts(10, (9, 9, 9))])       # compiles
     eng.reset_stats()

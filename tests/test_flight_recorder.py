@@ -127,7 +127,7 @@ class TestRecompileStorm:
         # n_slots=5 keeps the program shapes unique to this test so jit
         # caches from other tests can't swallow the recompiles.
         engine = LLMEngine(CFG, params, n_slots=5, max_len=64,
-                           kv_mode="paged", page_size=2, n_pages=40)
+                           page_size=2, n_pages=40)
         before = compile_watch.compiles_total("decode_multi_paged")
         _, latest = state.list_cluster_events(return_latest_seq=True)
         _drive(engine, [engine.submit([5, 9, 2], max_tokens=58)])
@@ -165,7 +165,7 @@ class TestLoadSnapshot:
         """Mid-burst and at drain, load_snapshot() must agree with the
         scheduler's own bookkeeping — these numbers feed the router."""
         engine = LLMEngine(CFG, params, n_slots=4, max_len=64,
-                           kv_mode="paged", page_size=4, n_pages=24,
+                           page_size=4, n_pages=24,
                            prefill_chunk=8, prefill_token_budget=8)
         reqs = [engine.submit(list(range(2, 18)), max_tokens=4)
                 for _ in range(6)]
@@ -199,7 +199,7 @@ class TestLoadSnapshot:
 
     def test_snapshot_sets_gauges(self, params):
         engine = LLMEngine(CFG, params, n_slots=2, max_len=32,
-                           kv_mode="paged", page_size=4, n_pages=16)
+                           page_size=4, n_pages=16)
         engine.load_snapshot()
         rows = {r["name"]: r for r in profiling.metrics_snapshot()
                 if r["name"].startswith("llm_")}
@@ -210,12 +210,14 @@ class TestLoadSnapshot:
             assert rows[name]["tags"]["replica"] == "local"
         assert rows["llm_pool_pages_total"]["value"] == 16.0
 
-    def test_dense_engine_snapshot_has_no_pool_fields(self, params):
+    def test_default_engine_snapshot_has_the_pool_fields(self, params):
+        """An engine built with no option has a pool: half the slots'
+        worst case, and its snapshot and gauges say so."""
         engine = LLMEngine(CFG, params, n_slots=2, max_len=32,
-                           prefill_buckets=(8,))
+                           page_size=8)
         snap = engine.load_snapshot()
-        assert "pool_pages_total" not in snap
-        assert snap["active_slots"] == 0
+        assert snap["pool_pages_total"] == snap["pool_pages_free"] == 5
+        assert snap["prefill_chunk"] == 32 and snap["active_slots"] == 0
 
 
 def _hist_rows(name: str, buckets, boundaries=(0.5, 2.0)):

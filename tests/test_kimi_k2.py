@@ -316,7 +316,7 @@ def test_a_prompt_split_anywhere_gives_the_unsplit_logits(params, cut):
 
 
 def _engine(params, **kw):
-    opts = dict(n_slots=N_SLOTS, max_len=128, kv_mode="paged", page_size=PAGE,
+    opts = dict(n_slots=N_SLOTS, max_len=128, page_size=PAGE,
                 n_pages=N_PAGES, prefill_chunk=CHUNK, attn_impl="gather",
                 prefill_token_budget=ROWS * CHUNK)
     return LLMEngine(CFG, params, **{**opts, **kw})
@@ -395,8 +395,8 @@ def test_rows_over_counts_what_a_first_block_did_not_take(router):
     if router == "onto_held":       # s is a sigmoid: 2 outbids any score
         params["router_bias"] = params["router_bias"].at[
             :, :cfg.n_experts].add(2.0)
-    eng = LLMEngine(cfg, params, n_slots=slots, max_len=64, kv_mode="paged",
-                    page_size=PAGE, n_pages=4 * slots, prefill_chunk=CHUNK,
+    eng = LLMEngine(cfg, params, n_slots=slots, max_len=64, page_size=PAGE,
+                    n_pages=4 * slots, prefill_chunk=CHUNK,
                     attn_impl="gather", prefill_token_budget=4 * CHUNK)
     rng = np.random.default_rng(3)
     reqs = [eng.submit(rng.integers(1, cfg.vocab_size, 5).tolist(),
@@ -440,8 +440,6 @@ REFUSED = [
     ("tp", 2, "no rule for a row without heads"),
     ("weight_dtype", "int8", "no int8 form"),
     ("kv_dtype", "int8", "one-plane writer"),
-    ("kv_mode", "dense", "one latent row a token"),
-    ("prefill_chunk", 0, "whole-prompt program"),
     ("prefill_width_bucketing", True, "packs rows of several widths"),
 ]
 

@@ -51,7 +51,7 @@ def params():
 
 
 def _engine(params, **kw):
-    base = dict(n_slots=4, max_len=256, kv_mode="paged", page_size=PAGE,
+    base = dict(n_slots=4, max_len=256, page_size=PAGE,
                 prefill_chunk=CHUNK, prefill_token_budget=64,
                 decode_block=4)
     base.update(kw)
@@ -832,12 +832,17 @@ class TestWarmDiscovery:
 
 
 class TestKnobValidation:
-    def test_kv_transfer_explicit_requires_paged_chunked(self, params):
+    def test_kv_transfer_fits_the_knobs_chunk(self, params):
+        """The transfer asks for nothing but page-aligned chunks, and
+        the chunk the knob resolves to beside a short cache is whole
+        pages: an engine given `kv_transfer` and no chunk builds. Where
+        one page is longer than the cache no chunk can be aligned."""
+        eng = LLMEngine(CFG, params, max_len=100, page_size=16,
+                        kv_transfer=True, kv_store=LocalKVStore(budget=4))
+        assert eng.kv_transfer and eng.prefill_chunk == 96
         with pytest.raises(ValueError, match="page-set transfer"):
-            LLMEngine(CFG, params, kv_mode="dense", kv_transfer=True)
-        with pytest.raises(ValueError, match="page-set transfer"):
-            _engine(params, prefill_chunk=0, kv_transfer=True,
-                    prefill_token_budget=0)
+            LLMEngine(CFG, params, max_len=48, page_size=64,
+                      kv_transfer=True)
 
     def test_kv_transfer_requires_page_aligned_chunks(self, params):
         """chunk % page_size == 0 is load-bearing: cross-donation dedup
@@ -891,17 +896,13 @@ class TestKnobValidation:
             _engine(params, pool_role="both")
         with pytest.raises(ValueError, match="requires kv_transfer"):
             _engine(params, pool_role="prefill", kv_transfer=False)
-        with pytest.raises(ValueError, match="page-set transfer"):
-            LLMEngine(CFG, params, kv_mode="dense", pool_role="prefill")
 
-    def test_global_knob_soft_disables(self, params, monkeypatch):
+    def test_global_knob_applies(self, params, monkeypatch):
         monkeypatch.setenv("RAY_TPU_LLM_KV_TRANSFER", "1")
         from ray_tpu.core import config as _config
 
         monkeypatch.setattr(_config, "GLOBAL_CONFIG",
                             _config.Config.from_env())
-        dense = LLMEngine(CFG, params, kv_mode="dense")
-        assert dense.kv_transfer is False
         paged = _engine(params)
         assert paged.kv_transfer is True
         assert paged._kv_store is not None
@@ -912,8 +913,7 @@ class TestKnobValidation:
         with pytest.raises(ValueError, match="pool_peer"):
             LLMDeployment("tiny", n_slots=2, max_len=64,
                           pool_role="prefill",
-                          engine_kwargs={"kv_mode": "paged",
-                                         "page_size": 16,
+                          engine_kwargs={"page_size": 16,
                                          "prefill_chunk": 16})
 
 
@@ -971,7 +971,7 @@ class TestClusterPoolSplit:
     N_SLOTS = 4
     MAX_LEN = 256
     MAX_TOKENS = 16
-    ENGINE_KW = {"kv_mode": "paged", "page_size": 16,
+    ENGINE_KW = {"page_size": 16,
                  "prefill_chunk": 16, "prefill_token_budget": 64,
                  "decode_block": 4}
 

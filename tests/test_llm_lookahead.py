@@ -23,13 +23,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import plain_reference
+from plain_reference import ATOL
 from ray_tpu.models import (gpt, jamba, kimi_k2, laguna, mimo_v2,
                             nemotron_h, olmo_hybrid, paged_kv, qwen3_next,
                             zaya)
 from ray_tpu.serve import llm
 from ray_tpu.serve.llm import LLMEngine
 
-ATOL = 3e-5
 PAGE, CHUNK = 16, 16
 
 
@@ -50,7 +51,7 @@ def _fams() -> dict:
         "gpt": Fam(
             dataclasses.replace(gpt.GPTConfig.by_name("tiny"),
                                 dtype=jnp.float32),
-            gpt.init_params, lambda c, p, t: gpt.forward(p, t, c)),
+            gpt.init_params, plain_reference.gpt_forward),
         "zaya": Fam(zaya.ZayaConfig.tiny(dtype=jnp.float32),
                     zaya.init_params, zaya.forward),
         "laguna": Fam(laguna.LagunaConfig.tiny(dtype=jnp.float32),
@@ -81,18 +82,12 @@ def _fams() -> dict:
 
 def _serve(name):
     """(Fam, params): seeded weights with every matrix but the embedding
-    8x its initial size and moved off it, so that no projection is zero,
-    the mixers and experts all move the logits, and a greedy continuation
-    does not settle on one token (at the initial size every family but
-    one repeats a single token: a dropped step would not show)."""
+    8x its initial size and moved off it (`plain_reference.lively`), so
+    that the mixers and experts all move the logits and a greedy
+    continuation does not settle on one token."""
     fam = _fams()[name]
-    p = fam.init(fam.cfg, jax.random.key(0))
-    keys = jax.random.split(jax.random.key(1), len(p))
-    return fam, {
-        n: (8.0 * v + 0.02 * jax.random.normal(k, v.shape, v.dtype)
-            if v.ndim >= 2 and not n.startswith(("wte", "embed") + fam.as_is)
-            and jnp.issubdtype(v.dtype, jnp.floating) else v)
-        for k, (n, v) in zip(keys, sorted(p.items()))}
+    return fam, plain_reference.lively(
+        fam.init(fam.cfg, jax.random.key(0)), as_is=fam.as_is)
 
 
 def _drop_programs():
@@ -124,7 +119,7 @@ def zaya_served():
 
 
 def _engine(fam: Fam, params, **kw):
-    opts = dict(n_slots=3, max_len=128, kv_mode="paged", page_size=PAGE,
+    opts = dict(n_slots=3, max_len=128, page_size=PAGE,
                 n_pages=24, prefill_chunk=CHUNK, attn_impl="gather",
                 prefill_token_budget=2 * CHUNK, decode_block=8)
     return LLMEngine(fam.cfg, params, **{**opts, **kw})
@@ -145,13 +140,9 @@ def _run(eng, reqs, ticks=900):
 
 def _deficits(fam: Fam, params, r, out_ids=None):
     """How far under the plain forward's best logit each emitted token
-    lies, at its position (teacher-forced over the whole sequence)."""
-    out_ids = list(r.out_ids if out_ids is None else out_ids)
-    seq = np.asarray(list(r.prompt_ids[:r.n_prompt]) + out_ids, np.int32)
-    with jax.default_matmul_precision("highest"):
-        rows = np.asarray(fam.forward(fam.cfg, params, jnp.asarray(seq[None]))
-                          )[0, r.n_prompt - 1:len(seq) - 1]
-    return rows.max(axis=1) - rows[np.arange(len(out_ids)), out_ids]
+    lies, at its position (tests/plain_reference.py)."""
+    return plain_reference.request_deficits(fam.forward, fam.cfg, params, r,
+                                            out_ids)
 
 
 def _greedy(fam: Fam, params, prompt, n):
@@ -595,13 +586,14 @@ def test_draw_share_counts_the_windows_that_were_handed_a_temperature(
     assert m["decode_windows_drawn"] == 0 and m["decode_draw_share"] == 0.0
 
 
-@pytest.mark.parametrize("kind", ["speculative", "dense"])
-def test_other_engines_never_leave_a_step_in_flight(gpt_served, kind):
+@pytest.mark.parametrize("kind", ["speculative", "one-step"])
+def test_other_ticks_never_leave_a_step_in_flight(gpt_served, kind):
+    """A speculative tick and the one-step tick (a window of one row,
+    sampled on the host) read everything they dispatch."""
     fam, params = gpt_served
     eng = (_engine(fam, params, spec_draft=fam.cfg, spec_k=2,
                    spec_draft_params=params) if kind == "speculative" else
-           LLMEngine(fam.cfg, params, n_slots=2, max_len=64,
-                     kv_mode="dense", decode_block=8))
+           _engine(fam, params, decode_block=1))
     r = eng.submit(_prompt(8), max_tokens=20)
     with jax.default_matmul_precision("highest"):
         for _ in range(200):

@@ -1,8 +1,10 @@
 """LLM decode path + continuous-batching engine + Serve integration.
 
 Covers BASELINE config 5 (continuous-batched text generation) at test
-scale: KV-cache decode equivalence against the full-forward oracle,
-mid-flight request admission, streaming, and an LLMDeployment behind Serve.
+scale: the paged chunk and step programs against the full-forward oracle,
+engine token streams held to the plain reference
+(tests/plain_reference.py), mid-flight request admission, streaming, and
+an LLMDeployment behind Serve.
 """
 
 import threading
@@ -14,11 +16,12 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import plain_reference
 from ray_tpu.models import gpt
-from ray_tpu.models.decode import (
-    decode_step,
-    init_kv_cache,
-    prefill,
+from ray_tpu.models.paged_kv import (
+    decode_step_paged,
+    init_paged_kv,
+    prefill_chunk_paged,
     sample_token,
 )
 from ray_tpu.serve.llm import LLMEngine
@@ -31,45 +34,60 @@ def params():
     return gpt.init_params(CFG, jax.random.key(42))
 
 
-class TestDecodePath:
-    def test_decode_logits_match_full_forward(self, params):
-        """Prefill+decode logits equal full-forward logits position by
-        position (same math, cache path vs no-cache path)."""
-        prompt = [5, 9, 2, 7, 11]
-        n = len(prompt)
-        cache = init_kv_cache(CFG, n_slots=3, max_len=64)
-        padded = np.zeros((1, 8), np.int32)
-        padded[0, :n] = prompt
-        last, cache = prefill(CFG, params, jnp.asarray(padded), cache,
-                              jnp.int32(1), jnp.int32(n))
-        full = gpt.forward(params, jnp.asarray([prompt]), CFG)
-        np.testing.assert_allclose(
-            np.asarray(last), np.asarray(full[0, -1]), rtol=2e-4, atol=2e-4)
+@pytest.fixture(scope="module")
+def lively_params(params):
+    """Weights whose greedy continuation does not settle on one token."""
+    return plain_reference.lively(params)
 
-        # Decode 4 more tokens; compare logits against growing full forward.
-        seq = list(prompt)
-        tokens = np.zeros(3, np.int32)
-        positions = np.zeros(3, np.int32)
-        tok = int(np.argmax(np.asarray(last)))
-        for _ in range(4):
-            seq.append(tok)
-            tokens[1] = tok
-            positions[1] = len(seq) - 1
-            logits, cache = decode_step(
-                CFG, params, jnp.asarray(tokens), cache,
-                jnp.asarray(positions))
-            full = gpt.forward(params, jnp.asarray([seq]), CFG)
-            np.testing.assert_allclose(
-                np.asarray(logits[1]), np.asarray(full[0, -1]),
-                rtol=2e-4, atol=2e-4)
-            tok = int(np.argmax(np.asarray(logits[1])))
+
+def _paged_decode_vs_forward(cfg, params, prompt, *, slot, n_slots, steps):
+    """One chunk row writes `prompt` into `slot`'s pages, then `steps`
+    single-token steps advance ALL slots (the others idle on the null
+    page): at every position the slot's logits are the full forward's."""
+    ps, n_pg = 4, 8
+    pool = init_paged_kv(cfg, n_slots * n_pg, ps)
+    tables = np.zeros((n_slots, n_pg), np.int32)
+    tables[slot] = 1 + slot * n_pg + np.arange(n_pg)
+    row = np.zeros((1, 8), np.int32)
+    row[0, :len(prompt)] = prompt
+    last, pool = prefill_chunk_paged(
+        cfg, params, jnp.asarray(row), pool,
+        jnp.asarray(tables[slot:slot + 1]), jnp.zeros(1, jnp.int32),
+        jnp.asarray([len(prompt)], jnp.int32))
+    full = gpt.forward(params, jnp.asarray([prompt]), cfg)
+    np.testing.assert_allclose(np.asarray(last[0]), np.asarray(full[0, -1]),
+                               rtol=2e-4, atol=2e-4)
+    seq = list(prompt)
+    tokens = np.zeros(n_slots, np.int32)
+    positions = np.zeros(n_slots, np.int32)
+    tok = int(np.argmax(np.asarray(last[0])))
+    for _ in range(steps):
+        seq.append(tok)
+        tokens[slot] = tok
+        positions[slot] = len(seq) - 1
+        logits, pool = decode_step_paged(
+            cfg, params, jnp.asarray(tokens), pool, jnp.asarray(positions),
+            jnp.asarray(tables))
+        full = gpt.forward(params, jnp.asarray([seq]), cfg)
+        np.testing.assert_allclose(
+            np.asarray(logits[slot]), np.asarray(full[0, -1]),
+            rtol=2e-4, atol=2e-4)
+        tok = int(np.argmax(np.asarray(logits[slot])))
+
+
+class TestDecodePath:
+    def test_paged_step_logits_match_full_forward(self, params):
+        """Chunk + step logits equal full-forward logits position by
+        position (same math, page path vs no-cache path), for the middle
+        slot of three."""
+        _paged_decode_vs_forward(CFG, params, [5, 9, 2, 7, 11], slot=1,
+                                 n_slots=3, steps=4)
 
     def test_slots_are_independent(self, params):
         """Two prompts decoded in adjacent slots give the same results as
         each decoded alone."""
         def run_alone(prompt, steps):
-            eng = LLMEngine(CFG, params, n_slots=1, max_len=64,
-                            prefill_buckets=(8,))
+            eng = LLMEngine(CFG, params, n_slots=1, max_len=64)
             req = eng.submit(prompt, max_tokens=steps)
             while not req.done.is_set():
                 eng.step()
@@ -78,8 +96,7 @@ class TestDecodePath:
         a_alone = run_alone([5, 9, 2], 5)
         b_alone = run_alone([17, 3], 5)
 
-        eng = LLMEngine(CFG, params, n_slots=2, max_len=64,
-                        prefill_buckets=(8,))
+        eng = LLMEngine(CFG, params, n_slots=2, max_len=64)
         ra = eng.submit([5, 9, 2], max_tokens=5)
         rb = eng.submit([17, 3], max_tokens=5)
         while not (ra.done.is_set() and rb.done.is_set()):
@@ -87,44 +104,41 @@ class TestDecodePath:
         assert ra.out_ids == a_alone
         assert rb.out_ids == b_alone
 
-    def test_prefill_batch_matches_sequential(self, params):
-        """One batched multi-slot prefill produces the same last-token
-        logits and KV cache as N sequential single-slot prefills."""
-        from ray_tpu.models.decode import prefill_batch
-
+    def test_chunk_rows_match_sequential(self, params):
+        """One dispatch of three chunk rows (three prompts, three slots)
+        produces the same last-token logits and pool as three dispatches
+        of one row each."""
         rng = np.random.default_rng(5)
         prompts = [list(rng.integers(0, CFG.vocab_size, n))
                    for n in (3, 7, 5)]
-        bucket = 8
-        padded = np.zeros((3, bucket), np.int32)
+        chunk, ps = 8, 4
+        padded = np.zeros((3, chunk), np.int32)
         lengths = np.array([len(p) for p in prompts], np.int32)
         for i, p in enumerate(prompts):
             padded[i, :len(p)] = p
+        tables = 1 + np.arange(6, dtype=np.int32).reshape(3, 2)
+        zeros = jnp.zeros(3, jnp.int32)
 
-        seq_cache = init_kv_cache(CFG, 4, 32)
+        seq_pool = init_paged_kv(CFG, 8, ps)
         seq_logits = []
-        for i, p in enumerate(prompts):
-            row = np.zeros((1, bucket), np.int32)
-            row[0, :len(p)] = p
-            last, seq_cache = prefill(
-                CFG, params, jnp.asarray(row), seq_cache,
-                jnp.int32(i + 1), jnp.int32(len(p)))
-            seq_logits.append(np.asarray(last))
+        for i in range(3):
+            last, seq_pool = prefill_chunk_paged(
+                CFG, params, jnp.asarray(padded[i:i + 1]), seq_pool,
+                jnp.asarray(tables[i:i + 1]), zeros[:1],
+                jnp.asarray(lengths[i:i + 1]))
+            seq_logits.append(np.asarray(last[0]))
 
-        bat_cache = init_kv_cache(CFG, 4, 32)
-        bat_logits, bat_cache = prefill_batch(
-            CFG, params, jnp.asarray(padded), bat_cache,
-            jnp.asarray(np.array([1, 2, 3], np.int32)),
-            jnp.asarray(lengths))
+        bat_logits, bat_pool = prefill_chunk_paged(
+            CFG, params, jnp.asarray(padded), init_paged_kv(CFG, 8, ps),
+            jnp.asarray(tables), zeros, jnp.asarray(lengths))
         np.testing.assert_allclose(
             np.asarray(bat_logits), np.stack(seq_logits), rtol=2e-4,
             atol=2e-4)
-        np.testing.assert_allclose(
-            np.asarray(bat_cache["k"]), np.asarray(seq_cache["k"]),
-            rtol=2e-4, atol=2e-4)
-        np.testing.assert_allclose(
-            np.asarray(bat_cache["v"]), np.asarray(seq_cache["v"]),
-            rtol=2e-4, atol=2e-4)
+        for plane in ("k", "v"):
+            # (page 0 is the null page: every row's padding lands there)
+            np.testing.assert_allclose(
+                np.asarray(bat_pool[plane][:, 1:]),
+                np.asarray(seq_pool[plane][:, 1:]), rtol=2e-4, atol=2e-4)
 
     def test_sample_token_temperature(self):
         logits = jnp.asarray([0.0, 10.0, 0.0, 0.0])
@@ -146,29 +160,20 @@ class TestModelRegistry:
         with pytest.raises(KeyError):
             gpt.GPTConfig.by_name("nope")
 
-    def test_untied_decode_matches_forward(self):
-        """gptj/opt-style untied head through the cache path."""
+    def test_untied_paged_decode_matches_forward(self):
+        """gptj/opt-style untied head through the paged chunk and step."""
         cfg = gpt.GPTConfig.by_name("tiny_untied", dtype=jnp.float32)
         params = gpt.init_params(cfg, jax.random.key(7))
-        prompt = [3, 14, 15, 9]
-        cache = init_kv_cache(cfg, 2, 32)
-        pad = np.zeros((1, 8), np.int32)
-        pad[0, :4] = prompt
-        last, cache = prefill(cfg, params, jnp.asarray(pad), cache,
-                              jnp.int32(0), jnp.int32(4))
-        full = gpt.forward(params, jnp.asarray([prompt]), cfg)
-        np.testing.assert_allclose(np.asarray(last), np.asarray(full[0, -1]),
-                                   rtol=2e-4, atol=2e-4)
+        _paged_decode_vs_forward(cfg, params, [3, 14, 15, 9], slot=0,
+                                 n_slots=2, steps=3)
 
 
 class TestContinuousBatching:
     def test_midflight_admission(self, params):
         """A request submitted while another is decoding joins without
         perturbing the first request's output."""
-        eng = LLMEngine(CFG, params, n_slots=2, max_len=64,
-                        prefill_buckets=(8,))
-        solo = LLMEngine(CFG, params, n_slots=2, max_len=64,
-                         prefill_buckets=(8,))
+        eng = LLMEngine(CFG, params, n_slots=2, max_len=64)
+        solo = LLMEngine(CFG, params, n_slots=2, max_len=64)
         r_solo = solo.submit([5, 9, 2], max_tokens=8)
         while not r_solo.done.is_set():
             solo.step()
@@ -185,8 +190,7 @@ class TestContinuousBatching:
         assert m["completed"] == 2 and m["tokens_generated"] == 12
 
     def test_more_requests_than_slots(self, params):
-        eng = LLMEngine(CFG, params, n_slots=2, max_len=64,
-                        prefill_buckets=(8,))
+        eng = LLMEngine(CFG, params, n_slots=2, max_len=64)
         reqs = [eng.submit([3 + i], max_tokens=3) for i in range(5)]
         for _ in range(100):
             if all(r.done.is_set() for r in reqs):
@@ -195,8 +199,7 @@ class TestContinuousBatching:
         assert all(len(r.out_ids) == 3 for r in reqs)
 
     def test_engine_thread_and_streaming(self, params):
-        eng = LLMEngine(CFG, params, n_slots=2, max_len=64,
-                        prefill_buckets=(8,))
+        eng = LLMEngine(CFG, params, n_slots=2, max_len=64)
         eng.start()
         try:
             req = eng.submit([5, 9], max_tokens=6, stream=True)
@@ -217,8 +220,7 @@ class TestContinuousBatching:
         """If the engine thread dies (e.g. XLA OOM at compile), queued and
         active requests error out immediately instead of hanging until
         client timeout, and later submits are poisoned."""
-        eng = LLMEngine(CFG, params, n_slots=2, max_len=64,
-                        prefill_buckets=(8,))
+        eng = LLMEngine(CFG, params, n_slots=2, max_len=64)
         eng.step = lambda: (_ for _ in ()).throw(RuntimeError("boom"))
         req = eng.submit([5, 9], max_tokens=4, stream=True)  # pre-queued
         eng.start()
@@ -230,12 +232,13 @@ class TestContinuousBatching:
         eng.stop()
 
     def test_multi_step_matches_single_step(self, params):
-        """Fused decode windows (decode_multi) reproduce the exact greedy
-        token sequence of per-token decode_step dispatch."""
+        """Decode windows (on-device sampling, a step in flight)
+        reproduce the exact greedy token sequence of the one-step tick
+        (`decode_step_paged`, sampled on the host)."""
         eng = LLMEngine(CFG, params, n_slots=2, max_len=64,
-                        prefill_buckets=(8,), decode_block=8)
+                        decode_block=8)
         ref = LLMEngine(CFG, params, n_slots=2, max_len=64,
-                        prefill_buckets=(8,), decode_block=1)
+                        decode_block=1)
         eng.start()
         ref.start()
         try:
@@ -247,8 +250,7 @@ class TestContinuousBatching:
             ref.stop()
 
     def test_max_len_finishes_cleanly(self, params):
-        eng = LLMEngine(CFG, params, n_slots=1, max_len=12,
-                        prefill_buckets=(8,))
+        eng = LLMEngine(CFG, params, n_slots=1, max_len=12)
         req = eng.submit([1, 2, 3], max_tokens=100)
         for _ in range(50):
             if req.done.is_set():
@@ -259,13 +261,13 @@ class TestContinuousBatching:
 
 
 class TestPagedKV:
-    """Block-paged KV cache (models/paged_kv.py): exact-match vs the dense
-    engine, pool back-pressure, and preempt-by-recompute under a pool too
-    small for the working set (VERDICT r4 next #2)."""
+    """The engine over its block-paged KV cache (models/paged_kv.py):
+    every token the plain forward's greedy one, under pool back-pressure
+    and under preempt-by-recompute in a pool too small for the working
+    set (VERDICT r4 next #2)."""
 
-    def _run(self, params, prompts, *, kv_mode, max_tokens=6, **kw):
-        eng = LLMEngine(CFG, params, n_slots=4, max_len=64,
-                        prefill_buckets=(16,), kv_mode=kv_mode, **kw)
+    def _run(self, params, prompts, *, max_tokens=6, **kw):
+        eng = LLMEngine(CFG, params, n_slots=4, max_len=64, **kw)
         reqs = [eng.submit(p, max_tokens=max_tokens) for p in prompts]
         for _ in range(500):
             if all(r.done.is_set() for r in reqs):
@@ -273,46 +275,43 @@ class TestPagedKV:
             eng.step()
         assert all(r.done.is_set() for r in reqs)
         assert all(r.error is None for r in reqs)
+        plain_reference.assert_gpt_greedy(
+            CFG, params, [r.prompt_ids[:r.n_prompt] for r in reqs],
+            [r.out_ids for r in reqs], n=max_tokens)
         return [r.out_ids for r in reqs], eng
 
-    def test_paged_matches_dense(self, params):
-        """Same prompts, greedy: the paged engine emits byte-identical
-        token streams to the dense engine (the gather view reconstitutes
-        the exact dense timeline)."""
+    def test_paged_matches_plain_forward(self, lively_params):
+        """Greedy: the engine emits the plain forward's own continuation
+        of every prompt (the gather view reconstitutes each slot's exact
+        timeline), and the continuations are not one token repeated."""
         prompts = [[5, 9, 2], [17, 3], [1, 2, 3, 4, 5, 6, 7], [11]]
-        dense, _ = self._run(params, prompts, kv_mode="dense")
-        paged, eng = self._run(params, prompts, kv_mode="paged",
-                               page_size=16)
-        assert paged == dense
+        paged, eng = self._run(lively_params, prompts, page_size=16)
+        assert all(len(set(out)) > 2 for out in paged), paged
         m = eng.metrics()
         # All pages returned to the pool after the requests retired.
         assert m["kv_pages_free"] == m["kv_pages_total"]
         assert m["preemptions"] == 0
 
-    def test_pool_backpressure_queues_admissions(self, params):
+    def test_pool_backpressure_queues_admissions(self, lively_params):
         """A pool with fewer pages than slots×need still completes every
         request — admission defers instead of failing."""
         prompts = [[3 + i, 1, 4] for i in range(6)]
-        dense, _ = self._run(params, prompts, kv_mode="dense",
-                             max_tokens=4)
-        paged, eng = self._run(params, prompts, kv_mode="paged",
-                               page_size=4, n_pages=2, max_tokens=4)
-        assert paged == dense
+        paged, eng = self._run(lively_params, prompts, page_size=4,
+                               n_pages=2, prefill_chunk=4,
+                               prefill_token_budget=8, max_tokens=4)
         assert all(len(o) == 4 for o in paged)
         assert eng.metrics()["kv_pages_free"] == 2
 
-    def test_preemption_recompute_is_exact(self, params):
+    def test_preemption_recompute_is_exact(self, lively_params):
         """Pool sized so concurrent slots MUST run dry mid-generation:
         victims are evicted by recompute (context = prompt + generated)
         and still produce the exact greedy continuation."""
         prompts = [[5, 9, 2], [17, 3], [2, 4, 6], [8, 1, 0]]
-        dense, _ = self._run(params, prompts, kv_mode="dense",
-                             max_tokens=10)
         # Each request grows to 13 tokens → 4 pages of 4; four slots need
         # 16 pages but the pool has 7 → eviction pressure mid-flight.
-        paged, eng = self._run(params, prompts, kv_mode="paged",
-                               page_size=4, n_pages=7, max_tokens=10)
-        assert paged == dense
+        _, eng = self._run(lively_params, prompts, page_size=4, n_pages=7,
+                           prefill_chunk=4, prefill_token_budget=8,
+                           max_tokens=10)
         m = eng.metrics()
         assert m["preemptions"] > 0
         assert m["kv_pages_free"] == m["kv_pages_total"]
@@ -321,7 +320,6 @@ class TestPagedKV:
         """A prompt the pool can never cover is rejected loudly instead of
         requeueing forever."""
         eng = LLMEngine(CFG, params, n_slots=2, max_len=64,
-                        prefill_buckets=(16,), kv_mode="paged",
                         page_size=4, n_pages=2)
         with pytest.raises(ValueError, match="KV pages"):
             eng.submit(list(range(12)), max_tokens=4)
@@ -330,8 +328,8 @@ class TestPagedKV:
         """The engine reports device-side throughput split from the
         client path: decode tok/s, prefill tok/s, occupancy (VERDICT r4
         next #3)."""
-        _, eng = self._run(params, [[5, 9, 2], [7, 7]], kv_mode="paged",
-                           page_size=16, max_tokens=8)
+        _, eng = self._run(params, [[5, 9, 2], [7, 7]], page_size=16,
+                           max_tokens=8)
         m = eng.metrics()
         assert m["engine_decode_tok_s"] > 0
         assert m["engine_prefill_tok_s"] > 0
@@ -351,7 +349,7 @@ class TestServeIntegration:
             dep = serve.deployment(LLMDeployment, name="llm").options(
                 num_replicas=1).bind(
                 "tiny", n_slots=4, max_len=64, jax_platform="cpu",
-                engine_kwargs={"prefill_buckets": (8, 16)})
+                engine_kwargs={"page_size": 16})
             handle = serve.run(dep)
             refs = [
                 handle.method("generate", [5 + i, 9], max_tokens=4)
@@ -397,7 +395,7 @@ class TestServeIntegration:
             dep = serve.deployment(LLMDeployment, name="llmstream").options(
                 num_replicas=1, route_prefix="/llm").bind(
                 "tiny", n_slots=4, max_len=512, jax_platform="cpu",
-                engine_kwargs={"prefill_buckets": (8, 16)})
+                engine_kwargs={"page_size": 16})
             handle = serve.run(dep)
 
             # Warm: first generate compiles the prefill bucket + decode

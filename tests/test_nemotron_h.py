@@ -513,7 +513,7 @@ def test_a_fault_fails_the_tolerance(params, fault, monkeypatch):
 # ------------------------------------------------------- through LLMEngine
 
 def _engine(params, **kw):
-    opts = dict(n_slots=N_SLOTS, max_len=128, kv_mode="paged", page_size=PAGE,
+    opts = dict(n_slots=N_SLOTS, max_len=128, page_size=PAGE,
                 n_pages=N_PAGES, prefill_chunk=CHUNK, attn_impl="gather",
                 prefill_token_budget=ROWS * CHUNK)
     return LLMEngine(CFG, params, **{**opts, **kw})
@@ -590,8 +590,6 @@ def test_engine_recomputes_a_preempted_request_to_the_same_tokens(params):
 
 
 @pytest.mark.parametrize("option,value,needs", [
-    ("kv_mode", "dense", "cache backend"),
-    ("prefill_chunk", 0, "whole-prompt program"),
     ("prefill_width_bucketing", True, "every held expert"),
     ("prefix_cache", True, "snapshot"),
     ("spec_draft", "tiny", "multi-token-prediction"),

@@ -431,7 +431,7 @@ def test_a_fault_fails_the_tolerance(params, fault, monkeypatch):
 # ------------------------------------------------------- through LLMEngine
 
 def _engine(params, **kw):
-    opts = dict(n_slots=N_SLOTS, max_len=128, kv_mode="paged", page_size=PAGE,
+    opts = dict(n_slots=N_SLOTS, max_len=128, page_size=PAGE,
                 n_pages=N_PAGES, prefill_chunk=CHUNK, attn_impl="gather",
                 prefill_token_budget=ROWS * CHUNK)
     return LLMEngine(CFG, params, **{**opts, **kw})
@@ -505,8 +505,6 @@ REFUSED = [
     ("tp", 2, "pipeline stages"),
     ("weight_dtype", "int8", "int8 form of this family's tree"),
     ("kv_dtype", "int8", "float32 by the model's own definition"),
-    ("kv_mode", "dense", "cache backend"),
-    ("prefill_chunk", 0, "whole-prompt program"),
     ("prefill_width_bucketing", True, "packs rows of several widths"),
     ("pool_role", "prefill", "page set would have to carry"),
 ]
@@ -525,7 +523,7 @@ def test_the_fleet_knobs_soft_disable_for_the_family(params, monkeypatch):
     monkeypatch.setenv("RAY_TPU_LLM_KV_DTYPE", "int8")
     eng = LLMEngine(CFG, params, n_slots=2, max_len=128, page_size=PAGE,
                     n_pages=40, attn_impl="gather")     # knobs for the rest
-    assert (eng.kv_mode, eng.prefill_chunk) == ("paged", 128)
+    assert eng.prefill_chunk == 128
     assert eng.prefix_cache is None and eng.kv_dtype == "bf16"
     assert eng.tp == 1 and not eng.kv_transfer
     assert not eng.prefill_width_bucketing      # the knob's default is on

@@ -3,8 +3,8 @@
 Exact-match of the Pallas kernel path against the gather reference across
 page sizes, ragged slot lengths, and null-page tails — at the op level, at
 the jitted decode-step level (models/paged_kv.py), and end-to-end through
-the continuous-batching engine (greedy token streams identical to the
-dense engine). On CPU the kernel runs under interpret=True: the fallback
+the continuous-batching engine (greedy token streams the plain forward's
+own, tests/plain_reference.py). On CPU the kernel runs under interpret=True: the fallback
 is ASSERTED, never silently skipped — a broken pallas install fails here.
 """
 
@@ -16,6 +16,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import plain_reference
 from ray_tpu.models import gpt
 from ray_tpu.ops.paged_attention import (
     _interpret_default,
@@ -36,6 +37,12 @@ CFG = gpt.GPTConfig.tiny(attn_impl="xla", dtype=jnp.float32)
 @pytest.fixture(scope="module")
 def params():
     return gpt.init_params(CFG, jax.random.key(42))
+
+
+@pytest.fixture(scope="module")
+def lively_params(params):
+    """Weights whose greedy continuation does not settle on one token."""
+    return plain_reference.lively(params)
 
 
 def test_interpret_fallback_is_asserted_off_tpu():
@@ -703,32 +710,29 @@ class TestDecodeStepEquivalence:
     fp32-softmax tolerance, greedy tokens identical."""
 
     def _setup(self, params, *, page_size, prompt_lens):
-        from ray_tpu.models.paged_kv import init_paged_kv, prefill_batch_paged
+        from ray_tpu.models.paged_kv import init_paged_kv, prefill_chunk_paged
 
         B = len(prompt_lens)
         n_pg = 4
         rng = np.random.default_rng(7)
         n_pages = B * n_pg
         pool = init_paged_kv(CFG, n_pages, page_size)
-        bucket = 16
-        padded = np.zeros((B, bucket), np.int32)
+        chunk = 16
+        padded = np.zeros((B, chunk), np.int32)
         lengths = np.asarray(prompt_lens, np.int32)
         for i, n in enumerate(prompt_lens):
             padded[i, :n] = rng.integers(1, CFG.vocab_size, n)
-        pages = np.zeros((B, (bucket + page_size - 1) // page_size),
-                         np.int32)
         tables = np.zeros((B, n_pg), np.int32)
         nxt = 1
         for b in range(B):
             need = (prompt_lens[b] + page_size) // page_size + 1
             for j in range(min(need, n_pg)):
                 tables[b, j] = nxt
-                if j < pages.shape[1]:
-                    pages[b, j] = nxt
                 nxt += 1
-        last, pool = prefill_batch_paged(
-            CFG, params, jnp.asarray(padded), pool, jnp.asarray(pages),
-            jnp.asarray(lengths))
+        # Each prompt is one chunk row, from offset 0.
+        last, pool = prefill_chunk_paged(
+            CFG, params, jnp.asarray(padded), pool, jnp.asarray(tables),
+            jnp.zeros(B, jnp.int32), jnp.asarray(lengths))
         toks = np.argmax(np.asarray(last), axis=-1).astype(np.int32)
         return pool, jnp.asarray(tables), jnp.asarray(toks), jnp.asarray(
             lengths)
@@ -774,14 +778,14 @@ class TestDecodeStepEquivalence:
 
 
 class TestEngineKernelPath:
-    """LLMEngine(attn_impl="kernel"): token streams byte-identical to the
-    dense engine, including under pool pressure (preempt-by-recompute)."""
+    """LLMEngine(attn_impl="kernel"): every token the plain forward's
+    greedy one (tests/plain_reference.py), including under pool pressure
+    (preempt-by-recompute)."""
 
     def _run(self, params, prompts, *, max_tokens=6, **kw):
         from ray_tpu.serve.llm import LLMEngine
 
-        eng = LLMEngine(CFG, params, n_slots=4, max_len=64,
-                        prefill_buckets=(16,), **kw)
+        eng = LLMEngine(CFG, params, n_slots=4, max_len=64, **kw)
         reqs = [eng.submit(p, max_tokens=max_tokens) for p in prompts]
         for _ in range(500):
             if all(r.done.is_set() for r in reqs):
@@ -791,36 +795,35 @@ class TestEngineKernelPath:
         assert all(r.error is None for r in reqs)
         return [r.out_ids for r in reqs], eng
 
-    def test_kernel_engine_matches_dense(self, params):
+    def test_kernel_engine_matches_plain_forward(self, lively_params):
         prompts = [[5, 9, 2], [17, 3], [1, 2, 3, 4, 5, 6, 7], [11]]
-        dense, _ = self._run(params, prompts, kv_mode="dense")
-        kernel, eng = self._run(params, prompts, kv_mode="paged",
-                                page_size=16, attn_impl="kernel")
-        assert kernel == dense
+        kernel, eng = self._run(lively_params, prompts, page_size=16,
+                                attn_impl="kernel")
+        plain_reference.assert_gpt_greedy(CFG, lively_params, prompts, kernel,
+                                          n=6)
         m = eng.metrics()
         assert m["llm_attn_impl"] == "kernel"
         assert m["kv_pages_free"] == m["kv_pages_total"]
 
-    def test_kernel_engine_under_preemption(self, params):
+    def test_kernel_engine_under_preemption(self, lively_params):
         """Pool sized to force mid-generation eviction: the kernel path
-        recomputes victims exactly like gather."""
+        recomputes victims to the plain forward's tokens."""
         prompts = [[5, 9, 2], [17, 3], [2, 4, 6], [8, 1, 0]]
-        dense, _ = self._run(params, prompts, kv_mode="dense",
-                             max_tokens=10)
-        kernel, eng = self._run(params, prompts, kv_mode="paged",
-                                page_size=4, n_pages=7, max_tokens=10,
+        kernel, eng = self._run(lively_params, prompts, page_size=4,
+                                n_pages=7, max_tokens=10,
+                                prefill_chunk=4, prefill_token_budget=8,
                                 attn_impl="kernel")
-        assert kernel == dense
+        plain_reference.assert_gpt_greedy(CFG, lively_params, prompts, kernel,
+                                          n=10)
         assert eng.metrics()["preemptions"] > 0
 
     def test_gather_knob_restores_reference_path(self, params):
-        """llm_attn_impl=gather is byte-identical to the pre-kernel
-        engine (which is itself exact-match with dense, tested in
-        test_llm_serve.py)."""
+        """llm_attn_impl=gather emits the kernel engine's tokens (each
+        held to the plain forward above and in test_llm_serve.py)."""
         prompts = [[5, 9, 2], [17, 3]]
-        g, eng = self._run(params, prompts, kv_mode="paged", page_size=16,
+        g, eng = self._run(params, prompts, page_size=16,
                            attn_impl="gather")
-        k, _ = self._run(params, prompts, kv_mode="paged", page_size=16,
+        k, _ = self._run(params, prompts, page_size=16,
                          attn_impl="kernel")
         assert eng.metrics()["llm_attn_impl"] == "gather"
         assert g == k
@@ -832,8 +835,8 @@ class TestEngineKernelPath:
         from ray_tpu import profiling
         from ray_tpu.serve.llm import _DECODE_STEP_HIST
 
-        _, eng = self._run(params, [[5, 9, 2], [7, 7]], kv_mode="paged",
-                           page_size=16, attn_impl="kernel", max_tokens=8)
+        _, eng = self._run(params, [[5, 9, 2], [7, 7]], page_size=16,
+                           attn_impl="kernel", max_tokens=8)
         m = eng.metrics()
         assert m["decode_step_ms_p50"] > 0
         assert m["decode_step_ms_p95"] >= m["decode_step_ms_p50"]

@@ -42,7 +42,7 @@ def _drive(eng, reqs, max_steps=2000):
 
 
 def _engine(params, **kw):
-    base = dict(n_slots=4, max_len=128, kv_mode="paged", page_size=16,
+    base = dict(n_slots=4, max_len=128, page_size=16,
                 prefill_chunk=16, prefill_token_budget=32)
     base.update(kw)
     return LLMEngine(CFG, params, **base)
@@ -285,22 +285,18 @@ class TestLifecycle:
 
 
 class TestConfigAndParity:
-    def test_requires_paged_chunked(self, params):
-        with pytest.raises(ValueError, match="prefix_cache requires"):
-            LLMEngine(CFG, params, kv_mode="dense", prefix_cache=True)
-        with pytest.raises(ValueError, match="prefix_cache requires"):
-            _engine(params, prefill_chunk=0, prefix_cache=True)
+    def test_negative_page_budget_rejected(self, params):
         with pytest.raises(ValueError, match="prefix_cache_pages"):
             _engine(params, prefix_cache=True, prefix_cache_pages=-1)
 
-    def test_global_knob_soft_disables_on_incompatible_engine(
-            self, params, monkeypatch):
-        """Like llm_prefill_chunk: the GLOBAL knob beside a dense or
-        one-shot engine just stays off (explicit args still error)."""
+    def test_global_knob_turns_the_cache_on(self, params, monkeypatch):
+        """Every gpt engine can carry the cache (its granularity is the
+        prefill chunk, and every engine has one): the GLOBAL knob turns
+        it on, beside the default engine (no argument at all) too."""
         monkeypatch.setenv("RAY_TPU_LLM_PREFIX_CACHE", "1")
-        assert LLMEngine(CFG, params, kv_mode="dense").prefix_cache is None
-        assert _engine(params, prefill_chunk=0,
-                       prefill_token_budget=None).prefix_cache is None
+        eng = LLMEngine(CFG, params, max_len=64)
+        assert eng.prefix_cache is not None
+        assert eng.prefix_cache.chunk == eng.prefill_chunk == 64
         assert _engine(params).prefix_cache is not None
 
     def test_cache_off_parity(self, params):

@@ -12,7 +12,7 @@ precision. Then the pool contracts: the int8 KV pool's scale planes
 ride the existing page tables, so COW admission, donation/adoption,
 chaos faults, and tp reshard must all keep page-accounting closure and
 stream-level determinism with ZERO scheduler changes. Finally the knob
-surface: bad values raise, explicit int8-on-dense raises, and the
+surface: bad values raise, and the
 GLOBAL env knob soft-disables on misfit engines instead of crashing
 replica boot (the llm_tp pattern)."""
 
@@ -54,7 +54,6 @@ def draft_params():
 def _engine(params, **kw):
     kw.setdefault("n_slots", 4)
     kw.setdefault("max_len", 128)
-    kw.setdefault("kv_mode", "paged")
     kw.setdefault("page_size", PAGE)
     kw.setdefault("prefill_chunk", CHUNK)
     kw.setdefault("prefill_token_budget", 32)
@@ -348,23 +347,9 @@ class TestKnobs:
         with pytest.raises(ValueError, match="kv_dtype"):
             _engine(params, kv_dtype="int4")
 
-    def test_explicit_int8_on_dense_raises(self, params):
-        with pytest.raises(ValueError, match="paged"):
-            LLMEngine(CFG, params, kv_mode="dense", weight_dtype="int8")
-        with pytest.raises(ValueError, match="paged"):
-            LLMEngine(CFG, params, kv_mode="dense", kv_dtype="int8")
-
-    def test_global_knob_soft_off_on_dense(self, params, monkeypatch):
-        """A fleet-wide int8 export must not crash dense replicas —
-        the GLOBAL knob soft-disables to bf16 on misfit engines."""
-        monkeypatch.setenv("RAY_TPU_LLM_WEIGHT_DTYPE", "int8")
-        monkeypatch.setenv("RAY_TPU_LLM_KV_DTYPE", "int8")
-        eng = LLMEngine(CFG, params, kv_mode="dense")
-        assert eng.weight_dtype == "bf16" and eng.kv_dtype == "bf16"
-
-    def test_global_knob_applies_on_paged(self, params, monkeypatch):
-        """Same knob on a compatible engine pins the env→Config plumb
-        by actually quantizing: int8 planes + scale pool planes."""
+    def test_global_knob_applies(self, params, monkeypatch):
+        """The fleet-wide knob pins the env→Config plumb by actually
+        quantizing: int8 planes + scale pool planes."""
         monkeypatch.setenv("RAY_TPU_LLM_WEIGHT_DTYPE", "int8")
         monkeypatch.setenv("RAY_TPU_LLM_KV_DTYPE", "int8")
         eng = _engine(params)

@@ -394,8 +394,7 @@ class TestEnactedLoop:
         force_cpu_devices(1)
         cfg = gpt.GPTConfig.by_name("tiny")
         prompt = [5, 9, 2, 7, 1, 4, 3, 8]
-        engine_kwargs = {"prefill_buckets": (16, 32), "kv_mode": "paged",
-                         "page_size": 16, "prefill_chunk": 8,
+        engine_kwargs = {"page_size": 16, "prefill_chunk": 8,
                          "prefill_token_budget": 32}
         base = LLMEngine(cfg, None, n_slots=2, max_len=96, **engine_kwargs)
         ref = base.submit(prompt, max_tokens=24)
@@ -601,8 +600,7 @@ class TestOverloadShedding:
                 autoscaling_config={"min_replicas": 1, "max_replicas": 1,
                                     "target_ongoing_requests": 1.0},
             ).bind("tiny", n_slots=1, max_len=128, jax_platform="cpu",
-                   engine_kwargs={"prefill_buckets": (16, 32),
-                                  "decode_block": 1})
+                   engine_kwargs={"decode_block": 1})
             serve.run(dep, timeout=300.0)
             _proxy, port = serve.start_proxy()
             # Warm the route + the replica.
@@ -689,8 +687,7 @@ class TestAffinityCluster:
             "serve_router_spill_ongoing": 50.0,
         })
         try:
-            engine_kwargs = {"prefill_buckets": (16, 32),
-                             "kv_mode": "paged", "page_size": 16,
+            engine_kwargs = {"page_size": 16,
                              "prefill_chunk": 8,
                              "prefill_token_budget": 32,
                              "prefix_cache": True}

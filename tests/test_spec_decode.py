@@ -20,6 +20,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import plain_reference
 from ray_tpu.models import gpt
 from ray_tpu.serve.llm import LLMEngine, spec_accept_tokens
 
@@ -61,7 +62,6 @@ def _drive(eng, reqs, max_steps=2000):
 def _engine(params, *, spec=None, spec_params=None, spec_k=4, **kw):
     kw.setdefault("n_slots", 4)
     kw.setdefault("max_len", 128)
-    kw.setdefault("kv_mode", "paged")
     kw.setdefault("page_size", 16)
     kw.setdefault("prefill_chunk", 16)
     kw.setdefault("prefill_token_budget", 32)
@@ -113,17 +113,13 @@ class TestExactness:
     def test_exact_under_preemption(self, params, draft_params):
         """Pool sized so concurrent slots MUST run dry mid-generation:
         speculative growth + preempt-by-recompute still reproduce the
-        dense engine's streams exactly."""
+        plain forward's streams exactly."""
         prompts = [[5, 9, 2], [17, 3], [2, 4, 6], [8, 1, 0]]
-        dense = LLMEngine(CFG, params, n_slots=4, max_len=64,
-                          kv_mode="dense", prefill_buckets=(16,))
-        ref = _drive(dense, [dense.submit(p, max_tokens=10)
-                             for p in prompts])
         eng = _engine(params, spec=DRAFT_CFG, spec_params=draft_params,
                       spec_k=2, max_len=64, page_size=4, n_pages=7,
                       prefill_chunk=4, prefill_token_budget=8)
         out = _drive(eng, [eng.submit(p, max_tokens=10) for p in prompts])
-        assert out == ref
+        plain_reference.assert_gpt_greedy(CFG, params, prompts, out, n=10)
         m = eng.metrics()
         assert m["preemptions"] > 0
         assert m["kv_pages_free"] == m["kv_pages_total"]
@@ -147,17 +143,6 @@ class TestExactness:
 class TestKnobValidation:
     """Typed construction-time errors, the llm_prefill_chunk pattern."""
 
-    def test_dense_attention_rejected(self, params, draft_params):
-        with pytest.raises(ValueError, match="kv_mode='paged'"):
-            LLMEngine(CFG, params, kv_mode="dense",
-                      spec_draft=DRAFT_CFG, spec_draft_params=draft_params,
-                      spec_k=4)
-
-    def test_oneshot_admission_rejected(self, params, draft_params):
-        with pytest.raises(ValueError, match="prefill_chunk > 0"):
-            _engine(params, spec=DRAFT_CFG, spec_params=draft_params,
-                    prefill_chunk=0)
-
     def test_spec_k_floor(self, params, draft_params):
         with pytest.raises(ValueError, match="llm_spec_k"):
             _engine(params, spec=DRAFT_CFG, spec_params=draft_params,
@@ -176,7 +161,7 @@ class TestKnobValidation:
         the engine rejects the combination instead."""
         with pytest.raises(ValueError, match="spec_draft_params"):
             LLMEngine(CFG, params, n_slots=4, max_len=128,
-                      kv_mode="paged", page_size=16, prefill_chunk=16,
+                      page_size=16, prefill_chunk=16,
                       prefill_token_budget=32, spec_draft="",
                       spec_draft_params=draft_params)
 
@@ -190,15 +175,22 @@ class TestKnobValidation:
             eng.submit([1, 2, 3], max_tokens=4, temperature=-1.0)
 
     def test_global_knob_soft_off(self, params, monkeypatch):
-        """The GLOBAL llm_spec_draft knob alongside an incompatible
-        engine soft-disables (like llm_prefill_chunk on dense) instead
-        of erroring — only explicit constructor args are strict. The
-        positive path pins the env→Config plumb actually works: the
-        same knob on a compatible engine turns speculation ON."""
+        """The GLOBAL llm_spec_draft knob beside a model family that
+        cannot carry it soft-disables instead of erroring — only
+        explicit constructor args are strict. The positive path pins the
+        env→Config plumb actually works: the same knob on a gpt engine,
+        the default one (no argument at all) included, turns
+        speculation ON."""
+        from ray_tpu.models import zaya
+        from ray_tpu.serve.llm_options import _KNOBS, resolve_options
+
         monkeypatch.setenv("RAY_TPU_LLM_SPEC_DRAFT", "tiny")
-        eng = LLMEngine(CFG, params, kv_mode="dense")
-        assert eng.spec_k == 0
-        eng = _engine(params)  # paged + chunked: compatible
+        o = resolve_options(
+            zaya.ZayaConfig.tiny(), max_len=128, spec_draft_params=None,
+            pool_role=None, **{kw: None for kw, _field in _KNOBS})
+        assert o.spec_draft == "" and o.draft_cfg is None
+        assert LLMEngine(CFG, params, max_len=64).spec_k > 0
+        eng = _engine(params)
         assert eng.spec_k > 0
         assert eng.draft_cfg is not None
 

@@ -11,7 +11,7 @@ attention-out/MLP-down psums crossing shards (fp32-reassociation-level
 logit agreement; argmax/sampling consume replicated logits). Then the
 rule machinery itself (regex→PartitionSpec: scalar skip,
 unmatched-leaf typed error, precedence), knob validation (non-divisor
-tp, tp > devices, tp on dense/one-shot engines, global-knob soft-off),
+tp, tp > devices, global-knob soft-off),
 the sharding-topology observability fields, and the recompile-storm
 alarm attributing shard-induced recompiles to the owning program.
 """
@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec
 
+import plain_reference
 from ray_tpu.models import gpt, paged_kv, partition
 from ray_tpu.serve.llm import LLMEngine
 
@@ -59,7 +60,6 @@ def _drive(eng, reqs, max_steps=2000):
 def _engine(params, **kw):
     kw.setdefault("n_slots", 4)
     kw.setdefault("max_len", 128)
-    kw.setdefault("kv_mode", "paged")
     kw.setdefault("page_size", 16)
     kw.setdefault("prefill_chunk", 16)
     kw.setdefault("prefill_token_budget", 32)
@@ -194,14 +194,6 @@ class TestKnobValidation:
         with pytest.raises(ValueError, match="llm_tp"):
             _engine(params, tp=0)
 
-    def test_tp_on_dense_engine_rejected(self, params):
-        with pytest.raises(ValueError, match="kv_mode='paged'"):
-            LLMEngine(CFG, params, kv_mode="dense", tp=2)
-
-    def test_tp_on_oneshot_paged_rejected(self, params):
-        with pytest.raises(ValueError, match="prefill_chunk > 0"):
-            _engine(params, prefill_chunk=0, tp=2)
-
     def test_draft_non_divisor_rejected(self, params, draft_params):
         bad = gpt.GPTConfig.tiny(attn_impl="xla", dtype=jnp.float32,
                                  n_layers=1, d_model=32, n_heads=1,
@@ -212,14 +204,14 @@ class TestKnobValidation:
                         bad, jax.random.key(0)))
 
     def test_global_knob_soft_off(self, params, monkeypatch):
-        """The GLOBAL llm_tp knob alongside an incompatible engine
-        soft-disables to 1 (explicit args are strict, above); the same
-        knob on a compatible engine pins the env→Config plumb by
-        actually building the mesh."""
+        """The GLOBAL llm_tp knob on an engine it fits pins the
+        env→Config plumb by actually building the mesh, the default
+        engine (no argument at all) included; where it does not fit it
+        soft-disables to 1 (below; explicit args are strict, above)."""
         monkeypatch.setenv("RAY_TPU_LLM_TP", "2")
-        eng = LLMEngine(CFG, params, kv_mode="dense")
-        assert eng.tp == 1 and eng.mesh is None
-        eng = _engine(params)              # paged + chunked: compatible
+        eng = LLMEngine(CFG, params, max_len=64)
+        assert eng.tp == 2 and eng.mesh is not None
+        eng = _engine(params)
         assert eng.tp == 2
         assert eng.mesh is not None and eng.mesh.shape == {"tp": 2}
 
@@ -316,18 +308,14 @@ class TestExactness:
 
     def test_tp2_exact_under_preemption(self, params):
         """Pool sized so slots run dry mid-generation: preempt-by-
-        recompute on the sharded engine still reproduces the dense
-        single-chip streams (page ids are shard-invariant, so the
+        recompute on the sharded engine still reproduces the plain
+        forward's streams (page ids are shard-invariant, so the
         host-side allocator needs zero tp awareness)."""
         prompts = [[5, 9, 2], [17, 3], [2, 4, 6], [8, 1, 0]]
-        dense = LLMEngine(CFG, params, n_slots=4, max_len=64,
-                          kv_mode="dense", prefill_buckets=(16,))
-        ref = _drive(dense, [dense.submit(p, max_tokens=10)
-                             for p in prompts])
         eng = _engine(params, tp=2, max_len=64, page_size=4, n_pages=7,
                       prefill_chunk=4, prefill_token_budget=8)
         out = _drive(eng, [eng.submit(p, max_tokens=10) for p in prompts])
-        assert out == ref
+        plain_reference.assert_gpt_greedy(CFG, params, prompts, out, n=10)
         m = eng.metrics()
         assert m["preemptions"] > 0
         assert m["kv_pages_free"] == m["kv_pages_total"]

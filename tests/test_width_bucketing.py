@@ -54,7 +54,6 @@ def _drive(eng, reqs, max_steps=2000):
 def _engine(params, **kw):
     kw.setdefault("n_slots", 4)
     kw.setdefault("max_len", 128)
-    kw.setdefault("kv_mode", "paged")
     kw.setdefault("page_size", 16)
     kw.setdefault("prefill_chunk", 16)
     kw.setdefault("prefill_token_budget", 32)
@@ -199,9 +198,6 @@ class TestCompileBudget:
         eng = _engine(params, prefill_width_bucketing=True)
         assert eng.warmup_compile() > 0
         assert eng.warmup_compile() == 0       # once per engine
-        dense = LLMEngine(CFG, params, n_slots=2, max_len=64,
-                          prefill_buckets=(32,), kv_mode="dense")
-        assert dense.warmup_compile() == 0     # nothing to warm
         full = _engine(params, prefill_width_bucketing=False)
         assert full.warmup_compile() == 2      # one width: two heights, the head in both
 

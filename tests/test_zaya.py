@@ -259,7 +259,7 @@ def test_a_reused_slot_starts_from_zero_state(params):
 # ------------------------------------------------------- through LLMEngine
 
 def _engine(params, **kw):
-    opts = dict(n_slots=N_SLOTS, max_len=96, kv_mode="paged", page_size=PAGE,
+    opts = dict(n_slots=N_SLOTS, max_len=96, page_size=PAGE,
                 n_pages=N_PAGES, prefill_chunk=CHUNK, attn_impl="gather")
     return LLMEngine(CFG, params, **{**opts, **kw})
 
@@ -335,8 +335,6 @@ REFUSED = [
     ("tp", 2, "expert-parallel dispatch"),
     ("weight_dtype", "int8", "no int8 form"),
     ("kv_dtype", "int8", "scale planes"),
-    ("kv_mode", "dense", "cache backend"),
-    ("prefill_chunk", 0, "whole-prompt program"),
     ("prefill_width_bucketing", True, "packs rows of several widths"),
     ("pool_role", "prefill", "page set would have to carry"),
 ]
@@ -356,21 +354,20 @@ def test_the_fleet_knobs_soft_disable_for_the_family(params, monkeypatch):
     monkeypatch.setenv("RAY_TPU_LLM_KV_DTYPE", "int8")
     eng = LLMEngine(CFG, params, n_slots=2, max_len=128, page_size=PAGE,
                     n_pages=40, attn_impl="gather")     # knobs for the rest
-    assert (eng.kv_mode, eng.prefill_chunk) == ("paged", 128)
+    assert eng.prefill_chunk == 128
     assert eng.prefix_cache is None and eng.kv_dtype == "bf16"
     assert eng.tp == 1 and not eng.kv_transfer
     assert not eng.prefill_width_bucketing      # the knob's default is on
 
 
 def test_a_gpt_gets_exactly_todays_programs():
-    from ray_tpu.models import decode, gpt, paged_kv, serving
+    from ray_tpu.models import gpt, paged_kv, serving
 
     fam = serving.family_of(gpt.GPTConfig.tiny())
     assert fam.name == "gpt" and not fam.unsupported
     assert not fam.slot_state and not fam.expert_counters
     programs = fam.programs(1, None)
-    for name in serving._PAGED + ("prefill_batch_paged",):
+    assert set(programs) == set(serving._PAGED)
+    for name in serving._PAGED:
         assert programs[name] is getattr(paged_kv, name)
-    for name in serving._DENSE:
-        assert programs[name] is getattr(decode, name)
     assert serving.family_of(CFG).name == "zaya"
