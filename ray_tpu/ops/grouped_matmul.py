@@ -4,8 +4,8 @@
 
 `lhs` [M, K] holds its rows in contiguous groups of `sizes` [G] from row
 0; `rhs` [G, K, N] is one plane a group; the result is [M, N] float32,
-accumulated in float32. It is `jax.lax.ragged_dot` for the planes XLA:TPU's
-own kernel streams badly (ops/moe.py `_grouped_dot` says which).
+accumulated in float32. It is `jax.lax.ragged_dot` on the TPU, for every
+plane it can tile (`tiles`; ops/moe.py `_grouped_dot` asks).
 
 The kernel walks VISITS: a (group, row tile) pair for every tile of 128
 rows a non-empty group has a row in, in row order. A visit's weight
@@ -37,7 +37,9 @@ KERNEL_NAME = "moe_grouped_matmul"
 _LANES = 128
 _ROW_TILE = 128
 # One buffer of a weight block: the pipeline holds two. [1,024, 2,688]
-# bf16 is 5.5 MB; a wider plane is cut along N into equal parts.
+# bf16 is 5.5 MB; a wider plane is cut along N into equal parts (kimi-k2.6's
+# 29.4 MB in 4, mimo-v2-flash's 16.8 MB in 2). Neither the size of a part
+# nor how many are in flight moves a call: PERF.md section 6, PR 65.
 _PLANE_BYTES = 8 << 20
 _VMEM_LIMIT = 64 << 20
 
@@ -54,6 +56,13 @@ def _n_tile(K: int, N: int, itemsize: int) -> int:
         if parts % cut == 0 and K * (N // cut) * itemsize <= _PLANE_BYTES:
             return N // cut
     return _LANES
+
+
+def tiles(lhs_shape, rhs_shape) -> bool:
+    """Whether the kernel takes `lhs` [M, K] against `rhs` [G, K, N]: M a
+    multiple of 128 rows, K and N of 128 lanes."""
+    (M, K), (_, K_rhs, N) = lhs_shape, rhs_shape
+    return K_rhs == K and not (M % _ROW_TILE or K % _LANES or N % _LANES)
 
 
 def visits(sizes: jax.Array, n_rows: int):
@@ -105,8 +114,7 @@ def moe_grouped_matmul(lhs: jax.Array, rhs: jax.Array, sizes: jax.Array,
     if interpret is None:
         interpret = _interpret_default()
     (M, K), (G, _, N), tm = lhs.shape, rhs.shape, _ROW_TILE
-    if (rhs.shape[1] != K or sizes.shape != (G,) or M % tm
-            or K % _LANES or N % _LANES):
+    if not tiles(lhs.shape, rhs.shape) or sizes.shape != (G,):
         raise ValueError(
             f"moe_grouped_matmul: lhs {lhs.shape}, rhs {rhs.shape}, sizes "
             f"{sizes.shape}: rows must be a multiple of {tm}, K and N of "

@@ -23,17 +23,19 @@ a biased choice or not: models/blocks.py and the family modules); this
 layer takes its choices and gates, is told which experts of the
 router's width this chip HOLDS, sorts the held choices by expert and
 runs them as grouped matmuls, returning the held experts' part of the
-result. Which grouped matmul, `_grouped_dot` decides by the planes'
-shape alone:
+result. Which grouped matmul, `_grouped_dot` decides by the shapes it is
+handed and the backend, nothing else:
 
-    K and N multiples of 512   `jax.lax.ragged_dot`: XLA:TPU's own
-    (or either no multiple     kernel, `ragged-dot` in a trace, 55-61 %
-    of 128; off the TPU)       of the HBM peak over the held planes
-    K or N a multiple of 128   `moe_grouped_matmul` (ops/grouped_matmul.py):
-    and not of 512             XLA's kernel tiles such a plane 128 wide
-                               and streams it at 27-31 % (nemotron_h's
-                               2,688 = 21 x 128); the repo's fetches a
-                               plane in one DMA
+    on the TPU; rows, K and N   `moe_grouped_matmul` (ops/grouped_matmul.py),
+    multiples of 128            the repo's kernel: a held plane (or the
+                                part of its columns 8 MB hold) a DMA, the
+                                next in flight while this one multiplies;
+                                every served family's planes, 85-90 %
+                                of the HBM peak over the held planes in a
+                                decode step (PERF.md section 6, PR 65)
+    any other plane (the tiny   `jax.lax.ragged_dot`: XLA's own grouped
+    test families'); every      matmul, `ragged-dot` in a TPU's trace
+    plane off the TPU           (59-74 % there, 27-31 % at 2,688 wide)
 
 An expert is one of two forms, told apart by how many stacks it is
 handed:
@@ -60,8 +62,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops import scopes
-from ray_tpu.ops.grouped_matmul import moe_grouped_matmul
+from ray_tpu.ops import grouped_matmul, scopes
 
 
 @dataclasses.dataclass(frozen=True)
@@ -187,10 +188,10 @@ def moe_mlp(x: jax.Array, params: dict[str, jax.Array],
 #
 # The serving form of an expert layer. Every (row, choice) assignment is
 # kept: rows are sorted by expert, the experts THIS chip holds run as one
-# grouped matmul over their contiguous row groups (`_grouped_dot`:
-# `jax.lax.ragged_dot`, which XLA:TPU lowers to its own Mosaic kernel,
-# `ragged-dot` in a trace, or the repo's `moe_grouped_matmul` where that
-# kernel is slow), the result is unsorted and scaled by the gate. No [N, E, C]
+# grouped matmul over their contiguous row groups (`_grouped_dot`: the
+# repo's `moe_grouped_matmul` on the TPU, `jax.lax.ragged_dot` off it
+# and for a plane the kernel cannot tile), the result is unsorted and
+# scaled by the gate. No [N, E, C]
 # one-hot exists and nothing depends on a capacity. The layer is told
 # which experts it holds (`first_expert` .. + the weights' leading axis)
 # and returns only their part, so the parts of chips holding disjoint
@@ -202,8 +203,8 @@ def moe_mlp(x: jax.Array, params: dict[str, jax.Array],
 
 def _mixed_dot_default() -> bool:
     """Whether the backend is the TPU: it multiplies bf16 groups into a
-    float32 result (XLA:CPU has no such thunk for a ragged dot), and its
-    compiler's grouped matmul is the one `_narrow_tiled` knows."""
+    float32 result (XLA:CPU has no such thunk for a ragged dot), and the
+    repo's grouped matmul is compiled for it."""
     return jax.default_backend() == "tpu"
 
 
@@ -213,24 +214,16 @@ def _pad_rows(n: int) -> int:
     return 128 * (tiles + 1 - tiles % 2)
 
 
-def _narrow_tiled(K: int, N: int) -> bool:
-    """Whether XLA:TPU's grouped matmul tiles a [K, N] plane 128 wide: a
-    K or N that is a multiple of 128 and not of 512. It then streams the
-    planes at under a third of the HBM peak, against 55-60 % at the
-    widths on either side (PERF.md, PR 62's table)."""
-    return K % 128 == 0 and N % 128 == 0 and (K % 512 != 0 or N % 512 != 0)
-
-
 def _grouped_dot(lhs, rhs, sizes):
     """Rows of `lhs` [M, K], in contiguous groups of `sizes`, each
     against its group's `rhs[g]` [K, N], accumulated to float32. On the
-    TPU a plane that is `_narrow_tiled` goes to the repo's kernel
-    (`moe_grouped_matmul`, one DMA a plane); every other plane, and every
-    plane off the TPU, to `jax.lax.ragged_dot`. Off-TPU, narrower
-    operands go up to float32 first: the same values and exact products,
-    so the same sums."""
-    if _mixed_dot_default() and _narrow_tiled(*rhs.shape[-2:]):
-        return moe_grouped_matmul(lhs, rhs, sizes)
+    TPU the repo's kernel (`moe_grouped_matmul`) wherever it can tile
+    the shapes (rows, K and N multiples of 128: every served family's);
+    any other plane, and every plane off the TPU, goes to
+    `jax.lax.ragged_dot`. Off-TPU, narrower operands go up to float32
+    first: the same values and exact products, so the same sums."""
+    if _mixed_dot_default() and grouped_matmul.tiles(lhs.shape, rhs.shape):
+        return grouped_matmul.moe_grouped_matmul(lhs, rhs, sizes)
     if lhs.dtype != jnp.float32 and not _mixed_dot_default():
         lhs, rhs = lhs.astype(jnp.float32), rhs.astype(jnp.float32)
     return jax.lax.ragged_dot(lhs, rhs, sizes,

@@ -18,6 +18,7 @@ same reason; the persistent compile cache is off around these compiles
 (a described-device entry cannot be read back without a chip).
 """
 
+import contextlib
 import dataclasses
 import functools
 import re
@@ -87,6 +88,27 @@ def _compile(fn, *args, kernels=()):
         assert re.search(rf"%\w*{name}[\w.]* = [^\n]*custom-call\(", text), \
             f"no custom call named %{name}"
     return compiled
+
+
+@contextlib.contextmanager
+def _chips_answers(*modules):
+    """The backend questions of `ray_tpu.ops.<module>` steered to the
+    chip's answers: kernels compiled, not interpreted, and (`moe`) bf16
+    groups into float32 through the repo's grouped matmul."""
+    import importlib
+
+    asked = []
+    for name in modules:
+        mod = importlib.import_module("ray_tpu.ops." + name)
+        attr, answer = (("_mixed_dot_default", True) if name == "moe"
+                        else ("_interpret_default", False))
+        asked.append((mod, attr, getattr(mod, attr)))
+        setattr(mod, attr, lambda answer=answer: answer)
+    try:
+        yield
+    finally:
+        for mod, attr, fn in asked:
+            setattr(mod, attr, fn)
 
 
 def _pool(chip, dtype, heads=H, head_dim=K):
@@ -254,6 +276,21 @@ _RESULT = re.compile(r"^\s*(?:ROOT )?(%[\w.\-]+) = \(?(\w+)\[([\d,]*)\]")
 _OPCODE = re.compile(r"(?:^|\s)([a-z][\w\-.]*)\(")
 
 
+def _grouped_matmuls(text, a_layer=3):
+    """The grouped matmuls a compiled program's text holds: calls of the
+    repo's kernel, and none of the compiler's `ragged-dot` beside them.
+    The kernel's walk (the grid's length and the four arrays of scalars
+    it reads before `lhs`) is computed ONCE an expert layer: its
+    `a_layer` calls (gate, up and down; up and down of an ungated expert)
+    are handed the same five operands."""
+    assert "ragged-dot" not in text
+    calls = re.findall(
+        r"%\w*moe_grouped_matmul[\w.]* = [^\n]*?custom-call\(([^)]*)\)", text)
+    walks = {tuple(operands.split(", ")[:5]) for operands in calls}
+    assert len(walks) * a_layer == len(calls), walks
+    return len(calls)
+
+
 def _pool_moves(text, dtype, min_elems):
     """Lines of compiled HLO `text` that are a copy, dynamic-slice or
     dynamic-update-slice — the instruction itself or a fusion named
@@ -316,8 +353,6 @@ def _served(chip, cfg, stacks):
 @pytest.fixture(scope="module")
 def opt_serving(chip):
     """(cfg, params, pool) as shapes on one described chip."""
-    import importlib
-
     from ray_tpu.models import gpt, paged_kv
 
     cfg = gpt.GPTConfig.opt_1_3b(vocab_size=50272, max_seq=2048)
@@ -327,11 +362,8 @@ def opt_serving(chip):
         jax.eval_shape(lambda: paged_kv.init_paged_kv(cfg, CELL_PAGES, PS)))
     # The programs ask the backend whether to interpret; the backend here
     # is the CPU, the target is the chip — steer it in the test.
-    mod = importlib.import_module("ray_tpu.ops.paged_attention")
-    saved = mod._interpret_default
-    mod._interpret_default = lambda: False
-    yield cfg, params, pool
-    mod._interpret_default = saved
+    with _chips_answers("paged_attention"):
+        yield cfg, params, pool
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
@@ -478,9 +510,7 @@ def test_grouped_query_kernels_compile_at_head_size_128(chip):
 @pytest.fixture(scope="module")
 def zaya_serving(chip):
     """(cfg, params, pool) of the zaya cell as shapes on one described
-    chip, with the two backend questions steered to the chip's answers."""
-    import importlib
-
+    chip, with the backend questions steered to the chip's answers."""
     from ray_tpu.models import zaya
 
     cfg = zaya.ZayaConfig(n_layers=L)
@@ -489,13 +519,8 @@ def zaya_serving(chip):
         lambda x: chip(x.shape, x.dtype),
         jax.eval_shape(lambda: zaya.init_paged_kv(cfg, Z_PAGES, PS,
                                                   Z_SLOTS)))
-    attn = importlib.import_module("ray_tpu.ops.paged_attention")
-    moe = importlib.import_module("ray_tpu.ops.moe")
-    saved = attn._interpret_default, moe._mixed_dot_default
-    attn._interpret_default = lambda: False
-    moe._mixed_dot_default = lambda: True
-    yield cfg, params, pool
-    attn._interpret_default, moe._mixed_dot_default = saved
+    with _chips_answers("paged_attention", "moe", "grouped_matmul"):
+        yield cfg, params, pool
 
 
 @pytest.mark.parametrize("program", ONE_WIDTH_PROGRAMS)
@@ -516,8 +541,7 @@ def test_zaya_program_fits_and_moves_no_expert_layer(zaya_serving,
     assert re.search(rf"%\w*{kernel}[\w.]* = [^\n]*custom-call\(", text)
     # gate, up and down: three grouped matmuls over the WHOLE stack of
     # 24 x 16 experts, the layer picked by its groups' sizes.
-    assert len(re.findall(r"%ragged-dot[\w.\-]* = [^\n]*custom-call\(",
-                          text)) >= 3
+    assert _grouped_matmuls(text) == 3
     assert f"bf16[{L * cfg.n_experts},{cfg.d_model},{cfg.d_ff}]" in text
     expert_layer = cfg.n_experts * cfg.d_model * cfg.d_ff
     pool_layer = (Z_PAGES + 1) * PS * Z_G * Z_K
@@ -649,9 +673,7 @@ def test_decode_kernel_at_a_cells_shapes_fits_what_its_rule_reckons(chip,
 @pytest.fixture(scope="module")
 def laguna_serving(chip):
     """(cfg, params, pool) of the laguna cell as shapes on one described
-    chip, with the two backend questions steered to the chip's answers."""
-    import importlib
-
+    chip, with the backend questions steered to the chip's answers."""
     from ray_tpu.models import laguna
 
     cfg = laguna.LagunaConfig(n_layers=5, n_experts=128, vocab_size=50176)
@@ -661,13 +683,8 @@ def laguna_serving(chip):
         jax.eval_shape(lambda: laguna.init_paged_kv(
             cfg, G_PAGES, PS, G_SLOTS,
             dispatch_tokens=ONE_WIDTH_HEIGHTS[-1] * C)))
-    attn = importlib.import_module("ray_tpu.ops.paged_attention")
-    moe = importlib.import_module("ray_tpu.ops.moe")
-    saved = attn._interpret_default, moe._mixed_dot_default
-    attn._interpret_default = lambda: False
-    moe._mixed_dot_default = lambda: True
-    yield cfg, params, pool
-    attn._interpret_default, moe._mixed_dot_default = saved
+    with _chips_answers("paged_attention", "moe", "grouped_matmul"):
+        yield cfg, params, pool
 
 
 @pytest.mark.parametrize("program", ONE_WIDTH_PROGRAMS)
@@ -694,8 +711,8 @@ def test_laguna_program_fits_and_moves_no_expert_layer(laguna_serving,
     # Two full layers and three window layers (the plain name's pattern
     # finds the window calls too).
     assert calls(kernel + "_window") == 3 and calls(kernel) == 5
-    assert len(re.findall(r"%ragged-dot[\w.\-]* = [^\n]*custom-call\(",
-                          text)) >= 3
+    # three grouped matmuls an expert layer (four of the five layers)
+    assert _grouped_matmuls(text) == 3 * 4
     n_sparse = cfg.count("sparse")
     assert (f"bf16[{n_sparse * cfg.n_experts},{cfg.d_model},{cfg.d_ff}]"
             in text)
@@ -719,10 +736,8 @@ Q_SLOTS, Q_PAGES = 128, 8192
 def qwen3_next_serving(chip):
     """(cfg, params, pool) of the qwen3-next cell as shapes on one
     described chip, the weights as the engine serves them (the seam's
-    `lay_out`: a dense plane is a leaf a layer), with the three backend
+    `lay_out`: a dense plane is a leaf a layer), with the backend
     questions steered to the chip's answers."""
-    import importlib
-
     from ray_tpu.models import qwen3_next
 
     cfg = qwen3_next.Qwen3NextConfig(n_layers=8, n_experts=128,
@@ -732,15 +747,9 @@ def qwen3_next_serving(chip):
         lambda x: chip(x.shape, x.dtype),
         jax.eval_shape(lambda: qwen3_next.init_paged_kv(
             cfg, Q_PAGES, PS, Q_SLOTS)))
-    mods = [importlib.import_module("ray_tpu.ops." + m)
-            for m in ("paged_attention", "gated_delta", "moe")]
-    names = ("_interpret_default", "_interpret_default", "_mixed_dot_default")
-    saved = [getattr(m, n) for m, n in zip(mods, names)]
-    for m, n, answer in zip(mods, names, (False, False, True)):
-        setattr(m, n, lambda answer=answer: answer)
-    yield cfg, params, pool
-    for m, n, fn in zip(mods, names, saved):
-        setattr(m, n, fn)
+    with _chips_answers("paged_attention", "gated_delta", "moe",
+                        "grouped_matmul"):
+        yield cfg, params, pool
 
 
 @pytest.mark.parametrize("program", ONE_WIDTH_PROGRAMS)
@@ -767,8 +776,8 @@ def test_qwen3_next_program_fits_and_moves_no_state(qwen3_next_serving,
     for name, n in kernels.items():
         assert len(re.findall(rf"%\w*{name}[\w.]* = [^\n]*custom-call\(",
                               text)) == n, name
-    assert len(re.findall(r"%ragged-dot[\w.\-]* = [^\n]*custom-call\(",
-                          text)) >= 3
+    # three grouped matmuls an expert layer, all eight
+    assert _grouped_matmuls(text) == 3 * 8
     assert (f"bf16[{cfg.n_layers * cfg.n_experts},{cfg.d_model},{cfg.d_ff}]"
             in text)
     state_layer = (Q_SLOTS + 1) * 32 * 128 * 128
@@ -950,10 +959,8 @@ def test_decode_kernel_at_unequal_head_sizes_fits_what_its_rule_reckons(
 @pytest.fixture(scope="module")
 def mimo_v2_serving(chip):
     """(cfg, params, pool) of the mimo-v2-flash cell as shapes on one
-    described chip, with the two backend questions steered to the chip's
+    described chip, with the backend questions steered to the chip's
     answers."""
-    import importlib
-
     from ray_tpu.models import mimo_v2
 
     cfg = mimo_v2.MiMoV2Config(n_layers=7, n_experts=16, vocab_size=19072)
@@ -963,13 +970,8 @@ def mimo_v2_serving(chip):
         jax.eval_shape(lambda: mimo_v2.init_paged_kv(
             cfg, M_PAGES, PS, M_SLOTS,
             dispatch_tokens=ONE_WIDTH_HEIGHTS[-1] * C)))
-    attn = importlib.import_module("ray_tpu.ops.paged_attention")
-    moe = importlib.import_module("ray_tpu.ops.moe")
-    saved = attn._interpret_default, moe._mixed_dot_default
-    attn._interpret_default = lambda: False
-    moe._mixed_dot_default = lambda: True
-    yield cfg, params, pool
-    attn._interpret_default, moe._mixed_dot_default = saved
+    with _chips_answers("paged_attention", "moe", "grouped_matmul"):
+        yield cfg, params, pool
 
 
 @pytest.mark.parametrize("program", ONE_WIDTH_PROGRAMS)
@@ -998,8 +1000,8 @@ def test_mimo_v2_program_fits_and_moves_no_expert_layer(mimo_v2_serving,
     # Two full layers and five window layers (the plain name's pattern
     # finds the window calls too).
     assert calls(kernel + "_window") == 5 and calls(kernel) == 7
-    assert len(re.findall(r"%ragged-dot[\w.\-]* = [^\n]*custom-call\(",
-                          text)) >= 3
+    # three grouped matmuls an expert layer (six of the seven layers)
+    assert _grouped_matmuls(text) == 3 * 6
     n_sparse = cfg.count("sparse")
     assert (f"bf16[{n_sparse * cfg.n_experts},{cfg.d_model},{cfg.d_ff}]"
             in text)
@@ -1056,9 +1058,7 @@ J_SLOTS, J_PS, J_PAGES, J_WIDTH, J_H, J_K = 256, 128, 8192, 32, 20, 128
 @pytest.fixture(scope="module")
 def jamba_serving(chip):
     """(cfg, params, pool) of the jamba cell as shapes on one described
-    chip, with the two backend questions steered to the chip's answers."""
-    import importlib
-
+    chip, with the backend questions steered to the chip's answers."""
     from ray_tpu.models import jamba
 
     cfg = jamba.JambaConfig()
@@ -1067,14 +1067,8 @@ def jamba_serving(chip):
         lambda x: chip(x.shape, x.dtype),
         jax.eval_shape(lambda: jamba.init_paged_kv(
             cfg, J_PAGES, J_PS, J_SLOTS)))
-    mods = [importlib.import_module("ray_tpu.ops." + m)
-            for m in ("paged_attention", "selective_scan")]
-    saved = [m._interpret_default for m in mods]
-    for m in mods:
-        m._interpret_default = lambda: False
-    yield cfg, params, pool
-    for m, fn in zip(mods, saved):
-        m._interpret_default = fn
+    with _chips_answers("paged_attention", "selective_scan"):
+        yield cfg, params, pool
 
 
 @pytest.mark.parametrize("page", [64, 128])
@@ -1209,10 +1203,8 @@ K2_SLOTS, K2_PAGES, K2_WIDTH, K2_H, K2_ROW, K2_LATENT = (256, 18432, 72, 64,
 def kimi_k2_serving(chip):
     """(cfg, params, pool) of the kimi-k2.6 cell as shapes on one
     described chip (the tree the engine serves: `lay_out` over the
-    stacks), with the two backend questions steered to the chip's
+    stacks), with the backend questions steered to the chip's
     answers."""
-    import importlib
-
     from ray_tpu.models import kimi_k2
 
     cfg = kimi_k2.KimiK2Config(n_layers=5, n_experts=12, vocab_size=20480)
@@ -1221,13 +1213,8 @@ def kimi_k2_serving(chip):
         lambda x: chip(x.shape, x.dtype),
         jax.eval_shape(lambda: kimi_k2.init_paged_kv(
             cfg, K2_PAGES, PS, K2_SLOTS)))
-    attn = importlib.import_module("ray_tpu.ops.paged_attention")
-    moe = importlib.import_module("ray_tpu.ops.moe")
-    saved = attn._interpret_default, moe._mixed_dot_default
-    attn._interpret_default = lambda: False
-    moe._mixed_dot_default = lambda: True
-    yield cfg, params, pool
-    attn._interpret_default, moe._mixed_dot_default = saved
+    with _chips_answers("paged_attention", "moe", "grouped_matmul"):
+        yield cfg, params, pool
 
 
 def test_latent_kernels_compile_at_the_cells_shapes(chip):
@@ -1317,8 +1304,8 @@ def test_kimi_k2_program_fits_and_moves_no_plane(kimi_k2_serving,
     text = compiled.as_text()
     assert len(re.findall(rf"%\w*{kernel}[\w.]* = [^\n]*custom-call\(",
                           text)) == 5
-    assert len(re.findall(r"%ragged-dot[\w.\-]* = [^\n]*custom-call\(",
-                          text)) >= 3
+    # three grouped matmuls an expert layer (four of the five layers)
+    assert _grouped_matmuls(text) == 3 * 4
     assert f"bf16[{4 * 12},{cfg.d_model},{cfg.d_ff}]" in text
     moved = (_pool_moves(text, "bf16", 12 * cfg.d_model * cfg.d_ff)
              + _pool_moves(text, "bf16", (K2_PAGES + 1) * PS * K2_ROW))
@@ -1357,10 +1344,8 @@ O_SLOTS, O_PAGES, O_WIDTH, O_H, O_K = 96, 4224, 44, 30, 128
 def olmo_hybrid_serving(chip):
     """(cfg, params, pool) of the olmo-hybrid cell as shapes on one
     described chip (the tree is served as `param_specs` shapes it: the
-    family has no `lay_out`), with the two backend questions steered to
+    family has no `lay_out`), with the backend questions steered to
     the chip's answers."""
-    import importlib
-
     from ray_tpu.models import olmo_hybrid
 
     cfg = olmo_hybrid.OlmoHybridConfig(n_layers=8)
@@ -1369,14 +1354,8 @@ def olmo_hybrid_serving(chip):
         lambda x: chip(x.shape, x.dtype),
         jax.eval_shape(lambda: olmo_hybrid.init_paged_kv(
             cfg, O_PAGES, PS, O_SLOTS)))
-    mods = [importlib.import_module("ray_tpu.ops." + m)
-            for m in ("paged_attention", "gated_delta")]
-    saved = [m._interpret_default for m in mods]
-    for m in mods:
-        m._interpret_default = lambda: False
-    yield cfg, params, pool
-    for m, fn in zip(mods, saved):
-        m._interpret_default = fn
+    with _chips_answers("paged_attention", "gated_delta"):
+        yield cfg, params, pool
 
 
 def test_decode_kernels_compile_at_olmo_hybrid_rows(chip):
@@ -1480,10 +1459,8 @@ N_SLOTS, N_PAGES, N_WIDTH = 192, 6912, 36
 def nemotron_h_serving(chip):
     """(cfg, params, pool) of the nemotron-3-super cell as shapes on one
     described chip (the tree is served as `param_specs` shapes it: the
-    family has no `lay_out`), with the five backend questions steered to
+    family has no `lay_out`), with the backend questions steered to
     the chip's answers."""
-    import importlib
-
     from ray_tpu.models import nemotron_h
 
     cfg = nemotron_h.NemotronHConfig(vocab_size=32768, n_experts=128,
@@ -1493,18 +1470,9 @@ def nemotron_h_serving(chip):
         lambda x: chip(x.shape, x.dtype),
         jax.eval_shape(lambda: nemotron_h.init_paged_kv(
             cfg, N_PAGES, PS, N_SLOTS)))
-    mods = [importlib.import_module("ray_tpu.ops." + m)
-            for m in ("paged_attention", "gated_delta", "selective_scan",
-                      "grouped_matmul")]
-    moe = importlib.import_module("ray_tpu.ops.moe")
-    saved = [m._interpret_default for m in mods], moe._mixed_dot_default
-    for m in mods:
-        m._interpret_default = lambda: False
-    moe._mixed_dot_default = lambda: True
-    yield cfg, params, pool
-    for m, fn in zip(mods, saved[0]):
-        m._interpret_default = fn
-    moe._mixed_dot_default = saved[1]
+    with _chips_answers("paged_attention", "gated_delta", "selective_scan",
+                        "moe", "grouped_matmul"):
+        yield cfg, params, pool
 
 
 def test_decode_kernels_compile_at_nemotron_h_rows(chip):
@@ -1545,12 +1513,60 @@ def test_grouped_matmul_compiles_at_nemotron_h_planes(chip, K, N, rows):
     from ray_tpu.ops import grouped_matmul as gm
 
     assert gm._n_tile(K, N, 2) == N
+    _grouped_matmul_compiles(chip, K, N, rows, planes=640, held=128)
+
+
+def _grouped_matmul_compiles(chip, K, N, rows, planes, held):
+    """The repo's grouped matmul compiled for the chip at `rows` against
+    a stack of `planes` [K, N] bf16: its blocks fit the kernel's own VMEM
+    limit, no layer of `held` planes is moved, temporaries under 1 MB."""
+    from ray_tpu.ops import grouped_matmul as gm
+
+    tn = gm._n_tile(K, N, 2)
+    assert N % tn == 0 and K * tn * 2 <= gm._PLANE_BYTES
+    assert 2 * (K * tn * 2 + 128 * K * 2 + 128 * tn * 4) < gm._VMEM_LIMIT
     compiled = _compile(
         lambda a, b, s: gm.moe_grouped_matmul(a, b, s, interpret=False),
-        chip((rows, K), jnp.bfloat16), chip((640, K, N), jnp.bfloat16),
-        chip((640,), jnp.int32), kernels=(gm.KERNEL_NAME,))
-    assert not _pool_moves(compiled.as_text(), "bf16", 128 * K * N)
+        chip((rows, K), jnp.bfloat16), chip((planes, K, N), jnp.bfloat16),
+        chip((planes,), jnp.int32), kernels=(gm.KERNEL_NAME,))
+    assert not _pool_moves(compiled.as_text(), "bf16", held * K * N)
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+# configuration → (K, N of an expert's up plane, planes of the cell's
+# stack, held experts, the router's width, choices a row, decode slots)
+_SIBLING_PLANES = {
+    "zaya": (2048, 2048, L * 16, 16, None, 1, Z_SLOTS),
+    "laguna": (3072, 1024, 4 * 128, 128, 256, 10, G_SLOTS),
+    "qwen3_next": (2048, 512, 8 * 128, 128, 512, 10, Q_SLOTS),
+    "mimo_v2": (4096, 2048, 6 * 16, 16, 256, 8, M_SLOTS),
+    "kimi_k2": (7168, 2048, 4 * 12, 12, 384, 8, K2_SLOTS)}
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill-8"])
+@pytest.mark.parametrize("plane", ["up", "down"])
+@pytest.mark.parametrize("family", sorted(_SIBLING_PLANES))
+def test_grouped_matmul_compiles_at_sibling_planes(chip, family, plane,
+                                                   program):
+    """The repo's grouped matmul at the five sibling cells' shapes: a
+    block of the decode step's held rows and of the taller chunk
+    program's (`moe.block_rows`: 128 and 1,152 rows at zaya1-8b, 384 and
+    1,152 at kimi-k2.6, ...), the WHOLE stack of the cell's planes as
+    its operand (2 MB a plane at qwen3-next, 29.4 MB at kimi-k2.6),
+    float32 out. A weight block is a plane or the equal part of its
+    columns that `_PLANE_BYTES` holds (kimi-k2.6's cut in 4,
+    mimo-v2-flash's in 2), two of them in flight beside two row tiles of
+    `lhs` and of the float32 result: the chip's compiler takes it within
+    the kernel's own VMEM limit at every one, and the program holds the
+    stack once."""
+    from ray_tpu.ops import moe
+
+    K, N, planes, held, routed, k, slots = _SIBLING_PLANES[family]
+    if plane == "down":
+        K, N = N, K
+    tokens = slots if program == "decode" else 8 * C
+    rows = moe.block_rows(tokens * k, held, routed)
+    _grouped_matmul_compiles(chip, K, N, rows, planes, held)
 
 
 @pytest.mark.parametrize("program", ONE_WIDTH_PROGRAMS)
@@ -1586,8 +1602,7 @@ def test_nemotron_h_program_fits_and_moves_no_state(nemotron_h_serving,
     assert len(re.findall(r" while\(", text)) >= 1
     if program == "decode":
         assert calls("ssd_decode_step") == calls("ssm_conv_step") == 3
-    assert calls("moe_grouped_matmul") == 2 * 3
-    assert "ragged-dot" not in text
+    assert _grouped_matmuls(text, a_layer=2) == 2 * 3
     assert f"bf16[{5 * 128},1024,2688]" in text     # the stack, whole
     moved = (_pool_moves(text, "f32", (N_SLOTS + 1) * 64 * 128 * 128)
              + _pool_moves(text, "bf16", 128 * 1024 * 2688)
@@ -1692,7 +1707,7 @@ def test_decode_program_draws_only_inside_its_conditional(step_program,
 
 
 # --- the expert layer's block: the held rows alone (PR 59) ---------------
-_GROUPED = re.compile(r"%ragged-dot-none[\w.\-]* = f32\[(\d+),\d+\][^\n]*"
+_GROUPED = re.compile(r"%moe_grouped_matmul[\w.\-]* = f32\[(\d+),\d+\][^\n]*"
                       r"operand_layout_constraints=\{([^\n]*?)\}\}")
 _MOE_OPS = re.compile(r'op_name="([^"]*?)moe\.(?:route|experts)/')
 
@@ -1718,7 +1733,8 @@ def test_expert_layer_carries_only_a_block_of_held_rows(request, step_program,
     the block IS every choice (zaya holds all 16, laguna half of 256
     under top-10) the rows are the ones they were, and the expert layer
     brings no loop and no branch of its own. The grouped matmuls are the
-    compiler's `ragged-dot` in all five."""
+    repo's kernel in all five, the walk's small ops beside it in the
+    same loop and under no branch."""
     from ray_tpu.ops import moe
 
     slots, k, rows, loops = _EXPERT_BLOCKS[family]
@@ -1729,10 +1745,7 @@ def test_expert_layer_carries_only_a_block_of_held_rows(request, step_program,
                           getattr(cfg, "n_experts_routed", None)) == rows
     assert (rows == full) == (family in ("laguna", "zaya"))
     calls = _GROUPED.findall(text)
-    assert len(calls) >= 3
-    # (every plane of these five is a multiple of 512 both ways: the
-    # compiler's kernel, not the repo's: ops/moe.py `_narrow_tiled`)
-    assert "moe_grouped_matmul" not in text
+    assert len(calls) == _grouped_matmuls(text) >= 3
     for result_rows, operands in calls:
         assert int(result_rows) == rows and f"bf16[{rows}," in operands
         stack = re.search(r"bf16\[(\d+),\d+,\d+\]", operands)

@@ -334,12 +334,12 @@ def test_top_22_of_512_relu2_experts_in_a_latent(monkeypatch, held, first,
     as the program passes them. The held choices, and only they, against
     the per-token float64 loop; a shape the gated form would refuse. At a
     latent of 128 under experts 384 wide both grouped matmuls are the
-    repo's kernel's (`_narrow_tiled`; told it is on the TPU, interpreted
-    here), through the same layer."""
+    repo's kernel's (widths it can tile; told it is on the TPU,
+    interpreted here), through the same layer."""
     rng = np.random.default_rng(22)
     d_latent, f = widths
     scale = 0.3 if d_latent == 8 else 0.05
-    if moe._narrow_tiled(d_latent, f):
+    if d_latent % 128 == 0 and f % 128 == 0:
         monkeypatch.setattr(moe, "_mixed_dot_default", lambda: True)
     ids, gates = _top_k_routing(rng, n_rows, 512, 22)
     gates = gates * 2.0                                 # sum to 5
@@ -528,35 +528,51 @@ def test_block_rows_at_the_cells_shapes(n_choices, held, routed, rows, full):
 # ------- the grouped matmul of the repo's own, and where the layer takes it
 
 def _group_sizes(case, n_layers, live):
-    """→ sizes [n_layers * 6] int32, a layer's six groups non-empty only
+    """→ sizes [n_layers * groups] int32, a layer's groups non-empty only
     in layer `live`: groups of 0, 1 and many rows."""
     one = {"few": [0, 1, 5, 0, 3, 1],               # 10 rows in one tile
            "many": [1, 140, 0, 17, 131, 60],        # 349: groups across tiles
            "full": [100, 0, 28, 200, 50, 6],        # 384: no row past them
-           "none": [0] * 6}[case]
-    sizes = np.zeros(n_layers * 6, np.int32)
-    sizes[live * 6:(live + 1) * 6] = one
+           "none": [0] * 6,
+           # a zero row an expert and little else, as a decode step's are:
+           "ones": [1] * 13 + [9, 1, 2],            # 16 groups in one tile
+           "mostly_one": ([1] * 5 + [4, 1, 1, 17, 1] + [1] * 6) * 8,  # 128
+           }[case]
+    sizes = np.zeros(n_layers * len(one), np.int32)
+    sizes[live * len(one):(live + 1) * len(one)] = one
     return sizes
 
 
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
 @pytest.mark.parametrize("case,n_layers,live", [
     ("few", 1, 0), ("many", 1, 0), ("full", 1, 0), ("none", 1, 0),
-    ("few", 3, 1), ("many", 3, 1)])
+    ("few", 3, 1), ("many", 3, 1),
+    ("ones", 1, 0),             # 16 groups at 128 rows (zaya's decode step)
+    ("ones", 4, 2),             # ... one layer's of a stack, as `_layer_groups`
+    ("mostly_one", 2, 1)])      # 128 groups, most of one row (qwen3_next's)
 @pytest.mark.parametrize("K,N", [(128, 384), (384, 128), (256, 640)])
 def test_the_repos_grouped_matmul_is_ragged_dot(monkeypatch, K, N, case,
                                                 n_layers, live, dtype):
     """`moe_grouped_matmul`, interpreted, against `jax.lax.ragged_dot` in
     float32 on the same values: groups of 0, 1 and many rows, groups
-    that lie across row tiles, a stack of three layers of which only the
-    middle one's groups have rows; rows past the last group may come
-    back holding anything and are not compared. A plane wider than the
-    kernel's block budget (here made 128 x 128 values) is cut along N."""
+    that lie across row tiles, a stack of layers of which only one's
+    groups have rows (handed as `moe._layer_groups` hands them), 16
+    groups in one tile of 128 rows, 128 groups most of one row; rows
+    past the last group may come back holding anything and are not
+    compared. A plane wider than the kernel's block budget (here made
+    128 x 128 values) is cut along N: the same bits, a part at a time."""
     from ray_tpu.ops import grouped_matmul as gm
 
     rng = np.random.default_rng(K + N + n_layers)
     sizes = _group_sizes(case, n_layers, live)
-    M, n = 384, int(sizes.sum())
+    n = int(sizes.sum())
+    M = 128 if case == "ones" else 384
+    if n_layers > 1:
+        stack = jnp.zeros((n_layers, len(sizes) // n_layers, 1, 1))
+        one = sizes[sizes.size // n_layers * live:][:stack.shape[1]]
+        np.testing.assert_array_equal(
+            moe._layer_groups(jnp.asarray(one), jnp.int32(live), stack),
+            sizes)
     lhs = jnp.asarray(rng.normal(size=(M, K)), dtype)
     rhs = jnp.asarray(rng.normal(size=(len(sizes), K, N)) * 0.1, dtype)
     with jax.default_matmul_precision("highest"):
@@ -564,6 +580,7 @@ def test_the_repos_grouped_matmul_is_ragged_dot(monkeypatch, K, N, case,
                                   rhs.astype(jnp.float32), jnp.asarray(sizes))
         got = gm.moe_grouped_matmul(lhs, rhs, jnp.asarray(sizes),
                                     interpret=True)
+        assert gm._n_tile(K, N, rhs.dtype.itemsize) == N
         monkeypatch.setattr(gm, "_PLANE_BYTES", K * 128 * rhs.dtype.itemsize)
         assert gm._n_tile(K, N, rhs.dtype.itemsize) == 128
         # (the function under its `jit`: a new trace under the new budget)
@@ -574,6 +591,51 @@ def test_the_repos_grouped_matmul_is_ragged_dot(monkeypatch, K, N, case,
                                atol=2e-5)
     np.testing.assert_array_equal(np.asarray(in_parts)[:n],
                                   np.asarray(got)[:n])
+
+
+@pytest.mark.parametrize("K,N,parts", [
+    (7168, 2048, 4), (2048, 7168, 4),       # kimi-k2.6: 29.4 MB a plane
+    (4096, 2048, 2), (2048, 4096, 2),       # mimo-v2-flash: 16.8 MB
+    (2048, 2048, 1),                        # zaya1-8b: 8 MB, the budget
+    (3072, 1024, 1), (1024, 3072, 1),       # laguna-s-2.1
+    (2048, 512, 1), (512, 2048, 1),         # qwen3-next-80b-a3b: 2 MB
+    (1024, 2688, 1), (2688, 1024, 1)])      # nemotron-3-super
+def test_a_plane_over_the_block_budget_is_cut_along_n(K, N, parts):
+    """The weight block the kernel takes at every served plane, in bf16:
+    the whole plane where `_PLANE_BYTES` holds it, else the fewest equal
+    parts of N, each a multiple of 128 lanes, that it holds."""
+    from ray_tpu.ops import grouped_matmul as gm
+
+    tn = gm._n_tile(K, N, 2)
+    assert tn * parts == N and tn % 128 == 0
+    assert K * tn * 2 <= gm._PLANE_BYTES
+    # (no coarser equal cut would have fitted)
+    assert all(K * (N // cut) * 2 > gm._PLANE_BYTES
+               for cut in range(1, parts) if (N // 128) % cut == 0)
+
+
+def test_the_kernel_cut_along_n_at_a_plane_over_the_budget():
+    """A plane over `_PLANE_BYTES` as the budget stands (a quarter of
+    kimi-k2.6's in every dimension but N: [256, 16,384] float32 is 16.8
+    MB, cut in 2), interpreted, against `ragged_dot`: twelve groups in
+    one tile, a zero row an expert and a handful of others, one layer's
+    of a stack of two."""
+    from ray_tpu.ops import grouped_matmul as gm
+
+    K, N = 256, 16384
+    assert gm._n_tile(K, N, 4) == N // 2
+    rng = np.random.default_rng(12)
+    one = rng.multinomial(64, np.ones(12) / 12) + 1
+    sizes = np.concatenate([np.zeros(12, np.int32), one]).astype(np.int32)
+    n = int(sizes.sum())
+    lhs = jnp.asarray(rng.normal(size=(128, K)), jnp.float32)
+    rhs = jnp.asarray(rng.normal(size=(24, K, N)) * 0.1, jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        want = jax.lax.ragged_dot(lhs, rhs, jnp.asarray(sizes))
+        got = gm.moe_grouped_matmul(lhs, rhs, jnp.asarray(sizes),
+                                    interpret=True)
+    np.testing.assert_allclose(np.asarray(got)[:n], np.asarray(want)[:n],
+                               atol=2e-5)
 
 
 def test_the_repos_grouped_matmul_walks_no_empty_group():
@@ -620,21 +682,23 @@ def _grouped_calls(K, N, rows, groups):
     (1024, 2688, 11392, 640, True),     # ... in its chunk program
     (128, 384, 384, 6, True), (384, 128, 384, 6, True),
     (256, 640, 384, 6, True),
-    (2048, 2048, 128, 16, False),       # zaya1-8b
-    (3072, 1024, 896, 128, False), (1024, 3072, 896, 128, False),  # laguna
-    (2048, 512, 896, 128, False), (512, 2048, 896, 128, False),  # qwen3_next
-    (4096, 2048, 384, 16, False), (2048, 4096, 384, 16, False),  # mimo_v2
-    (7168, 2048, 384, 12, False), (2048, 7168, 384, 12, False),  # kimi_k2
+    (2048, 2048, 128, 16, True),        # zaya1-8b
+    (3072, 1024, 896, 128, True), (1024, 3072, 896, 128, True),  # laguna
+    (2048, 512, 896, 128, True), (512, 2048, 896, 128, True),  # qwen3_next
+    (4096, 2048, 384, 16, True), (2048, 4096, 384, 16, True),  # mimo_v2
+    (7168, 2048, 384, 12, True), (2048, 7168, 384, 12, True),  # kimi_k2
     (8, 6, 128, 8, False), (16, 12, 128, 8, False),     # the tiny families'
-    (64, 128, 128, 8, False), (1024, 2560, 384, 6, False)])
+    (64, 128, 128, 8, False), (1024, 2560, 384, 6, True)])
 def test_the_plane_says_which_grouped_matmul_runs(monkeypatch, K, N, rows,
                                                   groups, kernel):
-    """`_grouped_dot` on the TPU: the repo's kernel where a plane's K or
-    N is a multiple of 128 and not of 512 (nemotron-3-super's 2,688 =
-    21 x 128, which XLA:TPU's kernel tiles 128 wide), `ragged_dot` at
-    every plane of the five sibling configurations; off the TPU,
+    """`_grouped_dot` on the TPU: the repo's kernel wherever it can tile
+    the shapes (rows, K and N multiples of 128): every plane of the six
+    served configurations with experts, whatever its width;
+    `ragged_dot` at the tiny test families' planes; off the TPU,
     `ragged_dot` whatever the plane."""
-    assert moe._narrow_tiled(K, N) == kernel
+    from ray_tpu.ops import grouped_matmul as gm
+
+    assert gm.tiles((rows, K), (groups, K, N)) == kernel
     assert not moe._mixed_dot_default()
     assert _grouped_calls(K, N, rows, groups) == {"ragged_dot"}
     monkeypatch.setattr(moe, "_mixed_dot_default", lambda: True)
